@@ -1,0 +1,712 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             # one chip: trainer twice, then server
+    python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
+                                     # (and tp2 x pp2), nothing else
+
+GPT-2 125M at full width and depth (12 layers, H 768, 12 heads, S 1024,
+vocab 50304), random weights from the entry points' own seeds:
+
+- trainer: ``pretrain_gpt.py``'s ``main`` with the README Quick start's
+  arguments on synthetic data, once with ``--attention-impl auto`` (XLA
+  dense attention at S=1024) and once with ``--attention-impl pallas``
+  (the flash kernels);
+- server: ``tools/run_text_generation_server.py --preset gpt2-125m
+  --engine dynamic --paged-kv-cache`` answering real ``PUT /api`` requests.
+
+A chip belongs to one process at a time, so this parent imports no JAX and
+runs each phase as a child, one after the other; it learns the device from
+the ``device: {...}`` line every entry point prints at start-up. The LAST
+line of standard output is ``{"ok": ..., "device": {...}}``; the exit code
+is 0 only when every phase passed on a TPU. ``--tiny`` shrinks the sizes
+for a rehearsal of the control flow on the CPU: the phases then run, and
+the verdict still refuses because the platform is not a TPU.
+"""
+
+import argparse
+import json
+import os
+import re
+import signal
+import socket
+import statistics
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+LOG_DIR = os.path.join(ROOT, "chiprun_out")
+DEVICE_LINE_PREFIX = "device: "      # megatronapp_tpu/utils/platform.py
+RESULT_PREFIX = "smoke-result: "
+
+# README Quick start, first command; --train-iters cut to a handful and
+# every step logged so each loss and step time is on the output.
+MODEL_ARGS = ["--num-layers", "12", "--hidden-size", "768",
+              "--num-attention-heads", "12", "--seq-length", "1024"]
+# Tiny: a large init and learning rate, so that the loss visibly falls on
+# random tokens in 20 steps of 256 tokens.
+TINY_MODEL_ARGS = ["--num-layers", "2", "--hidden-size", "64",
+                   "--num-attention-heads", "4", "--seq-length", "64",
+                   "--vocab-size", "512", "--init-method-std", "0.2",
+                   "--lr", "0.01"]
+BATCH_ARGS = ["--micro-batch-size", "4", "--global-batch-size", "4",
+              "--log-interval", "1"]
+# Four-chip legs: the same global batch (8 x 1024 tokens) splits into
+# 4 / 2 / 4 microbatches of 2 on 1 device / dp2 / pp2.
+MULTI_BATCH_ARGS = ["--micro-batch-size", "2", "--global-batch-size", "8",
+                    "--train-iters", "6", "--log-interval", "1"]
+
+
+def _train_args(tiny):
+    return ((TINY_MODEL_ARGS if tiny else MODEL_ARGS) + BATCH_ARGS
+            + ["--train-iters", "20" if tiny else "30"])
+
+
+def _multi_args(tiny):
+    return (TINY_MODEL_ARGS if tiny else MODEL_ARGS) + MULTI_BATCH_ARGS
+
+
+# auto vs pallas differ only in how attention is computed (dense softmax
+# vs blockwise online softmax, both fp32 inside, bf16 matmul inputs), on
+# the same weights and the same first batch. The loss is ~10.98 (ln 50304
+# plus the initial logit variance), so 1e-2 is 0.1% of it: far above bf16
+# rounding through 12 layers (6.5e-5 on the chip, PR 23), far below what a
+# wrong mask or scale does.
+FIRST_LOSS_TOL = 1e-2
+# tp2 x dp2 / tp2 x pp2 vs one device: same seed, same global batch; what
+# differs is the order of bf16 partial sums (contractions split over tp,
+# gradients summed over dp, microbatches regrouped). Allowed to drift
+# 1e-2 (0.1% of the loss) at any of the six steps; 3.5e-4 on the chip
+# (PR 23), 4e-3 at --tiny size on the CPU.
+MULTI_LOSS_TOL = 1e-2
+
+_ITER_RE = re.compile(
+    r"iter\s+(\d+)/\s*(\d+) \| loss ([-\d.naninf]+) .*\| ([\d.]+) ms/step")
+
+
+def _say(msg=""):
+    print(msg, flush=True)
+
+
+class _Transcript:
+    """Child output: echoed with a tag, kept for parsing, written whole
+    under chiprun_out/ (the chip tool returns only the end of stdout)."""
+
+    def __init__(self, tag):
+        self.tag = tag
+        self.lines = []
+        os.makedirs(LOG_DIR, exist_ok=True)
+        self._file = open(os.path.join(LOG_DIR, f"chip_smoke_{tag}.log"),
+                          "w")
+
+    def note(self, line):
+        self.lines.append(line)
+        self._file.write(line + "\n")
+        self._file.flush()
+        # Tracebacks and warnings stay in the file; the echo keeps to
+        # what a reader of the last 24 kB needs.
+        if not line.startswith(("  ", "\t")) and len(line) < 400:
+            _say(f"[{self.tag}] {line}")
+
+    def pump(self, stream):
+        for raw in stream:
+            self.note(raw.rstrip("\n"))
+
+    def close(self):
+        self._file.close()
+
+
+def _tagged(lines, prefix):
+    """The JSON payloads of the lines that start with `prefix`."""
+    return [json.loads(ln[len(prefix):]) for ln in lines
+            if ln.startswith(prefix)]
+
+
+def _kernel_mode(dev, tiny):
+    """What a Pallas kernel must say of itself: compiled, except in a
+    --tiny rehearsal off the TPU."""
+    return ("(interpreted)" if tiny and dev and dev[0]["platform"] != "tpu"
+            else "(compiled)")
+
+
+def _spawn(tr, cmd):
+    """Start a child whose output is pumped into the transcript."""
+    proc = subprocess.Popen(
+        cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=dict(os.environ, PYTHONUNBUFFERED="1"), cwd=ROOT)
+    pump = threading.Thread(target=tr.pump, args=(proc.stdout,),
+                            daemon=True)
+    pump.start()
+    return proc, pump
+
+
+def _run_child(tag, child_args, timeout_s):
+    """Run `python chip_smoke.py --child ...` to its end; return
+    (returncode, transcript)."""
+    tr = _Transcript(tag)
+    proc, pump = _spawn(tr, [sys.executable,
+                             os.path.join(ROOT, "chip_smoke.py"),
+                             *child_args])
+    try:
+        rc = proc.wait(timeout=timeout_s)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        rc = proc.wait()
+        tr.note(f"killed after {timeout_s}s")
+    pump.join(timeout=10)
+    tr.close()
+    return rc, tr
+
+
+# ---------------------------------------------------------------------------
+# Phase: trainer
+# ---------------------------------------------------------------------------
+
+def check_train(rc, lines, impl, tiny=False):
+    """Pass/fail of one trainer run from its output lines alone (pure, so
+    the CPU tests can feed it recorded lines)."""
+    out = {"phase": f"train-{impl}", "ok": False, "problems": []}
+    bad = out["problems"].append
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    iters = [m.groups() for m in map(_ITER_RE.search, lines) if m]
+    if rc != 0:
+        bad(f"child exited {rc}")
+    if not dev:
+        bad("no device line")
+    if not res or not iters:
+        bad("no result line / no iteration lines")
+        return out
+    res = res[0]
+    losses = res["losses"]
+    step_ms = [float(g[3]) for g in iters]
+    out.update(
+        steps=len(losses), losses=losses,
+        first_step_s=round(step_ms[0] / 1e3, 3),
+        main_wall_s=res["wall_s"], compile_s=res["compile_s"],
+        cache_hits=res["cache_hits"], cache_misses=res["cache_misses"],
+        step_s_median_after_warmup=(
+            statistics.median(step_ms[2:]) / 1e3 if len(step_ms) > 2
+            else None),
+        peak_bytes_in_use=res["peak_bytes_in_use"],
+        index_builders=res["index_builders"])
+    if len(losses) != int(iters[0][1]):
+        bad(f"{len(losses)} losses for {iters[0][1]} iterations")
+    if not all(isinstance(x, float) and x == x and abs(x) < 1e30
+               for x in losses):
+        bad("a loss is not finite")
+    else:
+        q = max(len(losses) // 4, 1)
+        head, tail = sum(losses[:q]) / q, sum(losses[-q:]) / q
+        out["loss_first_quarter"], out["loss_last_quarter"] = head, tail
+        if not tail < head:
+            bad(f"loss did not fall: {head:.4f} -> {tail:.4f}")
+    att = [ln for ln in lines if ln.startswith("attention: self-attention")]
+    out["attention"] = att
+    want = ("pallas flash kernel " + _kernel_mode(dev, tiny)
+            if impl == "pallas" else "xla dense (auto)")
+    if not any(want in ln for ln in att):
+        bad(f"expected the trainer to say it ran {want!r}; it said {att}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+def phase_train(impl, tiny):
+    rc, tr = _run_child(f"train-{impl}",
+                        ["--child", "train", "--impl", impl]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_train(rc, tr.lines, impl, tiny)
+
+
+def child_train(impl, tiny):
+    """In the child: pretrain_gpt.py's main, as a command line would run
+    it, plus what only the process itself can report."""
+    import jax
+
+    _refuse_unless_tpu(jax, tiny)
+    meter = _CompileMeter(jax)
+    import pretrain_gpt         # this script's directory is sys.path[0]
+
+    argv = _train_args(tiny) + ["--attention-impl", impl]
+    _say("argv: pretrain_gpt.py " + " ".join(argv))
+    t0 = time.perf_counter()
+    result = pretrain_gpt.main(argv)
+    jax.block_until_ready(result.state)
+    wall = time.perf_counter() - t0
+    from megatronapp_tpu.data import helpers
+    builders = ("native" if helpers._LIB is not None else
+                "numpy" if helpers._LOAD_FAILED else
+                "not used (synthetic data)")
+    stats = jax.devices()[0].memory_stats() or {}
+    _say(RESULT_PREFIX + json.dumps({
+        "losses": [float(x) for x in result.losses],
+        "wall_s": round(wall, 3), **meter.report(),
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        "index_builders": builders}))
+
+
+def _refuse_unless_tpu(jax, tiny):
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not tiny:
+        raise SystemExit(f"chip_smoke: JAX found {dev.platform!r}, not a "
+                         "TPU; refusing to run the real size there")
+
+
+class _CompileMeter:
+    """Seconds JAX spent in the backend compiler (or fetching from the
+    persistent cache instead) and the cache's hit/miss counts."""
+
+    def __init__(self, jax):
+        self.seconds = 0.0
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._dur)
+        jax.monitoring.register_event_listener(self._evt)
+
+    def _dur(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _evt(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def report(self):
+        return {"compile_s": round(self.seconds, 3),
+                "cache_hits": self.hits, "cache_misses": self.misses}
+
+
+# ---------------------------------------------------------------------------
+# Phase: server
+# ---------------------------------------------------------------------------
+
+def _http(port, path, body=None, timeout=600):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}",
+        data=None if body is None else json.dumps(body).encode(),
+        method="GET" if body is None else "PUT",
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return json.loads(resp.read())
+
+
+def _generate(port, prompt_ids, n):
+    """One greedy PUT /api; returns (generated token ids, seconds)."""
+    t0 = time.perf_counter()
+    out = _http(port, "/api", {
+        "prompts": [" ".join(map(str, prompt_ids))],
+        "tokens_to_generate": n, "greedy": True})
+    return ([int(t) for t in out["segments"][0].split()],
+            time.perf_counter() - t0)
+
+
+def _prompt(length, salt):
+    return [(salt * 7919 + i * 104729) % 50000 + 1 for i in range(length)]
+
+
+def drive_server(port, tiny):
+    """The requests of the server phase, against a server already up.
+    Returns (facts, problems)."""
+    facts, problems = {}, []
+    n_new = 8 if tiny else 32
+    lengths = (5, 17, 40, 70) if not tiny else (3, 9, 20, 33)
+
+    stats0 = _http(port, "/stats")
+    facts["pool_blocks"] = stats0["pool"]["num_blocks"]
+    if stats0["pool"]["blocks_in_use"] != 0:
+        problems.append("pool in use before any request")
+
+    # 1. One request alone: its time includes every compile it triggers.
+    alone = _prompt(24 if not tiny else 12, salt=1)
+    toks_a, t_first = _generate(port, alone, n_new)
+    facts["first_answer_s_including_compiles"] = round(t_first, 3)
+    # 2. The same prompt again, greedy: same tokens.
+    toks_b, t_again = _generate(port, alone, n_new)
+    facts["same_prompt_again_s"] = round(t_again, 3)
+    if len(toks_a) != n_new or len(toks_b) != n_new:
+        problems.append(f"asked {n_new} tokens, got {len(toks_a)} and "
+                        f"{len(toks_b)}")
+    if toks_a != toks_b:
+        problems.append("same greedy prompt gave different tokens")
+
+    # 3. Several at once, different lengths, while /stats is sampled.
+    results, errors, seen = {}, [], []
+    done = threading.Event()
+
+    def ask(i, length):
+        try:
+            results[i] = _generate(port, _prompt(length, salt=10 + i),
+                                   n_new)
+        except Exception as e:  # noqa: BLE001 — reported as a problem
+            errors.append(f"request {i}: {type(e).__name__}: {e}")
+
+    def watch():
+        while not done.is_set():
+            try:
+                s = _http(port, "/stats", timeout=60)
+                seen.append((s["active"], s["pool"]["blocks_in_use"]))
+            except Exception as e:  # noqa: BLE001
+                errors.append(f"/stats: {type(e).__name__}: {e}")
+                return
+            time.sleep(0.01)
+
+    threads = [threading.Thread(target=ask, args=(i, ln))
+               for i, ln in enumerate(lengths)]
+    watcher = threading.Thread(target=watch)
+    t0 = time.perf_counter()
+    watcher.start()
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=900)
+    facts["concurrent_batch_s"] = round(time.perf_counter() - t0, 3)
+    done.set()
+    watcher.join(timeout=120)
+    problems.extend(errors)
+    for i in range(len(lengths)):
+        got = len(results.get(i, ([], 0))[0])
+        if got != n_new:
+            problems.append(f"concurrent request {i} (prompt "
+                            f"{lengths[i]}): asked {n_new}, got {got}")
+    facts["max_active_seen"] = max((a for a, _ in seen), default=0)
+    facts["max_blocks_in_use_seen"] = max((b for _, b in seen), default=0)
+
+    # 4. Steady state: everything is compiled now.
+    n_long = 16 if tiny else 128
+    toks_l, t_long = _generate(port, _prompt(lengths[1], salt=99), n_long)
+    if len(toks_l) != n_long:
+        problems.append(f"asked {n_long} tokens, got {len(toks_l)}")
+    facts["warm_single_stream_s_per_token_http_prefill_decode"] = round(
+        t_long / n_long, 5)
+
+    stats1 = _http(port, "/stats")
+    health = _http(port, "/healthz")
+    facts["driver_max_active"] = stats1.get("driver_max_active")
+    facts["blocks_in_use_after"] = stats1["pool"]["blocks_in_use"]
+    facts["prefill_tokens"] = stats1["pool"].get("prefill_tokens")
+    facts["decode_traces"] = stats1["decode_traces"]
+    facts["multiquery_traces"] = stats1["multiquery_traces"]
+    disp = stats1.get("decode_dispatch") or {}
+    facts["decode_pallas_calls_per_step"] = disp.get("kernels")
+    facts["decode_compiled"] = disp.get("compiled")
+    facts["healthz"] = {"status": health.get("status"),
+                        "stepper": health.get("stepper")}
+    if facts["max_blocks_in_use_seen"] <= 0:
+        problems.append("/stats never showed the paged pool in use")
+    if facts["blocks_in_use_after"] != 0:
+        problems.append("paged pool not released after the requests")
+    if (facts["driver_max_active"] or 0) < 2:
+        problems.append("the stepper never batched two requests")
+    if not disp.get("kernels"):
+        problems.append("no pallas_call in the traced decode step")
+    if health.get("status") != "ok":
+        problems.append(f"/healthz status {health.get('status')!r}")
+    return facts, problems
+
+
+def check_server(facts, problems, lines, tiny=False):
+    out = {"phase": "server", "ok": False, "problems": list(problems),
+           **facts}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    if not dev:
+        out["problems"].append("no device line")
+    att = [ln for ln in lines if ln.startswith("attention: paged")]
+    out["attention"] = att
+    mode = _kernel_mode(dev, tiny)
+    for site in ("paged decode", "paged multi-query"):
+        if not any(site in ln and mode in ln for ln in att):
+            out["problems"].append(
+                f"server did not say it ran the {site} kernel {mode}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+def phase_server(tiny):
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    cmd = [sys.executable,
+           os.path.join(ROOT, "tools", "run_text_generation_server.py"),
+           "--preset", "gpt2-125m", "--engine", "dynamic",
+           "--paged-kv-cache", "--host", "127.0.0.1", "--port", str(port)]
+    if tiny:
+        cmd += ["--max-seq-len", "128"]
+    tr = _Transcript("server")
+    tr.note("argv: " + " ".join(cmd[1:]))
+    proc, pump = _spawn(tr, cmd)
+    facts, problems = {}, []
+    try:
+        t0 = time.perf_counter()
+        while True:
+            if proc.poll() is not None:
+                problems.append(f"server exited {proc.returncode} before "
+                                "it answered")
+                break
+            try:
+                _http(port, "/healthz", timeout=5)
+                break
+            except OSError:
+                if time.perf_counter() - t0 > 300:
+                    problems.append("server not up after 300 s")
+                    break
+                time.sleep(0.5)
+        facts["server_up_s"] = round(time.perf_counter() - t0, 3)
+        dev = _tagged(tr.lines, DEVICE_LINE_PREFIX)
+        if not problems and dev and dev[0]["platform"] != "tpu" \
+                and not tiny:
+            problems.append(f"server is on {dev[0]['platform']!r}; not "
+                            "driving the real size there")
+        if not problems:
+            try:
+                facts2, problems = drive_server(port, tiny)
+                facts.update(facts2)
+            except Exception as e:  # noqa: BLE001 — a failed phase
+                problems.append(f"{type(e).__name__}: {e}")
+    finally:
+        if proc.poll() is None:
+            proc.send_signal(signal.SIGINT)
+            try:
+                proc.wait(timeout=30)
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+        pump.join(timeout=10)
+        tr.close()
+    return check_server(facts, problems, tr.lines, tiny)
+
+
+# ---------------------------------------------------------------------------
+# Phase: four chips (only with --chips 4)
+# ---------------------------------------------------------------------------
+
+def check_multichip(rc, lines):
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    res = _tagged(lines, RESULT_PREFIX)
+    problems = [f"child exited {rc}"] if rc != 0 else []
+    if not res:
+        problems.append("no result line")
+    res = res[0] if res else {"problems": []}
+    problems += res["problems"]
+    return {**res, "phase": "multichip", "ok": not problems,
+            "problems": problems, "device": dev[-1] if dev else None}
+
+
+def phase_multichip(tiny):
+    rc, tr = _run_child("multichip", ["--child", "multichip"]
+                        + (["--tiny"] if tiny else []), timeout_s=1100)
+    return check_multichip(rc, tr.lines)
+
+
+def child_multichip(tiny):
+    """One process holding all four chips: the same model, seed and
+    global batch on (a) one of the four devices, (b) tp2 x dp2 and
+    (c) tp2 x pp2, all through training/train.py's pretrain_gpt."""
+    import jax
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.arguments import (
+        build_parser, configs_from_args, parse_args,
+    )
+    from megatronapp_tpu.config.parallel_config import ParallelConfig
+    from megatronapp_tpu.data.mock import mock_batches
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.parallel.mesh import build_mesh
+    from megatronapp_tpu.trace.profiler_collectives import (
+        extract_hlo_collectives,
+    )
+    from megatronapp_tpu.training.optimizer import get_optimizer
+    from megatronapp_tpu.training.train import (
+        gpt_microbatch_loss, pretrain_gpt, reshape_global_batch,
+    )
+    from megatronapp_tpu.training.train_state import setup_train_state
+    from megatronapp_tpu.training.train_step import make_train_step
+
+    problems = []
+    devs = jax.devices()
+    if len(devs) != 4:
+        raise SystemExit(f"chip_smoke --chips 4: JAX sees {len(devs)} "
+                         "devices")
+    base = _multi_args(tiny)
+
+    def configs(extra):
+        _say("argv: pretrain_gpt.py " + " ".join(base + extra))
+        return configs_from_args(parse_args(build_parser("chip_smoke"),
+                                            base + extra))
+
+    def spread(x):
+        """(devices an array lives on, distinct shard index ranges)."""
+        return (len(x.sharding.device_set),
+                len({str(s.index) for s in x.addressable_shards}))
+
+    legs = {}
+    # (a) one of the four devices.
+    model, par, train, opt = configs([])
+    t0 = time.perf_counter()
+    ra = pretrain_gpt(model, ParallelConfig(), train, opt,
+                      ctx=build_mesh(ParallelConfig(), devices=devs[:1]))
+    legs["one_device"] = {"losses": [float(x) for x in ra.losses],
+                          "wall_s": round(time.perf_counter() - t0, 2)}
+    del ra
+
+    # (b) tp2 x dp2 on the mesh pretrain_gpt builds from all four.
+    model, par, train, opt = configs(["--tensor-model-parallel-size", "2"])
+    t0 = time.perf_counter()
+    rb = pretrain_gpt(model, par, train, opt)
+    leg = {"losses": [float(x) for x in rb.losses],
+           "wall_s": round(time.perf_counter() - t0, 2)}
+    ctx = build_mesh(par)
+    leg["mesh"] = dict(ctx.mesh.shape)
+    # The step as pretrain_gpt builds it, compiled once more for its
+    # text and for the sharding it takes its batch in.
+    optimizer = get_optimizer(
+        opt, train.train_iters,
+        distributed=par.distributed_optimizer and not par.fsdp)
+    state, shardings, _ = setup_train_state(
+        jax.random.PRNGKey(train.seed),
+        lambda k: init_gpt_params(k, model), optimizer, ctx)
+    step = make_train_step(gpt_microbatch_loss(model, ctx=ctx), optimizer,
+                           opt, ctx, shardings, train.train_iters,
+                           check_nan=train.check_for_nan_in_loss)
+    batch = reshape_global_batch(
+        next(mock_batches(train.seq_length, model.vocab_size,
+                          train.global_batch_size, seed=train.seed)),
+        train.num_microbatches(ctx.dp * ctx.ep))
+    with ctx.mesh:
+        compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    tokens = jax.device_put(batch["tokens"],
+                            compiled.input_shardings[0][1]["tokens"])
+    w = rb.state["params"]["block"]["attention"]["q_kernel"]
+    leg["q_kernel"] = {"shape": list(w.shape), "spec": str(w.sharding.spec),
+                       "devices,shards": spread(w)}
+    leg["batch_tokens"] = {"shape": list(tokens.shape),
+                           "spec": str(tokens.sharding.spec),
+                           "devices,shards": spread(tokens)}
+    if spread(w) != (4, 2):
+        problems.append(f"tp-sharded q_kernel on (devices, shards) "
+                        f"{spread(w)}, expected (4, 2)")
+    if spread(tokens) != (4, 2):
+        problems.append(f"batch on (devices, shards) {spread(tokens)}, "
+                        "expected (4, 2)")
+    kinds = {}
+    for c in extract_hlo_collectives(text, ctx.mesh).values():
+        kinds[c["kind"]] = kinds.get(c["kind"], 0) + 1
+    leg["collectives_in_compiled_step"] = kinds
+    leg["tpu_custom_calls"] = text.count("tpu_custom_call")
+    if not kinds:
+        problems.append("no collective in the compiled tp2 x dp2 step")
+    legs["tp2_dp2"] = leg
+    del rb, state, step, compiled
+
+    # (c) tp2 x pp2: flash attention is off inside the pipeline body
+    # (transformer/attention.py: no kernel inside a manual region).
+    model, par, train, opt = configs(
+        ["--tensor-model-parallel-size", "2",
+         "--pipeline-model-parallel-size", "2"])
+    t0 = time.perf_counter()
+    rc_ = pretrain_gpt(model, par, train, opt)
+    legs["tp2_pp2"] = {"losses": [float(x) for x in rc_.losses],
+                       "wall_s": round(time.perf_counter() - t0, 2),
+                       "mesh": dict(build_mesh(par).mesh.shape)}
+    del rc_
+
+    ref = legs["one_device"]["losses"]
+    for name in ("tp2_dp2", "tp2_pp2"):
+        got = legs[name]["losses"]
+        gap = max((abs(a - b) for a, b in zip(ref, got)),
+                  default=float("inf"))
+        legs[name]["max_abs_loss_gap_vs_one_device"] = gap
+        if len(got) != len(ref) or not gap <= MULTI_LOSS_TOL:
+            problems.append(f"{name} losses {got} vs one device {ref}: "
+                            f"gap {gap} > {MULTI_LOSS_TOL}")
+    _say(RESULT_PREFIX + json.dumps({"legs": legs, "problems": problems,
+                                     "tolerance": MULTI_LOSS_TOL}))
+
+
+# ---------------------------------------------------------------------------
+# Verdict
+# ---------------------------------------------------------------------------
+
+def verdict(phases, want_count):
+    """(ok, device, reasons): every phase passed, on a TPU, on the device
+    count this run was asked to use."""
+    reasons = []
+    device = None
+    for ph in phases:
+        dev = ph.get("device")
+        for p in ph.get("problems", []):
+            reasons.append(f"{ph['phase']}: {p}")
+        if not ph.get("ok") and not ph.get("problems"):
+            reasons.append(f"{ph['phase']}: failed")
+        if dev is None:
+            continue
+        device = device or {k: dev[k] for k in ("platform", "kind",
+                                                "count")}
+        if dev["platform"] != "tpu":
+            reasons.append(f"{ph['phase']}: ran on {dev['platform']!r}, "
+                           "not a TPU")
+        if dev["count"] != want_count:
+            reasons.append(f"{ph['phase']}: JAX saw {dev['count']} "
+                           f"devices, this run is for {want_count}")
+    if not phases:
+        reasons.append("no phase ran")
+    first = {ph["phase"]: ph["losses"][0] for ph in phases
+             if ph["phase"].startswith("train-") and ph.get("losses")}
+    if len(first) == 2:
+        gap = abs(first["train-auto"] - first["train-pallas"])
+        if not gap <= FIRST_LOSS_TOL:
+            reasons.append(f"first-step losses {first} differ by {gap}, "
+                           f"more than {FIRST_LOSS_TOL}")
+    return not reasons, device, reasons
+
+
+def run(chips, tiny):
+    phases = []
+    if chips == 4:
+        plan = [lambda: phase_multichip(tiny)]
+    else:
+        plan = [lambda: phase_train("auto", tiny),
+                lambda: phase_train("pallas", tiny),
+                lambda: phase_server(tiny)]
+    for step in plan:
+        ph = step()
+        phases.append(ph)
+        _say("phase: " + json.dumps(ph))
+        dev = ph.get("device")
+        if not tiny and (dev is None or dev["platform"] != "tpu"):
+            _say("no TPU under this phase; the remaining phases are not "
+                 "run")
+            break
+    ok, device, reasons = verdict(phases, chips)
+    for r in reasons:
+        _say("FAILED: " + r)
+    _say(json.dumps({"ok": ok, "device": device}))
+    return 0 if ok else 1
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=[1, 4], default=1)
+    ap.add_argument("--tiny", action="store_true",
+                    help="rehearsal sizes; phases may run on the CPU, the "
+                         "verdict still needs a TPU")
+    ap.add_argument("--child", choices=["train", "multichip"],
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    if args.child == "train":
+        child_train(args.impl, args.tiny)
+        return 0
+    if args.child == "multichip":
+        child_multichip(args.tiny)
+        return 0
+    return run(args.chips, args.tiny)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

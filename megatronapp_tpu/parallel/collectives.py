@@ -23,45 +23,32 @@ from jax import lax
 
 
 def shard_map_compat(body, mesh, in_specs, out_specs):
-    """FULL-MANUAL shard_map across jax versions.
+    """FULL-MANUAL shard_map: ``jax.shard_map(..., check_vma=False)`` (the
+    bodies are plain ring code; vma annotation adds nothing under full
+    manual).
 
-    Newer jax: ``jax.shard_map(..., check_vma=False)`` (the bodies are
-    plain ring code; vma annotation adds nothing under full manual).
-    jax 0.4.x (this image): ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep=False`` — the old rep checker predates varying-manual-axes
-    types and rejects valid ring accumulations.
+    Full manual (every mesh axis) is load-bearing: partial-auto manual
+    regions lower ppermute/axis_index through an SPMD path XLA:CPU aborts
+    on (spmd_partitioner IsManualSubgroup check / unsupported PartitionId)
+    — see parallel/overlap.py design notes. Axes a body does not
+    communicate over are simply threaded through the specs (split batch
+    dims) or replicated (unmentioned spec dims).
 
-    Full manual (every mesh axis) is load-bearing on this stack: the jax
-    0.4.x partial-auto manual regions lower ppermute/axis_index through an
-    SPMD path XLA:CPU aborts on (spmd_partitioner IsManualSubgroup check /
-    unsupported PartitionId) — see parallel/overlap.py design notes. Axes a
-    body does not communicate over are simply threaded through the specs
-    (split batch dims) or replicated (unmentioned spec dims).
-
-    Autodiff note (verified on jax 0.4.37): grads of inputs whose spec
-    leaves axes unmentioned come out correct — the transpose feeds output
-    cotangents to a single shard along unmentioned out-spec axes and sums
-    input cotangents across unmentioned in-spec axes — so replicated
-    params (split batch) and redundantly-computed axes both transpose
-    right without explicit psums. Explicit psums are still required for
-    reductions the MATH needs inside custom_vjp bodies (e.g. wgrads
-    across manual batch shards in overlap.py)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    Autodiff note: grads of inputs whose spec leaves axes unmentioned come
+    out correct — the transpose feeds output cotangents to a single shard
+    along unmentioned out-spec axes and sums input cotangents across
+    unmentioned in-spec axes — so replicated params (split batch) and
+    redundantly-computed axes both transpose right without explicit
+    psums. Explicit psums are still required for reductions the MATH
+    needs inside custom_vjp bodies (e.g. wgrads across manual batch
+    shards in overlap.py)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis, across jax versions.
-
-    jax 0.4.x has no ``lax.axis_size``; ``lax.psum(1, name)`` is the
-    canonical spelling there and constant-folds to a Python int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a bound mesh axis."""
+    return lax.axis_size(axis_name)
 
 
 def psum(x, axis_name):
@@ -69,48 +56,38 @@ def psum(x, axis_name):
     entry point for shard-partial reductions in full-manual bodies
     (tools/check_vma.py gate 1), e.g. the latent-column score/value
     partials of kernel_gen._tp_place_latent. Keep operands fp32 at the
-    call sites: bf16 manual all-reduces crash this XLA:CPU build
-    (README known constraints)."""
+    call sites: bf16 manual all-reduces crash XLA:CPU (README known
+    constraints)."""
     return lax.psum(x, axis_name)
 
 
 def pvary(x, axes: Tuple[str, ...]):
-    """Mark a replicated-over-``axes`` input as varying inside a manual
-    region, so its cotangent is psummed over ``axes`` exactly once.
+    """Mark a replicated-over-``axes`` input of a full-manual shard_map
+    body as contributing a partial cotangent per shard (pipeline stage
+    params over cp and the (dp, ep) microbatch shards; microbatch inputs
+    over pp), so that its cotangent is summed over ``axes`` exactly once.
 
-    Version-portable replacement for ``lax.pcast(x, axes, to="varying")``
-    at full-manual shard_map boundaries (pipeline stage params over cp and
-    the (dp, ep) microbatch shards; microbatch inputs over pp). On jax
-    0.4.x there is no pcast AND none is needed: the shard_map transpose
-    already psums input cotangents over every axis the in_spec leaves
-    unmentioned (verified on 0.4.37 — an explicit extra psum here would
-    double-count). Keep inputs fp32 at these call sites — bf16 manual
-    all-reduces crash this XLA:CPU build (README known constraints)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axes), to="varying")
+    Under ``check_vma=False`` (shard_map_compat) that sum is already what
+    shard_map's transpose does for every axis an in_spec leaves
+    unmentioned, so this is the identity: an explicit ``lax.pcast`` would
+    count twice, and its transpose (a psum of a value the untyped body
+    does not know to be varying) is refused outright. The call sites stay
+    as the record of which inputs rely on that sum. Keep those inputs
+    fp32 — bf16 manual all-reduces crash XLA:CPU (README known
+    constraints)."""
+    del axes
     return x
 
 
 def current_manual_axes() -> Tuple[str, ...]:
     """Mesh axes that are Manual in the ambient context (nested shard_maps
-    accumulate them).
-
-    Newer jax exposes this via the abstract mesh's axis types; on the
-    jax 0.4.x builds this image ships (no get_abstract_mesh/AxisType) the
-    manual axes are exactly the names shard_map bound into the tracing
-    axis env — same mechanism pmap/ppermute name resolution uses."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.shape:
-            return ()
-        Manual = jax.sharding.AxisType.Manual
-        return tuple(name for name, t in zip(m.axis_names, m.axis_types)
-                     if t == Manual)
-    try:
-        from jax._src.core import trace_ctx
-        return tuple(trace_ctx.axis_env.axis_names())
-    except (ImportError, AttributeError):
+    accumulate them), read off the abstract mesh's axis types."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.shape:
         return ()
+    Manual = jax.sharding.AxisType.Manual
+    return tuple(name for name, t in zip(m.axis_names, m.axis_types)
+                 if t == Manual)
 
 
 def ambient_manual(*axes: str) -> bool:
@@ -118,7 +95,7 @@ def ambient_manual(*axes: str) -> bool:
     the shared detection gate for code that must switch between GSPMD
     wrappers (outside any manual region) and ambient ring bodies (inside
     the full-manual pipeline/cp regions, where a nested shard_map or a
-    GSPMD collective would abort this XLA:CPU build)."""
+    GSPMD collective would abort XLA:CPU)."""
     manual = current_manual_axes()
     return all(a in manual for a in axes)
 
@@ -151,9 +128,8 @@ def span_tags(**tags):
 
     Scope caveat: custom_vjp BACKWARD ring bodies are traced during
     transposition — outside any forward-side ``with`` — so only
-    forward-pass spans carry the tag (same jax-0.4.x boundary as the
-    "pp hop spans appear on forward/eval only" scan-linearization
-    note)."""
+    forward-pass spans carry the tag (same boundary as the "pp hop spans
+    appear on forward/eval only" scan-linearization note)."""
     global _SPAN_TAGS
     prev = _SPAN_TAGS
     _SPAN_TAGS = {**prev, **tags}
@@ -171,7 +147,7 @@ def ring_span(name: str, ph: str, dep, axis_name: str, *, step=None,
     (tp-overlap-*, cp-overlap-*, moe-a2a-*, pp-overlap-*). Inserted only
     when tracing is enabled at trace time (zero overhead otherwise). Uses
     ``jax.debug.callback`` — the only callback flavor supported inside
-    shard_map manual regions in this build (ordered io_callback is
+    shard_map manual regions (ordered io_callback is
     rejected there); the data dependency on ``dep`` anchors the record
     near the op it brackets. One timeline per rank along ``axis_name``
     (tid = rank + 1; tid 0 stays the host-scope timeline).

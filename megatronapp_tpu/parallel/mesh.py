@@ -178,13 +178,11 @@ def build_mesh(parallel: ParallelConfig,
             dev_array = mesh_utils.create_hybrid_device_mesh(
                 per_slice, dcn, devices=devices)
         else:
-            try:
-                dev_array = mesh_utils.create_device_mesh(
-                    shape, devices=devices)
-            except (ValueError, NotImplementedError):
-                # Unusual topologies (e.g. subset meshes) — fall back to
-                # the enumeration order, which jax topology-sorts.
-                dev_array = np.asarray(devices).reshape(shape)
+            # No fallback: a shape the slice's topology cannot carry
+            # raises here instead of quietly running on the enumeration
+            # order.
+            dev_array = mesh_utils.create_device_mesh(
+                shape, devices=devices)
     else:
         dev_array = np.asarray(devices).reshape(shape)
     mesh = Mesh(dev_array, MESH_AXES)

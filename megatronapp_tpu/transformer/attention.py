@@ -30,6 +30,20 @@ from megatronapp_tpu.ops.normalization import rms_norm
 from megatronapp_tpu.ops import rotary
 from megatronapp_tpu.scope.hooks import scope_capture
 
+_announced: set = set()
+
+
+def _announce(site: str, impl: str, interpreted=None) -> None:
+    """Print, once per distinct choice in this process, which attention
+    implementation a call site traced — and, for a Pallas kernel, whether
+    it is compiled for the chip or interpreted (chip_smoke.py reads it)."""
+    if interpreted is not None:
+        impl += " (interpreted)" if interpreted else " (compiled)"
+    line = f"attention: {site} -> {impl}"
+    if line not in _announced:
+        _announced.add(line)
+        print(line, flush=True)
+
 
 def init_attention_params(rng, cfg: TransformerConfig, out_std: float):
     h = cfg.hidden_size
@@ -327,6 +341,7 @@ def attention_forward(
             # Multi-token paged append (speculative verify / chunked
             # prefill): write the ragged chunk then attend through the
             # multi-query kernel.
+            from megatronapp_tpu.ops.pallas import kernel_gen
             from megatronapp_tpu.ops.pallas.paged_attention import (
                 append_chunk_pages, paged_attention_multiquery,
                 paged_attention_multiquery_tp, quantize_kv_rows,
@@ -370,9 +385,12 @@ def attention_forward(
                 paged_out = paged_attention_multiquery(
                     q, ck, cv, page_table, cache_positions + counts,
                     counts, **sc_kw)
+            _announce("paged multi-query", "pallas ragged paged kernel",
+                      kernel_gen._interpret())
         elif page_table is not None:
             # Paged continuous-batching decode: kv_cache is the shared
             # block pool; cache_positions[b] is row b's append position.
+            from megatronapp_tpu.ops.pallas import kernel_gen
             from megatronapp_tpu.ops.pallas.paged_attention import (
                 append_token_pages, paged_attention_decode,
                 paged_attention_decode_tp, quantize_kv_rows,
@@ -410,6 +428,8 @@ def attention_forward(
                 paged_out = paged_attention_decode(
                     q[:, 0], ck, cv, page_table,
                     cache_positions + 1, **sc_kw)[:, None]  # [B,1,Hq,D]
+            _announce("paged decode", "pallas paged kernel",
+                      kernel_gen._interpret())
         elif cache_positions is not None:
             # Continuous-batching decode (dynamic_context.py analogue):
             # each row appends at ITS OWN position; causality MUST come
@@ -507,8 +527,9 @@ def attention_forward(
                          and nkv % ctx.tp == 0)
         if use_flash:
             from megatronapp_tpu.ops.pallas.flash_attention import (
-                flash_attention,
+                _interpret, flash_attention,
             )
+            _announce("self-attention", "pallas flash kernel", _interpret())
             causal = cfg.attn_mask_type == AttnMaskType.causal
             if multi_device:
                 from jax.sharding import PartitionSpec as P
@@ -554,6 +575,15 @@ def attention_forward(
                     segment_ids=segment_ids,
                     head_fold=getattr(cfg, "flash_head_fold", False))
         else:
+            if impl != "pallas":
+                _announce("self-attention",
+                          f"xla dense ({cfg.attention_impl})")
+            else:
+                _announce("self-attention", "xla dense, pallas asked for "
+                          "but " + ("unavailable inside a manual region"
+                                    if in_manual else
+                                    "the kernel does not take this mask/"
+                                    "cache/head split"))
             if segment_ids is not None:
                 seg_mask = (segment_ids[:, None, :, None]
                             == segment_ids[:, None, None, :])

@@ -12,9 +12,10 @@ from megatronapp_tpu.config.transformer_config import (
     ActivationKind, TransformerConfig,
 )
 
-# Peak bf16 FLOP/s per chip for MFU math (TPU v5e = 197 TFLOP/s bf16 —
-# the oft-quoted 394 is the int8 TOPS figure; v5p ≈ 459 bf16; override
-# with the actual platform at call sites if known).
+# Peak bf16 FLOP/s per chip for MFU math, keyed by a substring of the
+# lower-cased device_kind (TPU v5e = 197 TFLOP/s bf16 — the oft-quoted 394
+# is the int8 TOPS figure; v5p ≈ 459 bf16). A device that is not here has
+# no MFU: callers raise, they do not default.
 TPU_PEAK_FLOPS = {
     "v5litepod": 197e12,
     "v5 lite": 197e12,
@@ -23,7 +24,6 @@ TPU_PEAK_FLOPS = {
     "v4": 275e12,
     "v6e": 918e12,
     "v6 lite": 918e12,
-    "cpu": 1e12,
 }
 
 

@@ -1,0 +1,49 @@
+"""Start-up helpers shared by the entry points: where compiled programs are
+cached, and the one line that says which device a run is on.
+
+Entry points (config/arguments.py parse_args, tools/
+run_text_generation_server.py, bench.py's child, chip_smoke.py's children)
+call ``enable_compile_cache()`` before their first jit and print
+``device_line()`` once the mesh is known.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+from typing import Optional
+
+import jax
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+DEVICE_LINE_PREFIX = "device: "
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at ONE place and return it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is set here. Otherwise the cache is ``<checkout>/.jax_cache``:
+    the directory is part of what makes an entry findable again, so it is
+    never derived from the working directory, a temporary name, a process
+    id or the time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_line(mesh: Optional[jax.sharding.Mesh] = None) -> str:
+    """``device: {...}`` — platform, device_kind and device count as JAX
+    reports them, plus the mesh shape the run placed itself on."""
+    dev = jax.devices()[0]
+    return DEVICE_LINE_PREFIX + json.dumps({
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices()),
+        "mesh": None if mesh is None else dict(mesh.shape),
+    })

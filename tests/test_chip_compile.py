@@ -1,0 +1,254 @@
+"""The chip's compiler accepts the main path at real widths.
+
+Every test compiles for a DESCRIBED TPU v5e 2x2 (no chip attached, nothing
+runs): the Pallas kernels of the training and serving path, and the steps
+the entry points jit around them. A compile that passes is not a chip run
+— `chip_smoke.py` is — but what the chip's compiler refuses here costs no
+chip time (on-chip-measurement guide, section 2).
+
+This is the only file of its kind, on purpose: the process that describes
+the topology loads the TPU library and keeps it until it exits, so a second
+such file could land on another xdist worker and skip itself. The topology
+is described inside a module-scoped fixture, never at import.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from megatronapp_tpu.config.parallel_config import ParallelConfig
+from megatronapp_tpu.models.presets import PRESETS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_compile(monkeypatch):
+    """Kernels compiled (not interpreted), the persistent compilation cache
+    off: an entry written for a described chip cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from megatronapp_tpu.ops.pallas import flash_attention, kernel_gen
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(kernel_gen, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _custom_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------------------
+# Kernels
+# ---------------------------------------------------------------------------
+
+FLASH_SHAPES = {
+    "gpt2-125m-B4-S1024-H12-D64": (4, 1024, 12, 64),
+    "B2-S4096-H16-D128": (2, 4096, 16, 128),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_attention(one_chip, chip_compile, shape, backward):
+    from megatronapp_tpu.ops.pallas.flash_attention import flash_attention
+    x = _sds(FLASH_SHAPES[shape], jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    # forward kernel; backward adds dq and dk/dv
+    assert _custom_calls(compiled) >= (3 if backward else 1)
+
+
+@pytest.mark.parametrize("q_len", [1, 32], ids=["decode", "multiquery-32"])
+def test_paged_attention(one_chip, chip_compile, q_len):
+    """GPT-2 125M head shapes (Hq 12 / Hkv 12 / D 64), bf16 pool of 1024
+    blocks of 16, batch 4, up to 1024 cached positions a row."""
+    from megatronapp_tpu.ops.pallas.paged_attention import (
+        paged_attention_decode, paged_attention_multiquery,
+    )
+    b, hq, hkv, d, nb, bs = 4, 12, 12, 64, 1024, 16
+    pages = _sds((nb, bs, hkv, d), jnp.bfloat16, one_chip)
+    table = _sds((b, 1024 // bs), jnp.int32, one_chip)
+    lens = _sds((b,), jnp.int32, one_chip)
+    if q_len == 1:
+        q = _sds((b, hq, d), jnp.bfloat16, one_chip)
+        compiled = jax.jit(paged_attention_decode).lower(
+            q, pages, pages, table, lens).compile()
+    else:
+        q = _sds((b, q_len, hq, d), jnp.bfloat16, one_chip)
+        compiled = jax.jit(paged_attention_multiquery).lower(
+            q, pages, pages, table, lens, lens).compile()
+    assert _custom_calls(compiled) == 1
+
+
+# ---------------------------------------------------------------------------
+# The steps the entry points jit
+# ---------------------------------------------------------------------------
+
+def _train_step_for(devices, parallel, model, micro, global_batch, seq):
+    """(jitted step, abstract state, abstract batch, ctx) exactly as
+    pretrain_gpt assembles them, on a mesh of described devices. The state
+    is only traced (eval_shape), never placed."""
+    from megatronapp_tpu.config.training_config import (
+        OptimizerConfig, TrainingConfig,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.parallel.mesh import build_mesh
+    from megatronapp_tpu.training.optimizer import get_optimizer
+    from megatronapp_tpu.training.train import gpt_microbatch_loss
+    from megatronapp_tpu.training.train_state import setup_train_state
+    from megatronapp_tpu.training.train_step import (
+        batch_shardings, make_train_step,
+    )
+    ctx = build_mesh(parallel, devices=devices)
+    train = TrainingConfig(micro_batch_size=micro,
+                           global_batch_size=global_batch, seq_length=seq,
+                           train_iters=10)
+    opt = OptimizerConfig()
+    optimizer = get_optimizer(opt, train.train_iters,
+                              distributed=parallel.distributed_optimizer)
+    captured = {}
+
+    def init(rng):
+        state, shardings, _ = setup_train_state(
+            rng, lambda k: init_gpt_params(k, model), optimizer, ctx)
+        captured["shardings"] = shardings
+        return state
+
+    struct = jax.eval_shape(init, jax.random.PRNGKey(0))
+    shardings = captured["shardings"]
+    state = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
+                         struct, shardings)
+    step = make_train_step(gpt_microbatch_loss(model, ctx=ctx), optimizer,
+                           opt, ctx, shardings, train.train_iters)
+    num_micro = train.num_microbatches(ctx.dp * ctx.ep)
+    shape = (num_micro, global_batch // num_micro, seq)
+    bsh = batch_shardings(ctx)
+    batch = {"tokens": _sds(shape, jnp.int32, bsh),
+             "labels": _sds(shape, jnp.int32, bsh),
+             "loss_mask": _sds(shape, jnp.float32, bsh),
+             "position_ids": _sds(shape, jnp.int32, bsh)}
+    return step, state, batch, ctx
+
+
+def test_gpt2_125m_loss_and_grad_with_flash(one_chip, chip_compile):
+    """Full depth and width, micro-batch 4 x 1024, selective remat, the
+    flash kernels on: forward, dq, dk/dv (and the remat'd forward)."""
+    from megatronapp_tpu.models.gpt import gpt_loss, init_gpt_params
+    cfg = PRESETS["gpt2-125m"](attention_impl="pallas",
+                               remat_policy="selective")
+    params = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                       jax.random.PRNGKey(0)))
+    tok = _sds((4, 1024), jnp.int32, one_chip)
+    mask = _sds((4, 1024), jnp.float32, one_chip)
+
+    def loss(p, tokens, labels, loss_mask):
+        return gpt_loss(p, tokens, labels, loss_mask, cfg)[0]
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        params, tok, tok, mask).compile()
+    assert _custom_calls(compiled) >= 3     # "tpu_custom_call" in the text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16 * 2**30
+
+
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
+def test_train_step_one_chip(topo, chip_compile, impl):
+    """make_train_step's jit (optimizer, donation, NaN guard and all) on
+    the one-device mesh build_mesh lays out for a TPU; real widths, depth
+    cut to 2 layers to keep the test short."""
+    model = PRESETS["gpt2-125m"](num_layers=2, attention_impl=impl)
+    step, state, batch, ctx = _train_step_for(
+        topo.devices[:1], ParallelConfig(), model, micro=4, global_batch=4,
+        seq=1024)
+    with ctx.mesh:
+        compiled = step.lower(state, batch).compile()
+    assert (_custom_calls(compiled) > 0) == (impl == "pallas")
+
+
+def test_train_step_tp2_dp2(topo, chip_compile):
+    """The sharded step of `chip_smoke.py --chips 4`: tp 2 x dp 2 on the
+    2x2 mesh create_device_mesh lays out, collectives and all."""
+    model = PRESETS["gpt2-125m"](num_layers=2)
+    step, state, batch, ctx = _train_step_for(
+        topo.devices, ParallelConfig(tensor_parallel=2), model, micro=2,
+        global_batch=8, seq=1024)
+    assert dict(ctx.mesh.shape) == {"pp": 1, "dp": 2, "ep": 1, "cp": 1,
+                                    "tp": 2}
+    assert len(set(ctx.mesh.devices.ravel().tolist())) == 4
+    with ctx.mesh:
+        compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    per_device = compiled.memory_analysis()
+    assert per_device.argument_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_paged_steps(one_chip, chip_compile, which):
+    """DynamicInferenceEngine's own jits at GPT-2 125M widths (depth cut
+    to 2): the paged decode step at batch 4 and the one-chunk prefill."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    cfg = PRESETS["gpt2-125m"](num_layers=2)
+    params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=4, paged=True)
+
+    def spec(a):
+        return _sds(a.shape, a.dtype, one_chip)
+
+    p = jax.tree.map(spec, eng.params)
+    pages = jax.tree.map(spec, eng.pool.pages)
+    scales = jax.tree.map(spec, eng.pool.scales)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    if which == "decode":
+        b = eng.max_batch
+        compiled = eng._decode.lower(
+            p, i32(b, 1), pages, scales, i32(b, mb), i32(b),
+            _sds((b,), jnp.bool_, one_chip), None).compile()
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pages, scales, i32(1, mb),
+            i32(1), i32(1), _sds((1,), jnp.bool_, one_chip),
+            None).compile()
+    assert _custom_calls(compiled) >= 1

@@ -1,0 +1,286 @@
+"""chip_smoke.py's control flow on the CPU, and the compile-cache helper.
+
+The real run is on the chip (`python chip_smoke.py` through the chip tool).
+Here: the verdict logic on recorded lines, one end-to-end rehearsal of each
+mode at `--tiny` size in child processes, and the refusal to report success
+on anything but a TPU.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402 — the parent half imports no JAX
+
+TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "mesh": None}
+CONTRACT_LINE = ('{"ok": true, "device": {"platform": "tpu", '
+                 '"kind": "TPU v5 lite", "count": 1}}')
+
+
+# ---------------------------------------------------------------------------
+# Compile cache helper
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_config():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_is_left_to_jax(monkeypatch, cache_config):
+    from megatronapp_tpu.utils import platform
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: updates.append(a))
+    assert platform.enable_compile_cache() == "/somewhere/else"
+    assert updates == []          # the code set no directory of its own
+
+
+def test_compile_cache_default_is_one_fixed_path(monkeypatch, tmp_path,
+                                                 cache_config):
+    from megatronapp_tpu.utils import platform
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    paths = []
+    for name in ("a", "b"):
+        d = tmp_path / name
+        d.mkdir()
+        monkeypatch.chdir(d)
+        paths.append(platform.enable_compile_cache())
+        assert jax.config.jax_compilation_cache_dir == paths[-1]
+    assert paths[0] == paths[1] == os.path.join(ROOT, ".jax_cache")
+
+
+def test_entry_points_call_the_helper():
+    """parse_args (every pretrain_*.py), the server tool and bench.py's
+    child all go through enable_compile_cache; nobody sets a directory of
+    their own."""
+    for rel in ("megatronapp_tpu/config/arguments.py",
+                "tools/run_text_generation_server.py", "bench.py"):
+        with open(os.path.join(ROOT, rel)) as f:
+            assert "enable_compile_cache()" in f.read(), rel
+    hits = subprocess.run(
+        ["grep", "-rl", "--include=*.py", "compilation_cache_dir", ROOT,
+         "--exclude-dir=.git", "--exclude-dir=tests",
+         "--exclude-dir=build"],
+        capture_output=True, text=True).stdout.split()
+    assert [os.path.relpath(h, ROOT) for h in hits] == [
+        "megatronapp_tpu/utils/platform.py"]
+
+
+# ---------------------------------------------------------------------------
+# Verdict logic on recorded lines
+# ---------------------------------------------------------------------------
+
+def _train_lines(losses, impl="pallas", device=TPU, mode="(compiled)"):
+    att = ("attention: self-attention -> pallas flash kernel " + mode
+           if impl == "pallas" else
+           "attention: self-attention -> xla dense (auto)")
+    lines = [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device), att]
+    for i, loss in enumerate(losses):
+        lines.append(f"iter {i+1:6d}/{len(losses)} | loss {loss:.4f} | "
+                     f"grad_norm 1.000 | lr 3.00e-04 | skipped 0 | "
+                     f"{5000.0 if i == 0 else 50.0:.1f} ms/step | "
+                     "80,000 tok/s | 70.0 TFLOP/s/dev")
+    lines.append(chip_smoke.RESULT_PREFIX + json.dumps({
+        "losses": losses, "wall_s": 9.0, "compile_s": 4.5, "cache_hits": 0,
+        "cache_misses": 3, "peak_bytes_in_use": 123,
+        "index_builders": "not used (synthetic data)"}))
+    return lines
+
+
+FALLING = [10.98 - 0.004 * i for i in range(12)]
+
+
+def test_check_train_passes_on_a_good_run():
+    out = chip_smoke.check_train(0, _train_lines(FALLING), "pallas")
+    assert out["ok"], out["problems"]
+    assert out["steps"] == 12 and out["first_step_s"] == 5.0
+    assert out["step_s_median_after_warmup"] == pytest.approx(0.05)
+    assert out["device"]["platform"] == "tpu"
+
+
+@pytest.mark.parametrize("case,needle", [
+    ("nan", "not finite"),
+    ("flat", "did not fall"),
+    ("interpreted", "expected the trainer to say"),
+    ("wrong-impl", "expected the trainer to say"),
+    ("crashed", "child exited 1"),
+    ("silent", "no result line"),
+])
+def test_check_train_names_what_failed(case, needle):
+    losses, impl, mode, rc = FALLING, "pallas", "(compiled)", 0
+    if case == "nan":
+        losses = FALLING[:5] + [float("nan")] + FALLING[6:]
+    elif case == "flat":
+        losses = [10.98] * 12
+    elif case == "interpreted":
+        mode = "(interpreted)"       # on a TPU the kernel must be compiled
+    elif case == "crashed":
+        rc = 1
+    lines = _train_lines(losses, "auto" if case == "wrong-impl" else impl,
+                         mode=mode)
+    if case == "silent":
+        lines = lines[:2]
+    out = chip_smoke.check_train(rc, lines, impl)
+    assert not out["ok"]
+    assert any(needle in p for p in out["problems"]), out["problems"]
+
+
+def _phase(name, ok=True, device=TPU, **kw):
+    return {"phase": name, "ok": ok,
+            "problems": [] if ok else ["it broke"], "device": device, **kw}
+
+
+def test_verdict():
+    good = [_phase("train-auto", losses=[10.98]),
+            _phase("train-pallas", losses=[10.981]), _phase("server")]
+    ok, device, reasons = chip_smoke.verdict(good, 1)
+    assert ok and not reasons
+    assert device == {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+    def refused(phases, want=1):
+        ok, _, reasons = chip_smoke.verdict(phases, want)
+        assert not ok
+        return " | ".join(reasons)
+
+    assert "it broke" in refused(good[:2] + [_phase("server", ok=False)])
+    cpu = dict(TPU, platform="cpu", kind="cpu")
+    assert "not a TPU" in refused(good[:2] + [_phase("server", device=cpu)])
+    assert "this run is for 4" in refused(good, want=4)
+    assert "differ by" in refused(
+        [good[0], _phase("train-pallas", losses=[11.2]), good[2]])
+    assert "no phase ran" in refused([])
+
+
+def _run_with(monkeypatch, capsys, server_ok):
+    monkeypatch.setattr(
+        chip_smoke, "phase_train",
+        lambda impl, tiny: _phase(f"train-{impl}", losses=[10.98]))
+    monkeypatch.setattr(chip_smoke, "phase_server",
+                        lambda tiny: _phase("server", ok=server_ok))
+    rc = chip_smoke.main([])
+    return rc, capsys.readouterr().out.strip().splitlines()
+
+
+def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
+    rc, lines = _run_with(monkeypatch, capsys, server_ok=True)
+    assert rc == 0
+    assert lines[-1] == CONTRACT_LINE
+    phases = [json.loads(ln[len("phase: "):]) for ln in lines
+              if ln.startswith("phase: ")]
+    assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
+                                            "server"]
+
+
+def test_a_failing_phase_fails_the_run(monkeypatch, capsys):
+    rc, lines = _run_with(monkeypatch, capsys, server_ok=False)
+    assert rc != 0
+    last = json.loads(lines[-1])
+    assert last["ok"] is False and last["device"]["platform"] == "tpu"
+    assert any(ln.startswith("FAILED: server") for ln in lines)
+
+
+@pytest.mark.parametrize("module", ["chip_smoke", "bench"])
+def test_parents_that_start_children_import_no_jax(module):
+    """A parent that has touched JAX holds the chip its child needs."""
+    code = (f"import sys, {module}; "
+            "sys.exit(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules))")
+    assert subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          timeout=120).returncode == 0
+
+
+def test_bench_without_a_chip_fails_and_prints_no_record(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    proc = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
+                          capture_output=True, text=True, timeout=300,
+                          env=env, cwd=str(tmp_path))
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""          # no number under any name
+    assert "needs a TPU" in proc.stderr
+
+
+def test_no_peak_rate_for_a_cpu():
+    from megatronapp_tpu.utils.flops import TPU_PEAK_FLOPS
+    assert not any("cpu" in k for k in TPU_PEAK_FLOPS)
+    assert [v for k, v in TPU_PEAK_FLOPS.items()
+            if k in "tpu v5 lite"] == [197e12]
+
+
+# ---------------------------------------------------------------------------
+# End to end, in child processes, on the CPU
+# ---------------------------------------------------------------------------
+
+def _smoke(args, tmp_path, devices=1, timeout=600):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py"), *args],
+        capture_output=True, text=True, timeout=timeout, env=env,
+        cwd=str(tmp_path))
+    lines = proc.stdout.strip().splitlines()
+    phases = [json.loads(ln[len("phase: "):]) for ln in lines
+              if ln.startswith("phase: ")]
+    return proc.returncode, json.loads(lines[-1]), phases, lines
+
+
+def test_forced_to_the_cpu_it_refuses(tmp_path):
+    """No --tiny: the real size is not run on a CPU, and nothing is
+    reported as a success."""
+    rc, last, phases, lines = _smoke([], tmp_path)
+    assert rc != 0 and last["ok"] is False
+    assert [p["phase"] for p in phases] == ["train-auto"]
+    assert not phases[0]["ok"]
+    assert any("refusing to run the real size" in ln for ln in lines)
+    assert not any(ln.startswith("[train-auto] iter") for ln in lines)
+
+
+def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
+    rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
+    assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
+                                            "server"]
+    for p in phases:        # every phase's own checks passed ...
+        assert p["ok"], (p["phase"], p["problems"])
+    train = phases[1]
+    assert train["steps"] == 20 and train["compile_s"] > 0
+    assert any("pallas flash kernel (interpreted)" in a
+               for a in train["attention"])
+    assert abs(phases[0]["losses"][0] - train["losses"][0]) < 1e-2
+    server = phases[2]
+    assert server["driver_max_active"] >= 2
+    assert server["max_blocks_in_use_seen"] > 0
+    assert server["blocks_in_use_after"] == 0
+    assert server["decode_pallas_calls_per_step"] == 12
+    # ... and the run is refused all the same: this is not a TPU.
+    assert rc != 0
+    assert last == {"ok": False, "device": {"platform": "cpu",
+                                            "kind": "cpu", "count": 1}}
+    assert sum("not a TPU" in ln for ln in lines) == 3
+
+
+def test_four_chip_option_on_four_virtual_devices(tmp_path):
+    rc, last, phases, _ = _smoke(["--chips", "4", "--tiny"], tmp_path,
+                                 devices=4)
+    assert [p["phase"] for p in phases] == ["multichip"]
+    ph = phases[0]
+    assert ph["ok"], ph["problems"]
+    legs = ph["legs"]
+    assert set(legs) == {"one_device", "tp2_dp2", "tp2_pp2"}
+    assert legs["tp2_dp2"]["q_kernel"]["devices,shards"] == [4, 2]
+    assert legs["tp2_dp2"]["batch_tokens"]["devices,shards"] == [4, 2]
+    assert legs["tp2_dp2"]["collectives_in_compiled_step"]["all-reduce"] > 0
+    for name in ("tp2_dp2", "tp2_pp2"):
+        assert legs[name]["max_abs_loss_gap_vs_one_device"] < ph["tolerance"]
+    assert rc != 0 and last["ok"] is False
+    assert last["device"]["count"] == 4

@@ -21,8 +21,6 @@ fwd+bwd timings and the numeric diffs, as one JSON line:
 
   python tools/cp_a2a_benchmark.py --cp 4 --ep 4 --seq 512
 
-bench.py runs this as its `--cp-a2a` child and attaches the result to the
-round's benchmark record (extra.cp_a2a).
 
 Note on CPU numbers: XLA:CPU executes collectives synchronously, so the
 latency hiding itself contributes nothing here — the CPU-mesh win comes

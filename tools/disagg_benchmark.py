@@ -29,9 +29,7 @@ match exactly (asserted: parity_ok). Reported per mode:
                  per-chunk KV ship, so within ~10% of colocated).
 
 Runs on CPU out of the box (sub-meshes are virtual host devices; the
-paged kernels run in Pallas interpret mode). One JSON line; bench.py
-runs this as its `--disagg` child and attaches the result to the round's
-record (extra.disagg).
+paged kernels run in Pallas interpret mode). One JSON line.
 
   python tools/disagg_benchmark.py --long-len 192
 """

@@ -24,8 +24,6 @@ Runs on a CPU mesh out of the box:
 
   python tools/dist_opt_benchmark.py --dp 2
 
-bench.py runs this as its `--dist-opt` child and attaches the result to
-the round's benchmark record (extra.dist_opt).
 
 Note on CPU numbers: the ring's latency hiding and the reduce-scatter's
 bandwidth win need the TPU async collective engine; on XLA:CPU all legs

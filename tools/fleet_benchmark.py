@@ -26,8 +26,7 @@ Reported per policy:
   migrations        router-counted live migrations (the forced phase).
 
 Runs on CPU out of the box (replicas are plain paged engines on the
-host device). One JSON line; bench.py runs this as its `--fleet` child
-and attaches the result to the round's record (extra.fleet).
+host device). One JSON line.
 
   python tools/fleet_benchmark.py --groups 4 --followers 3
 """

@@ -31,9 +31,7 @@ Deterministic gates (the wall clock never decides pass/fail):
                       carries process rows from >= 2 distinct OS
                       replica processes
 
-Runs on CPU out of the box. One JSON line; bench.py runs this as its
-`--fleet-proc` child and attaches the result to the round's record
-(extra.fleet_proc).
+Runs on CPU out of the box. One JSON line.
 
   python tools/fleet_proc_benchmark.py --requests 12
   python tools/fleet_proc_benchmark.py --threaded   # no subprocesses

@@ -25,8 +25,7 @@ reported.
 
 Runs on CPU out of the box (interpret-mode kernels; the pools are
 stored bf16/int8 exactly as on TPU, so the byte accounting is
-platform-independent). bench.py runs this as its `--kv-quant` child and
-attaches the result to the round record (extra.kv_quant).
+platform-independent).
 
   python tools/kv_quant_benchmark.py --max-new 6
 """

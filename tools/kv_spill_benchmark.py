@@ -27,8 +27,7 @@ accounting — no wall-clock gates):
             and prefill_chunks_avoided >= 1 with exact chunk math
             (prefill_chunk=8 so a 25-token prompt spans >1 chunk).
 
-Runs on CPU out of the box. bench.py runs this as its `--kv-spill`
-child and attaches the result to the round record (extra.kv_spill).
+Runs on CPU out of the box.
 
   python tools/kv_spill_benchmark.py --local
 """

@@ -34,8 +34,8 @@ Standalone CLI (spawns a cross-process fleet, replays, one JSON line):
 
   python tools/loadgen.py --fleet-procs 2 --requests 24 --seed 0
 
-bench.py's `extra.fleet_proc` gate imports make_trace/replay instead of
-shelling out twice (tools/fleet_proc_benchmark.py).
+tools/fleet_proc_benchmark.py imports make_trace/replay instead of
+shelling out twice.
 """
 
 import argparse

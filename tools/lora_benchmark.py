@@ -17,8 +17,6 @@ the bank byte accounting is platform-independent):
   zero_b:   B=0 adapters add an exact 0.0 — streams through the LoRA
             path are BITWISE those of an engine with no adapter cache.
 
-bench.py runs this as its `--lora` child and attaches the result to
-the round record (extra.lora).
 
   python tools/lora_benchmark.py --adapters 8 --max-new 8
 """

@@ -16,9 +16,7 @@ must match token-for-token — asserted):
           dense recomputes the prefix per request.
 
 Runs on CPU out of the box (the paged-attention kernel runs in Pallas
-interpret mode there) and on TPU unchanged. Reports one JSON line;
-bench.py runs this as its `--paged-kv` child and attaches the result to
-the round's benchmark record (extra.paged_kv), mirroring extra.cp_a2a.
+interpret mode there) and on TPU unchanged. Reports one JSON line.
 
 Note on CPU numbers: interpret-mode Pallas adds per-step overhead the
 compiled TPU kernel doesn't have, so CPU decode throughput understates

@@ -17,8 +17,6 @@ Runs on a CPU mesh out of the box:
 
   python tools/pp_tp_benchmark.py --tp 2 --pp 2
 
-bench.py runs this as its `--pp-tp` child and attaches the result to the
-round's benchmark record (extra.pp_tp_overlap).
 
 Note on CPU numbers: the ring's latency hiding needs the TPU async
 collective engine, but the FLOP cut is backend-independent — each tp rank

@@ -49,6 +49,10 @@ def main():
     )
     add_serving_args(ap)
     args = ap.parse_args()
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
 
     # Telemetry opt-in BEFORE engine construction, so admission-time
     # counters and the first prefill spans are captured (ISSUE 12).
@@ -394,6 +398,7 @@ def main():
             # programmatically (inference/lora.py TenantSLO.assign).
             from megatronapp_tpu.inference.lora import TenantSLO
             engine.tenant_slo = TenantSLO()
+        print(device_line(None if tp_ctx is None else tp_ctx.mesh))
         print(f"serving continuous batching on {args.host}:{args.port} "
               f"(paged={args.paged_kv_cache}, "
               f"kv={args.kv_cache_dtype}, tp={args.serve_tp}, "
@@ -404,6 +409,7 @@ def main():
         return
     engine = StaticInferenceEngine(params, cfg, tokenizer=tok,
                                    max_seq_len=args.max_seq_len)
+    print(device_line())
     print(f"serving on {args.host}:{args.port} (PUT /api, WS /ws)")
     TextGenerationServer(engine, args.host, args.port).run()
 

@@ -19,9 +19,7 @@ because interpret-mode Pallas dominates, so tokens/step is the
 platform-independent metric (each verify step costs ~one decode step on
 a real chip — the K+1 queries batch into the same kernel launch).
 
-Reports one JSON line; bench.py runs this as its `--spec-decode` child
-and attaches the result to the round's record (extra.spec_decode),
-mirroring extra.paged_kv.
+Reports one JSON line.
 
   python tools/spec_decode_benchmark.py --max-new 24 --spec-k 4
 """

@@ -9,9 +9,7 @@ streams must match — asserted); the headline is the tokens/s ratio
 (gate: >= 0.95), plus the disabled-path microbench (ns per site call —
 one dict-truthiness check, the chaos.py bound).
 
-Runs on CPU out of the box; one JSON line; bench.py runs this as its
-`--telemetry` child and attaches the result to the round record
-(extra.telemetry), mirroring extra.paged_kv.
+Runs on CPU out of the box; one JSON line.
 
   python tools/telemetry_benchmark.py --max-new 24
 """

@@ -13,8 +13,6 @@ fwd+bwd timings and the numeric diff, as one JSON line:
 
   python tools/tp_overlap_benchmark.py --tp 4 --seq 512 --hidden 256
 
-bench.py runs this as its `--tp-overlap` child and attaches the result to
-the round's benchmark record (extra.tp_overlap).
 
 Note on CPU numbers: XLA:CPU executes collectives synchronously, so the
 ring path's win there is bounded (it mainly validates correctness + span
