@@ -63,7 +63,9 @@ from megatronapp_tpu.inference.paged_cache import (
     HostSpillTier, PagedKVCache, cdiv,
 )
 from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
-from megatronapp_tpu.trace.request_trace import get_request_tracer
+from megatronapp_tpu.trace.request_trace import (
+    PhaseStats, get_request_tracer,
+)
 from megatronapp_tpu.transformer.block import layer_forward
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
@@ -75,6 +77,51 @@ class DeadlineExceeded(RuntimeError):
     """A request's deadline passed: rejected at admission, or aborted
     mid-flight by the engine/stepper (its pool blocks are reclaimed on
     the retire path like any finished request)."""
+
+
+class StepStats(PhaseStats):
+    """The engine's always-on step counters, ``stats_snapshot()["steps"]``
+    (GET /stats). Every ``mta.engine.*`` span adds its wall time to its
+    phase here; beside the phases: ``queue_wait`` (observed where a
+    request leaves the queue; a preempted request's second wait counts
+    again) and ``slowest``, the flight recorder: the longest pure decode
+    rounds (steps that admitted nothing) since start, each with its step
+    index, wall time, batch and the seconds every phase took inside it.
+    The tokens the model ran are counted elsewhere already:
+    ``pool.stats["prefill_tokens"]`` (the snapshot's ``pool``) and
+    ``spec_stats["emitted_tokens"]``."""
+
+    PHASES = ("step", "admit", "prefill", "prefill_call", "capacity",
+              "decode_round", "decode.stage", "decode.wait",
+              "decode.record", "retire", "queue_wait")
+    SLOWEST = 8
+
+    def __init__(self):
+        super().__init__(self.PHASES)
+        self.slowest: List[dict] = []       # longest first
+
+    def totals(self) -> List[float]:
+        return [row[1] for row in self.phases.values()]
+
+    def note_round(self, wall_s: float, batch: int, before: List[float]):
+        """A pure decode round has ended; `before` is ``totals()`` as the
+        step began. Kept if it is among the SLOWEST longest so far."""
+        slowest = self.slowest
+        if len(slowest) == self.SLOWEST and wall_s <= slowest[-1]["wall_s"]:
+            return
+        split = {p: row[1] - b for (p, row), b
+                 in zip(self.phases.items(), before) if row[1] > b}
+        record = {"step": int(self.phases["step"][0]), "wall_s": wall_s,
+                  "batch": batch, "phases": split}
+        # A new list, put in place at once: /stats reads from another
+        # thread.
+        self.slowest = sorted(slowest + [record],
+                              key=lambda r: -r["wall_s"])[:self.SLOWEST]
+
+    def snapshot(self) -> dict:
+        return dict(super().snapshot(),
+                    slowest=[dict(r, phases=dict(r["phases"]))
+                             for r in self.slowest])
 
 
 def validate_admission(prompt_tokens, max_new_tokens: int,
@@ -595,6 +642,7 @@ class DynamicInferenceEngine:
         self.proposer = None
         self.spec_stats = {"rounds": 0, "proposed": 0, "accepted": 0,
                            "emitted_tokens": 0, "model_steps": 0}
+        self.step_stats = StepStats()
         # Pre-head hidden state at each slot's last verified position —
         # feeds the MTP self-draft proposer.
         self._h_last = np.zeros((max_batch, cfg.hidden_size), np.float32)
@@ -739,6 +787,13 @@ class DynamicInferenceEngine:
             self.pool.scales = tuple(new[2:])
         else:
             self.pool.pages = tuple(new)
+
+    def _span(self, name: str, rid: Optional[int] = None,
+              ring: Optional[str] = None, **attrs):
+        """One phase of this engine's work: profiler annotation, this
+        engine's step_stats, and the request ring under `ring`."""
+        return self._rt.span(name, rid, stats=self.step_stats, ring=ring,
+                             **attrs)
 
     # Bounded per-tenant label cardinality (/metrics + /stats): beyond
     # this many distinct tenants, new ones fold into "_other".
@@ -1317,9 +1372,14 @@ class DynamicInferenceEngine:
                 pass
 
     def _admit(self) -> List[Request]:
+        if (self.pause_admission or not self.waiting
+                or all(r is not None for r in self.slots)):
+            return []
+        with self._span("engine.admit", waiting=len(self.waiting)):
+            return self._admit_waiting()
+
+    def _admit_waiting(self) -> List[Request]:
         admitted = []
-        if self.pause_admission:
-            return admitted
         for slot in range(self.max_batch):
             if self.slots[slot] is not None or not self.waiting:
                 continue
@@ -1377,11 +1437,16 @@ class DynamicInferenceEngine:
             rid = req.request_id
             first_life = not req.generated   # vs resumed after preempt
             self._rt.end("queue-wait", rid)
-            telemetry.observe("serving_queue_wait_ms",
-                              (time.monotonic() - req.queued_t) * 1e3)
-            self._rt.begin("prefill", rid, prompt_tokens=len(req.tokens))
+            # One measurement feeds /stats and /metrics alike.
+            waited = time.monotonic() - req.queued_t
+            self.step_stats.add("queue_wait", waited)
+            telemetry.observe("serving_queue_wait_ms", waited * 1e3)
+            p_len = len(req.tokens)
+            cached = plan.cached_tokens if plan is not None else 0
             try:
-                self._prefill_into_slot(req, plan)
+                with self._span("engine.prefill", rid, ring="prefill",
+                                prompt_tokens=p_len, cached_tokens=cached):
+                    self._prefill_into_slot(req, plan)
             except Exception:
                 # Exception-safe rollback (the "kv-quant-write" chaos
                 # drill fires between quantize and page-table commit in
@@ -1397,10 +1462,8 @@ class DynamicInferenceEngine:
                 req.slot = -1
                 req.queued_t = time.monotonic()
                 self.waiting.appendleft(req)
-                self._rt.end("prefill", rid, error=True)
                 self._rt.begin("queue-wait", rid)   # requeued at the head
                 raise
-            self._rt.end("prefill", rid)
             if first_life:
                 # TTFT is a first-token metric: a preempted request's
                 # resume prefill emits its Nth token, not its first —
@@ -1438,14 +1501,15 @@ class DynamicInferenceEngine:
                     f"{self.max_seq_len})")
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :p_len] = tokens
-            tmp_cache = init_kv_cache(self.cfg, 1, bucket)
-            logits, tmp_cache = self._prefill(
-                self.params, jnp.asarray(padded), tmp_cache, 0)
-            # Scatter the kv rows into this slot of the shared cache.
-            slot = req.slot
-            self.cache = tuple(
-                c.at[:, slot, :bucket].set(t[:, 0]) for c, t in
-                zip(self.cache, tmp_cache))
+            with self._span("engine.prefill_call", tokens=p_len):
+                tmp_cache = init_kv_cache(self.cfg, 1, bucket)
+                logits, tmp_cache = self._prefill(
+                    self.params, jnp.asarray(padded), tmp_cache, 0)
+                # Scatter the kv rows into this slot of the shared cache.
+                slot = req.slot
+                self.cache = tuple(
+                    c.at[:, slot, :bucket].set(t[:, 0]) for c, t in
+                    zip(self.cache, tmp_cache))
             logits_last = logits[0, p_len - 1]
         self.lengths[req.slot] = p_len
         # First generated token comes from the last PROMPT position.
@@ -1483,13 +1547,14 @@ class DynamicInferenceEngine:
                 # fault costs one step and audit() stays clean (the
                 # tests/test_resilience.py drill).
                 chaos.fire("kv-quant-write")
-            logits, hid, new = self._mq_step(
-                self.params, jnp.asarray(chunk), self.pool.pages,
-                self.pool.scales,
-                table_row, jnp.asarray([pos], jnp.int32),
-                jnp.asarray([count], jnp.int32), jnp.ones((1,), bool),
-                self._lora_args(rows=self.row_adapter[slot:slot + 1]))
-            self._commit_pools(new)
+            with self._span("engine.prefill_call", tokens=count):
+                logits, hid, new = self._mq_step(
+                    self.params, jnp.asarray(chunk), self.pool.pages,
+                    self.pool.scales,
+                    table_row, jnp.asarray([pos], jnp.int32),
+                    jnp.asarray([count], jnp.int32), jnp.ones((1,), bool),
+                    self._lora_args(rows=self.row_adapter[slot:slot + 1]))
+                self._commit_pools(new)
             pos += count
         # Register the prompt's full blocks so concurrent same-prefix
         # requests hit them immediately.
@@ -1635,6 +1700,18 @@ class DynamicInferenceEngine:
         [ids], "preempted": [ids], "expired": [ids]} for this step
         (expired ⊆ finished: deadline-overdue requests aborted by this
         step's expiry sweep)."""
+        before = self.step_stats.totals()
+        with self._span("engine.step", waiting=len(self.waiting),
+                        active=sum(1 for r in self.slots
+                                   if r is not None)) as whole:
+            events, batch = self._step()
+        if batch and not events["admitted"]:
+            self.step_stats.note_round(whole.seconds, batch, before)
+        return events
+
+    def _step(self) -> Tuple[Dict[str, List], int]:
+        """step() inside its span; also returns the decode round's batch
+        (0 when no round ran)."""
         expired = self.expire_overdue()
         if self.spill is not None:
             self._no_repark.clear()
@@ -1646,8 +1723,9 @@ class DynamicInferenceEngine:
                   "finished": [], "preempted": [], "expired": expired}
 
         if self.paged:
-            events["preempted"] = [
-                r.request_id for r in self._ensure_decode_capacity()]
+            with self._span("engine.capacity"):
+                preempted = self._ensure_decode_capacity()
+            events["preempted"] = [r.request_id for r in preempted]
 
         active = [r for r in self.slots
                   if r is not None and not r.finished]
@@ -1668,17 +1746,21 @@ class DynamicInferenceEngine:
         else:
             self._last_round_t = None
 
-        events["finished"] = [r.request_id for r in self._retire()]
+        with self._span("engine.retire"):
+            retired = self._retire()
+        events["finished"] = [r.request_id for r in retired]
         events["finished"] += [r.request_id for r in self._aborted]
         self._aborted = []
-        return events
+        return events, len(active)
 
     def _plain_round(self, active: List[Request], events: Dict):
         """One-token decode for every active slot (non-speculative)."""
-        # try/finally like _spec_round's span: a failing step must not
-        # leak an orphan B that mis-pairs with a later round's E.
-        self._rt.begin("decode-step", None, batch=len(active))
-        try:
+        with self._span("engine.decode_round", ring="decode-step",
+                        batch=len(active)):
+            self._plain_round_inner(active, events)
+
+    def _plain_round_inner(self, active: List[Request], events: Dict):
+        with self._span("engine.decode.stage"):
             active_np = np.array(
                 [self.slots[i] is not None and not self.slots[i].finished
                  for i in range(self.max_batch)])
@@ -1698,7 +1780,9 @@ class DynamicInferenceEngine:
             # The decode wrote each active row's kv at lengths[slot].
             self.lengths += active_np.astype(np.int32)
             logits = mask_padded_vocab(logits, self.cfg)
+        with self._span("engine.decode.wait"):
             toks = self._sample_all(logits)
+        with self._span("engine.decode.record"):
             self.spec_stats["model_steps"] += 1
             self.spec_stats["emitted_tokens"] += len(active)
             telemetry.inc("serving_tokens_emitted", len(active))
@@ -1706,8 +1790,6 @@ class DynamicInferenceEngine:
                 tok = int(toks[req.slot])
                 self._record_token(req, tok)
                 events["tokens"].append((req.request_id, tok))
-        finally:
-            self._rt.end("decode-step", None)
 
     def _spec_round(self, active: List[Request], events: Dict):
         """One speculate+verify round: propose up to spec_k drafts per
@@ -1730,9 +1812,10 @@ class DynamicInferenceEngine:
                 k_caps[slot] = self.pool.extend_capacity(
                     slot, length + 1, want)
 
-        self._rt.begin("spec-round", None, batch=len(active))
         try:
-            self._spec_round_inner(active, events, k_caps)
+            with self._span("engine.decode_round", ring="spec-round",
+                            batch=len(active)):
+                self._spec_round_inner(active, events, k_caps)
         except Exception:
             # Leave the pool consistent on ANY mid-round failure (the
             # "spec-verify" chaos drill): every surviving slot rewinds
@@ -1748,8 +1831,6 @@ class DynamicInferenceEngine:
                     self.pool.rewind(req.slot,
                                      int(self.lengths[req.slot]) + 1)
             raise
-        finally:
-            self._rt.end("spec-round", None)
 
     def _spec_round_inner(self, active: List[Request], events: Dict,
                           k_caps: np.ndarray):
@@ -1765,87 +1846,89 @@ class DynamicInferenceEngine:
             for req in active:
                 self.pool.rewind(req.slot,
                                  int(self.lengths[req.slot]) + 1)
-            self._plain_round(active, events)
+            self._plain_round_inner(active, events)
             return
 
-        q_lens = np.ones((b,), np.int32)
-        tokens = np.zeros((b, k + 1), np.int32)
-        active_np = np.zeros((b,), bool)
-        for req in active:
-            slot = req.slot
-            active_np[slot] = True
-            tokens[slot, 0] = self.last_tokens[slot, 0]
-            n = int(counts[slot])
-            tokens[slot, 1:1 + n] = drafts[slot, :n]
-            q_lens[slot] = 1 + n
-        rows = self._sampling_rows()
+        with self._span("engine.decode.stage"):
+            q_lens = np.ones((b,), np.int32)
+            tokens = np.zeros((b, k + 1), np.int32)
+            active_np = np.zeros((b,), bool)
+            for req in active:
+                slot = req.slot
+                active_np[slot] = True
+                tokens[slot, 0] = self.last_tokens[slot, 0]
+                n = int(counts[slot])
+                tokens[slot, 1:1 + n] = drafts[slot, :n]
+                q_lens[slot] = 1 + n
+            rows = self._sampling_rows()
 
-        logits, hidden, new = self._mq_step(
-            self.params, jnp.asarray(tokens), self.pool.pages,
-            self.pool.scales,
-            jnp.asarray(self.pool.page_table[:self.max_batch]),
-            jnp.asarray(self.lengths),
-            jnp.asarray(q_lens), jnp.asarray(active_np),
-            self._lora_args())
-        self._commit_pools(new)
-        logits = mask_padded_vocab(logits, self.cfg)
-        # Chaos site "spec-verify": fires at the WORST point — the
-        # multi-query step already wrote every draft token's KV, nothing
-        # is accepted yet — so the drill proves _spec_round's rollback
-        # (rewind to the last verified length) keeps the pool auditable
-        # and the stream exact.
-        chaos.fire("spec-verify")
-        accepts, out_toks = self._verify_sample(
-            logits, jnp.asarray(drafts), jnp.asarray(q_lens), q_probs,
-            jnp.asarray(rows["seeds"]), jnp.asarray(rows["rids"]),
-            jnp.asarray(rows["steps"]), jnp.asarray(rows["temps"]),
-            jnp.asarray(rows["top_ks"]), jnp.asarray(rows["top_ps"]),
-            jnp.asarray(rows["greedys"]))
-        accepts = np.asarray(jax.device_get(accepts))
-        out_toks = np.asarray(jax.device_get(out_toks))
-        h_sel = None
-        if self.proposer.needs_hidden:
-            h_sel = np.asarray(jax.device_get(jnp.take_along_axis(
-                hidden, jnp.asarray(accepts)[:, None, None], axis=1)[:, 0]),
-                np.float32)
-
-        self.spec_stats["rounds"] += 1
-        self.spec_stats["model_steps"] += 1
-        for req in active:
-            slot = req.slot
-            n = int(counts[slot])
-            a = min(int(accepts[slot]), n)
-            emitted = [int(t) for t in drafts[slot, :a]]
-            emitted.append(int(out_toks[slot]))
-            len_before = int(self.lengths[slot])
-            m = 0
-            for tok in emitted:
-                self._record_token(req, tok)
-                events["tokens"].append((req.request_id, tok))
-                m += 1
-                if req.finished:
-                    break   # eod/budget: drop the rest of the window
-            # Valid KV = [last_token, accepted drafts] — rewind the
-            # written-but-rejected tail (and over-granted blocks).
-            self.lengths[slot] = len_before + m
-            self.pool.rewind(slot, len_before + m)
-            if h_sel is not None:
-                self._h_last[slot] = h_sel[slot]
-                self._h_valid[slot] = True
-            req.spec_proposed += n
-            req.spec_accepted += a
-            self.spec_stats["proposed"] += n
-            self.spec_stats["accepted"] += a
-            self.spec_stats["emitted_tokens"] += m
-            # Acceptance histogram (ISSUE 12): accepted drafts per
-            # verify round, per request row — /metrics percentiles show
-            # the acceptance DISTRIBUTION, not just the mean rate.
-            telemetry.observe("spec_accepted_per_round", a,
-                              lo=0.5, hi=64, growth=1.5)
-            telemetry.inc("spec_proposed_tokens", n)
-            telemetry.inc("spec_accepted_tokens", a)
-            telemetry.inc("serving_tokens_emitted", m)
-            self.proposer.on_verified(slot, a)
+            logits, hidden, new = self._mq_step(
+                self.params, jnp.asarray(tokens), self.pool.pages,
+                self.pool.scales,
+                jnp.asarray(self.pool.page_table[:self.max_batch]),
+                jnp.asarray(self.lengths),
+                jnp.asarray(q_lens), jnp.asarray(active_np),
+                self._lora_args())
+            self._commit_pools(new)
+            logits = mask_padded_vocab(logits, self.cfg)
+            # Chaos site "spec-verify": fires at the WORST point — the
+            # multi-query step already wrote every draft token's KV,
+            # nothing is accepted yet — so the drill proves _spec_round's
+            # rollback (rewind to the last verified length) keeps the
+            # pool auditable and the stream exact.
+            chaos.fire("spec-verify")
+        with self._span("engine.decode.wait"):
+            accepts, out_toks = self._verify_sample(
+                logits, jnp.asarray(drafts), jnp.asarray(q_lens), q_probs,
+                jnp.asarray(rows["seeds"]), jnp.asarray(rows["rids"]),
+                jnp.asarray(rows["steps"]), jnp.asarray(rows["temps"]),
+                jnp.asarray(rows["top_ks"]), jnp.asarray(rows["top_ps"]),
+                jnp.asarray(rows["greedys"]))
+            accepts = np.asarray(jax.device_get(accepts))
+            out_toks = np.asarray(jax.device_get(out_toks))
+            h_sel = None
+            if self.proposer.needs_hidden:
+                h_sel = np.asarray(jax.device_get(jnp.take_along_axis(
+                    hidden, jnp.asarray(accepts)[:, None, None],
+                    axis=1)[:, 0]), np.float32)
+        with self._span("engine.decode.record"):
+            self.spec_stats["rounds"] += 1
+            self.spec_stats["model_steps"] += 1
+            for req in active:
+                slot = req.slot
+                n = int(counts[slot])
+                a = min(int(accepts[slot]), n)
+                emitted = [int(t) for t in drafts[slot, :a]]
+                emitted.append(int(out_toks[slot]))
+                len_before = int(self.lengths[slot])
+                m = 0
+                for tok in emitted:
+                    self._record_token(req, tok)
+                    events["tokens"].append((req.request_id, tok))
+                    m += 1
+                    if req.finished:
+                        break   # eod/budget: drop the rest of the window
+                # Valid KV = [last_token, accepted drafts] — rewind the
+                # written-but-rejected tail (and over-granted blocks).
+                self.lengths[slot] = len_before + m
+                self.pool.rewind(slot, len_before + m)
+                if h_sel is not None:
+                    self._h_last[slot] = h_sel[slot]
+                    self._h_valid[slot] = True
+                req.spec_proposed += n
+                req.spec_accepted += a
+                self.spec_stats["proposed"] += n
+                self.spec_stats["accepted"] += a
+                self.spec_stats["emitted_tokens"] += m
+                # Acceptance histogram (ISSUE 12): accepted drafts per
+                # verify round, per request row — /metrics percentiles
+                # show the acceptance DISTRIBUTION, not just the mean.
+                telemetry.observe("spec_accepted_per_round", a,
+                                  lo=0.5, hi=64, growth=1.5)
+                telemetry.inc("spec_proposed_tokens", n)
+                telemetry.inc("spec_accepted_tokens", a)
+                telemetry.inc("serving_tokens_emitted", m)
+                self.proposer.on_verified(slot, a)
 
     def run_to_completion(self,
                           token_callback: Optional[Callable] = None
@@ -1942,6 +2025,7 @@ class DynamicInferenceEngine:
             "multiquery_traces": self.mq_traces,
             "decode_traces": self.decode_traces,
             "megakernel": self.megakernel,
+            "steps": self.step_stats.snapshot(),
         }
         if include_dispatch and self.paged:
             out["decode_dispatch"] = self.dispatch_stats()

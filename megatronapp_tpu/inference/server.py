@@ -46,7 +46,9 @@ from megatronapp_tpu.inference.dynamic_engine import DeadlineExceeded
 from megatronapp_tpu.inference.engine import (
     SamplingParams, StaticInferenceEngine,
 )
-from megatronapp_tpu.trace.request_trace import get_request_tracer
+from megatronapp_tpu.trace.request_trace import (
+    PhaseStats, get_request_tracer,
+)
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
 
@@ -102,6 +104,9 @@ class DynamicBatchingDriver:
         # Rolling reload state: (params, done_event) or None.
         self._reload = None
         self.reloads = 0
+        # Always on, like the engine's step_stats: what the token
+        # callbacks and done events cost the stepper after each step.
+        self.deliver_stats = PhaseStats(("deliver",))
 
     def _ensure_thread(self):
         if self._thread is None or not self._thread.is_alive():
@@ -276,7 +281,9 @@ class DynamicBatchingDriver:
                 continue
             self.max_active = max(self.max_active, sum(
                 1 for r in self.engine.slots if r is not None))
-            with self._cv:
+            with get_request_tracer().span(
+                    "driver.deliver", stats=self.deliver_stats,
+                    tokens=len(ev["tokens"])), self._cv:
                 # Deadline-expired requests get a clean error frame
                 # BEFORE the generic finished handling pops their sub
                 # (their pool blocks were reclaimed by the step's retire
@@ -312,6 +319,7 @@ class DynamicBatchingDriver:
             "subscribers": len(self._subs),
             "max_active": self.max_active,
             "reloads": self.reloads,
+            "deliver": self.deliver_stats.snapshot()["deliver"],
             "reload_pending": (self._reload is not None
                                or getattr(self.engine, "reload_pending",
                                           False)),
@@ -688,6 +696,8 @@ class TextGenerationServer:
                 "InferenceEngine", "").lower()}
         if self._driver is not None:
             out["driver_max_active"] = self._driver.max_active
+            out["driver_deliver"] = \
+                self._driver.deliver_stats.snapshot()["deliver"]
         return out
 
     async def handle_stats(self, request):

@@ -359,6 +359,7 @@ def _flash_forward_t(q, k, v, scale, causal, block_q, block_kv, nq, nk,
             pltpu.VMEM((1, block_q), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd_t",
     )(*inputs)
     return jnp.swapaxes(ot, -1, -2), lse_row[:, :, 0, :]
 
@@ -412,6 +413,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*inputs)
     return out, lse[..., 0]
 
@@ -813,6 +815,7 @@ def _flash_backward_fold(q, k, v, g, lse, delta, scale, causal,
         out_shape=jax.ShapeDtypeStruct((b, h // 2, sq, 2 * d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, 2 * d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq_fold",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     qp_spec_k = pl.BlockSpec((1, 1, block_q, 2 * d),
@@ -842,6 +845,7 @@ def _flash_backward_fold(q, k, v, g, lse, delta, scale, causal,
             pltpu.VMEM((block_kv, kv_dim), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv_fold",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dq = _unfold_heads(dqf)
@@ -1029,6 +1033,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(*dq_inputs, g, lse4, delta4)
 
     # dk/dv computed at q-head granularity [B, H, Skv, D]; grouped heads are
@@ -1069,6 +1074,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_inputs, g, lse4, delta4)
 
     if group > 1:
@@ -1119,6 +1125,7 @@ def _flash_backward_t(q, k, v, g, lse, delta, scale, causal,
         out_shape=jax.ShapeDtypeStruct((b, h, d, sq), q.dtype),
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq_t",
     )(*dq_inputs, g, lse_row, delta_row)
 
     lse4 = lse[..., None]
@@ -1158,6 +1165,7 @@ def _flash_backward_t(q, k, v, g, lse, delta, scale, causal,
             pltpu.VMEM((d, block_kv), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv_t",
     )(*dkv_inputs, g, lse4, delta4)
 
     dq = jnp.swapaxes(dqt, -1, -2)

@@ -121,6 +121,18 @@ def quant_qmax_of(pages_dtype) -> float:
     return QUANT_DTYPES[name][2]
 
 
+def _paged_name(ragged: bool, quant_dtype: Optional[str] = None,
+                variant: str = "") -> str:
+    """A paged kernel's ``pallas_call`` name, which the compiled HLO
+    instruction and so every device trace carries. The family prefix is
+    the contract trace readers match (perfbench/metrics): ``paged_decode``
+    for one query a row, ``paged_mq`` for the ragged multi-query kernel
+    that chunked prefill and speculative verify run; variant and pool type
+    follow (``paged_decode_latent_int8``)."""
+    return (("paged_mq" if ragged else "paged_decode") + variant
+            + (f"_{quant_dtype}" if quant_dtype else ""))
+
+
 def default_kv_tile(quant_dtype: Optional[str]):
     """Min TPU tile (sublane, lane) of the KV block windows for this
     storage dtype — the shape knob an on-chip tuning pass flips."""
@@ -557,10 +569,12 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, q_lat.dtype),
         interpret=_interpret(),
+        name=_paged_name(ragged, quant_dtype, "_latent"),
     )(*prefetch, *operands)
 
 
-def _latent_block_scores(q, pages, page_table, kv_lens, scales=None):
+def _latent_block_scores(q, pages, page_table, kv_lens, scales=None,
+                         family="paged_decode"):
     """Phase 1 of the latent-column tp path: ALL block scores
     q · pages^T over the page table — q [B, rows, d] × pages [NB, bs, d]
     → [B, rows, MB*bs] fp32, NO softmax. Out-of-range blocks write 0 so
@@ -617,10 +631,12 @@ def _latent_block_scores(q, pages, page_table, kv_lens, scales=None):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, mb * bs), jnp.float32),
         interpret=_interpret(),
+        name=f"{family}_latent_scores",
     )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32), *operands)
 
 
-def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None):
+def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None,
+                       family="paged_decode"):
     """Phase 2 of the latent-column tp path: probability-weighted value
     sum over the page table with the per-tile in-register re-expansion
     — p [B, rows, MB*bs] fp32 (masked softmax, zeros past each row's
@@ -697,6 +713,7 @@ def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, dv), jnp.float32),
         interpret=_interpret(),
+        name=f"{family}_latent_wsum",
     )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32), *operands)
 
 
@@ -721,6 +738,7 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
     from megatronapp_tpu.parallel.collectives import psum, shard_map_compat
 
     ragged = q_lens is not None
+    family = _paged_name(ragged)
     if ragged:
         b, s_q, nq, klat = q_lat.shape
     else:
@@ -765,11 +783,11 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
             b, rows, -1)
         qpf = (qp_.astype(jnp.float32) * softmax_scale).reshape(
             b, rows, -1)
-        s_nope = _latent_block_scores(qlf, lat_, t_, l_, ls_)
+        s_nope = _latent_block_scores(qlf, lat_, t_, l_, ls_, family)
         s_nope = psum(s_nope, TP_AXIS)
         # pe scores are replicated work (dpe is tiny) — identical on
         # every shard, no psum.
-        s = s_nope + _latent_block_scores(qpf, pe_, t_, l_, ps_)
+        s = s_nope + _latent_block_scores(qpf, pe_, t_, l_, ps_, family)
         pos = jnp.arange(mb * bs, dtype=jnp.int32)[None, None, :]
         if ragged:
             row_q = (jnp.arange(rows, dtype=jnp.int32)
@@ -783,7 +801,7 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
         pr = jnp.exp(s - jnp.maximum(m, _NEG_INF / 2))
         pr = jnp.where(valid, pr, 0.0)
         pr = pr / jnp.maximum(jnp.sum(pr, axis=-1, keepdims=True), 1e-20)
-        out = _latent_block_wsum(pr, lat_, t_, l_, wv_, ls_)
+        out = _latent_block_wsum(pr, lat_, t_, l_, wv_, ls_, family)
         out = psum(out, TP_AXIS).astype(out_dtype)
         return (out.reshape(b, s_q, nq, dv) if ragged
                 else out.reshape(b, nq, dv))
@@ -875,6 +893,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_interpret(),
+        name=_paged_name(ragged, quant_dtype),
     )(*prefetch, *operands)
 
 
@@ -1263,6 +1282,7 @@ def _fused_qkv(x, attn_p, cfg, cos, sin, tiles=None, lora=None):
                        jax.ShapeDtypeStruct((b, nkv, d), cdt),
                        jax.ShapeDtypeStruct((b, nkv, d), cdt)],
             interpret=_interpret(),
+            name="fused_qkv",
         )(*operands)
 
     # ---- tiled emission: grid over kv-head groups --------------------
@@ -1364,6 +1384,7 @@ def _fused_qkv(x, attn_p, cfg, cos, sin, tiles=None, lora=None):
                    jax.ShapeDtypeStruct((b, nkv, d), cdt),
                    jax.ShapeDtypeStruct((b, nkv, d), cdt)],
         interpret=_interpret(),
+        name="fused_qkv_tiled",
     )(*operands)
 
 
@@ -1498,6 +1519,7 @@ def _fused_mla_qkv(x, attn_p, cfg, cos, sin):
                    jax.ShapeDtypeStruct((b, klat), cdt),
                    jax.ShapeDtypeStruct((b, dpe), cdt)],
         interpret=_interpret(),
+        name="fused_mla_qkv",
     )(*operands)
 
 
@@ -1567,6 +1589,7 @@ def _fused_out_proj(attn_flat, attn_p, cfg, residual, tiles=None,
             kernel,
             out_shape=jax.ShapeDtypeStruct((b, h), residual.dtype),
             interpret=_interpret(),
+            name="fused_out_proj",
         )(*operands)
 
     h_t = h // t
@@ -1584,6 +1607,7 @@ def _fused_out_proj(attn_flat, attn_p, cfg, residual, tiles=None,
         out_specs=pl.BlockSpec((b, h_t), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, h), residual.dtype),
         interpret=_interpret(),
+        name="fused_out_proj_tiled",
     )(*operands)
 
 
@@ -1689,6 +1713,7 @@ def _fused_mlp(x, p, cfg, tiles=None, lora=None):
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, h), x.dtype),
         interpret=_interpret(),
+        name="fused_mlp",
     )(*operands)
 
 
@@ -1786,6 +1811,7 @@ def _fused_mlp_fc1(x, p, cfg, t):
         out_specs=pl.BlockSpec((b, f_t), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, ffn), cdt),
         interpret=_interpret(),
+        name="fused_mlp_fc1",
     )(*operands)
 
 
@@ -1837,6 +1863,7 @@ def _fused_mlp_fc2(y, x, p, cfg, t):
         out_specs=pl.BlockSpec((b, h_t), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, h), x.dtype),
         interpret=_interpret(),
+        name="fused_mlp_fc2",
     )(*operands)
 
 
@@ -2380,6 +2407,7 @@ def lora_segmented_delta(x, a_bank, b_bank, row_adapter):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, dout), jnp.float32),
         interpret=_interpret(),
+        name="lora_segmented_delta",
     )(seg_adapter, row_seg, x, a_bank, b_bank)
 
 

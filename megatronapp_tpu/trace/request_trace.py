@@ -29,6 +29,18 @@ it — no orphan B). tests/test_metrics.py pins every-B-has-a-matching-E
 across the full lifecycle including expire and preempt.
 
 The disabled path is one attribute truthiness check per call site.
+
+``span()`` is the ONE emission point of a serving phase (ISSUE 26): it
+always opens a ``jax.profiler.TraceAnnotation("mta." + name)``, so the
+phase lands on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
+device's ``XLA Ops``, on one clock, whenever a profiler session runs (and
+costs about a microsecond otherwise); it adds the phase's wall time to the
+``PhaseStats`` its caller owns (the engine's ``step_stats``, the driver's
+``deliver``), always; and it emits the ring's B/E pair under the ring's
+own name when the ring is on. The span names are an interface that
+perfbench's readers match: ``mta.engine.{step, admit, prefill,
+prefill_call, capacity, decode_round, decode.stage, decode.wait,
+decode.record, retire}`` and ``mta.driver.deliver``.
 """
 
 from __future__ import annotations
@@ -40,10 +52,73 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DECODE_PID = 0      # engine / decode sub-mesh timeline
 PREFILL_PID = 1     # disaggregated prefill sub-mesh timeline
 
 _PROCESS_NAMES = {DECODE_PID: "decode-mesh", PREFILL_PID: "prefill-mesh"}
+
+
+class PhaseStats:
+    """Always-on ``count`` / ``total_s`` / ``max_s`` per phase, owned by
+    whoever runs the phases (an engine, a driver) and fed by
+    ``RequestTracer.span``. One writer thread; readers take a snapshot."""
+
+    def __init__(self, phases=()):
+        self.phases: Dict[str, List[float]] = {p: [0, 0.0, 0.0]
+                                               for p in phases}
+
+    def add(self, phase: str, seconds: float):
+        row = self.phases.get(phase)
+        if row is None:
+            row = self.phases[phase] = [0, 0.0, 0.0]
+        row[0] += 1
+        row[1] += seconds
+        if seconds > row[2]:
+            row[2] = seconds
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        return {p: {"count": int(c), "total_s": t, "max_s": m}
+                for p, (c, t, m) in list(self.phases.items())}
+
+
+class _Span:
+    """What ``RequestTracer.span`` returns (a class, not a generator: ten
+    of these open in every engine step)."""
+
+    __slots__ = ("rt", "name", "rid", "stats", "ring", "attrs", "ann", "t0",
+                 "seconds")
+
+    def __init__(self, rt, name, rid, stats, ring, attrs):
+        self.rt, self.name, self.rid = rt, name, rid
+        self.stats, self.ring, self.attrs = stats, ring, attrs
+
+    def __enter__(self):
+        if self.ring is not None and self.rt.enabled:
+            self.rt.begin(self.ring, self.rid, **self.attrs)
+        if self.rid is None:
+            self.ann = TraceAnnotation("mta." + self.name, **self.attrs)
+        else:
+            self.ann = TraceAnnotation("mta." + self.name, rid=self.rid,
+                                       **self.attrs)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = time.perf_counter() - self.t0
+        self.ann.__exit__(exc_type, exc, tb)
+        if self.stats is not None:
+            # "engine.decode.wait" is the phase "decode.wait" of the
+            # engine's own stats.
+            self.stats.add(self.name.partition(".")[2], self.seconds)
+        if self.ring is not None and self.rt.enabled:
+            if exc_type is None:
+                self.rt.end(self.ring, self.rid)
+            else:
+                self.rt.end(self.ring, self.rid, error=True)
+        return False
 
 
 class RequestTracer:
@@ -133,6 +208,18 @@ class RequestTracer:
             if not spans:
                 self._open.pop(rid, None)
         self._emit(name, "E", rid, pid, attrs)
+
+    def span(self, name: str, rid: Optional[int] = None, *,
+             stats: Optional[PhaseStats] = None,
+             ring: Optional[str] = None, **attrs) -> _Span:
+        """Context manager around one phase of serving work: a
+        ``TraceAnnotation("mta." + name, **attrs)`` always, `stats` (the
+        caller's own PhaseStats; the singleton keeps no counters, fleet
+        replicas share it) always, and the ring's B/E pair under `ring`
+        when the ring is on. Closes on an exception too (the E then
+        carries ``error=True``), so a failing step leaves no open span
+        and counts once."""
+        return _Span(self, name, rid, stats, ring, attrs)
 
     def instant(self, name: str, rid: Optional[int] = None,
                 pid: int = DECODE_PID, **attrs):

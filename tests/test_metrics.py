@@ -289,6 +289,190 @@ class TestRequestLifecycleTrace:
 
 
 # ---------------------------------------------------------------------------
+def _pressure_engine(**kw):
+    """Two slots over five 8-token blocks: decode-time pool pressure
+    preempts the lower-priority request once (as the lifecycle test)."""
+    cfg = _gqa_cfg()
+    params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
+    eng = DynamicInferenceEngine(
+        params, cfg, max_batch=2, max_seq_len=48, prefill_buckets=(16,),
+        paged=True, block_size=8, num_blocks=5, **kw)
+    rng = np.random.default_rng(0)
+    for i, n in enumerate((9, 9)):
+        eng.add_request(rng.integers(0, 128, n).astype(np.int32), 12,
+                        SamplingParams(greedy=True), priority=i)
+    return eng
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and \
+        inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+class TestStepSpansAndCounters:
+    """ISSUE 26: the engine names its own phases. One ``span()`` site per
+    phase feeds the profiler (``mta.*`` on the host plane of the device
+    trace's own file), the always-on ``stats_snapshot()["steps"]`` and the
+    request ring."""
+
+    @pytest.mark.parametrize("spec", [None, "ngram"])
+    def test_profiler_sees_nested_program_spans(self, tmp_path, spec):
+        from perfbench import common, trace_reduce
+        cfg = _gqa_cfg()
+        params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
+        eng = DynamicInferenceEngine(
+            params, cfg, max_batch=2, max_seq_len=48, prefill_buckets=(16,),
+            paged=True, block_size=8, spec_method=spec, spec_k=2)
+        prompt = np.tile(np.arange(4, dtype=np.int32), 3)
+        eng.add_request(prompt, 6, SamplingParams(greedy=True))
+        eng.step()                                  # compiles, untraced
+        common.start_trace(str(tmp_path))
+        try:
+            while eng.has_work:
+                eng.step()
+        finally:
+            jax.profiler.stop_trace()
+        spans = trace_reduce.host_spans(
+            trace_reduce.load_xplane(str(tmp_path)), prefix="mta.")
+        by_name = defaultdict(list)
+        for ev in spans:
+            by_name[ev[0]].append(ev)
+        steps = by_name["mta.engine.step"]
+        rounds = by_name["mta.engine.decode_round"]
+        waits = by_name["mta.engine.decode.wait"]
+        assert steps and rounds and len(waits) == len(rounds)
+        # Nested by time on the stepper's one thread: step > round > wait.
+        for r in rounds:
+            assert sum(_inside(r, s) for s in steps) == 1
+        for w in waits:
+            assert sum(_inside(w, r) for r in rounds) == 1
+        for name in ("stage", "record"):
+            assert len(by_name[f"mta.engine.decode.{name}"]) == len(rounds)
+        assert by_name["mta.engine.retire"]
+        # No span of the program is named outside the interface.
+        assert {n.split(".")[1] for n in by_name} <= {"engine"}
+
+    def test_step_counters(self):
+        eng = _pressure_engine()
+        calls, admitting, prompt_tokens = 0, set(), 0
+        while eng.has_work:
+            waiting = {r.request_id: len(r.tokens) for r in eng.waiting}
+            ev = eng.step()
+            calls += 1
+            if ev["admitted"]:
+                admitting.add(calls)
+            prompt_tokens += sum(waiting[rid] for rid in ev["admitted"])
+        st = eng.stats_snapshot()["steps"]
+        pool = eng.pool.stats
+        assert pool["preemptions"] == 1
+        assert st["step"]["count"] == calls
+        # Two requests, one of them admitted again after its preemption.
+        assert st["queue_wait"]["count"] == 3
+        assert st["prefill"]["count"] == 3
+        assert st["queue_wait"]["max_s"] <= st["queue_wait"]["total_s"]
+        # Tokens the model ran + tokens the prefix cache served = prompt
+        # tokens (a resumed request's prompt includes what it generated);
+        # the pool and spec_stats count them, `steps` does not again.
+        assert pool["prefill_tokens"] + pool["prefix_hit_tokens"] \
+            == prompt_tokens
+        assert eng.spec_stats["emitted_tokens"] + st["prefill"]["count"] \
+            == 24
+        assert set(st) == set(eng.step_stats.PHASES) | {"slowest"}
+        total = {p: st[p]["total_s"] for p in st
+                 if isinstance(st[p], dict)}
+        assert total["admit"] + total["capacity"] + total["decode_round"] \
+            + total["retire"] <= total["step"]
+        assert total["decode.stage"] + total["decode.wait"] \
+            + total["decode.record"] <= total["decode_round"]
+        assert total["prefill_call"] <= total["prefill"] <= total["admit"]
+        for row in (st[p] for p in total):
+            assert 0 <= row["max_s"] <= row["total_s"] or row["count"] == 0
+        # The flight recorder: pure decode rounds only, longest first.
+        slowest = st["slowest"]
+        assert 0 < len(slowest) <= 8
+        assert not {r["step"] for r in slowest} & admitting
+        walls = [r["wall_s"] for r in slowest]
+        assert walls == sorted(walls, reverse=True)
+        for r in slowest:
+            assert r["batch"] >= 1 and "decode.wait" in r["phases"]
+            assert sum(v for p, v in r["phases"].items()
+                       if p in ("capacity", "decode_round", "retire")) \
+                <= r["phases"]["step"] == r["wall_s"]
+        import json
+        json.dumps(st)                      # GET /stats serves it
+
+    def test_flight_recorder_keeps_the_eight_longest(self):
+        from megatronapp_tpu.inference.dynamic_engine import StepStats
+        st = StepStats()
+        before = st.totals()
+        for i, wall in enumerate([5, 1, 9, 3, 7, 2, 8, 6, 4, 10, 0.5]):
+            st.add("step", wall)
+            st.note_round(float(wall), batch=2, before=before)
+            before = st.totals()
+        got = st.snapshot()["slowest"]
+        assert [r["wall_s"] for r in got] == [10, 9, 8, 7, 6, 5, 4, 3]
+        assert [r["step"] for r in got] == [10, 3, 7, 5, 8, 1, 9, 4]
+        assert all(r["phases"] == {"step": r["wall_s"]} for r in got)
+
+    @pytest.mark.parametrize("ring", [False, True])
+    def test_failing_step_closes_every_span_and_counts_once(self, ring):
+        rt = get_request_tracer()
+        rt.configure(enabled=ring)
+        eng = _pressure_engine()
+        eng.step()
+
+        def boom(logits):
+            raise RuntimeError("device lost")
+
+        eng._sample_all = boom
+        before = eng.stats_snapshot()["steps"]
+        with pytest.raises(RuntimeError, match="device lost"):
+            eng.step()
+        after = eng.stats_snapshot()["steps"]
+        for phase in ("step", "decode_round", "decode.stage",
+                      "decode.wait"):
+            assert after[phase]["count"] == before[phase]["count"] + 1
+        assert after["decode.record"] == before["decode.record"]
+        assert after["slowest"] == before["slowest"]
+        assert None not in rt._open             # no step-level span open
+        unmatched, orphan_e = _pair_records(
+            [r for r in rt.dump() if r["tid"] == 0])
+        assert not unmatched and not orphan_e
+        if ring:
+            last = [r for r in rt.dump() if r["name"] == "decode-step"][-1]
+            assert last["ph"] == "E" and last["args"] == {"error": True}
+
+    def test_queue_wait_is_one_measurement(self):
+        """/metrics' serving_queue_wait_ms and /stats' steps.queue_wait
+        come from the same clock reading."""
+        metrics.enable()
+        eng = _pressure_engine()
+        eng.run_to_completion()
+        st = eng.stats_snapshot()["steps"]["queue_wait"]
+        h = metrics.registry().histograms["serving_queue_wait_ms"]
+        assert h.count == st["count"] == 3
+        assert h.sum == pytest.approx(st["total_s"] * 1e3, rel=1e-9)
+
+    def test_driver_counts_deliveries(self):
+        from megatronapp_tpu.inference.server import DynamicBatchingDriver
+        cfg = _gqa_cfg()
+        params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
+        eng = DynamicInferenceEngine(
+            params, cfg, max_batch=2, max_seq_len=48, prefill_buckets=(16,),
+            paged=True, block_size=8)
+        driver = DynamicBatchingDriver(eng)
+        got = []
+        _, done = driver.submit(np.arange(5, dtype=np.int32), 4,
+                                SamplingParams(greedy=True),
+                                token_cb=lambda rid, tok: got.append(tok))
+        assert done.wait(timeout=120)
+        deliver = driver.stats()["deliver"]
+        steps = eng.stats_snapshot()["steps"]["step"]["count"]
+        assert len(got) == 4 and 1 <= deliver["count"] <= steps
+        assert 0 < deliver["max_s"] <= deliver["total_s"]
+
+
+# ---------------------------------------------------------------------------
 class TestServerEndpoints:
     def _server(self):
         from megatronapp_tpu.data.tokenizers import NullTokenizer
@@ -312,6 +496,35 @@ class TestServerEndpoints:
                 bounds.append(float("inf") if le == "+Inf" else float(le))
                 cums.append(int(line.rsplit(" ", 1)[1]))
         return bounds, cums
+
+    def test_stats_endpoint_serves_step_counters(self):
+        """GET /stats: the engine's `steps` and the driver's deliveries,
+        with nothing switched on."""
+        srv = self._server()
+
+        async def run():
+            from aiohttp.test_utils import TestClient
+            from aiohttp.test_utils import TestServer as ATestServer
+            client = TestClient(ATestServer(srv.build_app()))
+            await client.start_server()
+            resp = await client.put("/api", json={
+                "prompts": ["1 2 3"], "tokens_to_generate": 4,
+                "greedy": True})
+            assert resp.status == 200
+            stats = await (await client.get("/stats")).json()
+            health = await (await client.get("/healthz")).json()
+            await client.close()
+            return stats, health
+
+        stats, health = asyncio.run(run())
+        steps = stats["steps"]
+        assert steps["step"]["count"] == 3      # the first admits and decodes
+        assert steps["queue_wait"]["count"] == 1
+        assert steps["decode_round"]["count"] == 3
+        assert stats["pool"]["prefill_tokens"] == 3
+        assert stats["driver_deliver"]["count"] >= 1
+        assert health["stepper"]["deliver"]["count"] \
+            == stats["driver_deliver"]["count"]
 
     def test_metrics_endpoint_and_p99_consistency(self):
         """GET /metrics serves Prometheus text whose token-interval
