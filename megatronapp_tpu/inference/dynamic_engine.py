@@ -21,7 +21,12 @@ Two cache backends:
   waiting queue (it resumes by re-prefilling prompt+generated, usually
   re-hitting its own cached blocks), and decode attends through the
   ragged paged-attention Pallas kernel
-  (ops/pallas/paged_attention.py).
+  (ops/pallas/paged_attention.py). A compiled step updates the pool IN
+  PLACE: the pools are donated, ride the layer loop as a carry, are
+  written row by row (kernel_gen.paged_append) and read through the
+  layer id, and stay row-major on the device (paged_cache.pool_format),
+  so the device holds one pool and a step touches the rows it appends
+  and the blocks it attends.
 
 TPU-first: all shapes static; the decode step is ONE jit for all slots
 (per-row rope positions + per-row masking), prefill runs through
@@ -60,7 +65,7 @@ from megatronapp_tpu.inference.engine import (
     SamplingParams, init_kv_cache, mask_padded_vocab,
 )
 from megatronapp_tpu.inference.paged_cache import (
-    HostSpillTier, PagedKVCache, cdiv,
+    HostSpillTier, PagedKVCache, cdiv, pool_format,
 )
 from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
 from megatronapp_tpu.trace.request_trace import (
@@ -69,6 +74,7 @@ from megatronapp_tpu.trace.request_trace import (
 from megatronapp_tpu.transformer.block import layer_forward
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
+from megatronapp_tpu.utils.platform import fresh_compiles
 
 logger = logging.getLogger(__name__)
 
@@ -248,6 +254,39 @@ def _decode_step(params, tokens, cache, lengths, active,
     return logits, new_caches
 
 
+def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
+    """The layer loop of both paged steps: h through every layer, each
+    appending its new rows to the pools and attending through them.
+
+    The stacked pools [L, NB, bs, ...] (K and V — MLA: latent and k_pe —
+    and the scale pools of a quantised pool) ride the loop as a CARRY:
+    each layer writes its rows into its own plane in place and reads that
+    plane through the layer id (kernel_gen.paged_append /
+    paged_attention(layer=)), so the buffer that enters the step is the
+    buffer that leaves it, and with the callers' donation a step holds one
+    pool. The per-layer inputs (xs) are the block params, the layer ids
+    and lora's factor banks (a, b per target, leading L dim).
+    layer(layer_p, hh, lid, kv_cache, kv_scales, lora_l) runs one layer and
+    returns layer_forward's ((h, new_cache), aux). Returns (h, (k, v[,
+    k_scales, v_scales]))."""
+    def body(carry, xs):
+        hh, kv, kvs = carry
+        layer_p, lid, banks = xs
+        ll = None
+        if lora is not None:
+            ll = {"row_adapter": lora["row_adapter"], "banks": banks}
+        (hh, new), _ = layer(layer_p, hh, lid, kv, kvs, ll)
+        return (hh, tuple(new[:2]),
+                None if kvs is None else tuple(new[2:])), None
+
+    banks = None if lora is None else lora["banks"]
+    (h, pages, scales), _ = jax.lax.scan(
+        body, (h, tuple(pages), None if scales is None else tuple(scales)),
+        (params["block"], jnp.arange(cfg.num_layers), banks),
+        unroll=cfg.scan_unroll)
+    return h, pages + (scales or ())
+
+
 def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
                        cfg: TransformerConfig, max_seq_len: int, ctx=None,
                        scales=None, fused: bool = False, lora=None):
@@ -259,19 +298,20 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     their outputs discarded). scales: ([L, NB, bs, Hkv] fp32, same) for
     an int8 pool — the step then quantizes the appended rows in-jit and
     returns the updated scale pools alongside. fused: megakernel layer
-    body (ISSUE 11) — each scanned layer runs the fused Pallas kernels
+    body (ISSUE 11) — each layer runs the fused Pallas kernels
     of kernel_gen.fused_layer_decode instead of the unfused op tail
     (callers gate on megakernel_ineligible_reason; streams token-exact).
     lora: batched adapter deltas (inference/lora.py) — {"row_adapter":
     [B] int32 bank slots, "banks": {target: (a [L, slots, din, r],
-    b [L, slots, r, dout])}}; the banks join the layer scan's xs (the
-    leading L dim slices per layer) and each projection matmul grows a
+    b [L, slots, r, dout])}}; the banks are sliced per layer by the
+    layer loop and each projection matmul grows a
     per-row low-rank delta (slot 0 = the all-zero null adapter, so the
     trace is identical whether or not any row has a real adapter).
-    The layer scan honors cfg.scan_unroll (PERF lever 3: unrolling
-    removes the while-loop dispatch overhead and lets XLA fuse across
-    layer boundaries). Returns (last_logits [B,V], new pages[, new
-    scales] as one stacked tuple)."""
+    The layer loop (_scan_paged_layers) honors cfg.scan_unroll (PERF
+    lever 3: unrolling removes the while-loop dispatch overhead and lets
+    XLA fuse across layer boundaries). Returns (last_logits [B,V], the
+    pools (k, v[, k_scales, v_scales])): the arrays that came in, each
+    with B new rows a layer written in place."""
     h = gpt_embed(params, tokens, cfg, position_ids=lengths[:, None])
     cos_full, sin_full = gpt_rope_tables(cfg, max_seq_len)
     if cos_full is not None:
@@ -283,43 +323,16 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     # The ragged kernels mask by per-row kv length themselves (MLA
     # included since ISSUE 17 — the latent kernel attends through the
     # page table, no dense gather and no host-built mask).
-    mask = None
-
-    pa, pb = pages
-    lids = jnp.arange(cfg.num_layers)
-
-    # xs layout: block params, kv pools, [kv scale pools,] [lora factor
-    # banks (a, b per target, sorted),] layer ids. The body re-parses by
-    # the same flags so one body covers all four pool/lora combinations.
-    xs = [params["block"], pa, pb]
-    if scales is not None:
-        xs += list(scales)
-    lora_targets = tuple(sorted(lora["banks"])) if lora is not None else ()
-    for t in lora_targets:
-        xs += [lora["banks"][t][0], lora["banks"][t][1]]
-    xs.append(lids)
-
-    def body(carry, layer_in):
-        hh = carry
-        it = iter(layer_in)
-        layer_p, a_l, b_l = next(it), next(it), next(it)
-        kvs = (next(it), next(it)) if scales is not None else None
-        ll = None
-        if lora is not None:
-            ll = {"row_adapter": lora["row_adapter"],
-                  "banks": {t: (next(it), next(it))
-                            for t in lora_targets}}
-        lid = next(it)
-        (hh, new_cache), _ = layer_forward(
-            layer_p, hh, cfg, cos, sin, mask, layer_id=lid,
-            kv_cache=(a_l, b_l), cache_index=None,
+    def layer(layer_p, hh, lid, kv, kvs, ll):
+        return layer_forward(
+            layer_p, hh, cfg, cos, sin, None, layer_id=lid,
+            kv_cache=kv, cache_index=None,
             cache_positions=lengths, page_table=page_table,
             active=active, ctx=ctx, kv_scales=kvs,
             fused_decode=fused, lora=ll)
-        return hh, new_cache
 
-    h, new_pages = jax.lax.scan(body, h, tuple(xs),
-                                unroll=cfg.scan_unroll)
+    h, new_pages = _scan_paged_layers(params, h, pages, scales, lora, cfg,
+                                      layer)
     logits = gpt_head(params, h, cfg)[:, -1]
     return logits, new_pages
 
@@ -336,7 +349,8 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     outputs are garbage); active [B] bool. Row b's token i lands at
     position starts[b] + i and attends the paged context plus the new
     tail causally. Returns (logits [B, S, V], hidden [B, S, H] pre-head,
-    new pages) — hidden feeds the MTP self-draft proposer. fused: run
+    the pools, written in place as in _paged_decode_step) — hidden feeds
+    the MTP self-draft proposer. fused: run
     each layer as kernel_gen.fused_layer_multiquery (megakernel verify/
     chunked-prefill; callers gate on megakernel_ineligible_reason)."""
     b, s = tokens.shape
@@ -353,44 +367,72 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     # The multi-query ragged kernels mask themselves (MLA included since
     # ISSUE 17 — the latent kernel's scalar-prefetched q_lens carries
     # the causal tail mask).
-    mask = None
-
-    pa, pb = pages
-    lids = jnp.arange(cfg.num_layers)
-
-    # Same xs layout as _paged_decode_step: optional scale pools then
-    # optional lora factor banks, parsed back by the closed-over flags.
-    xs = [params["block"], pa, pb]
-    if scales is not None:
-        xs += list(scales)
-    lora_targets = tuple(sorted(lora["banks"])) if lora is not None else ()
-    for t in lora_targets:
-        xs += [lora["banks"][t][0], lora["banks"][t][1]]
-    xs.append(lids)
-
-    def body(carry, layer_in):
-        hh = carry
-        it = iter(layer_in)
-        layer_p, a_l, b_l = next(it), next(it), next(it)
-        kvs = (next(it), next(it)) if scales is not None else None
-        ll = None
-        if lora is not None:
-            ll = {"row_adapter": lora["row_adapter"],
-                  "banks": {t: (next(it), next(it))
-                            for t in lora_targets}}
-        lid = next(it)
-        (hh, new_cache), _ = layer_forward(
-            layer_p, hh, cfg, cos, sin, mask, layer_id=lid,
-            kv_cache=(a_l, b_l), cache_index=None,
+    def layer(layer_p, hh, lid, kv, kvs, ll):
+        return layer_forward(
+            layer_p, hh, cfg, cos, sin, None, layer_id=lid,
+            kv_cache=kv, cache_index=None,
             cache_positions=starts, page_table=page_table,
             active=active, chunk_counts=q_lens, ctx=ctx,
             kv_scales=kvs, fused_decode=fused, lora=ll)
-        return hh, new_cache
 
-    h, new_pages = jax.lax.scan(body, h, tuple(xs),
-                                unroll=cfg.scan_unroll)
+    h, new_pages = _scan_paged_layers(params, h, pages, scales, lora, cfg,
+                                      layer)
     logits = gpt_head(params, h, cfg)
     return logits, h, new_pages
+
+
+class _PoolStep:
+    """The jit of one paged step, `fn(params, tokens, pages, scales,
+    ...)`, whose output is `n_lead` arrays and then the pools it was
+    given.
+
+    The pools are donated and pinned to `pool_format` (row-major, the
+    kernels' order) on the way in and on the way out, so a step aliases
+    them and never relayouts them. A jit's layout names its devices, and
+    the same engine code meets its pools on a CPU (tests), on the chip,
+    and as ShapeDtypeStructs on a DESCRIBED chip (tests/test_chip_compile
+    and perfbench's compile rehearsal lower these steps) — so the jit is
+    built for the sharding the pools arrive with, once, and kept. Pools
+    that carry no sharding (tracers under make_jaxpr) get the plain jit.
+    A call that compiles (the first with its tokens' shape) compiles
+    afresh: utils/platform.fresh_compiles says why."""
+
+    def __init__(self, fn, n_lead: int):
+        self._fn = fn
+        self._n_lead = n_lead
+        self._jits = {}              # pools' shardings -> jit
+        self._called = set()         # (pools' shardings, tokens' shape)
+
+    def _jit(self, args):
+        pools = tuple(args[2]) + tuple(args[3] or ())
+        shardings = tuple(
+            None if isinstance(a, jax.core.Tracer)
+            else getattr(a, "sharding", None) for a in pools)
+        if shardings not in self._jits:
+            pinned = {}
+            if None not in shardings:
+                fmt = tuple(pool_format(sh, a.ndim)
+                            for sh, a in zip(shardings, pools))
+                pinned = dict(
+                    in_shardings=(None, None, fmt[:2], fmt[2:] or None)
+                    + (None,) * (self._fn.__code__.co_argcount - 4),
+                    out_shardings=(None,) * self._n_lead + (fmt,))
+            self._jits[shardings] = jax.jit(
+                self._fn, donate_argnums=(2, 3), **pinned)
+        return shardings, self._jits[shardings]
+
+    def __call__(self, *args):
+        shardings, jit = self._jit(args)
+        call = (shardings, args[1].shape)
+        if call in self._called:
+            return jit(*args)
+        with fresh_compiles():
+            out = jit(*args)
+        self._called.add(call)
+        return out
+
+    def lower(self, *args):
+        return self._jit(args)[1].lower(*args)
 
 
 def _request_keys(seeds, rids, steps):
@@ -730,12 +772,15 @@ class DynamicInferenceEngine:
                         "keeping the unfused decode step: %s", reason)
             fused = self.megakernel
 
-            # `scales` is the int8 pool's fp32 scale-pool pair (None for
-            # bf16 pools — an empty pytree, so the same jit signature
-            # serves both dtypes and donation is a no-op there). `lora`
-            # follows the same trick: None without an adapter cache,
-            # else {"row_adapter", "banks"} (the banks are NOT donated —
-            # they are the cache's resident HBM arrays and outlive the
+            # The pools (`pages`, and `scales`: the int8 pool's fp32
+            # scale-pool pair, None for bf16 pools — an empty pytree, so
+            # the same signature serves both dtypes) are DONATED, and the
+            # layer loop carries them and writes them in place: a step's
+            # output pools are its input buffers, and the device holds
+            # one pool (_PoolStep pins their layout too). `lora` follows
+            # the None trick: None without an adapter cache, else
+            # {"row_adapter", "banks"} (the banks are NOT donated — they
+            # are the cache's resident HBM arrays and outlive the
             # step).
             def _decode_traced(p, t, pages, scales, tbl, l, a, lora):
                 # Python side-effect: runs only while TRACING.
@@ -745,7 +790,7 @@ class DynamicInferenceEngine:
                                           scales=scales, fused=fused,
                                           lora=lora)
 
-            self._decode = jax.jit(_decode_traced, donate_argnums=(2, 3))
+            self._decode = _PoolStep(_decode_traced, n_lead=1)
 
             def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
                            lora):
@@ -756,7 +801,7 @@ class DynamicInferenceEngine:
                                               ctx=step_ctx, scales=scales,
                                               fused=fused, lora=lora)
 
-            self._mq_step = jax.jit(_mq_traced, donate_argnums=(2, 3))
+            self._mq_step = _PoolStep(_mq_traced, n_lead=2)
             if self.spec_method:
                 from megatronapp_tpu.inference.speculative import (
                     build_verify_sampler,
@@ -779,9 +824,10 @@ class DynamicInferenceEngine:
         self._build_jits()
 
     def _commit_pools(self, new):
-        """Install a step's updated pool arrays: bf16 pools return
-        (k, v); int8 pools return (k, v, k_scales, v_scales) — the scale
-        pools updated by the in-jit quantize ride the same scan."""
+        """Take a step's pools back: the donated buffers themselves,
+        written in place — (k, v) for bf16 pools, (k, v, k_scales,
+        v_scales) for int8 pools, whose in-jit quantize writes the scale
+        pools through the same layer loop."""
         if self.pool.quantized:
             self.pool.pages = tuple(new[:2])
             self.pool.scales = tuple(new[2:])

@@ -21,7 +21,9 @@ is recomputed — shared blocks are never written.
 All bookkeeping is host-side (numpy/python); the page DATA lives in jnp
 arrays on `self.pages` and is only touched by jit-able scatter/gather
 helpers (ops/pallas/paged_attention.py) plus the small copy-on-write
-block copy here.
+block copy here. The arrays are held ROW-MAJOR on their devices
+(`pool_format`): the layout in which the Pallas kernels read and write
+them, so the engine's steps update the one pool in place.
 
 Quantized pools (ISSUE 10, ``kv_cache_dtype="int8"``): pages store int8
 with a per-(row, kv-head) fp32 scale pool [L, NB, bs, Hkv] on
@@ -57,6 +59,7 @@ import numpy as np
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
+from megatronapp_tpu.utils.platform import fresh_compiles
 
 
 def cdiv(a: int, b: int) -> int:
@@ -158,6 +161,49 @@ class AdmitPlan:
     cow: bool                # last block was copy-on-write'd (full hit)
 
 
+def pool_format(sharding, ndim: int):
+    """How a pool array of `ndim` dims lies on the devices of `sharding`:
+    row-major, the order in which every Pallas kernel addresses it.
+
+    It has to be said: a TPU's default layout for an array whose minor
+    dim is not a multiple of 128 lanes (head dim 80 or 64) makes ANOTHER
+    axis minor-most to save the padding (the block axis, for a pool), and
+    a step that hands such an array to a kernel relayouts all of it on
+    the way in and again on the way out. Row-major pads D to 128 lanes on
+    the device instead (1.6x the bytes at D 80); `bytes_total` counts the
+    elements, as before. On a CPU this is the default layout."""
+    from jax.experimental.layout import Format, Layout
+    return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
+
+
+def _in_pool_format(a, sharding=None):
+    """`a` committed to `sharding` (default: where it is) in pool_format;
+    `a` itself when it already is, as every step's output is."""
+    import jax
+    if sharding is None:
+        sharding = a.sharding
+    if not (a.committed and a.sharding == sharding):
+        # manual-ok: host-side pool placement (may move the array; its
+        # layout follows below), no manual region
+        a = jax.device_put(a, sharding)
+    if a.format.layout.major_to_minor != tuple(range(a.ndim)):
+        with fresh_compiles():
+            # manual-ok: host-side relayout of a pool array
+            a = jax.device_put(a, pool_format(sharding, a.ndim))
+    return a
+
+
+def _new_pool(shape, dtype, fill):
+    """A pool array of `fill`s on the default device, made there in
+    pool_format (never in the default layout first: at a deployment's
+    size the two do not fit side by side)."""
+    import jax
+    fmt = pool_format(jnp.zeros((), dtype).sharding, len(shape))
+    with fresh_compiles():
+        return jax.jit(lambda: jnp.full(shape, fill, dtype),
+                       out_shardings=fmt)()
+
+
 class PagedKVCache:
     """Block pool + page tables + refcounted prefix cache."""
 
@@ -191,27 +237,21 @@ class PagedKVCache:
         # scales: per-(row, kv-head) fp32 quantization scales for int8
         # pools (None for bf16) — scattered/copied exactly like the data
         # pools (same leading [L, NB, bs] dims).
-        self.scales: Optional[Tuple[jnp.ndarray, ...]] = None
+        self.scales = None
+        dt = dtype_spec.page_dtype if self.quantized else cfg.compute_dtype
         if cfg.multi_latent_attention:
-            dt = (dtype_spec.page_dtype if self.quantized
-                  else cfg.compute_dtype)
-            self.pages: Tuple[jnp.ndarray, ...] = (
-                jnp.zeros((l, nb, bs, cfg.kv_lora_rank), dt),
-                jnp.zeros((l, nb, bs, cfg.qk_pos_emb_head_dim), dt))
-            if self.quantized:
-                # The latent/pe rows have no kv-head axis — the scales
-                # are one SCALAR per (layer, block, row).
-                self.scales = (jnp.ones((l, nb, bs), jnp.float32),
-                               jnp.ones((l, nb, bs), jnp.float32))
+            # The latent/pe rows have no kv-head axis — the scales are
+            # one SCALAR per (layer, block, row).
+            shapes = ((l, nb, bs, cfg.kv_lora_rank),
+                      (l, nb, bs, cfg.qk_pos_emb_head_dim))
+            sshape = (l, nb, bs)
         else:
-            shape = (l, nb, bs, cfg.num_query_groups, cfg.head_dim)
-            dt = (dtype_spec.page_dtype if self.quantized
-                  else cfg.compute_dtype)
-            self.pages = (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-            if self.quantized:
-                sshape = (l, nb, bs, cfg.num_query_groups)
-                self.scales = (jnp.ones(sshape, jnp.float32),
-                               jnp.ones(sshape, jnp.float32))
+            shapes = ((l, nb, bs, cfg.num_query_groups, cfg.head_dim),) * 2
+            sshape = (l, nb, bs, cfg.num_query_groups)
+        self.pages = tuple(_new_pool(sh, dt, 0) for sh in shapes)
+        if self.quantized:
+            self.scales = tuple(_new_pool(sshape, jnp.float32, 1)
+                                for _ in shapes)
 
         self.page_table = np.zeros((self.num_slots, self.max_blocks_per_seq),
                                    np.int32)
@@ -238,6 +278,30 @@ class PagedKVCache:
         self.flush_listener = None
 
     # ---- placement -------------------------------------------------------
+    @property
+    def pages(self) -> Optional[Tuple[jnp.ndarray, ...]]:
+        """The data pools (K, V — MLA: latent, k_pe), each in pool_format.
+        Whatever is assigned is put into it: an engine step hands its
+        pools back as they are, an eager `.at[].set` of the writers below
+        hands back the default layout. None frees them."""
+        return self._pages
+
+    @pages.setter
+    def pages(self, new):
+        self._pages = (None if new is None
+                       else tuple(_in_pool_format(a) for a in new))
+
+    @property
+    def scales(self) -> Optional[Tuple[jnp.ndarray, ...]]:
+        """The fp32 scale pools of a quantised pool (else None), held
+        like `pages`."""
+        return self._scales
+
+    @scales.setter
+    def scales(self, new):
+        self._scales = (None if new is None
+                        else tuple(_in_pool_format(a) for a in new))
+
     def place_pages(self, sharding, scales_sharding=None):
         """Commit the page pools to an explicit device placement (tp
         serving mesh: sharded on the Hkv dim — MLA: latent columns —
@@ -247,10 +311,8 @@ class PagedKVCache:
         each be a single sharding applied to every pool, OR a sequence
         with one entry per pool (the MLA tp layout shards the latent
         pool but replicates the pe pool). Later jnp updates (CoW copy,
-        the engine's scatter/append jits) preserve the committed
-        sharding by propagation."""
-        import jax
-
+        the engine's steps) preserve the committed sharding by
+        propagation, and the setters keep the layout."""
         def _per_pool(sh, n):
             if isinstance(sh, (list, tuple)):
                 assert len(sh) == n, (len(sh), n)
@@ -259,14 +321,14 @@ class PagedKVCache:
 
         data_sh = _per_pool(sharding, len(self.pages))
         # manual-ok: host-side pool placement, no manual region
-        self.pages = tuple(jax.device_put(p, s)
+        self.pages = tuple(_in_pool_format(p, s)
                            for p, s in zip(self.pages, data_sh))
         if self.scales is not None:
             sc_sh = _per_pool(scales_sharding if scales_sharding is not None
                               else sharding, len(self.scales))
             self.scales = tuple(
                 # manual-ok: host-side pool placement, no manual region
-                jax.device_put(s, sh)
+                _in_pool_format(s, sh)
                 for s, sh in zip(self.scales, sc_sh))
 
     # ---- sizing ----------------------------------------------------------

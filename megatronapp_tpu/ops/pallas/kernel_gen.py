@@ -230,14 +230,15 @@ def emit_paged_kernel(spec: PagedSpec):
     ragged, quantized = spec.ragged, spec.quantized
     scale = spec.scale
 
-    def kernel(*refs):
+    def kernel(lid_ref, *refs):
         if ragged:
             table_ref, lens_ref, qlens_ref = refs[:3]
             rest = refs[3:]
         else:
             table_ref, lens_ref = refs[:2]
             rest = refs[2:]
-        del table_ref  # indirection is consumed by the BlockSpec index maps
+        # layer and block indirection are consumed by the BlockSpec index maps
+        del lid_ref, table_ref
         q_ref, k_ref, v_ref = rest[:3]
         rest = rest[3:]
         if quantized:
@@ -368,14 +369,15 @@ def emit_latent_kernel(spec: PagedSpec):
     ragged, quantized = spec.ragged, spec.quantized
     scale = spec.scale
 
-    def kernel(*refs):
+    def kernel(lid_ref, *refs):
         if ragged:
             table_ref, lens_ref, qlens_ref = refs[:3]
             rest = refs[3:]
         else:
             table_ref, lens_ref = refs[:2]
             rest = refs[2:]
-        del table_ref  # indirection is consumed by the BlockSpec index maps
+        # layer and block indirection are consumed by the BlockSpec index maps
+        del lid_ref, table_ref
         ql_ref, qp_ref, lat_ref, pe_ref = rest[:4]
         rest = rest[4:]
         if quantized:
@@ -464,6 +466,19 @@ def emit_latent_kernel(spec: PagedSpec):
     return kernel
 
 
+def _stacked(layer, *pools):
+    """(layer id as int32[1], the pools with a leading layer axis).
+
+    Every paged kernel reads a STACKED pool [L, NB, bs, ...]: the layer id
+    is scalar-prefetched and leads each pool index map, so the engine's
+    steps hand the whole pool over and no per-layer slice is materialised.
+    A caller that holds one layer's pool (layer None) gets a unit axis."""
+    if layer is None:
+        return (jnp.zeros((1,), jnp.int32),
+                tuple(None if p is None else p[None] for p in pools))
+    return jnp.reshape(layer, (1,)).astype(jnp.int32), pools
+
+
 def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                            lat_pages: jnp.ndarray, pe_pages: jnp.ndarray,
                            page_table: jnp.ndarray, kv_lens: jnp.ndarray,
@@ -472,7 +487,7 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                            softmax_scale: Optional[float] = None,
                            lat_scales: Optional[jnp.ndarray] = None,
                            pe_scales: Optional[jnp.ndarray] = None,
-                           mesh=None) -> jnp.ndarray:
+                           mesh=None, layer=None) -> jnp.ndarray:
     """MLA latent-space ragged paged attention with absorbed q weights
     (ISSUE 17 tentpole) — the latent-family entry point.
 
@@ -488,6 +503,8 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     the MLA scale 1/sqrt(dqk + dpe) is not derivable from the latent
     width. mesh: latent-COLUMN-shard over the tp axis (_tp_place_latent
     — MLA has no KV heads to split); callers gate on tp_paged_eligible.
+    layer: int32 scalar — the pools (and scale pools) are then STACKED
+    with a leading layer axis and the kernel reads that layer's blocks.
     Returns [B(, S_q), nq, dv] in q_lat's dtype."""
     ragged = q_lens is not None
     if softmax_scale is None:
@@ -495,11 +512,13 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
             "paged_attention_latent requires softmax_scale: the MLA "
             "scale is 1/sqrt(qk_head_dim + qk_pos_emb_head_dim), which "
             "cannot be derived from the latent width")
+    lid, (lat_pages, pe_pages, lat_scales, pe_scales) = _stacked(
+        layer, lat_pages, pe_pages, lat_scales, pe_scales)
     if mesh is not None:
         return _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages,
                                 page_table, kv_lens, w_v, q_lens,
                                 softmax_scale, lat_scales, pe_scales,
-                                mesh)
+                                mesh, lid)
     if ragged:
         b, s_q, nq, klat = q_lat.shape
     else:
@@ -507,7 +526,7 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
         s_q = 1
     dpe = q_pe.shape[-1]
     dv = w_v.shape[-1]
-    nb, bs, _ = lat_pages.shape
+    bs = lat_pages.shape[2]
     mb = page_table.shape[1]
     quantized = lat_scales is not None
     quant_dtype = quant_dtype_of(lat_pages.dtype) if quantized else None
@@ -523,10 +542,10 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                      latent=True, klat=klat, dpe=dpe, dv=dv)
     kernel = emit_paged_kernel(spec)
 
-    lat_spec = pl.BlockSpec((1, bs, klat),
-                            lambda b_, j, t, *_: (t[b_, j], 0, 0))
-    pe_spec = pl.BlockSpec((1, bs, dpe),
-                           lambda b_, j, t, *_: (t[b_, j], 0, 0))
+    lat_spec = pl.BlockSpec((None, 1, bs, klat),
+                            lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
+    pe_spec = pl.BlockSpec((None, 1, bs, dpe),
+                           lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
     if ragged:
         ql_spec = pl.BlockSpec((1, s_q, nq, klat),
                                lambda b_, j, *_: (b_, 0, 0, 0))
@@ -545,14 +564,14 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     in_specs = [ql_spec, qp_spec, lat_spec, pe_spec]
     operands = [q_lat, q_pe, lat_pages, pe_pages]
     if quantized:
-        sc_spec = pl.BlockSpec((1, bs),
-                               lambda b_, j, t, *_: (t[b_, j], 0))
+        sc_spec = pl.BlockSpec((None, 1, bs),
+                               lambda b_, j, l, t, *_: (l[0], t[b_, j], 0))
         in_specs += [sc_spec, sc_spec]
         operands += [lat_scales, pe_scales]
     in_specs.append(pl.BlockSpec(w_v.shape, lambda b_, j, *_: (0, 0, 0)))
     operands.append(w_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 if ragged else 2,
+        num_scalar_prefetch=4 if ragged else 3,
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=o_spec,
@@ -562,7 +581,8 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
             pltpu.VMEM((s_q * nq, 1), jnp.float32),
         ],
     )
-    prefetch = [page_table.astype(jnp.int32), kv_lens.astype(jnp.int32)]
+    prefetch = [lid, page_table.astype(jnp.int32),
+                kv_lens.astype(jnp.int32)]
     if ragged:
         prefetch.append(q_lens.astype(jnp.int32))
     return pl.pallas_call(
@@ -573,28 +593,29 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     )(*prefetch, *operands)
 
 
-def _latent_block_scores(q, pages, page_table, kv_lens, scales=None,
+def _latent_block_scores(q, pages, page_table, kv_lens, lid, scales=None,
                          family="paged_decode"):
     """Phase 1 of the latent-column tp path: ALL block scores
-    q · pages^T over the page table — q [B, rows, d] × pages [NB, bs, d]
+    q · pages^T over the page table — q [B, rows, d] × layer lid[0] of the
+    stacked pages [L, NB, bs, d] (scales [L, NB, bs])
     → [B, rows, MB*bs] fp32, NO softmax. Out-of-range blocks write 0 so
     the cross-shard psum of klat-column partials stays finite; the
     caller masks before its fp32 softmax. scales [NB, bs] fp32 mark a
     quantized pool (per-row scalar scales compose multiplicatively with
     column shards, so per-shard dequant partials sum exactly)."""
     b, rows, d = q.shape
-    nb, bs, _ = pages.shape
+    bs = pages.shape[2]
     mb = page_table.shape[1]
     quantized = scales is not None
 
-    def kernel(*refs):
+    def kernel(lid_ref, *refs):
         table_ref, lens_ref, q_ref, kv_ref = refs[:4]
         rest = refs[4:]
         if quantized:
             sc_ref, o_ref = rest
         else:
             o_ref, = rest
-        del table_ref
+        del lid_ref, table_ref
         b_ = pl.program_id(0)
         j = pl.program_id(1)
         kv_len = lens_ref[b_]
@@ -612,17 +633,17 @@ def _latent_block_scores(q, pages, page_table, kv_lens, scales=None,
         def _zero():
             o_ref[0] = jnp.zeros_like(o_ref)[0]
 
-    kv_spec = pl.BlockSpec((1, bs, d),
-                           lambda b_, j, t, *_: (t[b_, j], 0, 0))
+    kv_spec = pl.BlockSpec((None, 1, bs, d),
+                           lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
     q_spec = pl.BlockSpec((1, rows, d), lambda b_, j, *_: (b_, 0, 0))
     in_specs = [q_spec, kv_spec]
     operands = [q, pages]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, bs),
-                                     lambda b_, j, t, *_: (t[b_, j], 0)))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bs), lambda b_, j, l, t, *_: (l[0], t[b_, j], 0)))
         operands.append(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, bs), lambda b_, j, *_: (b_, 0, j)),
@@ -632,33 +653,35 @@ def _latent_block_scores(q, pages, page_table, kv_lens, scales=None,
         out_shape=jax.ShapeDtypeStruct((b, rows, mb * bs), jnp.float32),
         interpret=_interpret(),
         name=f"{family}_latent_scores",
-    )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32), *operands)
+    )(lid, page_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      *operands)
 
 
-def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None,
-                       family="paged_decode"):
+def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, lid,
+                       scales=None, family="paged_decode"):
     """Phase 2 of the latent-column tp path: probability-weighted value
     sum over the page table with the per-tile in-register re-expansion
     — p [B, rows, MB*bs] fp32 (masked softmax, zeros past each row's
-    run) × pages [NB, bs, klat_local] through w_v [klat_local, nq, dv]
+    run) × layer lid[0] of the stacked pages [L, NB, bs, klat_local]
+    through w_v [klat_local, nq, dv]
     → [B, rows, dv] fp32 partials (the caller psums over the klat
     shards)."""
     b, rows, _ = p.shape
-    nb, bs, _ = pages.shape
+    bs = pages.shape[2]
     mb = page_table.shape[1]
     nq, dv = w_v.shape[1], w_v.shape[2]
     s_q = rows // nq
     quantized = scales is not None
     mbs_ = mb
 
-    def kernel(*refs):
+    def kernel(lid_ref, *refs):
         table_ref, lens_ref, p_ref, kv_ref = refs[:4]
         rest = refs[4:]
         if quantized:
             sc_ref, wv_ref, o_ref, acc = rest
         else:
             wv_ref, o_ref, acc = rest
-        del table_ref
+        del lid_ref, table_ref
         b_ = pl.program_id(0)
         j = pl.program_id(1)
 
@@ -691,19 +714,19 @@ def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None,
         def _finalize():
             o_ref[0] = acc[:]
 
-    kv_spec = pl.BlockSpec((1, bs, pages.shape[-1]),
-                           lambda b_, j, t, *_: (t[b_, j], 0, 0))
+    kv_spec = pl.BlockSpec((None, 1, bs, pages.shape[-1]),
+                           lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
     p_spec = pl.BlockSpec((1, rows, bs), lambda b_, j, *_: (b_, 0, j))
     in_specs = [p_spec, kv_spec]
     operands = [p, pages]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, bs),
-                                     lambda b_, j, t, *_: (t[b_, j], 0)))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bs), lambda b_, j, l, t, *_: (l[0], t[b_, j], 0)))
         operands.append(scales)
     in_specs.append(pl.BlockSpec(w_v.shape, lambda b_, j, *_: (0, 0, 0)))
     operands.append(w_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, dv), lambda b_, j, *_: (b_, 0, 0)),
@@ -714,12 +737,13 @@ def _latent_block_wsum(p, pages, page_table, kv_lens, w_v, scales=None,
         out_shape=jax.ShapeDtypeStruct((b, rows, dv), jnp.float32),
         interpret=_interpret(),
         name=f"{family}_latent_wsum",
-    )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32), *operands)
+    )(lid, page_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      *operands)
 
 
 def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
                      kv_lens, w_v, q_lens, softmax_scale, lat_scales,
-                     pe_scales, mesh):
+                     pe_scales, mesh, lid):
     """Latent-COLUMN sharded placement of the MLA kernel family: MLA
     has no KV heads to split, so the tp axis shards the klat dim of the
     latent pool, the absorbed query, and kv_up's v rows (q_pe / pe
@@ -728,7 +752,9 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
     couples every latent column, so the body runs TWO emitted kernels
     around a replicated fp32 softmax: block scores (nope partials
     psum'd over shards + replicated pe scores) → host mask/softmax →
-    weighted value sum (dv partials psum'd). The latent pool is read
+    weighted value sum (dv partials psum'd). The pools arrive STACKED
+    [L, NB, bs, ...] with the layer id lid int32[1] (replicated). The
+    latent pool is read
     once per phase; the output is fully replicated (the psum), so the
     out-projection runs identically on every device and per-request
     streams stay engine-exact."""
@@ -747,32 +773,33 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
     dv = w_v.shape[-1]
     rows = s_q * nq
     mb = page_table.shape[1]
-    bs = lat_pages.shape[1]
+    bs = lat_pages.shape[2]
     quantized = lat_scales is not None
     out_dtype = q_lat.dtype
 
     q_sh = (P(None, None, None, TP_AXIS) if ragged
             else P(None, None, TP_AXIS))
     q_rep = (P(None, None, None, None) if ragged else P(None, None, None))
-    pool_sh = P(None, None, TP_AXIS)
-    pool_rep = P(None, None, None)
-    rep2, rep1 = P(None, None), P(None)
+    pool_sh = P(None, None, None, TP_AXIS)
+    pool_rep = P(None, None, None, None)
+    rep3, rep2, rep1 = P(None, None, None), P(None, None), P(None)
     out_sh = (P(None, None, None, None) if ragged else P(None, None, None))
 
     in_specs = [q_sh, q_rep, pool_sh, pool_rep, rep2, rep1,
-                P(TP_AXIS, None, None)]
+                P(TP_AXIS, None, None), rep1]
     operands = [q_lat, q_pe, lat_pages, pe_pages, page_table, kv_lens,
-                w_v]
+                w_v, lid]
     if ragged:
         in_specs.append(rep1)
         operands.append(q_lens)
     if quantized:
-        in_specs += [rep2, rep2]
+        in_specs += [rep3, rep3]
         operands += [lat_scales, pe_scales]
 
     def body(*args):
         it = iter(args)
-        ql_, qp_, lat_, pe_, t_, l_, wv_ = (next(it) for _ in range(7))
+        ql_, qp_, lat_, pe_, t_, l_, wv_, lid_ = (next(it)
+                                                  for _ in range(8))
         qlens_ = next(it) if ragged else None
         ls_ = ps_ = None
         if quantized:
@@ -783,11 +810,13 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
             b, rows, -1)
         qpf = (qp_.astype(jnp.float32) * softmax_scale).reshape(
             b, rows, -1)
-        s_nope = _latent_block_scores(qlf, lat_, t_, l_, ls_, family)
+        s_nope = _latent_block_scores(qlf, lat_, t_, l_, lid_, ls_,
+                                      family)
         s_nope = psum(s_nope, TP_AXIS)
         # pe scores are replicated work (dpe is tiny) — identical on
         # every shard, no psum.
-        s = s_nope + _latent_block_scores(qpf, pe_, t_, l_, ps_, family)
+        s = s_nope + _latent_block_scores(qpf, pe_, t_, l_, lid_, ps_,
+                                          family)
         pos = jnp.arange(mb * bs, dtype=jnp.int32)[None, None, :]
         if ragged:
             row_q = (jnp.arange(rows, dtype=jnp.int32)
@@ -801,7 +830,8 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
         pr = jnp.exp(s - jnp.maximum(m, _NEG_INF / 2))
         pr = jnp.where(valid, pr, 0.0)
         pr = pr / jnp.maximum(jnp.sum(pr, axis=-1, keepdims=True), 1e-20)
-        out = _latent_block_wsum(pr, lat_, t_, l_, wv_, ls_, family)
+        out = _latent_block_wsum(pr, lat_, t_, l_, wv_, lid_, ls_,
+                                 family)
         out = psum(out, TP_AXIS).astype(out_dtype)
         return (out.reshape(b, s_q, nq, dv) if ragged
                 else out.reshape(b, nq, dv))
@@ -820,7 +850,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     softmax_scale: Optional[float] = None,
                     k_scales: Optional[jnp.ndarray] = None,
                     v_scales: Optional[jnp.ndarray] = None,
-                    mesh=None) -> jnp.ndarray:
+                    mesh=None, layer=None) -> jnp.ndarray:
     """Ragged paged attention — the single generator entry point.
 
     q [B, Hq, D] (decode) or [B, S_q, Hq, D] with q_lens [B] (ragged
@@ -829,18 +859,23 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     pools (dequant rides the same page-table indirection, in-register).
     mesh: head-shard the emitted kernel over the tp axis of this mesh
     (full-manual shard_map — q on heads, pools + scale pools on Hkv,
-    table/lens replicated); callers gate on tp_paged_eligible. Returns
-    q's shape."""
+    table/lens replicated); callers gate on tp_paged_eligible. layer:
+    int32 scalar — the pools (and scale pools) are then STACKED
+    [L, NB, bs, Hkv, D] and the kernel reads that layer's blocks (the
+    engine's steps: the pool is a loop carry, never sliced). Returns q's
+    shape."""
     ragged = q_lens is not None
+    lid, (k_pages, v_pages, k_scales, v_scales) = _stacked(
+        layer, k_pages, v_pages, k_scales, v_scales)
     if mesh is not None:
         return _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
-                         softmax_scale, k_scales, v_scales, mesh)
+                         softmax_scale, k_scales, v_scales, mesh, lid)
     if ragged:
         b, s_q, hq, d = q.shape
     else:
         b, hq, d = q.shape
         s_q = 1
-    nb, bs, hkv, _ = k_pages.shape
+    _, _, bs, hkv, _ = k_pages.shape
     mb = page_table.shape[1]
     if softmax_scale is None:
         softmax_scale = 1.0 / (d ** 0.5)
@@ -858,11 +893,13 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     kernel = emit_paged_kernel(spec)
 
-    # Page-table indirection: the table and per-slot lengths (and ragged
-    # q_lens) are scalar-prefetched so the index maps can DMA block
-    # t[b, j] straight from HBM — int8 scale blocks ride the same map.
-    kv_spec = pl.BlockSpec((1, bs, hkv, d),
-                           lambda b_, j, t, *_: (t[b_, j], 0, 0, 0))
+    # Page-table indirection: the layer id, the table and per-slot
+    # lengths (and ragged q_lens) are scalar-prefetched so the index maps
+    # can DMA block t[b, j] of layer l straight from the stacked pool in
+    # HBM — int8 scale blocks ride the same map.
+    kv_spec = pl.BlockSpec(
+        (None, 1, bs, hkv, d),
+        lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0, 0))
     if ragged:
         q_spec = pl.BlockSpec((1, s_q, hq, d),
                               lambda b_, j, *_: (b_, 0, 0, 0))
@@ -871,12 +908,13 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pages, v_pages]
     if quantized:
-        sc_spec = pl.BlockSpec((1, bs, hkv),
-                               lambda b_, j, t, *_: (t[b_, j], 0, 0))
+        sc_spec = pl.BlockSpec(
+            (None, 1, bs, hkv),
+            lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 if ragged else 2,
+        num_scalar_prefetch=4 if ragged else 3,
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -886,7 +924,8 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             pltpu.VMEM((s_q * hq, 1), jnp.float32),
         ],
     )
-    prefetch = [page_table.astype(jnp.int32), kv_lens.astype(jnp.int32)]
+    prefetch = [lid, page_table.astype(jnp.int32),
+                kv_lens.astype(jnp.int32)]
     if ragged:
         prefetch.append(q_lens.astype(jnp.int32))
     return pl.pallas_call(
@@ -898,10 +937,11 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
-              softmax_scale, k_scales, v_scales, mesh):
+              softmax_scale, k_scales, v_scales, mesh, lid):
     """Head-sharded placement of the emitted kernel: a FULL-MANUAL
-    shard_map over the tp axis — q sharded on heads, pools (and int8
-    scale pools) on Hkv, page table / lengths / q_lens replicated. Each
+    shard_map over the tp axis — q sharded on heads, the STACKED pools
+    (and int8 scale pools) on Hkv, page table / lengths / q_lens / layer
+    id replicated. Each
     shard owns matched GQA groups (contiguous slicing of both head dims
     preserves h // group), so the per-shard body is the UNMODIFIED
     emitted kernel; no collectives run inside. tp_paged_eligible callers
@@ -916,12 +956,12 @@ def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
         softmax_scale = 1.0 / (q.shape[-1] ** 0.5)
     head = (P(None, None, TP_AXIS, None) if ragged
             else P(None, TP_AXIS, None))
-    pages = P(None, None, TP_AXIS, None)      # pools [NB, bs, Hkv, D]
-    scales = P(None, None, TP_AXIS)           # scale pools [NB, bs, Hkv]
+    pages = P(None, None, None, TP_AXIS, None)  # [L, NB, bs, Hkv, D]
+    scales = P(None, None, None, TP_AXIS)       # [L, NB, bs, Hkv]
     rep2, rep1 = P(None, None), P(None)
 
-    in_specs = [head, pages, pages, rep2, rep1]
-    operands = [q, k_pages, v_pages, page_table, kv_lens]
+    in_specs = [head, pages, pages, rep2, rep1, rep1]
+    operands = [q, k_pages, v_pages, page_table, kv_lens, lid]
     if ragged:
         in_specs.append(rep1)
         operands.append(q_lens)
@@ -930,8 +970,8 @@ def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
         operands += [k_scales, v_scales]
 
     def body(*args):
-        q_, k_, v_, t_, l_ = args[:5]
-        rest = args[5:]
+        q_, k_, v_, t_, l_, lid_ = args[:6]
+        rest = args[6:]
         ql_ = None
         if ragged:
             ql_, rest = rest[0], rest[1:]
@@ -940,12 +980,107 @@ def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
             ks_, vs_ = rest
         return paged_attention(q_, k_, v_, t_, l_, q_lens=ql_,
                                softmax_scale=softmax_scale,
-                               k_scales=ks_, v_scales=vs_)
+                               k_scales=ks_, v_scales=vs_, layer=lid_[0])
 
     # manual-ok: full-manual kernel placement, no collectives in body;
     # tp_paged_eligible callers gate on no ambient manual axes.
     return shard_map_compat(body, mesh, in_specs=tuple(in_specs),
                             out_specs=head)(*operands)
+
+
+# ---------------------------------------------------------------------------
+# The pool writer: new rows go into the stacked pool in place
+# ---------------------------------------------------------------------------
+
+
+def paged_append(pool: jnp.ndarray, rows: jnp.ndarray, layer,
+                 blocks: jnp.ndarray, offsets: jnp.ndarray,
+                 mesh=None) -> jnp.ndarray:
+    """Write `rows` into one layer of a stacked page pool, IN PLACE.
+
+    pool [L, NB, bs, *row]; rows [N, *row]; layer int32 scalar; blocks /
+    offsets [N] int32. Row i lands at pool[layer, blocks[i], offsets[i]];
+    a row whose block id is NB or more is DROPPED, never clamped (an
+    inactive slot's table may name a block another request owns now, and
+    padding rows of a ragged chunk have no position). Returns the pool:
+    the same buffer wherever the caller's own copy of it is dead (a
+    donated jit argument, a loop carry), so a step touches the N rows it
+    appends and not the pool.
+
+    K/V rows ([Hkv, D]: whole tiled planes of the pool) go through ONE
+    Pallas call whose output aliases the pool. Its grid runs over the
+    VALID rows only (they are sorted first and their count bounds the
+    grid, so a call with no valid row writes nothing), each step copying
+    a row into the block the scalar-prefetched (layer, block, offset)
+    name. Narrower rows (scale rows [Hkv], MLA latent rows [klat], latent
+    scale rows []) are part of a tile, which no DMA addresses: they are
+    written by one `dynamic_update_slice` a row, which XLA applies in
+    place to a loop carry or a donated argument.
+
+    mesh: the pool is sharded over the tp axis on its first row dim (Hkv)
+    and the Pallas call is placed like the readers (`_tp_place`); the XLA
+    rows need no placement (GSPMD partitions a dynamic_update_slice)."""
+    nb = pool.shape[1]
+    layer = jnp.asarray(layer, jnp.int32)
+    blocks = blocks.astype(jnp.int32)
+    offsets = offsets.astype(jnp.int32)
+    row = rows.shape[1:]
+    if len(row) < 2:
+        tail = (0,) * len(row)
+
+        def one(i, pool_):
+            at = (layer, jnp.minimum(blocks[i], nb - 1), offsets[i]) + tail
+            new = rows[i][None, None, None]
+            old = jax.lax.dynamic_slice(pool_, at, new.shape)
+            return jax.lax.dynamic_update_slice(
+                pool_, jnp.where(blocks[i] < nb, new, old), at)
+
+        return jax.lax.fori_loop(0, rows.shape[0], one, pool)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from megatronapp_tpu.config.parallel_config import TP_AXIS
+        from megatronapp_tpu.parallel.collectives import shard_map_compat
+        rest = (None,) * (len(row) - 1)
+        pool_spec = P(None, None, None, TP_AXIS, *rest)
+        # manual-ok: full-manual kernel placement, no collectives in body;
+        # tp_paged_eligible callers gate on no ambient manual axes.
+        return shard_map_compat(
+            paged_append, mesh,
+            in_specs=(pool_spec, P(None, TP_AXIS, *rest), P(), P(None),
+                      P(None)),
+            out_specs=pool_spec)(pool, rows, layer, blocks, offsets)
+
+    valid = blocks < nb
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+    zeros = (0,) * len(row)
+
+    def kernel(lid_ref, blk_ref, off_ref, src_ref, rows_ref, pool_ref,
+               out_ref):
+        # where each row goes is all in the index maps; the pool is only
+        # here to be aliased
+        del lid_ref, blk_ref, off_ref, src_ref, pool_ref
+        out_ref[...] = rows_ref[...]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(jnp.sum(valid, dtype=jnp.int32),),
+        in_specs=[
+            pl.BlockSpec((None,) + row,
+                         lambda i, lid, blk, off, src: (src[i],) + zeros),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, None, None) + row,
+            lambda i, lid, blk, off, src: (lid[0], blk[i], off[i]) + zeros),
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={5: 0},
+        interpret=_interpret(),
+        name="paged_append",
+    )(layer.reshape(1), blocks[order], offsets[order], order, rows, pool)
 
 
 # ---------------------------------------------------------------------------
@@ -1869,22 +2004,21 @@ def _fused_mlp_fc2(y, x, p, cfg, t):
 
 def _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
                      cache_positions, counts, page_table, active,
-                     kv_scales=None):
+                     layer_id, kv_scales=None):
     """One MLA layer as fused kernels (ISSUE 17 carve-out c): [fused
     MLA prologue — norm + q path + rope + absorption + latent/k_pe] →
-    [compressed append scatter] → [generated latent-space paged kernel]
+    [compressed append, in place] → [generated latent-space paged kernel]
     → [fused out-proj + residual] → [fused norm+MLP + residual].
 
     Handles BOTH the s == 1 decode body (counts=None) and the ragged
     multiquery body (counts [B]) — the prologue is row-wise, so the
     B·S flattening is bitwise-safe exactly like fused_layer_multiquery.
-    kv_scales: int8/fp8 latent pool scale pools ([L-sliced NB, bs] per
-    pool) — the new rows quantize per-row right here (ONE fused jit
-    covers prologue + quantize + scatter + attend) and new_cache
-    carries four pools."""
-    from megatronapp_tpu.ops.pallas.paged_attention import (
-        append_chunk_pages, append_token_pages, quantize_kv_rows,
-    )
+    kv_cache: the STACKED latent/k_pe pools [L, NB, bs, ...], of which
+    layer_id names this layer's plane. kv_scales: int8/fp8 latent pool
+    scale pools ([L, NB, bs] per pool) — the new rows quantize per-row
+    right here (ONE fused jit covers prologue + quantize + append +
+    attend) and new_cache carries four pools."""
+    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
     b, s, h = x.shape
     nq = cfg.num_attention_heads
     dqk, dpe, dv = cfg.qk_head_dim, cfg.qk_pos_emb_head_dim, cfg.v_head_dim
@@ -1903,48 +2037,30 @@ def _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
     w_v = attn_p["kv_up"].astype(dt).reshape(
         klat, nq, dqk + dv)[..., dqk:]
 
-    c_lat, c_pe = kv_cache
     if active is None:
         active = jnp.ones((b,), bool)
-    if ragged:
-        lat_r, pe_r = lat.reshape(b, s, klat), pe.reshape(b, s, dpe)
-
-        def _append(pool, rows_):
-            return append_chunk_pages(pool, rows_, page_table,
-                                      cache_positions, counts, active)
+    rows_ = ((lat.reshape(b, s, klat), pe.reshape(b, s, dpe)) if ragged
+             else (lat, pe))
+    (c_lat, c_pe), new_scales = append_kv(
+        kv_cache, kv_scales, rows_, page_table, cache_positions, active,
+        layer_id, counts)
+    if new_scales is None:
+        new_cache, sc_kw = (c_lat, c_pe), {}
     else:
-        lat_r, pe_r = lat[:, None], pe[:, None]
-
-        def _append(pool, rows_):
-            return append_token_pages(pool, rows_[:, 0], page_table,
-                                      cache_positions, active)
-
-    if kv_scales is not None:
-        ls_p, ps_p = kv_scales
-        lat_q, lat_s = quantize_kv_rows(lat_r, dtype=c_lat.dtype)
-        pe_q, pe_s = quantize_kv_rows(pe_r, dtype=c_pe.dtype)
-        c_lat = _append(c_lat, lat_q)
-        c_pe = _append(c_pe, pe_q)
-        ls_p = _append(ls_p, lat_s)
-        ps_p = _append(ps_p, pe_s)
-        new_cache = (c_lat, c_pe, ls_p, ps_p)
-        sc_kw = {"lat_scales": ls_p, "pe_scales": ps_p}
-    else:
-        c_lat = _append(c_lat, lat_r.astype(c_lat.dtype))
-        c_pe = _append(c_pe, pe_r.astype(c_pe.dtype))
-        new_cache = (c_lat, c_pe)
-        sc_kw = {}
+        new_cache = (c_lat, c_pe) + new_scales
+        sc_kw = {"lat_scales": new_scales[0], "pe_scales": new_scales[1]}
 
     scale = 1.0 / float((dqk + dpe) ** 0.5)
     if ragged:
         attn = paged_attention_latent(
             q_lat.reshape(b, s, nq, klat), q_pe.reshape(b, s, nq, dpe),
             c_lat, c_pe, page_table, cache_positions + counts, w_v,
-            q_lens=counts, softmax_scale=scale, **sc_kw)
+            q_lens=counts, softmax_scale=scale, layer=layer_id, **sc_kw)
     else:
         attn = paged_attention_latent(
             q_lat, q_pe, c_lat, c_pe, page_table, cache_positions + 1,
-            w_v, softmax_scale=scale, **sc_kw)        # [B, nq, dv]
+            w_v, softmax_scale=scale, layer=layer_id,
+            **sc_kw)                                  # [B, nq, dv]
     x2 = _fused_out_proj(attn.reshape(b * s, nq * dv), attn_p, cfg, xf)
     x2 = _fused_mlp(x2, p, cfg)
     out = x2[:, None] if not ragged else x2.reshape(b, s, h)
@@ -1972,11 +2088,12 @@ def _lora_gathered(lora, s: int = 1):
 
 
 def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                       cache_positions, page_table, active,
+                       cache_positions, page_table, active, layer_id,
                        kv_scales=None, lora=None):
     """One decode layer as fused kernels: [fused norm+QKV+rope] →
-    [append scatter] → [generated paged-attention kernel] → [fused
-    out-proj + residual] → [fused norm+MLP + residual].
+    [append, in place] → [generated paged-attention kernel] → [fused
+    out-proj + residual] → [fused norm+MLP + residual]. kv_cache is the
+    STACKED pool pair and layer_id names this layer's plane of it.
 
     Drop-in for transformer/block.layer_forward's s == 1 paged decode
     path (cfg.megakernel_decode; DynamicInferenceEngine(fused_decode=
@@ -1984,9 +2101,7 @@ def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
     streams token-exact vs the unfused body. MegaScope capture /
     disturbance sites are NOT traced here — megakernel_ineligible_reason
     gates the fused path off while hooks are active."""
-    from megatronapp_tpu.ops.pallas.paged_attention import (
-        append_token_pages, quantize_kv_rows,
-    )
+    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
     b = x.shape[0]
     assert x.shape[1] == 1, "fused_layer_decode is the s == 1 decode body"
     if cfg.multi_latent_attention:
@@ -1995,7 +2110,7 @@ def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
             "megakernel_ineligible_reason(lora_rank=) gates MLA off")
         return _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
                                 cache_positions, None, page_table,
-                                active, kv_scales=kv_scales)
+                                active, layer_id, kv_scales=kv_scales)
     nq, d = cfg.num_attention_heads, cfg.head_dim
     attn_p = p["attention"]
     x2 = x[:, 0]
@@ -2010,31 +2125,19 @@ def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
                                  if "ln1_bias" in p else {})},
                          cfg, cos, sin, lora=qkv_lora)
 
-    ck, cv = kv_cache
     if active is None:
         active = jnp.ones((b,), bool)
-    if kv_scales is not None:
-        cks, cvs = kv_scales
-        k_q, k_s = quantize_kv_rows(k, dtype=ck.dtype)
-        v_q, v_s = quantize_kv_rows(v, dtype=cv.dtype)
-        ck = append_token_pages(ck, k_q, page_table, cache_positions,
-                                active)
-        cv = append_token_pages(cv, v_q, page_table, cache_positions,
-                                active)
-        cks = append_token_pages(cks, k_s, page_table, cache_positions,
-                                 active)
-        cvs = append_token_pages(cvs, v_s, page_table, cache_positions,
-                                 active)
-        new_cache = (ck, cv, cks, cvs)
-        sc_kw = {"k_scales": cks, "v_scales": cvs}
+    (ck, cv), new_scales = append_kv(
+        kv_cache, kv_scales, (k, v), page_table, cache_positions, active,
+        layer_id)
+    if new_scales is None:
+        new_cache, sc_kw = (ck, cv), {}
     else:
-        ck = append_token_pages(ck, k, page_table, cache_positions, active)
-        cv = append_token_pages(cv, v, page_table, cache_positions, active)
-        new_cache = (ck, cv)
-        sc_kw = {}
+        new_cache = (ck, cv) + new_scales
+        sc_kw = {"k_scales": new_scales[0], "v_scales": new_scales[1]}
 
     attn = paged_attention(q, ck, cv, page_table, cache_positions + 1,
-                           **sc_kw)                       # [B, nq, D]
+                           layer=layer_id, **sc_kw)       # [B, nq, D]
     x2 = _fused_out_proj(attn.reshape(b, nq * d), attn_p, cfg, x2,
                          lora=out_lora)
     x2 = _fused_mlp(x2, p, cfg, lora=mlp_lora)
@@ -2043,11 +2146,11 @@ def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
 
 def fused_layer_multiquery(p, x, cfg, rope_cos, rope_sin, kv_cache,
                            cache_positions, counts, page_table, active,
-                           kv_scales=None, lora=None):
+                           layer_id, kv_scales=None, lora=None):
     """One ragged multi-query layer (speculative verify rounds and
     chunked prefill) as the SAME fused kernels around the generated
     ragged paged-attention kernel: [fused norm+QKV+rope on the B·S
-    flattened rows] → [chunk append scatter] → [ragged paged attention,
+    flattened rows] → [chunk append, in place] → [ragged paged attention,
     q_lens scalar-prefetch path] → [fused out-proj + residual] →
     [fused norm+MLP + residual].
 
@@ -2056,9 +2159,7 @@ def fused_layer_multiquery(p, x, cfg, rope_cos, rope_sin, kv_cache,
     (q_len ∈ [1, S] per row). Row-flattening is bitwise-safe — every
     fused op is row-wise (norms, rope, activations) or contracts the
     last dim only — so verify/prefill streams keep the PR 4 pins."""
-    from megatronapp_tpu.ops.pallas.paged_attention import (
-        append_chunk_pages, quantize_kv_rows,
-    )
+    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
     b, s, h = x.shape
     if cfg.multi_latent_attention:
         assert lora is None, (
@@ -2066,7 +2167,7 @@ def fused_layer_multiquery(p, x, cfg, rope_cos, rope_sin, kv_cache,
             "megakernel_ineligible_reason(lora_rank=) gates MLA off")
         return _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
                                 cache_positions, counts, page_table,
-                                active, kv_scales=kv_scales)
+                                active, layer_id, kv_scales=kv_scales)
     nq, nkv, d = (cfg.num_attention_heads, cfg.num_query_groups,
                   cfg.head_dim)
     attn_p = p["attention"]
@@ -2085,34 +2186,20 @@ def fused_layer_multiquery(p, x, cfg, rope_cos, rope_sin, kv_cache,
     k = k.reshape(b, s, nkv, d)
     v = v.reshape(b, s, nkv, d)
 
-    ck, cv = kv_cache
     if active is None:
         active = jnp.ones((b,), bool)
-    if kv_scales is not None:
-        cks, cvs = kv_scales
-        k_q, k_s = quantize_kv_rows(k, dtype=ck.dtype)
-        v_q, v_s = quantize_kv_rows(v, dtype=cv.dtype)
-        ck = append_chunk_pages(ck, k_q, page_table, cache_positions,
-                                counts, active)
-        cv = append_chunk_pages(cv, v_q, page_table, cache_positions,
-                                counts, active)
-        cks = append_chunk_pages(cks, k_s, page_table, cache_positions,
-                                 counts, active)
-        cvs = append_chunk_pages(cvs, v_s, page_table, cache_positions,
-                                 counts, active)
-        new_cache = (ck, cv, cks, cvs)
-        sc_kw = {"k_scales": cks, "v_scales": cvs}
+    (ck, cv), new_scales = append_kv(
+        kv_cache, kv_scales, (k, v), page_table, cache_positions, active,
+        layer_id, counts)
+    if new_scales is None:
+        new_cache, sc_kw = (ck, cv), {}
     else:
-        ck = append_chunk_pages(ck, k, page_table, cache_positions,
-                                counts, active)
-        cv = append_chunk_pages(cv, v, page_table, cache_positions,
-                                counts, active)
-        new_cache = (ck, cv)
-        sc_kw = {}
+        new_cache = (ck, cv) + new_scales
+        sc_kw = {"k_scales": new_scales[0], "v_scales": new_scales[1]}
 
     attn = paged_attention(q, ck, cv, page_table,
                            cache_positions + counts, q_lens=counts,
-                           **sc_kw)                    # [B, S, nq, D]
+                           layer=layer_id, **sc_kw)    # [B, S, nq, D]
     x2 = _fused_out_proj(attn.reshape(b * s, nq * d), attn_p, cfg, xf,
                          lora=out_lora)
     x2 = _fused_mlp(x2, p, cfg, lora=mlp_lora)

@@ -12,15 +12,17 @@ The kernel BODIES live in ops/pallas/kernel_gen.py (ISSUE 11): one
 dtype/shard/raggedness-parameterized generator emits the decode and
 multi-query variants from a spec — the four hand-written bodies this
 module used to carry (decode / multiquery × plain / tp, each × bf16 /
-int8) are deleted; the public names below are thin dispatchers kept for
-call-site compatibility (attention.py, dynamic_engine.py, disagg.py,
-speculative.py, tests). The emitted bodies are bitwise-identical to the
-legacy variants (pinned in tests/test_kernel_gen.py).
+int8) are deleted; the public names below are thin dispatchers over one
+layer's pool, kept for the tests and tools that call a kernel alone (the
+layer bodies call kernel_gen.paged_attention on the stacked pool). The
+emitted bodies are bitwise-identical to the legacy variants (pinned in
+tests/test_kernel_gen.py).
 
 This module keeps what is NOT kernel-body generation: the jnp parity
 oracles, the quantization helper (`quantize_kv_rows` — symmetric
 per-(row, kv-head) int8, fused into the engine's write-path jits), the
-page write/gather scatter helpers, and the tp eligibility predicate
+page write/gather helpers (the steps' appends go through
+kernel_gen.paged_append, in place), and the tp eligibility predicate
 (`tp_paged_eligible` / `tp_paged_ineligible_reason`).
 
 TP sharding (ISSUE 9): GSPMD cannot partition a pallas_call, so the
@@ -327,47 +329,87 @@ def write_prompt_pages(pages: jnp.ndarray, rows: jnp.ndarray,
     return pages.at[:, blocks, pos % bs].set(rows, mode="drop")
 
 
+def _page_slots(page_table, pos, valid, nb: int, bs: int):
+    """(block ids, in-block offsets) of absolute positions `pos` through
+    their rows' page tables; an invalid position gets block id `nb`, which
+    `kernel_gen.paged_append` drops (never clamps onto a live block)."""
+    mb = page_table.shape[1]
+    blocks = jnp.take_along_axis(
+        page_table, jnp.clip(pos // bs, 0, mb - 1), axis=1)
+    return jnp.where(valid, blocks, nb), pos % bs
+
+
 def append_token_pages(pages: jnp.ndarray, vals: jnp.ndarray,
                        page_table: jnp.ndarray, positions: jnp.ndarray,
-                       active: jnp.ndarray) -> jnp.ndarray:
+                       active: jnp.ndarray, layer=None,
+                       mesh=None) -> jnp.ndarray:
     """Write one decode token per slot at its own (block, offset).
 
-    pages [num_blocks, block_size, ...]; vals [B, ...]; positions [B]
-    (append position per slot); active [B] bool — inactive slots' page
-    tables may reference freed blocks, so their writes are dropped, not
-    clamped (the dense engine could write inactive rows harmlessly; a
-    shared pool cannot)."""
-    nb, bs = pages.shape[0], pages.shape[1]
-    b = vals.shape[0]
-    blocks = jnp.take_along_axis(page_table, (positions // bs)[:, None],
-                                 axis=1)[:, 0]
-    blocks = jnp.where(active, blocks, nb)
-    return pages.at[blocks, positions % bs].set(vals, mode="drop")
+    pages [num_blocks, block_size, ...], or with `layer` (int32 scalar)
+    the STACKED pool [L, num_blocks, block_size, ...] of which that layer
+    is written in place (`kernel_gen.paged_append`: the engine's steps);
+    vals [B, ...]; positions [B] (append position per slot); active [B]
+    bool — inactive slots' page tables may reference freed blocks, so
+    their writes are dropped, not clamped (the dense engine could write
+    inactive rows harmlessly; a shared pool cannot). mesh: the pool is
+    tp-sharded on its first row dim (see paged_append)."""
+    return append_chunk_pages(pages, vals[:, None], page_table, positions,
+                              jnp.ones_like(positions), active, layer, mesh)
 
 
 def append_chunk_pages(pages: jnp.ndarray, vals: jnp.ndarray,
                        page_table: jnp.ndarray, starts: jnp.ndarray,
-                       counts: jnp.ndarray, active: jnp.ndarray
-                       ) -> jnp.ndarray:
+                       counts: jnp.ndarray, active: jnp.ndarray,
+                       layer=None, mesh=None) -> jnp.ndarray:
     """Write a ragged multi-token run per slot (speculative verify /
     chunked prefill): row b's token i lands at absolute position
     starts[b] + i for i < counts[b]; padding rows and inactive slots are
     dropped, never clamped onto live blocks.
 
-    pages [num_blocks, block_size, ...]; vals [B, S, ...]; starts/counts
-    [B] int32; active [B] bool. counts[b] == 1 reduces to
-    append_token_pages."""
-    nb, bs = pages.shape[0], pages.shape[1]
+    pages [num_blocks, block_size, ...] (with `layer`: the stacked pool
+    [L, num_blocks, block_size, ...], that layer written in place); vals
+    [B, S, ...]; starts/counts [B] int32; active [B] bool. counts[b] == 1
+    is append_token_pages."""
+    from megatronapp_tpu.ops.pallas.kernel_gen import paged_append
+    pool = pages[None] if layer is None else pages
+    nb, bs = pool.shape[1], pool.shape[2]
     b, s = vals.shape[0], vals.shape[1]
-    mb = page_table.shape[1]
     pos = starts[:, None] + jnp.arange(s)[None, :]           # [B, S]
-    blocks = jnp.take_along_axis(
-        page_table, jnp.clip(pos // bs, 0, mb - 1), axis=1)  # [B, S]
     valid = (jnp.arange(s)[None, :] < counts[:, None]) & active[:, None]
-    blocks = jnp.where(valid, blocks, nb)
-    flat = lambda x: x.reshape((b * s,) + x.shape[2:])  # noqa: E731
-    return pages.at[flat(blocks), flat(pos % bs)].set(flat(vals),
-                                                      mode="drop")
+    blocks, offs = _page_slots(page_table, pos, valid, nb, bs)
+    pool = paged_append(pool, vals.reshape((b * s,) + vals.shape[2:]),
+                        0 if layer is None else layer,
+                        blocks.reshape(b * s), offs.reshape(b * s),
+                        mesh=mesh)
+    return pool[0] if layer is None else pool
+
+
+def append_kv(kv_cache, kv_scales, rows, page_table, positions, active,
+              layer, counts=None, mesh=None):
+    """One layer's new rows into its pools: what every paged layer body
+    does between its projections and its attention kernel.
+
+    kv_cache: the pool pair, STACKED [L, NB, bs, ...] (K and V; MLA: the
+    latent and k_pe pools); rows: the matching pair, [B, ...] for one
+    token a slot or, with counts [B], a ragged [B, S, ...] chunk starting
+    at positions. kv_scales: the scale-pool pair of a quantised pool —
+    the rows then quantize per (row, head) right here, in the step's jit,
+    and their scales go through the same page table. Returns (pools,
+    scale pools or None), each the buffer it was given (paged_append)."""
+    def put(pool, r):
+        if counts is None:
+            return append_token_pages(pool, r, page_table, positions,
+                                      active, layer, mesh)
+        return append_chunk_pages(pool, r, page_table, positions, counts,
+                                  active, layer, mesh)
+
+    if kv_scales is None:
+        return tuple(put(p, r.astype(p.dtype))
+                     for p, r in zip(kv_cache, rows)), None
+    quant = [quantize_kv_rows(r, dtype=p.dtype)
+             for p, r in zip(kv_cache, rows)]
+    return (tuple(put(p, q) for p, (q, _) in zip(kv_cache, quant)),
+            tuple(put(sp, sc) for sp, (_, sc) in zip(kv_scales, quant)))
 
 
 def gather_prefix_pages(pages: jnp.ndarray, table_row: jnp.ndarray,

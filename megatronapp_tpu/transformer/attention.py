@@ -110,10 +110,12 @@ def attention_forward(
     """x: [B, S, H] → [B, S, H]. Returns (out, new_kv_cache).
 
     page_table: [B, max_blocks_per_seq] int32 — marks kv_cache as PAGED
-    block-pool storage [num_blocks, block_size, Hkv, D]
-    (inference/paged_cache.py): each row appends its token at its own
-    (block, offset) and attends through the ragged paged-attention
-    kernel, which masks by per-row kv length (no caller mask needed).
+    block-pool storage, the whole STACKED pool
+    [L, num_blocks, block_size, Hkv, D] (inference/paged_cache.py) with
+    layer_id (required then) naming this layer's plane: each row appends
+    its token at its own (block, offset) of that plane, in place, and
+    attends through the ragged paged-attention kernel, which masks by
+    per-row kv length (no caller mask needed).
     active: [B] bool — inactive rows' writes are dropped (their page
     tables may reference blocks re-allocated to other requests).
     chunk_counts: [B] int32 — multi-token paged append (speculative
@@ -122,8 +124,8 @@ def attention_forward(
     through the multi-query ragged kernel (causal within the new tail,
     full attention to the paged context). Rows past a row's count are
     padding whose outputs are garbage (callers discard them).
-    kv_scales: (k_scales, v_scales) fp32 [NB, bs, Hkv] — marks the paged
-    pools as int8 (PagedKVCache kv_cache_dtype="int8"): new rows
+    kv_scales: (k_scales, v_scales) fp32 [L, NB, bs, Hkv] — marks the
+    paged pools as int8 (PagedKVCache kv_cache_dtype="int8"): new rows
     quantize per (row, head) in this jit before the scatter, the ragged
     kernels dequantize each DMA'd block in-register, and new_cache grows
     to (k, v, k_scales, v_scales). Paged paths only.
@@ -320,116 +322,67 @@ def attention_forward(
     if kv_cache is not None:
         ck, cv = kv_cache
         if page_table is not None:
+            # Paged continuous-batching decode, and (s > 1 or chunk_counts)
+            # the multi-token append of speculative verify / chunked
+            # prefill. kv_cache holds the STACKED block pools
+            # [L, NB, bs, Hkv, D] and layer_id names this layer's plane:
+            # the new rows are written into it in place and the ragged
+            # kernel reads it through the layer id, so the pool passes
+            # through a step as one buffer (kernel_gen.paged_append).
+            #
             # TP serving mesh (ISSUE 9): head-shard the paged kernels
             # over ctx's tp axis — the pool is sharded on Hkv (1/tp of
-            # the KV bytes and attention FLOPs per device) and the
-            # kernel is placed with a full-manual shard_map, exactly
-            # like the flash wrapper above. The output is constrained
-            # back to REPLICATED before the out-projection so every
-            # device runs the identical dense matmul — per-request
-            # greedy streams stay bit-identical to the single-device
-            # engine (the tp2 parity pin in tests/test_disagg.py).
+            # the KV bytes and attention FLOPs per device) and writer
+            # and reader are placed with a full-manual shard_map,
+            # exactly like the flash wrapper above. The output is
+            # constrained back to REPLICATED before the out-projection
+            # so every device runs the identical dense matmul —
+            # per-request greedy streams stay bit-identical to the
+            # single-device engine (the tp2 parity pin in
+            # tests/test_disagg.py).
+            from megatronapp_tpu.ops.pallas import kernel_gen
             from megatronapp_tpu.ops.pallas.paged_attention import (
-                tp_paged_eligible,
+                append_kv, tp_paged_eligible,
             )
             from megatronapp_tpu.parallel.collectives import (
                 current_manual_axes,
             )
+            if layer_id is None:
+                raise ValueError(
+                    "paged attention reads the stacked pool through "
+                    "layer_id — pass this layer's index")
             tp_paged = (tp_paged_eligible(cfg, ctx)
                         and not current_manual_axes())
-        if page_table is not None and (s > 1 or chunk_counts is not None):
-            # Multi-token paged append (speculative verify / chunked
-            # prefill): write the ragged chunk then attend through the
-            # multi-query kernel.
-            from megatronapp_tpu.ops.pallas import kernel_gen
-            from megatronapp_tpu.ops.pallas.paged_attention import (
-                append_chunk_pages, paged_attention_multiquery,
-                paged_attention_multiquery_tp, quantize_kv_rows,
-            )
+            # manual-ok: tp_paged requires no ambient manual axes
+            mesh = ctx.shard_map_mesh if tp_paged else None
             if active is None:
                 active = jnp.ones((b,), bool)
-            counts = (chunk_counts if chunk_counts is not None
-                      else jnp.full((b,), s, jnp.int32))
-            if kv_scales is not None:
-                # int8 pool: quantize the new rows per (row, head) right
-                # here — ONE fused jit covers quantize + scatter +
-                # attend — and scatter the scales through the same page
-                # table.
-                cks, cvs = kv_scales
-                k_q, k_s = quantize_kv_rows(k, dtype=ck.dtype)
-                v_q, v_s = quantize_kv_rows(v, dtype=cv.dtype)
-                ck = append_chunk_pages(ck, k_q, page_table,
-                                        cache_positions, counts, active)
-                cv = append_chunk_pages(cv, v_q, page_table,
-                                        cache_positions, counts, active)
-                cks = append_chunk_pages(cks, k_s, page_table,
-                                         cache_positions, counts, active)
-                cvs = append_chunk_pages(cvs, v_s, page_table,
-                                         cache_positions, counts, active)
-                new_scales = (cks, cvs)
-                sc_kw = {"k_scales": cks, "v_scales": cvs}
-            else:
-                ck = append_chunk_pages(ck, k, page_table,
-                                        cache_positions, counts, active)
-                cv = append_chunk_pages(cv, v, page_table,
-                                        cache_positions, counts, active)
-                sc_kw = {}
-            new_cache = (ck, cv)
-            if tp_paged:
-                # manual-ok: tp_paged requires no ambient manual axes
-                paged_out = paged_attention_multiquery_tp(
+            ragged = s > 1 or chunk_counts is not None
+            counts = None
+            if ragged:
+                counts = (chunk_counts if chunk_counts is not None
+                          else jnp.full((b,), s, jnp.int32))
+            (ck, cv), new_scales = append_kv(
+                kv_cache, kv_scales, (k, v) if ragged else (k[:, 0], v[:, 0]),
+                page_table, cache_positions, active, layer_id, counts,
+                mesh)
+            sc_kw = ({} if new_scales is None else
+                     {"k_scales": new_scales[0], "v_scales": new_scales[1]})
+            if ragged:
+                paged_out = kernel_gen.paged_attention(
                     q, ck, cv, page_table, cache_positions + counts,
-                    counts, ctx.shard_map_mesh, **sc_kw)
-                paged_out = _replicate_heads(paged_out, ctx)
+                    q_lens=counts, mesh=mesh, layer=layer_id, **sc_kw)
+                _announce("paged multi-query",
+                          "pallas ragged paged kernel",
+                          kernel_gen._interpret())
             else:
-                paged_out = paged_attention_multiquery(
-                    q, ck, cv, page_table, cache_positions + counts,
-                    counts, **sc_kw)
-            _announce("paged multi-query", "pallas ragged paged kernel",
-                      kernel_gen._interpret())
-        elif page_table is not None:
-            # Paged continuous-batching decode: kv_cache is the shared
-            # block pool; cache_positions[b] is row b's append position.
-            from megatronapp_tpu.ops.pallas import kernel_gen
-            from megatronapp_tpu.ops.pallas.paged_attention import (
-                append_token_pages, paged_attention_decode,
-                paged_attention_decode_tp, quantize_kv_rows,
-            )
-            if active is None:
-                active = jnp.ones((b,), bool)
-            if kv_scales is not None:
-                cks, cvs = kv_scales
-                k_q, k_s = quantize_kv_rows(k[:, 0], dtype=ck.dtype)
-                v_q, v_s = quantize_kv_rows(v[:, 0], dtype=cv.dtype)
-                ck = append_token_pages(ck, k_q, page_table,
-                                        cache_positions, active)
-                cv = append_token_pages(cv, v_q, page_table,
-                                        cache_positions, active)
-                cks = append_token_pages(cks, k_s, page_table,
-                                         cache_positions, active)
-                cvs = append_token_pages(cvs, v_s, page_table,
-                                         cache_positions, active)
-                new_scales = (cks, cvs)
-                sc_kw = {"k_scales": cks, "v_scales": cvs}
-            else:
-                ck = append_token_pages(ck, k[:, 0], page_table,
-                                        cache_positions, active)
-                cv = append_token_pages(cv, v[:, 0], page_table,
-                                        cache_positions, active)
-                sc_kw = {}
-            new_cache = (ck, cv)
-            if tp_paged:
-                # manual-ok: tp_paged requires no ambient manual axes
-                paged_out = paged_attention_decode_tp(
+                paged_out = kernel_gen.paged_attention(
                     q[:, 0], ck, cv, page_table, cache_positions + 1,
-                    ctx.shard_map_mesh, **sc_kw)[:, None]
+                    mesh=mesh, layer=layer_id, **sc_kw)[:, None]
+                _announce("paged decode", "pallas paged kernel",
+                          kernel_gen._interpret())
+            if tp_paged:
                 paged_out = _replicate_heads(paged_out, ctx)
-            else:
-                paged_out = paged_attention_decode(
-                    q[:, 0], ck, cv, page_table,
-                    cache_positions + 1, **sc_kw)[:, None]  # [B,1,Hq,D]
-            _announce("paged decode", "pallas paged kernel",
-                      kernel_gen._interpret())
         elif cache_positions is not None:
             # Continuous-batching decode (dynamic_context.py analogue):
             # each row appends at ITS OWN position; causality MUST come
@@ -452,8 +405,8 @@ def attention_forward(
                                                      axis=1)
             q_offset = cache_index
         k, v = ck, cv
-        # Quantized paged paths return the scale pools alongside so the
-        # engine's lax.scan carries all four updated pools per layer.
+        # Quantized paged paths return the scale pools alongside: the
+        # engine's layer loop carries all four pools.
         new_cache = ((ck, cv) if new_scales is None
                      else (ck, cv) + new_scales)
 

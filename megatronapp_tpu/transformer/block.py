@@ -76,9 +76,11 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
 
     page_table/active: paged-KV decode (inference/paged_cache.py) —
-    kv_cache is then the per-layer block pool and each batch row appends
-    at its own page-table position (see attention.py / mla.py).
-    kv_scales: per-layer fp32 scale pools marking a quantized paged pool
+    kv_cache is then the whole STACKED block pool [L, NB, bs, ...], of
+    which layer_id names this layer's plane: each batch row appends at
+    its own page-table position of it, in place (see attention.py /
+    mla.py).
+    kv_scales: stacked fp32 scale pools marking a quantized paged pool
     (see attention.py; MLA: per-row scalar scales on the latent/pe
     pools, see mla.py); new_cache then carries four pools.
 
@@ -121,7 +123,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             return fused_layer_multiquery(
                 p, x, cfg, rope_cos, rope_sin, kv_cache,
                 cache_positions, chunk_counts, page_table, active,
-                kv_scales=kv_scales, lora=lora)
+                layer_id, kv_scales=kv_scales, lora=lora)
         if x.shape[1] != 1:
             raise ValueError(
                 "fused_decode without chunk_counts is the s == 1 "
@@ -129,7 +131,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 "multi-token steps")
         return fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
                                   cache_positions, page_table, active,
-                                  kv_scales=kv_scales, lora=lora)
+                                  layer_id, kv_scales=kv_scales, lora=lora)
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
                    cfg.layernorm_epsilon)

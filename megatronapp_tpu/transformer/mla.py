@@ -94,7 +94,7 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     no per-step kv_up over the whole history.
 
     kv_scales: optional (lat_scales, pe_scales) per-row scalar fp32
-    scale pools [NB, bs] marking a QUANTIZED latent/pe pool (paged path
+    scale pools [L, NB, bs] marking a QUANTIZED latent/pe pool (paged path
     only); new rows quantize on insert (quantize_kv_rows) and new_cache
     then carries four pools.
 
@@ -149,9 +149,10 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         c_lat, c_pe = kv_cache
         if page_table is not None:
             # Paged continuous-batching decode (ISSUE 17): kv_cache is
-            # the shared latent/k_pe block pool ([num_blocks, block_size,
-            # klat/dpe], inference/paged_cache.py). Each row appends at
-            # its own (block, offset); attention then runs IN LATENT
+            # the shared latent/k_pe block pool, STACKED ([L, num_blocks,
+            # block_size, klat/dpe], inference/paged_cache.py), of which
+            # layer_id names this layer's plane. Each row appends at its
+            # own (block, offset), in place; attention then runs IN LATENT
             # SPACE through the generated ragged paged kernel — q
             # absorbed through kv_up's k_nope columns, values
             # re-expanded per-tile in-register — so the history is never
@@ -166,78 +167,34 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 paged_attention_latent,
             )
             from megatronapp_tpu.ops.pallas.paged_attention import (
-                append_chunk_pages, append_token_pages, quantize_kv_rows,
-                tp_paged_eligible,
+                append_kv, tp_paged_eligible,
             )
             from megatronapp_tpu.scope import hooks as scope_hooks
+            if layer_id is None:
+                raise ValueError(
+                    "paged attention reads the stacked pool through "
+                    "layer_id — pass this layer's index")
             if active is None:
                 active = jnp.ones((b,), bool)
-            new_scales = None
+            # Multi-token paged append (speculative verify / chunked
+            # prefill): ragged per-row chunk starting at cache_positions;
+            # the kernel's scalar-prefetched q_lens carries the causal
+            # tail mask. Quantized latent/pe pools: per-row SCALAR scales
+            # (the rows have no kv-head axis) quantized on insert and
+            # written through the same page table (append_kv).
             ragged = s > 1 or chunk_counts is not None
+            counts = None
             if ragged:
-                # Multi-token paged append (speculative verify / chunked
-                # prefill): ragged per-row chunk starting at
-                # cache_positions; the kernel's scalar-prefetched q_lens
-                # carries the causal tail mask.
                 counts = (chunk_counts if chunk_counts is not None
                           else jnp.full((b,), s, jnp.int32))
-                if kv_scales is not None:
-                    # Quantized latent/pe pools: per-row SCALAR scales
-                    # (the rows have no kv-head axis) quantized on
-                    # insert and scattered through the same page table.
-                    c_ls, c_ps = kv_scales
-                    lat_q, lat_s = quantize_kv_rows(latent,
-                                                    dtype=c_lat.dtype)
-                    pe_q, pe_s = quantize_kv_rows(k_pe, dtype=c_pe.dtype)
-                    c_lat = append_chunk_pages(c_lat, lat_q, page_table,
-                                               cache_positions, counts,
-                                               active)
-                    c_pe = append_chunk_pages(c_pe, pe_q, page_table,
-                                              cache_positions, counts,
-                                              active)
-                    c_ls = append_chunk_pages(c_ls, lat_s, page_table,
-                                              cache_positions, counts,
-                                              active)
-                    c_ps = append_chunk_pages(c_ps, pe_s, page_table,
-                                              cache_positions, counts,
-                                              active)
-                    new_scales = (c_ls, c_ps)
-                    sc_kw = {"lat_scales": c_ls, "pe_scales": c_ps}
-                else:
-                    c_lat = append_chunk_pages(
-                        c_lat, latent.astype(c_lat.dtype), page_table,
-                        cache_positions, counts, active)
-                    c_pe = append_chunk_pages(
-                        c_pe, k_pe.astype(c_pe.dtype), page_table,
-                        cache_positions, counts, active)
-                    sc_kw = {}
-                kv_lens = cache_positions + counts
-            else:
-                if kv_scales is not None:
-                    c_ls, c_ps = kv_scales
-                    lat_q, lat_s = quantize_kv_rows(latent[:, 0],
-                                                    dtype=c_lat.dtype)
-                    pe_q, pe_s = quantize_kv_rows(k_pe[:, 0],
-                                                  dtype=c_pe.dtype)
-                    c_lat = append_token_pages(c_lat, lat_q, page_table,
-                                               cache_positions, active)
-                    c_pe = append_token_pages(c_pe, pe_q, page_table,
-                                              cache_positions, active)
-                    c_ls = append_token_pages(c_ls, lat_s, page_table,
-                                              cache_positions, active)
-                    c_ps = append_token_pages(c_ps, pe_s, page_table,
-                                              cache_positions, active)
-                    new_scales = (c_ls, c_ps)
-                    sc_kw = {"lat_scales": c_ls, "pe_scales": c_ps}
-                else:
-                    c_lat = append_token_pages(
-                        c_lat, latent[:, 0].astype(c_lat.dtype),
-                        page_table, cache_positions, active)
-                    c_pe = append_token_pages(
-                        c_pe, k_pe[:, 0].astype(c_pe.dtype), page_table,
-                        cache_positions, active)
-                    sc_kw = {}
-                kv_lens = cache_positions + 1
+            (c_lat, c_pe), new_scales = append_kv(
+                kv_cache, kv_scales,
+                (latent, k_pe) if ragged else (latent[:, 0], k_pe[:, 0]),
+                page_table, cache_positions, active, layer_id, counts)
+            sc_kw = ({} if new_scales is None else
+                     {"lat_scales": new_scales[0],
+                      "pe_scales": new_scales[1]})
+            kv_lens = cache_positions + (counts if ragged else 1)
             new_cache = ((c_lat, c_pe) if new_scales is None
                          else (c_lat, c_pe) + new_scales)
 
@@ -275,12 +232,12 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 attn = paged_attention_latent(
                     q_abs, q_pe, c_lat, c_pe, page_table, kv_lens, w_v,
                     q_lens=counts, softmax_scale=scale, mesh=mesh,
-                    **sc_kw)
+                    layer=layer_id, **sc_kw)
             else:
                 attn = paged_attention_latent(
                     q_abs[:, 0], q_pe[:, 0], c_lat, c_pe, page_table,
                     kv_lens, w_v, softmax_scale=scale, mesh=mesh,
-                    **sc_kw)[:, None]
+                    layer=layer_id, **sc_kw)[:, None]
             if tp_paged:
                 from jax.sharding import NamedSharding, PartitionSpec
                 # manual-ok: replicate the kernel output so the
@@ -299,11 +256,13 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 from megatronapp_tpu.ops.pallas.paged_attention import (
                     gather_pages_batched,
                 )
-                g_lat = gather_pages_batched(c_lat, page_table)
-                g_pe = gather_pages_batched(c_pe, page_table)
+                g_lat = gather_pages_batched(c_lat[layer_id], page_table)
+                g_pe = gather_pages_batched(c_pe[layer_id], page_table)
                 if new_scales is not None:
-                    g_ls = gather_pages_batched(new_scales[0], page_table)
-                    g_ps = gather_pages_batched(new_scales[1], page_table)
+                    g_ls = gather_pages_batched(new_scales[0][layer_id],
+                                                page_table)
+                    g_ps = gather_pages_batched(new_scales[1][layer_id],
+                                                page_table)
                     g_lat = g_lat.astype(jnp.float32) * g_ls[..., None]
                     g_pe = g_pe.astype(jnp.float32) * g_ps[..., None]
                 g_lat, g_pe = g_lat.astype(dt), g_pe.astype(dt)

@@ -9,6 +9,7 @@ call ``enable_compile_cache()`` before their first jit and print
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 from typing import Optional
@@ -35,6 +36,31 @@ def enable_compile_cache() -> str:
     path = os.path.join(_REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+@contextlib.contextmanager
+def fresh_compiles():
+    """Compile what the body compiles, whatever the persistent cache holds,
+    and write none of it there.
+
+    For the jits that pin a device LAYOUT (the paged KV pool's row-major
+    one, inference/paged_cache.pool_format). On the installed JAX (0.9.0)
+    an executable loaded from the persistent cache hands back arrays that
+    REPORT the default layout while their buffers have the layout it was
+    compiled for, and every later use of such an array trusts the report:
+    the next step is refused or fails on the buffer's size (my chip runs,
+    PR 27; a program compiled in the same process reports right). The
+    switch is the process's, not the thread's: a compile in another thread
+    meanwhile just misses the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 def device_line(mesh: Optional[jax.sharding.Mesh] = None) -> str:

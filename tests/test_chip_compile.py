@@ -223,17 +223,43 @@ def test_train_step_tp2_dp2(topo, chip_compile):
     assert per_device.argument_size_in_bytes < 16 * 2**30
 
 
+_HLO_RESULT = re.compile(r"%([\w.\-]+) = \(?\w+\[([\d,]*)\]")
+
+
+def _pool_shaped(compiled, named, shapes):
+    """Names of the compiled module's instructions that match the pattern
+    `named` and whose result has one of `shapes`."""
+    dims = {",".join(map(str, sh)) for sh in shapes}
+    return [name for name, d in _HLO_RESULT.findall(compiled.as_text())
+            if d in dims and re.search(named, name)]
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
-def test_engine_paged_steps(one_chip, chip_compile, which):
+def test_engine_paged_steps(one_chip, chip_compile, which, kv):
     """DynamicInferenceEngine's own jits at GPT-2 125M widths (depth cut
-    to 2): the paged decode step at batch 4 and the one-chunk prefill."""
+    to 2): the paged decode step at batch 4 and the one-chunk prefill, over
+    a pool of 1024 blocks so that ONE LAYER's K slice (25 MB bf16, 12.6 MB
+    int8) is far above everything else a step holds.
+
+    The step updates the pool in place: what the compiler reports aliased
+    is at least the pools, its temporaries are smaller than one layer's K
+    slice (the parent's held a second pool), and no `copy` or
+    `dynamic-update-slice` in its text gives a K/V pool or a layer's slice
+    of one. An int8 pool's fp32 scale rows are part of a tile, so they are
+    written by dynamic_update_slice on the loop's carry: in place too (the
+    temporaries say so), and never relayouted (`copy.N`; at this size the
+    compiler parks the 1.5 MB scale pools in fast memory for the loop, a
+    `copy-start`/`copy-done` pair that a deployment's pool is too big
+    for)."""
     from megatronapp_tpu.inference.dynamic_engine import (
         DynamicInferenceEngine,
     )
     from megatronapp_tpu.models.gpt import init_gpt_params
     cfg = PRESETS["gpt2-125m"](num_layers=2)
     params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
-    eng = DynamicInferenceEngine(params, cfg, max_batch=4, paged=True)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=4, paged=True,
+                                 num_blocks=1024, kv_cache_dtype=kv)
 
     def spec(a):
         return _sds(a.shape, a.dtype, one_chip)
@@ -256,9 +282,23 @@ def test_engine_paged_steps(one_chip, chip_compile, which):
             p, i32(1, eng.prefill_chunk), pages, scales, i32(1, mb),
             i32(1), i32(1), _sds((1,), jnp.bool_, one_chip),
             None).compile()
-    assert _custom_calls(compiled) >= 1
     _assert_kernels_named(
-        compiled, "paged_decode" if which == "decode" else "paged_mq")
+        compiled, "paged_decode" if which == "decode" else "paged_mq",
+        "paged_append")
+
+    k = eng.pool.pages[0]
+    layer_bytes = k[0].size * k.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= eng.pool.bytes_total
+    assert mem.temp_size_in_bytes < layer_bytes, (
+        mem.temp_size_in_bytes, layer_bytes)
+    kv_shapes = [k.shape, (1,) + k.shape[1:], k.shape[1:]]
+    all_shapes = kv_shapes + [sh for sc in eng.pool.scales or ()
+                              for sh in (sc.shape, (1,) + sc.shape[1:],
+                                         sc.shape[1:])]
+    assert not _pool_shaped(compiled, r"^copy(\.\d+)?$", all_shapes)
+    assert not _pool_shaped(
+        compiled, r"copy|dynamic[-_](update[-_])?slice", kv_shapes)
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +349,11 @@ def _compile_family(family, one_chip):
 
         fn = loss if "fwd" in family else jax.grad(loss, argnums=(0, 1, 2))
         return jax.jit(fn).lower(x, x, x).compile()
+    if family == "paged_append":
+        # a stacked pool of 2 layers, 24 rows into layer `lid`
+        return jax.jit(kg.paged_append).lower(
+            bf16(2, 256, 16, 12, 64), bf16(24, 12, 64), i32(), i32(24),
+            i32(24)).compile()
     if family.startswith("paged"):
         b, h, d, nb, bs = 4, 12, 64, 256, 16
         pages, table, lens = bf16(nb, bs, h, d), i32(b, 16), i32(b)
@@ -341,6 +386,7 @@ def _compile_family(family, one_chip):
     ("flash_bwd_d128", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     ("paged_decode", ["paged_decode"]),
     ("paged_mq", ["paged_mq"]),
+    ("paged_append", ["paged_append"]),
     ("fused", ["fused_"]),
 ])
 def test_kernel_family_names(one_chip, chip_compile, family, prefixes):

@@ -261,7 +261,8 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert server["driver_max_active"] >= 2
     assert server["max_blocks_in_use_seen"] > 0
     assert server["blocks_in_use_after"] == 0
-    assert server["decode_pallas_calls_per_step"] == 12
+    # 12 layers, each one paged_decode and a paged_append for K and for V
+    assert server["decode_pallas_calls_per_step"] == 36
     # ... and the run is refused all the same: this is not a TPU.
     assert rc != 0
     assert last == {"ok": False, "device": {"platform": "cpu",

@@ -105,6 +105,102 @@ class TestPagedAttentionKernel:
             np.testing.assert_allclose(np.asarray(out[i]), o, atol=1e-5)
 
 
+# (name, one row's dims, dtype): K/V rows are whole tiled planes and go
+# through the Pallas writer; scale rows, MLA latent rows and latent scale
+# rows are part of a tile and go through per-row dynamic_update_slice.
+_POOL_KINDS = [("bf16-kv", (2, 8), jnp.bfloat16),
+               ("int8-kv", (2, 8), jnp.int8),
+               ("kv-scales", (2,), jnp.float32),
+               ("latent", (16,), jnp.bfloat16),
+               ("latent-scales", (), jnp.float32)]
+
+
+@pytest.mark.parametrize("kind,row,dtype", _POOL_KINDS,
+                         ids=[k[0] for k in _POOL_KINDS])
+class TestPoolWriter:
+    """kernel_gen.paged_append (through append_token_pages /
+    append_chunk_pages with `layer`) against what the writers did before
+    it: `pages.at[blocks, offs].set(vals, mode="drop")` on one layer's
+    slice, an out-of-range block id for every row that must not land."""
+    L, NB, BS, LAYER = 3, 6, 4, 1
+
+    def _setup(self, row, dtype):
+        rng = np.random.default_rng(7)
+
+        def draw(shape):
+            x = rng.integers(-100, 100, size=shape)
+            return jnp.asarray(x, jnp.float32).astype(dtype)
+
+        pool = draw((self.L, self.NB, self.BS) + row)
+        # Slot 2 is inactive and its table still names block 4, which slot
+        # 0 owns now (freed and handed on): nothing of slot 2 may land.
+        table = jnp.asarray([[4, 1], [3, 0], [4, 5]], jnp.int32)
+        return pool, table, draw
+
+    def _old(self, pool, vals, table, starts, counts, active):
+        """The deleted scatter, on the one layer."""
+        b, s = vals.shape[:2]
+        pos = starts[:, None] + jnp.arange(s)[None, :]
+        blocks = jnp.take_along_axis(
+            table, jnp.clip(pos // self.BS, 0, table.shape[1] - 1), axis=1)
+        valid = (jnp.arange(s)[None, :] < counts[:, None]) & active[:, None]
+        blocks = jnp.where(valid, blocks, self.NB)
+        flat = lambda x: x.reshape((b * s,) + x.shape[2:])  # noqa: E731
+        layer = pool[self.LAYER].at[flat(blocks), flat(pos % self.BS)].set(
+            flat(vals), mode="drop")
+        return pool.at[self.LAYER].set(layer)
+
+    def test_ragged_chunk_drops_what_the_scatter_dropped(self, kind, row,
+                                                         dtype):
+        from megatronapp_tpu.ops.pallas.paged_attention import (
+            append_chunk_pages,
+        )
+        pool, table, draw = self._setup(row, dtype)
+        vals = draw((3, 3) + row)
+        starts = jnp.asarray([2, 5, 1], jnp.int32)     # slot 0 crosses a block
+        counts = jnp.asarray([3, 1, 3], jnp.int32)     # slot 1: two padding rows
+        active = jnp.asarray([True, True, False])
+        new = jax.jit(append_chunk_pages)(pool, vals, table, starts, counts,
+                                          active, jnp.int32(self.LAYER))
+        want = self._old(pool, vals, table, starts, counts, active)
+        np.testing.assert_array_equal(np.asarray(new.astype(jnp.float32)),
+                                      np.asarray(want.astype(jnp.float32)))
+        got = np.asarray(new.astype(jnp.float32))
+        was = np.asarray(pool.astype(jnp.float32))
+        # the other layers, and every row of the layer that no valid
+        # position names, are as they were: 4 rows landed, no more
+        np.testing.assert_array_equal(got[[0, 2]], was[[0, 2]])
+        changed = (got[1] != was[1]).reshape(self.NB, self.BS, -1).any(-1)
+        assert changed.sum() <= 4 and not changed[[2, 3, 5]].any()
+        # block 4 holds slot 0's rows (positions 2, 3), none of slot 2's
+        np.testing.assert_array_equal(
+            got[1, 4, 2:4], np.asarray(vals[0, :2].astype(jnp.float32)))
+        np.testing.assert_array_equal(got[1, 4, :2], was[1, 4, :2])
+
+    def test_one_token_form_and_no_valid_row(self, kind, row, dtype):
+        from megatronapp_tpu.ops.pallas.paged_attention import (
+            append_chunk_pages, append_token_pages,
+        )
+        pool, table, draw = self._setup(row, dtype)
+        vals = draw((3, 1) + row)
+        starts = jnp.asarray([0, 3, 5], jnp.int32)
+        active = jnp.asarray([True, True, False])
+        lid = jnp.int32(self.LAYER)
+        one = jnp.ones(3, jnp.int32)
+        a = append_chunk_pages(pool, vals, table, starts, one, active, lid)
+        b = append_token_pages(pool, vals[:, 0], table, starts, active, lid)
+        want = self._old(pool, vals, table, starts, one, active)
+        for got in (a, b):
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)))
+        # a round in which no slot is active writes nothing at all
+        idle = append_token_pages(pool, vals[:, 0], table, starts,
+                                  jnp.zeros(3, bool), lid)
+        np.testing.assert_array_equal(np.asarray(idle.astype(jnp.float32)),
+                                      np.asarray(pool.astype(jnp.float32)))
+
+
 class TestBlockPool:
     def _pool(self, num_blocks=8, block_size=4, max_batch=2):
         return PagedKVCache(_gqa_cfg(), max_batch, 32,
