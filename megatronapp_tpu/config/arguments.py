@@ -513,6 +513,15 @@ def build_parser(title: str = "megatronapp-tpu") -> argparse.ArgumentParser:
                    choices=[k.value for k in PositionEmbeddingKind])
     g.add_argument("--rotary-base", type=float, default=10000.0)
     g.add_argument("--rotary-percent", type=float, default=1.0)
+    g.add_argument("--rope-scaling-factor", type=float, default=1.0,
+                   help="YaRN context multiplier (HF rope_scaling.factor; "
+                        "with --position-embedding-type yarn)")
+    g.add_argument("--yarn-original-max-position", type=int, default=4096,
+                   help="HF rope_scaling.original_max_position_embeddings")
+    g.add_argument("--yarn-mscale-coeff", type=float, default=0.1,
+                   help="logits carry (coeff * ln(factor) + 1)**2; HF "
+                        "0.1 * rope_scaling.mscale_all_dim (DeepSeek-V2: "
+                        "0.0707), with mscale == mscale_all_dim")
     g.add_argument("--normalization", default="LayerNorm",
                    choices=[k.value for k in NormKind])
     g.add_argument("--swiglu", action="store_true")
@@ -547,6 +556,15 @@ def build_parser(title: str = "megatronapp-tpu") -> argparse.ArgumentParser:
     g.add_argument("--moe-z-loss-coeff", type=float, default=0.0)
     g.add_argument("--moe-expert-capacity-factor", type=float, default=None)
     g.add_argument("--moe-layer-freq", type=int, default=1)
+    g.add_argument("--moe-first-k-dense", type=int, default=0,
+                   help="the first k layers are dense MLPs of "
+                        "--ffn-hidden-size (HF first_k_dense_replace)")
+    g.add_argument("--moe-router-no-norm-topk-prob", action="store_false",
+                   dest="moe_router_norm_topk_prob",
+                   help="keep the top-k softmax probabilities as they are "
+                        "(HF norm_topk_prob false: DeepSeek-V2-Lite)")
+    g.add_argument("--moe-routed-scaling-factor", type=float, default=1.0,
+                   help="HF routed_scaling_factor")
     g.add_argument("--moe-shared-expert-intermediate-size", type=int,
                    default=None)
 
@@ -1074,6 +1092,9 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
                 args.position_embedding_type),
             rotary_base=args.rotary_base,
             rotary_percent=args.rotary_percent,
+            rope_scaling_factor=args.rope_scaling_factor,
+            yarn_original_max_position=args.yarn_original_max_position,
+            yarn_mscale_coeff=args.yarn_mscale_coeff,
             normalization=NormKind(args.normalization),
             activation=activation,
             add_bias_linear=not args.disable_bias_linear,
@@ -1089,6 +1110,9 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             moe_z_loss_coeff=args.moe_z_loss_coeff,
             moe_capacity_factor=args.moe_expert_capacity_factor,
             moe_layer_freq=args.moe_layer_freq,
+            moe_first_k_dense=args.moe_first_k_dense,
+            moe_router_norm_topk_prob=args.moe_router_norm_topk_prob,
+            moe_routed_scaling_factor=args.moe_routed_scaling_factor,
             moe_shared_expert_intermediate_size=(
                 args.moe_shared_expert_intermediate_size),
             mtp_num_layers=args.mtp_num_layers,

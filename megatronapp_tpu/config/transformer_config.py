@@ -108,6 +108,15 @@ class TransformerConfig:
     moe_capacity_factor: Optional[float] = None
     # Layer frequency: 1 = every layer is MoE; k = every k-th layer.
     moe_layer_freq: int = 1
+    # Whether the router divides the k chosen probabilities by their sum
+    # (HF `norm_topk_prob`; Mixtral does, DeepSeek-V2-Lite does not), and
+    # the factor on the routed experts' output (HF `routed_scaling_factor`).
+    moe_router_norm_topk_prob: bool = True
+    moe_routed_scaling_factor: float = 1.0
+    # The first k layers are dense MLPs of width ffn_hidden_size, the
+    # rest MoE (HF `first_k_dense_replace`; DeepSeek-V2/V3: 1 or 3). They
+    # run as a prologue before the scanned stack (params["lead_block"]).
+    moe_first_k_dense: int = 0
 
     # Multi-token prediction (DeepSeek-V3; reference
     # multi_token_prediction.py + transformer_config mtp_num_layers /
@@ -263,6 +272,13 @@ class TransformerConfig:
             )
         if self.num_moe_experts is not None and self.moe_ffn_hidden_size is None:
             self.moe_ffn_hidden_size = self.ffn_hidden_size
+        if self.moe_first_k_dense and not (
+                self.is_moe and self.moe_layer_freq == 1
+                and 0 < self.moe_first_k_dense < self.num_layers):
+            raise ValueError(
+                f"moe_first_k_dense={self.moe_first_k_dense} needs an MoE "
+                f"model with moe_layer_freq 1 and more than that many "
+                f"layers (num_layers={self.num_layers})")
         from megatronapp_tpu.ops.context_parallel import CP_COMM_TYPES
         if self.cp_comm_type not in CP_COMM_TYPES:
             raise ValueError(

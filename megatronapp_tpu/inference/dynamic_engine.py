@@ -267,24 +267,36 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
     pool. The per-layer inputs (xs) are the block params, the layer ids
     and lora's factor banks (a, b per target, leading L dim).
     layer(layer_p, hh, lid, kv_cache, kv_scales, lora_l) runs one layer and
-    returns layer_forward's ((h, new_cache), aux). Returns (h, (k, v[,
-    k_scales, v_scales]))."""
+    returns layer_forward's ((h, new_cache), aux).
+
+    An MoE model's leading dense layers (params["lead_block"], layer ids
+    0..k-1) run first, through the same body and into planes 0..k-1 of the
+    same pools; the scanned stack takes ids k..L-1.
+
+    Returns (h, moe, (k, v[, k_scales, v_scales])); moe is None for a
+    dense model, else int32 [2]: the MoE layers' routing_counts summed."""
     def body(carry, xs):
         hh, kv, kvs = carry
         layer_p, lid, banks = xs
         ll = None
         if lora is not None:
             ll = {"row_adapter": lora["row_adapter"], "banks": banks}
-        (hh, new), _ = layer(layer_p, hh, lid, kv, kvs, ll)
+        (hh, new), aux = layer(layer_p, hh, lid, kv, kvs, ll)
         return (hh, tuple(new[:2]),
-                None if kvs is None else tuple(new[2:])), None
+                None if kvs is None else tuple(new[2:])), aux
 
     banks = None if lora is None else lora["banks"]
-    (h, pages, scales), _ = jax.lax.scan(
-        body, (h, tuple(pages), None if scales is None else tuple(scales)),
-        (params["block"], jnp.arange(cfg.num_layers), banks),
+    carry = (h, tuple(pages), None if scales is None else tuple(scales))
+    lead = cfg.moe_first_k_dense
+    if lead:
+        carry, _ = jax.lax.scan(
+            body, carry, (params["lead_block"], jnp.arange(lead), banks))
+    (h, pages, scales), moe = jax.lax.scan(
+        body, carry,
+        (params["block"], jnp.arange(lead, cfg.num_layers), banks),
         unroll=cfg.scan_unroll)
-    return h, pages + (scales or ())
+    moe = jnp.sum(moe, axis=0) if cfg.is_moe else None
+    return h, moe, pages + (scales or ())
 
 
 def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
@@ -309,9 +321,11 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     trace is identical whether or not any row has a real adapter).
     The layer loop (_scan_paged_layers) honors cfg.scan_unroll (PERF
     lever 3: unrolling removes the while-loop dispatch overhead and lets
-    XLA fuse across layer boundaries). Returns (last_logits [B,V], the
-    pools (k, v[, k_scales, v_scales])): the arrays that came in, each
-    with B new rows a layer written in place."""
+    XLA fuse across layer boundaries). Returns (last_logits [B,V], moe,
+    the pools (k, v[, k_scales, v_scales])): moe is None for a dense
+    model, else int32 [2], the round's token-expert assignments and
+    (layer, expert) pairs touched by active rows; the pools are the arrays
+    that came in, each with B new rows a layer written in place."""
     h = gpt_embed(params, tokens, cfg, position_ids=lengths[:, None])
     cos_full, sin_full = gpt_rope_tables(cfg, max_seq_len)
     if cos_full is not None:
@@ -331,10 +345,10 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
             active=active, ctx=ctx, kv_scales=kvs,
             fused_decode=fused, lora=ll)
 
-    h, new_pages = _scan_paged_layers(params, h, pages, scales, lora, cfg,
-                                      layer)
+    h, moe, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
+                                           cfg, layer)
     logits = gpt_head(params, h, cfg)[:, -1]
-    return logits, new_pages
+    return logits, moe, new_pages
 
 
 def _paged_multiquery_step(params, tokens, pages, page_table, starts,
@@ -375,8 +389,8 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
             active=active, chunk_counts=q_lens, ctx=ctx,
             kv_scales=kvs, fused_decode=fused, lora=ll)
 
-    h, new_pages = _scan_paged_layers(params, h, pages, scales, lora, cfg,
-                                      layer)
+    h, _, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
+                                         cfg, layer)
     logits = gpt_head(params, h, cfg)
     return logits, h, new_pages
 
@@ -468,16 +482,20 @@ def _warp_logits(logits, temps, top_ks, top_ps):
 
 
 def _sample_batched(logits, seeds, rids, steps, temps, top_ks, top_ps,
-                    greedys):
+                    greedys, tail=None):
     """Batched on-device sampling, one jit for all slots (replaces the
     per-request device_get loop). Per-row params; rows mirror
     engine.sample_logits semantics exactly: temperature → top-k →
-    top-p → categorical, greedy bypasses all. logits [B,V] → [B]."""
+    top-p → categorical, greedy bypasses all. logits [B,V] → [B].
+    tail: a few int32 counters of the step (an MoE model's routing
+    counts), appended to the tokens so that they reach the host in the
+    same small array."""
     keys = _request_keys(seeds, rids, steps)
     x = _warp_logits(logits, temps, top_ks, top_ps)
     sampled = jax.vmap(jax.random.categorical)(keys, x)
-    return jnp.where(greedys, jnp.argmax(logits, axis=-1),
+    toks = jnp.where(greedys, jnp.argmax(logits, axis=-1),
                      sampled).astype(jnp.int32)
+    return toks if tail is None else jnp.concatenate([toks, tail])
 
 
 class DynamicInferenceEngine:
@@ -685,6 +703,12 @@ class DynamicInferenceEngine:
         self.spec_stats = {"rounds": 0, "proposed": 0, "accepted": 0,
                            "emitted_tokens": 0, "model_steps": 0}
         self.step_stats = StepStats()
+        # Always-on routing counters of an MoE model's plain decode
+        # rounds (stats_snapshot()["moe"]): token-expert assignments of the
+        # running requests, and how many (layer, expert) pairs they
+        # touched; each round could touch moe_layers x num_moe_experts.
+        self.moe_stats = {"decode_rounds": 0, "assignments": 0,
+                          "expert_pairs_touched": 0}
         # Pre-head hidden state at each slot's last verified position —
         # feeds the MTP self-draft proposer.
         self._h_last = np.zeros((max_batch, cfg.hidden_size), np.float32)
@@ -709,6 +733,9 @@ class DynamicInferenceEngine:
         # loud log naming the SPECIFIC failed predicate.
         self._fused_requested = bool(fused_decode)
         self.megakernel = False
+        # Why it is off (None while it is on); _build_jits says, and the
+        # start-up line and /stats repeat it.
+        self.megakernel_off: Optional[str] = "needs the paged backend"
         if fused_decode and not paged:
             raise ValueError(
                 "fused_decode=True requires the paged backend (the "
@@ -727,6 +754,14 @@ class DynamicInferenceEngine:
         # compile at the engine's shapes).
         self._dispatch_stats = None
         self._build_jits()
+        logger.info(self.startup_line())
+
+    def startup_line(self) -> str:
+        """What this engine runs, for the log and a server's banner."""
+        mk = "on" if self.megakernel else f"off: {self.megakernel_off}"
+        return (f"dynamic engine: paged={self.paged}, max_batch="
+                f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
+                f"prefill_chunk={self.prefill_chunk}, megakernel {mk}")
 
     def _build_jits(self):
         cfg = self.cfg
@@ -747,6 +782,13 @@ class DynamicInferenceEngine:
             # Megakernel decode eligibility (re-checked per build so
             # MegaScope hook toggles + reset_compilation re-gate it).
             self.megakernel = False
+            self.megakernel_off = ("not asked for (fused_decode / "
+                                   "--megakernel-decode)")
+            if cfg.is_moe:
+                # Known without asking: say it at start-up, not only to
+                # whoever passes the flag.
+                self.megakernel_off = \
+                    "MoE layers: expert dispatch is not fused yet"
             if self._fused_requested:
                 from megatronapp_tpu.ops.pallas.kernel_gen import (
                     megakernel_ineligible_reason,
@@ -764,6 +806,7 @@ class DynamicInferenceEngine:
                     params=self.params, mq_rows=mq_rows,
                     lora_rank=(self.adapters.rank
                                if self.adapters is not None else None))
+                self.megakernel_off = reason
                 if reason is None:
                     self.megakernel = True
                 else:
@@ -790,7 +833,7 @@ class DynamicInferenceEngine:
                                           scales=scales, fused=fused,
                                           lora=lora)
 
-            self._decode = _PoolStep(_decode_traced, n_lead=1)
+            self._decode = _PoolStep(_decode_traced, n_lead=2)
 
             def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
                            lora):
@@ -1651,15 +1694,16 @@ class DynamicInferenceEngine:
             rows["top_ps"][i], rows["greedys"][i] = s.top_p, s.greedy
         return rows
 
-    def _sample_all(self, logits) -> np.ndarray:
+    def _sample_all(self, logits, tail=None) -> np.ndarray:
         """Batched on-device sampling for every slot. ONE device
-        round-trip per decode step instead of one per request."""
+        round-trip per decode step instead of one per request; `tail`
+        (_sample_batched) rides behind the tokens."""
         r = self._sampling_rows()
         toks = self._sample_b(
             logits, jnp.asarray(r["seeds"]), jnp.asarray(r["rids"]),
             jnp.asarray(r["steps"]), jnp.asarray(r["temps"]),
             jnp.asarray(r["top_ks"]), jnp.asarray(r["top_ps"]),
-            jnp.asarray(r["greedys"]))
+            jnp.asarray(r["greedys"]), tail)
         return np.asarray(jax.device_get(toks))
 
     def _record_token(self, req: Request, tok: int):
@@ -1802,7 +1846,9 @@ class DynamicInferenceEngine:
     def _plain_round(self, active: List[Request], events: Dict):
         """One-token decode for every active slot (non-speculative)."""
         with self._span("engine.decode_round", ring="decode-step",
-                        batch=len(active)):
+                        batch=len(active),
+                        kv_tokens=int(self.lengths[
+                            [r.slot for r in active]].sum())):
             self._plain_round_inner(active, events)
 
     def _plain_round_inner(self, active: List[Request], events: Dict):
@@ -1812,8 +1858,9 @@ class DynamicInferenceEngine:
                  for i in range(self.max_batch)])
             active_mask = jnp.asarray(active_np)
             lengths = jnp.asarray(self.lengths)
+            moe = None
             if self.paged:
-                logits, new = self._decode(
+                logits, moe, new = self._decode(
                     self.params, jnp.asarray(self.last_tokens),
                     self.pool.pages, self.pool.scales,
                     jnp.asarray(self.pool.page_table[:self.max_batch]),
@@ -1827,8 +1874,12 @@ class DynamicInferenceEngine:
             self.lengths += active_np.astype(np.int32)
             logits = mask_padded_vocab(logits, self.cfg)
         with self._span("engine.decode.wait"):
-            toks = self._sample_all(logits)
+            toks = self._sample_all(logits, tail=moe)
         with self._span("engine.decode.record"):
+            if moe is not None:
+                self.moe_stats["decode_rounds"] += 1
+                self.moe_stats["assignments"] += int(toks[-2])
+                self.moe_stats["expert_pairs_touched"] += int(toks[-1])
             self.spec_stats["model_steps"] += 1
             self.spec_stats["emitted_tokens"] += len(active)
             telemetry.inc("serving_tokens_emitted", len(active))
@@ -2073,6 +2124,14 @@ class DynamicInferenceEngine:
             "megakernel": self.megakernel,
             "steps": self.step_stats.snapshot(),
         }
+        if self.megakernel_off:
+            out["megakernel_off"] = self.megakernel_off
+        if self.cfg.is_moe:
+            per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)
+                         * self.cfg.num_moe_experts)
+            out["moe"] = dict(
+                self.moe_stats, expert_pairs_possible=(
+                    per_round * self.moe_stats["decode_rounds"]))
         if include_dispatch and self.paged:
             out["decode_dispatch"] = self.dispatch_stats()
         if self.paged:

@@ -133,6 +133,11 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     cache — latent [L, B, S_max, kv_lora_rank] + shared roped key
     [L, B, S_max, qk_pos_emb_head_dim] (reference MLA's storage win:
     klat+dpe floats per token instead of 2*Hkv*D)."""
+    if cfg.moe_first_k_dense:
+        raise ValueError(
+            "moe_first_k_dense: the dense-cache engines scan one uniform "
+            "stack; serve a model with leading dense layers through the "
+            "paged engine (paged=True / --engine dynamic --paged-kv-cache)")
     if cfg.multi_latent_attention:
         return (jnp.zeros((cfg.num_layers, batch, max_len,
                            cfg.kv_lora_rank), cfg.compute_dtype),

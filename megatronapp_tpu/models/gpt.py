@@ -52,7 +52,20 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
     if cfg.normalization == NormKind.layernorm:
         p["final_ln_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
         ax["final_ln_bias"] = ("embed",)
-    p["block"], ax["block"] = init_block_params(k_block, cfg)
+    lead = cfg.moe_first_k_dense
+    p["block"], ax["block"] = init_block_params(
+        k_block, cfg, num_layers=cfg.num_layers - lead)
+    if lead:
+        # The leading dense layers (ids 0..lead-1) of an MoE model: a
+        # stack of their own, run as a prologue before the scanned MoE
+        # stack (gpt_forward, dynamic_engine._scan_paged_layers).
+        if pp > 1:
+            raise ValueError(
+                "moe_first_k_dense: the leading dense layers are not "
+                "pipelined yet — run with pp == 1")
+        p["lead_block"], ax["lead_block"] = init_block_params(
+            jax.random.fold_in(k_block, lead), cfg, num_layers=lead,
+            force_dense=True)
     if pp > 1:
         from megatronapp_tpu.parallel.pipeline import (
             reshape_params_for_pipeline,
@@ -214,7 +227,12 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
     h = gpt_embed(p, tokens, cfg, position_offset, position_ids=positions)
     cos, sin = gpt_rope_tables(cfg, s, position_offset,
                                positions=(positions[0] if zz else positions))
+    if "lead_block" in p:
+        h, _ = block_forward(p["lead_block"], h, cfg, cos, sin,
+                             attention_mask, ctx=ctx, zigzag=zz,
+                             segment_ids=segment_ids)
     h, aux = block_forward(p["block"], h, cfg, cos, sin, attention_mask,
+                           layer_offset=cfg.moe_first_k_dense,
                            ctx=ctx, zigzag=zz, segment_ids=segment_ids,
                            fp8=None if fp8 is None else fp8["block"])
     logits = gpt_head(p, h, cfg)
@@ -320,6 +338,9 @@ def gpt_pipeline_loss(p, tokens_mb, targets_mb, loss_mask_mb,
     )
 
     m, mb, s = tokens_mb.shape
+    if "lead_block" in p:
+        raise ValueError("moe_first_k_dense: the leading dense layers are "
+                         "not pipelined yet")
     if segment_ids_mb is not None:
         if schedule == "zero-bubble":
             raise NotImplementedError(

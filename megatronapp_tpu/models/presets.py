@@ -64,6 +64,37 @@ def mixtral_8x7b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def deepseek_v2_lite(**kw) -> TransformerConfig:
+    """DeepSeek-V2-Lite (15.7B, 2.4B active) as its config.json publishes
+    it (https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite): 27 layers,
+    MLA without a query latent, layer 0 dense, then 64 experts top-6 with 2
+    shared experts (one SwiGLU of twice the width), probabilities not
+    renormalised, YaRN x40 with mscale == mscale_all_dim == 0.707.
+    tests/test_deepseek_v2.py holds this, the benchmark's configuration
+    file and the catalog's numbers to each other."""
+    d = dict(num_layers=27, hidden_size=2048, num_attention_heads=16,
+             ffn_hidden_size=10944, vocab_size=102400,
+             max_position_embeddings=163840,
+             activation=ActivationKind.swiglu,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-6,
+             add_bias_linear=False,
+             untie_embeddings_and_output_weights=True,
+             position_embedding=PositionEmbeddingKind.yarn,
+             rotary_base=10000.0, rope_scaling_factor=40.0,
+             yarn_original_max_position=4096, yarn_beta_fast=32.0,
+             yarn_beta_slow=1.0, yarn_mscale_coeff=0.1 * 0.707,
+             multi_latent_attention=True, q_lora_rank=None,
+             kv_lora_rank=512, qk_head_dim=128, qk_pos_emb_head_dim=64,
+             v_head_dim=128,
+             num_moe_experts=64, moe_router_topk=6,
+             moe_ffn_hidden_size=1408,
+             moe_shared_expert_intermediate_size=2 * 1408,
+             moe_router_norm_topk_prob=False,
+             moe_routed_scaling_factor=1.0, moe_first_k_dense=1)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def bert_base(**kw) -> TransformerConfig:
     from megatronapp_tpu.models.bert import bert_config
     d = dict(num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -96,6 +127,7 @@ PRESETS = {
     "gpt-16l-2048h": gpt_16l_2048h,
     "llama3-8b": llama3_8b,
     "mixtral-8x7b": mixtral_8x7b,
+    "deepseek-v2-lite": deepseek_v2_lite,
     "bert-base": bert_base,
     "t5-base": t5_base,
 }

@@ -135,43 +135,48 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
                    cfg.layernorm_epsilon)
-    if cfg.multi_latent_attention:
-        if lora is not None:
-            raise ValueError(
-                "lora serving targets the GQA projection kernels — MLA "
-                "has no q_kernel/kv_kernel (lora.AdapterCache rejects "
-                "MLA configs at construction)")
-        from megatronapp_tpu.transformer.mla import mla_forward
-        if segment_ids is not None:
-            # MLA routes through the reference attention impl — packed
-            # segments densify into the mask here.
-            seg_mask = (segment_ids[:, None, :, None]
-                        == segment_ids[:, None, None, :])
-            attention_mask = (seg_mask if attention_mask is None
-                              else attention_mask & seg_mask)
-        if kv_cache is not None:
-            attn_out, new_cache = mla_forward(
-                p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-                layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
-                cache_index=cache_index, cache_positions=cache_positions,
-                page_table=page_table, active=active,
-                chunk_counts=chunk_counts, kv_scales=kv_scales)
+    # The scopes put a layer's three parts into the compiled program's
+    # op_names (HLO text, the profiler's own viewer). The events of a TPU
+    # trace as jax.profiler.ProfileData gives them do not carry op_names,
+    # so perfbench reads kernels by name instead (PERF.md, PR 28).
+    with jax.named_scope("attention"):
+        if cfg.multi_latent_attention:
+            if lora is not None:
+                raise ValueError(
+                    "lora serving targets the GQA projection kernels — MLA "
+                    "has no q_kernel/kv_kernel (lora.AdapterCache rejects "
+                    "MLA configs at construction)")
+            from megatronapp_tpu.transformer.mla import mla_forward
+            if segment_ids is not None:
+                # MLA routes through the reference attention impl — packed
+                # segments densify into the mask here.
+                seg_mask = (segment_ids[:, None, :, None]
+                            == segment_ids[:, None, None, :])
+                attention_mask = (seg_mask if attention_mask is None
+                                  else attention_mask & seg_mask)
+            if kv_cache is not None:
+                attn_out, new_cache = mla_forward(
+                    p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
+                    layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
+                    cache_index=cache_index, cache_positions=cache_positions,
+                    page_table=page_table, active=active,
+                    chunk_counts=chunk_counts, kv_scales=kv_scales)
+            else:
+                attn_out = mla_forward(
+                    p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
+                    layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
+                new_cache = None
         else:
-            attn_out = mla_forward(
+            attn_out, new_cache = attention_forward(
                 p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-                layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
-            new_cache = None
-    else:
-        attn_out, new_cache = attention_forward(
-            p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-            kv_cache=kv_cache, cache_index=cache_index,
-            cache_positions=cache_positions, layer_id=layer_id,
-            ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
-            page_table=page_table, active=active,
-            chunk_counts=chunk_counts, tp_sharded=tp_sharded,
-            kv_scales=kv_scales,
-            fp8=None if fp8 is None else fp8["attention"],
-            lora=lora)
+                kv_cache=kv_cache, cache_index=cache_index,
+                cache_positions=cache_positions, layer_id=layer_id,
+                ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
+                page_table=page_table, active=active,
+                chunk_counts=chunk_counts, tp_sharded=tp_sharded,
+                kv_scales=kv_scales,
+                fp8=None if fp8 is None else fp8["attention"],
+                lora=lora)
     # Tag for the 'selective_attn' remat policy (a no-op otherwise).
     attn_out = checkpoint_name(attn_out, "attn_out")
     x = residual + attn_out.astype(residual.dtype)
@@ -187,13 +192,27 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         if lora is not None:
             raise ValueError("lora serving targets the dense fc1/fc2 "
                              "kernels — MoE layers are unsupported")
-        mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
-                                   ctx=ctx, tp_sharded=tp_sharded)
+        count_rows = None
+        if page_table is not None:
+            # A paged serving step: its real tokens are the active rows'
+            # first chunk_counts positions; moe_forward counts their
+            # routing in place of the aux loss.
+            b, s = x.shape[:2]
+            count_rows = jnp.ones((b, s), bool)
+            if active is not None:
+                count_rows &= active[:, None]
+            if chunk_counts is not None:
+                count_rows &= jnp.arange(s)[None, :] < chunk_counts[:, None]
+        with jax.named_scope("moe"):
+            mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
+                                       ctx=ctx, tp_sharded=tp_sharded,
+                                       count_rows=count_rows)
     else:
-        mlp_out = mlp_forward(p["mlp"], h, cfg, layer_id=layer_id, ctx=ctx,
-                              tp_sharded=tp_sharded,
-                              fp8=None if fp8 is None else fp8["mlp"],
-                              lora=lora)
+        with jax.named_scope("mlp"):
+            mlp_out = mlp_forward(p["mlp"], h, cfg, layer_id=layer_id,
+                                  ctx=ctx, tp_sharded=tp_sharded,
+                                  fp8=None if fp8 is None else fp8["mlp"],
+                                  lora=lora)
     x = residual + mlp_out.astype(residual.dtype)
     # MegaScope 'system' perturbation + capture site between layers
     # (transformer_block.py:542-544).
@@ -234,8 +253,12 @@ def _stack_layers(per_layer, extra_axis: str = "layers"):
     return stacked, ax
 
 
-def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None):
+def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
+                      force_dense: bool = False):
     """Stacked layer params for lax.scan.
+
+    force_dense: `num_layers` dense-MLP layers of an MoE model (its leading
+    dense layers, cfg.moe_first_k_dense), stacked like a uniform block.
 
     Uniform case: every leaf gains a leading [L] 'layers' axis.
     moe_layer_freq > 1 (reference transformer_config moe_layer_freq int
@@ -251,9 +274,10 @@ def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None):
         )
         return init_hetero_block_params(rng, cfg)
     freq = cfg.moe_layer_freq if cfg.is_moe else 1
-    if freq == 1:
+    if freq == 1 or force_dense:
         keys = jax.random.split(rng, n)
-        return _stack_layers([init_layer_params(k, cfg) for k in keys])
+        return _stack_layers([init_layer_params(k, cfg, force_dense)
+                              for k in keys])
 
     if n % freq != 0:
         raise ValueError(f"num_layers={n} not divisible by "

@@ -8,6 +8,13 @@ allgather/all-to-all collectives; TPU-first, we build GShard-style dispatch/
 combine einsums against experts stacked on an 'experts'-sharded leading axis —
 XLA lowers the token exchange to a ragged all-to-all over the 'ep' mesh axis.
 
+The router scores with a softmax over all experts and takes the top k.
+Whether the k chosen probabilities are then divided by their sum is the
+model's choice, not this module's: ``cfg.moe_router_norm_topk_prob`` (HF
+config key ``norm_topk_prob``: true for Mixtral and the reference TopKRouter's
+default, false for DeepSeek-V2-Lite), and the routed experts' output is
+multiplied by ``cfg.moe_routed_scaling_factor`` (HF ``routed_scaling_factor``).
+
 Two dispatch modes, matching the reference's semantics:
 - moe_capacity_factor=None (the reference DEFAULT): exact dropless —
   token copies are sorted by expert and run through ``lax.ragged_dot``
@@ -64,8 +71,10 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     """Top-k softmax router with load-balance + z losses.
 
     x_flat: [T, H]. Returns (topk_idx [T,K], topk_probs [T,K], aux_loss).
-    Softmax-then-topk with prob renormalization — reference TopKRouter
-    (router.py:102) default scoring.
+    Softmax over all experts, then top-k (reference TopKRouter,
+    router.py:102). The k probabilities are divided by their sum only when
+    ``cfg.moe_router_norm_topk_prob`` says so (HF ``norm_topk_prob``), and
+    carry ``cfg.moe_routed_scaling_factor`` (HF ``routed_scaling_factor``).
 
     stats_mean: optional reducer applied to the per-expert token-mean
     statistics (frac, mean_prob, z² mean) BEFORE the nonlinear aux-loss
@@ -79,8 +88,11 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     logits = x_flat.astype(jnp.float32) @ p["router_kernel"]
     probs = jax.nn.softmax(logits, axis=-1)
     topk_probs, topk_idx = jax.lax.top_k(probs, cfg.moe_router_topk)
-    topk_probs = topk_probs / jnp.maximum(
-        jnp.sum(topk_probs, -1, keepdims=True), 1e-9)
+    if cfg.moe_router_norm_topk_prob:
+        topk_probs = topk_probs / jnp.maximum(
+            jnp.sum(topk_probs, -1, keepdims=True), 1e-9)
+    if cfg.moe_routed_scaling_factor != 1.0:
+        topk_probs = topk_probs * cfg.moe_routed_scaling_factor
 
     if stats_mean is None:
         stats_mean = lambda s: s  # noqa: E731 — identity reducer
@@ -160,10 +172,24 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
         y.astype(jnp.float32) * w_sorted[:, None])
 
 
+def routing_counts(topk_idx, count_rows, num_experts: int) -> jnp.ndarray:
+    """int32 [2] of one layer's routing: token-expert assignments of the
+    rows that `count_rows` ([T] bool) marks as real tokens, and how many of
+    the experts those rows touched."""
+    hit = jax.nn.one_hot(topk_idx, num_experts, dtype=jnp.bool_)  # [T,K,E]
+    hit = hit & count_rows[:, None, None]
+    return jnp.stack([jnp.sum(hit), jnp.sum(jnp.any(hit, axis=(0, 1)))]
+                     ).astype(jnp.int32)
+
+
 def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
-                ctx=None, tp_sharded: bool = False
+                ctx=None, tp_sharded: bool = False, count_rows=None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: [B,S,H] → ([B,S,H], aux_loss scalar).
+
+    count_rows: [B,S] bool, given by the serving steps (which have no use
+    for the aux loss): the second result is then ``routing_counts`` of
+    those rows, for the engine's always-on `moe` counters.
 
     ctx with ep > 1 selects the explicit all-to-all dispatch
     (_a2a_expert_forward): expert weights stay home on their ep shard and
@@ -228,6 +254,9 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
             stats_mean = lambda st: jax.lax.pmean(st, token_axes)  # noqa: E731
     topk_idx, topk_probs, aux = _router(p, x_flat, cfg,
                                         stats_mean=stats_mean)
+
+    if count_rows is not None:
+        aux = routing_counts(topk_idx, count_rows.reshape(t), e)
 
     if cfg.moe_capacity_factor is None:
         out = _dropless_experts(p, x_flat, topk_idx, topk_probs, cfg)

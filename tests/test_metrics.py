@@ -421,7 +421,7 @@ class TestStepSpansAndCounters:
         eng = _pressure_engine()
         eng.step()
 
-        def boom(logits):
+        def boom(logits, tail=None):
             raise RuntimeError("device lost")
 
         eng._sample_all = boom

@@ -310,7 +310,7 @@ class TestDecodeLogitsParity:
         active = jnp.ones((b,), bool)
         d_logits, _ = _decode_step(params, tokens, dense, lens, active,
                                    cfg)
-        p_logits, _ = _paged_decode_step(
+        p_logits, _, _ = _paged_decode_step(
             params, tokens, pages, jnp.asarray(table), lens, active, cfg,
             s_max)
         np.testing.assert_allclose(np.asarray(d_logits),

@@ -172,9 +172,9 @@ def run_logits_parity(block_size: int = 8):
     lens = jnp.asarray(lengths)
     active = jnp.ones((b,), bool)
     tbl = jnp.asarray(table)
-    base, _ = _paged_decode_step(params, tokens, tuple(pools), tbl, lens,
+    base, _, _ = _paged_decode_step(params, tokens, tuple(pools), tbl, lens,
                                  active, cfg, mb * bs)
-    quant, _ = _paged_decode_step(params, tokens, tuple(qpools), tbl,
+    quant, _, _ = _paged_decode_step(params, tokens, tuple(qpools), tbl,
                                   lens, active, cfg, mb * bs,
                                   scales=tuple(spools))
     diff = float(jnp.max(jnp.abs(base.astype(jnp.float32)

@@ -405,6 +405,7 @@ def main():
               f"megakernel={engine.megakernel}, "
               f"lora={'on' if args.lora_dir else 'off'}, "
               f"spec={engine.spec_method or 'off'})")
+        print(engine.startup_line())
         TextGenerationServer(engine, args.host, args.port).run()
         return
     engine = StaticInferenceEngine(params, cfg, tokenizer=tok,
