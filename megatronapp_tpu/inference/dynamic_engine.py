@@ -709,6 +709,11 @@ class DynamicInferenceEngine:
         # touched; each round could touch moe_layers x num_moe_experts.
         self.moe_stats = {"decode_rounds": 0, "assignments": 0,
                           "expert_pairs_touched": 0}
+        # Always-on counters of the paged kernels' walk over plain decode
+        # rounds (stats_snapshot()["paged"]): the blocks the running slots
+        # hold against running slots x max_blocks_per_seq.
+        self.paged_stats = {"decode_rounds": 0, "blocks_live": 0,
+                            "blocks_table": 0}
         # Pre-head hidden state at each slot's last verified position —
         # feeds the MTP self-draft proposer.
         self._h_last = np.zeros((max_batch, cfg.hidden_size), np.float32)
@@ -1845,10 +1850,19 @@ class DynamicInferenceEngine:
 
     def _plain_round(self, active: List[Request], events: Dict):
         """One-token decode for every active slot (non-speculative)."""
+        lens = self.lengths[[r.slot for r in active]]
+        attrs = {"kv_tokens": int(lens.sum())}
+        if self.paged:
+            # the blocks this round's paged kernel walks (it reads the
+            # row the round appends too), of those the table could name
+            bs = self.pool.block_size
+            attrs["kv_blocks"] = int((lens // bs + 1).sum())
+            self.paged_stats["decode_rounds"] += 1
+            self.paged_stats["blocks_live"] += attrs["kv_blocks"]
+            self.paged_stats["blocks_table"] += (
+                len(active) * self.pool.page_table.shape[1])
         with self._span("engine.decode_round", ring="decode-step",
-                        batch=len(active),
-                        kv_tokens=int(self.lengths[
-                            [r.slot for r in active]].sum())):
+                        batch=len(active), **attrs):
             self._plain_round_inner(active, events)
 
     def _plain_round_inner(self, active: List[Request], events: Dict):
@@ -2109,6 +2123,9 @@ class DynamicInferenceEngine:
         """JSON-ready serving stats (the server's GET /stats payload):
         pool occupancy, prefix-cache hit rate, speculative acceptance,
         active batch size — serving is observable without log scraping.
+        On a paged engine "paged" holds the walk's counters over plain
+        decode rounds: blocks_live of blocks_table is the share of the
+        page table's width that held rows (False on a dense-cache one).
 
         include_dispatch=True adds the compiled decode-step dispatch
         accounting (dispatch_stats; the first call pays one AOT compile
@@ -2135,6 +2152,7 @@ class DynamicInferenceEngine:
         if include_dispatch and self.paged:
             out["decode_dispatch"] = self.dispatch_stats()
         if self.paged:
+            out["paged"] = dict(self.paged_stats)
             pool = self.pool
             st = dict(pool.stats)
             seen = st["prefix_hit_tokens"] + st["prefill_tokens"]

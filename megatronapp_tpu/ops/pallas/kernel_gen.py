@@ -18,15 +18,20 @@ three axes, so the bodies are now EMITTED from a spec instead of copied:
                    the emitted kernel on its matched GQA groups against
                    its 1/tp slice of the pool).
 
-``paged_attention`` is the one entry point; the legacy names in
-paged_attention.py are thin wrappers over it. The emitted body is
-op-for-op the legacy body (the ragged=False specialization collapses the
-window transposes exactly the way the hand-written decode kernel did),
-so generated kernels are BITWISE-identical to the variants they replace
-— pinned in tests/test_kernel_gen.py against frozen copies of the old
-bodies across {bf16, int8} × {tp1, tp2} × {q_len 1, ragged} ×
-{GQA, MHA}. New variants (fp8 pools, MLA latent layouts, token-tree
-masks) are parameters here, not new copies.
+``paged_attention`` is the one entry point (``paged_attention_latent``
+for MLA pools); the legacy names in paged_attention.py are thin wrappers
+over it. Both run ONE scaffold, ``emit_paged_kernel`` under
+``_walk_call`` (ISSUE 29): a one-dimensional grid over the REAL steps of
+a call — a slot is walked for the blocks it holds, ``pages_per_step``
+pages a step (a key tile 128 wide at blocks of 16), and no step, branch
+or DMA exists for a table entry past its length — around the body's
+tile functions (``_dense_tile``, ``_latent_tile``). The mathematics is
+the legacy bodies' (online softmax in fp32, causal tail mask, in-register
+dequant), folded a tile at a time: tests/test_kernel_gen.py holds the
+kernels BITWISE to a jax.numpy replay of the walk and allclose to frozen
+copies of the old bodies across {bf16, int8} × {tp1, tp2} × {q_len 1,
+ragged} × {GQA, MHA}. New variants (fp8 pools, MLA latent layouts,
+token-tree masks) are parameters here, not new copies.
 
 The second half of the module is the FUSED DECODE STEP (megakernel
 direction, *Event Tensor* arXiv 2604.13327): at decode batch sizes the
@@ -56,6 +61,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 import os
 from typing import Optional
 
@@ -74,8 +80,9 @@ def _interpret() -> bool:
 
 
 def _dequant_block(k, ks):
-    """[bs, Hkv, D] int8 block × [bs, Hkv] fp32 scales → fp32 block (the
-    in-register dequant of one DMA'd page)."""
+    """[rows, Hkv, D] quantized K/V rows × [rows, Hkv] fp32 scales (or
+    [rows, d] latent rows × [rows] scales) → fp32 rows: the in-register
+    dequant of the pages a step holds."""
     return k.astype(jnp.float32) * ks[..., None]
 
 
@@ -134,11 +141,43 @@ def _paged_name(ragged: bool, quant_dtype: Optional[str] = None,
 
 
 def default_kv_tile(quant_dtype: Optional[str]):
-    """Min TPU tile (sublane, lane) of the KV block windows for this
-    storage dtype — the shape knob an on-chip tuning pass flips."""
+    """Min TPU tile (sublane, lane) of a KV page for this storage dtype:
+    what a page's last two dims are padded to in VMEM."""
     if quant_dtype is None:
         return (16, 128)
     return QUANT_DTYPES[quant_dtype][1]
+
+
+# What a walk's page blocks, double-buffered by the pipeline, may take of
+# VMEM. Mosaic's default scope is 16 MiB and a compute block's
+# temporaries (the transposed tile, scores, probabilities) share it.
+WALK_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def _pages_vmem_bytes(pools, kv_tile) -> int:
+    """Bytes ONE page of every pool takes in VMEM together. pools: the
+    stacked [L, NB, bs, ...] arrays of a call, KV pools (tiled
+    `kv_tile`) first, their fp32 scale pools (tiled (8, 128)) after. A
+    page's last dim is padded to the tile's lanes, the one before to its
+    sublanes."""
+    total = 0
+    for i, pool in enumerate(pools):
+        sub, lane = kv_tile if i < 2 else (8, 128)
+        *lead, rows, cols = (1,) + tuple(pool.shape[2:])
+        total += (math.prod(lead) * -(-rows // sub) * sub
+                  * -(-cols // lane) * lane * pool.dtype.itemsize)
+    return total
+
+
+def pages_per_step(block_size: int, page_bytes: int, table_blocks: int,
+                   budget: int = WALK_VMEM_BUDGET) -> int:
+    """Pages in one compute block of the walk (`PagedSpec.pages`), from
+    what the code can see: as many as make the key tile 128 wide (8 at
+    block_size 16), fewer if two buffers of them would pass `budget`
+    (`page_bytes`: one page of every pool of the call, as VMEM holds
+    it), never more than the table holds."""
+    return max(1, min(128 // block_size, budget // (2 * page_bytes),
+                      table_blocks))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,13 +190,14 @@ class PagedSpec:
     scale-block refs and the in-register dequant of each DMA'd block —
     the dequant body (cast to fp32 × per-(row, head) scale) is shared by
     both quantized formats, so a new storage dtype is a registry entry
-    (QUANT_DTYPES), not a new body. kv_tile is the (sublane, lane) min
-    tile of the KV block windows (dtype-dependent on TPU — fp8/int8 want
-    (32, 128)); interpret mode ignores it, and paged_attention derives
-    the per-dtype default, so it only needs touching for on-chip layout
-    experiments. The tp head-shard axis is NOT part of the body spec —
-    sharding is pure placement (``paged_attention(..., mesh=)`` wraps
-    the same emitted kernel in a full-manual shard_map)."""
+    (QUANT_DTYPES), not a new body. pages is how many pages one step of
+    the walk takes (the key tile is pages × block_size wide) and
+    kv_tile the (sublane, lane) min tile a KV page is padded to in VMEM
+    (dtype-dependent — fp8/int8 want (32, 128)); the entry points
+    derive both from the shapes (`pages_per_step`, `default_kv_tile`),
+    no caller sets them. The tp head-shard axis is NOT part of the body
+    spec — sharding is pure placement (``paged_attention(..., mesh=)``
+    wraps the same emitted kernel in a full-manual shard_map)."""
 
     ragged: bool
     quant_dtype: Optional[str]
@@ -168,6 +208,7 @@ class PagedSpec:
     group: int
     scale: float
     kv_tile: tuple = (16, 128)
+    pages: int = 1
     # MLA latent layout (ISSUE 17): pages hold [block, klat] latent +
     # [block, dpe] roped-key blocks with NO per-head axis; hkv carries
     # the QUERY head count (every head attends the one shared latent,
@@ -211,259 +252,292 @@ class PagedSpec:
 
 
 def emit_paged_kernel(spec: PagedSpec):
-    """Emit the kernel body for `spec`.
+    """Emit the kernel for `spec`: ONE walk, written once, around the
+    body's tile functions (dense K/V pages, or MLA latent pages).
 
-    Grid (B, max_blocks_per_seq); block j of slot b is DMA'd from page
-    table[b, j] (scalar-prefetched index map). Online softmax over the
-    ragged valid range [0, lens[b]); fully-out-of-range blocks are
-    skipped whole. Ragged kernels additionally mask each local query row
-    i (absolute position kv_len - q_len + i) causally within the new
-    tail; at q_len == 1 the math collapses to the decode body's exact
-    block/accumulator order — the two legacy variants were the
-    ragged=False / ragged=True points of this one template."""
-    if spec.latent:
-        return emit_latent_kernel(spec)
-    bs = spec.block_size
-    mbs = spec.num_blocks_seq
-    hkv, group, s_q = spec.hkv, spec.group, spec.s_q
-    hq = hkv * group
-    ragged, quantized = spec.ragged, spec.quantized
-    scale = spec.scale
+    The grid is one-dimensional and its bound is the call's count of REAL
+    steps (`_walk_steps`): step g belongs to slot ``slot_of[g]`` and is
+    that slot's ``step_of[g]``-th compute block of ``spec.pages`` pages, a
+    key tile pages × bs wide. A slot is visited for blocks
+    0 … cdiv(lens[b], bs) − 1 and nothing else: no grid step, no branch
+    and no DMA for a table entry past the slot's length (`_walk_call`'s
+    index maps; Pallas keeps step g+1's pages in flight while step g is
+    computed). The last step of a slot may be partial: its missing pages
+    re-name pages the pipeline already holds and their columns are
+    masked. Online softmax in fp32 over the valid range [0, lens[b]),
+    query row i (absolute position kv_len − q_len + i; q_len is 1 where
+    the kernel is not ragged) masked causally within the new tail. A
+    slot with no cached row gets one step that computes nothing and
+    writes zeros."""
+    pages, ragged = spec.pages, spec.ragged
+    width = pages * spec.block_size
+    n_pools = 4 if spec.quantized else 2
+    n_q, prep, row_q, scores, finish = (
+        _latent_tile if spec.latent else _dense_tile)(spec)
 
-    def kernel(lid_ref, *refs):
-        if ragged:
-            table_ref, lens_ref, qlens_ref = refs[:3]
-            rest = refs[3:]
-        else:
-            table_ref, lens_ref = refs[:2]
-            rest = refs[2:]
-        # layer and block indirection are consumed by the BlockSpec index maps
-        del lid_ref, table_ref
-        q_ref, k_ref, v_ref = rest[:3]
-        rest = rest[3:]
-        if quantized:
-            ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
-        else:
-            o_ref, acc, m_scr, l_scr = rest
-        b = pl.program_id(0)
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
-        def _init():
-            acc[:] = jnp.zeros_like(acc)
-            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-            l_scr[:] = jnp.zeros_like(l_scr)
-
+    def kernel(lid_ref, lens_ref, slot_ref, step_ref, block_ref, *refs):
+        # layer and block indirection are consumed by the index maps
+        del lid_ref, block_ref
+        refs = list(refs)
+        qlens_ref = refs.pop(0) if ragged else None
+        q_refs, refs = refs[:n_q], refs[n_q:]
+        tiles = [refs[k * pages:(k + 1) * pages] for k in range(n_pools)]
+        *consts, o_ref, acc, m_scr, l_scr = refs[n_pools * pages:]
+        g = pl.program_id(0)
+        b, i = slot_ref[g], step_ref[g]
         kv_len = lens_ref[b]
-        if ragged:
-            q_len = qlens_ref[b]
-            q_start = kv_len - q_len   # absolute position of local query 0
 
-        @pl.when(j * bs < kv_len)
-        def _compute():
-            q = q_ref[0].astype(jnp.float32) * scale
-            if quantized:
-                k = _dequant_block(k_ref[0], ks_ref[0])   # [bs, Hkv, D]
-                v = _dequant_block(v_ref[0], vs_ref[0])
-            else:
-                k = k_ref[0]                              # [bs, Hkv, D]
-                v = v_ref[0]
-            d = q.shape[-1]
-            if ragged:
-                # [Hkv, S_q*group, D] with inner index i = s*group + g
-                # (row i's query position is i // group after unfolding
-                # back through the [S_q, Hq] layout below).
-                q3 = jnp.transpose(q.reshape(s_q, hkv, group, d),
-                                   (1, 0, 2, 3)).reshape(hkv, s_q * group,
-                                                         d)
-            else:
-                q3 = q.reshape(hkv, group, d)
-            k3 = jnp.swapaxes(k, 0, 1)                    # [Hkv, bs, D]
-            v3 = jnp.swapaxes(v, 0, 1)
-            s = jax.lax.dot_general(                      # [Hkv, rows, bs]
-                q3.astype(k3.dtype), k3,
-                (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            pos = j * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (1, bs), 1)[0]
-            if ragged:
-                row_q = jax.lax.broadcasted_iota(
-                    jnp.int32, (s_q * group, 1), 0)[:, 0] // group
-                abs_q = q_start + row_q                   # [S_q*group]
-                valid = ((pos[None, :] <= abs_q[:, None])
-                         & (pos[None, :] < kv_len))       # [S_q*g, bs]
-                s = jnp.where(valid[None], s, _NEG_INF)
-                # [S_q*Hq, bs] with row = s*hq + h (h = kvh*group + g).
-                s2 = jnp.transpose(
-                    s.reshape(hkv, s_q, group, bs),
-                    (1, 0, 2, 3)).reshape(s_q * hq, bs)
-                p_mask = jnp.transpose(
-                    jnp.broadcast_to(valid.reshape(1, s_q, group, bs),
-                                     (hkv, s_q, group, bs)),
-                    (1, 0, 2, 3)).reshape(s_q * hq, bs)
-            else:
-                valid = pos < kv_len                      # [bs]
-                s = jnp.where(valid[None, None, :], s, _NEG_INF)
-                s2 = s.reshape(hq, bs)
-                p_mask = valid[None, :]
-
-            m_prev = m_scr[:, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1))
-            m_safe = jnp.maximum(m_new, _NEG_INF / 2)
-            p = jnp.exp(s2 - m_safe[:, None])
-            p = jnp.where(p_mask, p, 0.0)
-            corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-            corr = jnp.where(m_prev <= _NEG_INF / 2, 0.0, corr)
-            l_scr[:, 0] = l_scr[:, 0] * corr + jnp.sum(p, axis=1)
-            if ragged:
-                p3 = jnp.transpose(
-                    p.reshape(s_q, hkv, group, bs),
-                    (1, 0, 2, 3)).reshape(hkv, s_q * group, bs)
-            else:
-                p3 = p.reshape(hkv, group, bs)
-            pv = jax.lax.dot_general(                     # [Hkv, rows, D]
-                p3.astype(v3.dtype), v3,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            if ragged:
-                pv2 = jnp.transpose(
-                    pv.reshape(hkv, s_q, group, d),
-                    (1, 0, 2, 3)).reshape(s_q * hq, d)
-            else:
-                pv2 = pv.reshape(hq, d)
-            acc[:] = acc[:] * corr[:, None] + pv2
-            m_scr[:, 0] = m_new
-
-        @pl.when(j == mbs - 1)
-        def _finalize():
-            l = jnp.maximum(l_scr[:, 0], 1e-20)
-            if ragged:
-                a = acc[:]
-                o_ref[0] = (a / l[:, None]).reshape(
-                    s_q, hq, a.shape[-1]).astype(o_ref.dtype)
-            else:
-                o_ref[0] = (acc[:] / l[:, None]).astype(o_ref.dtype)
-
-    return kernel
-
-
-def emit_latent_kernel(spec: PagedSpec):
-    """Emit the MLA latent-space body for a latent `spec` (ISSUE 17).
-
-    Same grid / online-softmax / causal-tail scaffolding as the dense
-    template, but the pool blocks are the COMPRESSED run ([bs, klat]
-    latent + [bs, dpe] roped shared key, no per-head axis) and the
-    score contraction runs directly in latent space: the caller absorbs
-    q_nope through kv_up's k_nope columns so block scores are
-    q_lat · latent^T + q_pe · k_pe^T. The value path re-expands THIS
-    tile's v rows in-register (dequantized latent block × kv_up's v
-    columns) — the dense [B, S_kv, nq, dqk+dv] reconstitution the old
-    mla_forward gather paid every step never materializes. Rows are
-    s_q * nq with row = s*nq + h (group == 1: every head shares the
-    latent row, so no GQA fold)."""
-    bs = spec.block_size
-    mbs = spec.num_blocks_seq
-    nq, s_q = spec.hkv, spec.s_q
-    klat, dpe, dv = spec.klat, spec.dpe, spec.dv
-    rows = s_q * nq
-    ragged, quantized = spec.ragged, spec.quantized
-    scale = spec.scale
-
-    def kernel(lid_ref, *refs):
-        if ragged:
-            table_ref, lens_ref, qlens_ref = refs[:3]
-            rest = refs[3:]
-        else:
-            table_ref, lens_ref = refs[:2]
-            rest = refs[2:]
-        # layer and block indirection are consumed by the BlockSpec index maps
-        del lid_ref, table_ref
-        ql_ref, qp_ref, lat_ref, pe_ref = rest[:4]
-        rest = rest[4:]
-        if quantized:
-            ls_ref, ps_ref = rest[:2]
-            rest = rest[2:]
-        wv_ref, o_ref, acc, m_scr, l_scr = rest
-        b = pl.program_id(0)
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
+        @pl.when(i == 0)
         def _init():
-            acc[:] = jnp.zeros_like(acc)
-            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-            l_scr[:] = jnp.zeros_like(l_scr)
+            acc[...] = jnp.zeros_like(acc)
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
 
-        kv_len = lens_ref[b]
-        if ragged:
-            q_len = qlens_ref[b]
-            q_start = kv_len - q_len   # absolute position of local query 0
-
-        @pl.when(j * bs < kv_len)
-        def _compute():
-            ql = ql_ref[0].astype(jnp.float32).reshape(rows, klat) * scale
-            qp = qp_ref[0].astype(jnp.float32).reshape(rows, dpe) * scale
-            if quantized:
-                # Per-ROW scalar scales ([bs] fp32): the whole latent
-                # row quantizes as one unit (quantize_kv_rows over the
-                # trailing dim — no head axis to split on).
-                lat = lat_ref[0].astype(jnp.float32) * ls_ref[0][:, None]
-                pe = pe_ref[0].astype(jnp.float32) * ps_ref[0][:, None]
-            else:
-                lat = lat_ref[0]                          # [bs, klat]
-                pe = pe_ref[0]                            # [bs, dpe]
-            s2 = (jnp.dot(ql.astype(lat.dtype), lat.T,   # [rows, bs]
-                          preferred_element_type=jnp.float32)
-                  + jnp.dot(qp.astype(pe.dtype), pe.T,
-                            preferred_element_type=jnp.float32))
-            pos = j * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (1, bs), 1)[0]
-            if ragged:
-                row_q = jax.lax.broadcasted_iota(
-                    jnp.int32, (rows, 1), 0)[:, 0] // nq
-                abs_q = q_start + row_q                   # [rows]
-                valid = ((pos[None, :] <= abs_q[:, None])
-                         & (pos[None, :] < kv_len))       # [rows, bs]
-            else:
-                valid = jnp.broadcast_to(pos[None, :] < kv_len,
-                                         (rows, bs))
-            s2 = jnp.where(valid, s2, _NEG_INF)
-
-            m_prev = m_scr[:, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1))
+        @pl.when(i * width < kv_len)
+        def _fold():
+            s, values = scores(prep(*q_refs), tiles, *consts)
+            pos = i * width + jax.lax.broadcasted_iota(
+                jnp.int32, (1, width), 1)
+            # the local query of row r sits at kv_len - q_len + row_q(r)
+            # (one query a slot: at kv_len - 1, behind every cached row)
+            abs_q = (kv_len - (qlens_ref[b] if ragged else 1)) + row_q(
+                jax.lax.broadcasted_iota(jnp.int32, (s.shape[-2], 1), 0))
+            valid = (pos < kv_len) & (pos <= abs_q)       # [rows, width]
+            s = jnp.where(valid, s, _NEG_INF)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
             m_safe = jnp.maximum(m_new, _NEG_INF / 2)
-            p = jnp.exp(s2 - m_safe[:, None])
+            p = jnp.exp(s - m_safe)
             p = jnp.where(valid, p, 0.0)
             corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
             corr = jnp.where(m_prev <= _NEG_INF / 2, 0.0, corr)
-            l_scr[:, 0] = l_scr[:, 0] * corr + jnp.sum(p, axis=1)
-            # Value path, re-expanded per-tile in-register: v rows of
-            # THIS block from the (dequantized) latent block through
-            # kv_up's v columns.
-            wv = wv_ref[...]
-            v_t = jax.lax.dot_general(                    # [bs, nq, dv]
-                lat, wv.astype(lat.dtype),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            v3 = jnp.swapaxes(v_t, 0, 1)                  # [nq, bs, dv]
-            p3 = jnp.transpose(p.reshape(s_q, nq, bs), (1, 0, 2))
-            pv = jax.lax.dot_general(                     # [nq, s_q, dv]
-                p3.astype(v3.dtype), v3,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            pv2 = jnp.transpose(pv, (1, 0, 2)).reshape(rows, dv)
-            acc[:] = acc[:] * corr[:, None] + pv2
-            m_scr[:, 0] = m_new
+            l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+            acc[...] = acc[...] * corr + values(p)
+            m_scr[...] = m_new
 
-        @pl.when(j == mbs - 1)
+        @pl.when((i + 1) * width >= kv_len)
         def _finalize():
-            l = jnp.maximum(l_scr[:, 0], 1e-20)
-            a = acc[:] / l[:, None]
-            if ragged:
-                o_ref[0] = a.reshape(s_q, nq, dv).astype(o_ref.dtype)
-            else:
-                o_ref[0] = a.reshape(nq, dv).astype(o_ref.dtype)
+            o_ref[0] = finish(
+                acc[...] / jnp.maximum(l_scr[...], 1e-20)
+            ).reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
     return kernel
+
+
+def _tile_of(page_refs, scale_refs=None):
+    """A step's pages [1, bs, ...] as one tile [pages*bs, ...], with
+    their scale pages dequantized in-register."""
+    def joined(refs):
+        x = jnp.concatenate([r[...] for r in refs], axis=0)
+        return x.reshape((-1,) + x.shape[2:])
+    x = joined(page_refs)
+    return x if scale_refs is None else _dequant_block(x, joined(scale_refs))
+
+
+def _dense_tile(spec: PagedSpec):
+    """The dense body's part of the walk: K and V pages [bs, Hkv, D]
+    (int8/fp8 pools: their scale pages [bs, Hkv] beside them). Rows are
+    [Hkv, S_q*group] with inner index i = s*group + g, held that way
+    from a slot's first tile to its last: the [S_q, Hq] layout is left
+    in `prep` and entered again in `finish` only."""
+    hkv, group, s_q = spec.hkv, spec.group, spec.s_q
+    rows = s_q * group
+
+    def prep(q_ref):
+        q = q_ref[0].astype(jnp.float32) * spec.scale
+        d = q.shape[-1]
+        if s_q == 1:
+            return q.reshape(hkv, group, d)
+        return jnp.transpose(q.reshape(s_q, hkv, group, d),
+                             (1, 0, 2, 3)).reshape(hkv, rows, d)
+
+    def scores(q3, tiles):
+        k_refs, v_refs, *scale_refs = tiles
+        ks_refs, vs_refs = scale_refs or (None, None)
+        k3 = jnp.swapaxes(_tile_of(k_refs, ks_refs), 0, 1)  # [Hkv, w, D]
+        s = jax.lax.dot_general(                          # [Hkv, rows, w]
+            q3.astype(k3.dtype), k3,
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+        def values(p):
+            v3 = jnp.swapaxes(_tile_of(v_refs, vs_refs), 0, 1)
+            return jax.lax.dot_general(                   # [Hkv, rows, D]
+                p.astype(v3.dtype), v3,
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+
+        return s, values
+
+    def finish(a):
+        d = a.shape[-1]
+        if s_q > 1:
+            a = jnp.transpose(a.reshape(hkv, s_q, group, d), (1, 0, 2, 3))
+        return a                                  # [(S_q,) Hkv, group, D]
+
+    return 1, prep, lambda r: r // group, scores, finish
+
+
+def _latent_tile(spec: PagedSpec):
+    """The MLA latent body's part of the walk (ISSUE 17): the pool pages
+    are the COMPRESSED run ([bs, klat] latent + [bs, dpe] roped shared
+    key, no per-head axis; quantized pools: a per-ROW scalar scale [bs]
+    each) and the score contraction runs directly in latent space: the
+    caller absorbs q_nope through kv_up's k_nope columns, so tile scores
+    are q_lat · latent^T + q_pe · k_pe^T. The value path re-expands THIS
+    tile's v rows in-register (dequantized latent tile × kv_up's v
+    columns, which arrive as [klat, nq*dv]: head h's value rows are
+    columns [h*dv, (h+1)*dv) of the product); a dense
+    [B, S_kv, nq, dqk+dv] reconstitution never materializes. Rows are
+    nq * s_q with row = h*s_q + s (group == 1: every head shares the
+    latent row, so no GQA fold)."""
+    nq, s_q = spec.hkv, spec.s_q
+    klat, dpe, dv = spec.klat, spec.dpe, spec.dv
+    rows = nq * s_q
+
+    def prep(ql_ref, qp_ref):
+        def head_major(ref, d):
+            q = ref[0].astype(jnp.float32)
+            if s_q > 1:
+                q = jnp.swapaxes(q, 0, 1)                 # [nq, s_q, d]
+            return q.reshape(rows, d) * spec.scale
+        return head_major(ql_ref, klat), head_major(qp_ref, dpe)
+
+    def scores(qs, tiles, wv_ref):
+        ql, qp = qs
+        lat_refs, pe_refs, *scale_refs = tiles
+        ls_refs, ps_refs = scale_refs or (None, None)
+        lat = _tile_of(lat_refs, ls_refs)                 # [w, klat]
+        pe = _tile_of(pe_refs, ps_refs)                   # [w, dpe]
+        nt = (((1,), (1,)), ((), ()))                     # a · b^T
+        s = (jax.lax.dot_general(ql.astype(lat.dtype), lat, nt,  # [rows, w]
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qp.astype(pe.dtype), pe, nt,
+                                   preferred_element_type=jnp.float32))
+
+        def values(p):
+            v = jnp.dot(lat, wv_ref[...].astype(lat.dtype),  # [w, nq*dv]
+                        preferred_element_type=jnp.float32)
+            p3 = p.reshape(nq, s_q, -1)
+            return jnp.concatenate([                       # [rows, dv]
+                jnp.dot(p3[h], v[:, h * dv:(h + 1) * dv],
+                        preferred_element_type=jnp.float32)
+                for h in range(nq)], axis=0)
+
+        return s, values
+
+    def finish(a):
+        if s_q > 1:
+            a = jnp.swapaxes(a.reshape(nq, s_q, dv), 0, 1)
+        return a                                  # [(S_q,) nq, dv]
+
+    return 2, prep, lambda r: r % s_q, scores, finish
+
+
+def _walk_steps(page_table, kv_lens, bs: int, pages: int):
+    """The grid steps of a walk, from the table and the lengths.
+
+    Slot b takes cdiv(kv_lens[b], pages*bs) steps of `pages` pages (one,
+    to write its zeros, if it holds no row; never more than its table
+    row can name). → their count, and for every step g up to the most
+    there can be: slot_of[g]; step_of[g], its index within the slot; and
+    block_of[g*pages + p], the pool block of its p-th page. A slot's
+    steps are consecutive. A page a partial last step does not have
+    names the block its operand held a step earlier (the pipeline then
+    copies nothing), the slot's last page if the slot has no earlier
+    step, block 0 if the slot holds nothing: never a table entry past
+    the slot's length. Entries past the count repeat the last slot (no
+    grid step reads them)."""
+    b, max_blocks = page_table.shape
+    max_steps = -(-max_blocks // pages)
+    kv_lens = kv_lens.astype(jnp.int32)
+    n = jnp.clip(-(-kv_lens // (pages * bs)), 1, max_steps)
+    ends = jnp.cumsum(n)
+    g = jnp.arange(b * max_steps, dtype=jnp.int32)
+    before = g[:, None] >= ends[None, :]        # slots wholly before step g
+    slot_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    step_of = g - jnp.sum(jnp.where(before, n[None, :], 0), axis=1,
+                          dtype=jnp.int32)
+    held = jnp.minimum(-(-kv_lens // bs), max_blocks)[slot_of][:, None]
+    first = step_of[:, None] == 0
+    page = step_of[:, None] * pages + jnp.arange(pages, dtype=jnp.int32)
+    page = jnp.where(page < held, page,
+                     jnp.where(first, held - 1, page - pages))
+    block_of = jnp.where(
+        held > 0, page_table[slot_of[:, None], jnp.maximum(page, 0)], 0)
+    return ends[-1], slot_of, step_of, block_of.reshape(-1)
+
+
+def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
+               queries, pools, consts, out_shape, acc_shape):
+    """The one `pallas_call` of the paged family. Scalar-prefetched: the
+    layer id, the lengths and the walk's step maps (ragged: q_lens
+    last). A slot's query block and its output block follow ``slot_of``;
+    each of a step's ``spec.pages`` pages is a block [1, bs, ...] of the
+    STACKED pool [L, NB, bs, ...], named by ``block_of``."""
+    pages = spec.pages
+    total, slot_of, step_of, block_of = _walk_steps(
+        page_table, kv_lens, spec.block_size, pages)
+
+    def slot_block(shape):
+        rest = (0,) * (len(shape) - 1)
+        return pl.BlockSpec((1,) + tuple(shape[1:]),
+                            lambda g, l, n, slot, *_: (slot[g],) + rest)
+
+    def page_block(pool, p):
+        rest = (0,) * (pool.ndim - 2)
+        return pl.BlockSpec(
+            (None, 1) + tuple(pool.shape[2:]),
+            lambda g, l, n, slot, step, block, *_:
+            (l[0], block[g * pages + p]) + rest)
+
+    prefetch = [lid, kv_lens, slot_of, step_of, block_of]
+    if spec.ragged:
+        prefetch.append(q_lens)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(total,),
+        in_specs=([slot_block(q.shape) for q in queries]
+                  + [page_block(pool, p) for pool in pools
+                     for p in range(pages)]
+                  + [pl.BlockSpec(c.shape, lambda *_, n=c.ndim: (0,) * n)
+                     for c in consts]),
+        out_specs=slot_block(out_shape),
+        scratch_shapes=[
+            pltpu.VMEM(acc_shape, jnp.float32),
+            pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32),
+            pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32)],
+    )
+    return pl.pallas_call(
+        emit_paged_kernel(spec), grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, queries[0].dtype),
+        interpret=_interpret(), name=name,
+    )(*(a.astype(jnp.int32) for a in prefetch), *queries,
+      *(pool for pool in pools for _ in range(pages)), *consts)
+
+
+def _call_pools(pages, scales, table_blocks: int):
+    """(a call's pools in the kernel's order, what the spec takes from
+    them): the two stacked page pools [L, NB, bs, ...], then their scale
+    pools where the pages are quantized (scales not None), and the
+    spec's quant_dtype, kv_tile and pages a step."""
+    quant_dtype = None
+    if scales[0] is not None:
+        quant_dtype = quant_dtype_of(pages[0].dtype)
+        if quant_dtype is None:
+            raise ValueError(
+                f"scales passed but page dtype {pages[0].dtype} is not a "
+                f"registered quantized storage format "
+                f"({sorted(QUANT_DTYPES)})")
+        pages = pages + scales
+    kv_tile = default_kv_tile(quant_dtype)
+    return pages, dict(
+        quant_dtype=quant_dtype, kv_tile=kv_tile,
+        pages=pages_per_step(pages[0].shape[2],
+                             _pages_vmem_bytes(pages, kv_tile),
+                             table_blocks))
 
 
 def _stacked(layer, *pools):
@@ -528,69 +602,17 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     dv = w_v.shape[-1]
     bs = lat_pages.shape[2]
     mb = page_table.shape[1]
-    quantized = lat_scales is not None
-    quant_dtype = quant_dtype_of(lat_pages.dtype) if quantized else None
-    if quantized and quant_dtype is None:
-        raise ValueError(
-            f"scales passed but latent page dtype {lat_pages.dtype} is "
-            f"not a registered quantized storage format "
-            f"({sorted(QUANT_DTYPES)})")
-    spec = PagedSpec(ragged=ragged, quant_dtype=quant_dtype, s_q=s_q,
-                     block_size=bs, num_blocks_seq=mb, hkv=nq, group=1,
-                     scale=float(softmax_scale),
-                     kv_tile=default_kv_tile(quant_dtype),
-                     latent=True, klat=klat, dpe=dpe, dv=dv)
-    kernel = emit_paged_kernel(spec)
-
-    lat_spec = pl.BlockSpec((None, 1, bs, klat),
-                            lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
-    pe_spec = pl.BlockSpec((None, 1, bs, dpe),
-                           lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
-    if ragged:
-        ql_spec = pl.BlockSpec((1, s_q, nq, klat),
-                               lambda b_, j, *_: (b_, 0, 0, 0))
-        qp_spec = pl.BlockSpec((1, s_q, nq, dpe),
-                               lambda b_, j, *_: (b_, 0, 0, 0))
-        o_spec = pl.BlockSpec((1, s_q, nq, dv),
-                              lambda b_, j, *_: (b_, 0, 0, 0))
-        out_shape = (b, s_q, nq, dv)
-    else:
-        ql_spec = pl.BlockSpec((1, nq, klat),
-                               lambda b_, j, *_: (b_, 0, 0))
-        qp_spec = pl.BlockSpec((1, nq, dpe),
-                               lambda b_, j, *_: (b_, 0, 0))
-        o_spec = pl.BlockSpec((1, nq, dv), lambda b_, j, *_: (b_, 0, 0))
-        out_shape = (b, nq, dv)
-    in_specs = [ql_spec, qp_spec, lat_spec, pe_spec]
-    operands = [q_lat, q_pe, lat_pages, pe_pages]
-    if quantized:
-        sc_spec = pl.BlockSpec((None, 1, bs),
-                               lambda b_, j, l, t, *_: (l[0], t[b_, j], 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [lat_scales, pe_scales]
-    in_specs.append(pl.BlockSpec(w_v.shape, lambda b_, j, *_: (0, 0, 0)))
-    operands.append(w_v)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if ragged else 3,
-        grid=(b, mb),
-        in_specs=in_specs,
-        out_specs=o_spec,
-        scratch_shapes=[
-            pltpu.VMEM((s_q * nq, dv), jnp.float32),
-            pltpu.VMEM((s_q * nq, 1), jnp.float32),
-            pltpu.VMEM((s_q * nq, 1), jnp.float32),
-        ],
-    )
-    prefetch = [lid, page_table.astype(jnp.int32),
-                kv_lens.astype(jnp.int32)]
-    if ragged:
-        prefetch.append(q_lens.astype(jnp.int32))
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, q_lat.dtype),
-        interpret=_interpret(),
-        name=_paged_name(ragged, quant_dtype, "_latent"),
-    )(*prefetch, *operands)
+    pools, by_pools = _call_pools(
+        [lat_pages, pe_pages], [lat_scales, pe_scales], mb)
+    spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
+                     num_blocks_seq=mb, hkv=nq, group=1,
+                     scale=float(softmax_scale), latent=True, klat=klat,
+                     dpe=dpe, dv=dv, **by_pools)
+    return _walk_call(
+        spec, _paged_name(ragged, spec.quant_dtype, "_latent"), lid,
+        page_table, kv_lens, q_lens, [q_lat, q_pe], pools,
+        [w_v.reshape(klat, nq * dv)],
+        q_lat.shape[:-1] + (dv,), (s_q * nq, dv))
 
 
 def _latent_block_scores(q, pages, page_table, kv_lens, lid, scales=None,
@@ -879,61 +901,14 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     mb = page_table.shape[1]
     if softmax_scale is None:
         softmax_scale = 1.0 / (d ** 0.5)
-    quantized = k_scales is not None
-    quant_dtype = quant_dtype_of(k_pages.dtype) if quantized else None
-    if quantized and quant_dtype is None:
-        raise ValueError(
-            f"scales passed but page dtype {k_pages.dtype} is not a "
-            f"registered quantized storage format "
-            f"({sorted(QUANT_DTYPES)})")
-    spec = PagedSpec(ragged=ragged, quant_dtype=quant_dtype, s_q=s_q,
-                     block_size=bs, num_blocks_seq=mb, hkv=hkv,
-                     group=hq // hkv, scale=float(softmax_scale),
-                     kv_tile=default_kv_tile(quant_dtype))
-
-    kernel = emit_paged_kernel(spec)
-
-    # Page-table indirection: the layer id, the table and per-slot
-    # lengths (and ragged q_lens) are scalar-prefetched so the index maps
-    # can DMA block t[b, j] of layer l straight from the stacked pool in
-    # HBM — int8 scale blocks ride the same map.
-    kv_spec = pl.BlockSpec(
-        (None, 1, bs, hkv, d),
-        lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0, 0))
-    if ragged:
-        q_spec = pl.BlockSpec((1, s_q, hq, d),
-                              lambda b_, j, *_: (b_, 0, 0, 0))
-    else:
-        q_spec = pl.BlockSpec((1, hq, d), lambda b_, j, *_: (b_, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [q, k_pages, v_pages]
-    if quantized:
-        sc_spec = pl.BlockSpec(
-            (None, 1, bs, hkv),
-            lambda b_, j, l, t, *_: (l[0], t[b_, j], 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if ragged else 3,
-        grid=(b, mb),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((s_q * hq, d), jnp.float32),
-            pltpu.VMEM((s_q * hq, 1), jnp.float32),
-            pltpu.VMEM((s_q * hq, 1), jnp.float32),
-        ],
-    )
-    prefetch = [lid, page_table.astype(jnp.int32),
-                kv_lens.astype(jnp.int32)]
-    if ragged:
-        prefetch.append(q_lens.astype(jnp.int32))
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
-        name=_paged_name(ragged, quant_dtype),
-    )(*prefetch, *operands)
+    pools, by_pools = _call_pools(
+        [k_pages, v_pages], [k_scales, v_scales], mb)
+    spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
+                     num_blocks_seq=mb, hkv=hkv, group=hq // hkv,
+                     scale=float(softmax_scale), **by_pools)
+    return _walk_call(spec, _paged_name(ragged, spec.quant_dtype), lid,
+                      page_table, kv_lens, q_lens, [q], pools, [], q.shape,
+                      (hkv, s_q * (hq // hkv), d))
 
 
 def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,

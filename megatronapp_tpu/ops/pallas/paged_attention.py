@@ -4,9 +4,11 @@ vLLM-style paged KV serving ("Ragged Paged Attention", arXiv 2604.15464,
 PAPERS.md): the decode cache lives in a shared block pool shaped
 [num_blocks, block_size, Hkv, D]; each slot owns an ordered page table of
 block ids, and one query token per active slot gathers K/V through its
-table with an online softmax over VALID blocks only — no slot pays for
-another slot's length, and admission is per-block instead of per-S_max
-row (inference/paged_cache.py is the allocator).
+table with an online softmax over the blocks it HOLDS, several pages a
+step (kernel_gen.emit_paged_kernel: the grid runs over the call's real
+steps, not over what the table could hold) — no slot pays for another
+slot's length or for the table's width, and admission is per-block
+instead of per-S_max row (inference/paged_cache.py is the allocator).
 
 The kernel BODIES live in ops/pallas/kernel_gen.py (ISSUE 11): one
 dtype/shard/raggedness-parameterized generator emits the decode and
@@ -15,8 +17,9 @@ module used to carry (decode / multiquery × plain / tp, each × bf16 /
 int8) are deleted; the public names below are thin dispatchers over one
 layer's pool, kept for the tests and tools that call a kernel alone (the
 layer bodies call kernel_gen.paged_attention on the stacked pool). The
-emitted bodies are bitwise-identical to the legacy variants (pinned in
-tests/test_kernel_gen.py).
+emitted kernels are the legacy variants' mathematics folded a tile of
+several pages at a time (pinned in tests/test_kernel_gen.py: bitwise to a
+replay of the walk, allclose to the frozen legacy bodies).
 
 This module keeps what is NOT kernel-body generation: the jnp parity
 oracles, the quantization helper (`quantize_kv_rows` — symmetric
@@ -36,8 +39,8 @@ block pool and does 1/tp of the attention FLOPs/bytes.
 Quantized KV (ISSUE 10, `k_scales`/`v_scales`): pools may be stored int8
 with a per-(row, kv-head) fp32 scale pool [NB, bs, Hkv] alongside — rows
 quantize independently on insert (`quantize_kv_rows`), so CoW copies,
-rewind, and stale-row overwrites need no re-scaling. The scale blocks
-ride the SAME scalar-prefetched page-table indirection as the KV blocks
+rewind, and stale-row overwrites need no re-scaling. The scale pages
+ride the SAME scalar-prefetched page-table indirection as the KV pages
 and dequantize in-register; no bf16 pool is ever materialized.
 """
 

@@ -12,6 +12,7 @@ such file could land on another xdist worker and skip itself. The topology
 is described inside a module-scoped fixture, never at import.
 """
 
+import functools
 import re
 
 import jax
@@ -92,25 +93,56 @@ def test_flash_attention(one_chip, chip_compile, shape, backward):
     assert _custom_calls(compiled) >= (3 if backward else 1)
 
 
+# (batch, query heads, kv heads, head dim, pool blocks, table blocks) of a
+# dense cell; (batch, heads, latent, roped-key and value columns, pool
+# blocks, table blocks) of a latent one. Blocks of 16 rows.
+PAGED_SHAPES = {
+    "gpt2-125m": (4, 12, 12, 64, 1024, 64),
+    # serve.gpt3-2.7b.batch-closed: D 80 (padded to 128 lanes), 24 slots,
+    # 2048 positions
+    "gpt3-2.7b": (24, 32, 32, 80, 896, 128),
+}
+LATENT_SHAPES = {
+    # serve.deepseek-v2-lite.longgen-closed: rows of 512 + 64 columns, 32
+    # slots, 4096 positions
+    "deepseek-v2-lite": (32, 16, 512, 64, 128, 8192, 256),
+}
+
+
 @pytest.mark.parametrize("q_len", [1, 32], ids=["decode", "multiquery-32"])
-def test_paged_attention(one_chip, chip_compile, q_len):
-    """GPT-2 125M head shapes (Hq 12 / Hkv 12 / D 64), bf16 pool of 1024
-    blocks of 16, batch 4, up to 1024 cached positions a row."""
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES) + list(LATENT_SHAPES))
+def test_paged_attention(one_chip, chip_compile, shape, q_len):
+    """The paged kernels at the cells' shapes, bf16 pools of blocks of 16:
+    one query a slot, and the [1, 32] ragged call of a chunked prefill."""
+    from megatronapp_tpu.ops.pallas import kernel_gen as kg
     from megatronapp_tpu.ops.pallas.paged_attention import (
         paged_attention_decode, paged_attention_multiquery,
     )
-    b, hq, hkv, d, nb, bs = 4, 12, 12, 64, 1024, 16
-    pages = _sds((nb, bs, hkv, d), jnp.bfloat16, one_chip)
-    table = _sds((b, 1024 // bs), jnp.int32, one_chip)
-    lens = _sds((b,), jnp.int32, one_chip)
-    if q_len == 1:
-        q = _sds((b, hq, d), jnp.bfloat16, one_chip)
-        compiled = jax.jit(paged_attention_decode).lower(
-            q, pages, pages, table, lens).compile()
+    bs = 16
+    bf16 = functools.partial(_sds, dtype=jnp.bfloat16, sharding=one_chip)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    if shape in LATENT_SHAPES:
+        b, nq, klat, dpe, dv, nb, mb = LATENT_SHAPES[shape]
+        b = b if q_len == 1 else 1
+        lead = (b,) if q_len == 1 else (b, q_len)
+        fn = functools.partial(kg.paged_attention_latent,
+                               softmax_scale=(128 + dpe) ** -0.5)
+        args = (bf16(lead + (nq, klat)), bf16(lead + (nq, dpe)),
+                bf16((nb, bs, klat)), bf16((nb, bs, dpe)), i32((b, mb)),
+                i32((b,)), bf16((klat, nq, dv)))
+        args += () if q_len == 1 else (i32((b,)),)
     else:
-        q = _sds((b, q_len, hq, d), jnp.bfloat16, one_chip)
-        compiled = jax.jit(paged_attention_multiquery).lower(
-            q, pages, pages, table, lens, lens).compile()
+        b, hq, hkv, d, nb, mb = PAGED_SHAPES[shape]
+        b = b if q_len == 1 else 1
+        pages = bf16((nb, bs, hkv, d))
+        if q_len == 1:
+            fn = paged_attention_decode
+            args = (bf16((b, hq, d)), pages, pages, i32((b, mb)), i32((b,)))
+        else:
+            fn = paged_attention_multiquery
+            args = (bf16((b, q_len, hq, d)), pages, pages, i32((b, mb)),
+                    i32((b,)), i32((b,)))
+    compiled = jax.jit(fn).lower(*args).compile()
     assert _custom_calls(compiled) == 1
 
 
@@ -301,6 +333,58 @@ def test_engine_paged_steps(one_chip, chip_compile, which, kv):
         compiled, r"copy|dynamic[-_](update[-_])?slice", kv_shapes)
 
 
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+@pytest.mark.parametrize("model,batch,blocks,seq", [
+    ("gpt3-2.7b", 24, 896, 2048),
+    ("deepseek-v2-lite", 32, 8192, 4096),
+])
+def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
+                                           batch, blocks, seq, which):
+    """The same two jits at the serving cells' attention shapes (depth cut
+    to 2, a small vocabulary and 4 experts: neither reaches a paged
+    kernel): D 80 over a table of 128 blocks, and latent rows of 512 + 64
+    columns over a table of 256. The weights and the pool go in as
+    abstract values; the step still aliases its pools."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    over = dict(num_layers=2, vocab_size=1024)
+    if model == "deepseek-v2-lite":
+        over.update(num_moe_experts=8, max_position_embeddings=seq)
+    cfg = PRESETS[model](**over)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
+                                 max_seq_len=seq, paged=True, num_blocks=8)
+
+    def spec(a):
+        return _sds(a.shape, a.dtype, one_chip)
+
+    pages = tuple(_sds((cfg.num_layers, blocks) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages)
+    mb = eng.pool.page_table.shape[1]
+    assert mb == seq // 16
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(spec, abstract)
+    if which == "decode":
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pages, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None).compile()
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pages, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None).compile()
+    family = "paged_decode" if which == "decode" else "paged_mq"
+    _assert_kernels_named(
+        compiled, family + ("_latent" if cfg.multi_latent_attention else ""))
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pages)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
 # ---------------------------------------------------------------------------
 # Kernel names (ISSUE 26): a device trace names an event by its HLO
 # instruction, and perfbench's readers tell kernels apart by family prefix
@@ -354,6 +438,16 @@ def _compile_family(family, one_chip):
         return jax.jit(kg.paged_append).lower(
             bf16(2, 256, 16, 12, 64), bf16(24, 12, 64), i32(), i32(24),
             i32(24)).compile()
+    if family.endswith("_latent"):
+        b, nq, klat, dpe, dv, nb, bs = 4, 16, 512, 64, 128, 256, 16
+        lead = (b,) if family == "paged_decode_latent" else (b, 32)
+        args = (bf16(*lead, nq, klat), bf16(*lead, nq, dpe),
+                bf16(nb, bs, klat), bf16(nb, bs, dpe), i32(b, 16), i32(b),
+                bf16(klat, nq, dv))
+        args += () if family == "paged_decode_latent" else (i32(b),)
+        return jax.jit(functools.partial(
+            kg.paged_attention_latent, softmax_scale=0.07)).lower(
+                *args).compile()
     if family.startswith("paged"):
         b, h, d, nb, bs = 4, 12, 64, 256, 16
         pages, table, lens = bf16(nb, bs, h, d), i32(b, 16), i32(b)
@@ -386,6 +480,8 @@ def _compile_family(family, one_chip):
     ("flash_bwd_d128", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     ("paged_decode", ["paged_decode"]),
     ("paged_mq", ["paged_mq"]),
+    ("paged_decode_latent", ["paged_decode_latent"]),
+    ("paged_mq_latent", ["paged_mq_latent"]),
     ("paged_append", ["paged_append"]),
     ("fused", ["fused_"]),
 ])

@@ -352,6 +352,38 @@ class TestStepSpansAndCounters:
         # No span of the program is named outside the interface.
         assert {n.split(".")[1] for n in by_name} <= {"engine"}
 
+    def test_paged_walk_counters(self, monkeypatch):
+        """ISSUE 29: a plain decode round names the blocks its paged
+        kernel walks (`kv_blocks` beside `kv_tokens`: the round appends a
+        row and reads it), and `stats_snapshot()["paged"]` sums them
+        against what the running slots' table rows could name."""
+        eng = _pressure_engine()
+        bs, table = eng.pool.block_size, eng.pool.page_table.shape[1]
+        rounds = []
+        span = eng._span
+
+        def spy(name, *a, **kw):
+            if name == "engine.decode_round":
+                lens = [int(eng.lengths[r.slot]) for r in eng.slots
+                        if r is not None and not r.finished]
+                rounds.append((kw, lens))
+            return span(name, *a, **kw)
+
+        monkeypatch.setattr(eng, "_span", spy)
+        while eng.has_work:
+            eng.step()
+        assert rounds
+        for kw, lens in rounds:
+            assert kw["batch"] == len(lens)
+            assert kw["kv_tokens"] == sum(lens)
+            assert kw["kv_blocks"] == sum(-(-(n + 1) // bs) for n in lens)
+        paged = eng.stats_snapshot()["paged"]
+        assert paged == {
+            "decode_rounds": len(rounds),
+            "blocks_live": sum(kw["kv_blocks"] for kw, _ in rounds),
+            "blocks_table": sum(kw["batch"] for kw, _ in rounds) * table}
+        assert 0 < paged["blocks_live"] <= paged["blocks_table"]
+
     def test_step_counters(self):
         eng = _pressure_engine()
         calls, admitting, prompt_tokens = 0, set(), 0
