@@ -68,38 +68,11 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "MLA latent/pe pools quantize with per-row "
                         "scalar scales; quantized pools cost "
                         "~(D+4)/2D of the bf16 bytes)")
-    g.add_argument("--megakernel-decode", action="store_true",
-                   help="fused (megakernel) decode step (ISSUE 11/16, "
-                        "ops/pallas/kernel_gen.py): the per-token layer "
-                        "body runs as fat Pallas kernels around the "
-                        "paged-attention kernel instead of the "
-                        "~15-fusion unfused tail (needs --engine "
-                        "dynamic --paged-kv-cache; streams stay "
-                        "token-exact). Large H/FFN shapes grid-tile "
-                        "their weight columns to fit "
-                        "--megakernel-vmem-budget; resident "
-                        "--quantized-weights dequantize in-register; "
-                        "speculative verify and chunked prefill run "
-                        "the fused ragged step; composes with "
-                        "--serve-disagg and --serve-fleet; MLA runs "
-                        "the fused latent prologue + absorbed-q latent "
-                        "kernel. Ineligible configs (MoE, --serve-tp>1, "
-                        "MegaScope hooks) keep the unfused step with a "
-                        "logged reason")
-    g.add_argument("--megakernel-vmem-budget", type=int, default=None,
-                   metavar="BYTES",
-                   help="per-kernel operand budget (bytes) for the "
-                        "fused decode kernels — tile counts are chosen "
-                        "as the smallest grid that fits it (default: "
-                        "MEGAKERNEL_VMEM_BUDGET env or 12 MiB; values "
-                        "above ~16 MiB/core exceed real TPU VMEM and "
-                        "are warned). The fallback log names this flag "
-                        "when even the finest tiling cannot fit")
     g.add_argument("--scan-unroll", type=int, default=1,
                    help="lax.scan unroll factor for the layer stack "
                         "(PERF.md lever #3): unrolls the training "
                         "layer scan AND the serving decode/multi-query "
-                        "step scans — pairs with --megakernel-decode")
+                        "step scans")
     g.add_argument("--quantized-weights", action="store_true",
                    help="serve from int8 weights kept RESIDENT (per-"
                         "channel dequant fused at matmul entry, param "
@@ -311,22 +284,6 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
             mla=multi_latent_attention)
     except ValueError as e:
         raise SystemExit(str(e))
-    if getattr(args, "megakernel_decode", False):
-        if getattr(args, "engine", "static") != "dynamic":
-            raise SystemExit(
-                "--megakernel-decode requires --engine dynamic (the "
-                "fused step is the dynamic engine's decode body)")
-        if not getattr(args, "paged_kv_cache", False):
-            raise SystemExit(
-                "--megakernel-decode requires --paged-kv-cache (the "
-                "fused step is built around the paged-attention "
-                "kernel)")
-    budget = getattr(args, "megakernel_vmem_budget", None)
-    if budget is not None and budget <= 0:
-        raise SystemExit(
-            f"--megakernel-vmem-budget must be a positive byte count "
-            f"(got {budget}); the tiling planner divides weight "
-            "columns until each kernel's operands fit it")
     # Fleet serving (ISSUE 14): parse-time validation in the usual
     # first-failed-predicate style — each impossible combination gets
     # its own actionable message.

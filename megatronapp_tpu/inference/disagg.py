@@ -394,8 +394,7 @@ class DisaggServingEngine:
                  spec_k: int = 4, draft_params=None, draft_cfg=None,
                  idle_chunks_per_step: int = 4,
                  kv_cache_dtype: str = "bf16",
-                 prefill_devices: Optional[int] = None,
-                 fused_decode: bool = False):
+                 prefill_devices: Optional[int] = None):
         self.prefill_ctx, self.decode_ctx = split_serving_meshes(
             tp=tp, devices=devices, prefill_devices=prefill_devices)
         max_seq_len = max_seq_len or cfg.max_position_embeddings
@@ -404,18 +403,13 @@ class DisaggServingEngine:
             block_size=block_size,
             enable_prefix_caching=enable_prefix_caching,
             extra_slots=prefill_slots, kv_cache_dtype=kv_cache_dtype)
-        # fused_decode (--megakernel-decode) threads into the DECODE
-        # engine only — eligibility is re-checked per jit build there
-        # (a tp>1 decode sub-mesh keeps the unfused body with a logged
-        # reason); the prefill worker's bucketed dense prefill is not a
-        # decode-step shape and stays unfused.
         self.engine = DynamicInferenceEngine(
             params, cfg, tokenizer=tokenizer, max_batch=max_batch,
             max_seq_len=max_seq_len, prefill_buckets=prefill_buckets,
             paged=True, prefill_chunk=prefill_chunk,
             spec_method=spec_method, spec_k=spec_k,
             draft_params=draft_params, draft_cfg=draft_cfg,
-            ctx=self.decode_ctx, pool=pool, fused_decode=fused_decode)
+            ctx=self.decode_ctx, pool=pool)
         self.worker = PrefillWorker(
             params, cfg, pool, self.prefill_ctx, self.decode_ctx,
             prefill_chunk, prefill_buckets, max_seq_len)
@@ -461,12 +455,6 @@ class DisaggServingEngine:
     @property
     def paged(self) -> bool:
         return True
-
-    @property
-    def megakernel(self) -> bool:
-        """Whether the decode engine's fused (megakernel) step is live
-        (re-gated on every decode-jit build)."""
-        return self.engine.megakernel
 
     @property
     def has_work(self) -> bool:
@@ -863,7 +851,7 @@ class DisaggServingEngine:
         """Engine snapshot + the disagg section: per-queue depths, SLO
         attainment (histogram-backed percentiles, ISSUE 12), handoff
         accounting (the /stats payload). include_dispatch forwards to
-        the decode engine's compiled-dispatch accounting (ISSUE 11) —
+        the decode engine's launch counts (dispatch_stats) —
         the facade accepts the same kwarg as the plain engine, so the
         server no longer TypeError-falls-back to a dispatch-less
         snapshot."""

@@ -301,7 +301,7 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
 
 def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
                        cfg: TransformerConfig, max_seq_len: int, ctx=None,
-                       scales=None, fused: bool = False, lora=None):
+                       scales=None, lora=None):
     """One-token decode for every slot against the paged block pool.
 
     pages: ([L, NB, bs, Hkv, D], same) K/V pools (MLA: latent + k_pe
@@ -309,10 +309,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     positions; active [B] bool (inactive rows' writes are dropped and
     their outputs discarded). scales: ([L, NB, bs, Hkv] fp32, same) for
     an int8 pool — the step then quantizes the appended rows in-jit and
-    returns the updated scale pools alongside. fused: megakernel layer
-    body (ISSUE 11) — each layer runs the fused Pallas kernels
-    of kernel_gen.fused_layer_decode instead of the unfused op tail
-    (callers gate on megakernel_ineligible_reason; streams token-exact).
+    returns the updated scale pools alongside.
     lora: batched adapter deltas (inference/lora.py) — {"row_adapter":
     [B] int32 bank slots, "banks": {target: (a [L, slots, din, r],
     b [L, slots, r, dout])}}; the banks are sliced per layer by the
@@ -342,8 +339,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
             layer_p, hh, cfg, cos, sin, None, layer_id=lid,
             kv_cache=kv, cache_index=None,
             cache_positions=lengths, page_table=page_table,
-            active=active, ctx=ctx, kv_scales=kvs,
-            fused_decode=fused, lora=ll)
+            active=active, ctx=ctx, kv_scales=kvs, lora=ll)
 
     h, moe, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
                                            cfg, layer)
@@ -354,7 +350,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
 def _paged_multiquery_step(params, tokens, pages, page_table, starts,
                            q_lens, active, cfg: TransformerConfig,
                            max_seq_len: int, ctx=None, scales=None,
-                           fused: bool = False, lora=None):
+                           lora=None):
     """Ragged multi-token step against the paged pool — the UNIFIED
     prefill/decode primitive (speculative verify + chunked prefill).
 
@@ -364,9 +360,7 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     position starts[b] + i and attends the paged context plus the new
     tail causally. Returns (logits [B, S, V], hidden [B, S, H] pre-head,
     the pools, written in place as in _paged_decode_step) — hidden feeds
-    the MTP self-draft proposer. fused: run
-    each layer as kernel_gen.fused_layer_multiquery (megakernel verify/
-    chunked-prefill; callers gate on megakernel_ineligible_reason)."""
+    the MTP self-draft proposer."""
     b, s = tokens.shape
     positions = starts[:, None] + jnp.arange(s)[None, :]       # [B, S]
     positions = jnp.minimum(positions, max_seq_len - 1)
@@ -387,7 +381,7 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
             kv_cache=kv, cache_index=None,
             cache_positions=starts, page_table=page_table,
             active=active, chunk_counts=q_lens, ctx=ctx,
-            kv_scales=kvs, fused_decode=fused, lora=ll)
+            kv_scales=kvs, lora=ll)
 
     h, _, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
                                          cfg, layer)
@@ -528,7 +522,6 @@ class DynamicInferenceEngine:
                  draft_params=None, draft_cfg=None,
                  prefill_chunk: int = 32, ctx=None, pool=None,
                  kv_cache_dtype: str = "bf16",
-                 fused_decode: bool = False,
                  adapter_cache=None,
                  spill_host_mb: float = 0.0,
                  spill_watermark_blocks: int = 0):
@@ -731,22 +724,6 @@ class DynamicInferenceEngine:
             if self.proposer is not None:
                 self.spec_method = spec_method
 
-        # Megakernel decode (ISSUE 11): requested via fused_decode=True /
-        # --megakernel-decode; eligibility is re-checked on every jit
-        # build (reset_compilation re-gates after MegaScope hook
-        # toggles). Ineligible requests keep the unfused step with a
-        # loud log naming the SPECIFIC failed predicate.
-        self._fused_requested = bool(fused_decode)
-        self.megakernel = False
-        # Why it is off (None while it is on); _build_jits says, and the
-        # start-up line and /stats repeat it.
-        self.megakernel_off: Optional[str] = "needs the paged backend"
-        if fused_decode and not paged:
-            raise ValueError(
-                "fused_decode=True requires the paged backend (the "
-                "fused step is built around the paged-attention "
-                "kernel) — pass paged=True / --paged-kv-cache")
-
         # Trace counter for the unified multi-query step (chunked prefill
         # + speculative verify): increments ONLY when jax re-traces, so
         # tests can assert chunked prefill stops retracing per
@@ -754,19 +731,17 @@ class DynamicInferenceEngine:
         # plain decode step (the /stats jit-count satellite).
         self.mq_traces = 0
         self.decode_traces = 0
-        # Compiled decode-step dispatch accounting, cached per jit build
-        # (utils/dispatch.py; computed lazily — it costs one AOT
-        # compile at the engine's shapes).
+        # Launch counts of the traced decode step, cached per jit build
+        # (dispatch_stats(); computed lazily: one trace of the jaxpr).
         self._dispatch_stats = None
         self._build_jits()
         logger.info(self.startup_line())
 
     def startup_line(self) -> str:
         """What this engine runs, for the log and a server's banner."""
-        mk = "on" if self.megakernel else f"off: {self.megakernel_off}"
         return (f"dynamic engine: paged={self.paged}, max_batch="
                 f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
-                f"prefill_chunk={self.prefill_chunk}, megakernel {mk}")
+                f"prefill_chunk={self.prefill_chunk}")
 
     def _build_jits(self):
         cfg = self.cfg
@@ -784,42 +759,6 @@ class DynamicInferenceEngine:
             # attention_forward); otherwise the trace stays identical to
             # the single-device engine.
             step_ctx = self.ctx if self.tp_paged else None
-            # Megakernel decode eligibility (re-checked per build so
-            # MegaScope hook toggles + reset_compilation re-gate it).
-            self.megakernel = False
-            self.megakernel_off = ("not asked for (fused_decode / "
-                                   "--megakernel-decode)")
-            if cfg.is_moe:
-                # Known without asking: say it at start-up, not only to
-                # whoever passes the flag.
-                self.megakernel_off = \
-                    "MoE layers: expert dispatch is not fused yet"
-            if self._fused_requested:
-                from megatronapp_tpu.ops.pallas.kernel_gen import (
-                    megakernel_ineligible_reason,
-                )
-                # Tile plans are sized for the widest flattened row
-                # count any fused step sees: decode runs [B, 1],
-                # chunked prefill [1, prefill_chunk], speculative
-                # verify [B, K+1] — the mq rows flatten to B·S.
-                mq_rows = max(
-                    self.max_batch, self.prefill_chunk,
-                    self.max_batch * (self.spec_k + 1)
-                    if self.spec_method else 0)
-                reason = megakernel_ineligible_reason(
-                    cfg, batch=self.max_batch, tp_paged=self.tp_paged,
-                    params=self.params, mq_rows=mq_rows,
-                    lora_rank=(self.adapters.rank
-                               if self.adapters is not None else None))
-                self.megakernel_off = reason
-                if reason is None:
-                    self.megakernel = True
-                else:
-                    logger.warning(
-                        "megakernel decode requested but ineligible — "
-                        "keeping the unfused decode step: %s", reason)
-            fused = self.megakernel
-
             # The pools (`pages`, and `scales`: the int8 pool's fp32
             # scale-pool pair, None for bf16 pools — an empty pytree, so
             # the same signature serves both dtypes) are DONATED, and the
@@ -835,8 +774,7 @@ class DynamicInferenceEngine:
                 self.decode_traces += 1
                 return _paged_decode_step(p, t, pages, tbl, l, a, cfg,
                                           msl, ctx=step_ctx,
-                                          scales=scales, fused=fused,
-                                          lora=lora)
+                                          scales=scales, lora=lora)
 
             self._decode = _PoolStep(_decode_traced, n_lead=2)
 
@@ -847,7 +785,7 @@ class DynamicInferenceEngine:
                 return _paged_multiquery_step(p, t, pages, tbl, starts,
                                               qlens, act, cfg, msl,
                                               ctx=step_ctx, scales=scales,
-                                              fused=fused, lora=lora)
+                                              lora=lora)
 
             self._mq_step = _PoolStep(_mq_traced, n_lead=2)
             if self.spec_method:
@@ -2062,20 +2000,15 @@ class DynamicInferenceEngine:
 
     # ---- observability ----------------------------------------------------
     def dispatch_stats(self, force: bool = False) -> Optional[Dict]:
-        """Compiled decode-step dispatch accounting (ISSUE 11): lowers +
-        compiles the decode jit AOT at the engine's shapes and counts
-        executable fusions / custom-calls / while-loops per step
-        (utils/dispatch.py). Cached per jit build — the first call pays
-        one extra compile; /stats serves the cached value afterwards.
-        The megakernel fusion win is gated off THESE counts (the
-        compiled module), not wall time."""
+        """Launch counts of the traced decode step at the engine's
+        shapes (utils/dispatch.launch_stats): `kernels` is its
+        pallas_calls a step, scan bodies times their length. One trace
+        of the jaxpr, cached per jit build; nothing is compiled."""
         if self._dispatch_stats is not None and not force:
             return self._dispatch_stats
         if not self.paged:
             return None
-        from megatronapp_tpu.utils.dispatch import (
-            compiled_stats, launch_stats,
-        )
+        from megatronapp_tpu.utils.dispatch import launch_stats
         spec = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
             a.shape, a.dtype)
         p_spec = jax.tree.map(spec, self.params)
@@ -2090,33 +2023,12 @@ class DynamicInferenceEngine:
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.bool_),
                 jax.tree.map(spec, self._lora_args()))
         try:
-            # Gate metric: estimated kernel launches per executed step
-            # off the traced module (pallas_call == ONE TPU custom
-            # call; scan bodies × length; unroll credits loop steps).
             stats = launch_stats(self._decode, *args)
-            # Record metrics: what THIS backend actually compiled (on
-            # CPU the interpret-mode kernels inline into plain HLO) +
-            # the XLA cost-model totals.
-            stats["compiled"] = compiled_stats(self._decode, *args)
         except Exception as e:  # noqa: BLE001 — observability must not
-            # take the serving loop down with it (backend-specific
-            # lowering quirks degrade to a reported error).
+            # take the serving loop down with it.
             logger.warning("decode dispatch accounting failed: %s", e)
             stats = {"error": str(e)}
-        stats["megakernel"] = self.megakernel
-        stats["scan_unroll"] = self.cfg.scan_unroll
         self._dispatch_stats = stats
-        # MegaScan: the fusion win is a monitored metric — emit it into
-        # the trace stream when a tracer is configured.
-        try:
-            from megatronapp_tpu.trace.tracer import get_tracer
-            tr = get_tracer()
-            if getattr(tr, "enabled", False):
-                tr.instant("decode-dispatch", **{
-                    k: v for k, v in stats.items()
-                    if isinstance(v, (int, float, bool))})
-        except Exception:  # noqa: BLE001 — tracing is best-effort
-            pass
         return stats
 
     def stats_snapshot(self, include_dispatch: bool = False) -> Dict:
@@ -2127,9 +2039,9 @@ class DynamicInferenceEngine:
         decode rounds: blocks_live of blocks_table is the share of the
         page table's width that held rows (False on a dense-cache one).
 
-        include_dispatch=True adds the compiled decode-step dispatch
-        accounting (dispatch_stats; the first call pays one AOT compile
-        — /stats opts in, /healthz stays cheap)."""
+        include_dispatch=True adds the traced decode step's launch
+        counts (dispatch_stats; the first call traces the step once and
+        compiles nothing — /stats opts in, /healthz stays free of it)."""
         out = {
             "engine": "dynamic",
             "paged": self.paged,
@@ -2138,11 +2050,8 @@ class DynamicInferenceEngine:
             "waiting": len(self.waiting),
             "multiquery_traces": self.mq_traces,
             "decode_traces": self.decode_traces,
-            "megakernel": self.megakernel,
             "steps": self.step_stats.snapshot(),
         }
-        if self.megakernel_off:
-            out["megakernel_off"] = self.megakernel_off
         if self.cfg.is_moe:
             per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)
                          * self.cfg.num_moe_experts)

@@ -1140,8 +1140,8 @@ class FleetRouter:
     def stats_snapshot(self, include_dispatch: bool = False) -> Dict:
         """Fleet snapshot: aggregated pool + per-replica sections + the
         router's own accounting (the /stats payload; /healthz slims it).
-        include_dispatch forwards to replica 0 only — the dispatch
-        accounting is per-compiled-program, identical across replicas
+        include_dispatch forwards to replica 0 only — the launch counts
+        are those of one traced decode step, identical across replicas
         of one config."""
         live = [r for r in self.replicas if r.state != DEAD]
         agg_pool = {

@@ -26,8 +26,8 @@ subsystem:
   partition after every step.
 
 The device-side half — the segmented batched-LoRA GEMM with
-scalar-prefetched per-row adapter ids, its jnp oracle, the eager
-fallback, and the megakernel epilogues — lives in
+scalar-prefetched per-row adapter ids, its jnp oracle and the eager
+fallback — lives in
 ops/pallas/kernel_gen.py (``lora_delta`` and friends).
 
 Chaos site ``lora-load`` fires between the registry fetch and the bank

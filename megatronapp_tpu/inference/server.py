@@ -681,10 +681,11 @@ class TextGenerationServer:
     def stats_snapshot(self) -> dict:
         """Serving stats for GET /stats. Dynamic engines report their
         full snapshot (pool / speculation / batch occupancy — plus the
-        compiled decode-step dispatch accounting, ISSUE 11: /stats opts
-        into include_dispatch, whose FIRST call pays one AOT compile and
-        is cached after; /healthz keeps the cheap snapshot); static and
-        mamba engines report what exists for them."""
+        traced decode step's launch counts: /stats opts into
+        include_dispatch, whose first call traces the step once and is
+        cached after, and nothing is compiled; /healthz keeps the
+        snapshot without it); static and mamba engines report what
+        exists for them."""
         eng = self.engine
         if hasattr(eng, "stats_snapshot"):
             # Both the plain engine and the disagg facade accept

@@ -600,9 +600,7 @@ def _bwd_dkv_kernel_t(*refs, scale, causal,
 # group even (pair inside one group) or group == 1 with hkv even (kv
 # folds alongside q). Opt-in via flash_attention(head_fold=True) /
 # --flash-head-fold; grad parity vs the unfolded kernels is pinned
-# ≤ 1e-5 in tests/test_kernel_gen.py. No on-chip A/B yet (ROADMAP S2);
-# the CPU evidence is the fwd+bwd wall ratio + cost model in
-# tools/megakernel_benchmark.py.
+# ≤ 1e-5 in tests/test_kernel_gen.py. No on-chip A/B yet (ROADMAP S2).
 # ---------------------------------------------------------------------------
 
 

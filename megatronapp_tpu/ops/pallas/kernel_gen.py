@@ -1,4 +1,4 @@
-"""Paged-attention kernel GENERATOR + fused (megakernel) decode kernels.
+"""Paged-attention kernel GENERATOR, the pool writer and the batched-LoRA delta.
 
 ISSUE 11 tentpole. Before this module, ops/pallas/paged_attention.py
 hand-wrote four kernel variants (decode / multiquery × plain / tp) × two
@@ -33,44 +33,21 @@ copies of the old bodies across {bf16, int8} × {tp1, tp2} × {q_len 1,
 ragged} × {GQA, MHA}. New variants (fp8 pools, MLA latent layouts,
 token-tree masks) are parameters here, not new copies.
 
-The second half of the module is the FUSED DECODE STEP (megakernel
-direction, *Event Tensor* arXiv 2604.13327): at decode batch sizes the
-per-token step is dispatch-dominated (PERF.md: 35.7% MFU full-step vs
-63.6% one layer body), so the dispatch-heavy tail of the layer body is
-folded into three fat Pallas kernels —
-
-  - ``fused_qkv``      RMS/LayerNorm + QKV projection + (optional) QK
-                       layernorm + rope, one kernel per layer entry;
-  - ``fused_out_proj`` attention epilogue: GQA head-flatten + out
-                       projection + bias + residual add;
-  - ``fused_mlp``      pre-MLP norm + fc1 + activation (incl. gated) +
-                       fc2 + bias + residual add.
-
-``fused_layer_decode`` assembles them around the generated paged
-attention kernel; transformer/block.py dispatches it for the s == 1
-paged decode path when ``cfg.megakernel_decode`` is on
-(DynamicInferenceEngine(fused_decode=True) / --megakernel-decode).
-Greedy streams are pinned token-exact against the unfused engine; the
-win is gated off the COMPILED module (utils/dispatch.py counts
-executable fusions/custom-calls per decode step), not wall time: there
-is no on-chip wall number for it yet (PERF.md round-15, ROADMAP S4).
+After the emitter come ``paged_append`` (the in-place pool writer every
+paged step and prefill call shares) and the batched-LoRA delta kernels
+(``lora_segmented_delta`` / ``apply_lora_delta`` and their jnp oracle).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-import logging
 import math
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-logger = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
 
@@ -1059,1283 +1036,12 @@ def paged_append(pool: jnp.ndarray, rows: jnp.ndarray, layer,
 
 
 # ---------------------------------------------------------------------------
-# Fused (megakernel) decode-layer kernels
-#
-# One decode token's layer body is ~15 small XLA fusions (two norms, two
-# projection matmuls + biases, rope, GQA reshapes, out-proj, fc1/act/
-# fc2, two residual adds) — each a separate dispatch inside the scan
-# body. The kernels below fold that tail into fat single-program Pallas
-# kernels around the generated paged-attention kernel. Math is op-for-op
-# the unfused path's (same norm/rope/activation formulas, same
-# dtypes/casts), so greedy streams stay token-exact — pinned in
-# tests/test_kernel_gen.py. Shapes: decode x is [B, H] with B = a
-# handful of slots, so whole-operand (no-grid) kernels are the small-
-# shape fast path; when the operand set would blow the VMEM budget, the
-# SAME kernels re-emit with a grid over OUTPUT COLUMNS (kv-head groups
-# for QKV, H columns for out-proj/fc2, ffn columns for fc1). Column
-# tiling keeps the contraction dimension whole per tile, so every tiled
-# output column is BITWISE the no-grid one (an accumulator-carrying
-# contraction split would reorder the fp32 sums and break the stream
-# pins). Resident-quantized weights ({"qint8","qscale"} leaves) stay
-# int8 kernel operands and dequantize in-register at matmul entry —
-# exactly resolve_param's formula — so --quantized-weights and
-# --megakernel-decode stack.
-# ---------------------------------------------------------------------------
-
-# Per-kernel operand budget for the fused kernels: tile counts are
-# chosen as the smallest grid whose per-step operand blocks fit it.
-# Real TPU VMEM is ~16 MB/core; interpret mode (CPU) has no limit but
-# keeps the same gate so eligibility is platform-independent. The env
-# var seeds the initial default; serving entry points override it at
-# runtime via --megakernel-vmem-budget / set_megakernel_vmem_budget.
-MEGAKERNEL_VMEM_BUDGET = int(os.environ.get(
-    "MEGAKERNEL_VMEM_BUDGET", 12 * 1024 * 1024))
-
-_vmem_budget = MEGAKERNEL_VMEM_BUDGET
-
-# Above this, the per-kernel operand blocks cannot all be VMEM-resident
-# on today's chips (~16 MiB/core) — allowed (useful on CPU engines),
-# but warned, because on-chip the compiler would spill.
-_VMEM_BUDGET_WARN = 16 * 1024 * 1024
-
-
-def get_megakernel_vmem_budget() -> int:
-    """The active per-kernel operand budget (bytes) for the fused
-    decode kernels — tile planning and eligibility both read this."""
-    return _vmem_budget
-
-
-def set_megakernel_vmem_budget(nbytes) -> int:
-    """Set the per-kernel operand budget (--megakernel-vmem-budget).
-    Positive int; values above ~16 MiB/core exceed real TPU VMEM and
-    are warned (fine for CPU/interpret engines). Returns the value."""
-    global _vmem_budget
-    n = int(nbytes)
-    if n <= 0:
-        raise ValueError(
-            f"megakernel VMEM budget must be a positive byte count, "
-            f"got {nbytes}")
-    if n > _VMEM_BUDGET_WARN:
-        logger.warning(
-            "megakernel VMEM budget %d B exceeds ~16 MiB/core of real "
-            "TPU VMEM — fused kernels planned against it will spill "
-            "on-chip (harmless for CPU/interpret engines)", n)
-    _vmem_budget = n
-    return n
-
-
-def _weight_itemsize(leaf) -> int:
-    """Per-element bytes a weight operand costs in VMEM: resident-
-    quantized leaves ship their int8 buffer (scales are counted
-    separately by the tile planners)."""
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    if is_resident_leaf(leaf):
-        return 1
-    return jnp.dtype(leaf.dtype).itemsize if hasattr(leaf, "dtype") \
-        else jnp.dtype(jnp.float32).itemsize
-
-
-def _weight_operands(leaf):
-    """Kernel operand list for one weight leaf: [w] for a plain array,
-    [qint8, qscale] for a resident-quantized pair (dequantized
-    in-register by _dequant_weight)."""
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    if is_resident_leaf(leaf):
-        return [leaf["qint8"], leaf["qscale"]]
-    return [leaf]
-
-
-def _dequant_weight(w_ref, s_ref, cdt):
-    """Matmul-entry weight read. Plain: cast to compute dtype (the
-    no-grid kernels' original `w_ref[...].astype(cdt)`). Resident int8
-    (or fp8) with per-output-channel fp32 scales: the exact
-    resolve_param formula — int8 → fp32 × scale → compute dtype — so
-    fused streams stay token-exact vs the resident unfused engine."""
-    w = w_ref[...]
-    if s_ref is None:
-        return w.astype(cdt)
-    return (w.astype(jnp.float32) * s_ref[...]).astype(cdt)
-
-
-def _pick_grid(n_units, fixed_bytes, unit_bytes, budget, align=1):
-    """Smallest divisor T of `n_units` such that one grid step's
-    operands (fixed + per-unit × n_units/T) fit `budget`, preferring
-    tile widths that stay `align`-divisible (128-lane layouts). 1 means
-    the no-grid body fits; 0 means even one unit per tile does not."""
-    first = 0
-    for t in range(1, n_units + 1):
-        if n_units % t:
-            continue
-        per = n_units // t
-        if fixed_bytes + per * unit_bytes > budget:
-            continue
-        if not first:
-            first = t
-        if per % align == 0:
-            return t
-    return first
-
-
-def _qkv_tiles(h, nq, nkv, d, rows, wq_item, wkv_item, act_item,
-               q_scaled, kv_scaled, budget):
-    """Tile count for _fused_qkv: the grid unit is one kv-head GROUP
-    (its nq/nkv query heads + its K and V head), so GQA q/k/v column
-    blocks stay aligned. Byte math is shared with
-    megakernel_ineligible_reason — eligibility and emission cannot
-    drift."""
-    group = nq // nkv
-    unit = (group * h * d * wq_item + 2 * h * d * wkv_item
-            + (group + 2) * d * (4 + rows * act_item))
-    if q_scaled:
-        unit += group * d * 4
-    if kv_scaled:
-        unit += 2 * d * 4
-    fixed = rows * h * (act_item + 4)
-    return _pick_grid(nkv, fixed, unit, budget)
-
-
-def _out_tiles(h, nqd, rows, w_item, act_item, scaled, budget):
-    """Tile count for _fused_out_proj: the grid unit is one output (H)
-    column — full-nqd contraction per tile."""
-    unit = nqd * w_item + 2 * rows * act_item + 4 + (4 if scaled else 0)
-    fixed = rows * nqd * act_item
-    return _pick_grid(h, fixed, unit, budget, align=128)
-
-
-def _mlp_tiles(h, ffn, gated, rows, w1_item, w2_item, act_item,
-               s1, s2, budget):
-    """MLP plan: None = the whole norm+fc1+act+fc2+residual body fits
-    one no-grid kernel (the original fast path); otherwise (t1, t2) =
-    tile counts for the two-kernel split (fc1+activation over ffn
-    columns, then fc2+residual over H columns — the intermediate
-    y [rows, ffn] lives in compute dtype, which apply_activation
-    preserves, so the store/reload between the two kernels is lossless
-    vs the single-kernel body). A 0 in the tuple means even one column
-    per tile does not fit."""
-    gm = 2 if gated else 1
-    fc1_out = gm * ffn
-    whole = (h * fc1_out * w1_item + ffn * h * w2_item
-             + rows * (2 * h + fc1_out) * act_item)
-    if s1:
-        whole += fc1_out * 4
-    if s2:
-        whole += h * 4
-    if whole <= budget:
-        return None
-    unit1 = gm * (h * w1_item + 4) + rows * act_item + (gm * 4 if s1
-                                                        else 0)
-    fixed1 = rows * h * (act_item + 4)
-    t1 = _pick_grid(ffn, fixed1, unit1, budget)
-    unit2 = ffn * w2_item + 2 * rows * act_item + 4 + (4 if s2 else 0)
-    fixed2 = rows * ffn * act_item
-    t2 = _pick_grid(h, fixed2, unit2, budget, align=128)
-    return (t1, t2)
-
-
-def _rope_rows(x, cos, sin):
-    """Half-rotation RoPE on [B, H, D] rows with per-row tables
-    [B, half] — elementwise-identical to ops.rotary.apply_rope on the
-    [B, 1, H, D] decode shape (fp32 rotate, cast back)."""
-    half = cos.shape[-1]
-    rot = 2 * half
-    x_rot, x_pass = x[..., :rot], x[..., rot:]
-    x1, x2 = x_rot[..., :half], x_rot[..., half:]
-    c = cos[:, None, :]
-    s = sin[:, None, :]
-    out1 = x1.astype(jnp.float32) * c - x2.astype(jnp.float32) * s
-    out2 = x2.astype(jnp.float32) * c + x1.astype(jnp.float32) * s
-    out = jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
-    if x_pass.shape[-1]:
-        out = jnp.concatenate([out, x_pass], axis=-1)
-    return out
-
-
-def _full_spec(a):
-    """BlockSpec mapping the WHOLE array into every grid step."""
-    return pl.BlockSpec(a.shape, lambda i, _n=a.ndim: (0,) * _n)
-
-
-def _lora_epilogue(xv, a, b, out_dtype):
-    """In-kernel per-row LoRA delta (ISSUE 19 megakernel epilogue):
-    xv [B*, din] (the SAME block the base matmul consumed), per-row
-    gathered factors a [B*, din, rank] / b [B*, rank, dout] → the
-    fp32 two-step product (x_b @ A_b) @ B_b cast to out_dtype. Row-wise
-    by construction — batch composition cannot perturb a row's delta —
-    and an all-zero B factor contributes an exact +0.0."""
-    t = jnp.einsum("bi,bir->br", xv.astype(jnp.float32),
-                   a.astype(jnp.float32))
-    return jnp.einsum("br,bro->bo", t,
-                      b.astype(jnp.float32)).astype(out_dtype)
-
-
-def _fused_qkv(x, attn_p, cfg, cos, sin, tiles=None, lora=None):
-    """Norm + QKV projection + (optional) QK-layernorm + rope in ONE
-    kernel — the attention kernel's entry, fused.
-
-    Small shapes run the original no-grid body; when the whole operand
-    set would exceed get_megakernel_vmem_budget(), the kernel re-emits
-    with a grid over kv-head GROUPS: each grid step reads the full x
-    block plus 1/T of the Q/K/V weight columns (the packed KV weight is
-    passed twice — K block at column-block t, V block at t + T, valid
-    because nkv*D == T*(nkv_t*D)) and writes 1/T of the heads. The
-    contraction stays whole per tile, and the norm recomputes from the
-    full x block (row statistics are tile-independent), so tiled heads
-    are BITWISE the no-grid ones. Resident-quantized weights dequantize
-    in-register (_dequant_weight).
-
-    x [B*, H] (residual dtype; B* = decode batch rows, or B·S flattened
-    ragged rows for the fused multiquery step); returns (q, k, v) as
-    [B*, nq, D] / [B*, nkv, D] in compute dtype, exactly as the unfused
-    layer_forward → attention_forward prologue produces them. tiles:
-    test/tuning override of the planned tile count (must divide nkv).
-    lora: optional (aq, bq, akv, bkv) per-row adapter factors
-    ([B*, H, rk], [B*, rk, nq·D], [B*, H, rk], [B*, rk, 2·nkv·D]) —
-    the no-grid body grows a LoRA epilogue adding each row's delta to
-    its projections between matmul and bias (the exact unfused
-    placement); megakernel_ineligible_reason(lora_rank=) gates the
-    tiled emission off."""
-    from megatronapp_tpu.config.transformer_config import NormKind
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    from megatronapp_tpu.ops.normalization import apply_norm, rms_norm
-
-    b, h = x.shape
-    nq, nkv, d = (cfg.num_attention_heads, cfg.num_query_groups,
-                  cfg.head_dim)
-    group = nq // nkv
-    cdt = cfg.compute_dtype
-    eps = cfg.layernorm_epsilon
-    kind = cfg.normalization
-    has_ln_bias = kind == NormKind.layernorm
-    has_bias = "q_bias" in attn_p
-    has_rope = cos is not None
-    has_qk_ln = cfg.qk_layernorm
-
-    wq_leaf, wkv_leaf = attn_p["q_kernel"], attn_p["kv_kernel"]
-    q_res = is_resident_leaf(wq_leaf)
-    kv_res = is_resident_leaf(wkv_leaf)
-    t = tiles if tiles is not None else _qkv_tiles(
-        h, nq, nkv, d, b, _weight_itemsize(wq_leaf),
-        _weight_itemsize(wkv_leaf), jnp.dtype(cdt).itemsize,
-        q_res, kv_res, get_megakernel_vmem_budget())
-    if not t:
-        raise ValueError(
-            "fused QKV kernel exceeds the VMEM budget even at one "
-            "kv-head group per tile — megakernel_ineligible_reason "
-            "gates callers before tracing")
-    assert nkv % t == 0, f"qkv tile count {t} must divide nkv={nkv}"
-    has_lora = lora is not None
-    assert not (has_lora and t != 1), (
-        "LoRA epilogue rides the no-grid fused QKV body only — "
-        "megakernel_ineligible_reason(lora_rank=) gates callers")
-
-    if t == 1:
-        operands = [x, attn_p["ln1_scale"]]
-        if has_ln_bias:
-            operands.append(attn_p["ln1_bias"])
-        operands += _weight_operands(wq_leaf) + _weight_operands(wkv_leaf)
-        if has_bias:
-            operands += [attn_p["q_bias"], attn_p["kv_bias"]]
-        if has_rope:
-            operands += [cos, sin]
-        if has_qk_ln:
-            operands += [attn_p["q_ln_scale"], attn_p["k_ln_scale"]]
-        if has_lora:
-            operands += list(lora)
-
-        def kernel(*refs):
-            it = iter(refs)
-            x_ref = next(it)
-            ln_s = next(it)
-            ln_b = next(it) if has_ln_bias else None
-            wq_ref = next(it)
-            wqs_ref = next(it) if q_res else None
-            wkv_ref = next(it)
-            wkvs_ref = next(it) if kv_res else None
-            qb_ref = next(it) if has_bias else None
-            kvb_ref = next(it) if has_bias else None
-            cos_ref = next(it) if has_rope else None
-            sin_ref = next(it) if has_rope else None
-            qln_ref = next(it) if has_qk_ln else None
-            kln_ref = next(it) if has_qk_ln else None
-            if has_lora:
-                aq_ref, bq_ref = next(it), next(it)
-                akv_ref, bkv_ref = next(it), next(it)
-            q_out, k_out, v_out = next(it), next(it), next(it)
-
-            xn = apply_norm(kind, x_ref[...], ln_s[...],
-                            ln_b[...] if ln_b is not None else None, eps)
-            xn = xn.astype(cdt)
-            q = xn @ _dequant_weight(wq_ref, wqs_ref, cdt)
-            kv = xn @ _dequant_weight(wkv_ref, wkvs_ref, cdt)
-            if has_lora:
-                q = q + _lora_epilogue(xn, aq_ref[...], bq_ref[...], cdt)
-                kv = kv + _lora_epilogue(xn, akv_ref[...], bkv_ref[...],
-                                         cdt)
-            if has_bias:
-                q = q + qb_ref[...].astype(cdt)
-                kv = kv + kvb_ref[...].astype(cdt)
-            q = q.reshape(b, nq, d)
-            k, v = jnp.split(kv.reshape(b, 2 * nkv, d), 2, axis=1)
-            if has_qk_ln:
-                q = rms_norm(q, qln_ref[...], eps)
-                k = rms_norm(k, kln_ref[...], eps)
-            if has_rope:
-                q = _rope_rows(q, cos_ref[...], sin_ref[...])
-                k = _rope_rows(k, cos_ref[...], sin_ref[...])
-            q_out[...] = q
-            k_out[...] = k
-            v_out[...] = v
-
-        return pl.pallas_call(
-            kernel,
-            out_shape=[jax.ShapeDtypeStruct((b, nq, d), cdt),
-                       jax.ShapeDtypeStruct((b, nkv, d), cdt),
-                       jax.ShapeDtypeStruct((b, nkv, d), cdt)],
-            interpret=_interpret(),
-            name="fused_qkv",
-        )(*operands)
-
-    # ---- tiled emission: grid over kv-head groups --------------------
-    nkv_t = nkv // t
-    nq_t = group * nkv_t
-
-    def col_w(width, off=0):
-        return pl.BlockSpec((h, width), lambda i, _o=off: (0, _o + i))
-
-    def col_s(width, off=0):
-        return pl.BlockSpec((1, width), lambda i, _o=off: (0, _o + i))
-
-    def col_b(width, off=0):
-        return pl.BlockSpec((width,), lambda i, _o=off: (_o + i,))
-
-    operands = [x, attn_p["ln1_scale"]]
-    in_specs = [_full_spec(x), _full_spec(attn_p["ln1_scale"])]
-    if has_ln_bias:
-        operands.append(attn_p["ln1_bias"])
-        in_specs.append(_full_spec(attn_p["ln1_bias"]))
-    operands += _weight_operands(wq_leaf)
-    in_specs.append(col_w(nq_t * d))
-    if q_res:
-        in_specs.append(col_s(nq_t * d))
-    # KV weight columns are [K | V] packed: pass the leaf TWICE with
-    # the V block offset by T column-blocks (nkv*D == T * nkv_t*D).
-    kv_ops = _weight_operands(wkv_leaf)
-    operands += kv_ops + kv_ops
-    in_specs.append(col_w(nkv_t * d))
-    if kv_res:
-        in_specs.append(col_s(nkv_t * d))
-    in_specs.append(col_w(nkv_t * d, off=t))
-    if kv_res:
-        in_specs.append(col_s(nkv_t * d, off=t))
-    if has_bias:
-        operands += [attn_p["q_bias"], attn_p["kv_bias"],
-                     attn_p["kv_bias"]]
-        in_specs += [col_b(nq_t * d), col_b(nkv_t * d),
-                     col_b(nkv_t * d, off=t)]
-    if has_rope:
-        operands += [cos, sin]
-        in_specs += [_full_spec(cos), _full_spec(sin)]
-    if has_qk_ln:
-        operands += [attn_p["q_ln_scale"], attn_p["k_ln_scale"]]
-        in_specs += [_full_spec(attn_p["q_ln_scale"]),
-                     _full_spec(attn_p["k_ln_scale"])]
-
-    def tiled(*refs):
-        it = iter(refs)
-        x_ref = next(it)
-        ln_s = next(it)
-        ln_b = next(it) if has_ln_bias else None
-        wq_ref = next(it)
-        wqs_ref = next(it) if q_res else None
-        wk_ref = next(it)
-        wks_ref = next(it) if kv_res else None
-        wv_ref = next(it)
-        wvs_ref = next(it) if kv_res else None
-        qb_ref = next(it) if has_bias else None
-        kb_ref = next(it) if has_bias else None
-        vb_ref = next(it) if has_bias else None
-        cos_ref = next(it) if has_rope else None
-        sin_ref = next(it) if has_rope else None
-        qln_ref = next(it) if has_qk_ln else None
-        kln_ref = next(it) if has_qk_ln else None
-        q_out, k_out, v_out = next(it), next(it), next(it)
-
-        xn = apply_norm(kind, x_ref[...], ln_s[...],
-                        ln_b[...] if ln_b is not None else None, eps)
-        xn = xn.astype(cdt)
-        q = xn @ _dequant_weight(wq_ref, wqs_ref, cdt)
-        k = xn @ _dequant_weight(wk_ref, wks_ref, cdt)
-        v = xn @ _dequant_weight(wv_ref, wvs_ref, cdt)
-        if has_bias:
-            q = q + qb_ref[...].astype(cdt)
-            k = k + kb_ref[...].astype(cdt)
-            v = v + vb_ref[...].astype(cdt)
-        q = q.reshape(b, nq_t, d)
-        k = k.reshape(b, nkv_t, d)
-        v = v.reshape(b, nkv_t, d)
-        if has_qk_ln:
-            q = rms_norm(q, qln_ref[...], eps)
-            k = rms_norm(k, kln_ref[...], eps)
-        if has_rope:
-            q = _rope_rows(q, cos_ref[...], sin_ref[...])
-            k = _rope_rows(k, cos_ref[...], sin_ref[...])
-        q_out[...] = q
-        k_out[...] = k
-        v_out[...] = v
-
-    return pl.pallas_call(
-        tiled,
-        grid=(t,),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((b, nq_t, d), lambda i: (0, i, 0)),
-                   pl.BlockSpec((b, nkv_t, d), lambda i: (0, i, 0)),
-                   pl.BlockSpec((b, nkv_t, d), lambda i: (0, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, nq, d), cdt),
-                   jax.ShapeDtypeStruct((b, nkv, d), cdt),
-                   jax.ShapeDtypeStruct((b, nkv, d), cdt)],
-        interpret=_interpret(),
-        name="fused_qkv_tiled",
-    )(*operands)
-
-
-def _mla_qkv_bytes(cfg, rows, w_item, act_item):
-    """Operand bytes of the no-grid fused MLA QKV prologue — shared by
-    _fused_mla_qkv's budget check and megakernel_ineligible_reason so
-    eligibility and emission cannot drift. MLA prologue weights are
-    never resident-quantized (quantization.RESIDENT_KERNELS is
-    name-gated and carries none of q_down/q_up/q_proj/kv_down/kv_up),
-    so one itemsize covers them all."""
-    h = cfg.hidden_size
-    nq = cfg.num_attention_heads
-    dqk, dpe, dv = cfg.qk_head_dim, cfg.qk_pos_emb_head_dim, cfg.v_head_dim
-    klat, qlr = cfg.kv_lora_rank, cfg.q_lora_rank
-    if qlr:
-        wb = h * qlr + qlr + qlr * nq * (dqk + dpe)
-    else:
-        wb = h * nq * (dqk + dpe)
-    wb += h * (klat + dpe) + klat + klat * nq * (dqk + dv)
-    ins = rows * h
-    outs = rows * (nq * klat + nq * dpe + klat + dpe)
-    rope = 2 * rows * (dpe // 2) * 4
-    return wb * w_item + (ins + outs) * act_item + h * 4 + rope
-
-
-def _fused_mla_qkv(x, attn_p, cfg, cos, sin):
-    """The MLA megakernel prologue (ISSUE 17 carve-out c), ONE no-grid
-    kernel: pre-attention norm → q path (q_proj, or q_down → rms →
-    q_up) → split/rope the decoupled q_pe heads → ABSORB q_nope through
-    kv_up's k_nope columns (× YaRN mscale² when active — the cached
-    latent is unscaled, so the absorbed query carries both factors) →
-    kv_down → split → rms-normed latent → roped shared k_pe row.
-
-    x [B*, H] (residual dtype; B* = decode batch rows or B·S flattened
-    ragged rows) with per-row rope tables [B*, dpe/2]; returns
-    (q_lat [B*, nq, klat], q_pe [B*, nq, dpe], latent [B*, klat],
-    k_pe [B*, dpe]) in compute dtype — exactly the operands
-    paged_attention_latent and the append scatter consume. Math is
-    op-for-op the unfused mla_forward paged prologue (same einsum
-    absorption, same norm/rope formulas), so fused MLA streams stay
-    token-exact. No grid: megakernel_ineligible_reason gates callers
-    on _mla_qkv_bytes before tracing."""
-    from megatronapp_tpu.config.transformer_config import (
-        NormKind, PositionEmbeddingKind,
-    )
-    from megatronapp_tpu.ops import rotary
-    from megatronapp_tpu.ops.normalization import apply_norm, rms_norm
-
-    b, h = x.shape
-    nq = cfg.num_attention_heads
-    dqk, dpe, dv = cfg.qk_head_dim, cfg.qk_pos_emb_head_dim, cfg.v_head_dim
-    klat = cfg.kv_lora_rank
-    cdt = cfg.compute_dtype
-    eps = cfg.layernorm_epsilon
-    kind = cfg.normalization
-    has_ln_bias = kind == NormKind.layernorm
-    has_rope = cos is not None
-    has_q_lora = "q_down" in attn_p
-    m2 = 1.0
-    if cfg.position_embedding == PositionEmbeddingKind.yarn:
-        m = rotary.yarn_mscale(cfg.rope_scaling_factor,
-                               cfg.yarn_mscale_coeff)
-        m2 = m * m
-
-    budget = get_megakernel_vmem_budget()
-    need = _mla_qkv_bytes(cfg, b, jnp.dtype(cfg.params_dtype).itemsize,
-                          jnp.dtype(cdt).itemsize)
-    if need > budget:
-        raise ValueError(
-            "fused MLA QKV prologue exceeds the VMEM budget — "
-            "megakernel_ineligible_reason gates callers before tracing")
-
-    operands = [x, attn_p["ln1_scale"]]
-    if has_ln_bias:
-        operands.append(attn_p["ln1_bias"])
-    if has_q_lora:
-        operands += [attn_p["q_down"], attn_p["q_ln_scale"],
-                     attn_p["q_up"]]
-    else:
-        operands.append(attn_p["q_proj"])
-    operands += [attn_p["kv_down"], attn_p["kv_ln_scale"],
-                 attn_p["kv_up"]]
-    if has_rope:
-        operands += [cos, sin]
-
-    def kernel(*refs):
-        it = iter(refs)
-        x_ref = next(it)
-        ln_s = next(it)
-        ln_b = next(it) if has_ln_bias else None
-        if has_q_lora:
-            qd_ref, qln_ref, qu_ref = next(it), next(it), next(it)
-        else:
-            qp_ref = next(it)
-        kvd_ref, kvln_ref, kvu_ref = next(it), next(it), next(it)
-        cos_ref = next(it) if has_rope else None
-        sin_ref = next(it) if has_rope else None
-        ql_out, qpe_out, lat_out, pe_out = (next(it), next(it),
-                                            next(it), next(it))
-
-        xn = apply_norm(kind, x_ref[...], ln_s[...],
-                        ln_b[...] if ln_b is not None else None, eps)
-        xn = xn.astype(cdt)
-        if has_q_lora:
-            q0 = xn @ qd_ref[...].astype(cdt)
-            q0 = rms_norm(q0, qln_ref[...], eps)
-            qf = q0 @ qu_ref[...].astype(cdt)
-        else:
-            qf = xn @ qp_ref[...].astype(cdt)
-        qf = qf.reshape(b, nq, dqk + dpe)
-        q_nope, q_pe = qf[..., :dqk], qf[..., dqk:]
-        if has_rope:
-            q_pe = _rope_rows(q_pe, cos_ref[...], sin_ref[...])
-        kv = xn @ kvd_ref[...].astype(cdt)
-        lat_row, pe_row = kv[..., :klat], kv[..., klat:]
-        lat_row = rms_norm(lat_row, kvln_ref[...], eps)
-        if has_rope:
-            pe_row = _rope_rows(pe_row[:, None, :], cos_ref[...],
-                                sin_ref[...])[:, 0]
-        wk = kvu_ref[...].astype(cdt).reshape(
-            klat, nq, dqk + dv)[..., :dqk]
-        q_abs = q_nope * m2 if m2 != 1.0 else q_nope
-        ql_out[...] = jnp.einsum("bnd,knd->bnk", q_abs, wk)
-        qpe_out[...] = q_pe
-        lat_out[...] = lat_row
-        pe_out[...] = pe_row
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=[jax.ShapeDtypeStruct((b, nq, klat), cdt),
-                   jax.ShapeDtypeStruct((b, nq, dpe), cdt),
-                   jax.ShapeDtypeStruct((b, klat), cdt),
-                   jax.ShapeDtypeStruct((b, dpe), cdt)],
-        interpret=_interpret(),
-        name="fused_mla_qkv",
-    )(*operands)
-
-
-def _fused_out_proj(attn_flat, attn_p, cfg, residual, tiles=None,
-                    lora=None):
-    """Attention epilogue in ONE kernel: out projection + bias +
-    residual add (the paged-attention output arrives head-flat
-    [B*, nq*D] — the GQA transpose/reshape is folded into the caller's
-    free reshape). residual [B*, H] keeps its dtype; returns [B*, H].
-
-    Large H re-emits the same body over a grid of H-column tiles: each
-    step reads the full attn_flat block and 1/T of the weight columns
-    (full contraction per tile — tiled columns bitwise the no-grid
-    ones). Resident-quantized weights dequantize in-register. tiles:
-    test/tuning override (must divide H). lora: optional (a, b)
-    per-row factors ([B*, nq·D, rk], [B*, rk, H]) — the no-grid body
-    adds each row's delta between matmul and bias."""
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-
-    b, h = residual.shape
-    cdt = cfg.compute_dtype
-    has_bias = "out_bias" in attn_p
-    w_leaf = attn_p["out_kernel"]
-    res = is_resident_leaf(w_leaf)
-    nqd = attn_flat.shape[1]
-    t = tiles if tiles is not None else _out_tiles(
-        h, nqd, b, _weight_itemsize(w_leaf), jnp.dtype(cdt).itemsize,
-        res, get_megakernel_vmem_budget())
-    if not t:
-        raise ValueError(
-            "fused out-proj kernel exceeds the VMEM budget even at one "
-            "output column per tile — megakernel_ineligible_reason "
-            "gates callers before tracing")
-    assert h % t == 0, f"out-proj tile count {t} must divide H={h}"
-    has_lora = lora is not None
-    assert not (has_lora and t != 1), (
-        "LoRA epilogue rides the no-grid fused out-proj body only — "
-        "megakernel_ineligible_reason(lora_rank=) gates callers")
-
-    def kernel(*refs):
-        it = iter(refs)
-        a_ref = next(it)
-        w_ref = next(it)
-        ws_ref = next(it) if res else None
-        r_ref = next(it)
-        b_ref = next(it) if has_bias else None
-        if has_lora:
-            la_ref, lb_ref = next(it), next(it)
-        o_ref = next(it)
-        out = a_ref[...] @ _dequant_weight(w_ref, ws_ref, cdt)
-        if has_lora:
-            out = out + _lora_epilogue(a_ref[...], la_ref[...],
-                                       lb_ref[...], cdt)
-        if has_bias:
-            out = out + b_ref[...].astype(cdt)
-        r = r_ref[...]
-        o_ref[...] = r + out.astype(r.dtype)
-
-    operands = [attn_flat] + _weight_operands(w_leaf) + [residual]
-    if has_bias:
-        operands.append(attn_p["out_bias"])
-    if has_lora:
-        operands += list(lora)
-
-    if t == 1:
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((b, h), residual.dtype),
-            interpret=_interpret(),
-            name="fused_out_proj",
-        )(*operands)
-
-    h_t = h // t
-    in_specs = [_full_spec(attn_flat),
-                pl.BlockSpec((nqd, h_t), lambda i: (0, i))]
-    if res:
-        in_specs.append(pl.BlockSpec((1, h_t), lambda i: (0, i)))
-    in_specs.append(pl.BlockSpec((b, h_t), lambda i: (0, i)))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((h_t,), lambda i: (i,)))
-    return pl.pallas_call(
-        kernel,
-        grid=(t,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((b, h_t), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((b, h), residual.dtype),
-        interpret=_interpret(),
-        name="fused_out_proj_tiled",
-    )(*operands)
-
-
-def _fused_mlp(x, p, cfg, tiles=None, lora=None):
-    """Pre-MLP norm + fc1 + activation (incl. gated) + fc2 + biases +
-    residual add. x [B*, H] (residual dtype) → [B*, H].
-
-    When the whole operand set fits the VMEM budget this is the
-    original ONE no-grid kernel. Otherwise it splits into TWO tiled
-    kernels: fc1+activation over ffn-column tiles producing y
-    [B*, ffn] in compute dtype (apply_activation preserves its input
-    dtype, so the store/reload is lossless), then fc2+bias+residual
-    over H-column tiles with the full-ffn contraction — every output
-    bitwise the single-kernel body's. tiles: test/tuning override —
-    a (t1, t2) pair forces the split emission. lora: optional
-    (a1, b1, a2, b2) per-row factors ([B*, H, rk], [B*, rk, fc1_out],
-    [B*, ffn, rk], [B*, rk, H]) — the single-kernel body adds fc1's
-    delta (from the normed input) and fc2's delta (from the ACTIVATED
-    intermediate) between each matmul and its bias; the split emission
-    does not carry it."""
-    from megatronapp_tpu.config.transformer_config import NormKind
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    from megatronapp_tpu.ops.activations import apply_activation, is_gated
-    from megatronapp_tpu.ops.normalization import apply_norm
-
-    b, h = x.shape
-    cdt = cfg.compute_dtype
-    eps = cfg.layernorm_epsilon
-    kind = cfg.normalization
-    act = cfg.activation
-    gated = is_gated(act)
-    has_ln_bias = kind == NormKind.layernorm
-    mlp_p = p["mlp"]
-    has_bias = "fc1_bias" in mlp_p
-    w1_leaf, w2_leaf = mlp_p["fc1_kernel"], mlp_p["fc2_kernel"]
-    r1 = is_resident_leaf(w1_leaf)
-    r2 = is_resident_leaf(w2_leaf)
-    plan = tiles if tiles is not None else _mlp_tiles(
-        h, cfg.ffn_hidden_size, gated, b, _weight_itemsize(w1_leaf),
-        _weight_itemsize(w2_leaf), jnp.dtype(cdt).itemsize, r1, r2,
-        get_megakernel_vmem_budget())
-
-    has_lora = lora is not None
-    if plan is not None:
-        assert not has_lora, (
-            "LoRA epilogue rides the one-kernel fused MLP body only — "
-            "megakernel_ineligible_reason(lora_rank=) gates callers")
-        t1, t2 = plan
-        if not t1 or not t2:
-            raise ValueError(
-                "fused MLP kernels exceed the VMEM budget even at one "
-                "column per tile — megakernel_ineligible_reason gates "
-                "callers before tracing")
-        y = _fused_mlp_fc1(x, p, cfg, t1)
-        return _fused_mlp_fc2(y, x, p, cfg, t2)
-
-    operands = [x, p["ln2_scale"]]
-    if has_ln_bias:
-        operands.append(p["ln2_bias"])
-    operands += _weight_operands(w1_leaf) + _weight_operands(w2_leaf)
-    if has_bias:
-        operands += [mlp_p["fc1_bias"], mlp_p["fc2_bias"]]
-    if has_lora:
-        operands += list(lora)
-
-    def kernel(*refs):
-        it = iter(refs)
-        x_ref, ln_s = next(it), next(it)
-        ln_b = next(it) if has_ln_bias else None
-        w1_ref = next(it)
-        w1s_ref = next(it) if r1 else None
-        w2_ref = next(it)
-        w2s_ref = next(it) if r2 else None
-        b1_ref = next(it) if has_bias else None
-        b2_ref = next(it) if has_bias else None
-        if has_lora:
-            a1_ref, b1l_ref = next(it), next(it)
-            a2_ref, b2l_ref = next(it), next(it)
-        o_ref = next(it)
-
-        xn = apply_norm(kind, x_ref[...], ln_s[...],
-                        ln_b[...] if ln_b is not None else None, eps)
-        xn = xn.astype(cdt)
-        y = xn @ _dequant_weight(w1_ref, w1s_ref, cdt)
-        if has_lora:
-            y = y + _lora_epilogue(xn, a1_ref[...], b1l_ref[...], cdt)
-        if has_bias:
-            y = y + b1_ref[...].astype(cdt)
-        if gated:
-            gate, val = jnp.split(y, 2, axis=-1)
-            y = apply_activation(act, val, gate)
-        else:
-            y = apply_activation(act, y)
-        out = y @ _dequant_weight(w2_ref, w2s_ref, cdt)
-        if has_lora:
-            out = out + _lora_epilogue(y, a2_ref[...], b2l_ref[...], cdt)
-        if has_bias:
-            out = out + b2_ref[...].astype(cdt)
-        r = x_ref[...]
-        o_ref[...] = r + out.astype(r.dtype)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h), x.dtype),
-        interpret=_interpret(),
-        name="fused_mlp",
-    )(*operands)
-
-
-def _fused_mlp_fc1(x, p, cfg, t):
-    """Kernel A of the tiled MLP split: pre-MLP norm + fc1 + bias +
-    activation over a grid of ffn-column tiles. The gated variant reads
-    the packed [gate | value] fc1 weight TWICE (value block offset by T
-    column-blocks), so the activation sees exactly the columns the
-    single-kernel split(y, 2) produces. Returns y [B*, ffn] in compute
-    dtype."""
-    from megatronapp_tpu.config.transformer_config import NormKind
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    from megatronapp_tpu.ops.activations import apply_activation, is_gated
-    from megatronapp_tpu.ops.normalization import apply_norm
-
-    b, h = x.shape
-    cdt = cfg.compute_dtype
-    eps = cfg.layernorm_epsilon
-    kind = cfg.normalization
-    act = cfg.activation
-    gated = is_gated(act)
-    has_ln_bias = kind == NormKind.layernorm
-    mlp_p = p["mlp"]
-    has_bias = "fc1_bias" in mlp_p
-    w1_leaf = mlp_p["fc1_kernel"]
-    r1 = is_resident_leaf(w1_leaf)
-    ffn = cfg.ffn_hidden_size
-    assert ffn % t == 0, f"fc1 tile count {t} must divide ffn={ffn}"
-    f_t = ffn // t
-
-    def col_w(off=0):
-        return pl.BlockSpec((h, f_t), lambda i, _o=off: (0, _o + i))
-
-    def col_s(off=0):
-        return pl.BlockSpec((1, f_t), lambda i, _o=off: (0, _o + i))
-
-    def col_b(off=0):
-        return pl.BlockSpec((f_t,), lambda i, _o=off: (_o + i,))
-
-    operands = [x, p["ln2_scale"]]
-    in_specs = [_full_spec(x), _full_spec(p["ln2_scale"])]
-    if has_ln_bias:
-        operands.append(p["ln2_bias"])
-        in_specs.append(_full_spec(p["ln2_bias"]))
-    w1_ops = _weight_operands(w1_leaf)
-    operands += w1_ops
-    in_specs.append(col_w())
-    if r1:
-        in_specs.append(col_s())
-    if gated:
-        operands += w1_ops
-        in_specs.append(col_w(off=t))
-        if r1:
-            in_specs.append(col_s(off=t))
-    if has_bias:
-        operands.append(mlp_p["fc1_bias"])
-        in_specs.append(col_b())
-        if gated:
-            operands.append(mlp_p["fc1_bias"])
-            in_specs.append(col_b(off=t))
-
-    def kernel(*refs):
-        it = iter(refs)
-        x_ref, ln_s = next(it), next(it)
-        ln_b = next(it) if has_ln_bias else None
-        wg_ref = next(it)
-        wgs_ref = next(it) if r1 else None
-        wv_ref = next(it) if gated else None
-        wvs_ref = next(it) if (gated and r1) else None
-        bg_ref = next(it) if has_bias else None
-        bv_ref = next(it) if (has_bias and gated) else None
-        y_out = next(it)
-
-        xn = apply_norm(kind, x_ref[...], ln_s[...],
-                        ln_b[...] if ln_b is not None else None, eps)
-        xn = xn.astype(cdt)
-        if gated:
-            gate = xn @ _dequant_weight(wg_ref, wgs_ref, cdt)
-            val = xn @ _dequant_weight(wv_ref, wvs_ref, cdt)
-            if has_bias:
-                gate = gate + bg_ref[...].astype(cdt)
-                val = val + bv_ref[...].astype(cdt)
-            y = apply_activation(act, val, gate)
-        else:
-            y = xn @ _dequant_weight(wg_ref, wgs_ref, cdt)
-            if has_bias:
-                y = y + bg_ref[...].astype(cdt)
-            y = apply_activation(act, y)
-        y_out[...] = y
-
-    return pl.pallas_call(
-        kernel,
-        grid=(t,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((b, f_t), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((b, ffn), cdt),
-        interpret=_interpret(),
-        name="fused_mlp_fc1",
-    )(*operands)
-
-
-def _fused_mlp_fc2(y, x, p, cfg, t):
-    """Kernel B of the tiled MLP split: fc2 + bias + residual add over
-    a grid of H-column tiles with the full-ffn contraction per tile.
-    y [B*, ffn] (compute dtype, from _fused_mlp_fc1), x [B*, H] the
-    pre-norm residual; returns [B*, H] in the residual dtype."""
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-
-    b, h = x.shape
-    cdt = cfg.compute_dtype
-    mlp_p = p["mlp"]
-    has_bias = "fc2_bias" in mlp_p
-    w2_leaf = mlp_p["fc2_kernel"]
-    r2 = is_resident_leaf(w2_leaf)
-    ffn = y.shape[1]
-    assert h % t == 0, f"fc2 tile count {t} must divide H={h}"
-    h_t = h // t
-
-    operands = [y] + _weight_operands(w2_leaf) + [x]
-    in_specs = [_full_spec(y),
-                pl.BlockSpec((ffn, h_t), lambda i: (0, i))]
-    if r2:
-        in_specs.append(pl.BlockSpec((1, h_t), lambda i: (0, i)))
-    in_specs.append(pl.BlockSpec((b, h_t), lambda i: (0, i)))
-    if has_bias:
-        operands.append(mlp_p["fc2_bias"])
-        in_specs.append(pl.BlockSpec((h_t,), lambda i: (i,)))
-
-    def kernel(*refs):
-        it = iter(refs)
-        y_ref = next(it)
-        w2_ref = next(it)
-        w2s_ref = next(it) if r2 else None
-        x_ref = next(it)
-        b2_ref = next(it) if has_bias else None
-        o_ref = next(it)
-        out = y_ref[...] @ _dequant_weight(w2_ref, w2s_ref, cdt)
-        if has_bias:
-            out = out + b2_ref[...].astype(cdt)
-        r = x_ref[...]
-        o_ref[...] = r + out.astype(r.dtype)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(t,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((b, h_t), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((b, h), x.dtype),
-        interpret=_interpret(),
-        name="fused_mlp_fc2",
-    )(*operands)
-
-
-def _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                     cache_positions, counts, page_table, active,
-                     layer_id, kv_scales=None):
-    """One MLA layer as fused kernels (ISSUE 17 carve-out c): [fused
-    MLA prologue — norm + q path + rope + absorption + latent/k_pe] →
-    [compressed append, in place] → [generated latent-space paged kernel]
-    → [fused out-proj + residual] → [fused norm+MLP + residual].
-
-    Handles BOTH the s == 1 decode body (counts=None) and the ragged
-    multiquery body (counts [B]) — the prologue is row-wise, so the
-    B·S flattening is bitwise-safe exactly like fused_layer_multiquery.
-    kv_cache: the STACKED latent/k_pe pools [L, NB, bs, ...], of which
-    layer_id names this layer's plane. kv_scales: int8/fp8 latent pool
-    scale pools ([L, NB, bs] per pool) — the new rows quantize per-row
-    right here (ONE fused jit covers prologue + quantize + append +
-    attend) and new_cache carries four pools."""
-    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
-    b, s, h = x.shape
-    nq = cfg.num_attention_heads
-    dqk, dpe, dv = cfg.qk_head_dim, cfg.qk_pos_emb_head_dim, cfg.v_head_dim
-    klat = cfg.kv_lora_rank
-    dt = cfg.compute_dtype
-    attn_p = p["attention"]
-    ragged = counts is not None
-    xf = x.reshape(b * s, h)
-    cos = rope_cos.reshape(b * s, -1) if rope_cos is not None else None
-    sin = rope_sin.reshape(b * s, -1) if rope_sin is not None else None
-
-    q_lat, q_pe, lat, pe = _fused_mla_qkv(
-        xf, {**attn_p, "ln1_scale": p["ln1_scale"],
-             **({"ln1_bias": p["ln1_bias"]} if "ln1_bias" in p else {})},
-        cfg, cos, sin)
-    w_v = attn_p["kv_up"].astype(dt).reshape(
-        klat, nq, dqk + dv)[..., dqk:]
-
-    if active is None:
-        active = jnp.ones((b,), bool)
-    rows_ = ((lat.reshape(b, s, klat), pe.reshape(b, s, dpe)) if ragged
-             else (lat, pe))
-    (c_lat, c_pe), new_scales = append_kv(
-        kv_cache, kv_scales, rows_, page_table, cache_positions, active,
-        layer_id, counts)
-    if new_scales is None:
-        new_cache, sc_kw = (c_lat, c_pe), {}
-    else:
-        new_cache = (c_lat, c_pe) + new_scales
-        sc_kw = {"lat_scales": new_scales[0], "pe_scales": new_scales[1]}
-
-    scale = 1.0 / float((dqk + dpe) ** 0.5)
-    if ragged:
-        attn = paged_attention_latent(
-            q_lat.reshape(b, s, nq, klat), q_pe.reshape(b, s, nq, dpe),
-            c_lat, c_pe, page_table, cache_positions + counts, w_v,
-            q_lens=counts, softmax_scale=scale, layer=layer_id, **sc_kw)
-    else:
-        attn = paged_attention_latent(
-            q_lat, q_pe, c_lat, c_pe, page_table, cache_positions + 1,
-            w_v, softmax_scale=scale, layer=layer_id,
-            **sc_kw)                                  # [B, nq, dv]
-    x2 = _fused_out_proj(attn.reshape(b * s, nq * dv), attn_p, cfg, xf)
-    x2 = _fused_mlp(x2, p, cfg)
-    out = x2[:, None] if not ragged else x2.reshape(b, s, h)
-    return (out, new_cache), None
-
-
-def _lora_gathered(lora, s: int = 1):
-    """Gather per-row adapter factors from one layer's bank slices:
-    lora = {"row_adapter": [B] int32 bank slots, "banks":
-    {target: (a [slots, din, rk], b [slots, rk, dout])}} → the fused
-    bodies' operand tuples (qkv, out, mlp) with the batch's ids
-    repeated over S for flattened ragged rows (every token row wears
-    its slot's adapter). XLA gathers OUTSIDE the kernels; the bodies
-    see dense [B*, …] factor operands."""
-    ids = lora["row_adapter"]
-    if s > 1:
-        ids = jnp.repeat(ids, s)
-    g = {t: (a[ids], b[ids]) for t, (a, b) in lora["banks"].items()}
-    qkv = (g["q_kernel"][0], g["q_kernel"][1],
-           g["kv_kernel"][0], g["kv_kernel"][1])
-    out = g["out_kernel"]
-    mlp = (g["fc1_kernel"][0], g["fc1_kernel"][1],
-           g["fc2_kernel"][0], g["fc2_kernel"][1])
-    return qkv, out, mlp
-
-
-def fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                       cache_positions, page_table, active, layer_id,
-                       kv_scales=None, lora=None):
-    """One decode layer as fused kernels: [fused norm+QKV+rope] →
-    [append, in place] → [generated paged-attention kernel] → [fused
-    out-proj + residual] → [fused norm+MLP + residual]. kv_cache is the
-    STACKED pool pair and layer_id names this layer's plane of it.
-
-    Drop-in for transformer/block.layer_forward's s == 1 paged decode
-    path (cfg.megakernel_decode; DynamicInferenceEngine(fused_decode=
-    True)): same arguments, same ((out, new_cache), aux) return, greedy
-    streams token-exact vs the unfused body. MegaScope capture /
-    disturbance sites are NOT traced here — megakernel_ineligible_reason
-    gates the fused path off while hooks are active."""
-    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
-    b = x.shape[0]
-    assert x.shape[1] == 1, "fused_layer_decode is the s == 1 decode body"
-    if cfg.multi_latent_attention:
-        assert lora is None, (
-            "LoRA targets the GQA projections — "
-            "megakernel_ineligible_reason(lora_rank=) gates MLA off")
-        return _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                                cache_positions, None, page_table,
-                                active, layer_id, kv_scales=kv_scales)
-    nq, d = cfg.num_attention_heads, cfg.head_dim
-    attn_p = p["attention"]
-    x2 = x[:, 0]
-    cos = rope_cos[:, 0] if rope_cos is not None else None
-    sin = rope_sin[:, 0] if rope_sin is not None else None
-    qkv_lora = out_lora = mlp_lora = None
-    if lora is not None:
-        qkv_lora, out_lora, mlp_lora = _lora_gathered(lora)
-
-    q, k, v = _fused_qkv(x2, {**attn_p, "ln1_scale": p["ln1_scale"],
-                              **({"ln1_bias": p["ln1_bias"]}
-                                 if "ln1_bias" in p else {})},
-                         cfg, cos, sin, lora=qkv_lora)
-
-    if active is None:
-        active = jnp.ones((b,), bool)
-    (ck, cv), new_scales = append_kv(
-        kv_cache, kv_scales, (k, v), page_table, cache_positions, active,
-        layer_id)
-    if new_scales is None:
-        new_cache, sc_kw = (ck, cv), {}
-    else:
-        new_cache = (ck, cv) + new_scales
-        sc_kw = {"k_scales": new_scales[0], "v_scales": new_scales[1]}
-
-    attn = paged_attention(q, ck, cv, page_table, cache_positions + 1,
-                           layer=layer_id, **sc_kw)       # [B, nq, D]
-    x2 = _fused_out_proj(attn.reshape(b, nq * d), attn_p, cfg, x2,
-                         lora=out_lora)
-    x2 = _fused_mlp(x2, p, cfg, lora=mlp_lora)
-    return (x2[:, None], new_cache), None
-
-
-def fused_layer_multiquery(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                           cache_positions, counts, page_table, active,
-                           layer_id, kv_scales=None, lora=None):
-    """One ragged multi-query layer (speculative verify rounds and
-    chunked prefill) as the SAME fused kernels around the generated
-    ragged paged-attention kernel: [fused norm+QKV+rope on the B·S
-    flattened rows] → [chunk append, in place] → [ragged paged attention,
-    q_lens scalar-prefetch path] → [fused out-proj + residual] →
-    [fused norm+MLP + residual].
-
-    Drop-in for transformer/block.layer_forward's chunk_counts paged
-    path: x [B, S, H] with rope tables [B, S, half] and counts [B]
-    (q_len ∈ [1, S] per row). Row-flattening is bitwise-safe — every
-    fused op is row-wise (norms, rope, activations) or contracts the
-    last dim only — so verify/prefill streams keep the PR 4 pins."""
-    from megatronapp_tpu.ops.pallas.paged_attention import append_kv
-    b, s, h = x.shape
-    if cfg.multi_latent_attention:
-        assert lora is None, (
-            "LoRA targets the GQA projections — "
-            "megakernel_ineligible_reason(lora_rank=) gates MLA off")
-        return _fused_mla_layer(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                                cache_positions, counts, page_table,
-                                active, layer_id, kv_scales=kv_scales)
-    nq, nkv, d = (cfg.num_attention_heads, cfg.num_query_groups,
-                  cfg.head_dim)
-    attn_p = p["attention"]
-    xf = x.reshape(b * s, h)
-    cos = rope_cos.reshape(b * s, -1) if rope_cos is not None else None
-    sin = rope_sin.reshape(b * s, -1) if rope_sin is not None else None
-    qkv_lora = out_lora = mlp_lora = None
-    if lora is not None:
-        qkv_lora, out_lora, mlp_lora = _lora_gathered(lora, s)
-
-    q, k, v = _fused_qkv(xf, {**attn_p, "ln1_scale": p["ln1_scale"],
-                              **({"ln1_bias": p["ln1_bias"]}
-                                 if "ln1_bias" in p else {})},
-                         cfg, cos, sin, lora=qkv_lora)
-    q = q.reshape(b, s, nq, d)
-    k = k.reshape(b, s, nkv, d)
-    v = v.reshape(b, s, nkv, d)
-
-    if active is None:
-        active = jnp.ones((b,), bool)
-    (ck, cv), new_scales = append_kv(
-        kv_cache, kv_scales, (k, v), page_table, cache_positions, active,
-        layer_id, counts)
-    if new_scales is None:
-        new_cache, sc_kw = (ck, cv), {}
-    else:
-        new_cache = (ck, cv) + new_scales
-        sc_kw = {"k_scales": new_scales[0], "v_scales": new_scales[1]}
-
-    attn = paged_attention(q, ck, cv, page_table,
-                           cache_positions + counts, q_lens=counts,
-                           layer=layer_id, **sc_kw)    # [B, S, nq, D]
-    x2 = _fused_out_proj(attn.reshape(b * s, nq * d), attn_p, cfg, xf,
-                         lora=out_lora)
-    x2 = _fused_mlp(x2, p, cfg, lora=mlp_lora)
-    return (x2.reshape(b, s, h), new_cache), None
-
-
-def megakernel_ineligible_reason(cfg, *, batch, tp_paged=False,
-                                 paged=True, params=None,
-                                 mq_rows=None,
-                                 lora_rank=None) -> Optional[str]:
-    """Why the fused (megakernel) decode step may NOT run — None when
-    eligible, otherwise the FIRST failed predicate by name (same
-    loud-fallback contract as tp_paged_ineligible_reason). params: the
-    engine's param pytree when available — resident-quantized leaves
-    change the weight-operand byte math (int8 blocks + fp32 scale rows
-    enter the kernels and dequantize in-register; they are NOT a
-    carve-out anymore). mq_rows: the widest flattened row count the
-    fused multiquery step will see (prefill_chunk / max_batch·(K+1));
-    tile plans are sized for the worse of batch and mq_rows. lora_rank:
-    the serving adapter rank when an AdapterCache is attached — the
-    LoRA epilogue rides only the NO-GRID fused bodies, so its
-    predicates re-plan each body with the per-row factor bytes charged
-    against the budget.
-
-    Size no longer disqualifies a config outright: the fused kernels
-    grid-tile their weight columns to fit the VMEM budget
-    (get_megakernel_vmem_budget / --megakernel-vmem-budget), so the
-    size predicates below fail only when even ONE column/kv-head-group
-    per tile exceeds the budget. The same _qkv_tiles/_out_tiles/
-    _mlp_tiles byte math drives kernel emission — eligibility and
-    emission cannot drift."""
-    if not paged:
-        return "dense (non-paged) backend — the fused step is built " \
-               "around the paged-attention kernel"
-    if cfg.is_moe:
-        return "MoE layers: expert dispatch is not fused yet"
-    if getattr(cfg, "hetero_block_specs", None):
-        return "heterogeneous per-layer configs unroll their own bodies"
-    if tp_paged:
-        return "tp head-sharded serving mesh: fused prologue/epilogue " \
-               "kernels are single-device (the tp engine keeps the " \
-               "unfused body)"
-    from megatronapp_tpu.scope import hooks
-    from megatronapp_tpu.scope.disturbance import get_disturbance
-    cap_sites = ("qkv_q", "qkv_k", "qkv_v", "context", "mlp1", "mlp2",
-                 "between_layers")
-    if any(hooks.is_enabled(s) for s in cap_sites):
-        return "MegaScope capture hooks active (fused kernels do not " \
-               "trace capture sites)"
-    dist = get_disturbance()
-    if any(dist.active(s) for s in ("weight", "calculation", "system")):
-        return "MegaScope disturbance sites active (fused kernels do " \
-               "not trace perturbations)"
-    # Size: plan the tile grids at the engine's worst row count; a 0
-    # tile count means even the finest tiling cannot fit the budget.
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-    from megatronapp_tpu.ops.activations import is_gated
-    blk = params.get("block", {}) if isinstance(params, dict) else {}
-    attn = blk.get("attention", {}) if isinstance(blk, dict) else {}
-    mlp = blk.get("mlp", {}) if isinstance(blk, dict) else {}
-    h = cfg.hidden_size
-    mla = cfg.multi_latent_attention
-    nq = cfg.num_attention_heads
-    rows = max(int(batch), int(mq_rows or 0))
-    act_item = jnp.dtype(cfg.compute_dtype).itemsize
-    default_item = jnp.dtype(cfg.params_dtype).itemsize
-
-    def _wi(leaf):
-        return 1 if is_resident_leaf(leaf) else default_item
-
-    budget = get_megakernel_vmem_budget()
-    flag = "raise --megakernel-vmem-budget to fuse anyway"
-    if mla:
-        # The MLA prologue (q path + absorption + latent projection +
-        # rope, _fused_mla_qkv) has no column-tiling axis — the kv_up
-        # absorption couples every head to the whole latent — so it
-        # runs no-grid only and fails as one predicate.
-        if _mla_qkv_bytes(cfg, rows, default_item, act_item) > budget:
-            return (f"fused MLA QKV prologue (q path + kv_up absorption "
-                    f"+ latent projection) exceeds the VMEM budget "
-                    f"({budget} B) as one no-grid kernel — {flag}")
-        nqd = nq * cfg.v_head_dim
-    else:
-        nkv, d = cfg.num_query_groups, cfg.head_dim
-        if not _qkv_tiles(h, nq, nkv, d, rows, _wi(attn.get("q_kernel")),
-                          _wi(attn.get("kv_kernel")), act_item,
-                          is_resident_leaf(attn.get("q_kernel")),
-                          is_resident_leaf(attn.get("kv_kernel")),
-                          budget):
-            return (f"fused QKV kernel: one kv-head group per tile "
-                    f"still exceeds the VMEM budget ({budget} B) — "
-                    f"{flag}")
-        nqd = nq * d
-    if not _out_tiles(h, nqd, rows, _wi(attn.get("out_kernel")),
-                      act_item, is_resident_leaf(attn.get("out_kernel")),
-                      budget):
-        return (f"fused out-proj kernel: one output column per tile "
-                f"still exceeds the VMEM budget ({budget} B) — {flag}")
-    plan = _mlp_tiles(h, cfg.ffn_hidden_size, is_gated(cfg.activation),
-                      rows, _wi(mlp.get("fc1_kernel")),
-                      _wi(mlp.get("fc2_kernel")), act_item,
-                      is_resident_leaf(mlp.get("fc1_kernel")),
-                      is_resident_leaf(mlp.get("fc2_kernel")), budget)
-    if plan is not None and (not plan[0] or not plan[1]):
-        return (f"fused MLP kernels: one ffn/output column per tile "
-                f"still exceeds the VMEM budget ({budget} B) — {flag}")
-    if lora_rank:
-        if mla:
-            return ("LoRA serving targets the GQA projection kernels — "
-                    "the MLA megakernel has no q_kernel/kv_kernel to "
-                    "compose an adapter epilogue onto")
-        # LoRA epilogue (ISSUE 19): the fused bodies add per-row
-        # adapter factors as extra whole-array operands, which only the
-        # NO-GRID emissions carry (the tiled emissions' column blocks
-        # would have to split the B factor's dout dim in lockstep —
-        # not built). Re-plan each body with the budget reduced by its
-        # fp32 per-row factor bytes: still no-grid → base + LoRA fits.
-        rk = int(lora_rank)
-        d_qkv = nq * cfg.head_dim + 2 * cfg.num_query_groups * cfg.head_dim
-        lb = rows * rk * (2 * h + d_qkv) * 4
-        if _qkv_tiles(h, nq, cfg.num_query_groups, cfg.head_dim, rows,
-                      _wi(attn.get("q_kernel")),
-                      _wi(attn.get("kv_kernel")), act_item,
-                      is_resident_leaf(attn.get("q_kernel")),
-                      is_resident_leaf(attn.get("kv_kernel")),
-                      budget - lb) != 1:
-            return (f"LoRA epilogue (rank {rk}) needs the no-grid fused "
-                    f"QKV body with its per-row factors VMEM-resident — "
-                    f"over the budget ({budget} B) at rows={rows}; {flag}")
-        lb = rows * rk * (nqd + h) * 4
-        if _out_tiles(h, nqd, rows, _wi(attn.get("out_kernel")),
-                      act_item,
-                      is_resident_leaf(attn.get("out_kernel")),
-                      budget - lb) != 1:
-            return (f"LoRA epilogue (rank {rk}) needs the no-grid fused "
-                    f"out-proj body with its per-row factors "
-                    f"VMEM-resident — over the budget ({budget} B) at "
-                    f"rows={rows}; {flag}")
-        ffn = cfg.ffn_hidden_size
-        fc1_out = (2 if is_gated(cfg.activation) else 1) * ffn
-        lb = rows * rk * (h + fc1_out + ffn + h) * 4
-        if _mlp_tiles(h, ffn, is_gated(cfg.activation), rows,
-                      _wi(mlp.get("fc1_kernel")),
-                      _wi(mlp.get("fc2_kernel")), act_item,
-                      is_resident_leaf(mlp.get("fc1_kernel")),
-                      is_resident_leaf(mlp.get("fc2_kernel")),
-                      budget - lb) is not None:
-            return (f"LoRA epilogue (rank {rk}) needs the one-kernel "
-                    f"fused MLP body with its per-row factors "
-                    f"VMEM-resident — over the budget ({budget} B) at "
-                    f"rows={rows}; {flag}")
-    return None
-
-
-# ---------------------------------------------------------------------------
 # Batched-LoRA delta kernels (ISSUE 19): one decode batch, many adapters
 # ---------------------------------------------------------------------------
 # The device half of inference/lora.py: a decode batch carries a per-row
 # bank-slot id (0 = the NULL adapter), and every LoRA-targeted matmul
 # adds delta[b] = (x[b] @ A_{id[b]}) @ B_{id[b]} to its base output.
-# Three interchangeable per-row-exact implementations:
+# Two interchangeable per-row-exact implementations:
 #
 #   - lora_delta_reference  the jnp oracle AND the eager fallback:
 #                           gather the per-row factors, two einsums in
@@ -2346,14 +1052,16 @@ def megakernel_ineligible_reason(cfg, *, batch, tp_paged=False,
 #                           table so each grid step DMAs exactly one
 #                           adapter's [din, rank]/[rank, dout] factors
 #                           from the bank (vs the reference's [rows, …]
-#                           gathered copies);
-#   - the megakernel epilogue (``lora=`` on the fused bodies above):
-#                           per-row gathered factors ride into the
-#                           no-grid fused kernels as extra operands.
+#                           gathered copies).
 #
-# All three compute row b's delta from row b's x and factors ONLY —
+# Both compute row b's delta from row b's x and factors ONLY —
 # never from batch composition — which is what makes a mixed-tenant
 # batch token-exact vs serving each tenant serially.
+
+
+# What one grid step of the segmented kernel may hold in VMEM (Mosaic's
+# default scope is 16 MiB); a delta over it takes the eager fallback.
+LORA_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def lora_segment_info(row_adapter):
@@ -2400,16 +1108,15 @@ def lora_kernel_ineligible_reason(din: int, dout: int, rank: int,
         return (f"adapter rank {rank} exceeds min(din={din}, "
                 f"dout={dout}) — a low-rank delta this fat is an eager "
                 f"gather, not a segmented GEMM")
-    budget = get_megakernel_vmem_budget()
     # One grid step holds x [rows, din], one adapter's factors, the
     # rank-space intermediate and the fp32 accumulator + row_seg.
     need = 4 * (rows * din + din * rank + rank * dout
                 + rows * rank + rows * dout + rows)
-    if need > budget:
+    if need > LORA_VMEM_BUDGET:
         return (f"segmented-LoRA kernel operands ({need} B at "
                 f"rows={rows}, din={din}, dout={dout}, rank={rank}) "
-                f"exceed the VMEM budget ({budget} B) — raise "
-                f"--megakernel-vmem-budget or take the eager fallback")
+                f"exceed the VMEM budget ({LORA_VMEM_BUDGET} B): the eager "
+                f"fallback serves it")
     return None
 
 
@@ -2502,7 +1209,7 @@ def _lora_rows_delta(x, bank_pair, row_adapter):
 def apply_lora_delta(y, x, lora, target):
     """Add ``target``'s adapter delta to base output y (computed from
     input x), when lora carries that target; no-op otherwise. The ONE
-    call-site helper the unfused forward passes use — delta in fp32,
+    call-site helper the forward passes use — delta in fp32,
     cast into y's dtype at the add (zero-B adapters add an exact 0.0
     and leave y's token stream bitwise unchanged)."""
     if lora is None or target not in lora["banks"]:

@@ -72,7 +72,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                   zigzag: bool = False, segment_ids=None,
                   page_table=None, active=None, chunk_counts=None,
                   tp_sharded: bool = False, kv_scales=None,
-                  fused_decode: bool = False, fp8=None, lora=None):
+                  fp8=None, lora=None):
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
 
     page_table/active: paged-KV decode (inference/paged_cache.py) —
@@ -88,13 +88,6 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     the local [B, S/tp, H] seq chunk; norms/residuals run on it directly
     (elementwise over seq) and the sublayers take their ring paths.
 
-    fused_decode: megakernel decode body (ISSUE 11) — the s == 1 paged
-    decode layer runs as the three fused Pallas kernels around the
-    generated paged-attention kernel (ops/pallas/kernel_gen.py
-    fused_layer_decode) instead of the ~15-fusion unfused tail. Callers
-    (DynamicInferenceEngine fused_decode=True) gate eligibility via
-    kernel_gen.megakernel_ineligible_reason; streams stay token-exact.
-
     fp8: this layer's delayed-scaling amax state (training/fp8.py,
     ISSUE 13) — {"attention": {"qkv", "out"}, "mlp": {"fc1", "fc2"}}
     sub-dicts threaded into the tp-overlap ring GEMMs; the updated
@@ -104,34 +97,9 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     {"row_adapter": [B] int32 bank slots, "banks": {target: (a, b)}}
     with THIS layer's factor banks a [slots, din, r] / b [slots, r, dout]
     per RESIDENT_KERNELS target. Serving paths only: each projection
-    matmul grows a ``base(x) + B_i A_i x`` delta (unfused via
-    kernel_gen.apply_lora_delta, fused via the megakernel LoRA
-    epilogues); slot 0 is the all-zero null adapter."""
-    if fused_decode:
-        if page_table is None or kv_cache is None or "moe" in p:
-            raise ValueError(
-                "fused_decode covers the dense-MLP paged "
-                "decode/multiquery bodies only — gate callers on "
-                "kernel_gen.megakernel_ineligible_reason")
-        from megatronapp_tpu.ops.pallas.kernel_gen import (
-            fused_layer_decode, fused_layer_multiquery,
-        )
-        if chunk_counts is not None:
-            # Ragged multi-token rows (speculative verify / chunked
-            # prefill): the fused kernels run on the flattened B·S rows
-            # around the ragged paged-attention kernel.
-            return fused_layer_multiquery(
-                p, x, cfg, rope_cos, rope_sin, kv_cache,
-                cache_positions, chunk_counts, page_table, active,
-                layer_id, kv_scales=kv_scales, lora=lora)
-        if x.shape[1] != 1:
-            raise ValueError(
-                "fused_decode without chunk_counts is the s == 1 "
-                "decode body — pass chunk_counts for ragged "
-                "multi-token steps")
-        return fused_layer_decode(p, x, cfg, rope_cos, rope_sin, kv_cache,
-                                  cache_positions, page_table, active,
-                                  layer_id, kv_scales=kv_scales, lora=lora)
+    matmul grows a ``base(x) + B_i A_i x`` delta
+    (kernel_gen.apply_lora_delta); slot 0 is the all-zero null
+    adapter."""
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
                    cfg.layernorm_epsilon)

@@ -1,36 +1,22 @@
-"""Per-decode-step dispatch accounting (ISSUE 11 observability).
+"""Launch counts of a traced step (``GET /stats``' ``decode_dispatch``).
 
-At decode batch sizes the per-token step is dispatch-dominated, not
-FLOP-dominated (PERF.md round-2: 35.7% MFU for the full step vs 63.6%
-for one layer body) — so the megakernel work's figure of merit is "how
-many kernels does one decode step launch", measured deterministically
-(no wall clock, no chip needed).
-
-Two probes, both off the traced/compiled module:
-
-``jaxpr_launch_stats`` — the GATE metric. Walks the closed jaxpr of the
-decode step and estimates kernel launches per executed step: each
-``pallas_call`` is exactly ONE launch (a TPU custom call — on CPU the
-interpret-mode expansion is a simulation detail, which is why the CPU
-HLO text is NOT the gate: it inlines the kernels and inverts the
-comparison), a ``scan`` contributes length × its body's launches plus
-ceil(length / unroll) loop steps (the while-iteration overhead the
-scan-unroll lever removes), and ordinary equations count one launch
-apiece minus a small free-op set (reshape & friends never dispatch).
-Pre-fusion op counts overestimate both A/B legs the same way, so the
-REDUCTION is sound; tests and tools/megakernel_benchmark.py gate on it.
-
-``module_dispatch_stats`` / ``compiled_stats`` — the RECORD metrics:
-optimized-HLO fusion/custom-call/while counts plus the XLA cost-model
-totals (flops, bytes accessed) of the actually-compiled module, reported
-alongside for the round tables; not yet validated on the chip.
+``jaxpr_launch_stats`` walks a closed jaxpr and estimates the kernel
+launches of one execution: each ``pallas_call`` is ONE launch (a TPU
+custom call — on the CPU the interpret-mode expansion is a simulation
+detail, which is why the count is taken from the jaxpr and not from
+compiled HLO), a ``scan`` contributes length × its body's launches plus
+ceil(length / unroll) loop steps, and ordinary equations count one
+launch apiece minus a small free-op set (reshape & friends never
+dispatch). It counts equations before XLA fuses them, so `launches`
+overestimates; `kernels`, the pallas_calls a step, is exact, and is what
+chip_smoke.py checks of a served decode step. Nothing is compiled or
+executed.
 """
 
 from __future__ import annotations
 
 import math
-import re
-from typing import Dict, Optional
+from typing import Dict
 
 # Equations that never become their own kernel launch (pure
 # layout/metadata in XLA).
@@ -110,78 +96,4 @@ def launch_stats(fn, *args, **kwargs) -> Dict[str, float]:
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     stats = jaxpr_launch_stats(closed.jaxpr)
     stats["dispatches_per_step"] = stats["launches"] + stats["loop_steps"]
-    return stats
-
-
-# ---------------------------------------------------------------------------
-# Compiled-module record metrics (optimized HLO text + XLA cost model)
-# ---------------------------------------------------------------------------
-
-_HDR = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*)?\{\s*$")
-_WHILE_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
-
-
-def _split_computations(hlo_text: str) -> Dict[str, str]:
-    """{computation name: body text} from HLO long text. Line-based:
-    computation headers end with '{' and bodies close with a bare '}'
-    (inline one-line metadata braces never span lines)."""
-    comps: Dict[str, str] = {}
-    name = None
-    buf: list = []
-    for line in hlo_text.splitlines():
-        if name is None:
-            m = _HDR.match(line.strip())
-            if m and "=" not in line.split("{")[0]:
-                name = m.group(2)
-                buf = []
-        else:
-            if line.strip() == "}":
-                comps[name] = "\n".join(buf)
-                name = None
-            else:
-                buf.append(line)
-    return comps
-
-
-def module_dispatch_stats(hlo_text: str) -> Dict:
-    """Fusion / custom-call / while counts of one optimized HLO module,
-    split into while-loop bodies vs the rest. NOTE: on CPU the
-    interpret-mode Pallas kernels are inlined into ordinary HLO here —
-    these counts are the record of what THIS backend compiled, not the
-    TPU launch count (jaxpr_launch_stats is the gate)."""
-    comps = _split_computations(hlo_text)
-    body_names = set(_WHILE_BODY.findall(hlo_text))
-    in_loop = {"fusions": 0, "custom_calls": 0}
-    out_loop = {"fusions": 0, "custom_calls": 0}
-    for name, body in comps.items():
-        # Fusion computations' insides execute as ONE kernel — count
-        # only the call sites.
-        if name.startswith("fused_computation"):
-            continue
-        tgt = in_loop if name in body_names else out_loop
-        tgt["fusions"] += len(re.findall(r"=\s*\S+\s+fusion\(", body))
-        tgt["custom_calls"] += len(
-            re.findall(r"=\s*\S+\s+custom-call\(", body))
-    return {"computations": len(comps),
-            "while_loops": len(body_names),
-            "in_loop": in_loop, "out_of_loop": out_loop}
-
-
-def compiled_stats(jitted, *args, **kwargs) -> Dict:
-    """Lower + compile `jitted` at the given (abstract or concrete)
-    arguments: module_dispatch_stats of the optimized HLO plus the XLA
-    cost-model totals (flops / bytes accessed) when the backend exposes
-    them. This is an AOT compile — one extra compilation at these
-    shapes; callers cache."""
-    compiled = jitted.lower(*args, **kwargs).compile()
-    stats = module_dispatch_stats(compiled.as_text())
-    try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        stats["cost"] = {k: float(cost[k])
-                         for k in ("flops", "bytes accessed")
-                         if k in cost}
-    except Exception:  # noqa: BLE001 — cost model is backend-optional
-        pass
     return stats

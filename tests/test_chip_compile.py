@@ -448,29 +448,14 @@ def _compile_family(family, one_chip):
         return jax.jit(functools.partial(
             kg.paged_attention_latent, softmax_scale=0.07)).lower(
                 *args).compile()
-    if family.startswith("paged"):
-        b, h, d, nb, bs = 4, 12, 64, 256, 16
-        pages, table, lens = bf16(nb, bs, h, d), i32(b, 16), i32(b)
-        if family == "paged_decode":
-            return jax.jit(paged_attention_decode).lower(
-                bf16(b, h, d), pages, pages, table, lens).compile()
-        return jax.jit(paged_attention_multiquery).lower(
-            bf16(b, 32, h, d), pages, pages, table, lens, lens).compile()
-    assert family == "fused"
-    # float32 and a narrow model: the fused bodies' bf16 matmuls do not pass
-    # Mosaic's verifier today (ROADMAP S7), and this test is about the name.
-    from megatronapp_tpu.config.transformer_config import TransformerConfig
-    from megatronapp_tpu.models.gpt import init_gpt_params
-    cfg = TransformerConfig(num_layers=1, hidden_size=256,
-                            num_attention_heads=4, vocab_size=512,
-                            max_position_embeddings=64,
-                            compute_dtype=jnp.float32)
-    layer = jax.tree.map(
-        lambda s: _sds(s.shape[1:], s.dtype, one_chip),
-        jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
-                       jax.random.PRNGKey(0))["block"])
-    return jax.jit(lambda p, x: kg._fused_mlp(x, p, cfg)).lower(
-        layer, _sds((8, cfg.hidden_size), jnp.float32, one_chip)).compile()
+    assert family in ("paged_decode", "paged_mq")
+    b, h, d, nb, bs = 4, 12, 64, 256, 16
+    pages, table, lens = bf16(nb, bs, h, d), i32(b, 16), i32(b)
+    if family == "paged_decode":
+        return jax.jit(paged_attention_decode).lower(
+            bf16(b, h, d), pages, pages, table, lens).compile()
+    return jax.jit(paged_attention_multiquery).lower(
+        bf16(b, 32, h, d), pages, pages, table, lens, lens).compile()
 
 
 @pytest.mark.parametrize("family,prefixes", [
@@ -483,7 +468,6 @@ def _compile_family(family, one_chip):
     ("paged_decode_latent", ["paged_decode_latent"]),
     ("paged_mq_latent", ["paged_mq_latent"]),
     ("paged_append", ["paged_append"]),
-    ("fused", ["fused_"]),
 ])
 def test_kernel_family_names(one_chip, chip_compile, family, prefixes):
     """Each family's kernel is a `tpu_custom_call` whose HLO instruction

@@ -193,29 +193,6 @@ class TestTpPagedEngine:
 
 # ---------------------------------------------------------------------------
 class TestDisaggHandoff:
-    def test_fused_decode_threads_to_decode_engine(self, gqa_params):
-        """--megakernel-decode composes with --serve-disagg since
-        ISSUE 16: fused_decode threads into the DECODE engine only
-        (the prefill worker keeps the unfused multi-query body it
-        already batches), and outputs stay oracle-exact through the
-        handoff."""
-        cfg, params = gqa_params
-        rng = np.random.default_rng(6)
-        prompts = [rng.integers(0, 128, n).astype(np.int32)
-                   for n in (7, 19)]
-        eng = DisaggServingEngine(
-            params, cfg, max_batch=2, max_seq_len=64,
-            prefill_buckets=(32,), block_size=8, prefill_chunk=8,
-            prefill_slots=1, fused_decode=True)
-        assert eng.megakernel, "decode engine must report the fused step"
-        rids = [eng.add_request(p, 5, SamplingParams(greedy=True))
-                for p in prompts]
-        res = eng.run_to_completion()
-        for rid, p in zip(rids, prompts):
-            assert res[rid].tolist() == _greedy_oracle(params, cfg, p, 5)
-        eng.pool.audit()
-        assert eng.pool.blocks_in_use() == 0
-
     def test_oracle_exact_and_refcount_transfer(self, gqa_params):
         """Outputs oracle-exact through the prefill→decode handoff, and
         the handoff itself is a pure ownership transfer: the decode slot

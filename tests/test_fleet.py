@@ -310,43 +310,6 @@ class TestMigratedStreams:
 
 
 # ---------------------------------------------------------------------------
-class TestFusedFleet:
-    """--megakernel-decode composes with the fleet since ISSUE 16:
-    fused_decode threads into every replica build, and live migration
-    (export_slot/import_slot) stays token-exact under the fused step —
-    the KV payload is engine-agnostic."""
-
-    def test_migration_token_exact_under_fused_decode(self, gqa_params):
-        cfg, params = gqa_params
-        rng = np.random.default_rng(4)
-        prompt = rng.integers(0, 128, 13).astype(np.int32)
-        base_eng = _engine(params, cfg)
-        r0 = base_eng.add_request(prompt, 10, SamplingParams(greedy=True))
-        base = base_eng.run_to_completion()[r0].tolist()
-
-        def factory(i, **h):
-            return DynamicInferenceEngine(
-                params, cfg, max_batch=2, max_seq_len=48,
-                prefill_buckets=(16,), paged=True, block_size=8,
-                kv_cache_dtype="bf16", fused_decode=True)
-
-        fr = FleetRouter(engine_factory=factory, num_replicas=2,
-                         migrate=True)
-        assert all(rep.engine.megakernel for rep in fr.replicas)
-        rid = fr.add_request(prompt, 10, SamplingParams(greedy=True))
-        src = fr._owner[rid]
-        while len(fr.replicas[src].engine.requests[rid].generated) < 4:
-            fr.step()
-        assert fr.migrate_request(rid, 1 - src)
-        out = fr.run_to_completion()[rid].tolist()
-        assert out == base
-        for rep in fr.replicas:
-            rep.engine.pool.audit()
-        assert fr.replicas[src].engine.pool.blocks_in_use() == 0
-        assert fr.router_stats["migrations"] == 1
-
-
-# ---------------------------------------------------------------------------
 class TestAffinityRouting:
     def test_followers_steer_to_prefix_replica(self, gqa_params):
         """Same-prefix followers land on the replica whose pool holds
@@ -907,16 +870,6 @@ class TestFleetArgs:
         )
         args = self._parse(["--engine", "dynamic", "--paged-kv-cache",
                             "--serve-fleet", "2", "--fleet-migrate"])
-        validate_serving_args(args)
-
-    def test_fleet_megakernel_combo_passes(self):
-        """--serve-fleet composes with --megakernel-decode since
-        ISSUE 16 (fused_decode threads into every replica build)."""
-        from megatronapp_tpu.config.arguments import (
-            validate_serving_args,
-        )
-        args = self._parse(["--engine", "dynamic", "--paged-kv-cache",
-                            "--serve-fleet", "2", "--megakernel-decode"])
         validate_serving_args(args)
 
     def test_mismatched_replica_pools_rejected(self, gqa_params):

@@ -12,9 +12,8 @@ Layer by layer:
   and the dtype registry keeps the CLI choices / server validation /
   pool check in lockstep;
 - engine: greedy streams on the fp8 pool match the bf16-pool streams
-  and the dense oracle; the fused megakernel decode stays token-exact
-  on fp8 pools; the disagg handoff ships fp8 rows + scales through the
-  existing drills;
+  and the dense oracle; the disagg handoff ships fp8 rows + scales
+  through the existing drills;
 - training: fp8 ring GEMMs track the bf16 loss curve within the
   documented tolerance on the CPU A/B (tp2), the amax/scale state
   survives checkpoint save → restore bitwise, all three ZeRO-1
@@ -288,32 +287,6 @@ class TestFp8Engine:
         assert base == f8
         for p, out in zip(prompts, f8):
             assert out == _greedy_oracle(params, cfg, p, 6)
-
-    def test_fused_megakernel_on_fp8_pool(self):
-        """--megakernel-decode on an fp8 pool: the fused decode step
-        quantizes/dequantizes through the same generated kernels and
-        streams stay token-exact vs the unfused fp8 engine."""
-        cfg = _gqa_cfg()
-        params, _ = init_gpt_params(jax.random.PRNGKey(7), cfg)
-        rng = np.random.default_rng(4)
-        prompts = [rng.integers(0, 128, n).astype(np.int32)
-                   for n in (5, 11)]
-
-        def run(fused):
-            eng = DynamicInferenceEngine(
-                params, cfg, max_batch=2, max_seq_len=48,
-                prefill_buckets=(16,), paged=True, block_size=8,
-                kv_cache_dtype="fp8", fused_decode=fused)
-            if fused:
-                assert eng.megakernel, "fp8 pool must stay megakernel-" \
-                    "eligible (only resident weights are excluded)"
-            ids = [eng.add_request(p, 5, SamplingParams(greedy=True))
-                   for p in prompts]
-            res = eng.run_to_completion()
-            eng.pool.audit()
-            return [res[r].tolist() for r in ids]
-
-        assert run(False) == run(True)
 
     def test_spec_decode_exact_on_fp8_pool(self):
         cfg = _gqa_cfg()

@@ -1,24 +1,21 @@
-"""ISSUE 11 — megakernel decode: kernel generator + fused decode step.
+"""The paged-attention kernel generator (ops/pallas/kernel_gen.py).
 
-Pins, per the acceptance criteria:
-
-- the GENERATOR (ops/pallas/kernel_gen.py) emits kernels held two ways
-  (ISSUE 29: the walk folds several pages a step, an order the legacy
-  bodies do not have): BITWISE against a test-local jax.numpy replay of
-  the walk (same pages a step, same tile order), and allclose against
-  the legacy hand-written variants it replaced. The legacy bodies are
-  deleted from the tree, so FROZEN copies live here as the oracle
-  (verbatim the pre-ISSUE-11 `_decode_kernel` / `_multiquery_kernel` +
-  their pallas_call builders), pinned across {fp32, bf16} × {bf16,
-  int8 pools} × {tp1, tp2} × {q_len 1, ragged} × {GQA, MHA};
-- the FUSED decode step (fused_decode=True) leaves greedy streams
-  token-exact vs the unfused engine AND the dense oracle (bf16 + int8
-  pools, scan-unroll on), while the estimated kernel launches per
-  decode step (utils/dispatch.py) drop measurably;
+- the GENERATOR emits kernels held two ways (ISSUE 29: the walk folds
+  several pages a step, an order the legacy bodies do not have): BITWISE
+  against a test-local jax.numpy replay of the walk (same pages a step,
+  same tile order), and allclose against the legacy hand-written
+  variants it replaced. The legacy bodies are deleted from the tree, so
+  FROZEN copies live here as the oracle (verbatim the pre-ISSUE-11
+  `_decode_kernel` / `_multiquery_kernel` + their pallas_call
+  builders), pinned across {fp32, bf16} × {bf16, int8 pools} × {tp1,
+  tp2} × {q_len 1, ragged} × {GQA, MHA};
+- the walk itself: all four bodies over fp32, bf16, int8 and fp8 pages,
+  scale pages included, never read past a slot's length;
+- the engine on these kernels: what /stats counts of a decode step, and
+  streams at the dense cell's heads of 80 against the dense oracle;
 - flash backward head-fold grad parity <= 1e-5 and scan-unroll loss
   parity (exact) — the two staged PERF levers;
-- eligibility reasons name the SPECIFIC failed predicate;
-- the megakernel benchmark smoke-gates.
+- eligibility reasons name the SPECIFIC failed predicate.
 """
 
 import dataclasses
@@ -826,46 +823,71 @@ _W_BS, _W_MB, _W_SQ = 16, 20, 3          # 8 pages a step: 2.5 tiles a row
 _W_SCALE = 1.0 / ((16 + 8) ** 0.5)
 
 
-def _walk_case(body, lens, nan_past=False, seed=29):
+# pages' dtype, and the dtype their rows are quantised to (None: stored
+# as they are)
+_WALK_POOLS = {"fp32": (jnp.float32, None), "bf16": (jnp.bfloat16, None),
+               "int8": (jnp.float32, jnp.int8),
+               "fp8": (jnp.float32, jnp.float8_e4m3fn)}
+
+
+def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29):
     """(kernel output, oracle output or None) of `body` over slots of
-    `lens` cached rows at the walk shapes. nan_past: every table entry
-    past a slot's length names a page filled with NaN (no oracle then:
-    the oracles gather the whole table)."""
+    `lens` cached rows at the walk shapes, pages stored as `pool` (int8
+    and fp8 pages come with their fp32 scale pools). nan_past: every
+    table entry past a slot's length names a page filled with NaN, and a
+    scale page filled with NaN (an int8 page cannot hold one: there the
+    scale page alone carries it); no oracle then: the oracles gather the
+    whole table."""
     rng = np.random.default_rng(seed)
     b, ragged, latent = len(lens), "_mq" in body, "latent" in body
     s_q = _W_SQ if ragged else 0
+    dtype, qdtype = _WALK_POOLS[pool]
     kv_lens = jnp.asarray(lens, jnp.int32)
     q_lens = jnp.minimum(kv_lens, _W_SQ) if ragged else None
     if latent:
         ql, qp, lat, pe, w_v, tbl, _, _, _ = _mk_latent_inputs(
-            rng, b, s_q, 4, 32, 8, 16, _W_BS, _W_MB, False, jnp.float32)
+            rng, b, s_q, 4, 32, 8, 16, _W_BS, _W_MB, False, dtype)
         pools = [lat, pe]
     else:
         q, kp, vp, tbl, _, _, _ = _mk_inputs(
-            rng, b, s_q, 4, 2, 16, _W_BS, _W_MB, False, jnp.float32)
+            rng, b, s_q, 4, 2, 16, _W_BS, _W_MB, False, dtype)
         pools = [kp, vp]
+    scales = [None, None]
+    if qdtype is not None:
+        pools, scales = zip(*(quantize_kv_rows(p, dtype=qdtype)
+                              for p in pools))
     if nan_past:
         # the pool's second half is NaN, and only entries past a slot's
         # length name it
         nb = pools[0].shape[0]
-        pools = [jnp.concatenate([p, jnp.full_like(p, jnp.nan)])
-                 for p in pools]
+
+        def dirty(p):
+            fill = jnp.nan if jnp.issubdtype(p.dtype, jnp.floating) else 127
+            return jnp.concatenate([p, jnp.full_like(p, fill)])
+
+        pools = [dirty(p) for p in pools]
+        scales = [None if sc is None else dirty(sc) for sc in scales]
         held = (kv_lens[:, None] + _W_BS - 1) // _W_BS
         tbl = jnp.where(jnp.arange(_W_MB)[None, :] < held, tbl, tbl + nb)
     if latent:
         args = (ql, qp, *pools, tbl, kv_lens, w_v)
-        kw = dict(q_lens=q_lens, softmax_scale=_W_SCALE)
+        kw = dict(q_lens=q_lens, softmax_scale=_W_SCALE,
+                  lat_scales=scales[0], pe_scales=scales[1])
         out = paged_attention_latent(*args, **kw)
         ref = None if nan_past else paged_attention_latent_reference(
             *args, **kw)
-    elif ragged:
-        out = paged_attention(q, *pools, tbl, kv_lens, q_lens=q_lens)
-        ref = None if nan_past else paged_attention_multiquery_reference(
-            q, *pools, tbl, kv_lens, q_lens)
     else:
-        out = paged_attention(q, *pools, tbl, kv_lens)
-        ref = None if nan_past else paged_attention_reference(
-            q, *pools, tbl, kv_lens)
+        kw = dict(k_scales=scales[0], v_scales=scales[1])
+        if ragged:
+            out = paged_attention(q, *pools, tbl, kv_lens, q_lens=q_lens,
+                                  **kw)
+            ref = (None if nan_past else
+                   paged_attention_multiquery_reference(
+                       q, *pools, tbl, kv_lens, q_lens, **kw))
+        else:
+            out = paged_attention(q, *pools, tbl, kv_lens, **kw)
+            ref = None if nan_past else paged_attention_reference(
+                q, *pools, tbl, kv_lens, **kw)
     if ragged:
         # rows past a slot's q_len are padding: whatever they hold is
         # dropped by the caller
@@ -883,6 +905,16 @@ class TestWalk:
 
     TOL = dict(atol=2e-5, rtol=2e-5)
 
+    def _tol(self, body, pool):
+        """Quantised pages are dequantised by kernel and oracle alike, so
+        they agree as fp32 pages do; bf16 pages to the last bits of a
+        bf16 result, as TestGeneratorBitwise and TestLatentKernelPins
+        hold them."""
+        if pool != "bf16":
+            return self.TOL
+        return (dict(atol=3e-2, rtol=3e-2) if "latent" in body
+                else _legacy_tol(jnp.bfloat16))
+
     @pytest.mark.parametrize("length", [
         1, _W_BS, _W_BS + 1, 8 * _W_BS, 8 * _W_BS + 1, _W_MB * _W_BS],
         ids=["one", "page", "page+1", "tile", "tile+1", "table"])
@@ -891,10 +923,12 @@ class TestWalk:
         out, ref = _walk_case(body, [length])
         _assert_close(out, ref, **self.TOL)
 
+    @pytest.mark.parametrize("pool", ["fp32", "bf16", "int8", "fp8"])
     @pytest.mark.parametrize("body", _BODIES)
-    def test_shortest_beside_longest(self, body):
-        out, ref = _walk_case(body, [1, _W_MB * _W_BS, 8 * _W_BS + 1, 2])
-        _assert_close(out, ref, **self.TOL)
+    def test_shortest_beside_longest(self, body, pool):
+        out, ref = _walk_case(body, [1, _W_MB * _W_BS, 8 * _W_BS + 1, 2],
+                              pool=pool)
+        _assert_close(out, ref, **self._tol(body, pool))
 
     @pytest.mark.parametrize("body", ["paged_mq", "paged_mq_latent"])
     def test_ragged_tail_straddles_a_tile(self, body):
@@ -912,13 +946,15 @@ class TestWalk:
         assert bool(jnp.all(out[0] == 0.0))
         _assert_close(out[1], ref[1], **self.TOL)
 
+    @pytest.mark.parametrize("pool", ["fp32", "bf16", "int8", "fp8"])
     @pytest.mark.parametrize("body", _BODIES)
-    def test_pages_past_the_length_are_never_read(self, body):
-        """Table entries past a slot's length name NaN pages: the output
-        is the clean run's, bit for bit."""
+    def test_pages_past_the_length_are_never_read(self, body, pool):
+        """Table entries past a slot's length name NaN pages, and NaN
+        scale pages where the pool has them: the output is the clean
+        run's, bit for bit."""
         lens = [1, _W_BS + 1, 8 * _W_BS + 1, 0]
-        dirty, _ = _walk_case(body, lens, nan_past=True)
-        clean, _ = _walk_case(body, lens)
+        dirty, _ = _walk_case(body, lens, nan_past=True, pool=pool)
+        clean, _ = _walk_case(body, lens, pool=pool)
         assert bool(jnp.all(jnp.isfinite(dirty)))
         assert bool(jnp.all(dirty == clean))
 
@@ -957,7 +993,7 @@ class TestWalk:
 
 
 # ---------------------------------------------------------------------------
-# Fused (megakernel) decode step
+# The engine on the paged kernels
 # ---------------------------------------------------------------------------
 
 
@@ -970,14 +1006,12 @@ def _engine_cfg(**over):
     return TransformerConfig(**kw)
 
 
-@pytest.fixture(scope="module")
-def engine_setup():
-    cfg = _engine_cfg()
-    params, _ = init_gpt_params(jax.random.PRNGKey(5), cfg)
-    rng = np.random.default_rng(5)
+def _engine_case(cfg, seed=5):
+    params, _ = init_gpt_params(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (4, 9, 17)]
-    return cfg, params, prompts
+    return params, prompts
 
 
 def _stream(cfg, params, prompts, max_new=8, **kw):
@@ -998,82 +1032,38 @@ def _greedy_oracle(params, cfg, prompt, n):
     return toks[0].tolist()
 
 
-class TestFusedDecode:
-    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-    def test_streams_token_exact_vs_plain(self, engine_setup, kv_dtype):
-        cfg, params, prompts = engine_setup
-        plain, _ = _stream(cfg, params, prompts, kv_cache_dtype=kv_dtype)
-        fused, eng = _stream(cfg, params, prompts, kv_cache_dtype=kv_dtype,
-                             fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-        eng.pool.audit()
-
-    def test_streams_match_dense_oracle_with_unroll(self, engine_setup):
-        """Fused + scan-unroll streams == the step-by-step dense greedy
-        oracle (absolute pin, not just engine-vs-engine)."""
-        cfg, params, prompts = engine_setup
-        cfg2 = dataclasses.replace(cfg, scan_unroll=2)
-        fused, _ = _stream(cfg2, params, prompts, fused_decode=True)
-        for p, out in zip(prompts, fused):
-            assert out == _greedy_oracle(params, cfg, p, 8)
-
-    def test_dispatch_count_reduced(self, engine_setup):
-        """THE acceptance gate: estimated kernel launches per compiled
-        decode step measurably reduced (off the traced module — each
-        pallas_call is one TPU custom call; wall time is not the
-        gate)."""
-        cfg, params, prompts = engine_setup
-        _, plain = _stream(cfg, params, prompts[:1], max_new=2)
-        _, fused = _stream(dataclasses.replace(cfg, scan_unroll=2),
-                           params, prompts[:1], max_new=2,
-                           fused_decode=True)
-        sp = plain.dispatch_stats()
-        sf = fused.dispatch_stats()
-        assert sf["dispatches_per_step"] <= 0.85 * sp["dispatches_per_step"]
-        assert sf["kernels"] > sp["kernels"]          # fat pallas kernels
-        assert sf["loop_steps"] < sp["loop_steps"]    # unroll lever
-        # Cached per jit build; /stats serves it without recompiling.
-        assert plain.dispatch_stats() is sp
-
-    def test_stats_snapshot_exposes_dispatch(self, engine_setup):
-        cfg, params, prompts = engine_setup
-        _, eng = _stream(cfg, params, prompts[:1], max_new=2,
-                         fused_decode=True)
+class TestPagedEngine:
+    def test_stats_snapshot_exposes_dispatch(self):
+        cfg = _engine_cfg()
+        params, prompts = _engine_case(cfg)
+        _, eng = _stream(cfg, params, prompts[:1], max_new=2)
         snap = eng.stats_snapshot()
-        assert snap["megakernel"] is True
         assert snap["decode_traces"] >= 1          # jit-count counter
-        assert "decode_dispatch" not in snap       # cheap by default
-        snap = eng.stats_snapshot(include_dispatch=True)
-        assert snap["decode_dispatch"]["dispatches_per_step"] > 0
-        assert "compiled" in snap["decode_dispatch"]
+        assert "decode_dispatch" not in snap       # no tracing by default
+        disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
+        # a layer's K append, V append and attention
+        assert disp["kernels"] == 3 * cfg.num_layers
+        assert "compiled" not in disp
 
-    def test_ineligible_fallback_is_loud_and_unfused(self, caplog):
-        """MoE config (still a carve-out): the engine keeps the unfused
-        step and logs the SPECIFIC predicate. (MLA left this list in
-        ISSUE 17 — see TestMLAFusedDecode.)"""
-        import logging
-        cfg = _engine_cfg(num_moe_experts=4, moe_router_topk=2)
-        params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
-        with caplog.at_level(logging.WARNING,
-                             "megatronapp_tpu.inference.dynamic_engine"):
-            eng = DynamicInferenceEngine(params, cfg, max_batch=2,
-                                         max_seq_len=64, paged=True,
-                                         block_size=8, fused_decode=True)
-        assert not eng.megakernel
-        assert any("MoE" in r.message for r in caplog.records)
-
-    def test_fused_requires_paged(self, engine_setup):
-        cfg, params, _ = engine_setup
-        with pytest.raises(ValueError, match="paged"):
-            DynamicInferenceEngine(params, cfg, max_batch=2,
-                                   max_seq_len=64, paged=False,
-                                   fused_decode=True)
-
-
-# ---------------------------------------------------------------------------
-# MLA fused decode (ISSUE 17 carve-out c)
-# ---------------------------------------------------------------------------
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_streams_at_heads_of_80(self, kv_dtype):
+        """The dense cell's head geometry (4 heads of 80 in 2 KV groups;
+        80 is no multiple of a lane tile's 128 and twice decided what
+        Mosaic would take): streams through the paged engine are the
+        dense oracle's, on the int8 pool as tests/test_kv_quant.py holds
+        int8 streams, and the pool's books balance. The default init, as
+        there: a large one makes the streams depend on their context,
+        and then a fresh engine now and then emits a wrong stream on the
+        CPU, at the parent too (ROADMAP S3: arrays handed to an
+        asynchronous step and then written)."""
+        cfg = _engine_cfg(hidden_size=320)
+        assert cfg.head_dim == 80
+        params, prompts = _engine_case(cfg, seed=80)
+        out, eng = _stream(cfg, params, prompts, kv_cache_dtype=kv_dtype)
+        eng.pool.audit()
+        assert eng.pool.pages[0].shape[-1] == 80
+        for p, toks in zip(prompts, out):
+            assert toks == _greedy_oracle(params, cfg, p, 8)
 
 
 def _mla_cfg(**over):
@@ -1082,325 +1072,6 @@ def _mla_cfg(**over):
     kw.update(over)
     return _engine_cfg(**kw)
 
-
-@pytest.fixture(scope="module")
-def mla_setup():
-    cfg = _mla_cfg()
-    params, _ = init_gpt_params(jax.random.PRNGKey(11), cfg)
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in (4, 9, 17)]
-    return cfg, params, prompts
-
-
-class TestMLAFusedDecode:
-    """ISSUE 17 carve-out (c): --megakernel-decode no longer rejects
-    multi_latent_attention — the fused MLA prologue (q path + kv_up
-    absorption) feeds the absorbed-q latent kernel inside one fused
-    layer body. Streams pinned token-exact vs the unfused engine (which
-    runs the SAME latent kernel via mla_forward) and the dense greedy
-    oracle."""
-
-    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-    def test_streams_token_exact_vs_plain(self, mla_setup, kv_dtype):
-        cfg, params, prompts = mla_setup
-        plain, _ = _stream(cfg, params, prompts, kv_cache_dtype=kv_dtype)
-        fused, eng = _stream(cfg, params, prompts,
-                             kv_cache_dtype=kv_dtype, fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-        eng.pool.audit()
-
-    def test_streams_match_dense_oracle(self, mla_setup):
-        cfg, params, prompts = mla_setup
-        fused, eng = _stream(cfg, params, prompts, fused_decode=True)
-        assert eng.megakernel
-        for p, out in zip(prompts, fused):
-            assert out == _greedy_oracle(params, cfg, p, 8)
-
-    def test_sampled_streams_token_exact(self, mla_setup):
-        """Sampled streams too: fused and unfused MLA steps produce the
-        same logits into the same per-request key chain."""
-        cfg, params, prompts = mla_setup
-        sp = SamplingParams(temperature=0.8, top_k=20, seed=9)
-
-        def run(**kw):
-            eng = DynamicInferenceEngine(params, cfg, max_batch=3,
-                                         max_seq_len=64, paged=True,
-                                         block_size=8, **kw)
-            ids = [eng.add_request(p, 8, sp) for p in prompts]
-            res = eng.run_to_completion()
-            return [res[i].tolist() for i in ids], eng
-
-        plain, _ = run()
-        fused, eng = run(fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-
-    def test_dispatch_count_reduced(self, mla_setup):
-        """The ISSUE 17 launch gate on the real engine: the fused MLA
-        decode step traces ≤0.85× the unfused step's kernel launches."""
-        cfg, params, prompts = mla_setup
-        _, plain = _stream(cfg, params, prompts[:1], max_new=2)
-        _, fused = _stream(dataclasses.replace(cfg, scan_unroll=2),
-                           params, prompts[:1], max_new=2,
-                           fused_decode=True)
-        sp = plain.dispatch_stats()
-        sf = fused.dispatch_stats()
-        assert sf["dispatches_per_step"] <= 0.85 * sp["dispatches_per_step"]
-
-    @pytest.mark.slow
-    def test_chunked_prefill_streams_token_exact(self, mla_setup):
-        """MLA chunked prefill (the only paged MLA prefill path since
-        ISSUE 17) rides the fused ragged multiquery step chunk by
-        chunk."""
-        cfg, params, prompts = mla_setup
-        plain, _ = _stream(cfg, params, prompts, prefill_chunk=8)
-        fused, eng = _stream(cfg, params, prompts, prefill_chunk=8,
-                             fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-
-
-# ---------------------------------------------------------------------------
-# Grid-tiled megakernel emission (ISSUE 16)
-# ---------------------------------------------------------------------------
-
-
-def _layer0(params):
-    """Layer-0 slice of the stacked block tree (resident {qint8,
-    qscale} leaves slice both members)."""
-    from megatronapp_tpu.inference.quantization import is_resident_leaf
-
-    def f(v):
-        if is_resident_leaf(v):
-            return {"qint8": v["qint8"][0], "qscale": v["qscale"][0]}
-        return v[0]
-
-    out = {}
-    for k, v in params["block"].items():
-        if isinstance(v, dict) and not is_resident_leaf(v):
-            out[k] = {k2: f(v2) for k2, v2 in v.items()}
-        else:
-            out[k] = f(v)
-    return out
-
-
-def _resident(params):
-    from megatronapp_tpu.inference.quantization import (
-        quantize_params, residentize_params,
-    )
-    q, _ = quantize_params(params, resident_only=True)
-    return residentize_params(q)
-
-
-class TestTiledMegakernel:
-    """Column-tiled emission is BITWISE the no-grid fast path: each
-    tile keeps the full contraction and recomputes the row norm from
-    the whole x block, so fp32 sums never reorder."""
-
-    @pytest.fixture(scope="class")
-    def kernel_inputs(self):
-        cfg = _engine_cfg()
-        params, _ = init_gpt_params(jax.random.PRNGKey(5), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1),
-                              (3, cfg.hidden_size), jnp.float32)
-        half = cfg.head_dim // 2
-        cos = jax.random.normal(jax.random.PRNGKey(2), (3, half),
-                                jnp.float32)
-        sin = jax.random.normal(jax.random.PRNGKey(3), (3, half),
-                                jnp.float32)
-        attn_flat = jax.random.normal(
-            jax.random.PRNGKey(4),
-            (3, cfg.num_attention_heads * cfg.head_dim), jnp.float32)
-        return cfg, params, x, cos, sin, attn_flat
-
-    @pytest.mark.parametrize("resident", [False, True],
-                             ids=["fp32", "resident-int8"])
-    def test_qkv_tiled_bitwise(self, kernel_inputs, resident):
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        cfg, params, x, cos, sin, _ = kernel_inputs
-        p0 = _layer0(_resident(params) if resident else params)
-        attn_p = {**p0["attention"], "ln1_scale": p0["ln1_scale"],
-                  **({"ln1_bias": p0["ln1_bias"]}
-                     if "ln1_bias" in p0 else {})}
-        ref = kg._fused_qkv(x, attn_p, cfg, cos, sin, tiles=1)
-        tiled = kg._fused_qkv(x, attn_p, cfg, cos, sin, tiles=2)
-        for a, b in zip(ref, tiled):
-            assert bool(jnp.all(a == b))
-
-    @pytest.mark.parametrize("resident", [False, True],
-                             ids=["fp32", "resident-int8"])
-    def test_out_proj_tiled_bitwise(self, kernel_inputs, resident):
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        cfg, params, x, _, _, attn_flat = kernel_inputs
-        p0 = _layer0(_resident(params) if resident else params)
-        attn_p = {**p0["attention"], "ln1_scale": p0["ln1_scale"]}
-        ref = kg._fused_out_proj(attn_flat, attn_p, cfg, x, tiles=1)
-        tiled = kg._fused_out_proj(attn_flat, attn_p, cfg, x, tiles=2)
-        assert bool(jnp.all(ref == tiled))
-
-    @pytest.mark.parametrize("resident", [False, True],
-                             ids=["fp32", "resident-int8"])
-    def test_mlp_tiled_bitwise(self, kernel_inputs, resident):
-        """The tiled MLP is a TWO-kernel split (fc1+act over ffn
-        columns, fc2+residual over H columns); the intermediate lives
-        in compute dtype, so store/reload is lossless vs the no-grid
-        single kernel."""
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        cfg, params, x, _, _, _ = kernel_inputs
-        p0 = _layer0(_resident(params) if resident else params)
-        ref = kg._fused_mlp(x, p0, cfg)
-        tiled = kg._fused_mlp(x, p0, cfg, tiles=(2, 2))
-        assert bool(jnp.all(ref == tiled))
-
-    def test_budget_setter_validates(self):
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        old = kg.get_megakernel_vmem_budget()
-        try:
-            with pytest.raises(ValueError, match="positive byte count"):
-                kg.set_megakernel_vmem_budget(0)
-            with pytest.raises(ValueError, match="positive byte count"):
-                kg.set_megakernel_vmem_budget(-4096)
-            assert kg.set_megakernel_vmem_budget(old) == old
-        finally:
-            kg.set_megakernel_vmem_budget(old)
-
-    def test_budget_setter_warns_above_vmem(self, caplog):
-        import logging
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        old = kg.get_megakernel_vmem_budget()
-        try:
-            with caplog.at_level(logging.WARNING,
-                                 "megatronapp_tpu.ops.pallas.kernel_gen"):
-                kg.set_megakernel_vmem_budget(32 * 1024 * 1024)
-            assert any("VMEM" in r.message for r in caplog.records)
-        finally:
-            kg.set_megakernel_vmem_budget(old)
-
-    def test_tiny_budget_stream_token_exact(self, engine_setup):
-        """Budget-driven tiling end to end: a budget small enough to
-        force qkv AND mlp grids (but large enough to stay eligible)
-        keeps the greedy stream token-exact."""
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        cfg, params, prompts = engine_setup
-        plain, _ = _stream(cfg, params, prompts)
-        old = kg.get_megakernel_vmem_budget()
-        try:
-            kg.set_megakernel_vmem_budget(192 * 1024)
-            # the plan actually tiles at this budget (qkv over both
-            # kv-head groups, mlp split)
-            rows = 32
-            assert kg._qkv_tiles(cfg.hidden_size, 4, 2, cfg.head_dim,
-                                 rows, 4, 4, 4, False, False,
-                                 192 * 1024) == 2
-            assert kg._mlp_tiles(cfg.hidden_size, cfg.ffn_hidden_size,
-                                 True, rows, 4, 4, 4, False, False,
-                                 192 * 1024) is not None
-            fused, eng = _stream(cfg, params, prompts, fused_decode=True)
-            assert eng.megakernel
-        finally:
-            kg.set_megakernel_vmem_budget(old)
-        assert plain == fused
-
-    @pytest.mark.slow
-    def test_large_shape_formerly_fallback_now_fused(self):
-        """THE ISSUE 16 acceptance gate: a shape whose fused MLP body
-        exceeds the VMEM budget (fc1 weights alone: 768*6144*4 ≈ 18.9
-        MB > 12 MiB) used to log the VMEM fallback; it now tiles, and
-        the traced decode step launches ≤0.85× the unfused engine's
-        kernels (launch_stats traces only — no AOT compile)."""
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        from megatronapp_tpu.utils.dispatch import launch_stats
-        cfg = _engine_cfg(num_layers=1, hidden_size=768,
-                          num_attention_heads=12, num_query_groups=4,
-                          ffn_hidden_size=3072)
-        # fused MLP body does NOT fit whole at the default budget...
-        assert kg._mlp_tiles(768, 3072, True, 32, 4, 4, 4, False, False,
-                             kg.get_megakernel_vmem_budget()) is not None
-        # ...but the shape is eligible (tiled), not a fallback:
-        assert kg.megakernel_ineligible_reason(cfg, batch=2) is None
-        params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
-
-        def traced_launches(fused):
-            eng = DynamicInferenceEngine(params, cfg, max_batch=2,
-                                         max_seq_len=64, paged=True,
-                                         block_size=8,
-                                         fused_decode=fused)
-            assert eng.megakernel is fused
-            spec = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-                a.shape, a.dtype)
-            p_spec = jax.tree.map(spec, eng.params)
-            pages_spec = jax.tree.map(spec, eng.pool.pages)
-            scales_spec = jax.tree.map(spec, eng.pool.scales)
-            mb = eng.pool.page_table.shape[1]
-            args = (p_spec,
-                    jax.ShapeDtypeStruct((eng.max_batch, 1), jnp.int32),
-                    pages_spec, scales_spec,
-                    jax.ShapeDtypeStruct((eng.max_batch, mb), jnp.int32),
-                    jax.ShapeDtypeStruct((eng.max_batch,), jnp.int32),
-                    jax.ShapeDtypeStruct((eng.max_batch,), jnp.bool_))
-            return launch_stats(eng._decode, *args)
-
-        sp = traced_launches(False)
-        sf = traced_launches(True)
-        assert sf["dispatches_per_step"] <= 0.85 * sp["dispatches_per_step"]
-
-
-class TestMegakernelComposition:
-    """The fused step composes with the features it was carved out
-    from: resident int8 weights, speculation, and chunked prefill —
-    each pinned token-exact against the unfused engine."""
-
-    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-    def test_resident_int8_streams_token_exact(self, engine_setup,
-                                               kv_dtype):
-        cfg, params, prompts = engine_setup
-        res = _resident(params)
-        plain, _ = _stream(cfg, res, prompts, kv_cache_dtype=kv_dtype)
-        fused, eng = _stream(cfg, res, prompts, kv_cache_dtype=kv_dtype,
-                             fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-        eng.pool.audit()
-
-    @pytest.mark.slow
-    def test_spec_ngram_streams_token_exact(self, engine_setup):
-        """Speculative verify rounds ride the FUSED ragged multiquery
-        step ([B, K+1] q rows) — streams keep the verifier's
-        bit-identity pin."""
-        cfg, params, prompts = engine_setup
-        plain, _ = _stream(cfg, params, prompts, spec_method="ngram",
-                           spec_k=3)
-        fused, eng = _stream(cfg, params, prompts, spec_method="ngram",
-                             spec_k=3, fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-
-    @pytest.mark.slow
-    def test_chunked_prefill_streams_token_exact(self, engine_setup):
-        """Chunked prefill runs the fused multiquery step at
-        [1, prefill_chunk] — the 17-token prompt spans 3 chunks."""
-        cfg, params, prompts = engine_setup
-        plain, _ = _stream(cfg, params, prompts, prefill_chunk=8)
-        fused, eng = _stream(cfg, params, prompts, prefill_chunk=8,
-                             fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
-
-    @pytest.mark.slow
-    def test_quantized_spec_stack(self, engine_setup):
-        """The full stack at once: resident int8 weights + int8 KV +
-        ngram speculation under the fused step."""
-        cfg, params, prompts = engine_setup
-        res = _resident(params)
-        plain, _ = _stream(cfg, res, prompts, kv_cache_dtype="int8",
-                           spec_method="ngram", spec_k=3)
-        fused, eng = _stream(cfg, res, prompts, kv_cache_dtype="int8",
-                             spec_method="ngram", spec_k=3,
-                             fused_decode=True)
-        assert eng.megakernel
-        assert plain == fused
 
 
 # ---------------------------------------------------------------------------
@@ -1520,24 +1191,6 @@ class TestEligibilityReasons:
         assert tp_paged_ineligible_reason(
             _mla_cfg(num_query_groups=1), Ctx()) is None
 
-    def test_megakernel_mla_reasons(self):
-        """Satellite 1: MLA is ELIGIBLE at the default budget (the
-        multi_latent_attention rejection predicate is gone), and when
-        the fused MLA prologue cannot fit, the reason names it plus the
-        flag that raises the budget."""
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        assert kg.megakernel_ineligible_reason(_mla_cfg(),
-                                               batch=4) is None
-        old = kg.get_megakernel_vmem_budget()
-        try:
-            kg.set_megakernel_vmem_budget(4096)
-            reason = kg.megakernel_ineligible_reason(_mla_cfg(), batch=4)
-            assert reason is not None
-            assert "MLA" in reason
-            assert "--megakernel-vmem-budget" in reason
-        finally:
-            kg.set_megakernel_vmem_budget(old)
-
     def test_tp_stage_reasons(self):
         from megatronapp_tpu.parallel.overlap import (
             tp_stage_eligible, tp_stage_ineligible_reason,
@@ -1564,182 +1217,3 @@ class TestEligibilityReasons:
         assert "kill-switch" in tp_stage_ineligible_reason(off, Ctx(), 64)
         assert "ffn_hidden_size" in tp_stage_ineligible_reason(
             _engine_cfg(ffn_hidden_size=511), Ctx(), 64)
-
-    def test_megakernel_reasons(self):
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        cfg = _engine_cfg()
-        assert kg.megakernel_ineligible_reason(cfg, batch=4) is None
-        assert "paged" in kg.megakernel_ineligible_reason(cfg, batch=4,
-                                                          paged=False)
-        assert "tp head-sharded" in kg.megakernel_ineligible_reason(
-            cfg, batch=4, tp_paged=True)
-        moe = _engine_cfg(num_moe_experts=4, moe_router_topk=2)
-        assert "MoE" in kg.megakernel_ineligible_reason(moe, batch=4)
-        # Since ISSUE 16, large H/FFN shapes TILE into the budget
-        # instead of falling back — the formerly-ineligible 4096 shape
-        # is now fused.
-        big = _engine_cfg(hidden_size=4096, num_attention_heads=32,
-                          num_query_groups=32)
-        assert kg.megakernel_ineligible_reason(big, batch=4) is None
-
-    def test_megakernel_size_reasons_name_failed_kernel(self):
-        """When even the finest tiling cannot fit the budget, the
-        reason names the FIRST failed kernel and the flag that raises
-        the budget."""
-        from megatronapp_tpu.ops.pallas import kernel_gen as kg
-        big = _engine_cfg(hidden_size=4096, num_attention_heads=32,
-                          num_query_groups=32)
-        old = kg.get_megakernel_vmem_budget()
-        try:
-            kg.set_megakernel_vmem_budget(4096)
-            reason = kg.megakernel_ineligible_reason(big, batch=4)
-            assert reason is not None
-            assert "fused QKV kernel" in reason
-            assert "VMEM" in reason
-            assert "--megakernel-vmem-budget" in reason
-        finally:
-            kg.set_megakernel_vmem_budget(old)
-
-    def test_megakernel_resident_weights_eligible(self):
-        """Resident int8 weights are ELIGIBLE since ISSUE 16: the fused
-        kernels take {qint8, qscale} operand pairs and dequantize
-        in-register at matmul entry (exactly resolve_param's
-        arithmetic), so the resident-HBM win survives fusion. Eligible
-        byte math counts 1-byte weights + fp32 scale rows."""
-        from megatronapp_tpu.inference.quantization import (
-            quantize_params, residentize_params,
-        )
-        from megatronapp_tpu.ops.pallas.kernel_gen import (
-            megakernel_ineligible_reason,
-        )
-        cfg = _engine_cfg()
-        params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
-        assert megakernel_ineligible_reason(cfg, batch=4,
-                                            params=params) is None
-        q, _ = quantize_params(params, resident_only=True)
-        res = residentize_params(q)
-        assert megakernel_ineligible_reason(cfg, batch=4,
-                                            params=res) is None
-        eng = DynamicInferenceEngine(res, cfg, max_batch=2,
-                                     max_seq_len=64, paged=True,
-                                     block_size=8, fused_decode=True)
-        assert eng.megakernel
-
-    def test_serving_args_megakernel_combos(self):
-        """Parse-time validation: --megakernel-decode still needs
-        dynamic+paged, but composes with --serve-disagg and
-        --serve-fleet since ISSUE 16 (fused_decode is threaded through
-        both constructors); --megakernel-vmem-budget must be a
-        positive byte count."""
-        import argparse
-
-        from megatronapp_tpu.config.arguments import validate_serving_args
-
-        def ns(**kw):
-            base = dict(engine="dynamic", paged_kv_cache=True,
-                        megakernel_decode=True, serve_disagg=False,
-                        serve_fleet=1, kv_cache_dtype="bf16",
-                        quantized_weights=False,
-                        megakernel_vmem_budget=None)
-            base.update(kw)
-            return argparse.Namespace(**base)
-
-        validate_serving_args(ns(), multi_latent_attention=False)
-        # Deployment combos are accepted now — threading is real.
-        validate_serving_args(ns(serve_disagg=True),
-                              multi_latent_attention=False)
-        validate_serving_args(ns(serve_fleet=2),
-                              multi_latent_attention=False)
-        validate_serving_args(ns(quantized_weights=True),
-                              multi_latent_attention=False)
-        with pytest.raises(SystemExit, match="paged"):
-            validate_serving_args(ns(paged_kv_cache=False),
-                                  multi_latent_attention=False)
-        with pytest.raises(SystemExit, match="dynamic"):
-            validate_serving_args(ns(engine="static"),
-                                  multi_latent_attention=False)
-        with pytest.raises(SystemExit, match="positive byte count"):
-            validate_serving_args(ns(megakernel_vmem_budget=0),
-                                  multi_latent_attention=False)
-        with pytest.raises(SystemExit, match="positive byte count"):
-            validate_serving_args(ns(megakernel_vmem_budget=-1),
-                                  multi_latent_attention=False)
-
-    def test_megakernel_hooks_gate(self):
-        """Capture hooks force the unfused step (fused kernels don't
-        trace capture sites); reset_compilation re-gates."""
-        from megatronapp_tpu.ops.pallas.kernel_gen import (
-            megakernel_ineligible_reason,
-        )
-        from megatronapp_tpu.scope import hooks
-        cfg = _engine_cfg()
-        hooks.configure(True, sites={"qkv_q": True},
-                        sink=lambda *a: None)
-        try:
-            assert "capture" in megakernel_ineligible_reason(cfg, batch=4)
-        finally:
-            hooks.configure(False)
-        assert megakernel_ineligible_reason(cfg, batch=4) is None
-
-
-# ---------------------------------------------------------------------------
-# Benchmark smoke
-# ---------------------------------------------------------------------------
-
-
-class TestBenchmarkSmoke:
-    def test_decode_ab_gates(self):
-        import tools.megakernel_benchmark as mb
-        res = mb.run_decode_ab(max_new=3, scan_unroll=2)
-        assert res["greedy_match"]
-        assert res["within_gate"], res
-        assert res["dispatch_ratio"] < 1.0
-
-    @pytest.mark.slow
-    def test_decode_ab_quantized_gates(self):
-        import tools.megakernel_benchmark as mb
-        res = mb.run_decode_ab(max_new=3, scan_unroll=2, quantized=True)
-        assert res["quantized_weights"]
-        assert res["greedy_match"]
-        assert res["within_gate"], res
-
-    def test_mla_ab_gates(self):
-        """ISSUE 17 acceptance: the MLA leg gates launch ratio <=0.85x
-        AND the latent-vs-dense byte ratio <=0.25x (analytically ~0.14x
-        at klat=512/dpe=64/nq=16)."""
-        import tools.megakernel_benchmark as mb
-        res = mb.run_mla_ab(max_new=3)
-        assert res["greedy_match"], res
-        assert res["within_gate"], res
-        assert res["bytes_within_gate"], res
-        assert res["bytes_ratio"] < 0.15          # analytical ~0.14
-        assert res["dispatch_ratio"] < 1.0
-
-    @pytest.mark.slow
-    def test_mla_ab_int8_gates(self):
-        import tools.megakernel_benchmark as mb
-        res = mb.run_mla_ab(max_new=3, kv_dtype="int8")
-        assert res["kv_dtype"] == "int8"
-        assert res["greedy_match"], res
-        assert res["within_gate"], res
-        assert res["bytes_within_gate"], res
-
-    @pytest.mark.slow
-    def test_tiled_ab_gates(self):
-        import tools.megakernel_benchmark as mb
-        res = mb.run_tiled_ab(max_new=2)
-        assert res["mlp_plan_tiled"], res   # the shape genuinely tiles
-        assert res["eligible"], res         # ...and is no longer a fallback
-        assert res["fused_engine_megakernel"], res
-        assert res["greedy_match"], res
-        assert res["within_gate"], res
-
-    def test_train_levers_gates(self):
-        import tools.megakernel_benchmark as mb
-        res = mb.run_train_levers(iters=3, seq=128)
-        assert res["loss_parity"], res
-        # Wall gate: levers-on must not lose to baseline (min-of-rounds,
-        # interleaved). Report-only margin below 1.0 would hide a real
-        # regression — keep the hard gate; the lever removes ~half the
-        # flash grid's head extent so the margin is structural.
-        assert res["fwd_bwd_ratio"] >= 1.0, res

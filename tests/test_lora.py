@@ -13,8 +13,8 @@ Covers the tentpole and its satellites:
 - serving parity: zero-B adapters leave streams BITWISE unchanged; a
   mixed batch of >=4 distinct adapters decodes in ONE batched step
   with greedy streams token-exact vs serial single-adapter runs, on
-  the bf16 base AND the resident-int8 base; the megakernel epilogue
-  leg matches the unfused engine; cache audit() clean after EVERY step;
+  the bf16 base AND the resident-int8 base; cache audit() clean after
+  EVERY step;
 - fleet: a session carrying an adapter migrates mid-decode token-exact
   (banks re-acquired on dst, released on src);
 - per-tenant SLO classes composing with (priority, rid) scheduling,
@@ -339,32 +339,6 @@ class TestServingParity:
         assert got != want, (
             "a scale-2.0 adapter did not perturb the greedy stream")
 
-    def test_megakernel_epilogue_matches_unfused(self, gqa_params):
-        """The fused decode step's LoRA epilogue leg is token-exact vs
-        the unfused engine over the same adapter mix."""
-        cfg, params = gqa_params
-        prompts = _prompts(3, seed=4)
-        ids = ["a", "b", "c"]
-        reg = _registry(cfg, ids)
-
-        def run(fused):
-            eng = _engine(params, cfg,
-                          AdapterCache(cfg, reg, max_resident=4,
-                                       rank=RANK),
-                          max_batch=3, fused_decode=fused)
-            rids = [eng.add_request(p, 6, SamplingParams(greedy=True),
-                                    request_id=i, adapter_id=aid)
-                    for i, (p, aid) in enumerate(zip(prompts, ids))]
-            res = eng.run_to_completion()
-            eng.adapters.audit()
-            return [res[r].tolist() for r in rids], eng
-
-        plain, _ = run(False)
-        fused, eng = run(True)
-        assert eng.megakernel
-        assert plain == fused
-
-
 # ---------------------------------------------------------------------------
 class TestFleetMigration:
     def test_migrated_adapter_stream_token_exact(self, gqa_params):
@@ -478,10 +452,9 @@ class TestLoadgenTenants:
 class TestServingArgs:
     def _ns(self, **kw):
         base = dict(engine="dynamic", paged_kv_cache=True,
-                    megakernel_decode=False, serve_disagg=False,
+                    serve_disagg=False,
                     serve_fleet=1, kv_cache_dtype="bf16",
                     quantized_weights=False,
-                    megakernel_vmem_budget=None,
                     lora_dir="/tmp/adapters", lora_rank=4,
                     max_resident_adapters=4)
         base.update(kw)

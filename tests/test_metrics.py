@@ -529,6 +529,33 @@ class TestServerEndpoints:
                 cums.append(int(line.rsplit(" ", 1)[1]))
         return bounds, cums
 
+    def test_stats_compiles_nothing(self):
+        """What GET /stats returns, on a warmed paged engine: the decode
+        step's launch counts come off its jaxpr, so no backend compile
+        fires, and a second call serves the cached dict."""
+        srv = self._server()
+        eng = srv.engine
+        eng.add_request(np.arange(1, 6, dtype=np.int32), 3,
+                        SamplingParams(greedy=True))
+        eng.run_to_completion()
+        compiles = []
+
+        def on_duration(event, secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            first = srv.stats_snapshot()
+            second = srv.stats_snapshot()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        assert not compiles
+        disp = first["decode_dispatch"]
+        assert disp["kernels"] > 0
+        assert "compiled" not in disp
+        assert second["decode_dispatch"] is disp
+
     def test_stats_endpoint_serves_step_counters(self):
         """GET /stats: the engine's `steps` and the driver's deliveries,
         with nothing switched on."""

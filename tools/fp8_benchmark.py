@@ -120,7 +120,6 @@ def run_train(iters=6, hist_len=4):
 
     from megatronapp_tpu.training.fp8 import init_fp8_state
     from megatronapp_tpu.training.train import gpt_microbatch_loss
-    from megatronapp_tpu.utils.dispatch import compiled_stats
 
     ctx = build_mesh(ParallelConfig(tensor_parallel=2),
                      devices=jax.devices()[:2])
@@ -142,13 +141,17 @@ def run_train(iters=6, hist_len=4):
         return jax.value_and_grad(
             lambda t: loss_f(t[0], m, fp8=t[1])[0])(pair)
 
+    def bytes_accessed(jitted, *args):
+        cost = jitted.lower(*args).compile().cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        return float(cost.get("bytes accessed", 0.0))
+
     with ctx.mesh:
-        cb = compiled_stats(jax.jit(grad_b), params, micro)
-        cf = compiled_stats(jax.jit(grad_f), (params, fp8_state), micro)
+        bytes_b = bytes_accessed(jax.jit(grad_b), params, micro)
+        bytes_f = bytes_accessed(jax.jit(grad_f), (params, fp8_state),
+                                 micro)
         pb_b = permute_bytes(jax.jit(grad_b), params, micro)
         pb_f = permute_bytes(jax.jit(grad_f), (params, fp8_state), micro)
-    bytes_b = cb.get("cost", {}).get("bytes accessed", 0.0)
-    bytes_f = cf.get("cost", {}).get("bytes accessed", 0.0)
 
     f8 = rf.state["fp8"]["block"]
     hist_filled = all(

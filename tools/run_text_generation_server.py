@@ -43,7 +43,7 @@ def main():
     # Serving flags shared with the main parser (config/arguments.py
     # add_serving_args — single source of truth): --engine, --max-batch,
     # --paged-kv-cache, --kv-block-size, --num-kv-blocks,
-    # --scan-unroll, --megakernel-vmem-budget, --no-prefix-caching.
+    # --scan-unroll, --no-prefix-caching.
     from megatronapp_tpu.config.arguments import (
         add_serving_args, validate_serving_args,
     )
@@ -74,11 +74,6 @@ def main():
     cfg = PRESETS[args.preset]()
     validate_serving_args(
         args, multi_latent_attention=cfg.multi_latent_attention)
-    if args.megakernel_vmem_budget is not None:
-        from megatronapp_tpu.ops.pallas.kernel_gen import (
-            set_megakernel_vmem_budget,
-        )
-        set_megakernel_vmem_budget(args.megakernel_vmem_budget)
     if args.scan_unroll != 1:
         import dataclasses
         cfg = dataclasses.replace(cfg, scan_unroll=args.scan_unroll)
@@ -307,8 +302,7 @@ def main():
                         devices=devices[i * per:(i + 1) * per],
                         spec_method=spec, spec_k=args.spec_k,
                         draft_params=draft_params, draft_cfg=draft_cfg,
-                        kv_cache_dtype=args.kv_cache_dtype,
-                        fused_decode=args.megakernel_decode, **hints)
+                        kv_cache_dtype=args.kv_cache_dtype, **hints)
                 return DynamicInferenceEngine(
                     params, cfg, tokenizer=tok,
                     max_batch=args.max_batch,
@@ -320,7 +314,6 @@ def main():
                     draft_params=draft_params, draft_cfg=draft_cfg,
                     prefill_chunk=args.prefill_chunk,
                     kv_cache_dtype=args.kv_cache_dtype,
-                    fused_decode=args.megakernel_decode,
                     adapter_cache=make_adapter_cache(),
                     spill_host_mb=args.kv_spill_host_mb,
                     spill_watermark_blocks=(
@@ -337,8 +330,7 @@ def main():
                   f"replicas on {args.host}:{args.port} "
                   f"(policy=affinity, migrate={args.fleet_migrate}, "
                   f"autoscale={args.fleet_autoscale}, "
-                  f"kv={args.kv_cache_dtype}, "
-                  f"megakernel={args.megakernel_decode})")
+                  f"kv={args.kv_cache_dtype})")
             TextGenerationServer(engine, args.host, args.port).run()
             return
         if args.serve_disagg:
@@ -359,14 +351,12 @@ def main():
                 decode_slo_ms=args.decode_slo_ms, tp=args.serve_tp,
                 spec_method=spec, spec_k=args.spec_k,
                 draft_params=draft_params, draft_cfg=draft_cfg,
-                kv_cache_dtype=args.kv_cache_dtype,
-                fused_decode=args.megakernel_decode)
+                kv_cache_dtype=args.kv_cache_dtype)
             print(f"serving DISAGGREGATED on {args.host}:{args.port} "
                   f"(prefill {engine.prefill_ctx.num_devices}d / decode "
                   f"{engine.decode_ctx.num_devices}d, tp={args.serve_tp}, "
                   f"slo={args.decode_slo_ms} ms, "
                   f"kv={args.kv_cache_dtype}, "
-                  f"megakernel={engine.megakernel}, "
                   f"spec={spec or 'off'})")
             TextGenerationServer(engine, args.host, args.port).run()
             return
@@ -388,7 +378,6 @@ def main():
             spec_k=args.spec_k, draft_params=draft_params,
             draft_cfg=draft_cfg, prefill_chunk=args.prefill_chunk,
             ctx=tp_ctx, kv_cache_dtype=args.kv_cache_dtype,
-            fused_decode=args.megakernel_decode,
             adapter_cache=make_adapter_cache(),
             spill_host_mb=args.kv_spill_host_mb,
             spill_watermark_blocks=args.kv_spill_watermark_blocks)
@@ -402,7 +391,6 @@ def main():
         print(f"serving continuous batching on {args.host}:{args.port} "
               f"(paged={args.paged_kv_cache}, "
               f"kv={args.kv_cache_dtype}, tp={args.serve_tp}, "
-              f"megakernel={engine.megakernel}, "
               f"lora={'on' if args.lora_dir else 'off'}, "
               f"spec={engine.spec_method or 'off'})")
         print(engine.startup_line())
