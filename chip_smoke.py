@@ -392,6 +392,7 @@ def drive_server(port, tiny):
     facts["multiquery_traces"] = stats1["multiquery_traces"]
     disp = stats1.get("decode_dispatch") or {}
     facts["decode_pallas_calls_per_step"] = disp.get("kernels")
+    facts["expert_stack_slices"] = disp.get("expert_stack_slices")
     facts["healthz"] = {"status": health.get("status"),
                         "stepper": health.get("stepper")}
     if facts["max_blocks_in_use_seen"] <= 0:

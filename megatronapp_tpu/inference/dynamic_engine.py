@@ -72,6 +72,7 @@ from megatronapp_tpu.trace.request_trace import (
     PhaseStats, get_request_tracer,
 )
 from megatronapp_tpu.transformer.block import layer_forward
+from megatronapp_tpu.transformer.moe import StackedLayer
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
 from megatronapp_tpu.utils.platform import fresh_compiles
@@ -254,7 +255,8 @@ def _decode_step(params, tokens, cache, lengths, active,
     return logits, new_caches
 
 
-def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
+def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
+                       ctx=None):
     """The layer loop of both paged steps: h through every layer, each
     appending its new rows to the pools and attending through them.
 
@@ -269,15 +271,36 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
     layer(layer_p, hh, lid, kv_cache, kv_scales, lora_l) runs one layer and
     returns layer_forward's ((h, new_cache), aux).
 
+    The experts' fc1/fc2 stacks [L_moe, E, K, N] are read through the layer
+    id too: a grouped GEMM is a custom call, for which the loop's slice of
+    an xs leaf is a copy of the layer's experts, so the stacks enter the
+    loop whole and a layer gets moe.StackedLayer(stack, its index). Only
+    the single-device trace does this, and only for plain arrays: resident
+    int8 pairs dequantize a layer at a time, and on a mesh (ctx) the
+    kernels stay per-layer operands.
+
     An MoE model's leading dense layers (params["lead_block"], layer ids
     0..k-1) run first, through the same body and into planes 0..k-1 of the
     same pools; the scanned stack takes ids k..L-1.
 
     Returns (h, moe, (k, v[, k_scales, v_scales])); moe is None for a
     dense model, else int32 [2]: the MoE layers' routing_counts summed."""
+    lead = cfg.moe_first_k_dense
+    block = params["block"]
+    stacks = {}
+    if ctx is None and isinstance(block, dict) and "moe" in block:
+        stacks = {k: w for k, w in block["moe"].items()
+                  if k in ("fc1_kernel", "fc2_kernel")
+                  and not isinstance(w, dict)}
+        block = dict(block, moe={k: w for k, w in block["moe"].items()
+                                 if k not in stacks})
+
     def body(carry, xs):
         hh, kv, kvs = carry
         layer_p, lid, banks = xs
+        if stacks and "moe" in layer_p:
+            layer_p = dict(layer_p, moe=dict(layer_p["moe"], **{
+                k: StackedLayer(w, lid - lead) for k, w in stacks.items()}))
         ll = None
         if lora is not None:
             ll = {"row_adapter": lora["row_adapter"], "banks": banks}
@@ -287,13 +310,11 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer):
 
     banks = None if lora is None else lora["banks"]
     carry = (h, tuple(pages), None if scales is None else tuple(scales))
-    lead = cfg.moe_first_k_dense
     if lead:
         carry, _ = jax.lax.scan(
             body, carry, (params["lead_block"], jnp.arange(lead), banks))
     (h, pages, scales), moe = jax.lax.scan(
-        body, carry,
-        (params["block"], jnp.arange(lead, cfg.num_layers), banks),
+        body, carry, (block, jnp.arange(lead, cfg.num_layers), banks),
         unroll=cfg.scan_unroll)
     moe = jnp.sum(moe, axis=0) if cfg.is_moe else None
     return h, moe, pages + (scales or ())
@@ -342,7 +363,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
             active=active, ctx=ctx, kv_scales=kvs, lora=ll)
 
     h, moe, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
-                                           cfg, layer)
+                                           cfg, layer, ctx)
     logits = gpt_head(params, h, cfg)[:, -1]
     return logits, moe, new_pages
 
@@ -384,7 +405,7 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
             kv_scales=kvs, lora=ll)
 
     h, _, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
-                                         cfg, layer)
+                                         cfg, layer, ctx)
     logits = gpt_head(params, h, cfg)
     return logits, h, new_pages
 
@@ -2002,8 +2023,11 @@ class DynamicInferenceEngine:
     def dispatch_stats(self, force: bool = False) -> Optional[Dict]:
         """Launch counts of the traced decode step at the engine's
         shapes (utils/dispatch.launch_stats): `kernels` is its
-        pallas_calls a step, scan bodies times their length. One trace
-        of the jaxpr, cached per jit build; nothing is compiled."""
+        pallas_calls a step, scan bodies times their length;
+        `expert_stack_slices` its equations that cut one layer's expert
+        kernel out of the stack (0 when the grouped GEMMs read the stack
+        in place, and on a dense model). One trace of the jaxpr, cached
+        per jit build; nothing is compiled."""
         if self._dispatch_stats is not None and not force:
             return self._dispatch_stats
         if not self.paged:
@@ -2023,7 +2047,14 @@ class DynamicInferenceEngine:
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.bool_),
                 jax.tree.map(spec, self._lora_args()))
         try:
-            stats = launch_stats(self._decode, *args)
+            block = self.params["block"]
+            moe = block.get("moe", {}) if isinstance(block, dict) else {}
+            kernels = [moe[k]["qint8"] if isinstance(moe[k], dict)
+                       else moe[k]
+                       for k in ("fc1_kernel", "fc2_kernel") if k in moe]
+            stats = launch_stats(
+                self._decode, *args,
+                slice_shapes=[w.shape[1:] for w in kernels])
         except Exception as e:  # noqa: BLE001 — observability must not
             # take the serving loop down with it.
             logger.warning("decode dispatch accounting failed: %s", e)

@@ -28,7 +28,7 @@ Two dispatch modes, matching the reference's semantics:
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -123,19 +123,61 @@ def _apply_act(cfg: TransformerConfig, y: jnp.ndarray) -> jnp.ndarray:
     return apply_activation(cfg.activation, y)
 
 
-def _expert_ffn(p, x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
-    """Batched expert MLP: x [E, C, H] → [E, C, H] (GroupedMLP analogue).
+class StackedLayer(NamedTuple):
+    """Layer `layer` of `stack`: the other way of naming one layer's expert
+    kernel. An expert kernel is either an [E, K, N] array (or its resident
+    int8 pair) or this pair of the whole [L, E, K, N] stack and an int32
+    index, which the paged serving loop builds
+    (inference/dynamic_engine._scan_paged_layers) so that the dropless
+    grouped GEMM reads the stack where it lies; every other consumer takes
+    the slice (_expert_kernel)."""
+    stack: jnp.ndarray
+    layer: jnp.ndarray
 
-    Expert kernels resolve at matmul entry (inference/quantization.py
-    resolve_param — a no-op on plain arrays): serving-resident int8
-    expert stacks stay int8 in HBM with the per-channel dequant fused
-    into the expert GEMMs, exactly like the dense fc1/fc2 path."""
+
+def _expert_kernel(w, dt) -> jnp.ndarray:
+    """One layer's [E, K, N] expert kernel at matmul entry, in `dt`: a
+    StackedLayer gives its slice, a serving-resident int8 pair dequantizes
+    (inference/quantization.py resolve_param — a no-op on plain arrays, so
+    the int8 stack is what lives in HBM and the per-channel dequant fuses
+    into the consuming GEMM, exactly like the dense fc1/fc2 path)."""
     from megatronapp_tpu.inference.quantization import resolve_param
+    if isinstance(w, StackedLayer):
+        w = jax.lax.dynamic_index_in_dim(w.stack, w.layer, keepdims=False)
+    return resolve_param(w, dt)
+
+
+def _expert_ffn(p, x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+    """Batched expert MLP: x [E, C, H] → [E, C, H] (GroupedMLP analogue)."""
     dt = cfg.compute_dtype
     y = jnp.einsum("ech,ehf->ecf", x.astype(dt),
-                   resolve_param(p["fc1_kernel"], dt))
+                   _expert_kernel(p["fc1_kernel"], dt))
     return jnp.einsum("ecf,efh->ech", _apply_act(cfg, y),
-                      resolve_param(p["fc2_kernel"], dt))
+                      _expert_kernel(p["fc2_kernel"], dt))
+
+
+def _grouped_gemm(x, w, group_sizes, dt) -> jnp.ndarray:
+    """``lax.ragged_dot`` of the rows x [M, K], sorted by expert, against one
+    layer's expert kernel w; group_sizes [E] int32.
+
+    XLA:TPU lowers ragged_dot to a custom call, which cannot take a fused
+    slice as its operand: handed a layer's slice of a stack it first copies
+    the layer out (1.1 GB a DeepSeek-V2-Lite layer, more time than the GEMM
+    itself). So a StackedLayer is read in place: the stack viewed as
+    [L·E, K, N] (merging leading dimensions moves nothing), with the layer's
+    group sizes at offset layer·E and zero rows for every other group. The
+    kernel's grid runs over the tiles that hold rows, so the empty groups
+    cost no step, and the rows, tiles and accumulation order are those of
+    the per-layer call: the same numbers. A stack that is not held in the
+    compute dtype would be converted whole, so it takes the slice."""
+    if isinstance(w, StackedLayer) and w.stack.dtype == dt:
+        l, e = w.stack.shape[:2]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((l * e,), group_sizes.dtype), group_sizes,
+            (w.layer * e,))
+        return jax.lax.ragged_dot(
+            x, w.stack.reshape((l * e,) + w.stack.shape[2:]), sizes)
+    return jax.lax.ragged_dot(x, _expert_kernel(w, dt), group_sizes)
 
 
 def _dropless_experts(p, x_flat, topk_idx, topk_probs,
@@ -145,7 +187,6 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
     row groups — static shapes, no capacity buffer, zero drops. This is
     the reference's default behavior (no --moe-expert-capacity-factor ⇒
     dispatchers never drop; experts.py GroupedMLP runs ragged groups)."""
-    from megatronapp_tpu.inference.quantization import resolve_param
     t, h = x_flat.shape
     k = cfg.moe_router_topk
     e = cfg.num_moe_experts
@@ -155,16 +196,9 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
     token_of = order // k
     group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
 
-    # Resident int8 expert stacks dequantize here, at matmul entry
-    # (resolve_param is a no-op on plain arrays) — the ragged grouped
-    # GEMM consumes the dequant directly, so the int8 stack is what
-    # lives in HBM.
     x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
-    y = jax.lax.ragged_dot(x_sorted, resolve_param(p["fc1_kernel"], dt),
-                           group_sizes)
-    y = jax.lax.ragged_dot(_apply_act(cfg, y),
-                           resolve_param(p["fc2_kernel"], dt),
-                           group_sizes)
+    y = _grouped_gemm(x_sorted, p["fc1_kernel"], group_sizes, dt)
+    y = _grouped_gemm(_apply_act(cfg, y), p["fc2_kernel"], group_sizes, dt)
 
     w_sorted = jnp.take(topk_probs.reshape(t * k), order).astype(
         jnp.float32)

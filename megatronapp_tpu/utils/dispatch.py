@@ -9,14 +9,16 @@ ceil(length / unroll) loop steps, and ordinary equations count one
 launch apiece minus a small free-op set (reshape & friends never
 dispatch). It counts equations before XLA fuses them, so `launches`
 overestimates; `kernels`, the pallas_calls a step, is exact, and is what
-chip_smoke.py checks of a served decode step. Nothing is compiled or
-executed.
+chip_smoke.py checks of a served decode step. ``stack_slices`` counts the
+equations that cut one layer's array out of a stack: for a custom call
+(a grouped GEMM, a Pallas kernel) such a slice is a copy of the layer.
+Nothing is compiled or executed.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict
+from typing import Dict, Iterable
 
 # Equations that never become their own kernel launch (pure
 # layout/metadata in XLA).
@@ -88,12 +90,51 @@ def jaxpr_launch_stats(jaxpr) -> Dict[str, float]:
             "loop_steps": loop_steps, "eqns": eqns}
 
 
-def launch_stats(fn, *args, **kwargs) -> Dict[str, float]:
+def _inner_jaxprs(eqn):
+    """Every jaxpr among an equation's parameters: a loop's body, a cond's
+    branches, a call's callee."""
+    for v in eqn.params.values():
+        for j in v if isinstance(v, (tuple, list)) else (v,):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def stack_slices(jaxpr, shapes: Iterable[tuple]) -> int:
+    """Equations of `jaxpr` (its inner jaxprs included) that cut an array
+    of one of `shapes` out of a stack of them: a ``slice`` or
+    ``dynamic_slice`` with such a result, leading 1s aside, and a ``scan``
+    for each of its xs that reaches the body with such a shape. Equations
+    are counted, not executions: 2 for a layer loop that slices two
+    stacks."""
+    shapes = {tuple(s) for s in shapes}
+    n = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("slice", "dynamic_slice"):
+            shape = tuple(eqn.outvars[0].aval.shape)
+            while shape[:1] == (1,):
+                shape = shape[1:]
+            n += shape in shapes
+        elif name == "scan":
+            body = _sub_jaxpr(eqn.params["jaxpr"])
+            xs = body.invars[eqn.params["num_consts"]
+                             + eqn.params["num_carry"]:]
+            n += sum(tuple(v.aval.shape) in shapes for v in xs)
+        n += sum(stack_slices(j, shapes) for j in _inner_jaxprs(eqn))
+    return n
+
+
+def launch_stats(fn, *args, slice_shapes: Iterable[tuple] = ()
+                 ) -> Dict[str, float]:
     """jaxpr_launch_stats of `fn` traced at the given (abstract or
-    concrete) arguments. `fn` may be jitted (the pjit wrapper is
-    recursed through) — nothing is compiled or executed."""
+    concrete) arguments, and under `expert_stack_slices` its stack_slices
+    of `slice_shapes` (one layer's expert kernels). `fn` may be jitted
+    (the pjit wrapper is recursed through) — nothing is compiled or
+    executed."""
     import jax
-    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    closed = jax.make_jaxpr(fn)(*args)
     stats = jaxpr_launch_stats(closed.jaxpr)
     stats["dispatches_per_step"] = stats["launches"] + stats["loop_steps"]
+    stats["expert_stack_slices"] = stack_slices(closed.jaxpr, slice_shapes)
     return stats

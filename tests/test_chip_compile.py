@@ -340,18 +340,29 @@ def test_engine_paged_steps(one_chip, chip_compile, which, kv):
 ])
 def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
                                            batch, blocks, seq, which):
-    """The same two jits at the serving cells' attention shapes (depth cut
-    to 2, a small vocabulary and 4 experts: neither reaches a paged
-    kernel): D 80 over a table of 128 blocks, and latent rows of 512 + 64
-    columns over a table of 256. The weights and the pool go in as
-    abstract values; the step still aliases its pools."""
+    """The same two jits at the serving cells' attention shapes (a small
+    vocabulary; depth cut to 2, and for DeepSeek-V2-Lite to 1 dense + 2 MoE
+    layers of 8 experts at the published widths, so that the layer loop
+    is a loop): D 80 over a table of 128 blocks, and latent rows of 512 +
+    64 columns over a table of 256. The weights and the pool go in as
+    abstract values; the step still aliases its pools.
+
+    The experts' grouped GEMMs (`ragged-dot-none*`, custom calls) read the
+    fc1/fc2 stacks in place through the layer id (ISSUE 31): nothing
+    sliced or copied has the shape of one layer's experts, and the step's
+    temporaries are smaller than one layer's fc1 kernel (the parent held
+    one: `dynamic-slice_bitcast_fusion`, 94.5 MB of temporaries here)."""
     from megatronapp_tpu.inference.dynamic_engine import (
         DynamicInferenceEngine,
     )
     from megatronapp_tpu.models.gpt import init_gpt_params
     over = dict(num_layers=2, vocab_size=1024)
     if model == "deepseek-v2-lite":
-        over.update(num_moe_experts=8, max_position_embeddings=seq)
+        # bf16 weights, as the cell holds them: a float32 stack is
+        # converted a layer at a time, which is a slice
+        over.update(num_layers=3, num_moe_experts=8,
+                    max_position_embeddings=seq,
+                    params_dtype=jnp.bfloat16)
     cfg = PRESETS[model](**over)
     abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
@@ -382,7 +393,17 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     _assert_kernels_named(
         compiled, family + ("_latent" if cfg.multi_latent_attention else ""))
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pages)
-    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if cfg.is_moe:
+        experts = abstract["block"]["moe"]
+        assert any("ragged-dot" in n for n in _kernel_names(compiled))
+        assert not _pool_shaped(
+            compiled, r"slice|copy",
+            [experts[k].shape[1:] for k in ("fc1_kernel", "fc2_kernel")])
+        fc1 = experts["fc1_kernel"]
+        assert mem.temp_size_in_bytes < (fc1.size // fc1.shape[0]
+                                         * fc1.dtype.itemsize)
 
 
 # ---------------------------------------------------------------------------

@@ -132,16 +132,29 @@ def _prefill_then_decode(cfg, params, prompt, n_new, chunk=8, bs=4):
 
 
 class TestPagedLatentPool:
-    @pytest.mark.parametrize("dtype,above,below", [
-        (jnp.float32, 0.0, TOL_F32), (jnp.bfloat16, TOL_F32, TOL_BF16)],
-        ids=["fp32", "bf16"])
-    def test_prefill_then_decode_matches_reference(self, dtype, above, below):
+    @pytest.mark.parametrize("dtype,weights,above,below", [
+        (jnp.float32, None, 0.0, TOL_F32),
+        (jnp.bfloat16, None, TOL_F32, TOL_BF16),
+        (jnp.bfloat16, jnp.bfloat16, TOL_F32, TOL_BF16)],
+        ids=["fp32", "bf16", "bf16-weights"])
+    def test_prefill_then_decode_matches_reference(self, dtype, weights,
+                                                   above, below):
         """Chunked prefill (20 tokens in chunks of 8: a ragged tail), then
         12 decoded tokens through the paged latent pool: the logits at every
         position against the reference's one full forward pass. bf16's gap
         also lies ABOVE float32's limit: the two limits tell the types
-        apart."""
+        apart. With float32 weights the float32 steps read the expert
+        stacks in place and the bf16 steps a converted slice; with the
+        weights rounded to bf16 (the serving cell's case; the reference
+        runs on the same rounded weights) the bf16 steps read them in
+        place."""
         cfg, params = _model(compute_dtype=dtype)
+        if weights is not None:
+            params = jax.tree.map(
+                lambda a: a.astype(weights) if a.dtype == jnp.float32 else a,
+                params)
+            params["block"]["moe"]["router_kernel"] = params["block"]["moe"][
+                "router_kernel"].astype(jnp.float32)
         seq, logits, _, _ = _prefill_then_decode(cfg, params,
                                                  _tokens((20,), 1), 12)
         assert len(seq) == 32 and logits.shape[0] == 32
@@ -211,6 +224,47 @@ class TestEngine:
         assert moe["expert_pairs_possible"] == 4 * 2 * 8
         assert 4 * 2 * 3 <= moe["expert_pairs_touched"] <= moe["assignments"]
 
+    @pytest.mark.parametrize("experts,slices", [("plain", 0), ("int8", 2)])
+    def test_decode_dispatch_counts_expert_stack_slices(self, experts,
+                                                        slices):
+        """`decode_dispatch.expert_stack_slices`: equations of the traced
+        decode step that cut one layer's expert kernel out of its stack. 0
+        when the layer loop hands the grouped GEMMs the stack and the layer
+        id; 2 a layer loop (fc1 and fc2 as the scan's xs) where the kernels
+        stay per-layer operands, as resident int8 pairs do."""
+        from megatronapp_tpu.inference.quantization import (
+            quantize_params, residentize_params,
+        )
+        cfg, params = _model()
+        if experts == "int8":
+            params = residentize_params(
+                quantize_params(params, resident_only=True)[0])
+            assert "qint8" in params["block"]["moe"]["fc1_kernel"]
+        eng = DynamicInferenceEngine(params, cfg, max_batch=2,
+                                     max_seq_len=64, paged=True,
+                                     num_blocks=16, block_size=4,
+                                     prefill_chunk=8)
+        disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
+        assert disp["expert_stack_slices"] == slices, disp
+        assert disp["kernels"] == cfg.num_layers    # the latent kernel
+
+    @pytest.mark.parametrize("n,seed", [(10, 4), (17, 5)])
+    def test_streams_equal_the_dense_oracle(self, n, seed):
+        """The greedy stream of the two paged steps (experts read through
+        the layer id) is that of gpt_forward over the whole sequence (the
+        training scan: per-layer kernels), in float32. The steps are
+        driven directly: a fresh engine on the CPU now and then leaves
+        the oracle whatever the model (ROADMAP S3)."""
+        cfg, params = _model()
+        prompt = _tokens((n,), seed)
+        seq, _, _, _ = _prefill_then_decode(cfg, params, prompt, 6)
+        toks = np.asarray(prompt)[None]
+        for _ in range(6):
+            logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
+            toks = np.concatenate(
+                [toks, [[int(jnp.argmax(logits[0, -1]))]]], axis=1)
+        assert seq.tolist() == toks[0].tolist()
+
     def test_dense_model_step_is_unchanged(self):
         """A dense model's decode step returns no counts, so its sampler
         gets no tail and /stats no `moe` section."""
@@ -227,8 +281,9 @@ class TestEngine:
                                      max_seq_len=32, paged=True)
         eng.add_request(_tokens((6,), 6) % 64, 3)
         eng.run_to_completion()
-        snap = eng.stats_snapshot()
+        snap = eng.stats_snapshot(include_dispatch=True)
         assert "moe" not in snap and eng.moe_stats["decode_rounds"] == 0
+        assert snap["decode_dispatch"]["expert_stack_slices"] == 0
 
     def test_dense_cache_engines_refuse_leading_layers(self):
         cfg, params = _model()

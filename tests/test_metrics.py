@@ -553,6 +553,7 @@ class TestServerEndpoints:
         assert not compiles
         disp = first["decode_dispatch"]
         assert disp["kernels"] > 0
+        assert disp["expert_stack_slices"] == 0     # a dense model
         assert "compiled" not in disp
         assert second["decode_dispatch"] is disp
 

@@ -250,3 +250,137 @@ class TestNoInvoluntaryRematerialization:
         assert "Involuntary full rematerialization" not in proc.stderr, (
             "SPMD partitioner fell back to replicate+repartition:\n"
             + proc.stderr[-2000:])
+
+
+def _ragged_dot_expert_shapes(jaxpr):
+    """Under `jaxpr`, the shape of every ragged_dot's grouped [G, K, N]
+    array: the expert operand of a forward GEMM, the result of the one
+    that forms a kernel's gradient."""
+    from megatronapp_tpu.utils.dispatch import _inner_jaxprs
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("ragged_dot"):
+            shapes += [tuple(v.aval.shape)
+                       for v in (eqn.invars[1], eqn.outvars[0])
+                       if v.aval.ndim == 3]
+        for inner in _inner_jaxprs(eqn):
+            shapes += _ragged_dot_expert_shapes(inner)
+    return shapes
+
+
+class TestStackedLayer:
+    """A layer's expert kernel named as "layer i of the stack"
+    (moe.StackedLayer, what the paged serving loop hands a layer): the
+    dropless grouped GEMMs read the stack as [L·E, K, N] with the layer's
+    groups at offset i·E, and give the numbers of the same call on
+    stack[i]."""
+    L, E, K, T = 3, 8, 2, 24
+
+    def _case(self, params_dtype, compute_dtype, routing, **kw):
+        from megatronapp_tpu.config.transformer_config import ActivationKind
+        cfg = _cfg(num_moe_experts=self.E, moe_router_topk=self.K,
+                   activation=ActivationKind.swiglu,
+                   params_dtype=params_dtype, compute_dtype=compute_dtype,
+                   init_method_std=0.3, **kw)
+        keys = jax.random.split(jax.random.PRNGKey(3), self.L)
+        layers = [init_moe_params(k, cfg, out_std=0.3)[0] for k in keys]
+        stack = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+        rng = np.random.default_rng(7)
+        if routing == "skewed":
+            # two thirds of the copies go to expert 1, the rest anywhere
+            first = np.where(rng.random(self.T) < 0.67, 1,
+                             rng.integers(0, self.E, self.T))
+            second = (first + rng.integers(1, self.E, self.T)) % self.E
+        else:
+            # "empty": six of the eight groups hold no row
+            first = np.full(self.T, 2)
+            second = np.full(self.T, 5)
+        idx = jnp.asarray(np.stack([first, second], 1), jnp.int32)
+        probs = jnp.asarray(rng.random((self.T, self.K)), jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(4), (self.T, 32),
+                              jnp.float32)
+        return cfg, stack, x, idx, probs
+
+    @pytest.mark.parametrize("layer", range(3))
+    @pytest.mark.parametrize("routing", ["skewed", "empty"])
+    @pytest.mark.parametrize("params_dtype,compute_dtype", [
+        (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16),
+        (jnp.float32, jnp.bfloat16)], ids=["fp32", "bf16", "fp32-as-bf16"])
+    def test_dropless_equals_the_slice(
+            self, params_dtype, compute_dtype, routing, layer):
+        """In bf16 bit for bit. In float32 to the last bits: the CPU's
+        stand-in for the grouped GEMM is ONE dot that contracts over
+        groups x K with the other groups' rows zeroed, so its blocks of
+        partial sums shift with the number of groups (3e-7 of the largest
+        value, measured; on the TPU both namings run the same kernel over
+        the same tiles, and a chip run compared them bit for bit:
+        PERF.md, PR 31)."""
+        from megatronapp_tpu.transformer.moe import (
+            StackedLayer, _dropless_experts,
+        )
+        cfg, stack, x, idx, probs = self._case(params_dtype, compute_dtype,
+                                               routing)
+
+        def named(i):
+            return {k: StackedLayer(stack[k], i)
+                    for k in ("fc1_kernel", "fc2_kernel")}
+
+        def sliced(i):
+            return {k: stack[k][i] for k in ("fc1_kernel", "fc2_kernel")}
+
+        run = jax.jit(lambda p: _dropless_experts(p, x, idx, probs, cfg))
+        got = np.asarray(run(named(jnp.int32(layer))))
+        want = np.asarray(run(sliced(layer)))
+        assert np.abs(want).max() > 1.0
+        if compute_dtype == jnp.bfloat16:
+            assert got.tobytes() == want.tobytes()
+        else:
+            assert np.abs(got - want).max() < 1e-6 * np.abs(want).max()
+        # another layer's experts give other numbers: the offset is live
+        other = np.asarray(run(sliced((layer + 1) % self.L)))
+        assert np.abs(got - other).max() > 1.0
+        # A stack held in the compute dtype is read whole; one that would
+        # have to be converted is sliced first.
+        groups = [s[0] for s in _ragged_dot_expert_shapes(
+            jax.make_jaxpr(run)(named(jnp.int32(layer))).jaxpr)]
+        in_place = params_dtype == compute_dtype
+        assert groups == [self.L * self.E if in_place else self.E] * 2
+
+    @pytest.mark.parametrize("capacity", [None, 8.0],
+                             ids=["dropless", "capacity"])
+    def test_moe_forward_takes_either_name(self, capacity):
+        """Every consumer but the dropless GEMM takes the slice: the
+        capacity path's batched einsum, given a StackedLayer, equals the
+        same layer given as an array."""
+        from megatronapp_tpu.transformer.moe import StackedLayer
+        cfg, stack, x, _, _ = self._case(jnp.float32, jnp.float32, "skewed",
+                                         moe_capacity_factor=capacity)
+        x = x.reshape(2, self.T // 2, 32)
+        one = jax.tree.map(lambda a: a[1], stack)
+        named = dict(one, **{k: StackedLayer(stack[k], jnp.int32(1))
+                             for k in ("fc1_kernel", "fc2_kernel")})
+        want, _ = moe_forward(one, x, cfg)
+        got, _ = moe_forward(named, x, cfg)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-6 * float(
+                                       jnp.abs(want).max()))
+
+    def test_training_keeps_per_layer_kernels(self):
+        """The training scan hands a layer its own [E, K, N] kernels, and
+        jax.grad gives each stack a gradient of its own shape: no
+        [L·E, K, N] operand and no such cotangent."""
+        from megatronapp_tpu.models.gpt import gpt_loss, init_gpt_params
+        cfg = _cfg(num_layers=2, moe_capacity_factor=None)
+        p, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 64)
+        grad = jax.grad(lambda q: gpt_loss(q, toks, toks, None, cfg)[0])
+        shapes = _ragged_dot_expert_shapes(jax.make_jaxpr(grad)(p).jaxpr)
+        e = cfg.num_moe_experts
+        assert len(shapes) >= 4 and all(s[0] == e for s in shapes), shapes
+        g = grad(p)
+        for name in ("fc1_kernel", "fc2_kernel"):
+            w = p["block"]["moe"][name]
+            assert g["block"]["moe"][name].shape == w.shape == (2, e) + \
+                w.shape[2:]
+            assert bool(jnp.all(jnp.any(g["block"]["moe"][name] != 0,
+                                        axis=(1, 2, 3))))
