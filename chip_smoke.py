@@ -1,6 +1,7 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py             # one chip: trainer twice, then server
+    python chip_smoke.py             # one chip: trainer twice, the server,
+                                     # then a tiny hybrid state-space engine
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -12,7 +13,10 @@ vocab 50304), random weights from the entry points' own seeds:
   dense attention at S=1024) and once with ``--attention-impl pallas``
   (the flash kernels);
 - server: ``tools/run_text_generation_server.py --preset gpt2-125m
-  --engine dynamic --paged-kv-cache`` answering real ``PUT /api`` requests.
+  --engine dynamic --paged-kv-cache`` answering real ``PUT /api`` requests;
+- hybrid: a tiny model with state-space layers through the paged engine (no
+  preset of that kind is small): the compiled decode step holds one
+  ``ssm_update`` a scanned run of such layers and aliases the state pools.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -481,6 +485,115 @@ def phase_server(tiny):
 
 
 # ---------------------------------------------------------------------------
+# Phase: a hybrid state-space stack through the paged engine, tiny widths
+# ---------------------------------------------------------------------------
+
+HYBRID = dict(num_layers=8, attn_layer_period=4, attn_layer_offset=1)
+
+
+def phase_hybrid(tiny):
+    rc, tr = _run_child("hybrid", ["--child", "hybrid"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_hybrid(rc, tr.lines, tiny)
+
+
+def child_hybrid(tiny):
+    """In the child: a model with state-space layers (8 layers of which 1
+    and 5 attend with one key/value head, E 256, state 16) serves three
+    requests through DynamicInferenceEngine(paged=True) on the device, and
+    the compiled decode step says what it holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, PositionEmbeddingKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    cfg = TransformerConfig(
+        hidden_size=128, num_attention_heads=2, num_query_groups=1,
+        ffn_hidden_size=256, vocab_size=512, max_position_embeddings=128,
+        normalization=NormKind.rmsnorm, activation=ActivationKind.swiglu,
+        add_bias_linear=False,
+        position_embedding=PositionEmbeddingKind.none, ssm_inner_norms=True,
+        params_dtype=jnp.bfloat16, **HYBRID)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
+                                 paged=True)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n in (40, 9, 70):
+        eng.add_request(rng.integers(0, 512, n).astype(np.int32), 12,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), bool), None).compile()
+    text = compiled.as_text()
+    pools = eng._pools()
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 512 for v in out.values()
+                             for t in v)),
+        "state": eng.stats_snapshot()["state"],
+        "ssm_update_calls": sum(
+            1 for ln in text.splitlines()
+            if "custom-call(" in ln and " %ssm_update" in ln.split("=")[0]),
+        "pool_shapes": [list(p.shape) for p in pools],
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_hybrid(rc, lines, tiny=False):
+    out = {"phase": "hybrid", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    interpreted = tiny and dev and dev[0]["platform"] != "tpu"
+    # One ssm_update a scanned run of state-space layers, not one a layer:
+    # a period of 4 with the attention layer second is a run of one layer
+    # and a run of two under the outer scan over the two periods. (The
+    # interpreter inlines a kernel into plain HLO: nothing to count.)
+    if not interpreted and out["ssm_update_calls"] != 2:
+        out["problems"].append(
+            f"{out['ssm_update_calls']} ssm_update custom calls in the "
+            "compiled decode step, not one a layer loop (2)")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    if out["tokens"] != 40 + 9 + 70 + 3 * 12 or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back, or one "
+                               "outside the vocabulary")
+    state = out["state"] or {}
+    if (state.get("layers"), state.get("resets")) != (6, 3):
+        out["problems"].append(f"state counters {state}")
+    if not any("paged decode" in ln and _kernel_mode(dev, tiny) in ln
+               for ln in lines):
+        out["problems"].append("the engine did not say it ran the paged "
+                               f"decode kernel {_kernel_mode(dev, tiny)}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Phase: four chips (only with --chips 4)
 # ---------------------------------------------------------------------------
 
@@ -672,7 +785,8 @@ def run(chips, tiny):
     else:
         plan = [lambda: phase_train("auto", tiny),
                 lambda: phase_train("pallas", tiny),
-                lambda: phase_server(tiny)]
+                lambda: phase_server(tiny),
+                lambda: phase_hybrid(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -695,7 +809,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
-    ap.add_argument("--child", choices=["train", "multichip"],
+    ap.add_argument("--child", choices=["train", "multichip", "hybrid"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -704,6 +818,9 @@ def main(argv=None):
         return 0
     if args.child == "multichip":
         child_multichip(args.tiny)
+        return 0
+    if args.child == "hybrid":
+        child_hybrid(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

@@ -23,6 +23,36 @@ from megatronapp_tpu.config.transformer_config import (
 )
 
 
+_HYBRID_FIELDS = ("attn_layer_period", "attn_layer_offset",
+                  "ssm_inner_norms")
+
+
+def add_hybrid_args(ap: argparse.ArgumentParser):
+    """The layer pattern of a hybrid state-space stack
+    (TransformerConfig.attn_layer_period; HF `jamba`'s keys) — shared by
+    the main parser (the hybrid trains through pretrain_gpt.py) and
+    tools/run_text_generation_server.py (it serves through --engine dynamic
+    --paged-kv-cache). The mixer's sizes stay the model's own (a preset's,
+    or TransformerConfig's defaults: state 16, conv 4, expand 2, dt rank
+    hidden / 16). Every default is None = the model's own."""
+    g = ap.add_argument_group("hybrid state-space stack")
+    g.add_argument("--attn-layer-period", type=int, default=None,
+                   help="layer i attends iff i %% period == offset; every "
+                        "other layer is a Mamba-1 selective-state-space "
+                        "layer (HF attn_layer_period)")
+    g.add_argument("--attn-layer-offset", type=int, default=None,
+                   help="HF attn_layer_offset")
+    g.add_argument("--ssm-inner-norms", action="store_const", const=True,
+                   default=None,
+                   help="RMS norms on dt, B and C after x_proj (Jamba)")
+
+
+def hybrid_fields(args) -> dict:
+    """The TransformerConfig fields the add_hybrid_args flags set."""
+    return {f: getattr(args, f) for f in _HYBRID_FIELDS
+            if getattr(args, f, None) is not None}
+
+
 def add_serving_args(ap: argparse.ArgumentParser):
     """Serving / paged-KV flags (ISSUE 3) — single source of truth shared
     by the main parser (so config-YAML runs and --use-checkpoint-args
@@ -775,6 +805,7 @@ def build_parser(title: str = "megatronapp-tpu") -> argparse.ArgumentParser:
                         "<--save>/non_persistent)")
 
     add_serving_args(ap)   # paged KV serving flags (ISSUE 3)
+    add_hybrid_args(ap)
 
     g = ap.add_argument_group("megascan")  # reference arguments.py:2705ff
     g.add_argument("--trace", action="store_true")
@@ -1028,6 +1059,7 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             val = getattr(args, flag)
             if val != getattr(sentinel, flag):
                 overrides[field] = val
+        overrides.update(hybrid_fields(args))
         if overrides:
             model = _dc.replace(model, **overrides)
     else:
@@ -1095,6 +1127,7 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             flash_head_fold=args.flash_head_fold,
             compute_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,
             heterogeneous_layers_config_json=_hetero_json(args),
+            **hybrid_fields(args),
         )
 
     if getattr(args, "fp8", False):

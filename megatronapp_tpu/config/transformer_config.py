@@ -118,6 +118,22 @@ class TransformerConfig:
     # run as a prologue before the scanned stack (params["lead_block"]).
     moe_first_k_dense: int = 0
 
+    # Hybrid state-space stacks (HF `jamba`: attn_layer_period /
+    # attn_layer_offset): layer i attends iff i % period == offset, and
+    # every other layer's first half is a Mamba-1 selective-state-space
+    # mixer (transformer/ssm.py). None = every layer attends. The ssm_*
+    # fields are that mixer's sizes (HF mamba_d_state, mamba_d_conv,
+    # mamba_expand, mamba_dt_rank; None = ceil(hidden / 16)) and Jamba's
+    # RMS norms on dt, B and C. The convolution has a bias and the two
+    # projections none (HF mamba_conv_bias true, mamba_proj_bias false).
+    attn_layer_period: Optional[int] = None
+    attn_layer_offset: int = 0
+    ssm_state_dim: int = 16
+    ssm_conv_kernel: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: Optional[int] = None
+    ssm_inner_norms: bool = False
+
     # Multi-token prediction (DeepSeek-V3; reference
     # multi_token_prediction.py + transformer_config mtp_num_layers /
     # mtp_loss_scaling_factor).
@@ -279,6 +295,17 @@ class TransformerConfig:
                 f"moe_first_k_dense={self.moe_first_k_dense} needs an MoE "
                 f"model with moe_layer_freq 1 and more than that many "
                 f"layers (num_layers={self.num_layers})")
+        if self.attn_layer_period is not None:
+            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                raise ValueError(
+                    f"attn_layer_offset={self.attn_layer_offset} must lie "
+                    f"in [0, attn_layer_period={self.attn_layer_period})")
+            if (self.is_moe or self.multi_latent_attention
+                    or self.heterogeneous_layers_config_json):
+                raise ValueError(
+                    "a hybrid state-space stack (attn_layer_period) runs "
+                    "dense feed-forwards and plain attention layers: no "
+                    "MoE, MLA or heterogeneous block configs")
         from megatronapp_tpu.ops.context_parallel import CP_COMM_TYPES
         if self.cp_comm_type not in CP_COMM_TYPES:
             raise ValueError(
@@ -293,6 +320,22 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.kv_channels
+
+    def layer_is_attention(self, i: int) -> bool:
+        return (self.attn_layer_period is None
+                or i % self.attn_layer_period == self.attn_layer_offset)
+
+    @property
+    def num_attention_layers(self) -> int:
+        """Layers that attend, and so own a plane of the KV cache."""
+        return sum(self.layer_is_attention(i)
+                   for i in range(self.num_layers))
+
+    @property
+    def num_ssm_layers(self) -> int:
+        """Layers whose first half is a state-space mixer (0 unless
+        attn_layer_period is set)."""
+        return self.num_layers - self.num_attention_layers
 
     def num_parameters(self) -> int:
         """Approximate parameter count (embedding + blocks + final norm)."""

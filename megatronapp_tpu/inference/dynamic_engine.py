@@ -71,13 +71,25 @@ from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
 from megatronapp_tpu.trace.request_trace import (
     PhaseStats, get_request_tracer,
 )
-from megatronapp_tpu.transformer.block import layer_forward
+from megatronapp_tpu.transformer.block import (
+    hybrid_layer_loop, hybrid_layer_params, layer_forward,
+)
 from megatronapp_tpu.transformer.moe import StackedLayer
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
 from megatronapp_tpu.utils.platform import fresh_compiles
 
 logger = logging.getLogger(__name__)
+
+
+def _handed_over(host_array) -> jnp.ndarray:
+    """A host array for an asynchronous step, as a COPY: the engine goes
+    on writing `lengths`, `last_tokens` and the page table in place while
+    the dispatched step has yet to read them, and on a CPU jnp.asarray may
+    share the numpy buffer (ROADMAP S3). A step that reads them late (a
+    hybrid stack's first attention layer comes after its state-space
+    layers) then sees the next round's values every time."""
+    return jnp.asarray(np.array(host_array))
 
 
 class DeadlineExceeded(RuntimeError):
@@ -256,7 +268,7 @@ def _decode_step(params, tokens, cache, lengths, active,
 
 
 def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
-                       ctx=None):
+                       ctx=None, rows=None):
     """The layer loop of both paged steps: h through every layer, each
     appending its new rows to the pools and attending through them.
 
@@ -283,8 +295,42 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     0..k-1) run first, through the same body and into planes 0..k-1 of the
     same pools; the scanned stack takes ids k..L-1.
 
-    Returns (h, moe, (k, v[, k_scales, v_scales])); moe is None for a
-    dense model, else int32 [2]: the MoE layers' routing_counts summed."""
+    A hybrid stack (cfg.attn_layer_period) has two kinds of layer and two
+    kinds of state: `pages` is then (k, v, ssm, conv), the KV pools with a
+    plane an ATTENTION layer and behind them the recurrent-state pools
+    [L_ssm, slots, ...] of the state-space layers (paged_cache.py), all
+    four carried and written in place alike. The loop is
+    block.hybrid_layer_loop's scanned runs; an attention layer gets its
+    plane (kv_plane), a state-space layer the state pools, its plane of
+    them and `rows`, the slots of h's rows (None: row b is slot b).
+
+    Returns (h, moe, (k, v[, ssm, conv][, k_scales, v_scales])); moe is
+    None for a dense model, else int32 [2]: the MoE layers' routing_counts
+    summed."""
+    if cfg.attn_layer_period is not None:
+        if lora is not None or ctx is not None:
+            raise ValueError("a hybrid state-space stack serves on one "
+                             "device, without lora")
+
+        def run(carry, attends, k, lid):
+            hh, pools, kvs = carry
+            layer_p = hybrid_layer_params(params["block"], attends, k, lid)
+            if attends:
+                (hh, new), _ = layer(layer_p, hh, lid, pools[:2], kvs, None,
+                                     kv_plane=k)
+                pools = tuple(new[:2]) + pools[2:]
+                kvs = None if kvs is None else tuple(new[2:])
+            else:
+                (hh, new), _ = layer(layer_p, hh, lid, None, None, None,
+                                     ssm_state=pools[2:] + (k,),
+                                     state_rows=rows)
+                pools = pools[:2] + tuple(new)
+            return hh, pools, kvs
+
+        h, pages, scales = hybrid_layer_loop(
+            cfg, (h, tuple(pages), None if scales is None else tuple(scales)),
+            run)
+        return h, None, pages + (scales or ())
     lead = cfg.moe_first_k_dense
     block = params["block"]
     stacks = {}
@@ -326,7 +372,9 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     """One-token decode for every slot against the paged block pool.
 
     pages: ([L, NB, bs, Hkv, D], same) K/V pools (MLA: latent + k_pe
-    pools); page_table [B, max_blocks_per_seq] int32; lengths [B] append
+    pools; a hybrid stack: then its two state pools, and every slot's
+    state advances a token); page_table [B, max_blocks_per_seq] int32;
+    lengths [B] append
     positions; active [B] bool (inactive rows' writes are dropped and
     their outputs discarded). scales: ([L, NB, bs, Hkv] fp32, same) for
     an int8 pool — the step then quantizes the appended rows in-jit and
@@ -355,12 +403,12 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     # The ragged kernels mask by per-row kv length themselves (MLA
     # included since ISSUE 17 — the latent kernel attends through the
     # page table, no dense gather and no host-built mask).
-    def layer(layer_p, hh, lid, kv, kvs, ll):
+    def layer(layer_p, hh, lid, kv, kvs, ll, **kind):
         return layer_forward(
             layer_p, hh, cfg, cos, sin, None, layer_id=lid,
             kv_cache=kv, cache_index=None,
             cache_positions=lengths, page_table=page_table,
-            active=active, ctx=ctx, kv_scales=kvs, lora=ll)
+            active=active, ctx=ctx, kv_scales=kvs, lora=ll, **kind)
 
     h, moe, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
                                            cfg, layer, ctx)
@@ -371,7 +419,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
 def _paged_multiquery_step(params, tokens, pages, page_table, starts,
                            q_lens, active, cfg: TransformerConfig,
                            max_seq_len: int, ctx=None, scales=None,
-                           lora=None):
+                           lora=None, rows=None):
     """Ragged multi-token step against the paged pool — the UNIFIED
     prefill/decode primitive (speculative verify + chunked prefill).
 
@@ -379,7 +427,10 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     token counts in [1, S] (rows past a row's count are padding whose
     outputs are garbage); active [B] bool. Row b's token i lands at
     position starts[b] + i and attends the paged context plus the new
-    tail causally. Returns (logits [B, S, V], hidden [B, S, H] pre-head,
+    tail causally. On a hybrid stack row b runs in slot rows[b]: its
+    state-space layers scan the chunk from that slot's state (from zeros
+    where starts[b] is 0: a sequence begins), stop at q_lens[b] and write
+    the state back. Returns (logits [B, S, V], hidden [B, S, H] pre-head,
     the pools, written in place as in _paged_decode_step) — hidden feeds
     the MTP self-draft proposer."""
     b, s = tokens.shape
@@ -396,16 +447,16 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     # The multi-query ragged kernels mask themselves (MLA included since
     # ISSUE 17 — the latent kernel's scalar-prefetched q_lens carries
     # the causal tail mask).
-    def layer(layer_p, hh, lid, kv, kvs, ll):
+    def layer(layer_p, hh, lid, kv, kvs, ll, **kind):
         return layer_forward(
             layer_p, hh, cfg, cos, sin, None, layer_id=lid,
             kv_cache=kv, cache_index=None,
             cache_positions=starts, page_table=page_table,
             active=active, chunk_counts=q_lens, ctx=ctx,
-            kv_scales=kvs, lora=ll)
+            kv_scales=kvs, lora=ll, **kind)
 
     h, _, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
-                                         cfg, layer, ctx)
+                                         cfg, layer, ctx, rows)
     logits = gpt_head(params, h, cfg)
     return logits, h, new_pages
 
@@ -437,18 +488,20 @@ class _PoolStep:
         shardings = tuple(
             None if isinstance(a, jax.core.Tracer)
             else getattr(a, "sharding", None) for a in pools)
-        if shardings not in self._jits:
+        key = (shardings, len(args))
+        if key not in self._jits:
             pinned = {}
             if None not in shardings:
                 fmt = tuple(pool_format(sh, a.ndim)
                             for sh, a in zip(shardings, pools))
+                n = len(args[2])
                 pinned = dict(
-                    in_shardings=(None, None, fmt[:2], fmt[2:] or None)
-                    + (None,) * (self._fn.__code__.co_argcount - 4),
+                    in_shardings=(None, None, fmt[:n], fmt[n:] or None)
+                    + (None,) * (len(args) - 4),
                     out_shardings=(None,) * self._n_lead + (fmt,))
-            self._jits[shardings] = jax.jit(
+            self._jits[key] = jax.jit(
                 self._fn, donate_argnums=(2, 3), **pinned)
-        return shardings, self._jits[shardings]
+        return shardings, self._jits[key]
 
     def __call__(self, *args):
         shardings, jit = self._jit(args)
@@ -559,6 +612,32 @@ class DynamicInferenceEngine:
         # True, _admit leaves the waiting queue untouched so running
         # requests drain and the params swap lands on an empty batch.
         self.pause_admission = False
+
+        # A model with state-space layers (cfg.attn_layer_period) keeps,
+        # beside its attention layers' pages, a recurrent state a slot
+        # (PagedKVCache.state). Whatever would move a request's cache
+        # between places needs a snapshot of that state, which does not
+        # exist yet: each refuses here or at its call, in words.
+        self.has_state = cfg.num_ssm_layers > 0
+        if self.has_state:
+            refused = [name for name, on in (
+                ("paged=False (the dense cache)", not paged),
+                ("spec_method (speculative decoding rewinds rejected "
+                 "tokens)", spec_method and spec_method != "none"),
+                ("spill_host_mb (parking a session)", spill_host_mb),
+                ("adapter_cache (lora)", adapter_cache is not None),
+                ("an injected pool (disaggregated prefill)",
+                 pool is not None),
+                ("ctx (a serving mesh)", ctx is not None)) if on]
+            if refused:
+                raise ValueError(
+                    "this model has state-space layers, whose recurrent "
+                    "state lives in the paged engine's slots on one device "
+                    "and has no state snapshots yet (ROADMAP M4): cannot "
+                    "serve it with " + "; ".join(refused))
+            # A prefix hit skips tokens whose state nobody kept.
+            enable_prefix_caching = False
+        self.state_stats = {"resets": 0, "dropped": 0, "prefill_scans": 0}
 
         self.paged = paged
         if paged:
@@ -760,9 +839,15 @@ class DynamicInferenceEngine:
 
     def startup_line(self) -> str:
         """What this engine runs, for the log and a server's banner."""
-        return (f"dynamic engine: paged={self.paged}, max_batch="
+        line = (f"dynamic engine: paged={self.paged}, max_batch="
                 f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
                 f"prefill_chunk={self.prefill_chunk}")
+        if self.has_state:
+            line += (f", state={self.cfg.num_ssm_layers} state-space layers"
+                     f" x {self.pool.state_bytes_per_slot} B a slot, prefix "
+                     "reuse off (a prefix hit would skip tokens whose "
+                     "state nobody kept)")
+        return line
 
     def _build_jits(self):
         cfg = self.cfg
@@ -782,7 +867,9 @@ class DynamicInferenceEngine:
             step_ctx = self.ctx if self.tp_paged else None
             # The pools (`pages`, and `scales`: the int8 pool's fp32
             # scale-pool pair, None for bf16 pools — an empty pytree, so
-            # the same signature serves both dtypes) are DONATED, and the
+            # the same signature serves both dtypes; a model with
+            # state-space layers: its state pools behind the two page
+            # pools, self._pools()) are DONATED, and the
             # layer loop carries them and writes them in place: a step's
             # output pools are its input buffers, and the device holds
             # one pool (_PoolStep pins their layout too). `lora` follows
@@ -800,13 +887,13 @@ class DynamicInferenceEngine:
             self._decode = _PoolStep(_decode_traced, n_lead=2)
 
             def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
-                           lora):
+                           lora, rows=None):
                 # Python side-effect: runs only while TRACING.
                 self.mq_traces += 1
                 return _paged_multiquery_step(p, t, pages, tbl, starts,
                                               qlens, act, cfg, msl,
                                               ctx=step_ctx, scales=scales,
-                                              lora=lora)
+                                              lora=lora, rows=rows)
 
             self._mq_step = _PoolStep(_mq_traced, n_lead=2)
             if self.spec_method:
@@ -830,16 +917,23 @@ class DynamicInferenceEngine:
         pin stale traces in the paged backend."""
         self._build_jits()
 
+    def _pools(self):
+        """A step's `pages` operand: the page pools and, behind them, the
+        recurrent-state pools of a model that has them."""
+        return self.pool.pages + (self.pool.state or ())
+
     def _commit_pools(self, new):
         """Take a step's pools back: the donated buffers themselves,
-        written in place — (k, v) for bf16 pools, (k, v, k_scales,
-        v_scales) for int8 pools, whose in-jit quantize writes the scale
-        pools through the same layer loop."""
+        written in place — (k, v), then (ssm, conv) for a model with
+        state-space layers, then (k_scales, v_scales) for int8 pools,
+        whose in-jit quantize writes the scale pools through the same
+        layer loop."""
+        self.pool.pages = tuple(new[:2])
+        rest = tuple(new[2:])
+        if self.has_state:
+            self.pool.state, rest = rest[:2], rest[2:]
         if self.pool.quantized:
-            self.pool.pages = tuple(new[:2])
-            self.pool.scales = tuple(new[2:])
-        else:
-            self.pool.pages = tuple(new)
+            self.pool.scales = rest
 
     def _span(self, name: str, rid: Optional[int] = None,
               ring: Optional[str] = None, **attrs):
@@ -1066,11 +1160,20 @@ class DynamicInferenceEngine:
             self.requests.pop(req.request_id, None)
             self._rt.finish(req.request_id, "abort")
 
+    def _refuse_on_state(self, what: str):
+        if self.has_state:
+            raise ValueError(
+                f"{what}: this model's state-space layers keep a recurrent "
+                "state a slot, and moving a request needs state snapshots, "
+                "which do not exist yet (ROADMAP M4)")
+
     def _free_slot(self, slot: int):
         """Clear every per-slot engine resource (request ref, length,
         proposer state, MTP hidden) — the ONE place to extend when a new
         per-slot resource is added; pool blocks are released by the
-        caller (release semantics differ per path)."""
+        caller (release semantics differ per path). A slot's recurrent
+        state needs nothing: the next sequence's first prefill call starts
+        from zeros whatever the slot holds."""
         self.slots[slot] = None
         self.lengths[slot] = 0
         self._h_valid[slot] = False
@@ -1119,6 +1222,7 @@ class DynamicInferenceEngine:
         already sampled prefill-side with the identical fold_in chain).
         Returns the decode slot."""
         assert self.paged, "adoption requires the paged backend"
+        self._refuse_on_state("adopt_request")
         slot = next(i for i in range(self.max_batch)
                     if self.slots[i] is None)
         if self.adapters is not None:
@@ -1152,6 +1256,7 @@ class DynamicInferenceEngine:
         nothing back if the migration dies between export and import
         (the "fleet-migrate" chaos site)."""
         assert self.paged, "session export requires the paged backend"
+        self._refuse_on_state("export_request")
         req = self.requests.get(rid)
         if req is not None and not req.finished and rid in self._parked:
             # A PARKED session migrates too (a drained/reloading replica
@@ -1177,6 +1282,7 @@ class DynamicInferenceEngine:
         shipped (proposal-quality-only, same note as the disagg adopt
         path); ngram/draft proposers are unaffected."""
         assert self.paged, "session import requires the paged backend"
+        self._refuse_on_state("import_request")
         req: Request = payload["req"]
         slot = next((i for i in range(self.max_batch)
                      if self.slots[i] is None), None)
@@ -1585,7 +1691,14 @@ class DynamicInferenceEngine:
         pool = self.pool
         cached = plan.cached_tokens
         c = self.prefill_chunk
-        table_row = jnp.asarray(pool.page_table[slot][None])     # [1, MB]
+        table_row = _handed_over(pool.page_table[slot][None])    # [1, MB]
+        # The sequence's state starts from zeros inside its first call
+        # (start 0: prefix reuse is off on such a model), in slot `slot`.
+        rows = ()
+        if self.has_state:
+            assert cached == 0, cached
+            rows = (jnp.asarray([slot], jnp.int32),)
+            self.state_stats["resets"] += 1
         pos, count = cached, 0
         logits = hid = None
         while pos < p_len:
@@ -1602,12 +1715,14 @@ class DynamicInferenceEngine:
                 chaos.fire("kv-quant-write")
             with self._span("engine.prefill_call", tokens=count):
                 logits, hid, new = self._mq_step(
-                    self.params, jnp.asarray(chunk), self.pool.pages,
+                    self.params, jnp.asarray(chunk), self._pools(),
                     self.pool.scales,
                     table_row, jnp.asarray([pos], jnp.int32),
                     jnp.asarray([count], jnp.int32), jnp.ones((1,), bool),
-                    self._lora_args(rows=self.row_adapter[slot:slot + 1]))
+                    self._lora_args(rows=self.row_adapter[slot:slot + 1]),
+                    *rows)
                 self._commit_pools(new)
+                self.state_stats["prefill_scans"] += self.cfg.num_ssm_layers
             pos += count
         # Register the prompt's full blocks so concurrent same-prefix
         # requests hit them immediately.
@@ -1682,8 +1797,11 @@ class DynamicInferenceEngine:
     def _preempt(self, req: Request, out: List[Request]):
         """Push a running request back to the waiting queue, releasing its
         blocks (full blocks stay prefix-cached while evictable, so the
-        resume prefill usually re-hits its own KV)."""
+        resume prefill usually re-hits its own KV). A recurrent state is
+        dropped with the slot: the request is recomputed from its
+        tokens."""
         slot = req.slot
+        self.state_stats["dropped"] += int(self.has_state)
         self.pool.release(slot, np.asarray(req.tokens),
                           int(self.lengths[slot]), preempted=True)
         self._free_slot(slot)
@@ -1830,18 +1948,18 @@ class DynamicInferenceEngine:
                 [self.slots[i] is not None and not self.slots[i].finished
                  for i in range(self.max_batch)])
             active_mask = jnp.asarray(active_np)
-            lengths = jnp.asarray(self.lengths)
+            lengths = _handed_over(self.lengths)
             moe = None
             if self.paged:
                 logits, moe, new = self._decode(
-                    self.params, jnp.asarray(self.last_tokens),
-                    self.pool.pages, self.pool.scales,
-                    jnp.asarray(self.pool.page_table[:self.max_batch]),
+                    self.params, _handed_over(self.last_tokens),
+                    self._pools(), self.pool.scales,
+                    _handed_over(self.pool.page_table[:self.max_batch]),
                     lengths, active_mask, self._lora_args())
                 self._commit_pools(new)
             else:
                 logits, self.cache = self._decode(
-                    self.params, jnp.asarray(self.last_tokens), self.cache,
+                    self.params, _handed_over(self.last_tokens), self.cache,
                     lengths, active_mask)
             # The decode wrote each active row's kv at lengths[slot].
             self.lengths += active_np.astype(np.int32)
@@ -2036,7 +2154,7 @@ class DynamicInferenceEngine:
         spec = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
             a.shape, a.dtype)
         p_spec = jax.tree.map(spec, self.params)
-        pages_spec = jax.tree.map(spec, self.pool.pages)
+        pages_spec = jax.tree.map(spec, self._pools())
         scales_spec = jax.tree.map(spec, self.pool.scales)
         mb = self.pool.page_table.shape[1]
         args = (p_spec,
@@ -2069,6 +2187,12 @@ class DynamicInferenceEngine:
         On a paged engine "paged" holds the walk's counters over plain
         decode rounds: blocks_live of blocks_table is the share of the
         page table's width that held rows (False on a dense-cache one).
+        "state" is a dict on a model with state-space layers (False
+        otherwise): `layers` and `slots` of recurrent state at
+        `bytes_per_slot`, `resets` (sequences started from zeros at
+        admission), `dropped` (states thrown away by preemption),
+        `prefill_scans` (chunk scans run: prefill calls x state-space
+        layers).
 
         include_dispatch=True adds the traced decode step's launch
         counts (dispatch_stats; the first call traces the step once and
@@ -2082,7 +2206,13 @@ class DynamicInferenceEngine:
             "multiquery_traces": self.mq_traces,
             "decode_traces": self.decode_traces,
             "steps": self.step_stats.snapshot(),
+            "state": False,
         }
+        if self.has_state:
+            out["state"] = dict(
+                self.state_stats, layers=self.cfg.num_ssm_layers,
+                slots=self.max_batch,
+                bytes_per_slot=self.pool.state_bytes_per_slot)
         if self.cfg.is_moe:
             per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)
                          * self.cfg.num_moe_experts)

@@ -232,7 +232,9 @@ class PagedKVCache:
         # decode slot via transfer_slot (pure bookkeeping, no KV copy).
         self.num_slots = max_batch + extra_slots
 
-        l = cfg.num_layers
+        # A plane a layer that attends: a hybrid stack's state-space
+        # layers cache no token (their state is `self.state`, below).
+        l = cfg.num_attention_layers
         nb, bs = self.num_blocks, self.block_size
         # scales: per-(row, kv-head) fp32 quantization scales for int8
         # pools (None for bf16) — scattered/copied exactly like the data
@@ -252,6 +254,36 @@ class PagedKVCache:
         if self.quantized:
             self.scales = tuple(_new_pool(sshape, jnp.float32, 1)
                                 for _ in shapes)
+
+        # The second tenant: the recurrent state of a hybrid stack's
+        # state-space layers (transformer/ssm.py), which no page table
+        # names. A slot owns row `slot` of every layer's plane, whatever
+        # its sequence's length: h [N, E] in float32 (E minor: a whole
+        # number of 128-lane vregs) and the convolution's last k-1 inputs
+        # in the compute type, side by side in one row [(k-1) * E] (as
+        # [L, slots, k-1, E] XLA pads 3 taps to 4 sublanes and relayouts
+        # all of it on the way into a decode step and out again; as
+        # [L, k-1, slots, E] it does the same in a prefill call). Nothing
+        # here writes them: the engine's steps carry, donate and update
+        # them in place like the pages, and a sequence's first prefill
+        # call starts from zeros whatever the slot held, so admission,
+        # release and preemption have nothing to do. They cannot be
+        # shared, exported or rewound (yet): the engine refuses what would
+        # need a snapshot of them.
+        self.state = None
+        if cfg.num_ssm_layers:
+            if extra_slots:
+                raise ValueError(
+                    "staging slots (disaggregated prefill) hand a sequence "
+                    "over by its page table; a state-space layer's state "
+                    "has no snapshot to hand over yet")
+            e = cfg.ssm_expand * cfg.hidden_size
+            self.state = (
+                _new_pool((cfg.num_ssm_layers, max_batch, cfg.ssm_state_dim,
+                           e), jnp.float32, 0),
+                _new_pool((cfg.num_ssm_layers, max_batch,
+                           (cfg.ssm_conv_kernel - 1) * e), cfg.compute_dtype,
+                          0))
 
         self.page_table = np.zeros((self.num_slots, self.max_blocks_per_seq),
                                    np.int32)
@@ -289,6 +321,17 @@ class PagedKVCache:
     @pages.setter
     def pages(self, new):
         self._pages = (None if new is None
+                       else tuple(_in_pool_format(a) for a in new))
+
+    @property
+    def state(self) -> Optional[Tuple[jnp.ndarray, ...]]:
+        """The recurrent-state pools (ssm, conv) of a model with
+        state-space layers (else None), held like `pages`."""
+        return self._state
+
+    @state.setter
+    def state(self, new):
+        self._state = (None if new is None
                        else tuple(_in_pool_format(a) for a in new))
 
     @property
@@ -337,14 +380,25 @@ class PagedKVCache:
 
     @property
     def bytes_total(self) -> int:
-        """Resident pool bytes, dtype-aware: int8 data + fp32 scales for
-        quantized pools, compute-dtype data otherwise — always read off
-        the addressable arrays, never derived from the param dtype."""
-        return sum(p.size * p.dtype.itemsize for p in self._arrays())
+        """Resident cache bytes, dtype-aware: int8 data + fp32 scales for
+        quantized pools, compute-dtype data otherwise, and the recurrent
+        state of a model that has it — always read off the addressable
+        arrays, never derived from the param dtype."""
+        return self.num_blocks * self.bytes_per_block \
+            + self.max_batch * self.state_bytes_per_slot
 
     @property
     def bytes_per_block(self) -> int:
-        return self.bytes_total // self.num_blocks
+        """What one block of cached tokens takes (pages and scales)."""
+        return sum(p.size * p.dtype.itemsize
+                   for p in self._arrays()) // self.num_blocks
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """What one slot's recurrent state takes (0 without state-space
+        layers)."""
+        return sum(p.size * p.dtype.itemsize
+                   for p in self.state or ()) // self.max_batch
 
     def blocks_in_use(self) -> int:
         """Blocks with live references (excludes free + evictable)."""

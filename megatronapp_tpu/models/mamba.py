@@ -1,4 +1,7 @@
-"""Mamba (selective state-space) model.
+"""Mamba (selective state-space) model: a pure-M stack (and the unrolled
+'M*' hybrids of pretrain_mamba.py) around the mixer of transformer/ssm.py.
+The scanned hybrid that trains and serves through models/gpt.py and the
+paged engine is TransformerConfig.attn_layer_period.
 
 Parity with /root/reference/megatron/core/ssm/ (MambaMixer/MambaBlock,
 1.6k LoC; hybrid mamba/attention layer allocation in mamba_hybrid_layer_
@@ -30,6 +33,9 @@ from megatronapp_tpu.parallel.sharding import is_logical_axes
 from megatronapp_tpu.transformer.block import (
     _remat_wrap, init_layer_params, layer_forward,
 )
+from megatronapp_tpu.transformer.ssm import (
+    SsmDims, init_ssm_params, selective_scan, ssm_forward,
+)
 
 
 @dataclasses.dataclass
@@ -44,148 +50,41 @@ class MambaConfig:
     hybrid_pattern: Optional[str] = None
 
 
+def _dims(mcfg: MambaConfig) -> SsmDims:
+    return SsmDims(mcfg.state_dim, mcfg.conv_kernel, mcfg.expand,
+                   mcfg.dt_rank)
+
+
 def init_mamba_mixer_params(rng, cfg: TransformerConfig, mcfg: MambaConfig):
-    h = cfg.hidden_size
-    e = mcfg.expand * h
-    n = mcfg.state_dim
-    dt_rank = mcfg.dt_rank or max(h // 16, 1)
-    keys = jax.random.split(rng, 6)
-    std = cfg.init_method_std
-    p = {
-        "in_kernel": jax.random.normal(keys[0], (h, 2 * e),
-                                       cfg.params_dtype) * std,
-        "conv_kernel": jax.random.normal(
-            keys[1], (mcfg.conv_kernel, e), cfg.params_dtype) * std,
-        "conv_bias": jnp.zeros((e,), cfg.params_dtype),
-        # x → (Δ_rank, B, C)
-        "x_proj": jax.random.normal(keys[2], (e, dt_rank + 2 * n),
-                                    cfg.params_dtype) * std,
-        "dt_proj": jax.random.normal(keys[3], (dt_rank, e),
-                                     cfg.params_dtype) * std,
-        # softplus(dt_bias) initialized in [1e-3, 1e-1] (reference dt init).
-        "dt_bias": jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
-            keys[4], (e,), jnp.float32,
-            jnp.log(1e-3), jnp.log(1e-1))))).astype(cfg.params_dtype),
-        # A negative-real diagonal, initialized -[1..N] per channel.
-        "A_log": jnp.log(jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32),
-                                  (e, 1))).astype(cfg.params_dtype),
-        "D": jnp.ones((e,), cfg.params_dtype),
-        "out_kernel": jax.random.normal(
-            keys[5], (e, h), cfg.params_dtype) * (
-                std / jnp.sqrt(2.0 * cfg.num_layers)),
-    }
-    ax = {
-        "in_kernel": ("embed", "mlp"), "conv_kernel": (None, "mlp"),
-        "conv_bias": ("mlp",), "x_proj": ("mlp", None),
-        "dt_proj": (None, "mlp"), "dt_bias": ("mlp",),
-        "A_log": ("mlp", None), "D": ("mlp",),
-        "out_kernel": ("mlp", "embed"),
-    }
-    return p, ax
+    return init_ssm_params(rng, cfg, _dims(mcfg))
 
 
 def _selective_scan(u, dt, A, B, C, D, return_h: bool = False):
-    """u,dt [B,S,E]; A [E,N]; B,C [B,S,N]; D [E] → y [B,S,E].
-
-    h_t = exp(dt_t A) h_{t-1} + dt_t B_t u_t ;  y_t = C_t · h_t + D u_t.
-    Runs as a parallel associative scan over the sequence axis.
-    return_h also yields the final state h_S [B,E,N] (decode prefill).
-    """
-    # Discretize: a [B,S,E,N], b [B,S,E,N].
-    a = jnp.exp(dt[..., None] * A[None, None])            # [B,S,E,N]
-    b = dt[..., None] * B[:, :, None, :] * u[..., None]   # [B,S,E,N]
-
-    def combine(left, right):
-        a_l, b_l = left
-        a_r, b_r = right
-        return a_l * a_r, a_r * b_l + b_r
-
-    _, h = jax.lax.associative_scan(combine, (a, b), axis=1)
-    y = jnp.einsum("bsen,bsn->bse", h, C)
-    y = y + u * D[None, None]
-    return (y, h[:, -1]) if return_h else y
+    """u,dt [B,S,E]; A [E,N]; B,C [B,S,N]; D [E] → y [B,S,E] (and, with
+    return_h, the final state [B,N,E]): transformer/ssm.selective_scan
+    from a zero state."""
+    y, h_last = selective_scan(u, dt, A.T, B, C, D)
+    return (y, h_last) if return_h else y
 
 
 def mamba_mixer_forward(p, x, cfg: TransformerConfig, mcfg: MambaConfig,
                         return_state: bool = False):
-    """x [B,S,H] → [B,S,H] (+ (conv_tail [B,k-1,E], h_last [B,E,N]) when
+    """x [B,S,H] → [B,S,H] (+ (conv_tail [B,k-1,E], h_last [B,N,E]) when
     return_state — the decode cache seeded by prefill)."""
-    b, s, h = x.shape
-    e = mcfg.expand * h
-    n = mcfg.state_dim
-    dt_rank = mcfg.dt_rank or max(h // 16, 1)
-    dt_f32 = jnp.float32
-    xz = x.astype(cfg.compute_dtype) @ p["in_kernel"].astype(
-        cfg.compute_dtype)
-    u_raw, z = jnp.split(xz, 2, axis=-1)
-
-    # Causal depthwise conv along seq.
-    k = mcfg.conv_kernel
-    u_pad = jnp.pad(u_raw, ((0, 0), (k - 1, 0), (0, 0)))
-    windows = jnp.stack([u_pad[:, i:i + s] for i in range(k)], axis=0)
-    u = jnp.einsum("kbse,ke->bse", windows,
-                   p["conv_kernel"].astype(u_raw.dtype))
-    u = u + p["conv_bias"].astype(u.dtype)
-    u = jax.nn.silu(u)
-
-    proj = u @ p["x_proj"].astype(u.dtype)  # [B,S,dt_rank+2N]
-    dt_r, B_, C_ = jnp.split(proj, [dt_rank, dt_rank + n], axis=-1)
-    dt = jax.nn.softplus(
-        dt_r.astype(dt_f32) @ p["dt_proj"].astype(dt_f32)
-        + p["dt_bias"].astype(dt_f32))
-    A = -jnp.exp(p["A_log"].astype(dt_f32))
-    y = _selective_scan(u.astype(dt_f32), dt, A, B_.astype(dt_f32),
-                        C_.astype(dt_f32), p["D"].astype(dt_f32),
-                        return_h=return_state)
-    if return_state:
-        y, h_last = y
-    y = y.astype(cfg.compute_dtype) * jax.nn.silu(z)
-    out = y @ p["out_kernel"].astype(cfg.compute_dtype)
-    if not return_state:
-        return out
-    # conv cache = last k-1 PRE-conv inputs (pad with zeros for short
-    # prompts, matching the forward's zero padding).
-    conv_tail = u_pad[:, s: s + k - 1]
-    return out, (conv_tail, h_last)
+    out, state = ssm_forward(p, x, cfg, _dims(mcfg))
+    return (out, state) if return_state else out
 
 
 def mamba_mixer_step(p, conv_buf, ssm_h, x, cfg: TransformerConfig,
                      mcfg: MambaConfig):
-    """One-token recurrent mixer step (the reference decodes Mamba with
-    Triton selective_state_update; here plain jnp — the per-token work is
-    a handful of small matmuls).
+    """One-token recurrent mixer step, plain jnp.
 
-    conv_buf [B,k-1,E] (pre-conv inputs), ssm_h [B,E,N], x [B,H] →
+    conv_buf [B,k-1,E] (pre-conv inputs), ssm_h [B,N,E], x [B,H] →
     (y [B,H], (conv_buf', ssm_h')).
     """
-    h = x.shape[-1]
-    n = mcfg.state_dim
-    dt_rank = mcfg.dt_rank or max(h // 16, 1)
-    dt_f32 = jnp.float32
-    xz = x.astype(cfg.compute_dtype) @ p["in_kernel"].astype(
-        cfg.compute_dtype)
-    u_raw, z = jnp.split(xz, 2, axis=-1)              # [B,E]
-
-    window = jnp.concatenate([conv_buf, u_raw[:, None]], axis=1)  # [B,k,E]
-    u = jnp.einsum("bke,ke->be", window,
-                   p["conv_kernel"].astype(u_raw.dtype))
-    u = jax.nn.silu(u + p["conv_bias"].astype(u.dtype))
-
-    proj = u @ p["x_proj"].astype(u.dtype)
-    dt_r, B_, C_ = jnp.split(proj, [dt_rank, dt_rank + n], axis=-1)
-    dt = jax.nn.softplus(
-        dt_r.astype(dt_f32) @ p["dt_proj"].astype(dt_f32)
-        + p["dt_bias"].astype(dt_f32))                # [B,E]
-    A = -jnp.exp(p["A_log"].astype(dt_f32))           # [E,N]
-    a = jnp.exp(dt[..., None] * A[None])              # [B,E,N]
-    b = dt[..., None] * B_.astype(dt_f32)[:, None, :] \
-        * u.astype(dt_f32)[..., None]
-    ssm_h = a * ssm_h + b
-    y = jnp.einsum("ben,bn->be", ssm_h, C_.astype(dt_f32))
-    y = y + u.astype(dt_f32) * p["D"].astype(dt_f32)[None]
-    y = y.astype(cfg.compute_dtype) * jax.nn.silu(z)
-    out = y @ p["out_kernel"].astype(cfg.compute_dtype)
-    return out, (window[:, 1:], ssm_h)
+    out, state = ssm_forward(p, x[:, None], cfg, _dims(mcfg),
+                             state=(conv_buf, ssm_h))
+    return out[:, 0], state
 
 
 def init_mamba_params(rng, cfg: TransformerConfig, mcfg: MambaConfig):

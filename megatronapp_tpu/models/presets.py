@@ -120,7 +120,26 @@ def mamba_130m(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def jamba2_3b(**kw) -> TransformerConfig:
+    """ai21labs/AI21-Jamba2-3B: 28 layers of which 7 and 21 attend (20
+    heads, 1 key/value head) and 26 are Mamba-1 layers; a dense SwiGLU of
+    8192 in every layer; RMSNorm, a tied head, no positional term. Serves
+    through --engine dynamic --paged-kv-cache."""
+    d = dict(num_layers=28, hidden_size=2560, num_attention_heads=20,
+             num_query_groups=1, ffn_hidden_size=8192, vocab_size=65536,
+             max_position_embeddings=262144,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-6,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             position_embedding=PositionEmbeddingKind.none,
+             attn_layer_period=14, attn_layer_offset=7, ssm_state_dim=16,
+             ssm_conv_kernel=4, ssm_expand=2, ssm_dt_rank=160,
+             ssm_inner_norms=True)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 PRESETS = {
+    "jamba2-3b": jamba2_3b,
     "gpt2-125m": gpt2_125m,
     "gpt3-2.7b": gpt3_2p7b,
     "mamba-130m": mamba_130m,

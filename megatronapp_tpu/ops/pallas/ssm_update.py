@@ -1,0 +1,92 @@
+"""The decode step of a selective-state-space layer: one Pallas call that
+updates the running slots' recurrent state in place.
+
+A Mamba-1 layer carries, per sequence, h [N, E] float32 (N the state size,
+E the expanded width; E is the minor dimension, a whole number of 128-lane
+vregs). A decode round advances every running slot by one token:
+
+    h' = exp(dt * A) * h + (dt * B) * u ;  y = sum_n h'[n] * C[n] + D * u
+
+The states of all the model's state-space layers live in one stacked pool
+[L, slots, N, E] (inference/paged_cache.py) which rides the engine's layer
+loop as a carry. The kernel reads the layer's plane through the
+scalar-prefetched layer id and writes h' over h (input_output_aliases), so
+a step holds one copy of the pool and touches, a layer, the planes of the
+slots that run: the rows are sorted running-first and their count bounds
+the grid, so an inactive slot's state is neither read nor written and its
+y is 0.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from megatronapp_tpu.ops.pallas import kernel_gen
+
+
+def ssm_update_reference(h, dt, u, b, c, a_t, d):
+    """The plain update, [rows, N, E] at once: (y [rows, E], h')."""
+    h = jnp.exp(dt[:, None, :] * a_t[None]) * h \
+        + (dt[:, None, :] * b[:, :, None]) * u[:, None, :]
+    y = jnp.sum(h * c[:, :, None], axis=1) + u * d[None]
+    return y, h
+
+
+def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
+               b: jnp.ndarray, c: jnp.ndarray, a_t: jnp.ndarray,
+               d: jnp.ndarray, active: jnp.ndarray):
+    """pool [L, slots, N, E] f32; layer int32 scalar; dt, u [slots, E]
+    f32; b, c [slots, N] f32; a_t [N, E] f32 (A transposed); d [E] f32;
+    active [slots] bool. Returns (y [slots, E] f32, pool): the pool is the
+    buffer that came in wherever the caller's copy of it is dead (a
+    donated argument, a loop carry), with plane `layer` of the active
+    slots advanced one token."""
+    slots, n, e = pool.shape[1:]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+
+    def kernel(lid_ref, row_ref, dt_ref, u_ref, b_ref, c_ref, a_ref, d_ref,
+               h_ref, y0_ref, y_ref, h_out_ref):
+        del lid_ref, row_ref, y0_ref
+        dt_, u_ = dt_ref[...], u_ref[...]                      # [1, E]
+        h = jnp.exp(dt_ * a_ref[...]) * h_ref[...] \
+            + (dt_ * b_ref[...]) * u_                          # [N, E]
+        h_out_ref[...] = h
+        y_ref[...] = jnp.sum(h * c_ref[...], axis=0, keepdims=True) \
+            + u_ * d_ref[...]
+
+    def row(i, lid, rows):
+        return (rows[i], 0, 0)
+
+    def fixed(i, lid, rows):
+        return (0, 0)
+
+    def plane(i, lid, rows):
+        return (lid[0], rows[i], 0, 0)
+
+    # A row's vectors come as [slots, 1, E] and [slots, N, 1]: blocks whose
+    # last two dims are the array's own, which Mosaic takes whole.
+    wide = pl.BlockSpec((None, 1, e), row)
+    tall = pl.BlockSpec((None, n, 1), row)
+    state = pl.BlockSpec((None, None, n, e), plane)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(jnp.sum(active, dtype=jnp.int32),),
+        in_specs=[wide, wide, tall, tall, pl.BlockSpec((n, e), fixed),
+                  pl.BlockSpec((1, e), fixed), state, wide],
+        out_specs=[wide, state],
+    )
+    y, pool = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((slots, 1, e), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={8: 1, 9: 0},
+        interpret=kernel_gen._interpret(),
+        name="ssm_update",
+    )(layer, order, dt[:, None, :], u[:, None, :], b[:, :, None],
+      c[:, :, None], a_t, d[None, :], pool,
+      jnp.zeros((slots, 1, e), jnp.float32))
+    return y[:, 0], pool

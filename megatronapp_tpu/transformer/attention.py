@@ -106,6 +106,7 @@ def attention_forward(
     kv_scales=None,
     fp8=None,
     lora=None,
+    kv_plane=None,
 ) -> jnp.ndarray:
     """x: [B, S, H] → [B, S, H]. Returns (out, new_kv_cache).
 
@@ -115,7 +116,9 @@ def attention_forward(
     layer_id (required then) naming this layer's plane: each row appends
     its token at its own (block, offset) of that plane, in place, and
     attends through the ragged paged-attention kernel, which masks by
-    per-row kv length (no caller mask needed).
+    per-row kv length (no caller mask needed). kv_plane names the plane
+    where it is not the layer id: a hybrid stack's pools hold planes for
+    its attention layers only.
     active: [B] bool — inactive rows' writes are dropped (their page
     tables may reference blocks re-allocated to other requests).
     chunk_counts: [B] int32 — multi-token paged append (speculative
@@ -347,7 +350,8 @@ def attention_forward(
             from megatronapp_tpu.parallel.collectives import (
                 current_manual_axes,
             )
-            if layer_id is None:
+            plane = layer_id if kv_plane is None else kv_plane
+            if plane is None:
                 raise ValueError(
                     "paged attention reads the stacked pool through "
                     "layer_id — pass this layer's index")
@@ -364,21 +368,21 @@ def attention_forward(
                           else jnp.full((b,), s, jnp.int32))
             (ck, cv), new_scales = append_kv(
                 kv_cache, kv_scales, (k, v) if ragged else (k[:, 0], v[:, 0]),
-                page_table, cache_positions, active, layer_id, counts,
+                page_table, cache_positions, active, plane, counts,
                 mesh)
             sc_kw = ({} if new_scales is None else
                      {"k_scales": new_scales[0], "v_scales": new_scales[1]})
             if ragged:
                 paged_out = kernel_gen.paged_attention(
                     q, ck, cv, page_table, cache_positions + counts,
-                    q_lens=counts, mesh=mesh, layer=layer_id, **sc_kw)
+                    q_lens=counts, mesh=mesh, layer=plane, **sc_kw)
                 _announce("paged multi-query",
                           "pallas ragged paged kernel",
                           kernel_gen._interpret())
             else:
                 paged_out = kernel_gen.paged_attention(
                     q[:, 0], ck, cv, page_table, cache_positions + 1,
-                    mesh=mesh, layer=layer_id, **sc_kw)[:, None]
+                    mesh=mesh, layer=plane, **sc_kw)[:, None]
                 _announce("paged decode", "pallas paged kernel",
                           kernel_gen._interpret())
             if tp_paged:

@@ -18,6 +18,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from megatronapp_tpu.config.transformer_config import (
@@ -32,37 +33,53 @@ from megatronapp_tpu.transformer.moe import init_moe_params, moe_forward
 from megatronapp_tpu.scope.hooks import scope_capture
 
 
+def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
+    """A layer's first half: its norm and its mixer (attention, MLA, or with
+    `ssm` a selective-state-space mixer)."""
+    if ssm:
+        from megatronapp_tpu.transformer.ssm import init_ssm_params, ssm_dims
+        name = "ssm"
+        mix_p, mix_ax = init_ssm_params(rng, cfg, ssm_dims(cfg), out_std)
+    elif cfg.multi_latent_attention:
+        from megatronapp_tpu.transformer.mla import init_mla_params
+        name = "attention"
+        mix_p, mix_ax = init_mla_params(rng, cfg, out_std)
+    else:
+        name = "attention"
+        mix_p, mix_ax = init_attention_params(rng, cfg, out_std)
+    p = {"ln1_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype),
+         name: mix_p}
+    ax = {"ln1_scale": ("embed",), name: mix_ax}
+    if cfg.normalization == NormKind.layernorm:
+        p["ln1_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
+        ax["ln1_bias"] = ("embed",)
+    return p, ax
+
+
+def _init_ffn_half(rng, cfg: TransformerConfig, out_std,
+                   force_dense: bool = False):
+    """A layer's second half: its norm and its feed-forward."""
+    p = {"ln2_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype)}
+    ax = {"ln2_scale": ("embed",)}
+    if cfg.normalization == NormKind.layernorm:
+        p["ln2_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
+        ax["ln2_bias"] = ("embed",)
+    if cfg.is_moe and not force_dense:
+        p["moe"], ax["moe"] = init_moe_params(rng, cfg, out_std)
+    else:
+        p["mlp"], ax["mlp"] = init_mlp_params(rng, cfg, out_std)
+    return p, ax
+
+
 def init_layer_params(rng, cfg: TransformerConfig, force_dense: bool = False):
     """One layer's params + logical axes (unstacked)."""
     # Scaled init for residual-out projections: std/sqrt(2*num_layers)
     # (reference scaled_init_method_normal, training/utils).
     out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
     k_attn, k_mlp = jax.random.split(rng)
-    if cfg.multi_latent_attention:
-        from megatronapp_tpu.transformer.mla import init_mla_params
-        attn_p, attn_ax = init_mla_params(k_attn, cfg, out_std)
-    else:
-        attn_p, attn_ax = init_attention_params(k_attn, cfg, out_std)
-    p = {
-        "ln1_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype),
-        "ln2_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype),
-        "attention": attn_p,
-    }
-    ax = {
-        "ln1_scale": ("embed",),
-        "ln2_scale": ("embed",),
-        "attention": attn_ax,
-    }
-    if cfg.normalization == NormKind.layernorm:
-        p["ln1_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
-        p["ln2_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
-        ax["ln1_bias"] = ("embed",)
-        ax["ln2_bias"] = ("embed",)
-    if cfg.is_moe and not force_dense:
-        p["moe"], ax["moe"] = init_moe_params(k_mlp, cfg, out_std)
-    else:
-        p["mlp"], ax["mlp"] = init_mlp_params(k_mlp, cfg, out_std)
-    return p, ax
+    p, ax = _init_mixer_half(k_attn, cfg, out_std)
+    ffn_p, ffn_ax = _init_ffn_half(k_mlp, cfg, out_std, force_dense)
+    return {**p, **ffn_p}, {**ax, **ffn_ax}
 
 
 def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
@@ -72,8 +89,17 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                   zigzag: bool = False, segment_ids=None,
                   page_table=None, active=None, chunk_counts=None,
                   tp_sharded: bool = False, kv_scales=None,
-                  fp8=None, lora=None):
+                  fp8=None, lora=None, kv_plane=None, ssm_state=None,
+                  state_rows=None):
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
+
+    A layer whose params hold "ssm" in place of "attention" runs the
+    selective-state-space mixer (transformer/ssm.py) as its first half.
+    In a paged serving step such a layer has no kv_cache: ssm_state =
+    (ssm pool, conv pool, this layer's plane of them), state_rows maps x's
+    rows to slots, and new_cache is the two state pools. kv_plane: the
+    plane of the paged KV pools an attention layer owns where that is not
+    its layer id (a hybrid stack's pools hold its attention layers only).
 
     page_table/active: paged-KV decode (inference/paged_cache.py) —
     kv_cache is then the whole STACKED block pool [L, NB, bs, ...], of
@@ -107,44 +133,68 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     # op_names (HLO text, the profiler's own viewer). The events of a TPU
     # trace as jax.profiler.ProfileData gives them do not carry op_names,
     # so perfbench reads kernels by name instead (PERF.md, PR 28).
-    with jax.named_scope("attention"):
-        if cfg.multi_latent_attention:
-            if lora is not None:
-                raise ValueError(
-                    "lora serving targets the GQA projection kernels — MLA "
-                    "has no q_kernel/kv_kernel (lora.AdapterCache rejects "
-                    "MLA configs at construction)")
-            from megatronapp_tpu.transformer.mla import mla_forward
-            if segment_ids is not None:
-                # MLA routes through the reference attention impl — packed
-                # segments densify into the mask here.
-                seg_mask = (segment_ids[:, None, :, None]
-                            == segment_ids[:, None, None, :])
-                attention_mask = (seg_mask if attention_mask is None
-                                  else attention_mask & seg_mask)
-            if kv_cache is not None:
-                attn_out, new_cache = mla_forward(
-                    p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-                    layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
-                    cache_index=cache_index, cache_positions=cache_positions,
-                    page_table=page_table, active=active,
-                    chunk_counts=chunk_counts, kv_scales=kv_scales)
+    def attend():
+        mask = attention_mask
+        with jax.named_scope("attention"):
+            if cfg.multi_latent_attention:
+                if lora is not None:
+                    raise ValueError(
+                        "lora serving targets the GQA projection kernels "
+                        "— MLA has no q_kernel/kv_kernel (lora.AdapterCache "
+                        "rejects MLA configs at construction)")
+                from megatronapp_tpu.transformer.mla import mla_forward
+                if segment_ids is not None:
+                    # MLA routes through the reference attention impl —
+                    # packed segments densify into the mask here.
+                    seg_mask = (segment_ids[:, None, :, None]
+                                == segment_ids[:, None, None, :])
+                    mask = seg_mask if mask is None else mask & seg_mask
+                if kv_cache is not None:
+                    attn_out, new_cache = mla_forward(
+                        p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                        layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
+                        cache_index=cache_index,
+                        cache_positions=cache_positions,
+                        page_table=page_table, active=active,
+                        chunk_counts=chunk_counts, kv_scales=kv_scales)
+                else:
+                    attn_out = mla_forward(
+                        p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                        layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
+                    new_cache = None
             else:
-                attn_out = mla_forward(
-                    p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-                    layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
+                attn_out, new_cache = attention_forward(
+                    p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                    kv_cache=kv_cache, cache_index=cache_index,
+                    cache_positions=cache_positions, layer_id=layer_id,
+                    ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
+                    page_table=page_table, active=active,
+                    chunk_counts=chunk_counts, tp_sharded=tp_sharded,
+                    kv_scales=kv_scales,
+                    fp8=None if fp8 is None else fp8["attention"],
+                    lora=lora, kv_plane=kv_plane)
+        return attn_out, new_cache
+
+    if "ssm" in p:
+        if segment_ids is not None or tp_sharded or lora is not None:
+            raise NotImplementedError(
+                "a state-space layer runs whole sequences on one tp shard: "
+                "no packed segments (the state would cross them), "
+                "tp-sharded stage body or lora")
+        from megatronapp_tpu.transformer.ssm import (
+            ssm_dims, ssm_forward, ssm_paged_forward,
+        )
+        with jax.named_scope("ssm"):
+            if ssm_state is not None:
+                attn_out, new_cache = ssm_paged_forward(
+                    p["ssm"], h, cfg, ssm_state, rows=state_rows,
+                    starts=cache_positions, counts=chunk_counts,
+                    active=active)
+            else:
+                attn_out, _ = ssm_forward(p["ssm"], h, cfg, ssm_dims(cfg))
                 new_cache = None
-        else:
-            attn_out, new_cache = attention_forward(
-                p["attention"], h, cfg, rope_cos, rope_sin, attention_mask,
-                kv_cache=kv_cache, cache_index=cache_index,
-                cache_positions=cache_positions, layer_id=layer_id,
-                ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
-                page_table=page_table, active=active,
-                chunk_counts=chunk_counts, tp_sharded=tp_sharded,
-                kv_scales=kv_scales,
-                fp8=None if fp8 is None else fp8["attention"],
-                lora=lora)
+    else:
+        attn_out, new_cache = attend()
     # Tag for the 'selective_attn' remat policy (a no-op otherwise).
     attn_out = checkpoint_name(attn_out, "attn_out")
     x = residual + attn_out.astype(residual.dtype)
@@ -221,6 +271,103 @@ def _stack_layers(per_layer, extra_axis: str = "layers"):
     return stacked, ax
 
 
+def init_hybrid_block_params(rng, cfg: TransformerConfig):
+    """A hybrid stack (cfg.attn_layer_period): the state-space layers'
+    first halves stacked [num_ssm_layers, ...] under "mixers_ssm", the
+    attention layers' [num_attention_layers, ...] under "mixers_attn", and
+    every layer's feed-forward half [num_layers, ...] under "ffn", each in
+    layer order (hybrid_layer_loop walks them)."""
+    out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
+    keys = jax.vmap(jax.random.split)(
+        jax.random.split(rng, cfg.num_layers))        # [L, (mixer, ffn)]
+    attends = np.asarray([cfg.layer_is_attention(i)
+                          for i in range(cfg.num_layers)])
+
+    def stacked(keys, init):
+        """init(key) -> (params, axes) for every key, as ONE vmapped
+        program a kind: the same numbers as a Python loop of per-layer
+        initialisers and a stack, without a copy of the initialiser a layer
+        in the program (87 s of a 3B model's first compile, PERF.md, PR 32)."""
+        axes = []
+
+        def one(key):
+            p, ax = init(key)
+            axes.append(ax)
+            return p
+
+        params = jax.vmap(one)(keys)
+        return params, jax.tree.map(lambda a: ("layers",) + a, axes[0],
+                                    is_leaf=_is_axes)
+
+    kinds = {
+        "mixers_ssm": (keys[~attends, 0], functools.partial(
+            _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
+        "mixers_attn": (keys[attends, 0], functools.partial(
+            _init_mixer_half, cfg=cfg, out_std=out_std, ssm=False)),
+        "ffn": (keys[:, 1], functools.partial(
+            _init_ffn_half, cfg=cfg, out_std=out_std)),
+    }
+    done = {k: stacked(*v) for k, v in kinds.items() if len(v[0])}
+    return ({k: v[0] for k, v in done.items()},
+            {k: v[1] for k, v in done.items()})
+
+
+def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
+    """Walk a hybrid stack in layer order as SCANNED runs, not unrolled
+    and without carrying both kinds' weights through every layer: an outer
+    scan over whole periods of (scan `offset` state-space layers, the
+    period's one attention layer, scan the rest), then the same over a
+    last partial period.
+
+    run(carry, attends, k, layer_id) → carry runs one layer: `attends`
+    (static) says which kind, k is its index among the layers of its kind
+    (its row of "mixers_attn"/"mixers_ssm", and for an attention layer its
+    plane of the KV pools), layer_id its index in the model (its row of
+    "ffn"); both are int32 scalars, traced inside the scans."""
+    period, offset = cfg.attn_layer_period, cfg.attn_layer_offset
+
+    def ssm_run(carry, k0, lid0, count):
+        if count <= 0:
+            return carry
+        if count == 1:
+            return run(carry, False, k0, lid0)
+        return jax.lax.scan(
+            lambda c, j: (run(c, False, k0 + j, lid0 + j), None), carry,
+            jnp.arange(count, dtype=jnp.int32))[0]
+
+    def part_period(carry, p, count):
+        """The first `count` (static) layers of period p."""
+        lid0, k0 = p * period, p * (period - 1)
+        carry = ssm_run(carry, k0, lid0, min(count, offset))
+        if count > offset:
+            carry = run(carry, True, p, lid0 + offset)
+            carry = ssm_run(carry, k0 + offset, lid0 + offset + 1,
+                            count - offset - 1)
+        return carry
+
+    whole, rest = divmod(cfg.num_layers, period)
+    if whole == 1:
+        carry = part_period(carry, jnp.int32(0), period)
+    elif whole:
+        carry = jax.lax.scan(
+            lambda c, p: (part_period(c, p, period), None), carry,
+            jnp.arange(whole, dtype=jnp.int32))[0]
+    if rest:
+        carry = part_period(carry, jnp.int32(whole), rest)
+    return carry
+
+
+def hybrid_layer_params(stacked_p, attends: bool, k, layer_id):
+    """One layer's params out of a hybrid stack: row k of its kind's first
+    halves and row layer_id of the feed-forward halves."""
+    def row(stack, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            stack)
+    mixers = stacked_p["mixers_attn" if attends else "mixers_ssm"]
+    return {**row(mixers, k), **row(stacked_p["ffn"], layer_id)}
+
+
 def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
                       force_dense: bool = False):
     """Stacked layer params for lax.scan.
@@ -241,6 +388,8 @@ def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
             init_hetero_block_params,
         )
         return init_hetero_block_params(rng, cfg)
+    if cfg.attn_layer_period is not None:
+        return init_hybrid_block_params(rng, cfg)
     freq = cfg.moe_layer_freq if cfg.is_moe else 1
     if freq == 1 or force_dense:
         keys = jax.random.split(rng, n)
@@ -296,6 +445,24 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
         return hetero_block_forward(
             stacked_p, x, cfg, rope_cos, rope_sin, attention_mask,
             layer_offset=layer_offset, ctx=ctx)
+    if cfg.attn_layer_period is not None:
+        if fp8 is not None or tp_sharded or zigzag:
+            raise NotImplementedError(
+                "a hybrid state-space stack trains on the plain path: no "
+                "fp8, tp-sharded stage body or zigzag cp")
+
+        def one_layer(layer_p, h, lid):
+            (h2, _), _ = layer_forward(
+                layer_p, h, cfg, rope_cos, rope_sin, attention_mask,
+                layer_id=lid, ctx=ctx, segment_ids=segment_ids)
+            return h2
+
+        one_layer = _remat_wrap(one_layer, cfg.remat_policy)
+        x = hybrid_layer_loop(
+            cfg, x, lambda h, attends, k, lid: one_layer(
+                hybrid_layer_params(stacked_p, attends, k, lid), h,
+                lid + layer_offset))
+        return x, jnp.zeros((), jnp.float32)
     hetero = isinstance(stacked_p, dict) and "dense" in stacked_p
 
     def run_layer(layer_p, h, lid, fp8_l=None):

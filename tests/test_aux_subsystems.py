@@ -426,7 +426,7 @@ class TestChipRTTProbe:
         from megatronapp_tpu.utils.straggler import (
             detect_slow_chips, probe_chip_rtts,
         )
-        rtts = probe_chip_rtts(devices8[:4], size=64, repeats=2)
+        rtts = probe_chip_rtts(devices8[:4], size=64, repeats=20)
         assert len(rtts) == 4
         assert all(r["rtt_ms"] > 0 for r in rtts)
         # Homogeneous virtual devices: nothing should be flagged at 5x.

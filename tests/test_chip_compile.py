@@ -406,6 +406,61 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
                                          * fc1.dtype.itemsize)
 
 
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
+    """The two jits for a hybrid state-space stack at Jamba2-3B's published
+    widths and the chat cell's sizes (a small vocabulary; 6 layers of which
+    1 and 4 attend, so that both kinds of run are loops): 128 slots of
+    h [16, 5120] float32 a layer, one key/value head of 128. Mosaic takes
+    `ssm_update` and the one-head pools; the step aliases the page pools
+    and the state pools alike, copies nothing of the state pools' shape or
+    one plane's, and holds less in temporaries than one layer's states."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    batch, blocks, seq = 128, 16384, 2048
+    cfg = PRESETS["jamba2-3b"](num_layers=6, attn_layer_period=3,
+                               attn_layer_offset=1, vocab_size=1024,
+                               params_dtype=jnp.bfloat16)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
+                                 max_seq_len=seq, paged=True, num_blocks=8)
+    ssm, conv = eng.pool.state
+    assert ssm.shape == (4, 128, 16, 5120) and ssm.dtype == jnp.float32
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + tuple(_sds(p.shape, p.dtype, one_chip) for p in (ssm, conv))
+    assert pools[0].shape == (2, blocks, 16, 1, 128)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None).compile()
+        _assert_kernels_named(compiled, "paged_decode", "ssm_update")
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1)).compile()
+        _assert_kernels_named(compiled, "paged_mq")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    # (an in-place dynamic-update-slice has its operand's shape. The
+    # convolution's tails are not held to this here: at 4 layers their pool
+    # is 16 MB and XLA prefetches all of it; at 26 it does not: PERF.md)
+    assert not _pool_shaped(compiled,
+                            r"copy|transpose|(?<!update[_-])slice",
+                            [ssm.shape, ssm.shape[1:], (1,) + ssm.shape[1:]])
+    assert mem.temp_size_in_bytes < ssm.size // ssm.shape[0] * 4
+
+
 # ---------------------------------------------------------------------------
 # Kernel names (ISSUE 26): a device trace names an event by its HLO
 # instruction, and perfbench's readers tell kernels apart by family prefix

@@ -167,6 +167,8 @@ def _run_with(monkeypatch, capsys, server_ok):
         lambda impl, tiny: _phase(f"train-{impl}", losses=[10.98]))
     monkeypatch.setattr(chip_smoke, "phase_server",
                         lambda tiny: _phase("server", ok=server_ok))
+    monkeypatch.setattr(chip_smoke, "phase_hybrid",
+                        lambda tiny: _phase("hybrid"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -178,7 +180,33 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
     phases = [json.loads(ln[len("phase: "):]) for ln in lines
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server"]
+                                            "server", "hybrid"]
+
+
+def _hybrid_lines(device=TPU, **kw):
+    res = {"tokens": 155, "in_vocab": True, "ssm_update_calls": 2,
+           "state": {"layers": 6, "resets": 3}, "pool_bytes": 100,
+           "alias_bytes": 128, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            "attention: paged decode -> pallas paged kernel (compiled)",
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"ssm_update_calls": 6}, "not one a layer loop"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"tokens": 150}, "tokens came back"),
+    ({"state": {"layers": 6, "resets": 2}}, "state counters"),
+])
+def test_check_hybrid(kw, needle):
+    """The hybrid phase's facts: one ssm_update a scanned run of
+    state-space layers in the compiled decode step, the state pools
+    aliased in and out, every token back, a reset an admission."""
+    out = chip_smoke.check_hybrid(0, _hybrid_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_hybrid(1, _hybrid_lines())["ok"]
 
 
 def test_a_failing_phase_fails_the_run(monkeypatch, capsys):
@@ -249,7 +277,7 @@ def test_forced_to_the_cpu_it_refuses(tmp_path):
 def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server"]
+                                            "server", "hybrid"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -267,7 +295,10 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert rc != 0
     assert last == {"ok": False, "device": {"platform": "cpu",
                                             "kind": "cpu", "count": 1}}
-    assert sum("not a TPU" in ln for ln in lines) == 3
+    hybrid = phases[3]
+    assert hybrid["state"]["resets"] == 3 and hybrid["state"]["layers"] == 6
+    assert hybrid["alias_bytes"] >= hybrid["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 4
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):

@@ -45,9 +45,13 @@ def main():
     # --paged-kv-cache, --kv-block-size, --num-kv-blocks,
     # --scan-unroll, --no-prefix-caching.
     from megatronapp_tpu.config.arguments import (
-        add_serving_args, validate_serving_args,
+        add_hybrid_args, add_serving_args, hybrid_fields,
+        validate_serving_args,
     )
     add_serving_args(ap)
+    # --attn-layer-period / -offset, --ssm-inner-norms: a hybrid
+    # state-space stack on a preset, served by --engine dynamic.
+    add_hybrid_args(ap)
     args = ap.parse_args()
     from megatronapp_tpu.utils.platform import (
         device_line, enable_compile_cache,
@@ -74,8 +78,16 @@ def main():
     cfg = PRESETS[args.preset]()
     validate_serving_args(
         args, multi_latent_attention=cfg.multi_latent_attention)
+    import dataclasses
+    if hybrid_fields(args):
+        cfg = dataclasses.replace(cfg, **hybrid_fields(args))
+    if cfg.num_ssm_layers and not (args.engine == "dynamic"
+                                   and args.paged_kv_cache):
+        raise SystemExit(
+            "a model with state-space layers keeps its recurrent state in "
+            "the paged engine's slots: serve it with --engine dynamic "
+            "--paged-kv-cache")
     if args.scan_unroll != 1:
-        import dataclasses
         cfg = dataclasses.replace(cfg, scan_unroll=args.scan_unroll)
     mcfg = None
     if args.engine == "mamba":
