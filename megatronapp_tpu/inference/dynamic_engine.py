@@ -534,19 +534,39 @@ def _warp_logits(logits, temps, top_ks, top_ps):
     semantics: `_sample_batched` (plain decode) and the speculative
     rejection-sampling verifier (inference/speculative.py) both warp
     through here, so speculation preserves the target distribution wrt
-    the EXACT sampler plain decode uses."""
+    the EXACT sampler plain decode uses.
+
+    The vocabulary is ordered only when a row asks for top-k or top-p
+    (a `lax.cond` on the per-row parameters: a temperature alone filters
+    nothing), and then once: what top-k masks is the tail of the sorted
+    row, so the masked row in order is the sorted row masked."""
     v = logits.shape[-1]
     x = logits / jnp.maximum(temps[:, None], 1e-6)
-    sorted_desc = jnp.sort(x, axis=-1)[:, ::-1]
-    k_idx = jnp.clip(top_ks - 1, 0, v - 1)
-    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-    x = jnp.where((top_ks[:, None] > 0) & (x < kth), -1e30, x)
-    sorted2 = jnp.sort(x, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted2, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.sum(cum < top_ps[:, None], axis=-1)
-    cutoff = jnp.take_along_axis(sorted2, cutoff_idx[:, None], axis=-1)
-    return jnp.where((top_ps[:, None] > 0.0) & (x < cutoff), -1e30, x)
+
+    def ordered(x):
+        sorted_desc = jnp.sort(x, axis=-1)[:, ::-1]
+        k_idx = jnp.clip(top_ks - 1, 0, v - 1)
+        kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+        use_k = top_ks[:, None] > 0
+        x = jnp.where(use_k & (x < kth), -1e30, x)
+        sorted_desc = jnp.where(use_k & (sorted_desc < kth), -1e30,
+                                sorted_desc)
+        probs = jax.nn.softmax(sorted_desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.sum(cum < top_ps[:, None], axis=-1)
+        cutoff = jnp.take_along_axis(sorted_desc, cutoff_idx[:, None],
+                                     axis=-1)
+        return jnp.where((top_ps[:, None] > 0.0) & (x < cutoff), -1e30, x)
+
+    return jax.lax.cond(jnp.any(_asks_order(top_ks, top_ps)),
+                        ordered, lambda x: x, x)
+
+
+def _asks_order(top_ks, top_ps):
+    """Rows whose filter needs the vocabulary in order (numpy or jax:
+    the sampler's conditional and the host's counters read the same
+    expression)."""
+    return (top_ks > 0) | (top_ps > 0.0)
 
 
 def _sample_batched(logits, seeds, rids, steps, temps, top_ks, top_ps,
@@ -555,14 +575,24 @@ def _sample_batched(logits, seeds, rids, steps, temps, top_ks, top_ps,
     per-request device_get loop). Per-row params; rows mirror
     engine.sample_logits semantics exactly: temperature → top-k →
     top-p → categorical, greedy bypasses all. logits [B,V] → [B].
+    The step does what its rows ask for, inside the one executable: all
+    greedy, `argmax` alone; else keys, the warp and a categorical, with
+    the vocabulary ordered only for a sampling row's top-k or top-p.
     tail: a few int32 counters of the step (an MoE model's routing
     counts), appended to the tokens so that they reach the host in the
     same small array."""
-    keys = _request_keys(seeds, rids, steps)
-    x = _warp_logits(logits, temps, top_ks, top_ps)
-    sampled = jax.vmap(jax.random.categorical)(keys, x)
-    toks = jnp.where(greedys, jnp.argmax(logits, axis=-1),
-                     sampled).astype(jnp.int32)
+    greedy_toks = jnp.argmax(logits, axis=-1)
+
+    def sample():
+        keys = _request_keys(seeds, rids, steps)
+        # A greedy row's warp is thrown away: it asks for no ordering.
+        x = _warp_logits(logits, temps, jnp.where(greedys, 0, top_ks),
+                         jnp.where(greedys, 0.0, top_ps))
+        sampled = jax.vmap(jax.random.categorical)(keys, x)
+        return jnp.where(greedys, greedy_toks, sampled)
+
+    toks = jax.lax.cond(jnp.all(greedys), lambda: greedy_toks,
+                        sample).astype(jnp.int32)
     return toks if tail is None else jnp.concatenate([toks, tail])
 
 
@@ -802,6 +832,14 @@ class DynamicInferenceEngine:
         # touched; each round could touch moe_layers x num_moe_experts.
         self.moe_stats = {"decode_rounds": 0, "assignments": 0,
                           "expert_pairs_touched": 0}
+        # Always-on counters of what the sampler was asked for
+        # (stats_snapshot()["sampler"]), read off the rows it is handed:
+        # plain decode rounds and prefills' first samples that took
+        # argmax alone (greedy), drew a categorical (sampled), or ordered
+        # the vocabulary for a top-k or top-p first (ordered).
+        self.sampler_stats = {
+            f"{site}_{kind}": 0 for site in ("rounds", "prefills")
+            for kind in ("greedy", "sampled", "ordered")}
         # Always-on counters of the paged kernels' walk over plain decode
         # rounds (stats_snapshot()["paged"]): the blocks the running slots
         # hold against running slots x max_blocks_per_seq.
@@ -1738,6 +1776,7 @@ class DynamicInferenceEngine:
         batched decode sampler, so a request's sample stream is
         reproducible and independent of batch composition."""
         s = req.sampling
+        self._count_sample("prefills", s.greedy, s.top_k, s.top_p)
         tok = self._sample_b(
             logits,
             jnp.asarray([s.seed], jnp.int32),
@@ -1749,12 +1788,25 @@ class DynamicInferenceEngine:
             jnp.asarray([s.greedy], bool))
         return jax.device_get(tok)
 
+    def _count_sample(self, site: str, greedys, top_ks, top_ps):
+        """One call of the sampler into sampler_stats, by the predicates
+        `_sample_batched` reduces from the same rows (or one row's
+        scalars)."""
+        sampling = np.logical_not(greedys)
+        if not sampling.any():
+            kind = "greedy"
+        elif (sampling & _asks_order(top_ks, top_ps)).any():
+            kind = "ordered"
+        else:
+            kind = "sampled"
+        self.sampler_stats[f"{site}_{kind}"] += 1
+
     def _sampling_rows(self) -> Dict[str, np.ndarray]:
         """Per-slot sampling parameters + key-chain inputs for every
-        non-finished slot (inactive rows keep neutral defaults; their
-        outputs are ignored). Single source for the plain sampler, the
-        speculative verifier, and the draft proposer — one place to
-        thread a future sampling field through."""
+        non-finished slot (inactive rows are greedy, which asks the
+        sampler for nothing; their outputs are ignored). Single source
+        for the plain sampler, the speculative verifier, and the draft
+        proposer — one place to thread a future sampling field through."""
         b = self.max_batch
         rows = {"seeds": np.zeros(b, np.int32),
                 "rids": np.zeros(b, np.int32),
@@ -1762,7 +1814,7 @@ class DynamicInferenceEngine:
                 "temps": np.ones(b, np.float32),
                 "top_ks": np.zeros(b, np.int32),
                 "top_ps": np.zeros(b, np.float32),
-                "greedys": np.zeros(b, bool)}
+                "greedys": np.ones(b, bool)}
         for i, r in enumerate(self.slots):
             if r is None or r.finished:
                 continue
@@ -1778,6 +1830,7 @@ class DynamicInferenceEngine:
         round-trip per decode step instead of one per request; `tail`
         (_sample_batched) rides behind the tokens."""
         r = self._sampling_rows()
+        self._count_sample("rounds", r["greedys"], r["top_ks"], r["top_ps"])
         toks = self._sample_b(
             logits, jnp.asarray(r["seeds"]), jnp.asarray(r["rids"]),
             jnp.asarray(r["steps"]), jnp.asarray(r["temps"]),
@@ -2192,7 +2245,10 @@ class DynamicInferenceEngine:
         `bytes_per_slot`, `resets` (sequences started from zeros at
         admission), `dropped` (states thrown away by preemption),
         `prefill_scans` (chunk scans run: prefill calls x state-space
-        layers).
+        layers). "sampler" counts what the sampler was asked for, by
+        plain decode rounds and by prefills' first samples: `*_greedy`
+        (argmax alone), `*_sampled` (a categorical, the vocabulary not
+        ordered), `*_ordered` (a sort ran for some row's top-k or top-p).
 
         include_dispatch=True adds the traced decode step's launch
         counts (dispatch_stats; the first call traces the step once and
@@ -2206,6 +2262,7 @@ class DynamicInferenceEngine:
             "multiquery_traces": self.mq_traces,
             "decode_traces": self.decode_traces,
             "steps": self.step_stats.snapshot(),
+            "sampler": dict(self.sampler_stats),
             "state": False,
         }
         if self.has_state:

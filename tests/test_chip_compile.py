@@ -534,6 +534,25 @@ def _compile_family(family, one_chip):
         bf16(b, 32, h, d), pages, pages, table, lens, lens).compile()
 
 
+def test_sampler_sorts_only_inside_its_ordered_branch(one_chip, chip_compile):
+    """The compiled `_sample_batched` at the dense cell's shape: XLA keeps
+    the one `sort` inside the conditional (a greedy round runs none), and
+    what the top level does to the `[rows, vocabulary]` logits besides the
+    `argmax` is an asynchronous prefetch, not a sort's relayout."""
+    from megatronapp_tpu.inference.dynamic_engine import _sample_batched
+    b, v = 24, 50304
+    row = lambda dt: _sds((b,), dt, one_chip)  # noqa: E731
+    text = jax.jit(_sample_batched).lower(
+        _sds((b, v), jnp.float32, one_chip), row(jnp.int32), row(jnp.int32),
+        row(jnp.int32), row(jnp.float32), row(jnp.int32), row(jnp.float32),
+        row(jnp.bool_)).compile().as_text()
+    assert len(re.findall(r" sort\(", text)) == 1
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert " sort(" not in entry and " conditional(" in entry
+    assert not re.search(rf"f32\[{b},{v}\]\S* copy\(", entry)
+
+
 @pytest.mark.parametrize("family,prefixes", [
     ("flash_fwd", ["flash_fwd"]),
     ("flash_fwd_d128", ["flash_fwd"]),
