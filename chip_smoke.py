@@ -2,6 +2,7 @@
 
     python chip_smoke.py             # one chip: trainer twice, the server,
                                      # then a tiny hybrid state-space engine
+                                     # and a tiny EVA-attention engine
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -17,6 +18,11 @@ vocab 50304), random weights from the entry points' own seeds:
 - hybrid: a tiny model with state-space layers through the paged engine (no
   preset of that kind is small): the compiled decode step holds one
   ``ssm_update`` a scanned run of such layers and aliases the state pools.
+
+- eva: a tiny model with EVA attention (a window of 256 exact rows, one
+  pooled row for every 16 older ones) through the paged engine: requests
+  that close windows, one ``eva_summary`` a layer loop in the compiled decode
+  step, a slot's blocks bounded by its rows and not its length.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -594,6 +600,140 @@ def check_hybrid(rc, lines, tiny=False):
 
 
 # ---------------------------------------------------------------------------
+# Phase: EVA attention through the paged engine, tiny widths
+# ---------------------------------------------------------------------------
+
+EVA = dict(eva_window_size=256, eva_chunk_size=16)
+EVA_WINDOW_BLOCKS = 16      # blocks of 16 rows a window of 256 fills
+
+
+def _eva_requests(tiny):
+    """(prompt, new tokens) of the phase's three requests: windows close in
+    prefill (the first and third) and, at the real size, in decode (the
+    second); the interpreter's rounds are too slow for that one."""
+    return ((270, 6), (20, 6), (520, 6)) if tiny else (
+        (300, 40), (9, 270), (600, 30))
+
+
+def phase_eva(tiny):
+    rc, tr = _run_child("eva", ["--child", "eva"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_eva(rc, tr.lines, tiny)
+
+
+def child_eva(tiny):
+    """In the child: a model with EVA attention (4 layers, 2 heads of 128,
+    an 8-column byte head) serves three requests that close one, one and
+    two windows through DynamicInferenceEngine(paged=True) on the device,
+    and the compiled decode step says what it holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    cfg = TransformerConfig(
+        num_layers=4, hidden_size=256, num_attention_heads=2,
+        ffn_hidden_size=512, vocab_size=320, true_vocab_size=320,
+        max_position_embeddings=1024, rotary_base=1e5,
+        normalization=NormKind.rmsnorm, norm_unit_offset=True,
+        activation=ActivationKind.swiglu, add_bias_linear=False,
+        untie_embeddings_and_output_weights=True, num_pred_heads=8,
+        params_dtype=jnp.bfloat16, **EVA)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=4, max_seq_len=1024,
+                                 paged=True)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n, new in _eva_requests(tiny):
+        eng.add_request(rng.integers(0, 320, n).astype(np.int32), new,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), bool), None).compile()
+    text = compiled.as_text()
+    pools = eng._pools()
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 320 for v in out.values()
+                             for t in v)),
+        "eva": eng.stats_snapshot()["eva"],
+        "table_blocks": mb,
+        "blocks_in_use_after": eng.pool.blocks_in_use(),
+        "eva_summary_calls": sum(
+            1 for ln in text.splitlines()
+            if "custom-call(" in ln and " %eva_summary" in ln.split("=")[0]),
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_eva(rc, lines, tiny=False):
+    out = {"phase": "eva", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    interpreted = tiny and dev and dev[0]["platform"] != "tpu"
+    # The layers are one scanned stack: one summariser in the loop's body.
+    # (The interpreter inlines a kernel into plain HLO: nothing to count.)
+    if not interpreted and out["eva_summary_calls"] != 1:
+        out["problems"].append(
+            f"{out['eva_summary_calls']} eva_summary custom calls in the "
+            "compiled decode step, not one a layer loop (1)")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    requests = _eva_requests(tiny)
+    want = sum(n + new for n, new in requests)
+    if out["tokens"] != want or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back, not "
+                               f"{want}, or one outside the vocabulary")
+    eva = out["eva"] or {}
+    # a request caches all its tokens but the last: 339, 278 and 629 rows
+    # at the real size close 1 + 1 + 2 windows
+    closed = sum((n + new - 2) // EVA["eva_window_size"]
+                 for n, new in requests)
+    if (eva.get("windows_closed"), eva.get("blocks_freed")) != (
+            closed, closed * EVA_WINDOW_BLOCKS):
+        out["problems"].append(f"eva counters {eva}, not {closed} windows "
+                               "closed and 16 blocks freed by each")
+    # 16 blocks of window + one of summaries a window of the 1024 positions
+    if not 0 < eva.get("max_blocks_slot", 0) <= out["table_blocks"] == 20:
+        out["problems"].append(
+            f"a slot held {eva.get('max_blocks_slot')} blocks of a table "
+            f"of {out['table_blocks']} (20: 16 + 1 a window)")
+    if out["blocks_in_use_after"]:
+        out["problems"].append(f"{out['blocks_in_use_after']} blocks "
+                               "still held after the last request")
+    if not any("paged decode" in ln and _kernel_mode(dev, tiny) in ln
+               for ln in lines):
+        out["problems"].append("the engine did not say it ran the paged "
+                               f"decode kernel {_kernel_mode(dev, tiny)}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Phase: four chips (only with --chips 4)
 # ---------------------------------------------------------------------------
 
@@ -786,7 +926,8 @@ def run(chips, tiny):
         plan = [lambda: phase_train("auto", tiny),
                 lambda: phase_train("pallas", tiny),
                 lambda: phase_server(tiny),
-                lambda: phase_hybrid(tiny)]
+                lambda: phase_hybrid(tiny),
+                lambda: phase_eva(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -809,7 +950,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
-    ap.add_argument("--child", choices=["train", "multichip", "hybrid"],
+    ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -821,6 +962,9 @@ def main(argv=None):
         return 0
     if args.child == "hybrid":
         child_hybrid(args.tiny)
+        return 0
+    if args.child == "eva":
+        child_eva(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

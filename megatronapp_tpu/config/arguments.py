@@ -53,6 +53,33 @@ def hybrid_fields(args) -> dict:
             if getattr(args, f, None) is not None}
 
 
+_EVA_FIELDS = ("eva_window_size", "eva_chunk_size")
+
+
+def add_eva_args(ap: argparse.ArgumentParser):
+    """EVA attention (TransformerConfig.eva_window_size; transformer/eva.py),
+    shared by the main parser and tools/run_text_generation_server.py like
+    add_hybrid_args. Every default is None = the model's own (a preset's,
+    or TransformerConfig's: 0 = plain attention). What else HF `evabyte`
+    adds to a decoder (norm_unit_offset, num_pred_heads) has no flag: the
+    preset carries it."""
+    g = ap.add_argument_group("EVA attention")
+    g.add_argument("--eva-window-size", type=int, default=None,
+                   help="a query sees the rows of its own aligned window "
+                        "of this many positions exactly (HF window_size); "
+                        "0 = plain attention")
+    g.add_argument("--eva-chunk-size", type=int, default=None,
+                   help="and one pooled key/value row for every this many "
+                        "positions of each earlier window (HF chunk_size); "
+                        "the paged cache wants it equal to --kv-block-size")
+
+
+def eva_fields(args) -> dict:
+    """The TransformerConfig fields the add_eva_args flags set."""
+    return {f: getattr(args, f) for f in _EVA_FIELDS
+            if getattr(args, f, None) is not None}
+
+
 def add_serving_args(ap: argparse.ArgumentParser):
     """Serving / paged-KV flags (ISSUE 3) — single source of truth shared
     by the main parser (so config-YAML runs and --use-checkpoint-args
@@ -806,6 +833,7 @@ def build_parser(title: str = "megatronapp-tpu") -> argparse.ArgumentParser:
 
     add_serving_args(ap)   # paged KV serving flags (ISSUE 3)
     add_hybrid_args(ap)
+    add_eva_args(ap)
 
     g = ap.add_argument_group("megascan")  # reference arguments.py:2705ff
     g.add_argument("--trace", action="store_true")
@@ -1060,6 +1088,7 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             if val != getattr(sentinel, flag):
                 overrides[field] = val
         overrides.update(hybrid_fields(args))
+        overrides.update(eva_fields(args))
         if overrides:
             model = _dc.replace(model, **overrides)
     else:
@@ -1128,6 +1157,7 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             compute_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,
             heterogeneous_layers_config_json=_hetero_json(args),
             **hybrid_fields(args),
+            **eva_fields(args),
         )
 
     if getattr(args, "fp8", False):

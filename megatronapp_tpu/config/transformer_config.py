@@ -134,6 +134,23 @@ class TransformerConfig:
     ssm_dt_rank: Optional[int] = None
     ssm_inner_norms: bool = False
 
+    # EVA attention (HF `evabyte`: attention_class "eva", window_size,
+    # chunk_size; Zheng et al. 2023, "Efficient Attention via Control
+    # Variates", transformer/eva.py): a query sees the rows of its own
+    # aligned window of eva_window_size positions exactly and ONE pooled
+    # key/value row for every eva_chunk_size positions of each earlier
+    # window, under one softmax. 0 = plain attention. Each attention layer
+    # then holds two more leaves a key/value head, eva_phi and eva_mu.
+    eva_window_size: int = 0
+    eva_chunk_size: int = 0
+    # RMSNorm / LayerNorm whose scale is 1 + g (HF norm_add_unit_offset):
+    # the layers' two norms and the final one; g starts at 0.
+    norm_unit_offset: bool = False
+    # The head has num_pred_heads x vocab_size columns (HF num_pred_heads:
+    # head j predicts token t+1+j); generation samples the first
+    # vocab_size of them. Needs an untied head.
+    num_pred_heads: int = 1
+
     # Multi-token prediction (DeepSeek-V3; reference
     # multi_token_prediction.py + transformer_config mtp_num_layers /
     # mtp_loss_scaling_factor).
@@ -306,6 +323,33 @@ class TransformerConfig:
                     "a hybrid state-space stack (attn_layer_period) runs "
                     "dense feed-forwards and plain attention layers: no "
                     "MoE, MLA or heterogeneous block configs")
+        if self.eva_window_size or self.eva_chunk_size:
+            w, c = self.eva_window_size, self.eva_chunk_size
+            if w <= 0 or c <= 0 or w % c:
+                raise ValueError(
+                    f"EVA attention needs eva_window_size ({w}) a positive "
+                    f"multiple of eva_chunk_size ({c})")
+            if (self.multi_latent_attention or self.mtp_num_layers
+                    or self.attn_layer_period is not None
+                    or self.heterogeneous_layers_config_json
+                    or self.attn_mask_type != AttnMaskType.causal):
+                raise ValueError(
+                    "EVA attention (eva_window_size) runs plain causal "
+                    "attention layers in one uniform stack: no MLA, MTP, "
+                    "hybrid state-space stack or heterogeneous block "
+                    "configs")
+        if self.norm_unit_offset and (
+                self.mtp_num_layers or self.heterogeneous_layers_config_json):
+            raise ValueError(
+                "norm_unit_offset covers the layers' two norms and the "
+                "final norm: no MTP depth modules or heterogeneous block "
+                "configs, which norm on their own")
+        if self.num_pred_heads < 1 or (
+                self.num_pred_heads > 1
+                and not self.untie_embeddings_and_output_weights):
+            raise ValueError(
+                f"num_pred_heads={self.num_pred_heads} needs an untied "
+                "head (untie_embeddings_and_output_weights)")
         from megatronapp_tpu.ops.context_parallel import CP_COMM_TYPES
         if self.cp_comm_type not in CP_COMM_TYPES:
             raise ValueError(
@@ -316,6 +360,10 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_moe_experts is not None
+
+    @property
+    def is_eva(self) -> bool:
+        return self.eva_window_size > 0
 
     @property
     def head_dim(self) -> int:

@@ -74,6 +74,7 @@ from megatronapp_tpu.trace.request_trace import (
 from megatronapp_tpu.transformer.block import (
     hybrid_layer_loop, hybrid_layer_params, layer_forward,
 )
+from megatronapp_tpu.transformer.eva import table_rows
 from megatronapp_tpu.transformer.moe import StackedLayer
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
@@ -166,7 +167,7 @@ def validate_admission(prompt_tokens, max_new_tokens: int,
             f"prompt({len(prompt)}) + max_new({max_new_tokens}) exceeds "
             f"max_seq_len({max_seq_len})")
     if pool is not None:
-        need = cdiv(len(prompt) + max_new_tokens, pool.block_size)
+        need = pool.blocks_for(len(prompt) + max_new_tokens)
         if need > pool.num_blocks:
             raise ValueError(
                 f"request needs {need} blocks "
@@ -668,6 +669,48 @@ class DynamicInferenceEngine:
             # A prefix hit skips tokens whose state nobody kept.
             enable_prefix_caching = False
         self.state_stats = {"resets": 0, "dropped": 0, "prefill_scans": 0}
+        # A model with EVA attention (cfg.eva_window_size) caches, behind
+        # each closed window, one pooled row a chunk in a second region of
+        # the slot's table (paged_cache.py). Whatever would share, move or
+        # rewind a slot's rows would have to do the same to its chunk
+        # summaries, which nothing does yet: each refuses here or at its
+        # call, in words.
+        self.eva = cfg.is_eva
+        if self.eva:
+            refused = [name for name, on in (
+                ("paged=False (the dense cache)", not paged),
+                ("spec_method (speculative decoding rewinds rejected "
+                 "tokens)", spec_method and spec_method != "none"),
+                ("spill_host_mb (parking a session)", spill_host_mb),
+                ("adapter_cache (lora)", adapter_cache is not None),
+                ("an injected pool (disaggregated prefill)",
+                 pool is not None),
+                ("ctx (a serving mesh)", ctx is not None),
+                (f"kv_cache_dtype {kv_cache_dtype!r} (a quantized pool)",
+                 kv_cache_dtype != "bf16")) if on]
+            if refused:
+                raise ValueError(
+                    "this model's attention is EVA, whose chunk summaries "
+                    "live in a second region of each slot's page table on "
+                    "one device and have no snapshots yet (ROADMAP M4): "
+                    "cannot serve it with " + "; ".join(refused))
+            w, c = cfg.eva_window_size, cfg.eva_chunk_size
+            if w % self.prefill_chunk or (self.prefill_chunk % c
+                                          and c % self.prefill_chunk):
+                raise ValueError(
+                    f"prefill_chunk ({self.prefill_chunk}) must divide the "
+                    f"EVA window ({w}) and hold whole chunk summaries "
+                    f"({c}) or lie inside one: a prefill call never "
+                    "straddles a window's edge")
+            # (Prefix reuse: the pool turns it off on such a model.)
+        # Always-on counters of an EVA model's plain decode rounds
+        # (stats_snapshot()["eva"]): the rows the paged kernel walked
+        # (R(T) a slot, summed) against what full attention would have
+        # (T + 1), and the chunk summaries written, a chunk counted once
+        # whatever the layers, by decode rounds and prefill calls alike.
+        self.eva_stats = {"decode_rounds": 0, "rows_walked": 0,
+                          "rows_full_attention": 0,
+                          "summary_rows_written": 0}
 
         self.paged = paged
         if paged:
@@ -885,6 +928,14 @@ class DynamicInferenceEngine:
                      f" x {self.pool.state_bytes_per_slot} B a slot, prefix "
                      "reuse off (a prefix hit would skip tokens whose "
                      "state nobody kept)")
+        if self.eva:
+            line += (f", eva=window {self.cfg.eva_window_size} exact rows + "
+                     f"one summary row every {self.cfg.eva_chunk_size} "
+                     f"older ones, at most {self.pool.max_blocks_per_seq} "
+                     "blocks a slot; refused on chunk summaries: prefix "
+                     "reuse (off), spec_method, spill/park, export/import/"
+                     "adopt, lora, an injected pool or mesh, a quantized "
+                     "pool")
         return line
 
     def _build_jits(self):
@@ -1199,6 +1250,12 @@ class DynamicInferenceEngine:
             self._rt.finish(req.request_id, "abort")
 
     def _refuse_on_state(self, what: str):
+        if self.eva:
+            raise ValueError(
+                f"{what}: this model's cache keeps EVA chunk summaries in a "
+                "second region of each slot's page table, and moving a "
+                "request needs snapshots of them, which do not exist yet "
+                "(ROADMAP M4)")
         if self.has_state:
             raise ValueError(
                 f"{what}: this model's state-space layers keep a recurrent "
@@ -1739,8 +1796,26 @@ class DynamicInferenceEngine:
             self.state_stats["resets"] += 1
         pos, count = cached, 0
         logits = hid = None
+        call_attrs = {}
         while pos < p_len:
             count = min(c, p_len - pos)
+            if self.eva:
+                # A call stays inside one window (prefill_chunk divides
+                # it); its blocks, and the closing of the window before,
+                # are the pool's (admission saw to the room).
+                w = self.cfg.eva_window_size
+                count = min(count, w - pos % w)
+                if cached or not pool.ensure_rows(slot, pos, count):
+                    raise RuntimeError(
+                        f"EVA prefill of slot {slot} at position {pos}: "
+                        f"{cached} cached tokens, or the pool ran out of "
+                        "the blocks its admission had counted")
+                table_row = _handed_over(pool.page_table[slot][None])
+                call_attrs = {"summaries": (
+                    (pos + count) // self.cfg.eva_chunk_size
+                    - pos // self.cfg.eva_chunk_size)}
+                self.eva_stats["summary_rows_written"] += (
+                    call_attrs["summaries"])
             chunk = np.zeros((1, c), np.int32)
             chunk[0, :count] = tokens[pos:pos + count]
             if pool.quantized:
@@ -1751,7 +1826,8 @@ class DynamicInferenceEngine:
                 # fault costs one step and audit() stays clean (the
                 # tests/test_resilience.py drill).
                 chaos.fire("kv-quant-write")
-            with self._span("engine.prefill_call", tokens=count):
+            with self._span("engine.prefill_call", tokens=count,
+                            **call_attrs):
                 logits, hid, new = self._mq_step(
                     self.params, jnp.asarray(chunk), self._pools(),
                     self.pool.scales,
@@ -1986,7 +2062,24 @@ class DynamicInferenceEngine:
             # the blocks this round's paged kernel walks (it reads the
             # row the round appends too), of those the table could name
             bs = self.pool.block_size
-            attrs["kv_blocks"] = int((lens // bs + 1).sum())
+            rows = lens
+            if self.eva:
+                # kv_rows: what the kernel walks, R(T) a slot, the rows of
+                # its closed windows' summaries (summary_rows) among them.
+                cfg, st = self.cfg, self.eva_stats
+                rows = table_rows(cfg, lens)
+                attrs["kv_rows"] = int((rows + 1).sum())
+                attrs["summary_rows"] = int(
+                    (lens // cfg.eva_window_size).sum()
+                    * (cfg.eva_window_size // cfg.eva_chunk_size))
+                st["decode_rounds"] += 1
+                st["rows_walked"] += attrs["kv_rows"]
+                st["rows_full_attention"] += int((lens + 1).sum())
+                # summaries: the chunks this round fills, and so pools
+                attrs["summaries"] = int(
+                    ((lens + 1) % cfg.eva_chunk_size == 0).sum())
+                st["summary_rows_written"] += attrs["summaries"]
+            attrs["kv_blocks"] = int((rows // bs + 1).sum())
             self.paged_stats["decode_rounds"] += 1
             self.paged_stats["blocks_live"] += attrs["kv_blocks"]
             self.paged_stats["blocks_table"] += (
@@ -2249,6 +2342,13 @@ class DynamicInferenceEngine:
         plain decode rounds and by prefills' first samples: `*_greedy`
         (argmax alone), `*_sampled` (a categorical, the vocabulary not
         ordered), `*_ordered` (a sort ran for some row's top-k or top-p).
+        "eva" is a dict on a model with EVA attention (False otherwise):
+        `layers`, `window`, `chunk`; `windows_closed` and the `blocks_freed`
+        by them; `summary_rows_written` (chunks pooled, a chunk counted
+        once whatever the layers); over plain decode rounds `rows_walked`
+        (R(T) a slot: what the paged kernel read) against
+        `rows_full_attention` (T + 1); `max_blocks_slot`, the most blocks
+        one slot has held.
 
         include_dispatch=True adds the traced decode step's launch
         counts (dispatch_stats; the first call traces the step once and
@@ -2264,7 +2364,14 @@ class DynamicInferenceEngine:
             "steps": self.step_stats.snapshot(),
             "sampler": dict(self.sampler_stats),
             "state": False,
+            "eva": False,
         }
+        if self.eva:
+            out["eva"] = dict(
+                self.eva_stats, **self.pool.eva_stats,
+                layers=self.cfg.num_layers,
+                window=self.cfg.eva_window_size,
+                chunk=self.cfg.eva_chunk_size)
         if self.has_state:
             out["state"] = dict(
                 self.state_stats, layers=self.cfg.num_ssm_layers,

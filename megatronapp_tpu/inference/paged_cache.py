@@ -221,6 +221,38 @@ class PagedKVCache:
         self.max_seq_len = max_seq_len
         self.block_size = block_size
         self.max_blocks_per_seq = cdiv(max_seq_len, block_size)
+        # EVA attention (transformer/eva.py): a slot's table row is TWO
+        # regions the kernels walk as one, the summary rows of its closed
+        # windows (one a chunk, `_eva_spb` blocks a window) and behind them
+        # the open window's exact rows (at most `_eva_wb` blocks); its last
+        # `_eva_spb` columns, which no kernel walks, name the blocks the
+        # open window's summaries are written to as its chunks fill. When a
+        # window closes those blocks join the first region and the window's
+        # exact blocks go back to the free list (`_eva_close`), so a slot
+        # holds cdiv(R(T)) blocks and a few, never cdiv(T). `_eva_counts`
+        # [slot] = blocks of (summaries, open window, pending summaries);
+        # `_slot_blocks` lists them in that order.
+        self.eva = cfg.is_eva
+        if self.eva:
+            from megatronapp_tpu.transformer.eva import (
+                summary_blocks_per_window,
+            )
+            self._eva_spb = summary_blocks_per_window(cfg, block_size)
+            self._eva_wb = cfg.eva_window_size // block_size
+            refused = [what for what, on in (
+                ("a quantized pool (kv_cache_dtype "
+                 f"{kv_cache_dtype!r})", dtype_spec.quantized),
+                ("staging slots (disaggregated prefill)", extra_slots),
+                ) if on]
+            if refused:
+                raise ValueError(
+                    "EVA chunk summaries are pooled from the cached rows of "
+                    "one slot's own table: cannot keep them with "
+                    + "; ".join(refused))
+            # A prefix hit would skip tokens whose summaries nobody kept.
+            enable_prefix_caching = False
+            self.max_blocks_per_seq = self._eva_wb + self._eva_spb * cdiv(
+                max_seq_len, cfg.eva_window_size)
         # Default pool = dense capacity (max_batch full sequences); size
         # it down for the actual workload to realize the memory win.
         self.num_blocks = (num_blocks if num_blocks is not None
@@ -294,6 +326,9 @@ class PagedKVCache:
         self._lru: OrderedDict = OrderedDict()  # rc==0 hashed blocks
         self._slot_blocks: List[List[int]] = [
             [] for _ in range(self.num_slots)]
+        self._eva_counts = np.zeros((self.num_slots, 3), np.int64)
+        self.eva_stats = {"windows_closed": 0, "blocks_freed": 0,
+                          "max_blocks_slot": 0}
         self.stats = {"prefix_hit_tokens": 0, "prefill_tokens": 0,
                       "cow_copies": 0, "evictions": 0, "preemptions": 0,
                       "peak_blocks_in_use": 0, "handoff_transfers": 0,
@@ -481,6 +516,17 @@ class PagedKVCache:
         when the pool cannot supply the fresh blocks."""
         assert not self._slot_blocks[slot], f"slot {slot} still holds blocks"
         p_len = len(tokens)
+        if self.eva:
+            # The prefill takes its blocks a call at a time (ensure_rows)
+            # and gives windows back as they close; nothing else allocates
+            # meanwhile, so room for its largest holding now is room
+            # throughout.
+            if self.blocks_for(p_len + 1) > self.available_blocks():
+                return None
+            self.page_table[slot, :] = 0
+            self.stats["prefill_tokens"] += p_len
+            telemetry.inc("paged_prefill_tokens", p_len)
+            return AdmitPlan([], 0, False)
         need_total = cdiv(p_len, self.block_size)
 
         hits: List[int] = []
@@ -546,6 +592,8 @@ class PagedKVCache:
     def ensure_capacity(self, slot: int, position: int) -> bool:
         """Make sure `slot` owns the block covering `position` (decode
         appends grow one block at a time)."""
+        if self.eva:
+            return self.ensure_rows(slot, position, 1)
         idx = position // self.block_size
         owned = self._slot_blocks[slot]
         if idx < len(owned):
@@ -561,6 +609,88 @@ class PagedKVCache:
         self.page_table[slot, idx] = blk
         self._note_usage()
         return True
+
+    # ---- EVA: two regions of one table ------------------------------------
+    def _eva_hold(self, position: int) -> int:
+        """Blocks a slot holds once `position` has its capacity."""
+        w, bs = self.cfg.eva_window_size, self.block_size
+        at = position % w
+        return (self._eva_spb * (position // w) + at // bs + 1
+                + at // (bs * bs) + 1)
+
+    def blocks_for(self, length: int) -> int:
+        """The most blocks a sequence holds at once while it grows to
+        `length` cached rows. EVA: the last window that fills on the way,
+        or the end."""
+        if not self.eva:
+            return cdiv(length, self.block_size)
+        last = max(length, 1) - 1
+        w = self.cfg.eva_window_size
+        return max(self._eva_hold(last),
+                   self._eva_hold(last // w * w - 1) if last >= w else 0)
+
+    def _eva_close(self, slot: int):
+        """The open window is full and the next position opens another: its
+        summaries, written as its chunks filled, join the table's first
+        region, and its exact rows' blocks go back to the free list."""
+        n_sum, n_win, n_pend = (int(n) for n in self._eva_counts[slot])
+        assert n_win == self._eva_wb and n_pend == self._eva_spb, (
+            f"slot {slot} closes a window it has not filled: "
+            f"{n_win} window blocks, {n_pend} summary blocks")
+        owned = self._slot_blocks[slot]
+        for blk in owned[n_sum:n_sum + n_win]:
+            self._release_block(blk)
+        owned[:] = owned[:n_sum] + owned[n_sum + n_win:]
+        self._eva_counts[slot] = (n_sum + n_pend, 0, 0)
+        self.page_table[slot, :] = 0
+        self.page_table[slot, :len(owned)] = owned
+        self.eva_stats["windows_closed"] += 1
+        self.eva_stats["blocks_freed"] += n_win
+
+    def ensure_rows(self, slot: int, position: int, count: int) -> bool:
+        """EVA: make sure `slot` owns the blocks for positions
+        [position, position + count), which lie in one window: the exact
+        rows' blocks in the open window's region and the blocks of the
+        summaries their chunks will be pooled into. A position that opens a
+        window closes the one before it first. False when the pool runs
+        out (what was taken stays owned)."""
+        w, bs = self.cfg.eva_window_size, self.block_size
+        last = position + count - 1
+        assert position // w == last // w, (
+            f"positions {position}..{last} cross a window's edge")
+        owned = self._slot_blocks[slot]
+        counts = self._eva_counts[slot]
+        if counts[0] < self._eva_spb * (position // w):
+            self._eva_close(slot)
+        assert counts[0] == self._eva_spb * (position // w), (
+            f"slot {slot} skipped a window: position {position}, "
+            f"{int(counts[0])} summary blocks")
+        need = ((1, last % w // bs + 1),            # the open window
+                (2, last % w // (bs * bs) + 1))     # its summaries
+        for region, blocks in need:
+            while counts[region] < blocks:
+                blk = self._take_free()
+                if blk is None:
+                    return False
+                self._refcount[blk] = 1
+                at = int(counts[:region + 1].sum())
+                owned.insert(at, blk)
+                col = (at if region == 1 else
+                       self.max_blocks_per_seq - self._eva_spb
+                       + int(counts[2]))
+                self.page_table[slot, col] = blk
+                counts[region] += 1
+        self.eva_stats["max_blocks_slot"] = max(
+            self.eva_stats["max_blocks_slot"], len(owned))
+        self._note_usage()
+        return True
+
+    def _refuse_on_eva(self, what: str):
+        if self.eva:
+            raise ValueError(
+                f"{what}: this model's cache keeps EVA chunk summaries in a "
+                "second region of each slot's table, which this path does "
+                "not move")
 
     def extend_capacity(self, slot: int, position: int, span: int) -> int:
         """Best-effort growth for a multi-token (speculative) append:
@@ -604,6 +734,7 @@ class PagedKVCache:
         the block list move, refcounts and the page DATA are untouched,
         so adoption never copies KV (the no-dense-copy pin in
         tests/test_disagg.py)."""
+        self._refuse_on_eva("transfer_slot (disaggregated handoff)")
         assert not self._slot_blocks[dst], (
             f"transfer_slot: destination slot {dst} still holds blocks")
         self._slot_blocks[dst] = self._slot_blocks[src]
@@ -624,6 +755,7 @@ class PagedKVCache:
         here mutates the source pool: a migration that fails after the
         export (the "fleet-migrate" chaos site) leaves the source slot
         fully intact."""
+        self._refuse_on_eva("export_slot (migration, parking)")
         import jax
         from megatronapp_tpu.ops.pallas.paged_attention import (
             gather_prefix_pages,
@@ -662,6 +794,7 @@ class PagedKVCache:
         scatter fault — `audit()` passes either way. The storage dtype
         must match (rows are stored bytes, never converted): fleet
         replicas share one --kv-cache-dtype by construction."""
+        self._refuse_on_eva("import_slot (migration, unparking)")
         if payload["kv_cache_dtype"] != self.kv_cache_dtype:
             raise ValueError(
                 f"cannot import {payload['kv_cache_dtype']!r} KV rows "
@@ -730,6 +863,7 @@ class PagedKVCache:
         corrupting the prefix cache. Rewinding never splits a block:
         KV rows past valid_len inside the kept tail block are simply
         overwritten by the next append."""
+        self._refuse_on_eva("rewind (speculative decoding)")
         keep = cdiv(max(valid_len, 1), self.block_size)
         owned = self._slot_blocks[slot]
         while len(owned) > keep:
@@ -768,6 +902,24 @@ class PagedKVCache:
             f"held={len(held)} != {nb}")
         for blk in lru:
             assert blk in self._hash_of, f"unhashed block {blk} on LRU"
+        if self.eva:
+            # Both regions and the pending summaries: the table's row is
+            # the slot's blocks, region by region, and nothing else.
+            spb, mb = self._eva_spb, self.max_blocks_per_seq
+            for slot, blocks in enumerate(self._slot_blocks):
+                n_sum, n_win, n_pend = (int(n)
+                                        for n in self._eva_counts[slot])
+                assert len(blocks) == n_sum + n_win + n_pend, (
+                    f"slot {slot}: {len(blocks)} blocks, regions "
+                    f"{(n_sum, n_win, n_pend)}")
+                assert n_sum % spb == 0 and n_win <= self._eva_wb \
+                    and n_pend <= cdiv(n_win, self.block_size), (
+                    f"slot {slot}: regions {(n_sum, n_win, n_pend)}")
+                row = np.zeros((mb,), np.int32)
+                row[:n_sum + n_win] = blocks[:n_sum + n_win]
+                row[mb - spb:mb - spb + n_pend] = blocks[n_sum + n_win:]
+                assert np.array_equal(row, self.page_table[slot]), (
+                    f"slot {slot}: table row differs from its regions")
         return True
 
     def register_prefix(self, slot: int, tokens: np.ndarray, valid_len: int):
@@ -802,6 +954,7 @@ class PagedKVCache:
         for blk in self._slot_blocks[slot]:
             self._release_block(blk)
         self._slot_blocks[slot] = []
+        self._eva_counts[slot] = 0
         self.page_table[slot, :] = 0
         if preempted:
             self.stats["preemptions"] += 1

@@ -38,7 +38,9 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
             "word": jax.random.normal(
                 k_emb, (cfg.vocab_size, cfg.hidden_size), cfg.params_dtype) * std,
         },
-        "final_ln_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype),
+        "final_ln_scale": jnp.full(
+            (cfg.hidden_size,), 0.0 if cfg.norm_unit_offset else 1.0,
+            cfg.params_dtype),
     }
     ax = {
         "embedding": {"word": ("vocab", "embed")},
@@ -87,8 +89,10 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
             lambda axes: ("pp_stage", "vpp_chunk", "stage_layers") + axes[1:],
             ax["block"], is_leaf=is_logical_axes)
     if cfg.untie_embeddings_and_output_weights:
+        # num_pred_heads > 1: head j's vocab_size columns follow head j-1's.
         p["output"] = jax.random.normal(
-            k_out, (cfg.hidden_size, cfg.vocab_size), cfg.params_dtype) * std
+            k_out, (cfg.hidden_size, cfg.vocab_size * cfg.num_pred_heads),
+            cfg.params_dtype) * std
         ax["output"] = ("embed", "vocab")
     if cfg.mtp_num_layers:
         # MTP depth modules are NOT part of the pipelined stack: like the
@@ -254,6 +258,10 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
         zigzag_active, zigzag_indices,
     )
     mtp_metrics = {}
+    if cfg.num_pred_heads > 1:
+        raise NotImplementedError(
+            "a head of num_pred_heads x vocab_size columns has no training "
+            "loss here yet: such a model is served, not trained")
     if cfg.mtp_num_layers:
         if segment_ids is not None:
             raise NotImplementedError(
@@ -306,7 +314,8 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
 def gpt_head(p, h: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
     """Final norm + vocab projection. h [..., S, H] → logits fp32."""
     h = apply_norm(cfg.normalization, h, p["final_ln_scale"],
-                   p.get("final_ln_bias"), cfg.layernorm_epsilon)
+                   p.get("final_ln_bias"), cfg.layernorm_epsilon,
+                   cfg.norm_unit_offset)
     out_kernel = (p["output"] if "output" in p
                   else p["embedding"]["word"].T)
     logits = h.astype(cfg.compute_dtype) @ out_kernel.astype(cfg.compute_dtype)

@@ -138,7 +138,29 @@ def jamba2_3b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def evabyte_6p5b(**kw) -> TransformerConfig:
+    """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
+    32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
+    theta 100,000, SwiGLU 11008, RMSNorm 1e-5 whose scale is 1 + g, no
+    bias, an untied head of 8 byte-prediction heads x 320, and EVA
+    attention: an exact window of 2048 bytes and one pooled key/value row
+    for every 16 older bytes (transformer/eva.py). Serves through --engine
+    dynamic --paged-kv-cache, ids in and out (no byte tokenizer yet)."""
+    d = dict(num_layers=32, hidden_size=4096, num_attention_heads=32,
+             num_query_groups=32, ffn_hidden_size=11008, vocab_size=320,
+             true_vocab_size=320, max_position_embeddings=32768,
+             rotary_base=100000.0, activation=ActivationKind.swiglu,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             norm_unit_offset=True, add_bias_linear=False,
+             untie_embeddings_and_output_weights=True, num_pred_heads=8,
+             init_method_std=0.01275,
+             eva_window_size=2048, eva_chunk_size=16)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 PRESETS = {
+    "evabyte-6.5b": evabyte_6p5b,
     "jamba2-3b": jamba2_3b,
     "gpt2-125m": gpt2_125m,
     "gpt3-2.7b": gpt3_2p7b,

@@ -35,7 +35,12 @@ def rms_norm(x, scale, eps: float = 1e-5):
     return out.astype(dtype)
 
 
-def apply_norm(kind: NormKind, x, scale, bias=None, eps: float = 1e-5):
+def apply_norm(kind: NormKind, x, scale, bias=None, eps: float = 1e-5,
+               unit_offset: bool = False):
+    """unit_offset: the stored leaf is g and the scale 1 + g
+    (cfg.norm_unit_offset), added in float32."""
+    if unit_offset:
+        scale = 1.0 + scale.astype(jnp.float32)
     if kind == NormKind.rmsnorm:
         return rms_norm(x, scale, eps)
     return layer_norm(x, scale, bias, eps)

@@ -1035,6 +1035,72 @@ def paged_append(pool: jnp.ndarray, rows: jnp.ndarray, layer,
     )(layer.reshape(1), blocks[order], offsets[order], order, rows, pool)
 
 
+def eva_summary(k_pool: jnp.ndarray, v_pool: jnp.ndarray, phi, mu, layer,
+                src: jnp.ndarray, dst: jnp.ndarray, offsets: jnp.ndarray,
+                scale: float):
+    """EVA's chunk summariser (transformer/eva.py), IN PLACE: pool the rows
+    of a page into one key and one value row of the same pools.
+
+    k_pool / v_pool [L, NB, bs, Hkv, D] (a chunk is a page); phi, mu
+    [Hkv, D]; layer int32 scalar; src / dst / offsets [N] int32. For each i
+    the page (layer, src[i]) is read, alpha = softmax over its bs rows of
+    scale * k . phi a head, and k~ = sum alpha k + mu, v~ = sum alpha v
+    (float32 inside) land at row (layer, dst[i], offsets[i]). An i whose
+    dst is NB or more is DROPPED: like `paged_append`, the valid ones are
+    sorted first and their count bounds the grid, so a step that fills no
+    chunk runs no grid step. Both pools are aliased to the outputs: a step
+    touches the pages it pools and the rows it writes. No dst block may be
+    a src block of the same call (the engine's are another region of a
+    slot's table)."""
+    nb, bs, hkv, d = k_pool.shape[1:]
+    layer = jnp.asarray(layer, jnp.int32)
+    valid = dst < nb
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+    src = jnp.minimum(src.astype(jnp.int32), nb - 1)[order]
+    dst = dst.astype(jnp.int32)[order]
+    offsets = offsets.astype(jnp.int32)[order]
+
+    def kernel(lid_ref, src_ref, dst_ref, off_ref, phi_ref, mu_ref, k_ref,
+               v_ref, ko_ref, vo_ref):
+        del lid_ref, src_ref, dst_ref, off_ref
+        k = k_ref[...].astype(jnp.float32)                  # [bs, Hkv, D]
+        v = v_ref[...].astype(jnp.float32)
+        logit = jnp.sum(k * phi_ref[...].astype(jnp.float32)[None],
+                        axis=-1, keepdims=True) * scale     # [bs, Hkv, 1]
+        e = jnp.exp(logit - jnp.max(logit, axis=0, keepdims=True))
+        alpha = e / jnp.sum(e, axis=0, keepdims=True)
+        ko_ref[...] = (jnp.sum(alpha * k, axis=0)
+                       + mu_ref[...].astype(jnp.float32)).astype(ko_ref.dtype)
+        vo_ref[...] = jnp.sum(alpha * v, axis=0).astype(vo_ref.dtype)
+
+    def whole(i, lid, src_, dst_, off):
+        return (0, 0)
+
+    def page(i, lid, src_, dst_, off):
+        return (lid[0], src_[i], 0, 0, 0)
+
+    def row(i, lid, src_, dst_, off):
+        return (lid[0], dst_[i], off[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(jnp.sum(valid, dtype=jnp.int32),),
+        in_specs=[pl.BlockSpec((hkv, d), whole), pl.BlockSpec((hkv, d), whole),
+                  pl.BlockSpec((None, None, bs, hkv, d), page),
+                  pl.BlockSpec((None, None, bs, hkv, d), page)],
+        out_specs=[pl.BlockSpec((None, None, None, hkv, d), row),
+                   pl.BlockSpec((None, None, None, hkv, d), row)],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={6: 0, 7: 1},
+        interpret=_interpret(),
+        name="eva_summary",
+    )(layer.reshape(1), src, dst, offsets, phi, mu, k_pool, v_pool)
+
+
 # ---------------------------------------------------------------------------
 # Batched-LoRA delta kernels (ISSUE 19): one decode batch, many adapters
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ Param leaf layout (per layer, unstacked):
   out_kernel [n_heads*D, H]        logical ('qkv', 'embed')
   out_bias   [H]                   logical ('embed',)
   (optional) q_ln_scale, k_ln_scale [D]
+  (EVA, cfg.eva_window_size) eva_phi, eva_mu [n_kv, D]: transformer/eva.py
 """
 
 from __future__ import annotations
@@ -29,6 +30,7 @@ from megatronapp_tpu.ops.attention import dot_product_attention
 from megatronapp_tpu.ops.normalization import rms_norm
 from megatronapp_tpu.ops import rotary
 from megatronapp_tpu.scope.hooks import scope_capture
+from megatronapp_tpu.transformer import eva
 
 _announced: set = set()
 
@@ -74,6 +76,10 @@ def init_attention_params(rng, cfg: TransformerConfig, out_std: float):
         p["k_ln_scale"] = jnp.ones((d,), cfg.params_dtype)
         ax["q_ln_scale"] = ("head_dim",)
         ax["k_ln_scale"] = ("head_dim",)
+    if cfg.is_eva:
+        eva_p, eva_ax = eva.init_eva_params(jax.random.fold_in(rng, 3), cfg)
+        p.update(eva_p)
+        ax.update(eva_ax)
     return p, ax
 
 
@@ -202,7 +208,7 @@ def attention_forward(
         # Ambient-manual tp-sharded stage body: see docstring. Local head
         # counts; s stays the LOCAL seq chunk length, sf the full length.
         if (kv_cache is not None or attention_mask is not None
-                or segment_ids is not None or zigzag):
+                or segment_ids is not None or zigzag or cfg.is_eva):
             raise NotImplementedError(
                 "tp-sharded stage body supports the plain training path "
                 "only (no kv cache / explicit mask / packing / zigzag) — "
@@ -366,10 +372,21 @@ def attention_forward(
             if ragged:
                 counts = (chunk_counts if chunk_counts is not None
                           else jnp.full((b,), s, jnp.int32))
+            true_positions = cache_positions
+            if cfg.is_eva:
+                # The slot's table is summaries, then the open window:
+                # rows land at, and the kernels walk to, a position's row
+                # of THAT table (transformer/eva.py; the engine and the
+                # pool refuse a quantized pool and a mesh on such a model).
+                cache_positions = eva.table_rows(cfg, true_positions)
             (ck, cv), new_scales = append_kv(
                 kv_cache, kv_scales, (k, v) if ragged else (k[:, 0], v[:, 0]),
                 page_table, cache_positions, active, plane, counts,
                 mesh)
+            if cfg.is_eva:
+                ck, cv = eva.write_summaries(
+                    p, cfg, (ck, cv), page_table, true_positions, counts,
+                    active, plane, width=s)
             sc_kw = ({} if new_scales is None else
                      {"k_scales": new_scales[0], "v_scales": new_scales[1]})
             if ragged:
@@ -387,6 +404,11 @@ def attention_forward(
                           kernel_gen._interpret())
             if tp_paged:
                 paged_out = _replicate_heads(paged_out, ctx)
+        elif cfg.is_eva:
+            raise ValueError(
+                "EVA attention keeps chunk summaries in a paged cache's "
+                "table: a dense cache has no place for them (serve with "
+                "paged=True; gpt_forward runs whole sequences)")
         elif cache_positions is not None:
             # Continuous-batching decode (dynamic_context.py analogue):
             # each row appends at ITS OWN position; causality MUST come
@@ -421,6 +443,18 @@ def attention_forward(
     # and intentionally has no effect on the math.
     if paged_out is not None:
         attn_out = paged_out
+    elif cfg.is_eva:
+        # Whole sequences from position 0, by XLA ops: the flash kernels
+        # and the cp rings have no window term.
+        if (attention_mask is not None or segment_ids is not None
+                or tp_sharded or (ctx is not None and ctx.cp > 1)):
+            raise ValueError(
+                "EVA attention has no window term in the flash kernels or "
+                "the context-parallel rings: whole causal sequences only "
+                "(no explicit mask, packed segments, cp or tp-sharded "
+                "stage body)")
+        _announce("self-attention", "xla eva (windows and chunk summaries)")
+        attn_out = eva.eva_attention(q, k, v, p["eva_phi"], p["eva_mu"], cfg)
     elif ctx is not None and ctx.cp > 1 and kv_cache is None:
         # Context-parallel attention over the cp axis (seq sharded).
         from megatronapp_tpu.ops.context_parallel import (

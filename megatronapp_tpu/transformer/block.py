@@ -33,6 +33,13 @@ from megatronapp_tpu.transformer.moe import init_moe_params, moe_forward
 from megatronapp_tpu.scope.hooks import scope_capture
 
 
+def _norm_scale(cfg: TransformerConfig):
+    """A norm's scale leaf at initialisation: 1, or with norm_unit_offset
+    the g = 0 of a scale 1 + g."""
+    return jnp.full((cfg.hidden_size,), 0.0 if cfg.norm_unit_offset else 1.0,
+                    cfg.params_dtype)
+
+
 def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
     """A layer's first half: its norm and its mixer (attention, MLA, or with
     `ssm` a selective-state-space mixer)."""
@@ -47,8 +54,7 @@ def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
     else:
         name = "attention"
         mix_p, mix_ax = init_attention_params(rng, cfg, out_std)
-    p = {"ln1_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype),
-         name: mix_p}
+    p = {"ln1_scale": _norm_scale(cfg), name: mix_p}
     ax = {"ln1_scale": ("embed",), name: mix_ax}
     if cfg.normalization == NormKind.layernorm:
         p["ln1_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
@@ -59,7 +65,7 @@ def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
 def _init_ffn_half(rng, cfg: TransformerConfig, out_std,
                    force_dense: bool = False):
     """A layer's second half: its norm and its feed-forward."""
-    p = {"ln2_scale": jnp.ones((cfg.hidden_size,), cfg.params_dtype)}
+    p = {"ln2_scale": _norm_scale(cfg)}
     ax = {"ln2_scale": ("embed",)}
     if cfg.normalization == NormKind.layernorm:
         p["ln2_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
@@ -128,7 +134,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     adapter."""
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
-                   cfg.layernorm_epsilon)
+                   cfg.layernorm_epsilon, cfg.norm_unit_offset)
     # The scopes put a layer's three parts into the compiled program's
     # op_names (HLO text, the profiler's own viewer). The events of a TPU
     # trace as jax.profiler.ProfileData gives them do not carry op_names,
@@ -201,7 +207,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
 
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln2_scale"], p.get("ln2_bias"),
-                   cfg.layernorm_epsilon)
+                   cfg.layernorm_epsilon, cfg.norm_unit_offset)
     aux = None
     if "moe" in p:
         if fp8 is not None:

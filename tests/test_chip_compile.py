@@ -337,6 +337,7 @@ def test_engine_paged_steps(one_chip, chip_compile, which, kv):
 @pytest.mark.parametrize("model,batch,blocks,seq", [
     ("gpt3-2.7b", 24, 896, 2048),
     ("deepseek-v2-lite", 32, 8192, 4096),
+    ("evabyte-6.5b", 32, 3584, 16384),
 ])
 def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
                                            batch, blocks, seq, which):
@@ -344,8 +345,11 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     vocabulary; depth cut to 2, and for DeepSeek-V2-Lite to 1 dense + 2 MoE
     layers of 8 experts at the published widths, so that the layer loop
     is a loop): D 80 over a table of 128 blocks, and latent rows of 512 +
-    64 columns over a table of 256. The weights and the pool go in as
-    abstract values; the step still aliases its pools.
+    64 columns over a table of 256; EvaByte's 32 key/value heads of 128 (8
+    layers, as the cell cuts it) over a two-region table of 192 blocks,
+    not 1024, with `eva_summary` pooling filled chunks in place. The
+    weights and the pool go in as abstract values; the step still aliases
+    its pools.
 
     The experts' grouped GEMMs (`ragged-dot-none*`, custom calls) read the
     fc1/fc2 stacks in place through the layer id (ISSUE 31): nothing
@@ -363,6 +367,9 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
         over.update(num_layers=3, num_moe_experts=8,
                     max_position_embeddings=seq,
                     params_dtype=jnp.bfloat16)
+    if model == "evabyte-6.5b":
+        over.update(num_layers=8, vocab_size=320,
+                    params_dtype=jnp.bfloat16)
     cfg = PRESETS[model](**over)
     abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
@@ -375,7 +382,7 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     pages = tuple(_sds((cfg.num_layers, blocks) + p.shape[2:], p.dtype,
                        one_chip) for p in eng.pool.pages)
     mb = eng.pool.page_table.shape[1]
-    assert mb == seq // 16
+    assert mb == (128 + 8 * 8 if cfg.is_eva else seq // 16)
 
     def i32(*shape):
         return _sds(shape, jnp.int32, one_chip)
@@ -391,7 +398,8 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
             i32(1), _sds((1,), jnp.bool_, one_chip), None).compile()
     family = "paged_decode" if which == "decode" else "paged_mq"
     _assert_kernels_named(
-        compiled, family + ("_latent" if cfg.multi_latent_attention else ""))
+        compiled, family + ("_latent" if cfg.multi_latent_attention else ""),
+        *(("eva_summary", "paged_append") if cfg.is_eva else ()))
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pages)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes

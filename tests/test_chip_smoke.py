@@ -169,6 +169,7 @@ def _run_with(monkeypatch, capsys, server_ok):
                         lambda tiny: _phase("server", ok=server_ok))
     monkeypatch.setattr(chip_smoke, "phase_hybrid",
                         lambda tiny: _phase("hybrid"))
+    monkeypatch.setattr(chip_smoke, "phase_eva", lambda tiny: _phase("eva"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -180,7 +181,7 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
     phases = [json.loads(ln[len("phase: "):]) for ln in lines
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server", "hybrid"]
+                                            "server", "hybrid", "eva"]
 
 
 def _hybrid_lines(device=TPU, **kw):
@@ -207,6 +208,38 @@ def test_check_hybrid(kw, needle):
     assert out["ok"] is (needle is None), out["problems"]
     assert needle is None or needle in " | ".join(out["problems"])
     assert not chip_smoke.check_hybrid(1, _hybrid_lines())["ok"]
+
+
+def _eva_lines(device=TPU, **kw):
+    res = {"tokens": 1249, "in_vocab": True, "eva_summary_calls": 1,
+           "eva": {"windows_closed": 4, "blocks_freed": 64,
+                   "max_blocks_slot": 19},
+           "table_blocks": 20, "blocks_in_use_after": 0, "pool_bytes": 100,
+           "alias_bytes": 128, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            "attention: paged decode -> pallas paged kernel (compiled)",
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"eva_summary_calls": 4}, "not one a layer loop"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"tokens": 1200}, "tokens came back"),
+    ({"eva": {"windows_closed": 0, "blocks_freed": 0,
+              "max_blocks_slot": 19}}, "eva counters"),
+    ({"eva": {"windows_closed": 4, "blocks_freed": 64,
+              "max_blocks_slot": 40}}, "a slot held 40 blocks"),
+    ({"blocks_in_use_after": 3}, "still held"),
+])
+def test_check_eva(kw, needle):
+    """The EVA phase's facts: one summariser a layer loop in the compiled
+    decode step, the pools aliased, every token back, windows closed and
+    their blocks freed, a slot's blocks bounded by its table."""
+    out = chip_smoke.check_eva(0, _eva_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_eva(1, _eva_lines())["ok"]
 
 
 def test_a_failing_phase_fails_the_run(monkeypatch, capsys):
@@ -277,7 +310,7 @@ def test_forced_to_the_cpu_it_refuses(tmp_path):
 def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server", "hybrid"]
+                                            "server", "hybrid", "eva"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -298,7 +331,10 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     hybrid = phases[3]
     assert hybrid["state"]["resets"] == 3 and hybrid["state"]["layers"] == 6
     assert hybrid["alias_bytes"] >= hybrid["pool_bytes"] > 0
-    assert sum("not a TPU" in ln for ln in lines) == 4
+    eva = phases[4]
+    assert eva["eva"]["windows_closed"] == 3 and eva["table_blocks"] == 20
+    assert eva["alias_bytes"] >= eva["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 5
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):

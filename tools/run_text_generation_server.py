@@ -45,13 +45,16 @@ def main():
     # --paged-kv-cache, --kv-block-size, --num-kv-blocks,
     # --scan-unroll, --no-prefix-caching.
     from megatronapp_tpu.config.arguments import (
-        add_hybrid_args, add_serving_args, hybrid_fields,
-        validate_serving_args,
+        add_eva_args, add_hybrid_args, add_serving_args, eva_fields,
+        hybrid_fields, validate_serving_args,
     )
     add_serving_args(ap)
     # --attn-layer-period / -offset, --ssm-inner-norms: a hybrid
     # state-space stack on a preset, served by --engine dynamic.
     add_hybrid_args(ap)
+    # --eva-window-size / --eva-chunk-size: EVA attention on a preset,
+    # served by --engine dynamic --paged-kv-cache.
+    add_eva_args(ap)
     args = ap.parse_args()
     from megatronapp_tpu.utils.platform import (
         device_line, enable_compile_cache,
@@ -79,8 +82,15 @@ def main():
     validate_serving_args(
         args, multi_latent_attention=cfg.multi_latent_attention)
     import dataclasses
-    if hybrid_fields(args):
-        cfg = dataclasses.replace(cfg, **hybrid_fields(args))
+    shape = {**hybrid_fields(args), **eva_fields(args)}
+    if shape:
+        cfg = dataclasses.replace(cfg, **shape)
+    if cfg.is_eva and not (args.engine == "dynamic"
+                           and args.paged_kv_cache):
+        raise SystemExit(
+            "a model with EVA attention keeps its chunk summaries in the "
+            "paged engine's page tables: serve it with --engine dynamic "
+            "--paged-kv-cache")
     if cfg.num_ssm_layers and not (args.engine == "dynamic"
                                    and args.paged_kv_cache):
         raise SystemExit(
