@@ -173,11 +173,18 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "head-sharded over a tp mesh with per-shard KV "
                         "pools (with --serve-disagg, EACH sub-mesh is "
                         "this wide)")
-    g.add_argument("--prefill-chunk", type=int, default=32,
-                   help="chunked-prefill chunk size — with "
-                        "--serve-disagg also the prefill-side "
-                        "scheduling quantum (chunks defer when the "
-                        "decode SLO is at risk)")
+    g.add_argument("--prefill-chunk", type=int, default=None,
+                   help="width of a paged prefill call, in tokens (one "
+                        "compiled program; a prompt's last call is padded "
+                        "to it). Default: the engine chooses from the "
+                        "model's shapes and the device (what the call's "
+                        "weight stream pays for: 256 for a dense bf16 "
+                        "model on a v5e; at most --max-seq-len; 32 on a "
+                        "CPU) and GET /stats "
+                        "shows it under prefill.width. With "
+                        "--serve-disagg also the prefill-side scheduling "
+                        "quantum (chunks defer when the decode SLO is at "
+                        "risk), 32 unless given")
     g.add_argument("--disagg-prefill-slots", type=int, default=2,
                    help="staging page-table rows for in-flight/parked "
                         "prefills on the prefill sub-mesh")

@@ -388,7 +388,7 @@ class DisaggServingEngine:
                  prefill_buckets: Tuple[int, ...] = (32, 128, 512),
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  enable_prefix_caching: bool = True,
-                 prefill_chunk: int = 32, prefill_slots: int = 2,
+                 prefill_chunk: Optional[int] = 32, prefill_slots: int = 2,
                  decode_slo_ms: Optional[float] = None, tp: int = 1,
                  devices=None, spec_method: Optional[str] = None,
                  spec_k: int = 4, draft_params=None, draft_cfg=None,
@@ -398,6 +398,10 @@ class DisaggServingEngine:
         self.prefill_ctx, self.decode_ctx = split_serving_meshes(
             tp=tp, devices=devices, prefill_devices=prefill_devices)
         max_seq_len = max_seq_len or cfg.max_position_embeddings
+        # One quantum for the worker's dense prefill and the engine's
+        # paged calls (preemption's recomputation): the server passes None
+        # where --prefill-chunk is not given.
+        prefill_chunk = prefill_chunk or 32
         pool = PagedKVCache(
             cfg, max_batch, max_seq_len, num_blocks=num_blocks,
             block_size=block_size,

@@ -52,6 +52,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import logging
+import math
 import time
 from collections import OrderedDict, deque
 from typing import Callable, Dict, List, Optional, Tuple
@@ -78,6 +79,7 @@ from megatronapp_tpu.transformer.eva import table_rows
 from megatronapp_tpu.transformer.moe import StackedLayer
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
+from megatronapp_tpu.utils.flops import tpu_roofs
 from megatronapp_tpu.utils.platform import fresh_compiles
 
 logger = logging.getLogger(__name__)
@@ -420,7 +422,7 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
 def _paged_multiquery_step(params, tokens, pages, page_table, starts,
                            q_lens, active, cfg: TransformerConfig,
                            max_seq_len: int, ctx=None, scales=None,
-                           lora=None, rows=None):
+                           lora=None, rows=None, last=None):
     """Ragged multi-token step against the paged pool — the UNIFIED
     prefill/decode primitive (speculative verify + chunked prefill).
 
@@ -433,7 +435,10 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     where starts[b] is 0: a sequence begins), stop at q_lens[b] and write
     the state back. Returns (logits [B, S, V], hidden [B, S, H] pre-head,
     the pools, written in place as in _paged_decode_step) — hidden feeds
-    the MTP self-draft proposer."""
+    the MTP self-draft proposer. last [B] (a prefill call, which samples
+    from one position a row): the head runs on row b's position last[b]
+    alone, and logits and hidden are [B, 1, ...]; speculative verify reads
+    every position and passes none."""
     b, s = tokens.shape
     positions = starts[:, None] + jnp.arange(s)[None, :]       # [B, S]
     positions = jnp.minimum(positions, max_seq_len - 1)
@@ -458,6 +463,8 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
 
     h, _, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
                                          cfg, layer, ctx, rows)
+    if last is not None:
+        h = jnp.take_along_axis(h, last[:, None, None], axis=1)
     logits = gpt_head(params, h, cfg)
     return logits, h, new_pages
 
@@ -597,6 +604,66 @@ def _sample_batched(logits, seeds, rids, steps, temps, top_ks, top_ps,
     return toks if tail is None else jnp.concatenate([toks, tail])
 
 
+def prefill_call_costs(cfg: TransformerConfig, params):
+    """What a [1, width] prefill call costs, from the shapes alone: (bytes
+    of weights it streams whatever its width, matmul flops a position).
+
+    Every matrix of the model is read once a call: the layers' (each expert
+    of an MoE layer too: tens of routed rows touch nearly all of them), the
+    head, and the word embedding where the head is tied to it (an untied
+    embedding is gathered by row). A position multiplies each matrix once,
+    of an expert stack [L, E, K, N] its top-k."""
+    stream = flops = 0.0
+    tied = "output" not in params
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        name = jax.tree_util.keystr(path)
+        if leaf.ndim < 2 or ("embedding" in name
+                             and not (tied and "word" in name)):
+            continue
+        share = 1.0
+        if (cfg.is_moe and leaf.ndim == 4
+                and leaf.shape[1] == cfg.num_moe_experts):
+            share = cfg.moe_router_topk / cfg.num_moe_experts
+        stream += leaf.size * leaf.dtype.itemsize
+        flops += 2.0 * leaf.size * share
+    return stream, flops
+
+
+def choose_prefill_width(cfg: TransformerConfig, params, max_seq_len: int,
+                         block_size: int,
+                         device_kind: Optional[str] = None) -> int:
+    """The width of the engine's prefill call where the caller gives none,
+    from what the engine can see.
+
+    A call reads every weight once whatever its width, so a position is
+    free until the positions' matmuls take as long as the stream: (stream
+    bytes / HBM rate) over (flops a position / peak), the device's ridge
+    (240 flops a byte on a v5e) for a dense bf16 model, more where a
+    position computes on a share of what the call streams (an MoE layer's
+    top-k of all its experts); the nearest power of two. Wider, a call
+    costs by the position.
+
+    The result is at most max_seq_len and at least the pool's block; on an
+    EVA model at most the window, which a power of two divides, as it holds
+    whole chunks (the chunk is the block). A device whose roofs
+    utils/flops.py does not know (a CPU, where the tests run) gets 32."""
+    if device_kind is None:
+        # where the weights are; abstract ones: JAX's default device
+        leaf = next(iter(jax.tree.leaves(params)), None)
+        devices = (leaf.devices() if isinstance(leaf, jax.Array)
+                   else jax.devices())
+        device_kind = next(iter(devices)).device_kind
+    roofs = tpu_roofs(device_kind)
+    if roofs is None:
+        return min(32, max_seq_len)
+    peak, rate = roofs
+    stream, flops = prefill_call_costs(cfg, params)
+    width = 2 ** round(math.log2((stream / rate) / (flops / peak)))
+    if cfg.is_eva:
+        width = min(width, 2 ** int(math.log2(cfg.eva_window_size)))
+    return max(min(width, max_seq_len), block_size)
+
+
 class DynamicInferenceEngine:
     """Continuous-batching engine (reference DynamicInferenceEngine).
 
@@ -625,7 +692,7 @@ class DynamicInferenceEngine:
                  enable_prefix_caching: bool = True,
                  spec_method: Optional[str] = None, spec_k: int = 4,
                  draft_params=None, draft_cfg=None,
-                 prefill_chunk: int = 32, ctx=None, pool=None,
+                 prefill_chunk: Optional[int] = None, ctx=None, pool=None,
                  kv_cache_dtype: str = "bf16",
                  adapter_cache=None,
                  spill_host_mb: float = 0.0,
@@ -638,6 +705,11 @@ class DynamicInferenceEngine:
         self.prefill_buckets = tuple(
             b for b in sorted(prefill_buckets) if b <= self.max_seq_len
         ) or (self.max_seq_len,)
+        # The width of a prefill call ([1, prefill_chunk], one compiled
+        # program): the caller's, or chosen from the shapes.
+        if prefill_chunk is None:
+            prefill_chunk = choose_prefill_width(
+                cfg, params, self.max_seq_len, block_size)
         self.prefill_chunk = min(prefill_chunk, self.max_seq_len)
         # Rolling reload (DynamicBatchingDriver.request_reload): while
         # True, _admit leaves the waiting queue untouched so running
@@ -883,6 +955,10 @@ class DynamicInferenceEngine:
         self.sampler_stats = {
             f"{site}_{kind}": 0 for site in ("rounds", "prefills")
             for kind in ("greedy", "sampled", "ordered")}
+        # Always-on counters of the paged prefill calls
+        # (stats_snapshot()["prefill"]): every call is `prefill_chunk` rows
+        # wide, and `tokens` of calls x width were a prompt's.
+        self.prefill_stats = {"calls": 0, "tokens": 0}
         # Always-on counters of the paged kernels' walk over plain decode
         # rounds (stats_snapshot()["paged"]): the blocks the running slots
         # hold against running slots x max_blocks_per_seq.
@@ -976,13 +1052,14 @@ class DynamicInferenceEngine:
             self._decode = _PoolStep(_decode_traced, n_lead=2)
 
             def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
-                           lora, rows=None):
+                           lora, rows=None, last=None):
                 # Python side-effect: runs only while TRACING.
                 self.mq_traces += 1
                 return _paged_multiquery_step(p, t, pages, tbl, starts,
                                               qlens, act, cfg, msl,
                                               ctx=step_ctx, scales=scales,
-                                              lora=lora, rows=rows)
+                                              lora=lora, rows=rows,
+                                              last=last)
 
             self._mq_step = _PoolStep(_mq_traced, n_lead=2)
             if self.spec_method:
@@ -1789,10 +1866,10 @@ class DynamicInferenceEngine:
         table_row = _handed_over(pool.page_table[slot][None])    # [1, MB]
         # The sequence's state starts from zeros inside its first call
         # (start 0: prefix reuse is off on such a model), in slot `slot`.
-        rows = ()
+        rows = None
         if self.has_state:
             assert cached == 0, cached
-            rows = (jnp.asarray([slot], jnp.int32),)
+            rows = jnp.asarray([slot], jnp.int32)
             self.state_stats["resets"] += 1
         pos, count = cached, 0
         logits = hid = None
@@ -1826,26 +1903,30 @@ class DynamicInferenceEngine:
                 # fault costs one step and audit() stays clean (the
                 # tests/test_resilience.py drill).
                 chaos.fire("kv-quant-write")
-            with self._span("engine.prefill_call", tokens=count,
+            with self._span("engine.prefill_call", tokens=count, width=c,
                             **call_attrs):
+                # The head runs on the call's last real position: the one
+                # the first sample reads, of the prompt's last call.
                 logits, hid, new = self._mq_step(
                     self.params, jnp.asarray(chunk), self._pools(),
                     self.pool.scales,
                     table_row, jnp.asarray([pos], jnp.int32),
                     jnp.asarray([count], jnp.int32), jnp.ones((1,), bool),
                     self._lora_args(rows=self.row_adapter[slot:slot + 1]),
-                    *rows)
+                    rows, jnp.asarray([count - 1], jnp.int32))
                 self._commit_pools(new)
                 self.state_stats["prefill_scans"] += self.cfg.num_ssm_layers
+            self.prefill_stats["calls"] += 1
+            self.prefill_stats["tokens"] += count
             pos += count
         # Register the prompt's full blocks so concurrent same-prefix
         # requests hit them immediately.
         pool.register_prefix(slot, np.asarray(tokens), p_len)
         if self.proposer is not None and self.proposer.needs_hidden:
             self._h_last[slot] = np.asarray(
-                jax.device_get(hid[0, count - 1]), np.float32)
+                jax.device_get(hid[0, 0]), np.float32)
             self._h_valid[slot] = True
-        return logits[0, count - 1]
+        return logits[0, 0]
 
     def _sample(self, logits, req: Request):
         """Single-row sampling (prefill). Same fold_in key chain as the
@@ -2342,6 +2423,11 @@ class DynamicInferenceEngine:
         plain decode rounds and by prefills' first samples: `*_greedy`
         (argmax alone), `*_sampled` (a categorical, the vocabulary not
         ordered), `*_ordered` (a sort ran for some row's top-k or top-p).
+        "prefill" is a dict on a paged engine (False on a dense-cache
+        one): the `width` of a prefill call (`prefill_chunk`: given, or
+        chosen from the shapes), the `calls` made, the prompt `tokens` they
+        ran, and `fill_share` = tokens / (calls x width), the share of the
+        calls' rows that were prompt and not padding.
         "eva" is a dict on a model with EVA attention (False otherwise):
         `layers`, `window`, `chunk`; `windows_closed` and the `blocks_freed`
         by them; `summary_rows_written` (chunks pooled, a chunk counted
@@ -2363,6 +2449,7 @@ class DynamicInferenceEngine:
             "decode_traces": self.decode_traces,
             "steps": self.step_stats.snapshot(),
             "sampler": dict(self.sampler_stats),
+            "prefill": False,
             "state": False,
             "eva": False,
         }
@@ -2387,6 +2474,11 @@ class DynamicInferenceEngine:
             out["decode_dispatch"] = self.dispatch_stats()
         if self.paged:
             out["paged"] = dict(self.paged_stats)
+            rows = self.prefill_stats["calls"] * self.prefill_chunk
+            out["prefill"] = dict(
+                self.prefill_stats, width=self.prefill_chunk,
+                fill_share=(round(self.prefill_stats["tokens"] / rows, 4)
+                            if rows else 0.0))
             pool = self.pool
             st = dict(pool.stats)
             seen = st["prefix_hit_tokens"] + st["prefill_tokens"]

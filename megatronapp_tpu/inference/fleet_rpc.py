@@ -144,7 +144,7 @@ def default_engine_spec(**overrides) -> dict:
         "prefill_buckets": [16],
         "block_size": 8, "num_blocks": None,
         "kv_cache_dtype": "bf16",
-        "prefill_chunk": 32,
+        "prefill_chunk": None,      # None: the engine's choice
         # Host-RAM KV spill tier (ISSUE 20): parked sessions per worker.
         "kv_spill_host_mb": 0.0,
         "kv_spill_watermark_blocks": 0,
@@ -203,7 +203,7 @@ def build_engine_from_spec(spec: dict):
         paged=True, block_size=spec["block_size"],
         num_blocks=spec.get("num_blocks"),
         kv_cache_dtype=spec.get("kv_cache_dtype", "bf16"),
-        prefill_chunk=spec.get("prefill_chunk", 32),
+        prefill_chunk=spec.get("prefill_chunk"),
         adapter_cache=adapter_cache,
         spill_host_mb=spec.get("kv_spill_host_mb", 0.0) or 0.0,
         spill_watermark_blocks=(
@@ -531,6 +531,9 @@ class ReplicaServer:
                          else 0.0),
             "hist": hist.state() if hist is not None else None,
             "steps": self.steps,
+            # the width of the engine's prefill call (its own choice where
+            # the spec gives none): the router's chunks-avoided arithmetic
+            "prefill_chunk": getattr(eng, "prefill_chunk", None),
         }
 
     def _do_abort(self, msg):
@@ -756,6 +759,7 @@ class _ProcReplica:
     free_slots: int = 1
     pressure: float = 0.0
     hist: Optional[Histogram] = None
+    prefill_chunk: Optional[int] = None    # from its step replies
 
     def attainment(self, slo_ms: Optional[float]) -> float:
         if self.hist is None or slo_ms is None or not self.hist.count:
@@ -1113,14 +1117,19 @@ class ProcessFleetRouter:
         if not seeded:
             return
         p_len = len(prompt)
-        chunk = int(self.spec.get("prefill_chunk", 32))
+        self.router_stats["prefix_store_admission_hits"] += 1
+        # the width of the replica's prefill call: the spec's, or the
+        # engine's own choice as its step replies give it (a replica that
+        # has not stepped yet: the chunks are not counted)
+        chunk = self.spec.get("prefill_chunk") or rep.prefill_chunk
+        if not chunk:
+            return
 
         def chunks_at(blocks_cached: int) -> int:
             cached = min(blocks_cached * block_size, p_len - 1)
             return cdiv(p_len - cached, chunk)
 
         avoided = chunks_at(local) - chunks_at(chain)
-        self.router_stats["prefix_store_admission_hits"] += 1
         self.router_stats["prefill_chunks_avoided"] += avoided
         telemetry.inc("fleet_prefill_chunks_avoided", avoided)
 
@@ -1446,6 +1455,7 @@ class ProcessFleetRouter:
                 rep.active = r["active"]
                 rep.free_slots = r["free_slots"]
                 rep.pressure = r["pressure"]
+                rep.prefill_chunk = r.get("prefill_chunk")
                 if r["hist"] is not None:
                     rep.hist = Histogram.from_state(r["hist"])
                 for key in r["prefix_keys"]:

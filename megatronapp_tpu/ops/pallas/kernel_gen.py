@@ -131,6 +131,10 @@ def default_kv_tile(quant_dtype: Optional[str]):
 WALK_VMEM_BUDGET = 8 * 1024 * 1024
 
 
+def _padded(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
 def _pages_vmem_bytes(pools, kv_tile) -> int:
     """Bytes ONE page of every pool takes in VMEM together. pools: the
     stacked [L, NB, bs, ...] arrays of a call, KV pools (tiled
@@ -141,8 +145,8 @@ def _pages_vmem_bytes(pools, kv_tile) -> int:
     for i, pool in enumerate(pools):
         sub, lane = kv_tile if i < 2 else (8, 128)
         *lead, rows, cols = (1,) + tuple(pool.shape[2:])
-        total += (math.prod(lead) * -(-rows // sub) * sub
-                  * -(-cols // lane) * lane * pool.dtype.itemsize)
+        total += (math.prod(lead) * _padded(rows, sub)
+                  * _padded(cols, lane) * pool.dtype.itemsize)
     return total
 
 
@@ -155,6 +159,111 @@ def pages_per_step(block_size: int, page_bytes: int, table_blocks: int,
     it), never more than the table holds."""
     return max(1, min(128 // block_size, budget // (2 * page_bytes),
                       table_blocks))
+
+
+# Mosaic's default scoped VMEM: what one kernel may hold at a time. A
+# ragged walk's query tile gets what the page blocks leave of it. (Asking
+# Mosaic for more and holding a 256-wide call in one tile was tried: the
+# kernel alone gains 13% over tiles of 64 at 1,280 cached rows, 3% of the
+# call. PERF.md section 6, PR 35.)
+VMEM_SCOPE = 16 * 1024 * 1024
+
+
+def _query_vmem_budget(pools, kv_tile, pages: int) -> int:
+    """What a ragged walk's query tile may take of VMEM: the scope less the
+    step's page blocks, twice (the pipeline's buffers); less one more copy
+    of the key and the value tile as a step computes on them (transposed in
+    the pool's dtype; float32 where the pages are quantized and
+    dequantized in-register); less 1 MiB."""
+    held = 2 * pages * _pages_vmem_bytes(pools, kv_tile)
+    stored = pools[0].dtype.itemsize
+    computed = 4 if len(pools) > 2 else stored
+    copies = pages * _pages_vmem_bytes(pools[:2], kv_tile) // stored * computed
+    return VMEM_SCOPE - held - copies - (1 << 20)
+
+
+def _query_row_vmem_bytes(queries, out_cols: int, key_tile: int) -> int:
+    """Bytes ONE query position of a ragged call takes in VMEM, all its
+    heads together. queries: the call's query arrays [B, S_q, heads, cols]
+    (the lanes hold cols, padded to 128). Counted: each query block and the
+    output block twice (the pipeline's buffers), a float32 copy of the
+    queries, the float32 accumulator, the running max and sum (a
+    lane-padded column each) and a step's float32 scores. Held against what
+    Mosaic asks for at the serving cells' shapes (a search over
+    `vmem_limit_bytes` for a described v5e): 112 KB here for the 104 KB a
+    position it takes at 32 heads of 128, 70 for 47-65 at 20 heads over one
+    key/value head, 120 for 58 at MLA's 16 heads of 512 + 64 columns."""
+    heads = queries[0].shape[2]
+    lanes = sum(_padded(q.shape[3], 128) for q in queries)
+    item = queries[0].dtype.itemsize
+    out = _padded(out_cols, 128)
+    return heads * (2 * item * (lanes + out) + 4 * lanes + 4 * out
+                    + 2 * 4 * 128 + 4 * _padded(key_tile, 128))
+
+
+def _latent_tp_row_vmem_bytes(heads: int, klat_local: int, key_tile: int,
+                              dv: int) -> int:
+    """Bytes ONE query position takes in VMEM, all its heads together, in
+    the latent-column tp path's two kernels (`_latent_block_scores`,
+    `_latent_block_wsum`), whichever holds more, all float32: the scores
+    kernel's query block and output block [., key_tile] twice each (the
+    pipeline's buffers) and the query cast to the page's dtype; the
+    weighted sum's probability block and output twice each, the
+    accumulator, the probabilities regrouped by head and the product
+    before and after it is regrouped."""
+    cols, keys, out = (_padded(n, 128) for n in (klat_local, key_tile, dv))
+    return heads * 4 * max(3 * cols + 2 * keys, 3 * keys + 5 * out)
+
+
+def query_rows_per_step(s_q: int, row_bytes: int, budget: int) -> int:
+    """Query positions of one slot that a step of the ragged walk holds
+    (the query tile), from what the code can see: all `s_q` of them where
+    they fit `budget` (`row_bytes`: one position's, all heads), else the
+    fewest equal tiles that do, each a multiple of 8 rows. No caller sets
+    it, as none sets `pages_per_step`."""
+    fit = max(8, budget // row_bytes // 8 * 8)
+    if s_q <= fit:
+        return s_q
+    return _padded(-(-s_q // -(-s_q // fit)), 8)
+
+
+def _query_tiled(tile: int, page_table, kv_lens, q_lens, queries):
+    """A ragged call whose slots bring more queries than a step holds, as
+    the call the walk takes: slot b's S_q queries become cdiv(S_q, tile)
+    rows of `tile` queries that share b's table row.
+
+    The walk gives every row its own kv_len and q_len already. Row i of
+    slot b holds the slot's queries [i*tile, (i+1)*tile): q_len
+    clip(q_lens[b] - i*tile, 0, tile) of them are real, and the last of
+    those sits at position kv_len - 1 with
+    kv_len = (kv_lens[b] - q_lens[b]) + i*tile + q_len: the slot's own
+    rows are in its pages before the kernel runs, so a tile attends the
+    context and the new tail up to itself, and the causal mask
+    (kv_len - q_len + row) is the kernel's own. A tile past the slot's
+    count has kv_len 0: the walk's one step that holds no row and writes
+    zeros. -> (page_table, kv_lens, q_lens, queries) of the tiled call and
+    the function that gives its output the caller's shape back."""
+    b, s_q = queries[0].shape[:2]
+    if tile >= s_q:
+        return page_table, kv_lens, q_lens, queries, lambda out: out
+    n = -(-s_q // tile)
+    first = jnp.arange(n, dtype=jnp.int32)[None, :] * tile       # [1, n]
+    kv_lens, q_lens = kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32)
+    q_tile = jnp.clip(q_lens[:, None] - first, 0, tile)          # [B, n]
+    kv_tile = jnp.where(
+        q_tile > 0,
+        jnp.maximum((kv_lens - q_lens)[:, None] + first + q_tile, 0), 0)
+
+    def split(q):
+        q = jnp.pad(q, ((0, 0), (0, n * tile - s_q))
+                    + ((0, 0),) * (q.ndim - 2))
+        return q.reshape((b * n, tile) + q.shape[2:])
+
+    def join(out):
+        return out.reshape((b, n * tile) + out.shape[2:])[:, :s_q]
+
+    return (jnp.repeat(page_table, n, axis=0), kv_tile.reshape(-1),
+            q_tile.reshape(-1), [split(q) for q in queries], join)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -517,6 +626,17 @@ def _call_pools(pages, scales, table_blocks: int):
                              table_blocks))
 
 
+def _query_tile(queries, out_cols: int, pools, by_pools) -> int:
+    """The query tile of a ragged call (`query_rows_per_step`) from its
+    shapes: queries [B, S_q, heads, cols], the pools and what
+    `_call_pools` took from them."""
+    pages = by_pools["pages"]
+    return query_rows_per_step(
+        queries[0].shape[1],
+        _query_row_vmem_bytes(queries, out_cols, pages * pools[0].shape[2]),
+        _query_vmem_budget(pools, by_pools["kv_tile"], pages))
+
+
 def _stacked(layer, *pools):
     """(layer id as int32[1], the pools with a leading layer axis).
 
@@ -581,15 +701,21 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     mb = page_table.shape[1]
     pools, by_pools = _call_pools(
         [lat_pages, pe_pages], [lat_scales, pe_scales], mb)
+    queries, join = [q_lat, q_pe], None
+    if ragged:
+        s_q = _query_tile(queries, dv, pools, by_pools)
+        page_table, kv_lens, q_lens, queries, join = _query_tiled(
+            s_q, page_table, kv_lens, q_lens, queries)
     spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
                      num_blocks_seq=mb, hkv=nq, group=1,
                      scale=float(softmax_scale), latent=True, klat=klat,
                      dpe=dpe, dv=dv, **by_pools)
-    return _walk_call(
+    out = _walk_call(
         spec, _paged_name(ragged, spec.quant_dtype, "_latent"), lid,
-        page_table, kv_lens, q_lens, [q_lat, q_pe], pools,
+        page_table, kv_lens, q_lens, queries, pools,
         [w_v.reshape(klat, nq * dv)],
-        q_lat.shape[:-1] + (dv,), (s_q * nq, dv))
+        queries[0].shape[:-1] + (dv,), (s_q * nq, dv))
+    return join(out) if ragged else out
 
 
 def _latent_block_scores(q, pages, page_table, kv_lens, lid, scales=None,
@@ -764,15 +890,31 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
 
     ragged = q_lens is not None
     family = _paged_name(ragged)
+    dv = w_v.shape[-1]
+    bs = lat_pages.shape[2]
+    join = None
     if ragged:
+        # The two kernels hold a row's whole query block, as the walk
+        # does: a slot's queries go in as rows of one query tile
+        # (`_query_tiled`; the mask below is l_ - qlens_ + row, a row's
+        # own). Beside a tile VMEM holds a shard's rows of w_v twice (the
+        # pipeline's buffers) and a page's values before and after they
+        # are regrouped by head; 1 MiB is left over.
+        tp = mesh.shape[TP_AXIS]
+        nq = q_lat.shape[2]
+        tile = query_rows_per_step(
+            q_lat.shape[1],
+            _latent_tp_row_vmem_bytes(nq, -(-q_lat.shape[3] // tp), bs, dv),
+            VMEM_SCOPE - 2 * w_v.size // tp * w_v.dtype.itemsize
+            - 2 * 4 * bs * nq * _padded(dv, 128) - (1 << 20))
+        page_table, kv_lens, q_lens, (q_lat, q_pe), join = _query_tiled(
+            tile, page_table, kv_lens, q_lens, [q_lat, q_pe])
         b, s_q, nq, klat = q_lat.shape
     else:
         b, nq, klat = q_lat.shape
         s_q = 1
-    dv = w_v.shape[-1]
     rows = s_q * nq
     mb = page_table.shape[1]
-    bs = lat_pages.shape[2]
     quantized = lat_scales is not None
     out_dtype = q_lat.dtype
 
@@ -838,8 +980,9 @@ def _tp_place_latent(q_lat, q_pe, lat_pages, pe_pages, page_table,
     # manual-ok: full-manual kernel placement; the only collectives are
     # the two psums over the klat shards. tp_paged_eligible callers
     # gate on no ambient manual axes.
-    return shard_map_compat(body, mesh, in_specs=tuple(in_specs),
-                            out_specs=out_sh)(*operands)
+    out = shard_map_compat(body, mesh, in_specs=tuple(in_specs),
+                           out_specs=out_sh)(*operands)
+    return join(out) if ragged else out
 
 
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -880,12 +1023,18 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         softmax_scale = 1.0 / (d ** 0.5)
     pools, by_pools = _call_pools(
         [k_pages, v_pages], [k_scales, v_scales], mb)
+    join = None
+    if ragged:
+        s_q = _query_tile([q], d, pools, by_pools)
+        page_table, kv_lens, q_lens, (q,), join = _query_tiled(
+            s_q, page_table, kv_lens, q_lens, [q])
     spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
                      num_blocks_seq=mb, hkv=hkv, group=hq // hkv,
                      scale=float(softmax_scale), **by_pools)
-    return _walk_call(spec, _paged_name(ragged, spec.quant_dtype), lid,
-                      page_table, kv_lens, q_lens, [q], pools, [], q.shape,
-                      (hkv, s_q * (hq // hkv), d))
+    out = _walk_call(spec, _paged_name(ragged, spec.quant_dtype), lid,
+                     page_table, kv_lens, q_lens, [q], pools, [], q.shape,
+                     (hkv, s_q * (hq // hkv), d))
+    return join(out) if ragged else out
 
 
 def _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,

@@ -10,8 +10,9 @@ each through an RMS norm of its own); the recurrence
 
 over a diagonal A; gate by silu(z); out_proj. The reference leans on Triton
 kernels for the scan; here a whole sequence (training, a prefill chunk) is
-a `lax.associative_scan` (the first-order recurrence is associative, so
-XLA lowers it to a log-depth parallel scan), and a decode step is one
+a `lax.associative_scan` a block of positions (the first-order recurrence
+is associative, so XLA lowers it to a log-depth parallel scan), the blocks
+in sequence, and a decode step is one
 Pallas call that updates the state in place (ops/pallas/ssm_update.py).
 
 The state is h [B, N, E] float32, E (the expanded width) minor: E is a
@@ -102,12 +103,20 @@ def init_ssm_params(rng, cfg: TransformerConfig, dims: SsmDims,
     return p, ax
 
 
-def selective_scan(u, dt, a_t, b, c, d, h0=None):
-    """u, dt [B,S,E]; a_t [N,E] (A transposed); b, c [B,S,N]; d [E];
-    h0 [B,N,E] or None (zeros) → (y [B,S,E], h_S [B,N,E]).
+# Positions one parallel scan holds. A longer sequence is scanned block
+# after block, the state carried between them: lax.associative_scan makes
+# log2(S) passes over [S, N, E] float32 operands, and what it costs a
+# position depends on where XLA keeps them. Read off a v5e at [., 16, 5120]
+# (21 MB an operand at 64 positions): one scan costs 0.05 ms a position of a
+# 26-layer prefill call up to 64 positions and 0.3 ms from 96 on; compiled
+# for that chip, a [1, 256] call in blocks of 64 (or 32) holds 20 MB of
+# temporaries in HBM and one in blocks of 128 holds 120 MB; on the chip,
+# blocks of 32 and of 64 serve alike (PERF.md section 6, PR 35).
+SCAN_BLOCK = 64
 
-    A parallel associative scan over the sequence axis. A position whose
-    dt is 0 leaves the state as it was (exp(0) = 1 and a zero input)."""
+
+def _scan_block(u, dt, a_t, b, c, d, h0=None):
+    """selective_scan over one block: a parallel associative scan."""
     a = jnp.exp(dt[:, :, None, :] * a_t[None, None])          # [B,S,N,E]
     x = dt[:, :, None, :] * b[..., None] * u[:, :, None, :]   # [B,S,N,E]
     if h0 is not None:
@@ -121,6 +130,35 @@ def selective_scan(u, dt, a_t, b, c, d, h0=None):
     _, h = jax.lax.associative_scan(combine, (a, x), axis=1)
     y = jnp.einsum("bsne,bsn->bse", h, c) + u * d[None, None]
     return y, h[:, -1]
+
+
+def selective_scan(u, dt, a_t, b, c, d, h0=None):
+    """u, dt [B,S,E]; a_t [N,E] (A transposed); b, c [B,S,N]; d [E];
+    h0 [B,N,E] or None (zeros) → (y [B,S,E], h_S [B,N,E]).
+
+    A parallel associative scan over blocks of SCAN_BLOCK positions, the
+    blocks one after the other (S up to a block: one scan, no loop). A
+    position whose dt is 0 leaves the state as it was (exp(0) = 1 and a
+    zero input): so does the padding of the last block."""
+    bsz, s, e = u.shape
+    if s <= SCAN_BLOCK:
+        return _scan_block(u, dt, a_t, b, c, d, h0)
+    blocks = -(-s // SCAN_BLOCK)
+
+    def split(t):                           # [B,S,.] → [blocks,B,block,.]
+        t = jnp.pad(t, ((0, 0), (0, blocks * SCAN_BLOCK - s), (0, 0)))
+        return jnp.swapaxes(
+            t.reshape(bsz, blocks, SCAN_BLOCK, t.shape[-1]), 0, 1)
+
+    def step(h, xs):
+        y, h = _scan_block(*xs[:2], a_t, *xs[2:], d, h)
+        return h, y
+
+    if h0 is None:
+        h0 = jnp.zeros((bsz,) + a_t.shape,
+                       jnp.result_type(u, dt, a_t, b))
+    h, y = jax.lax.scan(step, h0, tuple(map(split, (u, dt, b, c))))
+    return jnp.swapaxes(y, 0, 1).reshape(bsz, -1, e)[:, :s], h
 
 
 def _plain_update(h, dt, u, b, c, a_t, d):

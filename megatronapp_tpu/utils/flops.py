@@ -26,6 +26,28 @@ TPU_PEAK_FLOPS = {
     "v6 lite": 918e12,
 }
 
+# HBM bytes/s per chip, by the same keys: with the peak above, the flops a
+# byte of a streamed operand has to pay for before the stream is hidden.
+TPU_HBM_BYTES_PER_S = {
+    "v5litepod": 819e9,
+    "v5 lite": 819e9,
+    "v5e": 819e9,
+    "v5p": 2765e9,
+    "v4": 1228e9,
+    "v6e": 1640e9,
+    "v6 lite": 1640e9,
+}
+
+
+def tpu_roofs(device_kind: str):
+    """(peak bf16 flop/s, HBM bytes/s) of a device kind, or None for a
+    device the tables do not know (a CPU)."""
+    kind = device_kind.lower()
+    for key, peak in TPU_PEAK_FLOPS.items():
+        if key in kind:
+            return peak, TPU_HBM_BYTES_PER_S[key]
+    return None
+
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     """Forward+backward FLOPs per token (3x forward matmul FLOPs)."""

@@ -101,6 +101,11 @@ PAGED_SHAPES = {
     # serve.gpt3-2.7b.batch-closed: D 80 (padded to 128 lanes), 24 slots,
     # 2048 positions
     "gpt3-2.7b": (24, 32, 32, 80, 896, 128),
+    # serve.evabyte-6.5b.bytegen-closed: 32 key/value heads of 128, the most
+    # VMEM a query and a page take in any cell; a two-region table
+    "evabyte-6.5b": (32, 32, 32, 128, 3584, 192),
+    # serve.jamba2-3b.chat-closed: 20 query heads over one key/value head
+    "jamba2-3b": (128, 20, 1, 128, 16384, 128),
 }
 LATENT_SHAPES = {
     # serve.deepseek-v2-lite.longgen-closed: rows of 512 + 64 columns, 32
@@ -109,11 +114,15 @@ LATENT_SHAPES = {
 }
 
 
-@pytest.mark.parametrize("q_len", [1, 32], ids=["decode", "multiquery-32"])
+@pytest.mark.parametrize("q_len", [1, 32, 512],
+                         ids=["decode", "multiquery-32", "multiquery-512"])
 @pytest.mark.parametrize("shape", list(PAGED_SHAPES) + list(LATENT_SHAPES))
 def test_paged_attention(one_chip, chip_compile, shape, q_len):
     """The paged kernels at the cells' shapes, bf16 pools of blocks of 16:
-    one query a slot, and the [1, 32] ragged call of a chunked prefill."""
+    one query a slot, the [1, 32] ragged call of a speculative verify or a
+    narrow prefill, and a [1, 512] call, which no step could hold whole
+    (ISSUE 35: ~100 KB of VMEM a query at 32 heads of 128 lanes) and the
+    entry points cut into query tiles."""
     from megatronapp_tpu.ops.pallas import kernel_gen as kg
     from megatronapp_tpu.ops.pallas.paged_attention import (
         paged_attention_decode, paged_attention_multiquery,
@@ -144,6 +153,46 @@ def test_paged_attention(one_chip, chip_compile, shape, q_len):
                     i32((b,)), i32((b,)))
     compiled = jax.jit(fn).lower(*args).compile()
     assert _custom_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("q_len", [1, "chosen"],
+                         ids=["decode", "multiquery-chosen-width"])
+def test_paged_attention_latent_tp2(topo, one_chip, chip_compile, q_len):
+    """The latent-column tp placement (`--serve-tp` on an MLA model: block
+    scores, a replicated softmax, the weighted sum, two Pallas calls around
+    two psums) at DeepSeek-V2-Lite's widths over a tp 2 mesh: one query a
+    slot, and a prefill call of the width the engine chooses for the model
+    on this chip. Its kernels hold a row's whole query block, so the call's
+    queries go in as query tiles (ISSUE 35; in one block a [1, 1024] call
+    asks Mosaic for 24 MiB). Blocks of 128 rows: the kernels' score block
+    is [rows, block], and Mosaic takes no 16-lane block. The kernels and
+    not the engine's step: an engine puts its pools on its mesh when it is
+    built, which a described mesh cannot hold."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from megatronapp_tpu.ops.pallas import kernel_gen as kg
+    from megatronapp_tpu.parallel.mesh import build_mesh
+    if q_len == "chosen":
+        q_len = _cell_prefill_width(one_chip, "deepseek-v2-lite", 4096,
+                                    num_layers=9)
+        assert q_len == 1024
+    ctx = build_mesh(ParallelConfig(tensor_parallel=2),
+                     devices=topo.devices[:2])
+    everywhere = NamedSharding(ctx.mesh, P())
+    bf16 = functools.partial(_sds, dtype=jnp.bfloat16, sharding=everywhere)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=everywhere)
+    b, nq, klat, dpe, dv, _, _ = LATENT_SHAPES["deepseek-v2-lite"]
+    bs, nb, mb = 128, 1024, 32
+    b = b if q_len == 1 else 1
+    lead = (b,) if q_len == 1 else (b, q_len)
+    args = (bf16(lead + (nq, klat)), bf16(lead + (nq, dpe)),
+            bf16((nb, bs, klat)), bf16((nb, bs, dpe)), i32((b, mb)),
+            i32((b,)), bf16((klat, nq, dv)))
+    args += () if q_len == 1 else (i32((b,)),)
+    compiled = jax.jit(functools.partial(
+        kg.paged_attention_latent, softmax_scale=(128 + dpe) ** -0.5,
+        mesh=ctx.mesh)).lower(*args).compile()
+    assert _custom_calls(compiled) == 3        # scores twice, the sum
+    assert "all-reduce" in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -333,14 +382,28 @@ def test_engine_paged_steps(one_chip, chip_compile, which, kv):
         compiled, r"copy|dynamic[-_](update[-_])?slice", kv_shapes)
 
 
+def _cell_prefill_width(one_chip, model, seq, **cut):
+    """The width `choose_prefill_width` gives a serving cell on the
+    described chip: from the cell's own weights (its cut of the depth, bf16,
+    as abstract values) and its max_seq_len."""
+    from megatronapp_tpu.inference.dynamic_engine import choose_prefill_width
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    cfg = PRESETS[model](params_dtype=jnp.bfloat16, **cut)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    kind = next(iter(one_chip.device_set)).device_kind
+    return choose_prefill_width(cfg, abstract, seq, 16, device_kind=kind)
+
+
 @pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
-@pytest.mark.parametrize("model,batch,blocks,seq", [
-    ("gpt3-2.7b", 24, 896, 2048),
-    ("deepseek-v2-lite", 32, 8192, 4096),
-    ("evabyte-6.5b", 32, 3584, 16384),
+@pytest.mark.parametrize("model,batch,blocks,seq,cut,width", [
+    ("gpt3-2.7b", 24, 896, 2048, {}, 256),
+    ("deepseek-v2-lite", 32, 8192, 4096, {"num_layers": 9}, 1024),
+    ("evabyte-6.5b", 32, 3584, 16384, {"num_layers": 8}, 256),
 ])
 def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
-                                           batch, blocks, seq, which):
+                                           batch, blocks, seq, cut, width,
+                                           which):
     """The same two jits at the serving cells' attention shapes (a small
     vocabulary; depth cut to 2, and for DeepSeek-V2-Lite to 1 dense + 2 MoE
     layers of 8 experts at the published widths, so that the layer loop
@@ -355,10 +418,18 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     fc1/fc2 stacks in place through the layer id (ISSUE 31): nothing
     sliced or copied has the shape of one layer's experts, and the step's
     temporaries are smaller than one layer's fc1 kernel (the parent held
-    one: `dynamic-slice_bitcast_fusion`, 94.5 MB of temporaries here)."""
+    one: `dynamic-slice_bitcast_fusion`, 94.5 MB of temporaries here).
+
+    The prefill call is as wide as the engine makes it on this chip for the
+    cell's configuration (ISSUE 35; the width is chosen from the cell's own
+    weights, the step compiled at the cut depth): 256 for the dense bf16
+    models (a v5e's 240 flops a byte); 1024 where a position computes on 6
+    of 64 experts of what the call streams. Its ragged kernel, cut into
+    query tiles, fits Mosaic's VMEM, and its head runs on one position."""
     from megatronapp_tpu.inference.dynamic_engine import (
         DynamicInferenceEngine,
     )
+    assert _cell_prefill_width(one_chip, model, seq, **cut) == width
     from megatronapp_tpu.models.gpt import init_gpt_params
     over = dict(num_layers=2, vocab_size=1024)
     if model == "deepseek-v2-lite":
@@ -374,7 +445,8 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
     eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
-                                 max_seq_len=seq, paged=True, num_blocks=8)
+                                 max_seq_len=seq, paged=True, num_blocks=8,
+                                 prefill_chunk=width)
 
     def spec(a):
         return _sds(a.shape, a.dtype, one_chip)
@@ -395,7 +467,13 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     else:
         compiled = eng._mq_step.lower(
             p, i32(1, eng.prefill_chunk), pages, None, i32(1, mb), i32(1),
-            i32(1), _sds((1,), jnp.bool_, one_chip), None).compile()
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, None,
+            i32(1)).compile()
+        # the head ran on one position: no [1, width, columns] logits
+        columns = (abstract["output"].shape[1] if "output" in abstract
+                   else abstract["embedding"]["word"].shape[0])
+        assert columns != cfg.hidden_size
+        assert not _pool_shaped(compiled, r".", [(1, width, columns)])
     family = "paged_decode" if which == "decode" else "paged_mq"
     _assert_kernels_named(
         compiled, family + ("_latent" if cfg.multi_latent_attention else ""),
@@ -419,22 +497,30 @@ def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
     """The two jits for a hybrid state-space stack at Jamba2-3B's published
     widths and the chat cell's sizes (a small vocabulary; 6 layers of which
     1 and 4 attend, so that both kinds of run are loops): 128 slots of
-    h [16, 5120] float32 a layer, one key/value head of 128. Mosaic takes
-    `ssm_update` and the one-head pools; the step aliases the page pools
-    and the state pools alike, copies nothing of the state pools' shape or
-    one plane's, and holds less in temporaries than one layer's states."""
+    h [16, 5120] float32 a layer, one key/value head of 128, a prefill call
+    of the 256 positions the engine chooses for the cell on this chip.
+    Mosaic takes `ssm_update` and the one-head pools; the step aliases the
+    page pools and the state pools alike, copies nothing of the state
+    pools' shape or one plane's, and holds less in temporaries than one
+    layer's states: the chunk scan runs block after block
+    (`transformer/ssm.SCAN_BLOCK`), and a block's operands,
+    [1, 64, 16, 5120] float32 each, are no temporaries in HBM (20 MB in all
+    at 256 positions; one scan over 128 positions holds 120 MB there)."""
     from megatronapp_tpu.inference.dynamic_engine import (
         DynamicInferenceEngine,
     )
     from megatronapp_tpu.models.gpt import init_gpt_params
     batch, blocks, seq = 128, 16384, 2048
+    width = _cell_prefill_width(one_chip, "jamba2-3b", seq)
+    assert width == 256
     cfg = PRESETS["jamba2-3b"](num_layers=6, attn_layer_period=3,
                                attn_layer_offset=1, vocab_size=1024,
                                params_dtype=jnp.bfloat16)
     abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
     eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
-                                 max_seq_len=seq, paged=True, num_blocks=8)
+                                 max_seq_len=seq, paged=True, num_blocks=8,
+                                 prefill_chunk=width)
     ssm, conv = eng.pool.state
     assert ssm.shape == (4, 128, 16, 5120) and ssm.dtype == jnp.float32
     pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
@@ -455,7 +541,8 @@ def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
     else:
         compiled = eng._mq_step.lower(
             p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
-            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1)).compile()
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1),
+            i32(1)).compile()
         _assert_kernels_named(compiled, "paged_mq")
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize

@@ -248,16 +248,38 @@ class TestEngine:
         assert disp["expert_stack_slices"] == slices, disp
         assert disp["kernels"] == cfg.num_layers    # the latent kernel
 
-    @pytest.mark.parametrize("n,seed", [(10, 4), (17, 5)])
-    def test_streams_equal_the_dense_oracle(self, n, seed):
+    @pytest.mark.parametrize("n,seed,chunk", [
+        (10, 4, 8), (17, 5, 8), (45, 6, 32), (45, 6, None), (45, 6, 7)],
+        ids=["10-in-8s", "17-in-8s", "45-in-32", "45-in-a-v5e's",
+             "45-in-7s"])
+    def test_streams_equal_the_dense_oracle(self, monkeypatch, n, seed,
+                                            chunk):
         """The greedy stream of the two paged steps (experts read through
         the layer id) is that of gpt_forward over the whole sequence (the
         training scan: per-layer kernels), in float32. The steps are
         driven directly: a fresh engine on the CPU now and then leaves
-        the oracle whatever the model (ROADMAP S3)."""
+        the oracle whatever the model (ROADMAP S3). Whatever the width of
+        the prefill call (ISSUE 35): 8, 32 (every engine's until then; the
+        latent kernel here cuts the call into query tiles of 8 under a
+        small VMEM budget), what the engine chooses for these shapes on a
+        v5e and 7, which divides no prompt."""
+        from megatronapp_tpu.inference.dynamic_engine import (
+            choose_prefill_width,
+        )
+        from megatronapp_tpu.ops.pallas import kernel_gen
         cfg, params = _model()
+        if chunk is None:
+            # float32 weights, 2 of 4 experts a position: over 500 flops
+            # a byte; held to a max_seq_len
+            chunk = choose_prefill_width(cfg, params, 16, 4,
+                                         device_kind="TPU v5 lite")
+            assert chunk == 16
+        if chunk == 32:
+            monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
+                                lambda *a: 100_000)
         prompt = _tokens((n,), seed)
-        seq, _, _, _ = _prefill_then_decode(cfg, params, prompt, 6)
+        seq, _, _, _ = _prefill_then_decode(cfg, params, prompt, 6,
+                                            chunk=chunk)
         toks = np.asarray(prompt)[None]
         for _ in range(6):
             logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)

@@ -84,12 +84,16 @@ def _recorded(eng):
     mq, dec = eng._mq_step, eng._decode
 
     def mq_step(*a):
-        out = mq(*a)
+        # the engine asks for its last position's logits alone (a[10]);
+        # take every position's, and hand it the one it asked for
+        logits_all, hid, pools = mq(*a[:10])
+        last = int(a[10][0])
+        out = (logits_all[:, last:last + 1], hid[:, last:last + 1], pools)
         rid = next(r.request_id for r in eng.slots if r is not None
                    and np.array_equal(eng.pool.page_table[r.slot],
                                       np.asarray(a[4][0])))
         logits.setdefault(rid, []).append(
-            np.asarray(out[0][0, :int(a[6][0])], np.float32))
+            np.asarray(logits_all[0, :int(a[6][0])], np.float32))
         return out
 
     def decode(*a):
@@ -293,6 +297,41 @@ class TestEngine:
         assert eng.pool.eva_stats["windows_closed"] - closed == 148 // W
         eng.pool.audit()
         assert eng.pool.blocks_in_use() == 0
+
+    @pytest.mark.parametrize("width", [32, None],
+                             ids=["a-window", "chosen-for-a-v5e"])
+    def test_a_wider_call_stops_at_a_window_edge(self, monkeypatch, width):
+        """ISSUE 35: a prompt of two windows and 7 bytes in calls of a whole
+        window (32: more would straddle an edge) and of the width the
+        engine chooses for these shapes on a v5e under a max_seq_len of 16
+        (with more room it is the window): neither divides it, each call stops at its window's
+        edge, pools the chunks it fills and is cut into query tiles of 8 by
+        the ragged kernel; the logits at every position are the
+        reference's."""
+        from megatronapp_tpu.inference.dynamic_engine import (
+            choose_prefill_width,
+        )
+        from megatronapp_tpu.ops.pallas import kernel_gen
+        cfg, params = _model()
+        if width is None:
+            kind = "TPU v5 lite"
+            assert choose_prefill_width(cfg, params, 192, 4,
+                                        device_kind=kind) == W
+            width = choose_prefill_width(cfg, params, 16, 4,
+                                         device_kind=kind)
+            assert width == 16
+        monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
+                            lambda *a: 100_000)
+        eng = _engine(cfg, params, prefill_chunk=width)
+        logits = _recorded(eng)
+        n = 2 * W + 7
+        req = eng.requests[eng.add_request(_tokens(n, n), 100 - n, GREEDY)]
+        eng.run_to_completion()
+        assert _worst_gap(params, req, logits) < TOL
+        assert eng.pool.eva_stats["windows_closed"] == 98 // W
+        pre = eng.stats_snapshot()["prefill"]
+        assert pre["calls"] == 2 * (W // width) + 1 and pre["tokens"] == n
+        eng.pool.audit()
 
     def test_four_slots_in_four_windows_share_a_round(self, served):
         params, eng, logits = served

@@ -302,11 +302,15 @@ class TestShmRing:
             target=_producer_proc, args=(name, arrs))
         proc.start()
         got = []
-        deadline = time.time() + 30
+        # the fresh interpreter imports this module (JAX and the training
+        # stack) before it pushes: over 30 s beside five busy xdist workers
+        deadline = time.time() + 120
         while len(got) < len(arrs) and time.time() < deadline:
             out = ring.pop_array()
             if out is not None:
                 got.append(out)
+            else:
+                time.sleep(0.001)
         proc.join(timeout=10)
         ring.close()
         ring.unlink()

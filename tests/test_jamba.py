@@ -74,10 +74,14 @@ def _recorded(eng):
     mq, dec = eng._mq_step, eng._decode
 
     def mq_step(*a):
-        out = mq(*a)
+        # the engine asks for its last position's logits alone (a[10]);
+        # take every position's, and hand it the one it asked for
+        logits_all, hid, pools = mq(*a[:10])
+        last = int(a[10][0])
+        out = (logits_all[:, last:last + 1], hid[:, last:last + 1], pools)
         slot = int(a[9][0])
         logits.setdefault(eng.slots[slot].request_id, []).append(
-            np.asarray(out[0][0, :int(a[6][0])], np.float32))
+            np.asarray(logits_all[0, :int(a[6][0])], np.float32))
         return out
 
     def decode(*a):
@@ -168,21 +172,58 @@ class TestForward:
 
 
 class TestEngine:
-    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, TOL_F32),
-                                           (jnp.bfloat16, TOL_BF16)])
-    def test_chunked_prefill_then_decode(self, dtype, tol):
+    @pytest.mark.parametrize("dtype,tol,width,n", [
+        (jnp.float32, TOL_F32, 8, 18), (jnp.bfloat16, TOL_BF16, 8, 18),
+        (jnp.float32, TOL_F32, 32, 34), (jnp.float32, TOL_F32, None, 0),
+        (jnp.float32, TOL_F32, 7, 16)],
+        ids=["float32-8", "bfloat16-8", "float32-32", "float32-a-v5e's",
+             "float32-odd-7"])
+    def test_chunked_prefill_then_decode(self, dtype, tol, width, n):
         """18 tokens in chunks of 8: two full calls and one of 2, under 3
         past the chunk's edge, so its convolution tail reaches back into
-        the call before; then 12 decode rounds through ssm_update."""
+        the call before and h crosses the edge; then 12 decode rounds
+        through ssm_update. The same 2 past the edge of a call of 32
+        (every engine's width before ISSUE 35), of the width the engine
+        chooses for these shapes on a v5e, and of 7, which divides no
+        prompt: the logits at every position are the reference's whatever
+        the width."""
+        from megatronapp_tpu.inference.dynamic_engine import (
+            choose_prefill_width,
+        )
         cfg, params = _model(dtype)
-        eng = _engine(cfg, params)
+        if width is None:
+            # float32 weights: 481 flops a byte; held to a max_seq_len
+            width = choose_prefill_width(cfg, params, 16, 4,
+                                         device_kind="TPU v5 lite")
+            assert width == 16
+            n = width + 2
+        assert n % width == 2
+        eng = _engine(cfg, params, prefill_chunk=width)
         logits = _recorded(eng)
-        req = eng.requests[eng.add_request(_tokens(18, 4), 13, GREEDY)]
+        req = eng.requests[eng.add_request(_tokens(n, 4), 13, GREEDY)]
         eng.run_to_completion()
         assert _worst_gap(params, req, logits) < tol
         state = eng.stats_snapshot()["state"]
         assert state["resets"] == 1 and state["dropped"] == 0
-        assert state["prefill_scans"] == 3 * cfg.num_ssm_layers
+        calls = -(-n // width)
+        assert state["prefill_scans"] == calls * cfg.num_ssm_layers
+        assert eng.stats_snapshot()["prefill"] == {
+            "calls": calls, "tokens": n, "width": width,
+            "fill_share": round(n / (calls * width), 4)}
+
+    def test_chunk_scan_in_blocks(self, monkeypatch):
+        """A call wider than the scan's block (ISSUE 35: a prefill call of
+        256 positions over blocks of 64) scans block after block, h carried
+        between them and the last block padded: calls of 8 over blocks of
+        3, 2 past a call's edge."""
+        from megatronapp_tpu.transformer import ssm
+        monkeypatch.setattr(ssm, "SCAN_BLOCK", 3)
+        cfg, params = _model()
+        eng = _engine(cfg, params, prefill_chunk=8)
+        logits = _recorded(eng)
+        req = eng.requests[eng.add_request(_tokens(18, 4), 13, GREEDY)]
+        eng.run_to_completion()
+        assert _worst_gap(params, req, logits) < TOL_F32
 
     def test_continuous_batching_and_slot_reuse(self):
         """Requests of different lengths admitted at different steps; the

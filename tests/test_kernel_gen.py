@@ -33,6 +33,7 @@ from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
 from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.ops.pallas import kernel_gen
 from megatronapp_tpu.ops.pallas.kernel_gen import (
     _NEG_INF, _dequant_block, _interpret, _pages_vmem_bytes,
     default_kv_tile, paged_attention, paged_attention_latent,
@@ -830,20 +831,25 @@ _WALK_POOLS = {"fp32": (jnp.float32, None), "bf16": (jnp.bfloat16, None),
                "fp8": (jnp.float32, jnp.float8_e4m3fn)}
 
 
-def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29):
+def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
+               s_q=_W_SQ, q_lens=None):
     """(kernel output, oracle output or None) of `body` over slots of
     `lens` cached rows at the walk shapes, pages stored as `pool` (int8
-    and fp8 pages come with their fp32 scale pools). nan_past: every
+    and fp8 pages come with their fp32 scale pools). A ragged body's slots
+    bring `s_q` query rows, of which `q_lens` are real (by default as many
+    as the slot's length allows). nan_past: every
     table entry past a slot's length names a page filled with NaN, and a
     scale page filled with NaN (an int8 page cannot hold one: there the
     scale page alone carries it); no oracle then: the oracles gather the
     whole table."""
     rng = np.random.default_rng(seed)
     b, ragged, latent = len(lens), "_mq" in body, "latent" in body
-    s_q = _W_SQ if ragged else 0
+    s_q = s_q if ragged else 0
     dtype, qdtype = _WALK_POOLS[pool]
     kv_lens = jnp.asarray(lens, jnp.int32)
-    q_lens = jnp.minimum(kv_lens, _W_SQ) if ragged else None
+    if ragged:
+        q_lens = (jnp.minimum(kv_lens, s_q) if q_lens is None
+                  else jnp.asarray(q_lens, jnp.int32))
     if latent:
         ql, qp, lat, pe, w_v, tbl, _, _, _ = _mk_latent_inputs(
             rng, b, s_q, 4, 32, 8, 16, _W_BS, _W_MB, False, dtype)
@@ -891,8 +897,8 @@ def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29):
     if ragged:
         # rows past a slot's q_len are padding: whatever they hold is
         # dropped by the caller
-        keep = (jnp.arange(_W_SQ)[None, :] < q_lens[:, None])[..., None,
-                                                              None]
+        keep = (jnp.arange(s_q)[None, :] < q_lens[:, None])[..., None,
+                                                            None]
         out = jnp.where(keep, out, 0.0)
         ref = None if ref is None else jnp.where(keep, ref, 0.0)
     return out, ref

@@ -384,6 +384,51 @@ class TestStepSpansAndCounters:
             "blocks_table": sum(kw["batch"] for kw, _ in rounds) * table}
         assert 0 < paged["blocks_live"] <= paged["blocks_table"]
 
+    @pytest.mark.parametrize("width", [None, 8, 5])
+    def test_prefill_call_counters(self, monkeypatch, width):
+        """ISSUE 35: every `mta.engine.prefill_call` names the call's
+        `width` beside its real `tokens`, and `stats_snapshot()["prefill"]`
+        counts calls, tokens, width and `fill_share` = tokens / (calls x
+        width) exactly, for prompts of 3, 8, 13 and 21 tokens: at the
+        engine's own choice (32 on a CPU), at 8 and at 5."""
+        cfg = _gqa_cfg()
+        params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
+        kw = {} if width is None else {"prefill_chunk": width}
+        eng = DynamicInferenceEngine(
+            params, cfg, max_batch=4, max_seq_len=48, paged=True,
+            block_size=8, enable_prefix_caching=False, **kw)
+        width = width or 32
+        assert eng.prefill_chunk == width
+        assert eng.stats_snapshot()["prefill"] == {
+            "calls": 0, "tokens": 0, "width": width, "fill_share": 0.0}
+        calls = []
+        span = eng._span
+
+        def spy(name, *a, **kw):
+            if name == "engine.prefill_call":
+                calls.append(kw)
+            return span(name, *a, **kw)
+
+        monkeypatch.setattr(eng, "_span", spy)
+        prompts = (3, 8, 13, 21)
+        rng = np.random.default_rng(1)
+        for n in prompts:
+            eng.add_request(rng.integers(0, 128, n).astype(np.int32), 2,
+                            SamplingParams(greedy=True))
+        eng.run_to_completion()
+        want = sum(-(-n // width) for n in prompts)
+        assert len(calls) == want
+        assert all(c["width"] == width and 1 <= c["tokens"] <= width
+                   for c in calls)
+        assert sum(c["tokens"] for c in calls) == sum(prompts)
+        assert eng.stats_snapshot()["prefill"] == {
+            "calls": want, "tokens": 45, "width": width,
+            "fill_share": round(45 / (want * width), 4)}
+        # a dense-cache engine makes no such calls
+        dense = DynamicInferenceEngine(params, cfg, max_batch=2,
+                                       max_seq_len=48)
+        assert dense.stats_snapshot()["prefill"] is False
+
     def test_step_counters(self):
         eng = _pressure_engine()
         calls, admitting, prompt_tokens = 0, set(), 0
@@ -582,6 +627,8 @@ class TestServerEndpoints:
         assert steps["queue_wait"]["count"] == 1
         assert steps["decode_round"]["count"] == 3
         assert stats["pool"]["prefill_tokens"] == 3
+        assert stats["prefill"] == {"calls": 1, "tokens": 3, "width": 32,
+                                    "fill_share": round(3 / 32, 4)}
         assert stats["driver_deliver"]["count"] >= 1
         assert health["stepper"]["deliver"]["count"] \
             == stats["driver_deliver"]["count"]

@@ -1,0 +1,211 @@
+"""ISSUE 35: the ragged paged kernels cut a call's queries into query tiles
+(ops/pallas/kernel_gen.py: `query_rows_per_step`, `_query_tiled`), and the
+engine's streams are the dense oracle's whatever the width of its prefill
+call. A file of its own beside tests/test_kernel_gen.py, whose helpers it
+uses: pytest-xdist hands out whole files, and that one is the longest."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import test_kernel_gen as base
+from megatronapp_tpu.config.parallel_config import ParallelConfig
+from megatronapp_tpu.ops.pallas import kernel_gen
+from megatronapp_tpu.ops.pallas.kernel_gen import paged_attention_latent
+from megatronapp_tpu.parallel.mesh import build_mesh
+
+
+class TestQueryTiles:
+    """ISSUE 35: a ragged call whose slots bring more queries than a step
+    of the walk can hold (a prefill call as wide as its weight stream pays
+    for) is cut into query tiles by the entry points, each a row of the
+    walk with its own kv_len and q_len over the slot's table row. Against
+    the gather-everything oracles, and against the same call in one
+    tile."""
+
+    S_Q = 40
+    # (cached rows, real queries) a slot: a full call over a long context;
+    # one that ends inside the second tile, with no context before it; one
+    # that ends on a tile's edge; one query; one inside the first tile; a
+    # slot that holds nothing
+    SLOTS = [(200 + 40, 40), (21, 21), (150 + 16, 16), (1, 1), (97, 7),
+             (0, 0)]
+
+    @pytest.fixture
+    def tiles(self, monkeypatch):
+        """A VMEM budget under which a step holds 16 to 24 of these
+        queries (the real `query_rows_per_step` picks); -> the tiles the
+        calls under it were cut into."""
+        seen = []
+        cut = kernel_gen._query_tiled
+
+        def spy(tile, *a):
+            seen.append(tile)
+            return cut(tile, *a)
+
+        monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
+                            lambda *a: 400_000)
+        monkeypatch.setattr(kernel_gen, "_query_tiled", spy)
+        return seen
+
+    def _case(self, body, pool):
+        lens, q_lens = zip(*self.SLOTS)
+        return base._walk_case(body, list(lens), pool=pool, s_q=self.S_Q,
+                          q_lens=list(q_lens))
+
+    @pytest.mark.parametrize("pool", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("body", ["paged_mq", "paged_mq_latent"])
+    def test_tiled_call_matches_the_oracle(self, tiles, body, pool):
+        out, ref = self._case(body, pool)
+        assert tiles and 8 <= tiles[-1] < self.S_Q and tiles[-1] % 8 == 0
+        assert bool(jnp.all(out[-1] == 0.0))       # the slot with no row
+        base._assert_close(out, ref, **base.TestWalk()._tol(body, pool))
+
+    @pytest.mark.parametrize("body", ["paged_mq", "paged_mq_latent"])
+    def test_tiles_change_no_bit(self, monkeypatch, body):
+        """A query row folds the same key tiles in the same order whatever
+        tile of queries it sits in (the tiles past its own position are
+        masked whole and leave max, sum and accumulator as they were): the
+        tiled call's real rows equal the one-tile call's bit for bit."""
+        whole, _ = self._case(body, "fp32")
+        monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
+                            lambda *a: 400_000)
+        tiled, _ = self._case(body, "fp32")
+        assert bool(jnp.all(tiled == whole))
+
+    def test_a_verify_call_is_not_cut(self, monkeypatch):
+        """Speculative verify's [B, k + 1] queries fit one tile under the
+        real budget: `_query_tiled` hands the call back as it came, so the
+        kernel that runs is the parent's, operand for operand."""
+        cuts = []
+        cut = kernel_gen._query_tiled
+        monkeypatch.setattr(
+            kernel_gen, "_query_tiled",
+            lambda tile, *a: cuts.append((tile, a, cut(tile, *a)))
+            or cuts[-1][2])
+        out, ref = base._walk_case("paged_mq", [40, 9, 130, 5], s_q=5)
+        base._assert_close(out, ref, **base.TestWalk.TOL)
+        (tile, (tbl, kv, ql, queries), got), = cuts
+        assert tile == 5
+        assert got[0] is tbl and got[1] is kv and got[2] is ql
+        assert got[3] is queries
+
+    def test_query_rows_per_step_follows_the_shapes(self):
+        """The tile the serving cells' prefill calls get on a v5e (16 MiB
+        of scoped VMEM), from shapes alone; Mosaic takes each
+        (tests/test_chip_compile.py)."""
+        bf16 = jnp.bfloat16
+
+        def sds(*shape, dtype=bf16):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        def tile(s_q, hq, hkv, d, nb, mb):
+            pools, by_pools = kernel_gen._call_pools(
+                [sds(2, nb, 16, hkv, d), sds(2, nb, 16, hkv, d)],
+                [None, None], mb)
+            return kernel_gen._query_tile([sds(1, s_q, hq, d)], d, pools,
+                                          by_pools)
+
+        # dense GPT-3 2.7B (32 heads of 80, padded to 128 lanes) and
+        # EvaByte (32 of 128): 112 KB a query beside 4 MiB of page buffers
+        assert tile(256, 32, 32, 80, 896, 128) == 64
+        assert tile(256, 32, 32, 128, 3584, 192) == 64
+        assert tile(32, 32, 32, 128, 3584, 192) == 32     # fits whole
+        # 20 query heads over one key/value head: the pages are small, and
+        # the hybrid cell's call of 256 is two tiles
+        assert tile(256, 20, 1, 128, 16384, 128) == 128
+        assert tile(64, 20, 1, 128, 16384, 128) == 64
+        # MLA: 16 heads of 512 + 64 latent columns, 120 queries fit: 512
+        # are five tiles of 104, the cell's call of 1,024 nine of 120
+        pools, by_pools = kernel_gen._call_pools(
+            [sds(9, 8192, 16, 512), sds(9, 8192, 16, 64)], [None, None],
+            256)
+        assert kernel_gen._query_tile(
+            [sds(1, 512, 16, 512), sds(1, 512, 16, 64)], 128, pools,
+            by_pools) == 104
+        assert kernel_gen._query_tile(
+            [sds(1, 1024, 16, 512), sds(1, 1024, 16, 64)], 128, pools,
+            by_pools) == 120
+        # equal tiles, multiples of 8, never under 8
+        assert kernel_gen.query_rows_per_step(100, 1000, 64_000) == 56
+        assert kernel_gen.query_rows_per_step(100, 1000, 1_000) == 8
+        assert kernel_gen.query_rows_per_step(100, 1000, -5) == 8
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_tp2_ragged_latent_queries_are_tiled(self, devices8,
+                                                 monkeypatch, quant):
+        """The tp placement's two kernels hold a row's whole query block as
+        the walk does, so a call wider than a step holds (a prefill call
+        of the engine's width) is cut into query tiles before the
+        shard_map too: q_lens that fill the call, end inside its second
+        tile and are 1, under a VMEM budget that holds 8 queries."""
+        rng = np.random.default_rng(25)
+        s_q = 20
+        scale = base.TestLatentKernelPins.SCALE
+        (q_lat, q_pe, lat, pe, w_v, tbl, lens, ls,
+         ps) = base._mk_latent_inputs(rng, 3, s_q, 4, 32, 8, 16, 8, 6, quant,
+                                      jnp.float32)
+        lens = jnp.maximum(lens, s_q)
+        qlens = jnp.asarray([s_q, 11, 1], jnp.int32)
+        ref = paged_attention_latent(q_lat, q_pe, lat, pe, tbl, lens,
+                                     w_v, q_lens=qlens,
+                                     softmax_scale=scale,
+                                     lat_scales=ls, pe_scales=ps)
+        tiles = []
+        cut = kernel_gen._query_tiled
+        monkeypatch.setattr(
+            kernel_gen, "_query_tiled",
+            lambda tile, *a: tiles.append(tile) or cut(tile, *a))
+        monkeypatch.setattr(kernel_gen, "_latent_tp_row_vmem_bytes",
+                            lambda *a: kernel_gen.VMEM_SCOPE // 8)
+        ctx = build_mesh(ParallelConfig(tensor_parallel=2),
+                         devices=jax.devices()[:2])
+        tp = paged_attention_latent(q_lat, q_pe, lat, pe, tbl, lens,
+                                    w_v, q_lens=qlens,
+                                    softmax_scale=scale,
+                                    lat_scales=ls, pe_scales=ps,
+                                    mesh=ctx.mesh)
+        assert tiles == [8]
+        real = (np.arange(s_q)[None, :] < np.asarray(qlens)[:, None])
+        np.testing.assert_allclose(np.asarray(ref)[real],
+                                   np.asarray(tp)[real],
+                                   atol=2e-5, rtol=2e-5)
+
+
+class TestPrefillWidths:
+    @pytest.mark.parametrize("width", [32, "a v5e's", 7],
+                             ids=["32", "chosen-for-a-v5e", "odd-7"])
+    def test_streams_at_any_prefill_width(self, monkeypatch, width):
+        """ISSUE 35: greedy streams are the dense oracle's whatever the
+        width of the prefill call: 32 (what every engine had; here under a
+        VMEM budget so small that the ragged kernel cuts each call into
+        query tiles of 8), the width the engine chooses for these shapes
+        on a v5e (max_seq_len: a call holds a whole prompt) and 7, which
+        divides no prompt. An init wide enough that a stream is not one
+        token repeated."""
+        from megatronapp_tpu.inference.dynamic_engine import (
+            choose_prefill_width,
+        )
+        cfg = base._engine_cfg(init_method_std=0.2)
+        params, prompts = base._engine_case(cfg, seed=35)
+        prompts = [np.random.default_rng(36).integers(
+            0, cfg.vocab_size, 45).astype(np.int32), prompts[2]]
+        if width == 32:
+            monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
+                                lambda *a: 100_000)
+        elif width != 7:
+            width = choose_prefill_width(cfg, params, 64, 8,
+                                         device_kind="TPU v5 lite")
+            assert width == 64      # the engine's max_seq_len: one call
+        out, eng = base._stream(cfg, params, prompts, max_new=5,
+                           prefill_chunk=width)
+        eng.pool.audit()
+        pre = eng.stats_snapshot()["prefill"]
+        assert pre["width"] == width and pre["tokens"] == 45 + 17
+        assert pre["calls"] == sum(-(-len(p) // width) for p in prompts)
+        assert eng.mq_traces == 1
+        streams = [toks[len(p):] for p, toks in zip(prompts, out)]
+        assert any(len(set(st)) > 2 for st in streams), streams
+        for p, toks in zip(prompts, out):
+            assert toks == base._greedy_oracle(params, cfg, p, 5)
