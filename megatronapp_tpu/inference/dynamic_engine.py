@@ -49,6 +49,7 @@ pair.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -69,6 +70,7 @@ from megatronapp_tpu.inference.paged_cache import (
     HostSpillTier, PagedKVCache, cdiv, pool_format,
 )
 from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
+from megatronapp_tpu.trace import scope_map
 from megatronapp_tpu.trace.request_trace import (
     PhaseStats, get_request_tracer,
 )
@@ -483,13 +485,19 @@ class _PoolStep:
     built for the sharding the pools arrive with, once, and kept. Pools
     that carry no sharding (tracers under make_jaxpr) get the plain jit.
     A call that compiles (the first with its tokens' shape) compiles
-    afresh: utils/platform.fresh_compiles says why."""
+    afresh: utils/platform.fresh_compiles says why. `fn` must not close
+    over arrays (nor over the engine, which holds the weights): the scope
+    map registry keeps the step past its engine's life."""
 
-    def __init__(self, fn, n_lead: int):
+    def __init__(self, fn, n_lead: int, kind: str, guard=None):
         self._fn = fn
         self._n_lead = n_lead
         self._jits = {}              # pools' shardings -> jit
         self._called = set()         # (pools' shardings, tokens' shape)
+        # trace/scope_map.scope_maps() lowers this step again, afresh as
+        # here, from the abstract arguments of each first call.
+        self._scope_step = scope_map.register(
+            self.lower, kind=kind, guard=guard)
 
     def _jit(self, args):
         pools = tuple(args[2]) + tuple(args[3] or ())
@@ -516,6 +524,8 @@ class _PoolStep:
         call = (shardings, args[1].shape)
         if call in self._called:
             return jit(*args)
+        if None not in shardings:       # real pools, not make_jaxpr's tracers
+            self._scope_step.note(call, args)
         with fresh_compiles():
             out = jit(*args)
         self._called.add(call)
@@ -986,13 +996,22 @@ class DynamicInferenceEngine:
         # tests can assert chunked prefill stops retracing per
         # (bucket, cached-length) pair. decode_traces mirrors it for the
         # plain decode step (the /stats jit-count satellite).
-        self.mq_traces = 0
-        self.decode_traces = 0
+        # The jitted steps close over this dict, not over the engine (see
+        # _PoolStep); `mq_traces` and `decode_traces` read it.
+        self._trace_counts = {"mq": 0, "decode": 0}
         # Launch counts of the traced decode step, cached per jit build
         # (dispatch_stats(); computed lazily: one trace of the jaxpr).
         self._dispatch_stats = None
         self._build_jits()
         logger.info(self.startup_line())
+
+    @property
+    def mq_traces(self) -> int:
+        return self._trace_counts["mq"]
+
+    @property
+    def decode_traces(self) -> int:
+        return self._trace_counts["decode"]
 
     def startup_line(self) -> str:
         """What this engine runs, for the log and a server's banner."""
@@ -1019,9 +1038,26 @@ class DynamicInferenceEngine:
         import functools
 
         from megatronapp_tpu.inference.engine import _forward_with_cache
-        self._prefill = jax.jit(
-            functools.partial(_forward_with_cache, cfg=cfg))
-        self._sample_b = jax.jit(_sample_batched)
+        counts = self._trace_counts
+
+        @contextlib.contextmanager
+        def counts_kept():
+            # A lowering for the scope map traces the step again; the
+            # counters say what the ENGINE's calls traced.
+            was = dict(counts)
+            try:
+                yield
+            finally:
+                counts.update(was)
+
+        self._prefill = scope_map.noted(
+            jax.jit(functools.partial(_forward_with_cache, cfg=cfg)),
+            kind="prefill")
+        # A module of its own and one part as a whole: its instructions
+        # name none.
+        self._sample_b = scope_map.noted(
+            jax.jit(_sample_batched), kind="sampler",
+            default_part="sampler")
         self._dispatch_stats = None
         if self.paged:
             msl = self.max_seq_len
@@ -1044,24 +1080,26 @@ class DynamicInferenceEngine:
             # step).
             def _decode_traced(p, t, pages, scales, tbl, l, a, lora):
                 # Python side-effect: runs only while TRACING.
-                self.decode_traces += 1
+                counts["decode"] += 1
                 return _paged_decode_step(p, t, pages, tbl, l, a, cfg,
                                           msl, ctx=step_ctx,
                                           scales=scales, lora=lora)
 
-            self._decode = _PoolStep(_decode_traced, n_lead=2)
+            self._decode = _PoolStep(_decode_traced, n_lead=2,
+                                     kind="decode", guard=counts_kept)
 
             def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
                            lora, rows=None, last=None):
                 # Python side-effect: runs only while TRACING.
-                self.mq_traces += 1
+                counts["mq"] += 1
                 return _paged_multiquery_step(p, t, pages, tbl, starts,
                                               qlens, act, cfg, msl,
                                               ctx=step_ctx, scales=scales,
                                               lora=lora, rows=rows,
                                               last=last)
 
-            self._mq_step = _PoolStep(_mq_traced, n_lead=2)
+            self._mq_step = _PoolStep(_mq_traced, n_lead=2,
+                                      kind="prefill", guard=counts_kept)
             if self.spec_method:
                 from megatronapp_tpu.inference.speculative import (
                     build_verify_sampler,
@@ -1071,10 +1109,12 @@ class DynamicInferenceEngine:
                 self.proposer.reset_compilation()
         else:
             def _decode_traced_dense(p, t, c, l, a):
-                self.decode_traces += 1
+                counts["decode"] += 1
                 return _decode_step(p, t, c, l, a, cfg)
 
-            self._decode = jax.jit(_decode_traced_dense)
+            self._decode = scope_map.noted(
+                jax.jit(_decode_traced_dense), kind="decode",
+                guard=counts_kept)
 
     def reset_compilation(self):
         """Re-trace on next call (after MegaScope hook toggles — see

@@ -114,13 +114,16 @@ def gpt_embed(p, tokens: jnp.ndarray, cfg: TransformerConfig,
     position_ids: optional explicit positions ([B,S] or [1,S]) — packed
     sequences reset positions per segment for learned-absolute embeddings
     too (reference resets the position_ids fed to the embedding)."""
-    h = jnp.take(p["embedding"]["word"], tokens, axis=0)
-    if "pos" in p["embedding"]:
-        if position_ids is None:
-            position_ids = jnp.arange(tokens.shape[1])[None, :]
-        pos = position_ids + position_offset
-        h = h + jnp.take(p["embedding"]["pos"], pos, axis=0)
-    return h.astype(dtype or cfg.compute_dtype)
+    # The scope names this part in the compiled program's op_names
+    # (trace/scope_map.py joins them to a device trace).
+    with jax.named_scope("embedding"):
+        h = jnp.take(p["embedding"]["word"], tokens, axis=0)
+        if "pos" in p["embedding"]:
+            if position_ids is None:
+                position_ids = jnp.arange(tokens.shape[1])[None, :]
+            pos = position_ids + position_offset
+            h = h + jnp.take(p["embedding"]["pos"], pos, axis=0)
+        return h.astype(dtype or cfg.compute_dtype)
 
 
 def rope_params(cfg: TransformerConfig):
@@ -303,7 +306,8 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
         targets = jnp.take(targets, idx, axis=1)
         if loss_mask is not None:
             loss_mask = jnp.take(loss_mask, idx, axis=1)
-    loss, _ = cross_entropy_loss(logits, targets, loss_mask)
+    with jax.named_scope("head"):       # in training the head has the loss
+        loss, _ = cross_entropy_loss(logits, targets, loss_mask)
     mtp_scaled_term = mtp_metrics.pop("_mtp_scaled",
                                       jnp.zeros((), jnp.float32))
     return loss + aux + mtp_scaled_term, {"lm_loss": loss,
@@ -313,14 +317,16 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
 
 def gpt_head(p, h: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
     """Final norm + vocab projection. h [..., S, H] → logits fp32."""
-    h = apply_norm(cfg.normalization, h, p["final_ln_scale"],
-                   p.get("final_ln_bias"), cfg.layernorm_epsilon,
-                   cfg.norm_unit_offset)
-    out_kernel = (p["output"] if "output" in p
-                  else p["embedding"]["word"].T)
-    logits = h.astype(cfg.compute_dtype) @ out_kernel.astype(cfg.compute_dtype)
-    logits = scope_capture("result", logits)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        h = apply_norm(cfg.normalization, h, p["final_ln_scale"],
+                       p.get("final_ln_bias"), cfg.layernorm_epsilon,
+                       cfg.norm_unit_offset)
+        out_kernel = (p["output"] if "output" in p
+                      else p["embedding"]["word"].T)
+        logits = (h.astype(cfg.compute_dtype)
+                  @ out_kernel.astype(cfg.compute_dtype))
+        logits = scope_capture("result", logits)
+        return logits.astype(jnp.float32)
 
 
 def gpt_pipeline_loss(p, tokens_mb, targets_mb, loss_mask_mb,
@@ -479,7 +485,8 @@ def gpt_pipeline_loss(p, tokens_mb, targets_mb, loss_mask_mb,
         mtp_metrics["mtp_loss"] = mtp_mean
 
     logits = gpt_head(p, out_mb, cfg)
-    loss, _ = cross_entropy_loss(logits, targets_mb, loss_mask_mb)
+    with jax.named_scope("head"):
+        loss, _ = cross_entropy_loss(logits, targets_mb, loss_mask_mb)
     return loss + aux + mtp_scaled_term, {"lm_loss": loss,
                                           "moe_aux_loss": aux,
                                           **mtp_metrics}
@@ -526,5 +533,6 @@ def _gpt_pipeline_loss_packed(p, tokens_mb, targets_mb, loss_mask_mb,
     aux_loss = aux_loss / m
 
     logits = gpt_head(p, out_mb, cfg)
-    loss, _ = cross_entropy_loss(logits, targets_mb, loss_mask_mb)
+    with jax.named_scope("head"):
+        loss, _ = cross_entropy_loss(logits, targets_mb, loss_mask_mb)
     return loss + aux_loss, {"lm_loss": loss, "moe_aux_loss": aux_loss}

@@ -14,7 +14,10 @@ SPMD partitioning, so host code never sees them. Instead we
 join the two on the HLO op name into tracer-contract event dicts
 ({pid, name, ts, dur, args:{id, group, bytes, bandwidth_gbps,
 iteration}}) that flow through trace/dependency.py ``build_dependencies``
-and trace/detect.py stage 2 unchanged. This also restores collective
+and trace/detect.py stage 2 unchanged. The same join gives every other
+device operation its part of the model (``device_op_events``: the compiled
+text keeps each instruction's ``jax.named_scope``, trace/scope_map.py reads
+it). This also restores collective
 visibility on backends without host callbacks (trace/tracer.py
 ``callbacks_supported``): the profiler path needs no in-graph
 instrumentation at all.
@@ -40,11 +43,10 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s32": 4,
                 "s8": 1, "u8": 1, "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_INSTR_RE = re.compile(
-    r"%?([\w.-]+)\s*=\s*(\([^)]*\)|\S+)\s+"
-    r"(all-reduce-start|all-gather-start|reduce-scatter|all-reduce|"
-    r"all-gather|collective-permute-start|collective-permute|all-to-all)"
-    r"\(")
+_COLLECTIVE_OPCODES = frozenset((
+    "all-reduce-start", "all-gather-start", "reduce-scatter", "all-reduce",
+    "all-gather", "collective-permute-start", "collective-permute",
+    "all-to-all"))
 _GROUPS_RE = re.compile(r"replica_groups=(\{\{[\d,{}\s]*\}\}|\[[^\]]*\]"
                         r"<=\[[^\]]*\](?:T\([\d,]*\))?)")
 _SRC_TGT_RE = re.compile(r"source_target_pairs=\{([\d,{}\s]*)\}")
@@ -106,31 +108,36 @@ def _axes_of_groups(groups: List[List[int]], mesh) -> str:
     return "x".join(varying)
 
 
-def extract_hlo_collectives(hlo_text: str, mesh=None) -> Dict[str, dict]:
-    """Map HLO op name → {kind, bytes, groups, axes} for every collective
-    in a compiled module (the static half of the join)."""
+def collectives_of(parsed, mesh=None) -> Dict[str, dict]:
+    """HLO op name → {kind, bytes, groups, axes} for every collective of a
+    parsed module (``scope_map.parse_hlo_text``, the one reader of HLO
+    text): the static half of the join."""
     out: Dict[str, dict] = {}
-    for line in hlo_text.splitlines():
-        m = _INSTR_RE.search(line)
-        if not m:
+    for ins in parsed.instructions.values():
+        if ins.opcode not in _COLLECTIVE_OPCODES:
             continue
-        name, shape_text, kind = m.groups()
-        is_async = kind.endswith("-start")
-        kind = kind.replace("-start", "")
+        is_async = ins.opcode.endswith("-start")
+        kind = ins.opcode.replace("-start", "")
         info = {"kind": kind,
-                "bytes": _shape_bytes(shape_text, result_only=is_async)}
-        gm = _GROUPS_RE.search(line)
+                "bytes": _shape_bytes(ins.shape, result_only=is_async)}
+        gm = _GROUPS_RE.search(ins.line)
         groups = _parse_groups(gm.group(1)) if gm else []
         if not groups and kind == "collective-permute":
-            pm = _SRC_TGT_RE.search(line)
+            pm = _SRC_TGT_RE.search(ins.line)
             if pm:
                 pairs = re.findall(r"\{(\d+),(\d+)\}", "{" + pm.group(1) + "}")
                 members = sorted({int(a) for p in pairs for a in p})
                 groups = [members]
         info["groups"] = groups
         info["axes"] = _axes_of_groups(groups, mesh)
-        out[name] = info
+        out[ins.name] = info
     return out
+
+
+def extract_hlo_collectives(hlo_text: str, mesh=None) -> Dict[str, dict]:
+    """``collectives_of`` a compiled module's text."""
+    from megatronapp_tpu.trace.scope_map import parse_hlo_text
+    return collectives_of(parse_hlo_text(hlo_text), mesh)
 
 
 def _attach_thread_ordinals(payload_events: List[dict],
@@ -170,6 +177,38 @@ def _attach_thread_ordinals(payload_events: List[dict],
             ordinal_of[(e.get("pid"), e.get("tid"))]
 
 
+_DEVICE_PROCESS = re.compile(r"^/device:\w+:(\d+)")
+_OPS_THREAD = "XLA Ops"
+
+
+def _name_device_line_events(payload_events: List[dict]) -> None:
+    """Give a TPU's device operations the ``args.hlo_op`` and
+    ``args.device_ordinal`` that a CPU's carry.
+
+    A TPU's Chrome trace has one process a chip (``/device:TPU:<n>``) whose
+    thread ``XLA Ops`` holds one X event an executed instruction, NAMED by
+    the instruction (``fusion.12``) and with ``long_name``, ``hlo_category``
+    and byte counts for arguments: no ``hlo_op`` (my chip run, PR 36, where
+    the filter below found 0 events of a traced step's 3 windows)."""
+    ordinal, ops_threads = {}, set()
+    for e in payload_events:
+        if e.get("ph") != "M":
+            continue
+        if e.get("name") == "process_name":
+            m = _DEVICE_PROCESS.match(str(e["args"].get("name", "")))
+            if m:
+                ordinal[e.get("pid")] = int(m.group(1))
+        elif e.get("name") == "thread_name" \
+                and e["args"].get("name") == _OPS_THREAD:
+            ops_threads.add((e.get("pid"), e.get("tid")))
+    for e in payload_events:
+        if e.get("ph") == "X" and e.get("pid") in ordinal \
+                and (e.get("pid"), e.get("tid")) in ops_threads:
+            args = e.setdefault("args", {})
+            args.setdefault("hlo_op", e["name"])
+            args.setdefault("device_ordinal", ordinal[e["pid"]])
+
+
 def parse_profile_dir(trace_dir: str, cleanup: bool = False) -> List[dict]:
     """Read a jax.profiler output directory → the raw per-device
     Chrome-trace X events that carry an hlo_op."""
@@ -180,6 +219,7 @@ def parse_profile_dir(trace_dir: str, cleanup: bool = False) -> List[dict]:
         with gzip.open(paths[-1]) as f:
             payload = json.load(f)
         all_events = payload.get("traceEvents", [])
+        _name_device_line_events(all_events)
         events = [e for e in all_events
                   if e.get("ph") == "X" and "hlo_op" in e.get("args", {})]
         _attach_thread_ordinals(all_events, events)
@@ -258,6 +298,41 @@ def collective_events(raw_events: Sequence[dict],
                      "iteration": iteration},
         })
         next_id += 1
+    return out
+
+
+_CONTROL_FLOW = frozenset(("while", "conditional", "call"))
+
+
+def device_op_events(raw_events: Sequence[dict], smap,
+                     iteration: int = 0,
+                     process_index: Optional[int] = None) -> List[dict]:
+    """Every other device operation of the profiled execution as a record
+    named by its HLO instruction, with ``args.part`` / ``args.pass`` from
+    the step's scope map `smap` (trace/scope_map.py), so that the merged Chrome
+    trace shows operators by part of the model. Collectives are left to
+    ``collective_events``; an operation that encloses others (``while``,
+    ``conditional``, ``call``) is left out, so durations add up."""
+    import jax
+
+    if process_index is None:
+        process_index = jax.process_index()
+    out: List[dict] = []
+    for e in raw_events:
+        op = e["args"]["hlo_op"]
+        scoped = smap.instructions.get(op)
+        if scoped is None or op in smap.collectives \
+                or scoped.opcode in _CONTROL_FLOW:
+            continue
+        ordinal = int(e["args"].get("device_ordinal", e.get("pid", 0)))
+        out.append({
+            "ph": "X", "pid": 1000 * (process_index + 1) + ordinal,
+            "tid": e.get("tid", 0), "name": op, "ts": float(e["ts"]),
+            "dur": float(e.get("dur", 0.0)),
+            "args": {"hlo_op": op, "part": scoped.part,
+                     "pass": scoped.pass_, "process": process_index,
+                     "iteration": iteration},
+        })
     return out
 
 

@@ -532,12 +532,18 @@ def pretrain_gpt(
         """The ONE build site for the jitted SPMD step — startup, the
         phase-traced variant, and the planner's _apply_schedule rebuild
         all go through it so they can never drift apart."""
-        return make_train_step(
-            loss_fn_, optimizer, opt_cfg, ctx, shardings,
-            train_cfg.train_iters,
-            check_nan=train_cfg.check_for_nan_in_loss,
-            pipeline=ctx.pp > 1, trace_phases=trace_phases,
-            donate=donate, fp8=fp8_on)
+        from megatronapp_tpu.trace.scope_map import noted
+        # Registered for trace/scope_map.scope_maps(): the abstract
+        # arguments of its first call with a batch shape, nothing lowered.
+        return noted(
+            make_train_step(
+                loss_fn_, optimizer, opt_cfg, ctx, shardings,
+                train_cfg.train_iters,
+                check_nan=train_cfg.check_for_nan_in_loss,
+                pipeline=ctx.pp > 1, trace_phases=trace_phases,
+                donate=donate, fp8=fp8_on),
+            kind="train", key=lambda state, batch: batch["tokens"].shape,
+            guard=lambda: ctx.mesh, mesh=ctx.mesh)
 
     if not use_dpp_runtime:
         step_fn = _build_step(loss_fn)
@@ -604,7 +610,6 @@ def pretrain_gpt(
 
     tracer = get_tracer()
     traced_step_fn = step_fn
-    fenced_trace = False
     phase_traced = False
     if train_cfg.trace:
         tracer.configure(
@@ -627,76 +632,21 @@ def pretrain_gpt(
             phase_traced = True
             traced_step_fn = _build_step(loss_fn, trace_phases=True)
         else:
-            # Host-timestamped dispatch windows: a backend without host
-            # callbacks (tracer.callbacks_supported) cannot carry
-            # in-graph phase markers, so traced iterations run as FENCED
-            # dispatches instead: (1) a forward-only loss, fenced by
-            # device_get — the 'forward' span; (2) the full step, fenced
-            # — the 'backward' span, whose attrs carry the honest
-            # arithmetic (it re-runs the forward and includes the
-            # optimizer; backward_est_ms = span - forward). Cost (one
-            # extra forward + two fences) is confined to traced
-            # iterations — the reference's per-window tracing perturbs
-            # its traced iterations the same way.
-            log_fn("trace: backend lacks host callbacks; using fenced "
-                   "dispatch windows for schedule-phase spans")
-            fenced_trace = True
-            if planner is not None:
-                # Committing a re-plan the loop below cannot apply would
-                # desync the planner's state/metrics from the schedule
-                # actually running — planning stays observational here
-                # (EWMAs + gauges only; maybe_replan is never called).
-                log_fn("pp-planner: fenced-dispatch trace mode pins the "
-                       "compiled step — planning is OBSERVATIONAL (no "
-                       "re-plans); restart with --pp-schedule to change "
-                       "schedules")
-            if ctx.pp > 1:
-                _fwd_only = jax.jit(lambda p, b: loss_fn(p, b)[0])
-            else:
-                def _fwd_loss(p, b):
-                    def body(acc, micro):
-                        l, _ = loss_fn(p, micro)
-                        return acc + l, None
-                    tot, _ = jax.lax.scan(
-                        body, jnp.zeros((), jnp.float32), b)
-                    return tot / jax.tree.leaves(b)[0].shape[0]
-                _fwd_only = jax.jit(_fwd_loss)
+            # No in-graph phase markers without host callbacks: a traced
+            # window keeps the host scopes, and its profiled iteration's
+            # collectives and device operations by part (below).
+            log_fn("trace: backend lacks host callbacks; no schedule-phase "
+                   "spans (host scopes, collectives and device operations "
+                   "by part remain)")
 
-            def fenced_step(state, batch):
-                import time as _time
-                t0 = _time.perf_counter()
-                with tracer.scope("forward", fenced=True):
-                    jax.device_get(_fwd_only(state["params"], batch))
-                fwd_ms = (_time.perf_counter() - t0) * 1e3
-                with tracer.scope("backward", fenced=True,
-                                  includes="fwd_rerun+optimizer",
-                                  forward_ms=round(fwd_ms, 3)) as tr:
-                    new_state, metrics = step_fn(state, batch)
-                    jax.device_get(metrics["loss"])
-                    tr.set_attr(backward_est_ms=round(
-                        (_time.perf_counter() - t0) * 1e3 - 2 * fwd_ms,
-                        3))
-                return new_state, metrics
-
-            # The profiler-collectives join still needs compiled HLO;
-            # the fenced wrapper exposes the underlying jitted step.
-            fenced_step._hlo_source = step_fn
-            traced_step_fn = fenced_step
-
-    def _apply_schedule(new_schedule: str) -> bool:
+    def _apply_schedule(new_schedule: str) -> None:
         """Planner re-plan: swap the pipeline schedule program and
         rebuild the jitted step family (one recompile, loudly logged).
-        Returns True when applied. Grads are schedule-invariant
+        Grads are schedule-invariant
         (zero-bubble parity pinned ≤1e-6), so switching mid-run never
         perturbs the optimizer trajectory beyond accumulation order."""
         nonlocal loss_fn, step_fn, replay_step_fn, traced_step_fn
         nonlocal pp_schedule
-        if fenced_trace:
-            log_fn("pp-planner: re-plan NOT applied — fenced-dispatch "
-                   "trace mode pins the compiled step (backend without "
-                   "host callbacks); restart with --pp-schedule "
-                   f"{new_schedule} to take it")
-            return False
         log_fn(f"pp-planner: APPLYING schedule {new_schedule!r} "
                f"(was {pp_schedule!r}) — rebuilding the train step "
                "(one-time recompile)")
@@ -707,21 +657,21 @@ def pretrain_gpt(
         traced_step_fn = step_fn
         if phase_traced:
             traced_step_fn = _build_step(loss_fn, trace_phases=True)
-        return True
 
-    # Per-collective events via the XLA profiler (reference
-    # mappings.py:27-60 group+bytes instrumentation; here synthesized
-    # post-hoc since SPMD inserts the collectives — see
-    # trace/profiler_collectives.py). One profiled iteration per trace
-    # window keeps the profiler overhead off the steady state.
-    _coll = {"hlo": {}, "window": -1}
+    # Per-collective and per-operator events via the XLA profiler
+    # (reference mappings.py:27-60 group+bytes instrumentation; here
+    # synthesized post-hoc since SPMD inserts the collectives — see
+    # trace/profiler_collectives.py — and every device operation gets the
+    # part of the model it belongs to from the step's scope map,
+    # trace/scope_map.py). One profiled iteration per trace window keeps
+    # the profiler overhead off the steady state.
+    _coll = {"window": -1}
 
     def run_step_maybe_profiled(active_fn, state, batch, it):
-        # Fenced traced steps expose their inner jitted step for the HLO
-        # join; host-driven (DPP) steps have no single lowered HLO at
-        # all — the runner's metrics cover them.
-        hlo_source = getattr(active_fn, "_hlo_source", active_fn)
-        if (not tracer.active or not hasattr(hlo_source, "lower") or
+        # Host-driven (DPP) steps have no single lowered HLO at all — the
+        # runner's metrics cover them.
+        scoped = getattr(active_fn, "scope_step", None)
+        if (not tracer.active or scoped is None or
                 train_cfg.trace_granularity not in ("full", "collective")):
             return active_fn(state, batch)
         window = it // tracer.interval
@@ -729,27 +679,18 @@ def pretrain_gpt(
             return active_fn(state, batch)
         _coll["window"] = window
         from megatronapp_tpu.trace.profiler_collectives import (
-            collective_events, extract_hlo_collectives, profile_run,
+            collective_events, device_op_events, profile_run,
         )
-        # Keyed on batch leaf shapes as well as the fn: under batch-size
-        # rampup a later window recompiles the step, and joining profiler
-        # events against the first shape's HLO table would silently
-        # misattribute bytes/bandwidth per collective.
-        shape_key = tuple(
-            (getattr(l, "shape", ()), str(getattr(l, "dtype", "")))
-            for l in jax.tree_util.tree_leaves(batch))
-        key = (id(active_fn), shape_key)
-        if key not in _coll["hlo"]:
-            try:
-                compiled = hlo_source.lower(state, batch).compile()
-                _coll["hlo"][key] = extract_hlo_collectives(
-                    compiled.as_text(), ctx.mesh)
-            except Exception as e:  # pragma: no cover — backend-specific
-                log_fn(f"trace: collective HLO extraction failed ({e}); "
-                       "profiler collectives disabled")
-                _coll["hlo"][key] = None
-        info = _coll["hlo"][key]
-        if not info:
+        # The step's own scope map (trace/scope_map.py), made once a batch
+        # shape: under batch-size rampup a later window recompiles the
+        # step, and joining profiler events against the first shape's
+        # table would silently misattribute bytes/bandwidth per collective.
+        key = active_fn.key_of(state, batch)
+        # The call below shares this compile, as it always did (a compile
+        # inside the capture would drown the device events).
+        smap = scoped.map_for(key, about_to_call=(state, batch))
+        by_part = train_cfg.trace_granularity == "full"
+        if smap is None or not (smap.collectives or by_part):
             return active_fn(state, batch)
         result = {}
 
@@ -764,7 +705,9 @@ def pretrain_gpt(
         try:
             raw = profile_run(run)
             tracer.add_collective_records(
-                collective_events(raw, info, iteration=it),
+                collective_events(raw, smap.collectives, iteration=it)
+                + (device_op_events(raw, smap, iteration=it)
+                   if by_part else []),
                 offset_us=offset_us)
         except Exception as e:  # pragma: no cover — profiler optional
             log_fn(f"trace: profiler capture failed ({e})")
@@ -876,16 +819,7 @@ def pretrain_gpt(
                            "sequences (segment_ids in batch) — "
                            "reverting to 1f1b (grads are schedule-"
                            "invariant; perf-only change)")
-                    if not _apply_schedule("1f1b"):
-                        # Fenced-dispatch trace mode pins the compiled
-                        # zero-bubble step — the packed batch WOULD
-                        # crash on retrace with a confusing
-                        # NotImplementedError; name the conflict now.
-                        raise ValueError(
-                            "packed batch (segment_ids) in the stream "
-                            "while the zero-bubble step is pinned by "
-                            "fenced-dispatch trace mode — restart with "
-                            "--pp-schedule 1f1b for packed data")
+                    _apply_schedule("1f1b")
                     if planner is not None and \
                             planner.current is not None:
                         planner.current = _dc_plan.replace(
@@ -1014,7 +948,7 @@ def pretrain_gpt(
                     # aux inputs, and the stream may mix).
                     planner.observe_step(step_time_ms / 1e3)
                     planner.export_metrics()
-                    if not saw_packed and not fenced_trace:
+                    if not saw_packed:
                         newp = planner.maybe_replan(cur_micro)
                         if newp is not None:
                             _apply_schedule(newp.schedule)

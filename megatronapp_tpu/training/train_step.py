@@ -171,13 +171,15 @@ def make_train_step(
                     (loss, metrics), (g, g8) = grad_fn(
                         (params, state["fp8"]), micro)
                     gp_acc, f8_acc = g_acc
-                    g_acc = (jax.tree.map(
-                        lambda a, b: a + b.astype(a.dtype), gp_acc, g),
-                        fp8_accumulate(f8_acc, g8))
+                    with jax.named_scope("grad_accum"):
+                        g_acc = (jax.tree.map(
+                            lambda a, b: a + b.astype(a.dtype), gp_acc, g),
+                            fp8_accumulate(f8_acc, g8))
                 else:
                     (loss, metrics), g = grad_fn(params, micro)
-                    g_acc = jax.tree.map(
-                        lambda a, b: a + b.astype(a.dtype), g_acc, g)
+                    with jax.named_scope("grad_accum"):
+                        g_acc = jax.tree.map(
+                            lambda a, b: a + b.astype(a.dtype), g_acc, g)
                 return (g_acc, loss_acc + loss,
                         jax.tree.map(lambda a, b: a + b, aux_acc,
                                      metrics)), None
@@ -208,7 +210,8 @@ def make_train_step(
                 from megatronapp_tpu.training.fp8 import fp8_carry_sat
                 fp8_new = fp8_carry_sat(state["fp8"], fp8_new)
             inv = 1.0 / num_micro
-            grads = jax.tree.map(lambda g: g * inv, g_sum)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree.map(lambda g: g * inv, g_sum)
             loss = loss_sum * inv
             aux = jax.tree.map(lambda a: a * inv, aux_sum)
 
@@ -217,51 +220,53 @@ def make_train_step(
                 phase_span_begin, phase_span_end,
             )
             grads = phase_span_begin(grads, "allreduce")
-        grad_norm = global_grad_norm(grads)
-        if trace_phases:
-            grad_norm = phase_span_end(grad_norm, "allreduce")
-            grads = phase_span_begin(grads, "optimizer")
-        finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+        # "optimizer": the norm the clip reads, the update, ZeRO-1's gather.
+        with jax.named_scope("optimizer"):
+            grad_norm = global_grad_norm(grads)
+            if trace_phases:
+                grad_norm = phase_span_end(grad_norm, "allreduce")
+                grads = phase_span_begin(grads, "optimizer")
+            finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
 
-        def do_update(_):
-            if zero1_manual:
-                from megatronapp_tpu.training.distributed_optimizer \
-                    import manual_apply
-                new_params, new_opt = manual_apply(
-                    optimizer, grads, state["opt_state"], params,
-                    state_shardings, ctx.mesh, zero1_plan,
-                    overlap=(opt_cfg.dist_opt_comm == "ring"))
-            else:
-                updates, new_opt = optimizer.update(
-                    grads, state["opt_state"], params)
-                if hasattr(optimizer, "apply_updates"):
-                    # Master-weight aware (ZeRO-1 mixed precision):
-                    # params become the rounded image of the fp32
-                    # master shard.
-                    new_params = optimizer.apply_updates(params, updates,
-                                                         new_opt)
+            def do_update(_):
+                if zero1_manual:
+                    from megatronapp_tpu.training.distributed_optimizer \
+                        import manual_apply
+                    new_params, new_opt = manual_apply(
+                        optimizer, grads, state["opt_state"], params,
+                        state_shardings, ctx.mesh, zero1_plan,
+                        overlap=(opt_cfg.dist_opt_comm == "ring"))
                 else:
-                    new_params = jax.tree.map(
-                        lambda p, u: (p + u.astype(p.dtype)), params,
-                        updates)
-            if fp8:
-                # The accumulated fp8 "gradient" IS the next history
-                # (rolled, amaxes in slot 0) — installed directly,
-                # never via the optimizer.
-                return new_params, new_opt, fp8_new
-            return new_params, new_opt
+                    updates, new_opt = optimizer.update(
+                        grads, state["opt_state"], params)
+                    if hasattr(optimizer, "apply_updates"):
+                        # Master-weight aware (ZeRO-1 mixed precision):
+                        # params become the rounded image of the fp32
+                        # master shard.
+                        new_params = optimizer.apply_updates(params, updates,
+                                                             new_opt)
+                    else:
+                        new_params = jax.tree.map(
+                            lambda p, u: (p + u.astype(p.dtype)), params,
+                            updates)
+                if fp8:
+                    # The accumulated fp8 "gradient" IS the next history
+                    # (rolled, amaxes in slot 0) — installed directly,
+                    # never via the optimizer.
+                    return new_params, new_opt, fp8_new
+                return new_params, new_opt
 
-        def skip(_):
-            if fp8:
-                return params, state["opt_state"], state["fp8"]
-            return params, state["opt_state"]
+            def skip(_):
+                if fp8:
+                    return params, state["opt_state"], state["fp8"]
+                return params, state["opt_state"]
 
-        if check_nan:
-            updated = jax.lax.cond(finite, do_update, skip, operand=None)
-            skipped = jnp.where(finite, 0, 1).astype(jnp.int32)
-        else:
-            updated = do_update(None)
-            skipped = jnp.zeros((), jnp.int32)
+            if check_nan:
+                updated = jax.lax.cond(finite, do_update, skip, operand=None)
+                skipped = jnp.where(finite, 0, 1).astype(jnp.int32)
+            else:
+                updated = do_update(None)
+                skipped = jnp.zeros((), jnp.int32)
         if fp8:
             new_params, new_opt, new_fp8 = updated
         else:

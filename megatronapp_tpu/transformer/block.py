@@ -135,10 +135,11 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
                    cfg.layernorm_epsilon, cfg.norm_unit_offset)
-    # The scopes put a layer's three parts into the compiled program's
-    # op_names (HLO text, the profiler's own viewer). The events of a TPU
-    # trace as jax.profiler.ProfileData gives them do not carry op_names,
-    # so perfbench reads kernels by name instead (PERF.md, PR 28).
+    # The scopes put a layer's parts into the compiled program's op_names
+    # (HLO text, the profiler's own viewer). The events of a TPU trace as
+    # jax.profiler.ProfileData gives them carry an instruction's name and
+    # no op_name: trace/scope_map.py reads the scopes from the compiled
+    # text and a reader joins the two by instruction name (PERF.md, PR 36).
     def attend():
         mask = attention_mask
         with jax.named_scope("attention"):
