@@ -725,9 +725,13 @@ def build_parser(title: str = "megatronapp-tpu") -> argparse.ArgumentParser:
                    choices=["none", "selective", "selective_attn", "full"])
     g.add_argument("--attention-impl", default="auto",
                    choices=["auto", "pallas", "reference"],
-                   help="auto = flash above --flash-min-seq, dense below")
-    g.add_argument("--flash-min-seq", type=int, default=2048,
-                   help="flash/dense crossover sequence length (PERF.md)")
+                   help="auto = chosen from the call's shapes: on a TPU "
+                        "the Pallas flash kernels from S 2048 on and "
+                        "wherever the dense float32 scores of a call were "
+                        "measured to leave the chip (bf16, heads of "
+                        "64..128, S >= 512, 128 MiB a device) or pass 1 GB; "
+                        "XLA's dense attention elsewhere. pallas / "
+                        "reference force either")
     # --scan-unroll lives in add_serving_args (single source of truth
     # for both the training layer scan and the serving step scans).
     g.add_argument("--flash-head-fold", action="store_true",
@@ -1158,7 +1162,6 @@ def configs_from_args(args) -> Tuple[TransformerConfig, ParallelConfig,
             cp_comm_overlap=args.cp_comm_overlap,
             moe_comm_overlap=args.moe_comm_overlap,
             attention_impl=args.attention_impl,
-            flash_min_seq=args.flash_min_seq,
             scan_unroll=args.scan_unroll,
             flash_head_fold=args.flash_head_fold,
             compute_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,

@@ -204,8 +204,10 @@ class TransformerConfig:
 
     # Kernel implementation selection (spec_utils.py ModuleSpec analogue):
     # 'reference' = pure jnp; 'pallas' = fused Pallas flash attention;
-    # 'auto' = on TPU, pallas for sequences >= flash_min_seq and the
-    # XLA dense path below it, reference elsewhere.
+    # 'auto' = on TPU, pallas from S 2048 on and wherever the call's dense
+    # scores were measured to leave the chip or would not fit, from the
+    # call's own shapes (ops/pallas/flash_attention.py choose_attention);
+    # reference elsewhere.
     attention_impl: str = "auto"
 
     # Latency-hiding tensor-parallel matmuls (reference --tp-comm-overlap;
@@ -229,17 +231,10 @@ class TransformerConfig:
     # INSIDE the sharded body.
     tp_sharded_stage: bool = True
 
-    # Flash/dense crossover for 'auto' (PERF.md lever #2): at short
-    # sequences the O(S^2) dense backward is FASTER on this chip than
-    # the flash backward kernels at D=64 (measured 8x at S=1024 —
-    # half-empty MXU lanes + recompute overhead dominate below the
-    # memory-capacity regime flash exists for). 'pallas' forces flash
-    # regardless.
-    flash_min_seq: int = 2048
-
-    # Fused dot-product attention blockwise kernel sizes (Pallas).
-    flash_block_q: int = 512
-    flash_block_kv: int = 512
+    # Tiles of the Pallas flash kernels. Unset: from the call's S
+    # (ops/pallas/flash_attention.py flash_tiles); a value is honoured.
+    flash_block_q: Optional[int] = None
+    flash_block_kv: Optional[int] = None
 
     # lax.scan unroll factor for the layer stack (PERF.md lever #3:
     # unrolling lets XLA software-pipeline across layer boundaries at

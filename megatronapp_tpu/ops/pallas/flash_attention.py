@@ -18,7 +18,7 @@ Layout: [B, H, S, D] per-head-contiguous (callers reshape from [B,S,H,D]).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1231,15 +1231,104 @@ def _seg_bwd_rule(scale, causal, block_q, block_kv, res, g):
 _flash_attention_seg_bhsd.defvjp(_seg_fwd_rule, _seg_bwd_rule)
 
 
+class AttentionChoice(NamedTuple):
+    """What `choose_attention` decided for one call: the implementation
+    ('pallas' or 'reference'), the flash kernels' tiles, and the term that
+    decided, for the line `transformer/attention.py` prints once a shape."""
+    impl: str
+    block_q: int
+    block_kv: int
+    why: str
+
+
+# What `choose_attention` rests on (tools/bench_profile.py's sweep on a TPU
+# v5e; the tables are in PERF.md, PR 37): forward + backward under selective
+# recomputation, bf16, heads of 64 / 80 / 128, S 512..2048, causal and
+# bidirectional, packed and not. XLA's dense attention keeps the float32
+# [B, H, S, S] scores of a call on the chip while they fit its fast memory
+# and then beats the kernels (by 10-40% at 64 MiB of scores, level at 108
+# MiB); from 128 MiB on it streams them through HBM about eleven times a
+# layer and the kernels are 1.5-4 times faster. What decides is the bytes,
+# whichever of batch, heads and S they come from. At S 2048, where the
+# kernels have always run, they are level with dense below 128 MiB (0.94 to
+# 1.24 times its speed) and 2-4 times faster from there.
+_MEASURED_SEQ = (512, 2048)
+_MEASURED_HEAD_DIM = (64, 128)
+_DENSE_SCORES_SPILL = 128 << 20
+# Beside what was measured, the second reason to take the kernels that the
+# rule before this function had: dense float32 scores and probabilities
+# past 1 GB on a device, whatever the dtype, the heads and S.
+_DENSE_BYTES_MAX = 1 << 30
+
+
+def flash_tiles(seq: int, block_q: Optional[int] = None,
+                block_kv: Optional[int] = None) -> tuple:
+    """(block_q, block_kv) of the three flash kernels: the caller's where it
+    names them (clamped to S), else one tile a sequence up to S 1024 and
+    512 x 512 past it. Measured
+    (PERF.md, PR 37): at S 1024 one 1024 x 1024 tile is 20-30% faster than
+    four 512 x 512 of which causal skipping drops one (no rescaling between
+    key/value tiles, a quarter of the grid steps) and every smaller tile is
+    slower still; at S 2048 tiles of 1024 gain under 10% and Mosaic refuses
+    them with segments at batch 4 (VMEM), so the four-chip cell keeps its
+    512 x 512. A compile for a described v5e takes one tile a sequence at
+    every S <= 1024, D <= 128, batch 1..16, packed or not, grouped or not."""
+    chosen = seq if seq <= 1024 else 512
+    return min(block_q or chosen, seq), min(block_kv or chosen, seq)
+
+
+def choose_attention(*, impl: str, batch: int, seq: int, heads: int,
+                     head_dim: int, dtype, segments: bool, backend: str,
+                     block_q: Optional[int] = None,
+                     block_kv: Optional[int] = None) -> AttentionChoice:
+    """The attention implementation and the flash tiles for one call, from
+    what the call can see: `impl` as configured ('auto', 'pallas',
+    'reference'), batch and query heads ON ONE DEVICE, S, D, the compute
+    dtype, packed segments or not, and the backend. Pure: no device, no
+    configuration, no environment is read.
+
+    'auto' on a TPU takes the Pallas kernels from S 2048 on, as it always
+    has; below, where the call's dense scores were measured not to stay on
+    the chip (bf16, heads of 64..128, S from 512: the constants above) or
+    would pass 1 GB with their probabilities; and keeps XLA's dense
+    attention everywhere else: nobody measured float32 compute (the kernels
+    feed the MXU bf16), other head sizes, or S under 512. Other backends
+    keep XLA's dense attention. Explicit tiles are honoured; unset ones come
+    from `flash_tiles`."""
+    def choice(impl_, why):
+        return AttentionChoice(impl_, *flash_tiles(seq, block_q, block_kv),
+                               why)
+
+    shape = f"S={seq} D={head_dim}" + (" segments" if segments else "")
+    if impl != "auto":
+        return choice(impl, shape if impl == "pallas" else impl)
+    if backend != "tpu":
+        return choice("reference", f"auto: backend {backend}")
+    if seq >= _MEASURED_SEQ[1]:
+        return choice("pallas", shape)
+    scores = 4 * batch * heads * seq * seq
+    mib = f"{scores / 2**20:.0f} MiB of scores"
+    if 2 * scores > _DENSE_BYTES_MAX:
+        return choice("pallas", f"{shape}, dense scores over 1 GB")
+    if not (jnp.dtype(dtype) == jnp.bfloat16 and seq >= _MEASURED_SEQ[0]
+            and _MEASURED_HEAD_DIM[0] <= head_dim <= _MEASURED_HEAD_DIM[1]):
+        return choice("reference", f"auto: {shape} {jnp.dtype(dtype).name} "
+                                   "not measured")
+    if scores >= _DENSE_SCORES_SPILL:
+        return choice("pallas", f"{shape}, {mib}")
+    return choice("reference", f"auto: {mib} stay on the chip")
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     softmax_scale: Optional[float] = None,
-                    block_q: int = 512, block_kv: int = 512,
+                    block_q: Optional[int] = None,
+                    block_kv: Optional[int] = None,
                     segment_ids: Optional[jnp.ndarray] = None,
                     head_fold: bool = False):
     """Flash attention on [B, S, H, D] tensors (GQA-aware).
 
     Returns [B, Sq, H, D]. Drop-in for ops.attention.dot_product_attention's
-    causal/bidirectional paths.
+    causal/bidirectional paths. Tiles left unset come from `flash_tiles`.
 
     segment_ids: optional [B, S] int packing map — attention is restricted
     to within-segment (packed sequences, reference THD/packed_seq_params
@@ -1255,6 +1344,7 @@ def flash_attention(q, k, v, causal: bool = True,
     b, sq, h, d = q.shape
     if softmax_scale is None:
         softmax_scale = 1.0 / (d ** 0.5)
+    block_q, block_kv = flash_tiles(sq, block_q, block_kv)
     qt = jnp.swapaxes(q, 1, 2)   # [B,H,S,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

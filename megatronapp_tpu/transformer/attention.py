@@ -28,11 +28,18 @@ from megatronapp_tpu.config.transformer_config import (
 )
 from megatronapp_tpu.ops.attention import dot_product_attention
 from megatronapp_tpu.ops.normalization import rms_norm
+from megatronapp_tpu.ops.pallas import flash_attention as fa
 from megatronapp_tpu.ops import rotary
 from megatronapp_tpu.scope.hooks import scope_capture
 from megatronapp_tpu.transformer import eva
 
 _announced: set = set()
+
+
+def _backend() -> str:
+    """The backend `choose_attention` is told (tests/test_chip_compile.py
+    compiles for a described chip from a CPU process and says "tpu")."""
+    return jax.default_backend()
 
 
 def _announce(site: str, impl: str, interpreted=None) -> None:
@@ -478,26 +485,20 @@ def attention_forward(
     else:
         from megatronapp_tpu.parallel.collectives import current_manual_axes
 
-        impl = cfg.attention_impl
-        if impl == "auto":
-            # Crossover: dense XLA attention below flash_min_seq (the
-            # flash bwd kernels lose to the fused dense backward at
-            # short S with D=64 — PERF.md), flash above it. The dense
-            # fallback is memory-guarded: it materializes fp32
-            # [B, H, S, S] scores+probs, so configs whose score tensors
-            # exceed ~1 GB per device keep the O(S)-memory flash kernel
-            # regardless of S.
-            dense_bytes = 2 * 4 * b * nq * s * s
-            if ctx is not None and ctx.num_devices > 1:
-                # The [B,H,S,S] score tensor shards only over dp/ep/tp
-                # (batch and heads) — pp/cp devices each hold a full
-                # copy, so dividing by the whole mesh would undercount
-                # per-device memory by up to pp*cp x and OOM a config
-                # just below flash_min_seq.
-                dense_bytes //= max(1, ctx.dp * ctx.ep * ctx.tp)
-            impl = ("pallas" if jax.default_backend() == "tpu"
-                    and (s >= cfg.flash_min_seq or dense_bytes > 1 << 30)
-                    else "reference")
+        # Per-device batch and heads: the [B,H,S,S] scores of the dense
+        # path shard only over dp/ep/tp; pp/cp devices each hold a full
+        # copy.
+        multi_device = ctx is not None and ctx.num_devices > 1
+        b_dev, nq_dev = b, nq
+        if multi_device:
+            b_dev = -(-b // (ctx.dp * ctx.ep))
+            nq_dev = -(-nq // ctx.tp)
+        choice = fa.choose_attention(
+            impl=cfg.attention_impl, batch=b_dev, seq=s, heads=nq_dev,
+            head_dim=d, dtype=q.dtype, segments=segment_ids is not None,
+            backend=_backend(),
+            block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv)
+        impl = choice.impl
         # GSPMD cannot partition a pallas_call (it would replicate full
         # attention on every device), so the kernel must be placed
         # explicitly: on a multi-device mesh we shard_map it manually over
@@ -511,17 +512,16 @@ def attention_forward(
             and kv_cache is None and not in_manual
             and cfg.attn_mask_type in (AttnMaskType.causal,
                                        AttnMaskType.bidirectional))
-        multi_device = ctx is not None and ctx.num_devices > 1
         if use_flash and multi_device:
             dp_ep = ctx.dp * ctx.ep
             use_flash = (b % dp_ep == 0 and nq % ctx.tp == 0
                          and nkv % ctx.tp == 0)
         if use_flash:
-            from megatronapp_tpu.ops.pallas.flash_attention import (
-                _interpret, flash_attention,
-            )
-            _announce("self-attention", "pallas flash kernel", _interpret())
+            _announce("self-attention",
+                      f"pallas flash kernel, {choice.block_q}x"
+                      f"{choice.block_kv}, {choice.why}", fa._interpret())
             causal = cfg.attn_mask_type == AttnMaskType.causal
+            tiles = dict(block_q=choice.block_q, block_kv=choice.block_kv)
             if multi_device:
                 from jax.sharding import PartitionSpec as P
                 from megatronapp_tpu.config.parallel_config import (
@@ -538,10 +538,8 @@ def attention_forward(
                 if segment_ids is None:
                     # manual-ok: use_flash requires `not in_manual` above
                     flash = jax.jit(shard_map_compat(
-                        lambda q_, k_, v_: flash_attention(
-                            q_, k_, v_, causal=causal,
-                            block_q=cfg.flash_block_q,
-                            block_kv=cfg.flash_block_kv,
+                        lambda q_, k_, v_: fa.flash_attention(
+                            q_, k_, v_, causal=causal, **tiles,
                             head_fold=getattr(cfg, "flash_head_fold",
                                               False)),
                         ctx.shard_map_mesh,
@@ -551,24 +549,21 @@ def attention_forward(
                 else:
                     # manual-ok: use_flash requires `not in_manual` above
                     flash = jax.jit(shard_map_compat(
-                        lambda q_, k_, v_, s_: flash_attention(
-                            q_, k_, v_, causal=causal,
-                            block_q=cfg.flash_block_q,
-                            block_kv=cfg.flash_block_kv, segment_ids=s_),
+                        lambda q_, k_, v_, s_: fa.flash_attention(
+                            q_, k_, v_, causal=causal, **tiles,
+                            segment_ids=s_),
                         ctx.shard_map_mesh,
                         in_specs=(spec, spec, spec, seg_spec),
                         out_specs=spec))
                     attn_out = flash(q, k, v, segment_ids)
             else:
-                attn_out = flash_attention(
-                    q, k, v, causal=causal,
-                    block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+                attn_out = fa.flash_attention(
+                    q, k, v, causal=causal, **tiles,
                     segment_ids=segment_ids,
                     head_fold=getattr(cfg, "flash_head_fold", False))
         else:
             if impl != "pallas":
-                _announce("self-attention",
-                          f"xla dense ({cfg.attention_impl})")
+                _announce("self-attention", f"xla dense ({choice.why})")
             else:
                 _announce("self-attention", "xla dense, pallas asked for "
                           "but " + ("unavailable inside a manual region"

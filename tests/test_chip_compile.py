@@ -43,12 +43,15 @@ def one_chip(topo):
 
 @pytest.fixture
 def chip_compile(monkeypatch):
-    """Kernels compiled (not interpreted), the persistent compilation cache
-    off: an entry written for a described chip cannot be read back."""
+    """Kernels compiled (not interpreted), `auto` attention told it is on a
+    TPU (it chooses what the chip would run), the persistent compilation
+    cache off: an entry written for a described chip cannot be read back."""
     from jax.experimental.compilation_cache import compilation_cache
     from megatronapp_tpu.ops.pallas import flash_attention, kernel_gen
+    from megatronapp_tpu.transformer import attention
     monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
     monkeypatch.setattr(kernel_gen, "_interpret", lambda: False)
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -199,10 +202,12 @@ def test_paged_attention_latent_tp2(topo, one_chip, chip_compile, q_len):
 # The steps the entry points jit
 # ---------------------------------------------------------------------------
 
-def _train_step_for(devices, parallel, model, micro, global_batch, seq):
+def _train_step_for(devices, parallel, model, micro, global_batch, seq,
+                    segments=False):
     """(jitted step, abstract state, abstract batch, ctx) exactly as
     pretrain_gpt assembles them, on a mesh of described devices. The state
-    is only traced (eval_shape), never placed."""
+    is only traced (eval_shape), never placed. `segments`: packed
+    documents, a `segment_ids` row beside the tokens."""
     from megatronapp_tpu.config.training_config import (
         OptimizerConfig, TrainingConfig,
     )
@@ -242,6 +247,8 @@ def _train_step_for(devices, parallel, model, micro, global_batch, seq):
              "labels": _sds(shape, jnp.int32, bsh),
              "loss_mask": _sds(shape, jnp.float32, bsh),
              "position_ids": _sds(shape, jnp.int32, bsh)}
+    if segments:
+        batch["segment_ids"] = _sds(shape, jnp.int32, bsh)
     return step, state, batch, ctx
 
 
@@ -269,26 +276,58 @@ def test_gpt2_125m_loss_and_grad_with_flash(one_chip, chip_compile):
             + mem.output_size_in_bytes) < 16 * 2**30
 
 
-@pytest.mark.parametrize("impl", ["auto", "pallas"])
-def test_train_step_one_chip(topo, chip_compile, impl):
+def _gpt2_medium(**kw):
+    """perfbench/configs/gpt2-medium.json's widths: 16 heads of 64, FFN
+    4096, 1024 positions (24 layers in the cell)."""
+    return PRESETS["gpt2-125m"](hidden_size=1024, num_attention_heads=16,
+                                ffn_hidden_size=4096, **kw)
+
+
+# (model, micro-batch, packed segments, the line `auto` prints)
+ONE_CHIP_STEPS = {
+    "auto": (lambda: PRESETS["gpt2-125m"](num_layers=2), 4, False,
+             "1024x1024, S=1024 D=64, 192 MiB of scores (compiled)"),
+    "pallas": (lambda: PRESETS["gpt2-125m"](num_layers=2,
+                                            attention_impl="pallas"),
+               4, False, "1024x1024, S=1024 D=64 (compiled)"),
+    # train.gpt2-medium.packed-1k: micro-batch 4 x 1024, 16 heads of 64,
+    # packed documents, selective recomputation; depth cut 24 -> 2
+    "auto-cell-1": (lambda: _gpt2_medium(num_layers=2,
+                                         remat_policy="selective"),
+                    4, True, "1024x1024, S=1024 D=64 segments, 256 MiB of "
+                             "scores (compiled)"),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_CHIP_STEPS))
+def test_train_step_one_chip(topo, chip_compile, capsys, case):
     """make_train_step's jit (optimizer, donation, NaN guard and all) on
     the one-device mesh build_mesh lays out for a TPU; real widths, depth
-    cut to 2 layers to keep the test short."""
-    model = PRESETS["gpt2-125m"](num_layers=2, attention_impl=impl)
+    cut to 2 layers to keep the test short. `auto` is told its backend is
+    a TPU (`chip_compile`) and takes the flash kernels at S 1024, as the
+    chip's own process does, inside the chip's memory."""
+    from megatronapp_tpu.transformer import attention
+    model, micro, segments, line = ONE_CHIP_STEPS[case]
     step, state, batch, ctx = _train_step_for(
-        topo.devices[:1], ParallelConfig(), model, micro=4, global_batch=4,
-        seq=1024)
+        topo.devices[:1], ParallelConfig(), model(), micro=micro,
+        global_batch=micro, seq=1024, segments=segments)
+    attention._announced.clear()
     with ctx.mesh:
         compiled = step.lower(state, batch).compile()
-    assert (_custom_calls(compiled) > 0) == (impl == "pallas")
-    if impl == "pallas":
-        _assert_kernels_named(compiled, "flash_fwd", "flash_bwd_dq",
-                              "flash_bwd_dkv")
+    assert ("attention: self-attention -> pallas flash kernel, " + line
+            in capsys.readouterr().out)
+    _assert_kernels_named(compiled, "flash_fwd", "flash_bwd_dq",
+                          "flash_bwd_dkv")
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16 * 2**30
 
 
 def test_train_step_tp2_dp2(topo, chip_compile):
     """The sharded step of `chip_smoke.py --chips 4`: tp 2 x dp 2 on the
-    2x2 mesh create_device_mesh lays out, collectives and all."""
+    2x2 mesh create_device_mesh lays out, collectives and all. A device's
+    share is 2 x 1024 at 6 heads, 48 MiB of dense scores: `auto` keeps XLA's
+    dense attention there, on a TPU too."""
     model = PRESETS["gpt2-125m"](num_layers=2)
     step, state, batch, ctx = _train_step_for(
         topo.devices, ParallelConfig(tensor_parallel=2), model, micro=2,
@@ -300,6 +339,7 @@ def test_train_step_tp2_dp2(topo, chip_compile):
         compiled = step.lower(state, batch).compile()
     text = compiled.as_text()
     assert "all-reduce" in text
+    assert _custom_calls(compiled) == 0
     per_device = compiled.memory_analysis()
     assert per_device.argument_size_in_bytes < 16 * 2**30
 

@@ -81,9 +81,9 @@ def test_entry_points_call_the_helper():
 # ---------------------------------------------------------------------------
 
 def _train_lines(losses, impl="pallas", device=TPU, mode="(compiled)"):
-    att = ("attention: self-attention -> pallas flash kernel " + mode
-           if impl == "pallas" else
-           "attention: self-attention -> xla dense (auto)")
+    att = ("attention: self-attention -> pallas flash kernel, 256x512, "
+           "S=1024 D=64 " + mode if impl == "pallas" else
+           f"attention: self-attention -> xla dense ({impl})")
     lines = [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device), att]
     for i, loss in enumerate(losses):
         lines.append(f"iter {i+1:6d}/{len(losses)} | loss {loss:.4f} | "
@@ -106,6 +106,22 @@ def test_check_train_passes_on_a_good_run():
     assert out["steps"] == 12 and out["first_step_s"] == 5.0
     assert out["step_s_median_after_warmup"] == pytest.approx(0.05)
     assert out["device"]["platform"] == "tpu"
+
+
+@pytest.mark.parametrize("impl,tiny,said,ok", [
+    # the real size on the chip: `auto` takes the flash kernels
+    ("auto", False, "pallas", True),
+    ("auto", False, "auto: S=1024 under 2048", False),
+    # --tiny: S 64, dense under `auto` wherever it runs
+    ("auto", True, "auto: S=64 under 512", True),
+    ("auto", True, "auto: backend cpu", True),
+    # the forced side of the A/B
+    ("reference", False, "reference", True),
+    ("reference", False, "pallas", False),
+])
+def test_check_train_follows_what_auto_announces(impl, tiny, said, ok):
+    out = chip_smoke.check_train(0, _train_lines(FALLING, said), impl, tiny)
+    assert out["ok"] == ok, out["problems"]
 
 
 @pytest.mark.parametrize("case,needle", [
@@ -142,7 +158,7 @@ def _phase(name, ok=True, device=TPU, **kw):
 
 def test_verdict():
     good = [_phase("train-auto", losses=[10.98]),
-            _phase("train-pallas", losses=[10.981]), _phase("server")]
+            _phase("train-reference", losses=[10.981]), _phase("server")]
     ok, device, reasons = chip_smoke.verdict(good, 1)
     assert ok and not reasons
     assert device == {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
@@ -157,7 +173,7 @@ def test_verdict():
     assert "not a TPU" in refused(good[:2] + [_phase("server", device=cpu)])
     assert "this run is for 4" in refused(good, want=4)
     assert "differ by" in refused(
-        [good[0], _phase("train-pallas", losses=[11.2]), good[2]])
+        [good[0], _phase("train-reference", losses=[11.2]), good[2]])
     assert "no phase ran" in refused([])
 
 
@@ -180,7 +196,7 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
     assert lines[-1] == CONTRACT_LINE
     phases = [json.loads(ln[len("phase: "):]) for ln in lines
               if ln.startswith("phase: ")]
-    assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
+    assert [p["phase"] for p in phases] == ["train-auto", "train-reference",
                                             "server", "hybrid", "eva"]
 
 
@@ -315,7 +331,7 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
     assert train["steps"] == 20 and train["compile_s"] > 0
-    assert any("pallas flash kernel (interpreted)" in a
+    assert any("pallas flash kernel" in a and "(interpreted)" in a
                for a in train["attention"])
     assert abs(phases[0]["losses"][0] - train["losses"][0]) < 1e-2
     server = phases[2]

@@ -10,7 +10,9 @@ import pytest
 
 from megatronapp_tpu.config.transformer_config import AttnMaskType
 from megatronapp_tpu.ops.attention import dot_product_attention
-from megatronapp_tpu.ops.pallas.flash_attention import flash_attention
+from megatronapp_tpu.ops.pallas.flash_attention import (
+    choose_attention, flash_attention, flash_tiles,
+)
 
 
 def make_qkv(b=2, s=128, h=4, hkv=4, d=32, dtype=jnp.float32):
@@ -226,3 +228,98 @@ class TestFlashAttention:
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-5)
+
+
+def _choose(**kw):
+    facts = dict(impl="auto", batch=4, seq=1024, heads=16, head_dim=64,
+                 dtype=jnp.bfloat16, segments=True, backend="tpu")
+    facts.update(kw)
+    return choose_attention(**facts)
+
+
+@pytest.mark.parametrize("facts,impl,tiles", [
+    # train.gpt2-medium.packed-1k: micro-batch 4 x 1024, 16 heads of 64:
+    # 256 MiB of dense scores, one tile a sequence
+    ({}, "pallas", (1024, 1024)),
+    ({"segments": False}, "pallas", (1024, 1024)),
+    # train.gpt3-2.7b.tp2dp2-2k, one chip's share: 1 x 2048, 16 heads of 80
+    ({"batch": 1, "seq": 2048, "head_dim": 80}, "pallas", (512, 512)),
+    # the same bytes from another batch and S; 128 MiB is the least measured
+    # to leave the chip, 108 MiB the most measured to stay
+    ({"batch": 16, "seq": 512, "head_dim": 128}, "pallas", (512, 512)),
+    ({"batch": 2}, "pallas", (1024, 1024)),
+    ({"batch": 3, "seq": 768}, "reference", None),
+    ({"batch": 1}, "reference", None),
+    ({"batch": 4, "seq": 512}, "reference", None),
+    # from S 2048 on the kernels run whatever the bytes, as they always have
+    ({"batch": 1, "heads": 4, "seq": 2048}, "pallas", (512, 512)),
+    # nobody measured: short sequences, float32 compute, heads outside
+    # 64..128 keep XLA's dense attention under S 2048
+    ({"seq": 256, "batch": 64}, "reference", None),
+    ({"dtype": jnp.float32}, "reference", None),
+    ({"head_dim": 32}, "reference", None),
+    ({"head_dim": 256}, "reference", None),
+    ({"seq": 2048, "dtype": jnp.float32}, "pallas", (512, 512)),
+    ({"seq": 4096, "batch": 1, "heads": 1}, "pallas", (512, 512)),
+    # dense scores and probabilities past 1 GB: flash whatever S and dtype
+    ({"batch": 128, "seq": 256, "heads": 32}, "pallas", (256, 256)),
+    ({"batch": 32, "dtype": jnp.float32}, "pallas", (1024, 1024)),
+    # another backend keeps XLA's dense attention
+    ({"backend": "cpu"}, "reference", None),
+    ({"backend": "gpu", "seq": 4096}, "reference", None),
+    # forced either way; explicit tiles are honoured, and clamped to S
+    ({"impl": "pallas", "seq": 64, "backend": "cpu"}, "pallas", (64, 64)),
+    ({"impl": "reference"}, "reference", None),
+    ({"block_q": 128, "block_kv": 2048}, "pallas", (128, 1024)),
+    ({"block_kv": 256}, "pallas", (1024, 256)),
+])
+def test_choose_attention(facts, impl, tiles):
+    choice = _choose(**facts)
+    assert choice.impl == impl, choice
+    if tiles is not None:
+        assert (choice.block_q, choice.block_kv) == tiles, choice
+    assert choice.why
+
+
+def test_choose_attention_names_what_decided():
+    assert _choose().why == "S=1024 D=64 segments, 256 MiB of scores"
+    assert _choose(seq=2048, batch=1).why == "S=2048 D=64 segments"
+    assert "64 MiB of scores stay" in _choose(batch=1).why
+    assert "1 GB" in _choose(batch=128, seq=256, heads=32).why
+    assert "float32" in _choose(dtype=jnp.float32).why
+    assert "cpu" in _choose(backend="cpu").why
+
+
+@pytest.mark.parametrize("seq,tiles", [
+    (64, (64, 64)), (768, (768, 768)), (1024, (1024, 1024)),
+    (1536, (512, 512)), (2048, (512, 512)), (8192, (512, 512))])
+def test_flash_tiles(seq, tiles):
+    assert flash_tiles(seq) == tiles
+
+
+def test_segment_grads_unequal_tiles():
+    """Packed-segment gradients through the transposed kernels at UNEQUAL
+    tiles (256 x 512 at S 1024, D 64, 2 heads, batch 1): a causal grid whose
+    query and key/value tiles differ, with segment edges inside tiles and on
+    a tile's edge. (The tiles `flash_tiles` gives this S, one 1024 x 1024,
+    run under the same oracle.)"""
+    s, h, d = 1024, 2, 64
+    q, k, v = make_qkv(b=1, s=s, h=h, hkv=h, d=d)
+    seg = jnp.asarray(np.repeat(np.arange(4), [300, 212, 412, 100]))[None]
+
+    def oracle(args):
+        qq, kk, vv = args
+        sc = jnp.einsum("bqhd,bkhd->bhqk", qq, kk) / (d ** 0.5)
+        mask = (seg[:, None, :, None] == seg[:, None, None, :])
+        mask = mask & jnp.tril(jnp.ones((s, s), jnp.bool_))[None, None]
+        p = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
+        return jnp.sum(jnp.einsum("bhqk,bkhd->bqhd", p, vv) ** 2)
+
+    gr = jax.grad(oracle)((q, k, v))
+    for tiles in ({"block_q": 256, "block_kv": 512}, {}):
+        gf = jax.grad(lambda args: jnp.sum(flash_attention(
+            *args, causal=True, segment_ids=seg, **tiles) ** 2))((q, k, v))
+        for a, b in zip(gf, gr):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5)
