@@ -1,8 +1,9 @@
 """The quickest proof that the system still starts on the chip.
 
     python chip_smoke.py             # one chip: trainer twice, the server,
-                                     # then a tiny hybrid state-space engine
-                                     # and a tiny EVA-attention engine
+                                     # then a tiny hybrid state-space engine,
+                                     # a tiny EVA-attention engine and a
+                                     # tiny double-layer expert-share engine
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -25,6 +26,12 @@ vocab 50304), random weights from the entry points' own seeds:
   pooled row for every 16 older ones) through the paged engine: requests
   that close windows, one ``eva_summary`` a layer loop in the compiled decode
   step, a slot's blocks bounded by its rows and not its length.
+
+- share: a tiny shortcut-connected double-layer model (two latent-attention
+  sublayers with a query latent a layer, a router over 8 computing and 4
+  zero-compute experts of which 4 are held) through the paged engine: two
+  latent kernels a layer loop over pools of 2 planes a layer, every pick
+  counted as held, absent or zero-compute.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -740,6 +747,128 @@ def check_eva(rc, lines, tiny=False):
 
 
 # ---------------------------------------------------------------------------
+# Phase: a double layer with a share of its experts, tiny widths
+# ---------------------------------------------------------------------------
+
+SHARE = dict(num_layers=2, num_moe_experts=8, moe_zero_experts=4,
+             moe_router_topk=3, moe_experts_held=(0, 4))
+SHARE_REQUESTS = ((40, 12), (9, 12), (70, 12))      # (prompt, new tokens)
+
+
+def phase_share(tiny):
+    rc, tr = _run_child("share", ["--child", "share"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_share(rc, tr.lines, tiny)
+
+
+def child_share(tiny):
+    """In the child: a shortcut-connected double-layer model (latent
+    attention at the published column widths 512 + 64 and 128-wide heads,
+    4 heads, a query latent of 128, both scale corrections; 8 + 4 experts
+    top-3 with a selection bias, 4 held) serves three requests through
+    DynamicInferenceEngine(paged=True) on the device, and the compiled
+    decode step says what it holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    cfg = TransformerConfig(
+        hidden_size=256, num_attention_heads=4, ffn_hidden_size=512,
+        vocab_size=512, max_position_embeddings=128,
+        normalization=NormKind.rmsnorm, activation=ActivationKind.swiglu,
+        add_bias_linear=False, untie_embeddings_and_output_weights=True,
+        multi_latent_attention=True, q_lora_rank=128, kv_lora_rank=512,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        moe_ffn_hidden_size=128, moe_router_norm_topk_prob=False,
+        moe_routed_scaling_factor=6.0, moe_router_selection_bias=True,
+        moe_shortcut_double_layer=True, params_dtype=jnp.bfloat16, **SHARE)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
+                                 paged=True)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n, new in SHARE_REQUESTS:
+        eng.add_request(rng.integers(0, 512, n).astype(np.int32), new,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), bool), None).compile()
+    text = compiled.as_text()
+    pools = eng._pools()
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 512 for v in out.values()
+                             for t in v)),
+        "moe": eng.stats_snapshot()["moe"],
+        "latent_kernel_calls": sum(
+            1 for ln in text.splitlines() if "custom-call(" in ln
+            and " %paged_decode_latent" in ln.split("=")[0]),
+        "pool_shapes": [list(p.shape) for p in pools],
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_share(rc, lines, tiny=False):
+    out = {"phase": "share", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    interpreted = tiny and dev and dev[0]["platform"] != "tpu"
+    # The layers are one scanned stack of double layers: the loop's body
+    # holds one latent kernel a sublayer. (The interpreter inlines a kernel
+    # into plain HLO: nothing to count.)
+    if not interpreted and out["latent_kernel_calls"] != 2:
+        out["problems"].append(
+            f"{out['latent_kernel_calls']} paged_decode_latent custom "
+            "calls in the compiled decode step, not two a layer loop")
+    if [sh[0] for sh in out["pool_shapes"]] != [4, 4]:
+        out["problems"].append(f"pools {out['pool_shapes']}: not two "
+                               "planes a layer of 2 layers")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    want = sum(n + new for n, new in SHARE_REQUESTS)
+    if out["tokens"] != want or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back, not "
+                               f"{want}, or one outside the vocabulary")
+    moe = out["moe"] or {}
+    picks = moe.get("tokens", 0) * SHARE["moe_router_topk"] \
+        * SHARE["num_layers"]
+    parts = [moe.get(k, 0) for k in ("assignments_zero", "assignments_here",
+                                     "assignments_absent")]
+    if not picks or sum(parts) != picks or not all(parts) \
+            or moe.get("experts_here") != SHARE["moe_experts_held"][1]:
+        out["problems"].append(
+            f"moe counters {moe}: the picks are not held + absent + "
+            f"zero-compute = {picks}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Phase: four chips (only with --chips 4)
 # ---------------------------------------------------------------------------
 
@@ -938,7 +1067,8 @@ def run(chips, tiny):
                                     tiny),
                 lambda: phase_server(tiny),
                 lambda: phase_hybrid(tiny),
-                lambda: phase_eva(tiny)]
+                lambda: phase_eva(tiny),
+                lambda: phase_share(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -961,7 +1091,8 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
-    ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva"],
+    ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva",
+                                        "share"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -976,6 +1107,9 @@ def main(argv=None):
         return 0
     if args.child == "eva":
         child_eva(args.tiny)
+        return 0
+    if args.child == "share":
+        child_share(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

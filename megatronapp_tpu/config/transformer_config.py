@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -64,6 +64,11 @@ class TransformerConfig:
     # multiple (reference --make-vocab-size-divisible-by): inference masks
     # logits for padded ids so sampling cannot emit out-of-vocab tokens.
     true_vocab_size: Optional[int] = None
+    # The published vocabulary of which this model's vocab_size rows are
+    # one rank's slice (embedding and head sharded over the vocabulary in
+    # the deployment). A sliced vocabulary is simply a smaller one: ids,
+    # logits and sampling run over vocab_size. None: nothing is sliced.
+    vocab_slice_of: Optional[int] = None
     max_position_embeddings: int = 2048
 
     # Normalization / activation / position embedding.
@@ -117,6 +122,27 @@ class TransformerConfig:
     # rest MoE (HF `first_k_dense_replace`; DeepSeek-V2/V3: 1 or 3). They
     # run as a prologue before the scanned stack (params["lead_block"]).
     moe_first_k_dense: int = 0
+    # Model facts of a router wider than the experts a layer computes (HF
+    # `longcat_flash`), none of them a tuning knob:
+    # moe_zero_experts: zero-compute experts behind the num_moe_experts
+    #   computing ones in the router's softmax (HF `zero_expert_num`, type
+    #   identity): a pick of one returns the MoE's own input times its weight
+    #   and costs no GEMM row.
+    # moe_router_selection_bias: the router holds a bias b ("router_bias",
+    #   one entry a routed expert, zero-compute ones included): the top-k is
+    #   taken on p + b, the weights stay p (HF `e_score_correction_bias`).
+    # moe_experts_held: (first, count), the experts of the published
+    #   num_moe_experts whose weights this layer holds (one rank of an
+    #   expert-parallel deployment). It routes over all of them, computes its
+    #   own experts' terms and the identity term, and leaves the others' out.
+    # moe_shortcut_double_layer: a layer is two attention sublayers and two
+    #   dense FFNs, with one MoE on the first sublayer's normed output whose
+    #   result is added after the second FFN (transformer/block.py); it owns
+    #   two planes of the KV pools. moe_first_k_dense stays 0 with it.
+    moe_zero_experts: int = 0
+    moe_router_selection_bias: bool = False
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    moe_shortcut_double_layer: bool = False
 
     # Hybrid state-space stacks (HF `jamba`: attn_layer_period /
     # attn_layer_offset): layer i attends iff i % period == offset, and
@@ -161,6 +187,11 @@ class TransformerConfig:
     multi_latent_attention: bool = False
     q_lora_rank: Optional[int] = None
     kv_lora_rank: int = 512
+    # HF `mla_scale_q_lora` / `mla_scale_kv_lora`: the expanded query and
+    # the normed latent are multiplied by sqrt(hidden_size / rank)
+    # (transformer/mla.py); the latent is cached scaled.
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     qk_head_dim: int = 128
     qk_pos_emb_head_dim: int = 64
     v_head_dim: int = 128
@@ -307,6 +338,43 @@ class TransformerConfig:
                 f"moe_first_k_dense={self.moe_first_k_dense} needs an MoE "
                 f"model with moe_layer_freq 1 and more than that many "
                 f"layers (num_layers={self.num_layers})")
+        if ((self.moe_zero_experts or self.moe_router_selection_bias
+             or self.moe_experts_held or self.moe_shortcut_double_layer)
+                and not (self.is_moe and self.moe_layer_freq == 1
+                         and self.moe_capacity_factor is None)):
+            raise ValueError(
+                "moe_zero_experts, moe_router_selection_bias, "
+                "moe_experts_held and moe_shortcut_double_layer are facts of "
+                "a dropless MoE model with experts in every layer "
+                "(num_moe_experts, moe_layer_freq 1, no capacity factor)")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.num_moe_experts):
+                raise ValueError(
+                    f"moe_experts_held={self.moe_experts_held} is no "
+                    f"(first, count) within num_moe_experts="
+                    f"{self.num_moe_experts}")
+        if self.moe_shortcut_double_layer and (
+                self.moe_first_k_dense or self.mtp_num_layers
+                or self.moe_shared_expert_intermediate_size
+                or self.heterogeneous_layers_config_json):
+            raise ValueError(
+                "the shortcut-connected double layer is one uniform stack "
+                "with no shared expert: no moe_first_k_dense, MTP or "
+                "heterogeneous block configs")
+        if (self.mla_scale_q_lora and not self.q_lora_rank) or (
+                (self.mla_scale_q_lora or self.mla_scale_kv_lora)
+                and not self.multi_latent_attention):
+            raise ValueError(
+                "mla_scale_q_lora / mla_scale_kv_lora scale the latents of "
+                "multi_latent_attention (the first needs q_lora_rank)")
+        if self.vocab_slice_of is not None and not (
+                self.vocab_size <= self.vocab_slice_of):
+            raise ValueError(
+                f"vocab_slice_of={self.vocab_slice_of} is the published "
+                f"vocabulary that vocab_size={self.vocab_size} rows are a "
+                "slice of")
         if self.attn_layer_period is not None:
             if not 0 <= self.attn_layer_offset < self.attn_layer_period:
                 raise ValueError(
@@ -373,6 +441,29 @@ class TransformerConfig:
         """Layers that attend, and so own a plane of the KV cache."""
         return sum(self.layer_is_attention(i)
                    for i in range(self.num_layers))
+
+    @property
+    def kv_planes(self) -> int:
+        """Planes of the paged KV pools: one an attention sublayer."""
+        return self.num_attention_layers * (
+            2 if self.moe_shortcut_double_layer else 1)
+
+    @property
+    def moe_router_width(self) -> int:
+        """What the router's softmax runs over: the published computing
+        experts and, behind them, the zero-compute ones."""
+        return (self.num_moe_experts or 0) + self.moe_zero_experts
+
+    @property
+    def moe_picks_unheld(self) -> bool:
+        """Whether a router's pick may fall on no expert whose weights are
+        held here: on a zero-compute expert, or on one held elsewhere."""
+        return self.moe_experts_held is not None or self.moe_zero_experts > 0
+
+    @property
+    def moe_experts_here(self) -> Tuple[int, int]:
+        """(first, count) of the computing experts whose weights are held."""
+        return self.moe_experts_held or (0, self.num_moe_experts or 0)
 
     @property
     def num_ssm_layers(self) -> int:

@@ -78,7 +78,7 @@ from megatronapp_tpu.transformer.block import (
     hybrid_layer_loop, hybrid_layer_params, layer_forward,
 )
 from megatronapp_tpu.transformer.eva import table_rows
-from megatronapp_tpu.transformer.moe import StackedLayer
+from megatronapp_tpu.transformer.moe import HELD_COUNTS, StackedLayer
 from megatronapp_tpu.utils import chaos
 from megatronapp_tpu.utils import metrics as telemetry
 from megatronapp_tpu.utils.flops import tpu_roofs
@@ -272,6 +272,21 @@ def _decode_step(params, tokens, cache, lengths, active,
     return logits, new_caches
 
 
+def _moe_of(tree):
+    """The "moe" params of a layer or of a stack of layers (None without):
+    a shortcut-connected double layer keeps them in its first half."""
+    if not isinstance(tree, dict):
+        return None
+    return tree.get("first", tree).get("moe")
+
+
+def _with_moe(tree, moe):
+    """`tree` with `moe` where _moe_of found the old one."""
+    if "first" in tree:
+        return dict(tree, first=dict(tree["first"], moe=moe))
+    return dict(tree, moe=moe)
+
+
 def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
                        ctx=None, rows=None):
     """The layer loop of both paged steps: h through every layer, each
@@ -309,9 +324,14 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     plane (kv_plane), a state-space layer the state pools, its plane of
     them and `rows`, the slots of h's rows (None: row b is slot b).
 
+    A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
+    one step of the same scan: layer_forward runs its two attention
+    sublayers into planes 2·lid and 2·lid + 1 of the pools [2L, NB, ...].
+
     Returns (h, moe, (k, v[, ssm, conv][, k_scales, v_scales])); moe is
     None for a dense model, else int32 [2]: the MoE layers' routing_counts
-    summed."""
+    summed ([6], moe.HELD_COUNTS, on a model that holds a share of its
+    experts or has zero-compute ones)."""
     if cfg.attn_layer_period is not None:
         if lora is not None or ctx is not None:
             raise ValueError("a hybrid state-space stack serves on one "
@@ -339,18 +359,20 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     lead = cfg.moe_first_k_dense
     block = params["block"]
     stacks = {}
-    if ctx is None and isinstance(block, dict) and "moe" in block:
-        stacks = {k: w for k, w in block["moe"].items()
+    moe = _moe_of(block)
+    if ctx is None and moe is not None:
+        stacks = {k: w for k, w in moe.items()
                   if k in ("fc1_kernel", "fc2_kernel")
                   and not isinstance(w, dict)}
-        block = dict(block, moe={k: w for k, w in block["moe"].items()
-                                 if k not in stacks})
+        block = _with_moe(block, {k: w for k, w in moe.items()
+                                  if k not in stacks})
 
     def body(carry, xs):
         hh, kv, kvs = carry
         layer_p, lid, banks = xs
-        if stacks and "moe" in layer_p:
-            layer_p = dict(layer_p, moe=dict(layer_p["moe"], **{
+        moe_p = _moe_of(layer_p)
+        if stacks and moe_p is not None:
+            layer_p = _with_moe(layer_p, dict(moe_p, **{
                 k: StackedLayer(w, lid - lead) for k, w in stacks.items()}))
         ll = None
         if lora is not None:
@@ -367,6 +389,9 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     (h, pages, scales), moe = jax.lax.scan(
         body, carry, (block, jnp.arange(lead, cfg.num_layers), banks),
         unroll=cfg.scan_unroll)
+    # The layers' counts add up, but for the last of a share's
+    # (moe.HELD_COUNTS): Σ over MoE layers of the most rows one held expert
+    # got is a sum of maxima already.
     moe = jnp.sum(moe, axis=0) if cfg.is_moe else None
     return h, moe, pages + (scales or ())
 
@@ -632,8 +657,10 @@ def prefill_call_costs(cfg: TransformerConfig, params):
             continue
         share = 1.0
         if (cfg.is_moe and leaf.ndim == 4
-                and leaf.shape[1] == cfg.num_moe_experts):
-            share = cfg.moe_router_topk / cfg.num_moe_experts
+                and leaf.shape[1] == cfg.moe_experts_here[1]):
+            # a position's top-k fall on the router's whole width, of
+            # which this stack holds [1] experts
+            share = cfg.moe_router_topk / cfg.moe_router_width
         stream += leaf.size * leaf.dtype.itemsize
         flops += 2.0 * leaf.size * share
     return stream, flops
@@ -794,6 +821,29 @@ class DynamicInferenceEngine:
                           "rows_full_attention": 0,
                           "summary_rows_written": 0}
 
+        # A shortcut-connected double layer owns two planes of the paged
+        # pools, and a layer that holds a share of the experts runs
+        # without its exchange: what would need more refuses in words.
+        if cfg.moe_shortcut_double_layer or cfg.moe_experts_held is not None:
+            refused = [name for name, on in (
+                ("paged=False (the dense cache holds one plane a layer)",
+                 cfg.moe_shortcut_double_layer and not paged),
+                ("adapter_cache (lora on latent attention and experts)",
+                 adapter_cache is not None),
+                ("ctx (a serving mesh: the all-to-all between expert shares "
+                 "and a latent pool sharded under two sublayers)",
+                 ctx is not None),
+                ("an injected pool (disaggregated prefill fills a dense "
+                 "one-plane cache)", pool is not None),
+                (f"kv_cache_dtype {kv_cache_dtype!r} (int8/fp8 latent pools "
+                 "under the scaled latent)", kv_cache_dtype != "bf16"),
+                ) if on]
+            if refused:
+                raise ValueError(
+                    "this model runs double layers over two planes of the "
+                    "paged pools and holds a share of its experts on one "
+                    "device (ROADMAP M3, M6): cannot serve it with "
+                    + "; ".join(refused))
         self.paged = paged
         if paged:
             # An injected pool (disagg) carries its own kv_cache_dtype.
@@ -955,8 +1005,19 @@ class DynamicInferenceEngine:
         # rounds (stats_snapshot()["moe"]): token-expert assignments of the
         # running requests, and how many (layer, expert) pairs they
         # touched; each round could touch moe_layers x num_moe_experts.
-        self.moe_stats = {"decode_rounds": 0, "assignments": 0,
-                          "expert_pairs_touched": 0}
+        # On a model that holds a share of its experts or has zero-compute
+        # ones (moe.HELD_COUNTS) the pairs are of the experts HELD here,
+        # and the assignments split into assignments_zero (picks of a
+        # zero-compute expert: compute a token did not cost),
+        # assignments_here and assignments_absent (picks of experts held
+        # elsewhere, left out), with here_max_rows = Σ over rounds and MoE
+        # layers of the most rows one held expert got; elsewhere they read
+        # 0, all, 0, 0. `tokens`: the rounds' running requests, counted on
+        # the host (assignments = tokens x top-k x MoE layers).
+        self.moe_stats = {"decode_rounds": 0, "tokens": 0, "assignments": 0,
+                          "expert_pairs_touched": 0, "assignments_zero": 0,
+                          "assignments_here": 0, "assignments_absent": 0,
+                          "here_max_rows": 0}
         # Always-on counters of what the sampler was asked for
         # (stats_snapshot()["sampler"]), read off the rows it is handed:
         # plain decode rounds and prefills' first samples that took
@@ -1031,6 +1092,21 @@ class DynamicInferenceEngine:
                      "reuse (off), spec_method, spill/park, export/import/"
                      "adopt, lora, an injected pool or mesh, a quantized "
                      "pool")
+        cfg = self.cfg
+        if cfg.moe_shortcut_double_layer:
+            line += (f", layers={cfg.num_layers} double layers x 2 attention "
+                     f"sublayers = {cfg.kv_planes} planes of the pools, one "
+                     "MoE a layer on a shortcut")
+        if cfg.moe_picks_unheld:
+            first, count = cfg.moe_experts_here
+            line += (f", experts={count} held ({first}..{first + count - 1}) "
+                     f"of {cfg.num_moe_experts} published + "
+                     f"{cfg.moe_zero_experts} zero-compute, top-"
+                     f"{cfg.moe_router_topk} of {cfg.moe_router_width}; "
+                     "absent experts' terms are left out (no exchange)")
+        if cfg.vocab_slice_of:
+            line += (f", vocabulary={cfg.vocab_size} rows, a slice of "
+                     f"{cfg.vocab_slice_of}")
         return line
 
     def _build_jits(self):
@@ -2235,9 +2311,13 @@ class DynamicInferenceEngine:
             toks = self._sample_all(logits, tail=moe)
         with self._span("engine.decode.record"):
             if moe is not None:
-                self.moe_stats["decode_rounds"] += 1
-                self.moe_stats["assignments"] += int(toks[-2])
-                self.moe_stats["expert_pairs_touched"] += int(toks[-1])
+                st, tail = self.moe_stats, toks[self.max_batch:]
+                st["decode_rounds"] += 1
+                st["tokens"] += int(active_np.sum())
+                counts = dict(zip(HELD_COUNTS, (int(n) for n in tail)))
+                counts.setdefault("assignments_here", counts["assignments"])
+                for name, n in counts.items():
+                    st[name] += n
             self.spec_stats["model_steps"] += 1
             self.spec_stats["emitted_tokens"] += len(active)
             telemetry.inc("serving_tokens_emitted", len(active))
@@ -2432,8 +2512,7 @@ class DynamicInferenceEngine:
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.bool_),
                 jax.tree.map(spec, self._lora_args()))
         try:
-            block = self.params["block"]
-            moe = block.get("moe", {}) if isinstance(block, dict) else {}
+            moe = _moe_of(self.params["block"]) or {}
             kernels = [moe[k]["qint8"] if isinstance(moe[k], dict)
                        else moe[k]
                        for k in ("fc1_kernel", "fc2_kernel") if k in moe]
@@ -2475,6 +2554,15 @@ class DynamicInferenceEngine:
         (R(T) a slot: what the paged kernel read) against
         `rows_full_attention` (T + 1); `max_blocks_slot`, the most blocks
         one slot has held.
+        "moe" is a dict on an MoE model, summed over plain decode rounds:
+        `decode_rounds`, `tokens` (their running requests), `assignments`
+        (tokens x top-k x MoE layers), `expert_pairs_touched` of
+        `expert_pairs_possible` (MoE layers x `experts_here` x rounds); on
+        a model that holds a share of its experts or routes to
+        zero-compute ones the assignments split into `assignments_zero`,
+        `assignments_here` and `assignments_absent`, and `here_max_rows`
+        sums the most rows one held expert got a layer and round
+        (elsewhere 0, all, 0, 0).
 
         include_dispatch=True adds the traced decode step's launch
         counts (dispatch_stats; the first call traces the step once and
@@ -2505,10 +2593,11 @@ class DynamicInferenceEngine:
                 slots=self.max_batch,
                 bytes_per_slot=self.pool.state_bytes_per_slot)
         if self.cfg.is_moe:
+            here = self.cfg.moe_experts_here[1]
             per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)
-                         * self.cfg.num_moe_experts)
+                         * here)
             out["moe"] = dict(
-                self.moe_stats, expert_pairs_possible=(
+                self.moe_stats, experts_here=here, expert_pairs_possible=(
                     per_round * self.moe_stats["decode_rounds"]))
         if include_dispatch and self.paged:
             out["decode_dispatch"] = self.dispatch_stats()

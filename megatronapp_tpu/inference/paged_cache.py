@@ -264,9 +264,11 @@ class PagedKVCache:
         # decode slot via transfer_slot (pure bookkeeping, no KV copy).
         self.num_slots = max_batch + extra_slots
 
-        # A plane a layer that attends: a hybrid stack's state-space
-        # layers cache no token (their state is `self.state`, below).
-        l = cfg.num_attention_layers
+        # A plane an attention sublayer: a hybrid stack's state-space
+        # layers cache no token (their state is `self.state`, below), a
+        # shortcut-connected double layer's two sublayers own planes
+        # 2·layer and 2·layer + 1.
+        l = cfg.kv_planes
         nb, bs = self.num_blocks, self.block_size
         # scales: per-(row, kv-head) fp32 quantization scales for int8
         # pools (None for bf16) — scattered/copied exactly like the data
@@ -902,6 +904,11 @@ class PagedKVCache:
             f"held={len(held)} != {nb}")
         for blk in lru:
             assert blk in self._hash_of, f"unhashed block {blk} on LRU"
+        for pool in self._arrays():
+            assert pool.shape[:3] == (self.cfg.kv_planes, nb,
+                                      self.block_size), (
+                f"a pool of shape {pool.shape} for {self.cfg.kv_planes} "
+                f"planes of {nb} blocks")
         if self.eva:
             # Both regions and the pending summaries: the table's row is
             # the slot's blocks, region by region, and nothing else.

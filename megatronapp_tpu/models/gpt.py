@@ -55,6 +55,11 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
         p["final_ln_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
         ax["final_ln_bias"] = ("embed",)
     lead = cfg.moe_first_k_dense
+    if cfg.moe_shortcut_double_layer and pp > 1:
+        raise ValueError(
+            "the shortcut-connected double layer is not pipelined yet (two "
+            "attention sublayers and a shortcut a stage unit) — run with "
+            "pp == 1")
     p["block"], ax["block"] = init_block_params(
         k_block, cfg, num_layers=cfg.num_layers - lead)
     if lead:

@@ -95,6 +95,41 @@ def deepseek_v2_lite(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def longcat_flash_chat(**kw) -> TransformerConfig:
+    """LongCat-Flash-Chat (560B, 18.6-31.3B active a token) as its
+    config.json publishes it
+    (https://huggingface.co/meituan-longcat/LongCat-Flash-Chat): 28
+    shortcut-connected double layers (two latent-attention sublayers with a
+    query latent of 1536 and the two sqrt(hidden / rank) scale corrections,
+    two dense SwiGLUs of 12288, one MoE on a shortcut), a 768-way softmax
+    router over 512 experts of width 2048 and 256 zero-compute (identity)
+    experts, top-12 on biased scores, weights unbiased x 6 and not
+    renormalised, RoPE theta 1e7 over 64 roped columns, no YaRN. Whole it
+    is 1.1 TB of bf16 weights: a deployment passes moe_experts_held (its
+    rank's experts), a vocabulary slice and its stages' num_layers, as the
+    benchmark's configuration does (perfbench/configs/
+    longcat-flash-chat.json; tests/test_longcat_flash.py holds the two and
+    the catalog's numbers to each other)."""
+    d = dict(num_layers=28, hidden_size=6144, num_attention_heads=64,
+             ffn_hidden_size=12288, vocab_size=131072,
+             max_position_embeddings=131072,
+             activation=ActivationKind.swiglu,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             add_bias_linear=False,
+             untie_embeddings_and_output_weights=True,
+             position_embedding=PositionEmbeddingKind.rope,
+             rotary_base=10000000.0,
+             multi_latent_attention=True, q_lora_rank=1536,
+             kv_lora_rank=512, qk_head_dim=128, qk_pos_emb_head_dim=64,
+             v_head_dim=128, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+             num_moe_experts=512, moe_zero_experts=256, moe_router_topk=12,
+             moe_ffn_hidden_size=2048, moe_router_norm_topk_prob=False,
+             moe_routed_scaling_factor=6.0, moe_router_selection_bias=True,
+             moe_shortcut_double_layer=True)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def bert_base(**kw) -> TransformerConfig:
     from megatronapp_tpu.models.bert import bert_config
     d = dict(num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -169,6 +204,7 @@ PRESETS = {
     "llama3-8b": llama3_8b,
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-v2-lite": deepseek_v2_lite,
+    "longcat-flash-chat": longcat_flash_chat,
     "bert-base": bert_base,
     "t5-base": t5_base,
 }

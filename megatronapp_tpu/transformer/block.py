@@ -78,7 +78,27 @@ def _init_ffn_half(rng, cfg: TransformerConfig, out_std,
 
 
 def init_layer_params(rng, cfg: TransformerConfig, force_dense: bool = False):
-    """One layer's params + logical axes (unstacked)."""
+    """One layer's params + logical axes (unstacked).
+
+    A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
+    {"first", "second"}: two ordinary halves-of-a-layer pairs (norm,
+    attention, norm, dense FFN), the first of which also holds the "moe"
+    that reads its second norm's output (layer_forward)."""
+    if cfg.moe_shortcut_double_layer:
+        # five residual-out projections a layer, four of them in sequence
+        out_std = cfg.init_method_std / jnp.sqrt(4.0 * cfg.num_layers)
+        halves = {}
+        for name, key in zip(("first", "second"), jax.random.split(rng)):
+            k_attn, k_mlp, k_moe = jax.random.split(key, 3)
+            p, ax = _init_mixer_half(k_attn, cfg, out_std)
+            ffn_p, ffn_ax = _init_ffn_half(k_mlp, cfg, out_std,
+                                           force_dense=True)
+            p, ax = {**p, **ffn_p}, {**ax, **ffn_ax}
+            if name == "first":
+                p["moe"], ax["moe"] = init_moe_params(k_moe, cfg, out_std)
+            halves[name] = (p, ax)
+        return ({k: v[0] for k, v in halves.items()},
+                {k: v[1] for k, v in halves.items()})
     # Scaled init for residual-out projections: std/sqrt(2*num_layers)
     # (reference scaled_init_method_normal, training/utils).
     out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
@@ -131,7 +151,47 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     per RESIDENT_KERNELS target. Serving paths only: each projection
     matmul grows a ``base(x) + B_i A_i x`` delta
     (kernel_gen.apply_lora_delta); slot 0 is the all-zero null
-    adapter."""
+    adapter.
+
+    A shortcut-connected double layer (p holds "first" and "second",
+    cfg.moe_shortcut_double_layer) runs
+
+        x1 = x  + A1(norm1(x));   h = norm2(x1);   m = MoE(h)
+        x2 = x1 + F1(h);   x3 = x2 + A2(norm3(x2));   x4 = x3 + F2(norm4(x3))
+        out = x4 + m
+
+    as two calls of this function on its halves: a half that holds both
+    "mlp" and "moe" adds the dense FFN to the stream and hands the MoE's
+    output back beside aux, as (aux, m), for the caller to add at the end.
+    In a paged step its attention sublayers own planes 2·layer_id and
+    2·layer_id + 1 of the pools."""
+    if "second" in p:
+        refused = [what for what, on in (
+            ("a tp-sharded stage body", tp_sharded), ("fp8", fp8 is not None),
+            ("lora", lora is not None), ("zigzag cp", zigzag),
+            ("a quantized pool (kv_scales)", kv_scales is not None),
+            ("a dense (unpaged) KV cache",
+             kv_cache is not None and page_table is None),
+            ("context parallelism", ctx is not None and ctx.cp > 1),
+            ) if on]
+        if refused:
+            raise NotImplementedError(
+                "the shortcut-connected double layer runs whole sequences "
+                "or paged bf16 pools on one device: cannot run with "
+                + "; ".join(refused))
+        both = dict(
+            rope_cos=rope_cos, rope_sin=rope_sin,
+            attention_mask=attention_mask, layer_id=layer_id,
+            cache_index=cache_index, cache_positions=cache_positions,
+            ctx=ctx, segment_ids=segment_ids, page_table=page_table,
+            active=active, chunk_counts=chunk_counts)
+        plane = None if kv_cache is None else 2 * layer_id
+        (x, cache), (aux, m) = layer_forward(
+            p["first"], x, cfg, kv_cache=kv_cache, kv_plane=plane, **both)
+        (x, cache), _ = layer_forward(
+            p["second"], x, cfg, kv_cache=cache,
+            kv_plane=None if plane is None else plane + 1, **both)
+        return (x + m.astype(x.dtype), cache), aux
     residual = x
     h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
                    cfg.layernorm_epsilon, cfg.norm_unit_offset)
@@ -163,7 +223,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                         cache_index=cache_index,
                         cache_positions=cache_positions,
                         page_table=page_table, active=active,
-                        chunk_counts=chunk_counts, kv_scales=kv_scales)
+                        chunk_counts=chunk_counts, kv_scales=kv_scales,
+                        kv_plane=kv_plane)
                 else:
                     attn_out = mla_forward(
                         p["attention"], h, cfg, rope_cos, rope_sin, mask,
@@ -232,7 +293,10 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
                                        ctx=ctx, tp_sharded=tp_sharded,
                                        count_rows=count_rows)
-    else:
+    if "mlp" in p:
+        if "moe" in p:
+            # The shortcut: the MoE's output skips the rest of the layer.
+            aux = (aux, mlp_out)
         with jax.named_scope("mlp"):
             mlp_out = mlp_forward(p["mlp"], h, cfg, layer_id=layer_id,
                                   ctx=ctx, tp_sharded=tp_sharded,
@@ -278,6 +342,23 @@ def _stack_layers(per_layer, extra_axis: str = "layers"):
     return stacked, ax
 
 
+def _vmapped_layers(keys, init):
+    """init(key) -> (params, axes) for every key, as ONE vmapped program:
+    the same numbers as a Python loop of per-layer initialisers and a
+    stack, without a copy of the initialiser a layer in the program (87 s
+    of a 3B model's first compile, PERF.md, PR 32)."""
+    axes = []
+
+    def one(key):
+        p, ax = init(key)
+        axes.append(ax)
+        return p
+
+    params = jax.vmap(one)(keys)
+    return params, jax.tree.map(lambda a: ("layers",) + a, axes[0],
+                                is_leaf=_is_axes)
+
+
 def init_hybrid_block_params(rng, cfg: TransformerConfig):
     """A hybrid stack (cfg.attn_layer_period): the state-space layers'
     first halves stacked [num_ssm_layers, ...] under "mixers_ssm", the
@@ -290,22 +371,6 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     attends = np.asarray([cfg.layer_is_attention(i)
                           for i in range(cfg.num_layers)])
 
-    def stacked(keys, init):
-        """init(key) -> (params, axes) for every key, as ONE vmapped
-        program a kind: the same numbers as a Python loop of per-layer
-        initialisers and a stack, without a copy of the initialiser a layer
-        in the program (87 s of a 3B model's first compile, PERF.md, PR 32)."""
-        axes = []
-
-        def one(key):
-            p, ax = init(key)
-            axes.append(ax)
-            return p
-
-        params = jax.vmap(one)(keys)
-        return params, jax.tree.map(lambda a: ("layers",) + a, axes[0],
-                                    is_leaf=_is_axes)
-
     kinds = {
         "mixers_ssm": (keys[~attends, 0], functools.partial(
             _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
@@ -314,7 +379,7 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
         "ffn": (keys[:, 1], functools.partial(
             _init_ffn_half, cfg=cfg, out_std=out_std)),
     }
-    done = {k: stacked(*v) for k, v in kinds.items() if len(v[0])}
+    done = {k: _vmapped_layers(*v) for k, v in kinds.items() if len(v[0])}
     return ({k: v[0] for k, v in done.items()},
             {k: v[1] for k, v in done.items()})
 
@@ -398,6 +463,10 @@ def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
     if cfg.attn_layer_period is not None:
         return init_hybrid_block_params(rng, cfg)
     freq = cfg.moe_layer_freq if cfg.is_moe else 1
+    if cfg.moe_shortcut_double_layer:
+        return _vmapped_layers(
+            jax.random.split(rng, n),
+            functools.partial(init_layer_params, cfg=cfg))
     if freq == 1 or force_dense:
         keys = jax.random.split(rng, n)
         return _stack_layers([init_layer_params(k, cfg, force_dense)

@@ -67,13 +67,24 @@ def init_mla_params(rng, cfg: TransformerConfig, out_std: float):
     return p, ax
 
 
+def latent_scales(cfg: TransformerConfig):
+    """(s_q, s_kv): what cfg.mla_scale_q_lora / mla_scale_kv_lora multiply
+    the expanded query and the normed latent by, sqrt(hidden_size / rank);
+    None where the model has no such correction."""
+    return (
+        (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
+        if cfg.mla_scale_q_lora else None,
+        (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
+        if cfg.mla_scale_kv_lora else None)
+
+
 def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 rope_cos=None, rope_sin=None,
                 attention_mask: Optional[jnp.ndarray] = None,
                 layer_id=None, ctx=None, kv_cache=None, cache_index=None,
                 cache_positions=None, page_table=None, active=None,
                 chunk_counts=None, tp_sharded: bool = False,
-                kv_scales=None):
+                kv_scales=None, kv_plane=None):
     """kv_cache: optional (latent_cache [B, Smax, kv_lora_rank],
     kpe_cache [B, Smax, dpe]) — the COMPRESSED decode cache (the latent +
     shared roped key; reference MLA's defining cache shape). Returns
@@ -97,6 +108,15 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     scale pools [L, NB, bs] marking a QUANTIZED latent/pe pool (paged path
     only); new rows quantize on insert (quantize_kv_rows) and new_cache
     then carries four pools.
+
+    kv_plane: the plane of the stacked paged pools this sublayer owns where
+    that is not its layer id (a double layer's two attention sublayers own
+    planes 2·layer and 2·layer + 1).
+
+    The query latent (q_down → norm → q_up) and the two scale corrections
+    (latent_scales) run on every path: the paged one absorbs the scaled
+    query into the latent as it absorbs an unscaled one, and caches the
+    latent scaled.
 
     tp_sharded: ambient-manual tp-sharded stage body (see
     transformer/attention.py docstring) — training path only."""
@@ -123,6 +143,9 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         q_lat = x @ p["q_down"].astype(dt)
         q_lat = rms_norm(q_lat, p["q_ln_scale"], cfg.layernorm_epsilon)
         q = q_lat @ p["q_up"].astype(dt)
+    s_q, s_kv = latent_scales(cfg)
+    if s_q is not None:
+        q = q * s_q
     q = q.reshape(b, s, nq, dqk + dpe)
     q_nope, q_pe = q[..., :dqk], q[..., dqk:]
 
@@ -130,6 +153,8 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                          layer_id).astype(dt)  # [B,S,klat+dpe]
     latent, k_pe = kv[..., :klat], kv[..., klat:]
     latent = rms_norm(latent, p["kv_ln_scale"], cfg.layernorm_epsilon)
+    if s_kv is not None:
+        latent = latent * s_kv
 
     if rope_cos is not None:
         q_pe = rotary.apply_rope(q_pe, rope_cos, rope_sin)
@@ -174,6 +199,7 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 raise ValueError(
                     "paged attention reads the stacked pool through "
                     "layer_id — pass this layer's index")
+            plane = layer_id if kv_plane is None else kv_plane
             if active is None:
                 active = jnp.ones((b,), bool)
             # Multi-token paged append (speculative verify / chunked
@@ -190,7 +216,7 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             (c_lat, c_pe), new_scales = append_kv(
                 kv_cache, kv_scales,
                 (latent, k_pe) if ragged else (latent[:, 0], k_pe[:, 0]),
-                page_table, cache_positions, active, layer_id, counts)
+                page_table, cache_positions, active, plane, counts)
             sc_kw = ({} if new_scales is None else
                      {"lat_scales": new_scales[0],
                       "pe_scales": new_scales[1]})
@@ -232,12 +258,12 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 attn = paged_attention_latent(
                     q_abs, q_pe, c_lat, c_pe, page_table, kv_lens, w_v,
                     q_lens=counts, softmax_scale=scale, mesh=mesh,
-                    layer=layer_id, **sc_kw)
+                    layer=plane, **sc_kw)
             else:
                 attn = paged_attention_latent(
                     q_abs[:, 0], q_pe[:, 0], c_lat, c_pe, page_table,
                     kv_lens, w_v, softmax_scale=scale, mesh=mesh,
-                    layer=layer_id, **sc_kw)[:, None]
+                    layer=plane, **sc_kw)[:, None]
             if tp_paged:
                 from jax.sharding import NamedSharding, PartitionSpec
                 # manual-ok: replicate the kernel output so the
@@ -256,12 +282,12 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 from megatronapp_tpu.ops.pallas.paged_attention import (
                     gather_pages_batched,
                 )
-                g_lat = gather_pages_batched(c_lat[layer_id], page_table)
-                g_pe = gather_pages_batched(c_pe[layer_id], page_table)
+                g_lat = gather_pages_batched(c_lat[plane], page_table)
+                g_pe = gather_pages_batched(c_pe[plane], page_table)
                 if new_scales is not None:
-                    g_ls = gather_pages_batched(new_scales[0][layer_id],
+                    g_ls = gather_pages_batched(new_scales[0][plane],
                                                 page_table)
-                    g_ps = gather_pages_batched(new_scales[1][layer_id],
+                    g_ps = gather_pages_batched(new_scales[1][plane],
                                                 page_table)
                     g_lat = g_lat.astype(jnp.float32) * g_ls[..., None]
                     g_pe = g_pe.astype(jnp.float32) * g_ps[..., None]
@@ -416,6 +442,9 @@ def _mla_forward_tp_sharded(p, x, cfg: TransformerConfig, rope_cos,
         quw = lax.dynamic_slice_in_dim(p["q_up"].astype(dt),
                                        me * nql * dq, nql * dq, axis=1)
         q = all_gather_matmul_manual(q_lat, quw, tp, ov)
+    s_q, s_kv = latent_scales(cfg)
+    if s_q is not None:
+        q = q * s_q
     q = q.reshape(b, sf, nql, dq)
     q_nope, q_pe = q[..., :dqk], q[..., dqk:]
 
@@ -423,6 +452,8 @@ def _mla_forward_tp_sharded(p, x, cfg: TransformerConfig, rope_cos,
                          layer_id).astype(dt)            # [B, S/tp, klat+dpe]
     latent, k_pe = kv[..., :klat], kv[..., klat:]
     latent = rms_norm(latent, p["kv_ln_scale"], cfg.layernorm_epsilon)
+    if s_kv is not None:
+        latent = latent * s_kv
 
     # kv_up rides a ring all-gather of the latent seq chunks; the shared
     # rope key gathers explicitly (dpe-wide — negligible traffic).

@@ -40,13 +40,17 @@ from megatronapp_tpu.ops.activations import apply_activation, is_gated
 def init_moe_params(rng, cfg: TransformerConfig, out_std: float):
     h = cfg.hidden_size
     f = cfg.moe_ffn_hidden_size
-    e = cfg.num_moe_experts
+    # The router is as wide as the model publishes it (its zero-compute
+    # experts behind the computing ones); the kernels are those of the
+    # experts held here (cfg.moe_experts_held: all of them unless told).
+    e = cfg.moe_experts_here[1]
     k_router, k1, k2, k_shared = jax.random.split(rng, 4)
     std = cfg.init_method_std
     fc1_out = 2 * f if is_gated(cfg.activation) else f
     p = {
         # Router in fp32 (reference router.py keeps router params fp32).
-        "router_kernel": jax.random.normal(k_router, (h, e), jnp.float32) * std,
+        "router_kernel": jax.random.normal(
+            k_router, (h, cfg.moe_router_width), jnp.float32) * std,
         "fc1_kernel": jax.random.normal(k1, (e, h, fc1_out), cfg.params_dtype) * std,
         "fc2_kernel": jax.random.normal(k2, (e, f, h), cfg.params_dtype) * out_std,
     }
@@ -55,6 +59,9 @@ def init_moe_params(rng, cfg: TransformerConfig, out_std: float):
         "fc1_kernel": ("experts", "embed", "mlp"),
         "fc2_kernel": ("experts", "mlp", "embed"),
     }
+    if cfg.moe_router_selection_bias:
+        p["router_bias"] = jnp.zeros((cfg.moe_router_width,), jnp.float32)
+        ax["router_bias"] = (None,)
     if cfg.moe_shared_expert_intermediate_size:
         fs = cfg.moe_shared_expert_intermediate_size
         shared_out = 2 * fs if is_gated(cfg.activation) else fs
@@ -75,6 +82,10 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     router.py:102). The k probabilities are divided by their sum only when
     ``cfg.moe_router_norm_topk_prob`` says so (HF ``norm_topk_prob``), and
     carry ``cfg.moe_routed_scaling_factor`` (HF ``routed_scaling_factor``).
+    The softmax runs over the router's whole width (cfg.moe_router_width:
+    zero-compute experts have ids past the computing ones). A router that
+    holds a selection bias ("router_bias") takes its top-k on p + b and
+    keeps p, unbiased, as the weights.
 
     stats_mean: optional reducer applied to the per-expert token-mean
     statistics (frac, mean_prob, z² mean) BEFORE the nonlinear aux-loss
@@ -84,10 +95,15 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     per-shard products (which differs whenever shards see different
     routing mixes).
     """
-    e = cfg.num_moe_experts
+    e = cfg.moe_router_width
     logits = x_flat.astype(jnp.float32) @ p["router_kernel"]
     probs = jax.nn.softmax(logits, axis=-1)
-    topk_probs, topk_idx = jax.lax.top_k(probs, cfg.moe_router_topk)
+    if "router_bias" in p:
+        _, topk_idx = jax.lax.top_k(probs + p["router_bias"],
+                                    cfg.moe_router_topk)
+        topk_probs = jnp.take_along_axis(probs, topk_idx, axis=-1)
+    else:
+        topk_probs, topk_idx = jax.lax.top_k(probs, cfg.moe_router_topk)
     if cfg.moe_router_norm_topk_prob:
         topk_probs = topk_probs / jnp.maximum(
             jnp.sum(topk_probs, -1, keepdims=True), 1e-9)
@@ -186,7 +202,14 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
     run grouped GEMMs (``lax.ragged_dot``) over the contiguous per-expert
     row groups — static shapes, no capacity buffer, zero drops. This is
     the reference's default behavior (no --moe-expert-capacity-factor ⇒
-    dispatchers never drop; experts.py GroupedMLP runs ragged groups)."""
+    dispatchers never drop; experts.py GroupedMLP runs ragged groups).
+
+    A layer that holds a share of the experts (cfg.moe_experts_held) or
+    routes to zero-compute ones (cfg.moe_zero_experts) takes
+    _dropless_held_experts: the same sort and GEMMs over its own experts'
+    rows alone."""
+    if cfg.moe_picks_unheld:
+        return _dropless_held_experts(p, x_flat, topk_idx, topk_probs, cfg)
     t, h = x_flat.shape
     k = cfg.moe_router_topk
     e = cfg.num_moe_experts
@@ -206,6 +229,60 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
         y.astype(jnp.float32) * w_sorted[:, None])
 
 
+def _held_slot(flat_expert, cfg: TransformerConfig):
+    """A pick's place among the experts held here: its expert's index in
+    [0, count), or count for a pick of an expert held elsewhere or of a
+    zero-compute one."""
+    first, count = cfg.moe_experts_here
+    local = flat_expert - first
+    return jnp.where((local >= 0) & (local < count), local, count), count
+
+
+def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
+                           cfg: TransformerConfig) -> jnp.ndarray:
+    """_dropless_experts for a layer that is told which experts it holds
+    and whose router may pick zero-compute experts:
+
+        Σ_{picks of a held expert e} w_e · FFN_e(x)  +  (Σ_{picks of a
+        zero-compute expert} w_e) · x
+
+    The picks of the held experts sort to the front of the row buffer,
+    group by group; the group sizes cover those rows alone, so the grouped
+    GEMMs' tiles run over them and over nothing else. The buffer itself
+    stays T*k rows, what static shapes force (any token may pick k held
+    experts): the rows behind the groups are picks of absent or
+    zero-compute experts, belong to no group, cost no GEMM step, and their
+    (undefined) output rows are masked before the weighted sum. What the
+    absent experts would have added is left out; the identity term needs
+    no weights and is computed here whole."""
+    t, h = x_flat.shape
+    k = cfg.moe_router_topk
+    dt = cfg.compute_dtype
+    flat_expert = topk_idx.reshape(t * k)
+    slot, count = _held_slot(flat_expert, cfg)
+    order = jnp.argsort(slot)
+    token_of = order // k
+    group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
+        jnp.int32)
+
+    x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
+    y = _grouped_gemm(x_sorted, p["fc1_kernel"], group_sizes, dt)
+    y = _grouped_gemm(_apply_act(cfg, y), p["fc2_kernel"], group_sizes, dt)
+
+    flat_w = topk_probs.reshape(t * k).astype(jnp.float32)
+    in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
+    y = jnp.where(in_group[:, None],
+                  y.astype(jnp.float32) * jnp.take(flat_w, order)[:, None],
+                  0.0)
+    out = jnp.zeros((t, h), jnp.float32).at[token_of].add(y)
+    if cfg.moe_zero_experts:
+        w_zero = jnp.sum(jnp.where(topk_idx >= cfg.num_moe_experts,
+                                   topk_probs.astype(jnp.float32), 0.0),
+                         axis=-1)
+        out = out + w_zero[:, None] * x_flat.astype(jnp.float32)
+    return out
+
+
 def routing_counts(topk_idx, count_rows, num_experts: int) -> jnp.ndarray:
     """int32 [2] of one layer's routing: token-expert assignments of the
     rows that `count_rows` ([T] bool) marks as real tokens, and how many of
@@ -216,6 +293,31 @@ def routing_counts(topk_idx, count_rows, num_experts: int) -> jnp.ndarray:
                      ).astype(jnp.int32)
 
 
+# What routing_counts_held returns, in order (the engine's `moe` counters).
+HELD_COUNTS = ("assignments", "expert_pairs_touched", "assignments_zero",
+               "assignments_here", "assignments_absent", "here_max_rows")
+
+
+def routing_counts_held(topk_idx, count_rows,
+                        cfg: TransformerConfig) -> jnp.ndarray:
+    """routing_counts for a layer that holds a share of the experts or
+    routes to zero-compute ones, int32 [6] in HELD_COUNTS' order: the real
+    tokens' assignments (tokens x top-k); how many of the experts HELD HERE
+    they touched; their picks of zero-compute experts, of held experts and
+    of experts held elsewhere, each counted from the indices (the three add
+    up to the first); and the most rows one held expert got."""
+    slot, count = _held_slot(topk_idx, cfg)
+    hit = jax.nn.one_hot(slot, count, dtype=jnp.int32)            # [T,K,C]
+    rows = jnp.sum(hit * count_rows[:, None, None], axis=(0, 1))  # [C]
+    real = count_rows[:, None]
+    zero = topk_idx >= cfg.num_moe_experts
+    return jnp.stack([
+        jnp.sum(real) * topk_idx.shape[1], jnp.sum(rows > 0),
+        jnp.sum(real & zero), jnp.sum(rows),
+        jnp.sum(real & ~zero & (slot == count)),
+        jnp.max(rows)]).astype(jnp.int32)
+
+
 def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
                 ctx=None, tp_sharded: bool = False, count_rows=None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -223,7 +325,9 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
 
     count_rows: [B,S] bool, given by the serving steps (which have no use
     for the aux loss): the second result is then ``routing_counts`` of
-    those rows, for the engine's always-on `moe` counters.
+    those rows (``routing_counts_held`` on a model with a share of the
+    experts or zero-compute ones), for the engine's always-on `moe`
+    counters.
 
     ctx with ep > 1 selects the explicit all-to-all dispatch
     (_a2a_expert_forward): expert weights stay home on their ep shard and
@@ -245,6 +349,13 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
     k = cfg.moe_router_topk
 
     from megatronapp_tpu.parallel.collectives import current_manual_axes
+    if cfg.moe_picks_unheld and (
+            tp_sharded or (ctx is not None and getattr(ctx, "ep", 1) > 1)):
+        raise NotImplementedError(
+            "a layer that holds a share of the experts (moe_experts_held) "
+            "or routes to zero-compute ones runs without an exchange on "
+            "one device: no ep all-to-all between shares and no tp-sharded "
+            "stage body yet (ROADMAP M3)")
     if (ctx is not None and getattr(ctx, "ep", 1) > 1
             and not current_manual_axes()
             and e % ctx.ep == 0
@@ -290,7 +401,10 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
                                         stats_mean=stats_mean)
 
     if count_rows is not None:
-        aux = routing_counts(topk_idx, count_rows.reshape(t), e)
+        if cfg.moe_picks_unheld:
+            aux = routing_counts_held(topk_idx, count_rows.reshape(t), cfg)
+        else:
+            aux = routing_counts(topk_idx, count_rows.reshape(t), e)
 
     if cfg.moe_capacity_factor is None:
         out = _dropless_experts(p, x_flat, topk_idx, topk_probs, cfg)

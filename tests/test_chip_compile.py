@@ -435,11 +435,18 @@ def _cell_prefill_width(one_chip, model, seq, **cut):
     return choose_prefill_width(cfg, abstract, seq, 16, device_kind=kind)
 
 
+# The agent cell's cut of LongCat-Flash-Chat: one chip's share of a
+# deployment (perfbench/configs/longcat-flash-chat.json).
+LONGCAT_CUT = {"num_layers": 4, "vocab_size": 16384,
+               "vocab_slice_of": 131072, "moe_experts_held": (0, 16)}
+
+
 @pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
 @pytest.mark.parametrize("model,batch,blocks,seq,cut,width", [
     ("gpt3-2.7b", 24, 896, 2048, {}, 256),
     ("deepseek-v2-lite", 32, 8192, 4096, {"num_layers": 9}, 1024),
     ("evabyte-6.5b", 32, 3584, 16384, {"num_layers": 8}, 256),
+    ("longcat-flash-chat", 64, 16384, 4096, LONGCAT_CUT, 512),
 ])
 def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
                                            batch, blocks, seq, cut, width,
@@ -465,9 +472,17 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     weights, the step compiled at the cut depth): 256 for the dense bf16
     models (a v5e's 240 flops a byte); 1024 where a position computes on 6
     of 64 experts of what the call streams. Its ragged kernel, cut into
-    query tiles, fits Mosaic's VMEM, and its head runs on one position."""
+    query tiles, fits Mosaic's VMEM, and its head runs on one position.
+
+    LongCat-Flash-Chat at its published widths, 2 double layers of the
+    cell's 4: 64 heads through both latent kernels (the absorbed queries
+    are [rows, 64, 512 + 64]; kv_up's value columns [512, 64 x 128] are an
+    8 MB operand of every tile; a 512-wide call is cut into 22 tiles of 24
+    positions), two planes a layer of pools [2L, 16384, 16, .], 16 held
+    experts read in place, a call 512 wide (10.4 GB of weights over 5.3
+    GFLOP a position: a position computes on 12 x 16 / 768 of an expert)."""
     from megatronapp_tpu.inference.dynamic_engine import (
-        DynamicInferenceEngine,
+        DynamicInferenceEngine, _moe_of,
     )
     assert _cell_prefill_width(one_chip, model, seq, **cut) == width
     from megatronapp_tpu.models.gpt import init_gpt_params
@@ -481,6 +496,11 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     if model == "evabyte-6.5b":
         over.update(num_layers=8, vocab_size=320,
                     params_dtype=jnp.bfloat16)
+    if model == "longcat-flash-chat":
+        # (not the cell's 16384 rows: kv_up's [512, 64 x 256] slice of a
+        # layer would read as [1, width, columns] logits below)
+        over = dict(cut, num_layers=2, vocab_size=1024,
+                    params_dtype=jnp.bfloat16)
     cfg = PRESETS[model](**over)
     abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
@@ -491,7 +511,7 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     def spec(a):
         return _sds(a.shape, a.dtype, one_chip)
 
-    pages = tuple(_sds((cfg.num_layers, blocks) + p.shape[2:], p.dtype,
+    pages = tuple(_sds((cfg.kv_planes, blocks) + p.shape[2:], p.dtype,
                        one_chip) for p in eng.pool.pages)
     mb = eng.pool.page_table.shape[1]
     assert mb == (128 + 8 * 8 if cfg.is_eva else seq // 16)
@@ -522,7 +542,7 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     if cfg.is_moe:
-        experts = abstract["block"]["moe"]
+        experts = _moe_of(abstract["block"])
         assert any("ragged-dot" in n for n in _kernel_names(compiled))
         assert not _pool_shaped(
             compiled, r"slice|copy",

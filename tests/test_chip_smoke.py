@@ -186,6 +186,8 @@ def _run_with(monkeypatch, capsys, server_ok):
     monkeypatch.setattr(chip_smoke, "phase_hybrid",
                         lambda tiny: _phase("hybrid"))
     monkeypatch.setattr(chip_smoke, "phase_eva", lambda tiny: _phase("eva"))
+    monkeypatch.setattr(chip_smoke, "phase_share",
+                        lambda tiny: _phase("share"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -197,7 +199,8 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
     phases = [json.loads(ln[len("phase: "):]) for ln in lines
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-reference",
-                                            "server", "hybrid", "eva"]
+                                            "server", "hybrid", "eva",
+                                            "share"]
 
 
 def _hybrid_lines(device=TPU, **kw):
@@ -256,6 +259,42 @@ def test_check_eva(kw, needle):
     assert out["ok"] is (needle is None), out["problems"]
     assert needle is None or needle in " | ".join(out["problems"])
     assert not chip_smoke.check_eva(1, _eva_lines())["ok"]
+
+
+def _share_lines(device=TPU, **kw):
+    res = {"tokens": 155, "in_vocab": True, "latent_kernel_calls": 2,
+           "moe": {"tokens": 33, "assignments_zero": 60,
+                   "assignments_here": 70, "assignments_absent": 68,
+                   "experts_here": 4},
+           "pool_shapes": [[4, 64, 16, 512], [4, 64, 16, 64]],
+           "pool_bytes": 100, "alias_bytes": 128, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            "attention: paged decode -> pallas paged kernel (compiled)",
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"latent_kernel_calls": 4}, "not two a layer loop"),
+    ({"pool_shapes": [[2, 64, 16, 512], [2, 64, 16, 64]]},
+     "not two planes a layer"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"tokens": 150}, "tokens came back"),
+    ({"moe": {"tokens": 33, "assignments_zero": 60, "assignments_here": 70,
+              "assignments_absent": 60, "experts_here": 4}},
+     "the picks are not held"),
+    ({"moe": {"tokens": 33, "assignments_zero": 60, "assignments_here": 138,
+              "assignments_absent": 0, "experts_here": 4}},
+     "the picks are not held"),
+])
+def test_check_share(kw, needle):
+    """The share phase's facts: two latent kernels a layer loop in the
+    compiled decode step, pools of two planes a layer aliased in and out,
+    every token back, every pick held, absent or zero-compute."""
+    out = chip_smoke.check_share(0, _share_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_share(1, _share_lines())["ok"]
 
 
 def test_a_failing_phase_fails_the_run(monkeypatch, capsys):
@@ -326,7 +365,8 @@ def test_forced_to_the_cpu_it_refuses(tmp_path):
 def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server", "hybrid", "eva"]
+                                            "server", "hybrid", "eva",
+                                            "share"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -350,7 +390,11 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     eva = phases[4]
     assert eva["eva"]["windows_closed"] == 3 and eva["table_blocks"] == 20
     assert eva["alias_bytes"] >= eva["pool_bytes"] > 0
-    assert sum("not a TPU" in ln for ln in lines) == 5
+    share = phases[5]
+    assert share["moe"]["experts_here"] == 4
+    assert share["pool_shapes"][0][0] == 4      # two planes a layer
+    assert share["alias_bytes"] >= share["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 6
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):
