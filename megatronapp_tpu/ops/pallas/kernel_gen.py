@@ -23,15 +23,20 @@ for MLA pools); the legacy names in paged_attention.py are thin wrappers
 over it. Both run ONE scaffold, ``emit_paged_kernel`` under
 ``_walk_call`` (ISSUE 29): a one-dimensional grid over the REAL steps of
 a call — a slot is walked for the blocks it holds, ``pages_per_step``
-pages a step (a key tile 128 wide at blocks of 16), and no step, branch
-or DMA exists for a table entry past its length — around the body's
-tile functions (``_dense_tile``, ``_latent_tile``). The mathematics is
-the legacy bodies' (online softmax in fp32, causal tail mask, in-register
-dequant), folded a tile at a time: tests/test_kernel_gen.py holds the
+pages a step (a key tile 128 wide at blocks of 16, 256 for latent pages),
+and no step, branch or DMA exists for a table entry past its length —
+around the body's tile functions (``_dense_tile``, ``_latent_tile``). The
+mathematics is the legacy bodies' (online softmax in fp32, causal tail
+mask, in-register dequant), folded a tile at a time:
+tests/test_kernel_gen.py holds the
 kernels BITWISE to a jax.numpy replay of the walk and allclose to frozen
 copies of the old bodies across {bf16, int8} × {tp1, tp2} × {q_len 1,
 ragged} × {GQA, MHA}. New variants (fp8 pools, MLA latent layouts,
-token-tree masks) are parameters here, not new copies.
+token-tree masks) are parameters here, not new copies. The latent family
+sums its values IN LATENT SPACE (ISSUE 39): kv_up's value columns do not
+depend on the key, so the walk accumulates p · latent over a slot's tiles
+and ``paged_attention_latent`` expands the normalised sum through them
+once a query row, after the walk.
 
 After the emitter come ``paged_append`` (the in-place pool writer every
 paged step and prefill call shares) and the batched-LoRA delta kernels
@@ -150,14 +155,27 @@ def _pages_vmem_bytes(pools, kv_tile) -> int:
     return total
 
 
+# Keys a step of the walk folds. The dense family's pages are large (a
+# page of 32 heads is 128 KiB a pool) and its kernels run at their roof at
+# 128. A latent page is 20 KiB and a latent step's cost is what it does
+# whatever the keys: 16 page DMAs and their index maps, and the rescaling
+# of a [rows, klat] float32 accumulator. At 256 keys a step the decode
+# kernel takes a tenth less and the ragged one 27-31% less than at 128; at
+# 512 the [rows, 512] scores take the query tile's VMEM and the ragged
+# kernel is slower again (a v5e, 16 and 64 heads: PERF.md section 6, PR 39).
+KEY_TILE = 128
+LATENT_KEY_TILE = 256
+
+
 def pages_per_step(block_size: int, page_bytes: int, table_blocks: int,
-                   budget: int = WALK_VMEM_BUDGET) -> int:
+                   budget: int = WALK_VMEM_BUDGET,
+                   key_tile: int = KEY_TILE) -> int:
     """Pages in one compute block of the walk (`PagedSpec.pages`), from
-    what the code can see: as many as make the key tile 128 wide (8 at
-    block_size 16), fewer if two buffers of them would pass `budget`
-    (`page_bytes`: one page of every pool of the call, as VMEM holds
-    it), never more than the table holds."""
-    return max(1, min(128 // block_size, budget // (2 * page_bytes),
+    what the code can see: as many as make the key tile `key_tile` wide (8
+    at block_size 16, 16 for latent pages), fewer if two buffers of them
+    would pass `budget` (`page_bytes`: one page of every pool of the call,
+    as VMEM holds it), never more than the table holds."""
+    return max(1, min(key_tile // block_size, budget // (2 * page_bytes),
                       table_blocks))
 
 
@@ -182,23 +200,29 @@ def _query_vmem_budget(pools, kv_tile, pages: int) -> int:
     return VMEM_SCOPE - held - copies - (1 << 20)
 
 
-def _query_row_vmem_bytes(queries, out_cols: int, key_tile: int) -> int:
+def _query_row_vmem_bytes(queries, out_cols: int, key_tile: int,
+                          out_dtype=None) -> int:
     """Bytes ONE query position of a ragged call takes in VMEM, all its
     heads together. queries: the call's query arrays [B, S_q, heads, cols]
-    (the lanes hold cols, padded to 128). Counted: each query block and the
-    output block twice (the pipeline's buffers), a float32 copy of the
-    queries, the float32 accumulator, the running max and sum (a
-    lane-padded column each) and a step's float32 scores. Held against what
-    Mosaic asks for at the serving cells' shapes (a search over
-    `vmem_limit_bytes` for a described v5e): 112 KB here for the 104 KB a
-    position it takes at 32 heads of 128, 70 for 47-65 at 20 heads over one
-    key/value head, 120 for 58 at MLA's 16 heads of 512 + 64 columns."""
+    (the lanes hold cols, padded to 128); out_cols, out_dtype: the output
+    block's columns and type (the queries' type if None). The accumulator
+    is as wide as the output: a head's D columns in the dense family, the
+    klat columns of the latent sum, in float32, in the latent one.
+    Counted: each query block and the output block twice (the pipeline's
+    buffers), a float32 copy of the queries, the float32 accumulator, the
+    running max and sum (a lane-padded column each) and a step's float32
+    scores. Held against what Mosaic asks for at the serving cells' shapes
+    (a search over `vmem_limit_bytes` for a described v5e): 112 KB here for
+    the 104 KB a position it takes at 32 heads of 128, 70 for 47-65 at 20
+    heads over one key/value head; the latent family's, at 512 + 64
+    columns, is in tests/test_chip_compile.py."""
     heads = queries[0].shape[2]
     lanes = sum(_padded(q.shape[3], 128) for q in queries)
     item = queries[0].dtype.itemsize
+    out_item = item if out_dtype is None else jnp.dtype(out_dtype).itemsize
     out = _padded(out_cols, 128)
-    return heads * (2 * item * (lanes + out) + 4 * lanes + 4 * out
-                    + 2 * 4 * 128 + 4 * _padded(key_tile, 128))
+    return heads * (2 * item * lanes + 2 * out_item * out + 4 * lanes
+                    + 4 * out + 2 * 4 * 128 + 4 * _padded(key_tile, 128))
 
 
 def _latent_tp_row_vmem_bytes(heads: int, klat_local: int, key_tile: int,
@@ -299,12 +323,12 @@ class PagedSpec:
     # [block, dpe] roped-key blocks with NO per-head axis; hkv carries
     # the QUERY head count (every head attends the one shared latent,
     # group == 1) and the kernel contracts q_lat · latent^T + q_pe ·
-    # k_pe^T directly, re-expanding the value path per-tile through
-    # kv_up's v columns ([klat, nq, dv] kernel operand).
+    # k_pe^T directly and sums p · latent: its output is the normalised
+    # latent sum [., nq, klat] in float32, which the entry point expands
+    # through kv_up's v columns after the walk.
     latent: bool = False
     klat: int = 0
     dpe: int = 0
-    dv: int = 0
 
     @property
     def quantized(self) -> bool:
@@ -325,10 +349,10 @@ class PagedSpec:
                 f"kv_tile must be (sublane, lane) with lane a multiple "
                 f"of 128, got {self.kv_tile!r}")
         if self.latent:
-            if self.klat <= 0 or self.dpe <= 0 or self.dv <= 0:
+            if self.klat <= 0 or self.dpe <= 0:
                 raise ValueError(
-                    f"latent specs need klat/dpe/dv > 0, got "
-                    f"({self.klat}, {self.dpe}, {self.dv})")
+                    f"latent specs need klat/dpe > 0, got "
+                    f"({self.klat}, {self.dpe})")
             if self.group != 1:
                 raise ValueError(
                     "latent specs have no GQA grouping (every query "
@@ -368,7 +392,7 @@ def emit_paged_kernel(spec: PagedSpec):
         qlens_ref = refs.pop(0) if ragged else None
         q_refs, refs = refs[:n_q], refs[n_q:]
         tiles = [refs[k * pages:(k + 1) * pages] for k in range(n_pools)]
-        *consts, o_ref, acc, m_scr, l_scr = refs[n_pools * pages:]
+        o_ref, acc, m_scr, l_scr = refs[n_pools * pages:]
         g = pl.program_id(0)
         b, i = slot_ref[g], step_ref[g]
         kv_len = lens_ref[b]
@@ -381,7 +405,7 @@ def emit_paged_kernel(spec: PagedSpec):
 
         @pl.when(i * width < kv_len)
         def _fold():
-            s, values = scores(prep(*q_refs), tiles, *consts)
+            s, values = scores(prep(*q_refs), tiles)
             pos = i * width + jax.lax.broadcasted_iota(
                 jnp.int32, (1, width), 1)
             # the local query of row r sits at kv_len - q_len + row_q(r)
@@ -472,15 +496,18 @@ def _latent_tile(spec: PagedSpec):
     key, no per-head axis; quantized pools: a per-ROW scalar scale [bs]
     each) and the score contraction runs directly in latent space: the
     caller absorbs q_nope through kv_up's k_nope columns, so tile scores
-    are q_lat · latent^T + q_pe · k_pe^T. The value path re-expands THIS
-    tile's v rows in-register (dequantized latent tile × kv_up's v
-    columns, which arrive as [klat, nq*dv]: head h's value rows are
-    columns [h*dv, (h+1)*dv) of the product); a dense
+    are q_lat · latent^T + q_pe · k_pe^T. The value path stays in latent
+    space too (ISSUE 39): kv_up's value columns are the same for every
+    key, so Σ_keys p · (latent · w_v) is (Σ_keys p · latent) · w_v, and a
+    tile adds p · latent, the (dequantized) tile the scores were taken
+    from, to a [rows, klat] float32 accumulator; the entry point expands
+    the normalised sum once a query row. Neither w_v nor a tile's
+    [w, nq*dv] value rows exist in the kernel, and a dense
     [B, S_kv, nq, dqk+dv] reconstitution never materializes. Rows are
     nq * s_q with row = h*s_q + s (group == 1: every head shares the
     latent row, so no GQA fold)."""
     nq, s_q = spec.hkv, spec.s_q
-    klat, dpe, dv = spec.klat, spec.dpe, spec.dv
+    klat, dpe = spec.klat, spec.dpe
     rows = nq * s_q
 
     def prep(ql_ref, qp_ref):
@@ -491,7 +518,7 @@ def _latent_tile(spec: PagedSpec):
             return q.reshape(rows, d) * spec.scale
         return head_major(ql_ref, klat), head_major(qp_ref, dpe)
 
-    def scores(qs, tiles, wv_ref):
+    def scores(qs, tiles):
         ql, qp = qs
         lat_refs, pe_refs, *scale_refs = tiles
         ls_refs, ps_refs = scale_refs or (None, None)
@@ -504,20 +531,15 @@ def _latent_tile(spec: PagedSpec):
                                    preferred_element_type=jnp.float32))
 
         def values(p):
-            v = jnp.dot(lat, wv_ref[...].astype(lat.dtype),  # [w, nq*dv]
-                        preferred_element_type=jnp.float32)
-            p3 = p.reshape(nq, s_q, -1)
-            return jnp.concatenate([                       # [rows, dv]
-                jnp.dot(p3[h], v[:, h * dv:(h + 1) * dv],
-                        preferred_element_type=jnp.float32)
-                for h in range(nq)], axis=0)
+            return jnp.dot(p.astype(lat.dtype), lat,      # [rows, klat]
+                           preferred_element_type=jnp.float32)
 
         return s, values
 
     def finish(a):
         if s_q > 1:
-            a = jnp.swapaxes(a.reshape(nq, s_q, dv), 0, 1)
-        return a                                  # [(S_q,) nq, dv]
+            a = jnp.swapaxes(a.reshape(nq, s_q, klat), 0, 1)
+        return a                                  # [(S_q,) nq, klat]
 
     return 2, prep, lambda r: r % s_q, scores, finish
 
@@ -557,12 +579,13 @@ def _walk_steps(page_table, kv_lens, bs: int, pages: int):
 
 
 def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
-               queries, pools, consts, out_shape, acc_shape):
+               queries, pools, out, acc_shape):
     """The one `pallas_call` of the paged family. Scalar-prefetched: the
     layer id, the lengths and the walk's step maps (ragged: q_lens
-    last). A slot's query block and its output block follow ``slot_of``;
-    each of a step's ``spec.pages`` pages is a block [1, bs, ...] of the
-    STACKED pool [L, NB, bs, ...], named by ``block_of``."""
+    last). A slot's query block and its block of the output `out` (a
+    ShapeDtypeStruct) follow ``slot_of``; each of a step's ``spec.pages``
+    pages is a block [1, bs, ...] of the STACKED pool [L, NB, bs, ...],
+    named by ``block_of``."""
     pages = spec.pages
     total, slot_of, step_of, block_of = _walk_steps(
         page_table, kv_lens, spec.block_size, pages)
@@ -587,28 +610,25 @@ def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
         grid=(total,),
         in_specs=([slot_block(q.shape) for q in queries]
                   + [page_block(pool, p) for pool in pools
-                     for p in range(pages)]
-                  + [pl.BlockSpec(c.shape, lambda *_, n=c.ndim: (0,) * n)
-                     for c in consts]),
-        out_specs=slot_block(out_shape),
+                     for p in range(pages)]),
+        out_specs=slot_block(out.shape),
         scratch_shapes=[
             pltpu.VMEM(acc_shape, jnp.float32),
             pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32),
             pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32)],
     )
     return pl.pallas_call(
-        emit_paged_kernel(spec), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, queries[0].dtype),
+        emit_paged_kernel(spec), grid_spec=grid_spec, out_shape=out,
         interpret=_interpret(), name=name,
     )(*(a.astype(jnp.int32) for a in prefetch), *queries,
-      *(pool for pool in pools for _ in range(pages)), *consts)
+      *(pool for pool in pools for _ in range(pages)))
 
 
-def _call_pools(pages, scales, table_blocks: int):
+def _call_pools(pages, scales, table_blocks: int, key_tile: int = KEY_TILE):
     """(a call's pools in the kernel's order, what the spec takes from
     them): the two stacked page pools [L, NB, bs, ...], then their scale
     pools where the pages are quantized (scales not None), and the
-    spec's quant_dtype, kv_tile and pages a step."""
+    spec's quant_dtype, kv_tile and pages a step (of `key_tile` keys)."""
     quant_dtype = None
     if scales[0] is not None:
         quant_dtype = quant_dtype_of(pages[0].dtype)
@@ -623,17 +643,20 @@ def _call_pools(pages, scales, table_blocks: int):
         quant_dtype=quant_dtype, kv_tile=kv_tile,
         pages=pages_per_step(pages[0].shape[2],
                              _pages_vmem_bytes(pages, kv_tile),
-                             table_blocks))
+                             table_blocks, key_tile=key_tile))
 
 
-def _query_tile(queries, out_cols: int, pools, by_pools) -> int:
+def _query_tile(queries, out_cols: int, pools, by_pools,
+                out_dtype=None) -> int:
     """The query tile of a ragged call (`query_rows_per_step`) from its
-    shapes: queries [B, S_q, heads, cols], the pools and what
-    `_call_pools` took from them."""
+    shapes: queries [B, S_q, heads, cols], the output's columns a head and
+    its type (the queries' if None), the pools and what `_call_pools` took
+    from them."""
     pages = by_pools["pages"]
     return query_rows_per_step(
         queries[0].shape[1],
-        _query_row_vmem_bytes(queries, out_cols, pages * pools[0].shape[2]),
+        _query_row_vmem_bytes(queries, out_cols, pages * pools[0].shape[2],
+                              out_dtype),
         _query_vmem_budget(pools, by_pools["kv_tile"], pages))
 
 
@@ -668,12 +691,15 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     scores form directly in latent space. q_pe [..., nq, dpe]: the
     roped decoupled heads. lat_pages [NB, bs, klat] / pe_pages
     [NB, bs, dpe]: the compressed pool (NO per-head axis). w_v
-    [klat, nq, dv]: kv_up's v columns — the value path re-expands per
-    DMA'd tile in-register. lat_scales/pe_scales [NB, bs] fp32 mark
-    int8/fp8 pools (per-ROW scalar scales). softmax_scale is REQUIRED:
+    [klat, nq, dv]: kv_up's v columns. They are no operand of the kernel:
+    the walk returns the normalised latent sum Σ p · latent
+    [B(, S_q), nq, klat] in float32 and `_expand_values` takes it through
+    w_v once a query row (ISSUE 39). lat_scales/pe_scales [NB, bs] fp32
+    mark int8/fp8 pools (per-ROW scalar scales). softmax_scale is REQUIRED:
     the MLA scale 1/sqrt(dqk + dpe) is not derivable from the latent
     width. mesh: latent-COLUMN-shard over the tp axis (_tp_place_latent
-    — MLA has no KV heads to split); callers gate on tp_paged_eligible.
+    — MLA has no KV heads to split; it keeps a value path of its own);
+    callers gate on tp_paged_eligible.
     layer: int32 scalar — the pools (and scale pools) are then STACKED
     with a leading layer axis and the kernel reads that layer's blocks.
     Returns [B(, S_q), nq, dv] in q_lat's dtype."""
@@ -696,26 +722,36 @@ def paged_attention_latent(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
         b, nq, klat = q_lat.shape
         s_q = 1
     dpe = q_pe.shape[-1]
-    dv = w_v.shape[-1]
     bs = lat_pages.shape[2]
     mb = page_table.shape[1]
     pools, by_pools = _call_pools(
-        [lat_pages, pe_pages], [lat_scales, pe_scales], mb)
+        [lat_pages, pe_pages], [lat_scales, pe_scales], mb, LATENT_KEY_TILE)
     queries, join = [q_lat, q_pe], None
     if ragged:
-        s_q = _query_tile(queries, dv, pools, by_pools)
+        s_q = _query_tile(queries, klat, pools, by_pools, jnp.float32)
         page_table, kv_lens, q_lens, queries, join = _query_tiled(
             s_q, page_table, kv_lens, q_lens, queries)
     spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
                      num_blocks_seq=mb, hkv=nq, group=1,
                      scale=float(softmax_scale), latent=True, klat=klat,
-                     dpe=dpe, dv=dv, **by_pools)
-    out = _walk_call(
+                     dpe=dpe, **by_pools)
+    summed = _walk_call(
         spec, _paged_name(ragged, spec.quant_dtype, "_latent"), lid,
         page_table, kv_lens, q_lens, queries, pools,
-        [w_v.reshape(klat, nq * dv)],
-        queries[0].shape[:-1] + (dv,), (s_q * nq, dv))
-    return join(out) if ragged else out
+        jax.ShapeDtypeStruct(queries[0].shape, jnp.float32),
+        (s_q * nq, klat))
+    return _expand_values(join(summed) if ragged else summed,
+                          w_v).astype(q_lat.dtype)
+
+
+def _expand_values(summed, w_v):
+    """A latent walk's normalised sums [..., nq, klat] float32 through
+    kv_up's value columns w_v [klat, nq, dv] -> [..., nq, dv] float32, once
+    a query row. The sums keep their float32: at the default precision the
+    MXU would round them to bfloat16 first."""
+    return jnp.einsum("...nk,knd->...nd", summed, w_v,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
 
 
 def _latent_block_scores(q, pages, page_table, kv_lens, lid, scales=None,
@@ -1032,7 +1068,8 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                      num_blocks_seq=mb, hkv=hkv, group=hq // hkv,
                      scale=float(softmax_scale), **by_pools)
     out = _walk_call(spec, _paged_name(ragged, spec.quant_dtype), lid,
-                     page_table, kv_lens, q_lens, [q], pools, [], q.shape,
+                     page_table, kv_lens, q_lens, [q], pools,
+                     jax.ShapeDtypeStruct(q.shape, q.dtype),
                      (hkv, s_q * (hq // hkv), d))
     return join(out) if ragged else out
 

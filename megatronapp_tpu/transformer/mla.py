@@ -101,8 +101,9 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     the query and attends IN LATENT SPACE through the generated ragged
     paged kernel (ops/pallas/kernel_gen.paged_attention_latent,
     ISSUE 17): scores are q_lat·latentᵀ + q_pe·k_peᵀ over the page
-    table, values re-expand per-tile in-register — no dense gather and
-    no per-step kv_up over the whole history.
+    table, values are summed as latent rows and go through kv_up's v
+    columns once a query row (ISSUE 39) — no dense gather and no
+    per-step kv_up over the whole history.
 
     kv_scales: optional (lat_scales, pe_scales) per-row scalar fp32
     scale pools [L, NB, bs] marking a QUANTIZED latent/pe pool (paged path
@@ -179,8 +180,9 @@ def mla_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             # layer_id names this layer's plane. Each row appends at its
             # own (block, offset), in place; attention then runs IN LATENT
             # SPACE through the generated ragged paged kernel — q
-            # absorbed through kv_up's k_nope columns, values
-            # re-expanded per-tile in-register — so the history is never
+            # absorbed through kv_up's k_nope columns, values summed as
+            # latent rows and taken through kv_up's v columns once a
+            # query row, after the walk — so the history is never
             # gathered dense nor re-expanded through kv_up per step.
             from megatronapp_tpu.config.transformer_config import (
                 PositionEmbeddingKind,

@@ -114,6 +114,9 @@ LATENT_SHAPES = {
     # serve.deepseek-v2-lite.longgen-closed: rows of 512 + 64 columns, 32
     # slots, 4096 positions
     "deepseek-v2-lite": (32, 16, 512, 64, 128, 8192, 256),
+    # serve.longcat-flash-chat.agent-closed: the same rows under 64 heads,
+    # 64 slots
+    "longcat-flash-chat": (64, 64, 512, 64, 128, 16384, 256),
 }
 
 
@@ -156,6 +159,60 @@ def test_paged_attention(one_chip, chip_compile, shape, q_len):
                     i32((b,)), i32((b,)))
     compiled = jax.jit(fn).lower(*args).compile()
     assert _custom_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("model,cut,width,tile", [
+    ("deepseek-v2-lite", {"num_layers": 9}, 1024, 64),
+    ("longcat-flash-chat", None, 512, 16),
+])
+def test_latent_walk_at_the_cells_widths(one_chip, chip_compile,
+                                         monkeypatch, model, cut, width,
+                                         tile):
+    """ISSUE 39: both latent kernels at the two cells' shapes (32 slots of
+    16 heads, 64 of 64) and the prefill width the engine chooses for each.
+    The walk sums latent rows: its accumulator and output block are 512
+    float32 columns a head, where they were a head's 128 value columns, and
+    the query tile the shapes give (16 pages, 256 keys a step) fits
+    Mosaic's 16 MiB (a search over `vmem_limit_bytes` reads 8.7 MB at 64
+    rows of 16 heads, 10.7 at 16 rows of 64); kv_up's value columns are no
+    operand of the kernel (8 MB at 64 heads), its result is the latent sum,
+    and one product after it gives the heads' values."""
+    from megatronapp_tpu.ops.pallas import kernel_gen as kg
+    cut = LONGCAT_CUT if cut is None else cut
+    seq = 4096
+    assert _cell_prefill_width(one_chip, model, seq, **cut) == width
+    b, nq, klat, dpe, dv, nb, mb = LATENT_SHAPES[model]
+    bs = 16
+    bf16 = functools.partial(_sds, dtype=jnp.bfloat16, sharding=one_chip)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    pools = (bf16((nb, bs, klat)), bf16((nb, bs, dpe)))
+    w_v = bf16((klat, nq, dv))
+    fn = functools.partial(kg.paged_attention_latent,
+                           softmax_scale=(128 + dpe) ** -0.5)
+
+    def lower(lead, rows, *q_lens):
+        return jax.jit(fn).lower(
+            bf16(lead + (nq, klat)), bf16(lead + (nq, dpe)), *pools,
+            i32((rows, mb)), i32((rows,)), w_v, *q_lens).compile()
+
+    def check(compiled, lead):
+        text = compiled.as_text()
+        call, = (line for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line)
+        assert " f32[" + ",".join(map(str, lead + (nq, klat))) + "]" in (
+            call.split("custom-call(")[0])
+        assert f"[{klat},{nq * dv}]" not in call
+        assert f"[{klat},{nq},{dv}]" not in call.split("custom-call(")[1]
+
+    check(lower((b,), b), (b,))
+    seen = []
+    rule = kg.query_rows_per_step
+    monkeypatch.setattr(
+        kg, "query_rows_per_step",
+        lambda *a: seen.append(rule(*a)) or seen[-1])
+    compiled = lower((1, width), 1, i32((1,)))
+    assert seen == [tile]
+    check(compiled, (width // tile, tile))
 
 
 @pytest.mark.parametrize("q_len", [1, "chosen"],

@@ -317,14 +317,15 @@ def _mk_inputs(rng, b, s_q, hq, hkv, d, bs, mb, quant, dtype):
     return q, kp, vp, tbl, lens, ks, vs
 
 
-def _pages_for(pools, mb):
+def _pages_for(pools, mb, key_tile=kernel_gen.KEY_TILE):
     """Pages a step, as the entry points derive them for these pools
     ([NB, bs, ...] each, KV pools first, then their scale pools)."""
     pools = [p[None] for p in pools if p is not None]
     quant = len(pools) == 4
     tile = default_kv_tile("int8" if quant else None)
     return pages_per_step(pools[0].shape[2],
-                          _pages_vmem_bytes(pools, tile), mb)
+                          _pages_vmem_bytes(pools, tile), mb,
+                          key_tile=key_tile)
 
 
 def _step_blocks(tbl_row, kv_len, i, pages, bs):
@@ -578,8 +579,10 @@ def _latent_sim_jit(q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens, w_v,
                     ragged):
     """jnp replay of the latent walk (emit_paged_kernel + _latent_tile):
     the same pages a step, the same tile order, the same op sequence a
-    tile (scaled-q dots, mask, online-softmax rescale, per-tile v
-    re-expansion), rows head-major (row = h*s_q + s). The replay must be
+    tile (scaled-q dots, mask, online-softmax rescale, p · latent into a
+    [rows, klat] accumulator), rows head-major (row = h*s_q + s); ->
+    the normalised latent sums in float32, which the caller expands as
+    the entry point does (ISSUE 39). The replay must be
     jitted as ONE computation so XLA applies the same fusions (mul+add →
     FMA) it applies to the interpreted kernel body — op-by-op eager
     replay drifts by one ulp on multi-tile accumulators. Do not
@@ -589,11 +592,9 @@ def _latent_sim_jit(q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens, w_v,
     else:
         (b, nq, klat), s_q = q_lat.shape, 1
     dpe = q_pe.shape[-1]
-    dv = w_v.shape[-1]
     bs = lat_pages.shape[1]
     rows, width = nq * s_q, pages * bs
     steps = -(-tbl.shape[1] // pages)
-    wv2 = w_v.reshape(klat, nq * dv)
 
     def head_major(x, d):
         x = x.astype(jnp.float32)
@@ -613,7 +614,7 @@ def _latent_sim_jit(q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens, w_v,
         ql = head_major(q_lat[bi], klat)
         qp = head_major(q_pe[bi], dpe)
         kv_len = kv_lens[bi]
-        state = (jnp.zeros((rows, dv), jnp.float32),
+        state = (jnp.zeros((rows, klat), jnp.float32),
                  jnp.full((rows, 1), _NEG_INF, jnp.float32),
                  jnp.zeros((rows, 1), jnp.float32))
         for i in range(steps):
@@ -631,32 +632,27 @@ def _latent_sim_jit(q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens, w_v,
             valid = (pos < kv_len) & (pos <= abs_q)
 
             def values(p, lat=lat):
-                v = jnp.dot(lat, wv2.astype(lat.dtype),
-                            preferred_element_type=jnp.float32)
-                p3 = p.reshape(nq, s_q, -1)
-                return jnp.concatenate([
-                    jnp.dot(p3[h], v[:, h * dv:(h + 1) * dv],
-                            preferred_element_type=jnp.float32)
-                    for h in range(nq)], axis=0)
+                return jnp.dot(p.astype(lat.dtype), lat,
+                               preferred_element_type=jnp.float32)
 
             state = _fold_tile(s, valid, i * width < kv_len, state, values)
         a = state[0] / jnp.maximum(state[2], 1e-20)
         if s_q > 1:
-            a = jnp.swapaxes(a.reshape(nq, s_q, dv), 0, 1)
-        outs.append(a.reshape(q_lat.shape[1:-1] + (dv,))
-                    .astype(q_lat.dtype))
+            a = jnp.swapaxes(a.reshape(nq, s_q, klat), 0, 1)
+        outs.append(a.reshape(q_lat.shape[1:]))
     return jnp.stack(outs)
 
 
 def _latent_blockwise_sim(q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens,
                           w_v, q_lens=None, softmax_scale=None,
                           lat_scales=None, pe_scales=None):
-    return _latent_sim_jit(
+    summed = _latent_sim_jit(
         q_lat, q_pe, lat_pages, pe_pages, tbl, kv_lens, w_v, q_lens,
         lat_scales, pe_scales, scale=float(softmax_scale),
         pages=_pages_for([lat_pages, pe_pages, lat_scales, pe_scales],
-                         tbl.shape[1]),
+                         tbl.shape[1], kernel_gen.LATENT_KEY_TILE),
         ragged=q_lens is not None)
+    return kernel_gen._expand_values(summed, w_v).astype(q_lat.dtype)
 
 
 class TestLatentKernelPins:
@@ -820,7 +816,8 @@ class TestLatentKernelPins:
 
 _BODIES = ["paged_decode", "paged_mq", "paged_decode_latent",
            "paged_mq_latent"]
-_W_BS, _W_MB, _W_SQ = 16, 20, 3          # 8 pages a step: 2.5 tiles a row
+# 8 pages a step, 2.5 tiles a row; a latent body 16 pages, 1.25 tiles
+_W_BS, _W_MB, _W_SQ = 16, 20, 3
 _W_SCALE = 1.0 / ((16 + 8) ** 0.5)
 
 
@@ -832,12 +829,13 @@ _WALK_POOLS = {"fp32": (jnp.float32, None), "bf16": (jnp.bfloat16, None),
 
 
 def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
-               s_q=_W_SQ, q_lens=None):
+               s_q=_W_SQ, q_lens=None, heads=4):
     """(kernel output, oracle output or None) of `body` over slots of
     `lens` cached rows at the walk shapes, pages stored as `pool` (int8
     and fp8 pages come with their fp32 scale pools). A ragged body's slots
     bring `s_q` query rows, of which `q_lens` are real (by default as many
-    as the slot's length allows). nan_past: every
+    as the slot's length allows); a latent body has `heads` query heads.
+    nan_past: every
     table entry past a slot's length names a page filled with NaN, and a
     scale page filled with NaN (an int8 page cannot hold one: there the
     scale page alone carries it); no oracle then: the oracles gather the
@@ -852,7 +850,7 @@ def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
                   else jnp.asarray(q_lens, jnp.int32))
     if latent:
         ql, qp, lat, pe, w_v, tbl, _, _, _ = _mk_latent_inputs(
-            rng, b, s_q, 4, 32, 8, 16, _W_BS, _W_MB, False, dtype)
+            rng, b, s_q, heads, 32, 8, 16, _W_BS, _W_MB, False, dtype)
         pools = [lat, pe]
     else:
         q, kp, vp, tbl, _, _, _ = _mk_inputs(
@@ -922,8 +920,10 @@ class TestWalk:
                 else _legacy_tol(jnp.bfloat16))
 
     @pytest.mark.parametrize("length", [
-        1, _W_BS, _W_BS + 1, 8 * _W_BS, 8 * _W_BS + 1, _W_MB * _W_BS],
-        ids=["one", "page", "page+1", "tile", "tile+1", "table"])
+        1, _W_BS, _W_BS + 1, 8 * _W_BS, 8 * _W_BS + 1, 16 * _W_BS,
+        16 * _W_BS + 1, _W_MB * _W_BS],
+        ids=["one", "page", "page+1", "tile", "tile+1", "latent-tile",
+             "latent-tile+1", "table"])
     @pytest.mark.parametrize("body", _BODIES)
     def test_one_slot_of_length(self, body, length):
         out, ref = _walk_case(body, [length])
@@ -936,11 +936,13 @@ class TestWalk:
                               pool=pool)
         _assert_close(out, ref, **self._tol(body, pool))
 
-    @pytest.mark.parametrize("body", ["paged_mq", "paged_mq_latent"])
-    def test_ragged_tail_straddles_a_tile(self, body):
-        """The new rows sit at positions 127, 128, 129: the causal tail
-        mask crosses from one step's tile into the next."""
-        out, ref = _walk_case(body, [8 * _W_BS + 2, 8 * _W_BS + 1])
+    @pytest.mark.parametrize("body,pages", [
+        ("paged_mq", 8), ("paged_mq_latent", 8), ("paged_mq_latent", 16)])
+    def test_ragged_tail_straddles_a_tile(self, body, pages):
+        """The new rows sit at positions 127, 128, 129 (a latent body's
+        tile is 16 pages: 255, 256, 257 too): the causal tail mask crosses
+        from one step's tile into the next."""
+        out, ref = _walk_case(body, [pages * _W_BS + 2, pages * _W_BS + 1])
         _assert_close(out, ref, **self.TOL)
 
     @pytest.mark.parametrize("body", _BODIES)
@@ -981,6 +983,9 @@ class TestWalk:
         lat_page = _pages_vmem_bytes(mla, default_kv_tile(None))
         assert lat_page == 16 * (512 + 128) * 2
         assert pages_per_step(16, lat_page, 256) == 8
+        # ... whose own key tile is 256 wide (ISSUE 39): 16 pages
+        assert pages_per_step(
+            16, lat_page, 256, key_tile=kernel_gen.LATENT_KEY_TILE) == 16
         # int8 K/V pages are (32, 128) tiles, their fp32 scale pages
         # (8, 128) ones
         int8 = (pools((2, 64, 16, 32, 80), (2, 64, 16, 32, 80),

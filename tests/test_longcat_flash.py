@@ -434,9 +434,13 @@ def test_what_it_cannot_do_yet_refuses_in_words(build, error, said):
 def test_deepseek_decode_step_did_not_grow_an_operation():
     """The shared MLA and MoE code serves DeepSeek-V2-Lite with the new
     fields off: its traced decode step (tiny widths, 1 dense + 2 MoE layers)
-    launches what it launched on the parent commit, equation for equation
-    (`launch_stats`, read off the jaxpr): 3 latent kernels, 15 loop steps,
-    1,345 launches, no slice of an expert stack."""
+    launches what it launched before the double layer came (PR 38: 1,345
+    launches, 1,360 dispatches) and, since ISSUE 39, one expansion a latent
+    call more (`launch_stats`, read off the jaxpr): 3 latent kernels, 15
+    loop steps, 1,351 launches (each of the 3 calls' latent sums goes
+    through kv_up's value columns after the walk: a product and its
+    operand's conversion, where `w_v` was reshaped for the kernel), no
+    slice of an expert stack."""
     model = manifest.load_module("models", "deepseek_v2")
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "deepseek-v2-lite.json")) as f:
@@ -446,8 +450,8 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
         model.init_params(cfg, seed=5), cfg, max_batch=2, max_seq_len=64,
         paged=True, num_blocks=16, block_size=4, prefill_chunk=8)
     assert eng.stats_snapshot(include_dispatch=True)["decode_dispatch"] == {
-        "launches": 1345, "kernels": 3, "loop_steps": 15, "eqns": 1,
-        "dispatches_per_step": 1360, "expert_stack_slices": 0}
+        "launches": 1351, "kernels": 3, "loop_steps": 15, "eqns": 1,
+        "dispatches_per_step": 1366, "expert_stack_slices": 0}
     moe_stats = eng.stats_snapshot()["moe"]
     assert moe_stats["experts_here"] == 8          # every expert is held
     # and the double layer's own step: two latent kernels a layer, the
