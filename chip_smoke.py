@@ -4,6 +4,7 @@
                                      # then a tiny hybrid state-space engine,
                                      # a tiny EVA-attention engine and a
                                      # tiny double-layer expert-share engine
+                                     # and a tiny short-convolution MoE one
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -32,6 +33,12 @@ vocab 50304), random weights from the entry points' own seeds:
   zero-compute experts of which 4 are held) through the paged engine: two
   latent kernels a layer loop over pools of 2 planes a layer, every pick
   counted as held, absent or zero-compute.
+
+- conv: a tiny hybrid of gated short convolutions and grouped-query
+  attention whose feed-forwards are experts behind a leading dense layer
+  (sigmoid scores, a selection bias) through the paged engine: one tail
+  pool beside the page pools, aliased; the experts' stacks read in place;
+  every pick counted.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -869,6 +876,131 @@ def check_share(rc, lines, tiny=False):
 
 
 # ---------------------------------------------------------------------------
+# Phase: gated short convolutions with MoE feed-forwards, tiny widths
+# ---------------------------------------------------------------------------
+
+CONV = dict(num_layers=5, attn_layer_period=4, attn_layer_offset=1,
+            shortconv_kernel=3, num_moe_experts=8, moe_router_topk=2,
+            moe_first_k_dense=1)
+CONV_REQUESTS = ((40, 12), (9, 12), (70, 12))       # (prompt, new tokens)
+
+
+def phase_conv(tiny):
+    rc, tr = _run_child("conv", ["--child", "conv"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_conv(rc, tr.lines, tiny)
+
+
+def child_conv(tiny):
+    """In the child: a hybrid of gated short convolutions and grouped-query
+    attention (5 layers of which 1 attends with 4 query heads on 2 key/value
+    heads of 64 and per-head norms; a leading dense layer, then 8 experts
+    top-2 by sigmoid scores + a selection bias) serves three requests
+    through DynamicInferenceEngine(paged=True) on the device, and the
+    compiled decode step says what it holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    cfg = TransformerConfig(
+        hidden_size=256, num_attention_heads=4, num_query_groups=2,
+        ffn_hidden_size=512, vocab_size=512, max_position_embeddings=128,
+        normalization=NormKind.rmsnorm, activation=ActivationKind.swiglu,
+        add_bias_linear=False, qk_layernorm=True, rotary_base=1e6,
+        moe_ffn_hidden_size=128, moe_router_score="sigmoid",
+        moe_router_selection_bias=True, params_dtype=jnp.bfloat16, **CONV)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
+                                 paged=True)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n, new in CONV_REQUESTS:
+        eng.add_request(rng.integers(0, 512, n).astype(np.int32), new,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), bool), None).compile()
+    pools = eng._pools()
+    stats = eng.stats_snapshot(include_dispatch=True)
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 512 for v in out.values()
+                             for t in v)),
+        "moe": stats["moe"], "state": stats["state"],
+        "expert_stack_slices": stats["decode_dispatch"].get(
+            "expert_stack_slices"),
+        "pool_shapes": [list(p.shape) for p in pools],
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_conv(rc, lines, tiny=False):
+    out = {"phase": "conv", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    # K and V of the one attention layer's plane, then the convolution
+    # layers' tails alone: [4, slots, 2 columns x 256], no h pool.
+    shapes = out["pool_shapes"]
+    if len(shapes) != 3 or shapes[0][0] != 1 or shapes[2] != [4, 8, 512]:
+        out["problems"].append(f"pools {shapes}: not one attention plane "
+                               "and one pool of 4 layers' two columns")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    if out["expert_stack_slices"] != 0:
+        out["problems"].append(
+            f"{out['expert_stack_slices']} slices of a layer's experts out "
+            "of their stacks in the traced decode step, not 0")
+    want = sum(n + new for n, new in CONV_REQUESTS)
+    if out["tokens"] != want or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back, not "
+                               f"{want}, or one outside the vocabulary")
+    state = out["state"] or {}
+    if (state.get("kind"), state.get("layers"), state.get("resets")) != (
+            "conv", 4, len(CONV_REQUESTS)):
+        out["problems"].append(f"state counters {state}: not 4 convolution "
+                               "layers' tails, reset once a request")
+    moe = out["moe"] or {}
+    picks = moe.get("tokens", 0) * CONV["moe_router_topk"] * (
+        CONV["num_layers"] - CONV["moe_first_k_dense"])
+    if not picks or moe.get("assignments") != picks \
+            or moe.get("experts_here") != CONV["num_moe_experts"]:
+        out["problems"].append(
+            f"moe counters {moe}: the picks are not tokens x top-k x MoE "
+            f"layers = {picks} over {CONV['num_moe_experts']} held experts")
+    if not any("paged decode" in ln and _kernel_mode(dev, tiny) in ln
+               for ln in lines):
+        out["problems"].append("the engine did not say it ran the paged "
+                               f"decode kernel {_kernel_mode(dev, tiny)}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Phase: four chips (only with --chips 4)
 # ---------------------------------------------------------------------------
 
@@ -1068,7 +1200,8 @@ def run(chips, tiny):
                 lambda: phase_server(tiny),
                 lambda: phase_hybrid(tiny),
                 lambda: phase_eva(tiny),
-                lambda: phase_share(tiny)]
+                lambda: phase_share(tiny),
+                lambda: phase_conv(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -1092,7 +1225,7 @@ def main(argv=None):
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
     ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva",
-                                        "share"],
+                                        "share", "conv"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -1110,6 +1243,9 @@ def main(argv=None):
         return 0
     if args.child == "share":
         child_share(args.tiny)
+        return 0
+    if args.child == "conv":
+        child_conv(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

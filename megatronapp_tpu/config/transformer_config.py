@@ -139,8 +139,15 @@ class TransformerConfig:
     #   dense FFNs, with one MoE on the first sublayer's normed output whose
     #   result is added after the second FFN (transformer/block.py); it owns
     #   two planes of the KV pools. moe_first_k_dense stays 0 with it.
+    # moe_router_score: what the router's scores are, "softmax" over its
+    #   width or an elementwise "sigmoid" (HF `lfm2_moe`: the top-k is taken
+    #   on sigmoid(logits) + b where there is a selection bias, the weights
+    #   are the chosen scores themselves, and norm_topk_prob divides them by
+    #   their sum + 1e-6, the published constant). Sigmoid scores have no
+    #   load-balance or z loss defined here.
     moe_zero_experts: int = 0
     moe_router_selection_bias: bool = False
+    moe_router_score: str = "softmax"
     moe_experts_held: Optional[Tuple[int, int]] = None
     moe_shortcut_double_layer: bool = False
 
@@ -152,6 +159,13 @@ class TransformerConfig:
     # mamba_expand, mamba_dt_rank; None = ceil(hidden / 16)) and Jamba's
     # RMS norms on dt, B and C. The convolution has a bias and the two
     # projections none (HF mamba_conv_bias true, mamba_proj_bias false).
+    # shortconv_kernel > 0 makes every non-attention layer's first half a
+    # gated short convolution instead (HF `lfm2` / `lfm2_moe`: layer_types
+    # "conv", conv_L_cache taps, conv_bias false; transformer/shortconv.py):
+    # no recurrence h, its whole state is the convolution's last
+    # shortconv_kernel - 1 gated inputs. Such a stack (and no state-space
+    # one) may run MoE feed-forwards behind moe_first_k_dense leading dense
+    # ones.
     attn_layer_period: Optional[int] = None
     attn_layer_offset: int = 0
     ssm_state_dim: int = 16
@@ -159,6 +173,7 @@ class TransformerConfig:
     ssm_expand: int = 2
     ssm_dt_rank: Optional[int] = None
     ssm_inner_norms: bool = False
+    shortconv_kernel: int = 0
 
     # EVA attention (HF `evabyte`: attention_class "eva", window_size,
     # chunk_size; Zheng et al. 2023, "Efficient Attention via Control
@@ -380,12 +395,49 @@ class TransformerConfig:
                 raise ValueError(
                     f"attn_layer_offset={self.attn_layer_offset} must lie "
                     f"in [0, attn_layer_period={self.attn_layer_period})")
-            if (self.is_moe or self.multi_latent_attention
+            if (self.multi_latent_attention
                     or self.heterogeneous_layers_config_json):
                 raise ValueError(
-                    "a hybrid state-space stack (attn_layer_period) runs "
-                    "dense feed-forwards and plain attention layers: no "
-                    "MoE, MLA or heterogeneous block configs")
+                    "a hybrid stack (attn_layer_period) runs plain "
+                    "attention layers in its own layer loop: no MLA or "
+                    "heterogeneous block configs")
+            if self.is_moe and not self.shortconv_kernel:
+                raise ValueError(
+                    "a hybrid state-space stack (attn_layer_period with "
+                    "Mamba mixers) runs dense feed-forwards: no MoE; only "
+                    "the gated short-convolution stack (shortconv_kernel) "
+                    "has been given MoE feed-forwards")
+            if self.is_moe and (
+                    self.moe_layer_freq != 1 or self.moe_picks_unheld
+                    or self.moe_shortcut_double_layer or self.mtp_num_layers
+                    or self.moe_aux_loss_coeff or self.moe_z_loss_coeff):
+                raise ValueError(
+                    "a hybrid stack's MoE feed-forwards sit in every layer "
+                    "behind moe_first_k_dense leading dense ones, hold every "
+                    "expert, and carry no aux loss through the hybrid layer "
+                    "loop: no moe_layer_freq, moe_experts_held, "
+                    "moe_zero_experts, shortcut double layer, MTP, "
+                    "moe_aux_loss_coeff or moe_z_loss_coeff")
+        if self.shortconv_kernel and (
+                self.shortconv_kernel < 2 or self.attn_layer_period is None):
+            raise ValueError(
+                f"shortconv_kernel={self.shortconv_kernel} is the taps (at "
+                "least 2) of the gated short convolution that the "
+                "non-attention layers of a hybrid stack (attn_layer_period) "
+                "run")
+        if self.moe_router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router_score={self.moe_router_score!r}: the router "
+                "scores by 'softmax' or 'sigmoid'")
+        if self.moe_router_score == "sigmoid" and (
+                not self.is_moe or self.moe_aux_loss_coeff
+                or self.moe_z_loss_coeff or self.moe_zero_experts):
+            raise ValueError(
+                "sigmoid router scores (moe_router_score) belong to an MoE "
+                "model and have no load-balance loss, z loss or zero-compute "
+                "experts defined: balance such a router by its selection "
+                "bias (moe_router_selection_bias), which nothing here "
+                "updates yet (ROADMAP M2)")
         if self.eva_window_size or self.eva_chunk_size:
             w, c = self.eva_window_size, self.eva_chunk_size
             if w <= 0 or c <= 0 or w % c:
@@ -466,10 +518,28 @@ class TransformerConfig:
         return self.moe_experts_held or (0, self.num_moe_experts or 0)
 
     @property
-    def num_ssm_layers(self) -> int:
-        """Layers whose first half is a state-space mixer (0 unless
-        attn_layer_period is set)."""
+    def num_recurrent_layers(self) -> int:
+        """Layers whose first half is no attention but a mixer with a
+        state a sequence (0 unless attn_layer_period is set)."""
         return self.num_layers - self.num_attention_layers
+
+    @property
+    def num_ssm_layers(self) -> int:
+        """Layers whose first half is a state-space mixer."""
+        return 0 if self.shortconv_kernel else self.num_recurrent_layers
+
+    @property
+    def num_conv_layers(self) -> int:
+        """Layers whose first half is a gated short convolution."""
+        return self.num_recurrent_layers if self.shortconv_kernel else 0
+
+    @property
+    def moe_counts_load(self) -> bool:
+        """Whether the serving steps count the load of each held expert
+        (moe.HELD_COUNTS, the most rows one expert got among them): where
+        picks may miss the held experts, and where the router carries a
+        selection bias, which exists to balance that load."""
+        return self.moe_picks_unheld or self.moe_router_selection_bias
 
     def num_parameters(self) -> int:
         """Approximate parameter count (embedding + blocks + final norm)."""

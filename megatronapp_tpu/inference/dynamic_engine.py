@@ -274,17 +274,35 @@ def _decode_step(params, tokens, cache, lengths, active,
 
 def _moe_of(tree):
     """The "moe" params of a layer or of a stack of layers (None without):
-    a shortcut-connected double layer keeps them in its first half."""
+    a shortcut-connected double layer keeps them in its first half, a
+    hybrid stack among its feed-forward halves ("ffn")."""
     if not isinstance(tree, dict):
         return None
-    return tree.get("first", tree).get("moe")
+    return tree.get("first", tree.get("ffn", tree)).get("moe")
 
 
 def _with_moe(tree, moe):
     """`tree` with `moe` where _moe_of found the old one."""
     if "first" in tree:
         return dict(tree, first=dict(tree["first"], moe=moe))
+    if "ffn" in tree:
+        return dict(tree, ffn=dict(tree["ffn"], moe=moe))
     return dict(tree, moe=moe)
+
+
+def _moe_stacks(block, ctx=None):
+    """(`block` without its experts' fc1/fc2 stacks, {name: stack}): what
+    the paged layer loop reads through the layer id and not as a layer's
+    slice (_scan_paged_layers). Nothing is taken out on a mesh, of a dense
+    model, or of resident int8 pairs."""
+    moe = _moe_of(block)
+    if ctx is not None or moe is None:
+        return block, {}
+    stacks = {k: w for k, w in moe.items()
+              if k in ("fc1_kernel", "fc2_kernel")
+              and not isinstance(w, dict)}
+    return _with_moe(block, {k: w for k, w in moe.items()
+                             if k not in stacks}), stacks
 
 
 def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
@@ -322,7 +340,11 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     four carried and written in place alike. The loop is
     block.hybrid_layer_loop's scanned runs; an attention layer gets its
     plane (kv_plane), a state-space layer the state pools, its plane of
-    them and `rows`, the slots of h's rows (None: row b is slot b).
+    them and `rows`, the slots of h's rows (None: row b is slot b). A stack
+    of gated short convolutions has the one tail pool: (k, v, conv). Its
+    feed-forwards may be experts behind leading dense layers: the stacks
+    are read through the layer id as below, and the layers' counts are
+    summed along the loop's carry.
 
     A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
     one step of the same scan: layer_forward runs its two attention
@@ -336,36 +358,40 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
         if lora is not None or ctx is not None:
             raise ValueError("a hybrid state-space stack serves on one "
                              "device, without lora")
+        block, stacks = _moe_stacks(params["block"])
+        n_lead = cfg.moe_first_k_dense
+        counts0 = None
+        if cfg.is_moe:
+            counts0 = jnp.zeros(
+                (len(HELD_COUNTS) if cfg.moe_counts_load else 2,), jnp.int32)
 
-        def run(carry, attends, k, lid):
-            hh, pools, kvs = carry
-            layer_p = hybrid_layer_params(params["block"], attends, k, lid)
+        def run(carry, attends, k, lid, lead=False):
+            hh, pools, kvs, counts = carry
+            layer_p = hybrid_layer_params(block, attends, k, lid, lead)
+            if stacks and not lead:
+                layer_p = _with_moe(layer_p, dict(layer_p["moe"], **{
+                    name: StackedLayer(w, lid - n_lead)
+                    for name, w in stacks.items()}))
             if attends:
-                (hh, new), _ = layer(layer_p, hh, lid, pools[:2], kvs, None,
-                                     kv_plane=k)
+                (hh, new), aux = layer(layer_p, hh, lid, pools[:2], kvs,
+                                       None, kv_plane=k)
                 pools = tuple(new[:2]) + pools[2:]
                 kvs = None if kvs is None else tuple(new[2:])
             else:
-                (hh, new), _ = layer(layer_p, hh, lid, None, None, None,
-                                     ssm_state=pools[2:] + (k,),
-                                     state_rows=rows)
+                (hh, new), aux = layer(layer_p, hh, lid, None, None, None,
+                                       ssm_state=pools[2:] + (k,),
+                                       state_rows=rows)
                 pools = pools[:2] + tuple(new)
-            return hh, pools, kvs
+            if counts is not None and not lead:
+                counts = counts + aux
+            return hh, pools, kvs, counts
 
-        h, pages, scales = hybrid_layer_loop(
-            cfg, (h, tuple(pages), None if scales is None else tuple(scales)),
-            run)
-        return h, None, pages + (scales or ())
+        h, pages, scales, moe = hybrid_layer_loop(
+            cfg, (h, tuple(pages), None if scales is None else tuple(scales),
+                  counts0), run)
+        return h, moe, pages + (scales or ())
     lead = cfg.moe_first_k_dense
-    block = params["block"]
-    stacks = {}
-    moe = _moe_of(block)
-    if ctx is None and moe is not None:
-        stacks = {k: w for k, w in moe.items()
-                  if k in ("fc1_kernel", "fc2_kernel")
-                  and not isinstance(w, dict)}
-        block = _with_moe(block, {k: w for k, w in moe.items()
-                                  if k not in stacks})
+    block, stacks = _moe_stacks(params["block"], ctx)
 
     def body(carry, xs):
         hh, kv, kvs = carry
@@ -753,12 +779,15 @@ class DynamicInferenceEngine:
         # requests drain and the params swap lands on an empty batch.
         self.pause_admission = False
 
-        # A model with state-space layers (cfg.attn_layer_period) keeps,
-        # beside its attention layers' pages, a recurrent state a slot
+        # A model with state-space layers or gated short convolutions
+        # (cfg.attn_layer_period) keeps, beside its attention layers'
+        # pages, a recurrent state or a convolution's tail a slot
         # (PagedKVCache.state). Whatever would move a request's cache
         # between places needs a snapshot of that state, which does not
         # exist yet: each refuses here or at its call, in words.
-        self.has_state = cfg.num_ssm_layers > 0
+        self.has_state = cfg.num_recurrent_layers > 0
+        self.state_kind = ("conv" if cfg.num_conv_layers
+                           else "ssm" if self.has_state else None)
         if self.has_state:
             refused = [name for name, on in (
                 ("paged=False (the dense cache)", not paged),
@@ -771,7 +800,7 @@ class DynamicInferenceEngine:
                 ("ctx (a serving mesh)", ctx is not None)) if on]
             if refused:
                 raise ValueError(
-                    "this model has state-space layers, whose recurrent "
+                    f"this model has {self._state_words()}, whose recurrent "
                     "state lives in the paged engine's slots on one device "
                     "and has no state snapshots yet (ROADMAP M4): cannot "
                     "serve it with " + "; ".join(refused))
@@ -1074,16 +1103,31 @@ class DynamicInferenceEngine:
     def decode_traces(self) -> int:
         return self._trace_counts["decode"]
 
+    def _state_words(self) -> str:
+        return ("gated short-convolution layers" if self.state_kind == "conv"
+                else "state-space layers")
+
     def startup_line(self) -> str:
         """What this engine runs, for the log and a server's banner."""
         line = (f"dynamic engine: paged={self.paged}, max_batch="
                 f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
                 f"prefill_chunk={self.prefill_chunk}")
+        cfg = self.cfg
         if self.has_state:
-            line += (f", state={self.cfg.num_ssm_layers} state-space layers"
-                     f" x {self.pool.state_bytes_per_slot} B a slot, prefix "
+            line += (f", state={cfg.num_recurrent_layers} "
+                     f"{self._state_words()} x "
+                     f"{self.pool.state_bytes_per_slot} B a slot, prefix "
                      "reuse off (a prefix hit would skip tokens whose "
                      "state nobody kept)")
+            if cfg.is_moe:
+                line += (f", layers={cfg.num_layers}: "
+                         f"{cfg.num_attention_layers} attention + "
+                         f"{cfg.num_recurrent_layers} {self._state_words()}"
+                         f", the first {cfg.moe_first_k_dense} with a dense "
+                         f"feed-forward, the others with "
+                         f"{cfg.num_moe_experts} experts, top-"
+                         f"{cfg.moe_router_topk} by {cfg.moe_router_score} "
+                         "scores")
         if self.eva:
             line += (f", eva=window {self.cfg.eva_window_size} exact rows + "
                      f"one summary row every {self.cfg.eva_chunk_size} "
@@ -1092,7 +1136,6 @@ class DynamicInferenceEngine:
                      "reuse (off), spec_method, spill/park, export/import/"
                      "adopt, lora, an injected pool or mesh, a quantized "
                      "pool")
-        cfg = self.cfg
         if cfg.moe_shortcut_double_layer:
             line += (f", layers={cfg.num_layers} double layers x 2 attention "
                      f"sublayers = {cfg.kv_planes} planes of the pools, one "
@@ -1207,13 +1250,15 @@ class DynamicInferenceEngine:
     def _commit_pools(self, new):
         """Take a step's pools back: the donated buffers themselves,
         written in place — (k, v), then (ssm, conv) for a model with
-        state-space layers, then (k_scales, v_scales) for int8 pools,
-        whose in-jit quantize writes the scale pools through the same
-        layer loop."""
+        state-space layers or (conv,) for one with gated short
+        convolutions, then (k_scales, v_scales) for int8 pools, whose
+        in-jit quantize writes the scale pools through the same layer
+        loop."""
         self.pool.pages = tuple(new[:2])
         rest = tuple(new[2:])
         if self.has_state:
-            self.pool.state, rest = rest[:2], rest[2:]
+            n = len(self.pool.state)
+            self.pool.state, rest = rest[:n], rest[n:]
         if self.pool.quantized:
             self.pool.scales = rest
 
@@ -1325,15 +1370,10 @@ class DynamicInferenceEngine:
         req = self.requests.get(request_id)
         if req is None:
             return None
-        if req in self.waiting:
-            try:
-                self.waiting.remove(req)
-            except ValueError:
-                pass    # raced with admission: treat as running below
-            else:
-                req.finished = True
-                self._rt.finish(request_id, "abort")
-                return "waiting"
+        if self._leave_queue(req):
+            req.finished = True
+            self._rt.finish(request_id, "abort")
+            return "waiting"
         if not req.finished:
             # Running — or mid-admission on the stepper thread (slot not
             # yet assigned): either way, marking finished retires it on
@@ -1342,6 +1382,22 @@ class DynamicInferenceEngine:
             self._rt.instant("abort", request_id)
             return "running"
         return None
+
+    def _leave_queue(self, req: Request) -> bool:
+        """Take `req` out of the waiting queue; False if it is not there
+        (it runs, or admission took it meanwhile). A canceller calls this
+        from its own thread while the stepper's admission pops from the
+        same deque, and a deque that changes under a search raises rather
+        than answers (a queue of 192 under 384 cancels did, in one drain of
+        seven: PERF.md, PR 41): such a search is made again."""
+        while True:
+            try:
+                self.waiting.remove(req)
+                return True
+            except ValueError:
+                return False
+            except (RuntimeError, IndexError):
+                continue
 
     def expire_overdue(self, now: Optional[float] = None) -> List[int]:
         """Abort every request whose deadline passed (per-request SLO
@@ -1451,9 +1507,9 @@ class DynamicInferenceEngine:
                 "(ROADMAP M4)")
         if self.has_state:
             raise ValueError(
-                f"{what}: this model's state-space layers keep a recurrent "
-                "state a slot, and moving a request needs state snapshots, "
-                "which do not exist yet (ROADMAP M4)")
+                f"{what}: this model's {self._state_words()} keep a "
+                "recurrent state a slot, and moving a request needs state "
+                "snapshots, which do not exist yet (ROADMAP M4)")
 
     def _free_slot(self, slot: int):
         """Clear every per-slot engine resource (request ref, length,
@@ -2031,7 +2087,8 @@ class DynamicInferenceEngine:
                     self._lora_args(rows=self.row_adapter[slot:slot + 1]),
                     rows, jnp.asarray([count - 1], jnp.int32))
                 self._commit_pools(new)
-                self.state_stats["prefill_scans"] += self.cfg.num_ssm_layers
+                self.state_stats["prefill_scans"] += (
+                    self.cfg.num_recurrent_layers)
             self.prefill_stats["calls"] += 1
             self.prefill_stats["tokens"] += count
             pos += count
@@ -2533,12 +2590,13 @@ class DynamicInferenceEngine:
         On a paged engine "paged" holds the walk's counters over plain
         decode rounds: blocks_live of blocks_table is the share of the
         page table's width that held rows (False on a dense-cache one).
-        "state" is a dict on a model with state-space layers (False
-        otherwise): `layers` and `slots` of recurrent state at
-        `bytes_per_slot`, `resets` (sequences started from zeros at
-        admission), `dropped` (states thrown away by preemption),
-        `prefill_scans` (chunk scans run: prefill calls x state-space
-        layers). "sampler" counts what the sampler was asked for, by
+        "state" is a dict on a model with state-space layers or gated
+        short convolutions (False otherwise): `kind` ("ssm" or "conv"),
+        `layers` and `slots` of recurrent state at `bytes_per_slot`,
+        `resets` (sequences started from zeros at admission), `dropped`
+        (states thrown away by preemption), `prefill_scans` (chunk scans
+        run: prefill calls x such layers).
+        "sampler" counts what the sampler was asked for, by
         plain decode rounds and by prefills' first samples: `*_greedy`
         (argmax alone), `*_sampled` (a categorical, the vocabulary not
         ordered), `*_ordered` (a sort ran for some row's top-k or top-p).
@@ -2558,8 +2616,9 @@ class DynamicInferenceEngine:
         `decode_rounds`, `tokens` (their running requests), `assignments`
         (tokens x top-k x MoE layers), `expert_pairs_touched` of
         `expert_pairs_possible` (MoE layers x `experts_here` x rounds); on
-        a model that holds a share of its experts or routes to
-        zero-compute ones the assignments split into `assignments_zero`,
+        a model that counts its held experts' load (cfg.moe_counts_load: a
+        share of the experts, zero-compute ones, or a router's selection
+        bias) the assignments split into `assignments_zero`,
         `assignments_here` and `assignments_absent`, and `here_max_rows`
         sums the most rows one held expert got a layer and round
         (elsewhere 0, all, 0, 0).
@@ -2589,8 +2648,8 @@ class DynamicInferenceEngine:
                 chunk=self.cfg.eva_chunk_size)
         if self.has_state:
             out["state"] = dict(
-                self.state_stats, layers=self.cfg.num_ssm_layers,
-                slots=self.max_batch,
+                self.state_stats, kind=self.state_kind,
+                layers=self.cfg.num_recurrent_layers, slots=self.max_batch,
                 bytes_per_slot=self.pool.state_bytes_per_slot)
         if self.cfg.is_moe:
             here = self.cfg.moe_experts_here[1]

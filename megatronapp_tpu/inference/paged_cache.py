@@ -304,20 +304,30 @@ class PagedKVCache:
         # release and preemption have nothing to do. They cannot be
         # shared, exported or rewound (yet): the engine refuses what would
         # need a snapshot of them.
+        # A hybrid stack of gated short convolutions
+        # (transformer/shortconv.py) has no h: its tenant is the one tail
+        # pool [L_conv, slots, (k-1) * H], held and carried the same way.
         self.state = None
-        if cfg.num_ssm_layers:
+        if cfg.num_recurrent_layers:
             if extra_slots:
                 raise ValueError(
                     "staging slots (disaggregated prefill) hand a sequence "
                     "over by its page table; a state-space layer's state "
-                    "has no snapshot to hand over yet")
-            e = cfg.ssm_expand * cfg.hidden_size
-            self.state = (
-                _new_pool((cfg.num_ssm_layers, max_batch, cfg.ssm_state_dim,
-                           e), jnp.float32, 0),
-                _new_pool((cfg.num_ssm_layers, max_batch,
-                           (cfg.ssm_conv_kernel - 1) * e), cfg.compute_dtype,
-                          0))
+                    "or a convolution's tail has no snapshot to hand over "
+                    "yet")
+            if cfg.num_conv_layers:
+                self.state = (_new_pool(
+                    (cfg.num_conv_layers, max_batch,
+                     (cfg.shortconv_kernel - 1) * cfg.hidden_size),
+                    cfg.compute_dtype, 0),)
+            else:
+                e = cfg.ssm_expand * cfg.hidden_size
+                self.state = (
+                    _new_pool((cfg.num_ssm_layers, max_batch,
+                               cfg.ssm_state_dim, e), jnp.float32, 0),
+                    _new_pool((cfg.num_ssm_layers, max_batch,
+                               (cfg.ssm_conv_kernel - 1) * e),
+                              cfg.compute_dtype, 0))
 
         self.page_table = np.zeros((self.num_slots, self.max_blocks_per_seq),
                                    np.int32)
@@ -362,8 +372,9 @@ class PagedKVCache:
 
     @property
     def state(self) -> Optional[Tuple[jnp.ndarray, ...]]:
-        """The recurrent-state pools (ssm, conv) of a model with
-        state-space layers (else None), held like `pages`."""
+        """The second tenant's pools, held like `pages`: (ssm, conv) of a
+        model with state-space layers, (conv,) of one with gated short
+        convolutions, else None."""
         return self._state
 
     @state.setter

@@ -54,7 +54,15 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
     if cfg.normalization == NormKind.layernorm:
         p["final_ln_bias"] = jnp.zeros((cfg.hidden_size,), cfg.params_dtype)
         ax["final_ln_bias"] = ("embed",)
-    lead = cfg.moe_first_k_dense
+    # A hybrid stack keeps its leading dense layers' halves inside its own
+    # block ("ffn_lead", transformer/block.py): no stack beside it.
+    hybrid = cfg.attn_layer_period is not None
+    lead = 0 if hybrid else cfg.moe_first_k_dense
+    if hybrid and cfg.is_moe and pp > 1:
+        raise ValueError(
+            "a hybrid stack with MoE feed-forwards is not pipelined yet "
+            "(its leading dense layers and two kinds of layer a stage "
+            "unit) — run with pp == 1")
     if cfg.moe_shortcut_double_layer and pp > 1:
         raise ValueError(
             "the shortcut-connected double layer is not pipelined yet (two "
@@ -244,7 +252,8 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
                              attention_mask, ctx=ctx, zigzag=zz,
                              segment_ids=segment_ids)
     h, aux = block_forward(p["block"], h, cfg, cos, sin, attention_mask,
-                           layer_offset=cfg.moe_first_k_dense,
+                           layer_offset=(cfg.moe_first_k_dense
+                                         if "lead_block" in p else 0),
                            ctx=ctx, zigzag=zz, segment_ids=segment_ids,
                            fp8=None if fp8 is None else fp8["block"])
     logits = gpt_head(p, h, cfg)

@@ -173,6 +173,34 @@ def jamba2_3b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def lfm2_24b_a2b(**kw) -> TransformerConfig:
+    """LiquidAI/LFM2-24B-A2B (`lfm2_moe`; 24B, 2B active a token) as its
+    config.json publishes it: 40 layers of H 2048 of which 2, 6, ..., 38
+    attend (32 query and 8 key/value heads of 64, RMS norms on each head's
+    query and key, RoPE theta 1e6) and 30 are gated short convolutions of 3
+    taps; layers 0 and 1 end in a dense SwiGLU of 11776, the other 38 in 64
+    experts of width 1536, top-4 on sigmoid scores + a selection bias,
+    weights unbiased and renormalised; RMSNorm 1e-5, a tied head. Whole it
+    is 47 GB of bf16 weights: a deployment passes its stages' num_layers,
+    attn_layer_offset and moe_first_k_dense, as the benchmark's
+    configuration does (perfbench/configs/lfm2-24b-a2b.json). Serves
+    through --engine dynamic --paged-kv-cache."""
+    d = dict(num_layers=40, hidden_size=2048, num_attention_heads=32,
+             num_query_groups=8, ffn_hidden_size=11776, vocab_size=65536,
+             max_position_embeddings=128000,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             position_embedding=PositionEmbeddingKind.rope,
+             rotary_base=1000000.0, qk_layernorm=True,
+             attn_layer_period=4, attn_layer_offset=2, shortconv_kernel=3,
+             num_moe_experts=64, moe_router_topk=4, moe_ffn_hidden_size=1536,
+             moe_first_k_dense=2, moe_router_score="sigmoid",
+             moe_router_selection_bias=True, moe_router_norm_topk_prob=True,
+             moe_routed_scaling_factor=1.0)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -197,6 +225,7 @@ def evabyte_6p5b(**kw) -> TransformerConfig:
 PRESETS = {
     "evabyte-6.5b": evabyte_6p5b,
     "jamba2-3b": jamba2_3b,
+    "lfm2-24b-a2b": lfm2_24b_a2b,
     "gpt2-125m": gpt2_125m,
     "gpt3-2.7b": gpt3_2p7b,
     "mamba-130m": mamba_130m,

@@ -53,7 +53,7 @@ logger = logging.getLogger(__name__)
 # written: a layer's halves (transformer/block.py), the embedders and heads
 # (models/), the train step's accumulation and update (training/
 # train_step.py). perfbench/scope_time.py and its metrics read them.
-PARTS = ("attention", "ssm", "mlp", "moe", "embedding", "head",
+PARTS = ("attention", "ssm", "conv", "mlp", "moe", "embedding", "head",
          "grad_accum", "optimizer")
 OTHER = "other"
 MAX_STEPS = 16

@@ -42,8 +42,15 @@ def _norm_scale(cfg: TransformerConfig):
 
 def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
     """A layer's first half: its norm and its mixer (attention, MLA, or with
-    `ssm` a selective-state-space mixer)."""
-    if ssm:
+    `ssm` a hybrid stack's other kind: a selective-state-space mixer, or
+    with cfg.shortconv_kernel a gated short convolution)."""
+    if ssm and cfg.shortconv_kernel:
+        from megatronapp_tpu.transformer.shortconv import (
+            init_shortconv_params,
+        )
+        name = "conv"
+        mix_p, mix_ax = init_shortconv_params(rng, cfg, out_std)
+    elif ssm:
         from megatronapp_tpu.transformer.ssm import init_ssm_params, ssm_dims
         name = "ssm"
         mix_p, mix_ax = init_ssm_params(rng, cfg, ssm_dims(cfg), out_std)
@@ -123,7 +130,10 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     selective-state-space mixer (transformer/ssm.py) as its first half.
     In a paged serving step such a layer has no kv_cache: ssm_state =
     (ssm pool, conv pool, this layer's plane of them), state_rows maps x's
-    rows to slots, and new_cache is the two state pools. kv_plane: the
+    rows to slots, and new_cache is the two state pools. A layer that
+    holds "conv" runs the gated short convolution (transformer/
+    shortconv.py) the same way; its ssm_state is (tail pool, plane) and
+    new_cache the one pool. kv_plane: the
     plane of the paged KV pools an attention layer owns where that is not
     its layer id (a hybrid stack's pools hold its attention layers only).
 
@@ -261,6 +271,24 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             else:
                 attn_out, _ = ssm_forward(p["ssm"], h, cfg, ssm_dims(cfg))
                 new_cache = None
+    elif "conv" in p:
+        if tp_sharded or lora is not None:
+            raise NotImplementedError(
+                "a gated short convolution runs on one tp shard, without "
+                "lora: a tap reads the positions before its own")
+        from megatronapp_tpu.transformer.shortconv import (
+            shortconv_forward, shortconv_paged_forward,
+        )
+        with jax.named_scope("conv"):
+            if ssm_state is not None:
+                attn_out, new_cache = shortconv_paged_forward(
+                    p["conv"], h, cfg, ssm_state, rows=state_rows,
+                    starts=cache_positions, counts=chunk_counts,
+                    active=active)
+            else:
+                attn_out, _ = shortconv_forward(p["conv"], h, cfg,
+                                                segment_ids=segment_ids)
+                new_cache = None
     else:
         attn_out, new_cache = attend()
     # Tag for the 'selective_attn' remat policy (a no-op otherwise).
@@ -361,23 +389,31 @@ def _vmapped_layers(keys, init):
 
 def init_hybrid_block_params(rng, cfg: TransformerConfig):
     """A hybrid stack (cfg.attn_layer_period): the state-space layers'
-    first halves stacked [num_ssm_layers, ...] under "mixers_ssm", the
+    first halves stacked [num_ssm_layers, ...] under "mixers_ssm" (gated
+    short convolutions: [num_conv_layers, ...] under "mixers_conv"), the
     attention layers' [num_attention_layers, ...] under "mixers_attn", and
-    every layer's feed-forward half [num_layers, ...] under "ffn", each in
-    layer order (hybrid_layer_loop walks them)."""
+    the layers' feed-forward halves under "ffn", each in layer order
+    (hybrid_layer_loop walks them): every layer's [num_layers, ...], or in
+    an MoE model with cfg.moe_first_k_dense the MoE layers'
+    [num_layers - k, ...], the k leading layers' dense halves being
+    "ffn_lead" [k, ...]."""
     out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
     keys = jax.vmap(jax.random.split)(
         jax.random.split(rng, cfg.num_layers))        # [L, (mixer, ffn)]
     attends = np.asarray([cfg.layer_is_attention(i)
                           for i in range(cfg.num_layers)])
+    lead = cfg.moe_first_k_dense
 
     kinds = {
-        "mixers_ssm": (keys[~attends, 0], functools.partial(
-            _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
+        "mixers_conv" if cfg.shortconv_kernel else "mixers_ssm": (
+            keys[~attends, 0], functools.partial(
+                _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
         "mixers_attn": (keys[attends, 0], functools.partial(
             _init_mixer_half, cfg=cfg, out_std=out_std, ssm=False)),
-        "ffn": (keys[:, 1], functools.partial(
+        "ffn": (keys[lead:, 1], functools.partial(
             _init_ffn_half, cfg=cfg, out_std=out_std)),
+        "ffn_lead": (keys[:lead, 1], functools.partial(
+            _init_ffn_half, cfg=cfg, out_std=out_std, force_dense=True)),
     }
     done = {k: _vmapped_layers(*v) for k, v in kinds.items() if len(v[0])}
     return ({k: v[0] for k, v in done.items()},
@@ -393,10 +429,26 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
 
     run(carry, attends, k, layer_id) → carry runs one layer: `attends`
     (static) says which kind, k is its index among the layers of its kind
-    (its row of "mixers_attn"/"mixers_ssm", and for an attention layer its
-    plane of the KV pools), layer_id its index in the model (its row of
-    "ffn"); both are int32 scalars, traced inside the scans."""
-    period, offset = cfg.attn_layer_period, cfg.attn_layer_offset
+    (its row of "mixers_attn"/"mixers_ssm"/"mixers_conv", and for an
+    attention layer its plane of the KV pools), layer_id its index in the
+    model (its row of "ffn"); both are int32 scalars, traced inside the
+    scans.
+
+    An MoE model's cfg.moe_first_k_dense leading layers (dense
+    feed-forwards: another body) run first, one after the other, with k
+    and layer_id Python ints and `lead=True`; the periods are then counted
+    from the first layer behind them."""
+    period = cfg.attn_layer_period
+    lead = cfg.moe_first_k_dense
+    # the kinds' layers among the leading ones, and where the attention
+    # layer lies in a period counted from the first layer behind them
+    lead_attn = sum(cfg.layer_is_attention(i) for i in range(lead))
+    lead_rec = lead - lead_attn
+    offset = (cfg.attn_layer_offset - lead) % period
+    for i in range(lead):
+        attends = cfg.layer_is_attention(i)
+        k = sum(cfg.layer_is_attention(j) == attends for j in range(i))
+        carry = run(carry, attends, k, i, lead=True)
 
     def ssm_run(carry, k0, lid0, count):
         if count <= 0:
@@ -409,15 +461,17 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
 
     def part_period(carry, p, count):
         """The first `count` (static) layers of period p."""
-        lid0, k0 = p * period, p * (period - 1)
+        lid0, k0, ka = p * period, p * (period - 1), p
+        if lead:
+            lid0, k0, ka = lid0 + lead, k0 + lead_rec, ka + lead_attn
         carry = ssm_run(carry, k0, lid0, min(count, offset))
         if count > offset:
-            carry = run(carry, True, p, lid0 + offset)
+            carry = run(carry, True, ka, lid0 + offset)
             carry = ssm_run(carry, k0 + offset, lid0 + offset + 1,
                             count - offset - 1)
         return carry
 
-    whole, rest = divmod(cfg.num_layers, period)
+    whole, rest = divmod(cfg.num_layers - lead, period)
     if whole == 1:
         carry = part_period(carry, jnp.int32(0), period)
     elif whole:
@@ -429,15 +483,26 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
     return carry
 
 
-def hybrid_layer_params(stacked_p, attends: bool, k, layer_id):
+def hybrid_layer_params(stacked_p, attends: bool, k, layer_id,
+                        lead: bool = False):
     """One layer's params out of a hybrid stack: row k of its kind's first
-    halves and row layer_id of the feed-forward halves."""
+    halves and its feed-forward half: row layer_id of "ffn_lead" for a
+    leading dense layer (`lead`), else of "ffn", which in a stack with
+    leading dense layers starts behind them."""
     def row(stack, i):
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             stack)
-    mixers = stacked_p["mixers_attn" if attends else "mixers_ssm"]
-    return {**row(mixers, k), **row(stacked_p["ffn"], layer_id)}
+    kind = "mixers_attn" if attends else (
+        "mixers_ssm" if "mixers_ssm" in stacked_p else "mixers_conv")
+    if lead:
+        ffn = row(stacked_p["ffn_lead"], layer_id)
+    elif "ffn_lead" in stacked_p:
+        ffn = row(stacked_p["ffn"], layer_id - jax.tree.leaves(
+            stacked_p["ffn_lead"])[0].shape[0])
+    else:
+        ffn = row(stacked_p["ffn"], layer_id)
+    return {**row(stacked_p[kind], k), **ffn}
 
 
 def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
@@ -526,6 +591,13 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             raise NotImplementedError(
                 "a hybrid state-space stack trains on the plain path: no "
                 "fp8, tp-sharded stage body or zigzag cp")
+        if cfg.is_moe and ctx is not None and max(
+                ctx.tp, ctx.ep, ctx.cp, ctx.pp) > 1:
+            raise NotImplementedError(
+                "a hybrid stack with MoE feed-forwards runs whole "
+                "sequences on one device or data-parallel: its experts' "
+                "ep all-to-all, and tp, cp and pp layouts of its layer "
+                "loop, are not written yet (ROADMAP M4)")
 
         def one_layer(layer_p, h, lid):
             (h2, _), _ = layer_forward(
@@ -535,8 +607,8 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
 
         one_layer = _remat_wrap(one_layer, cfg.remat_policy)
         x = hybrid_layer_loop(
-            cfg, x, lambda h, attends, k, lid: one_layer(
-                hybrid_layer_params(stacked_p, attends, k, lid), h,
+            cfg, x, lambda h, attends, k, lid, lead=False: one_layer(
+                hybrid_layer_params(stacked_p, attends, k, lid, lead), h,
                 lid + layer_offset))
         return x, jnp.zeros((), jnp.float32)
     hetero = isinstance(stacked_p, dict) and "dense" in stacked_p

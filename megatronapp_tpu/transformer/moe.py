@@ -82,6 +82,9 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     router.py:102). The k probabilities are divided by their sum only when
     ``cfg.moe_router_norm_topk_prob`` says so (HF ``norm_topk_prob``), and
     carry ``cfg.moe_routed_scaling_factor`` (HF ``routed_scaling_factor``).
+    With ``cfg.moe_router_score`` "sigmoid" the scores are the logits'
+    elementwise sigmoid, and their renormalisation divides by the sum
+    + 1e-6; the config refuses the aux losses with them.
     The softmax runs over the router's whole width (cfg.moe_router_width:
     zero-compute experts have ids past the computing ones). A router that
     holds a selection bias ("router_bias") takes its top-k on p + b and
@@ -97,7 +100,9 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     """
     e = cfg.moe_router_width
     logits = x_flat.astype(jnp.float32) @ p["router_kernel"]
-    probs = jax.nn.softmax(logits, axis=-1)
+    sigmoid = cfg.moe_router_score == "sigmoid"
+    probs = (jax.nn.sigmoid(logits) if sigmoid
+             else jax.nn.softmax(logits, axis=-1))
     if "router_bias" in p:
         _, topk_idx = jax.lax.top_k(probs + p["router_bias"],
                                     cfg.moe_router_topk)
@@ -105,8 +110,10 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
     else:
         topk_probs, topk_idx = jax.lax.top_k(probs, cfg.moe_router_topk)
     if cfg.moe_router_norm_topk_prob:
-        topk_probs = topk_probs / jnp.maximum(
-            jnp.sum(topk_probs, -1, keepdims=True), 1e-9)
+        total = jnp.sum(topk_probs, -1, keepdims=True)
+        # + 1e-6: the published constant of the sigmoid routers (lfm2_moe)
+        topk_probs = topk_probs / (total + 1e-6 if sigmoid
+                                   else jnp.maximum(total, 1e-9))
     if cfg.moe_routed_scaling_factor != 1.0:
         topk_probs = topk_probs * cfg.moe_routed_scaling_factor
 
@@ -300,8 +307,11 @@ HELD_COUNTS = ("assignments", "expert_pairs_touched", "assignments_zero",
 
 def routing_counts_held(topk_idx, count_rows,
                         cfg: TransformerConfig) -> jnp.ndarray:
-    """routing_counts for a layer that holds a share of the experts or
-    routes to zero-compute ones, int32 [6] in HELD_COUNTS' order: the real
+    """routing_counts for a layer that counts its held experts' load
+    (cfg.moe_counts_load: it holds a share of the experts, routes to
+    zero-compute ones, or balances by a selection bias; on a layer that
+    holds every expert the zero and absent picks read 0),
+    int32 [6] in HELD_COUNTS' order: the real
     tokens' assignments (tokens x top-k); how many of the experts HELD HERE
     they touched; their picks of zero-compute experts, of held experts and
     of experts held elsewhere, each counted from the indices (the three add
@@ -325,9 +335,8 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
 
     count_rows: [B,S] bool, given by the serving steps (which have no use
     for the aux loss): the second result is then ``routing_counts`` of
-    those rows (``routing_counts_held`` on a model with a share of the
-    experts or zero-compute ones), for the engine's always-on `moe`
-    counters.
+    those rows (``routing_counts_held`` where cfg.moe_counts_load), for
+    the engine's always-on `moe` counters.
 
     ctx with ep > 1 selects the explicit all-to-all dispatch
     (_a2a_expert_forward): expert weights stay home on their ep shard and
@@ -356,6 +365,11 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
             "or routes to zero-compute ones runs without an exchange on "
             "one device: no ep all-to-all between shares and no tp-sharded "
             "stage body yet (ROADMAP M3)")
+    if "router_bias" in p and ctx is not None and getattr(ctx, "ep", 1) > 1:
+        raise NotImplementedError(
+            "the ep all-to-all dispatch routes by the router's kernel "
+            "alone: a router with a selection bias runs without ep "
+            "(ROADMAP M2)")
     if (ctx is not None and getattr(ctx, "ep", 1) > 1
             and not current_manual_axes()
             and e % ctx.ep == 0
@@ -401,7 +415,7 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
                                         stats_mean=stats_mean)
 
     if count_rows is not None:
-        if cfg.moe_picks_unheld:
+        if cfg.moe_counts_load:
             aux = routing_counts_held(topk_idx, count_rows.reshape(t), cfg)
         else:
             aux = routing_counts(topk_idx, count_rows.reshape(t), e)

@@ -426,10 +426,16 @@ class TestChipRTTProbe:
         from megatronapp_tpu.utils.straggler import (
             detect_slow_chips, probe_chip_rtts,
         )
-        rtts = probe_chip_rtts(devices8[:4], size=64, repeats=20)
-        assert len(rtts) == 4
-        assert all(r["rtt_ms"] > 0 for r in rtts)
-        # Homogeneous virtual devices: nothing should be flagged at 5x.
+        # Homogeneous virtual devices: nothing should be flagged at 5x. A
+        # loaded host can stall one device's 20 round trips (seen once under
+        # six test workers: 0.90 ms against 0.15), so ask again before
+        # believing it.
+        for _ in range(3):
+            rtts = probe_chip_rtts(devices8[:4], size=64, repeats=20)
+            assert len(rtts) == 4
+            assert all(r["rtt_ms"] > 0 for r in rtts)
+            if not detect_slow_chips(rtts, ratio_threshold=5.0):
+                break
         assert detect_slow_chips(rtts, ratio_threshold=5.0) == []
         # Synthetic slow chip is flagged.
         rigged = rtts[:3] + [{"device": "slow", "rtt_ms":

@@ -673,6 +673,71 @@ def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
     assert mem.temp_size_in_bytes < ssm.size // ssm.shape[0] * 4
 
 
+# The assist cell's cut of LFM2-24B-A2B: published layers 1..9
+# (perfbench/configs/lfm2-24b-a2b.json).
+LFM2_CUT = {"num_layers": 9, "attn_layer_offset": 1, "moe_first_k_dense": 1}
+
+
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_conv_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
+    """The two jits for a hybrid stack of gated short convolutions with MoE
+    feed-forwards at LFM2-24B-A2B's published widths and the assist cell's
+    sizes (a small vocabulary; the cell's own 9 layers: a leading dense
+    convolution layer, then two periods of attention + 3 convolutions, each
+    with all 64 experts): 192 slots, 8 key/value heads of 64 under 4 query
+    heads each, a tail pool [7, 192, 2 x 2048] bf16, a prefill call of the
+    2048 positions the engine chooses for the cell on this chip. Mosaic takes
+    the D 64 group-4 pools in both kernels; the step aliases the page pools
+    and the tail pool alike and cuts no layer's experts out of their stacks
+    (a scanned slice of [8, 64, 2048, 3072] is a copy of 0.8 GB a layer)."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    batch, blocks, seq = 192, 20480, 3072
+    width = _cell_prefill_width(one_chip, "lfm2-24b-a2b", seq, **LFM2_CUT)
+    assert width == 2048
+    cfg = PRESETS["lfm2-24b-a2b"](vocab_size=1024, params_dtype=jnp.bfloat16,
+                                  **LFM2_CUT)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
+                                 max_seq_len=seq, paged=True, num_blocks=8,
+                                 prefill_chunk=width)
+    tails, = eng.pool.state
+    assert tails.shape == (7, 192, 2 * 2048) and tails.dtype == jnp.bfloat16
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + (_sds(tails.shape, tails.dtype, one_chip),)
+    assert pools[0].shape == (2, blocks, 16, 8, 64)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None).compile()
+        _assert_kernels_named(compiled, "paged_decode")
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1),
+            i32(1)).compile()
+        _assert_kernels_named(compiled, "paged_mq")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    fc1 = abstract["block"]["ffn"]["moe"]["fc1_kernel"]
+    assert fc1.shape == (8, 64, 2048, 3072)
+    assert not _pool_shaped(compiled, r"copy|(?<!update[_-])slice",
+                            [fc1.shape[1:], (1,) + fc1.shape[1:]])
+    assert mem.temp_size_in_bytes < (fc1.size // fc1.shape[0]
+                                     * fc1.dtype.itemsize)
+
+
 # ---------------------------------------------------------------------------
 # Kernel names (ISSUE 26): a device trace names an event by its HLO
 # instruction, and perfbench's readers tell kernels apart by family prefix

@@ -188,6 +188,8 @@ def _run_with(monkeypatch, capsys, server_ok):
     monkeypatch.setattr(chip_smoke, "phase_eva", lambda tiny: _phase("eva"))
     monkeypatch.setattr(chip_smoke, "phase_share",
                         lambda tiny: _phase("share"))
+    monkeypatch.setattr(chip_smoke, "phase_conv",
+                        lambda tiny: _phase("conv"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -200,7 +202,7 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-reference",
                                             "server", "hybrid", "eva",
-                                            "share"]
+                                            "share", "conv"]
 
 
 def _hybrid_lines(device=TPU, **kw):
@@ -297,6 +299,40 @@ def test_check_share(kw, needle):
     assert not chip_smoke.check_share(1, _share_lines())["ok"]
 
 
+def _conv_lines(device=TPU, **kw):
+    res = {"tokens": 155, "in_vocab": True, "expert_stack_slices": 0,
+           "moe": {"tokens": 33, "assignments": 264, "experts_here": 8},
+           "state": {"kind": "conv", "layers": 4, "resets": 3},
+           "pool_shapes": [[1, 64, 16, 2, 64], [1, 64, 16, 2, 64],
+                           [4, 8, 512]],
+           "pool_bytes": 100, "alias_bytes": 128, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            "attention: paged decode -> pallas paged kernel (compiled)",
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"pool_shapes": [[1, 64, 16, 2, 64], [1, 64, 16, 2, 64],
+                      [4, 8, 8, 256], [4, 8, 512]]}, "not one attention"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"expert_stack_slices": 2}, "out of their stacks"),
+    ({"tokens": 150}, "tokens came back"),
+    ({"state": {"kind": "ssm", "layers": 4, "resets": 3}},
+     "not 4 convolution"),
+    ({"moe": {"tokens": 33, "assignments": 260, "experts_here": 8}},
+     "the picks are not tokens"),
+])
+def test_check_conv(kw, needle):
+    """The conv phase's facts: one attention plane and one pool of tails,
+    aliased in and out; the experts read in their stacks; every token back;
+    the tails reset once a request; every pick counted."""
+    out = chip_smoke.check_conv(0, _conv_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_conv(1, _conv_lines())["ok"]
+
+
 def test_a_failing_phase_fails_the_run(monkeypatch, capsys):
     rc, lines = _run_with(monkeypatch, capsys, server_ok=False)
     assert rc != 0
@@ -366,7 +402,7 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
                                             "server", "hybrid", "eva",
-                                            "share"]
+                                            "share", "conv"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -394,7 +430,12 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert share["moe"]["experts_here"] == 4
     assert share["pool_shapes"][0][0] == 4      # two planes a layer
     assert share["alias_bytes"] >= share["pool_bytes"] > 0
-    assert sum("not a TPU" in ln for ln in lines) == 6
+    conv = phases[6]
+    assert conv["state"]["kind"] == "conv" and conv["state"]["resets"] == 3
+    assert conv["moe"]["assignments"] == conv["moe"]["tokens"] * 2 * 4
+    assert conv["expert_stack_slices"] == 0
+    assert conv["alias_bytes"] >= conv["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 7
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):

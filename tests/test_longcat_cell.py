@@ -80,14 +80,19 @@ def test_benchmark_lists_the_cell_and_only_appends():
     if parent.returncode:
         return      # a checkout without history: nothing to compare with
     was = json.loads(parent.stdout)
+    had = {c["name"] for c in was["workloads"]} | {CELL}
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         for old, new in zip(was[group], manifest[group]):
             new = dict(new)
             if CELL in new.get("workloads", []):
+                # (cells that later PRs appended behind it are theirs to
+                # hold: tests/test_lfm2_cell.py)
+                new["workloads"] = [w for w in new["workloads"] if w in had]
                 assert new["workloads"][-1] == CELL
                 new["workloads"] = new["workloads"][:-1]
             assert old == new, old["name"]
     assert was["command"] == manifest["command"]
     assert was["run_seconds"] == manifest["run_seconds"]
-    assert len(manifest["workloads"]) == len(was["workloads"]) + 1
-    assert len(manifest["configs"]) == len(was["configs"]) + 1
+    assert manifest["workloads"][len(was["workloads"])]["name"] == CELL
+    assert manifest["configs"][len(was["configs"])]["name"] == \
+        "longcat-flash-chat"
