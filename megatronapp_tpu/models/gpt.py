@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from megatronapp_tpu.config.transformer_config import (
     NormKind, PositionEmbeddingKind, TransformerConfig,
 )
-from megatronapp_tpu.ops import rotary
+from megatronapp_tpu.ops import per_rank, rotary
 from megatronapp_tpu.ops.cross_entropy import cross_entropy_loss
 from megatronapp_tpu.ops.normalization import apply_norm
 from megatronapp_tpu.transformer.block import block_forward, init_block_params
@@ -130,7 +130,7 @@ def gpt_embed(p, tokens: jnp.ndarray, cfg: TransformerConfig,
     # The scope names this part in the compiled program's op_names
     # (trace/scope_map.py joins them to a device trace).
     with jax.named_scope("embedding"):
-        h = jnp.take(p["embedding"]["word"], tokens, axis=0)
+        h = per_rank.take(p["embedding"]["word"], tokens)
         if "pos" in p["embedding"]:
             if position_ids is None:
                 position_ids = jnp.arange(tokens.shape[1])[None, :]
@@ -336,9 +336,9 @@ def gpt_head(p, h: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
                        p.get("final_ln_bias"), cfg.layernorm_epsilon,
                        cfg.norm_unit_offset)
         out_kernel = (p["output"] if "output" in p
-                      else p["embedding"]["word"].T)
-        logits = (h.astype(cfg.compute_dtype)
-                  @ out_kernel.astype(cfg.compute_dtype))
+                      else jnp.swapaxes(p["embedding"]["word"], -1, -2))
+        logits = per_rank.dense(h.astype(cfg.compute_dtype),
+                                out_kernel.astype(cfg.compute_dtype))
         logits = scope_capture("result", logits)
         return logits.astype(jnp.float32)
 

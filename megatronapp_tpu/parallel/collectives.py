@@ -61,6 +61,15 @@ def psum(x, axis_name):
     return lax.psum(x, axis_name)
 
 
+def psum_scatter(x, axis_name, scatter_dimension: int):
+    """Reduce-scatter over a bound manual mesh axis: the sum's
+    ``scatter_dimension`` comes back split over ``axis_name``, a rank its
+    own block (tiled). The train step's one sum of the weight gradients
+    over dp lands in ZeRO-1's layout through here (fp32)."""
+    return lax.psum_scatter(x, axis_name,
+                            scatter_dimension=scatter_dimension, tiled=True)
+
+
 def pvary(x, axes: Tuple[str, ...]):
     """Mark a replicated-over-``axes`` input of a full-manual shard_map
     body as contributing a partial cotangent per shard (pipeline stage

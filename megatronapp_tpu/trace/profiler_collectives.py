@@ -95,12 +95,13 @@ def _axes_of_groups(groups: List[List[int]], mesh) -> str:
     a participant group (e.g. tp for the TP all-reduce)."""
     if mesh is None or not groups or len(groups[0]) < 2:
         return ""
-    coord_of = {}
-    it = np.nditer(np.asarray(mesh.devices, dtype=object),
-                   flags=["multi_index", "refs_ok"])
-    for dev in it:
-        coord_of[dev.item().id] = it.multi_index
-    g = [coord_of.get(d) for d in groups[0]]
+    # A partitioned module's groups name PARTITIONS: positions in the jit's
+    # device assignment, which is the mesh's devices in row-major order,
+    # not device ids (a v5e 2x2's mesh holds ids 0, 1, 3, 2).
+    shape = np.shape(mesh.devices)
+    n = int(np.prod(shape))
+    g = [np.unravel_index(d, shape) if 0 <= d < n else None
+         for d in groups[0]]
     if any(c is None for c in g):
         return ""
     varying = [mesh.axis_names[i] for i in range(len(mesh.axis_names))
@@ -109,10 +110,13 @@ def _axes_of_groups(groups: List[List[int]], mesh) -> str:
 
 
 def collectives_of(parsed, mesh=None) -> Dict[str, dict]:
-    """HLO op name → {kind, bytes, groups, axes} for every collective of a
-    parsed module (``scope_map.parse_hlo_text``, the one reader of HLO
-    text): the static half of the join."""
+    """HLO op name → {kind, bytes, groups, axes, in_loop} for every
+    collective of a parsed module (``scope_map.parse_hlo_text``, the one
+    reader of HLO text): the static half of the join. ``in_loop``: the
+    instruction lives in a ``while`` body (or in what one calls), so it runs
+    once an iteration and not once a step."""
     out: Dict[str, dict] = {}
+    loops = parsed.loop_computations()
     for ins in parsed.instructions.values():
         if ins.opcode not in _COLLECTIVE_OPCODES:
             continue
@@ -130,6 +134,7 @@ def collectives_of(parsed, mesh=None) -> Dict[str, dict]:
                 groups = [members]
         info["groups"] = groups
         info["axes"] = _axes_of_groups(groups, mesh)
+        info["in_loop"] = ins.computation in loops
         out[ins.name] = info
     return out
 

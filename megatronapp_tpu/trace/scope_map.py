@@ -67,6 +67,13 @@ _NAME = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s+")
 _OPCODE = re.compile(r"\s*([a-z][\w\-]*)\(")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
+# Every computation an instruction runs: a fusion's or a call's `calls`, a
+# reduction's `to_apply`, a while's `condition` and `body`, a conditional's
+# branches.
+_CALLED = re.compile(r"(?:calls|to_apply|condition|body|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_WHILE_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _ARRAY = re.compile(r"\w+\[[\d,]*\]")
 _SEGMENT = re.compile(r"[/()]+")
@@ -81,6 +88,7 @@ class HloInstruction:
     calls: Optional[str]     # a fusion's computation
     operands: Tuple[str, ...]
     line: str
+    computation: str = ""    # the computation the instruction is written in
 
 
 @dataclasses.dataclass
@@ -88,6 +96,28 @@ class HloText:
     module: str
     instructions: Dict[str, HloInstruction]
     roots: Dict[str, str]    # computation -> its ROOT instruction's name
+
+    def loop_computations(self) -> frozenset:
+        """The computations that run inside a loop: every `while` body and
+        whatever one calls, however deep (a nested loop's body and its
+        condition, a fusion, a conditional's branch). An outermost loop's
+        condition is not among them."""
+        called: Dict[str, set] = {}
+        bodies = set()
+        for ins in self.instructions.values():
+            names = _CALLED.findall(ins.line)
+            for group in _BRANCHES.findall(ins.line):
+                names += _OPERAND.findall(group)
+            called.setdefault(ins.computation, set()).update(names)
+            if ins.opcode == "while":
+                bodies.update(_WHILE_BODY.findall(ins.line))
+        inside, frontier = set(), list(bodies)
+        while frontier:
+            c = frontier.pop()
+            if c not in inside:
+                inside.add(c)
+                frontier.extend(called.get(c, ()))
+        return frozenset(inside)
 
 
 def _split_shape(rest: str) -> Tuple[str, str]:
@@ -130,7 +160,8 @@ def parse_hlo_text(text: str) -> HloText:
             op_name=meta.group(1) if meta else "",
             calls=calls.group(1) if calls else None,
             operands=tuple(_OPERAND.findall(_split_shape(
-                tail[op.end() - 1:])[0])) if op else (), line=line)
+                tail[op.end() - 1:])[0])) if op else (), line=line,
+            computation=computation)
         instructions[ins.name] = ins
         if m.group(1):                  # ROOT
             roots[computation] = ins.name

@@ -203,6 +203,36 @@ class _RowBuffer:
         return out
 
 
+def gpt_rank_kernels(cfg: TransformerConfig):
+    """({path: (rank axis, dtype)}, None) of the kernels `gpt_loss`
+    multiplies (``ops/per_rank.dense``) or looks up (``per_rank.take``)
+    one copy a data-parallel rank of, or (None, why it has none): what
+    ``train_step.make_train_step`` may hand the loss such copies of. The
+    plain layer stack's five kernels, the rank axis behind the layers' and
+    the copy in the type the layer multiplies in; the word embedding (and
+    an untied head), the rank axis first, in the parameters' own type (its
+    rows are added to the positions' before anything is rounded)."""
+    if cfg.is_moe:
+        return None, "the experts' grouped products are not per-rank ones"
+    if getattr(cfg, "tp_comm_overlap", False):
+        return None, ("--tp-comm-overlap's rings multiply inside their own "
+                      "full-manual regions")
+    if cfg.multi_latent_attention or cfg.attn_layer_period is not None \
+            or getattr(cfg, "hetero_block_specs", None) \
+            or cfg.mtp_num_layers:
+        return None, ("latent attention, hybrid, heterogeneous and multi "
+                      "token prediction stacks: not tried")
+    kernels = {("block", part, name): (1, cfg.compute_dtype)
+               for part, name in (
+                   ("attention", "q_kernel"), ("attention", "kv_kernel"),
+                   ("attention", "out_kernel"), ("mlp", "fc1_kernel"),
+                   ("mlp", "fc2_kernel"))}
+    kernels["embedding", "word"] = (0, None)
+    if cfg.untie_embeddings_and_output_weights:
+        kernels["output",] = (0, cfg.compute_dtype)
+    return kernels, None
+
+
 def gpt_microbatch_loss(cfg: TransformerConfig, ctx=None):
     def loss_fn(params, micro, fp8=None):
         loss, metrics = gpt_loss(params, micro["tokens"], micro["labels"],
@@ -210,6 +240,7 @@ def gpt_microbatch_loss(cfg: TransformerConfig, ctx=None):
                                  segment_ids=micro.get("segment_ids"),
                                  fp8=fp8)
         return loss, metrics
+    loss_fn.rank_kernels, loss_fn.no_rank_kernels = gpt_rank_kernels(cfg)
     return loss_fn
 
 

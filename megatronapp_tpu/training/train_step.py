@@ -3,10 +3,25 @@
 Parity with /root/reference/megatron/training/training.py:1367 (train_step:
 forward_backward_func over microbatches → finalize grads → clip → optimizer
 step → skipped-iter bookkeeping). TPU-first: one jit containing a lax.scan
-over microbatches; XLA overlaps the dp grad all-reduce with backward compute
-(the hand-written bucketing of param_and_grad_buffer.py:93 is subsumed by the
-compiler), and the NaN-skip is a lax.cond instead of the fp16 scaler path
-(optimizer.py:322).
+over microbatches, and the NaN-skip is a lax.cond instead of the fp16 scaler
+path (optimizer.py:322).
+
+Weight gradients cross the data-parallel axis ONCE a step (the reference's
+``no_sync`` for all but the last micro-batch, param_and_grad_buffer.py:93).
+Left to GSPMD, an accumulator laid out like the parameters (replicated over
+dp) forces the sum over dp where each weight-gradient product is made: a
+synchronous bf16 all-reduce of a layer's whole gradient in every layer of
+every micro-batch, which nothing hides (PERF.md, PR 42: 296 ms of a 1,620 ms
+step on four chips). So where dp > 1 the step hands the loss ONE COPY A RANK
+of the kernels it multiplies through ``ops/per_rank.dense`` (``[L, dp, K,
+N]``, the new axis split over dp: each chip holds the copy it always held).
+Their gradients come back a rank's own, unreduced, accumulate in fp32 in that
+shape, and are summed over the rank axis once behind the scan; ``grads``,
+the norm, the clip, the NaN skip and the ZeRO-1 update see what they always
+saw. Every other leaf (biases, norms, embeddings) and every loss that names
+no such kernels (``loss_fn.rank_kernels``) keeps the per-micro-batch
+reduction; the step says which it took in one line at its first trace
+(``gradients: ...``).
 """
 
 from __future__ import annotations
@@ -19,7 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from megatronapp_tpu.config.parallel_config import DP_AXIS
 from megatronapp_tpu.config.training_config import OptimizerConfig
+from megatronapp_tpu.parallel.collectives import (
+    psum, psum_scatter, shard_map_compat,
+)
 from megatronapp_tpu.parallel.mesh import MeshContext
 from megatronapp_tpu.training.optimizer import global_grad_norm, lr_schedule
 
@@ -71,6 +90,128 @@ def globalize_batch(batch: Any, ctx: MeshContext, shardings=None) -> Any:
     return {k: conv(v, shardings[k]) for k, v in batch.items()}
 
 
+def per_micro_batch_reason(loss_fn, ctx: MeshContext, state_shardings,
+                           pipeline: bool = False,
+                           fp8: bool = False) -> Optional[str]:
+    """Why this step leaves every gradient's sum over dp to GSPMD, inside
+    every micro-batch, or None where the loss's per-rank kernels
+    (``loss_fn.rank_kernels``) are summed once behind the scan. Decided from
+    the mesh, the step's mode, the state's layout and what the loss says of
+    itself."""
+    if pipeline or ctx.pp > 1:
+        return ("the pipeline schedules its micro-batches inside one "
+                "full-manual region")
+    if getattr(ctx, "abstract_collectives", False):
+        return "an abstract mesh (forward/backward disaggregation)"
+    if ctx.ep > 1:
+        return "ep > 1: the batch is split over (dp, ep) jointly"
+    if ctx.cp > 1:
+        return "cp > 1: ring attention is a full-manual region of its own"
+    if fp8:
+        return "fp8 multiplies inside the tp rings' full-manual regions"
+    if not getattr(loss_fn, "rank_kernels", None):
+        return getattr(loss_fn, "no_rank_kernels", None) or (
+            "this loss names no per-rank kernels (loss_fn.rank_kernels)")
+    if any(DP_AXIS in str(sh.spec)
+           for sh in jax.tree.leaves(state_shardings["params"])):
+        return "the parameters are split over dp themselves (fsdp)"
+    return None
+
+
+def _spec(sharding, ndim: int) -> list:
+    """A sharding's spec, an entry a dim."""
+    return list(sharding.spec) + [None] * (ndim - len(sharding.spec))
+
+
+class _PerRankKernels:
+    """The kernels a loss takes one copy a data-parallel rank of
+    (``loss_fn.rank_kernels``: {path: (the copies' axis, their type)}),
+    their copies and the one sum of their gradients."""
+
+    def __init__(self, kernels, ctx: MeshContext, param_shardings, landing):
+        self.kernels, self.ctx = dict(kernels), ctx
+        self.param_shardings, self.landing = param_shardings, landing
+
+    def of(self, path):
+        return self.kernels.get(tuple(getattr(k, "key", None) for k in path))
+
+    def copies(self, params):
+        """`params` with one more axis on each such kernel, of dp copies and
+        split over dp: no byte moves, a chip's copy is the one it held."""
+        def spread(path, p, sh):
+            if self.of(path) is None:
+                return p
+            axis, dtype = self.of(path)
+            spec = _spec(sh, p.ndim)
+            spec.insert(axis, DP_AXIS)
+            # In the type the layer multiplies in: the gradient a layer
+            # hands back is then that type's too (bf16, as the parent's was
+            # when it crossed dp), and fp32 from the accumulator on.
+            p = jnp.expand_dims(p.astype(dtype or p.dtype), axis)
+            return jax.lax.with_sharding_constraint(
+                jnp.broadcast_to(
+                    p, p.shape[:axis] + (self.ctx.dp,) + p.shape[axis + 1:]),
+                NamedSharding(self.ctx.mesh, P(*spec)))
+        return jax.tree_util.tree_map_with_path(
+            spread, params, self.param_shardings)
+
+    def summed(self, g_sum):
+        """The one sum over dp, fp32, a leaf at a time: each rank's own
+        copy's gradient through one psum, or one psum_scatter where the
+        landing layout splits a whole dim over dp."""
+        dp = self.ctx.dp
+
+        def one(path, g, p_sh, land_sh):
+            if self.of(path) is None:
+                return g
+            axis, nd = self.of(path)[0], g.ndim - 1
+            own, land = _spec(p_sh, nd), _spec(land_sh, nd)
+            shape = g.shape[:axis] + g.shape[axis + 1:]
+            split = next((d for d in range(nd)
+                          if land[d] == DP_AXIS and own[d] is None
+                          and shape[d] % dp == 0), None)
+
+            def body(x):
+                x = jnp.squeeze(x, axis)
+                if split is None:
+                    return psum(x, DP_AXIS)
+                return psum_scatter(x, DP_AXIS, split)
+
+            out = [DP_AXIS if d == split else e for d, e in enumerate(own)]
+            own.insert(axis, DP_AXIS)
+            return shard_map_compat(
+                body, self.ctx.shard_map_mesh, in_specs=(P(*own),),
+                out_specs=P(*out))(g)
+        return jax.tree_util.tree_map_with_path(
+            one, g_sum, self.param_shardings, self.landing)
+
+    def line(self, params, num_micro: int) -> str:
+        once = rest = 0
+        for (path, p), sh in zip(
+                jax.tree_util.tree_leaves_with_path(params),
+                jax.tree.leaves(self.param_shardings)):
+            held = int(np.prod(sh.shard_shape(p.shape))) * 4
+            if self.of(path) is None:
+                rest += held
+            else:
+                once += held
+        return (f"weights summed over dp once a step, fp32, "
+                f"{once / 1e9:.2f} GB a chip (was: inside each of "
+                f"{num_micro} micro-batches, as the other leaves' "
+                f"{rest / 1e9:.2f} GB still are)")
+
+
+_announced = set()
+
+
+def _announce(line: str) -> None:
+    """Print, once per distinct line in this process, where the gradients
+    are summed over dp (beside `device:` and `attention:`)."""
+    if line not in _announced:
+        _announced.add(line)
+        print("gradients: " + line, flush=True)
+
+
 def make_train_step(
     loss_fn: Callable[[Any, Dict[str, jnp.ndarray]], Tuple[jnp.ndarray, Dict]],
     optimizer,
@@ -103,6 +244,11 @@ def make_train_step(
     directly. A NaN-skipped step keeps the old history (nothing
     observed)."""
     sched = lr_schedule(opt_cfg, train_iters)
+    # Where the gradients are summed over dp (module docstring).
+    many_ranks = ctx.dp * ctx.ep > 1
+    per_micro = per_micro_batch_reason(
+        loss_fn, ctx, state_shardings, pipeline=pipeline,
+        fp8=fp8) if many_ranks else None
     # ZeRO-1 manual update path (--dist-opt-comm ring|bulk): the weight
     # update runs inside one full-manual shard_map with the updated
     # params returned through the overlap.py ring all-gather (ring) or a
@@ -121,6 +267,26 @@ def make_train_step(
         )
         zero1_plan = shard_plan(state_shardings["params"],
                                 state_shardings["opt_state"])
+    # Under ZeRO-1 the sum lands in the moments' layout (a leaf split over
+    # dp once more), which is all the update reads of it: a reduce-scatter,
+    # half an all-reduce's bytes, and no second whole accumulator. The
+    # manual update slices `grads` itself and wants them whole.
+    opt_sh = state_shardings.get("opt_state")
+    landing = (opt_sh["mu"] if getattr(optimizer, "zero1", False)
+               and not zero1_manual
+               and isinstance(opt_sh, dict) and "mu" in opt_sh
+               else state_shardings["params"])
+    ranks = (_PerRankKernels(loss_fn.rank_kernels, ctx,
+                             state_shardings["params"], landing)
+             if many_ranks and per_micro is None else None)
+
+    def announce(params, num_micro):
+        if ranks is not None:
+            _announce(ranks.line(params, num_micro))
+        elif many_ranks:
+            _announce("summed over dp inside every micro-batch (GSPMD): "
+                      + per_micro)
+
     if trace_phases:
         # MegaScan schedule-phase spans (trace/tracer.py): 'forward' spans
         # the loss computation; its custom-VJP mirrors emit the 'backward'
@@ -157,6 +323,7 @@ def make_train_step(
         params = state["params"]
         num_micro = jax.tree.leaves(batch)[0].shape[0]
         fp8_new = None
+        announce(params, num_micro)
 
         if pipeline:
             (loss, aux), grads = grad_fn(params, batch)
@@ -176,7 +343,7 @@ def make_train_step(
                             lambda a, b: a + b.astype(a.dtype), gp_acc, g),
                             fp8_accumulate(f8_acc, g8))
                 else:
-                    (loss, metrics), g = grad_fn(params, micro)
+                    (loss, metrics), g = grad_fn(loss_params, micro)
                     with jax.named_scope("grad_accum"):
                         g_acc = jax.tree.map(
                             lambda a, b: a + b.astype(a.dtype), g_acc, g)
@@ -184,8 +351,9 @@ def make_train_step(
                         jax.tree.map(lambda a, b: a + b, aux_acc,
                                      metrics)), None
 
+            loss_params = params if ranks is None else ranks.copies(params)
             zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                lambda p: jnp.zeros(p.shape, jnp.float32), loss_params)
             if fp8:
                 zeros = (zeros, fp8_zeros_like(state["fp8"]))
                 metrics_struct = jax.eval_shape(
@@ -211,6 +379,8 @@ def make_train_step(
                 fp8_new = fp8_carry_sat(state["fp8"], fp8_new)
             inv = 1.0 / num_micro
             with jax.named_scope("grad_accum"):
+                if ranks is not None:
+                    g_sum = ranks.summed(g_sum)
                 grads = jax.tree.map(lambda g: g * inv, g_sum)
             loss = loss_sum * inv
             aux = jax.tree.map(lambda a: a * inv, aux_sum)

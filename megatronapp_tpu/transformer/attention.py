@@ -28,6 +28,7 @@ from megatronapp_tpu.config.transformer_config import (
 )
 from megatronapp_tpu.ops.attention import dot_product_attention
 from megatronapp_tpu.ops.normalization import rms_norm
+from megatronapp_tpu.ops.per_rank import dense
 from megatronapp_tpu.ops.pallas import flash_attention as fa
 from megatronapp_tpu.ops import rotary
 from megatronapp_tpu.scope.hooks import scope_capture
@@ -305,8 +306,8 @@ def attention_forward(
             fp8=None if fp8 is None else fp8["qkv"],
             fp8_margin=fp8_margin)
     else:
-        q = x @ q_kernel.astype(cfg.compute_dtype)
-        kv = x @ kv_kernel.astype(cfg.compute_dtype)
+        q = dense(x, q_kernel.astype(cfg.compute_dtype))
+        kv = dense(x, kv_kernel.astype(cfg.compute_dtype))
     if lora is not None:
         from megatronapp_tpu.ops.pallas.kernel_gen import apply_lora_delta
         q = apply_lora_delta(q, x, lora, "q_kernel")
@@ -593,7 +594,7 @@ def attention_forward(
             fp8=None if fp8 is None else fp8["out"],
             fp8_margin=fp8_margin)
     else:
-        out = attn_out.reshape(b, s, nq * d) @ out_kernel
+        out = dense(attn_out.reshape(b, s, nq * d), out_kernel)
         if lora is not None:
             from megatronapp_tpu.ops.pallas.kernel_gen import (
                 apply_lora_delta)

@@ -25,6 +25,7 @@ from megatronapp_tpu.config.transformer_config import (
     NormKind, TransformerConfig,
 )
 from megatronapp_tpu.ops.normalization import apply_norm
+from megatronapp_tpu.ops.per_rank import RANK_DENSE_OUT
 from megatronapp_tpu.transformer.attention import (
     attention_forward, init_attention_params,
 )
@@ -339,14 +340,20 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     return (x, new_cache), aux
 
 
+_SAVE_MATMULS = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(RANK_DENSE_OUT))
+
+
 def _remat_wrap(fn, policy: str):
     if policy == "full":
         return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
     if policy == "selective":
         # Save matmul outputs, recompute the rest (attention softmax etc.) —
         # semantics of the reference --recompute-activations selective mode.
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        # A product batched over data-parallel ranks (ops/per_rank.py) is
+        # such an output by name.
+        return jax.checkpoint(fn, policy=_SAVE_MATMULS)
     if policy == "selective_attn":
         # Selective + the tagged attention outputs: skips the flash-kernel
         # forward recompute in the backward pass for one [B,S,H] bf16
@@ -354,7 +361,7 @@ def _remat_wrap(fn, policy: str):
         # little HBM for the kernel re-execution.
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                _SAVE_MATMULS,
                 jax.checkpoint_policies.save_only_these_names("attn_out")))
     return fn
 

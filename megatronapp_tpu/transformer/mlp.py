@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.ops.activations import apply_activation, is_gated
+from megatronapp_tpu.ops.per_rank import dense
 from megatronapp_tpu.scope.hooks import scope_capture
 
 
@@ -105,7 +106,7 @@ def mlp_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
                               fp8=None if fp8 is None else fp8["fc1"],
                               fp8_margin=margin)
     else:
-        y = x @ fc1_kernel
+        y = dense(x, fc1_kernel)
         if lora is not None:
             from megatronapp_tpu.ops.pallas.kernel_gen import (
                 apply_lora_delta)
@@ -129,7 +130,7 @@ def mlp_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
             y, fc2_kernel, ctx.shard_map_mesh,
             fp8=None if fp8 is None else fp8["fc2"], fp8_margin=margin)
     else:
-        out = y @ fc2_kernel
+        out = dense(y, fc2_kernel)
         if lora is not None:
             from megatronapp_tpu.ops.pallas.kernel_gen import (
                 apply_lora_delta)

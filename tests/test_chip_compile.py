@@ -401,6 +401,106 @@ def test_train_step_tp2_dp2(topo, chip_compile):
     assert per_device.argument_size_in_bytes < 16 * 2**30
 
 
+def _gpt3_2p7b_cell(**kw):
+    """perfbench/configs/gpt3-2.7b-tp2dp2.json's widths: H 2560, 32 heads
+    of 80, FFN 10240, 2048 positions, fp32 parameters, selective
+    recomputation (20 layers in the cell)."""
+    return PRESETS["gpt2-125m"](
+        hidden_size=2560, num_attention_heads=32, ffn_hidden_size=10240,
+        max_position_embeddings=2048, remat_policy="selective", **kw)
+
+
+def _collectives(compiled, mesh):
+    from megatronapp_tpu.trace.profiler_collectives import collectives_of
+    from megatronapp_tpu.trace.scope_map import parse_hlo_text
+    parsed = parse_hlo_text(compiled.as_text())
+    return parsed, collectives_of(parsed, mesh)
+
+
+# What the parent's step (PR 41) held inside its loops over tp at these
+# widths, 1 MB and more, by (kind, bytes): four bf16[1,2048,2560]
+# all-reduces in the layer loops and one in the head, the head's float32
+# one, the loss's tuple, and the all-to-alls around the flash kernels.
+# Measured on the parent with this file's own counting (PERF.md, PR 42).
+PARENT_TP_IN_LOOP = {
+    ("all-reduce", 10485760): 5, ("all-reduce", 20971520): 1,
+    ("all-reduce", 10493952): 1, ("all-to-all", 10485760): 1,
+    ("all-to-all", 5242880): 2,
+}
+PARENT_FLASH_CALLS = 4      # forward, its recomputation, dq, dk/dv
+
+
+def test_train_step_tp2_dp2_at_cell_widths(topo, chip_compile, capsys):
+    """`train.gpt3-2.7b.tp2dp2-2k`'s step (tp 2 x dp 2, ZeRO-1, 8
+    micro-batches of 1 x 2048 a rank, packed segments; depth cut 20 -> 2):
+    the weights' gradients leave the loops. No collective over dp above
+    1 MB lives in a `while` body but the parent's 20 MB all-to-all of the
+    position embedding's gradient (no per-rank kernel); the sums over dp
+    behind the loops are float32 reduce-scatters into ZeRO-1's layout, one
+    a kernel; the loops' tp collectives of 1 MB and more are the parent's by
+    kind, count and bytes, with no all-gather of an activation; the flash
+    kernels are all there."""
+    import collections
+    from megatronapp_tpu.training import train_step
+    model = _gpt3_2p7b_cell(num_layers=2)
+    step, state, batch, ctx = _train_step_for(
+        topo.devices,
+        ParallelConfig(tensor_parallel=2, data_parallel=2,
+                       distributed_optimizer=True),
+        model, micro=1, global_batch=16, seq=2048, segments=True)
+    train_step._announced.clear()
+    with ctx.mesh:
+        compiled = step.lower(state, batch).compile()
+    assert ("gradients: weights summed over dp once a step, fp32, "
+            "0.57 GB a chip (was: inside each of 8 micro-batches, as the "
+            "other leaves' 0.02 GB still are)" in capsys.readouterr().out)
+    parsed, colls = _collectives(compiled, ctx.mesh)
+    big = {n: c for n, c in colls.items() if c["bytes"] >= 2 ** 20}
+
+    dp_in_loop = [(c["kind"], c["bytes"]) for c in big.values()
+                  if "dp" in c["axes"] and c["in_loop"]]
+    assert dp_in_loop in ([], [("all-to-all", 20971520)]), dp_in_loop
+
+    once = {n: c for n, c in big.items()
+            if "dp" in c["axes"] and not c["in_loop"]
+            and c["kind"] in ("all-reduce", "reduce-scatter")}
+    # q, kv, out, fc1, fc2 and the word embedding
+    assert [c["kind"] for c in once.values()] == ["reduce-scatter"] * 6
+    assert all(parsed.instructions[n].shape.startswith("f32[")
+               for n in once)
+
+    tp_in_loop = collections.Counter(
+        (c["kind"], c["bytes"]) for c in big.values()
+        if c["axes"] == "tp" and c["in_loop"])
+    assert tp_in_loop == PARENT_TP_IN_LOOP
+    assert not [n for n, c in big.items()
+                if c["kind"] == "all-gather" and c["in_loop"]]
+
+    flash = [i for i in parsed.instructions.values()
+             if i.opcode == "custom-call" and "flash_" in i.name]
+    assert len(flash) == PARENT_FLASH_CALLS
+    _assert_kernels_named(compiled, "flash_fwd", "flash_bwd_dq",
+                          "flash_bwd_dkv")
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < 16 * 2**30
+
+
+def test_train_step_one_chip_has_no_collective(topo, chip_compile, capsys):
+    """dp = 1: no copy a rank is made, the step says nothing about
+    gradients, and its compiled text holds no collective."""
+    step, state, batch, ctx = _train_step_for(
+        topo.devices[:1], ParallelConfig(), _gpt2_medium(num_layers=2),
+        micro=4, global_batch=4, seq=1024, segments=True)
+    with ctx.mesh:
+        compiled = step.lower(state, batch).compile()
+    assert "gradients:" not in capsys.readouterr().out
+    assert not _collectives(compiled, ctx.mesh)[1]
+    _assert_kernels_named(compiled, "flash_fwd", "flash_bwd_dq",
+                          "flash_bwd_dkv")
+
+
 _HLO_RESULT = re.compile(r"%([\w.\-]+) = \(?\w+\[([\d,]*)\]")
 
 

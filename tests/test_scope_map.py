@@ -112,9 +112,85 @@ def test_collectives_are_a_view_of_the_same_parse():
     # inside parentheses): the one parser reads it.
     assert extract_hlo_collectives(HLO) == {"all-reduce-start.1": {
         "kind": "all-reduce", "bytes": 32, "groups": [[0, 1], [2, 3]],
-        "axes": ""}}
+        "axes": "", "in_loop": False}}
     assert sm.scope_map(sm.parse_hlo_text(HLO)).collectives \
         == extract_hlo_collectives(HLO)
+
+
+# A step as the train step compiles: an accumulation loop whose body calls
+# a computation that holds a layer loop; collectives at each depth, one in
+# the outer loop's CONDITION and one behind the loops.
+LOOPS_HLO = """HloModule jit_step, is_scheduled=true
+
+%add (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %sum = f32[] add(%x, %y)
+}
+
+%layer_body (p: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %p = (s32[], f32[8]) parameter(0)
+  %g.1 = f32[8]{0} get-tuple-element(%p), index=1
+  %all-reduce.layer = f32[8]{0} all-reduce(%g.1), channel_id=1, replica_groups=[2,2]<=[2,2]T(1,0), use_global_device_ids=true, to_apply=%add
+  %i.1 = s32[] get-tuple-element(%p), index=0
+  ROOT %t.1 = (s32[], f32[8]) tuple(%i.1, %all-reduce.layer)
+}
+
+%layer_cond (p.1: (s32[], f32[8])) -> pred[] {
+  %p.1 = (s32[], f32[8]) parameter(0)
+  ROOT %lt = pred[] constant(false)
+}
+
+%called (q: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %q = (s32[], f32[8]) parameter(0)
+  ROOT %while.layers = (s32[], f32[8]) while(%q), condition=%layer_cond, body=%layer_body
+}
+
+%micro_body (r: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %r = (s32[], f32[8]) parameter(0)
+  %g.3 = f32[8]{0} get-tuple-element(%r), index=1
+  %all-gather.micro = f32[16]{0} all-gather(%g.3), channel_id=3, replica_groups=[2,2]<=[4], dimensions={0}, use_global_device_ids=true
+  ROOT %call.1 = (s32[], f32[8]) call(%r), to_apply=%called
+}
+
+%micro_cond (r.1: (s32[], f32[8])) -> pred[] {
+  %r.1 = (s32[], f32[8]) parameter(0)
+  %g.2 = f32[8]{0} get-tuple-element(%r.1), index=1
+  %all-reduce.cond = f32[8]{0} all-reduce(%g.2), channel_id=2, replica_groups=[2,2]<=[4], use_global_device_ids=true, to_apply=%add
+  ROOT %lt.1 = pred[] constant(false)
+}
+
+ENTRY %main (a: (s32[], f32[8])) -> f32[8] {
+  %a = (s32[], f32[8]) parameter(0)
+  %while.micro = (s32[], f32[8]) while(%a), condition=%micro_cond, body=%micro_body
+  %g.4 = f32[8]{0} get-tuple-element(%while.micro), index=1
+  ROOT %reduce-scatter.once = f32[4]{0} reduce-scatter(%g.4), channel_id=4, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true, to_apply=%add
+}
+"""
+
+
+@pytest.mark.parametrize("name, in_loop, axes", [
+    ("all-gather.micro", True, "tp"),        # a `while` body
+    ("all-reduce.layer", True, "dp"),        # a body a body calls
+    ("all-reduce.cond", False, "tp"),        # the outer loop's condition
+    ("reduce-scatter.once", False, "dp"),    # behind the loops
+])
+def test_in_loop_on_hand_written_loops(name, in_loop, axes):
+    """`in_loop`: the instruction lives in a `while` body, or in what one
+    calls, however deep. `axes` are read by a group's PARTITIONS (positions
+    in the mesh's row-major device order), so a mesh whose device ids are
+    permuted, as a v5e 2x2's are (0, 1, 3, 2), names its axes right."""
+    from megatronapp_tpu.trace.profiler_collectives import collectives_of
+    parsed = sm.parse_hlo_text(LOOPS_HLO)
+    # the inner loop's condition runs once an outer iteration
+    assert parsed.loop_computations() == {"micro_body", "called",
+                                          "layer_body", "layer_cond", "add"}
+    Dev = collections.namedtuple("Dev", "id")
+    Mesh = collections.namedtuple("Mesh", "devices axis_names")
+    mesh = Mesh(np.array([[Dev(0), Dev(1)], [Dev(3), Dev(2)]], object),
+                ("dp", "tp"))
+    got = collectives_of(parsed, mesh)[name]
+    assert (got["in_loop"], got["axes"]) == (in_loop, axes)
 
 
 def test_shard_map_is_peeled(devices8):
