@@ -17,9 +17,13 @@ multiplied by ``cfg.moe_routed_scaling_factor`` (HF ``routed_scaling_factor``).
 
 Two dispatch modes, matching the reference's semantics:
 - moe_capacity_factor=None (the reference DEFAULT): exact dropless —
-  token copies are sorted by expert and run through ``lax.ragged_dot``
-  grouped GEMMs (static shapes, no capacity buffer, no token dropping;
-  the reference's allgather/a2a dispatchers with no capacity).
+  token copies are sorted by expert and run through grouped GEMMs (static
+  shapes, no capacity buffer, no token dropping; the reference's
+  allgather/a2a dispatchers with no capacity): ``lax.ragged_dot`` in
+  training, on a mesh and over int8-resident experts; the Pallas kernel of
+  ops/pallas/grouped_gemm.py where the paged serving loop hands a layer
+  its place in the experts' stack (StackedLayer), so that the stack is
+  read in place and every touched expert once (_grouped_gemm).
 - moe_capacity_factor=F: GShard capacity dispatch (tokens beyond
   F*T*k/E per expert dropped, prob-weighted combine) — the reference's
   --moe-expert-capacity-factor path; the GroupedMLP becomes one batched
@@ -35,6 +39,7 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.ops.activations import apply_activation, is_gated
+from megatronapp_tpu.ops.pallas.grouped_gemm import grouped_gemm
 
 
 def init_moe_params(rng, cfg: TransformerConfig, out_std: float):
@@ -180,33 +185,34 @@ def _expert_ffn(p, x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
 
 
 def _grouped_gemm(x, w, group_sizes, dt) -> jnp.ndarray:
-    """``lax.ragged_dot`` of the rows x [M, K], sorted by expert, against one
-    layer's expert kernel w; group_sizes [E] int32.
+    """The rows x [M, K], sorted by expert, against one layer's expert
+    kernel w, group by group; group_sizes [E] int32. Rows behind the last
+    group belong to none and their output rows are undefined.
 
-    XLA:TPU lowers ragged_dot to a custom call, which cannot take a fused
-    slice as its operand: handed a layer's slice of a stack it first copies
-    the layer out (1.1 GB a DeepSeek-V2-Lite layer, more time than the GEMM
-    itself). So a StackedLayer is read in place: the stack viewed as
-    [L·E, K, N] (merging leading dimensions moves nothing), with the layer's
-    group sizes at offset layer·E and zero rows for every other group. The
-    kernel's grid runs over the tiles that hold rows, so the empty groups
-    cost no step, and the rows, tiles and accumulation order are those of
-    the per-layer call: the same numbers. A stack that is not held in the
-    compute dtype would be converted whole, so it takes the slice."""
+    A StackedLayer held in the compute dtype (the paged serving loop on one
+    device, plain arrays, no gradient) runs the Pallas grouped GEMM
+    (ops/pallas/grouped_gemm.py): it reads the [L, E, K, N] stack where it
+    lies, through the layer id, and streams every touched expert's matrix
+    once, in tiles chosen from the call's shapes (choose_gemm_tiles); what
+    it chose is printed once a shape (`grouped gemm: ...`). A grouped GEMM
+    is a custom call either way, which cannot take a fused slice as its
+    operand: handed a layer's slice of a stack it would first copy the
+    layer out (1.1 GB a DeepSeek-V2-Lite layer, more time than the GEMM
+    itself).
+
+    Everything else (training, a mesh, resident int8 pairs, a stack that is
+    not held in the compute dtype and would be converted whole) takes the
+    layer's own [E, K, N] kernel through ``lax.ragged_dot``, which XLA
+    differentiates and partitions."""
     if isinstance(w, StackedLayer) and w.stack.dtype == dt:
-        l, e = w.stack.shape[:2]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((l * e,), group_sizes.dtype), group_sizes,
-            (w.layer * e,))
-        return jax.lax.ragged_dot(
-            x, w.stack.reshape((l * e,) + w.stack.shape[2:]), sizes)
+        return grouped_gemm(x, w.stack, group_sizes, layer=w.layer)
     return jax.lax.ragged_dot(x, _expert_kernel(w, dt), group_sizes)
 
 
 def _dropless_experts(p, x_flat, topk_idx, topk_probs,
                       cfg: TransformerConfig) -> jnp.ndarray:
     """Exact dropless dispatch: sort the T*k token copies by expert id and
-    run grouped GEMMs (``lax.ragged_dot``) over the contiguous per-expert
+    run grouped GEMMs (_grouped_gemm) over the contiguous per-expert
     row groups — static shapes, no capacity buffer, zero drops. This is
     the reference's default behavior (no --moe-expert-capacity-factor ⇒
     dispatchers never drop; experts.py GroupedMLP runs ragged groups).

@@ -592,6 +592,38 @@ def _cell_prefill_width(one_chip, model, seq, **cut):
     return choose_prefill_width(cfg, abstract, seq, 16, device_kind=kind)
 
 
+_SCOPED = re.compile(
+    r'%(grouped_gemm[\w.\-]*) = [^\n]*"scoped_memory_configs":\[\{[^\]]*?'
+    r'"offset":"(\d+)","size":"(\d+)"[^\n]*"used_scoped_memory_configs":'
+    r'\[\{[^\]]*?"size":"(\d+)"')
+
+
+def _assert_grouped_gemms(compiled, experts, rows):
+    """The step's grouped GEMMs are the Pallas kernel and none is XLA's
+    `ragged-dot*`; each call asked Mosaic for the VMEM of the tiles the
+    chooser gives fc1's or fc2's call (past the default 16 MiB), and what
+    Mosaic took of it (a call's scoped region starts at an offset, behind
+    what XLA keeps for itself) holds the two weight blocks."""
+    from megatronapp_tpu.ops.pallas import grouped_gemm as gg
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    calls = _SCOPED.findall(text)
+    assert len(calls) >= 2, _kernel_names(compiled)
+    want = {}
+    for key in ("fc1_kernel", "fc2_kernel"):
+        _, e, k, n = experts[key].shape
+        tiles = gg.choose_gemm_tiles(rows, e, k, n, experts[key].dtype)
+        blocks = 2 * k * tiles.n * experts[key].dtype.itemsize
+        assert 8 * 2**20 < blocks <= 2 * gg.WEIGHT_BLOCK_BYTES
+        want[gg.vmem_limit(tiles, experts[key].dtype)] = blocks
+    assert min(want) > 16 * 2**20
+    assert {int(asked) for _, _, asked, _ in calls} == set(want), (calls,
+                                                                   want)
+    for name, offset, asked, used in calls:
+        took = int(used) - int(offset)
+        assert want[int(asked)] < took <= int(asked), (name, asked, took)
+
+
 # The agent cell's cut of LongCat-Flash-Chat: one chip's share of a
 # deployment (perfbench/configs/longcat-flash-chat.json).
 LONGCAT_CUT = {"num_layers": 4, "vocab_size": 16384,
@@ -618,11 +650,14 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     weights and the pool go in as abstract values; the step still aliases
     its pools.
 
-    The experts' grouped GEMMs (`ragged-dot-none*`, custom calls) read the
+    The experts' grouped GEMMs are the Pallas kernel `grouped_gemm*`
+    (ISSUE 43; no `ragged-dot*` is left in a step), which reads the
     fc1/fc2 stacks in place through the layer id (ISSUE 31): nothing
     sliced or copied has the shape of one layer's experts, and the step's
     temporaries are smaller than one layer's fc1 kernel (the parent held
     one: `dynamic-slice_bitcast_fusion`, 94.5 MB of temporaries here).
+    Mosaic takes its VMEM at the tiles `choose_gemm_tiles` gives the call:
+    two whole-K weight blocks of up to 8 MiB, past the default 16 MiB.
 
     The prefill call is as wide as the engine makes it on this chip for the
     cell's configuration (ISSUE 35; the width is chosen from the cell's own
@@ -700,7 +735,9 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     assert mem.alias_size_in_bytes >= pool_bytes
     if cfg.is_moe:
         experts = _moe_of(abstract["block"])
-        assert any("ragged-dot" in n for n in _kernel_names(compiled))
+        _assert_grouped_gemms(
+            compiled, experts,
+            (batch if which == "decode" else width) * cfg.moe_router_topk)
         assert not _pool_shaped(
             compiled, r"slice|copy",
             [experts[k].shape[1:] for k in ("fc1_kernel", "fc2_kernel")])
@@ -789,7 +826,9 @@ def test_engine_conv_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
     2048 positions the engine chooses for the cell on this chip. Mosaic takes
     the D 64 group-4 pools in both kernels; the step aliases the page pools
     and the tail pool alike and cuts no layer's experts out of their stacks
-    (a scanned slice of [8, 64, 2048, 3072] is a copy of 0.8 GB a layer)."""
+    (a scanned slice of [8, 64, 2048, 3072] is a copy of 0.8 GB a layer): the
+    Pallas grouped GEMM reads them through the layer id, 768 rows a decode
+    round and 8,192 a prefill call, and no `ragged-dot*` is left."""
     from megatronapp_tpu.inference.dynamic_engine import (
         DynamicInferenceEngine,
     )
@@ -820,16 +859,19 @@ def test_engine_conv_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
         compiled = eng._decode.lower(
             p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
             _sds((batch,), jnp.bool_, one_chip), None).compile()
-        _assert_kernels_named(compiled, "paged_decode")
+        _assert_kernels_named(compiled, "paged_decode", "grouped_gemm")
     else:
         compiled = eng._mq_step.lower(
             p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
             i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1),
             i32(1)).compile()
-        _assert_kernels_named(compiled, "paged_mq")
+        _assert_kernels_named(compiled, "paged_mq", "grouped_gemm")
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
                                           for a in pools)
+    _assert_grouped_gemms(
+        compiled, abstract["block"]["ffn"]["moe"],
+        (batch if which == "decode" else width) * cfg.moe_router_topk)
     fc1 = abstract["block"]["ffn"]["moe"]["fc1_kernel"]
     assert fc1.shape == (8, 64, 2048, 3072)
     assert not _pool_shaped(compiled, r"copy|(?<!update[_-])slice",

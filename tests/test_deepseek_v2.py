@@ -231,7 +231,9 @@ class TestEngine:
         decode step that cut one layer's expert kernel out of its stack. 0
         when the layer loop hands the grouped GEMMs the stack and the layer
         id; 2 a layer loop (fc1 and fc2 as the scan's xs) where the kernels
-        stay per-layer operands, as resident int8 pairs do."""
+        stay per-layer operands, as resident int8 pairs do. The in-place
+        read is the Pallas grouped GEMM's (two calls a MoE layer beside the
+        latent kernel); the per-layer operands keep ``lax.ragged_dot``."""
         from megatronapp_tpu.inference.quantization import (
             quantize_params, residentize_params,
         )
@@ -246,7 +248,9 @@ class TestEngine:
                                      prefill_chunk=8)
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         assert disp["expert_stack_slices"] == slices, disp
-        assert disp["kernels"] == cfg.num_layers    # the latent kernel
+        moe_layers = cfg.num_layers - cfg.moe_first_k_dense
+        assert disp["kernels"] == cfg.num_layers + (   # the latent kernel
+            0 if experts == "int8" else 2 * moe_layers)
 
     @pytest.mark.parametrize("n,seed,chunk", [
         (10, 4, 8), (17, 5, 8), (45, 6, 32), (45, 6, None), (45, 6, 7)],

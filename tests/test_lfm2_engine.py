@@ -147,7 +147,8 @@ class TestEngine:
         eng = _engine(cfg, params)
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         assert disp["expert_stack_slices"] == 0, disp
-        assert disp["kernels"] == 3, disp       # paged_append x 2, decode
+        # paged_append x 2, decode; the grouped GEMM x 2 a run of MoE layers
+        assert disp["kernels"] == 3 + 2 * 4, disp
         line = eng.startup_line()
         for word in ("4 gated short-convolution layers x 2048 B a slot",
                      "prefix reuse off", "8 experts", "sigmoid"):
@@ -208,12 +209,14 @@ class TestEngine:
 # What the shared layer loop, router and tenant trace for the models that do
 # not use the new fields: the decode step of the tiny Jamba and
 # DeepSeek-V2-Lite (their modules' REHEARSAL sizes, 3 slots), read off the
-# parent commit (8990e6e) with the same lines.
+# parent commit (8990e6e) with the same lines; since ISSUE 43 with the
+# MoE layers' four grouped GEMMs as Pallas calls (3 + 4 kernels, 63 launches
+# a call for its visit list where the zero-padded sizes were 4).
 PARENT_DISPATCH = {
     "jamba": ("jamba2-3b", {"launches": 813, "kernels": 6, "loop_steps": 2,
                             "expert_stack_slices": 0}),
     "deepseek_v2": ("deepseek-v2-lite", {
-        "launches": 1615, "kernels": 3, "loop_steps": 21,
+        "launches": 1867, "kernels": 7, "loop_steps": 21,
         "expert_stack_slices": 0}),
 }
 

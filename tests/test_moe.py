@@ -268,12 +268,21 @@ def _ragged_dot_expert_shapes(jaxpr):
     return shapes
 
 
+def _grouped_gemm_weight_shapes(jaxpr):
+    """The weight operand's shape of every Pallas grouped GEMM among
+    `jaxpr`'s own equations (ops/pallas/grouped_gemm.py: the last operand,
+    [G, K, N])."""
+    return [tuple(eqn.invars[-1].aval.shape) for eqn in jaxpr.eqns
+            if eqn.primitive.name == "pallas_call"
+            and eqn.params["name"].startswith("grouped_gemm")]
+
+
 class TestStackedLayer:
     """A layer's expert kernel named as "layer i of the stack"
     (moe.StackedLayer, what the paged serving loop hands a layer): the
-    dropless grouped GEMMs read the stack as [L·E, K, N] with the layer's
-    groups at offset i·E, and give the numbers of the same call on
-    stack[i]."""
+    dropless grouped GEMMs are the Pallas kernel, which reads the stack as
+    [L·E, K, N] with the layer's groups at offset i·E, and give the numbers
+    of ``lax.ragged_dot`` on stack[i] to a rounding of the compute dtype."""
     L, E, K, T = 3, 8, 2, 24
 
     def _case(self, params_dtype, compute_dtype, routing, **kw):
@@ -308,13 +317,12 @@ class TestStackedLayer:
         (jnp.float32, jnp.bfloat16)], ids=["fp32", "bf16", "fp32-as-bf16"])
     def test_dropless_equals_the_slice(
             self, params_dtype, compute_dtype, routing, layer):
-        """In bf16 bit for bit. In float32 to the last bits: the CPU's
-        stand-in for the grouped GEMM is ONE dot that contracts over
-        groups x K with the other groups' rows zeroed, so its blocks of
-        partial sums shift with the number of groups (3e-7 of the largest
-        value, measured; on the TPU both namings run the same kernel over
-        the same tiles, and a chip run compared them bit for bit:
-        PERF.md, PR 31)."""
+        """The named layer runs the Pallas kernel over the whole stack, the
+        sliced one ``lax.ragged_dot``: each output element is one float32
+        accumulation over K rounded once to the compute dtype in both, in
+        another order, so they agree to the last bits in float32 and to a
+        rounding of bfloat16 per GEMM in bf16 (a chip run compared them at
+        the cells' shapes: PERF.md, PR 43)."""
         from megatronapp_tpu.transformer.moe import (
             StackedLayer, _dropless_experts,
         )
@@ -332,19 +340,29 @@ class TestStackedLayer:
         got = np.asarray(run(named(jnp.int32(layer))))
         want = np.asarray(run(sliced(layer)))
         assert np.abs(want).max() > 1.0
-        if compute_dtype == jnp.bfloat16:
-            assert got.tobytes() == want.tobytes()
-        else:
-            assert np.abs(got - want).max() < 1e-6 * np.abs(want).max()
+        ulp = 2.0 ** -8 if compute_dtype == jnp.bfloat16 else 1e-6
+        assert np.abs(got - want).max() < 2 * ulp * np.abs(want).max()
         # another layer's experts give other numbers: the offset is live
         other = np.asarray(run(sliced((layer + 1) % self.L)))
         assert np.abs(got - other).max() > 1.0
-        # A stack held in the compute dtype is read whole; one that would
-        # have to be converted is sliced first.
-        groups = [s[0] for s in _ragged_dot_expert_shapes(
-            jax.make_jaxpr(run)(named(jnp.int32(layer))).jaxpr)]
+        # A stack held in the compute dtype goes to the kernel whole and
+        # nothing of one layer's shape is cut out of it; one that would
+        # have to be converted is sliced first and takes ragged_dot.
+        from megatronapp_tpu.utils.dispatch import stack_slices
+        jaxpr = jax.make_jaxpr(
+            lambda p: _dropless_experts(p, x, idx, probs, cfg))(
+                named(jnp.int32(layer))).jaxpr
         in_place = params_dtype == compute_dtype
-        assert groups == [self.L * self.E if in_place else self.E] * 2
+        kernels = _grouped_gemm_weight_shapes(jaxpr)
+        ragged = [s[0] for s in _ragged_dot_expert_shapes(jaxpr)]
+        cut = stack_slices(jaxpr, [stack[k].shape[1:]
+                                   for k in ("fc1_kernel", "fc2_kernel")])
+        if in_place:
+            assert kernels == [(self.L * self.E,) + stack[k].shape[2:]
+                               for k in ("fc1_kernel", "fc2_kernel")]
+            assert not ragged and not cut, (ragged, cut)
+        else:
+            assert not kernels and ragged == [self.E] * 2 and cut == 2
 
     @pytest.mark.parametrize("capacity", [None, 8.0],
                              ids=["dropless", "capacity"])
