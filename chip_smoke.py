@@ -18,7 +18,7 @@ vocab 50304), random weights from the entry points' own seeds:
   the other side of the A/B); under ``--tiny`` ``auto`` is the dense side
   (S 64, or no TPU) and the other run forces ``pallas``;
 - server: ``tools/run_text_generation_server.py --preset gpt2-125m
-  --engine dynamic --paged-kv-cache`` answering real ``PUT /api`` requests;
+  --engine dynamic`` answering real ``PUT /api`` requests;
 - hybrid: a tiny model with state-space layers through the paged engine (no
   preset of that kind is small): the compiled decode step holds one
   ``ssm_update`` a scanned run of such layers and aliases the state pools.
@@ -463,7 +463,7 @@ def phase_server(tiny):
     cmd = [sys.executable,
            os.path.join(ROOT, "tools", "run_text_generation_server.py"),
            "--preset", "gpt2-125m", "--engine", "dynamic",
-           "--paged-kv-cache", "--host", "127.0.0.1", "--port", str(port)]
+           "--host", "127.0.0.1", "--port", str(port)]
     if tiny:
         cmd += ["--max-seq-len", "128"]
     tr = _Transcript("server")
@@ -526,7 +526,7 @@ def phase_hybrid(tiny):
 def child_hybrid(tiny):
     """In the child: a model with state-space layers (8 layers of which 1
     and 5 attend with one key/value head, E 256, state 16) serves three
-    requests through DynamicInferenceEngine(paged=True) on the device, and
+    requests through DynamicInferenceEngine on the device, and
     the compiled decode step says what it holds."""
     import jax
     import jax.numpy as jnp
@@ -554,8 +554,7 @@ def child_hybrid(tiny):
         position_embedding=PositionEmbeddingKind.none, ssm_inner_norms=True,
         params_dtype=jnp.bfloat16, **HYBRID)
     params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
-    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
-                                 paged=True)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128)
     _say(eng.startup_line())
     rng = np.random.default_rng(0)
     for n in (40, 9, 70):
@@ -644,7 +643,7 @@ def phase_eva(tiny):
 def child_eva(tiny):
     """In the child: a model with EVA attention (4 layers, 2 heads of 128,
     an 8-column byte head) serves three requests that close one, one and
-    two windows through DynamicInferenceEngine(paged=True) on the device,
+    two windows through DynamicInferenceEngine on the device,
     and the compiled decode step says what it holds."""
     import jax
     import jax.numpy as jnp
@@ -673,8 +672,7 @@ def child_eva(tiny):
         untie_embeddings_and_output_weights=True, num_pred_heads=8,
         params_dtype=jnp.bfloat16, **EVA)
     params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
-    eng = DynamicInferenceEngine(params, cfg, max_batch=4, max_seq_len=1024,
-                                 paged=True)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=4, max_seq_len=1024)
     _say(eng.startup_line())
     rng = np.random.default_rng(0)
     for n, new in _eva_requests(tiny):
@@ -773,7 +771,7 @@ def child_share(tiny):
     attention at the published column widths 512 + 64 and 128-wide heads,
     4 heads, a query latent of 128, both scale corrections; 8 + 4 experts
     top-3 with a selection bias, 4 held) serves three requests through
-    DynamicInferenceEngine(paged=True) on the device, and the compiled
+    DynamicInferenceEngine on the device, and the compiled
     decode step says what it holds."""
     import jax
     import jax.numpy as jnp
@@ -804,8 +802,7 @@ def child_share(tiny):
         moe_routed_scaling_factor=6.0, moe_router_selection_bias=True,
         moe_shortcut_double_layer=True, params_dtype=jnp.bfloat16, **SHARE)
     params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
-    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
-                                 paged=True)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128)
     _say(eng.startup_line())
     rng = np.random.default_rng(0)
     for n, new in SHARE_REQUESTS:
@@ -896,7 +893,7 @@ def child_conv(tiny):
     attention (5 layers of which 1 attends with 4 query heads on 2 key/value
     heads of 64 and per-head norms; a leading dense layer, then 8 experts
     top-2 by sigmoid scores + a selection bias) serves three requests
-    through DynamicInferenceEngine(paged=True) on the device, and the
+    through DynamicInferenceEngine on the device, and the
     compiled decode step says what it holds."""
     import jax
     import jax.numpy as jnp
@@ -924,8 +921,7 @@ def child_conv(tiny):
         moe_ffn_hidden_size=128, moe_router_score="sigmoid",
         moe_router_selection_bias=True, params_dtype=jnp.bfloat16, **CONV)
     params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
-    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
-                                 paged=True)
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128)
     _say(eng.startup_line())
     rng = np.random.default_rng(0)
     for n, new in CONV_REQUESTS:

@@ -31,8 +31,8 @@ def add_hybrid_args(ap: argparse.ArgumentParser):
     """The layer pattern of a hybrid state-space stack
     (TransformerConfig.attn_layer_period; HF `jamba`'s keys) — shared by
     the main parser (the hybrid trains through pretrain_gpt.py) and
-    tools/run_text_generation_server.py (it serves through --engine dynamic
-    --paged-kv-cache). The mixer's sizes stay the model's own (a preset's,
+    tools/run_text_generation_server.py (it serves through --engine
+    dynamic). The mixer's sizes stay the model's own (a preset's,
     or TransformerConfig's defaults: state 16, conv 4, expand 2, dt rank
     hidden / 16). Every default is None = the model's own."""
     g = ap.add_argument_group("hybrid state-space stack")
@@ -88,19 +88,16 @@ def add_serving_args(ap: argparse.ArgumentParser):
     g = ap.add_argument_group("serving")
     g.add_argument("--engine", choices=["static", "dynamic", "mamba"],
                    default="static",
-                   help="dynamic = continuous batching (connections "
-                        "share one decode batch through the server's "
-                        "stepper thread, inference/dynamic_engine.py); "
+                   help="dynamic = continuous batching over the block-"
+                        "pool paged KV cache (connections share one "
+                        "decode batch through the server's stepper "
+                        "thread, inference/dynamic_engine.py; per-block "
+                        "admission, prefix caching, preemption, "
+                        "inference/paged_cache.py); "
                         "mamba = recurrent-state decode for pure-M "
                         "presets (reference mamba server tool)")
     g.add_argument("--max-batch", type=int, default=4,
                    help="dynamic engine: concurrent decode slots")
-    g.add_argument("--paged-kv-cache", action="store_true",
-                   help="with --engine dynamic: block-pool paged KV "
-                        "cache + ragged paged-attention decode "
-                        "(inference/paged_cache.py, "
-                        "ops/pallas/paged_attention.py) — per-block "
-                        "admission, prefix caching, preemption")
     g.add_argument("--kv-block-size", type=int, default=16,
                    help="tokens per KV block")
     g.add_argument("--num-kv-blocks", type=int, default=None,
@@ -121,7 +118,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                    default="bf16",
                    help="paged KV-pool storage dtype — "
                         + kv_cache_dtype_help()
-                        + " (quantized dtypes need --paged-kv-cache; "
+                        + " (quantized dtypes need --engine dynamic; "
                         "MLA latent/pe pools quantize with per-row "
                         "scalar scales; quantized pools cost "
                         "~(D+4)/2D of the bf16 bytes)")
@@ -141,7 +138,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                    choices=["none", "draft", "mtp", "ngram"],
                    help="speculative decoding over the paged engine "
                         "(inference/speculative.py; needs --engine "
-                        "dynamic --paged-kv-cache): draft = small draft "
+                        "dynamic): draft = small draft "
                         "model (--draft-model), mtp = self-draft through "
                         "the model's MTP heads, ngram = model-free "
                         "prompt lookup. Greedy output is bit-identical "
@@ -165,8 +162,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "sub-mesh (2*serve_tp devices total) with KV "
                         "handoff through the shared block pool — decode "
                         "token intervals stop being hostage to long "
-                        "prefills (needs --engine dynamic "
-                        "--paged-kv-cache)")
+                        "prefills (needs --engine dynamic)")
     g.add_argument("--serve-tp", type=int, default=1,
                    help="tensor-parallel degree of the serving mesh: "
                         "the ragged paged-attention kernels run "
@@ -202,7 +198,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "replica death fails sessions over losslessly; "
                         "reloads roll one replica at a time. N=1 keeps "
                         "the single-engine path (needs --engine dynamic "
-                        "--paged-kv-cache for N>1; with --serve-disagg "
+                        "for N>1; with --serve-disagg "
                         "each replica is its own prefill/decode "
                         "sub-mesh pair)")
     g.add_argument("--fleet-migrate", action="store_true",
@@ -258,7 +254,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "LRU-evict, PagedKVCache discipline), and every "
                         "decode step applies the per-row low-rank "
                         "deltas via the segmented batched-LoRA kernel "
-                        "(needs --engine dynamic --paged-kv-cache; "
+                        "(needs --engine dynamic; "
                         "incompatible with --multi-latent-attention: "
                         "MLA has no q/kv projection leaves to adapt)")
     g.add_argument("--lora-rank", type=int, default=8, metavar="R",
@@ -284,8 +280,7 @@ def add_serving_args(ap: argparse.ArgumentParser):
                         "import_slot on the next token. Under pressure "
                         "the engine prefers parking over preemption "
                         "(a park costs an import, a preemption a "
-                        "re-prefill); needs --engine dynamic "
-                        "--paged-kv-cache")
+                        "re-prefill); needs --engine dynamic")
     g.add_argument("--kv-spill-watermark-blocks", type=int, default=0,
                    metavar="N",
                    help="park sessions whenever the pool's free+"
@@ -342,12 +337,16 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
         validate_kv_cache_dtype,
     )
     try:
-        validate_kv_cache_dtype(
+        spec = validate_kv_cache_dtype(
             getattr(args, "kv_cache_dtype", "bf16"),
-            paged=getattr(args, "paged_kv_cache", False),
             mla=multi_latent_attention)
     except ValueError as e:
         raise SystemExit(str(e))
+    if spec.quantized and getattr(args, "engine", "static") != "dynamic":
+        raise SystemExit(
+            f"--kv-cache-dtype {spec.name} requires --engine dynamic (the "
+            "per-block quantization scales live alongside its block pool; "
+            "the static engine's dense cache has no block structure)")
     # Fleet serving (ISSUE 14): parse-time validation in the usual
     # first-failed-predicate style — each impossible combination gets
     # its own actionable message.
@@ -363,11 +362,6 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
                 "--serve-fleet N>1 requires --engine dynamic (the "
                 "router drives replica step loops through the "
                 "continuous-batching driver)")
-        if not getattr(args, "paged_kv_cache", False):
-            raise SystemExit(
-                "--serve-fleet N>1 requires --paged-kv-cache (affinity "
-                "scoring rides the pool's rolling block hashes and "
-                "migration ships pool blocks)")
     if getattr(args, "fleet_migrate", False) and fleet < 2:
         raise SystemExit(
             "--fleet-migrate needs --serve-fleet >= 2 (live session "
@@ -401,11 +395,6 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
             raise SystemExit(
                 "--fleet-procs requires --engine dynamic (replica "
                 "workers serve DynamicInferenceEngine step loops)")
-        if not getattr(args, "paged_kv_cache", False):
-            raise SystemExit(
-                "--fleet-procs requires --paged-kv-cache (cross-"
-                "process migration ships pool blocks; affinity rides "
-                "the pool's rolling block hashes)")
     port = getattr(args, "replica_rpc_port", 0)
     if port and not procs:
         raise SystemExit(
@@ -430,10 +419,6 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
                 "--lora-dir requires --engine dynamic (the adapter "
                 "banks join the dynamic engine's decode scan; the "
                 "static engine has no per-row adapter plumbing)")
-        if not getattr(args, "paged_kv_cache", False):
-            raise SystemExit(
-                "--lora-dir requires --paged-kv-cache (the segmented "
-                "LoRA delta rides the paged decode/multi-query steps)")
         if multi_latent_attention:
             raise SystemExit(
                 "--lora-dir is incompatible with "
@@ -469,11 +454,6 @@ def validate_serving_args(args, multi_latent_attention: bool = False):
             raise SystemExit(
                 "--kv-spill-host-mb requires --engine dynamic (park/"
                 "unpark is the dynamic engine's slot machinery)")
-        if not getattr(args, "paged_kv_cache", False):
-            raise SystemExit(
-                "--kv-spill-host-mb requires --paged-kv-cache (the "
-                "spill tier parks pool blocks via export_slot/"
-                "import_slot)")
         if getattr(args, "serve_disagg", False):
             raise SystemExit(
                 "--kv-spill-host-mb does not compose with "

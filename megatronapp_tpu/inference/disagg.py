@@ -410,7 +410,7 @@ class DisaggServingEngine:
         self.engine = DynamicInferenceEngine(
             params, cfg, tokenizer=tokenizer, max_batch=max_batch,
             max_seq_len=max_seq_len, prefill_buckets=prefill_buckets,
-            paged=True, prefill_chunk=prefill_chunk,
+            prefill_chunk=prefill_chunk,
             spec_method=spec_method, spec_k=spec_k,
             draft_params=draft_params, draft_cfg=draft_cfg,
             ctx=self.decode_ctx, pool=pool)
@@ -455,10 +455,6 @@ class DisaggServingEngine:
     @property
     def slots(self):
         return self.engine.slots
-
-    @property
-    def paged(self) -> bool:
-        return True
 
     @property
     def has_work(self) -> bool:

@@ -1,4 +1,4 @@
-"""Dynamic inference engine: continuous batching over slot or paged KV.
+"""Dynamic inference engine: continuous batching over paged KV.
 
 Parity with /root/reference/megatron/core/inference/engines/dynamic_engine.py
 + contexts/dynamic_context.py + scheduler.py: requests of different lengths
@@ -6,36 +6,30 @@ enter a waiting queue; the engine admits them into free cache slots
 (prefill), decodes ONE token per step for every active slot, and retires
 finished requests — new requests join mid-flight without draining the batch.
 
-Two cache backends:
-
-- dense (default): the shared cache is [L, max_batch, S_max, Hkv, D] K/V
-  (MLA: the compressed latent + shared roped key pair) — every slot pays
-  for S_max regardless of actual length. Kept bit-exact as the parity
-  oracle for the paged backend.
-- ``paged=True``: KV lives in a shared block pool
-  [L, num_blocks, block_size, Hkv, D] with per-request page tables
-  (inference/paged_cache.py — vLLM-style): admission is by block
-  availability rather than whole slots, identical prompt prefixes are
-  served from the refcounted prefix cache instead of recomputed,
-  exhaustion preempts the lowest-priority running request back to the
-  waiting queue (it resumes by re-prefilling prompt+generated, usually
-  re-hitting its own cached blocks), and decode attends through the
-  ragged paged-attention Pallas kernel
-  (ops/pallas/paged_attention.py). A compiled step updates the pool IN
-  PLACE: the pools are donated, ride the layer loop as a carry, are
-  written row by row (kernel_gen.paged_append) and read through the
-  layer id, and stay row-major on the device (paged_cache.pool_format),
-  so the device holds one pool and a step touches the rows it appends
-  and the blocks it attends.
+One cache: KV lives in a shared block pool
+[L, num_blocks, block_size, Hkv, D] (MLA: the compressed latent + shared
+roped key pair) with per-request page tables (inference/paged_cache.py —
+vLLM-style): admission is by block availability rather than whole slots,
+identical prompt prefixes are served from the refcounted prefix cache
+instead of recomputed, exhaustion preempts the lowest-priority running
+request back to the waiting queue (it resumes by re-prefilling
+prompt+generated, usually re-hitting its own cached blocks), and decode
+attends through the ragged paged-attention Pallas kernel
+(ops/pallas/paged_attention.py). A compiled step updates the pool IN
+PLACE: the pools are donated, ride the layer loop as a carry, are written
+row by row (kernel_gen.paged_append) and read through the layer id, and
+stay row-major on the device (paged_cache.pool_format), so the device
+holds one pool and a step touches the rows it appends and the blocks it
+attends. The parity oracle is the static engine (inference/engine.py).
 
 TPU-first: all shapes static; the decode step is ONE jit for all slots
-(per-row rope positions + per-row masking), prefill runs through
-length-bucketed jits, and sampling is ONE batched on-device jit per step
+(per-row rope positions, per-row lengths in the kernel), prefill runs in
+calls of one width, and sampling is ONE batched on-device jit per step
 (per-request streams stay reproducible via fold_in key chains —
 PRNGKey(seed) ∘ request_id ∘ step — independent of batch composition).
 
 Speculative decoding (ISSUE 4, inference/speculative.py): with
-``spec_method`` set ("draft"/"mtp"/"ngram") on a paged engine, every
+``spec_method`` set ("draft"/"mtp"/"ngram"), every
 decode round proposes up to spec_k draft tokens per request, verifies
 them in ONE batched multi-query forward (`_paged_multiquery_step`, the
 unified prefill/decode primitive of arXiv 2604.15464), and exact
@@ -64,10 +58,10 @@ import numpy as np
 
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.inference.engine import (
-    SamplingParams, init_kv_cache, mask_padded_vocab,
+    SamplingParams, mask_padded_vocab,
 )
 from megatronapp_tpu.inference.paged_cache import (
-    HostSpillTier, PagedKVCache, cdiv, pool_format,
+    HostSpillTier, PagedKVCache, cdiv, check_tenants, pool_format,
 )
 from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
 from megatronapp_tpu.trace import scope_map
@@ -185,7 +179,7 @@ def validate_admission(prompt_tokens, max_new_tokens: int,
 class Request:
     """One generation request (reference inference_request.py analogue).
 
-    priority: lower = more important; the paged backend preempts the
+    priority: lower = more important; the engine preempts the
     highest (priority, request_id) running request when the block pool
     is exhausted.
 
@@ -228,48 +222,6 @@ class Request:
     def tokens(self) -> np.ndarray:
         return np.concatenate([self.prompt,
                                np.asarray(self.generated, np.int32)])
-
-
-def _decode_step(params, tokens, cache, lengths, active,
-                 cfg: TransformerConfig):
-    """One-token decode for every slot (dense backend).
-
-    tokens [B,1] (last token per slot), cache [L,B,Smax,...], lengths [B]
-    (tokens already in cache per slot), active [B] bool. Returns
-    (last_logits [B,V], new_cache)."""
-    b = tokens.shape[0]
-    max_len = cache[0].shape[2]
-    h = gpt_embed(params, tokens, cfg, position_ids=lengths[:, None])
-    cos_full, sin_full = gpt_rope_tables(cfg, max_len)
-    if cos_full is not None:
-        cos = jnp.take(cos_full, lengths, axis=0)[:, None]   # [B,1,half]
-        sin = jnp.take(sin_full, lengths, axis=0)[:, None]
-    else:
-        cos = sin = None
-
-    # Per-row causality: the new token (position lengths[b]) may attend
-    # cache positions <= lengths[b]; inactive rows are fully masked except
-    # self (keeps the softmax finite; results are discarded).
-    kv_pos = jnp.arange(max_len)
-    attend = kv_pos[None, :] <= lengths[:, None]             # [B,Smax]
-    mask = attend[:, None, None, :]                          # [B,1,1,Smax]
-
-    ck, cv = cache
-
-    def body(carry, layer_in):
-        hh = carry
-        layer_p, k_l, v_l, lid = layer_in
-        (hh, new_cache), _ = layer_forward(
-            layer_p, hh, cfg, cos, sin, mask, layer_id=lid,
-            kv_cache=(k_l, v_l), cache_index=None,
-            cache_positions=lengths)
-        return hh, new_cache
-
-    h, new_caches = jax.lax.scan(
-        body, h, (params["block"], ck, cv, jnp.arange(cfg.num_layers)),
-        unroll=cfg.scan_unroll)
-    logits = gpt_head(params, h, cfg)[:, -1]
-    return logits, new_caches
 
 
 def _moe_of(tree):
@@ -734,12 +686,14 @@ class DynamicInferenceEngine:
     request and admits waiting requests into free slots. Finished requests
     surface through the returned events and the optional token_callback.
 
-    paged=True switches to the block-pool backend (see module docstring):
-    block_size/num_blocks size the pool (num_blocks defaults to dense
-    capacity — pass less to run oversubscribed with preemption), and
-    enable_prefix_caching turns shared-prefix block reuse on/off.
+    block_size/num_blocks size the block pool (see module docstring;
+    num_blocks defaults to max_batch full sequences — pass less to run
+    oversubscribed with preemption), and enable_prefix_caching turns
+    shared-prefix block reuse on/off. `paged` is what is left of the dense
+    slot cache's switch: True, and False is refused (perfbench/ passes
+    True; ROADMAP D3 drops the argument with those three call sites).
 
-    spec_method ("draft"/"mtp"/"ngram", paged only) turns on speculative
+    spec_method ("draft"/"mtp"/"ngram") turns on speculative
     decoding with up to spec_k drafts per round (see module docstring);
     "draft" additionally needs draft_params/draft_cfg (a small model
     sharing the target vocab, e.g. from models/presets.py). When the
@@ -750,7 +704,7 @@ class DynamicInferenceEngine:
     def __init__(self, params, cfg: TransformerConfig, tokenizer=None,
                  max_batch: int = 4, max_seq_len: Optional[int] = None,
                  prefill_buckets: Tuple[int, ...] = (32, 128, 512),
-                 paged: bool = False, block_size: int = 16,
+                 paged: bool = True, block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  enable_prefix_caching: bool = True,
                  spec_method: Optional[str] = None, spec_k: int = 4,
@@ -760,6 +714,9 @@ class DynamicInferenceEngine:
                  adapter_cache=None,
                  spill_host_mb: float = 0.0,
                  spill_watermark_blocks: int = 0):
+        if not paged:
+            raise ValueError("paged=False: the dense slot cache went in PR "
+                             "44; this engine has the block pool alone")
         self.params = params
         self.cfg = cfg
         self.tokenizer = tokenizer
@@ -782,56 +739,26 @@ class DynamicInferenceEngine:
         # A model with state-space layers or gated short convolutions
         # (cfg.attn_layer_period) keeps, beside its attention layers'
         # pages, a recurrent state or a convolution's tail a slot
-        # (PagedKVCache.state). Whatever would move a request's cache
-        # between places needs a snapshot of that state, which does not
-        # exist yet: each refuses here or at its call, in words.
+        # (PagedKVCache.state); one with EVA attention (cfg.eva_window_size)
+        # one pooled row a chunk behind each closed window, in a second
+        # region of the slot's table; a shortcut-connected double layer owns
+        # two planes of the pools, and a layer that holds a share of the
+        # experts runs without its exchange. What each of them cannot do
+        # yet is paged_cache.TENANT_LACKS, and refuses here or at its call,
+        # in words.
         self.has_state = cfg.num_recurrent_layers > 0
         self.state_kind = ("conv" if cfg.num_conv_layers
                            else "ssm" if self.has_state else None)
-        if self.has_state:
-            refused = [name for name, on in (
-                ("paged=False (the dense cache)", not paged),
-                ("spec_method (speculative decoding rewinds rejected "
-                 "tokens)", spec_method and spec_method != "none"),
-                ("spill_host_mb (parking a session)", spill_host_mb),
-                ("adapter_cache (lora)", adapter_cache is not None),
-                ("an injected pool (disaggregated prefill)",
-                 pool is not None),
-                ("ctx (a serving mesh)", ctx is not None)) if on]
-            if refused:
-                raise ValueError(
-                    f"this model has {self._state_words()}, whose recurrent "
-                    "state lives in the paged engine's slots on one device "
-                    "and has no state snapshots yet (ROADMAP M4): cannot "
-                    "serve it with " + "; ".join(refused))
-            # A prefix hit skips tokens whose state nobody kept.
-            enable_prefix_caching = False
-        self.state_stats = {"resets": 0, "dropped": 0, "prefill_scans": 0}
-        # A model with EVA attention (cfg.eva_window_size) caches, behind
-        # each closed window, one pooled row a chunk in a second region of
-        # the slot's table (paged_cache.py). Whatever would share, move or
-        # rewind a slot's rows would have to do the same to its chunk
-        # summaries, which nothing does yet: each refuses here or at its
-        # call, in words.
         self.eva = cfg.is_eva
+        check_tenants(cfg, {cap: how for cap, how, on in (
+            ("rewind", "spec_method", spec_method and spec_method != "none"),
+            ("snapshot", "spill_host_mb", spill_host_mb),
+            ("adapters", "adapter_cache", adapter_cache is not None),
+            ("handoff", "an injected pool", pool is not None),
+            ("shard", "ctx", ctx is not None),
+            ("quantize", f"kv_cache_dtype {kv_cache_dtype!r}",
+             kv_cache_dtype != "bf16")) if on})
         if self.eva:
-            refused = [name for name, on in (
-                ("paged=False (the dense cache)", not paged),
-                ("spec_method (speculative decoding rewinds rejected "
-                 "tokens)", spec_method and spec_method != "none"),
-                ("spill_host_mb (parking a session)", spill_host_mb),
-                ("adapter_cache (lora)", adapter_cache is not None),
-                ("an injected pool (disaggregated prefill)",
-                 pool is not None),
-                ("ctx (a serving mesh)", ctx is not None),
-                (f"kv_cache_dtype {kv_cache_dtype!r} (a quantized pool)",
-                 kv_cache_dtype != "bf16")) if on]
-            if refused:
-                raise ValueError(
-                    "this model's attention is EVA, whose chunk summaries "
-                    "live in a second region of each slot's page table on "
-                    "one device and have no snapshots yet (ROADMAP M4): "
-                    "cannot serve it with " + "; ".join(refused))
             w, c = cfg.eva_window_size, cfg.eva_chunk_size
             if w % self.prefill_chunk or (self.prefill_chunk % c
                                           and c % self.prefill_chunk):
@@ -840,7 +767,7 @@ class DynamicInferenceEngine:
                     f"EVA window ({w}) and hold whole chunk summaries "
                     f"({c}) or lie inside one: a prefill call never "
                     "straddles a window's edge")
-            # (Prefix reuse: the pool turns it off on such a model.)
+        self.state_stats = {"resets": 0, "dropped": 0, "prefill_scans": 0}
         # Always-on counters of an EVA model's plain decode rounds
         # (stats_snapshot()["eva"]): the rows the paged kernel walked
         # (R(T) a slot, summed) against what full attention would have
@@ -849,48 +776,13 @@ class DynamicInferenceEngine:
         self.eva_stats = {"decode_rounds": 0, "rows_walked": 0,
                           "rows_full_attention": 0,
                           "summary_rows_written": 0}
-
-        # A shortcut-connected double layer owns two planes of the paged
-        # pools, and a layer that holds a share of the experts runs
-        # without its exchange: what would need more refuses in words.
-        if cfg.moe_shortcut_double_layer or cfg.moe_experts_held is not None:
-            refused = [name for name, on in (
-                ("paged=False (the dense cache holds one plane a layer)",
-                 cfg.moe_shortcut_double_layer and not paged),
-                ("adapter_cache (lora on latent attention and experts)",
-                 adapter_cache is not None),
-                ("ctx (a serving mesh: the all-to-all between expert shares "
-                 "and a latent pool sharded under two sublayers)",
-                 ctx is not None),
-                ("an injected pool (disaggregated prefill fills a dense "
-                 "one-plane cache)", pool is not None),
-                (f"kv_cache_dtype {kv_cache_dtype!r} (int8/fp8 latent pools "
-                 "under the scaled latent)", kv_cache_dtype != "bf16"),
-                ) if on]
-            if refused:
-                raise ValueError(
-                    "this model runs double layers over two planes of the "
-                    "paged pools and holds a share of its experts on one "
-                    "device (ROADMAP M3, M6): cannot serve it with "
-                    + "; ".join(refused))
-        self.paged = paged
-        if paged:
-            # An injected pool (disagg) carries its own kv_cache_dtype.
-            self.pool = pool if pool is not None else PagedKVCache(
-                cfg, max_batch, self.max_seq_len, num_blocks=num_blocks,
-                block_size=block_size,
-                enable_prefix_caching=enable_prefix_caching,
-                kv_cache_dtype=kv_cache_dtype)
-            self.cache = None
-        else:
-            assert pool is None, "pool injection requires paged=True"
-            from megatronapp_tpu.inference.paged_cache import (
-                validate_kv_cache_dtype,
-            )
-            validate_kv_cache_dtype(kv_cache_dtype, paged=False,
-                                    mla=cfg.multi_latent_attention)
-            self.pool = None
-            self.cache = init_kv_cache(cfg, max_batch, self.max_seq_len)
+        # An injected pool (disagg) carries its own kv_cache_dtype; prefix
+        # reuse is the pool's to switch off on a model that cannot share.
+        self.pool = pool if pool is not None else PagedKVCache(
+            cfg, max_batch, self.max_seq_len, num_blocks=num_blocks,
+            block_size=block_size,
+            enable_prefix_caching=enable_prefix_caching,
+            kv_cache_dtype=kv_cache_dtype)
 
         # TP serving mesh (ISSUE 9): with a MeshContext whose tp > 1 and
         # a tp-eligible paged config, params replicate over the mesh and
@@ -906,53 +798,48 @@ class DynamicInferenceEngine:
             # design here.
             self._params_sharding = NamedSharding(ctx.mesh, P())
             self.params = jax.device_put(params, self._params_sharding)  # manual-ok: see above
-            if paged:
-                from megatronapp_tpu.config.parallel_config import TP_AXIS
-                from megatronapp_tpu.ops.pallas.paged_attention import (
-                    tp_paged_ineligible_reason,
-                )
-                reason = tp_paged_ineligible_reason(cfg, ctx)
-                self.tp_paged = reason is None
-                if not self.tp_paged and ctx.tp > 1:
-                    # Name the SPECIFIC failed predicate instead of a
-                    # generic ineligible-fallback line (ISSUE 11
-                    # satellite).
-                    logger.warning(
-                        "paged kernels stay single-device on a tp=%d "
-                        "mesh: %s", ctx.tp, reason)
-                # Pages [L, NB, bs, Hkv, D]: shard Hkv when eligible so
-                # each device holds 1/tp of the pool; otherwise just
-                # commit them to this mesh (disagg decode sub-mesh). An
-                # int8 pool's scale pools [L, NB, bs, Hkv] shard on the
-                # same Hkv dim (their last). MLA pools are rank-4 with
-                # no head axis — the latent pool [L, NB, bs, klat]
-                # shards on its COLUMN dim (kernel_gen._tp_place_latent
-                # contracts per-shard columns and psums the logits), the
-                # tiny pe pool and the per-row scalar scale pools
-                # replicate.
-                if not self.tp_paged:
-                    pages_spec = scales_spec = P()
-                elif cfg.multi_latent_attention:
-                    pages_spec = [P(None, None, None, TP_AXIS), P()]
-                    scales_spec = P()
-                else:
-                    pages_spec = P(None, None, None, TP_AXIS, None)
-                    scales_spec = P(None, None, None, TP_AXIS)
-
-                def _sh(spec):
-                    if isinstance(spec, list):
-                        # manual-ok: constructor-time placement, no manual region
-                        return [NamedSharding(ctx.mesh, s) for s in spec]
-                    return NamedSharding(ctx.mesh, spec)  # manual-ok: see above
-
-                # manual-ok: constructor-time placement, no manual region
-                self.pool.place_pages(
-                    _sh(pages_spec),    # manual-ok: see above
-                    _sh(scales_spec))   # manual-ok: see above
+            from megatronapp_tpu.config.parallel_config import TP_AXIS
+            from megatronapp_tpu.ops.pallas.paged_attention import (
+                tp_paged_ineligible_reason,
+            )
+            reason = tp_paged_ineligible_reason(cfg, ctx)
+            self.tp_paged = reason is None
+            if not self.tp_paged and ctx.tp > 1:
+                # Name the SPECIFIC failed predicate instead of a
+                # generic ineligible-fallback line (ISSUE 11
+                # satellite).
+                logger.warning(
+                    "paged kernels stay single-device on a tp=%d "
+                    "mesh: %s", ctx.tp, reason)
+            # Pages [L, NB, bs, Hkv, D]: shard Hkv when eligible so
+            # each device holds 1/tp of the pool; otherwise just
+            # commit them to this mesh (disagg decode sub-mesh). An
+            # int8 pool's scale pools [L, NB, bs, Hkv] shard on the
+            # same Hkv dim (their last). MLA pools are rank-4 with
+            # no head axis — the latent pool [L, NB, bs, klat]
+            # shards on its COLUMN dim (kernel_gen._tp_place_latent
+            # contracts per-shard columns and psums the logits), the
+            # tiny pe pool and the per-row scalar scale pools
+            # replicate.
+            if not self.tp_paged:
+                pages_spec = scales_spec = P()
+            elif cfg.multi_latent_attention:
+                pages_spec = [P(None, None, None, TP_AXIS), P()]
+                scales_spec = P()
             else:
-                # manual-ok: constructor-time placement, no manual region
-                self.cache = jax.device_put(self.cache,
-                                            self._params_sharding)
+                pages_spec = P(None, None, None, TP_AXIS, None)
+                scales_spec = P(None, None, None, TP_AXIS)
+
+            def _sh(spec):
+                if isinstance(spec, list):
+                    # manual-ok: constructor-time placement, no manual region
+                    return [NamedSharding(ctx.mesh, s) for s in spec]
+                return NamedSharding(ctx.mesh, spec)  # manual-ok: see above
+
+            # manual-ok: constructor-time placement, no manual region
+            self.pool.place_pages(
+                _sh(pages_spec),    # manual-ok: see above
+                _sh(scales_spec))   # manual-ok: see above
         else:
             self._params_sharding = None
         # Telemetry (ISSUE 12): per-request lifecycle spans go to the
@@ -977,10 +864,6 @@ class DynamicInferenceEngine:
         # the slot lifecycle: _admit acquires, _free_slot releases — an
         # in-use adapter can never be evicted.
         self.adapters = adapter_cache
-        if adapter_cache is not None and not paged:
-            raise ValueError(
-                "adapter_cache requires the paged backend (batched LoRA "
-                "serves over the paged decode step) — pass paged=True")
         self.row_adapter = np.zeros((max_batch,), np.int32)
         # Optional lora.TenantSLO: the serving driver composes each
         # submit's (priority, deadline) through it when set.
@@ -1009,11 +892,6 @@ class DynamicInferenceEngine:
         self.spill: Optional[HostSpillTier] = None
         self.spill_watermark = int(spill_watermark_blocks)
         if spill_host_mb:
-            if not paged:
-                raise ValueError(
-                    "spill_host_mb requires the paged backend (the "
-                    "spill tier parks pool blocks) — pass paged=True / "
-                    "--paged-kv-cache")
             self.spill = HostSpillTier(int(spill_host_mb * (1 << 20)))
         elif spill_watermark_blocks:
             raise ValueError(
@@ -1062,18 +940,13 @@ class DynamicInferenceEngine:
         # Always-on counters of the paged kernels' walk over plain decode
         # rounds (stats_snapshot()["paged"]): the blocks the running slots
         # hold against running slots x max_blocks_per_seq.
-        self.paged_stats = {"decode_rounds": 0, "blocks_live": 0,
-                            "blocks_table": 0}
+        self.walk_stats = {"decode_rounds": 0, "blocks_live": 0,
+                           "blocks_table": 0}
         # Pre-head hidden state at each slot's last verified position —
         # feeds the MTP self-draft proposer.
         self._h_last = np.zeros((max_batch, cfg.hidden_size), np.float32)
         self._h_valid = np.zeros((max_batch,), bool)
         if spec_method and spec_method != "none":
-            if not paged:
-                raise ValueError(
-                    "speculative decoding runs over the paged-KV engine "
-                    "(multi-token append + rollback need the block pool) "
-                    "— pass paged=True")
             from megatronapp_tpu.inference.speculative import make_proposer
             self.proposer = make_proposer(spec_method, self,
                                           draft_params=draft_params,
@@ -1109,7 +982,7 @@ class DynamicInferenceEngine:
 
     def startup_line(self) -> str:
         """What this engine runs, for the log and a server's banner."""
-        line = (f"dynamic engine: paged={self.paged}, max_batch="
+        line = (f"dynamic engine: paged=True, max_batch="
                 f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
                 f"prefill_chunk={self.prefill_chunk}")
         cfg = self.cfg
@@ -1154,9 +1027,6 @@ class DynamicInferenceEngine:
 
     def _build_jits(self):
         cfg = self.cfg
-        import functools
-
-        from megatronapp_tpu.inference.engine import _forward_with_cache
         counts = self._trace_counts
 
         @contextlib.contextmanager
@@ -1169,77 +1039,63 @@ class DynamicInferenceEngine:
             finally:
                 counts.update(was)
 
-        self._prefill = scope_map.noted(
-            jax.jit(functools.partial(_forward_with_cache, cfg=cfg)),
-            kind="prefill")
         # A module of its own and one part as a whole: its instructions
         # name none.
         self._sample_b = scope_map.noted(
             jax.jit(_sample_batched), kind="sampler",
             default_part="sampler")
         self._dispatch_stats = None
-        if self.paged:
-            msl = self.max_seq_len
-            # ctx rides into the step only on a tp-paged mesh (it then
-            # dispatches the head-sharded kernel placement inside
-            # attention_forward); otherwise the trace stays identical to
-            # the single-device engine.
-            step_ctx = self.ctx if self.tp_paged else None
-            # The pools (`pages`, and `scales`: the int8 pool's fp32
-            # scale-pool pair, None for bf16 pools — an empty pytree, so
-            # the same signature serves both dtypes; a model with
-            # state-space layers: its state pools behind the two page
-            # pools, self._pools()) are DONATED, and the
-            # layer loop carries them and writes them in place: a step's
-            # output pools are its input buffers, and the device holds
-            # one pool (_PoolStep pins their layout too). `lora` follows
-            # the None trick: None without an adapter cache, else
-            # {"row_adapter", "banks"} (the banks are NOT donated — they
-            # are the cache's resident HBM arrays and outlive the
-            # step).
-            def _decode_traced(p, t, pages, scales, tbl, l, a, lora):
-                # Python side-effect: runs only while TRACING.
-                counts["decode"] += 1
-                return _paged_decode_step(p, t, pages, tbl, l, a, cfg,
-                                          msl, ctx=step_ctx,
-                                          scales=scales, lora=lora)
+        msl = self.max_seq_len
+        # ctx rides into the step only on a tp-paged mesh (it then
+        # dispatches the head-sharded kernel placement inside
+        # attention_forward); otherwise the trace stays identical to
+        # the single-device engine.
+        step_ctx = self.ctx if self.tp_paged else None
+        # The pools (`pages`, and `scales`: the int8 pool's fp32
+        # scale-pool pair, None for bf16 pools — an empty pytree, so
+        # the same signature serves both dtypes; a model with
+        # state-space layers: its state pools behind the two page
+        # pools, self._pools()) are DONATED, and the
+        # layer loop carries them and writes them in place: a step's
+        # output pools are its input buffers, and the device holds
+        # one pool (_PoolStep pins their layout too). `lora` follows
+        # the None trick: None without an adapter cache, else
+        # {"row_adapter", "banks"} (the banks are NOT donated — they
+        # are the cache's resident HBM arrays and outlive the
+        # step).
+        def _decode_traced(p, t, pages, scales, tbl, l, a, lora):
+            # Python side-effect: runs only while TRACING.
+            counts["decode"] += 1
+            return _paged_decode_step(p, t, pages, tbl, l, a, cfg,
+                                      msl, ctx=step_ctx,
+                                      scales=scales, lora=lora)
 
-            self._decode = _PoolStep(_decode_traced, n_lead=2,
-                                     kind="decode", guard=counts_kept)
+        self._decode = _PoolStep(_decode_traced, n_lead=2,
+                                 kind="decode", guard=counts_kept)
 
-            def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
-                           lora, rows=None, last=None):
-                # Python side-effect: runs only while TRACING.
-                counts["mq"] += 1
-                return _paged_multiquery_step(p, t, pages, tbl, starts,
-                                              qlens, act, cfg, msl,
-                                              ctx=step_ctx, scales=scales,
-                                              lora=lora, rows=rows,
-                                              last=last)
+        def _mq_traced(p, t, pages, scales, tbl, starts, qlens, act,
+                       lora, rows=None, last=None):
+            # Python side-effect: runs only while TRACING.
+            counts["mq"] += 1
+            return _paged_multiquery_step(p, t, pages, tbl, starts,
+                                          qlens, act, cfg, msl,
+                                          ctx=step_ctx, scales=scales,
+                                          lora=lora, rows=rows,
+                                          last=last)
 
-            self._mq_step = _PoolStep(_mq_traced, n_lead=2,
-                                      kind="prefill", guard=counts_kept)
-            if self.spec_method:
-                from megatronapp_tpu.inference.speculative import (
-                    build_verify_sampler,
-                )
-                self._verify_sample = build_verify_sampler(
-                    point_mass=self.proposer.point_mass)
-                self.proposer.reset_compilation()
-        else:
-            def _decode_traced_dense(p, t, c, l, a):
-                counts["decode"] += 1
-                return _decode_step(p, t, c, l, a, cfg)
-
-            self._decode = scope_map.noted(
-                jax.jit(_decode_traced_dense), kind="decode",
-                guard=counts_kept)
+        self._mq_step = _PoolStep(_mq_traced, n_lead=2,
+                                  kind="prefill", guard=counts_kept)
+        if self.spec_method:
+            from megatronapp_tpu.inference.speculative import (
+                build_verify_sampler,
+            )
+            self._verify_sample = build_verify_sampler(
+                point_mass=self.proposer.point_mass)
+            self.proposer.reset_compilation()
 
     def reset_compilation(self):
         """Re-trace on next call (after MegaScope hook toggles — see
-        StaticInferenceEngine.reset_compilation). Rebuilds the paged
-        decode/scatter/gather jits too, so toggled capture hooks cannot
-        pin stale traces in the paged backend."""
+        StaticInferenceEngine.reset_compilation)."""
         self._build_jits()
 
     def _pools(self):
@@ -1317,8 +1173,7 @@ class DynamicInferenceEngine:
                     adapter_id: Optional[str] = None,
                     tenant: Optional[str] = None) -> int:
         prompt = validate_admission(prompt_tokens, max_new_tokens,
-                                    self.max_seq_len,
-                                    pool=self.pool if self.paged else None,
+                                    self.max_seq_len, pool=self.pool,
                                     deadline_s=deadline_s)
         # Unknown adapters are a PERMANENT submit-time error (the
         # registry names what it knows) — transient all-slots-pinned
@@ -1483,12 +1338,11 @@ class DynamicInferenceEngine:
         for slot, req in enumerate(self.slots):
             if req is None:
                 continue
-            if self.paged:
-                try:
-                    self.pool.release(slot, np.asarray(req.tokens),
-                                      int(self.lengths[slot]))
-                except Exception:  # noqa: BLE001 — best-effort reclaim
-                    pass
+            try:
+                self.pool.release(slot, np.asarray(req.tokens),
+                                  int(self.lengths[slot]))
+            except Exception:  # noqa: BLE001 — best-effort reclaim
+                pass
             self._free_slot(slot)
             self.requests.pop(req.request_id, None)
             self._rt.finish(req.request_id, "abort")
@@ -1497,19 +1351,6 @@ class DynamicInferenceEngine:
             self._drop_parked(rid)
             self.requests.pop(req.request_id, None)
             self._rt.finish(req.request_id, "abort")
-
-    def _refuse_on_state(self, what: str):
-        if self.eva:
-            raise ValueError(
-                f"{what}: this model's cache keeps EVA chunk summaries in a "
-                "second region of each slot's page table, and moving a "
-                "request needs snapshots of them, which do not exist yet "
-                "(ROADMAP M4)")
-        if self.has_state:
-            raise ValueError(
-                f"{what}: this model's {self._state_words()} keep a "
-                "recurrent state a slot, and moving a request needs state "
-                "snapshots, which do not exist yet (ROADMAP M4)")
 
     def _free_slot(self, slot: int):
         """Clear every per-slot engine resource (request ref, length,
@@ -1545,8 +1386,7 @@ class DynamicInferenceEngine:
             # manual-ok: host-side reload path, no manual region
             params = jax.device_put(params, self._params_sharding)
         self.params = params
-        if self.pool is not None:
-            self.pool.flush_prefix_cache()
+        self.pool.flush_prefix_cache()
 
     def free_decode_slots(self) -> int:
         return sum(1 for r in self.slots if r is None)
@@ -1565,8 +1405,7 @@ class DynamicInferenceEngine:
         prompt KV rows written by prefill; the first generated token was
         already sampled prefill-side with the identical fold_in chain).
         Returns the decode slot."""
-        assert self.paged, "adoption requires the paged backend"
-        self._refuse_on_state("adopt_request")
+        check_tenants(self.cfg, {"handoff": "adopt_request"})
         slot = next(i for i in range(self.max_batch)
                     if self.slots[i] is None)
         if self.adapters is not None:
@@ -1599,8 +1438,7 @@ class DynamicInferenceEngine:
         requeue instead. Nothing is mutated here: the source rolls
         nothing back if the migration dies between export and import
         (the "fleet-migrate" chaos site)."""
-        assert self.paged, "session export requires the paged backend"
-        self._refuse_on_state("export_request")
+        check_tenants(self.cfg, {"snapshot": "export_request"})
         req = self.requests.get(rid)
         if req is not None and not req.finished and rid in self._parked:
             # A PARKED session migrates too (a drained/reloading replica
@@ -1625,8 +1463,7 @@ class DynamicInferenceEngine:
         cannot host the rows. The MTP proposer's pre-head hidden is not
         shipped (proposal-quality-only, same note as the disagg adopt
         path); ngram/draft proposers are unaffected."""
-        assert self.paged, "session import requires the paged backend"
-        self._refuse_on_state("import_request")
+        check_tenants(self.cfg, {"snapshot": "import_request"})
         req: Request = payload["req"]
         slot = next((i for i in range(self.max_batch)
                      if self.slots[i] is None), None)
@@ -1893,23 +1730,21 @@ class DynamicInferenceEngine:
             if req.finished:          # aborted while queued (racy path)
                 self._aborted.append(req)
                 continue
-            plan = None
-            if self.paged:
-                # Admission by block availability: if the pool cannot
-                # host this prompt now, keep FIFO order and wait for
-                # retirements/preemptions to free blocks.
-                plan = self.pool.admit(slot, req.tokens)
-                if plan is None and self.spill is not None:
-                    # Pressure path, spill preferred over waiting: park
-                    # idle-priority sessions (KV kept byte-exact in host
-                    # RAM) until the prompt fits — this is what lifts
-                    # concurrent sessions-at-budget past the HBM block
-                    # count.
-                    while plan is None and self._park_for_pressure():
-                        plan = self.pool.admit(slot, req.tokens)
-                if plan is None:
-                    self.waiting.appendleft(req)
-                    break
+            # Admission by block availability: if the pool cannot
+            # host this prompt now, keep FIFO order and wait for
+            # retirements/preemptions to free blocks.
+            plan = self.pool.admit(slot, req.tokens)
+            if plan is None and self.spill is not None:
+                # Pressure path, spill preferred over waiting: park
+                # idle-priority sessions (KV kept byte-exact in host
+                # RAM) until the prompt fits — this is what lifts
+                # concurrent sessions-at-budget past the HBM block
+                # count.
+                while plan is None and self._park_for_pressure():
+                    plan = self.pool.admit(slot, req.tokens)
+            if plan is None:
+                self.waiting.appendleft(req)
+                break
             if self.adapters is not None:
                 from megatronapp_tpu.inference.lora import (
                     AdapterSlotsPinned)
@@ -1920,8 +1755,7 @@ class DynamicInferenceEngine:
                     # requests — a transient capacity condition exactly
                     # like pool-full admit: keep FIFO order and wait for
                     # a retirement to unpin one.
-                    if self.paged:
-                        self.pool.release(slot, np.asarray(req.tokens), 0)
+                    self.pool.release(slot, np.asarray(req.tokens), 0)
                     self.waiting.appendleft(req)
                     break
                 except Exception:
@@ -1929,8 +1763,7 @@ class DynamicInferenceEngine:
                     # cache mutated nothing — release the admitted
                     # blocks, requeue at the head, re-raise for the
                     # stepper watchdog. The retry costs one step.
-                    if self.paged:
-                        self.pool.release(slot, np.asarray(req.tokens), 0)
+                    self.pool.release(slot, np.asarray(req.tokens), 0)
                     req.queued_t = time.monotonic()
                     self.waiting.appendleft(req)
                     raise
@@ -1945,10 +1778,10 @@ class DynamicInferenceEngine:
             self.step_stats.add("queue_wait", waited)
             telemetry.observe("serving_queue_wait_ms", waited * 1e3)
             p_len = len(req.tokens)
-            cached = plan.cached_tokens if plan is not None else 0
             try:
                 with self._span("engine.prefill", rid, ring="prefill",
-                                prompt_tokens=p_len, cached_tokens=cached):
+                                prompt_tokens=p_len,
+                                cached_tokens=plan.cached_tokens):
                     self._prefill_into_slot(req, plan)
             except Exception:
                 # Exception-safe rollback (the "kv-quant-write" chaos
@@ -1959,8 +1792,7 @@ class DynamicInferenceEngine:
                 # clear the slot, and requeue the request at the head so
                 # a transient fault costs one step. Re-raised for the
                 # stepper watchdog's accounting.
-                if self.paged:
-                    self.pool.release(slot, np.asarray(req.tokens), 0)
+                self.pool.release(slot, np.asarray(req.tokens), 0)
                 self._free_slot(slot)
                 req.slot = -1
                 req.queued_t = time.monotonic()
@@ -1978,42 +1810,20 @@ class DynamicInferenceEngine:
             admitted.append(req)
         return admitted
 
-    def _prefill_into_slot(self, req: Request, plan=None):
+    def _prefill_into_slot(self, req: Request, plan):
         # req.tokens (prompt + any pre-preemption generated tokens): a
         # resumed request re-prefills its full history and samples the
         # NEXT token, exactly like a fresh admission.
         tokens = req.tokens
         p_len = len(tokens)
-        if self.paged:
-            # Chunked prefill through the unified multi-query step: ONE
-            # trace per chunk shape instead of one per
-            # (bucket, cached-length) pair, and prefix-cache hits are
-            # attended directly through the page table (no dense gather;
-            # MLA rides the same path since ISSUE 17 — the latent kernel
-            # handles the ragged chunk, and quantized latent rows
-            # quantize inside the same _mq_step jit).
-            logits_last = self._paged_prefill_chunked(req, tokens, p_len,
-                                                      plan)
-        else:
-            bucket = next((b for b in self.prefill_buckets if b >= p_len),
-                          self.max_seq_len)
-            if bucket < p_len:
-                raise AssertionError(
-                    f"no prefill bucket covers length {p_len} (buckets "
-                    f"{self.prefill_buckets}, max_seq_len "
-                    f"{self.max_seq_len})")
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :p_len] = tokens
-            with self._span("engine.prefill_call", tokens=p_len):
-                tmp_cache = init_kv_cache(self.cfg, 1, bucket)
-                logits, tmp_cache = self._prefill(
-                    self.params, jnp.asarray(padded), tmp_cache, 0)
-                # Scatter the kv rows into this slot of the shared cache.
-                slot = req.slot
-                self.cache = tuple(
-                    c.at[:, slot, :bucket].set(t[:, 0]) for c, t in
-                    zip(self.cache, tmp_cache))
-            logits_last = logits[0, p_len - 1]
+        # Chunked prefill through the unified multi-query step: ONE
+        # trace per chunk shape instead of one per
+        # (bucket, cached-length) pair, and prefix-cache hits are
+        # attended directly through the page table (no dense gather;
+        # MLA rides the same path since ISSUE 17 — the latent kernel
+        # handles the ragged chunk, and quantized latent rows
+        # quantize inside the same _mq_step jit).
+        logits_last = self._paged_prefill_chunked(req, tokens, p_len, plan)
         self.lengths[req.slot] = p_len
         # First generated token comes from the last PROMPT position.
         logits_last = mask_padded_vocab(logits_last, self.cfg)
@@ -2030,7 +1840,6 @@ class DynamicInferenceEngine:
         compiler sees ONE program for every (prompt length, cached
         length) combination. Returns the last prompt position's logits
         [V] and records the pre-head hidden for the MTP proposer."""
-        assert plan is not None
         slot = req.slot
         pool = self.pool
         cached = plan.cached_tokens
@@ -2176,7 +1985,7 @@ class DynamicInferenceEngine:
                 len(req.generated) >= req.max_new_tokens):
             req.finished = True
 
-    # ---- paged-backend pressure handling ---------------------------------
+    # ---- pool pressure handling ------------------------------------------
     def _preempt(self, req: Request, out: List[Request]):
         """Push a running request back to the waiting queue, releasing its
         blocks (full blocks stay prefix-cached while evictable, so the
@@ -2233,12 +2042,11 @@ class DynamicInferenceEngine:
         for slot, req in enumerate(self.slots):
             if req is not None and req.finished:
                 done.append(req)
-                if self.paged:
-                    # The cache holds tokens[:-1] (the final sampled
-                    # token's KV was never written) — register/release
-                    # only the written rows.
-                    self.pool.release(slot, np.asarray(req.tokens),
-                                      int(self.lengths[slot]))
+                # The cache holds tokens[:-1] (the final sampled
+                # token's KV was never written) — register/release
+                # only the written rows.
+                self.pool.release(slot, np.asarray(req.tokens),
+                                  int(self.lengths[slot]))
                 self._free_slot(slot)
                 telemetry.inc("serving_requests_retired")
                 self._tenant_inc(req.tenant, "finished")
@@ -2277,10 +2085,9 @@ class DynamicInferenceEngine:
                              for r in admitted],
                   "finished": [], "preempted": [], "expired": expired}
 
-        if self.paged:
-            with self._span("engine.capacity"):
-                preempted = self._ensure_decode_capacity()
-            events["preempted"] = [r.request_id for r in preempted]
+        with self._span("engine.capacity"):
+            preempted = self._ensure_decode_capacity()
+        events["preempted"] = [r.request_id for r in preempted]
 
         active = [r for r in self.slots
                   if r is not None and not r.finished]
@@ -2312,32 +2119,31 @@ class DynamicInferenceEngine:
         """One-token decode for every active slot (non-speculative)."""
         lens = self.lengths[[r.slot for r in active]]
         attrs = {"kv_tokens": int(lens.sum())}
-        if self.paged:
-            # the blocks this round's paged kernel walks (it reads the
-            # row the round appends too), of those the table could name
-            bs = self.pool.block_size
-            rows = lens
-            if self.eva:
-                # kv_rows: what the kernel walks, R(T) a slot, the rows of
-                # its closed windows' summaries (summary_rows) among them.
-                cfg, st = self.cfg, self.eva_stats
-                rows = table_rows(cfg, lens)
-                attrs["kv_rows"] = int((rows + 1).sum())
-                attrs["summary_rows"] = int(
-                    (lens // cfg.eva_window_size).sum()
-                    * (cfg.eva_window_size // cfg.eva_chunk_size))
-                st["decode_rounds"] += 1
-                st["rows_walked"] += attrs["kv_rows"]
-                st["rows_full_attention"] += int((lens + 1).sum())
-                # summaries: the chunks this round fills, and so pools
-                attrs["summaries"] = int(
-                    ((lens + 1) % cfg.eva_chunk_size == 0).sum())
-                st["summary_rows_written"] += attrs["summaries"]
-            attrs["kv_blocks"] = int((rows // bs + 1).sum())
-            self.paged_stats["decode_rounds"] += 1
-            self.paged_stats["blocks_live"] += attrs["kv_blocks"]
-            self.paged_stats["blocks_table"] += (
-                len(active) * self.pool.page_table.shape[1])
+        # the blocks this round's paged kernel walks (it reads the
+        # row the round appends too), of those the table could name
+        bs = self.pool.block_size
+        rows = lens
+        if self.eva:
+            # kv_rows: what the kernel walks, R(T) a slot, the rows of
+            # its closed windows' summaries (summary_rows) among them.
+            cfg, st = self.cfg, self.eva_stats
+            rows = table_rows(cfg, lens)
+            attrs["kv_rows"] = int((rows + 1).sum())
+            attrs["summary_rows"] = int(
+                (lens // cfg.eva_window_size).sum()
+                * (cfg.eva_window_size // cfg.eva_chunk_size))
+            st["decode_rounds"] += 1
+            st["rows_walked"] += attrs["kv_rows"]
+            st["rows_full_attention"] += int((lens + 1).sum())
+            # summaries: the chunks this round fills, and so pools
+            attrs["summaries"] = int(
+                ((lens + 1) % cfg.eva_chunk_size == 0).sum())
+            st["summary_rows_written"] += attrs["summaries"]
+        attrs["kv_blocks"] = int((rows // bs + 1).sum())
+        self.walk_stats["decode_rounds"] += 1
+        self.walk_stats["blocks_live"] += attrs["kv_blocks"]
+        self.walk_stats["blocks_table"] += (
+            len(active) * self.pool.page_table.shape[1])
         with self._span("engine.decode_round", ring="decode-step",
                         batch=len(active), **attrs):
             self._plain_round_inner(active, events)
@@ -2349,18 +2155,12 @@ class DynamicInferenceEngine:
                  for i in range(self.max_batch)])
             active_mask = jnp.asarray(active_np)
             lengths = _handed_over(self.lengths)
-            moe = None
-            if self.paged:
-                logits, moe, new = self._decode(
-                    self.params, _handed_over(self.last_tokens),
-                    self._pools(), self.pool.scales,
-                    _handed_over(self.pool.page_table[:self.max_batch]),
-                    lengths, active_mask, self._lora_args())
-                self._commit_pools(new)
-            else:
-                logits, self.cache = self._decode(
-                    self.params, _handed_over(self.last_tokens), self.cache,
-                    lengths, active_mask)
+            logits, moe, new = self._decode(
+                self.params, _handed_over(self.last_tokens),
+                self._pools(), self.pool.scales,
+                _handed_over(self.pool.page_table[:self.max_batch]),
+                lengths, active_mask, self._lora_args())
+            self._commit_pools(new)
             # The decode wrote each active row's kv at lengths[slot].
             self.lengths += active_np.astype(np.int32)
             logits = mask_padded_vocab(logits, self.cfg)
@@ -2542,7 +2342,7 @@ class DynamicInferenceEngine:
         return results
 
     # ---- observability ----------------------------------------------------
-    def dispatch_stats(self, force: bool = False) -> Optional[Dict]:
+    def dispatch_stats(self, force: bool = False) -> Dict:
         """Launch counts of the traced decode step at the engine's
         shapes (utils/dispatch.launch_stats): `kernels` is its
         pallas_calls a step, scan bodies times their length;
@@ -2552,8 +2352,6 @@ class DynamicInferenceEngine:
         per jit build; nothing is compiled."""
         if self._dispatch_stats is not None and not force:
             return self._dispatch_stats
-        if not self.paged:
-            return None
         from megatronapp_tpu.utils.dispatch import launch_stats
         spec = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
             a.shape, a.dtype)
@@ -2587,9 +2385,9 @@ class DynamicInferenceEngine:
         """JSON-ready serving stats (the server's GET /stats payload):
         pool occupancy, prefix-cache hit rate, speculative acceptance,
         active batch size — serving is observable without log scraping.
-        On a paged engine "paged" holds the walk's counters over plain
-        decode rounds: blocks_live of blocks_table is the share of the
-        page table's width that held rows (False on a dense-cache one).
+        "paged" holds the walk's counters over plain decode rounds:
+        blocks_live of blocks_table is the share of the page table's
+        width that held rows.
         "state" is a dict on a model with state-space layers or gated
         short convolutions (False otherwise): `kind` ("ssm" or "conv"),
         `layers` and `slots` of recurrent state at `bytes_per_slot`,
@@ -2600,11 +2398,10 @@ class DynamicInferenceEngine:
         plain decode rounds and by prefills' first samples: `*_greedy`
         (argmax alone), `*_sampled` (a categorical, the vocabulary not
         ordered), `*_ordered` (a sort ran for some row's top-k or top-p).
-        "prefill" is a dict on a paged engine (False on a dense-cache
-        one): the `width` of a prefill call (`prefill_chunk`: given, or
-        chosen from the shapes), the `calls` made, the prompt `tokens` they
-        ran, and `fill_share` = tokens / (calls x width), the share of the
-        calls' rows that were prompt and not padding.
+        "prefill": the `width` of a prefill call (`prefill_chunk`: given,
+        or chosen from the shapes), the `calls` made, the prompt `tokens`
+        they ran, and `fill_share` = tokens / (calls x width), the share of
+        the calls' rows that were prompt and not padding.
         "eva" is a dict on a model with EVA attention (False otherwise):
         `layers`, `window`, `chunk`; `windows_closed` and the `blocks_freed`
         by them; `summary_rows_written` (chunks pooled, a chunk counted
@@ -2626,9 +2423,21 @@ class DynamicInferenceEngine:
         include_dispatch=True adds the traced decode step's launch
         counts (dispatch_stats; the first call traces the step once and
         compiles nothing — /stats opts in, /healthz stays free of it)."""
+        pool = self.pool
+        st = dict(pool.stats)
+        seen = st["prefix_hit_tokens"] + st["prefill_tokens"]
+        rows = self.prefill_stats["calls"] * self.prefill_chunk
+        # Byte accounting reads the ADDRESSABLE pool arrays (int8
+        # data + fp32 scales for quantized pools), never a dtype
+        # assumption — /stats and /healthz stay honest when the pool
+        # dtype differs from the param dtype. resident_bytes counts
+        # blocks whose data is live (in use + LRU-parked, still
+        # hittable); pool_bytes_total is the full allocation.
+        bpb = pool.bytes_per_block
+        resident_blocks = pool.num_blocks - pool.free_blocks()
         out = {
             "engine": "dynamic",
-            "paged": self.paged,
+            "paged": dict(self.walk_stats),
             "max_batch": self.max_batch,
             "active": sum(1 for r in self.slots if r is not None),
             "waiting": len(self.waiting),
@@ -2636,9 +2445,27 @@ class DynamicInferenceEngine:
             "decode_traces": self.decode_traces,
             "steps": self.step_stats.snapshot(),
             "sampler": dict(self.sampler_stats),
-            "prefill": False,
+            "prefill": dict(
+                self.prefill_stats, width=self.prefill_chunk,
+                fill_share=(round(self.prefill_stats["tokens"] / rows, 4)
+                            if rows else 0.0)),
             "state": False,
             "eva": False,
+            "pool": {
+                "num_blocks": pool.num_blocks,
+                "block_size": pool.block_size,
+                "kv_cache_dtype": pool.kv_cache_dtype,
+                "bytes_per_block": bpb,
+                "pool_bytes_total": pool.bytes_total,
+                "resident_bytes": resident_blocks * bpb,
+                "blocks_in_use": pool.blocks_in_use(),
+                "blocks_free": pool.free_blocks(),
+                "blocks_evictable": pool.evictable_blocks(),
+                "prefix_hit_rate": (
+                    round(st["prefix_hit_tokens"] / seen, 4) if seen
+                    else 0.0),
+                **st,
+            },
         }
         if self.eva:
             out["eva"] = dict(
@@ -2658,41 +2485,8 @@ class DynamicInferenceEngine:
             out["moe"] = dict(
                 self.moe_stats, experts_here=here, expert_pairs_possible=(
                     per_round * self.moe_stats["decode_rounds"]))
-        if include_dispatch and self.paged:
+        if include_dispatch:
             out["decode_dispatch"] = self.dispatch_stats()
-        if self.paged:
-            out["paged"] = dict(self.paged_stats)
-            rows = self.prefill_stats["calls"] * self.prefill_chunk
-            out["prefill"] = dict(
-                self.prefill_stats, width=self.prefill_chunk,
-                fill_share=(round(self.prefill_stats["tokens"] / rows, 4)
-                            if rows else 0.0))
-            pool = self.pool
-            st = dict(pool.stats)
-            seen = st["prefix_hit_tokens"] + st["prefill_tokens"]
-            # Byte accounting reads the ADDRESSABLE pool arrays (int8
-            # data + fp32 scales for quantized pools), never a dtype
-            # assumption — /stats and /healthz stay honest when the pool
-            # dtype differs from the param dtype. resident_bytes counts
-            # blocks whose data is live (in use + LRU-parked, still
-            # hittable); pool_bytes_total is the full allocation.
-            bpb = pool.bytes_per_block
-            resident_blocks = pool.num_blocks - pool.free_blocks()
-            out["pool"] = {
-                "num_blocks": pool.num_blocks,
-                "block_size": pool.block_size,
-                "kv_cache_dtype": pool.kv_cache_dtype,
-                "bytes_per_block": bpb,
-                "pool_bytes_total": pool.bytes_total,
-                "resident_bytes": resident_blocks * bpb,
-                "blocks_in_use": pool.blocks_in_use(),
-                "blocks_free": pool.free_blocks(),
-                "blocks_evictable": pool.evictable_blocks(),
-                "prefix_hit_rate": (
-                    round(st["prefix_hit_tokens"] / seen, 4) if seen
-                    else 0.0),
-                **st,
-            }
         if self.spill is not None:
             out["spill"] = {"watermark_blocks": self.spill_watermark,
                             "held": len(self._held),

@@ -137,7 +137,7 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
         raise ValueError(
             "moe_first_k_dense: the dense-cache engines scan one uniform "
             "stack; serve a model with leading dense layers through the "
-            "paged engine (paged=True / --engine dynamic --paged-kv-cache)")
+            "paged engine (DynamicInferenceEngine / --engine dynamic)")
     if cfg.multi_latent_attention:
         return (jnp.zeros((cfg.num_layers, batch, max_len,
                            cfg.kv_lora_rank), cfg.compute_dtype),

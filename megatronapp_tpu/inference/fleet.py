@@ -263,7 +263,6 @@ class FleetRouter:
                            else slo_weight)
         self.tokenizer = self.replicas[0].engine.tokenizer
         self.max_batch = sum(r.engine.max_batch for r in self.replicas)
-        self.paged = True
         self.pause_admission = False        # driver-facade compat
         # Bounded hash→replica affinity map (LRU past capacity).
         self.affinity_capacity = affinity_capacity

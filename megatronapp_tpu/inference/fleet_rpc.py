@@ -200,7 +200,7 @@ def build_engine_from_spec(spec: dict):
         params, cfg, max_batch=spec["max_batch"],
         max_seq_len=spec["max_seq_len"],
         prefill_buckets=tuple(spec.get("prefill_buckets") or (16,)),
-        paged=True, block_size=spec["block_size"],
+        block_size=spec["block_size"],
         num_blocks=spec.get("num_blocks"),
         kv_cache_dtype=spec.get("kv_cache_dtype", "bf16"),
         prefill_chunk=spec.get("prefill_chunk"),
@@ -804,7 +804,6 @@ class ProcessFleetRouter:
         self._lock = threading.RLock()
         self._rr = 0
         self.pause_admission = False        # driver-facade compat
-        self.paged = True
         self.tokenizer = None
         # Fleet-global prefix store (ISSUE 20): the router pulls newly
         # inserted prefix blocks off step replies (prefix_get) and

@@ -1,7 +1,7 @@
 """Paged KV-cache block pool: allocator, prefix cache, preemption support.
 
 vLLM-style block management for the continuous-batching engine
-(inference/dynamic_engine.py `paged=True`): KV storage is a shared pool
+(inference/dynamic_engine.py): KV storage is a shared pool
 shaped [L, num_blocks, block_size, Hkv, D] (MLA: the compressed latent
 [L, num_blocks, block_size, kv_lora_rank] + shared roped key
 [..., qk_pos_emb_head_dim] pair), and each slot owns an ordered page
@@ -130,27 +130,97 @@ def kv_cache_dtype_help() -> str:
     return "; ".join(f"{n}: {s.help}" for n, s in KV_CACHE_DTYPES.items())
 
 
-def validate_kv_cache_dtype(name: str, *, paged: bool = True,
-                            mla: bool = False) -> KvDtypeSpec:
+def validate_kv_cache_dtype(name: str, *, mla: bool = False) -> KvDtypeSpec:
     """Single source of truth for kv_cache_dtype validation: the pool
-    constructor, the engine, and the parse-time CLI check all raise
-    THESE messages (ValueError; entry points wrap in SystemExit)."""
+    constructor and the parse-time CLI check both raise THIS message
+    (ValueError; entry points wrap in SystemExit)."""
     spec = KV_CACHE_DTYPES.get(name)
     if spec is None:
         raise ValueError(
             f"kv_cache_dtype must be one of "
             f"{sorted(KV_CACHE_DTYPES)}, got {name!r}")
-    if spec.quantized and not paged:
-        raise ValueError(
-            f"kv_cache_dtype={spec.name} requires the paged backend "
-            "(the per-block quantization scales live alongside the "
-            "block pool; the dense slot cache has no block structure) "
-            "— pass paged=True / --paged-kv-cache")
     # mla is accepted (and kept in the signature) so call sites document
     # the layout they validate for; quantized MLA pools are supported
     # since ISSUE 17 (per-row scalar scales on the latent/pe pools).
     del mla
     return spec
+
+
+# What a tenant of a slot's cache other than plain K/V rows cannot do yet.
+# One row a kind of per-slot state: (whether a model has it, the sentence a
+# refusal opens with: what the state is, why, and the ROADMAP id that holds
+# the work, {capability it lacks: what asking for it would take}). A
+# capability a row does not list works on such a model. The capabilities:
+#   rewind    drop a slot's newest rows again (spec_method)
+#   snapshot  copy a slot's cache out and in again (spill_host_mb,
+#             export_request / import_request, the pool's export_slot /
+#             import_slot)
+#   handoff   fill a slot in one place and adopt it in another (an injected
+#             pool, staging slots, adopt_request, the pool's transfer_slot)
+#   adapters  per-row LoRA deltas in the paged steps (adapter_cache)
+#   shard     a serving mesh (ctx)
+#   quantize  int8/fp8 rows (kv_cache_dtype)
+#   prefix    serve a prompt's head from another request's blocks; asked for
+#             by default, so it is switched off and not refused
+# A table of facts with one reader, check_tenants: a model PR adds a row.
+_STATE_LACKS = {
+    "rewind": "speculative decoding rewinds rejected tokens",
+    "snapshot": "parking or moving a session",
+    "handoff": "disaggregated prefill hands a sequence over by its page "
+               "table",
+    "adapters": "lora",
+    "shard": "a serving mesh",
+    "prefix": "a prefix hit would skip tokens whose state nobody kept",
+}
+_STATE_WHY = ("this model has {}, whose recurrent state lives in the paged "
+              "engine's slots on one device and has no snapshot yet (state "
+              "snapshots: ROADMAP M4)")
+TENANT_LACKS = {
+    "ssm": (lambda cfg: cfg.num_ssm_layers > 0,
+            _STATE_WHY.format("state-space layers"), _STATE_LACKS),
+    "conv": (lambda cfg: cfg.num_conv_layers > 0,
+             _STATE_WHY.format("gated short-convolution layers"),
+             _STATE_LACKS),
+    "eva": (lambda cfg: cfg.is_eva,
+            "this model's attention is EVA, whose chunk summaries live in a "
+            "second region of each slot's page table on one device, pooled "
+            "from that slot's own cached rows, and have no snapshots yet "
+            "(ROADMAP M4)",
+            dict(_STATE_LACKS, quantize="a quantized pool",
+                 prefix="a prefix hit would skip tokens whose summaries "
+                        "nobody kept")),
+    # two planes a layer, or a held share of the experts
+    "double": (lambda cfg: (cfg.moe_shortcut_double_layer
+                            or cfg.moe_experts_held is not None),
+               "this model runs double layers over two planes of the paged "
+               "pools and holds a share of its experts on one device "
+               "(ROADMAP M3, M6)",
+               {"handoff": "disaggregated prefill fills a dense one-plane "
+                           "cache",
+                "adapters": "lora on latent attention and experts",
+                "shard": "a serving mesh: the all-to-all between expert "
+                         "shares and a latent pool sharded under two "
+                         "sublayers",
+                "quantize": "int8/fp8 latent pools under the scaled "
+                            "latent"}),
+}
+
+
+def check_tenants(cfg: TransformerConfig, asked=None) -> frozenset:
+    """The capabilities `cfg`'s cache lacks (TENANT_LACKS). `asked`:
+    {capability: the argument or call that asks for it}; a ValueError
+    names every one of them that a kind of state this model keeps lacks."""
+    lacking = set()
+    for has, why, lacks in TENANT_LACKS.values():
+        if not has(cfg):
+            continue
+        refused = [f"{how} ({lacks[cap]})"
+                   for cap, how in (asked or {}).items() if cap in lacks]
+        if refused:
+            raise ValueError(
+                f"{why}: cannot serve it with " + "; ".join(refused))
+        lacking.update(lacks)
+    return frozenset(lacking)
 
 
 @dataclasses.dataclass
@@ -212,7 +282,14 @@ class PagedKVCache:
                  block_size: int = 16, enable_prefix_caching: bool = True,
                  extra_slots: int = 0, kv_cache_dtype: str = "bf16"):
         dtype_spec = validate_kv_cache_dtype(
-            kv_cache_dtype, paged=True, mla=cfg.multi_latent_attention)
+            kv_cache_dtype, mla=cfg.multi_latent_attention)
+        self.lacks = check_tenants(cfg, {cap: how for cap, how, on in (
+            ("quantize", f"kv_cache_dtype {kv_cache_dtype!r}",
+             dtype_spec.quantized),
+            ("handoff", "staging slots (disaggregated prefill)",
+             extra_slots)) if on})
+        if "prefix" in self.lacks:
+            enable_prefix_caching = False
         self.cfg = cfg
         self.kv_cache_dtype = kv_cache_dtype
         self.dtype_spec = dtype_spec
@@ -239,18 +316,6 @@ class PagedKVCache:
             )
             self._eva_spb = summary_blocks_per_window(cfg, block_size)
             self._eva_wb = cfg.eva_window_size // block_size
-            refused = [what for what, on in (
-                ("a quantized pool (kv_cache_dtype "
-                 f"{kv_cache_dtype!r})", dtype_spec.quantized),
-                ("staging slots (disaggregated prefill)", extra_slots),
-                ) if on]
-            if refused:
-                raise ValueError(
-                    "EVA chunk summaries are pooled from the cached rows of "
-                    "one slot's own table: cannot keep them with "
-                    + "; ".join(refused))
-            # A prefix hit would skip tokens whose summaries nobody kept.
-            enable_prefix_caching = False
             self.max_blocks_per_seq = self._eva_wb + self._eva_spb * cdiv(
                 max_seq_len, cfg.eva_window_size)
         # Default pool = dense capacity (max_batch full sequences); size
@@ -302,19 +367,13 @@ class PagedKVCache:
         # them in place like the pages, and a sequence's first prefill
         # call starts from zeros whatever the slot held, so admission,
         # release and preemption have nothing to do. They cannot be
-        # shared, exported or rewound (yet): the engine refuses what would
-        # need a snapshot of them.
+        # shared, exported or rewound (yet): TENANT_LACKS holds what that
+        # refuses.
         # A hybrid stack of gated short convolutions
         # (transformer/shortconv.py) has no h: its tenant is the one tail
         # pool [L_conv, slots, (k-1) * H], held and carried the same way.
         self.state = None
         if cfg.num_recurrent_layers:
-            if extra_slots:
-                raise ValueError(
-                    "staging slots (disaggregated prefill) hand a sequence "
-                    "over by its page table; a state-space layer's state "
-                    "or a convolution's tail has no snapshot to hand over "
-                    "yet")
             if cfg.num_conv_layers:
                 self.state = (_new_pool(
                     (cfg.num_conv_layers, max_batch,
@@ -698,12 +757,9 @@ class PagedKVCache:
         self._note_usage()
         return True
 
-    def _refuse_on_eva(self, what: str):
-        if self.eva:
-            raise ValueError(
-                f"{what}: this model's cache keeps EVA chunk summaries in a "
-                "second region of each slot's table, which this path does "
-                "not move")
+    def _refuse_lacking(self, cap: str, call: str):
+        if cap in self.lacks:       # (a set lookup: rewind runs a slot a round)
+            check_tenants(self.cfg, {cap: call})
 
     def extend_capacity(self, slot: int, position: int, span: int) -> int:
         """Best-effort growth for a multi-token (speculative) append:
@@ -747,7 +803,7 @@ class PagedKVCache:
         the block list move, refcounts and the page DATA are untouched,
         so adoption never copies KV (the no-dense-copy pin in
         tests/test_disagg.py)."""
-        self._refuse_on_eva("transfer_slot (disaggregated handoff)")
+        self._refuse_lacking("handoff", "transfer_slot")
         assert not self._slot_blocks[dst], (
             f"transfer_slot: destination slot {dst} still holds blocks")
         self._slot_blocks[dst] = self._slot_blocks[src]
@@ -768,7 +824,7 @@ class PagedKVCache:
         here mutates the source pool: a migration that fails after the
         export (the "fleet-migrate" chaos site) leaves the source slot
         fully intact."""
-        self._refuse_on_eva("export_slot (migration, parking)")
+        self._refuse_lacking("snapshot", "export_slot")
         import jax
         from megatronapp_tpu.ops.pallas.paged_attention import (
             gather_prefix_pages,
@@ -807,7 +863,7 @@ class PagedKVCache:
         scatter fault — `audit()` passes either way. The storage dtype
         must match (rows are stored bytes, never converted): fleet
         replicas share one --kv-cache-dtype by construction."""
-        self._refuse_on_eva("import_slot (migration, unparking)")
+        self._refuse_lacking("snapshot", "import_slot")
         if payload["kv_cache_dtype"] != self.kv_cache_dtype:
             raise ValueError(
                 f"cannot import {payload['kv_cache_dtype']!r} KV rows "
@@ -876,7 +932,7 @@ class PagedKVCache:
         corrupting the prefix cache. Rewinding never splits a block:
         KV rows past valid_len inside the kept tail block are simply
         overwritten by the next append."""
-        self._refuse_on_eva("rewind (speculative decoding)")
+        self._refuse_lacking("rewind", "rewind")
         keep = cdiv(max(valid_len, 1), self.block_size)
         owned = self._slot_blocks[slot]
         while len(owned) > keep:

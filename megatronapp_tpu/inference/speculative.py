@@ -1,7 +1,7 @@
 """Speculative decoding over the paged-KV engine (ISSUE 4).
 
 Pluggable proposers + an EXACT rejection-sampling verifier for
-DynamicInferenceEngine(paged=True, spec_method=...):
+DynamicInferenceEngine(spec_method=...):
 
 - ``NGramProposer`` ("ngram"): model-free prompt-lookup — the longest
   suffix n-gram of the request's token history is matched against its
@@ -54,7 +54,7 @@ import numpy as np
 from megatronapp_tpu.inference.engine import (
     _forward_with_cache, init_kv_cache, mask_padded_vocab,
 )
-from megatronapp_tpu.models.gpt import gpt_embed, gpt_head
+from megatronapp_tpu.models.gpt import gpt_embed, gpt_head, gpt_rope_tables
 from megatronapp_tpu.ops.normalization import rms_norm
 from megatronapp_tpu.transformer.block import layer_forward
 
@@ -353,6 +353,48 @@ def _draft_sample(logits, seeds, rids, steps, temps, top_ks, top_ps,
     return toks, q
 
 
+def _decode_step(params, tokens, cache, lengths, active, cfg):
+    """One-token decode for every row of a DENSE cache: the draft model's
+    step (DraftModelProposer, its one caller), and why attention's per-row
+    `cache_positions` arm without a page table stays.
+
+    tokens [B,1] (last token per slot), cache [L,B,Smax,...], lengths [B]
+    (tokens already in cache per slot), active [B] bool. Returns
+    (last_logits [B,V], new_cache)."""
+    max_len = cache[0].shape[2]
+    h = gpt_embed(params, tokens, cfg, position_ids=lengths[:, None])
+    cos_full, sin_full = gpt_rope_tables(cfg, max_len)
+    if cos_full is not None:
+        cos = jnp.take(cos_full, lengths, axis=0)[:, None]   # [B,1,half]
+        sin = jnp.take(sin_full, lengths, axis=0)[:, None]
+    else:
+        cos = sin = None
+
+    # Per-row causality: the new token (position lengths[b]) may attend
+    # cache positions <= lengths[b]; inactive rows are fully masked except
+    # self (keeps the softmax finite; results are discarded).
+    kv_pos = jnp.arange(max_len)
+    attend = kv_pos[None, :] <= lengths[:, None]             # [B,Smax]
+    mask = attend[:, None, None, :]                          # [B,1,1,Smax]
+
+    ck, cv = cache
+
+    def body(carry, layer_in):
+        hh = carry
+        layer_p, k_l, v_l, lid = layer_in
+        (hh, new_cache), _ = layer_forward(
+            layer_p, hh, cfg, cos, sin, mask, layer_id=lid,
+            kv_cache=(k_l, v_l), cache_index=None,
+            cache_positions=lengths)
+        return hh, new_cache
+
+    h, new_caches = jax.lax.scan(
+        body, h, (params["block"], ck, cv, jnp.arange(cfg.num_layers)),
+        unroll=cfg.scan_unroll)
+    logits = gpt_head(params, h, cfg)[:, -1]
+    return logits, new_caches
+
+
 class DraftModelProposer(Proposer):
     """Small draft model with its own DENSE per-slot KV cache.
 
@@ -386,7 +428,6 @@ class DraftModelProposer(Proposer):
         self.reset_compilation()
 
     def reset_compilation(self):
-        from megatronapp_tpu.inference.dynamic_engine import _decode_step
         dcfg = self.cfg
         self._prefill_jit = jax.jit(
             functools.partial(_forward_with_cache, cfg=dcfg))

@@ -159,7 +159,7 @@ def jamba2_3b(**kw) -> TransformerConfig:
     """ai21labs/AI21-Jamba2-3B: 28 layers of which 7 and 21 attend (20
     heads, 1 key/value head) and 26 are Mamba-1 layers; a dense SwiGLU of
     8192 in every layer; RMSNorm, a tied head, no positional term. Serves
-    through --engine dynamic --paged-kv-cache."""
+    through --engine dynamic."""
     d = dict(num_layers=28, hidden_size=2560, num_attention_heads=20,
              num_query_groups=1, ffn_hidden_size=8192, vocab_size=65536,
              max_position_embeddings=262144,
@@ -184,7 +184,7 @@ def lfm2_24b_a2b(**kw) -> TransformerConfig:
     is 47 GB of bf16 weights: a deployment passes its stages' num_layers,
     attn_layer_offset and moe_first_k_dense, as the benchmark's
     configuration does (perfbench/configs/lfm2-24b-a2b.json). Serves
-    through --engine dynamic --paged-kv-cache."""
+    through --engine dynamic."""
     d = dict(num_layers=40, hidden_size=2048, num_attention_heads=32,
              num_query_groups=8, ffn_hidden_size=11776, vocab_size=65536,
              max_position_embeddings=128000,
@@ -208,7 +208,7 @@ def evabyte_6p5b(**kw) -> TransformerConfig:
     bias, an untied head of 8 byte-prediction heads x 320, and EVA
     attention: an exact window of 2048 bytes and one pooled key/value row
     for every 16 older bytes (transformer/eva.py). Serves through --engine
-    dynamic --paged-kv-cache, ids in and out (no byte tokenizer yet)."""
+    dynamic, ids in and out (no byte tokenizer yet)."""
     d = dict(num_layers=32, hidden_size=4096, num_attention_heads=32,
              num_query_groups=32, ffn_hidden_size=11008, vocab_size=320,
              true_vocab_size=320, max_position_embeddings=32768,

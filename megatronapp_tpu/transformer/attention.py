@@ -416,7 +416,7 @@ def attention_forward(
             raise ValueError(
                 "EVA attention keeps chunk summaries in a paged cache's "
                 "table: a dense cache has no place for them (serve with "
-                "paged=True; gpt_forward runs whole sequences)")
+                "the paged engine; gpt_forward runs whole sequences)")
         elif cache_positions is not None:
             # Continuous-batching decode (dynamic_context.py analogue):
             # each row appends at ITS OWN position; causality MUST come

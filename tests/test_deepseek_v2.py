@@ -312,9 +312,11 @@ class TestEngine:
         assert snap["decode_dispatch"]["expert_stack_slices"] == 0
 
     def test_dense_cache_engines_refuse_leading_layers(self):
+        from megatronapp_tpu.inference.engine import StaticInferenceEngine
         cfg, params = _model()
+        eng = StaticInferenceEngine(params, cfg, max_seq_len=32)
         with pytest.raises(ValueError, match="paged engine"):
-            DynamicInferenceEngine(params, cfg, max_batch=1, max_seq_len=32)
+            eng.generate(np.asarray([[1, 2, 3]], np.int32), 2)
 
 
 class TestTrainingPath:

@@ -572,7 +572,7 @@ class TestServingArgs:
         ap = argparse.ArgumentParser()
         add_serving_args(ap)
         args = ap.parse_args([
-            "--engine", "dynamic", "--paged-kv-cache", "--serve-disagg",
+            "--engine", "dynamic", "--serve-disagg",
             "--serve-tp", "2", "--prefill-chunk", "16",
             "--disagg-prefill-slots", "3", "--decode-slo-ms", "25"])
         assert args.serve_disagg and args.serve_tp == 2

@@ -35,7 +35,6 @@ class TestRefusals:
         assert eng.pool.stats["prefix_hit_tokens"] == 0
 
     @pytest.mark.parametrize("kw,word", [
-        ({"paged": False}, "paged=False"),
         ({"spec_method": "ngram"}, "spec_method"),
         ({"spill_host_mb": 1.0}, "spill_host_mb"),
         ({"adapter_cache": object()}, "adapter_cache"),

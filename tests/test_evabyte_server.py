@@ -26,7 +26,7 @@ class TestServer:
             [sys.executable,
              os.path.join(ROOT, "tools", "run_text_generation_server.py"),
              "--preset", "gpt2-125m",
-             "--engine", "dynamic", "--paged-kv-cache",
+             "--engine", "dynamic",
              "--kv-block-size", "4", "--eva-window-size", "32",
              "--eva-chunk-size", "4", "--prefill-chunk", "8",
              "--max-seq-len", "96", "--max-batch", "2",
@@ -85,4 +85,4 @@ class TestServer:
             env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT))
         assert out.returncode != 0
         assert "chunk summaries" in out.stderr
-        assert "--engine dynamic --paged-kv-cache" in out.stderr
+        assert "--engine dynamic" in out.stderr

@@ -840,20 +840,18 @@ class TestFleetArgs:
         return ap.parse_args(argv)
 
     def test_flags_parse(self):
-        args = self._parse(["--engine", "dynamic", "--paged-kv-cache",
+        args = self._parse(["--engine", "dynamic",
                             "--serve-fleet", "3", "--fleet-migrate"])
         assert args.serve_fleet == 3 and args.fleet_migrate
         assert not args.fleet_autoscale
 
     @pytest.mark.parametrize("argv,msg", [
         (["--serve-fleet", "2"], "--engine dynamic"),
-        (["--engine", "dynamic", "--serve-fleet", "2"],
-         "--paged-kv-cache"),
-        (["--engine", "dynamic", "--paged-kv-cache", "--fleet-migrate"],
+        (["--engine", "dynamic", "--fleet-migrate"],
          "--serve-fleet >= 2"),
-        (["--engine", "dynamic", "--paged-kv-cache", "--serve-fleet",
+        (["--engine", "dynamic", "--serve-fleet",
           "0"], ">= 1"),
-        (["--engine", "dynamic", "--paged-kv-cache",
+        (["--engine", "dynamic",
           "--fleet-autoscale"], "--serve-disagg"),
     ])
     def test_invalid_combos_rejected(self, argv, msg):
@@ -868,7 +866,7 @@ class TestFleetArgs:
         from megatronapp_tpu.config.arguments import (
             validate_serving_args,
         )
-        args = self._parse(["--engine", "dynamic", "--paged-kv-cache",
+        args = self._parse(["--engine", "dynamic",
                             "--serve-fleet", "2", "--fleet-migrate"])
         validate_serving_args(args)
 

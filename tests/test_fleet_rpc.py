@@ -223,7 +223,7 @@ class TestFleetProcArgs:
         assert args.fleet_procs == 0
         assert args.replica_rpc_port == 0
         assert args.supervisor == "off"
-        args = self._parse(["--engine", "dynamic", "--paged-kv-cache",
+        args = self._parse(["--engine", "dynamic",
                             "--fleet-procs", "3",
                             "--replica-rpc-port", "29000",
                             "--supervisor", "thread"])
@@ -231,20 +231,18 @@ class TestFleetProcArgs:
                 args.supervisor) == (3, 29000, "thread")
 
     @pytest.mark.parametrize("argv,msg", [
-        (["--engine", "dynamic", "--paged-kv-cache",
+        (["--engine", "dynamic",
           "--fleet-procs", "-1"], "must be >= 0"),
-        (["--engine", "dynamic", "--paged-kv-cache", "--serve-fleet",
+        (["--engine", "dynamic", "--serve-fleet",
           "2", "--fleet-procs", "2"], "mutually exclusive"),
         (["--fleet-procs", "2"], "--engine dynamic"),
-        (["--engine", "dynamic", "--fleet-procs", "2"],
-         "--paged-kv-cache"),
-        (["--engine", "dynamic", "--paged-kv-cache",
+        (["--engine", "dynamic",
           "--replica-rpc-port", "29000"], "needs --fleet-procs"),
-        (["--engine", "dynamic", "--paged-kv-cache", "--fleet-procs",
+        (["--engine", "dynamic", "--fleet-procs",
           "2", "--replica-rpc-port", "80"], "out of range"),
-        (["--engine", "dynamic", "--paged-kv-cache", "--fleet-procs",
+        (["--engine", "dynamic", "--fleet-procs",
           "4", "--replica-rpc-port", "65533"], "out of range"),
-        (["--engine", "dynamic", "--paged-kv-cache",
+        (["--engine", "dynamic",
           "--supervisor", "thread"], "needs --fleet-procs"),
     ])
     def test_invalid_combos_rejected(self, argv, msg):
@@ -259,7 +257,7 @@ class TestFleetProcArgs:
             validate_serving_args,
         )
         validate_serving_args(self._parse(
-            ["--engine", "dynamic", "--paged-kv-cache",
+            ["--engine", "dynamic",
              "--fleet-procs", "2", "--replica-rpc-port", "29000",
              "--supervisor", "process"]))
 

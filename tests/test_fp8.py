@@ -224,21 +224,20 @@ class TestFp8Pool:
         action = next(a for a in ap._actions
                       if a.dest == "kv_cache_dtype")
         assert sorted(action.choices) == sorted(KV_CACHE_DTYPES)
-        # fp8 without --paged-kv-cache: pool message == CLI message.
-        with pytest.raises(ValueError, match="paged"):
-            validate_kv_cache_dtype("fp8", paged=False)
+        # fp8 under --engine static (the default): refused by --engine.
         args = ap.parse_args(["--kv-cache-dtype", "fp8"])
-        with pytest.raises(SystemExit, match="paged"):
+        with pytest.raises(SystemExit, match="--engine dynamic"):
             validate_serving_args(args)
+        validate_serving_args(ap.parse_args(
+            ["--kv-cache-dtype", "fp8", "--engine", "dynamic"]))
         # fp8 + MLA validates since ISSUE 17 (quantized latent pool).
-        validate_kv_cache_dtype("fp8", paged=True, mla=True)  # no raise
+        validate_kv_cache_dtype("fp8", mla=True)  # no raise
         with pytest.raises(ValueError, match="one of"):
             validate_kv_cache_dtype("int4")
 
-    def test_fp8_mla_latent_pool_and_dense_rejected(self):
+    def test_fp8_mla_latent_pool(self):
         """fp8 MLA pools quantize since ISSUE 17 (per-row scalar scale
-        pools [L, NB, bs], same layout as int8); the dense backend still
-        rejects fp8."""
+        pools [L, NB, bs], same layout as int8)."""
         cfg = TransformerConfig(
             num_layers=2, hidden_size=64, num_attention_heads=4,
             vocab_size=128, max_position_embeddings=64,
@@ -252,12 +251,6 @@ class TestFp8Pool:
         assert pool.scales is not None
         assert all(s.shape == (2, 8, 4) and s.dtype == jnp.float32
                    for s in pool.scales)
-        cfg2 = _gqa_cfg()
-        params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg2)
-        with pytest.raises(ValueError, match="paged"):
-            DynamicInferenceEngine(params, cfg2, max_batch=1,
-                                   max_seq_len=32, paged=False,
-                                   kv_cache_dtype="fp8")
 
 
 # ---------------------------------------------------------------------------

@@ -353,7 +353,6 @@ class TestRefusals:
         assert eng.pool.stats["prefix_hit_tokens"] == 0
 
     @pytest.mark.parametrize("kw,word", [
-        ({"paged": False}, "dense cache"),
         ({"spec_method": "ngram"}, "spec_method"),
         ({"spill_host_mb": 1.0}, "spill_host_mb"),
         ({"adapter_cache": object()}, "adapter_cache"),

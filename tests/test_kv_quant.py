@@ -200,14 +200,6 @@ class TestQuantizedPool:
         assert all(s.shape == (2, 8, 4) and s.dtype == jnp.float32
                    for s in pool.scales)
 
-    def test_int8_requires_paged_backend(self):
-        cfg = _gqa_cfg()
-        params, _ = init_gpt_params(jax.random.PRNGKey(0), cfg)
-        with pytest.raises(ValueError, match="paged"):
-            DynamicInferenceEngine(params, cfg, max_batch=1,
-                                   max_seq_len=32, paged=False,
-                                   kv_cache_dtype="int8")
-
     def test_cow_copies_scales_alongside(self):
         """A copy-on-write block copy must carry the scale rows with the
         int8 rows — dequantized content of the private copy equals the
@@ -485,17 +477,16 @@ class TestServingArgsValidation:
             argv += [flag] if v is True else [flag, str(v)]
         return ap.parse_args(argv)
 
-    def test_int8_requires_paged_flag(self):
+    def test_int8_requires_the_dynamic_engine(self):
         from megatronapp_tpu.config.arguments import validate_serving_args
-        args = self._args(engine="dynamic", kv_cache_dtype="int8")
-        with pytest.raises(SystemExit, match="paged-kv-cache"):
+        args = self._args(engine="static", kv_cache_dtype="int8")
+        with pytest.raises(SystemExit, match="requires --engine dynamic"):
             validate_serving_args(args)
 
     def test_int8_accepted_for_mla_preset(self):
         """int8 + MLA validates since ISSUE 17 (quantized latent pool)."""
         from megatronapp_tpu.config.arguments import validate_serving_args
-        args = self._args(engine="dynamic", kv_cache_dtype="int8",
-                          paged_kv_cache=True)
+        args = self._args(engine="dynamic", kv_cache_dtype="int8")
         validate_serving_args(args, multi_latent_attention=True)  # no raise
 
     def test_quantized_weights_rejected_for_mamba(self):
@@ -506,8 +497,7 @@ class TestServingArgsValidation:
 
     def test_valid_combo_passes(self):
         from megatronapp_tpu.config.arguments import validate_serving_args
-        args = self._args(engine="dynamic", kv_cache_dtype="int8",
-                          paged_kv_cache=True)
+        args = self._args(engine="dynamic", kv_cache_dtype="int8")
         validate_serving_args(args)          # no raise
 
     def test_startup_ptq_quantizes_resident_leaves_only(self):

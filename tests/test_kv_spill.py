@@ -212,13 +212,6 @@ class TestParkUnparkExact:
         assert payload["nbytes"] == want
         assert eng.spill.bytes_used == want
 
-    def test_spill_requires_paged_backend(self, gqa_params):
-        cfg, params = gqa_params
-        with pytest.raises(ValueError, match="paged"):
-            DynamicInferenceEngine(
-                params, cfg, max_batch=2, max_seq_len=48,
-                prefill_buckets=(16,), paged=False, spill_host_mb=2.0)
-
     def test_watermark_without_budget_rejected(self, gqa_params):
         cfg, params = gqa_params
         with pytest.raises(ValueError, match="budget"):
@@ -498,10 +491,10 @@ class TestServingFlags:
                 validate_serving_args(args)
 
     def test_valid_combinations(self):
-        self._check(["--engine", "dynamic", "--paged-kv-cache",
+        self._check(["--engine", "dynamic",
                      "--kv-spill-host-mb", "64",
                      "--kv-spill-watermark-blocks", "4"])
-        self._check(["--engine", "dynamic", "--paged-kv-cache",
+        self._check(["--engine", "dynamic",
                      "--serve-fleet", "2",
                      "--fleet-prefix-store-mb", "8"])
 
@@ -509,12 +502,10 @@ class TestServingFlags:
         self._check(["--kv-spill-host-mb", "-1"], "kv-spill-host-mb")
         self._check(["--engine", "static", "--kv-spill-host-mb", "8"],
                     "dynamic")
-        self._check(["--engine", "dynamic", "--kv-spill-host-mb", "8"],
-                    "paged")
-        self._check(["--engine", "dynamic", "--paged-kv-cache",
+        self._check(["--engine", "dynamic",
                      "--serve-disagg", "--kv-spill-host-mb", "8"],
                     "disagg")
-        self._check(["--engine", "dynamic", "--paged-kv-cache",
+        self._check(["--engine", "dynamic",
                      "--kv-spill-watermark-blocks", "4"], "watermark")
         self._check(["--fleet-prefix-store-mb", "4"], "fleet")
 

@@ -256,7 +256,6 @@ class TestRefusals:
     yet refuses, once, in words (ROADMAP M2, M4 hold what remains)."""
 
     @pytest.mark.parametrize("kw,word", [
-        ({"paged": False}, "dense cache"),
         ({"spec_method": "ngram"}, "spec_method"),
         ({"spill_host_mb": 1.0}, "spill_host_mb"),
         ({"adapter_cache": object()}, "adapter_cache"),

@@ -396,7 +396,6 @@ def _tiny_cfg(**kw):
 
 
 @pytest.mark.parametrize("build,error,said", [
-    (lambda: _engine(paged=False), ValueError, "paged=False"),
     (lambda: _engine(kv_cache_dtype="int8"), ValueError, "int8/fp8 latent"),
     (lambda: _engine(ctx=_Ctx()), ValueError, "all-to-all between expert"),
     (lambda: _engine(adapter_cache=object()), ValueError, "lora"),
@@ -420,7 +419,7 @@ def _tiny_cfg(**kw):
      "scale the latents of multi_latent_attention"),
     (lambda: _tiny_cfg(vocab_size=512, vocab_slice_of=256), ValueError,
      "a slice of"),
-], ids=["dense-cache", "int8-pool", "mesh", "lora", "injected-pool",
+], ids=["int8-pool", "mesh", "lora", "injected-pool",
         "tp-sharded", "fp8", "dense-kv-cache", "ep-all-to-all", "pp",
         "zero-experts-no-moe", "held-out-of-range", "double+lead-dense",
         "scale-without-mla", "slice-smaller-than-vocab"])

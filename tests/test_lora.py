@@ -451,8 +451,7 @@ class TestLoadgenTenants:
 # ---------------------------------------------------------------------------
 class TestServingArgs:
     def _ns(self, **kw):
-        base = dict(engine="dynamic", paged_kv_cache=True,
-                    serve_disagg=False,
+        base = dict(engine="dynamic", serve_disagg=False,
                     serve_fleet=1, kv_cache_dtype="bf16",
                     quantized_weights=False,
                     lora_dir="/tmp/adapters", lora_rank=4,
@@ -468,9 +467,6 @@ class TestServingArgs:
            multi_latent_attention=False)
         with pytest.raises(SystemExit, match="dynamic"):
             ok(self._ns(engine="static"), multi_latent_attention=False)
-        with pytest.raises(SystemExit, match="paged"):
-            ok(self._ns(paged_kv_cache=False),
-               multi_latent_attention=False)
         with pytest.raises(SystemExit, match="multi-latent"):
             ok(self._ns(), multi_latent_attention=True)
         with pytest.raises(SystemExit, match="serve-disagg"):

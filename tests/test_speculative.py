@@ -509,12 +509,6 @@ class TestFallbacks:
                 block_size=8, spec_method="draft")
         assert eng.spec_method is None
 
-    def test_spec_requires_paged(self, model):
-        params, cfg = model
-        with pytest.raises(ValueError, match="paged"):
-            DynamicInferenceEngine(params, cfg, max_batch=1,
-                                   max_seq_len=64, spec_method="ngram")
-
     def test_draft_vocab_mismatch_rejected(self, model):
         params, cfg = model
         bad_cfg = TransformerConfig(

@@ -158,7 +158,7 @@ def run(n_short: int = 3, short_len: int = 8, short_new: int = 48,
         if mode == "colocated":
             eng = DynamicInferenceEngine(
                 params, cfg, max_batch=max_batch, max_seq_len=max_seq_len,
-                prefill_buckets=(32, max_seq_len), paged=True,
+                prefill_buckets=(32, max_seq_len),
                 block_size=block_size, prefill_chunk=prefill_chunk,
                 enable_prefix_caching=False)
         else:

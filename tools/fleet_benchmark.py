@@ -93,7 +93,7 @@ def run(n_replicas: int = 2, groups: int = 4, followers: int = 3,
             # one.
             return DynamicInferenceEngine(
                 params, cfg, max_batch=2, max_seq_len=max_seq_len,
-                prefill_buckets=(prefix_len + tail_len,), paged=True,
+                prefill_buckets=(prefix_len + tail_len,),
                 block_size=block_size, kv_cache_dtype=kv_cache_dtype,
                 num_blocks=groups * (prefix_len // block_size + 2)
                 + 4 * ((prefix_len + tail_len + max_new)
@@ -160,7 +160,7 @@ def run(n_replicas: int = 2, groups: int = 4, followers: int = 3,
                                   np.asarray([1, 2, 3], np.int32)])
     base_eng = DynamicInferenceEngine(
         params, cfg, max_batch=2, max_seq_len=max_seq_len,
-        prefill_buckets=(prefix_len + tail_len,), paged=True,
+        prefill_buckets=(prefix_len + tail_len,),
         block_size=block_size, kv_cache_dtype=kv_cache_dtype,
         enable_prefix_caching=False)
     b_rid = base_eng.add_request(long_prompt, 12, gp)

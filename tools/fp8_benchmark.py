@@ -204,7 +204,7 @@ def run_kv(max_new=6):
     for dtype in ("bf16", "fp8", "int8"):
         eng = DynamicInferenceEngine(
             params, cfg, max_batch=4, max_seq_len=96,
-            prefill_buckets=(32, 64), paged=True, block_size=8,
+            prefill_buckets=(32, 64), block_size=8,
             kv_cache_dtype=dtype)
         ids = [eng.add_request(p, max_new, SamplingParams(greedy=True))
                for p in prompts]

@@ -66,7 +66,7 @@ def _build(cfg, params, kv_dtype, max_batch=4, max_seq_len=96,
     )
     return DynamicInferenceEngine(
         params, cfg, max_batch=max_batch, max_seq_len=max_seq_len,
-        prefill_buckets=(32, 64), paged=True, block_size=block_size,
+        prefill_buckets=(32, 64), block_size=block_size,
         num_blocks=num_blocks, kv_cache_dtype=kv_dtype, **kw)
 
 

@@ -63,7 +63,7 @@ def _build(cfg, params, kv_dtype="bf16", max_batch=2, max_seq_len=48,
     )
     return DynamicInferenceEngine(
         params, cfg, tokenizer=tokenizer, max_batch=max_batch,
-        max_seq_len=max_seq_len, prefill_buckets=(16,), paged=True,
+        max_seq_len=max_seq_len, prefill_buckets=(16,),
         block_size=block_size, num_blocks=num_blocks,
         kv_cache_dtype=kv_dtype, enable_prefix_caching=prefix_caching,
         prefill_chunk=prefill_chunk, spill_host_mb=spill_mb,

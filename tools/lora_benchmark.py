@@ -49,7 +49,7 @@ def _build(params, cfg, cache=None, max_batch=8):
     )
     return DynamicInferenceEngine(
         params, cfg, max_batch=max_batch, max_seq_len=48,
-        prefill_buckets=(16,), paged=True, block_size=8,
+        prefill_buckets=(16,), block_size=8,
         adapter_cache=cache)
 
 

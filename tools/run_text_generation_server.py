@@ -42,8 +42,8 @@ def main():
     ap.add_argument("--max-seq-len", type=int, default=None)
     # Serving flags shared with the main parser (config/arguments.py
     # add_serving_args — single source of truth): --engine, --max-batch,
-    # --paged-kv-cache, --kv-block-size, --num-kv-blocks,
-    # --scan-unroll, --no-prefix-caching.
+    # --kv-block-size, --num-kv-blocks, --scan-unroll,
+    # --no-prefix-caching.
     from megatronapp_tpu.config.arguments import (
         add_eva_args, add_hybrid_args, add_serving_args, eva_fields,
         hybrid_fields, validate_serving_args,
@@ -53,7 +53,7 @@ def main():
     # state-space stack on a preset, served by --engine dynamic.
     add_hybrid_args(ap)
     # --eva-window-size / --eva-chunk-size: EVA attention on a preset,
-    # served by --engine dynamic --paged-kv-cache.
+    # served by --engine dynamic.
     add_eva_args(ap)
     args = ap.parse_args()
     from megatronapp_tpu.utils.platform import (
@@ -85,18 +85,14 @@ def main():
     shape = {**hybrid_fields(args), **eva_fields(args)}
     if shape:
         cfg = dataclasses.replace(cfg, **shape)
-    if cfg.is_eva and not (args.engine == "dynamic"
-                           and args.paged_kv_cache):
+    if cfg.is_eva and args.engine != "dynamic":
         raise SystemExit(
             "a model with EVA attention keeps its chunk summaries in the "
-            "paged engine's page tables: serve it with --engine dynamic "
-            "--paged-kv-cache")
-    if cfg.num_ssm_layers and not (args.engine == "dynamic"
-                                   and args.paged_kv_cache):
+            "paged engine's page tables: serve it with --engine dynamic")
+    if cfg.num_ssm_layers and args.engine != "dynamic":
         raise SystemExit(
             "a model with state-space layers keeps its recurrent state in "
-            "the paged engine's slots: serve it with --engine dynamic "
-            "--paged-kv-cache")
+            "the paged engine's slots: serve it with --engine dynamic")
     if args.scan_unroll != 1:
         cfg = dataclasses.replace(cfg, scan_unroll=args.scan_unroll)
     mcfg = None
@@ -328,7 +324,7 @@ def main():
                 return DynamicInferenceEngine(
                     params, cfg, tokenizer=tok,
                     max_batch=args.max_batch,
-                    max_seq_len=args.max_seq_len, paged=True,
+                    max_seq_len=args.max_seq_len,
                     block_size=args.kv_block_size,
                     num_blocks=args.num_kv_blocks,
                     enable_prefix_caching=args.prefix_caching,
@@ -356,9 +352,6 @@ def main():
             TextGenerationServer(engine, args.host, args.port).run()
             return
         if args.serve_disagg:
-            if not args.paged_kv_cache:
-                raise SystemExit("--serve-disagg needs --paged-kv-cache "
-                                 "(the KV handoff rides the block pool)")
             from megatronapp_tpu.inference.disagg import (
                 DisaggServingEngine,
             )
@@ -393,7 +386,7 @@ def main():
                 devices=jax.devices()[:args.serve_tp])
         engine = DynamicInferenceEngine(
             params, cfg, tokenizer=tok, max_batch=args.max_batch,
-            max_seq_len=args.max_seq_len, paged=args.paged_kv_cache,
+            max_seq_len=args.max_seq_len,
             block_size=args.kv_block_size, num_blocks=args.num_kv_blocks,
             enable_prefix_caching=args.prefix_caching,
             spec_method=spec,
@@ -411,8 +404,7 @@ def main():
             engine.tenant_slo = TenantSLO()
         print(device_line(None if tp_ctx is None else tp_ctx.mesh))
         print(f"serving continuous batching on {args.host}:{args.port} "
-              f"(paged={args.paged_kv_cache}, "
-              f"kv={args.kv_cache_dtype}, tp={args.serve_tp}, "
+              f"(kv={args.kv_cache_dtype}, tp={args.serve_tp}, "
               f"lora={'on' if args.lora_dir else 'off'}, "
               f"spec={engine.spec_method or 'off'})")
         print(engine.startup_line())

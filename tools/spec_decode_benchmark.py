@@ -63,7 +63,7 @@ def _run(params, cfg, prompts, max_new, spec_method, spec_k,
     from megatronapp_tpu.inference.engine import SamplingParams
     eng = DynamicInferenceEngine(
         params, cfg, max_batch=max_batch, max_seq_len=256,
-        prefill_buckets=(64, 128), paged=True, block_size=block_size,
+        prefill_buckets=(64, 128), block_size=block_size,
         spec_method=spec_method, spec_k=spec_k, prefill_chunk=32)
     ids = [eng.add_request(p, max_new, SamplingParams(greedy=True))
            for p in prompts]

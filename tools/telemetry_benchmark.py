@@ -63,7 +63,7 @@ def _soak(params, cfg, on: bool, n_requests: int, prompt_len: int,
     _set_telemetry(on)
     eng = DynamicInferenceEngine(
         params, cfg, max_batch=4, max_seq_len=96, prefill_buckets=(32,),
-        paged=True, block_size=8)
+        block_size=8)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, prompt_len).astype(np.int32)
                for _ in range(n_requests)]
