@@ -5,6 +5,7 @@
                                      # a tiny EVA-attention engine and a
                                      # tiny double-layer expert-share engine
                                      # and a tiny short-convolution MoE one
+                                     # and a tiny sliding-window MoE one
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -39,6 +40,13 @@ vocab 50304), random weights from the entry points' own seeds:
   (sigmoid scores, a selection bias) through the paged engine: one tail
   pool beside the page pools, aliased; the experts' stacks read in place;
   every pick counted.
+
+- window: a tiny sliding-window stack (full attention layers of 6 query
+  heads beside window layers of 8 over 2 key/value heads, a per-head gate,
+  two rotary tables, experts behind a leading dense layer) through the paged
+  engine: requests longer than the window, two pairs of page pools, both
+  aliased, the window planes' blocks given back, both families of paged
+  kernel in the compiled decode step.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -997,6 +1005,148 @@ def check_conv(rc, lines, tiny=False):
 
 
 # ---------------------------------------------------------------------------
+# Phase: sliding-window layers beside full ones, MoE feed-forwards, tiny widths
+# ---------------------------------------------------------------------------
+
+WINDOW = dict(num_layers=5, attn_layer_period=4, attn_layer_offset=0,
+              sliding_window=32, sliding_window_heads=8, num_moe_experts=8,
+              moe_router_topk=2, moe_first_k_dense=1)
+WINDOW_REQUESTS = ((90, 40), (9, 12), (70, 30))     # (prompt, new tokens)
+
+
+def phase_window(tiny):
+    rc, tr = _run_child("window", ["--child", "window"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_window(rc, tr.lines, tiny)
+
+
+def child_window(tiny):
+    """In the child: a sliding-window stack (5 layers: a dense full layer,
+    then sliding, sliding, sliding, full; 6 / 8 query heads on 2 key/value
+    heads of 64, per-head norms and gate, YaRN on half of a full layer's
+    head and plain RoPE on a sliding layer's; 8 experts top-2 by sigmoid
+    scores + a selection bias beside a shared one) serves three requests
+    through DynamicInferenceEngine on the device, and the compiled decode
+    step says what it holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, PositionEmbeddingKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    cfg = TransformerConfig(
+        hidden_size=256, num_attention_heads=6, num_query_groups=2,
+        kv_channels=64, ffn_hidden_size=512, vocab_size=512,
+        max_position_embeddings=256, normalization=NormKind.rmsnorm,
+        activation=ActivationKind.swiglu, add_bias_linear=False,
+        untie_embeddings_and_output_weights=True, qk_layernorm=True,
+        attention_output_gate=True,
+        position_embedding=PositionEmbeddingKind.yarn, rotary_base=5e5,
+        rotary_percent=0.5, rope_scaling_factor=64.0,
+        yarn_original_max_position=64, yarn_beta_fast=64.0,
+        sliding_rotary_base=1e4, moe_ffn_hidden_size=128,
+        moe_shared_expert_intermediate_size=128, moe_router_score="sigmoid",
+        moe_router_selection_bias=True, moe_routed_scaling_factor=2.5,
+        params_dtype=jnp.bfloat16, **WINDOW)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=256,
+                                 prefill_chunk=64)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n, new in WINDOW_REQUESTS:
+        eng.add_request(rng.integers(0, 512, n).astype(np.int32), new,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    eng.pool.audit()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    table = jnp.zeros((b, mb), jnp.int32)
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        (table, table), jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool),
+        None).compile()
+    pools = eng._pools()
+    stats = eng.stats_snapshot(include_dispatch=True)
+    text = compiled.as_text()
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 512 for v in out.values()
+                             for t in v)),
+        "moe": stats["moe"], "window": stats["window"],
+        "kernels": sorted({k for k in ("paged_decode", "paged_window_decode",
+                                       "grouped_gemm") if f"%{k}" in text}),
+        "pool_shapes": [list(p.shape) for p in pools],
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_window(rc, lines, tiny=False):
+    out = {"phase": "window", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    # K and V of the two full planes, then of the three window planes, whose
+    # pool holds 8 slots' windows (32 / 16 + 2 blocks) and one call's rows.
+    shapes = out["pool_shapes"]
+    if (len(shapes) != 4 or shapes[0][0] != 2 or shapes[2][:2] != [
+            3, 8 * 4 + 64 // 16 + 1]):
+        out["problems"].append(f"pools {shapes}: not two full planes and "
+                               "three window planes of 37 blocks")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    # (an interpreted kernel is no instruction of the compiled step)
+    if _kernel_mode(dev, tiny) == "(compiled)" and out["kernels"] != [
+            "grouped_gemm", "paged_decode", "paged_window_decode"]:
+        out["problems"].append(f"the compiled decode step names "
+                               f"{out['kernels']}: not both paged families "
+                               "and the grouped GEMM")
+    want = sum(n + new for n, new in WINDOW_REQUESTS)
+    if out["tokens"] != want or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back, not "
+                               f"{want}, or one outside the vocabulary")
+    window = out["window"] or {}
+    if (not window.get("blocks_taken")
+            or window.get("blocks_taken") != window.get("blocks_given_back")
+            or window.get("blocks_held") != 0
+            or not window.get("rows_walked", 0)
+            < window.get("rows_full_walk", 0)):
+        out["problems"].append(f"window counters {window}: blocks taken and "
+                               "not all given back, or no row spared")
+    moe = out["moe"] or {}
+    picks = moe.get("tokens", 0) * WINDOW["moe_router_topk"] * (
+        WINDOW["num_layers"] - WINDOW["moe_first_k_dense"])
+    if not picks or moe.get("assignments") != picks \
+            or moe.get("experts_here") != WINDOW["num_moe_experts"]:
+        out["problems"].append(
+            f"moe counters {moe}: the picks are not tokens x top-k x MoE "
+            f"layers = {picks} over {WINDOW['num_moe_experts']} held experts")
+    if not any("paged decode, sliding window 32" in ln
+               and _kernel_mode(dev, tiny) in ln for ln in lines):
+        out["problems"].append("the engine did not say it ran the window "
+                               f"decode kernel {_kernel_mode(dev, tiny)}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Phase: four chips (only with --chips 4)
 # ---------------------------------------------------------------------------
 
@@ -1197,7 +1347,8 @@ def run(chips, tiny):
                 lambda: phase_hybrid(tiny),
                 lambda: phase_eva(tiny),
                 lambda: phase_share(tiny),
-                lambda: phase_conv(tiny)]
+                lambda: phase_conv(tiny),
+                lambda: phase_window(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -1221,7 +1372,7 @@ def main(argv=None):
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
     ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva",
-                                        "share", "conv"],
+                                        "share", "conv", "window"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -1242,6 +1393,9 @@ def main(argv=None):
         return 0
     if args.child == "conv":
         child_conv(args.tiny)
+        return 0
+    if args.child == "window":
+        child_window(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

@@ -86,6 +86,9 @@ class TransformerConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_mscale_coeff: float = 0.1
+    # HF `attention_factor`: what YaRN multiplies cos and sin by, where the
+    # model states it (None: 1 + yarn_mscale_coeff ln(rope_scaling_factor)).
+    yarn_attention_factor: Optional[float] = None
     add_qkv_bias: bool = False
     add_bias_linear: bool = True
     qk_layernorm: bool = False
@@ -174,6 +177,30 @@ class TransformerConfig:
     ssm_dt_rank: Optional[int] = None
     ssm_inner_norms: bool = False
     shortconv_kernel: int = 0
+
+    # Sliding-window attention layers beside full ones in one stack (HF
+    # `laguna`: layer_types, sliding_window, num_attention_heads_per_layer,
+    # a rope_parameters group a layer kind, gating). With sliding_window > 0
+    # the layers of a hybrid stack (attn_layer_period / attn_layer_offset)
+    # that are NOT its full-attention layers are attention layers too, whose
+    # query at position t sees the keys t - sliding_window + 1 .. t (HF:
+    # t - s < sliding_window): no state-space or convolution mixer then. A
+    # window layer may differ from a full one in three things, each a fact
+    # of the model: its query heads (sliding_window_heads over the same
+    # num_query_groups key/value heads; None = num_attention_heads), and its
+    # rotary table, which is plain RoPE at sliding_rotary_base over
+    # sliding_rotary_percent of a head whatever position_embedding says of
+    # the full layers (None = the full layers' table). Its cached rows live
+    # in planes of their own that give a slot's blocks back as they fall
+    # behind the window (inference/paged_cache.py).
+    # attention_output_gate: every attention layer multiplies each head's
+    # output by sigmoid(u w_g) of the layer's normed input u before the
+    # output projection ("gate_kernel" [hidden, heads]).
+    sliding_window: int = 0
+    sliding_window_heads: Optional[int] = None
+    sliding_rotary_base: Optional[float] = None
+    sliding_rotary_percent: float = 1.0
+    attention_output_gate: bool = False
 
     # EVA attention (HF `evabyte`: attention_class "eva", window_size,
     # chunk_size; Zheng et al. 2023, "Efficient Attention via Control
@@ -401,12 +428,14 @@ class TransformerConfig:
                     "a hybrid stack (attn_layer_period) runs plain "
                     "attention layers in its own layer loop: no MLA or "
                     "heterogeneous block configs")
-            if self.is_moe and not self.shortconv_kernel:
+            if self.is_moe and not (self.shortconv_kernel
+                                    or self.sliding_window):
                 raise ValueError(
                     "a hybrid state-space stack (attn_layer_period with "
                     "Mamba mixers) runs dense feed-forwards: no MoE; only "
                     "the gated short-convolution stack (shortconv_kernel) "
-                    "has been given MoE feed-forwards")
+                    "and the sliding-window stack (sliding_window) have "
+                    "been given MoE feed-forwards")
             if self.is_moe and (
                     self.moe_layer_freq != 1 or self.moe_picks_unheld
                     or self.moe_shortcut_double_layer or self.mtp_num_layers
@@ -425,6 +454,24 @@ class TransformerConfig:
                 "least 2) of the gated short convolution that the "
                 "non-attention layers of a hybrid stack (attn_layer_period) "
                 "run")
+        if self.sliding_window:
+            heads = self.sliding_window_heads or self.num_attention_heads
+            if (self.sliding_window < 0 or self.attn_layer_period is None
+                    or self.shortconv_kernel or self.is_eva
+                    or heads % self.num_query_groups):
+                raise ValueError(
+                    f"sliding_window={self.sliding_window} is the keys a "
+                    "window layer's query sees, in a stack whose full-"
+                    "attention layers attn_layer_period / attn_layer_offset "
+                    "name and whose other layers are sliding-window "
+                    "attention (no shortconv_kernel, no EVA), with "
+                    f"sliding_window_heads ({heads}) a multiple of "
+                    f"num_query_groups ({self.num_query_groups})")
+        elif (self.sliding_window_heads is not None
+              or self.sliding_rotary_base is not None):
+            raise ValueError(
+                "sliding_window_heads and sliding_rotary_base describe the "
+                "window layers of a sliding_window stack")
         if self.moe_router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_router_score={self.moe_router_score!r}: the router "
@@ -518,10 +565,25 @@ class TransformerConfig:
         return self.moe_experts_held or (0, self.num_moe_experts or 0)
 
     @property
+    def num_window_layers(self) -> int:
+        """Sliding-window attention layers: a hybrid stack's other kind
+        where sliding_window is set. Each owns a plane of the WINDOW pools;
+        num_attention_layers and kv_planes count the full layers alone."""
+        return (self.num_layers - self.num_attention_layers
+                if self.sliding_window else 0)
+
+    @property
+    def window_heads(self) -> int:
+        """Query heads of a sliding-window layer."""
+        return self.sliding_window_heads or self.num_attention_heads
+
+    @property
     def num_recurrent_layers(self) -> int:
         """Layers whose first half is no attention but a mixer with a
-        state a sequence (0 unless attn_layer_period is set)."""
-        return self.num_layers - self.num_attention_layers
+        state a sequence (0 unless attn_layer_period is set, and in a
+        sliding-window stack, whose other kind attends too)."""
+        return (self.num_layers - self.num_attention_layers
+                - self.num_window_layers)
 
     @property
     def num_ssm_layers(self) -> int:

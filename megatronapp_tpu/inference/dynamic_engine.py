@@ -293,7 +293,10 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     block.hybrid_layer_loop's scanned runs; an attention layer gets its
     plane (kv_plane), a state-space layer the state pools, its plane of
     them and `rows`, the slots of h's rows (None: row b is slot b). A stack
-    of gated short convolutions has the one tail pool: (k, v, conv). Its
+    of gated short convolutions has the one tail pool: (k, v, conv). A
+    sliding-window stack (cfg.sliding_window) has no state but a second pair
+    of page pools: (k, v, k_window, v_window), a plane a window layer, which
+    the layer reads through the window planes' own table. Its
     feed-forwards may be experts behind leading dense layers: the stacks
     are read through the layer id as below, and the layers' counts are
     summed along the loop's carry.
@@ -329,6 +332,12 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
                                        None, kv_plane=k)
                 pools = tuple(new[:2]) + pools[2:]
                 kvs = None if kvs is None else tuple(new[2:])
+            elif cfg.sliding_window:
+                # a window layer: plane k of the window pools, through
+                # their own table and rotary table (`layer` knows both)
+                (hh, new), aux = layer(layer_p, hh, lid, pools[2:4], None,
+                                       None, kv_plane=k, window=True)
+                pools = pools[:2] + tuple(new[:2]) + pools[4:]
             else:
                 (hh, new), aux = layer(layer_p, hh, lid, None, None, None,
                                        ssm_state=pools[2:] + (k,),
@@ -374,6 +383,27 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     return h, moe, pages + (scales or ())
 
 
+def _rope_rows(cfg: TransformerConfig, max_seq_len: int, positions):
+    """((cos, sin) at `positions` [B, S] of the model's rotary table, None
+    where it has none; the same of a sliding-window stack's window layers'
+    table, None where the model has no such layers)."""
+    def rows(window):
+        cos, sin = gpt_rope_tables(cfg, max_seq_len, window=window)
+        if cos is None:
+            return None, None
+        return (jnp.take(cos, positions, axis=0),           # [B, S, half]
+                jnp.take(sin, positions, axis=0))
+    return rows(False), (rows(True) if cfg.sliding_window else None)
+
+
+def _split_tables(cfg: TransformerConfig, page_table):
+    """(the full planes' table, the window planes'): a step on a
+    sliding-window stack is handed the pair, any other the one table."""
+    if cfg.sliding_window:
+        return page_table
+    return page_table, None
+
+
 def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
                        cfg: TransformerConfig, max_seq_len: int, ctx=None,
                        scales=None, lora=None):
@@ -381,7 +411,9 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
 
     pages: ([L, NB, bs, Hkv, D], same) K/V pools (MLA: latent + k_pe
     pools; a hybrid stack: then its two state pools, and every slot's
-    state advances a token); page_table [B, max_blocks_per_seq] int32;
+    state advances a token; a sliding-window stack: then its window
+    planes' K/V pools); page_table [B, max_blocks_per_seq] int32 (a
+    sliding-window stack: the pair of it and the window planes' table);
     lengths [B] append
     positions; active [B] bool (inactive rows' writes are dropped and
     their outputs discarded). scales: ([L, NB, bs, Hkv] fp32, same) for
@@ -401,21 +433,20 @@ def _paged_decode_step(params, tokens, pages, page_table, lengths, active,
     (layer, expert) pairs touched by active rows; the pools are the arrays
     that came in, each with B new rows a layer written in place."""
     h = gpt_embed(params, tokens, cfg, position_ids=lengths[:, None])
-    cos_full, sin_full = gpt_rope_tables(cfg, max_seq_len)
-    if cos_full is not None:
-        cos = jnp.take(cos_full, lengths, axis=0)[:, None]
-        sin = jnp.take(sin_full, lengths, axis=0)[:, None]
-    else:
-        cos = sin = None
+    (cos, sin), window_rope = _rope_rows(cfg, max_seq_len, lengths[:, None])
+    page_table, window_table = _split_tables(cfg, page_table)
 
     # The ragged kernels mask by per-row kv length themselves (MLA
     # included since ISSUE 17 — the latent kernel attends through the
     # page table, no dense gather and no host-built mask).
-    def layer(layer_p, hh, lid, kv, kvs, ll, **kind):
+    def layer(layer_p, hh, lid, kv, kvs, ll, window=False, **kind):
+        if window:
+            kind.update(window_rope=window_rope)
         return layer_forward(
             layer_p, hh, cfg, cos, sin, None, layer_id=lid,
             kv_cache=kv, cache_index=None,
-            cache_positions=lengths, page_table=page_table,
+            cache_positions=lengths,
+            page_table=window_table if window else page_table,
             active=active, ctx=ctx, kv_scales=kvs, lora=ll, **kind)
 
     h, moe, new_pages = _scan_paged_layers(params, h, pages, scales, lora,
@@ -448,21 +479,20 @@ def _paged_multiquery_step(params, tokens, pages, page_table, starts,
     positions = starts[:, None] + jnp.arange(s)[None, :]       # [B, S]
     positions = jnp.minimum(positions, max_seq_len - 1)
     h = gpt_embed(params, tokens, cfg, position_ids=positions)
-    cos_full, sin_full = gpt_rope_tables(cfg, max_seq_len)
-    if cos_full is not None:
-        cos = jnp.take(cos_full, positions, axis=0)            # [B,S,half]
-        sin = jnp.take(sin_full, positions, axis=0)
-    else:
-        cos = sin = None
+    (cos, sin), window_rope = _rope_rows(cfg, max_seq_len, positions)
+    page_table, window_table = _split_tables(cfg, page_table)
 
     # The multi-query ragged kernels mask themselves (MLA included since
     # ISSUE 17 — the latent kernel's scalar-prefetched q_lens carries
     # the causal tail mask).
-    def layer(layer_p, hh, lid, kv, kvs, ll, **kind):
+    def layer(layer_p, hh, lid, kv, kvs, ll, window=False, **kind):
+        if window:
+            kind.update(window_rope=window_rope)
         return layer_forward(
             layer_p, hh, cfg, cos, sin, None, layer_id=lid,
             kv_cache=kv, cache_index=None,
-            cache_positions=starts, page_table=page_table,
+            cache_positions=starts,
+            page_table=window_table if window else page_table,
             active=active, chunk_counts=q_lens, ctx=ctx,
             kv_scales=kvs, lora=ll, **kind)
 
@@ -750,6 +780,10 @@ class DynamicInferenceEngine:
         self.state_kind = ("conv" if cfg.num_conv_layers
                            else "ssm" if self.has_state else None)
         self.eva = cfg.is_eva
+        # A sliding-window stack (cfg.sliding_window): its window layers'
+        # rows live in planes of their own, which give a slot's blocks back
+        # as they fall behind the window (PagedKVCache.window_pages).
+        self.has_window = cfg.num_window_layers > 0
         check_tenants(cfg, {cap: how for cap, how, on in (
             ("rewind", "spec_method", spec_method and spec_method != "none"),
             ("snapshot", "spill_host_mb", spill_host_mb),
@@ -782,7 +816,16 @@ class DynamicInferenceEngine:
             cfg, max_batch, self.max_seq_len, num_blocks=num_blocks,
             block_size=block_size,
             enable_prefix_caching=enable_prefix_caching,
-            kv_cache_dtype=kv_cache_dtype)
+            kv_cache_dtype=kv_cache_dtype,
+            window_call_rows=self.prefill_chunk)
+        # Always-on counters of a sliding-window stack's plain decode rounds
+        # (stats_snapshot()["window"]): the rows its window layers' walks
+        # read (whole blocks from the one that holds a slot's oldest visible
+        # key) against the rows a full-length walk would have, and the pool
+        # bytes the running slots held against their tokens in flight.
+        self.window_stats = {"decode_rounds": 0, "rows_walked": 0,
+                             "rows_full_walk": 0, "bytes_held": 0,
+                             "tokens_in_flight": 0}
 
         # TP serving mesh (ISSUE 9): with a MeshContext whose tp > 1 and
         # a tp-eligible paged config, params replicate over the mesh and
@@ -986,6 +1029,11 @@ class DynamicInferenceEngine:
                 f"{self.max_batch}, max_seq_len={self.max_seq_len}, "
                 f"prefill_chunk={self.prefill_chunk}")
         cfg = self.cfg
+        # a hybrid stack's feed-forwards where they are experts
+        moe_words = (f", the first {cfg.moe_first_k_dense} with a dense "
+                     f"feed-forward, the others with {cfg.num_moe_experts} "
+                     f"experts, top-{cfg.moe_router_topk} by "
+                     f"{cfg.moe_router_score} scores")
         if self.has_state:
             line += (f", state={cfg.num_recurrent_layers} "
                      f"{self._state_words()} x "
@@ -996,11 +1044,21 @@ class DynamicInferenceEngine:
                 line += (f", layers={cfg.num_layers}: "
                          f"{cfg.num_attention_layers} attention + "
                          f"{cfg.num_recurrent_layers} {self._state_words()}"
-                         f", the first {cfg.moe_first_k_dense} with a dense "
-                         f"feed-forward, the others with "
-                         f"{cfg.num_moe_experts} experts, top-"
-                         f"{cfg.moe_router_topk} by {cfg.moe_router_score} "
-                         "scores")
+                         + moe_words)
+        if self.has_window:
+            pool = self.pool
+            line += (f", planes={cfg.kv_planes} full ({cfg.num_attention_heads}"
+                     f" query heads, {pool.num_blocks} blocks) + "
+                     f"{cfg.num_window_layers} sliding-window "
+                     f"({cfg.window_heads} query heads, window "
+                     f"{cfg.sliding_window}, {pool.num_window_blocks} blocks:"
+                     f" at most {pool.window_blocks_slot} a slot between "
+                     "calls, blocks behind the window are given back); "
+                     "refused on window planes: prefix reuse (off), "
+                     "spec_method, spill/park, export/import/adopt, lora, an "
+                     "injected pool or mesh, a quantized pool")
+            if cfg.is_moe:
+                line += moe_words
         if self.eva:
             line += (f", eva=window {self.cfg.eva_window_size} exact rows + "
                      f"one summary row every {self.cfg.eva_chunk_size} "
@@ -1100,18 +1158,33 @@ class DynamicInferenceEngine:
 
     def _pools(self):
         """A step's `pages` operand: the page pools and, behind them, the
-        recurrent-state pools of a model that has them."""
-        return self.pool.pages + (self.pool.state or ())
+        window planes' pools or the recurrent-state pools of a model that
+        has them."""
+        return (self.pool.pages + (self.pool.window_pages or ())
+                + (self.pool.state or ()))
+
+    def _tables(self, rows=slice(None)):
+        """A step's table operand for slots `rows`: the full planes' table,
+        and on a sliding-window stack the pair of it and the window
+        planes'."""
+        pool = self.pool
+        if not self.has_window:
+            return _handed_over(pool.page_table[rows])
+        return (_handed_over(pool.page_table[rows]),
+                _handed_over(pool.window_table[rows]))
 
     def _commit_pools(self, new):
         """Take a step's pools back: the donated buffers themselves,
-        written in place — (k, v), then (ssm, conv) for a model with
+        written in place — (k, v), then (k_window, v_window) for a
+        sliding-window stack, (ssm, conv) for a model with
         state-space layers or (conv,) for one with gated short
         convolutions, then (k_scales, v_scales) for int8 pools, whose
         in-jit quantize writes the scale pools through the same layer
         loop."""
         self.pool.pages = tuple(new[:2])
         rest = tuple(new[2:])
+        if self.has_window:
+            self.pool.window_pages, rest = rest[:2], rest[2:]
         if self.has_state:
             n = len(self.pool.state)
             self.pool.state, rest = rest[:n], rest[n:]
@@ -1844,7 +1917,7 @@ class DynamicInferenceEngine:
         pool = self.pool
         cached = plan.cached_tokens
         c = self.prefill_chunk
-        table_row = _handed_over(pool.page_table[slot][None])    # [1, MB]
+        table_row = self._tables(slice(slot, slot + 1))          # [1, MB]
         # The sequence's state starts from zeros inside its first call
         # (start 0: prefix reuse is off on such a model), in slot `slot`.
         rows = None
@@ -1868,12 +1941,24 @@ class DynamicInferenceEngine:
                         f"EVA prefill of slot {slot} at position {pos}: "
                         f"{cached} cached tokens, or the pool ran out of "
                         "the blocks its admission had counted")
-                table_row = _handed_over(pool.page_table[slot][None])
+                table_row = self._tables(slice(slot, slot + 1))
                 call_attrs = {"summaries": (
                     (pos + count) // self.cfg.eva_chunk_size
                     - pos // self.cfg.eva_chunk_size)}
                 self.eva_stats["summary_rows_written"] += (
                     call_attrs["summaries"])
+            if self.has_window:
+                # The window planes' blocks for this call's rows; those
+                # wholly behind its first query's window go back first.
+                if cached or not pool.window_ensure(slot, pos, count):
+                    raise RuntimeError(
+                        f"prefill of slot {slot} at position {pos}: "
+                        f"{cached} cached tokens, or the window planes ran "
+                        f"out of blocks ({pool.num_window_blocks} for "
+                        f"{self.max_batch} slots and one call of {c})")
+                table_row = self._tables(slice(slot, slot + 1))
+                call_attrs = {"window_blocks": len(
+                    pool.window_slot_blocks(slot))}
             chunk = np.zeros((1, c), np.int32)
             chunk[0, :count] = tokens[pos:pos + count]
             if pool.quantized:
@@ -1901,6 +1986,8 @@ class DynamicInferenceEngine:
             self.prefill_stats["calls"] += 1
             self.prefill_stats["tokens"] += count
             pos += count
+        if self.has_window:
+            pool.window_trim(slot, p_len)
         # Register the prompt's full blocks so concurrent same-prefix
         # requests hit them immediately.
         pool.register_prefix(slot, np.asarray(tokens), p_len)
@@ -2007,6 +2094,14 @@ class DynamicInferenceEngine:
             rt.instant("preempt", req.request_id)
             rt.begin("queue-wait", req.request_id)
 
+    def _decode_capacity(self, req: Request) -> bool:
+        """The blocks that cover `req`'s append position, in the full
+        planes and, on a sliding-window stack, in the window planes, whose
+        blocks behind the round's window go back first."""
+        at = int(self.lengths[req.slot])
+        return self.pool.ensure_capacity(req.slot, at) and (
+            not self.has_window or self.pool.window_ensure(req.slot, at))
+
     def _ensure_decode_capacity(self) -> List[Request]:
         """Before a decode step, every active slot needs the block that
         covers its append position. Exhaustion preempts the
@@ -2019,8 +2114,7 @@ class DynamicInferenceEngine:
         for req in runners:
             if req.slot < 0:
                 continue                 # preempted earlier this step
-            while not self.pool.ensure_capacity(
-                    req.slot, int(self.lengths[req.slot])):
+            while not self._decode_capacity(req):
                 victim = next(r for r in reversed(runners)
                               if r.slot >= 0)
                 if (victim is not req and self.spill is not None
@@ -2139,6 +2233,21 @@ class DynamicInferenceEngine:
             attrs["summaries"] = int(
                 ((lens + 1) % cfg.eva_chunk_size == 0).sum())
             st["summary_rows_written"] += attrs["summaries"]
+        if self.has_window:
+            # window_blocks / window_rows: what the window layers' walks
+            # read a plane, whole blocks from the one that holds a slot's
+            # oldest visible key; bytes_held: the pool bytes of the running
+            # slots' blocks, both kinds of plane.
+            first = np.maximum(lens + 1 - self.cfg.sliding_window, 0) // bs
+            attrs["window_blocks"] = int((lens // bs + 1 - first).sum())
+            attrs["window_rows"] = int((lens + 1 - first * bs).sum())
+            attrs["bytes_held"] = self.pool.bytes_held()
+            st = self.window_stats
+            st["decode_rounds"] += 1
+            st["rows_walked"] += attrs["window_rows"]
+            st["rows_full_walk"] += int((lens + 1).sum())
+            st["bytes_held"] += attrs["bytes_held"]
+            st["tokens_in_flight"] += int((lens + 1).sum())
         attrs["kv_blocks"] = int((rows // bs + 1).sum())
         self.walk_stats["decode_rounds"] += 1
         self.walk_stats["blocks_live"] += attrs["kv_blocks"]
@@ -2158,7 +2267,7 @@ class DynamicInferenceEngine:
             logits, moe, new = self._decode(
                 self.params, _handed_over(self.last_tokens),
                 self._pools(), self.pool.scales,
-                _handed_over(self.pool.page_table[:self.max_batch]),
+                self._tables(slice(0, self.max_batch)),
                 lengths, active_mask, self._lora_args())
             self._commit_pools(new)
             # The decode wrote each active row's kv at lengths[slot].
@@ -2359,10 +2468,11 @@ class DynamicInferenceEngine:
         pages_spec = jax.tree.map(spec, self._pools())
         scales_spec = jax.tree.map(spec, self.pool.scales)
         mb = self.pool.page_table.shape[1]
+        table = jax.ShapeDtypeStruct((self.max_batch, mb), jnp.int32)
         args = (p_spec,
                 jax.ShapeDtypeStruct((self.max_batch, 1), jnp.int32),
                 pages_spec, scales_spec,
-                jax.ShapeDtypeStruct((self.max_batch, mb), jnp.int32),
+                (table, table) if self.has_window else table,
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.int32),
                 jax.ShapeDtypeStruct((self.max_batch,), jnp.bool_),
                 jax.tree.map(spec, self._lora_args()))
@@ -2409,6 +2519,16 @@ class DynamicInferenceEngine:
         (R(T) a slot: what the paged kernel read) against
         `rows_full_attention` (T + 1); `max_blocks_slot`, the most blocks
         one slot has held.
+        "window" is a dict on a sliding-window stack (False otherwise):
+        `window`, `planes_full` and `planes_window`; the window planes'
+        `num_blocks`, `blocks_taken`, `blocks_given_back` and `blocks_held`
+        (taken - given back), `blocks_slot_bound` (what a slot holds at most
+        between calls), `max_blocks_slot` and `peak_blocks_held`; over plain
+        decode rounds `rows_walked` (what a window layer's walk read, whole
+        blocks from the one that holds a slot's oldest visible key) against
+        `rows_full_walk` (T + 1 a slot), and `bytes_held` (pool bytes of the
+        running slots' blocks, both kinds of plane, summed over rounds)
+        against `tokens_in_flight` (T + 1 a slot, summed alike).
         "moe" is a dict on an MoE model, summed over plain decode rounds:
         `decode_rounds`, `tokens` (their running requests), `assignments`
         (tokens x top-k x MoE layers), `expert_pairs_touched` of
@@ -2451,6 +2571,7 @@ class DynamicInferenceEngine:
                             if rows else 0.0)),
             "state": False,
             "eva": False,
+            "window": False,
             "pool": {
                 "num_blocks": pool.num_blocks,
                 "block_size": pool.block_size,
@@ -2473,6 +2594,17 @@ class DynamicInferenceEngine:
                 layers=self.cfg.num_layers,
                 window=self.cfg.eva_window_size,
                 chunk=self.cfg.eva_chunk_size)
+        if self.has_window:
+            ws = pool.window_stats
+            out["window"] = dict(
+                self.window_stats, **ws,
+                window=self.cfg.sliding_window,
+                planes_full=self.cfg.kv_planes,
+                planes_window=self.cfg.num_window_layers,
+                num_blocks=pool.num_window_blocks,
+                blocks_slot_bound=pool.window_blocks_slot,
+                blocks_held=pool.window_blocks_held(),
+                bytes_total=pool.window_bytes_total)
         if self.has_state:
             out["state"] = dict(
                 self.state_stats, kind=self.state_kind,

@@ -189,6 +189,23 @@ TENANT_LACKS = {
             dict(_STATE_LACKS, quantize="a quantized pool",
                  prefix="a prefix hit would skip tokens whose summaries "
                         "nobody kept")),
+    "window": (lambda cfg: cfg.num_window_layers > 0,
+               "this model has sliding-window attention layers, whose planes "
+               "of the paged cache give a slot's blocks back as they fall "
+               "behind the window and hold the rows that are left on one "
+               "device, with no snapshot yet (window planes: ROADMAP M1)",
+               {"rewind": "speculative decoding rewinds rejected tokens, "
+                          "past a block the window planes may have given "
+                          "back",
+                "snapshot": "parking or moving a session",
+                "handoff": "disaggregated prefill hands a sequence over by "
+                           "its page table, and the window planes have a "
+                           "table of their own",
+                "adapters": "lora",
+                "shard": "a serving mesh",
+                "quantize": "a quantized pool",
+                "prefix": "a prefix hit would need the window planes' last "
+                          "rows of the prefix, which are gone"}),
     # two planes a layer, or a held share of the experts
     "double": (lambda cfg: (cfg.moe_shortcut_double_layer
                             or cfg.moe_experts_held is not None),
@@ -280,7 +297,12 @@ class PagedKVCache:
     def __init__(self, cfg: TransformerConfig, max_batch: int,
                  max_seq_len: int, num_blocks: Optional[int] = None,
                  block_size: int = 16, enable_prefix_caching: bool = True,
-                 extra_slots: int = 0, kv_cache_dtype: str = "bf16"):
+                 extra_slots: int = 0, kv_cache_dtype: str = "bf16",
+                 window_call_rows: Optional[int] = None):
+        """window_call_rows: on a model with sliding-window layers, the most
+        rows ONE call appends to a slot (the engine's prefill width; None:
+        max_seq_len, so any call fits): the window planes are sized for
+        every slot's window (`window_blocks_slot`) and one such call."""
         dtype_spec = validate_kv_cache_dtype(
             kv_cache_dtype, mla=cfg.multi_latent_attention)
         self.lacks = check_tenants(cfg, {cap: how for cap, how, on in (
@@ -388,6 +410,40 @@ class PagedKVCache:
                                (cfg.ssm_conv_kernel - 1) * e),
                               cfg.compute_dtype, 0))
 
+        # The window planes: a sliding-window stack's window layers cache
+        # their rows in pools of their OWN, [L_window, NB_window, bs, Hkv,
+        # D], under a table and a free list of their own. A query there
+        # sees its last cfg.sliding_window keys, so a block that lies wholly
+        # behind the window of the slot's next query is given back
+        # (`window_ensure`) and a slot holds window/bs + 2 blocks at most
+        # between calls, whatever its length, plus a call's rows while one
+        # runs. The table row keeps a sequence's block j in column j, like
+        # the full planes': the columns of blocks given back go stale, and
+        # the window walk never reads them (kernel_gen._window_first).
+        # `_window_first[slot]`: the first block the slot still holds;
+        # `_window_blocks[slot]`: those it holds, in order from there.
+        self.window = cfg.sliding_window if cfg.num_window_layers else 0
+        self.window_pages = None
+        self.window_stats = {"blocks_taken": 0, "blocks_given_back": 0,
+                             "peak_blocks_held": 0, "max_blocks_slot": 0}
+        if self.window:
+            call = (max_seq_len if window_call_rows is None
+                    else min(window_call_rows, max_seq_len))
+            self.num_window_blocks = min(
+                self.num_slots * cdiv(max_seq_len, bs),
+                self.num_slots * self.window_blocks_slot
+                + cdiv(call, bs) + 1)
+            wshape = (cfg.num_window_layers, self.num_window_blocks, bs,
+                      cfg.num_query_groups, cfg.head_dim)
+            self.window_pages = tuple(_new_pool(wshape, dt, 0)
+                                      for _ in range(2))
+            self.window_table = np.zeros(
+                (self.num_slots, self.max_blocks_per_seq), np.int32)
+            self._window_free: deque = deque(range(self.num_window_blocks))
+            self._window_first = np.zeros((self.num_slots,), np.int64)
+            self._window_blocks: List[List[int]] = [
+                [] for _ in range(self.num_slots)]
+
         self.page_table = np.zeros((self.num_slots, self.max_blocks_per_seq),
                                    np.int32)
         self._free: deque = deque(range(nb))
@@ -442,6 +498,17 @@ class PagedKVCache:
                        else tuple(_in_pool_format(a) for a in new))
 
     @property
+    def window_pages(self) -> Optional[Tuple[jnp.ndarray, ...]]:
+        """The window planes' pools (K, V) of a model with sliding-window
+        layers (else None), held like `pages`."""
+        return self._window_pages
+
+    @window_pages.setter
+    def window_pages(self, new):
+        self._window_pages = (None if new is None
+                              else tuple(_in_pool_format(a) for a in new))
+
+    @property
     def scales(self) -> Optional[Tuple[jnp.ndarray, ...]]:
         """The fp32 scale pools of a quantised pool (else None), held
         like `pages`."""
@@ -492,7 +559,39 @@ class PagedKVCache:
         state of a model that has it — always read off the addressable
         arrays, never derived from the param dtype."""
         return self.num_blocks * self.bytes_per_block \
-            + self.max_batch * self.state_bytes_per_slot
+            + self.max_batch * self.state_bytes_per_slot \
+            + self.window_bytes_total
+
+    @property
+    def window_bytes_total(self) -> int:
+        """What the window planes take (0 without sliding-window layers)."""
+        return sum(p.size * p.dtype.itemsize
+                   for p in self.window_pages or ())
+
+    @property
+    def window_blocks_slot(self) -> int:
+        """The most window-plane blocks a slot holds between calls: the
+        window's rows lie in at most cdiv(window, bs) + 1 blocks, and the
+        block the next row opens is taken before the one it closes behind
+        the window is given back."""
+        return cdiv(self.window, self.block_size) + 2
+
+    def window_blocks_held(self) -> int:
+        return self.num_window_blocks - len(self._window_free)
+
+    def window_slot_blocks(self, slot: int) -> List[int]:
+        """The window-plane blocks `slot` holds, from its first on."""
+        return list(self._window_blocks[slot])
+
+    def bytes_held(self) -> int:
+        """Pool bytes the live slots' blocks take now, full planes and
+        window planes together (prefix-cached blocks no slot holds are not
+        counted)."""
+        held = self.blocks_in_use() * self.bytes_per_block
+        if self.window:
+            held += (self.window_blocks_held() * self.window_bytes_total
+                     // self.num_window_blocks)
+        return held
 
     @property
     def bytes_per_block(self) -> int:
@@ -681,6 +780,56 @@ class PagedKVCache:
         self.page_table[slot, idx] = blk
         self._note_usage()
         return True
+
+    # ---- the window planes ------------------------------------------------
+    def window_ensure(self, slot: int, position: int, count: int = 1
+                      ) -> bool:
+        """Before a call that appends rows [position, position + count) to
+        `slot` through the window layers: give back every block that lies
+        wholly behind the window of the call's FIRST query (it sees the
+        keys position - window + 1 .. position), then take the blocks that
+        hold the call's rows. A block given back is on the free list at
+        once. False when the window planes run out (what was taken stays
+        the slot's): their size counts every slot's window and one call, so
+        that is a caller appending more than `window_call_rows`."""
+        bs = self.block_size
+        owned = self._window_blocks[slot]
+        keep = max(position - (self.window - 1), 0) // bs
+        first = int(self._window_first[slot])
+        while owned and first < keep:
+            self._window_free.append(owned.pop(0))
+            first += 1
+            self.window_stats["blocks_given_back"] += 1
+        if not owned:
+            first = keep        # a sequence's first call, or all went back
+        self._window_first[slot] = first
+        need = (position + count - 1) // bs + 1 - first
+        while len(owned) < need:
+            if not self._window_free:
+                return False
+            blk = self._window_free.popleft()
+            self.window_table[slot, first + len(owned)] = blk
+            owned.append(blk)
+            self.window_stats["blocks_taken"] += 1
+        st = self.window_stats
+        st["max_blocks_slot"] = max(st["max_blocks_slot"], len(owned))
+        st["peak_blocks_held"] = max(st["peak_blocks_held"],
+                                     self.window_blocks_held())
+        return True
+
+    def window_trim(self, slot: int, length: int):
+        """After a call: `slot` holds `length` rows, and the blocks behind
+        the window of its next query (at position `length`) go back."""
+        self.window_ensure(slot, length, 0)
+
+    def window_release(self, slot: int):
+        for blk in self._window_blocks[slot]:
+            self._window_free.append(blk)
+        self.window_stats["blocks_given_back"] += len(
+            self._window_blocks[slot])
+        self._window_blocks[slot] = []
+        self._window_first[slot] = 0
+        self.window_table[slot, :] = 0
 
     # ---- EVA: two regions of one table ------------------------------------
     def _eva_hold(self, position: int) -> int:
@@ -976,6 +1125,25 @@ class PagedKVCache:
                                       self.block_size), (
                 f"a pool of shape {pool.shape} for {self.cfg.kv_planes} "
                 f"planes of {nb} blocks")
+        if self.window:
+            # The window planes' own allocator: every block free or held by
+            # one slot; a slot's blocks in its table row from its first on;
+            # taken - given back = held.
+            wfree = list(self._window_free)
+            wheld = [b for blocks in self._window_blocks for b in blocks]
+            assert sorted(wfree + wheld) == list(
+                range(self.num_window_blocks)), (
+                f"window planes: free={len(wfree)} held={len(wheld)} of "
+                f"{self.num_window_blocks}, or a block in two places")
+            st = self.window_stats
+            assert st["blocks_taken"] - st["blocks_given_back"] == len(
+                wheld), (st, len(wheld))
+            for slot, blocks in enumerate(self._window_blocks):
+                first = int(self._window_first[slot])
+                assert list(self.window_table[
+                    slot, first:first + len(blocks)]) == blocks, (
+                    f"slot {slot}: window table row differs from its "
+                    "blocks")
         if self.eva:
             # Both regions and the pending summaries: the table's row is
             # the slot's blocks, region by region, and nothing else.
@@ -1030,6 +1198,8 @@ class PagedKVCache:
         self._slot_blocks[slot] = []
         self._eva_counts[slot] = 0
         self.page_table[slot, :] = 0
+        if self.window:
+            self.window_release(slot)
         if preempted:
             self.stats["preemptions"] += 1
             telemetry.inc("paged_preemptions")

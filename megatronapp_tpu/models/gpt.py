@@ -139,15 +139,21 @@ def gpt_embed(p, tokens: jnp.ndarray, cfg: TransformerConfig,
         return h.astype(dtype or cfg.compute_dtype)
 
 
-def rope_params(cfg: TransformerConfig):
+def rope_params(cfg: TransformerConfig, window: bool = False):
     """(inv_freq, mscale) for the configured rope variant, or (None, 1.0).
 
     Single source of truth for the variant selection so the per-token
     packed-sequence tables inherit YaRN's NTK-by-parts interpolation and
-    mscale exactly like the standard tables."""
+    mscale exactly like the standard tables. `window`: the table of a
+    sliding-window stack's window layers, plain RoPE at sliding_rotary_base
+    over sliding_rotary_percent of a head (the full layers' table where the
+    model names no second one)."""
     # MLA applies rope only on the decoupled position heads.
     rope_dim = (cfg.qk_pos_emb_head_dim if cfg.multi_latent_attention
                 else cfg.head_dim)
+    if window and cfg.sliding_rotary_base is not None:
+        return rotary.rope_frequencies(rope_dim, cfg.sliding_rotary_base,
+                                       cfg.sliding_rotary_percent), 1.0
     if cfg.position_embedding == PositionEmbeddingKind.rope:
         return rotary.rope_frequencies(rope_dim, cfg.rotary_base,
                                        cfg.rotary_percent), 1.0
@@ -158,17 +164,22 @@ def rope_params(cfg: TransformerConfig):
             original_max_position=cfg.yarn_original_max_position,
             beta_fast=cfg.yarn_beta_fast, beta_slow=cfg.yarn_beta_slow,
             rotary_percent=cfg.rotary_percent)
-        m = rotary.yarn_mscale(cfg.rope_scaling_factor, cfg.yarn_mscale_coeff)
+        m = (cfg.yarn_attention_factor
+             if cfg.yarn_attention_factor is not None
+             else rotary.yarn_mscale(cfg.rope_scaling_factor,
+                                     cfg.yarn_mscale_coeff))
         return inv_freq, m
     return None, 1.0
 
 
 def gpt_rope_tables(cfg: TransformerConfig, seq_len: int,
                     position_offset: int = 0,
-                    positions: Optional[jnp.ndarray] = None):
+                    positions: Optional[jnp.ndarray] = None,
+                    window: bool = False):
     """Rope cos/sin tables for arange positions, or explicit per-token
-    `positions` (packed sequences)."""
-    inv_freq, m = rope_params(cfg)
+    `positions` (packed sequences); `window`: a sliding-window stack's
+    window layers' (rope_params)."""
+    inv_freq, m = rope_params(cfg, window)
     if inv_freq is None:
         return None, None
     if positions is None:
@@ -247,6 +258,10 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
     h = gpt_embed(p, tokens, cfg, position_offset, position_ids=positions)
     cos, sin = gpt_rope_tables(cfg, s, position_offset,
                                positions=(positions[0] if zz else positions))
+    window_rope = None
+    if cfg.sliding_window:
+        window_rope = gpt_rope_tables(cfg, s, position_offset,
+                                      positions=positions, window=True)
     if "lead_block" in p:
         h, _ = block_forward(p["lead_block"], h, cfg, cos, sin,
                              attention_mask, ctx=ctx, zigzag=zz,
@@ -255,7 +270,8 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
                            layer_offset=(cfg.moe_first_k_dense
                                          if "lead_block" in p else 0),
                            ctx=ctx, zigzag=zz, segment_ids=segment_ids,
-                           fp8=None if fp8 is None else fp8["block"])
+                           fp8=None if fp8 is None else fp8["block"],
+                           window_rope=window_rope)
     logits = gpt_head(p, h, cfg)
     if zz and not zigzag_keep:
         logits = jnp.take(logits, jnp.asarray(zigzag_inverse_indices(

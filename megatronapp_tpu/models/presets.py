@@ -201,6 +201,44 @@ def lfm2_24b_a2b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def laguna_xs2(**kw) -> TransformerConfig:
+    """poolside/Laguna-XS.2 (33.4B parameters, ~2.8B active) as its
+    config.json publishes it: 40 layers of H 2048 over 8 key/value heads of
+    128, of which layers 0, 4, 8, ... are full attention with 48 query heads
+    (YaRN on half of each head: theta 5e5, factor 64 over 4096, beta 64 / 1,
+    attention factor 1.41589) and the other 30 sliding-window attention of
+    64 query heads over the last 512 keys (plain RoPE, theta 1e4, the whole
+    head); RMS norms on each head's q and k, a per-head sigmoid gate on the
+    attention output; layer 0 ends in a dense SwiGLU of 8192, the other 39
+    in 256 experts of width 512 beside a shared one, top-8 on sigmoid scores
+    + a selection bias, weights renormalised, x 2.5; RMSNorm 1e-6, an untied
+    head over 100,352. Whole it is 67 GB of bf16 weights: a deployment
+    passes its stages' num_layers, as the benchmark's configuration does
+    (perfbench/configs/laguna-xs.2.json, which also lists what the config
+    leaves to the family's convention). Serves through --engine dynamic."""
+    d = dict(num_layers=40, hidden_size=2048, num_attention_heads=48,
+             num_query_groups=8, kv_channels=128, ffn_hidden_size=8192,
+             vocab_size=100352, max_position_embeddings=262144,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-6,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             untie_embeddings_and_output_weights=True, qk_layernorm=True,
+             attention_output_gate=True,
+             position_embedding=PositionEmbeddingKind.yarn,
+             rotary_base=500000.0, rotary_percent=0.5,
+             rope_scaling_factor=64.0, yarn_original_max_position=4096,
+             yarn_beta_fast=64.0, yarn_beta_slow=1.0,
+             yarn_attention_factor=1.4158883083359672,
+             attn_layer_period=4, attn_layer_offset=0,
+             sliding_window=512, sliding_window_heads=64,
+             sliding_rotary_base=10000.0, sliding_rotary_percent=1.0,
+             num_moe_experts=256, moe_router_topk=8, moe_ffn_hidden_size=512,
+             moe_shared_expert_intermediate_size=512, moe_first_k_dense=1,
+             moe_router_score="sigmoid", moe_router_selection_bias=True,
+             moe_router_norm_topk_prob=True, moe_routed_scaling_factor=2.5)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -226,6 +264,7 @@ PRESETS = {
     "evabyte-6.5b": evabyte_6p5b,
     "jamba2-3b": jamba2_3b,
     "lfm2-24b-a2b": lfm2_24b_a2b,
+    "laguna-xs.2": laguna_xs2,
     "gpt2-125m": gpt2_125m,
     "gpt3-2.7b": gpt3_2p7b,
     "mamba-130m": mamba_130m,

@@ -1280,7 +1280,8 @@ def flash_tiles(seq: int, block_q: Optional[int] = None,
 def choose_attention(*, impl: str, batch: int, seq: int, heads: int,
                      head_dim: int, dtype, segments: bool, backend: str,
                      block_q: Optional[int] = None,
-                     block_kv: Optional[int] = None) -> AttentionChoice:
+                     block_kv: Optional[int] = None,
+                     window: int = 0) -> AttentionChoice:
     """The attention implementation and the flash tiles for one call, from
     what the call can see: `impl` as configured ('auto', 'pallas',
     'reference'), batch and query heads ON ONE DEVICE, S, D, the compute
@@ -1294,12 +1295,17 @@ def choose_attention(*, impl: str, batch: int, seq: int, heads: int,
     attention everywhere else: nobody measured float32 compute (the kernels
     feed the MXU bf16), other head sizes, or S under 512. Other backends
     keep XLA's dense attention. Explicit tiles are honoured; unset ones come
-    from `flash_tiles`."""
+    from `flash_tiles`. A sliding-window layer (`window` > 0) takes XLA's
+    dense attention under its band mask whatever was asked for: the flash
+    kernels have no window term (ROADMAP)."""
     def choice(impl_, why):
         return AttentionChoice(impl_, *flash_tiles(seq, block_q, block_kv),
                                why)
 
     shape = f"S={seq} D={head_dim}" + (" segments" if segments else "")
+    if window:
+        return choice("reference", f"sliding window {window}: the flash "
+                                   "kernels have no window term")
     if impl != "auto":
         return choice(impl, shape if impl == "pallas" else impl)
     if backend != "tpu":

@@ -111,14 +111,18 @@ def quant_qmax_of(pages_dtype) -> float:
 
 
 def _paged_name(ragged: bool, quant_dtype: Optional[str] = None,
-                variant: str = "") -> str:
+                variant: str = "", window: bool = False) -> str:
     """A paged kernel's ``pallas_call`` name, which the compiled HLO
     instruction and so every device trace carries. The family prefix is
     the contract trace readers match (perfbench/metrics): ``paged_decode``
     for one query a row, ``paged_mq`` for the ragged multi-query kernel
     that chunked prefill and speculative verify run; variant and pool type
-    follow (``paged_decode_latent_int8``)."""
-    return (("paged_mq" if ragged else "paged_decode") + variant
+    follow (``paged_decode_latent_int8``). A sliding-window layer's walk is
+    a family of its own, ``paged_window_decode`` / ``paged_window_mq``
+    (no reader of ``paged_decode`` or ``paged_mq`` matches it): it reads
+    other planes, and a trace tells the two apart."""
+    return (("paged_window_" if window else "paged_")
+            + ("mq" if ragged else "decode") + variant
             + (f"_{quant_dtype}" if quant_dtype else ""))
 
 
@@ -329,6 +333,14 @@ class PagedSpec:
     latent: bool = False
     klat: int = 0
     dpe: int = 0
+    # A sliding-window layer (window > 0): the query at position t sees the
+    # keys t - window + 1 .. t. The walk of a slot then STARTS at the block
+    # that holds its first query's oldest key (`_window_first`; scalar-
+    # prefetched, the slot's first row in `start_ref`), so no step and no
+    # DMA exists for a block wholly behind the window, whose table entries
+    # may name blocks the slot has given back; the rows of the first block
+    # that lie behind the window are masked.
+    window: int = 0
 
     @property
     def quantized(self) -> bool:
@@ -378,8 +390,10 @@ def emit_paged_kernel(spec: PagedSpec):
     query row i (absolute position kv_len − q_len + i; q_len is 1 where
     the kernel is not ragged) masked causally within the new tail. A
     slot with no cached row gets one step that computes nothing and
-    writes zeros."""
-    pages, ragged = spec.pages, spec.ragged
+    writes zeros. With spec.window the walk starts at the slot's row
+    ``start_ref[b]`` (a block's first) in place of row 0, and a key more
+    than window - 1 positions behind its query is masked."""
+    pages, ragged, window = spec.pages, spec.ragged, spec.window
     width = pages * spec.block_size
     n_pools = 4 if spec.quantized else 2
     n_q, prep, row_q, scores, finish = (
@@ -390,12 +404,17 @@ def emit_paged_kernel(spec: PagedSpec):
         del lid_ref, block_ref
         refs = list(refs)
         qlens_ref = refs.pop(0) if ragged else None
+        start_ref = refs.pop(0) if window else None
         q_refs, refs = refs[:n_q], refs[n_q:]
         tiles = [refs[k * pages:(k + 1) * pages] for k in range(n_pools)]
         o_ref, acc, m_scr, l_scr = refs[n_pools * pages:]
         g = pl.program_id(0)
         b, i = slot_ref[g], step_ref[g]
         kv_len = lens_ref[b]
+        # the first key row of step i
+        row0 = i * width
+        if window:
+            row0 += start_ref[b]
 
         @pl.when(i == 0)
         def _init():
@@ -403,16 +422,18 @@ def emit_paged_kernel(spec: PagedSpec):
             m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
 
-        @pl.when(i * width < kv_len)
+        @pl.when(row0 < kv_len)
         def _fold():
             s, values = scores(prep(*q_refs), tiles)
-            pos = i * width + jax.lax.broadcasted_iota(
+            pos = row0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, width), 1)
             # the local query of row r sits at kv_len - q_len + row_q(r)
             # (one query a slot: at kv_len - 1, behind every cached row)
             abs_q = (kv_len - (qlens_ref[b] if ragged else 1)) + row_q(
                 jax.lax.broadcasted_iota(jnp.int32, (s.shape[-2], 1), 0))
             valid = (pos < kv_len) & (pos <= abs_q)       # [rows, width]
+            if window:
+                valid &= abs_q - pos < window
             s = jnp.where(valid, s, _NEG_INF)
             m_prev = m_scr[...]
             m_new = jnp.maximum(m_prev,
@@ -427,7 +448,7 @@ def emit_paged_kernel(spec: PagedSpec):
             acc[...] = acc[...] * corr + values(p)
             m_scr[...] = m_new
 
-        @pl.when((i + 1) * width >= kv_len)
+        @pl.when(row0 + width >= kv_len)
         def _finalize():
             o_ref[0] = finish(
                 acc[...] / jnp.maximum(l_scr[...], 1e-20)
@@ -544,12 +565,22 @@ def _latent_tile(spec: PagedSpec):
     return 2, prep, lambda r: r % s_q, scores, finish
 
 
-def _walk_steps(page_table, kv_lens, bs: int, pages: int):
+def _window_first(kv_lens, q_lens, bs: int, window: int):
+    """The first block a window walk visits, a slot: the one that holds the
+    oldest key its FIRST query sees, position kv_len - q_len - (window - 1)
+    (q_len is 1 where q_lens is None)."""
+    q = 1 if q_lens is None else q_lens.astype(jnp.int32)
+    return jnp.maximum(kv_lens.astype(jnp.int32) - q - (window - 1), 0) // bs
+
+
+def _walk_steps(page_table, kv_lens, bs: int, pages: int, first=None):
     """The grid steps of a walk, from the table and the lengths.
 
     Slot b takes cdiv(kv_lens[b], pages*bs) steps of `pages` pages (one,
     to write its zeros, if it holds no row; never more than its table
-    row can name). → their count, and for every step g up to the most
+    row can name); with `first` [B] (a window walk, `_window_first`) its
+    steps start at block first[b] and cover the rows from there on, and no
+    entry before first[b] is read. → their count, and for every step g up to the most
     there can be: slot_of[g]; step_of[g], its index within the slot; and
     block_of[g*pages + p], the pool block of its p-th page. A slot's
     steps are consecutive. A page a partial last step does not have
@@ -561,7 +592,8 @@ def _walk_steps(page_table, kv_lens, bs: int, pages: int):
     b, max_blocks = page_table.shape
     max_steps = -(-max_blocks // pages)
     kv_lens = kv_lens.astype(jnp.int32)
-    n = jnp.clip(-(-kv_lens // (pages * bs)), 1, max_steps)
+    rows = kv_lens if first is None else kv_lens - first * bs
+    n = jnp.clip(-(-rows // (pages * bs)), 1, max_steps)
     ends = jnp.cumsum(n)
     g = jnp.arange(b * max_steps, dtype=jnp.int32)
     before = g[:, None] >= ends[None, :]        # slots wholly before step g
@@ -569,10 +601,12 @@ def _walk_steps(page_table, kv_lens, bs: int, pages: int):
     step_of = g - jnp.sum(jnp.where(before, n[None, :], 0), axis=1,
                           dtype=jnp.int32)
     held = jnp.minimum(-(-kv_lens // bs), max_blocks)[slot_of][:, None]
-    first = step_of[:, None] == 0
+    first_step = step_of[:, None] == 0
     page = step_of[:, None] * pages + jnp.arange(pages, dtype=jnp.int32)
+    if first is not None:
+        page += first[slot_of][:, None]
     page = jnp.where(page < held, page,
-                     jnp.where(first, held - 1, page - pages))
+                     jnp.where(first_step, held - 1, page - pages))
     block_of = jnp.where(
         held > 0, page_table[slot_of[:, None], jnp.maximum(page, 0)], 0)
     return ends[-1], slot_of, step_of, block_of.reshape(-1)
@@ -587,8 +621,11 @@ def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
     pages is a block [1, bs, ...] of the STACKED pool [L, NB, bs, ...],
     named by ``block_of``."""
     pages = spec.pages
+    first = None
+    if spec.window:
+        first = _window_first(kv_lens, q_lens, spec.block_size, spec.window)
     total, slot_of, step_of, block_of = _walk_steps(
-        page_table, kv_lens, spec.block_size, pages)
+        page_table, kv_lens, spec.block_size, pages, first)
 
     def slot_block(shape):
         rest = (0,) * (len(shape) - 1)
@@ -605,6 +642,8 @@ def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
     prefetch = [lid, kv_lens, slot_of, step_of, block_of]
     if spec.ragged:
         prefetch.append(q_lens)
+    if spec.window:
+        prefetch.append(first * spec.block_size)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(total,),
@@ -1028,8 +1067,13 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     softmax_scale: Optional[float] = None,
                     k_scales: Optional[jnp.ndarray] = None,
                     v_scales: Optional[jnp.ndarray] = None,
-                    mesh=None, layer=None) -> jnp.ndarray:
+                    mesh=None, layer=None, window: int = 0) -> jnp.ndarray:
     """Ragged paged attention — the single generator entry point.
+
+    window: > 0, a sliding-window layer's call (PagedSpec.window): a query
+    sees the window - 1 keys before it and itself, the walk of a slot starts
+    at the block that holds its first query's oldest key, and the table's
+    entries before that block are never read (one device, bf16 pools).
 
     q [B, Hq, D] (decode) or [B, S_q, Hq, D] with q_lens [B] (ragged
     multi-query); k_pages/v_pages [NB, bs, Hkv, D]; page_table [B, MB]
@@ -1045,6 +1089,10 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     ragged = q_lens is not None
     lid, (k_pages, v_pages, k_scales, v_scales) = _stacked(
         layer, k_pages, v_pages, k_scales, v_scales)
+    if window and (mesh is not None or k_scales is not None):
+        raise NotImplementedError(
+            "a sliding-window walk runs on one device over bf16 pools: no "
+            "mesh, no quantized pool")
     if mesh is not None:
         return _tp_place(q, k_pages, v_pages, page_table, kv_lens, q_lens,
                          softmax_scale, k_scales, v_scales, mesh, lid)
@@ -1066,8 +1114,10 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             s_q, page_table, kv_lens, q_lens, [q])
     spec = PagedSpec(ragged=ragged, s_q=s_q, block_size=bs,
                      num_blocks_seq=mb, hkv=hkv, group=hq // hkv,
-                     scale=float(softmax_scale), **by_pools)
-    out = _walk_call(spec, _paged_name(ragged, spec.quant_dtype), lid,
+                     scale=float(softmax_scale), window=int(window),
+                     **by_pools)
+    out = _walk_call(spec, _paged_name(ragged, spec.quant_dtype,
+                                       window=bool(window)), lid,
                      page_table, kv_lens, q_lens, [q], pools,
                      jax.ShapeDtypeStruct(q.shape, q.dtype),
                      (hkv, s_q * (hq // hkv), d))

@@ -185,9 +185,11 @@ def paged_attention_multiquery_reference(
         page_table: jnp.ndarray, kv_lens: jnp.ndarray, q_lens: jnp.ndarray,
         softmax_scale: Optional[float] = None,
         k_scales: Optional[jnp.ndarray] = None,
-        v_scales: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        v_scales: Optional[jnp.ndarray] = None,
+        window: int = 0) -> jnp.ndarray:
     """Pure-jnp oracle for the multi-query kernel (gathers dense,
-    masks per-(query, kv) causally; int8 pools dequantize dense)."""
+    masks per-(query, kv) causally; int8 pools dequantize dense; `window`:
+    a sliding-window layer's band, a query sees its last `window` keys)."""
     b, s_q, hq, d = q.shape
     nb, bs, hkv, _ = k_pages.shape
     mb = page_table.shape[1]
@@ -207,6 +209,8 @@ def paged_attention_multiquery_reference(
     abs_q = (kv_lens - q_lens)[:, None] + jnp.arange(s_q)[None, :]  # [B,Sq]
     mask = ((pos[None, None, :] <= abs_q[:, :, None])
             & (pos[None, None, :] < kv_lens[:, None, None]))
+    if window:
+        mask &= abs_q[:, :, None] - pos[None, None, :] < window
     s = jnp.where(mask[:, :, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bqhk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -218,10 +222,11 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
                               kv_lens: jnp.ndarray,
                               softmax_scale: Optional[float] = None,
                               k_scales: Optional[jnp.ndarray] = None,
-                              v_scales: Optional[jnp.ndarray] = None
-                              ) -> jnp.ndarray:
+                              v_scales: Optional[jnp.ndarray] = None,
+                              window: int = 0) -> jnp.ndarray:
     """Pure-jnp oracle with the same signature (gathers dense, masks;
-    int8 pools dequantize dense)."""
+    int8 pools dequantize dense; `window`: a sliding-window layer's band,
+    the query at kv_len - 1 sees its last `window` keys)."""
     b, hq, d = q.shape
     nb, bs, hkv, _ = k_pages.shape
     mb = page_table.shape[1]
@@ -238,7 +243,10 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * softmax_scale
     pos = jnp.arange(mb * bs)
-    s = jnp.where(pos[None, None, :] < kv_lens[:, None, None], s, _NEG_INF)
+    mask = pos[None, None, :] < kv_lens[:, None, None]
+    if window:
+        mask &= pos[None, None, :] >= kv_lens[:, None, None] - window
+    s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhs,bshd->bhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)

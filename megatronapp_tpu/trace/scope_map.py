@@ -55,6 +55,11 @@ logger = logging.getLogger(__name__)
 # train_step.py). perfbench/scope_time.py and its metrics read them.
 PARTS = ("attention", "ssm", "conv", "mlp", "moe", "embedding", "head",
          "grad_accum", "optimizer")
+# What tells two kinds of layer apart INSIDE a part: a sliding-window stack's
+# window layers run their attention under `attention/window`
+# (transformer/block.py). A sub-part is no part: the window layers' time
+# stays in `attention`, and Scoped.sub says which of it is theirs.
+SUBPARTS = {"attention": ("window",)}
 OTHER = "other"
 MAX_STEPS = 16
 # An option at its default value: the compiled program is the same, the
@@ -183,6 +188,17 @@ def part_of(op_name: str) -> Tuple[str, str]:
     return part, "bwd" if "transpose(" in op_name else "fwd"
 
 
+def sub_of(op_name: str) -> str:
+    """The sub-part of one ``op_name``: the segment of SUBPARTS that stands
+    right inside its part's, "" where there is none."""
+    segments = _SEGMENT.split(op_name)
+    for i in range(len(segments) - 1, -1, -1):
+        if segments[i] in PARTS:
+            nxt = segments[i + 1] if i + 1 < len(segments) else ""
+            return nxt if nxt in SUBPARTS.get(segments[i], ()) else ""
+    return ""
+
+
 @dataclasses.dataclass
 class Scoped:
     part: str
@@ -190,6 +206,7 @@ class Scoped:
     opcode: str
     shape: str               # shape_head
     op_name: str             # kept for `other` (what it is made of)
+    sub: str = ""            # sub_of: "window" in a window layer's attention
 
 
 @dataclasses.dataclass
@@ -265,7 +282,8 @@ def scope_map(parsed: HloText, kind: str = "",
             part = default_part
         out[ins.name] = Scoped(part, pass_, ins.opcode,
                                shape_head(ins.shape),
-                               op_names[ins.name] if part == OTHER else "")
+                               op_names[ins.name] if part == OTHER else "",
+                               sub_of(op_names[ins.name]))
     from megatronapp_tpu.trace.profiler_collectives import (
         collectives_of,
     )

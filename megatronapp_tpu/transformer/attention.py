@@ -14,6 +14,10 @@ Param leaf layout (per layer, unstacked):
   out_bias   [H]                   logical ('embed',)
   (optional) q_ln_scale, k_ln_scale [D]
   (EVA, cfg.eva_window_size) eva_phi, eva_mu [n_kv, D]: transformer/eva.py
+  (cfg.attention_output_gate) gate_kernel [H, n_heads]   ('embed', 'heads')
+
+n_heads is cfg.num_attention_heads, or in a sliding-window layer of a stack
+that says so cfg.window_heads: the forward reads it off q_kernel.
 """
 
 from __future__ import annotations
@@ -55,10 +59,14 @@ def _announce(site: str, impl: str, interpreted=None) -> None:
         print(line, flush=True)
 
 
-def init_attention_params(rng, cfg: TransformerConfig, out_std: float):
+def init_attention_params(rng, cfg: TransformerConfig, out_std: float,
+                          heads: Optional[int] = None):
+    """`heads`: this layer's query heads where they are not
+    cfg.num_attention_heads (a sliding-window layer's, cfg.window_heads);
+    attention_forward reads the count off q_kernel."""
     h = cfg.hidden_size
     d = cfg.head_dim
-    nq, nkv = cfg.num_attention_heads, cfg.num_query_groups
+    nq, nkv = heads or cfg.num_attention_heads, cfg.num_query_groups
     keys = jax.random.split(rng, 3)
     std = cfg.init_method_std
     p = {
@@ -88,6 +96,10 @@ def init_attention_params(rng, cfg: TransformerConfig, out_std: float):
         eva_p, eva_ax = eva.init_eva_params(jax.random.fold_in(rng, 3), cfg)
         p.update(eva_p)
         ax.update(eva_ax)
+    if cfg.attention_output_gate:
+        p["gate_kernel"] = jax.random.normal(
+            jax.random.fold_in(rng, 4), (h, nq), cfg.params_dtype) * std
+        ax["gate_kernel"] = ("embed", "heads")
     return p, ax
 
 
@@ -121,8 +133,17 @@ def attention_forward(
     fp8=None,
     lora=None,
     kv_plane=None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """x: [B, S, H] → [B, S, H]. Returns (out, new_kv_cache).
+
+    window: > 0 makes this a sliding-window layer: the query at position t
+    sees the keys t - window + 1 .. t. Whole sequences run XLA's dense
+    attention under a band mask (the flash kernels and the cp rings have no
+    window term: ROADMAP); a paged step's kernels walk from the block that
+    holds position len - window and mask the rows behind it
+    (kernel_gen.paged_attention(window=)), and kv_cache / page_table are
+    then the WINDOW pools and their table (inference/paged_cache.py).
 
     page_table: [B, max_blocks_per_seq] int32 — marks kv_cache as PAGED
     block-pool storage, the whole STACKED pool
@@ -212,6 +233,20 @@ def attention_forward(
                            layer_id)
     kv_kernel = _dist.apply("weight", resolve_param(p["kv_kernel"]),
                             layer_id)
+    # this layer's own query heads (a sliding-window layer may have more
+    # than cfg.num_attention_heads, over the same key/value heads)
+    nq = q_kernel.shape[-1] // d
+    gated = "gate_kernel" in p
+    if (window or gated) and (
+            tp_sharded or overlap or lora is not None or cfg.is_eva
+            or (ctx is not None and ctx.cp > 1)
+            or (kv_cache is not None and page_table is None)
+            or kv_scales is not None):
+        raise NotImplementedError(
+            "a sliding-window layer or a gated attention output runs whole "
+            "sequences under XLA's dense attention, or paged bf16 pools on "
+            "one device: no tp-sharded stage body, tp-overlap rings, cp, "
+            "lora, EVA, dense (unpaged) cache or quantized pool")
     if tp_sharded:
         # Ambient-manual tp-sharded stage body: see docstring. Local head
         # counts; s stays the LOCAL seq chunk length, sf the full length.
@@ -397,18 +432,21 @@ def attention_forward(
                     active, plane, width=s)
             sc_kw = ({} if new_scales is None else
                      {"k_scales": new_scales[0], "v_scales": new_scales[1]})
+            win = f", sliding window {window}" if window else ""
             if ragged:
                 paged_out = kernel_gen.paged_attention(
                     q, ck, cv, page_table, cache_positions + counts,
-                    q_lens=counts, mesh=mesh, layer=plane, **sc_kw)
-                _announce("paged multi-query",
+                    q_lens=counts, mesh=mesh, layer=plane, window=window,
+                    **sc_kw)
+                _announce("paged multi-query" + win,
                           "pallas ragged paged kernel",
                           kernel_gen._interpret())
             else:
                 paged_out = kernel_gen.paged_attention(
                     q[:, 0], ck, cv, page_table, cache_positions + 1,
-                    mesh=mesh, layer=plane, **sc_kw)[:, None]
-                _announce("paged decode", "pallas paged kernel",
+                    mesh=mesh, layer=plane, window=window,
+                    **sc_kw)[:, None]
+                _announce("paged decode" + win, "pallas paged kernel",
                           kernel_gen._interpret())
             if tp_paged:
                 paged_out = _replicate_heads(paged_out, ctx)
@@ -497,7 +535,7 @@ def attention_forward(
         choice = fa.choose_attention(
             impl=cfg.attention_impl, batch=b_dev, seq=s, heads=nq_dev,
             head_dim=d, dtype=q.dtype, segments=segment_ids is not None,
-            backend=_backend(),
+            backend=_backend(), window=window,
             block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv)
         impl = choice.impl
         # GSPMD cannot partition a pallas_call (it would replicate full
@@ -576,12 +614,27 @@ def attention_forward(
                             == segment_ids[:, None, None, :])
                 attention_mask = (seg_mask if attention_mask is None
                                   else attention_mask & seg_mask)
+            if window:
+                # the sliding window's band, beside the causal mask: the
+                # query at t sees keys t - window + 1 .. t (positions in a
+                # packed row run on across segments, which the segment mask
+                # cuts: a band over the row's index is the band within)
+                at = jnp.arange(s)
+                band = (at[:, None] - at[None, :] < window)[None, None]
+                attention_mask = (band if attention_mask is None
+                                  else attention_mask & band)
             attn_out = dot_product_attention(
                 q, k, v, mask_type=mask_type,
                 attention_mask=attention_mask, softmax_scale=None,
                 softmax_in_fp32=cfg.attention_softmax_in_fp32,
                 q_offset=q_offset, layer_id=layer_id)
     attn_out = scope_capture("context", attn_out, layer_id)
+    if gated:
+        # one sigmoid gate a head, from the layer's normed input
+        gate = jax.nn.sigmoid(dense(
+            x, resolve_param(p["gate_kernel"]).astype(cfg.compute_dtype)
+        ).astype(jnp.float32))
+        attn_out = (attn_out * gate[..., None]).astype(attn_out.dtype)
 
     out_kernel = _dist.apply("weight", resolve_param(p["out_kernel"]),
                              layer_id)

@@ -13,6 +13,7 @@ pre_mlp_layernorm → mlp → +residual).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -43,9 +44,14 @@ def _norm_scale(cfg: TransformerConfig):
 
 def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
     """A layer's first half: its norm and its mixer (attention, MLA, or with
-    `ssm` a hybrid stack's other kind: a selective-state-space mixer, or
-    with cfg.shortconv_kernel a gated short convolution)."""
-    if ssm and cfg.shortconv_kernel:
+    `ssm` a hybrid stack's other kind: a selective-state-space mixer, with
+    cfg.shortconv_kernel a gated short convolution, with cfg.sliding_window
+    a sliding-window attention layer of cfg.window_heads query heads)."""
+    if ssm and cfg.sliding_window:
+        name = "attention"
+        mix_p, mix_ax = init_attention_params(rng, cfg, out_std,
+                                              heads=cfg.window_heads)
+    elif ssm and cfg.shortconv_kernel:
         from megatronapp_tpu.transformer.shortconv import (
             init_shortconv_params,
         )
@@ -124,8 +130,14 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                   page_table=None, active=None, chunk_counts=None,
                   tp_sharded: bool = False, kv_scales=None,
                   fp8=None, lora=None, kv_plane=None, ssm_state=None,
-                  state_rows=None):
+                  state_rows=None, window_rope=None):
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
+
+    window_rope: (cos, sin) of a sliding-window stack's window layers
+    (models/gpt.py gpt_rope_tables(window=True)). Not None marks THIS layer
+    as one of them: it attends through cfg.sliding_window keys, rotates by
+    that table, and in a paged step kv_cache, page_table and kv_plane are
+    the window pools', their table's and its plane of them.
 
     A layer whose params hold "ssm" in place of "attention" runs the
     selective-state-space mixer (transformer/ssm.py) as its first half.
@@ -213,7 +225,13 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     # text and a reader joins the two by instruction name (PERF.md, PR 36).
     def attend():
         mask = attention_mask
-        with jax.named_scope("attention"):
+        cos, sin = ((rope_cos, rope_sin) if window_rope is None
+                    else window_rope)
+        # "window" is no part of its own (trace/scope_map.PARTS): a window
+        # layer's operations stay in `attention`, and carry the sub-part.
+        with jax.named_scope("attention"), (
+                jax.named_scope("window") if window_rope is not None
+                else contextlib.nullcontext()):
             if cfg.multi_latent_attention:
                 if lora is not None:
                     raise ValueError(
@@ -243,7 +261,9 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                     new_cache = None
             else:
                 attn_out, new_cache = attention_forward(
-                    p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                    p["attention"], h, cfg, cos, sin, mask,
+                    window=(cfg.sliding_window if window_rope is not None
+                            else 0),
                     kv_cache=kv_cache, cache_index=cache_index,
                     cache_positions=cache_positions, layer_id=layer_id,
                     ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
@@ -398,6 +418,8 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     """A hybrid stack (cfg.attn_layer_period): the state-space layers'
     first halves stacked [num_ssm_layers, ...] under "mixers_ssm" (gated
     short convolutions: [num_conv_layers, ...] under "mixers_conv"), the
+    sliding-window attention layers: [num_window_layers, ...] under
+    "mixers_swa"), the
     attention layers' [num_attention_layers, ...] under "mixers_attn", and
     the layers' feed-forward halves under "ffn", each in layer order
     (hybrid_layer_loop walks them): every layer's [num_layers, ...], or in
@@ -412,6 +434,7 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     lead = cfg.moe_first_k_dense
 
     kinds = {
+        "mixers_swa" if cfg.sliding_window else
         "mixers_conv" if cfg.shortconv_kernel else "mixers_ssm": (
             keys[~attends, 0], functools.partial(
                 _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
@@ -500,8 +523,9 @@ def hybrid_layer_params(stacked_p, attends: bool, k, layer_id,
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             stack)
-    kind = "mixers_attn" if attends else (
-        "mixers_ssm" if "mixers_ssm" in stacked_p else "mixers_conv")
+    kind = "mixers_attn" if attends else next(
+        k for k in ("mixers_ssm", "mixers_conv", "mixers_swa")
+        if k in stacked_p)
     if lead:
         ffn = row(stacked_p["ffn_lead"], layer_id)
     elif "ffn_lead" in stacked_p:
@@ -565,8 +589,11 @@ def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
 def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
                   rope_cos=None, rope_sin=None, attention_mask=None,
                   layer_offset: int = 0, ctx=None, zigzag: bool = False,
-                  segment_ids=None, tp_sharded: bool = False, fp8=None):
+                  segment_ids=None, tp_sharded: bool = False, fp8=None,
+                  window_rope=None):
     """Run all stacked layers via lax.scan. Returns (x, moe_aux_sum).
+
+    window_rope: the window layers' (cos, sin) of a sliding-window stack.
 
     tp_sharded: thread the ambient-manual tp-sharded stage-body path
     through every layer (pp pipeline; see layer_forward).
@@ -606,15 +633,19 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
                 "ep all-to-all, and tp, cp and pp layouts of its layer "
                 "loop, are not written yet (ROADMAP M4)")
 
-        def one_layer(layer_p, h, lid):
+        def one_layer(layer_p, h, lid, windowed=False):
             (h2, _), _ = layer_forward(
                 layer_p, h, cfg, rope_cos, rope_sin, attention_mask,
-                layer_id=lid, ctx=ctx, segment_ids=segment_ids)
+                layer_id=lid, ctx=ctx, segment_ids=segment_ids,
+                window_rope=window_rope if windowed else None)
             return h2
 
-        one_layer = _remat_wrap(one_layer, cfg.remat_policy)
+        # a body a kind of layer: which one a layer is, is static
+        bodies = {w: _remat_wrap(functools.partial(one_layer, windowed=w),
+                                 cfg.remat_policy) for w in (False, True)}
         x = hybrid_layer_loop(
-            cfg, x, lambda h, attends, k, lid, lead=False: one_layer(
+            cfg, x, lambda h, attends, k, lid, lead=False: bodies[
+                bool(cfg.sliding_window) and not attends](
                 hybrid_layer_params(stacked_p, attends, k, lid, lead), h,
                 lid + layer_offset))
         return x, jnp.zeros((), jnp.float32)

@@ -880,6 +880,82 @@ def test_engine_conv_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
                                      * fc1.dtype.itemsize)
 
 
+# What perfbench/configs/laguna-xs.2.json cuts the preset to: published
+# layers 0-4.
+LAGUNA_CUT = {"num_layers": 5}
+
+
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_window_moe_steps_at_cell_shapes(one_chip, chip_compile,
+                                                which):
+    """The two jits for a sliding-window stack with MoE feed-forwards at
+    Laguna-XS.2's published widths and the code cell's sizes (a small
+    vocabulary; the cell's own 5 layers: a leading dense full-attention
+    layer, then sliding, sliding, sliding, full, each with all 256 experts
+    and a shared one): 32 slots of up to 19,456 positions, 8 key/value heads
+    of 128 under 6 query heads each in the two full planes and 8 in the
+    three window planes, a prefill call of the 2048 positions the engine
+    chooses for the cell on this chip. Mosaic takes both families at both
+    group sizes, the window walk's traced start among its prefetched
+    scalars; the window layers' kernels carry their own names; the step
+    aliases the full and the window pools alike and cuts no layer's experts
+    out of their stacks."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    batch, blocks, seq = 32, 32768, 19456
+    width = _cell_prefill_width(one_chip, "laguna-xs.2", seq, **LAGUNA_CUT)
+    assert width == 2048
+    cfg = PRESETS["laguna-xs.2"](vocab_size=1024, params_dtype=jnp.bfloat16,
+                                 **LAGUNA_CUT)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=batch,
+                                 max_seq_len=seq, paged=True, num_blocks=8,
+                                 prefill_chunk=width)
+    # every slot's window (512 / 16 + 2 blocks) and one call's rows
+    assert eng.pool.num_window_blocks == 32 * 34 + 2048 // 16 + 1
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + tuple(_sds(p.shape, p.dtype, one_chip)
+                for p in eng.pool.window_pages)
+    assert pools[0].shape == (2, blocks, 16, 8, 128)
+    assert pools[2].shape == (3, 1217, 16, 8, 128)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pools, None, (i32(batch, mb), i32(batch, mb)),
+            i32(batch), _sds((batch,), jnp.bool_, one_chip), None).compile()
+        _assert_kernels_named(compiled, "paged_decode",
+                              "paged_window_decode", "grouped_gemm")
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pools, None,
+            (i32(1, mb), i32(1, mb)), i32(1), i32(1),
+            _sds((1,), jnp.bool_, one_chip), None, None, i32(1)).compile()
+        _assert_kernels_named(compiled, "paged_mq", "paged_window_mq",
+                              "grouped_gemm")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    # (an expert's whole matrix is one block of 4 or 2 MiB here: the VMEM a
+    # call asks stays under _assert_grouped_gemms' floor for large experts)
+    assert "ragged-dot" not in compiled.as_text()
+    assert len(_SCOPED.findall(compiled.as_text())) >= 2
+    fc1 = abstract["block"]["ffn"]["moe"]["fc1_kernel"]
+    assert fc1.shape == (4, 256, 2048, 1024)
+    assert not _pool_shaped(compiled, r"copy|(?<!update[_-])slice",
+                            [fc1.shape[1:], (1,) + fc1.shape[1:]])
+    assert mem.temp_size_in_bytes < (fc1.size // fc1.shape[0]
+                                     * fc1.dtype.itemsize)
+
+
 # ---------------------------------------------------------------------------
 # Kernel names (ISSUE 26): a device trace names an event by its HLO
 # instruction, and perfbench's readers tell kernels apart by family prefix

@@ -190,6 +190,8 @@ def _run_with(monkeypatch, capsys, server_ok):
                         lambda tiny: _phase("share"))
     monkeypatch.setattr(chip_smoke, "phase_conv",
                         lambda tiny: _phase("conv"))
+    monkeypatch.setattr(chip_smoke, "phase_window",
+                        lambda tiny: _phase("window"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -202,7 +204,51 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-reference",
                                             "server", "hybrid", "eva",
-                                            "share", "conv"]
+                                            "share", "conv", "window"]
+
+
+def _window_lines(device=TPU, **kw):
+    res = {"tokens": 251, "in_vocab": True,
+           "moe": {"tokens": 79, "assignments": 632, "experts_here": 8},
+           "window": {"blocks_taken": 18, "blocks_given_back": 18,
+                      "blocks_held": 0, "rows_walked": 2872,
+                      "rows_full_walk": 6920},
+           "kernels": ["grouped_gemm", "paged_decode", "paged_window_decode"],
+           "pool_shapes": [[2, 128, 16, 2, 64], [2, 128, 16, 2, 64],
+                           [3, 37, 16, 2, 64], [3, 37, 16, 2, 64]],
+           "pool_bytes": 100, "alias_bytes": 128, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            "attention: paged decode -> pallas paged kernel (compiled)",
+            "attention: paged decode, sliding window 32 -> pallas paged "
+            "kernel (compiled)",
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"pool_shapes": [[2, 128, 16, 2, 64], [2, 128, 16, 2, 64]]},
+     "not two full planes"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"kernels": ["grouped_gemm", "paged_decode"]}, "not both paged"),
+    ({"tokens": 150}, "tokens came back"),
+    ({"window": {"blocks_taken": 18, "blocks_given_back": 17,
+                 "blocks_held": 1, "rows_walked": 1, "rows_full_walk": 2}},
+     "not all given back"),
+    ({"window": {"blocks_taken": 18, "blocks_given_back": 18,
+                 "blocks_held": 0, "rows_walked": 9, "rows_full_walk": 9}},
+     "no row spared"),
+    ({"moe": {"tokens": 79, "assignments": 630, "experts_here": 8}},
+     "the picks are not tokens"),
+])
+def test_check_window(kw, needle):
+    """The window phase's facts: two pairs of page pools, aliased in and
+    out; both families of paged kernel in the compiled step; every token
+    back; the window planes' blocks all given back and rows spared; every
+    pick counted."""
+    out = chip_smoke.check_window(0, _window_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_window(1, _window_lines())["ok"]
 
 
 def _hybrid_lines(device=TPU, **kw):
@@ -402,7 +448,7 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
                                             "server", "hybrid", "eva",
-                                            "share", "conv"]
+                                            "share", "conv", "window"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -435,7 +481,13 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert conv["moe"]["assignments"] == conv["moe"]["tokens"] * 2 * 4
     assert conv["expert_stack_slices"] == 0
     assert conv["alias_bytes"] >= conv["pool_bytes"] > 0
-    assert sum("not a TPU" in ln for ln in lines) == 7
+    window = phases[7]
+    assert window["window"]["blocks_taken"] == window["window"][
+        "blocks_given_back"] > 0
+    assert window["pool_shapes"][2][:2] == [3, 37]
+    assert window["moe"]["assignments"] == window["moe"]["tokens"] * 2 * 4
+    assert window["alias_bytes"] >= window["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 8
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):

@@ -30,6 +30,9 @@ KINDS = {
     "conv": dict(attn_layer_period=2, attn_layer_offset=1,
                  shortconv_kernel=3),
     "eva": dict(eva_window_size=32, eva_chunk_size=4),
+    "window": dict(attn_layer_period=2, attn_layer_offset=0,
+                   sliding_window=8, num_query_groups=2,
+                   sliding_window_heads=8),
     "double": dict(MLA, num_moe_experts=8, moe_zero_experts=4,
                    moe_router_topk=3, moe_experts_held=(0, 4),
                    moe_ffn_hidden_size=32, moe_shortcut_double_layer=True),
@@ -40,6 +43,8 @@ LACKS = {
     "conv": {"rewind", "snapshot", "handoff", "adapters", "shard", "prefix"},
     "eva": {"rewind", "snapshot", "handoff", "adapters", "shard", "quantize",
             "prefix"},
+    "window": {"rewind", "snapshot", "handoff", "adapters", "shard",
+               "quantize", "prefix"},
     "double": {"handoff", "adapters", "shard", "quantize"},
 }
 # capability -> (the constructor argument that asks for it, how a refusal
