@@ -23,9 +23,12 @@ for MLA pools); the legacy names in paged_attention.py are thin wrappers
 over it. Both run ONE scaffold, ``emit_paged_kernel`` under
 ``_walk_call`` (ISSUE 29): a one-dimensional grid over the REAL steps of
 a call — a slot is walked for the blocks it holds, ``pages_per_step``
-pages a step (a key tile 128 wide at blocks of 16, 256 for latent pages),
-and no step, branch or DMA exists for a table entry past its length —
-around the body's tile functions (``_dense_tile``, ``_latent_tile``). The
+pages a step (a key tile 128 or 256 wide at blocks of 16, 256 for latent
+pages), and no step, branch or DMA exists for a table entry past its
+length — around the body's tile functions (``_dense_tile``,
+``_latent_tile``). A pool whose page is whole tiles stays in HBM and the
+kernel starts the copies of a step's pages itself, a step ahead (ISSUE
+46); the others are the pipeline's blocked operands. The
 mathematics is the legacy bodies' (online softmax in fp32, causal tail
 mask, in-register dequant), folded a tile at a time:
 tests/test_kernel_gen.py holds the
@@ -134,8 +137,9 @@ def default_kv_tile(quant_dtype: Optional[str]):
     return QUANT_DTYPES[quant_dtype][1]
 
 
-# What a walk's page blocks, double-buffered by the pipeline, may take of
-# VMEM. Mosaic's default scope is 16 MiB and a compute block's
+# What a step's pages, held twice (this step's and the next one's: the
+# kernel's own two buffers, or the pipeline's for blocked operands), may
+# take of VMEM. Mosaic's default scope is 16 MiB and a compute block's
 # temporaries (the transposed tile, scores, probabilities) share it.
 WALK_VMEM_BUDGET = 8 * 1024 * 1024
 
@@ -145,11 +149,11 @@ def _padded(n: int, to: int) -> int:
 
 
 def _pages_vmem_bytes(pools, kv_tile) -> int:
-    """Bytes ONE page of every pool takes in VMEM together. pools: the
-    stacked [L, NB, bs, ...] arrays of a call, KV pools (tiled
-    `kv_tile`) first, their fp32 scale pools (tiled (8, 128)) after. A
-    page's last dim is padded to the tile's lanes, the one before to its
-    sublanes."""
+    """Bytes ONE page of every pool takes in VMEM together, in a buffer
+    of the kernel's own as in a blocked operand. pools: the stacked
+    [L, NB, bs, ...] arrays of a call, KV pools (tiled `kv_tile`) first,
+    their fp32 scale pools (tiled (8, 128)) after. A page's last dim is
+    padded to the tile's lanes, the one before to its sublanes."""
     total = 0
     for i, pool in enumerate(pools):
         sub, lane = kv_tile if i < 2 else (8, 128)
@@ -159,16 +163,48 @@ def _pages_vmem_bytes(pools, kv_tile) -> int:
     return total
 
 
-# Keys a step of the walk folds. The dense family's pages are large (a
-# page of 32 heads is 128 KiB a pool) and its kernels run at their roof at
-# 128. A latent page is 20 KiB and a latent step's cost is what it does
-# whatever the keys: 16 page DMAs and their index maps, and the rescaling
-# of a [rows, klat] float32 accumulator. At 256 keys a step the decode
-# kernel takes a tenth less and the ragged one 27-31% less than at 128; at
-# 512 the [rows, 512] scores take the query tile's VMEM and the ragged
-# kernel is slower again (a v5e, 16 and 64 heads: PERF.md section 6, PR 39).
+# Keys a step of the walk folds. A step costs what it does whatever the
+# keys (the grid step, a query block's and an output block's bookkeeping,
+# the rescaling of the accumulator) and a page copy has a price of its own
+# whatever it holds (0.045 us started by the kernel, 0.063-0.078 us as a
+# blocked operand of the pipeline), so a walk of small pages wants many of
+# them a step and a walk of large ones is at its byte roof at 128 keys
+# already. The dense family folds WIDE_KEY_TILE keys where the kernel copies
+# the pages itself and a step of that many holds no more than
+# WIDE_STEP_BYTES of them as VMEM pads them (`_pages_vmem_bytes`: 8
+# key/value heads of 128), else KEY_TILE. Kernels alone on a v5e, us a call
+# at 128 -> 256 keys (PERF.md section 6, PR 46), one query a slot: 8 heads
+# of 128 copied by the kernel 1,848 -> 1,406 (1,322 at 512; as blocked
+# operands 2,225 -> 1,956). Over the cap: 32 heads of 128 take 1,818-1,834
+# in every form and 32 heads of 80 are SLOWER at 256, 851-862 -> 872, at
+# twice the VMEM, which a ragged call's query tile wants. A walk of blocked
+# operands keeps 128, the kernels and the bits it had before ISSUE 46:
+# alone its small pages gain at 256 too (8 heads of 64 1,276 -> 1,238, one
+# head of 128 1,192 -> 1,103, their prefill calls 1,264 -> 1,164 and 206 ->
+# 179), but neither cell's tokens a second moved (4,901.8 -> 4,892.6 and
+# 3,475.8 -> 3,462.8, a pair each, inside their spread) and the other sums'
+# order gave one of two runs of the first cell an emitted token 1.618 under
+# its reference's maximum where the cell allows 1.6 (0.99 at 128 keys on
+# the same seed): a re-drawn tail for no gain. A latent page is 20 KiB: its
+# decode kernel takes 732 us at 256 keys and 672 at 512 (693 at 1,024), but
+# at 512 the [rows, 512] scores take the query tile's VMEM and the ragged
+# kernel was slower (PR 39, 16 and 64 heads); a ragged call and a one-query
+# call of one slot have to fold the same tiles to give the same bits, so
+# both keep 256.
 KEY_TILE = 128
+WIDE_KEY_TILE = 256
+WIDE_STEP_BYTES = 2 * 1024 * 1024
 LATENT_KEY_TILE = 256
+
+
+def dense_key_tile(block_size: int, page_bytes: int, copied) -> int:
+    """Keys a step of a dense walk folds, from what the code can see:
+    `page_bytes`, one page of every pool of the call as VMEM holds it, and
+    `copied`, whether the kernel starts the copies of the key and the
+    value pages itself."""
+    wide = (all(copied) and WIDE_KEY_TILE // block_size * page_bytes
+            <= WIDE_STEP_BYTES)
+    return WIDE_KEY_TILE if wide else KEY_TILE
 
 
 def pages_per_step(block_size: int, page_bytes: int, table_blocks: int,
@@ -176,9 +212,10 @@ def pages_per_step(block_size: int, page_bytes: int, table_blocks: int,
                    key_tile: int = KEY_TILE) -> int:
     """Pages in one compute block of the walk (`PagedSpec.pages`), from
     what the code can see: as many as make the key tile `key_tile` wide (8
-    at block_size 16, 16 for latent pages), fewer if two buffers of them
-    would pass `budget` (`page_bytes`: one page of every pool of the call,
-    as VMEM holds it), never more than the table holds."""
+    or 16 at block_size 16, 16 for latent pages), fewer if two buffers of
+    them (the step's and the next one's, whoever copies them) would pass
+    `budget` (`page_bytes`: one page of every pool of the call, as VMEM
+    holds it), never more than the table holds."""
     return max(1, min(key_tile // block_size, budget // (2 * page_bytes),
                       table_blocks))
 
@@ -193,7 +230,7 @@ VMEM_SCOPE = 16 * 1024 * 1024
 
 def _query_vmem_budget(pools, kv_tile, pages: int) -> int:
     """What a ragged walk's query tile may take of VMEM: the scope less the
-    step's page blocks, twice (the pipeline's buffers); less one more copy
+    step's pages, twice (the two buffers); less one more copy
     of the key and the value tile as a step computes on them (transposed in
     the pool's dtype; float32 where the pages are quantized and
     dequantized in-register); less 1 MiB."""
@@ -308,10 +345,11 @@ class PagedSpec:
     the walk takes (the key tile is pages × block_size wide) and
     kv_tile the (sublane, lane) min tile a KV page is padded to in VMEM
     (dtype-dependent — fp8/int8 want (32, 128)); the entry points
-    derive both from the shapes (`pages_per_step`, `default_kv_tile`),
-    no caller sets them. The tp head-shard axis is NOT part of the body
-    spec — sharding is pure placement (``paged_attention(..., mesh=)``
-    wraps the same emitted kernel in a full-manual shard_map)."""
+    derive both, and `copied`, from the shapes (`pages_per_step`,
+    `default_kv_tile`, `_page_is_tiles`), no caller sets them. The tp
+    head-shard axis is NOT part of the body spec — sharding is pure
+    placement (``paged_attention(..., mesh=)`` wraps the same emitted
+    kernel in a full-manual shard_map)."""
 
     ragged: bool
     quant_dtype: Optional[str]
@@ -323,6 +361,10 @@ class PagedSpec:
     scale: float
     kv_tile: tuple = (16, 128)
     pages: int = 1
+    # A pool, in the call's order: whether the kernel starts the copies of
+    # its pages itself (`_page_is_tiles`) or the pipeline brings them as
+    # blocked operands. () is all blocked.
+    copied: tuple = ()
     # MLA latent layout (ISSUE 17): pages hold [block, klat] latent +
     # [block, dpe] roped-key blocks with NO per-head axis; hkv carries
     # the QUERY head count (every head attends the one shared latent,
@@ -345,6 +387,10 @@ class PagedSpec:
     @property
     def quantized(self) -> bool:
         return self.quant_dtype is not None
+
+    @property
+    def pools_copied(self) -> tuple:
+        return self.copied or (False,) * (4 if self.quantized else 2)
 
     def __post_init__(self):
         if not self.ragged and self.s_q != 1:
@@ -382,11 +428,33 @@ def emit_paged_kernel(spec: PagedSpec):
     that slot's ``step_of[g]``-th compute block of ``spec.pages`` pages, a
     key tile pages × bs wide. A slot is visited for blocks
     0 … cdiv(lens[b], bs) − 1 and nothing else: no grid step, no branch
-    and no DMA for a table entry past the slot's length (`_walk_call`'s
-    index maps; Pallas keeps step g+1's pages in flight while step g is
-    computed). The last step of a slot may be partial: its missing pages
-    re-name pages the pipeline already holds and their columns are
-    masked. Online softmax in fp32 over the valid range [0, lens[b]),
+    and no DMA for a table entry past the slot's length.
+
+    How a step gets its pages is decided a pool (``spec.copied``,
+    `_page_is_tiles`). A pool whose page is whole tiles stays in HBM
+    (``memory_space=pl.ANY``) and the kernel copies its pages itself
+    (ISSUE 46): it owns a buffer [2, pages*bs, ...] a pool and a DMA
+    semaphore a half and pool; step g waits for its own pages in half
+    g % 2 and, before it computes, starts step g+1's into the other half,
+    one ``make_async_copy`` a page (``block_of[(g+1)*pages + p]``, read
+    from SMEM), whichever slot the next step belongs to; step 0 starts its
+    own first. The pages are a loop's turns, traced once: spelled out page
+    by page the four families of a stack took a serving program 20 s
+    longer to trace (PERF.md section 6, PR 46). A page that a slot's partial last step lacks is not copied;
+    its rows of the buffer are blanked, since the probabilities (0) of its
+    masked columns still meet them in the value product. The tile a step
+    computes on is the buffer's half as it lies. Any other pool (a head dim
+    of 80 or 64, one key/value head, the roped keys' 64 columns, a latent
+    page of fewer rows than its dtype's sublane tile, scale pages: Mosaic
+    refuses to slice a buffer whose tiled dims are no whole tiles) is
+    ``pages`` blocked operands [1, bs, ...] of the pipeline,
+    which keeps step g+1's in flight while step g is computed and joins
+    them into the tile (`_tile_of`); there a missing page re-names a page
+    the pipeline already holds (`_walk_steps`), so nothing is copied for
+    it. Both kinds may meet in one call (the latent plane copied, the roped
+    keys blocked).
+
+    Online softmax in fp32 over the valid range [0, lens[b]),
     query row i (absolute position kv_len − q_len + i; q_len is 1 where
     the kernel is not ragged) masked causally within the new tail. A
     slot with no cached row gets one step that computes nothing and
@@ -394,27 +462,104 @@ def emit_paged_kernel(spec: PagedSpec):
     ``start_ref[b]`` (a block's first) in place of row 0, and a key more
     than window - 1 positions behind its query is masked."""
     pages, ragged, window = spec.pages, spec.ragged, spec.window
-    width = pages * spec.block_size
-    n_pools = 4 if spec.quantized else 2
+    bs = spec.block_size
+    width = pages * bs
+    copied = spec.pools_copied
     n_q, prep, row_q, scores, finish = (
         _latent_tile if spec.latent else _dense_tile)(spec)
 
     def kernel(lid_ref, lens_ref, slot_ref, step_ref, block_ref, *refs):
-        # layer and block indirection are consumed by the index maps
-        del lid_ref, block_ref
         refs = list(refs)
         qlens_ref = refs.pop(0) if ragged else None
         start_ref = refs.pop(0) if window else None
         q_refs, refs = refs[:n_q], refs[n_q:]
-        tiles = [refs[k * pages:(k + 1) * pages] for k in range(n_pools)]
-        o_ref, acc, m_scr, l_scr = refs[n_pools * pages:]
+        pools = []
+        for own in copied:
+            pools.append(refs.pop(0) if own else
+                         [refs.pop(0) for _ in range(pages)])
+        o_ref, acc, m_scr, l_scr, *bufs = refs
         g = pl.program_id(0)
         b, i = slot_ref[g], step_ref[g]
         kv_len = lens_ref[b]
-        # the first key row of step i
-        row0 = i * width
-        if window:
-            row0 += start_ref[b]
+
+        def span(step):
+            """(the first key row of grid step `step`, the pages of it that
+            its slot holds)."""
+            slot = slot_ref[step]
+            first = step_ref[step] * width
+            if window:
+                first += start_ref[slot]
+            held = jnp.minimum(-(-lens_ref[slot] // bs), spec.num_blocks_seq)
+            return first, jnp.clip(held - first // bs, 0, pages)
+
+        row0, n = span(g)
+        tiles = list(pools)
+        if any(copied):
+            # Step g computes on half g % 2 of the buffers. Its pages were
+            # started a step ago (step 0 starts its own); step g + 1's,
+            # whichever slot's they are, start now into the other half.
+            sem = bufs.pop()
+            half = g % 2
+            mine = [k for k, own in enumerate(copied) if own]
+            for k, buf in zip(mine, bufs):
+                tiles[k] = buf.at[half]
+
+            def rows_of(p):
+                return pl.ds(pl.multiple_of(p * bs, bs), bs)
+
+            def page_copies(step, into, p):
+                return [pltpu.make_async_copy(
+                    pools[k].at[lid_ref[0], block_ref[step * pages + p]],
+                    buf.at[into, rows_of(p)], sem.at[into, j])
+                    for j, (k, buf) in enumerate(zip(mine, bufs))]
+
+            def pages_between(lo, hi, do, **kw):
+                """do(p) for the pages lo <= p < hi of a step: one loop,
+                traced once however many pages a step has."""
+                jax.lax.fori_loop(lo, hi, lambda p, _: do(p), None, **kw)
+
+            def start(step, into):
+                """Start the copies of the pages step `step` holds."""
+                def page(p):
+                    for copy in page_copies(step, into, p):
+                        copy.start()
+
+                # a whole step (all but a slot's last) runs straight
+                # through: a loop of `count` turns costs a tenth more
+                count = span(step)[1]
+                pl.when(count == pages)(
+                    lambda: pages_between(0, pages, page, unroll=True))
+                pl.when(count < pages)(lambda: pages_between(0, count, page))
+
+            pl.when(g == 0)(lambda: start(0, 0))
+            pl.when(g + 1 < pl.num_programs(0))(
+                lambda: start(g + 1, 1 - half))
+
+            @pl.when(n == pages)
+            def _all_arrived():
+                # a semaphore counts what has arrived: a whole step's
+                # pages are its half of a buffer, one wait a pool
+                for j, buf in enumerate(bufs):
+                    pltpu.make_async_copy(buf.at[half], buf.at[half],
+                                          sem.at[half, j]).wait()
+
+            @pl.when(n < pages)
+            def _some_arrived():
+                def arrived(p):
+                    for copy in page_copies(g, half, p):
+                        copy.wait()
+
+                def blank(p):
+                    # a page the slot does not hold was not copied: what
+                    # the buffer holds there is masked out of the scores,
+                    # and has to be finite where the probabilities (0)
+                    # meet it
+                    for buf in bufs:
+                        rows = buf.at[half, rows_of(p)]
+                        rows[...] = jnp.zeros(rows.shape, rows.dtype)
+
+                pages_between(0, n, arrived)
+                pages_between(n, pages, blank)
 
         @pl.when(i == 0)
         def _init():
@@ -457,14 +602,17 @@ def emit_paged_kernel(spec: PagedSpec):
     return kernel
 
 
-def _tile_of(page_refs, scale_refs=None):
-    """A step's pages [1, bs, ...] as one tile [pages*bs, ...], with
-    their scale pages dequantized in-register."""
-    def joined(refs):
-        x = jnp.concatenate([r[...] for r in refs], axis=0)
+def _tile_of(pages, scales=None):
+    """A step's tile [pages*bs, ...]: the buffer's half that the kernel's
+    own copies filled, as it lies, or the pipeline's page blocks [1, bs, ...]
+    joined; its scale rows, held either way, dequantize it in-register."""
+    def joined(held):
+        if not isinstance(held, list):
+            return held[...]
+        x = jnp.concatenate([r[...] for r in held], axis=0)
         return x.reshape((-1,) + x.shape[2:])
-    x = joined(page_refs)
-    return x if scale_refs is None else _dequant_block(x, joined(scale_refs))
+    x = joined(pages)
+    return x if scales is None else _dequant_block(x, joined(scales))
 
 
 def _dense_tile(spec: PagedSpec):
@@ -485,16 +633,16 @@ def _dense_tile(spec: PagedSpec):
                              (1, 0, 2, 3)).reshape(hkv, rows, d)
 
     def scores(q3, tiles):
-        k_refs, v_refs, *scale_refs = tiles
-        ks_refs, vs_refs = scale_refs or (None, None)
-        k3 = jnp.swapaxes(_tile_of(k_refs, ks_refs), 0, 1)  # [Hkv, w, D]
+        k_ref, v_ref, *scale_refs = tiles
+        ks_ref, vs_ref = scale_refs or (None, None)
+        k3 = jnp.swapaxes(_tile_of(k_ref, ks_ref), 0, 1)  # [Hkv, w, D]
         s = jax.lax.dot_general(                          # [Hkv, rows, w]
             q3.astype(k3.dtype), k3,
             (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
         def values(p):
-            v3 = jnp.swapaxes(_tile_of(v_refs, vs_refs), 0, 1)
+            v3 = jnp.swapaxes(_tile_of(v_ref, vs_ref), 0, 1)
             return jax.lax.dot_general(                   # [Hkv, rows, D]
                 p.astype(v3.dtype), v3,
                 (((2,), (1,)), ((0,), (0,))),
@@ -541,10 +689,10 @@ def _latent_tile(spec: PagedSpec):
 
     def scores(qs, tiles):
         ql, qp = qs
-        lat_refs, pe_refs, *scale_refs = tiles
-        ls_refs, ps_refs = scale_refs or (None, None)
-        lat = _tile_of(lat_refs, ls_refs)                 # [w, klat]
-        pe = _tile_of(pe_refs, ps_refs)                   # [w, dpe]
+        lat_ref, pe_ref, *scale_refs = tiles
+        ls_ref, ps_ref = scale_refs or (None, None)
+        lat = _tile_of(lat_ref, ls_ref)                 # [w, klat]
+        pe = _tile_of(pe_ref, ps_ref)                   # [w, dpe]
         nt = (((1,), (1,)), ((), ()))                     # a · b^T
         s = (jax.lax.dot_general(ql.astype(lat.dtype), lat, nt,  # [rows, w]
                                  preferred_element_type=jnp.float32)
@@ -584,11 +732,12 @@ def _walk_steps(page_table, kv_lens, bs: int, pages: int, first=None):
     there can be: slot_of[g]; step_of[g], its index within the slot; and
     block_of[g*pages + p], the pool block of its p-th page. A slot's
     steps are consecutive. A page a partial last step does not have
-    names the block its operand held a step earlier (the pipeline then
-    copies nothing), the slot's last page if the slot has no earlier
-    step, block 0 if the slot holds nothing: never a table entry past
-    the slot's length. Entries past the count repeat the last slot (no
-    grid step reads them)."""
+    names the block its operand held a step earlier (a blocked operand's
+    pipeline then copies nothing; the kernel's own copies skip such a
+    page and never read the name), the slot's last page if the slot has no
+    earlier step, block 0 if the slot holds nothing: never a table entry
+    past the slot's length. Entries past the count repeat the last slot
+    (no grid step reads them)."""
     b, max_blocks = page_table.shape
     max_steps = -(-max_blocks // pages)
     kv_lens = kv_lens.astype(jnp.int32)
@@ -617,9 +766,14 @@ def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
     """The one `pallas_call` of the paged family. Scalar-prefetched: the
     layer id, the lengths and the walk's step maps (ragged: q_lens
     last). A slot's query block and its block of the output `out` (a
-    ShapeDtypeStruct) follow ``slot_of``; each of a step's ``spec.pages``
-    pages is a block [1, bs, ...] of the STACKED pool [L, NB, bs, ...],
-    named by ``block_of``."""
+    ShapeDtypeStruct) follow ``slot_of``. A STACKED pool [L, NB, bs, ...]
+    that ``spec.copied`` marks is handed over once, in HBM, with a VMEM
+    buffer [2, pages*bs, ...] of the kernel's own (all such pools share
+    one array of DMA semaphores [2, pools]); any other is handed over
+    ``spec.pages`` times, each a block [1, bs, ...] named by ``block_of``.
+    What a step copies goes into the call's metadata, where
+    utils/dispatch.page_copies reads it for ``GET /stats``, and is printed
+    once a walk (`_announce_walk`)."""
     pages = spec.pages
     first = None
     if spec.window:
@@ -644,30 +798,96 @@ def _walk_call(spec: PagedSpec, name, lid, page_table, kv_lens, q_lens,
         prefetch.append(q_lens)
     if spec.window:
         prefetch.append(first * spec.block_size)
+    copied = spec.pools_copied
+    own = [pool for pool, mine in zip(pools, copied) if mine]
+    pool_specs, pool_args = [], []
+    for pool, mine in zip(pools, copied):
+        specs = ([pl.BlockSpec(memory_space=pl.ANY)] if mine else
+                 [page_block(pool, p) for p in range(pages)])
+        pool_specs += specs
+        pool_args += [pool] * len(specs)
+    rows = pages * spec.block_size
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(total,),
-        in_specs=([slot_block(q.shape) for q in queries]
-                  + [page_block(pool, p) for pool in pools
-                     for p in range(pages)]),
+        in_specs=[slot_block(q.shape) for q in queries] + pool_specs,
         out_specs=slot_block(out.shape),
         scratch_shapes=[
             pltpu.VMEM(acc_shape, jnp.float32),
             pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32),
-            pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32)],
+            pltpu.VMEM(acc_shape[:-1] + (1,), jnp.float32),
+            *(pltpu.VMEM((2, rows) + tuple(pool.shape[3:]), pool.dtype)
+              for pool in own),
+            *([pltpu.SemaphoreType.DMA((2, len(own)))] if own else [])],
     )
+    page_bytes = [math.prod(pool.shape[2:]) * pool.dtype.itemsize
+                  for pool in pools]
+    _announce_walk(name, pages, page_bytes, copied)
     return pl.pallas_call(
         emit_paged_kernel(spec), grid_spec=grid_spec, out_shape=out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(), name=name,
-    )(*(a.astype(jnp.int32) for a in prefetch), *queries,
-      *(pool for pool in pools for _ in range(pages)))
+        metadata=dict(
+            page_copies_step=str(pages * len(pools)),
+            page_copy_bytes="+".join(map(str, page_bytes)),
+            page_copies_kernel=str(pages * len(own))),
+    )(*(a.astype(jnp.int32) for a in prefetch), *queries, *pool_args)
 
 
-def _call_pools(pages, scales, table_blocks: int, key_tile: int = KEY_TILE):
+_announced = set()
+
+
+def _announce_walk(name: str, pages: int, page_bytes, copied) -> None:
+    """Print, once per distinct walk in this process, how a step of it
+    gets its pages."""
+    def sizes(which):
+        return " + ".join(f"{n:,}" for n, own in zip(page_bytes, copied)
+                          if own == which)
+    parts = []
+    if any(copied):
+        parts.append(f"of {sizes(True)} B started by the kernel")
+    if not all(copied):
+        parts.append(f"of {sizes(False)} B (a page no whole tiles) left to "
+                     "the pipeline")
+    line = (f"paged walk: {name}, {pages} pages a step, {len(page_bytes)} "
+            f"copies a page: {', '.join(parts)} "
+            f"({'interpreted' if _interpret() else 'compiled'})")
+    if line not in _announced:
+        _announced.add(line)
+        print(line, flush=True)
+
+
+def _page_is_tiles(pool) -> bool:
+    """Whether the kernel can start the copies of the stacked `pool`'s
+    pages [L, NB, bs, ...] itself. Mosaic lets a copy address a slice of
+    the kernel's buffer only where the buffer's two tiled dims are whole
+    tiles (else "Slice shape along dimension 2 must be aligned to tiling",
+    even for a slice that takes those dims whole), and the tile follows the
+    dtype. A page of key/value heads [bs, Hkv, D] is cut out along an
+    untiled dim: D has to be whole 128 lanes and Hkv a multiple of 8 (8 and
+    32 heads of 128 in bf16, int8 and fp8 lower for a described v5e:
+    tests/test_chip_compile.py). A page with no head axis [bs, cols] (the
+    latent plane) is cut out along the sublanes: cols has to be whole 128
+    lanes and bs whole sublane tiles of its dtype (16 rows of bf16, 32 of
+    int8 and fp8: `default_kv_tile`). Every other pool (D 80, D 64, one
+    key/value head, the roped keys' 64 columns, a latent pool of 8-row
+    blocks or of quantized 16-row blocks, scale pages) stays the
+    pipeline's blocked operands."""
+    if pool.ndim < 4 or pool.shape[-1] % 128:
+        return False
+    if pool.ndim == 4:
+        return pool.shape[2] % default_kv_tile(
+            quant_dtype_of(pool.dtype))[0] == 0
+    return pool.shape[-2] % 8 == 0
+
+
+def _call_pools(pages, scales, table_blocks: int, key_tile=None):
     """(a call's pools in the kernel's order, what the spec takes from
     them): the two stacked page pools [L, NB, bs, ...], then their scale
     pools where the pages are quantized (scales not None), and the
-    spec's quant_dtype, kv_tile and pages a step (of `key_tile` keys)."""
+    spec's quant_dtype, kv_tile, which pools the kernel copies itself and
+    the pages a step (of `key_tile` keys; None: `dense_key_tile`'s)."""
     quant_dtype = None
     if scales[0] is not None:
         quant_dtype = quant_dtype_of(pages[0].dtype)
@@ -678,11 +898,13 @@ def _call_pools(pages, scales, table_blocks: int, key_tile: int = KEY_TILE):
                 f"({sorted(QUANT_DTYPES)})")
         pages = pages + scales
     kv_tile = default_kv_tile(quant_dtype)
+    bs, page_bytes = pages[0].shape[2], _pages_vmem_bytes(pages, kv_tile)
+    copied = tuple(_page_is_tiles(pool) for pool in pages)
     return pages, dict(
-        quant_dtype=quant_dtype, kv_tile=kv_tile,
-        pages=pages_per_step(pages[0].shape[2],
-                             _pages_vmem_bytes(pages, kv_tile),
-                             table_blocks, key_tile=key_tile))
+        quant_dtype=quant_dtype, kv_tile=kv_tile, copied=copied,
+        pages=pages_per_step(
+            bs, page_bytes, table_blocks, key_tile=key_tile
+            or dense_key_tile(bs, page_bytes, copied[:2])))
 
 
 def _query_tile(queries, out_cols: int, pools, by_pools,

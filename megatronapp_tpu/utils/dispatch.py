@@ -125,11 +125,33 @@ def stack_slices(jaxpr, shapes: Iterable[tuple]) -> int:
     return n
 
 
+def page_copies(jaxpr) -> Dict[str, Dict]:
+    """What a step of each paged walk in `jaxpr` (its inner jaxprs
+    included) copies, by kernel name, as `kernel_gen._walk_call` wrote it
+    into its pallas_call's metadata: `page_copies_step` (pages a step x
+    pools), `page_copy_bytes` (one copy's bytes, a pool) and
+    `page_copies_kernel` (how many of a step's copies the kernel starts
+    itself; the pipeline brings the others as blocked operands)."""
+    found: Dict[str, Dict] = {}
+    for eqn in jaxpr.eqns:
+        meta = eqn.params.get("metadata") or {}
+        if eqn.primitive.name == "pallas_call" and "page_copies_step" in meta:
+            found[eqn.params["name"]] = {
+                "page_copies_step": int(meta["page_copies_step"]),
+                "page_copy_bytes": [
+                    int(n) for n in meta["page_copy_bytes"].split("+")],
+                "page_copies_kernel": int(meta["page_copies_kernel"])}
+        for inner in _inner_jaxprs(eqn):
+            found.update(page_copies(inner))
+    return found
+
+
 def launch_stats(fn, *args, slice_shapes: Iterable[tuple] = ()
                  ) -> Dict[str, float]:
     """jaxpr_launch_stats of `fn` traced at the given (abstract or
-    concrete) arguments, and under `expert_stack_slices` its stack_slices
-    of `slice_shapes` (one layer's expert kernels). `fn` may be jitted
+    concrete) arguments; under `expert_stack_slices` its stack_slices
+    of `slice_shapes` (one layer's expert kernels); and `page_copies`'
+    three counts, each a dict by paged kernel name. `fn` may be jitted
     (the pjit wrapper is recursed through) — nothing is compiled or
     executed."""
     import jax
@@ -137,4 +159,7 @@ def launch_stats(fn, *args, slice_shapes: Iterable[tuple] = ()
     stats = jaxpr_launch_stats(closed.jaxpr)
     stats["dispatches_per_step"] = stats["launches"] + stats["loop_steps"]
     stats["expert_stack_slices"] = stack_slices(closed.jaxpr, slice_shapes)
+    walks = page_copies(closed.jaxpr)
+    for key in ("page_copies_step", "page_copy_bytes", "page_copies_kernel"):
+        stats[key] = {name: walk[key] for name, walk in walks.items()}
     return stats

@@ -109,7 +109,18 @@ PAGED_SHAPES = {
     "evabyte-6.5b": (32, 32, 32, 128, 3584, 192),
     # serve.jamba2-3b.chat-closed: 20 query heads over one key/value head
     "jamba2-3b": (128, 20, 1, 128, 16384, 128),
+    # serve.lfm2-24b-a2b.assist-closed: 4 queries a key/value head of 64
+    "lfm2-24b-a2b": (192, 32, 8, 64, 16384, 128),
+    # serve.laguna-xs.2.code-closed's full layers: 6 queries a head of 128,
+    # 19,456 positions (its window layers' 8 a head walk the same pages)
+    "laguna-xs.2": (32, 48, 8, 128, 20480, 1216),
 }
+# Where a page is whole tiles the kernel starts its copies itself, a step
+# ahead, into two buffers of its own (ISSUE 46): copies a step it starts, of
+# the step's all (pages x pools; the others are the pipeline's blocked
+# operands). 8 heads of 128 fold 256 keys a step, blocked walks 128.
+KERNEL_COPIES = {"evabyte-6.5b": (16, 16), "laguna-xs.2": (32, 32),
+                 "deepseek-v2-lite": (16, 32), "longcat-flash-chat": (16, 32)}
 LATENT_SHAPES = {
     # serve.deepseek-v2-lite.longgen-closed: rows of 512 + 64 columns, 32
     # slots, 4096 positions
@@ -159,6 +170,81 @@ def test_paged_attention(one_chip, chip_compile, shape, q_len):
                     i32((b,)), i32((b,)))
     compiled = jax.jit(fn).lower(*args).compile()
     assert _custom_calls(compiled) == 1
+    # Mosaic took the walk's own buffers within its default scope (the
+    # call asks for no more); which pools it copies follows the shapes
+    from megatronapp_tpu.utils.dispatch import page_copies
+    walk, = page_copies(jax.make_jaxpr(fn)(*args).jaxpr).values()
+    assert (walk["page_copies_kernel"], walk["page_copies_step"]) == \
+        KERNEL_COPIES.get(shape, (0, 16))
+
+
+@pytest.mark.parametrize("q_len", [1, 32], ids=["decode", "multiquery-32"])
+@pytest.mark.parametrize("heads", [8, 32])
+@pytest.mark.parametrize("kv", ["int8", "fp8"])
+def test_paged_attention_quantized(one_chip, chip_compile, kv, heads, q_len):
+    """int8 and fp8 pools of 8 and of 32 key/value heads of 128 (their tile
+    is (32, 128), a bf16 pool's (16, 128)): the kernel starts the copies of
+    the key and value pages itself, a page cut out of its buffer along an
+    untiled dim, and the fp32 scale pages [16, heads] stay blocked operands
+    of the same call; Mosaic takes both at either width. A quantized LATENT
+    pool is no case here: its scale page [1, 16] of [NB, 16] is a block
+    Pallas refuses for a TPU in every form, this one and the one before
+    ISSUE 46 (PERF.md section 7)."""
+    from megatronapp_tpu.ops.pallas import kernel_gen as kg
+    from megatronapp_tpu.utils.dispatch import page_copies
+    bs, nb, mb, d = 16, 2048, 128, 128
+    sds = functools.partial(_sds, sharding=one_chip)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    pages = sds((nb, bs, heads, d), kg.QUANT_DTYPES[kv][0])
+    scales = sds((nb, bs, heads), jnp.float32)
+    b = 32 if q_len == 1 else 1
+    lead = (b,) if q_len == 1 else (b, q_len)
+
+    def fn(q, k, v, ks, vs, table, lens, *q_lens):
+        return kg.paged_attention(q, k, v, table, lens, *q_lens,
+                                  k_scales=ks, v_scales=vs)
+
+    args = (sds(lead + (32, d), jnp.bfloat16), pages, pages, scales, scales,
+            i32((b, mb)), i32((b,))) + (() if q_len == 1 else (i32((b,)),))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    walk, = page_copies(jax.make_jaxpr(fn)(*args).jaxpr).values()
+    assert (walk["page_copies_kernel"], walk["page_copies_step"]) == (16, 32)
+
+
+@pytest.mark.parametrize("q_len", [1, 32], ids=["decode", "multiquery-32"])
+def test_paged_attention_tp2(topo, chip_compile, q_len):
+    """The head-sharded placement (`--serve-tp`; `_tp_place`, a full-manual
+    shard_map around the same call) over a tp 2 mesh at 32 key/value heads
+    of 128: a shard's pool of 16 heads stays in HBM inside the shard_map
+    and its kernel starts the page copies itself, as on one device (16
+    pages of 16 heads a step: 2 MiB). No
+    chip has run this placement for ISSUE 46 (`chip_smoke.py --chips 4`
+    trains and serves nothing)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from megatronapp_tpu.config.parallel_config import TP_AXIS
+    from megatronapp_tpu.ops.pallas import kernel_gen as kg
+    from megatronapp_tpu.parallel.mesh import build_mesh
+    from megatronapp_tpu.utils.dispatch import page_copies
+    ctx = build_mesh(ParallelConfig(tensor_parallel=2),
+                     devices=topo.devices[:2])
+
+    def sds(shape, dtype, *spec):
+        return _sds(shape, dtype, NamedSharding(ctx.mesh, P(*spec)))
+
+    bs, nb, mb, heads, d = 16, 3584, 192, 32, 128
+    b = 32 if q_len == 1 else 1
+    lead = (b,) if q_len == 1 else (b, q_len)
+    pages = sds((nb, bs, heads, d), jnp.bfloat16, None, None, TP_AXIS)
+    args = (sds(lead + (heads, d), jnp.bfloat16, *[None] * len(lead),
+                TP_AXIS), pages, pages, sds((b, mb), jnp.int32),
+            sds((b,), jnp.int32))
+    args += () if q_len == 1 else (sds((b,), jnp.int32),)
+    fn = functools.partial(kg.paged_attention, mesh=ctx.mesh)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    walk, = page_copies(jax.make_jaxpr(fn)(*args).jaxpr).values()
+    assert (walk["page_copies_kernel"], walk["page_copies_step"]) == (32, 32)
 
 
 @pytest.mark.parametrize("model,cut,width,tile", [

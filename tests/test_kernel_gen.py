@@ -317,15 +317,17 @@ def _mk_inputs(rng, b, s_q, hq, hkv, d, bs, mb, quant, dtype):
     return q, kp, vp, tbl, lens, ks, vs
 
 
-def _pages_for(pools, mb, key_tile=kernel_gen.KEY_TILE):
+def _pages_for(pools, mb, key_tile=None):
     """Pages a step, as the entry points derive them for these pools
     ([NB, bs, ...] each, KV pools first, then their scale pools)."""
     pools = [p[None] for p in pools if p is not None]
     quant = len(pools) == 4
     tile = default_kv_tile("int8" if quant else None)
-    return pages_per_step(pools[0].shape[2],
-                          _pages_vmem_bytes(pools, tile), mb,
-                          key_tile=key_tile)
+    bs, page = pools[0].shape[2], _pages_vmem_bytes(pools, tile)
+    copied = [kernel_gen._page_is_tiles(p) for p in pools[:2]]
+    return pages_per_step(
+        bs, page, mb,
+        key_tile=key_tile or kernel_gen.dense_key_tile(bs, page, copied))
 
 
 def _step_blocks(tbl_row, kv_len, i, pages, bs):
@@ -829,17 +831,22 @@ _WALK_POOLS = {"fp32": (jnp.float32, None), "bf16": (jnp.bfloat16, None),
 
 
 def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
-               s_q=_W_SQ, q_lens=None, heads=4):
+               s_q=_W_SQ, q_lens=None, heads=4, widths=None, bs=_W_BS):
     """(kernel output, oracle output or None) of `body` over slots of
     `lens` cached rows at the walk shapes, pages stored as `pool` (int8
     and fp8 pages come with their fp32 scale pools). A ragged body's slots
     bring `s_q` query rows, of which `q_lens` are real (by default as many
     as the slot's length allows); a latent body has `heads` query heads.
+    widths: (query heads, key/value heads, head dim) of a dense body
+    (default 4, 2, 16), (latent, roped-key columns) of a latent one
+    (default 32, 8): pages of whole tiles (8 x 128 a row and more) are the
+    ones whose copies the kernel starts itself. bs: rows a block.
     nan_past: every
     table entry past a slot's length names a page filled with NaN, and a
     scale page filled with NaN (an int8 page cannot hold one: there the
-    scale page alone carries it); no oracle then: the oracles gather the
-    whole table."""
+    scale page alone carries it), and so is every block of the pool that
+    no slot's table names within its length; no oracle then: the oracles
+    gather the whole table."""
     rng = np.random.default_rng(seed)
     b, ragged, latent = len(lens), "_mq" in body, "latent" in body
     s_q = s_q if ragged else 0
@@ -849,12 +856,14 @@ def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
         q_lens = (jnp.minimum(kv_lens, s_q) if q_lens is None
                   else jnp.asarray(q_lens, jnp.int32))
     if latent:
+        klat, dpe = widths or (32, 8)
         ql, qp, lat, pe, w_v, tbl, _, _, _ = _mk_latent_inputs(
-            rng, b, s_q, heads, 32, 8, 16, _W_BS, _W_MB, False, dtype)
+            rng, b, s_q, heads, klat, dpe, 16, bs, _W_MB, False, dtype)
         pools = [lat, pe]
     else:
+        hq, hkv, d = widths or (4, 2, 16)
         q, kp, vp, tbl, _, _, _ = _mk_inputs(
-            rng, b, s_q, 4, 2, 16, _W_BS, _W_MB, False, dtype)
+            rng, b, s_q, hq, hkv, d, bs, _W_MB, False, dtype)
         pools = [kp, vp]
     scales = [None, None]
     if qdtype is not None:
@@ -862,17 +871,23 @@ def _walk_case(body, lens, nan_past=False, pool="fp32", seed=29,
                               for p in pools))
     if nan_past:
         # the pool's second half is NaN, and only entries past a slot's
-        # length name it
+        # length name it; of the first half, the blocks that a slot's
+        # table names within its length are spared
         nb = pools[0].shape[0]
+        held = (kv_lens[:, None] + bs - 1) // bs
+        within = jnp.arange(_W_MB)[None, :] < held
+        read = jnp.zeros((nb,), bool).at[
+            jnp.where(within, tbl, nb)].set(True, mode="drop")
 
         def dirty(p):
             fill = jnp.nan if jnp.issubdtype(p.dtype, jnp.floating) else 127
-            return jnp.concatenate([p, jnp.full_like(p, fill)])
+            spared = read.reshape((nb,) + (1,) * (p.ndim - 1))
+            return jnp.concatenate([jnp.where(spared, p, fill),
+                                    jnp.full_like(p, fill)])
 
         pools = [dirty(p) for p in pools]
         scales = [None if sc is None else dirty(sc) for sc in scales]
-        held = (kv_lens[:, None] + _W_BS - 1) // _W_BS
-        tbl = jnp.where(jnp.arange(_W_MB)[None, :] < held, tbl, tbl + nb)
+        tbl = jnp.where(within, tbl, tbl + nb)
     if latent:
         args = (ql, qp, *pools, tbl, kv_lens, w_v)
         kw = dict(q_lens=q_lens, softmax_scale=_W_SCALE,
@@ -1055,6 +1070,13 @@ class TestPagedEngine:
         # a layer's K append, V append and attention
         assert disp["kernels"] == 3 * cfg.num_layers
         assert "compiled" not in disp
+        # what a step of the walk copies (ISSUE 46): 64 positions in blocks
+        # of 8 are one step of 8 pages of K and of V, as blocked operands
+        # (a page of these widths is no whole tiles)
+        page = 8 * cfg.num_query_groups * cfg.head_dim * 4
+        assert disp["page_copies_step"] == {"paged_decode": 16}
+        assert disp["page_copy_bytes"] == {"paged_decode": [page, page]}
+        assert disp["page_copies_kernel"] == {"paged_decode": 0}
 
     @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
     def test_streams_at_heads_of_80(self, kv_dtype):

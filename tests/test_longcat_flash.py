@@ -453,7 +453,12 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
         paged=True, num_blocks=16, block_size=4, prefill_chunk=8)
     assert eng.stats_snapshot(include_dispatch=True)["decode_dispatch"] == {
         "launches": 1603, "kernels": 7, "loop_steps": 15, "eqns": 1,
-        "dispatches_per_step": 1618, "expert_stack_slices": 0}
+        "dispatches_per_step": 1618, "expert_stack_slices": 0,
+        # ISSUE 46: a step of the latent walk, 16 blocks of 4 rows of
+        # 32 + 8 float32 columns, none of them whole tiles
+        "page_copies_step": {"paged_decode_latent": 32},
+        "page_copy_bytes": {"paged_decode_latent": [4 * 32 * 4, 4 * 8 * 4]},
+        "page_copies_kernel": {"paged_decode_latent": 0}}
     moe_stats = eng.stats_snapshot()["moe"]
     assert moe_stats["experts_here"] == 8          # every expert is held
     # and the double layer's own step: two latent kernels a layer, the

@@ -8,6 +8,7 @@ GET /trace endpoints, and the disaggregated two-mesh merged-trace
 smoke (+ the stats_snapshot include_dispatch satellite)."""
 
 import asyncio
+import dataclasses
 import time
 from collections import defaultdict
 
@@ -597,6 +598,29 @@ class TestServerEndpoints:
         assert disp["expert_stack_slices"] == 0     # a dense model
         assert "compiled" not in disp
         assert second["decode_dispatch"] is disp
+
+    @pytest.mark.parametrize("kind", ["dense", "latent"])
+    def test_stats_name_the_walks_page_copies(self, kind):
+        """ISSUE 46: `decode_dispatch` says what a step of each paged walk
+        copies, by kernel name, from the traced step's shapes: the copies
+        a step (pages x pools), one copy's bytes a pool, and how many of
+        them the kernel starts itself (none at these widths: a page of 16
+        or 8 columns is no whole tiles)."""
+        kw = dict(multi_latent_attention=True, kv_lora_rank=32,
+                  qk_head_dim=16, qk_pos_emb_head_dim=8,
+                  v_head_dim=16) if kind == "latent" else {}
+        cfg = dataclasses.replace(_gqa_cfg(), **kw)
+        params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
+        eng = DynamicInferenceEngine(
+            params, cfg, max_batch=2, max_seq_len=48, prefill_buckets=(16,),
+            paged=True, block_size=8)
+        disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
+        name = "paged_decode_latent" if kind == "latent" else "paged_decode"
+        rows = ([32, 8] if kind == "latent"
+                else [cfg.num_query_groups * cfg.head_dim] * 2)
+        assert disp["page_copies_step"] == {name: 48 // 8 * 2}
+        assert disp["page_copy_bytes"] == {name: [8 * 4 * n for n in rows]}
+        assert disp["page_copies_kernel"] == {name: 0}
 
     def test_stats_endpoint_serves_step_counters(self):
         """GET /stats: the engine's `steps` and the driver's deliveries,

@@ -286,3 +286,129 @@ class TestPrefillWidths:
         assert any(len(set(st)) > 2 for st in streams), streams
         for p, toks in zip(prompts, out):
             assert toks == base._greedy_oracle(params, cfg, p, 5)
+
+
+class TestOwnCopies:
+    """ISSUE 46: where a pool's page is whole tiles (a row of 8 x 128 and
+    more), the walk keeps the pool in HBM and starts the copies of a step's
+    pages itself, a step ahead, into its own two buffers; a page that a
+    slot's partial last step lacks is not copied and its rows are blanked.
+    A pool whose page is no whole tiles stays the pipeline's blocked
+    operands (every other test of the walk runs those). Widths here are
+    the smallest that are whole tiles; every pool block that no slot's
+    table names within its length, and every table entry past a length,
+    is NaN, so a copy that should not exist fails the comparison."""
+
+    # (query heads, key/value heads, head dim) | (latent, roped-key columns)
+    WIDTHS = {
+        "dense": (16, 8, 128),
+        "gqa-8-of-48": (48, 8, 128),
+        # the latent plane copied by the kernel, the roped keys blocked
+        "latent": (128, 8),
+        "latent-both": (128, 128),
+    }
+    # a slot with no row, a one-step slot, a partial last step followed by
+    # another slot's first step, and a whole step; one slot of one step
+    # (a grid of 1); whole steps only
+    SLOTS = {"mixed": [0, 5, 8 * 16 + 3, 40, 8 * 16],
+             "one-step": [37],
+             "whole": [8 * 16, 16 * 16]}
+
+    def _case(self, body, widths, lens, pool="fp32", **kw):
+        dirty, _ = base._walk_case(body, lens, nan_past=True, pool=pool,
+                                   widths=self.WIDTHS[widths], **kw)
+        clean, ref = base._walk_case(body, lens, pool=pool,
+                                     widths=self.WIDTHS[widths], **kw)
+        assert bool(jnp.all(jnp.isfinite(dirty)))
+        assert bool(jnp.all(dirty == clean))
+        # a slot that holds nothing gets zeros (the oracles average its
+        # table's garbage): compare the others
+        rows = np.asarray([n > 0 for n in lens])
+        assert bool(jnp.all(clean[~rows] == 0.0))
+        return clean[rows], ref[rows]
+
+    @pytest.mark.parametrize("slots", list(SLOTS))
+    @pytest.mark.parametrize("body,widths", [
+        ("paged_decode", "dense"), ("paged_decode", "gqa-8-of-48"),
+        ("paged_mq", "dense"), ("paged_decode_latent", "latent"),
+        ("paged_decode_latent", "latent-both"),
+        ("paged_mq_latent", "latent-both")])
+    def test_against_the_oracle_and_nan_pages(self, body, widths, slots):
+        out, ref = self._case(body, widths, self.SLOTS[slots])
+        base._assert_close(out, ref, **base.TestWalk.TOL)
+
+    @pytest.mark.parametrize("pool", ["bf16", "int8", "fp8"])
+    @pytest.mark.parametrize("body,widths", [
+        ("paged_decode", "dense"), ("paged_mq_latent", "latent-both")])
+    def test_pools_of_other_types(self, body, widths, pool):
+        """int8 and fp8 pages are copied by the kernel where they are whole
+        tiles of their type (a latent page's rows are the sublanes: 32 rows
+        a block); their fp32 scale pages ([bs, heads], [bs]) never are, and
+        stay blocked operands of the same call."""
+        bs = 32 if "latent" in widths and pool != "bf16" else 16
+        lens = [n * bs // 16 for n in self.SLOTS["mixed"]]
+        out, ref = self._case(body, widths, lens, pool=pool, bs=bs)
+        # bf16 pages under 128 + 128 columns: the oracle rounds elsewhere
+        tol = (dict(atol=0.2, rtol=5e-2) if (body, pool)
+               == ("paged_mq_latent", "bf16")
+               else base.TestWalk()._tol(body, pool))
+        if bs == 32:
+            # twice the rows a page: float32 sums in another order
+            tol = dict(atol=5e-5, rtol=5e-5)
+        base._assert_close(out, ref, **tol)
+
+    @pytest.mark.parametrize("body,widths", [
+        ("paged_mq", "dense"), ("paged_mq_latent", "latent")])
+    def test_tiled_queries(self, monkeypatch, body, widths):
+        """A ragged call cut into query tiles: every tile is a row of the
+        walk over its slot's table row, so one slot's last step is
+        followed by the same slot's first."""
+        monkeypatch.setattr(kernel_gen, "_query_tile", lambda *a, **kw: 8)
+        lens = [200 + 20, 21, 0]
+        out, ref = self._case(body, widths, lens, s_q=20,
+                              q_lens=[20, 21 - 8, 0])
+        base._assert_close(out, ref, **base.TestWalk.TOL)
+
+    def test_which_pools_the_kernel_copies(self):
+        def pool(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        is_tiles = kernel_gen._page_is_tiles
+        # the byte and code cells' pages, the latent plane
+        assert is_tiles(pool(2, 64, 16, 32, 128))
+        assert is_tiles(pool(2, 64, 16, 8, 128))
+        assert is_tiles(pool(2, 64, 16, 512))
+        # D 80 and D 64, one key/value head, the roped keys' 64 columns,
+        # scale pages
+        assert not is_tiles(pool(2, 64, 16, 32, 80))
+        assert not is_tiles(pool(2, 64, 16, 8, 64))
+        assert not is_tiles(pool(2, 64, 16, 1, 128))
+        assert not is_tiles(pool(2, 64, 16, 64))
+        assert not is_tiles(pool(2, 64, 16, 32, dtype=jnp.float32))
+        assert not is_tiles(pool(2, 64, 16, dtype=jnp.float32))
+        # the tile follows the dtype. Pages of heads are cut out along an
+        # untiled dim, whatever their type (both lower for a v5e:
+        # test_chip_compile.py); a latent page's rows are the sublanes, 16
+        # of bf16 and 32 of int8 or fp8 a tile
+        for quant in (jnp.int8, jnp.float8_e4m3fn):
+            assert is_tiles(pool(2, 64, 16, 8, 128, dtype=quant))
+            assert is_tiles(pool(2, 64, 16, 32, 128, dtype=quant))
+            assert not is_tiles(pool(2, 64, 16, 512, dtype=quant))
+            assert is_tiles(pool(2, 64, 32, 512, dtype=quant))
+        assert not is_tiles(pool(2, 64, 8, 512))
+        # and the keys a step folds: 256 where the kernel copies pages of
+        # 8 heads of 128 (the code cell), 128 where a step of 256 would
+        # hold 4 MiB (32 heads: the byte cell) or the pipeline brings the
+        # pages (8 heads of 64, one head: the assist and hybrid cells keep
+        # the kernels and the bits they had)
+        tile = kernel_gen.default_kv_tile(None)
+
+        def keys(hkv, d):
+            pools = [pool(2, 64, 16, hkv, d)] * 2
+            return kernel_gen.dense_key_tile(
+                16, kernel_gen._pages_vmem_bytes(pools, tile),
+                [is_tiles(p) for p in pools])
+
+        assert keys(8, 128) == kernel_gen.WIDE_KEY_TILE == 256
+        assert keys(32, 128) == kernel_gen.KEY_TILE == 128
+        assert keys(8, 64) == keys(1, 128) == keys(32, 80) == 128

@@ -149,3 +149,56 @@ def test_a_window_walk_refuses_what_it_was_not_written_for():
                            jnp.ones((1,), jnp.int32), window=8,
                            k_scales=jnp.ones((NB, BS, HKV)),
                            v_scales=jnp.ones((NB, BS, HKV)))
+
+
+@pytest.mark.parametrize("s_q", [0, 12], ids=["decode", "ragged"])
+@pytest.mark.parametrize("window", [40, 16])
+def test_pages_of_whole_tiles_are_copied_inside_the_window_alone(window, s_q):
+    """ISSUE 46: at 8 key/value heads of 128 a page is whole tiles and the
+    kernel starts its copies itself. Every pool block but those a slot's
+    table names from its window's first block to its length is NaN: the
+    blocks behind a window, the entries past a length, the blocks no table
+    names. Slots: shorter than the window, a one-step slot, two steps with
+    a partial last one before another slot's first, an empty one, a whole
+    step."""
+    bs, hkv, d, mb, pages = 16, 8, 128, 20, 8
+    rng = np.random.default_rng(window + s_q)
+    lens = np.array([5, window + 3, pages * bs + window + 7, 0, 60,
+                     pages * bs])
+    q_lens = np.minimum(np.array([1, 3, s_q, 0, 7, s_q]), lens)
+    b = len(lens)
+    nb = b * mb + 1
+    k = rng.normal(size=(nb, bs, hkv, d)).astype(np.float32)
+    v = rng.normal(size=(nb, bs, hkv, d)).astype(np.float32)
+    table = (rng.permutation(nb - 1)[:b * mb].reshape(b, mb) + 1).astype(
+        np.int32)
+    news = q_lens if s_q else np.minimum(lens, 1)
+    firsts = np.maximum(lens - news - (window - 1), 0) // bs
+    read = np.zeros(nb, bool)
+    for row, first, n in zip(table, firsts, lens):
+        read[row[first:-(-n // bs)]] = True
+    kp, vp = (jnp.asarray(np.where(read[:, None, None, None], x, np.nan))
+              for x in (k, v))
+    shape = (b, s_q, hkv * 2, d) if s_q else (b, hkv * 2, d)
+    q = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    args = (jnp.asarray(table), jnp.asarray(lens))
+    if s_q:
+        out = kg.paged_attention(q, kp, vp, *args,
+                                 q_lens=jnp.asarray(q_lens), window=window)
+        ref = paged_attention_multiquery_reference(
+            q, jnp.asarray(k), jnp.asarray(v), *args, jnp.asarray(q_lens),
+            window=window)
+        for i, n in enumerate(q_lens):
+            np.testing.assert_allclose(np.asarray(out)[i, :n],
+                                       np.asarray(ref)[i, :n],
+                                       atol=2e-5, rtol=2e-5)
+    else:
+        out = kg.paged_attention(q, kp, vp, *args, window=window)
+        ref = paged_attention_reference(q, jnp.asarray(k), jnp.asarray(v),
+                                        *args, window=window)
+        live = lens > 0
+        assert not np.isnan(np.asarray(out)).any()
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(ref)[live],
+                                   atol=2e-5, rtol=2e-5)
+        assert not np.asarray(out)[~live].any()
