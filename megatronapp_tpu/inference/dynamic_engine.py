@@ -22,6 +22,11 @@ stay row-major on the device (paged_cache.pool_format), so the device
 holds one pool and a step touches the rows it appends and the blocks it
 attends. The parity oracle is the static engine (inference/engine.py).
 
+The plain decode loop runs one round ahead: a step dispatches round n+1
+before it fetches round n's tokens, so the host's part of a round (record,
+retire, callbacks, the next sweep and admission) runs while the chip runs
+(`_plain_round` and the comment above `_owed` say what that takes).
+
 TPU-first: all shapes static; the decode step is ONE jit for all slots
 (per-row rope positions, per-row lengths in the kernel), prefill runs in
 calls of one width, and sampling is ONE batched on-device jit per step
@@ -107,7 +112,12 @@ class StepStats(PhaseStats):
     index, wall time, batch and the seconds every phase took inside it.
     The tokens the model ran are counted elsewhere already:
     ``pool.stats["prefill_tokens"]`` (the snapshot's ``pool``) and
-    ``spec_stats["emitted_tokens"]``."""
+    ``spec_stats["emitted_tokens"]``. ``rounds_ahead``: the plain decode
+    rounds that were dispatched before their predecessor's tokens were read
+    (over ``decode_round``'s count: the share of rounds the chip did not
+    wait for the host); ``overrun_rows``: rows of such rounds whose request
+    had ended meanwhile (on its ``eod_id``, or stopped from outside), whose
+    token was dropped."""
 
     PHASES = ("step", "admit", "prefill", "prefill_call", "capacity",
               "decode_round", "decode.stage", "decode.wait",
@@ -117,6 +127,8 @@ class StepStats(PhaseStats):
     def __init__(self):
         super().__init__(self.PHASES)
         self.slowest: List[dict] = []       # longest first
+        self.rounds_ahead = 0
+        self.overrun_rows = 0
 
     def totals(self) -> List[float]:
         return [row[1] for row in self.phases.values()]
@@ -138,6 +150,8 @@ class StepStats(PhaseStats):
 
     def snapshot(self) -> dict:
         return dict(super().snapshot(),
+                    rounds_ahead=self.rounds_ahead,
+                    overrun_rows=self.overrun_rows,
                     slowest=[dict(r, phases=dict(r["phases"]))
                              for r in self.slowest])
 
@@ -647,6 +661,40 @@ def _sample_batched(logits, seeds, rids, steps, temps, top_ks, top_ps,
     return toks if tail is None else jnp.concatenate([toks, tail])
 
 
+def _sample_round(logits, seeds, rids, steps, temps, top_ks, top_ps,
+                  greedys, tail=None, host_tokens=None, from_host=None):
+    """The engine's sampler step: `_sample_batched`, and beside it the
+    NEXT round's token operand [B, 1], so that a round's tokens reach the
+    round after it without a trip through the host: row b is the token
+    just sampled, or host_tokens[b] where from_host[b] says the host knows
+    better (a slot a prefill has filled since, a row that does not run).
+    A prefill's one-row call passes no host_tokens and gets None."""
+    toks = _sample_batched(logits, seeds, rids, steps, temps, top_ks,
+                           top_ps, greedys)
+    nxt = None
+    if host_tokens is not None:
+        nxt = jnp.where(from_host[:, None], host_tokens, toks[:, None])
+    return (toks if tail is None else jnp.concatenate([toks, tail])), nxt
+
+
+@dataclasses.dataclass
+class _Round:
+    """A plain decode round from its staging to the reading of its tokens.
+    The engine keeps at most one that it has dispatched and not read
+    (`DynamicInferenceEngine._round`)."""
+    rows: Dict[int, "Request"]      # slot -> the request that runs in it
+    attrs: dict                     # the span's, from the lengths before it
+    ahead: int = 0                  # 1: dispatched before the one before
+    #                                 it was read
+    logits: Optional[jnp.ndarray] = None    # [B, V], on the device
+    moe: Optional[jnp.ndarray] = None       # the step's routing counts
+    toks: Optional[jnp.ndarray] = None      # the sampler's, once dispatched
+    batch: int = dataclasses.field(init=False)      # rows at the dispatch
+
+    def __post_init__(self):
+        self.batch = len(self.rows)
+
+
 def prefill_call_costs(cfg: TransformerConfig, params):
     """What a [1, width] prefill call costs, from the shapes alone: (bytes
     of weights it streams whatever its width, matmul flops a position).
@@ -885,6 +933,13 @@ class DynamicInferenceEngine:
                 _sh(scales_spec))   # manual-ok: see above
         else:
             self._params_sharding = None
+        # Where a decode step's token operand lives: the sampler hands the
+        # next round its tokens on the device, committed there like every
+        # result of a step; the host's copy (_host_tokens) is committed to
+        # the same place, or the two would be two compiled programs.
+        self._tokens_sharding = self._params_sharding or (
+            jax.sharding.SingleDeviceSharding(
+                next(iter(self.pool.pages[0].devices()))))
         # Telemetry (ISSUE 12): per-request lifecycle spans go to the
         # singleton ring tracer (every call is one enabled check when
         # tracing is off); counters/histograms to utils/metrics.
@@ -918,6 +973,12 @@ class DynamicInferenceEngine:
         self._tenant_stats: Dict[str, Dict[str, int]] = {}
         self.lengths = np.zeros((max_batch,), np.int32)
         self.last_tokens = np.zeros((max_batch, 1), np.int32)
+        # The plain decode loop runs one round ahead (_plain_round): the
+        # round that is dispatched and whose tokens are not read yet, if
+        # any. `lengths`, `last_tokens` and every request's `generated`
+        # know nothing of it until its tokens are read: between two steps
+        # they say what they said when every round was read in its step.
+        self._round: Optional[_Round] = None
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.waiting: deque = deque()
         self.requests: Dict[int, Request] = {}
@@ -1100,7 +1161,7 @@ class DynamicInferenceEngine:
         # A module of its own and one part as a whole: its instructions
         # name none.
         self._sample_b = scope_map.noted(
-            jax.jit(_sample_batched), kind="sampler",
+            jax.jit(_sample_round), kind="sampler",
             default_part="sampler")
         self._dispatch_stats = None
         msl = self.max_seq_len
@@ -1233,7 +1294,7 @@ class DynamicInferenceEngine:
             return None
         if rows is None:
             rows = self.row_adapter
-        return {"row_adapter": jnp.asarray(np.asarray(rows, np.int32)),
+        return {"row_adapter": _handed_over(np.asarray(rows, np.int32)),
                 "banks": self.adapters.banks}
 
     # ---- request lifecycle ------------------------------------------------
@@ -1305,7 +1366,9 @@ class DynamicInferenceEngine:
         if not req.finished:
             # Running — or mid-admission on the stepper thread (slot not
             # yet assigned): either way, marking finished retires it on
-            # the next step, releasing its cache.
+            # the next step, releasing its cache. A canceller's thread
+            # fetches nothing: the stepper finds the mark when it reads
+            # the round in flight, and drops that round's row (_read).
             req.finished = True
             self._rt.instant("abort", request_id)
             return "running"
@@ -1404,6 +1467,9 @@ class DynamicInferenceEngine:
         # round would observe the crash + backoff gap as a "token
         # interval" and poison the histogram's tail.
         self._last_round_t = None
+        # The round in flight is not read (the device may be what failed):
+        # its tokens are lost with the requests.
+        self._round = None
         for req in list(self.waiting):
             self.requests.pop(req.request_id, None)
             self._rt.finish(req.request_id, "abort")
@@ -1431,7 +1497,9 @@ class DynamicInferenceEngine:
         per-slot resource is added; pool blocks are released by the
         caller (release semantics differ per path). A slot's recurrent
         state needs nothing: the next sequence's first prefill call starts
-        from zeros whatever the slot holds."""
+        from zeros whatever the slot holds. The slot's row of the round in
+        flight, if it has one, is an over-run from here on."""
+        self._drop_row(slot)
         self.slots[slot] = None
         self.lengths[slot] = 0
         self._h_valid[slot] = False
@@ -1522,6 +1590,9 @@ class DynamicInferenceEngine:
         if (req is None or req.finished or req.slot < 0
                 or self.slots[req.slot] is not req or not req.generated):
             return None
+        # The session leaves as of its last token read, which is what
+        # `lengths` counts; its row of the round in flight goes when the
+        # slot does (release_exported).
         valid_len = int(self.lengths[req.slot])
         payload = self.pool.export_slot(req.slot, valid_len)
         payload["req"] = req
@@ -2003,7 +2074,7 @@ class DynamicInferenceEngine:
         reproducible and independent of batch composition."""
         s = req.sampling
         self._count_sample("prefills", s.greedy, s.top_k, s.top_p)
-        tok = self._sample_b(
+        tok, _ = self._sample_b(
             logits,
             jnp.asarray([s.seed], jnp.int32),
             jnp.asarray([req.request_id], jnp.int32),
@@ -2027,12 +2098,16 @@ class DynamicInferenceEngine:
             kind = "sampled"
         self.sampler_stats[f"{site}_{kind}"] += 1
 
-    def _sampling_rows(self) -> Dict[str, np.ndarray]:
+    def _sampling_rows(self, held=None) -> Dict[str, np.ndarray]:
         """Per-slot sampling parameters + key-chain inputs for every
         non-finished slot (inactive rows are greedy, which asks the
         sampler for nothing; their outputs are ignored). Single source
         for the plain sampler, the speculative verifier, and the draft
-        proposer — one place to thread a future sampling field through."""
+        proposer — one place to thread a future sampling field through.
+        held: a round's {slot: request} where that is not who holds the
+        slots now (a round read a step after its dispatch). `steps` is
+        the request's tokens so far: the round that is read is the oldest
+        unread one, so the token it samples is the next of the chain."""
         b = self.max_batch
         rows = {"seeds": np.zeros(b, np.int32),
                 "rids": np.zeros(b, np.int32),
@@ -2041,7 +2116,8 @@ class DynamicInferenceEngine:
                 "top_ks": np.zeros(b, np.int32),
                 "top_ps": np.zeros(b, np.float32),
                 "greedys": np.ones(b, bool)}
-        for i, r in enumerate(self.slots):
+        for i, r in (enumerate(self.slots) if held is None
+                     else held.items()):
             if r is None or r.finished:
                 continue
             s = r.sampling
@@ -2051,18 +2127,45 @@ class DynamicInferenceEngine:
             rows["top_ps"][i], rows["greedys"][i] = s.top_p, s.greedy
         return rows
 
-    def _sample_all(self, logits, tail=None) -> np.ndarray:
-        """Batched on-device sampling for every slot. ONE device
-        round-trip per decode step instead of one per request; `tail`
-        (_sample_batched) rides behind the tokens."""
-        r = self._sampling_rows()
+    def _host_tokens(self) -> jnp.ndarray:
+        """The host's `last_tokens` as a step's operand: a copy
+        (_handed_over says why), committed where the sampler's own token
+        operand is (_tokens_sharding)."""
+        # manual-ok: a host array staged for a step, no manual region
+        return jax.device_put(np.array(self.last_tokens),
+                              self._tokens_sharding)
+
+    def _sample_round(self, rnd: _Round,
+                      then: Optional[Dict[int, Request]] = None):
+        """Dispatch the batched on-device sampler on `rnd`'s logits: ONE
+        call for every slot, the step's counters (`moe`) riding behind
+        the tokens. Nothing is fetched: `rnd.toks` stays on the device
+        (_sample_all reads it), and the returned [B, 1] operand hands the
+        tokens to the round that runs `then` {slot: request}: a row that
+        goes on from `rnd` takes the token just sampled, any other the
+        host's `last_tokens` (a slot a prefill has filled since, a row
+        that does not run)."""
+        r = self._sampling_rows(rnd.rows)
         self._count_sample("rounds", r["greedys"], r["top_ks"], r["top_ps"])
-        toks = self._sample_b(
-            logits, jnp.asarray(r["seeds"]), jnp.asarray(r["rids"]),
+        then = then or {}
+        from_host = np.array([s not in then or rnd.rows.get(s) is not then[s]
+                              for s in range(self.max_batch)])
+        rnd.toks, tokens = self._sample_b(
+            rnd.logits, jnp.asarray(r["seeds"]), jnp.asarray(r["rids"]),
             jnp.asarray(r["steps"]), jnp.asarray(r["temps"]),
             jnp.asarray(r["top_ks"]), jnp.asarray(r["top_ps"]),
-            jnp.asarray(r["greedys"]), tail)
-        return np.asarray(jax.device_get(toks))
+            jnp.asarray(r["greedys"]), rnd.moe, self._host_tokens(),
+            jnp.asarray(from_host))
+        return tokens
+
+    def _sample_all(self, rnd: _Round) -> np.ndarray:
+        """`rnd`'s sampled tokens (and the counters behind them) on the
+        host: ONE device round-trip per decode round. The sampler was
+        dispatched with the next round's stage where that round runs
+        ahead, else it is now."""
+        if rnd.toks is None:
+            self._sample_round(rnd)
+        return np.asarray(jax.device_get(rnd.toks))
 
     def _record_token(self, req: Request, tok: int):
         req.generated.append(tok)
@@ -2094,11 +2197,13 @@ class DynamicInferenceEngine:
             rt.instant("preempt", req.request_id)
             rt.begin("queue-wait", req.request_id)
 
-    def _decode_capacity(self, req: Request) -> bool:
+    def _decode_capacity(self, req: Request,
+                         after: Optional[_Round] = None) -> bool:
         """The blocks that cover `req`'s append position, in the full
         planes and, on a sliding-window stack, in the window planes, whose
-        blocks behind the round's window go back first."""
-        at = int(self.lengths[req.slot])
+        blocks behind the round's window go back first. after: the unread
+        round before the one to cover, which appends a row of its own."""
+        at = int(self.lengths[req.slot]) + self._owed(req, after)
         return self.pool.ensure_capacity(req.slot, at) and (
             not self.has_window or self.pool.window_ensure(req.slot, at))
 
@@ -2151,7 +2256,9 @@ class DynamicInferenceEngine:
     # ---- main loop --------------------------------------------------------
     def step(self) -> Dict[str, List]:
         """Admit → decode (one token, or a speculate+verify round) for
-        all active slots → retire.
+        all active slots → retire. A plain round's tokens are read a step
+        after its dispatch (the loop runs one round ahead, _plain_round):
+        a step still delivers one round's tokens, in order.
 
         Returns {"admitted": [ids], "tokens": [(id, tok)], "finished":
         [ids], "preempted": [ids], "expired": [ids]} for this step
@@ -2179,13 +2286,19 @@ class DynamicInferenceEngine:
                              for r in admitted],
                   "finished": [], "preempted": [], "expired": expired}
 
+        # With a round in flight the step to cover is the one after it,
+        # and that is asked for without preempting (_plain_round): a
+        # victim is chosen only when no round is in flight, as it always
+        # was.
         with self._span("engine.capacity"):
-            preempted = self._ensure_decode_capacity()
+            preempted = ([] if self._round is not None
+                         else self._ensure_decode_capacity())
         events["preempted"] = [r.request_id for r in preempted]
 
         active = [r for r in self.slots
                   if r is not None and not r.finished]
-        if active:
+        batch = self._round.batch if self._round else len(active)
+        if batch:
             # Token-interval telemetry: back-to-back decode rounds only
             # (an idle gap is not a token interval — same rule as the
             # disagg coordinator's SLO accounting).
@@ -2207,22 +2320,84 @@ class DynamicInferenceEngine:
         events["finished"] = [r.request_id for r in retired]
         events["finished"] += [r.request_id for r in self._aborted]
         self._aborted = []
-        return events, len(active)
+        return events, batch
 
-    def _plain_round(self, active: List[Request], events: Dict):
-        """One-token decode for every active slot (non-speculative)."""
-        lens = self.lengths[[r.slot for r in active]]
+    # ---- the plain decode loop, one round ahead ---------------------------
+    # A step reads ONE round's tokens. Before it blocks on them it stages
+    # and dispatches the round after (_plain_round), so the fetch, the
+    # record, the retire, the driver's callbacks and the next step's sweep,
+    # admission and capacity pass all run while the chip runs that round.
+    # Nothing in a round's inputs needs the host to have seen the round
+    # before: the tokens go from the sampler to the next step on the device
+    # (_sample_round), a row of the unread round appends at its slot's
+    # length + 1 (_owed), and who ends by count is known a round ahead
+    # (_goes_on). What is learned late is a stop on `eod_id`, or from outside
+    # (abort_request, expire_overdue): the round ahead then has a row too
+    # many, whose token is dropped (_drop_row). `lengths`, `last_tokens`
+    # and `generated` move when a round is READ, so between two steps they
+    # are what a loop that never ran ahead would hold, and whatever takes
+    # a request out of its slot there (_retire, _preempt, _park,
+    # release_exported: all through _free_slot) releases, registers or
+    # exports as of the last token read, fetches nothing, and drops the
+    # slot's row of the round in flight: the request leaves as of that
+    # token, and the dropped one is sampled again, the same, where it
+    # resumes (the key chain counts tokens, not rounds). The row such a
+    # round wrote lies behind the length released, in a block the slot
+    # still owned; a recurrent state it advanced dies with the slot. The
+    # device runs what it is sent in order, so blocks that go back to the
+    # pool under a round in flight are written by nothing before that
+    # round has read them; an admission's calls queue behind it likewise.
+    # Speculative rounds propose from the host's tokens and stay as they
+    # were (_spec_round).
+    @staticmethod
+    def _owed(req: Request, rnd: Optional[_Round]) -> bool:
+        """Whether `rnd`, a round that is dispatched and not read (None:
+        there is none), owes `req` a token."""
+        return rnd is not None and rnd.rows.get(req.slot) is req
+
+    def _goes_on(self, req: Request, rnd: Optional[_Round]) -> bool:
+        """Whether `req` runs in the round after `rnd`: it has not ended,
+        and the token `rnd` owes it is not its last by count."""
+        return (not req.finished and len(req.generated)
+                + self._owed(req, rnd) < req.max_new_tokens)
+
+    def _lengths_after(self, after: Optional[_Round]) -> np.ndarray:
+        """The slots' lengths as the step after `after` sees them (a copy):
+        a row the unread round appends counts."""
+        lengths = np.array(self.lengths)
+        if after is not None:
+            lengths[list(after.rows)] += 1
+        return lengths
+
+    def _drop_row(self, slot: int):
+        """`slot`'s row of the round in flight, if it has one, is an
+        over-run: its request ended or left the slot before the round's
+        token for it was read, and the token is dropped. A round left
+        with no row is not read at all."""
+        rnd = self._round
+        if rnd is not None and rnd.rows.pop(slot, None) is not None:
+            self.step_stats.overrun_rows += 1
+            if not rnd.rows:
+                self._round = None
+
+    def _new_round(self, rows: Dict[int, Request],
+                   after: Optional[_Round] = None) -> _Round:
+        """A round over `rows`, yet to be dispatched, with the
+        `decode_round` span's attributes, from the lengths its step will
+        see (_lengths_after), and the walk's always-on counters with
+        them."""
+        lens = self._lengths_after(after)[list(rows)]
         attrs = {"kv_tokens": int(lens.sum())}
         # the blocks this round's paged kernel walks (it reads the
         # row the round appends too), of those the table could name
         bs = self.pool.block_size
-        rows = lens
+        walked = lens
         if self.eva:
             # kv_rows: what the kernel walks, R(T) a slot, the rows of
             # its closed windows' summaries (summary_rows) among them.
             cfg, st = self.cfg, self.eva_stats
-            rows = table_rows(cfg, lens)
-            attrs["kv_rows"] = int((rows + 1).sum())
+            walked = table_rows(cfg, lens)
+            attrs["kv_rows"] = int((walked + 1).sum())
             attrs["summary_rows"] = int(
                 (lens // cfg.eva_window_size).sum()
                 * (cfg.eva_window_size // cfg.eva_chunk_size))
@@ -2248,49 +2423,105 @@ class DynamicInferenceEngine:
             st["rows_full_walk"] += int((lens + 1).sum())
             st["bytes_held"] += attrs["bytes_held"]
             st["tokens_in_flight"] += int((lens + 1).sum())
-        attrs["kv_blocks"] = int((rows // bs + 1).sum())
+        attrs["kv_blocks"] = int((walked // bs + 1).sum())
         self.walk_stats["decode_rounds"] += 1
         self.walk_stats["blocks_live"] += attrs["kv_blocks"]
         self.walk_stats["blocks_table"] += (
-            len(active) * self.pool.page_table.shape[1])
+            len(rows) * self.pool.page_table.shape[1])
+        return _Round(rows, attrs, ahead=int(after is not None))
+
+    def _plain_round(self, active: List[Request], events: Dict):
+        """One round's tokens for the step (non-speculative): the round in
+        flight's, or that of `active` where none is; and before they are
+        read, the dispatch of the round after for those of `active` who
+        go on, where the pool covers their next rows as it stands. Where
+        it does not, nothing runs ahead: this round is read, and the next
+        step starts afresh with no round in flight, preempting or parking
+        as the loop that never ran ahead would, for the same victim at
+        the same token."""
+        cur, self._round = self._round, None
+        if cur is None:
+            cur = self._new_round({r.slot: r for r in active})
+        self.step_stats.rounds_ahead += cur.ahead
         with self._span("engine.decode_round", ring="decode-step",
-                        batch=len(active), **attrs):
-            self._plain_round_inner(active, events)
+                        batch=cur.batch, ahead=cur.ahead, **cur.attrs):
+            if cur.logits is None:
+                self._dispatch(cur)
+            with self._span("engine.capacity"):
+                then = sorted((r for r in active if self._goes_on(r, cur)),
+                              key=lambda r: (r.priority, r.request_id))
+                covered = all(self._decode_capacity(r, cur) for r in then)
+            if then and covered:
+                self._round = self._new_round({r.slot: r for r in then},
+                                              after=cur)
+                self._dispatch(self._round, after=cur)
+            try:
+                self._read(cur, events["tokens"])
+            except Exception:
+                # Its tokens never came: the round ahead ran on them and
+                # goes too. The host's record is as of the round before,
+                # which is where the next step starts.
+                self._round = None
+                raise
 
     def _plain_round_inner(self, active: List[Request], events: Dict):
+        """A round dispatched and read at once, inside its caller's span:
+        what a speculative round falls back to when nothing was
+        proposed."""
+        rnd = _Round({r.slot: r for r in active}, {})
+        self._dispatch(rnd)
+        self._read(rnd, events["tokens"])
+
+    def _dispatch(self, rnd: _Round, after: Optional[_Round] = None):
+        """Stage and dispatch `rnd`. Its tokens are the host's
+        `last_tokens`, or come on the device from `after`, the unread
+        round before it, whose sampler is dispatched here; a row that
+        `after` appends lies a position further."""
         with self._span("engine.decode.stage"):
-            active_np = np.array(
-                [self.slots[i] is not None and not self.slots[i].finished
-                 for i in range(self.max_batch)])
-            active_mask = jnp.asarray(active_np)
-            lengths = _handed_over(self.lengths)
-            logits, moe, new = self._decode(
-                self.params, _handed_over(self.last_tokens),
-                self._pools(), self.pool.scales,
+            active_np = np.zeros((self.max_batch,), bool)
+            active_np[list(rnd.rows)] = True
+            tokens = (self._host_tokens() if after is None
+                      else self._sample_round(after, rnd.rows))
+            logits, rnd.moe, new = self._decode(
+                self.params, tokens, self._pools(), self.pool.scales,
                 self._tables(slice(0, self.max_batch)),
-                lengths, active_mask, self._lora_args())
+                jnp.asarray(self._lengths_after(after)),
+                jnp.asarray(active_np), self._lora_args())
             self._commit_pools(new)
-            # The decode wrote each active row's kv at lengths[slot].
-            self.lengths += active_np.astype(np.int32)
-            logits = mask_padded_vocab(logits, self.cfg)
+            rnd.logits = mask_padded_vocab(logits, self.cfg)
+
+    def _read(self, rnd: _Round, out: List[Tuple[int, int]]):
+        """Fetch `rnd`'s tokens and record them, (request id, token) onto
+        `out`; a row read has written its slot's kv at lengths[slot]. A
+        row whose request was stopped since the dispatch is an over-run,
+        and a request that ends here makes one of its row of the round
+        ahead."""
         with self._span("engine.decode.wait"):
-            toks = self._sample_all(logits, tail=moe)
+            toks = self._sample_all(rnd)
         with self._span("engine.decode.record"):
-            if moe is not None:
+            if rnd.moe is not None:
+                # what the step counted, over-runs among its rows
                 st, tail = self.moe_stats, toks[self.max_batch:]
                 st["decode_rounds"] += 1
-                st["tokens"] += int(active_np.sum())
+                st["tokens"] += rnd.batch
                 counts = dict(zip(HELD_COUNTS, (int(n) for n in tail)))
                 counts.setdefault("assignments_here", counts["assignments"])
                 for name, n in counts.items():
                     st[name] += n
             self.spec_stats["model_steps"] += 1
-            self.spec_stats["emitted_tokens"] += len(active)
-            telemetry.inc("serving_tokens_emitted", len(active))
-            for req in active:
-                tok = int(toks[req.slot])
+            before = len(out)
+            for slot, req in rnd.rows.items():
+                if req.finished:
+                    self.step_stats.overrun_rows += 1
+                    continue
+                tok = int(toks[slot])
+                self.lengths[slot] += 1
                 self._record_token(req, tok)
-                events["tokens"].append((req.request_id, tok))
+                out.append((req.request_id, tok))
+                if req.finished:
+                    self._drop_row(slot)
+            self.spec_stats["emitted_tokens"] += len(out) - before
+            telemetry.inc("serving_tokens_emitted", len(out) - before)
 
     def _spec_round(self, active: List[Request], events: Dict):
         """One speculate+verify round: propose up to spec_k drafts per

@@ -41,6 +41,23 @@ own name when the ring is on. The span names are an interface that
 perfbench's readers match: ``mta.engine.{step, admit, prefill,
 prefill_call, capacity, decode_round, decode.stage, decode.wait,
 decode.record, retire}`` and ``mta.driver.deliver``.
+
+``mta.engine.decode_round`` is one span a round, opened when the round's
+tokens are read, with the attributes of its dispatch (``batch``,
+``kv_tokens``, ``kv_blocks``, a tenant's ``kv_rows`` / ``window_blocks``
+/ ...: the byte functions' numerators) and, on a plain round, ``ahead``:
+1 where the round was dispatched before the tokens of the round before it
+were read (the engine's decode loop runs one round ahead, ISSUE 47), so a
+kept trace shows which rounds the chip did not wait for. Inside it:
+``decode.stage`` (the NEXT round's arrays, the sampler's and the step's
+dispatch), ``decode.wait`` (the ``device_get`` of this round's tokens),
+``decode.record``. Beside the phases, ``stats_snapshot()["steps"]`` counts
+``rounds_ahead`` (rounds with ``ahead`` = 1: over ``decode_round``'s count,
+the share of rounds the mechanism engaged in) and ``overrun_rows`` (rows
+of a round in flight whose request ended or left its slot before they
+were read; their tokens are dropped). No metric reads the three yet:
+``/stats``, ``perfbench/tools/slowest_rounds.py`` (prints the counters)
+and a kept trace do.
 """
 
 from __future__ import annotations

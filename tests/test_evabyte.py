@@ -361,9 +361,11 @@ class TestEngine:
             pool.audit()
             for req in reqs:
                 if req.slot >= 0 and not req.finished:
-                    # capacity stands for the last position written
+                    # capacity stands for the last position written, the
+                    # row of the round in flight (ISSUE 47) among them
                     assert len(pool.slot_blocks(req.slot)) == _held(
-                        pool, int(eng.lengths[req.slot]) - 1), req.slot
+                        pool, int(eng.lengths[req.slot]) - 1
+                        + eng._owed(req, eng._round)), req.slot
             # a closed window's blocks are on the free list at once: what
             # the slots do not hold can be taken
             assert pool.free_blocks() == pool.num_blocks - sum(
