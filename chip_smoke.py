@@ -46,7 +46,9 @@ vocab 50304), random weights from the entry points' own seeds:
   two rotary tables, experts behind a leading dense layer) through the paged
   engine: requests longer than the window, two pairs of page pools, both
   aliased, the window planes' blocks given back, both families of paged
-  kernel in the compiled decode step.
+  kernel in the compiled decode step; then one loss and gradient of the
+  same stack over packed sequences, through the flash kernels' window term
+  (``flash_window_fwd``, ``_bwd_dq``, ``_bwd_dkv`` in the compiled text).
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -1079,7 +1081,29 @@ def child_window(tiny):
     pools = eng._pools()
     stats = eng.stats_snapshot(include_dispatch=True)
     text = compiled.as_text()
+    # The same stack TRAINED over whole packed sequences: its window
+    # layers' loss and gradient run the flash kernels' band (a window of 32
+    # in tiles of 64 over 256 positions: most tiles are in no grid).
+    import dataclasses
+    from megatronapp_tpu.models.gpt import gpt_loss
+    tcfg = dataclasses.replace(cfg, attention_impl="pallas",
+                               flash_block_q=64, flash_block_kv=64)
+    tok = jnp.asarray(rng.integers(0, 512, (2, 256)), jnp.int32)
+    segs = jnp.asarray(np.arange(256)[None] // 100 * np.ones((2, 1)),
+                       jnp.int32)
+    grad = jax.jit(jax.value_and_grad(lambda p: gpt_loss(
+        p, tok, jnp.roll(tok, -1, 1), jnp.ones((2, 256), jnp.float32), tcfg,
+        segment_ids=segs)[0])).lower(params).compile()
+    train_loss, grads = grad(params)
+    train_text = grad.as_text()
     _say(RESULT_PREFIX + json.dumps({
+        "train_loss": float(train_loss),
+        "train_grads_finite": bool(all(
+            bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+            for g in jax.tree.leaves(grads))),
+        "train_kernels": sorted({k for k in (
+            "flash_window_fwd", "flash_window_bwd_dq",
+            "flash_window_bwd_dkv") if k in train_text}),
         "tokens": sum(len(v) for v in out.values()),
         "in_vocab": bool(all(0 <= t < 512 for v in out.values()
                              for t in v)),
@@ -1122,6 +1146,18 @@ def check_window(rc, lines, tiny=False):
     if out["tokens"] != want or not out["in_vocab"]:
         out["problems"].append(f"{out['tokens']} tokens came back, not "
                                f"{want}, or one outside the vocabulary")
+    # the same stack trained: a finite loss near ln(512) and finite
+    # gradients, through the three window kernels where they are compiled
+    if not (5.5 < out["train_loss"] < 7.5) or not out["train_grads_finite"]:
+        out["problems"].append(
+            f"the stack's training loss {out['train_loss']} (ln 512 = 6.24 "
+            "at seeded weights) or a gradient is not finite")
+    if _kernel_mode(dev, tiny) == "(compiled)" and out["train_kernels"] != [
+            "flash_window_bwd_dkv", "flash_window_bwd_dq",
+            "flash_window_fwd"]:
+        out["problems"].append(f"the compiled loss and gradient name "
+                               f"{out['train_kernels']}: not the three "
+                               "flash_window kernels")
     window = out["window"] or {}
     if (not window.get("blocks_taken")
             or window.get("blocks_taken") != window.get("blocks_given_back")

@@ -171,6 +171,12 @@ class TransformerConfig:
     # ones.
     attn_layer_period: Optional[int] = None
     attn_layer_offset: int = 0
+    # The depth that the scaled init of the residual-out projections divides
+    # by (std / sqrt(2 x depth)): the whole model's where this configuration
+    # is a stage or a share of it, so that a stage is initialised as the
+    # model's layers are. None = num_layers. Read by the plain and hybrid
+    # stacks of transformer/block.py.
+    scaled_init_layers: Optional[int] = None
     ssm_state_dim: int = 16
     ssm_conv_kernel: int = 4
     ssm_expand: int = 2
@@ -437,16 +443,13 @@ class TransformerConfig:
                     "and the sliding-window stack (sliding_window) have "
                     "been given MoE feed-forwards")
             if self.is_moe and (
-                    self.moe_layer_freq != 1 or self.moe_picks_unheld
-                    or self.moe_shortcut_double_layer or self.mtp_num_layers
-                    or self.moe_aux_loss_coeff or self.moe_z_loss_coeff):
+                    self.moe_layer_freq != 1 or self.moe_zero_experts
+                    or self.moe_shortcut_double_layer or self.mtp_num_layers):
                 raise ValueError(
                     "a hybrid stack's MoE feed-forwards sit in every layer "
-                    "behind moe_first_k_dense leading dense ones, hold every "
-                    "expert, and carry no aux loss through the hybrid layer "
-                    "loop: no moe_layer_freq, moe_experts_held, "
-                    "moe_zero_experts, shortcut double layer, MTP, "
-                    "moe_aux_loss_coeff or moe_z_loss_coeff")
+                    "behind moe_first_k_dense leading dense ones: no "
+                    "moe_layer_freq, moe_zero_experts, shortcut double "
+                    "layer or MTP")
         if self.shortconv_kernel and (
                 self.shortconv_kernel < 2 or self.attn_layer_period is None):
             raise ValueError(

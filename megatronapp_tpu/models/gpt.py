@@ -223,10 +223,11 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
                 position_offset: int = 0, ctx=None,
                 segment_ids: Optional[jnp.ndarray] = None,
                 zigzag_keep: bool = False, return_hidden: bool = False,
-                fp8=None):
+                fp8=None, moe_counts: bool = False):
     """tokens [B,S] → (logits [B,S,V] fp32, moe_aux_loss) —
     (+ pre-head hidden states and rope tables when return_hidden, for the
-    MTP depth modules).
+    MTP depth modules; + the layers' summed routing counts when moe_counts:
+    block_forward).
 
     segment_ids: optional [B,S] packing map — attention is restricted to
     within-segment (packed sequences).
@@ -266,19 +267,19 @@ def gpt_forward(p, tokens: jnp.ndarray, cfg: TransformerConfig,
         h, _ = block_forward(p["lead_block"], h, cfg, cos, sin,
                              attention_mask, ctx=ctx, zigzag=zz,
                              segment_ids=segment_ids)
-    h, aux = block_forward(p["block"], h, cfg, cos, sin, attention_mask,
-                           layer_offset=(cfg.moe_first_k_dense
-                                         if "lead_block" in p else 0),
-                           ctx=ctx, zigzag=zz, segment_ids=segment_ids,
-                           fp8=None if fp8 is None else fp8["block"],
-                           window_rope=window_rope)
+    h, aux, *counts = block_forward(
+        p["block"], h, cfg, cos, sin, attention_mask,
+        layer_offset=(cfg.moe_first_k_dense if "lead_block" in p else 0),
+        ctx=ctx, zigzag=zz, segment_ids=segment_ids,
+        fp8=None if fp8 is None else fp8["block"],
+        window_rope=window_rope, moe_counts=moe_counts)
     logits = gpt_head(p, h, cfg)
     if zz and not zigzag_keep:
         logits = jnp.take(logits, jnp.asarray(zigzag_inverse_indices(
             s, ctx.cp)), axis=1)
     if return_hidden:
         return logits, aux, h, (cos, sin)
-    return logits, aux
+    return (logits, aux, *counts)
 
 
 def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
@@ -290,7 +291,7 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
     from megatronapp_tpu.ops.context_parallel import (
         zigzag_active, zigzag_indices,
     )
-    mtp_metrics = {}
+    more = {}
     if cfg.num_pred_heads > 1:
         raise NotImplementedError(
             "a head of num_pred_heads x vocab_size columns has no training "
@@ -323,12 +324,26 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
         # it (unscaled, like main-stack layers); the scaled MTP CE is
         # carried separately into the total.
         aux = aux + mtp_layer_aux
-        mtp_metrics["mtp_loss"] = mtp_mean
-        mtp_metrics["_mtp_scaled"] = mtp_scaled
+        more["mtp_loss"] = mtp_mean
+        more["_mtp_scaled"] = mtp_scaled
     else:
-        logits, aux = gpt_forward(p, tokens, cfg, ctx=ctx,
-                                  segment_ids=segment_ids,
-                                  zigzag_keep=True, fp8=fp8)
+        # A hybrid stack that counts its held experts' load (a share of an
+        # expert-parallel job) counts it in training too.
+        counting = cfg.moe_counts_load and cfg.attn_layer_period is not None
+        logits, aux, *counts = gpt_forward(
+            p, tokens, cfg, ctx=ctx, segment_ids=segment_ids,
+            zigzag_keep=True, fp8=fp8, moe_counts=counting)
+        if counting:
+            from megatronapp_tpu.transformer.moe import HELD_COUNTS
+            moe_layers = cfg.num_layers - cfg.moe_first_k_dense
+            # "sums": a step's totals over micro-batches, not their mean
+            # (training/train_step.py)
+            more["sums"] = {
+                **dict(zip(HELD_COUNTS, counts[0])),
+                "experts_here": jnp.int32(
+                    cfg.moe_experts_here[1] * moe_layers),
+                "moe_layer_passes": jnp.int32(moe_layers),
+                "router_loss": aux}
     if zigzag_active(cfg, ctx) and segment_ids is None:
         # Logits are in zigzag order — permute targets/mask to match (the
         # masked-mean CE is permutation-invariant).
@@ -338,11 +353,11 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
             loss_mask = jnp.take(loss_mask, idx, axis=1)
     with jax.named_scope("head"):       # in training the head has the loss
         loss, _ = cross_entropy_loss(logits, targets, loss_mask)
-    mtp_scaled_term = mtp_metrics.pop("_mtp_scaled",
+    mtp_scaled_term = more.pop("_mtp_scaled",
                                       jnp.zeros((), jnp.float32))
     return loss + aux + mtp_scaled_term, {"lm_loss": loss,
                                           "moe_aux_loss": aux,
-                                          **mtp_metrics}
+                                          **more}
 
 
 def gpt_head(p, h: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:

@@ -239,6 +239,48 @@ def laguna_xs2(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def mellum2_12b_a2p5b(**kw) -> TransformerConfig:
+    """JetBrains/Mellum2-12B-A2.5B(-Instruct) (12.15B parameters, ~2.5B
+    active; `model_type` mellum) as its config.json publishes it
+    (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/
+    config.json): 28 pre-norm layers of H 2304, 32 query heads over 4
+    key/value heads of 128, no bias; layers 3, 7, 11, ... attend to every
+    earlier key with YaRN (theta 5e5, factor 16 over 8192, beta 32 / 1,
+    attention factor 1.27726), the other 21 to the last 1,024 keys with
+    plain rotary tables at the same theta; every layer ends in 64 experts
+    of width 896, the 8 largest of a softmax over all 64, renormalised, no
+    shared expert (`intermediate_size` 7168 is unused); RMSNorm 1e-6, an
+    untied head over 98,304. Assumed, as the family whose keys these are
+    (Qwen3-MoE) has them: an RMS norm on each head's q and k before the
+    rotation, half-rotation pairing, a load-balancing loss at 0.001
+    (`router_aux_loss_coef`; here divided by the top-k, Megatron's form),
+    init 0.02 and 0.02 / sqrt(2 x 28) whatever part of the depth is run
+    (`scaled_init_layers`). The "MTP head" its card
+    mentions is described by no key and left out. Whole it is 194 GB of
+    training state: a job passes its share (num_layers, moe_experts_held,
+    vocab_size), as the benchmark's configuration does
+    (perfbench/configs/mellum2-12b-a2.5b.json). Trains through
+    pretrain_gpt.py; window layers run the flash kernels' band."""
+    d = dict(num_layers=28, hidden_size=2304, num_attention_heads=32,
+             num_query_groups=4, kv_channels=128, ffn_hidden_size=7168,
+             vocab_size=98304, max_position_embeddings=131072,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-6,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             untie_embeddings_and_output_weights=True, qk_layernorm=True,
+             position_embedding=PositionEmbeddingKind.yarn,
+             rotary_base=500000.0, rope_scaling_factor=16.0,
+             yarn_original_max_position=8192,
+             yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+             yarn_attention_factor=1.2772588722239782,
+             attn_layer_period=4, attn_layer_offset=3,
+             sliding_window=1024, sliding_rotary_base=500000.0,
+             num_moe_experts=64, moe_router_topk=8, moe_ffn_hidden_size=896,
+             moe_router_norm_topk_prob=True, moe_aux_loss_coeff=0.001,
+             scaled_init_layers=28)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -265,6 +307,7 @@ PRESETS = {
     "jamba2-3b": jamba2_3b,
     "lfm2-24b-a2b": lfm2_24b_a2b,
     "laguna-xs.2": laguna_xs2,
+    "mellum2-12b-a2.5b": mellum2_12b_a2p5b,
     "gpt2-125m": gpt2_125m,
     "gpt3-2.7b": gpt3_2p7b,
     "mamba-130m": mamba_130m,

@@ -7,6 +7,9 @@ replacement obligation). This kernel:
 - blockwise online-softmax forward, O(S) memory (no [Sq,Skv] materialized),
   fp32 accumulators, bf16 matmul inputs on the MXU;
 - causal masking with whole-block skip for fully-masked tiles;
+- a sliding window (`window` keys a query, inside its segment): the grid of
+  a window layer's kernels holds only the tiles its band can touch
+  (`_BandGrid`), under names of their own (`flash_window_*`);
 - GQA: KV heads indexed as h // group via BlockSpec index maps, no repeat;
 - custom VJP with two backward kernels (dq; dk/dv), log-sum-exp residuals —
   the FlashAttention-2 recipe;
@@ -22,6 +25,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,10 +56,11 @@ def _mask_rows(x, start, limit):
     return jnp.where(idx < limit, x, jnp.zeros_like(x))
 
 def _valid_mask(q_start, k_start, block_q, block_kv, seq_q, seq_kv,
-                causal, bounded, qs_ref, ks_ref):
+                causal, bounded, qs_ref, ks_ref, window=0):
     """[bq, bkv] validity mask with only the statically-needed terms:
     bounds checks when the sequence doesn't divide the block, the causal
-    triangle, and packed-segment equality."""
+    triangle, a sliding window's band (the query at row i sees the keys
+    i - window < j <= i), and packed-segment equality."""
     rows = q_start + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_kv), 0)
     cols = k_start + jax.lax.broadcasted_iota(
@@ -67,6 +72,8 @@ def _valid_mask(q_start, k_start, block_q, block_kv, seq_q, seq_kv,
     else:
         valid = rows >= cols if causal else jnp.ones(
             (block_q, block_kv), jnp.bool_)
+    if window:
+        valid = valid & (rows - cols < window)
     if qs_ref is not None:
         # Packed sequences: attend within-segment only (segment ids
         # [bq,1] vs [1,bkv] broadcast to the score block).
@@ -75,13 +82,24 @@ def _valid_mask(q_start, k_start, block_q, block_kv, seq_q, seq_kv,
 
 
 def _dispatch_tiles(compute, causal, edge_mask, q_start, k_start,
-                    block_q, block_kv):
+                    block_q, block_kv, window=0, live=None):
     """Shared tile dispatch for all three kernels: skip tiles entirely
     above the causal diagonal, and route interior tiles (strictly below
     the diagonal, in-bounds, no segment ids) to compute(masked=False) —
     skipping the iota/compare/select chain on [bq, bkv] is the kernels'
-    main VPU saving."""
-    if causal:
+    main VPU saving. A window layer's grid (`_BandGrid`) holds no tile
+    outside the band but the steps a short row of tiles leaves over, on
+    which `live` is false; its interior tiles lie inside the band too."""
+    if window:
+        if edge_mask:
+            pl.when(live)(lambda: compute(True))
+        else:
+            interior = ((q_start >= k_start + block_kv)
+                        & (q_start + block_q - 1 - k_start < window))
+            pl.when(live & interior)(lambda: compute(False))
+            pl.when(live & jnp.logical_not(interior))(
+                lambda: compute(True))
+    elif causal:
         if edge_mask:
             @pl.when(q_start + block_q - 1 >= k_start)
             def _():
@@ -99,6 +117,53 @@ def _dispatch_tiles(compute, causal, edge_mask, q_start, k_start,
                 compute(True)
     else:
         compute(edge_mask)
+
+
+
+class _BandGrid(NamedTuple):
+    """The tiles that a causal band of `window` keys can touch, as the window
+    kernels' grids walk them: a q tile's kv tiles (forward and dq:
+    ``kv_tile``) and a kv tile's q tiles (dkv: ``q_tile``), a row of at most
+    ``kv_steps`` / ``q_steps`` of them. Both take the grid's (row, step) and
+    answer (tile, live): the tile's index, held at the row's last tile on the
+    steps that a shorter row leaves over (so no new copy starts), where
+    `live` is false. Tiles outside the band are in no grid: they cost no
+    step, where a masked tile would cost a whole one."""
+    window: int
+    block_q: int
+    block_kv: int
+    nq: int
+    nk: int
+
+    def kv_range(self, iq, xp=jnp):
+        q_start = iq * self.block_q
+        return (xp.maximum(q_start - (self.window - 1), 0) // self.block_kv,
+                xp.minimum((q_start + self.block_q - 1) // self.block_kv,
+                           self.nk - 1))
+
+    def q_range(self, ik, xp=jnp):
+        k_start = ik * self.block_kv
+        return (xp.minimum(k_start // self.block_q, self.nq - 1),
+                xp.minimum((k_start + self.block_kv + self.window - 2)
+                           // self.block_q, self.nq - 1))
+
+    @property
+    def kv_steps(self) -> int:
+        lo, hi = self.kv_range(np.arange(self.nq), np)
+        return int(np.max(hi - lo)) + 1
+
+    @property
+    def q_steps(self) -> int:
+        lo, hi = self.q_range(np.arange(self.nk), np)
+        return int(np.max(hi - lo)) + 1
+
+    def kv_tile(self, iq, step):
+        lo, hi = self.kv_range(iq)
+        return jnp.minimum(lo + step, hi), lo + step <= hi
+
+    def q_tile(self, ik, step):
+        lo, hi = self.q_range(ik)
+        return jnp.minimum(lo + step, hi), lo + step <= hi
 
 
 # ---------------------------------------------------------------------------
@@ -170,12 +235,43 @@ def _spec_qrow(block_q, q_major):
                         lambda b_, h_, ik, iq: (b_, h_, 0, iq))
 
 
+def _band_specs(band: _BandGrid, d, group, q_major, has_segs):
+    """The window kernels' BlockSpecs by operand, on a (b, h, row, step)
+    grid whose (row, step) the band turns into (iq, ik): rows are q tiles
+    and steps their kv tiles (`q_major`: forward, dq), or the other way
+    round (dkv). Straight orientation only."""
+    if q_major:
+        iq_of = lambda row, step: row                       # noqa: E731
+        ik_of = lambda row, step: band.kv_tile(row, step)[0]  # noqa: E731
+    else:
+        iq_of = lambda row, step: band.q_tile(row, step)[0]   # noqa: E731
+        ik_of = lambda row, step: row                       # noqa: E731
+    bq, bkv = band.block_q, band.block_kv
+    specs = {
+        "q": pl.BlockSpec((1, 1, bq, d), lambda b_, h_, r, t: (
+            b_, h_, iq_of(r, t), 0)),
+        "kv": pl.BlockSpec((1, 1, bkv, d), lambda b_, h_, r, t: (
+            b_, h_ // group, ik_of(r, t), 0)),
+        "dkv": pl.BlockSpec((1, 1, bkv, d), lambda b_, h_, r, t: (
+            b_, h_, ik_of(r, t), 0)),
+        "qcol": pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, r, t: (
+            b_, h_, iq_of(r, t), 0)),
+    }
+    specs["segs"] = [
+        pl.BlockSpec((1, bq, 1), lambda b_, h_, r, t: (b_, iq_of(r, t), 0)),
+        pl.BlockSpec((1, 1, bkv), lambda b_, h_, r, t: (b_, 0, ik_of(r, t))),
+    ] if has_segs else []
+    return specs
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
-                num_kv, seq_q, seq_kv, has_segs, bounded):
+                num_kv, seq_q, seq_kv, has_segs, bounded, band=None):
+    """`band` (a window layer): the grid's last axis walks the band's
+    `num_kv` steps of q tile iq, not all the kv tiles."""
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref,
          o_ref, lse_ref, acc, m_scr, l_scr) = refs
@@ -183,9 +279,11 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
         qs_ref = ks_ref = None
     iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    step = pl.program_id(3)
+    window = band.window if band else 0
+    ik, live = band.kv_tile(iq, step) if band else (step, None)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
@@ -208,7 +306,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
         if masked:
             valid = _valid_mask(q_start, k_start, block_q, block_kv,
                                 seq_q, seq_kv, causal, bounded,
-                                qs_ref, ks_ref)
+                                qs_ref, ks_ref, window)
             s = jnp.where(valid, s, _NEG_INF)
 
         m_prev = m_scr[:, 0]
@@ -227,9 +325,9 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
         m_scr[:, 0] = m_new
 
     _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+                    block_q, block_kv, window, live)
 
-    @pl.when(ik == num_kv - 1)
+    @pl.when(step == num_kv - 1)
     def _finalize():
         l = l_scr[:, 0]
         o_ref[0, 0] = (acc[:] / jnp.maximum(l, 1e-20)[:, None]).astype(
@@ -364,7 +462,37 @@ def _flash_forward_t(q, k, v, scale, causal, block_q, block_kv, nq, nk,
     return jnp.swapaxes(ot, -1, -2), lse_row[:, :, 0, :]
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None):
+def _flash_forward_window(q, k, v, scale, band: _BandGrid, bounded, group,
+                          segs):
+    """A window layer's forward: the straight kernel on the band's grid."""
+    b, h, sq, d = q.shape
+    specs = _band_specs(band, d, group, True, segs is not None)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, scale=scale, causal=True, block_q=band.block_q,
+            block_kv=band.block_kv, num_kv=band.kv_steps, seq_q=sq,
+            seq_kv=k.shape[2], has_segs=segs is not None, bounded=bounded,
+            band=band),
+        grid=(b, h, band.nq, band.kv_steps),
+        in_specs=[specs["q"], specs["kv"], specs["kv"]] + specs["segs"],
+        out_specs=[specs["q"], specs["qcol"]],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((band.block_q, d), jnp.float32),
+            pltpu.VMEM((band.block_q, 1), jnp.float32),
+            pltpu.VMEM((band.block_q, 1), jnp.float32),
+        ],
+        interpret=_interpret(),
+        name="flash_window_fwd",
+    )(q, k, v, *(segs or ()))
+    return out, lse[..., 0]
+
+
+def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None,
+                   window=0):
     b, h, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = h // hkv
@@ -374,6 +502,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None):
     nk = _cdiv(skv, block_kv)
 
     bounded = (sq % block_q != 0) or (skv % block_kv != 0)
+    if window:
+        return _flash_forward_window(
+            q, k, v, scale, _BandGrid(window, block_q, block_kv, nq, nk),
+            bounded, group, segs)
     if d < 128 and not _force_straight():
         return _flash_forward_t(q, k, v, scale, causal, block_q, block_kv,
                                 nq, nk, bounded, group, segs)
@@ -858,7 +990,7 @@ def _flash_backward_fold(q, k, v, g, lse, delta, scale, causal,
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
-                   seq_q, seq_kv, has_segs, bounded):
+                   seq_q, seq_kv, has_segs, bounded, band=None):
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_acc) = refs
@@ -867,9 +999,11 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
          dq_ref, dq_acc) = refs
         qs_ref = ks_ref = None
     iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    step = pl.program_id(3)
+    window = band.window if band else 0
+    ik, live = band.kv_tile(iq, step) if band else (step, None)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -895,7 +1029,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
         if masked:
             valid = _valid_mask(q_start, k_start, block_q, block_kv,
                                 seq_q, seq_kv, causal, bounded,
-                                qs_ref, ks_ref)
+                                qs_ref, ks_ref, window)
             s = jnp.where(valid, s, _NEG_INF)
             p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
             ds = jnp.where(valid, p * (dp - delta[:, None]), 0.0)
@@ -907,16 +1041,18 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
             preferred_element_type=jnp.float32) * scale
 
     _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+                    block_q, block_kv, window, live)
 
-    @pl.when(ik == num_kv - 1)
+    @pl.when(step == num_kv - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal,
                     block_q, block_kv, num_q, seq_q, seq_kv, has_segs,
-                    bounded):
+                    bounded, band=None):
+    """`band` (a window layer): the grid's last axis walks the band's
+    `num_q` steps of kv tile ik, not all the q tiles."""
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -925,9 +1061,11 @@ def _bwd_dkv_kernel(*refs, scale, causal,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         qs_ref = ks_ref = None
     ik = pl.program_id(2)
-    iq = pl.program_id(3)
+    step = pl.program_id(3)
+    window = band.window if band else 0
+    iq, live = band.q_tile(ik, step) if band else (step, None)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -956,7 +1094,7 @@ def _bwd_dkv_kernel(*refs, scale, causal,
         if masked:
             valid = _valid_mask(q_start, k_start, block_q, block_kv,
                                 seq_q, seq_kv, causal, bounded,
-                                qs_ref, ks_ref)
+                                qs_ref, ks_ref, window)
             s = jnp.where(valid, s, _NEG_INF)
             p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
             ds = jnp.where(valid, p * (dp - delta[:, None]), 0.0)
@@ -973,16 +1111,68 @@ def _bwd_dkv_kernel(*refs, scale, causal,
             preferred_element_type=jnp.float32)
 
     _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+                    block_q, block_kv, window, live)
 
-    @pl.when(iq == num_q - 1)
+    @pl.when(step == num_q - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _flash_backward_window(q, k, v, g, lse, delta, scale, band: _BandGrid,
+                           bounded, group, segs):
+    """A window layer's backward: the straight dq and dkv kernels on the
+    band's two grids; dk/dv come out a query head and are summed over a
+    key/value head's group outside, as in `_flash_backward`."""
+    b, h, sq, d = q.shape
+    hkv, skv = k.shape[1], k.shape[2]
+    has_segs = segs is not None
+    static = dict(scale=scale, causal=True, block_q=band.block_q,
+                  block_kv=band.block_kv, seq_q=sq, seq_kv=skv,
+                  has_segs=has_segs, bounded=bounded, band=band)
+    inputs = (q, k, v, *(segs or ()), g, lse[..., None], delta[..., None])
+
+    def in_specs(specs):
+        return ([specs["q"], specs["kv"], specs["kv"]] + specs["segs"]
+                + [specs["q"], specs["qcol"], specs["qcol"]])
+
+    specs = _band_specs(band, d, group, True, has_segs)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, num_kv=band.kv_steps, **static),
+        grid=(b, h, band.nq, band.kv_steps),
+        in_specs=in_specs(specs),
+        out_specs=specs["q"],
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((band.block_q, d), jnp.float32)],
+        interpret=_interpret(),
+        name="flash_window_bwd_dq",
+    )(*inputs)
+
+    specs = _band_specs(band, d, group, False, has_segs)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, num_q=band.q_steps, **static),
+        grid=(b, h, band.nk, band.q_steps),
+        in_specs=in_specs(specs),
+        out_specs=[specs["dkv"], specs["dkv"]],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, skv, d), k.dtype),
+            jax.ShapeDtypeStruct((b, h, skv, d), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((band.block_kv, d), jnp.float32),
+            pltpu.VMEM((band.block_kv, d), jnp.float32),
+        ],
+        interpret=_interpret(),
+        name="flash_window_bwd_dkv",
+    )(*inputs)
+    if group > 1:
+        dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2)
+        dv = dv.reshape(b, hkv, group, skv, d).sum(axis=2)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
 def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
-                    head_fold: bool = False):
+                    head_fold: bool = False, window=0):
     q, k, v, out, lse = res
     b, h, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -995,6 +1185,11 @@ def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [B,H,Sq]
+    if window:
+        return _flash_backward_window(
+            q, k, v, g, lse, delta, scale,
+            _BandGrid(window, block_q, block_kv, nq, nk), bounded, group,
+            segs)
     if head_fold and head_fold_eligible(h, hkv, d, segs):
         return _flash_backward_fold(
             q, k, v, g, lse, delta, scale, causal, block_q, block_kv,
@@ -1231,6 +1426,32 @@ def _seg_bwd_rule(scale, causal, block_q, block_kv, res, g):
 _flash_attention_seg_bhsd.defvjp(_seg_fwd_rule, _seg_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_window_bhsd(q, k, v, segs, scale, window, block_q, block_kv):
+    """A sliding-window layer: causal, `window` keys a query; `segs` is
+    (q_segs, kv_segs) of a packed batch or None."""
+    return _flash_forward(q, k, v, scale, True, block_q, block_kv, segs,
+                          window)[0]
+
+
+def _window_fwd_rule(q, k, v, segs, scale, window, block_q, block_kv):
+    out, lse = _flash_forward(q, k, v, scale, True, block_q, block_kv, segs,
+                              window)
+    return out, (q, k, v, out, lse, segs)
+
+
+def _window_bwd_rule(scale, window, block_q, block_kv, res, g):
+    q, k, v, out, lse, segs = res
+    dq, dk, dv = _flash_backward((q, k, v, out, lse), g, scale, True,
+                                 block_q, block_kv, segs=segs, window=window)
+    d_segs = None if segs is None else tuple(
+        np.zeros(x.shape, jax.dtypes.float0) for x in segs)
+    return dq, dk, dv, d_segs
+
+
+_flash_window_bhsd.defvjp(_window_fwd_rule, _window_bwd_rule)
+
+
 class AttentionChoice(NamedTuple):
     """What `choose_attention` decided for one call: the implementation
     ('pallas' or 'reference'), the flash kernels' tiles, and the term that
@@ -1262,10 +1483,12 @@ _DENSE_BYTES_MAX = 1 << 30
 
 
 def flash_tiles(seq: int, block_q: Optional[int] = None,
-                block_kv: Optional[int] = None) -> tuple:
+                block_kv: Optional[int] = None, window: int = 0) -> tuple:
     """(block_q, block_kv) of the three flash kernels: the caller's where it
     names them (clamped to S), else one tile a sequence up to S 1024 and
-    512 x 512 past it. Measured
+    512 x 512 past it; a window layer whose band is shorter than the
+    sequence takes 512 x 512 at every S, since one tile a sequence would
+    skip nothing (not measured below S 2048). Measured
     (PERF.md, PR 37): at S 1024 one 1024 x 1024 tile is 20-30% faster than
     four 512 x 512 of which causal skipping drops one (no rescaling between
     key/value tiles, a quarter of the grid steps) and every smaller tile is
@@ -1273,7 +1496,7 @@ def flash_tiles(seq: int, block_q: Optional[int] = None,
     them with segments at batch 4 (VMEM), so the four-chip cell keeps its
     512 x 512. A compile for a described v5e takes one tile a sequence at
     every S <= 1024, D <= 128, batch 1..16, packed or not, grouped or not."""
-    chosen = seq if seq <= 1024 else 512
+    chosen = seq if seq <= 1024 and not 0 < window < seq else 512
     return min(block_q or chosen, seq), min(block_kv or chosen, seq)
 
 
@@ -1295,17 +1518,16 @@ def choose_attention(*, impl: str, batch: int, seq: int, heads: int,
     attention everywhere else: nobody measured float32 compute (the kernels
     feed the MXU bf16), other head sizes, or S under 512. Other backends
     keep XLA's dense attention. Explicit tiles are honoured; unset ones come
-    from `flash_tiles`. A sliding-window layer (`window` > 0) takes XLA's
-    dense attention under its band mask whatever was asked for: the flash
-    kernels have no window term (ROADMAP)."""
+    from `flash_tiles`. A sliding-window layer (`window` > 0) is chosen for
+    by the same terms: XLA's dense attention under a band mask holds the
+    same [B, heads, S, S] scores whatever the window, and the kernels' band
+    (`flash_window_*`) only does less."""
     def choice(impl_, why):
-        return AttentionChoice(impl_, *flash_tiles(seq, block_q, block_kv),
-                               why)
+        return AttentionChoice(
+            impl_, *flash_tiles(seq, block_q, block_kv, window), why)
 
-    shape = f"S={seq} D={head_dim}" + (" segments" if segments else "")
-    if window:
-        return choice("reference", f"sliding window {window}: the flash "
-                                   "kernels have no window term")
+    shape = (f"S={seq} D={head_dim}" + (f" window {window}" if window else "")
+             + (" segments" if segments else ""))
     if impl != "auto":
         return choice(impl, shape if impl == "pallas" else impl)
     if backend != "tpu":
@@ -1330,7 +1552,7 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_kv: Optional[int] = None,
                     segment_ids: Optional[jnp.ndarray] = None,
-                    head_fold: bool = False):
+                    head_fold: bool = False, window: int = 0):
     """Flash attention on [B, S, H, D] tensors (GQA-aware).
 
     Returns [B, Sq, H, D]. Drop-in for ops.attention.dot_product_attention's
@@ -1346,15 +1568,30 @@ def flash_attention(q, k, v, causal: bool = True,
     --flash-head-fold). Silently keeps the standard kernels when
     ineligible (head_fold_eligible: 2D > 128, odd head counts, packed
     segments). Forward math is unchanged; grads parity-pinned ≤ 1e-5.
+
+    window: > 0 makes the layer a sliding-window one (causal only): the
+    query at position i of a segment sees the keys i - window < j <= i of
+    that segment. Its three kernels (`flash_window_fwd`, `_bwd_dq`,
+    `_bwd_dkv`; straight orientation at every D) run grids that hold the
+    band's tiles alone; with window 0 nothing of this is traced.
     """
     b, sq, h, d = q.shape
     if softmax_scale is None:
         softmax_scale = 1.0 / (d ** 0.5)
-    block_q, block_kv = flash_tiles(sq, block_q, block_kv)
+    block_q, block_kv = flash_tiles(sq, block_q, block_kv, window)
     qt = jnp.swapaxes(q, 1, 2)   # [B,H,S,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    if segment_ids is None:
+    if window:
+        if not causal:
+            raise ValueError("a sliding window is causal")
+        segs = None
+        if segment_ids is not None:
+            segs = segment_ids.astype(jnp.int32)
+            segs = (segs[:, :, None], segs[:, None, :])
+        out = _flash_window_bhsd(qt, kt, vt, segs, float(softmax_scale),
+                                 int(window), block_q, block_kv)
+    elif segment_ids is None:
         out = _flash_attention_bhsd(qt, kt, vt, float(softmax_scale),
                                     causal, block_q, block_kv,
                                     bool(head_fold))

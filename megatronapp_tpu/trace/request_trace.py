@@ -40,7 +40,14 @@ costs about a microsecond otherwise); it adds the phase's wall time to the
 own name when the ring is on. The span names are an interface that
 perfbench's readers match: ``mta.engine.{step, admit, prefill,
 prefill_call, capacity, decode_round, decode.stage, decode.wait,
-decode.record, retire}`` and ``mta.driver.deliver``.
+decode.record, retire}`` and ``mta.driver.deliver``; the training loop's
+``mta.train.step`` (``iteration``, ``micro_batches``, ``tokens``: a step's
+dispatch) and ``mta.train.sync`` (the ``device_get`` of a log interval's
+metrics; ``steps``, its last step's ``loss`` and ``grad_norm`` unrounded
+and, on a model that counts its held experts' load, the
+interval's ``assignments``, ``assignments_here``, ``assignments_absent``,
+``here_max_rows``, ``experts_here``, ``moe_layer_passes``, ``router_loss``,
+summed over its steps, micro-batches and layers: training/train.py).
 
 ``mta.engine.decode_round`` is one span a round, opened when the round's
 tokens are read, with the attributes of its dispatch (``batch``,
@@ -105,11 +112,12 @@ class _Span:
     of these open in every engine step)."""
 
     __slots__ = ("rt", "name", "rid", "stats", "ring", "attrs", "ann", "t0",
-                 "seconds")
+                 "seconds", "late")
 
     def __init__(self, rt, name, rid, stats, ring, attrs):
         self.rt, self.name, self.rid = rt, name, rid
         self.stats, self.ring, self.attrs = stats, ring, attrs
+        self.late = None
 
     def __enter__(self):
         if self.ring is not None and self.rt.enabled:
@@ -123,6 +131,13 @@ class _Span:
         self.t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs):
+        """Attributes known only inside the span (what its device_get
+        returned): they join the annotation's before it closes, and the
+        ring's E record carries them."""
+        self.late = attrs
+        self.ann.set_metadata(**attrs)
+
     def __exit__(self, exc_type, exc, tb):
         self.seconds = time.perf_counter() - self.t0
         self.ann.__exit__(exc_type, exc, tb)
@@ -132,7 +147,7 @@ class _Span:
             self.stats.add(self.name.partition(".")[2], self.seconds)
         if self.ring is not None and self.rt.enabled:
             if exc_type is None:
-                self.rt.end(self.ring, self.rid)
+                self.rt.end(self.ring, self.rid, **(self.late or {}))
             else:
                 self.rt.end(self.ring, self.rid, error=True)
         return False

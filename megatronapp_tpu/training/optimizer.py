@@ -51,14 +51,20 @@ _NO_DECAY_SUFFIXES = ("_bias", "_scale")
 _NO_DECAY_NAMES = frozenset({"A_log", "D"})
 
 
+def leaf_name(path) -> str:
+    """The name of a parameter leaf: the last dict key of its tree path."""
+    import jax.tree_util as jtu
+    return next((k.key for k in reversed(path)
+                 if isinstance(k, jtu.DictKey)), "")
+
+
 def _weight_decay_mask(params):
     """No decay for biases and norm params — reference get_param_groups
     (optimizer/__init__.py) no_weight_decay_cond default."""
     import jax.tree_util as jtu
 
     def decay(path, p):
-        name = next((k.key for k in reversed(path)
-                     if isinstance(k, jtu.DictKey)), "")
+        name = leaf_name(path)
         if name.endswith(_NO_DECAY_SUFFIXES) or name in _NO_DECAY_NAMES:
             return False
         return p.ndim > 1

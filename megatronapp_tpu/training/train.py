@@ -8,6 +8,8 @@ save/resume, MegaScan tracing hooks, NaN-skip accounting.
 
 from __future__ import annotations
 
+import functools
+
 import contextlib
 import dataclasses
 import threading
@@ -203,6 +205,21 @@ class _RowBuffer:
         return out
 
 
+def _moe_log_part(sums: Dict[str, float]) -> str:
+    """`moe here 0.250 max/mean 1.07 router 1.0e-03 | ` of a log interval's
+    counters, on a model whose layers count their held experts' load
+    (models/gpt.py gpt_loss "sums"): the share of the tokens' picks that
+    fell on an expert held here, the most loaded held expert's rows over
+    the mean, and the router's loss a layer; "" on every other model."""
+    if not sums.get("assignments_here"):
+        return ""
+    passes = sums["moe_layer_passes"]
+    mean_rows = sums["assignments_here"] / sums["experts_here"]
+    return (f"moe here {sums['assignments_here'] / sums['assignments']:.3f} "
+            f"max/mean {sums['here_max_rows'] / passes / mean_rows:.2f} "
+            f"router {sums['router_loss'] / passes:.1e} | ")
+
+
 def gpt_rank_kernels(cfg: TransformerConfig):
     """({path: (rank axis, dtype)}, None) of the kernels `gpt_loss`
     multiplies (``ops/per_rank.dense``) or looks up (``per_rank.take``)
@@ -241,7 +258,42 @@ def gpt_microbatch_loss(cfg: TransformerConfig, ctx=None):
                                  fp8=fp8)
         return loss, metrics
     loss_fn.rank_kernels, loss_fn.no_rank_kernels = gpt_rank_kernels(cfg)
+    if cfg.params_dtype != cfg.compute_dtype:
+        loss_fn.compute_copies = functools.partial(
+            compute_dtype_kernels, dtype=cfg.compute_dtype)
     return loss_fn
+
+
+# The kernels every layer that has them casts to the compute type where it
+# multiplies them: attention's and MLA's projections, a dense MLP's and the
+# experts' two, the state-space and short-convolution mixers' projections,
+# the per-head output gate. NOT a router's (multiplied in float32) nor a
+# mixer's "conv_kernel" (its taps are float32).
+_COMPUTE_DTYPE_KERNELS = frozenset({
+    "q_kernel", "kv_kernel", "out_kernel", "fc1_kernel", "fc2_kernel",
+    "in_kernel", "gate_kernel"})
+
+
+def compute_dtype_kernels(params, dtype):
+    """`params` with the kernels that the model multiplies in `dtype` cast
+    to it: the leaves named in _COMPUTE_DTYPE_KERNELS and an untied head
+    ("output"). Where the parameters' type is not the compute type and the
+    loss gets no copy a data-parallel rank (``_PerRankKernels``, which does
+    the same a rank), ``train_step.make_train_step`` differentiates the loss
+    by these copies, made once a step: each is used once a micro-batch, so
+    the gradients are those by the float32 leaves rounded to `dtype` (which
+    their casts' cotangents are too, where XLA does not keep excess
+    precision), a micro-batch's gradient tree is half the bytes beside the
+    float32 accumulator, and XLA has no cast of the stacks to hoist out of
+    the micro-batch loop and keep beside them."""
+    from megatronapp_tpu.training.optimizer import leaf_name
+
+    def one(path, leaf):
+        name = leaf_name(path)
+        multiplied = name in _COMPUTE_DTYPE_KERNELS or name == "output"
+        return leaf.astype(dtype) if multiplied else leaf
+
+    return jax.tree_util.tree_map_with_path(one, params)
 
 
 def pretrain_gpt(
@@ -798,6 +850,16 @@ def pretrain_gpt(
     window_start_iter = start_step   # first iteration of the open window
 
     last_sync_iter = start_step
+    # The loop's own phases, through the serving engine's one emission point
+    # (trace/request_trace.py span): `mta.train.step` around a step's
+    # dispatch, `mta.train.sync` around the device_get of a log interval's
+    # metrics. The second carries its last step's loss and gradient norm
+    # unrounded and the interval's counters (what the steps returned under
+    # metrics["sums"], kept on the device until this one sync) summed over
+    # its steps.
+    from megatronapp_tpu.trace.request_trace import get_request_tracer
+    spans = get_request_tracer()
+    pending_sums = []
     rows = _RowBuffer(batch_iter)
     interrupted = False
     # Exit-signal sync cadence: should_exit() is a host-level collective
@@ -862,14 +924,30 @@ def pretrain_gpt(
             straggler.start()
             with tracer.scope("train-step"):
                 active_fn = traced_step_fn if tracer.active else step_fn
-                state, metrics = run_step_maybe_profiled(
-                    active_fn, state, batch, it)
+                with spans.span("train.step", ring="train-step",
+                                iteration=it + 1,
+                                micro_batches=cur_micro,
+                                tokens=tokens_per_step):
+                    state, metrics = run_step_maybe_profiled(
+                        active_fn, state, batch, it)
+                if "sums" in metrics:
+                    pending_sums.append(metrics.pop("sums"))
                 # Block for accurate per-step timing only when tracing or
                 # logging this step; otherwise let steps pipeline.
                 should_log = ((it + 1) % train_cfg.log_interval == 0 or
                               it + 1 == train_cfg.train_iters)
                 if tracer.active or should_log:
-                    metrics = jax.device_get(metrics)
+                    with spans.span("train.sync", ring="train-sync") as sync:
+                        metrics, got = jax.device_get(
+                            (metrics, pending_sums))
+                        interval_sums = {
+                            k: sum(float(g[k]) for g in got)
+                            for k in (got[0] if got else ())}
+                        sync.set(steps=it + 1 - last_sync_iter,
+                                 loss=float(metrics["loss"]),
+                                 grad_norm=float(metrics["grad_norm"]),
+                                 **interval_sums)
+                    pending_sums = []
                     # Straggler sampling: normalize the sync-to-sync window
                     # by the number of pipelined steps it covers, so traced
                     # (1-step) and logged (log_interval-step) samples share
@@ -938,6 +1016,7 @@ def pretrain_gpt(
                     f"{float(metrics['grad_norm']):.3f} | "
                     f"lr {float(metrics['lr']):.2e} | "
                     f"skipped {int(metrics['skipped'])} | "
+                    f"{_moe_log_part(interval_sums)}"
                     f"{step_time_ms:.1f} ms/step | "
                     f"{tokens_per_sec:,.0f} tok/s | "
                     f"{tflops:.1f} TFLOP/s/dev")

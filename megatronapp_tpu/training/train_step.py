@@ -227,6 +227,9 @@ def make_train_step(
 ):
     """loss_fn(params, microbatch_dict) -> (loss, metrics_dict).
 
+    The step's metrics are the micro-batches' means, but for what the loss
+    puts under metrics["sums"] (counters), which are their totals.
+
     Returns jitted step(state, batch) -> (state, metrics); batch arrays are
     [num_micro, global_batch, seq]. In pipeline mode, loss_fn consumes the
     whole microbatched batch at once (the pipeline schedules microbatches
@@ -279,6 +282,11 @@ def make_train_step(
     ranks = (_PerRankKernels(loss_fn.rank_kernels, ctx,
                              state_shardings["params"], landing)
              if many_ranks and per_micro is None else None)
+    # What the loss is differentiated by in the micro-batch loop where it
+    # gets no copy a rank and the parameters' type is not the compute type:
+    # its compute-type copies of the kernels it multiplies
+    # (train.compute_dtype_kernels), the same gradients in fewer bytes.
+    compute_copies = getattr(loss_fn, "compute_copies", None)
 
     def announce(params, num_micro):
         if ranks is not None:
@@ -352,6 +360,8 @@ def make_train_step(
                                      metrics)), None
 
             loss_params = params if ranks is None else ranks.copies(params)
+            if ranks is None and compute_copies is not None:
+                loss_params = compute_copies(params)
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), loss_params)
             if fp8:
@@ -383,7 +393,11 @@ def make_train_step(
                     g_sum = ranks.summed(g_sum)
                 grads = jax.tree.map(lambda g: g * inv, g_sum)
             loss = loss_sum * inv
+            # "sums" (counters) stay the step's totals; the rest are means
+            sums = aux_sum.pop("sums", None)
             aux = jax.tree.map(lambda a: a * inv, aux_sum)
+            if sums is not None:
+                aux["sums"] = sums
 
         if trace_phases:
             from megatronapp_tpu.trace.tracer import (

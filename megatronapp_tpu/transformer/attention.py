@@ -138,9 +138,10 @@ def attention_forward(
     """x: [B, S, H] → [B, S, H]. Returns (out, new_kv_cache).
 
     window: > 0 makes this a sliding-window layer: the query at position t
-    sees the keys t - window + 1 .. t. Whole sequences run XLA's dense
-    attention under a band mask (the flash kernels and the cp rings have no
-    window term: ROADMAP); a paged step's kernels walk from the block that
+    sees the keys t - window + 1 .. t. Whole sequences run the flash
+    kernels' band (`flash_window_*`) or XLA's dense attention under a band
+    mask, as `choose_attention` says (the cp rings have no window term:
+    ROADMAP); a paged step's kernels walk from the block that
     holds position len - window and mask the rows behind it
     (kernel_gen.paged_attention(window=)), and kv_cache / page_table are
     then the WINDOW pools and their table (inference/paged_cache.py).
@@ -244,7 +245,7 @@ def attention_forward(
             or kv_scales is not None):
         raise NotImplementedError(
             "a sliding-window layer or a gated attention output runs whole "
-            "sequences under XLA's dense attention, or paged bf16 pools on "
+            "sequences on one tp shard, or paged bf16 pools on "
             "one device: no tp-sharded stage body, tp-overlap rings, cp, "
             "lora, EVA, dense (unpaged) cache or quantized pool")
     if tp_sharded:
@@ -550,7 +551,9 @@ def attention_forward(
             impl == "pallas" and attention_mask is None
             and kv_cache is None and not in_manual
             and cfg.attn_mask_type in (AttnMaskType.causal,
-                                       AttnMaskType.bidirectional))
+                                       AttnMaskType.bidirectional)
+            and not (window
+                     and cfg.attn_mask_type != AttnMaskType.causal))
         if use_flash and multi_device:
             dp_ep = ctx.dp * ctx.ep
             use_flash = (b % dp_ep == 0 and nq % ctx.tp == 0
@@ -579,6 +582,7 @@ def attention_forward(
                     flash = jax.jit(shard_map_compat(
                         lambda q_, k_, v_: fa.flash_attention(
                             q_, k_, v_, causal=causal, **tiles,
+                            window=window,
                             head_fold=getattr(cfg, "flash_head_fold",
                                               False)),
                         ctx.shard_map_mesh,
@@ -590,7 +594,7 @@ def attention_forward(
                     flash = jax.jit(shard_map_compat(
                         lambda q_, k_, v_, s_: fa.flash_attention(
                             q_, k_, v_, causal=causal, **tiles,
-                            segment_ids=s_),
+                            segment_ids=s_, window=window),
                         ctx.shard_map_mesh,
                         in_specs=(spec, spec, spec, seg_spec),
                         out_specs=spec))
@@ -598,7 +602,7 @@ def attention_forward(
             else:
                 attn_out = fa.flash_attention(
                     q, k, v, causal=causal, **tiles,
-                    segment_ids=segment_ids,
+                    segment_ids=segment_ids, window=window,
                     head_fold=getattr(cfg, "flash_head_fold", False))
         else:
             if impl != "pallas":

@@ -31,7 +31,9 @@ from megatronapp_tpu.transformer.attention import (
     attention_forward, init_attention_params,
 )
 from megatronapp_tpu.transformer.mlp import init_mlp_params, mlp_forward
-from megatronapp_tpu.transformer.moe import init_moe_params, moe_forward
+from megatronapp_tpu.transformer.moe import (
+    EXPERT_GEMM_OUT, init_moe_params, moe_forward,
+)
 from megatronapp_tpu.scope.hooks import scope_capture
 
 
@@ -40,6 +42,11 @@ def _norm_scale(cfg: TransformerConfig):
     the g = 0 of a scale 1 + g."""
     return jnp.full((cfg.hidden_size,), 0.0 if cfg.norm_unit_offset else 1.0,
                     cfg.params_dtype)
+
+
+def _init_depth(cfg: TransformerConfig) -> int:
+    """The depth the scaled init divides by (cfg.scaled_init_layers)."""
+    return cfg.scaled_init_layers or cfg.num_layers
 
 
 def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
@@ -100,7 +107,7 @@ def init_layer_params(rng, cfg: TransformerConfig, force_dense: bool = False):
     that reads its second norm's output (layer_forward)."""
     if cfg.moe_shortcut_double_layer:
         # five residual-out projections a layer, four of them in sequence
-        out_std = cfg.init_method_std / jnp.sqrt(4.0 * cfg.num_layers)
+        out_std = cfg.init_method_std / jnp.sqrt(4.0 * _init_depth(cfg))
         halves = {}
         for name, key in zip(("first", "second"), jax.random.split(rng)):
             k_attn, k_mlp, k_moe = jax.random.split(key, 3)
@@ -115,7 +122,7 @@ def init_layer_params(rng, cfg: TransformerConfig, force_dense: bool = False):
                 {k: v[1] for k, v in halves.items()})
     # Scaled init for residual-out projections: std/sqrt(2*num_layers)
     # (reference scaled_init_method_normal, training/utils).
-    out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
+    out_std = cfg.init_method_std / jnp.sqrt(2.0 * _init_depth(cfg))
     k_attn, k_mlp = jax.random.split(rng)
     p, ax = _init_mixer_half(k_attn, cfg, out_std)
     ffn_p, ffn_ax = _init_ffn_half(k_mlp, cfg, out_std, force_dense)
@@ -130,8 +137,11 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                   page_table=None, active=None, chunk_counts=None,
                   tp_sharded: bool = False, kv_scales=None,
                   fp8=None, lora=None, kv_plane=None, ssm_state=None,
-                  state_rows=None, window_rope=None):
+                  state_rows=None, window_rope=None, moe_counts: bool = False):
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
+
+    moe_counts: a training step's MoE layer hands back (aux loss, its
+    routing counts: moe.routing_counts_held) in place of the aux loss.
 
     window_rope: (cos, sin) of a sliding-window stack's window layers
     (models/gpt.py gpt_rope_tables(window=True)). Not None marks THIS layer
@@ -341,7 +351,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         with jax.named_scope("moe"):
             mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
                                        ctx=ctx, tp_sharded=tp_sharded,
-                                       count_rows=count_rows)
+                                       count_rows=count_rows,
+                                       train_counts=moe_counts)
     if "mlp" in p:
         if "moe" in p:
             # The shortcut: the MoE's output skips the rest of the layer.
@@ -362,7 +373,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
 
 _SAVE_MATMULS = jax.checkpoint_policies.save_from_both_policies(
     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    jax.checkpoint_policies.save_only_these_names(RANK_DENSE_OUT))
+    jax.checkpoint_policies.save_only_these_names(RANK_DENSE_OUT,
+                                                  EXPERT_GEMM_OUT))
 
 
 def _remat_wrap(fn, policy: str):
@@ -371,8 +383,9 @@ def _remat_wrap(fn, policy: str):
     if policy == "selective":
         # Save matmul outputs, recompute the rest (attention softmax etc.) —
         # semantics of the reference --recompute-activations selective mode.
-        # A product batched over data-parallel ranks (ops/per_rank.py) is
-        # such an output by name.
+        # A product batched over data-parallel ranks (ops/per_rank.py) and
+        # an MoE layer's grouped products (`lax.ragged_dot`, which the dot
+        # policy does not know) are such outputs by name.
         return jax.checkpoint(fn, policy=_SAVE_MATMULS)
     if policy == "selective_attn":
         # Selective + the tagged attention outputs: skips the flash-kernel
@@ -426,7 +439,7 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     an MoE model with cfg.moe_first_k_dense the MoE layers'
     [num_layers - k, ...], the k leading layers' dense halves being
     "ffn_lead" [k, ...]."""
-    out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
+    out_std = cfg.init_method_std / jnp.sqrt(2.0 * _init_depth(cfg))
     keys = jax.vmap(jax.random.split)(
         jax.random.split(rng, cfg.num_layers))        # [L, (mixer, ffn)]
     attends = np.asarray([cfg.layer_is_attention(i)
@@ -450,7 +463,8 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
             {k: v[1] for k, v in done.items()})
 
 
-def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
+def hybrid_layer_loop(cfg: TransformerConfig, carry, run,
+                      scan_runs: bool = True):
     """Walk a hybrid stack in layer order as SCANNED runs, not unrolled
     and without carrying both kinds' weights through every layer: an outer
     scan over whole periods of (scan `offset` state-space layers, the
@@ -463,6 +477,18 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
     attention layer its plane of the KV pools), layer_id its index in the
     model (its row of "ffn"); both are int32 scalars, traced inside the
     scans.
+
+    scan_runs: False writes a period's runs out layer by layer and leaves
+    the scan over periods the only one. block_forward, which is
+    differentiated, does. Measured on the sliding-window MoE training cell
+    (PERF.md, PR 48): its step compiled for a described v5e holds 10.49 GiB
+    with its run of three written out, 14.00 with the run scanned and 13.69
+    scanned with the run's rows handed to the scan as its xs in place of the
+    whole stacks (under a gradient a scan keeps its own cotangent
+    accumulator for every stack its body reads and the residuals of all its
+    turns); on the chip the written-out step trains 24,441 tokens a second
+    and the scanned one 23,380. The serving steps keep nothing for a
+    backward pass and scan every run of two or more: a launch a run.
 
     An MoE model's cfg.moe_first_k_dense leading layers (dense
     feed-forwards: another body) run first, one after the other, with k
@@ -485,6 +511,10 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run):
             return carry
         if count == 1:
             return run(carry, False, k0, lid0)
+        if not scan_runs:
+            for j in range(count):
+                carry = run(carry, False, k0 + j, lid0 + j)
+            return carry
         return jax.lax.scan(
             lambda c, j: (run(c, False, k0 + j, lid0 + j), None), carry,
             jnp.arange(count, dtype=jnp.int32))[0]
@@ -590,10 +620,14 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
                   rope_cos=None, rope_sin=None, attention_mask=None,
                   layer_offset: int = 0, ctx=None, zigzag: bool = False,
                   segment_ids=None, tp_sharded: bool = False, fp8=None,
-                  window_rope=None):
+                  window_rope=None, moe_counts: bool = False):
     """Run all stacked layers via lax.scan. Returns (x, moe_aux_sum).
 
     window_rope: the window layers' (cos, sin) of a sliding-window stack.
+
+    moe_counts: return (x, moe_aux_sum, counts) with the layers' routing
+    counts summed (int32, moe.HELD_COUNTS' order): a hybrid stack whose
+    layers count their held experts' load (cfg.moe_counts_load) alone.
 
     tp_sharded: thread the ambient-manual tp-sharded stage-body path
     through every layer (pp pipeline; see layer_forward).
@@ -633,22 +667,45 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
                 "ep all-to-all, and tp, cp and pp layouts of its layer "
                 "loop, are not written yet (ROADMAP M4)")
 
-        def one_layer(layer_p, h, lid, windowed=False):
-            (h2, _), _ = layer_forward(
-                layer_p, h, cfg, rope_cos, rope_sin, attention_mask,
-                layer_id=lid, ctx=ctx, segment_ids=segment_ids,
-                window_rope=window_rope if windowed else None)
-            return h2
+        from megatronapp_tpu.transformer.moe import HELD_COUNTS
+
+        def one_layer(stacks, carry, k, lid, attends, lead):
+            # The layer's rows are cut out of the stacks INSIDE the
+            # recomputed body: cut outside it they are its inputs, and the
+            # scans would keep every layer's row for the backward pass, a
+            # second copy of the block's parameters.
+            # The router's loss and the routing counts ride the scans'
+            # carry beside the stream, in both passes and under
+            # recomputation (the counts are integers: no cotangent).
+            h, aux_sum, counts = carry
+            windowed = bool(cfg.sliding_window) and not attends
+            (h2, _), aux = layer_forward(
+                hybrid_layer_params(stacks, attends, k, lid, lead), h, cfg,
+                rope_cos, rope_sin, attention_mask,
+                layer_id=lid + layer_offset, ctx=ctx,
+                segment_ids=segment_ids,
+                window_rope=window_rope if windowed else None,
+                moe_counts=moe_counts)
+            if aux is not None:         # a leading dense layer has none
+                if moe_counts:
+                    aux, layer_counts = aux
+                    counts = counts + layer_counts
+                aux_sum = aux_sum + aux
+            return h2, aux_sum, counts
 
         # a body a kind of layer: which one a layer is, is static
-        bodies = {w: _remat_wrap(functools.partial(one_layer, windowed=w),
-                                 cfg.remat_policy) for w in (False, True)}
-        x = hybrid_layer_loop(
-            cfg, x, lambda h, attends, k, lid, lead=False: bodies[
-                bool(cfg.sliding_window) and not attends](
-                hybrid_layer_params(stacked_p, attends, k, lid, lead), h,
-                lid + layer_offset))
-        return x, jnp.zeros((), jnp.float32)
+        bodies = {(a, ld): _remat_wrap(
+            functools.partial(one_layer, attends=a, lead=ld),
+            cfg.remat_policy) for a in (False, True) for ld in (False, True)}
+        x, aux, counts = hybrid_layer_loop(
+            cfg, (x, jnp.zeros((), jnp.float32),
+                  jnp.zeros((len(HELD_COUNTS),), jnp.int32)),
+            lambda c, attends, k, lid, lead=False: bodies[attends, lead](
+                stacked_p, c, k, lid), scan_runs=False)
+        return (x, aux, counts) if moe_counts else (x, aux)
+    if moe_counts:
+        raise NotImplementedError(
+            "routing counts ride the hybrid layer loop's carry alone")
     hetero = isinstance(stacked_p, dict) and "dense" in stacked_p
 
     def run_layer(layer_p, h, lid, fp8_l=None):

@@ -36,10 +36,14 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.ops.activations import apply_activation, is_gated
 from megatronapp_tpu.ops.pallas.grouped_gemm import grouped_gemm
+
+# The grouped products' outputs under jax.checkpoint (checkpoint_name).
+EXPERT_GEMM_OUT = "expert_gemm_out"
 
 
 def init_moe_params(rng, cfg: TransformerConfig, out_std: float):
@@ -206,7 +210,29 @@ def _grouped_gemm(x, w, group_sizes, dt) -> jnp.ndarray:
     differentiates and partitions."""
     if isinstance(w, StackedLayer) and w.stack.dtype == dt:
         return grouped_gemm(x, w.stack, group_sizes, layer=w.layer)
-    return jax.lax.ragged_dot(x, _expert_kernel(w, dt), group_sizes)
+    # Named for the layer loop's recomputation policy: a grouped product is
+    # a matrix product, and 'selective' keeps those (transformer/block.py).
+    return checkpoint_name(
+        _ragged_dot(x, _expert_kernel(w, dt), group_sizes), EXPERT_GEMM_OUT)
+
+
+def _ragged_dot(x, w, group_sizes):
+    """``lax.ragged_dot`` with the rows behind the last group (a share of the
+    experts: `_dropless_held_experts`) set to 0 on both sides of it. Such
+    rows' output is undefined, on a TPU whatever the buffer held, NaN among
+    it, and so is their row of x's cotangent in the backward products. A
+    reader that only masks what it reads is not safe under a gradient: the
+    zero cotangent of a masked row still meets the row's value (0 x NaN in
+    the router weights' and the gated activation's gradients), and the
+    undefined cotangent rows are scatter-added into the tokens'. With both
+    selects every value a training step touches is defined (its gradient
+    was NaN on the chip without them, from the first step or some steps
+    later: PERF.md, PR 48). The paged serving steps run the Pallas kernel,
+    not this."""
+    keep = (jnp.arange(x.shape[0]) < jnp.sum(group_sizes))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(keep, x, jnp.zeros_like(x)), w,
+                             group_sizes)
+    return jnp.where(keep, out, jnp.zeros_like(out))
 
 
 def _dropless_experts(p, x_flat, topk_idx, topk_probs,
@@ -278,12 +304,12 @@ def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
     group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
         jnp.int32)
 
+    in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
     x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
     y = _grouped_gemm(x_sorted, p["fc1_kernel"], group_sizes, dt)
     y = _grouped_gemm(_apply_act(cfg, y), p["fc2_kernel"], group_sizes, dt)
 
     flat_w = topk_probs.reshape(t * k).astype(jnp.float32)
-    in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
     y = jnp.where(in_group[:, None],
                   y.astype(jnp.float32) * jnp.take(flat_w, order)[:, None],
                   0.0)
@@ -335,7 +361,8 @@ def routing_counts_held(topk_idx, count_rows,
 
 
 def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
-                ctx=None, tp_sharded: bool = False, count_rows=None
+                ctx=None, tp_sharded: bool = False, count_rows=None,
+                train_counts: bool = False
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: [B,S,H] → ([B,S,H], aux_loss scalar).
 
@@ -343,6 +370,11 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
     for the aux loss): the second result is then ``routing_counts`` of
     those rows (``routing_counts_held`` where cfg.moe_counts_load), for
     the engine's always-on `moe` counters.
+
+    train_counts: a training step that counts its held experts' load too
+    (cfg.moe_counts_load): the second result is then (aux_loss,
+    ``routing_counts_held`` of every row), the router's loss over its whole
+    width whatever is held.
 
     ctx with ep > 1 selects the explicit all-to-all dispatch
     (_a2a_expert_forward): expert weights stay home on their ep shard and
@@ -425,6 +457,8 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
             aux = routing_counts_held(topk_idx, count_rows.reshape(t), cfg)
         else:
             aux = routing_counts(topk_idx, count_rows.reshape(t), e)
+    elif train_counts:
+        aux = (aux, routing_counts_held(topk_idx, jnp.ones((t,), bool), cfg))
 
     if cfg.moe_capacity_factor is None:
         out = _dropless_experts(p, x_flat, topk_idx, topk_probs, cfg)

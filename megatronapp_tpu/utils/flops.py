@@ -50,29 +50,41 @@ def tpu_roofs(device_kind: str):
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
-    """Forward+backward FLOPs per token (3x forward matmul FLOPs)."""
+    """Forward+backward FLOPs per token (3x forward matmul FLOPs;
+    recomputed operations do not count). A full-attention layer counts
+    seq_len keys a query, a sliding-window layer (its own head count where
+    the model gives it one) min(seq_len, window); an MoE layer its top-k
+    picks, a shared expert and the router's whole width, behind
+    moe_first_k_dense dense layers. A share of the experts
+    (moe_experts_held) is counted as the whole model: what the other
+    shares' chips would compute of a token is in the number."""
     h = cfg.hidden_size
     d = cfg.head_dim
-    nq, nkv = cfg.num_attention_heads, cfg.num_query_groups
-    l = cfg.num_layers
+    nkv = cfg.num_query_groups
 
-    # Attention projections: Q + KV + out.
-    proj = 2 * h * (nq * d) + 2 * h * (2 * nkv * d) + 2 * (nq * d) * h
-    # Attention scores + context: 2 * S * (nq*d) each (per token, seq_len kv).
-    attn = 2 * 2 * seq_len * nq * d
-    # MLP.
-    f = cfg.ffn_hidden_size
-    if cfg.is_moe:
-        f_active = cfg.moe_ffn_hidden_size * cfg.moe_router_topk
-        if cfg.moe_shared_expert_intermediate_size:
-            f_active += cfg.moe_shared_expert_intermediate_size
-        f = f_active
+    def attention(nq, keys):
+        # Projections Q + KV + out; scores + context over `keys`.
+        return (2 * h * (nq * d) + 2 * h * (2 * nkv * d) + 2 * (nq * d) * h
+                + 2 * 2 * keys * nq * d)
+
+    attn = attention(cfg.num_attention_heads, seq_len)
+    mixers = (cfg.num_layers - cfg.num_window_layers) * attn
+    if cfg.num_window_layers:
+        mixers += cfg.num_window_layers * attention(
+            cfg.window_heads, min(seq_len, cfg.sliding_window))
     gated = cfg.activation in (ActivationKind.swiglu, ActivationKind.geglu)
-    mlp = (3 if gated else 2) * 2 * h * f
-    per_layer = proj + attn + mlp
+    per_width = (3 if gated else 2) * 2 * h
+    dense = per_width * cfg.ffn_hidden_size
+    if cfg.is_moe:
+        f_active = cfg.moe_ffn_hidden_size * cfg.moe_router_topk + (
+            cfg.moe_shared_expert_intermediate_size or 0)
+        moe = per_width * f_active + 2 * h * cfg.moe_router_width
+        lead = cfg.moe_first_k_dense
+        ffns = lead * dense + (cfg.num_layers - lead) * moe
+    else:
+        ffns = cfg.num_layers * dense
     logits = 2 * h * cfg.vocab_size
-    fwd = l * per_layer + logits
-    return 3.0 * fwd  # fwd + bwd (2x fwd)
+    return 3.0 * (mixers + ffns + logits)  # fwd + bwd (2x fwd)
 
 
 def mfu(tokens_per_sec_per_chip: float, cfg: TransformerConfig,

@@ -208,7 +208,10 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
 
 
 def _window_lines(device=TPU, **kw):
-    res = {"tokens": 251, "in_vocab": True,
+    res = {"tokens": 251, "in_vocab": True, "train_loss": 6.31,
+           "train_grads_finite": True,
+           "train_kernels": ["flash_window_bwd_dkv", "flash_window_bwd_dq",
+                             "flash_window_fwd"],
            "moe": {"tokens": 79, "assignments": 632, "experts_here": 8},
            "window": {"blocks_taken": 18, "blocks_given_back": 18,
                       "blocks_held": 0, "rows_walked": 2872,
@@ -239,6 +242,9 @@ def _window_lines(device=TPU, **kw):
      "no row spared"),
     ({"moe": {"tokens": 79, "assignments": 630, "experts_here": 8}},
      "the picks are not tokens"),
+    ({"train_kernels": ["flash_window_fwd"]}, "not the three flash_window"),
+    ({"train_loss": float("inf")}, "is not finite"),
+    ({"train_grads_finite": False}, "is not finite"),
 ])
 def test_check_window(kw, needle):
     """The window phase's facts: two pairs of page pools, aliased in and
@@ -487,6 +493,7 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert window["pool_shapes"][2][:2] == [3, 37]
     assert window["moe"]["assignments"] == window["moe"]["tokens"] * 2 * 4
     assert window["alias_bytes"] >= window["pool_bytes"] > 0
+    assert 5.5 < window["train_loss"] < 7.5 and window["train_grads_finite"]
     assert sum("not a TPU" in ln for ln in lines) == 8
 
 

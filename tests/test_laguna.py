@@ -247,16 +247,21 @@ def test_the_router_scores_by_sigmoid_selects_with_the_bias_and_scales():
         atol=1e-6)
 
 
-def test_a_window_layer_takes_the_dense_path_in_its_own_words():
+def test_a_window_layer_is_chosen_for_like_a_full_one():
+    """Since the flash kernels have a window term (PR 48) a window layer's
+    whole sequences go where a full layer's go, and say their window."""
     from megatronapp_tpu.ops.pallas.flash_attention import choose_attention
     kw = dict(batch=1, seq=4096, heads=64, head_dim=128, dtype=jnp.bfloat16,
               segments=False, backend="tpu")
     assert choose_attention(impl="auto", **kw).impl == "pallas"
     for impl in ("auto", "pallas"):
         choice = choose_attention(impl=impl, window=512, **kw)
-        assert choice.impl == "reference"
-        assert choice.why == ("sliding window 512: the flash kernels have "
-                              "no window term")
+        assert choice.impl == "pallas"
+        assert choice.why == "S=4096 D=128 window 512"
+    assert choose_attention(impl="reference", window=512, **kw).impl == (
+        "reference")
+    assert choose_attention(impl="auto", window=512,
+                            **dict(kw, backend="cpu")).impl == "reference"
 
 
 @pytest.mark.parametrize("bad,match", [

@@ -298,10 +298,12 @@ class TestRefusals:
         ({"attn_layer_period": 4, "num_moe_experts": 4}, "no MoE"),
         ({"attn_layer_period": 4, "shortconv_kernel": 3,
           "multi_latent_attention": True}, "no MLA"),
+        # (an aux loss and a share of the experts go through the hybrid
+        # layer loop since PR 48: tests/test_mellum.py)
         ({"attn_layer_period": 4, "shortconv_kernel": 3, "num_moe_experts": 4,
-          "moe_aux_loss_coeff": 0.01}, "no aux loss"),
+          "moe_zero_experts": 2}, "moe_zero_experts"),
         ({"attn_layer_period": 4, "shortconv_kernel": 3, "num_moe_experts": 4,
-          "moe_experts_held": (0, 2)}, "hold every"),
+          "mtp_num_layers": 1}, "or MTP"),
         ({"attn_layer_period": 4, "shortconv_kernel": 3, "num_moe_experts": 4,
           "moe_layer_freq": 2}, "every layer"),
         ({"moe_router_score": "tanh"}, "'softmax' or 'sigmoid'"),
