@@ -334,12 +334,12 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
             p, tokens, cfg, ctx=ctx, segment_ids=segment_ids,
             zigzag_keep=True, fp8=fp8, moe_counts=counting)
         if counting:
-            from megatronapp_tpu.transformer.moe import HELD_COUNTS
+            from megatronapp_tpu.transformer.moe import TRAIN_COUNTS
             moe_layers = cfg.num_layers - cfg.moe_first_k_dense
             # "sums": a step's totals over micro-batches, not their mean
             # (training/train_step.py)
             more["sums"] = {
-                **dict(zip(HELD_COUNTS, counts[0])),
+                **dict(zip(TRAIN_COUNTS, counts[0])),
                 "experts_here": jnp.int32(
                     cfg.moe_experts_here[1] * moe_layers),
                 "moe_layer_passes": jnp.int32(moe_layers),

@@ -46,8 +46,10 @@ dispatch) and ``mta.train.sync`` (the ``device_get`` of a log interval's
 metrics; ``steps``, its last step's ``loss`` and ``grad_norm`` unrounded
 and, on a model that counts its held experts' load, the
 interval's ``assignments``, ``assignments_here``, ``assignments_absent``,
-``here_max_rows``, ``experts_here``, ``moe_layer_passes``, ``router_loss``,
-summed over its steps, micro-batches and layers: training/train.py).
+``here_max_rows``, ``row_buffer_rows`` (the rows of the buffers the held
+experts walked: moe._row_buffer_rungs), ``experts_here``,
+``moe_layer_passes``, ``router_loss``, summed over its steps, micro-batches
+and layers: training/train.py).
 
 ``mta.engine.decode_round`` is one span a round, opened when the round's
 tokens are read, with the attributes of its dispatch (``batch``,

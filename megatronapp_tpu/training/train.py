@@ -206,16 +206,19 @@ class _RowBuffer:
 
 
 def _moe_log_part(sums: Dict[str, float]) -> str:
-    """`moe here 0.250 max/mean 1.07 router 1.0e-03 | ` of a log interval's
-    counters, on a model whose layers count their held experts' load
-    (models/gpt.py gpt_loss "sums"): the share of the tokens' picks that
-    fell on an expert held here, the most loaded held expert's rows over
-    the mean, and the router's loss a layer; "" on every other model."""
+    """`moe here 0.250 buffer 1.31 max/mean 1.07 router 1.0e-03 | ` of a log
+    interval's counters, on a model whose layers count their held experts'
+    load (models/gpt.py gpt_loss "sums"): the share of the tokens' picks
+    that fell on an expert held here, the rows of the buffers the held
+    experts walked over those picks (moe._row_buffer_rungs; 1 / here where
+    every call walks all T*k), the most loaded held expert's rows over the
+    mean, and the router's loss a layer; "" on every other model."""
     if not sums.get("assignments_here"):
         return ""
     passes = sums["moe_layer_passes"]
     mean_rows = sums["assignments_here"] / sums["experts_here"]
     return (f"moe here {sums['assignments_here'] / sums['assignments']:.3f} "
+            f"buffer {sums['row_buffer_rows'] / sums['assignments_here']:.2f} "
             f"max/mean {sums['here_max_rows'] / passes / mean_rows:.2f} "
             f"router {sums['router_loss'] / passes:.1e} | ")
 

@@ -141,7 +141,7 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     """One transformer layer. x: [B,S,H] → ((out, new_cache), aux_losses).
 
     moe_counts: a training step's MoE layer hands back (aux loss, its
-    routing counts: moe.routing_counts_held) in place of the aux loss.
+    routing counts, moe.TRAIN_COUNTS) in place of the aux loss.
 
     window_rope: (cos, sin) of a sliding-window stack's window layers
     (models/gpt.py gpt_rope_tables(window=True)). Not None marks THIS layer
@@ -626,7 +626,7 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
     window_rope: the window layers' (cos, sin) of a sliding-window stack.
 
     moe_counts: return (x, moe_aux_sum, counts) with the layers' routing
-    counts summed (int32, moe.HELD_COUNTS' order): a hybrid stack whose
+    counts summed (int32, moe.TRAIN_COUNTS' order): a hybrid stack whose
     layers count their held experts' load (cfg.moe_counts_load) alone.
 
     tp_sharded: thread the ambient-manual tp-sharded stage-body path
@@ -667,7 +667,7 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
                 "ep all-to-all, and tp, cp and pp layouts of its layer "
                 "loop, are not written yet (ROADMAP M4)")
 
-        from megatronapp_tpu.transformer.moe import HELD_COUNTS
+        from megatronapp_tpu.transformer.moe import TRAIN_COUNTS
 
         def one_layer(stacks, carry, k, lid, attends, lead):
             # The layer's rows are cut out of the stacks INSIDE the
@@ -699,7 +699,7 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             cfg.remat_policy) for a in (False, True) for ld in (False, True)}
         x, aux, counts = hybrid_layer_loop(
             cfg, (x, jnp.zeros((), jnp.float32),
-                  jnp.zeros((len(HELD_COUNTS),), jnp.int32)),
+                  jnp.zeros((len(TRAIN_COUNTS),), jnp.int32)),
             lambda c, attends, k, lid, lead=False: bodies[attends, lead](
                 stacked_p, c, k, lid), scan_runs=False)
         return (x, aux, counts) if moe_counts else (x, aux)

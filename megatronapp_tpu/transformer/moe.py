@@ -188,7 +188,7 @@ def _expert_ffn(p, x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
                       _expert_kernel(p["fc2_kernel"], dt))
 
 
-def _grouped_gemm(x, w, group_sizes, dt) -> jnp.ndarray:
+def _grouped_gemm(x, w, group_sizes, dt, given=None) -> jnp.ndarray:
     """The rows x [M, K], sorted by expert, against one layer's expert
     kernel w, group by group; group_sizes [E] int32. Rows behind the last
     group belong to none and their output rows are undefined.
@@ -207,9 +207,12 @@ def _grouped_gemm(x, w, group_sizes, dt) -> jnp.ndarray:
     Everything else (training, a mesh, resident int8 pairs, a stack that is
     not held in the compute dtype and would be converted whole) takes the
     layer's own [E, K, N] kernel through ``lax.ragged_dot``, which XLA
-    differentiates and partitions."""
+    differentiates and partitions; `given`, the product as a forward pass
+    kept it, stands in for computing it again (_product_given)."""
     if isinstance(w, StackedLayer) and w.stack.dtype == dt:
         return grouped_gemm(x, w.stack, group_sizes, layer=w.layer)
+    if given is not None:
+        return _product_given(x, _expert_kernel(w, dt), group_sizes, given)
     # Named for the layer loop's recomputation policy: a grouped product is
     # a matrix product, and 'selective' keeps those (transformer/block.py).
     return checkpoint_name(
@@ -233,6 +236,31 @@ def _ragged_dot(x, w, group_sizes):
     out = jax.lax.ragged_dot(jnp.where(keep, x, jnp.zeros_like(x)), w,
                              group_sizes)
     return jnp.where(keep, out, jnp.zeros_like(out))
+
+
+@jax.custom_vjp
+def _product_given(x, w, group_sizes, out):
+    """`out`, which is _ragged_dot(x, w, group_sizes) as an earlier pass
+    computed it, with that product's gradient: a backward pass that was
+    handed the product (_laddered_rows) differentiates through it without
+    multiplying again."""
+    return out
+
+
+def _product_given_fwd(x, w, group_sizes, out):
+    return out, (x, w, group_sizes)
+
+
+def _product_given_bwd(res, g):
+    x, w, group_sizes = res
+    dx, = jax.linear_transpose(
+        lambda x: _ragged_dot(x, w, group_sizes), x)(g)
+    dw, = jax.linear_transpose(
+        lambda w: _ragged_dot(x, w, group_sizes), w)(g)
+    return dx, dw, None, jnp.zeros_like(g)
+
+
+_product_given.defvjp(_product_given_fwd, _product_given_bwd)
 
 
 def _dropless_experts(p, x_flat, topk_idx, topk_probs,
@@ -277,6 +305,140 @@ def _held_slot(flat_expert, cfg: TransformerConfig):
     return jnp.where((local >= 0) & (local < count), local, count), count
 
 
+# A compact row buffer is another copy of the held experts' body to compile
+# and a conditional on the device: one is offered only where it leaves at
+# least this many rows of a call out (a paged serving step's 768 or 6,144
+# rows keep the one full buffer, and so the program they had).
+_RUNG_MIN_SKIPPED = 8192
+# Rungs are whole row tiles of either grouped product (lax.ragged_dot's 64
+# and 512 on a TPU, choose_gemm_tiles' at most 512).
+_RUNG_TILE = 512
+# The compact rungs, over the rows the picks land here with by chance. The
+# first lies above the mode of the calls' shares, not on it: a rung on the
+# mode moves calls between rungs with every change of the routing, and the
+# step's time with them (PERF.md, PR 49, has the calls' distribution).
+_RUNG_FACTORS = ((5, 4), (3, 2), (2, 1))
+
+
+def _row_buffer_rungs(rows: int, count: int, width: int) -> Tuple[int, ...]:
+    """The sizes the held experts' row buffer may take in a call of `rows`
+    picks whose layer holds `count` of the router's `width` outputs,
+    ascending; the last is `rows`, which holds whatever the router does. The
+    compact ones are 5/4, 3/2 and 2 times rows x count / width, the picks
+    that land here by chance, in whole tiles: a call takes the smallest
+    that holds its held picks (_dropless_held_experts). A function of the
+    three numbers alone."""
+    tiles = {-(-rows * count * num // (width * den * _RUNG_TILE))
+             for num, den in _RUNG_FACTORS}
+    return tuple(r for r in sorted(t * _RUNG_TILE for t in tiles)
+                 if rows - r >= _RUNG_MIN_SKIPPED) + (rows,)
+
+
+def _rung_taken(n, rungs: Tuple[int, ...]):
+    """Index of the smallest of `rungs` that holds `n` rows (int32 scalar on
+    the device)."""
+    return jnp.sum(n > jnp.asarray(rungs[:-1], jnp.int32)).astype(jnp.int32)
+
+
+def row_buffer_rows(n, rows: int, cfg: TransformerConfig):
+    """Rows of the buffer the dropless experts walk in a call of `rows`
+    picks of which `n` landed on the experts held here (a layer that holds
+    every expert has the one rung, `rows`)."""
+    rungs = _row_buffer_rungs(rows, cfg.moe_experts_here[1],
+                              cfg.moe_router_width)
+    return jnp.asarray(rungs, jnp.int32)[_rung_taken(n, rungs)]
+
+
+def _held_rows(kernels, x_flat, topk_probs, order, token_of, group_sizes,
+               cfg: TransformerConfig, rows: int, given=(None, None)):
+    """Σ_{picks of a held expert e} w_e · FFN_e(x) over a buffer of the first
+    `rows` sorted picks (`order`, and the tokens they are of): ([T, H]
+    float32, the two grouped products). Every group's rows have to lie
+    inside the buffer; the rows behind the groups belong to none, cost no
+    GEMM step, and their (undefined) output rows are masked before the
+    weighted sum. `given`: the two products as a forward pass kept them."""
+    t, h = x_flat.shape
+    dt = cfg.compute_dtype
+    fc1, fc2 = kernels
+    if rows < order.shape[0]:       # the T*k buffer: no slice, as before
+        order, token_of = order[:rows], token_of[:rows]
+    in_group = jnp.arange(rows) < jnp.sum(group_sizes)
+    x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
+    y1 = _grouped_gemm(x_sorted, fc1, group_sizes, dt, given[0])
+    y2 = _grouped_gemm(_apply_act(cfg, y1), fc2, group_sizes, dt, given[1])
+
+    flat_w = topk_probs.reshape(-1).astype(jnp.float32)
+    y = jnp.where(in_group[:, None],
+                  y2.astype(jnp.float32) * jnp.take(flat_w, order)[:, None],
+                  0.0)
+    return jnp.zeros((t, h), jnp.float32).at[token_of].add(y), (y1, y2)
+
+
+def _laddered_rows(cfg: TransformerConfig, rungs: Tuple[int, ...]):
+    """_held_rows' sum over the smallest of `rungs` that holds the call's
+    held picks, as a function of _held_rows' six operands: ``lax.switch``
+    over one copy of the body a rung, of which a TPU runs the one taken.
+
+    It carries its own backward pass, a second switch whose branch
+    differentiates that rung's body (``jax.vjp`` inside the branch), because
+    JAX's derivative of a conditional hands every branch's residuals out of
+    it, zero-filled by the branches not taken: the cell's step then needs
+    20.5 GB of the chip's 15.75 GiB (the compiler's refusal; PERF.md, PR 49).
+    What passes from the forward switch to the backward one is the two
+    grouped products alone, in buffers of the largest compact rung's rows
+    (half of what the T*k buffer's were), named for the layer loop's
+    recomputation policy outside the switch: 'selective' keeps them and the
+    backward pass multiplies nothing twice; without a policy that keeps
+    them, or in the last rung, whose products would not fit them, the
+    branch computes its own."""
+    kept_rows = rungs[-2]           # the largest compact rung
+
+    def taken(group_sizes):
+        return _rung_taken(jnp.sum(group_sizes), rungs)
+
+    def forward_rung(rows):
+        def run(*operands):
+            out, products = _held_rows(*operands, cfg, rows)
+            return out, tuple(
+                jnp.pad(y, ((0, kept_rows - rows), (0, 0)))
+                if rows <= kept_rows
+                else jnp.zeros((kept_rows, y.shape[1]), y.dtype)
+                for y in products)
+        return run
+
+    def walk_fwd(*operands):
+        out, products = jax.lax.switch(
+            taken(operands[-1]), [forward_rung(r) for r in rungs], *operands)
+        return out, (operands, tuple(checkpoint_name(y, EXPERT_GEMM_OUT)
+                                     for y in products))
+
+    @jax.custom_vjp
+    def walk(*operands):
+        return walk_fwd(*operands)[0]
+
+    def backward_rung(rows):
+        def run(operands, products, g):
+            kernels, x_flat, topk_probs, *sorted_picks = operands
+            given = (tuple(y[:rows] for y in products)
+                     if rows <= kept_rows else (None, None))
+            _, vjp = jax.vjp(
+                lambda *diff: _held_rows(*diff, *sorted_picks, cfg, rows,
+                                         given)[0],
+                kernels, x_flat, topk_probs)
+            return vjp(g)
+        return run
+
+    def walk_bwd(res, g):
+        operands, products = res
+        grads = jax.lax.switch(
+            taken(operands[-1]), [backward_rung(r) for r in rungs],
+            operands, products, g)
+        return (*grads, None, None, None)
+
+    walk.defvjp(walk_fwd, walk_bwd)
+    return walk
+
+
 def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
                            cfg: TransformerConfig) -> jnp.ndarray:
     """_dropless_experts for a layer that is told which experts it holds
@@ -285,18 +447,20 @@ def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
         Σ_{picks of a held expert e} w_e · FFN_e(x)  +  (Σ_{picks of a
         zero-compute expert} w_e) · x
 
-    The picks of the held experts sort to the front of the row buffer,
-    group by group; the group sizes cover those rows alone, so the grouped
-    GEMMs' tiles run over them and over nothing else. The buffer itself
-    stays T*k rows, what static shapes force (any token may pick k held
-    experts): the rows behind the groups are picks of absent or
-    zero-compute experts, belong to no group, cost no GEMM step, and their
-    (undefined) output rows are masked before the weighted sum. What the
-    absent experts would have added is left out; the identity term needs
-    no weights and is computed here whole."""
-    t, h = x_flat.shape
+    The picks of the held experts sort to the front, group by group; the
+    group sizes cover those rows alone, so the grouped GEMMs' tiles run
+    over them and over nothing else. What is gathered, multiplied, weighed
+    and scattered is a row buffer of the first R sorted picks (_held_rows),
+    R chosen in the call, on the device, as the smallest of a few static
+    sizes (_row_buffer_rungs) that holds the n picks that landed here: one
+    branch a size, of which a TPU runs the one taken. The last size is T*k
+    (any token may pick k held experts), so nothing is ever dropped, and
+    the sum is the T*k buffer's bit for bit: the real rows keep their order
+    in the scatter-add and the rows behind them added 0.0. What the absent
+    experts would have added is left out; the identity term needs no
+    weights and is computed here whole."""
+    t, _ = x_flat.shape
     k = cfg.moe_router_topk
-    dt = cfg.compute_dtype
     flat_expert = topk_idx.reshape(t * k)
     slot, count = _held_slot(flat_expert, cfg)
     order = jnp.argsort(slot)
@@ -304,16 +468,13 @@ def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
     group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
         jnp.int32)
 
-    in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
-    x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
-    y = _grouped_gemm(x_sorted, p["fc1_kernel"], group_sizes, dt)
-    y = _grouped_gemm(_apply_act(cfg, y), p["fc2_kernel"], group_sizes, dt)
-
-    flat_w = topk_probs.reshape(t * k).astype(jnp.float32)
-    y = jnp.where(in_group[:, None],
-                  y.astype(jnp.float32) * jnp.take(flat_w, order)[:, None],
-                  0.0)
-    out = jnp.zeros((t, h), jnp.float32).at[token_of].add(y)
+    rungs = _row_buffer_rungs(t * k, count, cfg.moe_router_width)
+    operands = ((p["fc1_kernel"], p["fc2_kernel"]), x_flat, topk_probs,
+                order, token_of, group_sizes)
+    if len(rungs) == 1:
+        out, _ = _held_rows(*operands, cfg, t * k)
+    else:
+        out = _laddered_rows(cfg, rungs)(*operands)
     if cfg.moe_zero_experts:
         w_zero = jnp.sum(jnp.where(topk_idx >= cfg.num_moe_experts,
                                    topk_probs.astype(jnp.float32), 0.0),
@@ -335,6 +496,9 @@ def routing_counts(topk_idx, count_rows, num_experts: int) -> jnp.ndarray:
 # What routing_counts_held returns, in order (the engine's `moe` counters).
 HELD_COUNTS = ("assignments", "expert_pairs_touched", "assignments_zero",
                "assignments_here", "assignments_absent", "here_max_rows")
+# What a training step's MoE layer counts (moe_forward(train_counts=)): those,
+# and the rows of the buffer its held experts walked (row_buffer_rows).
+TRAIN_COUNTS = HELD_COUNTS + ("row_buffer_rows",)
 
 
 def routing_counts_held(topk_idx, count_rows,
@@ -372,9 +536,10 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
     the engine's always-on `moe` counters.
 
     train_counts: a training step that counts its held experts' load too
-    (cfg.moe_counts_load): the second result is then (aux_loss,
-    ``routing_counts_held`` of every row), the router's loss over its whole
-    width whatever is held.
+    (cfg.moe_counts_load): the second result is then (aux_loss, int32 [7]
+    in TRAIN_COUNTS' order: ``routing_counts_held`` of every row and the
+    rows of the buffer the held experts walked), the router's loss over its
+    whole width whatever is held.
 
     ctx with ep > 1 selects the explicit all-to-all dispatch
     (_a2a_expert_forward): expert weights stay home on their ep shard and
@@ -458,7 +623,10 @@ def moe_forward(p, x: jnp.ndarray, cfg: TransformerConfig, layer_id=None,
         else:
             aux = routing_counts(topk_idx, count_rows.reshape(t), e)
     elif train_counts:
-        aux = (aux, routing_counts_held(topk_idx, jnp.ones((t,), bool), cfg))
+        counts = routing_counts_held(topk_idx, jnp.ones((t,), bool), cfg)
+        walked = row_buffer_rows(
+            counts[HELD_COUNTS.index("assignments_here")], t * k, cfg)
+        aux = (aux, jnp.concatenate([counts, walked[None]]))
 
     if cfg.moe_capacity_factor is None:
         out = _dropless_experts(p, x_flat, topk_idx, topk_probs, cfg)

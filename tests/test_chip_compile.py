@@ -511,11 +511,16 @@ def test_train_step_of_the_expert_share_cell(topo, chip_compile, capsys):
     # bound ISSUE 48 asked for. `temp_size_in_bytes` counts the donated
     # state (6.65 GiB) with the temporaries: 10.49 GiB here, where the
     # chip's allocator peaked at 10.9 GB running the step (PERF.md, PR 48);
-    # 14.00 with the run of three window layers scanned. The bound guards
-    # against a step that grows back.
+    # 14.00 with the run of three window layers scanned. Since ISSUE 49 the
+    # held experts' row buffer is one of four sizes behind a switch a pass:
+    # 11.29 GiB here (11.20 to 11.29 whichever rungs, one compact rung or
+    # three) while the chip's allocator peaked at 10.91 to 10.98 GB, where
+    # it had; JAX's own derivative of that switch needs 20.5 GB and is
+    # refused. The bound guards against a step that grows back.
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 11.25 * 2**30, (
+    assert mem.temp_size_in_bytes < 11.5 * 2**30, (
         mem.temp_size_in_bytes / 2**30)
+    assert text.count(" conditional(") >= 8     # a switch a layer and pass
 
 
 def test_train_step_tp2_dp2(topo, chip_compile):

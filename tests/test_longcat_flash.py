@@ -473,6 +473,58 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
     assert disp["expert_stack_slices"] == 0
 
 
+# sha256 of the two paged steps' lowered text at the parent commit (7ea5e97),
+# by _agent_step_texts run there.
+PARENT_STEP_SHA = {
+    ("float32", "prefill"):
+        "8a6e6545d9235e4b", ("float32", "decode"): "b0321b94085b5559",
+    ("bfloat16", "prefill"):
+        "47cdadf3c07404fc", ("bfloat16", "decode"): "5a7db2b80e9f4ad1",
+}
+
+
+def _agent_step_texts(compute_dtype):
+    """{step: sha256[:16] of its StableHLO}: the double layer's prefill call
+    (1 x 8 rows) and decode round at TINY's widths, traced from shapes."""
+    import hashlib
+    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype)
+    params = jax.eval_shape(lambda: MODEL.init_params(cfg, seed=5))
+    max_len, bs, chunk = 64, 4, 8
+    nb = max_len // bs
+    sds = jax.ShapeDtypeStruct
+    pages = (sds((cfg.kv_planes, nb, bs, cfg.kv_lora_rank), compute_dtype),
+             sds((cfg.kv_planes, nb, bs, cfg.qk_pos_emb_head_dim),
+                 compute_dtype))
+    table, one = sds((1, nb), jnp.int32), sds((1,), jnp.int32)
+    active = sds((1,), bool)
+    texts = {
+        "prefill": jax.jit(
+            lambda *a: _paged_multiquery_step(*a, cfg, max_len)).lower(
+            params, sds((1, chunk), jnp.int32), pages, table, one, one,
+            active).as_text(),
+        "decode": jax.jit(
+            lambda *a: _paged_decode_step(*a, cfg, max_len)).lower(
+            params, sds((1, 1), jnp.int32), pages, table, one,
+            active).as_text()}
+    return {k: hashlib.sha256(t.encode()).hexdigest()[:16]
+            for k, t in texts.items()}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_paged_steps_lower_to_the_parents_text(dtype, monkeypatch):
+    """ISSUE 49 gave the held experts' row buffer a ladder of sizes; a call
+    as small as a serving step's keeps the one T*k buffer and with it the
+    program it had, instruction for instruction: no switch is built
+    (`_laddered_rows` is never reached) and the steps' lowered text is the
+    parent commit's. (A change to the steps' other code moves these hashes
+    too: re-pin them from the commit before it.)"""
+    monkeypatch.setattr(moe, "_laddered_rows", None)
+    got = _agent_step_texts(dtype)
+    assert got == {step: sha for (name, step), sha in PARENT_STEP_SHA.items()
+                   if name == jnp.dtype(dtype).name}
+
+
 class TestThreeSourcesAgree:
     """The preset, the benchmark's configuration file and the catalog's row
     say the same model; the file differs by its three `reduced` keys."""

@@ -195,8 +195,20 @@ def test_the_four_shares_add_up_to_the_whole_layer():
     np.testing.assert_allclose(ref_aux, aux, rtol=1e-6)
 
 
+@pytest.fixture
+def small_rungs(monkeypatch):
+    """The held experts' ladder at a test's size: a micro-batch of 2 x 96
+    tokens x 4 picks = 768 rows of which 192 land here by chance, in tiles
+    of 8, where the program offers a compact buffer only to calls that skip
+    8,192 rows."""
+    monkeypatch.setattr(moe, "_RUNG_MIN_SKIPPED", 64)
+    monkeypatch.setattr(moe, "_RUNG_TILE", 8)
+    assert moe._row_buffer_rungs(768, 4, 16) == (240, 288, 384, 768)
+
+
 @pytest.mark.parametrize("policy", ["none", "selective", "full"])
-def test_the_hybrid_loop_carries_loss_and_counts_under_recomputation(policy):
+def test_the_hybrid_loop_carries_loss_and_counts_under_recomputation(
+        policy, small_rungs):
     config = _small(num_hidden_layers=8,
                     layer_types=model.REHEARSAL["layer_types"] * 2,
                     mlp_layer_types=["sparse"] * 8)
@@ -217,6 +229,53 @@ def test_the_hybrid_loop_carries_loss_and_counts_under_recomputation(policy):
     assert sums["experts_here"] == 4 * 8 and sums["moe_layer_passes"] == 8
     assert sums["here_max_rows"] >= sums["assignments_here"] / 4
     assert abs(sums["router_loss"] - float(metrics["moe_aux_loss"])) < 1e-9
+    # eight layer calls, each on one of the rungs (240, 288, 384, 768)
+    assert (sums["assignments_here"] <= sums["row_buffer_rows"]
+            < sums["assignments"])
+    assert sums["row_buffer_rows"] % 48 == 0
+    assert sums["row_buffer_rows"] >= 8 * 240
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bf16"])
+def test_the_laddered_model_is_the_full_buffers(dtype, small_rungs):
+    """The whole model, jitted, with its held experts on the ladder and on
+    the T*k buffer (the program at this size without the fixture): the same
+    loss and counts, and every leaf's gradient to a float32 rounding (to
+    one of bf16 where that is the compute type). What is equal bit for bit,
+    the layer's sum and the gradients of what enters it, is held in
+    tests/test_moe.py, a layer alone; two whole programs XLA:CPU fuses
+    differently."""
+    config = _small(num_hidden_layers=2, mlp_layer_types=["sparse"] * 2,
+                    layer_types=model.REHEARSAL["layer_types"][2:])
+    compute = jnp.float32 if dtype == "float32" else jnp.bfloat16
+    cfg = _cfg(config, compute_dtype=compute)
+    params = model.init_params(cfg, 7)
+    micro = {k: jnp.asarray(v) for k, v in _micro(config, packed=True).items()}
+
+    def program():
+        def loss(p):        # traced afresh: jax keeps a function's jaxpr
+            return gpt_loss(p, micro["tokens"], micro["labels"],
+                            micro["loss_mask"], cfg,
+                            segment_ids=micro["segment_ids"])
+        (value, metrics), grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(params)
+        return float(value), metrics, grads
+
+    value, metrics, grads = program()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "_row_buffer_rungs", lambda rows, c, w: (rows,))
+        full_value, full_metrics, full_grads = program()
+    assert value == full_value
+    sums, full_sums = (jax.tree.map(float, m["sums"])
+                       for m in (metrics, full_metrics))
+    assert full_sums.pop("row_buffer_rows") == sums["assignments"]
+    assert sums.pop("row_buffer_rows") < sums["assignments"]
+    assert sums == full_sums
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(full_grads),
+                         strict=True):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=(1e-5 if dtype == "float32" else 2e-2)
+            * float(jnp.abs(want).max()))
 
 
 def test_a_hybrid_stack_takes_held_experts_and_the_routers_loss():
@@ -335,7 +394,7 @@ def test_compute_dtype_kernels_casts_what_is_multiplied_in_it(seeded):
         **dense, compute_dtype=jnp.float32)), "compute_copies")
 
 
-def test_the_loop_logs_moe_and_emits_its_spans(tmp_path):
+def test_the_loop_logs_moe_and_emits_its_spans(tmp_path, small_rungs):
     from megatronapp_tpu.parallel.mesh import build_mesh
     from megatronapp_tpu.trace.request_trace import get_request_tracer
     from megatronapp_tpu.training.train import pretrain_gpt
@@ -365,11 +424,14 @@ def test_the_loop_logs_moe_and_emits_its_spans(tmp_path):
     logged = [ln for ln in lines if pretrain_cell.ITER_RE.search(ln)]
     assert len(logged) == 2
     for ln in logged:
-        m = re.search(r"skipped 0 \| moe here (\S+) max/mean (\S+) router "
-                      r"(\S+) \| \S+ ms/step", ln)
+        m = re.search(r"skipped 0 \| moe here (\S+) buffer (\S+) max/mean "
+                      r"(\S+) router (\S+) \| \S+ ms/step", ln)
         assert m, ln
-        assert 0.15 < float(m[1]) < 0.35 and float(m[2]) >= 1
-        assert 5e-4 < float(m[3]) < 2e-3       # ~ the coefficient a layer
+        assert 0.15 < float(m[1]) < 0.35 and float(m[3]) >= 1
+        # rows walked over picks held: the ladder's, under the T*k
+        # buffer's 1 / here
+        assert 1 <= float(m[2]) < 0.9 / float(m[1])
+        assert 5e-4 < float(m[4]) < 2e-3       # ~ the coefficient a layer
     steps = [r for r in records if r["name"] == "train-step"
              and r["ph"] == "B"]
     assert [r["args"]["iteration"] for r in steps] == [1, 2, 3, 4]
@@ -384,6 +446,11 @@ def test_the_loop_logs_moe_and_emits_its_spans(tmp_path):
                 == s["assignments"])
         assert s["experts_here"] / s["moe_layer_passes"] == 4
         assert s["loss"] > 0 and s["grad_norm"] > 0
+        # 2 steps x 2 micro-batches x 4 layers, each on a rung of
+        # (240, 288, 384, 768)
+        assert (s["assignments_here"] <= s["row_buffer_rows"]
+                < s["assignments"])
+        assert s["row_buffer_rows"] % 48 == 0
 
 
 def test_a_dense_models_log_line_and_metrics_are_what_they_were():
@@ -391,9 +458,10 @@ def test_a_dense_models_log_line_and_metrics_are_what_they_were():
     assert _moe_log_part({}) == ""
     assert _moe_log_part({
         "assignments": 800.0, "assignments_here": 200.0,
-        "here_max_rows": 40.0, "experts_here": 32.0,
-        "moe_layer_passes": 8.0, "router_loss": 0.008}) == (
-        "moe here 0.250 max/mean 0.80 router 1.0e-03 | ")
+        "row_buffer_rows": 262.0, "here_max_rows": 40.0,
+        "experts_here": 32.0, "moe_layer_passes": 8.0,
+        "router_loss": 0.008}) == (
+        "moe here 0.250 buffer 1.31 max/mean 0.80 router 1.0e-03 | ")
 
 
 # ---- the preset, the file, the counts --------------------------------------

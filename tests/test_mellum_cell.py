@@ -74,8 +74,40 @@ def test_the_cell_rehearses_correct_and_its_counters_add_up():
     metrics = line["metrics"]
     assert 0.15 < metrics["expert_rows_here_share.packed8k"]["value"] < 0.35
     assert metrics["expert_load_max_over_mean.packed8k"]["value"] >= 1
+    # 1,024 tokens x 4 picks a call: the T*k buffer alone at this size
+    assert counters["row_buffer_rows"] == counters["assignments"]
+    assert metrics["expert_rows_walked_share.packed8k"]["value"] == 1.0
+    assert " buffer " in said
     for name in ("step_ms", "mfu", "train_tok_s_chip", "setup_s"):
         assert metrics[name]["value"] > 0
+
+
+def test_the_share_of_rows_walked_reads_the_syncs_of_the_window():
+    """`expert_rows_walked_share.packed8k`: the traced interval's
+    `row_buffer_rows` over its `assignments`. A program that counts the
+    assignments and not the buffers' rows (the commit before the counter)
+    walks all of them, 1.0: `lastline.faults` refuses a traced line that
+    lacks one of its cell's metrics, so that commit has to read a number.
+    None without the counters."""
+    from perfbench import manifest as mf
+    read = mf.load_reader("expert_rows_walked_share.packed8k")
+    here = mf.load_reader("expert_rows_here_share.packed8k")
+
+    def run(*syncs):
+        return {"device_summary": {"window": (100, 200)},
+                "xplane_stats": {"spans": [
+                    ("mta.train.sync", start, 10, attrs)
+                    for start, attrs in syncs] + [
+                    ("mta.train.step", 120, 5, {"row_buffer_rows": 9e9})]}}
+    full = {"assignments": 1000.0, "assignments_here": 250.0}
+    got = run((110, dict(full, row_buffer_rows=375.0)),
+              (150, dict(full, row_buffer_rows=250.0)),
+              (195, dict(full, row_buffer_rows=1000.0)))   # ends outside
+    assert read(got) == pytest.approx(625.0 / 2000.0)
+    assert here(got) == pytest.approx(0.25)
+    assert read(run((110, full), (150, full))) == 1.0      # the parent
+    assert here(run((110, full), (150, full))) == pytest.approx(0.25)
+    assert read(run()) is None and read({}) is None
 
 
 def test_the_runners_window_counters():
@@ -140,7 +172,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
         "flash_window_roofline_pct.packed8k",
         "expert_gemm_roofline_pct.packed8k",
         "expert_rows_here_share.packed8k",
-        "expert_load_max_over_mean.packed8k"]
+        "expert_load_max_over_mean.packed8k",
+        "expert_rows_walked_share.packed8k"]
     for name in mine:
         assert mf.load_reader(name) is not None, name
     assert [m["name"] for m in mf.cell_metrics(manifest, CELL, "end_to_end")

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from megatronapp_tpu.config.transformer_config import TransformerConfig
+from megatronapp_tpu.ops.activations import ActivationKind
 from megatronapp_tpu.transformer.moe import (
     _router, init_moe_params, moe_forward,
 )
@@ -402,3 +403,194 @@ class TestStackedLayer:
                 w.shape[2:]
             assert bool(jnp.all(jnp.any(g["block"]["moe"][name] != 0,
                                         axis=(1, 2, 3))))
+
+
+# ---------------------------------------------------------------------------
+# A share of the experts walks the rows it holds (ISSUE 49)
+# ---------------------------------------------------------------------------
+
+# picks of 16 router outputs of which 4..7 are held, by how many of a
+# token's 4 picks are held ones, and the rung of (80, 96, 128, 256) that
+# the 64 tokens' held picks take: 0, 88, 112 and 256 of them
+ROUTINGS = {"absent": lambda t: 0, "quarter": lambda t: 1 + (t % 8 < 3),
+            "three-eighths": lambda t: 2 - (t % 4 == 0),
+            "held": lambda t: 4}
+RUNG_OF = {"absent": 80, "quarter": 96, "three-eighths": 128, "held": 256}
+
+
+class TestRowBufferLadder:
+    """_dropless_held_experts on a buffer of the picks that landed here."""
+    T, K, H = 64, 4, 32
+
+    @pytest.fixture
+    def small_rungs(self, monkeypatch):
+        """The ladder at a test's size: 256 rows in tiles of 8."""
+        from megatronapp_tpu.transformer import moe
+        monkeypatch.setattr(moe, "_RUNG_MIN_SKIPPED", 64)
+        monkeypatch.setattr(moe, "_RUNG_TILE", 8)
+        assert moe._row_buffer_rungs(self.T * self.K, 4, 16) == (
+            80, 96, 128, 256)
+
+    def _layer(self, dtype):
+        cfg = _cfg(hidden_size=self.H, num_moe_experts=16,
+                   moe_router_topk=self.K, moe_experts_held=(4, 4),
+                   moe_ffn_hidden_size=16, compute_dtype=dtype,
+                   activation=ActivationKind.swiglu)
+        p, _ = init_moe_params(jax.random.PRNGKey(0), cfg, 0.02)
+        return cfg, p
+
+    def _picks(self, routing):
+        rng = np.random.default_rng(3)
+        held = ROUTINGS[routing]
+        return jnp.asarray(np.stack([np.concatenate([
+            rng.permutation(np.arange(4, 8))[:held(t)],
+            rng.permutation(np.r_[0:4, 8:16])[:self.K - held(t)]])
+            for t in range(self.T)]), jnp.int32)
+
+    def test_the_ladder_is_a_function_of_three_numbers(self):
+        import inspect
+        from megatronapp_tpu.transformer import moe
+        rungs = moe._row_buffer_rungs
+        assert list(inspect.signature(rungs).parameters) == [
+            "rows", "count", "width"]
+        # the training cell's call: 8,192 tokens x 8 picks, 16 of 64 held
+        assert rungs(65536, 16, 64) == (20480, 24576, 32768, 65536)
+        # the agent cell's decode round and prefill call, 16 of 768 held:
+        # the one full buffer, and so the program they had
+        assert rungs(768, 16, 768) == (768,)
+        assert rungs(6144, 16, 768) == (6144,)
+        assert rungs(65536, 32, 64) == (40960, 49152, 65536)    # half held
+        assert rungs(65536, 64, 64) == (65536,)                 # all held
+        assert rungs(65536, 1, 768) == (512, 65536)
+        for rows in (8, 768, 4096, 6144, 12288, 16384, 20480, 65536, 98304):
+            for count, width in ((1, 64), (16, 64), (16, 768), (64, 64)):
+                got = rungs(rows, count, width)
+                assert got == rungs(rows, count, width)
+                assert got[-1] == rows and list(got) == sorted(set(got))
+                assert all(r % moe._RUNG_TILE == 0 for r in got[:-1])
+                assert all(rows - r >= moe._RUNG_MIN_SKIPPED
+                           for r in got[:-1])
+                assert len(got) == 1 or rows > 6144
+
+    @pytest.mark.parametrize("policy", ["none", "selective"])
+    @pytest.mark.parametrize("routing", list(ROUTINGS))
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bf16"])
+    def test_the_laddered_sum_is_the_full_buffers(self, small_rungs, dtype,
+                                                  routing, policy):
+        """Each rung forced in turn by the routing: the output and a loss
+        equal the T*k buffer's to the last bit, and in float32 so do the
+        gradients of the tokens and of the router's weights, with the layer
+        loop's 'selective' policy and without. The kernels' gradients are
+        the same sums of the same non-zero terms; XLA:CPU blocks the
+        contraction over a buffer's rows by the buffer's length, so there
+        they differ in the order of a float32 sum (measured: 4e-7 of the
+        largest element at most). In bf16 XLA:CPU spares a product its
+        rounding to bf16 where the same fusion reads it (excess precision),
+        and the two programs fuse differently: gradients to a float32
+        rounding."""
+        from megatronapp_tpu.transformer import block, moe
+        cfg, p = self._layer(dtype)
+        idx = self._picks(routing)
+        n = int(jnp.sum(moe._held_slot(idx.reshape(-1), cfg)[0] < 4))
+        rows = self.T * self.K
+        assert int(moe.row_buffer_rows(n, rows, cfg)) == RUNG_OF[routing], n
+        x = jax.random.normal(jax.random.PRNGKey(1), (self.T, self.H), dtype)
+        probs = jax.nn.softmax(
+            jax.random.normal(jax.random.PRNGKey(2), (self.T, self.K)))
+        weigh = jnp.cos(jnp.arange(self.T * self.H, dtype=jnp.float32)
+                        ).reshape(self.T, self.H)
+
+        def run(ladder):
+            def loss(fc1, fc2, x, probs):
+                out = moe._dropless_held_experts(
+                    dict(p, fc1_kernel=fc1, fc2_kernel=fc2), x, idx, probs,
+                    cfg)
+                return jnp.sum(out * weigh), out
+            if policy == "selective":
+                loss = jax.checkpoint(loss, policy=block._SAVE_MATMULS)
+            with pytest.MonkeyPatch.context() as mp:
+                if not ladder:
+                    mp.setattr(moe, "_row_buffer_rungs",
+                               lambda rows, count, width: (rows,))
+                return jax.jit(jax.value_and_grad(
+                    loss, argnums=(0, 1, 2, 3), has_aux=True))(
+                    p["fc1_kernel"], p["fc2_kernel"], x, probs)
+
+        (value, out), grads = run(ladder=True)
+        (want_value, want_out), want = run(ladder=False)
+        assert out.dtype == jnp.float32 and np.array_equal(out, want_out)
+        assert value == want_value
+        assert (n > 0) == bool(np.abs(np.asarray(want_out)).max() > 0)
+        for got, full in zip(grads[2:], want[2:]):          # x, probs
+            assert got.dtype == full.dtype
+            got, full = (np.asarray(g, np.float32) for g in (got, full))
+            if dtype == jnp.float32:
+                assert np.array_equal(got, full)
+            np.testing.assert_allclose(got, full, rtol=0,
+                                       atol=1e-2 * np.abs(full).max())
+        for got, full in zip(grads[:2], want[:2]):          # the kernels
+            np.testing.assert_allclose(
+                got, full, rtol=0,
+                atol=1e-6 * float(jnp.abs(full).max()))
+            if RUNG_OF[routing] == rows and dtype == jnp.float32:
+                assert np.array_equal(got, full)
+
+    def test_the_grouped_products_run_once_a_pass(self, small_rungs):
+        """Under the layer loop's 'selective' policy the backward pass is
+        handed both products of the rung the forward pass took: a rung's
+        body has 2 grouped products forward and 4 backward (each one's two
+        transposes), the last rung 2 more (its products are not kept), and
+        the forward switch is not run again. Recomputing everything runs
+        the compact rungs' forward products once more."""
+        from megatronapp_tpu.transformer import block, moe
+        cfg, p = self._layer(jnp.bfloat16)
+        idx = self._picks("quarter")
+        x = jnp.ones((self.T, self.H), jnp.bfloat16)
+        probs = jnp.full((self.T, self.K), 0.25)
+
+        def products(policy):
+            def loss(q, x):     # traced afresh: jax keeps a function's jaxpr
+                return jnp.sum(moe._dropless_held_experts(q, x, idx, probs,
+                                                          cfg))
+            grad = jax.grad(jax.checkpoint(loss, policy=policy), (0, 1))
+            # one grouped [G, K, N] array a ragged_dot, operand or result
+            return len(_ragged_dot_expert_shapes(
+                jax.make_jaxpr(grad)(p, x).jaxpr))
+        rungs = 4
+        assert products(block._SAVE_MATMULS) == (2 + 4) * rungs + 2
+        assert products(jax.checkpoint_policies.nothing_saveable) == (
+            (2 + 4) * rungs + 2 + 2 * (rungs - 1))
+        with pytest.MonkeyPatch.context() as mp:    # the T*k buffer alone
+            mp.setattr(moe, "_row_buffer_rungs", lambda r, c, w: (r,))
+            assert products(block._SAVE_MATMULS) == 2 + 4
+
+    def test_the_counter_is_the_sum_of_the_rungs_taken(self, small_rungs):
+        """moe_forward(train_counts=True) appends the rows of the buffer
+        the call walked to the held counts; summed over calls they are the
+        rungs taken, never under the picks that landed here."""
+        from megatronapp_tpu.transformer import moe
+        cfg, p = self._layer(jnp.float32)
+        rows = self.T * self.K
+        total = here = 0
+        want = 0
+        for seed in range(4):
+            x = jax.random.normal(jax.random.PRNGKey(seed),
+                                  (1, self.T, self.H)) * (1 + 4 * seed)
+            _, (_, counts) = moe.moe_forward(p, x, cfg, train_counts=True)
+            got = dict(zip(moe.TRAIN_COUNTS, np.asarray(counts).tolist()))
+            assert got["assignments"] == rows
+            assert got["row_buffer_rows"] >= got["assignments_here"]
+            want += min(r for r in (80, 96, 128, 256)
+                        if r >= got["assignments_here"])
+            total += got["row_buffer_rows"]
+            here += got["assignments_here"]
+        assert total == want and here <= total < 4 * rows
+        # a layer that holds every expert walks every pick
+        whole = _cfg(hidden_size=self.H, num_moe_experts=4,
+                     moe_router_topk=2, moe_router_selection_bias=True)
+        q, _ = init_moe_params(jax.random.PRNGKey(0), whole, 0.02)
+        assert whole.moe_counts_load and not whole.moe_picks_unheld
+        _, (_, counts) = moe.moe_forward(
+            q, jnp.ones((1, self.T, self.H)), whole, train_counts=True)
+        assert int(counts[-1]) == int(counts[0]) == self.T * 2
