@@ -117,11 +117,16 @@ class StepStats(PhaseStats):
     (over ``decode_round``'s count: the share of rounds the chip did not
     wait for the host); ``overrun_rows``: rows of such rounds whose request
     had ended meanwhile (on its ``eod_id``, or stopped from outside), whose
-    token was dropped."""
+    token was dropped. ``admit_steps``: the steps that admitted a request,
+    ``admitted``: the requests they admitted (a window's own are the
+    ``mta.engine.prefill`` spans inside its ``mta.engine.step`` spans:
+    perfbench/admission_spans.py)."""
 
-    PHASES = ("step", "admit", "prefill", "prefill_call", "capacity",
-              "decode_round", "decode.stage", "decode.wait",
-              "decode.record", "retire", "queue_wait")
+    PHASES = ("step", "admit", "prefill", "prefill_call", "prefill.sample",
+              "capacity", "decode_round", "decode.stage",
+              "decode.stage.sample", "decode.stage.put",
+              "decode.stage.dispatch", "decode.wait", "decode.record",
+              "retire", "queue_wait")
     SLOWEST = 8
 
     def __init__(self):
@@ -129,6 +134,8 @@ class StepStats(PhaseStats):
         self.slowest: List[dict] = []       # longest first
         self.rounds_ahead = 0
         self.overrun_rows = 0
+        self.admit_steps = 0
+        self.admitted = 0
 
     def totals(self) -> List[float]:
         return [row[1] for row in self.phases.values()]
@@ -152,6 +159,8 @@ class StepStats(PhaseStats):
         return dict(super().snapshot(),
                     rounds_ahead=self.rounds_ahead,
                     overrun_rows=self.overrun_rows,
+                    admit_steps=self.admit_steps,
+                    admitted=self.admitted,
                     slowest=[dict(r, phases=dict(r["phases"]))
                              for r in self.slowest])
 
@@ -1859,7 +1868,7 @@ class DynamicInferenceEngine:
         if (self.pause_admission or not self.waiting
                 or all(r is not None for r in self.slots)):
             return []
-        with self._span("engine.admit", waiting=len(self.waiting)):
+        with self._span("engine.admit"):
             return self._admit_waiting()
 
     def _admit_waiting(self) -> List[Request]:
@@ -1971,7 +1980,10 @@ class DynamicInferenceEngine:
         self.lengths[req.slot] = p_len
         # First generated token comes from the last PROMPT position.
         logits_last = mask_padded_vocab(logits_last, self.cfg)
-        tok = self._sample(logits_last[None], req)
+        # The host stands here until the device has run the round in
+        # flight and every call of the prompt.
+        with self._span("engine.prefill.sample", req.request_id):
+            tok = self._sample(logits_last[None], req)
         self._record_token(req, int(tok[0]))
         if self.proposer is not None:
             self.proposer.on_admit(req.slot, req)
@@ -2264,13 +2276,16 @@ class DynamicInferenceEngine:
         [ids], "preempted": [ids], "expired": [ids]} for this step
         (expired ⊆ finished: deadline-overdue requests aborted by this
         step's expiry sweep)."""
-        before = self.step_stats.totals()
-        with self._span("engine.step", waiting=len(self.waiting),
-                        active=sum(1 for r in self.slots
-                                   if r is not None)) as whole:
+        stats = self.step_stats
+        before = stats.totals()
+        with self._span("engine.step") as whole:
             events, batch = self._step()
-        if batch and not events["admitted"]:
-            self.step_stats.note_round(whole.seconds, batch, before)
+        admitted = len(events["admitted"])
+        if admitted:
+            stats.admit_steps += 1
+            stats.admitted += admitted
+        elif batch:
+            stats.note_round(whole.seconds, batch, before)
         return events
 
     def _step(self) -> Tuple[Dict[str, List], int]:
@@ -2478,17 +2493,24 @@ class DynamicInferenceEngine:
         round before it, whose sampler is dispatched here; a row that
         `after` appends lies a position further."""
         with self._span("engine.decode.stage"):
-            active_np = np.zeros((self.max_batch,), bool)
-            active_np[list(rnd.rows)] = True
-            tokens = (self._host_tokens() if after is None
-                      else self._sample_round(after, rnd.rows))
-            logits, rnd.moe, new = self._decode(
-                self.params, tokens, self._pools(), self.pool.scales,
-                self._tables(slice(0, self.max_batch)),
-                jnp.asarray(self._lengths_after(after)),
-                jnp.asarray(active_np), self._lora_args())
-            self._commit_pools(new)
-            rnd.logits = mask_padded_vocab(logits, self.cfg)
+            if after is not None:
+                with self._span("engine.decode.stage.sample"):
+                    tokens = self._sample_round(after, rnd.rows)
+            with self._span("engine.decode.stage.put"):
+                if after is None:
+                    tokens = self._host_tokens()
+                active_np = np.zeros((self.max_batch,), bool)
+                active_np[list(rnd.rows)] = True
+                tables = self._tables(slice(0, self.max_batch))
+                lengths = jnp.asarray(self._lengths_after(after))
+                active = jnp.asarray(active_np)
+                lora = self._lora_args()
+            with self._span("engine.decode.stage.dispatch"):
+                logits, rnd.moe, new = self._decode(
+                    self.params, tokens, self._pools(), self.pool.scales,
+                    tables, lengths, active, lora)
+                self._commit_pools(new)
+                rnd.logits = mask_padded_vocab(logits, self.cfg)
 
     def _read(self, rnd: _Round, out: List[Tuple[int, int]]):
         """Fetch `rnd`'s tokens and record them, (request id, token) onto
@@ -2582,33 +2604,36 @@ class DynamicInferenceEngine:
             return
 
         with self._span("engine.decode.stage"):
-            q_lens = np.ones((b,), np.int32)
-            tokens = np.zeros((b, k + 1), np.int32)
-            active_np = np.zeros((b,), bool)
-            for req in active:
-                slot = req.slot
-                active_np[slot] = True
-                tokens[slot, 0] = self.last_tokens[slot, 0]
-                n = int(counts[slot])
-                tokens[slot, 1:1 + n] = drafts[slot, :n]
-                q_lens[slot] = 1 + n
-            rows = self._sampling_rows()
-
-            logits, hidden, new = self._mq_step(
-                self.params, jnp.asarray(tokens), self.pool.pages,
-                self.pool.scales,
-                jnp.asarray(self.pool.page_table[:self.max_batch]),
-                jnp.asarray(self.lengths),
-                jnp.asarray(q_lens), jnp.asarray(active_np),
-                self._lora_args())
-            self._commit_pools(new)
-            logits = mask_padded_vocab(logits, self.cfg)
-            # Chaos site "spec-verify": fires at the WORST point — the
-            # multi-query step already wrote every draft token's KV,
-            # nothing is accepted yet — so the drill proves _spec_round's
-            # rollback (rewind to the last verified length) keeps the
-            # pool auditable and the stream exact.
-            chaos.fire("spec-verify")
+            with self._span("engine.decode.stage.sample"):
+                # The verifier's operands; its dispatch waits for the
+                # step's logits (decode.wait).
+                rows = self._sampling_rows()
+            with self._span("engine.decode.stage.put"):
+                q_lens = np.ones((b,), np.int32)
+                tokens = np.zeros((b, k + 1), np.int32)
+                active_np = np.zeros((b,), bool)
+                for req in active:
+                    slot = req.slot
+                    active_np[slot] = True
+                    tokens[slot, 0] = self.last_tokens[slot, 0]
+                    n = int(counts[slot])
+                    tokens[slot, 1:1 + n] = drafts[slot, :n]
+                    q_lens[slot] = 1 + n
+                operands = (
+                    jnp.asarray(tokens), self.pool.pages, self.pool.scales,
+                    jnp.asarray(self.pool.page_table[:self.max_batch]),
+                    jnp.asarray(self.lengths), jnp.asarray(q_lens),
+                    jnp.asarray(active_np), self._lora_args())
+            with self._span("engine.decode.stage.dispatch"):
+                logits, hidden, new = self._mq_step(self.params, *operands)
+                self._commit_pools(new)
+                logits = mask_padded_vocab(logits, self.cfg)
+                # Chaos site "spec-verify": fires at the WORST point — the
+                # multi-query step already wrote every draft token's KV,
+                # nothing is accepted yet — so the drill proves
+                # _spec_round's rollback (rewind to the last verified
+                # length) keeps the pool auditable and the stream exact.
+                chaos.fire("spec-verify")
         with self._span("engine.decode.wait"):
             accepts, out_toks = self._verify_sample(
                 logits, jnp.asarray(drafts), jnp.asarray(q_lens), q_probs,

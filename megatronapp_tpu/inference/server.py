@@ -282,8 +282,7 @@ class DynamicBatchingDriver:
             self.max_active = max(self.max_active, sum(
                 1 for r in self.engine.slots if r is not None))
             with get_request_tracer().span(
-                    "driver.deliver", stats=self.deliver_stats,
-                    tokens=len(ev["tokens"])), self._cv:
+                    "driver.deliver", stats=self.deliver_stats), self._cv:
                 # Deadline-expired requests get a clean error frame
                 # BEFORE the generic finished handling pops their sub
                 # (their pool blocks were reclaimed by the step's retire

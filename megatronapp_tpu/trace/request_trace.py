@@ -39,7 +39,8 @@ costs about a microsecond otherwise); it adds the phase's wall time to the
 ``deliver``), always; and it emits the ring's B/E pair under the ring's
 own name when the ring is on. The span names are an interface that
 perfbench's readers match: ``mta.engine.{step, admit, prefill,
-prefill_call, capacity, decode_round, decode.stage, decode.wait,
+prefill_call, prefill.sample, capacity, decode_round, decode.stage,
+decode.stage.sample, decode.stage.put, decode.stage.dispatch, decode.wait,
 decode.record, retire}`` and ``mta.driver.deliver``; the training loop's
 ``mta.train.step`` (``iteration``, ``micro_batches``, ``tokens``: a step's
 dispatch) and ``mta.train.sync`` (the ``device_get`` of a log interval's
@@ -51,22 +52,46 @@ experts walked: moe._row_buffer_rungs), ``experts_here``,
 ``moe_layer_passes``, ``router_loss``, summed over its steps, micro-batches
 and layers: training/train.py).
 
+``mta.engine.step`` carries no attribute: what a step admitted is the
+``mta.engine.prefill`` spans inside it (one a request), what it read the
+``mta.engine.decode_round`` inside it, and ``perfbench/admission_spans.py``
+splits the first chip's idle by the first (``admit_gap_ms_step``,
+``round_gap_ms_round``). ``stats_snapshot()["steps"]`` counts
+``admit_steps`` (steps that admitted) and ``admitted`` (ISSUE 50), for
+``/stats``.
+
+An admission is ``mta.engine.admit`` > ``mta.engine.prefill`` (``rid``,
+``prompt_tokens``, ``cached_tokens``: the ring's ``prefill`` record) >
+``mta.engine.prefill_call`` (``tokens``, ``width``, a tenant's
+``summaries`` / ``window_blocks``; read by ``prefill_call_host_ms``,
+``prefill_call_device_ms``, ``prefill_fill_share``), then
+``mta.engine.prefill.sample`` (``rid``): the request's first sample, from
+the sampler's dispatch to the ``device_get`` of its token, where the host
+stands until the device has run the round in flight and every call of the
+prompt (``first_sample_wait_ms``).
+
 ``mta.engine.decode_round`` is one span a round, opened when the round's
 tokens are read, with the attributes of its dispatch (``batch``,
 ``kv_tokens``, ``kv_blocks``, a tenant's ``kv_rows`` / ``window_blocks``
 / ...: the byte functions' numerators) and, on a plain round, ``ahead``:
 1 where the round was dispatched before the tokens of the round before it
-were read (the engine's decode loop runs one round ahead, ISSUE 47), so a
-kept trace shows which rounds the chip did not wait for. Inside it:
-``decode.stage`` (the NEXT round's arrays, the sampler's and the step's
-dispatch), ``decode.wait`` (the ``device_get`` of this round's tokens),
-``decode.record``. Beside the phases, ``stats_snapshot()["steps"]`` counts
-``rounds_ahead`` (rounds with ``ahead`` = 1: over ``decode_round``'s count,
-the share of rounds the mechanism engaged in) and ``overrun_rows`` (rows
-of a round in flight whose request ended or left its slot before they
-were read; their tokens are dropped). No metric reads the three yet:
-``/stats``, ``perfbench/tools/slowest_rounds.py`` (prints the counters)
-and a kept trace do.
+were read (the engine's decode loop runs one round ahead, ISSUE 47;
+``rounds_ahead_share``). Inside it: ``decode.stage`` (the NEXT round's),
+which is its three children one after the other: ``.stage.sample`` (the
+unread round's sampler, its operands and its dispatch; absent where no
+round runs ahead; on a speculative round the verifier's operands),
+``.stage.put`` (the step's host arrays to the device: tokens, page
+tables, lengths, the active mask, adapters) and ``.stage.dispatch`` (the
+decode or verify call and the commit of the pools it returns)
+(``stage_{sample,put,dispatch}_ms_round``); ``decode.wait`` (the
+``device_get`` of this round's tokens); ``decode.record``. Beside the
+phases, ``stats_snapshot()["steps"]`` counts ``rounds_ahead`` (rounds with
+``ahead`` = 1) and ``overrun_rows`` (rows of a round in flight whose
+request ended or left its slot before they were read; their tokens are
+dropped): ``/stats``, ``perfbench/tools/slowest_rounds.py`` and
+``tests/test_engine_run_ahead.py`` read them. Idle of the chip under
+``step``, ``admit``, ``prefill``, ``decode_round`` or ``decode.stage``
+themselves, or under no span, has no phase: ``idle_unnamed_share.serve``.
 """
 
 from __future__ import annotations

@@ -110,7 +110,7 @@ def test_benchmark_lists_the_cell_and_only_appends():
     by part and its own thirteen; every per-layer metric it lists has a
     reader file; what the parent's BENCHMARK.json had is there unchanged, in
     order, but for names appended to `workloads` lists."""
-    from perfbench import manifest as mf
+    from perfbench import admission_spans, manifest as mf
     manifest = mf.load_manifest()
     mine = [m["name"] for m in mf.cell_metrics(manifest, CELL, "per_layer")]
     assert mine == [
@@ -123,7 +123,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
         "paged_window_ms_round.code", "paged_window_roofline_pct.code",
         "window_rows_walked_share.code", "kv_bytes_held_per_token.code",
         "experts_touched_share.code", "expert_load_max_over_mean.code",
-        "moe_stream_roofline_pct.code"]
+        "moe_stream_roofline_pct.code"] + list(
+        admission_spans.METRICS)        # ISSUE 50: every serving cell's
     for name in mine:
         assert mf.load_reader(name) is not None, name
     assert [m["name"] for m in mf.cell_metrics(manifest, CELL, "end_to_end")

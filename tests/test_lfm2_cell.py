@@ -107,7 +107,7 @@ def test_benchmark_lists_the_cell_and_only_appends():
     file; what the parent's BENCHMARK.json had is there unchanged, in
     order, but for names appended to `workloads` lists (this cell's, and
     those of cells that later PRs add)."""
-    from perfbench import manifest as mf
+    from perfbench import admission_spans, manifest as mf
     manifest = mf.load_manifest()
     mine = [m["name"] for m in mf.cell_metrics(manifest, CELL, "per_layer")]
     assert mine == [
@@ -119,7 +119,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
         "batch_occupancy.assist", "conv_ms_round",
         "paged_decode_ms_round.assist", "paged_decode_roofline_pct.assist",
         "moe_stream_roofline_pct.assist", "experts_touched_share.assist",
-        "expert_load_max_over_mean.assist"]
+        "expert_load_max_over_mean.assist"] + list(
+        admission_spans.METRICS)        # ISSUE 50: every serving cell's
     for name in mine:
         assert mf.load_reader(name) is not None, name
     assert [m["name"] for m in mf.cell_metrics(manifest, CELL, "end_to_end")

@@ -58,7 +58,7 @@ def test_benchmark_lists_the_cell_and_only_appends():
     by part and its own ten; every per-layer metric it lists has a reader
     file; what the parent's BENCHMARK.json had is there unchanged, in
     order, but for the cell's name appended to `workloads` lists."""
-    from perfbench import manifest as mf
+    from perfbench import admission_spans, manifest as mf
     manifest = mf.load_manifest()
     mine = [m["name"] for m in mf.cell_metrics(manifest, CELL, "per_layer")]
     assert mine == [
@@ -69,7 +69,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
         "host_gap_ms_round.agent", "prefill_share.agent",
         "batch_occupancy.agent", "paged_latent_ms_round.agent",
         "paged_latent_roofline_pct.agent", "zero_expert_share.agent",
-        "experts_touched_share.agent", "expert_load_max_over_mean.agent"]
+        "experts_touched_share.agent", "expert_load_max_over_mean.agent"] + list(
+        admission_spans.METRICS)        # ISSUE 50: every serving cell's
     for name in mine:
         assert mf.load_reader(name) is not None, name
     assert [m["name"] for m in mf.cell_metrics(manifest, CELL, "end_to_end")

@@ -216,5 +216,6 @@ def test_benchmark_lists_the_cell_and_only_appends():
             ] == [CELL]
     assert [c["name"] for c in manifest["configs"][len(was["configs"]):]
             ] == ["mellum2-12b-a2.5b"]
+    # (what later PRs append comes behind the cell's own)
     assert [m["name"] for m in manifest["per_layer"][len(was["per_layer"]):]
-            ] == mine[10:]
+            ][:len(mine) - 10] == mine[10:]

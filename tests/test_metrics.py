@@ -318,7 +318,7 @@ class TestStepSpansAndCounters:
 
     @pytest.mark.parametrize("spec", [None, "ngram"])
     def test_profiler_sees_nested_program_spans(self, tmp_path, spec):
-        from perfbench import common, trace_reduce
+        from perfbench import common, trace_reduce, xplane_stats
         cfg = _gqa_cfg()
         params, _ = init_gpt_params(jax.random.PRNGKey(3), cfg)
         eng = DynamicInferenceEngine(
@@ -327,10 +327,15 @@ class TestStepSpansAndCounters:
         prompt = np.tile(np.arange(4, dtype=np.int32), 3)
         eng.add_request(prompt, 6, SamplingParams(greedy=True))
         eng.step()                                  # compiles, untraced
+        # The first traced step admits: a round is in flight by then on
+        # the plain path (ISSUE 47).
+        eng.add_request(np.arange(20, 29, dtype=np.int32), 4,
+                        SamplingParams(greedy=True))
+        admitted = []
         common.start_trace(str(tmp_path))
         try:
             while eng.has_work:
-                eng.step()
+                admitted.append(len(eng.step()["admitted"]))
         finally:
             jax.profiler.stop_trace()
         spans = trace_reduce.host_spans(
@@ -359,6 +364,45 @@ class TestStepSpansAndCounters:
         assert by_name["mta.engine.retire"]
         # No span of the program is named outside the interface.
         assert {n.split(".")[1] for n in by_name} <= {"engine"}
+        # ISSUE 50: a stage is its three children (the sampler's part, the
+        # host's arrays, the step's dispatch), one after the other. A
+        # speculative round that proposed nothing falls back to a plain
+        # one read at once, whose sampler waits for the fetch.
+        for child in ("sample", "put", "dispatch"):
+            kids = by_name["mta.engine.decode.stage." + child]
+            assert len(kids) == len(stages) or (
+                spec and child == "sample" and 1 <= len(kids) < len(stages))
+            for kid in kids:
+                assert sum(_inside(kid, g) for g in stages) == 1
+        # The first sample of the admitted request: inside its prefill,
+        # after the prompt's one call.
+        (sample,), (prefill,) = (by_name["mta.engine.prefill.sample"],
+                                 by_name["mta.engine.prefill"])
+        (call,) = by_name["mta.engine.prefill_call"]
+        assert _inside(sample, prefill) and _inside(call, prefill)
+        assert call[1] + call[2] <= sample[1]
+        # A step carries no attribute of its own: what it admitted is the
+        # prefill spans inside it, what it read the round inside it (what
+        # perfbench/admission_spans.py counts).
+        said = defaultdict(list)
+        for e in xplane_stats.load(str(tmp_path))["spans"]:
+            said[e[0]].append(e)
+        steps, rounds = said["mta.engine.step"], said["mta.engine.decode_round"]
+        assert len(steps) == len(rounds) == len(admitted)   # a round a step
+        assert admitted[0] == 1 and sum(admitted) == 1
+        for s, want, rnd in zip(steps, admitted, rounds):
+            assert not s[3] and _inside(rnd, s)
+            assert sum(_inside(p, s)
+                       for p in said["mta.engine.prefill"]) == want
+            assert int(rnd[3].get("ahead", 0)) == int(spec is None)
+        # The round in flight at the admission had the first request alone.
+        assert [int(r[3]["batch"]) for r in rounds[:2]] \
+            == ([1, 2] if spec is None else [2, 2])
+        (call,), (sample,) = (said["mta.engine.prefill_call"],
+                              said["mta.engine.prefill.sample"])
+        assert {k: int(v) for k, v in call[3].items()} \
+            == {"tokens": 9, "width": eng.prefill_chunk}
+        assert int(sample[3]["rid"]) == 1
 
     def test_paged_walk_counters(self, monkeypatch):
         """ISSUE 29: a plain decode round names the blocks its paged
@@ -466,7 +510,8 @@ class TestStepSpansAndCounters:
         assert eng.spec_stats["emitted_tokens"] + st["prefill"]["count"] \
             == 24
         assert set(st) == set(eng.step_stats.PHASES) | {
-            "slowest", "rounds_ahead", "overrun_rows"}
+            "slowest", "rounds_ahead", "overrun_rows", "admit_steps",
+            "admitted"}
         total = {p: st[p]["total_s"] for p in st
                  if isinstance(st[p], dict)}
         assert total["admit"] + total["capacity"] + total["decode_round"] \
@@ -474,6 +519,18 @@ class TestStepSpansAndCounters:
         assert total["decode.stage"] + total["decode.wait"] \
             + total["decode.record"] <= total["decode_round"]
         assert total["prefill_call"] <= total["prefill"] <= total["admit"]
+        assert st["admit_steps"] == len(admitting)
+        assert st["admitted"] == st["prefill.sample"]["count"] == 3
+        stage = ("decode.stage.sample", "decode.stage.put",
+                 "decode.stage.dispatch")
+        assert sum(total[p] for p in stage) <= total["decode.stage"]
+        assert {st[p]["count"] for p in stage[1:]} \
+            == {st["decode.stage"]["count"]}
+        # Where no round is in flight (the first, and the one after the
+        # preemption) the sampler waits for the fetch.
+        assert st["decode.stage.sample"]["count"] == st["rounds_ahead"] \
+            < st["decode.stage"]["count"]
+        assert total["prefill.sample"] <= total["prefill"]
         for row in (st[p] for p in total):
             assert 0 <= row["max_s"] <= row["total_s"] or row["count"] == 0
         # The flight recorder: pure decode rounds only, longest first.
@@ -519,7 +576,8 @@ class TestStepSpansAndCounters:
             eng.step()
         after = eng.stats_snapshot()["steps"]
         for phase in ("step", "decode_round", "decode.stage",
-                      "decode.wait"):
+                      "decode.stage.sample", "decode.stage.put",
+                      "decode.stage.dispatch", "decode.wait"):
             assert after[phase]["count"] == before[phase]["count"] + 1
         assert after["decode.record"] == before["decode.record"]
         assert after["slowest"] == before["slowest"]
@@ -530,6 +588,78 @@ class TestStepSpansAndCounters:
         if ring:
             last = [r for r in rt.dump() if r["name"] == "decode-step"][-1]
             assert last["ph"] == "E" and last["args"] == {"error": True}
+
+    @pytest.mark.parametrize("spec", [None, "ngram"])
+    def test_the_spans_inside_a_step_say_what_it_admitted(self, monkeypatch,
+                                                         spec):
+        """ISSUE 50: a step opens one `prefill` span and one
+        `prefill.sample` an admitted request and at most one
+        `decode_round`, through a preemption and the second admission it
+        brings (what perfbench/admission_spans.py counts, the step span
+        itself carrying no attribute); `admit_steps` and `admitted` count
+        the same."""
+        eng = _pressure_engine(**(
+            {"spec_method": spec, "spec_k": 2} if spec else {}))
+        opened = []
+        span = eng._span
+
+        def spy(name, *a, **kw):
+            opened.append((name, span(name, *a, **kw)))
+            return opened[-1][1]
+
+        monkeypatch.setattr(eng, "_span", spy)
+        admit_steps = admitted = 0
+        while eng.has_work:
+            del opened[:]
+            ev = eng.step()
+            by_name = defaultdict(list)
+            for name, sp in opened:
+                by_name[name].append(sp)
+            (step,) = by_name["engine.step"]
+            assert not step.attrs and not step.late
+            for name in ("engine.prefill", "engine.prefill.sample"):
+                assert [sp.rid for sp in by_name[name]] == ev["admitted"]
+            assert len(by_name["engine.decode_round"]) == bool(ev["tokens"])
+            admit_steps += bool(ev["admitted"])
+            admitted += len(ev["admitted"])
+        st = eng.stats_snapshot()["steps"]
+        assert (st["admit_steps"], st["admitted"]) \
+            == (admit_steps, admitted) == (2, 3)
+        assert st["rounds_ahead"] == (0 if spec else
+                                      st["decode.stage.sample"]["count"])
+
+    @pytest.mark.parametrize("site, closed", [
+        ("_sample", ("step", "admit", "prefill", "prefill_call",
+                     "prefill.sample")),
+        ("_decode", ("step", "decode_round", "decode.stage",
+                     "decode.stage.put", "decode.stage.dispatch"))])
+    def test_a_failure_inside_a_new_span_closes_it(self, site, closed):
+        """ISSUE 50: the first sample and the stage's children close and
+        count once when the call inside them raises; the counters count
+        nothing of a step that did not finish."""
+        rt = get_request_tracer()
+        rt.configure(enabled=True)
+        eng = _pressure_engine()
+        if site == "_decode":
+            eng.step()                      # admits both; one round ahead
+            eng._round = None               # so that the next one stages
+
+        def boom(*a, **kw):
+            raise RuntimeError("device lost")
+
+        setattr(eng, site, boom)
+        before = eng.stats_snapshot()["steps"]
+        with pytest.raises(RuntimeError, match="device lost"):
+            eng.step()
+        after = eng.stats_snapshot()["steps"]
+        for phase in closed:
+            assert after[phase]["count"] == before[phase]["count"] + 1
+        assert after["admit_steps"] == before["admit_steps"]
+        assert after["admitted"] == before["admitted"]
+        assert None not in rt._open
+        unmatched, orphan_e = _pair_records(
+            [r for r in rt.dump() if r["tid"] == 0])
+        assert not unmatched and not orphan_e
 
     def test_queue_wait_is_one_measurement(self):
         """/metrics' serving_queue_wait_ms and /stats' steps.queue_wait
@@ -661,6 +791,14 @@ class TestServerEndpoints:
         assert steps["step"]["count"] == 3      # the first admits and decodes
         assert steps["queue_wait"]["count"] == 1
         assert steps["decode_round"]["count"] == 3
+        # ISSUE 50: what a step admitted, and the new phases.
+        assert (steps["admit_steps"], steps["admitted"]) == (1, 1)
+        assert steps["prefill.sample"]["count"] == 1
+        assert steps["decode.stage.sample"]["count"] \
+            == steps["rounds_ahead"] == 2
+        assert steps["decode.stage.put"]["count"] \
+            == steps["decode.stage.dispatch"]["count"] \
+            == steps["decode.stage"]["count"] == 3
         assert stats["pool"]["prefill_tokens"] == 3
         assert stats["prefill"] == {"calls": 1, "tokens": 3, "width": 32,
                                     "fill_share": round(3 / 32, 4)}
