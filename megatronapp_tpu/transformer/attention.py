@@ -18,6 +18,13 @@ Param leaf layout (per layer, unstacked):
 
 n_heads is cfg.num_attention_heads, or in a sliding-window layer of a stack
 that says so cfg.window_heads: the forward reads it off q_kernel.
+
+The paged branch on one device keeps its q/kv projection a flat
+[tokens, H] x [H, heads*D] product (a barrier in front of the reshape to
+heads): folded into the dot, the reshape makes XLA:TPU want the kernel
+heads-major, and a layer's kernels, which arrive as lax.scan's slice of the
+[L, H, heads*D] stacks, are then cut out and written again every layer of
+every step (12% and 19% of two serving windows, PERF.md, PR 51).
 """
 
 from __future__ import annotations
@@ -351,6 +358,10 @@ def attention_forward(
     if "q_bias" in p:
         q = q + p["q_bias"].astype(cfg.compute_dtype)
         kv = kv + p["kv_bias"].astype(cfg.compute_dtype)
+    if kv_cache is not None and page_table is not None and ctx is None:
+        # A paged step on one device: see the module docstring. The values
+        # are the same; the dot's fusion then reads the stack in place.
+        q, kv = jax.lax.optimization_barrier((q, kv))
     q = q.reshape(b, s, nq, d)
     k, v = jnp.split(kv.reshape(b, s, 2 * nkv, d), 2, axis=2)
 

@@ -655,6 +655,38 @@ def _pool_shaped(compiled, named, shapes):
             if d in dims and re.search(named, name)]
 
 
+def _assert_projection_reads_the_stack(compiled, head_dim, *attentions):
+    """The q/kv projections of the step read a layer's kernels out of the
+    stacks `attentions` (trees of [L, H, heads x D] leaves) where they lie
+    (ISSUE 51): nothing the step runs ON ITS OWN (an instruction of no
+    fusion's computation: a `dynamic-slice` inside the dot's fusion is the
+    read in place) is a slice or a copy with the shape of one layer's
+    q_kernel or kv_kernel, in either axis order, flat or with the heads
+    split off. The parent cut each out (`constant_dynamic-slice_fusion.4/.5`)
+    and wrote it again heads-major (`copy.47/.48`) for a dot that had taken
+    the reshape to [tokens, heads, D] in. (`slice-start` / `copy-start` and
+    their `-done` are the compiler's prefetch of an operand into fast
+    memory, beside other work: the one read the dot needs, made early.)"""
+    from megatronapp_tpu.trace.scope_map import parse_hlo_text
+    parsed = parse_hlo_text(compiled.as_text())
+    fused = {i.calls for i in parsed.instructions.values()
+             if i.opcode == "fusion"}
+    dims = set()
+    for attention in attentions:
+        for name in ("q_kernel", "kv_kernel"):
+            _, h, n = attention[name].shape
+            for sh in ((h, n), (n, h), (h, n // head_dim, head_dim),
+                       (n // head_dim, head_dim, h)):
+                dims |= {",".join(map(str, sh)),
+                         ",".join(map(str, (1,) + sh))}
+    cut = [i.name for i in parsed.instructions.values()
+           if i.computation not in fused and re.search("slice|copy", i.name)
+           and not i.opcode.endswith(("-start", "-done"))
+           and any(d in dims for d in re.findall(r"\w+\[([\d,]*)\]",
+                                                 i.shape))]
+    assert not cut, cut
+
+
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
 def test_engine_paged_steps(one_chip, chip_compile, which, kv):
@@ -822,6 +854,9 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     assert _cell_prefill_width(one_chip, model, seq, **cut) == width
     from megatronapp_tpu.models.gpt import init_gpt_params
     over = dict(num_layers=2, vocab_size=1024)
+    if model == "gpt3-2.7b":
+        # as the cell holds them (perfbench/configs/gpt3-2.7b.json)
+        over.update(params_dtype=jnp.bfloat16, add_qkv_bias=True)
     if model == "deepseek-v2-lite":
         # bf16 weights, as the cell holds them: a float32 stack is
         # converted a layer at a time, which is a slice
@@ -876,6 +911,13 @@ def test_engine_paged_steps_at_cell_shapes(one_chip, chip_compile, model,
     pool_bytes = sum(a.size * a.dtype.itemsize for a in pages)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
+    if not cfg.multi_latent_attention:
+        # ... and with them went what the step held of a layer's kernels
+        attention = abstract["block"]["attention"]
+        _assert_projection_reads_the_stack(compiled, cfg.head_dim, attention)
+        kv = attention["kv_kernel"]
+        assert mem.temp_size_in_bytes < (kv.size // kv.shape[0]
+                                         * kv.dtype.itemsize)
     if cfg.is_moe:
         experts = _moe_of(abstract["block"])
         _assert_grouped_gemms(
@@ -951,6 +993,8 @@ def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
                             r"copy|transpose|(?<!update[_-])slice",
                             [ssm.shape, ssm.shape[1:], (1,) + ssm.shape[1:]])
     assert mem.temp_size_in_bytes < ssm.size // ssm.shape[0] * 4
+    _assert_projection_reads_the_stack(
+        compiled, cfg.head_dim, abstract["block"]["mixers_attn"]["attention"])
 
 
 # The assist cell's cut of LFM2-24B-A2B: published layers 1..9
@@ -1097,6 +1141,13 @@ def test_engine_window_moe_steps_at_cell_shapes(one_chip, chip_compile,
                             [fc1.shape[1:], (1,) + fc1.shape[1:]])
     assert mem.temp_size_in_bytes < (fc1.size // fc1.shape[0]
                                      * fc1.dtype.itemsize)
+    if which == "decode":
+        # (a call of 2048 positions at H 2048 has activations of the
+        # kernels' own shapes)
+        _assert_projection_reads_the_stack(
+            compiled, cfg.head_dim,
+            abstract["block"]["mixers_attn"]["attention"],
+            abstract["block"]["mixers_swa"]["attention"])
 
 
 # ---------------------------------------------------------------------------
