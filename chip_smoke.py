@@ -6,6 +6,7 @@
                                      # tiny double-layer expert-share engine
                                      # and a tiny short-convolution MoE one
                                      # and a tiny sliding-window MoE one
+                                     # and a tiny Mamba-2 expert-share one
     python chip_smoke.py --chips 4   # four chips: one device vs tp2 x dp2
                                      # (and tp2 x pp2), nothing else
 
@@ -49,6 +50,14 @@ vocab 50304), random weights from the entry points' own seeds:
   kernel in the compiled decode step; then one loss and gradient of the
   same stack over packed sequences, through the flash kernels' window term
   (``flash_window_fwd``, ``_bwd_dq``, ``_bwd_dkv`` in the compiled text).
+
+- ssd: a tiny hybrid of Mamba-2 mixers (4 heads of 64 columns, a [64, 128]
+  state a head, chunks of 32) and one grouped-query attention layer a
+  period without a positional term, 4 of 8 experts held beside a shared one,
+  Granite's four multipliers, through the paged engine: prompts longer than
+  a chunk and than a prefill call, one ``ssm_update`` a scanned run of
+  layers over a pool whose planes are tiled along E, every pick counted as
+  held or absent.
 
 A chip belongs to one process at a time, so this parent imports no JAX and
 runs each phase as a child, one after the other; it learns the device from
@@ -624,6 +633,131 @@ def check_hybrid(rc, lines, tiny=False):
                for ln in lines):
         out["problems"].append("the engine did not say it ran the paged "
                                f"decode kernel {_kernel_mode(dev, tiny)}")
+    out["ok"] = not out["problems"]
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Phase: Mamba-2 mixers, held experts and Granite's multipliers, tiny widths
+# ---------------------------------------------------------------------------
+
+SSD = dict(num_layers=8, attn_layer_period=4, attn_layer_offset=1,
+           ssm_heads=4, ssm_head_dim=64, ssm_state_dim=128,
+           ssm_chunk_size=32, num_moe_experts=8, moe_experts_held=(0, 4),
+           moe_router_topk=3, moe_ffn_hidden_size=64,
+           moe_shared_expert_intermediate_size=128)
+SSD_REQUESTS = ((70, 12), (9, 12), (40, 12))
+
+
+def phase_ssd(tiny):
+    rc, tr = _run_child("ssd", ["--child", "ssd"]
+                        + (["--tiny"] if tiny else []), timeout_s=420)
+    return check_ssd(rc, tr.lines, tiny)
+
+
+def child_ssd(tiny):
+    """In the child: 8 layers of H 128 of which 1 and 5 attend, the others
+    Mamba-2 mixers whose plane [128, 256] float32 the decode kernel takes in
+    two tiles of E (BLOCK_BYTES set to half a plane: at the published
+    [128, 8192] the tiles are the kernel's own), serve three requests
+    through DynamicInferenceEngine on the device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    _refuse_unless_tpu(jax, tiny)
+    from megatronapp_tpu.config.transformer_config import (
+        ActivationKind, NormKind, PositionEmbeddingKind, TransformerConfig,
+    )
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.inference.engine import SamplingParams
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.ops.pallas import ssm_update
+    from megatronapp_tpu.utils.platform import (
+        device_line, enable_compile_cache,
+    )
+    enable_compile_cache()
+    _say(device_line())
+    ssm_update.BLOCK_BYTES = 128 * 128 * 4
+    cfg = TransformerConfig(
+        hidden_size=128, num_attention_heads=2, num_query_groups=1,
+        ffn_hidden_size=128, vocab_size=512, max_position_embeddings=128,
+        normalization=NormKind.rmsnorm, activation=ActivationKind.swiglu,
+        add_bias_linear=False, position_embedding=PositionEmbeddingKind.none,
+        embedding_multiplier=12.0, attention_multiplier=1 / 64,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        params_dtype=jnp.bfloat16, **SSD)
+    params = init_gpt_params(jax.random.PRNGKey(0), cfg)[0]
+    eng = DynamicInferenceEngine(params, cfg, max_batch=8, max_seq_len=128,
+                                 prefill_chunk=64)
+    _say(eng.startup_line())
+    rng = np.random.default_rng(0)
+    for n, new in SSD_REQUESTS:
+        eng.add_request(rng.integers(0, 512, n).astype(np.int32), new,
+                        SamplingParams(greedy=True))
+    out = eng.run_to_completion()
+    b, mb = eng.max_batch, eng.pool.page_table.shape[1]
+    compiled = eng._decode.lower(
+        eng.params, jnp.zeros((b, 1), jnp.int32), eng._pools(), None,
+        jnp.zeros((b, mb), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.ones((b,), bool), None).compile()
+    text = compiled.as_text()
+    pools = eng._pools()
+    stats = eng.stats_snapshot()
+    _say(RESULT_PREFIX + json.dumps({
+        "tokens": sum(len(v) for v in out.values()),
+        "in_vocab": bool(all(0 <= t < 512 for v in out.values()
+                             for t in v)),
+        "state": stats["state"], "moe": stats["moe"],
+        "ssm_update_calls": sum(
+            1 for ln in text.splitlines()
+            if "custom-call(" in ln and " %ssm_update" in ln.split("=")[0]),
+        "e_tiles": 256 // ssm_update._tile(128, 256),
+        "pool_shapes": [list(p.shape) for p in pools],
+        "pool_bytes": sum(p.size * p.dtype.itemsize for p in pools),
+        "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}))
+
+
+def check_ssd(rc, lines, tiny=False):
+    out = {"phase": "ssd", "ok": False, "problems": []}
+    dev = _tagged(lines, DEVICE_LINE_PREFIX)
+    out["device"] = dev[0] if dev else None
+    res = _tagged(lines, RESULT_PREFIX)
+    if rc != 0 or not res:
+        out["problems"].append(f"child exited {rc} with "
+                               f"{len(res)} result lines")
+        return out
+    out.update(res[0])
+    interpreted = tiny and dev and dev[0]["platform"] != "tpu"
+    if not interpreted and out["ssm_update_calls"] != 2:
+        out["problems"].append(
+            f"{out['ssm_update_calls']} ssm_update custom calls in the "
+            "compiled decode step, not one a layer loop (2)")
+    if out["e_tiles"] != 2:
+        out["problems"].append(f"a plane in {out['e_tiles']} tiles of E, "
+                               "not 2")
+    if out["alias_bytes"] < out["pool_bytes"]:
+        out["problems"].append(
+            f"the decode step aliases {out['alias_bytes']} B of "
+            f"{out['pool_bytes']} B of pools: a pool is copied")
+    want = sum(n + new for n, new in SSD_REQUESTS)
+    if out["tokens"] != want or not out["in_vocab"]:
+        out["problems"].append(f"{out['tokens']} tokens came back (not "
+                               f"{want}), or one outside the vocabulary")
+    state = out["state"] or {}
+    if (state.get("mixer"), state.get("layers"), state.get("resets"),
+            state.get("conv_channels")) != ("mamba2", 6, 3, 512):
+        out["problems"].append(f"state counters {state}")
+    moe = out["moe"] or {}
+    picks = moe.get("tokens", 0) * 3 * 8
+    if (not picks or moe.get("assignments") != picks
+            or moe.get("assignments_here", 0)
+            + moe.get("assignments_absent", 0) != picks
+            or not moe.get("assignments_here")
+            or not moe.get("assignments_absent")):
+        out["problems"].append(f"the router's picks do not add up: {moe}")
     out["ok"] = not out["problems"]
     return out
 
@@ -1384,7 +1518,8 @@ def run(chips, tiny):
                 lambda: phase_eva(tiny),
                 lambda: phase_share(tiny),
                 lambda: phase_conv(tiny),
-                lambda: phase_window(tiny)]
+                lambda: phase_window(tiny),
+                lambda: phase_ssd(tiny)]
     for step in plan:
         ph = step()
         phases.append(ph)
@@ -1408,7 +1543,7 @@ def main(argv=None):
                     help="rehearsal sizes; phases may run on the CPU, the "
                          "verdict still needs a TPU")
     ap.add_argument("--child", choices=["train", "multichip", "hybrid", "eva",
-                                        "share", "conv", "window"],
+                                        "share", "conv", "window", "ssd"],
                     help=argparse.SUPPRESS)
     ap.add_argument("--impl", default="auto", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -1432,6 +1567,9 @@ def main(argv=None):
         return 0
     if args.child == "window":
         child_window(args.tiny)
+        return 0
+    if args.child == "ssd":
+        child_ssd(args.tiny)
         return 0
     return run(args.chips, args.tiny)
 

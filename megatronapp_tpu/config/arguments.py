@@ -24,7 +24,8 @@ from megatronapp_tpu.config.transformer_config import (
 
 
 _HYBRID_FIELDS = ("attn_layer_period", "attn_layer_offset",
-                  "ssm_inner_norms")
+                  "ssm_inner_norms", "ssm_heads", "ssm_head_dim",
+                  "ssm_state_dim", "ssm_chunk_size")
 
 
 def add_hybrid_args(ap: argparse.ArgumentParser):
@@ -32,19 +33,32 @@ def add_hybrid_args(ap: argparse.ArgumentParser):
     (TransformerConfig.attn_layer_period; HF `jamba`'s keys) — shared by
     the main parser (the hybrid trains through pretrain_gpt.py) and
     tools/run_text_generation_server.py (it serves through --engine
-    dynamic). The mixer's sizes stay the model's own (a preset's,
+    dynamic). The mixer's other sizes stay the model's own (a preset's,
     or TransformerConfig's defaults: state 16, conv 4, expand 2, dt rank
-    hidden / 16). Every default is None = the model's own."""
+    hidden / 16). Which mixer it is, is a fact of the model: --ssm-heads
+    makes it Mamba-2 (HF `granitemoehybrid`). Every default is None = the
+    model's own."""
     g = ap.add_argument_group("hybrid state-space stack")
     g.add_argument("--attn-layer-period", type=int, default=None,
                    help="layer i attends iff i %% period == offset; every "
-                        "other layer is a Mamba-1 selective-state-space "
-                        "layer (HF attn_layer_period)")
+                        "other layer is a selective-state-space layer "
+                        "(HF attn_layer_period)")
     g.add_argument("--attn-layer-offset", type=int, default=None,
                    help="HF attn_layer_offset")
     g.add_argument("--ssm-inner-norms", action="store_const", const=True,
                    default=None,
                    help="RMS norms on dt, B and C after x_proj (Jamba)")
+    g.add_argument("--ssm-heads", type=int, default=None,
+                   help="the state-space layers are Mamba-2 mixers of this "
+                        "many heads, a matrix state a head (HF "
+                        "mamba_n_heads); heads x --ssm-head-dim = 2 x hidden")
+    g.add_argument("--ssm-head-dim", type=int, default=None,
+                   help="columns of a Mamba-2 head (HF mamba_d_head)")
+    g.add_argument("--ssm-state-dim", type=int, default=None,
+                   help="the state's size N (HF mamba_d_state)")
+    g.add_argument("--ssm-chunk-size", type=int, default=None,
+                   help="positions a chunk of Mamba-2's prefill and "
+                        "training scan (HF mamba_chunk_size)")
 
 
 def hybrid_fields(args) -> dict:

@@ -105,6 +105,18 @@ class TransformerConfig:
     # Softmax / logits details (reference: apply_query_key_layer_scaling etc.).
     attention_softmax_in_fp32: bool = True
     apply_query_key_layer_scaling: bool = False
+    # Four scalar facts of a model (HF `granite*`: embedding_multiplier,
+    # attention_multiplier, residual_multiplier, logits_scaling), none a
+    # tuning knob: the embedding row is multiplied by the first; the
+    # attention scores are q.k times the second IN PLACE of 1 / sqrt(head
+    # dim) (None: that); each half's output is multiplied by the third
+    # before it joins the residual stream; the logits are DIVIDED by the
+    # fourth. Plain attention layers on the plain and paged paths read the
+    # second (no MLA, EVA or cp ring).
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # MoE (reference: transformer_config.py moe_* fields; moe/ directory).
     num_moe_experts: Optional[int] = None
@@ -154,21 +166,29 @@ class TransformerConfig:
     moe_experts_held: Optional[Tuple[int, int]] = None
     moe_shortcut_double_layer: bool = False
 
-    # Hybrid state-space stacks (HF `jamba`: attn_layer_period /
-    # attn_layer_offset): layer i attends iff i % period == offset, and
-    # every other layer's first half is a Mamba-1 selective-state-space
-    # mixer (transformer/ssm.py). None = every layer attends. The ssm_*
-    # fields are that mixer's sizes (HF mamba_d_state, mamba_d_conv,
+    # Hybrid state-space stacks (HF `jamba`, `granitemoehybrid`:
+    # attn_layer_period / attn_layer_offset): layer i attends iff i % period
+    # == offset, and every other layer's first half is a selective-state-
+    # space mixer (transformer/ssm.py). None = every layer attends. The
+    # ssm_* fields are that mixer's sizes (HF mamba_d_state, mamba_d_conv,
     # mamba_expand, mamba_dt_rank; None = ceil(hidden / 16)) and Jamba's
     # RMS norms on dt, B and C. The convolution has a bias and the two
     # projections none (HF mamba_conv_bias true, mamba_proj_bias false).
+    # Which mixer it is, is a fact of the model: with ssm_heads (HF
+    # mamba_n_heads) it is Mamba-2: ssm_heads heads of ssm_head_dim columns
+    # (HF mamba_d_head; heads x head_dim = ssm_expand x hidden_size), each
+    # with a matrix state [head_dim, ssm_state_dim], one scalar decay and
+    # one dt a head, B and C shared by the heads of one of ssm_groups groups
+    # (HF mamba_n_groups; 1 is what is written), a gated RMS norm before the
+    # output projection, and a prefill that runs as matrix products over
+    # chunks of ssm_chunk_size positions (HF mamba_chunk_size). Without
+    # ssm_heads it is Mamba-1 (a vector state [ssm_state_dim] a channel).
     # shortconv_kernel > 0 makes every non-attention layer's first half a
     # gated short convolution instead (HF `lfm2` / `lfm2_moe`: layer_types
     # "conv", conv_L_cache taps, conv_bias false; transformer/shortconv.py):
     # no recurrence h, its whole state is the convolution's last
-    # shortconv_kernel - 1 gated inputs. Such a stack (and no state-space
-    # one) may run MoE feed-forwards behind moe_first_k_dense leading dense
-    # ones.
+    # shortconv_kernel - 1 gated inputs. Any of these stacks may run MoE
+    # feed-forwards behind moe_first_k_dense leading dense ones.
     attn_layer_period: Optional[int] = None
     attn_layer_offset: int = 0
     # The depth that the scaled init of the residual-out projections divides
@@ -182,6 +202,10 @@ class TransformerConfig:
     ssm_expand: int = 2
     ssm_dt_rank: Optional[int] = None
     ssm_inner_norms: bool = False
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_chunk_size: int = 256
     shortconv_kernel: int = 0
 
     # Sliding-window attention layers beside full ones in one stack (HF
@@ -434,14 +458,6 @@ class TransformerConfig:
                     "a hybrid stack (attn_layer_period) runs plain "
                     "attention layers in its own layer loop: no MLA or "
                     "heterogeneous block configs")
-            if self.is_moe and not (self.shortconv_kernel
-                                    or self.sliding_window):
-                raise ValueError(
-                    "a hybrid state-space stack (attn_layer_period with "
-                    "Mamba mixers) runs dense feed-forwards: no MoE; only "
-                    "the gated short-convolution stack (shortconv_kernel) "
-                    "and the sliding-window stack (sliding_window) have "
-                    "been given MoE feed-forwards")
             if self.is_moe and (
                     self.moe_layer_freq != 1 or self.moe_zero_experts
                     or self.moe_shortcut_double_layer or self.mtp_num_layers):
@@ -450,6 +466,26 @@ class TransformerConfig:
                     "behind moe_first_k_dense leading dense ones: no "
                     "moe_layer_freq, moe_zero_experts, shortcut double "
                     "layer or MTP")
+        if self.ssm_heads:
+            e = self.ssm_expand * self.hidden_size
+            if (self.attn_layer_period is None or self.shortconv_kernel
+                    or self.sliding_window or self.ssm_inner_norms
+                    or self.ssm_heads * self.ssm_head_dim != e
+                    or self.ssm_groups != 1 or self.ssm_chunk_size < 1):
+                raise ValueError(
+                    f"ssm_heads={self.ssm_heads} makes the state-space "
+                    "layers of a hybrid stack (attn_layer_period; no "
+                    "shortconv_kernel, sliding_window or ssm_inner_norms) "
+                    f"Mamba-2 mixers: ssm_heads x ssm_head_dim "
+                    f"({self.ssm_head_dim}) is ssm_expand x hidden_size "
+                    f"({e}), and B and C are shared by every head "
+                    f"(ssm_groups={self.ssm_groups}: more than one group "
+                    "is not written)")
+        if self.attention_multiplier is not None and (
+                self.multi_latent_attention or self.is_eva):
+            raise ValueError(
+                "attention_multiplier replaces 1 / sqrt(head dim) in plain "
+                "attention layers: no MLA, no EVA")
         if self.shortconv_kernel and (
                 self.shortconv_kernel < 2 or self.attn_layer_period is None):
             raise ValueError(
@@ -592,6 +628,16 @@ class TransformerConfig:
     def num_ssm_layers(self) -> int:
         """Layers whose first half is a state-space mixer."""
         return 0 if self.shortconv_kernel else self.num_recurrent_layers
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Columns the state-space mixer's causal convolution runs over, and
+        so of a slot's cached tail: Mamba-1's expanded input; Mamba-2's x, B
+        and C side by side."""
+        e = self.ssm_expand * self.hidden_size
+        if self.ssm_heads:
+            return e + 2 * self.ssm_groups * self.ssm_state_dim
+        return e
 
     @property
     def num_conv_layers(self) -> int:

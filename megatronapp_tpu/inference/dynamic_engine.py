@@ -728,6 +728,13 @@ def prefill_call_costs(cfg: TransformerConfig, params):
             share = cfg.moe_router_topk / cfg.moe_router_width
         stream += leaf.size * leaf.dtype.itemsize
         flops += 2.0 * leaf.size * share
+    if cfg.ssm_heads:
+        # A Mamba-2 layer's chunked scan is matrix products too, a position
+        # (transformer/ssm.ssd_chunked): Q scores of N and Q x P a head
+        # within the chunk, N x E into the state and N x E out of it.
+        q, n = cfg.ssm_chunk_size, cfg.ssm_state_dim
+        e = cfg.ssm_expand * cfg.hidden_size
+        flops += cfg.num_ssm_layers * 2.0 * (q * n + q * e + 2 * n * e)
     return stream, flops
 
 
@@ -2010,7 +2017,9 @@ class DynamicInferenceEngine:
             self.state_stats["resets"] += 1
         pos, count = cached, 0
         logits = hid = None
-        call_attrs = {}
+        # (what each Mamba-2 layer of a call scans, chunk by chunk)
+        call_attrs = {"ssd_chunks": cdiv(
+            c, min(self.cfg.ssm_chunk_size, c))} if self.cfg.ssm_heads else {}
         while pos < p_len:
             count = min(c, p_len - pos)
             if self.eva:
@@ -2759,7 +2768,9 @@ class DynamicInferenceEngine:
         `layers` and `slots` of recurrent state at `bytes_per_slot`,
         `resets` (sequences started from zeros at admission), `dropped`
         (states thrown away by preemption), `prefill_scans` (chunk scans
-        run: prefill calls x such layers).
+        run: prefill calls x such layers); of kind "ssm" also which
+        `mixer` ("mamba1", "mamba2"), its `heads` (0: a vector state a
+        channel), `state_dim` and the convolution's `conv_channels`.
         "sampler" counts what the sampler was asked for, by
         plain decode rounds and by prefills' first samples: `*_greedy`
         (argmax alone), `*_sampled` (a categorical, the vocabulary not
@@ -2866,6 +2877,12 @@ class DynamicInferenceEngine:
                 self.state_stats, kind=self.state_kind,
                 layers=self.cfg.num_recurrent_layers, slots=self.max_batch,
                 bytes_per_slot=self.pool.state_bytes_per_slot)
+            if self.state_kind == "ssm":
+                out["state"].update(
+                    mixer="mamba2" if self.cfg.ssm_heads else "mamba1",
+                    heads=self.cfg.ssm_heads,
+                    state_dim=self.cfg.ssm_state_dim,
+                    conv_channels=self.cfg.ssm_conv_channels)
         if self.cfg.is_moe:
             here = self.cfg.moe_experts_here[1]
             per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)

@@ -380,8 +380,11 @@ class PagedKVCache:
         # state-space layers (transformer/ssm.py), which no page table
         # names. A slot owns row `slot` of every layer's plane, whatever
         # its sequence's length: h [N, E] in float32 (E minor: a whole
-        # number of 128-lane vregs) and the convolution's last k-1 inputs
-        # in the compute type, side by side in one row [(k-1) * E] (as
+        # number of 128-lane vregs; a Mamba-2 layer's matrix state a head
+        # is that head's columns of it, 4 MiB a slot a layer at [128, 8192])
+        # and the convolution's last k-1 inputs (Mamba-1: E columns each;
+        # Mamba-2: x, B and C, E + 2N)
+        # in the compute type, side by side in one row [(k-1) * C] (as
         # [L, slots, k-1, E] XLA pads 3 taps to 4 sublanes and relayouts
         # all of it on the way into a decode step and out again; as
         # [L, k-1, slots, E] it does the same in a prefill call). Nothing
@@ -407,7 +410,8 @@ class PagedKVCache:
                     _new_pool((cfg.num_ssm_layers, max_batch,
                                cfg.ssm_state_dim, e), jnp.float32, 0),
                     _new_pool((cfg.num_ssm_layers, max_batch,
-                               (cfg.ssm_conv_kernel - 1) * e),
+                               (cfg.ssm_conv_kernel - 1)
+                               * cfg.ssm_conv_channels),
                               cfg.compute_dtype, 0))
 
         # The window planes: a sliding-window stack's window layers cache

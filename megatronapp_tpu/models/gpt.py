@@ -136,6 +136,8 @@ def gpt_embed(p, tokens: jnp.ndarray, cfg: TransformerConfig,
                 position_ids = jnp.arange(tokens.shape[1])[None, :]
             pos = position_ids + position_offset
             h = h + jnp.take(p["embedding"]["pos"], pos, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * cfg.embedding_multiplier
         return h.astype(dtype or cfg.compute_dtype)
 
 
@@ -370,8 +372,10 @@ def gpt_head(p, h: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
                       else jnp.swapaxes(p["embedding"]["word"], -1, -2))
         logits = per_rank.dense(h.astype(cfg.compute_dtype),
                                 out_kernel.astype(cfg.compute_dtype))
-        logits = scope_capture("result", logits)
-        return logits.astype(jnp.float32)
+        logits = scope_capture("result", logits).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 def gpt_pipeline_loss(p, tokens_mb, targets_mb, loss_mask_mb,

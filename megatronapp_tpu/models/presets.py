@@ -281,6 +281,45 @@ def mellum2_12b_a2p5b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def granite_4_0_h_small(**kw) -> TransformerConfig:
+    """ibm-granite/granite-4.0-h-small (`granitemoehybrid`; 32B parameters,
+    ~9B active) as its config.json publishes it
+    (https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/
+    config.json): 40 layers of H 4096 of which 5, 15, 25 and 35 attend (32
+    query heads over 8 key/value heads of 128, NO positional term, softmax
+    of q.k / 128) and 36 are Mamba-2 mixers (128 heads of 64 columns, a
+    [64, 128] state a head, one group, convolution of 4 taps over x, B and
+    C, chunks of 256, a gated RMS norm before the output projection); every
+    layer ends in 72 experts of width 768, the 10 largest logits softmaxed
+    among themselves, beside a shared expert of 1536; the embedding times
+    12, each half's output times 0.22, the logits over 16; RMSNorm 1e-5, a
+    tied head over 100,352. Whole it is 64 GB of bf16 weights: a deployment
+    passes its share (num_layers, moe_experts_held, vocab_size), as the
+    benchmark's configuration does
+    (perfbench/configs/granite-4.0-h-small.json, which also lists what the
+    config leaves to the family's convention). Serves through --engine
+    dynamic."""
+    d = dict(num_layers=40, hidden_size=4096, num_attention_heads=32,
+             num_query_groups=8, ffn_hidden_size=1536, vocab_size=100352,
+             max_position_embeddings=131072,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             position_embedding=PositionEmbeddingKind.none,
+             attn_layer_period=10, attn_layer_offset=5,
+             ssm_state_dim=128, ssm_conv_kernel=4, ssm_expand=2,
+             ssm_heads=128, ssm_head_dim=64, ssm_groups=1,
+             ssm_chunk_size=256,
+             num_moe_experts=72, moe_router_topk=10,
+             moe_ffn_hidden_size=768,
+             moe_shared_expert_intermediate_size=1536,
+             moe_router_norm_topk_prob=True,
+             embedding_multiplier=12.0, attention_multiplier=0.0078125,
+             residual_multiplier=0.22, logits_scaling=16.0,
+             scaled_init_layers=40)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -304,6 +343,7 @@ def evabyte_6p5b(**kw) -> TransformerConfig:
 
 PRESETS = {
     "evabyte-6.5b": evabyte_6p5b,
+    "granite-4.0-h-small": granite_4_0_h_small,
     "jamba2-3b": jamba2_3b,
     "lfm2-24b-a2b": lfm2_24b_a2b,
     "laguna-xs.2": laguna_xs2,

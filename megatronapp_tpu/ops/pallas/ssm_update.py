@@ -1,11 +1,19 @@
 """The decode step of a selective-state-space layer: one Pallas call that
 updates the running slots' recurrent state in place.
 
-A Mamba-1 layer carries, per sequence, h [N, E] float32 (N the state size,
-E the expanded width; E is the minor dimension, a whole number of 128-lane
-vregs). A decode round advances every running slot by one token:
+A state-space layer carries, per sequence, h [N, E] float32 (N the state
+size, E the expanded width; E is the minor dimension, a whole number of
+128-lane vregs). A decode round advances every running slot by one token:
 
     h' = exp(dt * A) * h + (dt * B) * u ;  y = sum_n h'[n] * C[n] + D * u
+
+Mamba-1 (N 16) has an A [N, E]; Mamba-2 (N 128; transformer/ssm.py keeps a
+head's matrix state as the head's columns of h) one scalar a head, which
+comes as ONE row [1, E] with dt and D broadcast over a head's columns alike:
+the exponential is then taken a column and not an element. Where a slot's
+plane is larger than BLOCK_BYTES (Mamba-2's [128, 8192] is 4 MiB; in, out
+and double buffering of it would be the v5e's whole scoped VMEM) the grid
+has a second dimension over tiles of E: the columns are independent.
 
 The states of all the model's state-space layers live in one stacked pool
 [L, slots, N, E] (inference/paged_cache.py) which rides the engine's layer
@@ -35,11 +43,27 @@ def ssm_update_reference(h, dt, u, b, c, a_t, d):
     return y, h
 
 
+# The largest block of a slot's plane a grid step holds (Jamba's whole plane
+# [16, 5120] is 320 KiB: one tile, the kernel as it was).
+BLOCK_BYTES = 1 << 20
+
+
+def _tile(n: int, e: int) -> int:
+    """Columns of E a grid step takes: all of them where the plane fits
+    BLOCK_BYTES, else the largest multiple of 128 that divides E and
+    fits."""
+    if n * e * 4 <= BLOCK_BYTES or e % 128:
+        return e
+    return max((t for t in range(128, e, 128)
+                if e % t == 0 and n * t * 4 <= BLOCK_BYTES), default=128)
+
+
 def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
                b: jnp.ndarray, c: jnp.ndarray, a_t: jnp.ndarray,
                d: jnp.ndarray, active: jnp.ndarray):
     """pool [L, slots, N, E] f32; layer int32 scalar; dt, u [slots, E]
-    f32; b, c [slots, N] f32; a_t [N, E] f32 (A transposed); d [E] f32;
+    f32; b, c [slots, N] f32; a_t [N, E] f32 (A transposed) or [1, E] (one
+    A for every n); d [E] f32;
     active [slots] bool. Returns (y [slots, E] f32, pool): the pool is the
     buffer that came in wherever the caller's copy of it is dead (a
     donated argument, a loop carry), with plane `layer` of the active
@@ -58,25 +82,39 @@ def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
         y_ref[...] = jnp.sum(h * c_ref[...], axis=0, keepdims=True) \
             + u_ * d_ref[...]
 
-    def row(i, lid, rows):
-        return (rows[i], 0, 0)
+    # The grid: the running rows, and where a plane is tiled the tiles of E
+    # (ids = (i,) or (i, j), then the two prefetched scalars).
+    te = _tile(n, e)
+    tiled = te < e
 
-    def fixed(i, lid, rows):
-        return (0, 0)
+    def col(ids):
+        return ids[1] if tiled else 0
 
-    def plane(i, lid, rows):
-        return (lid[0], rows[i], 0, 0)
+    def row(*ids):
+        return (ids[-1][ids[0]], 0, col(ids))
+
+    def tall_row(*ids):
+        return (ids[-1][ids[0]], 0, 0)
+
+    def fixed(*ids):
+        return (0, col(ids))
+
+    def plane(*ids):
+        return (ids[-2][0], ids[-1][ids[0]], 0, col(ids))
 
     # A row's vectors come as [slots, 1, E] and [slots, N, 1]: blocks whose
-    # last two dims are the array's own, which Mosaic takes whole.
-    wide = pl.BlockSpec((None, 1, e), row)
-    tall = pl.BlockSpec((None, n, 1), row)
-    state = pl.BlockSpec((None, None, n, e), plane)
+    # last two dims are the array's own (or whole lane tiles of E), which
+    # Mosaic takes whole.
+    wide = pl.BlockSpec((None, 1, te), row)
+    tall = pl.BlockSpec((None, n, 1), tall_row)
+    state = pl.BlockSpec((None, None, n, te), plane)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(jnp.sum(active, dtype=jnp.int32),),
-        in_specs=[wide, wide, tall, tall, pl.BlockSpec((n, e), fixed),
-                  pl.BlockSpec((1, e), fixed), state, wide],
+        grid=(jnp.sum(active, dtype=jnp.int32),) + (
+            (e // te,) if tiled else ()),
+        in_specs=[wide, wide, tall, tall,
+                  pl.BlockSpec((a_t.shape[0], te), fixed),
+                  pl.BlockSpec((1, te), fixed), state, wide],
         out_specs=[wide, state],
     )
     y, pool = pl.pallas_call(

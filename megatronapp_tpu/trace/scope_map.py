@@ -58,8 +58,10 @@ PARTS = ("attention", "ssm", "conv", "mlp", "moe", "embedding", "head",
 # What tells two kinds of layer apart INSIDE a part: a sliding-window stack's
 # window layers run their attention under `attention/window`
 # (transformer/block.py). A sub-part is no part: the window layers' time
-# stays in `attention`, and Scoped.sub says which of it is theirs.
-SUBPARTS = {"attention": ("window",)}
+# stays in `attention`, and Scoped.sub says which of it is theirs. A Mamba-2
+# mixer's chunked scan and its gated norm are written under `ssm/ssd_chunk`
+# and `ssm/gated_norm` (transformer/ssm.py).
+SUBPARTS = {"attention": ("window",), "ssm": ("ssd_chunk", "gated_norm")}
 OTHER = "other"
 MAX_STEPS = 16
 # An option at its default value: the compiled program is the same, the

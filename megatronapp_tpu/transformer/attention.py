@@ -259,7 +259,8 @@ def attention_forward(
         # Ambient-manual tp-sharded stage body: see docstring. Local head
         # counts; s stays the LOCAL seq chunk length, sf the full length.
         if (kv_cache is not None or attention_mask is not None
-                or segment_ids is not None or zigzag or cfg.is_eva):
+                or segment_ids is not None or zigzag or cfg.is_eva
+                or cfg.attention_multiplier is not None):
             raise NotImplementedError(
                 "tp-sharded stage body supports the plain training path "
                 "only (no kv cache / explicit mask / packing / zigzag) — "
@@ -364,6 +365,10 @@ def attention_forward(
         q, kv = jax.lax.optimization_barrier((q, kv))
     q = q.reshape(b, s, nq, d)
     k, v = jnp.split(kv.reshape(b, s, 2 * nkv, d), 2, axis=2)
+    if cfg.attention_multiplier is not None:
+        # Every implementation below scales the scores by 1 / sqrt(d): the
+        # model's own factor goes onto the query, less that.
+        q = q * (cfg.attention_multiplier * d ** 0.5)
 
     # MegaScope QKV capture site (reference attention.py:979-981).
     q = scope_capture("qkv_q", q, layer_id)

@@ -324,6 +324,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         attn_out, new_cache = attend()
     # Tag for the 'selective_attn' remat policy (a no-op otherwise).
     attn_out = checkpoint_name(attn_out, "attn_out")
+    if cfg.residual_multiplier != 1.0:
+        attn_out = attn_out * cfg.residual_multiplier
     x = residual + attn_out.astype(residual.dtype)
 
     residual = x
@@ -362,6 +364,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                                   ctx=ctx, tp_sharded=tp_sharded,
                                   fp8=None if fp8 is None else fp8["mlp"],
                                   lora=lora)
+    if cfg.residual_multiplier != 1.0:
+        mlp_out = mlp_out * cfg.residual_multiplier
     x = residual + mlp_out.astype(residual.dtype)
     # MegaScope 'system' perturbation + capture site between layers
     # (transformer_block.py:542-544).

@@ -1,7 +1,9 @@
-"""Selective-state-space mixer (Mamba-1): the third kind of a layer's first
-half, beside attention.py and mla.py.
+"""Selective-state-space mixers: the third kind of a layer's first half,
+beside attention.py and mla.py. Two of them, and which one a model has is a
+fact of it (SsmDims.heads): Mamba-1 (HF `jamba`) and Mamba-2 (HF
+`granitemoehybrid`, `mamba2`).
 
-Parity with /root/reference/megatron/core/ssm/mamba_mixer.py and HF
+MAMBA-1. Parity with /root/reference/megatron/core/ssm/mamba_mixer.py and HF
 `modeling_jamba.JambaMambaMixer`: in_proj -> (u, z); causal depthwise
 conv1d over the last k positions; silu; data-dependent dt, B, C (Jamba:
 each through an RMS norm of its own); the recurrence
@@ -25,6 +27,27 @@ out_kernel [E, H]; with ssm_inner_norms dt_ln_scale [R], b_ln_scale [N],
 c_ln_scale [N]. The convolution has a bias and the two projections none:
 every model here says so (HF mamba_conv_bias true, mamba_proj_bias false),
 so they are no switches.
+
+MAMBA-2 (Dao & Gu 2024, "Transformers are SSMs"; HF
+`modeling_granitemoehybrid.GraniteMoeHybridMambaLayer`): E = heads x P
+columns in heads of P; in_proj -> [z | xBC | dt] (E | E + 2N | heads); the
+convolution and silu run over x, B and C TOGETHER; dt and A are one scalar a
+head, B and C [N] shared by every head (one group); the state a head is a
+matrix S [P, N]:
+
+    S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t ;  y_t = S_t C_t + D x_t
+
+then out = RMS(y * silu(z); g) W_out, the norm over all E columns. The state
+is kept as Mamba-1's h [B, N, E] (h[n, head * P + p] = S[head][p, n]): with
+dt, A and D broadcast over a head's P columns the decode step IS Mamba-1's,
+and runs in the same kernel and the same pool. A whole sequence cannot be
+Mamba-1's scan (its [B, block, N, E] float32 operands are 268 MB each at N
+128, E 8192): it is the chunked (SSD) form, matrix products over chunks of
+`chunk` positions carried chunk to chunk (`ssd_chunked`).
+
+Param leaves: in_kernel [H, 2E + 2N + heads], conv_kernel [k, E + 2N],
+conv_bias [E + 2N], dt_bias [heads], A_log [heads], D [heads], norm_scale
+[E], out_kernel [E, H].
 """
 
 from __future__ import annotations
@@ -46,6 +69,8 @@ class SsmDims(NamedTuple):
     expand: int = 2
     dt_rank: Optional[int] = None
     inner_norms: bool = False
+    heads: int = 0              # > 0: Mamba-2, of `heads` heads
+    chunk: int = 256            # positions a chunk of Mamba-2's prefill
 
     def rank(self, hidden: int) -> int:
         return self.dt_rank or max(hidden // 16, 1)
@@ -53,19 +78,63 @@ class SsmDims(NamedTuple):
 
 def ssm_dims(cfg: TransformerConfig) -> SsmDims:
     return SsmDims(cfg.ssm_state_dim, cfg.ssm_conv_kernel, cfg.ssm_expand,
-                   cfg.ssm_dt_rank, cfg.ssm_inner_norms)
+                   cfg.ssm_dt_rank, cfg.ssm_inner_norms, cfg.ssm_heads,
+                   cfg.ssm_chunk_size)
+
+
+def _init_dt_bias(key, shape, dtype):
+    """softplus(dt_bias) log-uniform in [1e-3, 1e-1] (reference dt init)."""
+    return jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1))))).astype(
+            dtype)
+
+
+def _init_ssm2_params(rng, cfg: TransformerConfig, dims: SsmDims, out_std):
+    """Mamba-2's leaves. dt_bias as Mamba-1's (softplus of it log-uniform in
+    [1e-3, 1e-1]), A = -(1..16) uniform a head (the published mixer's
+    A_init_range), D = 1, the gated norm's scale 1."""
+    h = cfg.hidden_size
+    e = dims.expand * h
+    n = dims.state_dim
+    c = e + 2 * n
+    keys = jax.random.split(rng, 5)
+    std = cfg.init_method_std
+    p = {
+        "in_kernel": jax.random.normal(
+            keys[0], (h, e + c + dims.heads), cfg.params_dtype) * std,
+        "conv_kernel": jax.random.normal(
+            keys[1], (dims.conv_kernel, c), cfg.params_dtype) * std,
+        "conv_bias": jnp.zeros((c,), cfg.params_dtype),
+        "dt_bias": _init_dt_bias(keys[2], (dims.heads,), cfg.params_dtype),
+        "A_log": jnp.log(jax.random.uniform(
+            keys[3], (dims.heads,), jnp.float32, 1.0, 16.0)).astype(
+                cfg.params_dtype),
+        "D": jnp.ones((dims.heads,), cfg.params_dtype),
+        "norm_scale": jnp.ones((e,), cfg.params_dtype),
+        "out_kernel": jax.random.normal(
+            keys[4], (e, h), cfg.params_dtype) * out_std,
+    }
+    ax = {
+        "in_kernel": ("embed", "mlp"), "conv_kernel": (None, "mlp"),
+        "conv_bias": ("mlp",), "dt_bias": (None,), "A_log": (None,),
+        "D": (None,), "norm_scale": ("mlp",),
+        "out_kernel": ("mlp", "embed"),
+    }
+    return p, ax
 
 
 def init_ssm_params(rng, cfg: TransformerConfig, dims: SsmDims,
                     out_std=None):
+    if out_std is None:
+        out_std = cfg.init_method_std / jnp.sqrt(2.0 * cfg.num_layers)
+    if dims.heads:
+        return _init_ssm2_params(rng, cfg, dims, out_std)
     h = cfg.hidden_size
     e = dims.expand * h
     n = dims.state_dim
     dt_rank = dims.rank(h)
     keys = jax.random.split(rng, 6)
     std = cfg.init_method_std
-    if out_std is None:
-        out_std = std / jnp.sqrt(2.0 * cfg.num_layers)
     p = {
         "in_kernel": jax.random.normal(keys[0], (h, 2 * e),
                                        cfg.params_dtype) * std,
@@ -77,10 +146,7 @@ def init_ssm_params(rng, cfg: TransformerConfig, dims: SsmDims,
                                     cfg.params_dtype) * std,
         "dt_proj": jax.random.normal(keys[3], (dt_rank, e),
                                      cfg.params_dtype) * std,
-        # softplus(dt_bias) initialized in [1e-3, 1e-1] (reference dt init).
-        "dt_bias": jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
-            keys[4], (e,), jnp.float32,
-            jnp.log(1e-3), jnp.log(1e-1))))).astype(cfg.params_dtype),
+        "dt_bias": _init_dt_bias(keys[4], (e,), cfg.params_dtype),
         # A negative-real diagonal, initialized -[1..N] per channel.
         "A_log": jnp.log(jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32),
                                   (e, 1))).astype(cfg.params_dtype),
@@ -161,9 +227,101 @@ def selective_scan(u, dt, a_t, b, c, d, h0=None):
     return jnp.swapaxes(y, 0, 1).reshape(bsz, -1, e)[:, :s], h
 
 
+def ssd_chunked(x, dt, a, b, c, d, chunk: int, h0=None):
+    """Mamba-2's recurrence over a whole sequence as matrix products (the
+    SSD form). x [B,S,E], E = heads x P; dt [B,S,heads] float32; a, d
+    [heads] float32 (a < 0); b, c [B,S,N]; h0 [B,N,E] float32 or None
+    (zeros) -> (y [B,S,E] float32, h_S [B,N,E] float32).
+
+    Chunks of `chunk` positions, one after the other, the state carried
+    between them (a `lax.scan`: one chunk's [B, heads, Q, Q] decays live at
+    a time, 33 MB at 128 heads and Q 256). With cum_i the running sum of
+    dt a inside a chunk:
+
+      y_i  = sum_{j<=i} (C_i . B_j) exp(cum_i - cum_j) dt_j x_j   (in it)
+           + exp(cum_i) C_i h                              (what came in)
+      h'   = exp(cum_Q) h + sum_j exp(cum_Q - cum_j) dt_j B_j (x) x_j
+
+    A position whose dt is 0 leaves the state as it was and adds nothing to
+    a later y: so does the padding of the last chunk. The products take
+    their operands in x's type and add up in float32; the state stays
+    float32."""
+    bsz, s, e = x.shape
+    heads, n = dt.shape[-1], b.shape[-1]
+    p = e // heads
+    f32 = jnp.float32
+    q = min(chunk, s)
+    chunks = -(-s // q)
+
+    def split(t):                           # [B,S,.] -> [chunks,B,q,.]
+        t = jnp.pad(t, ((0, 0), (0, chunks * q - s), (0, 0)))
+        return jnp.swapaxes(t.reshape(bsz, chunks, q, t.shape[-1]), 0, 1)
+
+    def wide(t):                            # [..., heads] -> [..., E]
+        return jnp.repeat(t, p, axis=-1)
+
+    lower = jnp.tril(jnp.ones((q, q), bool))
+
+    def step(h, xs):
+        x_c, dt_c, b_c, c_c = xs
+        cum = jnp.cumsum(dt_c * a, axis=1)                  # [B,q,heads]
+        cum_t = jnp.swapaxes(cum, 1, 2)                     # [B,heads,q]
+        decay = jnp.exp(jnp.where(
+            lower, cum_t[..., :, None] - cum_t[..., None, :], -jnp.inf))
+        scores = jnp.einsum("bin,bjn->bij", c_c, b_c,
+                            preferred_element_type=f32)
+        m = (scores[:, None] * decay).astype(x.dtype)       # [B,heads,q,q]
+        xdt = (x_c.reshape(bsz, q, heads, p).astype(f32)
+               * dt_c[..., None]).astype(x.dtype)
+        y = jnp.einsum("bhij,bjhp->bihp", m, xdt,
+                       preferred_element_type=f32).reshape(bsz, q, e)
+        y = y + wide(jnp.exp(cum)) * jnp.einsum(
+            "bin,bne->bie", c_c.astype(f32), h)
+        left = jnp.exp(cum[:, -1:] - cum) * dt_c            # [B,q,heads]
+        h = wide(jnp.exp(cum[:, -1]))[:, None, :] * h + jnp.einsum(
+            "bjn,bje->bne", b_c, (x_c.astype(f32) * wide(left)).astype(
+                x.dtype), preferred_element_type=f32)
+        return h, y
+
+    if h0 is None:
+        h0 = jnp.zeros((bsz, n, e), f32)
+    h, y = jax.lax.scan(step, h0, tuple(map(split, (x, dt, b, c))))
+    y = jnp.swapaxes(y, 0, 1).reshape(bsz, -1, e)[:, :s]
+    return y + x.astype(f32) * wide(d), h
+
+
 def _plain_update(h, dt, u, b, c, a_t, d):
     from megatronapp_tpu.ops.pallas.ssm_update import ssm_update_reference
     return ssm_update_reference(h, dt, u, b, c, a_t, d)
+
+
+def _causal_conv(raw, tail, p, k: int):
+    """The causal depthwise convolution of `raw` [B,S,C] over the k - 1
+    inputs before it (`tail` [B,k-1,C]; None: a sequence's start, zeros),
+    its bias and silu, in float32 -> (the padded inputs [B,S+k-1,C], the
+    result [B,S,C])."""
+    s = raw.shape[1]
+    if tail is None:
+        padded = jnp.pad(raw, ((0, 0), (k - 1, 0), (0, 0)))
+    else:
+        padded = jnp.concatenate([tail.astype(raw.dtype), raw], axis=1)
+    # k shifted products summed in float32, elementwise: as a dot_general
+    # (one contraction of length k a channel) XLA:TPU lays the operands
+    # out batch-minor and relayouts the tails on the way in and out.
+    f32 = jnp.float32
+    taps = p["conv_kernel"].astype(f32)
+    conv = sum(padded[:, i:i + s].astype(f32) * taps[i] for i in range(k))
+    return padded, jax.nn.silu(conv + p["conv_bias"].astype(f32))
+
+
+def _last_inputs(padded, s: int, counts, k: int):
+    """The k - 1 inputs behind the last REAL position of each row of
+    `padded` [B,S+k-1,C] (counts [B]; None: every position is real)."""
+    if counts is None:
+        return padded[:, s:]
+    return jnp.take_along_axis(
+        padded, (counts[:, None] + jnp.arange(k - 1)[None, :])[..., None],
+        axis=1)
 
 
 def ssm_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state=None,
@@ -179,6 +337,8 @@ def ssm_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state=None,
     update(h, dt, u, b, c, a_t, d) → (y, h'): how one token (S == 1 on a
     given state) advances h; the paged engine passes its in-place kernel,
     whose h is the whole pool (state[1] goes to it as it came)."""
+    if dims.heads:
+        return _ssm2_forward(p, x, cfg, dims, state, counts, update)
     bsz, s, hidden = x.shape
     n = dims.state_dim
     dt_rank = dims.rank(hidden)
@@ -190,16 +350,8 @@ def ssm_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state=None,
 
     # Causal depthwise conv along seq, over the tail and the new inputs.
     tail, h0 = state if state is not None else (None, None)
-    if tail is None:
-        u_pad = jnp.pad(u_raw, ((0, 0), (k - 1, 0), (0, 0)))
-    else:
-        u_pad = jnp.concatenate([tail.astype(u_raw.dtype), u_raw], axis=1)
-    # k shifted products summed in float32, elementwise: as a dot_general
-    # (one contraction of length k a channel) XLA:TPU lays the operands
-    # out batch-minor and relayouts the tails on the way in and out.
-    taps = p["conv_kernel"].astype(f32)
-    u = sum(u_pad[:, i:i + s].astype(f32) * taps[i] for i in range(k))
-    u = jax.nn.silu(u + p["conv_bias"].astype(f32)).astype(cd)
+    u_pad, u = _causal_conv(u_raw, tail, p, k)
+    u = u.astype(cd)
 
     proj = u @ p["x_proj"].astype(u.dtype)  # [B,S,dt_rank+2N]
     dt_r, b_, c_ = jnp.split(proj, [dt_rank, dt_rank + n], axis=-1)
@@ -225,20 +377,54 @@ def ssm_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state=None,
                                   c_.astype(f32), d, h0)
     y = y.astype(cd) * jax.nn.silu(z)
     out = y @ p["out_kernel"].astype(cd)
-    if counts is None:
-        new_tail = u_pad[:, s:]
+    return out, (_last_inputs(u_pad, s, counts, k), h_new)
+
+
+def _ssm2_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state,
+                  counts, update):
+    """ssm_forward for a Mamba-2 mixer: the same arguments and results, the
+    tail [B, k-1, E + 2N] (the convolution runs over x, B and C)."""
+    bsz, s, hidden = x.shape
+    e, n, k = dims.expand * hidden, dims.state_dim, dims.conv_kernel
+    f32 = jnp.float32
+    cd = cfg.compute_dtype
+    z, raw, dt = jnp.split(x.astype(cd) @ p["in_kernel"].astype(cd),
+                           [e, 2 * e + 2 * n], axis=-1)
+    tail, h0 = state if state is not None else (None, None)
+    padded, xbc = _causal_conv(raw, tail, p, k)
+    u, b_, c_ = jnp.split(xbc.astype(cd), [e, e + n], axis=-1)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    if counts is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < counts[:, None, None],
+                       dt, 0.0)
+    a = -jnp.exp(p["A_log"].astype(f32))
+    d = p["D"].astype(f32)
+    if s == 1 and h0 is not None:
+        # One scalar a head, broadcast over the head's columns, makes the
+        # step Mamba-1's: a_t one row [1, E] for all N.
+        def wide(t):
+            return jnp.repeat(t, e // dims.heads, axis=-1)
+
+        y, h_new = update(h0, wide(dt[:, 0]), u[:, 0].astype(f32),
+                          b_[:, 0].astype(f32), c_[:, 0].astype(f32),
+                          wide(a)[None], wide(d))
+        y = y[:, None]
     else:
-        new_tail = jnp.take_along_axis(
-            u_pad, (counts[:, None] + jnp.arange(k - 1)[None, :])[..., None],
-            axis=1)
-    return out, (new_tail, h_new)
+        with jax.named_scope("ssd_chunk"):
+            y, h_new = ssd_chunked(u, dt, a, b_, c_, d, dims.chunk, h0)
+    with jax.named_scope("gated_norm"):
+        y = rms_norm(y.astype(f32) * jax.nn.silu(z.astype(f32)),
+                     p["norm_scale"], cfg.layernorm_epsilon).astype(cd)
+    out = y @ p["out_kernel"].astype(cd)
+    return out, (_last_inputs(padded, s, counts, k), h_new)
 
 
 def ssm_paged_forward(p, x, cfg: TransformerConfig, state, rows=None,
                       starts=None, counts=None, active=None):
     """The mixer inside a paged serving step (inference/dynamic_engine.py).
 
-    state = (ssm [L, slots, N, E] f32, conv [L, slots, (k-1) * E], index):
+    state = (ssm [L, slots, N, E] f32, conv [L, slots, (k-1) * C], index; C
+    the convolution's channels, Mamba-1's E or Mamba-2's E + 2N):
     the engine's stacked state pools and this layer's plane of them (a
     slot's k-1 convolution inputs side by side in one row, so that the two
     minor dims tile whole and a slot's tail is one row to slice). x's
@@ -260,8 +446,9 @@ def ssm_paged_forward(p, x, cfg: TransformerConfig, state, rows=None,
     if active is None:
         active = jnp.ones((bsz,), bool)
     zero = jnp.int32(0)
-    e = ssm.shape[3]
-    taps = conv.shape[2] // e
+    # the tail's columns: Mamba-1's E, Mamba-2's E + 2N
+    taps = dims.conv_kernel - 1
+    e = conv.shape[2] // taps
     if counts is None:
         if rows is not None or bsz != ssm.shape[1] or x.shape[1] != 1:
             raise ValueError("a decode round advances every slot by one "

@@ -997,6 +997,78 @@ def test_engine_state_steps_at_cell_shapes(one_chip, chip_compile, which):
         compiled, cfg.head_dim, abstract["block"]["mixers_attn"]["attention"])
 
 
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_ssd_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
+    """The two jits at granite-4.0-h-small's published widths and the rag
+    cell's sizes (its cut: layers 0-9, experts 0-35 of 72, half the
+    vocabulary): 64 slots of h [128, 8192] float32 a Mamba-2 layer, one
+    attention layer of 8 key/value heads of 128, a prefill call of the 512
+    positions the engine chooses for the cell on this chip (two chunks of
+    256). Mosaic takes `ssm_update` with E tiled (a [128, 2048] block a grid
+    step: the whole plane, 4 MiB, would not fit beside its copy), the
+    grouped GEMMs of the held experts and the paged kernels; the step
+    aliases the page pools and the state pools alike, copies nothing of the
+    state pools' shape or one plane's, and a prefill call holds one chunk's
+    decays, not a call's."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    batch, blocks, seq = 64, 16384, 7168
+    cut = dict(num_layers=10, moe_experts_held=(0, 36), vocab_size=50176,
+               vocab_slice_of=100352)
+    width = _cell_prefill_width(one_chip, "granite-4.0-h-small", seq, **cut)
+    assert width == 512
+    cfg = PRESETS["granite-4.0-h-small"](params_dtype=jnp.bfloat16, **cut)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree.leaves(abstract)) == 4_757_211_776
+    # two slots give the jits; the cell's 64 go in as abstract pools
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=2, max_seq_len=seq,
+                                 paged=True, num_blocks=8,
+                                 prefill_chunk=width)
+    ssm, conv = ((p.shape[0], batch) + p.shape[2:] for p in eng.pool.state)
+    assert ssm == (9, 64, 128, 8192) and conv == (9, 64, 3 * 8448)
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + (_sds(ssm, jnp.float32, one_chip),
+           _sds(conv, jnp.bfloat16, one_chip))
+    assert pools[0].shape == (1, blocks, 16, 8, 128)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None).compile()
+        _assert_kernels_named(compiled, "paged_decode", "ssm_update",
+                              "grouped_gemm")
+    else:
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1),
+            i32(1)).compile()
+        _assert_kernels_named(compiled, "paged_mq", "grouped_gemm")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    assert not _pool_shaped(compiled,
+                            r"copy|transpose|(?<!update[_-])slice",
+                            [ssm, ssm[1:], (1,) + ssm[1:]])
+    # less than one layer's states (268 MB): a chunk's decays
+    # [128, 256, 256] float32 are 33.5 MB, with the scores times them in
+    # bf16 and the call's projections 120 MB; Mamba-1's scan block at these
+    # sizes, [1, 64, 128, 8192] float32 an operand, would be 268 MB each
+    assert mem.temp_size_in_bytes < ssm[1] * ssm[2] * ssm[3] * 4
+    # weights 9.51 GB + state 2.45 GB + pages 1.07 GB and the step's own
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 13.0e9 < total < 13.5e9
+
+
 # The assist cell's cut of LFM2-24B-A2B: published layers 1..9
 # (perfbench/configs/lfm2-24b-a2b.json).
 LFM2_CUT = {"num_layers": 9, "attn_layer_offset": 1, "moe_first_k_dense": 1}

@@ -192,6 +192,7 @@ def _run_with(monkeypatch, capsys, server_ok):
                         lambda tiny: _phase("conv"))
     monkeypatch.setattr(chip_smoke, "phase_window",
                         lambda tiny: _phase("window"))
+    monkeypatch.setattr(chip_smoke, "phase_ssd", lambda tiny: _phase("ssd"))
     rc = chip_smoke.main([])
     return rc, capsys.readouterr().out.strip().splitlines()
 
@@ -204,7 +205,7 @@ def test_run_prints_exactly_the_contract_line_last(monkeypatch, capsys):
               if ln.startswith("phase: ")]
     assert [p["phase"] for p in phases] == ["train-auto", "train-reference",
                                             "server", "hybrid", "eva",
-                                            "share", "conv", "window"]
+                                            "share", "conv", "window", "ssd"]
 
 
 def _window_lines(device=TPU, **kw):
@@ -281,6 +282,38 @@ def test_check_hybrid(kw, needle):
     assert out["ok"] is (needle is None), out["problems"]
     assert needle is None or needle in " | ".join(out["problems"])
     assert not chip_smoke.check_hybrid(1, _hybrid_lines())["ok"]
+
+
+def _ssd_lines(device=TPU, **kw):
+    res = {"tokens": 155, "in_vocab": True, "ssm_update_calls": 2,
+           "e_tiles": 2, "pool_bytes": 100, "alias_bytes": 128,
+           "state": {"mixer": "mamba2", "layers": 6, "resets": 3,
+                     "conv_channels": 512},
+           "moe": {"tokens": 33, "assignments": 792, "assignments_here": 363,
+                   "assignments_absent": 429}, **kw}
+    return [chip_smoke.DEVICE_LINE_PREFIX + json.dumps(device),
+            chip_smoke.RESULT_PREFIX + json.dumps(res)]
+
+
+@pytest.mark.parametrize("kw,needle", [
+    ({}, None),
+    ({"ssm_update_calls": 6}, "not one a layer loop"),
+    ({"e_tiles": 1}, "tiles of E"),
+    ({"alias_bytes": 64}, "a pool is copied"),
+    ({"tokens": 150}, "tokens came back"),
+    ({"state": {"mixer": "mamba1", "layers": 6, "resets": 3,
+                "conv_channels": 256}}, "state counters"),
+    ({"moe": {"tokens": 33, "assignments": 792, "assignments_here": 792,
+              "assignments_absent": 0}}, "do not add up"),
+])
+def test_check_ssd(kw, needle):
+    """The ssd phase's facts: one ssm_update a scanned run of Mamba-2
+    layers, a plane in tiles of E, the state pools aliased in and out, every
+    token back, every pick held or absent."""
+    out = chip_smoke.check_ssd(0, _ssd_lines(**kw))
+    assert out["ok"] is (needle is None), out["problems"]
+    assert needle is None or needle in " | ".join(out["problems"])
+    assert not chip_smoke.check_ssd(1, _ssd_lines())["ok"]
 
 
 def _eva_lines(device=TPU, **kw):
@@ -454,7 +487,7 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
     assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
                                             "server", "hybrid", "eva",
-                                            "share", "conv", "window"]
+                                            "share", "conv", "window", "ssd"]
     for p in phases:        # every phase's own checks passed ...
         assert p["ok"], (p["phase"], p["problems"])
     train = phases[1]
@@ -494,7 +527,12 @@ def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
     assert window["moe"]["assignments"] == window["moe"]["tokens"] * 2 * 4
     assert window["alias_bytes"] >= window["pool_bytes"] > 0
     assert 5.5 < window["train_loss"] < 7.5 and window["train_grads_finite"]
-    assert sum("not a TPU" in ln for ln in lines) == 8
+    ssd = phases[8]
+    assert ssd["state"]["mixer"] == "mamba2" and ssd["e_tiles"] == 2
+    assert ssd["moe"]["assignments_here"] + ssd["moe"][
+        "assignments_absent"] == ssd["moe"]["tokens"] * 3 * 8
+    assert ssd["alias_bytes"] >= ssd["pool_bytes"] > 0
+    assert sum("not a TPU" in ln for ln in lines) == 9
 
 
 def test_four_chip_option_on_four_virtual_devices(tmp_path):

@@ -390,8 +390,14 @@ class TestRefusals:
         )
         with pytest.raises(ValueError, match="attn_layer_offset"):
             TransformerConfig(attn_layer_period=4, attn_layer_offset=4)
-        with pytest.raises(ValueError, match="no MoE"):
-            TransformerConfig(attn_layer_period=4, num_moe_experts=4)
+        # (a state-space stack may run MoE feed-forwards since ISSUE 52:
+        # tests/test_granite.py; what a hybrid stack's experts still
+        # refuse is a frequency other than every layer)
+        assert TransformerConfig(attn_layer_period=4,
+                                 num_moe_experts=4).num_ssm_layers == 1
+        with pytest.raises(ValueError, match="moe_layer_freq"):
+            TransformerConfig(attn_layer_period=4, num_moe_experts=4,
+                              moe_layer_freq=2)
 
 
 class TestKernel:

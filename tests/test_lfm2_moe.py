@@ -295,7 +295,10 @@ class TestRefusals:
     @pytest.mark.parametrize("kw,word", [
         ({"shortconv_kernel": 3}, "hybrid stack"),
         ({"shortconv_kernel": 1, "attn_layer_period": 4}, "at least 2"),
-        ({"attn_layer_period": 4, "num_moe_experts": 4}, "no MoE"),
+        # (a state-space stack runs MoE feed-forwards since ISSUE 52:
+        # tests/test_granite.py; every layer's, like the other stacks')
+        ({"attn_layer_period": 4, "num_moe_experts": 4,
+          "moe_layer_freq": 2}, "every layer"),
         ({"attn_layer_period": 4, "shortconv_kernel": 3,
           "multi_latent_attention": True}, "no MLA"),
         # (an aux loss and a share of the experts go through the hybrid

@@ -49,8 +49,9 @@ def main():
         hybrid_fields, validate_serving_args,
     )
     add_serving_args(ap)
-    # --attn-layer-period / -offset, --ssm-inner-norms: a hybrid
-    # state-space stack on a preset, served by --engine dynamic.
+    # --attn-layer-period / -offset, --ssm-inner-norms, --ssm-heads /
+    # -head-dim / -state-dim / -chunk-size: a hybrid state-space stack on a
+    # preset (Mamba-1 or, with heads, Mamba-2), served by --engine dynamic.
     add_hybrid_args(ap)
     # --eva-window-size / --eva-chunk-size: EVA attention on a preset,
     # served by --engine dynamic.
