@@ -120,7 +120,10 @@ class StepStats(PhaseStats):
     token was dropped. ``admit_steps``: the steps that admitted a request,
     ``admitted``: the requests they admitted (a window's own are the
     ``mta.engine.prefill`` spans inside its ``mta.engine.step`` spans:
-    perfbench/admission_spans.py)."""
+    perfbench/admission_spans.py); ``first_samples_ahead``: those of them
+    whose first token was read after the next round's dispatch (ISSUE 53:
+    the ``mta.engine.prefill.sample`` spans with ``ahead`` = 1), so that the
+    chip did not stand while the host fetched it."""
 
     PHASES = ("step", "admit", "prefill", "prefill_call", "prefill.sample",
               "capacity", "decode_round", "decode.stage",
@@ -136,6 +139,7 @@ class StepStats(PhaseStats):
         self.overrun_rows = 0
         self.admit_steps = 0
         self.admitted = 0
+        self.first_samples_ahead = 0
 
     def totals(self) -> List[float]:
         return [row[1] for row in self.phases.values()]
@@ -161,6 +165,7 @@ class StepStats(PhaseStats):
                     overrun_rows=self.overrun_rows,
                     admit_steps=self.admit_steps,
                     admitted=self.admitted,
+                    first_samples_ahead=self.first_samples_ahead,
                     slowest=[dict(r, phases=dict(r["phases"]))
                              for r in self.slowest])
 
@@ -677,7 +682,9 @@ def _sample_round(logits, seeds, rids, steps, temps, top_ks, top_ps,
     round after it without a trip through the host: row b is the token
     just sampled, or host_tokens[b] where from_host[b] says the host knows
     better (a slot a prefill has filled since, a row that does not run).
-    A prefill's one-row call passes no host_tokens and gets None."""
+    An admission's one-row call ([1, V] logits, `from_host` false in its
+    slot alone) gets `host_tokens` back with the token just sampled in that
+    slot's row: the first token reaches the next round on the device too."""
     toks = _sample_batched(logits, seeds, rids, steps, temps, top_ks,
                            top_ps, greedys)
     nxt = None
@@ -995,6 +1002,15 @@ class DynamicInferenceEngine:
         # know nothing of it until its tokens are read: between two steps
         # they say what they said when every round was read in its step.
         self._round: Optional[_Round] = None
+        # An admitted request's first token that is sampled and not read
+        # (ISSUE 53): slot -> (request, the [1] token on the device), in
+        # the order of admission, and `last_tokens` with those tokens in
+        # their rows, on the device: the token operand of a round that is
+        # dispatched before they are read (_sample, _host_tokens). Both
+        # live inside one step: every step reads what it admitted (one
+        # that raises in a later admission leaves them to the next).
+        self._first: Dict[int, Tuple[Request, jnp.ndarray]] = {}
+        self._first_tokens: Optional[jnp.ndarray] = None
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.waiting: deque = deque()
         self.requests: Dict[int, Request] = {}
@@ -1484,8 +1500,11 @@ class DynamicInferenceEngine:
         # interval" and poison the histogram's tail.
         self._last_round_t = None
         # The round in flight is not read (the device may be what failed):
-        # its tokens are lost with the requests.
+        # its tokens are lost with the requests, and so is a first token
+        # that was not read.
         self._round = None
+        self._first.clear()
+        self._first_tokens = None
         for req in list(self.waiting):
             self.requests.pop(req.request_id, None)
             self._rt.finish(req.request_id, "abort")
@@ -1514,8 +1533,11 @@ class DynamicInferenceEngine:
         caller (release semantics differ per path). A slot's recurrent
         state needs nothing: the next sequence's first prefill call starts
         from zeros whatever the slot holds. The slot's row of the round in
-        flight, if it has one, is an over-run from here on."""
+        flight, if it has one, is an over-run from here on, and a first
+        token of its request that is sampled and not read is forgotten:
+        the request samples it again, the same, where it resumes."""
         self._drop_row(slot)
+        self._forget_first(slot)
         self.slots[slot] = None
         self.lengths[slot] = 0
         self._h_valid[slot] = False
@@ -1604,7 +1626,8 @@ class DynamicInferenceEngine:
             # here; release_exported drops the spill entry on commit.
             return dict(self._parked[rid])
         if (req is None or req.finished or req.slot < 0
-                or self.slots[req.slot] is not req or not req.generated):
+                or self.slots[req.slot] is not req or not req.generated
+                or self._first_owed(req)):
             return None
         # The session leaves as of its last token read, which is what
         # `lengths` counts; its row of the round in flight goes when the
@@ -1700,7 +1723,10 @@ class DynamicInferenceEngine:
         (tools/loadgen.py long-idle phases): the session stays parked
         until resume_request, excluded from the auto-unpark pass."""
         if (self.spill is None or req.finished or req.slot < 0
-                or self.slots[req.slot] is not req or not req.generated):
+                or self.slots[req.slot] is not req or not req.generated
+                or self._first_owed(req)):
+            # (a first token sampled and not read: still mid-admission,
+            # the cache holds a row more than `tokens[:-1]`)
             return False
         rid = req.request_id
         slot = req.slot
@@ -1931,7 +1957,6 @@ class DynamicInferenceEngine:
             req.slot = slot
             self.slots[slot] = req
             rid = req.request_id
-            first_life = not req.generated   # vs resumed after preempt
             self._rt.end("queue-wait", rid)
             # One measurement feeds /stats and /metrics alike.
             waited = time.monotonic() - req.queued_t
@@ -1944,31 +1969,28 @@ class DynamicInferenceEngine:
                                 cached_tokens=plan.cached_tokens):
                     self._prefill_into_slot(req, plan)
             except Exception:
-                # Exception-safe rollback (the "kv-quant-write" chaos
-                # drill fires between quantize and page-table commit in
-                # the chunk-scatter path): return every admitted block
-                # (valid_len=0 — partially-written rows are stale data
-                # the retry overwrites, never registered prefixes),
-                # clear the slot, and requeue the request at the head so
-                # a transient fault costs one step. Re-raised for the
-                # stepper watchdog's accounting.
-                self.pool.release(slot, np.asarray(req.tokens), 0)
-                self._free_slot(slot)
-                req.slot = -1
-                req.queued_t = time.monotonic()
-                self.waiting.appendleft(req)
-                self._rt.begin("queue-wait", rid)   # requeued at the head
+                # The "kv-quant-write" chaos drill fires between quantize
+                # and page-table commit in the chunk-scatter path.
+                # Re-raised for the stepper watchdog's accounting.
+                self._unadmit(req)
                 raise
-            if first_life:
-                # TTFT is a first-token metric: a preempted request's
-                # resume prefill emits its Nth token, not its first —
-                # re-observing would inflate the percentiles the fleet
-                # router scores replicas by.
-                telemetry.observe("serving_ttft_ms",
-                                  (time.monotonic() - req.admit_t) * 1e3)
-            self._rt.begin("decode", rid)
             admitted.append(req)
         return admitted
+
+    def _unadmit(self, req: Request):
+        """Exception-safe rollback of an admission whose prefill, or the
+        fetch of whose first token, raised: return every admitted block
+        (valid_len=0 — partially-written rows are stale data the retry
+        overwrites, never registered prefixes), clear the slot (its row of
+        the round ahead goes with it, _free_slot), and requeue the request
+        at the head so a transient fault costs one step."""
+        slot = req.slot
+        self.pool.release(slot, np.asarray(req.tokens), 0)
+        self._free_slot(slot)
+        req.slot = -1
+        req.queued_t = time.monotonic()
+        self.waiting.appendleft(req)
+        self._rt.begin("queue-wait", req.request_id)
 
     def _prefill_into_slot(self, req: Request, plan):
         # req.tokens (prompt + any pre-preemption generated tokens): a
@@ -1985,14 +2007,17 @@ class DynamicInferenceEngine:
         # quantize inside the same _mq_step jit).
         logits_last = self._paged_prefill_chunked(req, tokens, p_len, plan)
         self.lengths[req.slot] = p_len
-        # First generated token comes from the last PROMPT position.
+        # First generated token comes from the last PROMPT position. It
+        # is sampled on the device and stays there: the step dispatches
+        # the next round behind the prompt's calls, that token its row's
+        # operand, before it fetches it (_plain_round, _read_first).
         logits_last = mask_padded_vocab(logits_last, self.cfg)
-        # The host stands here until the device has run the round in
-        # flight and every call of the prompt.
-        with self._span("engine.prefill.sample", req.request_id):
-            tok = self._sample(logits_last[None], req)
-        self._record_token(req, int(tok[0]))
-        if self.proposer is not None:
+        self._first[req.slot] = (req, self._sample(logits_last[None], req))
+        if self.spec_method:
+            # A speculative round proposes from the host's tokens: the
+            # host stands here until the device has run every call of the
+            # prompt.
+            self._take_first(req.slot)
             self.proposer.on_admit(req.slot, req)
 
     def _paged_prefill_chunked(self, req: Request, tokens, p_len: int,
@@ -2089,13 +2114,18 @@ class DynamicInferenceEngine:
             self._h_valid[slot] = True
         return logits[0, 0]
 
-    def _sample(self, logits, req: Request):
-        """Single-row sampling (prefill). Same fold_in key chain as the
-        batched decode sampler, so a request's sample stream is
-        reproducible and independent of batch composition."""
+    def _sample(self, logits, req: Request) -> jnp.ndarray:
+        """Single-row sampling (prefill), dispatched and not fetched: the
+        [1] token stays on the device, and the same call puts it into its
+        slot's row of the next round's token operand (`_first_tokens`:
+        the program the one-row sampler always was, with one `where` more,
+        so an admission under a round in flight compiles nothing). Same
+        fold_in key chain as the batched decode sampler, so a request's
+        sample stream is reproducible and independent of batch
+        composition."""
         s = req.sampling
         self._count_sample("prefills", s.greedy, s.top_k, s.top_p)
-        tok, _ = self._sample_b(
+        tok, self._first_tokens = self._sample_b(
             logits,
             jnp.asarray([s.seed], jnp.int32),
             jnp.asarray([req.request_id], jnp.int32),
@@ -2103,8 +2133,9 @@ class DynamicInferenceEngine:
             jnp.asarray([s.temperature], jnp.float32),
             jnp.asarray([s.top_k], jnp.int32),
             jnp.asarray([s.top_p], jnp.float32),
-            jnp.asarray([s.greedy], bool))
-        return jax.device_get(tok)
+            jnp.asarray([s.greedy], bool), None, self._host_tokens(),
+            jnp.asarray(np.arange(self.max_batch) != req.slot))
+        return tok
 
     def _count_sample(self, site: str, greedys, top_ks, top_ps):
         """One call of the sampler into sampler_stats, by the predicates
@@ -2127,8 +2158,9 @@ class DynamicInferenceEngine:
         proposer — one place to thread a future sampling field through.
         held: a round's {slot: request} where that is not who holds the
         slots now (a round read a step after its dispatch). `steps` is
-        the request's tokens so far: the round that is read is the oldest
-        unread one, so the token it samples is the next of the chain."""
+        the request's tokens so far (a first token that is sampled and not
+        read counts): the round that is read is the oldest unread one, so
+        the token it samples is the next of the chain."""
         b = self.max_batch
         rows = {"seeds": np.zeros(b, np.int32),
                 "rids": np.zeros(b, np.int32),
@@ -2143,7 +2175,7 @@ class DynamicInferenceEngine:
                 continue
             s = r.sampling
             rows["seeds"][i], rows["rids"][i] = s.seed, r.request_id
-            rows["steps"][i] = len(r.generated)
+            rows["steps"][i] = len(r.generated) + self._first_owed(r)
             rows["temps"][i], rows["top_ks"][i] = s.temperature, s.top_k
             rows["top_ps"][i], rows["greedys"][i] = s.top_p, s.greedy
         return rows
@@ -2151,10 +2183,64 @@ class DynamicInferenceEngine:
     def _host_tokens(self) -> jnp.ndarray:
         """The host's `last_tokens` as a step's operand: a copy
         (_handed_over says why), committed where the sampler's own token
-        operand is (_tokens_sharding)."""
+        operand is (_tokens_sharding); with the first tokens that are
+        sampled and not read in their rows, where there are any (_sample
+        put them there on the device)."""
+        if self._first_tokens is not None:
+            return self._first_tokens
         # manual-ok: a host array staged for a step, no manual region
         return jax.device_put(np.array(self.last_tokens),
                               self._tokens_sharding)
+
+    def _first_owed(self, req: Request) -> bool:
+        """Whether `req`'s first token (of this admission) is sampled and
+        not read."""
+        return self._first.get(req.slot, (None,))[0] is req
+
+    def _forget_first(self, slot: int):
+        """`slot`'s first token is read, or will not be."""
+        self._first.pop(slot, None)
+        if not self._first:
+            self._first_tokens = None
+
+    def _take_first(self, slot: int, ahead: int = 0) -> Tuple[int, int]:
+        """Fetch and record the first token of the request admitted into
+        `slot` -> (request id, token). ahead: 1 where the next round was
+        dispatched since, that token its row's operand; the chip then runs
+        that round while the host stands here, else it stands too."""
+        req, tok = self._first[slot]
+        rid = req.request_id
+        with self._span("engine.prefill.sample", rid, ahead=ahead):
+            tok = int(jax.device_get(tok)[0])
+        self._forget_first(slot)
+        self.step_stats.first_samples_ahead += ahead
+        first_life = not req.generated   # vs resumed after preempt
+        self._record_token(req, tok)
+        if first_life:
+            # TTFT is a first-token metric: a preempted request's
+            # resume prefill emits its Nth token, not its first —
+            # re-observing would inflate the percentiles the fleet
+            # router scores replicas by.
+            telemetry.observe("serving_ttft_ms",
+                              (time.monotonic() - req.admit_t) * 1e3)
+        self._rt.begin("decode", rid)
+        if req.finished:
+            self._drop_row(slot)
+        return rid, tok
+
+    def _read_first(self, out: List[Tuple[int, int]], ahead: int = 0):
+        """Fetch and record every first token that is sampled and not
+        read, in the order of admission, (request id, token) onto `out`. A
+        fetch that raises rolls back the admissions not read yet, as a
+        failed prefill is (_unadmit), the last first so that the queue
+        keeps its order."""
+        try:
+            while self._first:
+                out.append(self._take_first(next(iter(self._first)), ahead))
+        except Exception:
+            for req, _ in reversed(list(self._first.values())):
+                self._unadmit(req)
+            raise
 
     def _sample_round(self, rnd: _Round,
                       then: Optional[Dict[int, Request]] = None):
@@ -2235,7 +2321,8 @@ class DynamicInferenceEngine:
         the needy request preempts ITSELF when it is the lowest."""
         preempted: List[Request] = []
         runners = sorted(
-            (r for r in self.slots if r is not None and not r.finished),
+            (r for r in self.slots if r is not None
+             and self._goes_on(r, None)),
             key=lambda r: (r.priority, r.request_id))
         for req in runners:
             if req.slot < 0:
@@ -2305,9 +2392,11 @@ class DynamicInferenceEngine:
             self._no_repark.clear()
             self._spill_policy()
         admitted = self._admit()
+        # (first tokens read at their admission: a speculative engine's;
+        # the others join when they are read, _read_first)
         events = {"admitted": [r.request_id for r in admitted],
                   "tokens": [(r.request_id, r.generated[-1])
-                             for r in admitted],
+                             for r in admitted if not self._first_owed(r)],
                   "finished": [], "preempted": [], "expired": expired}
 
         # With a round in flight the step to cover is the one after it,
@@ -2319,8 +2408,10 @@ class DynamicInferenceEngine:
                          else self._ensure_decode_capacity())
         events["preempted"] = [r.request_id for r in preempted]
 
+        # (a request whose unread first token is its last by count runs in
+        # no round)
         active = [r for r in self.slots
-                  if r is not None and not r.finished]
+                  if r is not None and self._goes_on(r, None)]
         batch = self._round.batch if self._round else len(active)
         if batch:
             # Token-interval telemetry: back-to-back decode rounds only
@@ -2338,6 +2429,8 @@ class DynamicInferenceEngine:
             self._last_round_t = time.monotonic()
         else:
             self._last_round_t = None
+        # No round ran, or none that read them (a speculative one).
+        self._read_first(events["tokens"])
 
         with self._span("engine.retire"):
             retired = self._retire()
@@ -2373,6 +2466,25 @@ class DynamicInferenceEngine:
     # round has read them; an admission's calls queue behind it likewise.
     # Speculative rounds propose from the host's tokens and stay as they
     # were (_spec_round).
+    # An admission is no exception (ISSUE 53): the prompt's calls and the
+    # one-row sampler are dispatched and nothing is fetched (`_first`, the
+    # pending first tokens; every request a step admits, so their calls
+    # queue back to back), the round after the one in flight takes the
+    # admitted row's token on the device (_host_tokens: the one-row sampler
+    # wrote it into `last_tokens`' copy there), and only then does the step
+    # fetch it, under `prefill.sample` with `ahead` = 1, beside the read of
+    # the round in flight (_read_first; the first token goes out before any
+    # later one of its request). To `_goes_on` and to the sampler's `steps`
+    # a pending first token is a token owed, like a row of the unread round
+    # (`_first_owed`; `_owed` itself is about positions, and the prompt's
+    # rows are in `lengths` since the prefill). A first token that ends its
+    # request late makes an over-run of the row staged with it; a fetch
+    # that raises rolls the admission back (_unadmit); whatever takes the
+    # request out of its slot before the fetch forgets the token
+    # (_free_slot), and parking or exporting refuses such a request.
+    # A step that dispatches no round (nothing goes on, or the pool does
+    # not cover the next rows) reads first tokens with `ahead` = 0, as a
+    # speculative engine does at the admission itself.
     @staticmethod
     def _owed(req: Request, rnd: Optional[_Round]) -> bool:
         """Whether `rnd`, a round that is dispatched and not read (None:
@@ -2381,9 +2493,11 @@ class DynamicInferenceEngine:
 
     def _goes_on(self, req: Request, rnd: Optional[_Round]) -> bool:
         """Whether `req` runs in the round after `rnd`: it has not ended,
-        and the token `rnd` owes it is not its last by count."""
+        and the token `rnd` owes it, or its first one where that is not
+        read yet, is not its last by count."""
         return (not req.finished and len(req.generated)
-                + self._owed(req, rnd) < req.max_new_tokens)
+                + self._owed(req, rnd) + self._first_owed(req)
+                < req.max_new_tokens)
 
     def _lengths_after(self, after: Optional[_Round]) -> np.ndarray:
         """The slots' lengths as the step after `after` sees them (a copy):
@@ -2462,14 +2576,16 @@ class DynamicInferenceEngine:
         it does not, nothing runs ahead: this round is read, and the next
         step starts afresh with no round in flight, preempting or parking
         as the loop that never ran ahead would, for the same victim at
-        the same token."""
+        the same token. The first tokens of the requests this step
+        admitted are read behind those dispatches, before the round's."""
         cur, self._round = self._round, None
         if cur is None:
             cur = self._new_round({r.slot: r for r in active})
         self.step_stats.rounds_ahead += cur.ahead
         with self._span("engine.decode_round", ring="decode-step",
                         batch=cur.batch, ahead=cur.ahead, **cur.attrs):
-            if cur.logits is None:
+            sent = cur.logits is None
+            if sent:
                 self._dispatch(cur)
             with self._span("engine.capacity"):
                 then = sorted((r for r in active if self._goes_on(r, cur)),
@@ -2479,7 +2595,9 @@ class DynamicInferenceEngine:
                 self._round = self._new_round({r.slot: r for r in then},
                                               after=cur)
                 self._dispatch(self._round, after=cur)
+                sent = True
             try:
+                self._read_first(events["tokens"], ahead=int(sent))
                 self._read(cur, events["tokens"])
             except Exception:
                 # Its tokens never came: the round ahead ran on them and
@@ -2491,10 +2609,13 @@ class DynamicInferenceEngine:
     def _plain_round_inner(self, active: List[Request], events: Dict):
         """A round dispatched and read at once, inside its caller's span:
         what a speculative round falls back to when nothing was
-        proposed."""
-        rnd = _Round({r.slot: r for r in active}, {})
-        self._dispatch(rnd)
-        self._read(rnd, events["tokens"])
+        proposed. It runs on the host's tokens: first tokens not read yet
+        (none on a speculative engine) are read before it."""
+        self._read_first(events["tokens"])
+        rnd = _Round({r.slot: r for r in active if not r.finished}, {})
+        if rnd.rows:
+            self._dispatch(rnd)
+            self._read(rnd, events["tokens"])
 
     def _dispatch(self, rnd: _Round, after: Optional[_Round] = None):
         """Stage and dispatch `rnd`. Its tokens are the host's

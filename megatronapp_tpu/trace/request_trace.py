@@ -57,18 +57,30 @@ and layers: training/train.py).
 ``mta.engine.decode_round`` inside it, and ``perfbench/admission_spans.py``
 splits the first chip's idle by the first (``admit_gap_ms_step``,
 ``round_gap_ms_round``). ``stats_snapshot()["steps"]`` counts
-``admit_steps`` (steps that admitted) and ``admitted`` (ISSUE 50), for
-``/stats``.
+``admit_steps`` (steps that admitted), ``admitted`` (ISSUE 50) and
+``first_samples_ahead`` (ISSUE 53, below), for ``/stats``.
 
 An admission is ``mta.engine.admit`` > ``mta.engine.prefill`` (``rid``,
 ``prompt_tokens``, ``cached_tokens``: the ring's ``prefill`` record) >
 ``mta.engine.prefill_call`` (``tokens``, ``width``, a tenant's
 ``summaries`` / ``window_blocks``; read by ``prefill_call_host_ms``,
-``prefill_call_device_ms``, ``prefill_fill_share``), then
-``mta.engine.prefill.sample`` (``rid``): the request's first sample, from
-the sampler's dispatch to the ``device_get`` of its token, where the host
-stands until the device has run the round in flight and every call of the
-prompt (``first_sample_wait_ms``).
+``prefill_call_device_ms``, ``prefill_fill_share``). The request's first
+token is sampled behind the prompt's last call, inside ``prefill``, and
+stays on the device; ``mta.engine.prefill.sample`` (``rid``, ``ahead``) is
+the ``device_get`` of it, where the host stands until the device has run
+the round in flight and every call of the prompt
+(``first_sample_wait_ms``). Since ISSUE 53 that span lies in the same step
+but no longer inside ``prefill``: it lies inside the step's
+``decode_round``, behind the ``decode.stage`` of the round ahead and
+before ``decode.wait``, one span after the other for the requests the step
+admitted, and ``ahead`` = 1 says that a round was dispatched between the
+sample and its fetch with that token as its row's operand, so that the chip
+runs that round while the host stands (``first_samples_ahead`` counts
+them). ``ahead`` = 0: the step dispatched no round (nothing goes on, or the
+pool does not cover the next rows: the span lies where it would, or
+straight inside ``step`` where no round is read either, and the chip
+stands under it), or the engine speculates, whose proposer reads the host's
+tokens (the span then lies inside ``prefill``, where it always was).
 
 ``mta.engine.decode_round`` is one span a round, opened when the round's
 tokens are read, with the attributes of its dispatch (``batch``,

@@ -7,7 +7,11 @@ hybrid, sliding-window and MoE tenants.
 The reference of a stream is the static engine (greedy), the same request
 run alone (sampled: the fold_in chain makes a stream independent of its
 batch), or `_serial`: the same engine with every round dispatched and read
-at once, the loop that never runs ahead."""
+at once, the loop that never runs ahead.
+
+Section (g), ISSUE 53: an admitted request's first token is sampled on the
+device and read after the next round's dispatch, the round taking it as its
+row's operand there."""
 import time
 
 import numpy as np
@@ -296,9 +300,9 @@ def test_a_migrated_session_leaves_as_of_its_last_token_read(tiny):
     dst.pool.audit()
 
 
-# ---- (d) the order of the host's work ---------------------------------------
-def test_next_round_is_dispatched_before_the_fetch(tiny, monkeypatch):
-    eng = _engine(tiny)
+def _listen_for_compiles():
+    """-> (the compile events so far, a function that stops listening): what
+    perfbench/common.CompileCounter counts."""
     compiles = []
 
     def on_compile(event, secs, **_):
@@ -306,6 +310,20 @@ def test_next_round_is_dispatched_before_the_fetch(tiny, monkeypatch):
             compiles.append(event)
 
     jax.monitoring.register_event_duration_secs_listener(on_compile)
+
+    def stop():
+        unregister = getattr(
+            jax.monitoring,
+            "_unregister_event_duration_listener_by_callback", None)
+        if unregister is not None:
+            unregister(on_compile)
+    return compiles, stop
+
+
+# ---- (d) the order of the host's work ---------------------------------------
+def test_next_round_is_dispatched_before_the_fetch(tiny, monkeypatch):
+    eng = _engine(tiny)
+    compiles, stop = _listen_for_compiles()
     try:
         # a lone request's first four tokens meet every program there is
         _drive(eng, {0: [dict(prompt_tokens=_prompts(1)[0], max_new_tokens=4,
@@ -329,19 +347,15 @@ def test_next_round_is_dispatched_before_the_fetch(tiny, monkeypatch):
             prompt_tokens=_prompts(1, seed=1)[0], max_new_tokens=33,
             sampling=GREEDY, request_id=5)]})
     finally:
-        unregister = getattr(
-            jax.monitoring,
-            "_unregister_event_duration_listener_by_callback", None)
-        if unregister is not None:
-            unregister(on_compile)
+        stop()
     assert len(streams[5]) == 33
     assert (eng.decode_traces, eng.mq_traces) == traces
     assert len(compiles) == warm, compiles[warm:]
-    # the prefill's first sample is a fetch too: the rounds' come after the
-    # first decode
-    log = log[log.index("decode"):]
+    # the prefill's first sample is a fetch too, behind the dispatch of the
+    # request's first two rounds (ISSUE 53) and before the first round's
+    assert log[:4] == ["decode", "decode", "get", "get"]
     dispatched = [i for i, what in enumerate(log) if what == "decode"]
-    fetched = [i for i, what in enumerate(log) if what == "get"]
+    fetched = [i for i, what in enumerate(log) if what == "get"][1:]
     assert len(dispatched) == len(fetched) == 32
     early = sum(dispatched[n + 1] < fetched[n] for n in range(31))
     assert early == 31
@@ -389,6 +403,9 @@ def test_speculative_rounds_do_not_run_ahead(tiny):
     assert seen_round and all(r is None for r in seen_round)
     st = _steps(eng)
     assert st["rounds_ahead"] == 0 and st["overrun_rows"] == 0
+    # a proposer reads the host's tokens: the first sample is fetched at the
+    # admission itself (ISSUE 53)
+    assert st["first_samples_ahead"] == 0 and st["admitted"] == 2
     static = StaticInferenceEngine(params, cfg)
     for rid, p in enumerate(prompts):
         ref = np.asarray(static.generate(p[None], 12, GREEDY))[0]
@@ -479,3 +496,197 @@ def test_moe_tenant_counts_a_round_once():
     assert moe["tokens"] * layers <= moe["expert_pairs_touched"] \
         <= moe["assignments"]
     assert _steps(ahead)["rounds_ahead"] > 0
+
+
+# ---- (g) an admission's first token is read after the next dispatch ---------
+def _first_in_its_step(seen):
+    """The step's contract for an admission: the request's first token is
+    in the events of the step that admitted it, and nothing of the request
+    came before."""
+    got = set()
+    for ev in seen:
+        toks = [rid for rid, _ in ev["tokens"]]
+        for rid in ev["admitted"]:
+            assert rid in toks and rid not in got, (rid, ev)
+        got.update(toks)
+
+
+def _sampling(kind, i):
+    return GREEDY if kind == "greedy" else SamplingParams(
+        temperature=0.9, top_k=(0, 20)[i % 2], top_p=(0.0, 0.8)[i % 2],
+        seed=200 + i)
+
+
+@pytest.mark.parametrize("kind", ["greedy", "sampled"])
+def test_admissions_under_a_round_in_flight(tiny, kind):
+    """One request runs; three more are admitted by ONE step under its
+    round in flight, two by another, one alone: every stream is that of the
+    request run alone in the loop that never runs ahead, and every first
+    sample was read behind a dispatch."""
+    prompts = _prompts(7, seed=11)
+    news = (24, 5, 9, 2, 7, 11, 3)
+    kws = [dict(prompt_tokens=p, max_new_tokens=m, sampling=_sampling(kind, i),
+                request_id=20 + i)
+           for i, (p, m) in enumerate(zip(prompts, news))]
+    eng = _engine(tiny, max_batch=4, enable_prefix_caching=False)
+    in_flight = []
+    streams, seen = _drive(
+        eng, {0: kws[:1], 3: kws[1:4], 9: kws[4:6], 14: kws[6:]},
+        act=lambda eng, k: in_flight.append(eng._round is not None))
+    _first_in_its_step(seen)
+    assert [len(ev["admitted"]) for ev in seen if ev["admitted"]] \
+        == [1, 3, 2, 1]
+    assert all(in_flight[k] for k in (3, 9, 14))
+    alone = _serial(_engine(tiny, enable_prefix_caching=False))
+    for kw in kws:
+        got, _ = _drive(alone, {0: [kw]})
+        assert streams[kw["request_id"]] == got[kw["request_id"]]
+    st = _steps(eng)
+    assert st["first_samples_ahead"] == st["admitted"] == 7
+    assert st["prefill.sample"]["count"] == 7 and st["overrun_rows"] == 0
+    assert _steps(alone)["first_samples_ahead"] == 0
+    assert eng.pool.blocks_in_use() == 0
+    eng.pool.audit()
+
+
+@pytest.mark.parametrize("how, overruns", [
+    ("eod", 1), ("abort", 1), ("expire", 1), ("count", 0)])
+def test_a_first_token_that_ends_its_request(tiny, how, overruns):
+    """A request admitted under a round in flight whose first token is its
+    last: on `eod_id` or stopped from outside between the dispatch and the
+    fetch, the row the round ahead runs for it is an over-run; by count
+    (`max_new_tokens` 1) the engine knows before the dispatch, and no row
+    runs."""
+    runner, late = _prompts(2, seed=21)
+    alone = _serial(_engine(tiny, enable_prefix_caching=False))
+    want, _ = _drive(alone, {0: [
+        dict(prompt_tokens=runner, max_new_tokens=12, sampling=GREEDY,
+             request_id=0)], 1: [
+        dict(prompt_tokens=late, max_new_tokens=6, sampling=GREEDY,
+             request_id=1)]})
+    eng = _engine(tiny, enable_prefix_caching=False)
+    kw = dict(prompt_tokens=late, sampling=GREEDY, request_id=1,
+              max_new_tokens=1 if how == "count" else 6,
+              eod_id=want[1][0] if how == "eod" else None)
+    read_first = eng._read_first
+    rows_ahead = []
+
+    def stopped_before_the_fetch(out, ahead=0):
+        if eng._first:
+            assert ahead == 1 and list(eng._first) == [1]
+            rows_ahead.append(sorted(
+                r.request_id for r in eng._round.rows.values()))
+            if how == "abort":
+                assert eng.abort_request(1) == "running"
+            elif how == "expire":
+                eng.requests[1].deadline_s = time.monotonic() - 1.0
+                assert eng.expire_overdue() == [1]
+        return read_first(out, ahead)
+
+    def act(eng, k):
+        if k == 3:
+            assert eng._round is not None
+            eng._read_first = stopped_before_the_fetch
+    streams, seen = _drive(eng, {0: [dict(
+        prompt_tokens=runner, max_new_tokens=12, sampling=GREEDY,
+        request_id=0)], 3: [kw]}, act)
+    _first_in_its_step(seen)
+    # the admitted row rides in the round ahead unless it ends by count
+    assert rows_ahead == [[0] if how == "count" else [0, 1]]
+    assert streams[0] == want[0] and streams[1] == want[1][:1]
+    assert eng.requests[1].finished
+    st = _steps(eng)
+    assert st["overrun_rows"] == overruns
+    assert st["first_samples_ahead"] == st["admitted"] == 2
+    assert any(1 in ev["finished"] for ev in seen)
+    assert eng.pool.blocks_in_use() == 0 and not eng._first
+    eng.pool.audit()
+
+
+def test_a_first_fetch_that_raises_rolls_the_admission_back(tiny,
+                                                            monkeypatch):
+    """As the `kv-quant-write` drill (tests/test_resilience.py), a step
+    later: the fetch of the first of two first tokens raises. Both
+    admissions are rolled back (blocks released, slots cleared, their rows
+    of the round ahead dropped, the requests back at the head of the queue
+    in their order), and the retry streams what a clean run streams."""
+    prompts = _prompts(3, seed=31)
+    kws = [dict(prompt_tokens=p, max_new_tokens=m, sampling=GREEDY,
+                request_id=i) for i, (p, m) in enumerate(zip(prompts,
+                                                             (14, 6, 8)))]
+    arrivals = {0: kws[:1], 3: kws[1:]}
+    clean, _ = _drive(_engine(tiny, enable_prefix_caching=False), arrivals)
+
+    eng = _engine(tiny, enable_prefix_caching=False)
+    get, armed, faults = jax.device_get, [], []
+
+    def device_get(x):
+        if armed and eng._first:
+            armed.pop()
+            raise RuntimeError("device lost")
+        return get(x)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    streams, k = {}, 0
+    arrivals = dict(arrivals)
+    while eng.has_work or arrivals:
+        for kw in arrivals.pop(k, ()):
+            eng.add_request(**kw)
+        if k == 3:
+            armed.append(True)
+            used = eng.pool.blocks_in_use()
+        try:
+            ev = eng.step()
+        except RuntimeError:
+            faults.append(k)
+            eng.pool.audit()                # rollback left no leak/skew
+            assert eng.pool.blocks_in_use() == used
+            assert [r and r.request_id for r in eng.slots] == [0, None, None]
+            assert [r.request_id for r in eng.waiting] == [1, 2]
+            assert [eng.requests[i].slot for i in (1, 2)] == [-1, -1]
+            assert eng._round is None and not eng._first
+            assert eng._first_tokens is None
+            assert not eng.requests[1].generated
+            k += 1
+            continue
+        for rid, tok in ev["tokens"]:
+            streams.setdefault(rid, []).append(int(tok))
+        k += 1
+        assert k < 200
+    assert faults == [3] and streams == clean
+    st = _steps(eng)
+    # the two rows of the round ahead, and the step's round was not read
+    assert st["overrun_rows"] == 2
+    assert st["admitted"] == 3 and st["admit_steps"] == 2
+    assert eng.pool.blocks_in_use() == 0
+    eng.pool.audit()
+
+
+def test_an_admission_under_a_round_in_flight_compiles_nothing(tiny):
+    """What perfbench/cells/serve_closed.py holds a run to: after ONE
+    warm-up request alone (two prefill calls, four tokens), a run that
+    admits under a round in flight, several requests in one step among
+    them, compiles nothing and traces neither step again. (Shapes no other
+    test of this process has: the jits' caches are the process's.)"""
+    eng = _engine(tiny, max_batch=5, max_seq_len=88, prefill_chunk=8,
+                  enable_prefix_caching=False)
+    compiles, stop = _listen_for_compiles()
+    try:
+        _drive(eng, {0: [dict(
+            prompt_tokens=(np.arange(eng.prefill_chunk + 8) % 97).astype(
+                np.int32), max_new_tokens=4, sampling=GREEDY)]})
+        warm = len(compiles)
+        traces = (eng.decode_traces, eng.mq_traces)
+        assert warm > 0 and traces == (1, 1)
+        prompts = _prompts(6, seed=41)
+        kws = [dict(prompt_tokens=p, max_new_tokens=m, sampling=GREEDY)
+               for p, m in zip(prompts, (20, 6, 1, 9, 4, 12))]
+        _, seen = _drive(eng, {0: kws[:1], 2: kws[1:4], 6: kws[4:]})
+    finally:
+        stop()
+    assert len(compiles) == warm, compiles[warm:]
+    assert (eng.decode_traces, eng.mq_traces) == traces
+    st = _steps(eng)
+    assert st["admitted"] == 7 and st["first_samples_ahead"] == 7
+    assert [len(ev["admitted"]) for ev in seen if ev["admitted"]] \
+        == [1, 3, 2]

@@ -374,13 +374,24 @@ class TestStepSpansAndCounters:
                 spec and child == "sample" and 1 <= len(kids) < len(stages))
             for kid in kids:
                 assert sum(_inside(kid, g) for g in stages) == 1
-        # The first sample of the admitted request: inside its prefill,
-        # after the prompt's one call.
+        # The first sample of the admitted request: after the prompt's
+        # one call; where a proposer reads the host's tokens, inside its
+        # prefill, else (ISSUE 53) inside the step's round, behind the stage
+        # of the round ahead and before the wait for the round in flight.
         (sample,), (prefill,) = (by_name["mta.engine.prefill.sample"],
                                  by_name["mta.engine.prefill"])
         (call,) = by_name["mta.engine.prefill_call"]
-        assert _inside(sample, prefill) and _inside(call, prefill)
+        assert _inside(call, prefill)
         assert call[1] + call[2] <= sample[1]
+        if spec:
+            assert _inside(sample, prefill)
+        else:
+            assert prefill[1] + prefill[2] <= sample[1]
+            (rnd,) = [r for r in rounds if _inside(sample, r)]
+            (stage,) = [g for g in stages if _inside(g, rnd)]
+            (wait,) = [w for w in waits if _inside(w, rnd)]
+            assert stage[1] + stage[2] <= sample[1]
+            assert sample[1] + sample[2] <= wait[1]
         # A step carries no attribute of its own: what it admitted is the
         # prefill spans inside it, what it read the round inside it (what
         # perfbench/admission_spans.py counts).
@@ -403,6 +414,7 @@ class TestStepSpansAndCounters:
         assert {k: int(v) for k, v in call[3].items()} \
             == {"tokens": 9, "width": eng.prefill_chunk}
         assert int(sample[3]["rid"]) == 1
+        assert int(sample[3]["ahead"]) == int(spec is None)
 
     def test_paged_walk_counters(self, monkeypatch):
         """ISSUE 29: a plain decode round names the blocks its paged
@@ -511,16 +523,20 @@ class TestStepSpansAndCounters:
             == 24
         assert set(st) == set(eng.step_stats.PHASES) | {
             "slowest", "rounds_ahead", "overrun_rows", "admit_steps",
-            "admitted"}
+            "admitted", "first_samples_ahead"}
         total = {p: st[p]["total_s"] for p in st
                  if isinstance(st[p], dict)}
         assert total["admit"] + total["capacity"] + total["decode_round"] \
             + total["retire"] <= total["step"]
-        assert total["decode.stage"] + total["decode.wait"] \
-            + total["decode.record"] <= total["decode_round"]
+        # (ISSUE 53: the first samples are read inside the step's round,
+        # behind its dispatches)
+        assert total["decode.stage"] + total["prefill.sample"] \
+            + total["decode.wait"] + total["decode.record"] \
+            <= total["decode_round"]
         assert total["prefill_call"] <= total["prefill"] <= total["admit"]
         assert st["admit_steps"] == len(admitting)
         assert st["admitted"] == st["prefill.sample"]["count"] == 3
+        assert st["first_samples_ahead"] == 3
         stage = ("decode.stage.sample", "decode.stage.put",
                  "decode.stage.dispatch")
         assert sum(total[p] for p in stage) <= total["decode.stage"]
@@ -530,7 +546,6 @@ class TestStepSpansAndCounters:
         # preemption) the sampler waits for the fetch.
         assert st["decode.stage.sample"]["count"] == st["rounds_ahead"] \
             < st["decode.stage"]["count"]
-        assert total["prefill.sample"] <= total["prefill"]
         for row in (st[p] for p in total):
             assert 0 <= row["max_s"] <= row["total_s"] or row["count"] == 0
         # The flight recorder: pure decode rounds only, longest first.
@@ -629,14 +644,17 @@ class TestStepSpansAndCounters:
                                       st["decode.stage.sample"]["count"])
 
     @pytest.mark.parametrize("site, closed", [
-        ("_sample", ("step", "admit", "prefill", "prefill_call",
-                     "prefill.sample")),
+        ("_sample", ("step", "admit", "prefill", "prefill_call")),
+        ("device_get", ("step", "admit", "decode_round", "prefill.sample")),
         ("_decode", ("step", "decode_round", "decode.stage",
                      "decode.stage.put", "decode.stage.dispatch"))])
-    def test_a_failure_inside_a_new_span_closes_it(self, site, closed):
+    def test_a_failure_inside_a_new_span_closes_it(self, monkeypatch, site,
+                                                   closed):
         """ISSUE 50: the first sample and the stage's children close and
         count once when the call inside them raises; the counters count
-        nothing of a step that did not finish."""
+        nothing of a step that did not finish. (ISSUE 53: the first
+        sample's dispatch lies in the prefill, its span is the fetch,
+        inside the step's round.)"""
         rt = get_request_tracer()
         rt.configure(enabled=True)
         eng = _pressure_engine()
@@ -647,7 +665,8 @@ class TestStepSpansAndCounters:
         def boom(*a, **kw):
             raise RuntimeError("device lost")
 
-        setattr(eng, site, boom)
+        monkeypatch.setattr(*((jax, site) if site == "device_get"
+                              else (eng, site)), boom)
         before = eng.stats_snapshot()["steps"]
         with pytest.raises(RuntimeError, match="device lost"):
             eng.step()
