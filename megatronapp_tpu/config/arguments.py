@@ -51,7 +51,8 @@ def add_hybrid_args(ap: argparse.ArgumentParser):
     g.add_argument("--ssm-heads", type=int, default=None,
                    help="the state-space layers are Mamba-2 mixers of this "
                         "many heads, a matrix state a head (HF "
-                        "mamba_n_heads); heads x --ssm-head-dim = 2 x hidden")
+                        "mamba_n_heads); the inner width is heads x "
+                        "--ssm-head-dim")
     g.add_argument("--ssm-head-dim", type=int, default=None,
                    help="columns of a Mamba-2 head (HF mamba_d_head)")
     g.add_argument("--ssm-state-dim", type=int, default=None,

@@ -43,6 +43,12 @@ class PositionEmbeddingKind(enum.Enum):
     none = "none"
 
 
+# The letters of TransformerConfig.layer_pattern (HF `nemotron_h`'s
+# hybrid_override_pattern): the ONE sublayer a layer is.
+PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention",
+                 "E": "the experts", "-": "a dense feed-forward"}
+
+
 @dataclasses.dataclass
 class TransformerConfig:
     """Architecture hyperparameters.
@@ -176,13 +182,17 @@ class TransformerConfig:
     # projections none (HF mamba_conv_bias true, mamba_proj_bias false).
     # Which mixer it is, is a fact of the model: with ssm_heads (HF
     # mamba_n_heads) it is Mamba-2: ssm_heads heads of ssm_head_dim columns
-    # (HF mamba_d_head; heads x head_dim = ssm_expand x hidden_size), each
-    # with a matrix state [head_dim, ssm_state_dim], one scalar decay and
-    # one dt a head, B and C shared by the heads of one of ssm_groups groups
-    # (HF mamba_n_groups; 1 is what is written), a gated RMS norm before the
-    # output projection, and a prefill that runs as matrix products over
-    # chunks of ssm_chunk_size positions (HF mamba_chunk_size). Without
-    # ssm_heads it is Mamba-1 (a vector state [ssm_state_dim] a channel).
+    # (HF mamba_d_head / mamba_head_dim; the inner width is heads x head_dim,
+    # ssm_inner, whatever ssm_expand says: `nemotron_h` has 64 x 64 = 4096
+    # beside a hidden size of 2688), each with a matrix state [head_dim,
+    # ssm_state_dim], one scalar decay and one dt a head, B and C [ssm_groups,
+    # ssm_state_dim] a token, shared by the ssm_heads / ssm_groups heads of a
+    # group (HF mamba_n_groups / n_groups: head h reads group h // (heads /
+    # groups)), a gated RMS norm before the output projection that runs over
+    # each group's columns alone, and a prefill that runs as matrix products
+    # over chunks of ssm_chunk_size positions (HF mamba_chunk_size). Without
+    # ssm_heads it is Mamba-1 (a vector state [ssm_state_dim] a channel,
+    # ssm_expand x hidden_size of them).
     # shortconv_kernel > 0 makes every non-attention layer's first half a
     # gated short convolution instead (HF `lfm2` / `lfm2_moe`: layer_types
     # "conv", conv_L_cache taps, conv_bias false; transformer/shortconv.py):
@@ -191,6 +201,17 @@ class TransformerConfig:
     # feed-forwards behind moe_first_k_dense leading dense ones.
     attn_layer_period: Optional[int] = None
     attn_layer_offset: int = 0
+    # A stack whose layers are ONE sublayer each (HF `nemotron_h`:
+    # hybrid_override_pattern): x' = x + Sub_i(norm(x)) once a layer, the
+    # i-th letter of the pattern saying which: "M" a state-space mixer, "*"
+    # attention, "E" the experts (with their shared expert), "-" a dense
+    # feed-forward. One letter a layer; no period and no offset, so
+    # attn_layer_period stays None. Each kind's layers are stacked in layer
+    # order under a key of their own and the loop walks the pattern in
+    # scanned runs of its repeating units (transformer/block.py). An "E" or
+    # "-" layer owns no plane of any pool. The residual-out projections are
+    # initialised at std / sqrt(depth): a layer adds to the stream once.
+    layer_pattern: Optional[str] = None
     # The depth that the scaled init of the residual-out projections divides
     # by (std / sqrt(2 x depth)): the whole model's where this configuration
     # is a stage or a share of it, so that a stage is initialised as the
@@ -466,21 +487,56 @@ class TransformerConfig:
                     "behind moe_first_k_dense leading dense ones: no "
                     "moe_layer_freq, moe_zero_experts, shortcut double "
                     "layer or MTP")
+        if self.layer_pattern is not None:
+            kinds = set(self.layer_pattern)
+            if (len(self.layer_pattern) != self.num_layers
+                    or kinds - set(PATTERN_KINDS)):
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} names one "
+                    f"sublayer a layer of num_layers={self.num_layers}, "
+                    "each of " + ", ".join(
+                        f"{k!r} ({what})"
+                        for k, what in PATTERN_KINDS.items()))
+            if (self.attn_layer_period is not None or self.moe_first_k_dense
+                    or self.shortconv_kernel or self.sliding_window
+                    or self.multi_latent_attention or self.is_eva
+                    or self.moe_shortcut_double_layer or self.mtp_num_layers
+                    or self.moe_zero_experts or self.residual_multiplier != 1
+                    or self.heterogeneous_layers_config_json
+                    or (self.is_moe and self.moe_layer_freq != 1)):
+                raise ValueError(
+                    "a layer_pattern stack is plain attention, state-space, "
+                    "expert and dense sublayers, one a layer: the pattern "
+                    "says which layers attend (no attn_layer_period) and "
+                    "which hold experts (no moe_first_k_dense or "
+                    "moe_layer_freq), and no short convolution, sliding "
+                    "window, MLA, EVA, double layer, zero-compute experts, "
+                    "MTP, residual multiplier or heterogeneous block "
+                    "configs is written for it")
+            if "E" in kinds and not self.is_moe:
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r}: its 'E' layers "
+                    "are the model's experts (num_moe_experts)")
+            if "M" in kinds and not self.ssm_heads:
+                raise ValueError(
+                    "a layer_pattern stack's 'M' layers are Mamba-2 mixers "
+                    "(ssm_heads): Mamba-1 is not written for it")
         if self.ssm_heads:
-            e = self.ssm_expand * self.hidden_size
-            if (self.attn_layer_period is None or self.shortconv_kernel
+            if ((self.attn_layer_period is None
+                 and self.layer_pattern is None) or self.shortconv_kernel
                     or self.sliding_window or self.ssm_inner_norms
-                    or self.ssm_heads * self.ssm_head_dim != e
-                    or self.ssm_groups != 1 or self.ssm_chunk_size < 1):
+                    or self.ssm_groups < 1
+                    or self.ssm_heads % self.ssm_groups
+                    or self.ssm_chunk_size < 1):
                 raise ValueError(
                     f"ssm_heads={self.ssm_heads} makes the state-space "
-                    "layers of a hybrid stack (attn_layer_period; no "
-                    "shortconv_kernel, sliding_window or ssm_inner_norms) "
-                    f"Mamba-2 mixers: ssm_heads x ssm_head_dim "
-                    f"({self.ssm_head_dim}) is ssm_expand x hidden_size "
-                    f"({e}), and B and C are shared by every head "
-                    f"(ssm_groups={self.ssm_groups}: more than one group "
-                    "is not written)")
+                    "layers of a hybrid stack (attn_layer_period or "
+                    "layer_pattern; no shortconv_kernel, sliding_window or "
+                    "ssm_inner_norms) Mamba-2 mixers of inner width "
+                    f"ssm_heads x ssm_head_dim ({self.ssm_inner}; "
+                    "ssm_expand is not read), whose B and C are shared by "
+                    f"the heads of each of ssm_groups={self.ssm_groups} "
+                    "groups: a whole number of heads a group")
         if self.attention_multiplier is not None and (
                 self.multi_latent_attention or self.is_eva):
             raise ValueError(
@@ -570,7 +626,17 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.kv_channels
 
+    @property
+    def hybrid_stack(self) -> bool:
+        """Whether the layers are of more than one kind, stacked a kind
+        and walked by a loop of their own (transformer/block.py): a period
+        of two-half layers, or a pattern of single-sublayer ones."""
+        return (self.attn_layer_period is not None
+                or self.layer_pattern is not None)
+
     def layer_is_attention(self, i: int) -> bool:
+        if self.layer_pattern is not None:
+            return self.layer_pattern[i] == "*"
         return (self.attn_layer_period is None
                 or i % self.attn_layer_period == self.attn_layer_offset)
 
@@ -621,6 +687,8 @@ class TransformerConfig:
         """Layers whose first half is no attention but a mixer with a
         state a sequence (0 unless attn_layer_period is set, and in a
         sliding-window stack, whose other kind attends too)."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern.count("M")
         return (self.num_layers - self.num_attention_layers
                 - self.num_window_layers)
 
@@ -630,14 +698,31 @@ class TransformerConfig:
         return 0 if self.shortconv_kernel else self.num_recurrent_layers
 
     @property
+    def num_moe_layers(self) -> int:
+        """Layers that hold experts, where they sit in every layer behind
+        moe_first_k_dense dense ones, or where a pattern says 'E'."""
+        if not self.is_moe:
+            return 0
+        if self.layer_pattern is not None:
+            return self.layer_pattern.count("E")
+        return self.num_layers - self.moe_first_k_dense
+
+    @property
+    def ssm_inner(self) -> int:
+        """E, the state-space mixer's inner width: Mamba-2's heads x
+        head_dim, Mamba-1's ssm_expand x hidden_size."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
+        return self.ssm_expand * self.hidden_size
+
+    @property
     def ssm_conv_channels(self) -> int:
         """Columns the state-space mixer's causal convolution runs over, and
         so of a slot's cached tail: Mamba-1's expanded input; Mamba-2's x, B
         and C side by side."""
-        e = self.ssm_expand * self.hidden_size
         if self.ssm_heads:
-            return e + 2 * self.ssm_groups * self.ssm_state_dim
-        return e
+            return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_dim
+        return self.ssm_inner
 
     @property
     def num_conv_layers(self) -> int:

@@ -75,6 +75,7 @@ from megatronapp_tpu.trace.request_trace import (
 )
 from megatronapp_tpu.transformer.block import (
     hybrid_layer_loop, hybrid_layer_params, layer_forward,
+    pattern_layer_loop, pattern_layer_params,
 )
 from megatronapp_tpu.transformer.eva import table_rows
 from megatronapp_tpu.transformer.moe import HELD_COUNTS, StackedLayer
@@ -329,6 +330,10 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     are read through the layer id as below, and the layers' counts are
     summed along the loop's carry.
 
+    A pattern stack (cfg.layer_pattern) has the same four pools, planes
+    counted by kind: block.pattern_layer_loop's scanned runs hand each
+    layer its letter and its index among its kind.
+
     A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
     one step of the same scan: layer_forward runs its two attention
     sublayers into planes 2·lid and 2·lid + 1 of the pools [2L, NB, ...].
@@ -337,7 +342,7 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     None for a dense model, else int32 [2]: the MoE layers' routing_counts
     summed ([6], moe.HELD_COUNTS, on a model that holds a share of its
     experts or has zero-compute ones)."""
-    if cfg.attn_layer_period is not None:
+    if cfg.hybrid_stack:
         if lora is not None or ctx is not None:
             raise ValueError("a hybrid state-space stack serves on one "
                              "device, without lora")
@@ -347,6 +352,32 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
         if cfg.is_moe:
             counts0 = jnp.zeros(
                 (len(HELD_COUNTS) if cfg.moe_counts_load else 2,), jnp.int32)
+
+        def run_letter(carry, kind, k, lid):
+            # A pattern stack's layer is one sublayer: "*" writes plane k
+            # of the KV pools, "M" of the state pools, and "E" (row k of
+            # the experts' stacks) and "-" own no plane of any pool.
+            hh, pools, kvs, counts = carry
+            layer_p = pattern_layer_params(block, kind, k)
+            if kind == "*":
+                (hh, new), _ = layer(layer_p, hh, lid, pools[:2], kvs,
+                                     None, kv_plane=k)
+                pools = tuple(new[:2]) + pools[2:]
+                kvs = None if kvs is None else tuple(new[2:])
+            elif kind == "M":
+                (hh, new), _ = layer(layer_p, hh, lid, None, None, None,
+                                     ssm_state=pools[2:] + (k,),
+                                     state_rows=rows)
+                pools = pools[:2] + tuple(new)
+            else:
+                if kind == "E" and stacks:
+                    layer_p = dict(layer_p, moe=dict(layer_p["moe"], **{
+                        name: StackedLayer(w, k)
+                        for name, w in stacks.items()}))
+                (hh, _), aux = layer(layer_p, hh, lid, None, None, None)
+                if kind == "E":
+                    counts = counts + aux
+            return hh, pools, kvs, counts
 
         def run(carry, attends, k, lid, lead=False):
             hh, pools, kvs, counts = carry
@@ -375,9 +406,11 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
                 counts = counts + aux
             return hh, pools, kvs, counts
 
-        h, pages, scales, moe = hybrid_layer_loop(
+        pattern = cfg.layer_pattern is not None
+        h, pages, scales, moe = (pattern_layer_loop if pattern
+                                 else hybrid_layer_loop)(
             cfg, (h, tuple(pages), None if scales is None else tuple(scales),
-                  counts0), run)
+                  counts0), run_letter if pattern else run)
         return h, moe, pages + (scales or ())
     lead = cfg.moe_first_k_dense
     block, stacks = _moe_stacks(params["block"], ctx)
@@ -737,11 +770,11 @@ def prefill_call_costs(cfg: TransformerConfig, params):
         flops += 2.0 * leaf.size * share
     if cfg.ssm_heads:
         # A Mamba-2 layer's chunked scan is matrix products too, a position
-        # (transformer/ssm.ssd_chunked): Q scores of N and Q x P a head
-        # within the chunk, N x E into the state and N x E out of it.
-        q, n = cfg.ssm_chunk_size, cfg.ssm_state_dim
-        e = cfg.ssm_expand * cfg.hidden_size
-        flops += cfg.num_ssm_layers * 2.0 * (q * n + q * e + 2 * n * e)
+        # (transformer/ssm.ssd_chunked): Q scores of N a group and Q x P a
+        # head within the chunk, N x E into the state and N x E out of it.
+        q, n, e = cfg.ssm_chunk_size, cfg.ssm_state_dim, cfg.ssm_inner
+        flops += cfg.num_ssm_layers * 2.0 * (
+            q * n * cfg.ssm_groups + q * e + 2 * n * e)
     return stream, flops
 
 
@@ -1133,7 +1166,15 @@ class DynamicInferenceEngine:
                      f"{self.pool.state_bytes_per_slot} B a slot, prefix "
                      "reuse off (a prefix hit would skip tokens whose "
                      "state nobody kept)")
-            if cfg.is_moe:
+            if cfg.layer_pattern is not None:
+                line += (f", layers={cfg.num_layers}, one sublayer each "
+                         f"({cfg.layer_pattern}): "
+                         f"{cfg.num_attention_layers} attention + "
+                         f"{cfg.num_recurrent_layers} {self._state_words()}"
+                         f" + {cfg.num_moe_layers} of {cfg.num_moe_experts} "
+                         f"experts, top-{cfg.moe_router_topk} by "
+                         f"{cfg.moe_router_score} scores")
+            elif cfg.is_moe:
                 line += (f", layers={cfg.num_layers}: "
                          f"{cfg.num_attention_layers} attention + "
                          f"{cfg.num_recurrent_layers} {self._state_words()}"
@@ -3006,8 +3047,7 @@ class DynamicInferenceEngine:
                     conv_channels=self.cfg.ssm_conv_channels)
         if self.cfg.is_moe:
             here = self.cfg.moe_experts_here[1]
-            per_round = ((self.cfg.num_layers - self.cfg.moe_first_k_dense)
-                         * here)
+            per_round = self.cfg.num_moe_layers * here
             out["moe"] = dict(
                 self.moe_stats, experts_here=here, expert_pairs_possible=(
                     per_round * self.moe_stats["decode_rounds"]))

@@ -405,10 +405,10 @@ class PagedKVCache:
                      (cfg.shortconv_kernel - 1) * cfg.hidden_size),
                     cfg.compute_dtype, 0),)
             else:
-                e = cfg.ssm_expand * cfg.hidden_size
                 self.state = (
                     _new_pool((cfg.num_ssm_layers, max_batch,
-                               cfg.ssm_state_dim, e), jnp.float32, 0),
+                               cfg.ssm_state_dim, cfg.ssm_inner),
+                              jnp.float32, 0),
                     _new_pool((cfg.num_ssm_layers, max_batch,
                                (cfg.ssm_conv_kernel - 1)
                                * cfg.ssm_conv_channels),

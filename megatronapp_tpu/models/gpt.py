@@ -56,7 +56,7 @@ def init_gpt_params(rng, cfg: TransformerConfig, pp: int = 1, vpp: int = 1):
         ax["final_ln_bias"] = ("embed",)
     # A hybrid stack keeps its leading dense layers' halves inside its own
     # block ("ffn_lead", transformer/block.py): no stack beside it.
-    hybrid = cfg.attn_layer_period is not None
+    hybrid = cfg.hybrid_stack
     lead = 0 if hybrid else cfg.moe_first_k_dense
     if hybrid and cfg.is_moe and pp > 1:
         raise ValueError(
@@ -331,13 +331,13 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
     else:
         # A hybrid stack that counts its held experts' load (a share of an
         # expert-parallel job) counts it in training too.
-        counting = cfg.moe_counts_load and cfg.attn_layer_period is not None
+        counting = cfg.moe_counts_load and cfg.hybrid_stack
         logits, aux, *counts = gpt_forward(
             p, tokens, cfg, ctx=ctx, segment_ids=segment_ids,
             zigzag_keep=True, fp8=fp8, moe_counts=counting)
         if counting:
             from megatronapp_tpu.transformer.moe import TRAIN_COUNTS
-            moe_layers = cfg.num_layers - cfg.moe_first_k_dense
+            moe_layers = cfg.num_moe_layers
             # "sums": a step's totals over micro-batches, not their mean
             # (training/train_step.py)
             more["sums"] = {

@@ -320,6 +320,52 @@ def granite_4_0_h_small(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+NEMOTRON_3_NANO_PATTERN = (
+    "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+def nemotron_3_nano_30b_a3b(**kw) -> TransformerConfig:
+    """nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (`nemotron_h`; 31.6B
+    parameters, ~3.2B active) as its config.json publishes it
+    (https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/
+    blob/main/config.json): 52 layers of H 2688, each ONE sublayer behind an
+    RMS norm (hybrid_override_pattern: 23 Mamba-2 mixers, 23 expert layers,
+    6 attention layers). Mamba-2: 64 heads of 64 columns (inner width 4096,
+    not 2 x 2688), a [64, 128] state a head, B and C in 8 groups of 8 heads,
+    a gated RMS norm over each group's 512 columns, convolution of 4 taps,
+    chunks of 128. Attention: 32 query heads over 2 key/value heads of 128,
+    NO positional term. Experts: 128 of width 1856, two matrices and relu^2
+    (no gate), a sigmoid router whose 6 picks are the largest of s + b, the
+    weights s renormalised and times 2.5, beside a shared expert of 3712.
+    RMSNorm 1e-5, an untied head over 131,072. Whole it is 63 GB of bf16
+    weights: a deployment passes its share (num_layers and layer_pattern,
+    moe_experts_held, vocab_size), as the benchmark's configuration does
+    (perfbench/configs/nemotron-3-nano-30b-a3b.json, which also lists what
+    the config leaves to the family's convention). Serves through --engine
+    dynamic. The router divides the picks' weights by their sum + 1e-6
+    (transformer/moe.py's sigmoid constant) where `nemotron_h` adds 1e-20:
+    3e-7 of a weight."""
+    d = dict(num_layers=52, hidden_size=2688, num_attention_heads=32,
+             num_query_groups=2, kv_channels=128, ffn_hidden_size=1856,
+             vocab_size=131072, max_position_embeddings=262144,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             activation=ActivationKind.squared_relu, add_bias_linear=False,
+             position_embedding=PositionEmbeddingKind.none,
+             untie_embeddings_and_output_weights=True,
+             layer_pattern=NEMOTRON_3_NANO_PATTERN, scaled_init_layers=52,
+             ssm_state_dim=128, ssm_conv_kernel=4, ssm_heads=64,
+             ssm_head_dim=64, ssm_groups=8, ssm_chunk_size=128,
+             num_moe_experts=128, moe_router_topk=6,
+             moe_ffn_hidden_size=1856,
+             moe_shared_expert_intermediate_size=3712,
+             moe_router_score="sigmoid", moe_router_selection_bias=True,
+             moe_router_norm_topk_prob=True, moe_routed_scaling_factor=2.5)
+    d.update(kw)
+    if "num_layers" in kw and "layer_pattern" not in kw:
+        d["layer_pattern"] = NEMOTRON_3_NANO_PATTERN[:kw["num_layers"]]
+    return TransformerConfig(**d)
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -342,6 +388,7 @@ def evabyte_6p5b(**kw) -> TransformerConfig:
 
 
 PRESETS = {
+    "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b,
     "evabyte-6.5b": evabyte_6p5b,
     "granite-4.0-h-small": granite_4_0_h_small,
     "jamba2-3b": jamba2_3b,

@@ -180,6 +180,16 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
 
     stack = w.reshape((-1, k, n))
     cols = n // tn
+    # An N that is no whole number of lanes (1856 = 14.5 x 128) under a K
+    # that is one: the chip keeps such a matrix with K minor (N would be
+    # padded), which is the transposed matrix [N, K] in rows. The kernel
+    # reads it as that (the swap moves nothing there; a row-major operand
+    # would be a copy of the whole stack a call, and Mosaic slices no
+    # dimension that is padded), one column tile, an expert's whole matrix
+    # a block, and contracts both operands' last dimension.
+    by_rows = bool(n % 128) and not k % 128
+    if by_rows:
+        stack = jnp.swapaxes(stack, 1, 2)
 
     def kernel(lid_ref, off_ref, gid_ref, tid_ref, nxt_ref, x_ref, w_hbm,
                o_ref, w_buf, sem, slot_ref):
@@ -187,9 +197,10 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         g = gid_ref[v]
 
         def block(group, col, slot):
+            at = ((lid_ref[0] * e + group,) if by_rows else
+                  (lid_ref[0] * e + group, slice(None), pl.ds(col * tn, tn)))
             return pltpu.make_async_copy(
-                w_hbm.at[lid_ref[0] * e + group, :, pl.ds(col * tn, tn)],
-                w_buf.at[slot], sem.at[slot])
+                w_hbm.at[at], w_buf.at[slot], sem.at[slot])
 
         # A weight block is fetched when its group's first visit of a
         # column tile begins, one block ahead: the next group's block (or
@@ -226,8 +237,10 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         row = tid_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, tn), 0)
         mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
-        y = jnp.dot(x_ref[...], w_buf[slot],
-                    preferred_element_type=jnp.float32)
+        y = jax.lax.dot_general(
+            x_ref[...], w_buf[slot],
+            (((1,), (1 if by_rows else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         o_ref[...] = jnp.where(mine, y, o_ref[...].astype(jnp.float32)
                                ).astype(o_ref.dtype)
 
@@ -244,7 +257,8 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         in_specs=[pl.BlockSpec((tm, k), x_block),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tm, tn), o_block),
-        scratch_shapes=[pltpu.VMEM((2, k, tn), x.dtype),
+        scratch_shapes=[pltpu.VMEM((2, n, k) if by_rows else (2, k, tn),
+                                   x.dtype),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SMEM((1,), jnp.int32)],
     )

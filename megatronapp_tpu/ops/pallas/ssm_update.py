@@ -36,10 +36,18 @@ from megatronapp_tpu.ops.pallas import kernel_gen
 
 
 def ssm_update_reference(h, dt, u, b, c, a_t, d):
-    """The plain update, [rows, N, E] at once: (y [rows, E], h')."""
+    """The plain update, [rows, N, E] at once: (y [rows, E], h'). b, c
+    [rows, N], or [rows, G, N]: G groups of E / G columns, each reading its
+    own."""
+    if b.ndim == 3:     # [rows, G, N] -> [rows, N, E], a group's columns
+        width = h.shape[-1] // b.shape[1]
+        b, c = (jnp.repeat(jnp.swapaxes(t, 1, 2), width, axis=2)
+                for t in (b, c))
+    else:
+        b, c = b[:, :, None], c[:, :, None]
     h = jnp.exp(dt[:, None, :] * a_t[None]) * h \
-        + (dt[:, None, :] * b[:, :, None]) * u[:, None, :]
-    y = jnp.sum(h * c[:, :, None], axis=1) + u * d[None]
+        + (dt[:, None, :] * b) * u[:, None, :]
+    y = jnp.sum(h * c, axis=1) + u * d[None]
     return y, h
 
 
@@ -62,8 +70,11 @@ def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
                b: jnp.ndarray, c: jnp.ndarray, a_t: jnp.ndarray,
                d: jnp.ndarray, active: jnp.ndarray):
     """pool [L, slots, N, E] f32; layer int32 scalar; dt, u [slots, E]
-    f32; b, c [slots, N] f32; a_t [N, E] f32 (A transposed) or [1, E] (one
-    A for every n); d [E] f32;
+    f32; b, c [slots, N] f32, or [slots, G, N] where the columns of E fall
+    into G groups that each read a B and C of their own (Mamba-2 with
+    ssm_groups: a tile of E then lies inside one group, and its b and c
+    block is that group's); a_t [N, E] f32 (A transposed) or [1, E] (one A
+    for every n); d [E] f32;
     active [slots] bool. Returns (y [slots, E] f32, pool): the pool is the
     buffer that came in wherever the caller's copy of it is dead (a
     donated argument, a loop carry), with plane `layer` of the active
@@ -84,7 +95,9 @@ def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
 
     # The grid: the running rows, and where a plane is tiled the tiles of E
     # (ids = (i,) or (i, j), then the two prefetched scalars).
-    te = _tile(n, e)
+    groups = b.shape[1] if b.ndim == 3 else 1
+    # with groups, the tile of a plane [N, E / G]: no tile crosses a group
+    te = _tile(n, e // groups)
     tiled = te < e
 
     def col(ids):
@@ -106,7 +119,13 @@ def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
     # last two dims are the array's own (or whole lane tiles of E), which
     # Mosaic takes whole.
     wide = pl.BlockSpec((None, 1, te), row)
-    tall = pl.BlockSpec((None, n, 1), tall_row)
+    if groups > 1:      # [slots, G, N, 1]: the block of the tile's group
+        tall = pl.BlockSpec(
+            (None, None, n, 1),
+            lambda *ids: (ids[-1][ids[0]], ids[1] * te // (e // groups),
+                          0, 0))
+    else:
+        tall = pl.BlockSpec((None, n, 1), tall_row)
     state = pl.BlockSpec((None, None, n, te), plane)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -124,7 +143,7 @@ def ssm_update(pool: jnp.ndarray, layer, dt: jnp.ndarray, u: jnp.ndarray,
         input_output_aliases={8: 1, 9: 0},
         interpret=kernel_gen._interpret(),
         name="ssm_update",
-    )(layer, order, dt[:, None, :], u[:, None, :], b[:, :, None],
-      c[:, :, None], a_t, d[None, :], pool,
+    )(layer, order, dt[:, None, :], u[:, None, :], b[..., None],
+      c[..., None], a_t, d[None, :], pool,
       jnp.zeros((slots, 1, e), jnp.float32))
     return y[:, 0], pool

@@ -237,7 +237,7 @@ def gpt_rank_kernels(cfg: TransformerConfig):
     if getattr(cfg, "tp_comm_overlap", False):
         return None, ("--tp-comm-overlap's rings multiply inside their own "
                       "full-manual regions")
-    if cfg.multi_latent_attention or cfg.attn_layer_period is not None \
+    if cfg.multi_latent_attention or cfg.hybrid_stack \
             or getattr(cfg, "hetero_block_specs", None) \
             or cfg.mtp_num_layers:
         return None, ("latent attention, hybrid, heterogeneous and multi "

@@ -225,148 +225,164 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
             p["second"], x, cfg, kv_cache=cache,
             kv_plane=None if plane is None else plane + 1, **both)
         return (x + m.astype(x.dtype), cache), aux
-    residual = x
-    h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
-                   cfg.layernorm_epsilon, cfg.norm_unit_offset)
-    # The scopes put a layer's parts into the compiled program's op_names
-    # (HLO text, the profiler's own viewer). The events of a TPU trace as
-    # jax.profiler.ProfileData gives them carry an instruction's name and
-    # no op_name: trace/scope_map.py reads the scopes from the compiled
-    # text and a reader joins the two by instruction name (PERF.md, PR 36).
-    def attend():
-        mask = attention_mask
-        cos, sin = ((rope_cos, rope_sin) if window_rope is None
-                    else window_rope)
-        # "window" is no part of its own (trace/scope_map.PARTS): a window
-        # layer's operations stay in `attention`, and carry the sub-part.
-        with jax.named_scope("attention"), (
-                jax.named_scope("window") if window_rope is not None
-                else contextlib.nullcontext()):
-            if cfg.multi_latent_attention:
-                if lora is not None:
-                    raise ValueError(
-                        "lora serving targets the GQA projection kernels "
-                        "— MLA has no q_kernel/kv_kernel (lora.AdapterCache "
-                        "rejects MLA configs at construction)")
-                from megatronapp_tpu.transformer.mla import mla_forward
-                if segment_ids is not None:
-                    # MLA routes through the reference attention impl —
-                    # packed segments densify into the mask here.
-                    seg_mask = (segment_ids[:, None, :, None]
-                                == segment_ids[:, None, None, :])
-                    mask = seg_mask if mask is None else mask & seg_mask
-                if kv_cache is not None:
-                    attn_out, new_cache = mla_forward(
-                        p["attention"], h, cfg, rope_cos, rope_sin, mask,
-                        layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
-                        cache_index=cache_index,
-                        cache_positions=cache_positions,
-                        page_table=page_table, active=active,
-                        chunk_counts=chunk_counts, kv_scales=kv_scales,
-                        kv_plane=kv_plane)
+    def mixer_half(x):
+        """x + Mixer(norm(x)), and the cache the mixer wrote."""
+        residual = x
+        h = apply_norm(cfg.normalization, x, p["ln1_scale"], p.get("ln1_bias"),
+                       cfg.layernorm_epsilon, cfg.norm_unit_offset)
+        # The scopes put a layer's parts into the compiled program's op_names
+        # (HLO text, the profiler's own viewer). The events of a TPU trace as
+        # jax.profiler.ProfileData gives them carry an instruction's name and
+        # no op_name: trace/scope_map.py reads the scopes from the compiled
+        # text and a reader joins the two by instruction name (PERF.md, PR 36).
+        def attend():
+            mask = attention_mask
+            cos, sin = ((rope_cos, rope_sin) if window_rope is None
+                        else window_rope)
+            # "window" is no part of its own (trace/scope_map.PARTS): a window
+            # layer's operations stay in `attention`, and carry the sub-part.
+            with jax.named_scope("attention"), (
+                    jax.named_scope("window") if window_rope is not None
+                    else contextlib.nullcontext()):
+                if cfg.multi_latent_attention:
+                    if lora is not None:
+                        raise ValueError(
+                            "lora serving targets the GQA projection "
+                            "kernels — MLA has no q_kernel/kv_kernel "
+                            "(lora.AdapterCache rejects MLA configs at "
+                            "construction)")
+                    from megatronapp_tpu.transformer.mla import mla_forward
+                    if segment_ids is not None:
+                        # MLA routes through the reference attention impl —
+                        # packed segments densify into the mask here.
+                        seg_mask = (segment_ids[:, None, :, None]
+                                    == segment_ids[:, None, None, :])
+                        mask = seg_mask if mask is None else mask & seg_mask
+                    if kv_cache is not None:
+                        attn_out, new_cache = mla_forward(
+                            p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                            layer_id=layer_id, ctx=ctx, kv_cache=kv_cache,
+                            cache_index=cache_index,
+                            cache_positions=cache_positions,
+                            page_table=page_table, active=active,
+                            chunk_counts=chunk_counts, kv_scales=kv_scales,
+                            kv_plane=kv_plane)
+                    else:
+                        attn_out = mla_forward(
+                            p["attention"], h, cfg, rope_cos, rope_sin, mask,
+                            layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
+                        new_cache = None
                 else:
-                    attn_out = mla_forward(
-                        p["attention"], h, cfg, rope_cos, rope_sin, mask,
-                        layer_id=layer_id, ctx=ctx, tp_sharded=tp_sharded)
+                    attn_out, new_cache = attention_forward(
+                        p["attention"], h, cfg, cos, sin, mask,
+                        window=(cfg.sliding_window if window_rope is not None
+                                else 0),
+                        kv_cache=kv_cache, cache_index=cache_index,
+                        cache_positions=cache_positions, layer_id=layer_id,
+                        ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
+                        page_table=page_table, active=active,
+                        chunk_counts=chunk_counts, tp_sharded=tp_sharded,
+                        kv_scales=kv_scales,
+                        fp8=None if fp8 is None else fp8["attention"],
+                        lora=lora, kv_plane=kv_plane)
+            return attn_out, new_cache
+
+        if "ssm" in p:
+            if segment_ids is not None or tp_sharded or lora is not None:
+                raise NotImplementedError(
+                    "a state-space layer runs whole sequences on one tp "
+                    "shard: no packed segments (the state would cross "
+                    "them), tp-sharded stage body or lora")
+            from megatronapp_tpu.transformer.ssm import (
+                ssm_dims, ssm_forward, ssm_paged_forward,
+            )
+            with jax.named_scope("ssm"):
+                if ssm_state is not None:
+                    attn_out, new_cache = ssm_paged_forward(
+                        p["ssm"], h, cfg, ssm_state, rows=state_rows,
+                        starts=cache_positions, counts=chunk_counts,
+                        active=active)
+                else:
+                    attn_out, _ = ssm_forward(p["ssm"], h, cfg, ssm_dims(cfg))
                     new_cache = None
-            else:
-                attn_out, new_cache = attention_forward(
-                    p["attention"], h, cfg, cos, sin, mask,
-                    window=(cfg.sliding_window if window_rope is not None
-                            else 0),
-                    kv_cache=kv_cache, cache_index=cache_index,
-                    cache_positions=cache_positions, layer_id=layer_id,
-                    ctx=ctx, zigzag=zigzag, segment_ids=segment_ids,
-                    page_table=page_table, active=active,
-                    chunk_counts=chunk_counts, tp_sharded=tp_sharded,
-                    kv_scales=kv_scales,
-                    fp8=None if fp8 is None else fp8["attention"],
-                    lora=lora, kv_plane=kv_plane)
-        return attn_out, new_cache
+        elif "conv" in p:
+            if tp_sharded or lora is not None:
+                raise NotImplementedError(
+                    "a gated short convolution runs on one tp shard, without "
+                    "lora: a tap reads the positions before its own")
+            from megatronapp_tpu.transformer.shortconv import (
+                shortconv_forward, shortconv_paged_forward,
+            )
+            with jax.named_scope("conv"):
+                if ssm_state is not None:
+                    attn_out, new_cache = shortconv_paged_forward(
+                        p["conv"], h, cfg, ssm_state, rows=state_rows,
+                        starts=cache_positions, counts=chunk_counts,
+                        active=active)
+                else:
+                    attn_out, _ = shortconv_forward(p["conv"], h, cfg,
+                                                    segment_ids=segment_ids)
+                    new_cache = None
+        else:
+            attn_out, new_cache = attend()
+        # Tag for the 'selective_attn' remat policy (a no-op otherwise).
+        attn_out = checkpoint_name(attn_out, "attn_out")
+        if cfg.residual_multiplier != 1.0:
+            attn_out = attn_out * cfg.residual_multiplier
+        x = residual + attn_out.astype(residual.dtype)
+        return x, new_cache
 
-    if "ssm" in p:
-        if segment_ids is not None or tp_sharded or lora is not None:
-            raise NotImplementedError(
-                "a state-space layer runs whole sequences on one tp shard: "
-                "no packed segments (the state would cross them), "
-                "tp-sharded stage body or lora")
-        from megatronapp_tpu.transformer.ssm import (
-            ssm_dims, ssm_forward, ssm_paged_forward,
-        )
-        with jax.named_scope("ssm"):
-            if ssm_state is not None:
-                attn_out, new_cache = ssm_paged_forward(
-                    p["ssm"], h, cfg, ssm_state, rows=state_rows,
-                    starts=cache_positions, counts=chunk_counts,
-                    active=active)
-            else:
-                attn_out, _ = ssm_forward(p["ssm"], h, cfg, ssm_dims(cfg))
-                new_cache = None
-    elif "conv" in p:
-        if tp_sharded or lora is not None:
-            raise NotImplementedError(
-                "a gated short convolution runs on one tp shard, without "
-                "lora: a tap reads the positions before its own")
-        from megatronapp_tpu.transformer.shortconv import (
-            shortconv_forward, shortconv_paged_forward,
-        )
-        with jax.named_scope("conv"):
-            if ssm_state is not None:
-                attn_out, new_cache = shortconv_paged_forward(
-                    p["conv"], h, cfg, ssm_state, rows=state_rows,
-                    starts=cache_positions, counts=chunk_counts,
-                    active=active)
-            else:
-                attn_out, _ = shortconv_forward(p["conv"], h, cfg,
-                                                segment_ids=segment_ids)
-                new_cache = None
-    else:
-        attn_out, new_cache = attend()
-    # Tag for the 'selective_attn' remat policy (a no-op otherwise).
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    if cfg.residual_multiplier != 1.0:
-        attn_out = attn_out * cfg.residual_multiplier
-    x = residual + attn_out.astype(residual.dtype)
-
-    residual = x
-    h = apply_norm(cfg.normalization, x, p["ln2_scale"], p.get("ln2_bias"),
-                   cfg.layernorm_epsilon, cfg.norm_unit_offset)
-    aux = None
-    if "moe" in p:
-        if fp8 is not None:
-            raise ValueError("fp8 does not support MoE layers "
-                             "(fp8_ineligible_reason gates this off)")
-        if lora is not None:
-            raise ValueError("lora serving targets the dense fc1/fc2 "
-                             "kernels — MoE layers are unsupported")
-        count_rows = None
-        if page_table is not None:
-            # A paged serving step: its real tokens are the active rows'
-            # first chunk_counts positions; moe_forward counts their
-            # routing in place of the aux loss.
-            b, s = x.shape[:2]
-            count_rows = jnp.ones((b, s), bool)
-            if active is not None:
-                count_rows &= active[:, None]
-            if chunk_counts is not None:
-                count_rows &= jnp.arange(s)[None, :] < chunk_counts[:, None]
-        with jax.named_scope("moe"):
-            mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
-                                       ctx=ctx, tp_sharded=tp_sharded,
-                                       count_rows=count_rows,
-                                       train_counts=moe_counts)
-    if "mlp" in p:
+    def ffn_half(x):
+        """x + FFN(norm(x)), and what the feed-forward hands back."""
+        residual = x
+        h = apply_norm(cfg.normalization, x, p["ln2_scale"], p.get("ln2_bias"),
+                       cfg.layernorm_epsilon, cfg.norm_unit_offset)
+        aux = None
         if "moe" in p:
-            # The shortcut: the MoE's output skips the rest of the layer.
-            aux = (aux, mlp_out)
-        with jax.named_scope("mlp"):
-            mlp_out = mlp_forward(p["mlp"], h, cfg, layer_id=layer_id,
-                                  ctx=ctx, tp_sharded=tp_sharded,
-                                  fp8=None if fp8 is None else fp8["mlp"],
-                                  lora=lora)
-    if cfg.residual_multiplier != 1.0:
-        mlp_out = mlp_out * cfg.residual_multiplier
-    x = residual + mlp_out.astype(residual.dtype)
+            if fp8 is not None:
+                raise ValueError("fp8 does not support MoE layers "
+                                 "(fp8_ineligible_reason gates this off)")
+            if lora is not None:
+                raise ValueError("lora serving targets the dense fc1/fc2 "
+                                 "kernels — MoE layers are unsupported")
+            count_rows = None
+            if page_table is not None:
+                # A paged serving step: its real tokens are the active rows'
+                # first chunk_counts positions; moe_forward counts their
+                # routing in place of the aux loss.
+                b, s = x.shape[:2]
+                count_rows = jnp.ones((b, s), bool)
+                if active is not None:
+                    count_rows &= active[:, None]
+                if chunk_counts is not None:
+                    count_rows &= (jnp.arange(s)[None, :]
+                                   < chunk_counts[:, None])
+            with jax.named_scope("moe"):
+                mlp_out, aux = moe_forward(p["moe"], h, cfg, layer_id=layer_id,
+                                           ctx=ctx, tp_sharded=tp_sharded,
+                                           count_rows=count_rows,
+                                           train_counts=moe_counts)
+        if "mlp" in p:
+            if "moe" in p:
+                # The shortcut: the MoE's output skips the rest of the layer.
+                aux = (aux, mlp_out)
+            with jax.named_scope("mlp"):
+                mlp_out = mlp_forward(p["mlp"], h, cfg, layer_id=layer_id,
+                                      ctx=ctx, tp_sharded=tp_sharded,
+                                      fp8=None if fp8 is None else fp8["mlp"],
+                                      lora=lora)
+        if cfg.residual_multiplier != 1.0:
+            mlp_out = mlp_out * cfg.residual_multiplier
+        x = residual + mlp_out.astype(residual.dtype)
+        return x, aux
+
+    # A layer of a pattern stack (cfg.layer_pattern) is ONE sublayer: it
+    # holds one half, and runs that.
+    new_cache = aux = None
+    if "ln1_scale" in p:
+        x, new_cache = mixer_half(x)
+    if "ln2_scale" in p:
+        x, aux = ffn_half(x)
     # MegaScope 'system' perturbation + capture site between layers
     # (transformer_block.py:542-544).
     from megatronapp_tpu.scope.disturbance import get_disturbance
@@ -443,6 +459,8 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     an MoE model with cfg.moe_first_k_dense the MoE layers'
     [num_layers - k, ...], the k leading layers' dense halves being
     "ffn_lead" [k, ...]."""
+    if cfg.layer_pattern is not None:
+        return _init_pattern_block_params(rng, cfg)
     out_std = cfg.init_method_std / jnp.sqrt(2.0 * _init_depth(cfg))
     keys = jax.vmap(jax.random.split)(
         jax.random.split(rng, cfg.num_layers))        # [L, (mixer, ffn)]
@@ -465,6 +483,108 @@ def init_hybrid_block_params(rng, cfg: TransformerConfig):
     done = {k: _vmapped_layers(*v) for k, v in kinds.items() if len(v[0])}
     return ({k: v[0] for k, v in done.items()},
             {k: v[1] for k, v in done.items()})
+
+
+# Where each kind of a pattern stack's layers is stacked, in layer order
+# (cfg.layer_pattern's letters): the keys a period's stack uses for the same
+# halves, so that what reads a hybrid block by key reads this one.
+PATTERN_STACKS = {"M": "mixers_ssm", "*": "mixers_attn", "E": "ffn",
+                  "-": "ffn_dense"}
+
+
+def _init_pattern_block_params(rng, cfg: TransformerConfig):
+    """A pattern stack (cfg.layer_pattern): every layer is ONE sublayer
+    behind its norm, and the layers of a kind are stacked in layer order
+    under PATTERN_STACKS[kind]: "M" and "*" hold {"ln1_scale", mixer}, "E"
+    and "-" {"ln2_scale", "moe" / "mlp"}. A layer adds to the stream once,
+    so its residual-out projection starts at std / sqrt(depth) (HF
+    `nemotron_h` rescale_prenorm_residual)."""
+    out_std = cfg.init_method_std / jnp.sqrt(1.0 * _init_depth(cfg))
+    keys = jax.random.split(rng, cfg.num_layers)
+    letters = np.asarray(list(cfg.layer_pattern))
+    inits = {
+        "M": functools.partial(_init_mixer_half, cfg=cfg, out_std=out_std,
+                               ssm=True),
+        "*": functools.partial(_init_mixer_half, cfg=cfg, out_std=out_std),
+        "E": functools.partial(_init_ffn_half, cfg=cfg, out_std=out_std),
+        "-": functools.partial(_init_ffn_half, cfg=cfg, out_std=out_std,
+                               force_dense=True),
+    }
+    done = {PATTERN_STACKS[kind]: _vmapped_layers(keys[letters == kind], init)
+            for kind, init in inits.items() if kind in cfg.layer_pattern}
+    return ({k: v[0] for k, v in done.items()},
+            {k: v[1] for k, v in done.items()})
+
+
+def tandem_runs(pattern: str):
+    """[(unit, repeats)] that spell `pattern`: from the left, the repeated
+    unit that covers the most letters (the shortest such), or where nothing
+    repeats the next letter alone, once. "MEMEM*EMEMEM*" is ("ME", 2), "M",
+    "*", ("EM", 3), "*"."""
+    runs, at, n = [], 0, len(pattern)
+    while at < n:
+        unit, reps = 1, 1
+        for u in range(1, (n - at) // 2 + 1):
+            r = 1
+            while pattern.startswith(pattern[at:at + u], at + r * u):
+                r += 1
+            if r > 1 and u * r > unit * reps:
+                unit, reps = u, r
+        runs.append((pattern[at:at + unit], reps))
+        at += unit * reps
+    return runs
+
+
+def pattern_layer_loop(cfg: TransformerConfig, carry, run,
+                       scan_runs: bool = True):
+    """Walk a pattern stack (cfg.layer_pattern) in layer order as SCANNED
+    runs of its repeating units (tandem_runs; a unit's own repeats are
+    scanned inside it), the letters between them one after the other:
+    nothing is unrolled a layer, no stack is copied, and a layer reads the
+    stack of its own kind alone.
+
+    run(carry, kind, k, layer_id) -> carry runs one layer: `kind` (static)
+    is its letter, k its index among the layers of its kind (its row of
+    PATTERN_STACKS[kind]; an attention layer's plane of the KV pools, a
+    state-space layer's of the state pools), layer_id its index in the
+    model; both Python ints outside a scan, int32 scalars inside one.
+
+    scan_runs: False writes the runs out (block_forward, which is
+    differentiated: hybrid_layer_loop says what a scanned run costs
+    there)."""
+    def walk(carry, pattern, seen, lid):
+        # seen: {kind: layers of it before `pattern`}; lid: layers before it
+        for unit, reps in tandem_runs(pattern):
+            per = {kind: unit.count(kind) for kind in set(unit)}
+
+            def after(j, per=per, seen=seen):
+                """`seen` behind j turns of the unit."""
+                return {**seen, **{k: seen.get(k, 0) + j * n
+                                   for k, n in per.items()}}
+
+            def turn(c, j, unit=unit, after=after, lid=lid):
+                return walk(c, unit, after(j), lid + j * len(unit))
+
+            if reps == 1:           # one letter
+                carry = run(carry, unit, seen.get(unit, 0), lid)
+            elif scan_runs:
+                carry = jax.lax.scan(
+                    lambda c, j, turn=turn: (turn(c, j), None), carry,
+                    jnp.arange(reps, dtype=jnp.int32))[0]
+            else:
+                for j in range(reps):
+                    carry = turn(carry, j)
+            seen, lid = after(reps), lid + reps * len(unit)
+        return carry
+
+    return walk(carry, cfg.layer_pattern, {}, 0)
+
+
+def pattern_layer_params(stacked_p, kind: str, k):
+    """One layer's params out of a pattern stack: row k of its kind's."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, k, 0, keepdims=False),
+        stacked_p[PATTERN_STACKS[kind]])
 
 
 def hybrid_layer_loop(cfg: TransformerConfig, carry, run,
@@ -590,7 +710,7 @@ def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
             init_hetero_block_params,
         )
         return init_hetero_block_params(rng, cfg)
-    if cfg.attn_layer_period is not None:
+    if cfg.hybrid_stack:
         return init_hybrid_block_params(rng, cfg)
     freq = cfg.moe_layer_freq if cfg.is_moe else 1
     if cfg.moe_shortcut_double_layer:
@@ -658,7 +778,7 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
         return hetero_block_forward(
             stacked_p, x, cfg, rope_cos, rope_sin, attention_mask,
             layer_offset=layer_offset, ctx=ctx)
-    if cfg.attn_layer_period is not None:
+    if cfg.hybrid_stack:
         if fp8 is not None or tp_sharded or zigzag:
             raise NotImplementedError(
                 "a hybrid state-space stack trains on the plain path: no "
@@ -683,8 +803,12 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             # recomputation (the counts are integers: no cotangent).
             h, aux_sum, counts = carry
             windowed = bool(cfg.sliding_window) and not attends
+            # a pattern stack's `attends` is the layer's letter
+            layer_p = (pattern_layer_params(stacks, attends, k)
+                       if cfg.layer_pattern is not None else
+                       hybrid_layer_params(stacks, attends, k, lid, lead))
             (h2, _), aux = layer_forward(
-                hybrid_layer_params(stacks, attends, k, lid, lead), h, cfg,
+                layer_p, h, cfg,
                 rope_cos, rope_sin, attention_mask,
                 layer_id=lid + layer_offset, ctx=ctx,
                 segment_ids=segment_ids,
@@ -698,10 +822,14 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             return h2, aux_sum, counts
 
         # a body a kind of layer: which one a layer is, is static
+        pattern = cfg.layer_pattern is not None
         bodies = {(a, ld): _remat_wrap(
             functools.partial(one_layer, attends=a, lead=ld),
-            cfg.remat_policy) for a in (False, True) for ld in (False, True)}
-        x, aux, counts = hybrid_layer_loop(
+            cfg.remat_policy)
+            for a in (set(cfg.layer_pattern) if pattern else (False, True))
+            for ld in (False, True)}
+        x, aux, counts = (pattern_layer_loop if pattern
+                          else hybrid_layer_loop)(
             cfg, (x, jnp.zeros((), jnp.float32),
                   jnp.zeros((len(TRAIN_COUNTS),), jnp.int32)),
             lambda c, attends, k, lid, lead=False: bodies[attends, lead](

@@ -29,24 +29,30 @@ every model here says so (HF mamba_conv_bias true, mamba_proj_bias false),
 so they are no switches.
 
 MAMBA-2 (Dao & Gu 2024, "Transformers are SSMs"; HF
-`modeling_granitemoehybrid.GraniteMoeHybridMambaLayer`): E = heads x P
-columns in heads of P; in_proj -> [z | xBC | dt] (E | E + 2N | heads); the
-convolution and silu run over x, B and C TOGETHER; dt and A are one scalar a
-head, B and C [N] shared by every head (one group); the state a head is a
-matrix S [P, N]:
+`modeling_granitemoehybrid.GraniteMoeHybridMambaLayer`,
+`modeling_nemotron_h.NemotronHMamba2Mixer`): E = heads x P columns in heads
+of P (SsmDims.head_dim; E is NOT expand x H: `nemotron_h` has 64 x 64 beside
+H 2688); in_proj -> [z | xBC | dt] (E | E + 2GN | heads); the convolution
+and silu run over x, B and C TOGETHER; dt and A are one scalar a head, B and
+C [G, N] a token, shared by the heads / G heads of a group (head h reads
+group h // (heads / G); Granite has one group, `nemotron_h` 8); the state a
+head is a matrix S [P, N]:
 
-    S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t ;  y_t = S_t C_t + D x_t
+    S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t[g] ;  y_t = S_t C_t[g] + D x_t
 
-then out = RMS(y * silu(z); g) W_out, the norm over all E columns. The state
+then out = RMS_g(y * silu(z); g) W_out, the norm over each group's E / G
+columns alone (one group: over all E). The state
 is kept as Mamba-1's h [B, N, E] (h[n, head * P + p] = S[head][p, n]): with
 dt, A and D broadcast over a head's P columns the decode step IS Mamba-1's,
-and runs in the same kernel and the same pool. A whole sequence cannot be
+and runs in the same kernel and the same pool; with G groups a column of E
+reads its group's B and C. A whole sequence cannot be
 Mamba-1's scan (its [B, block, N, E] float32 operands are 268 MB each at N
 128, E 8192): it is the chunked (SSD) form, matrix products over chunks of
-`chunk` positions carried chunk to chunk (`ssd_chunked`).
+`chunk` positions carried chunk to chunk (`ssd_chunked`), the scores C B^T
+one [Q, Q] a group.
 
-Param leaves: in_kernel [H, 2E + 2N + heads], conv_kernel [k, E + 2N],
-conv_bias [E + 2N], dt_bias [heads], A_log [heads], D [heads], norm_scale
+Param leaves: in_kernel [H, 2E + 2GN + heads], conv_kernel [k, E + 2GN],
+conv_bias [E + 2GN], dt_bias [heads], A_log [heads], D [heads], norm_scale
 [E], out_kernel [E, H].
 """
 
@@ -71,15 +77,21 @@ class SsmDims(NamedTuple):
     inner_norms: bool = False
     heads: int = 0              # > 0: Mamba-2, of `heads` heads
     chunk: int = 256            # positions a chunk of Mamba-2's prefill
+    head_dim: int = 0           # Mamba-2: a head's columns
+    groups: int = 1             # Mamba-2: groups of heads that share B, C
 
     def rank(self, hidden: int) -> int:
         return self.dt_rank or max(hidden // 16, 1)
+
+    def inner(self, hidden: int) -> int:
+        """E: Mamba-2's heads x head_dim, Mamba-1's expand x hidden."""
+        return self.heads * self.head_dim or self.expand * hidden
 
 
 def ssm_dims(cfg: TransformerConfig) -> SsmDims:
     return SsmDims(cfg.ssm_state_dim, cfg.ssm_conv_kernel, cfg.ssm_expand,
                    cfg.ssm_dt_rank, cfg.ssm_inner_norms, cfg.ssm_heads,
-                   cfg.ssm_chunk_size)
+                   cfg.ssm_chunk_size, cfg.ssm_head_dim, cfg.ssm_groups)
 
 
 def _init_dt_bias(key, shape, dtype):
@@ -94,9 +106,9 @@ def _init_ssm2_params(rng, cfg: TransformerConfig, dims: SsmDims, out_std):
     [1e-3, 1e-1]), A = -(1..16) uniform a head (the published mixer's
     A_init_range), D = 1, the gated norm's scale 1."""
     h = cfg.hidden_size
-    e = dims.expand * h
+    e = dims.inner(h)
     n = dims.state_dim
-    c = e + 2 * n
+    c = e + 2 * dims.groups * n
     keys = jax.random.split(rng, 5)
     std = cfg.init_method_std
     p = {
@@ -230,8 +242,11 @@ def selective_scan(u, dt, a_t, b, c, d, h0=None):
 def ssd_chunked(x, dt, a, b, c, d, chunk: int, h0=None):
     """Mamba-2's recurrence over a whole sequence as matrix products (the
     SSD form). x [B,S,E], E = heads x P; dt [B,S,heads] float32; a, d
-    [heads] float32 (a < 0); b, c [B,S,N]; h0 [B,N,E] float32 or None
-    (zeros) -> (y [B,S,E] float32, h_S [B,N,E] float32).
+    [heads] float32 (a < 0); b, c [B,S,N], or [B,S,G,N] where G groups of
+    heads / G heads each read a B and C of their own (the scores are then
+    one [Q, Q] a group, and a group's E / G columns of the state meet its
+    own B and C); h0 [B,N,E] float32 or None (zeros) -> (y [B,S,E] float32,
+    h_S [B,N,E] float32).
 
     Chunks of `chunk` positions, one after the other, the state carried
     between them (a `lax.scan`: one chunk's [B, heads, Q, Q] decays live at
@@ -253,14 +268,54 @@ def ssd_chunked(x, dt, a, b, c, d, chunk: int, h0=None):
     q = min(chunk, s)
     chunks = -(-s // q)
 
-    def split(t):                           # [B,S,.] -> [chunks,B,q,.]
-        t = jnp.pad(t, ((0, 0), (0, chunks * q - s), (0, 0)))
-        return jnp.swapaxes(t.reshape(bsz, chunks, q, t.shape[-1]), 0, 1)
+    def split(t):                           # [B,S,...] -> [chunks,B,q,...]
+        t = jnp.pad(t, ((0, 0), (0, chunks * q - s))
+                    + ((0, 0),) * (t.ndim - 2))
+        return jnp.swapaxes(t.reshape((bsz, chunks, q) + t.shape[2:]), 0, 1)
 
     def wide(t):                            # [..., heads] -> [..., E]
         return jnp.repeat(t, p, axis=-1)
 
     lower = jnp.tril(jnp.ones((q, q), bool))
+    # One group: the products as they were. G groups: the scores batched
+    # over the group, and the two products with the state one a group over
+    # the group's columns of E (contiguous, whole lane tiles), side by side:
+    # batched over the group they would want the state [B, G, N, E / G], and
+    # XLA relays the whole pool out around the step for it (seen compiling
+    # the reason cell's prefill call for a described v5e: two copies of
+    # [6, 192, 128, 4096] float32 a call).
+    groups = b.shape[2] if b.ndim == 4 else 0
+    if groups:
+        width = e // groups
+
+        def scores_of(c_c, b_c):                            # [B,heads,q,q]
+            return jnp.repeat(jnp.einsum(
+                "bign,bjgn->bgij", c_c, b_c, preferred_element_type=f32),
+                heads // groups, axis=1)
+
+        def read(c_c, h):                                   # C h: [B,q,E]
+            return jnp.concatenate([jnp.einsum(
+                "bin,bnw->biw", c_c[:, :, g].astype(f32),
+                h[..., g * width:(g + 1) * width])
+                for g in range(groups)], axis=-1)
+
+        def write(b_c, xw):                                 # B (x) x: [B,N,E]
+            return jnp.concatenate([jnp.einsum(
+                "bjn,bjw->bnw", b_c[:, :, g],
+                xw[..., g * width:(g + 1) * width],
+                preferred_element_type=f32)
+                for g in range(groups)], axis=-1)
+    else:
+        def scores_of(c_c, b_c):
+            return jnp.einsum("bin,bjn->bij", c_c, b_c,
+                              preferred_element_type=f32)[:, None]
+
+        def read(c_c, h):
+            return jnp.einsum("bin,bne->bie", c_c.astype(f32), h)
+
+        def write(b_c, xw):
+            return jnp.einsum("bjn,bje->bne", b_c, xw,
+                              preferred_element_type=f32)
 
     def step(h, xs):
         x_c, dt_c, b_c, c_c = xs
@@ -268,19 +323,15 @@ def ssd_chunked(x, dt, a, b, c, d, chunk: int, h0=None):
         cum_t = jnp.swapaxes(cum, 1, 2)                     # [B,heads,q]
         decay = jnp.exp(jnp.where(
             lower, cum_t[..., :, None] - cum_t[..., None, :], -jnp.inf))
-        scores = jnp.einsum("bin,bjn->bij", c_c, b_c,
-                            preferred_element_type=f32)
-        m = (scores[:, None] * decay).astype(x.dtype)       # [B,heads,q,q]
+        m = (scores_of(c_c, b_c) * decay).astype(x.dtype)   # [B,heads,q,q]
         xdt = (x_c.reshape(bsz, q, heads, p).astype(f32)
                * dt_c[..., None]).astype(x.dtype)
         y = jnp.einsum("bhij,bjhp->bihp", m, xdt,
                        preferred_element_type=f32).reshape(bsz, q, e)
-        y = y + wide(jnp.exp(cum)) * jnp.einsum(
-            "bin,bne->bie", c_c.astype(f32), h)
+        y = y + wide(jnp.exp(cum)) * read(c_c, h)
         left = jnp.exp(cum[:, -1:] - cum) * dt_c            # [B,q,heads]
-        h = wide(jnp.exp(cum[:, -1]))[:, None, :] * h + jnp.einsum(
-            "bjn,bje->bne", b_c, (x_c.astype(f32) * wide(left)).astype(
-                x.dtype), preferred_element_type=f32)
+        h = wide(jnp.exp(cum[:, -1]))[:, None, :] * h + write(
+            b_c, (x_c.astype(f32) * wide(left)).astype(x.dtype))
         return h, y
 
     if h0 is None:
@@ -385,14 +436,17 @@ def _ssm2_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state,
     """ssm_forward for a Mamba-2 mixer: the same arguments and results, the
     tail [B, k-1, E + 2N] (the convolution runs over x, B and C)."""
     bsz, s, hidden = x.shape
-    e, n, k = dims.expand * hidden, dims.state_dim, dims.conv_kernel
+    e, n, k = dims.inner(hidden), dims.state_dim, dims.conv_kernel
+    groups = dims.groups
     f32 = jnp.float32
     cd = cfg.compute_dtype
     z, raw, dt = jnp.split(x.astype(cd) @ p["in_kernel"].astype(cd),
-                           [e, 2 * e + 2 * n], axis=-1)
+                           [e, 2 * e + 2 * groups * n], axis=-1)
     tail, h0 = state if state is not None else (None, None)
     padded, xbc = _causal_conv(raw, tail, p, k)
-    u, b_, c_ = jnp.split(xbc.astype(cd), [e, e + n], axis=-1)
+    u, b_, c_ = jnp.split(xbc.astype(cd), [e, e + groups * n], axis=-1)
+    if groups > 1:      # [B,S,G,N]: a group's heads read their own B and C
+        b_, c_ = (t.reshape(bsz, s, groups, n) for t in (b_, c_))
     dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
     if counts is not None:
         dt = jnp.where(jnp.arange(s)[None, :, None] < counts[:, None, None],
@@ -413,8 +467,14 @@ def _ssm2_forward(p, x, cfg: TransformerConfig, dims: SsmDims, state,
         with jax.named_scope("ssd_chunk"):
             y, h_new = ssd_chunked(u, dt, a, b_, c_, d, dims.chunk, h0)
     with jax.named_scope("gated_norm"):
-        y = rms_norm(y.astype(f32) * jax.nn.silu(z.astype(f32)),
-                     p["norm_scale"], cfg.layernorm_epsilon).astype(cd)
+        y = y.astype(f32) * jax.nn.silu(z.astype(f32))
+        if groups > 1:  # the norm over each group's columns alone
+            y = rms_norm(y.reshape(bsz, s, groups, -1),
+                         p["norm_scale"].reshape(groups, -1),
+                         cfg.layernorm_epsilon).reshape(bsz, s, e).astype(cd)
+        else:
+            y = rms_norm(y, p["norm_scale"],
+                         cfg.layernorm_epsilon).astype(cd)
     out = y @ p["out_kernel"].astype(cd)
     return out, (_last_inputs(padded, s, counts, k), h_new)
 

@@ -1069,6 +1069,92 @@ def test_engine_ssd_moe_steps_at_cell_shapes(one_chip, chip_compile, which):
     assert 13.0e9 < total < 13.5e9
 
 
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_pattern_steps_at_cell_shapes(one_chip, chip_compile, which,
+                                             capsys):
+    """The two jits at NVIDIA-Nemotron-3-Nano-30B-A3B's published widths and
+    the reason cell's sizes (its cut: layers 0-12 MEMEM*EMEMEM*, experts 0-63
+    of 128, half the vocabulary): 192 slots of h [128, 4096] float32 a
+    Mamba-2 layer in 8 groups, two attention layers of 2 key/value heads of
+    128 under 32 query heads, five layers of 64 held two-matrix experts of
+    width 1856, a prefill call of the 1,024 positions the engine chooses for
+    the cell on this chip (eight chunks of 128). Mosaic takes `ssm_update`
+    with a tile inside one group (a [128, 512] block a grid step, its b and
+    c the group's), `grouped_gemm` at N 1856 (no multiple of 128, so
+    `choose_gemm_tiles` takes N whole: fc1 a [2688, 1856] block of 9.98 MB,
+    over WEIGHT_BLOCK_BYTES; fc2 K 1856 whole, 7 lane tiles of N a block)
+    and the paged kernels at 16 queries a key/value head; the step aliases
+    the page pools and the state pools alike and copies nothing of the state
+    pools' shape or one plane's; an E layer owns no plane of any pool."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    from megatronapp_tpu.ops.pallas.grouped_gemm import (
+        WEIGHT_BLOCK_BYTES, choose_gemm_tiles,
+    )
+    batch, blocks, seq = 192, 49152, 6144
+    cut = dict(num_layers=13, moe_experts_held=(0, 64), vocab_size=65536,
+               vocab_slice_of=131072)
+    width = _cell_prefill_width(one_chip, "nemotron-3-nano-30b-a3b", seq,
+                                **cut)
+    assert width == 1024
+    cfg = PRESETS["nemotron-3-nano-30b-a3b"](params_dtype=jnp.bfloat16,
+                                             **cut)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree.leaves(abstract)) == 3_926_018_560
+    # two slots give the jits; the cell's 192 go in as abstract pools
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=2, max_seq_len=seq,
+                                 paged=True, num_blocks=8,
+                                 prefill_chunk=width)
+    ssm, conv = ((p.shape[0], batch) + p.shape[2:] for p in eng.pool.state)
+    assert ssm == (6, 192, 128, 4096) and conv == (6, 192, 3 * 6144)
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + (_sds(ssm, jnp.float32, one_chip),
+           _sds(conv, jnp.bfloat16, one_chip))
+    assert pools[0].shape == (2, blocks, 16, 2, 128)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        rows = batch * 6
+        compiled = eng._decode.lower(
+            p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None).compile()
+        _assert_kernels_named(compiled, "paged_decode", "ssm_update",
+                              "grouped_gemm")
+    else:
+        rows = width * 6
+        compiled = eng._mq_step.lower(
+            p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1),
+            i32(1)).compile()
+        _assert_kernels_named(compiled, "paged_mq", "grouped_gemm")
+    # what choose_gemm_tiles chose at the off-lane width, and Mosaic took
+    tm1, tk1, tn1 = choose_gemm_tiles(rows, 64, 2688, 1856, jnp.bfloat16)
+    tm2, tk2, tn2 = choose_gemm_tiles(rows, 64, 1856, 2688, jnp.bfloat16)
+    assert (tk1, tn1) == (2688, 1856) and tk1 * tn1 * 2 > WEIGHT_BLOCK_BYTES
+    assert (tk2, tn2) == (1856, 896)
+    said = capsys.readouterr().out
+    assert f"groups of [2688, 1856] -> pallas, tiles ({tm1}, 2688, 1856)" \
+        in said
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    assert not _pool_shaped(compiled,
+                            r"copy|transpose|(?<!update[_-])slice",
+                            [ssm, ssm[1:], (1,) + ssm[1:]])
+    # weights 7.85 GB + state 2.46 GB + pages 1.61 GB and the step's own
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.9e9 < total < 13.0e9, total
+
+
 # The assist cell's cut of LFM2-24B-A2B: published layers 1..9
 # (perfbench/configs/lfm2-24b-a2b.json).
 LFM2_CUT = {"num_layers": 9, "attn_layer_offset": 1, "moe_first_k_dense": 1}

@@ -164,8 +164,11 @@ class TestRefusals:
         assert "prefix reuse off" in eng.startup_line()
 
     @pytest.mark.parametrize("kw,word", [
-        ({"ssm_head_dim": 16}, "ssm_heads x ssm_head_dim"),
-        ({"ssm_groups": 2}, "more than one group"),
+        # PR 54 wrote groups of heads and an inner width of heads x head
+        # columns: what is refused now is a group that is no whole number
+        # of heads, and no chunk
+        ({"ssm_groups": 3}, "a whole number of heads a group"),
+        ({"ssm_chunk_size": 0}, "ssm_heads x ssm_head_dim"),
         ({"ssm_inner_norms": True}, "Mamba-2"),
         ({"attn_layer_period": None}, "Mamba-2"),
     ])
