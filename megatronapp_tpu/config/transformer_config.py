@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -44,9 +45,26 @@ class PositionEmbeddingKind(enum.Enum):
 
 
 # The letters of TransformerConfig.layer_pattern (HF `nemotron_h`'s
-# hybrid_override_pattern): the ONE sublayer a layer is.
+# hybrid_override_pattern): the ONE sublayer a layer is, and the key of the
+# parameter tree's "block" under which the layers of that kind are stacked.
 PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention",
                  "E": "the experts", "-": "a dense feed-forward"}
+PATTERN_STACKS = {"M": "mixers_ssm", "*": "mixers_attn", "E": "ffn",
+                  "-": "ffn_dense"}
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_plan(num_layers, period, offset, pattern, lead, conv, window):
+    """TransformerConfig.stack_plan of the fields that spell it."""
+    if pattern is not None:
+        return tuple((PATTERN_STACKS[letter],) for letter in pattern)
+    if period is None:
+        return None
+    other = ("mixers_swa" if window else
+             "mixers_conv" if conv else "mixers_ssm")
+    return tuple(("mixers_attn" if i % period == offset else other,
+                  "ffn_lead" if i < lead else "ffn")
+                 for i in range(num_layers))
 
 
 @dataclasses.dataclass
@@ -172,7 +190,9 @@ class TransformerConfig:
     moe_experts_held: Optional[Tuple[int, int]] = None
     moe_shortcut_double_layer: bool = False
 
-    # Hybrid state-space stacks (HF `jamba`, `granitemoehybrid`:
+    # A stack of several kinds of layer has two spellings, which are the
+    # models' own, and ONE plan (stack_plan, below) that everything behind
+    # this file reads. This one (HF `jamba`, `granitemoehybrid`:
     # attn_layer_period / attn_layer_offset): layer i attends iff i % period
     # == offset, and every other layer's first half is a selective-state-
     # space mixer (transformer/ssm.py). None = every layer attends. The
@@ -201,16 +221,14 @@ class TransformerConfig:
     # feed-forwards behind moe_first_k_dense leading dense ones.
     attn_layer_period: Optional[int] = None
     attn_layer_offset: int = 0
-    # A stack whose layers are ONE sublayer each (HF `nemotron_h`:
-    # hybrid_override_pattern): x' = x + Sub_i(norm(x)) once a layer, the
-    # i-th letter of the pattern saying which: "M" a state-space mixer, "*"
-    # attention, "E" the experts (with their shared expert), "-" a dense
-    # feed-forward. One letter a layer; no period and no offset, so
-    # attn_layer_period stays None. Each kind's layers are stacked in layer
-    # order under a key of their own and the loop walks the pattern in
-    # scanned runs of its repeating units (transformer/block.py). An "E" or
-    # "-" layer owns no plane of any pool. The residual-out projections are
-    # initialised at std / sqrt(depth): a layer adds to the stream once.
+    # The other spelling: a stack whose layers are ONE sublayer each (HF
+    # `nemotron_h`: hybrid_override_pattern): x' = x + Sub_i(norm(x)) once a
+    # layer, the i-th letter of the pattern saying which: "M" a state-space
+    # mixer, "*" attention, "E" the experts (with their shared expert), "-"
+    # a dense feed-forward. One letter a layer; no period and no offset, so
+    # attn_layer_period stays None. An "E" or "-" layer owns no plane of any
+    # pool. The residual-out projections are initialised at std /
+    # sqrt(depth): a layer adds to the stream once.
     layer_pattern: Optional[str] = None
     # The depth that the scaled init of the residual-out projections divides
     # by (std / sqrt(2 x depth)): the whole model's where this configuration
@@ -627,24 +645,43 @@ class TransformerConfig:
         return self.kv_channels
 
     @property
+    def stack_plan(self):
+        """A stack of several kinds of layer, one entry a layer: the keys of
+        the parameter tree's "block" whose stacks hold the layer's halves, in
+        the order it runs them (each stack in layer order, so a layer's row
+        of one is the number of earlier entries that name it). None: one
+        uniform stack. The ONE place that says which layer is of which kind,
+        from either spelling: a period of two-half layers (a mixer:
+        "mixers_attn" where i % attn_layer_period == attn_layer_offset, else
+        "mixers_swa" with sliding_window, "mixers_conv" with
+        shortconv_kernel, "mixers_ssm"; and a feed-forward: "ffn_lead", the
+        dense one of the moe_first_k_dense leading layers, else "ffn"), or a
+        layer_pattern of single-sublayer ones (PATTERN_STACKS). What walks,
+        initialises or serves such a stack reads this (transformer/block.py
+        layer_loop)."""
+        return _stack_plan(
+            self.num_layers, self.attn_layer_period, self.attn_layer_offset,
+            self.layer_pattern, self.moe_first_k_dense,
+            bool(self.shortconv_kernel), bool(self.sliding_window))
+
+    @property
     def hybrid_stack(self) -> bool:
-        """Whether the layers are of more than one kind, stacked a kind
-        and walked by a loop of their own (transformer/block.py): a period
-        of two-half layers, or a pattern of single-sublayer ones."""
-        return (self.attn_layer_period is not None
-                or self.layer_pattern is not None)
+        """Whether the layers are of more than one kind (stack_plan)."""
+        return self.stack_plan is not None
+
+    def _layers_holding(self, key: str) -> int:
+        return sum(key in layer for layer in self.stack_plan or ())
 
     def layer_is_attention(self, i: int) -> bool:
-        if self.layer_pattern is not None:
-            return self.layer_pattern[i] == "*"
-        return (self.attn_layer_period is None
-                or i % self.attn_layer_period == self.attn_layer_offset)
+        plan = self.stack_plan
+        return plan is None or "mixers_attn" in plan[i]
 
     @property
     def num_attention_layers(self) -> int:
         """Layers that attend, and so own a plane of the KV cache."""
-        return sum(self.layer_is_attention(i)
-                   for i in range(self.num_layers))
+        if self.stack_plan is None:
+            return self.num_layers
+        return self._layers_holding("mixers_attn")
 
     @property
     def kv_planes(self) -> int:
@@ -671,11 +708,10 @@ class TransformerConfig:
 
     @property
     def num_window_layers(self) -> int:
-        """Sliding-window attention layers: a hybrid stack's other kind
-        where sliding_window is set. Each owns a plane of the WINDOW pools;
-        num_attention_layers and kv_planes count the full layers alone."""
-        return (self.num_layers - self.num_attention_layers
-                if self.sliding_window else 0)
+        """Sliding-window attention layers. Each owns a plane of the WINDOW
+        pools; num_attention_layers and kv_planes count the full layers
+        alone."""
+        return self._layers_holding("mixers_swa")
 
     @property
     def window_heads(self) -> int:
@@ -684,28 +720,30 @@ class TransformerConfig:
 
     @property
     def num_recurrent_layers(self) -> int:
-        """Layers whose first half is no attention but a mixer with a
-        state a sequence (0 unless attn_layer_period is set, and in a
-        sliding-window stack, whose other kind attends too)."""
-        if self.layer_pattern is not None:
-            return self.layer_pattern.count("M")
-        return (self.num_layers - self.num_attention_layers
-                - self.num_window_layers)
+        """Layers whose mixer is no attention but one with a state a
+        sequence."""
+        return self.num_ssm_layers + self.num_conv_layers
 
     @property
     def num_ssm_layers(self) -> int:
-        """Layers whose first half is a state-space mixer."""
-        return 0 if self.shortconv_kernel else self.num_recurrent_layers
+        """Layers whose mixer is a state-space mixer."""
+        return self._layers_holding("mixers_ssm")
+
+    @property
+    def num_conv_layers(self) -> int:
+        """Layers whose mixer is a gated short convolution."""
+        return self._layers_holding("mixers_conv")
 
     @property
     def num_moe_layers(self) -> int:
-        """Layers that hold experts, where they sit in every layer behind
-        moe_first_k_dense dense ones, or where a pattern says 'E'."""
+        """Layers that hold experts: the plan's "ffn" of an MoE model, or
+        every layer of a uniform stack behind moe_first_k_dense dense
+        ones."""
         if not self.is_moe:
             return 0
-        if self.layer_pattern is not None:
-            return self.layer_pattern.count("E")
-        return self.num_layers - self.moe_first_k_dense
+        if self.stack_plan is None:
+            return self.num_layers - self.moe_first_k_dense
+        return self._layers_holding("ffn")
 
     @property
     def ssm_inner(self) -> int:
@@ -723,11 +761,6 @@ class TransformerConfig:
         if self.ssm_heads:
             return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_dim
         return self.ssm_inner
-
-    @property
-    def num_conv_layers(self) -> int:
-        """Layers whose first half is a gated short convolution."""
-        return self.num_recurrent_layers if self.shortconv_kernel else 0
 
     @property
     def moe_counts_load(self) -> bool:

@@ -74,8 +74,7 @@ from megatronapp_tpu.trace.request_trace import (
     PhaseStats, get_request_tracer,
 )
 from megatronapp_tpu.transformer.block import (
-    hybrid_layer_loop, hybrid_layer_params, layer_forward,
-    pattern_layer_loop, pattern_layer_params,
+    layer_forward, layer_loop, layer_params,
 )
 from megatronapp_tpu.transformer.eva import table_rows
 from megatronapp_tpu.transformer.moe import HELD_COUNTS, StackedLayer
@@ -314,25 +313,17 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     0..k-1) run first, through the same body and into planes 0..k-1 of the
     same pools; the scanned stack takes ids k..L-1.
 
-    A hybrid stack (cfg.attn_layer_period) has two kinds of layer and two
-    kinds of state: `pages` is then (k, v, ssm, conv), the KV pools with a
-    plane an ATTENTION layer and behind them the recurrent-state pools
-    [L_ssm, slots, ...] of the state-space layers (paged_cache.py), all
-    four carried and written in place alike. The loop is
-    block.hybrid_layer_loop's scanned runs; an attention layer gets its
-    plane (kv_plane), a state-space layer the state pools, its plane of
-    them and `rows`, the slots of h's rows (None: row b is slot b). A stack
-    of gated short convolutions has the one tail pool: (k, v, conv). A
-    sliding-window stack (cfg.sliding_window) has no state but a second pair
-    of page pools: (k, v, k_window, v_window), a plane a window layer, which
-    the layer reads through the window planes' own table. Its
-    feed-forwards may be experts behind leading dense layers: the stacks
-    are read through the layer id as below, and the layers' counts are
-    summed along the loop's carry.
-
-    A pattern stack (cfg.layer_pattern) has the same four pools, planes
-    counted by kind: block.pattern_layer_loop's scanned runs hand each
-    layer its letter and its index among its kind.
+    A stack of several kinds of layer (cfg.stack_plan) is walked by
+    block.layer_loop's scanned runs, and `pages` holds a pool a kind of
+    state behind the KV pools (paged_cache.py), all carried and written in
+    place alike: (k, v, ssm, conv), (k, v, conv) or (k, v, k_window,
+    v_window). A layer's mixer says which it writes, in the plane that is
+    its row of its stack: "mixers_attn" the KV pools (kv_plane), "mixers_swa"
+    the window pools through their own table, "mixers_ssm" / "mixers_conv"
+    the state pools at `rows`, the slots of h's rows (None: row b is slot
+    b); a feed-forward alone none. The experts' stacks are read through the
+    layer's row of "ffn" as below, and the layers' counts are summed along
+    the loop's carry.
 
     A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
     one step of the same scan: layer_forward runs its two attention
@@ -347,70 +338,44 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
             raise ValueError("a hybrid state-space stack serves on one "
                              "device, without lora")
         block, stacks = _moe_stacks(params["block"])
-        n_lead = cfg.moe_first_k_dense
         counts0 = None
         if cfg.is_moe:
             counts0 = jnp.zeros(
                 (len(HELD_COUNTS) if cfg.moe_counts_load else 2,), jnp.int32)
 
-        def run_letter(carry, kind, k, lid):
-            # A pattern stack's layer is one sublayer: "*" writes plane k
-            # of the KV pools, "M" of the state pools, and "E" (row k of
-            # the experts' stacks) and "-" own no plane of any pool.
+        def run(carry, entry, at, lid):
             hh, pools, kvs, counts = carry
-            layer_p = pattern_layer_params(block, kind, k)
-            if kind == "*":
-                (hh, new), _ = layer(layer_p, hh, lid, pools[:2], kvs,
-                                     None, kv_plane=k)
-                pools = tuple(new[:2]) + pools[2:]
-                kvs = None if kvs is None else tuple(new[2:])
-            elif kind == "M":
-                (hh, new), _ = layer(layer_p, hh, lid, None, None, None,
-                                     ssm_state=pools[2:] + (k,),
-                                     state_rows=rows)
-                pools = pools[:2] + tuple(new)
-            else:
-                if kind == "E" and stacks:
-                    layer_p = dict(layer_p, moe=dict(layer_p["moe"], **{
-                        name: StackedLayer(w, k)
-                        for name, w in stacks.items()}))
-                (hh, _), aux = layer(layer_p, hh, lid, None, None, None)
-                if kind == "E":
-                    counts = counts + aux
-            return hh, pools, kvs, counts
-
-        def run(carry, attends, k, lid, lead=False):
-            hh, pools, kvs, counts = carry
-            layer_p = hybrid_layer_params(block, attends, k, lid, lead)
-            if stacks and not lead:
-                layer_p = _with_moe(layer_p, dict(layer_p["moe"], **{
-                    name: StackedLayer(w, lid - n_lead)
+            layer_p = layer_params(block, entry, at)
+            if stacks and "ffn" in entry:
+                layer_p = dict(layer_p, moe=dict(layer_p["moe"], **{
+                    name: StackedLayer(w, at["ffn"])
                     for name, w in stacks.items()}))
-            if attends:
+            mixer = entry[0]
+            if mixer == "mixers_attn":
                 (hh, new), aux = layer(layer_p, hh, lid, pools[:2], kvs,
-                                       None, kv_plane=k)
+                                       None, kv_plane=at[mixer])
                 pools = tuple(new[:2]) + pools[2:]
                 kvs = None if kvs is None else tuple(new[2:])
-            elif cfg.sliding_window:
-                # a window layer: plane k of the window pools, through
-                # their own table and rotary table (`layer` knows both)
+            elif mixer == "mixers_swa":
+                # the window pools, through their own table and rotary
+                # table (`layer` knows both)
                 (hh, new), aux = layer(layer_p, hh, lid, pools[2:4], None,
-                                       None, kv_plane=k, window=True)
+                                       None, kv_plane=at[mixer], window=True)
                 pools = pools[:2] + tuple(new[:2]) + pools[4:]
-            else:
+            elif mixer in ("mixers_ssm", "mixers_conv"):
                 (hh, new), aux = layer(layer_p, hh, lid, None, None, None,
-                                       ssm_state=pools[2:] + (k,),
+                                       ssm_state=pools[2:] + (at[mixer],),
                                        state_rows=rows)
                 pools = pools[:2] + tuple(new)
-            if counts is not None and not lead:
+            else:                   # a feed-forward alone owns no plane
+                (hh, _), aux = layer(layer_p, hh, lid, None, None, None)
+            if counts is not None and "ffn" in entry:
                 counts = counts + aux
             return hh, pools, kvs, counts
 
-        pattern = cfg.layer_pattern is not None
-        h, pages, scales, moe = (pattern_layer_loop if pattern
-                                 else hybrid_layer_loop)(
+        h, pages, scales, moe = layer_loop(
             cfg, (h, tuple(pages), None if scales is None else tuple(scales),
-                  counts0), run_letter if pattern else run)
+                  counts0), run)
         return h, moe, pages + (scales or ())
     lead = cfg.moe_first_k_dense
     block, stacks = _moe_stacks(params["block"], ctx)
@@ -871,7 +836,7 @@ class DynamicInferenceEngine:
         self.pause_admission = False
 
         # A model with state-space layers or gated short convolutions
-        # (cfg.attn_layer_period) keeps, beside its attention layers'
+        # (cfg.stack_plan) keeps, beside its attention layers'
         # pages, a recurrent state or a convolution's tail a slot
         # (PagedKVCache.state); one with EVA attention (cfg.eva_window_size)
         # one pooled row a chunk behind each closed window, in a second
@@ -1166,9 +1131,8 @@ class DynamicInferenceEngine:
                      f"{self.pool.state_bytes_per_slot} B a slot, prefix "
                      "reuse off (a prefix hit would skip tokens whose "
                      "state nobody kept)")
-            if cfg.layer_pattern is not None:
-                line += (f", layers={cfg.num_layers}, one sublayer each "
-                         f"({cfg.layer_pattern}): "
+            if len(cfg.stack_plan[0]) == 1:
+                line += (f", layers={cfg.num_layers}, one sublayer each: "
                          f"{cfg.num_attention_layers} attention + "
                          f"{cfg.num_recurrent_layers} {self._state_words()}"
                          f" + {cfg.num_moe_layers} of {cfg.num_moe_experts} "

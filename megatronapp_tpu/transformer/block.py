@@ -13,6 +13,7 @@ pre_mlp_layernorm → mlp → +residual).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 from typing import Optional
@@ -49,22 +50,24 @@ def _init_depth(cfg: TransformerConfig) -> int:
     return cfg.scaled_init_layers or cfg.num_layers
 
 
-def _init_mixer_half(rng, cfg: TransformerConfig, out_std, ssm: bool = False):
-    """A layer's first half: its norm and its mixer (attention, MLA, or with
-    `ssm` a hybrid stack's other kind: a selective-state-space mixer, with
-    cfg.shortconv_kernel a gated short convolution, with cfg.sliding_window
-    a sliding-window attention layer of cfg.window_heads query heads)."""
-    if ssm and cfg.sliding_window:
+def _init_mixer_half(rng, cfg: TransformerConfig, out_std,
+                     stack: str = "mixers_attn"):
+    """A layer's first half: its norm and its mixer, the one whose layers
+    cfg.stack_plan stacks under `stack`: attention (or MLA), under
+    "mixers_swa" a sliding-window attention layer of cfg.window_heads query
+    heads, under "mixers_conv" a gated short convolution, under "mixers_ssm"
+    a selective-state-space mixer."""
+    if stack == "mixers_swa":
         name = "attention"
         mix_p, mix_ax = init_attention_params(rng, cfg, out_std,
                                               heads=cfg.window_heads)
-    elif ssm and cfg.shortconv_kernel:
+    elif stack == "mixers_conv":
         from megatronapp_tpu.transformer.shortconv import (
             init_shortconv_params,
         )
         name = "conv"
         mix_p, mix_ax = init_shortconv_params(rng, cfg, out_std)
-    elif ssm:
+    elif stack == "mixers_ssm":
         from megatronapp_tpu.transformer.ssm import init_ssm_params, ssm_dims
         name = "ssm"
         mix_p, mix_ax = init_ssm_params(rng, cfg, ssm_dims(cfg), out_std)
@@ -376,8 +379,8 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
         x = residual + mlp_out.astype(residual.dtype)
         return x, aux
 
-    # A layer of a pattern stack (cfg.layer_pattern) is ONE sublayer: it
-    # holds one half, and runs that.
+    # A layer may be ONE sublayer (cfg.stack_plan): it holds one half, and
+    # runs that.
     new_cache = aux = None
     if "ln1_scale" in p:
         x, new_cache = mixer_half(x)
@@ -447,163 +450,85 @@ def _vmapped_layers(keys, init):
                                 is_leaf=_is_axes)
 
 
+# How the halves under each key of cfg.stack_plan start: init(rng, cfg=,
+# out_std=) -> (params, axes).
+HALF_INITS = {
+    **{stack: functools.partial(_init_mixer_half, stack=stack)
+       for stack in ("mixers_attn", "mixers_swa", "mixers_conv",
+                     "mixers_ssm")},
+    "ffn": _init_ffn_half,
+    "ffn_lead": functools.partial(_init_ffn_half, force_dense=True),
+    "ffn_dense": functools.partial(_init_ffn_half, force_dense=True),
+}
+
+
 def init_hybrid_block_params(rng, cfg: TransformerConfig):
-    """A hybrid stack (cfg.attn_layer_period): the state-space layers'
-    first halves stacked [num_ssm_layers, ...] under "mixers_ssm" (gated
-    short convolutions: [num_conv_layers, ...] under "mixers_conv"), the
-    sliding-window attention layers: [num_window_layers, ...] under
-    "mixers_swa"), the
-    attention layers' [num_attention_layers, ...] under "mixers_attn", and
-    the layers' feed-forward halves under "ffn", each in layer order
-    (hybrid_layer_loop walks them): every layer's [num_layers, ...], or in
-    an MoE model with cfg.moe_first_k_dense the MoE layers'
-    [num_layers - k, ...], the k leading layers' dense halves being
-    "ffn_lead" [k, ...]."""
-    if cfg.layer_pattern is not None:
-        return _init_pattern_block_params(rng, cfg)
-    out_std = cfg.init_method_std / jnp.sqrt(2.0 * _init_depth(cfg))
-    keys = jax.vmap(jax.random.split)(
-        jax.random.split(rng, cfg.num_layers))        # [L, (mixer, ffn)]
-    attends = np.asarray([cfg.layer_is_attention(i)
-                          for i in range(cfg.num_layers)])
-    lead = cfg.moe_first_k_dense
+    """A stack of several kinds of layer (cfg.stack_plan): the halves that
+    the plan names alike are stacked under that name, in layer order
+    ("mixers_attn" [num_attention_layers, ...], "ffn" a row each layer that
+    holds one, ...; layer_loop walks them). A mixer half is {"ln1_scale",
+    its mixer}, a feed-forward half {"ln2_scale", "moe" / "mlp"}.
 
-    kinds = {
-        "mixers_swa" if cfg.sliding_window else
-        "mixers_conv" if cfg.shortconv_kernel else "mixers_ssm": (
-            keys[~attends, 0], functools.partial(
-                _init_mixer_half, cfg=cfg, out_std=out_std, ssm=True)),
-        "mixers_attn": (keys[attends, 0], functools.partial(
-            _init_mixer_half, cfg=cfg, out_std=out_std, ssm=False)),
-        "ffn": (keys[lead:, 1], functools.partial(
-            _init_ffn_half, cfg=cfg, out_std=out_std)),
-        "ffn_lead": (keys[:lead, 1], functools.partial(
-            _init_ffn_half, cfg=cfg, out_std=out_std, force_dense=True)),
-    }
-    done = {k: _vmapped_layers(*v) for k, v in kinds.items() if len(v[0])}
-    return ({k: v[0] for k, v in done.items()},
-            {k: v[1] for k, v in done.items()})
-
-
-# Where each kind of a pattern stack's layers is stacked, in layer order
-# (cfg.layer_pattern's letters): the keys a period's stack uses for the same
-# halves, so that what reads a hybrid block by key reads this one.
-PATTERN_STACKS = {"M": "mixers_ssm", "*": "mixers_attn", "E": "ffn",
-                  "-": "ffn_dense"}
-
-
-def _init_pattern_block_params(rng, cfg: TransformerConfig):
-    """A pattern stack (cfg.layer_pattern): every layer is ONE sublayer
-    behind its norm, and the layers of a kind are stacked in layer order
-    under PATTERN_STACKS[kind]: "M" and "*" hold {"ln1_scale", mixer}, "E"
-    and "-" {"ln2_scale", "moe" / "mlp"}. A layer adds to the stream once,
-    so its residual-out projection starts at std / sqrt(depth) (HF
+    Layer i draws from split(rng, L)[i]: split once more into (mixer,
+    feed-forward) where a layer holds two halves, as it is where it is one
+    sublayer. The residual-out projections start at std / sqrt(halves x
+    depth): a layer adds to the stream once a half (single sublayers: HF
     `nemotron_h` rescale_prenorm_residual)."""
-    out_std = cfg.init_method_std / jnp.sqrt(1.0 * _init_depth(cfg))
+    plan = cfg.stack_plan
+    halves = len(plan[0])
+    out_std = cfg.init_method_std / jnp.sqrt(float(halves) * _init_depth(cfg))
     keys = jax.random.split(rng, cfg.num_layers)
-    letters = np.asarray(list(cfg.layer_pattern))
-    inits = {
-        "M": functools.partial(_init_mixer_half, cfg=cfg, out_std=out_std,
-                               ssm=True),
-        "*": functools.partial(_init_mixer_half, cfg=cfg, out_std=out_std),
-        "E": functools.partial(_init_ffn_half, cfg=cfg, out_std=out_std),
-        "-": functools.partial(_init_ffn_half, cfg=cfg, out_std=out_std,
-                               force_dense=True),
-    }
-    done = {PATTERN_STACKS[kind]: _vmapped_layers(keys[letters == kind], init)
-            for kind, init in inits.items() if kind in cfg.layer_pattern}
+    if halves > 1:
+        keys = jax.vmap(jax.random.split)(keys)         # [L, (mixer, ffn)]
+    done = {}
+    for stack in sorted({stack for layer in plan for stack in layer}):
+        rows = np.asarray([i for i, layer in enumerate(plan)
+                           if stack in layer])
+        half = plan[rows[0]].index(stack)       # which of a layer's keys
+        done[stack] = _vmapped_layers(
+            keys[rows] if halves == 1 else keys[rows, half],
+            functools.partial(HALF_INITS[stack], cfg=cfg, out_std=out_std))
     return ({k: v[0] for k, v in done.items()},
             {k: v[1] for k, v in done.items()})
 
 
-def tandem_runs(pattern: str):
-    """[(unit, repeats)] that spell `pattern`: from the left, the repeated
-    unit that covers the most letters (the shortest such), or where nothing
-    repeats the next letter alone, once. "MEMEM*EMEMEM*" is ("ME", 2), "M",
-    "*", ("EM", 3), "*"."""
-    runs, at, n = [], 0, len(pattern)
+def tandem_runs(layers):
+    """[(unit, repeats)] that spell `layers` (a string of letters, a tuple
+    of cfg.stack_plan's entries): from the left, the repeated unit that
+    covers the most of them (the shortest such), or where nothing repeats
+    the next one alone, once. "MEMEM*EMEMEM*" is ("ME", 2), "M", "*",
+    ("EM", 3), "*"."""
+    runs, at, n = [], 0, len(layers)
     while at < n:
         unit, reps = 1, 1
         for u in range(1, (n - at) // 2 + 1):
             r = 1
-            while pattern.startswith(pattern[at:at + u], at + r * u):
+            while layers[at + r * u:at + (r + 1) * u] == layers[at:at + u]:
                 r += 1
             if r > 1 and u * r > unit * reps:
                 unit, reps = u, r
-        runs.append((pattern[at:at + unit], reps))
+        runs.append((layers[at:at + unit], reps))
         at += unit * reps
     return runs
 
 
-def pattern_layer_loop(cfg: TransformerConfig, carry, run,
-                       scan_runs: bool = True):
-    """Walk a pattern stack (cfg.layer_pattern) in layer order as SCANNED
-    runs of its repeating units (tandem_runs; a unit's own repeats are
-    scanned inside it), the letters between them one after the other:
-    nothing is unrolled a layer, no stack is copied, and a layer reads the
-    stack of its own kind alone.
+def layer_loop(cfg: TransformerConfig, carry, run, scan_runs: bool = True):
+    """Walk a stack of several kinds of layer (cfg.stack_plan) in layer
+    order as SCANNED runs of its repeating units (tandem_runs; a unit's own
+    repeats are scanned inside it), the layers between them one after the
+    other: nothing is unrolled a layer, no stack is copied, and a layer
+    reads the stacks of its own halves alone.
 
-    run(carry, kind, k, layer_id) -> carry runs one layer: `kind` (static)
-    is its letter, k its index among the layers of its kind (its row of
-    PATTERN_STACKS[kind]; an attention layer's plane of the KV pools, a
-    state-space layer's of the state pools), layer_id its index in the
-    model; both Python ints outside a scan, int32 scalars inside one.
+    run(carry, layer, rows, layer_id) -> carry runs one layer: `layer`
+    (static) is its entry of the plan, rows[key] its row of the stack under
+    each key it names (the number of earlier layers that name it: an
+    attention layer's plane of the KV pools, a state-space layer's of the
+    state pools), layer_id its index in the model; Python ints outside a
+    scan, int32 scalars inside one.
 
-    scan_runs: False writes the runs out (block_forward, which is
-    differentiated: hybrid_layer_loop says what a scanned run costs
-    there)."""
-    def walk(carry, pattern, seen, lid):
-        # seen: {kind: layers of it before `pattern`}; lid: layers before it
-        for unit, reps in tandem_runs(pattern):
-            per = {kind: unit.count(kind) for kind in set(unit)}
-
-            def after(j, per=per, seen=seen):
-                """`seen` behind j turns of the unit."""
-                return {**seen, **{k: seen.get(k, 0) + j * n
-                                   for k, n in per.items()}}
-
-            def turn(c, j, unit=unit, after=after, lid=lid):
-                return walk(c, unit, after(j), lid + j * len(unit))
-
-            if reps == 1:           # one letter
-                carry = run(carry, unit, seen.get(unit, 0), lid)
-            elif scan_runs:
-                carry = jax.lax.scan(
-                    lambda c, j, turn=turn: (turn(c, j), None), carry,
-                    jnp.arange(reps, dtype=jnp.int32))[0]
-            else:
-                for j in range(reps):
-                    carry = turn(carry, j)
-            seen, lid = after(reps), lid + reps * len(unit)
-        return carry
-
-    return walk(carry, cfg.layer_pattern, {}, 0)
-
-
-def pattern_layer_params(stacked_p, kind: str, k):
-    """One layer's params out of a pattern stack: row k of its kind's."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, k, 0, keepdims=False),
-        stacked_p[PATTERN_STACKS[kind]])
-
-
-def hybrid_layer_loop(cfg: TransformerConfig, carry, run,
-                      scan_runs: bool = True):
-    """Walk a hybrid stack in layer order as SCANNED runs, not unrolled
-    and without carrying both kinds' weights through every layer: an outer
-    scan over whole periods of (scan `offset` state-space layers, the
-    period's one attention layer, scan the rest), then the same over a
-    last partial period.
-
-    run(carry, attends, k, layer_id) → carry runs one layer: `attends`
-    (static) says which kind, k is its index among the layers of its kind
-    (its row of "mixers_attn"/"mixers_ssm"/"mixers_conv", and for an
-    attention layer its plane of the KV pools), layer_id its index in the
-    model (its row of "ffn"); both are int32 scalars, traced inside the
-    scans.
-
-    scan_runs: False writes a period's runs out layer by layer and leaves
-    the scan over periods the only one. block_forward, which is
+    scan_runs: False keeps the scan over an outermost unit of several
+    layers and writes every other repeat out: the runs inside the unit and
+    a run of one kind wherever it lies. block_forward, which is
     differentiated, does. Measured on the sliding-window MoE training cell
     (PERF.md, PR 48): its step compiled for a described v5e holds 10.49 GiB
     with its run of three written out, 14.00 with the run scanned and 13.69
@@ -614,80 +539,60 @@ def hybrid_layer_loop(cfg: TransformerConfig, carry, run,
     and the scanned one 23,380. The serving steps keep nothing for a
     backward pass and scan every run of two or more: a launch a run.
 
-    An MoE model's cfg.moe_first_k_dense leading layers (dense
-    feed-forwards: another body) run first, one after the other, with k
-    and layer_id Python ints and `lead=True`; the periods are then counted
-    from the first layer behind them."""
-    period = cfg.attn_layer_period
-    lead = cfg.moe_first_k_dense
-    # the kinds' layers among the leading ones, and where the attention
-    # layer lies in a period counted from the first layer behind them
-    lead_attn = sum(cfg.layer_is_attention(i) for i in range(lead))
-    lead_rec = lead - lead_attn
-    offset = (cfg.attn_layer_offset - lead) % period
-    for i in range(lead):
-        attends = cfg.layer_is_attention(i)
-        k = sum(cfg.layer_is_attention(j) == attends for j in range(i))
-        carry = run(carry, attends, k, i, lead=True)
+    An MoE model's leading dense layers (the entries that hold "ffn_lead":
+    another body) are never folded into a run: they run first, one after
+    the other, and the units are found from the first layer behind them."""
+    plan = cfg.stack_plan
 
-    def ssm_run(carry, k0, lid0, count):
-        if count <= 0:
-            return carry
-        if count == 1:
-            return run(carry, False, k0, lid0)
-        if not scan_runs:
-            for j in range(count):
-                carry = run(carry, False, k0 + j, lid0 + j)
-            return carry
-        return jax.lax.scan(
-            lambda c, j: (run(c, False, k0 + j, lid0 + j), None), carry,
-            jnp.arange(count, dtype=jnp.int32))[0]
+    def held(layers):
+        return collections.Counter(k for layer in layers for k in layer)
 
-    def part_period(carry, p, count):
-        """The first `count` (static) layers of period p."""
-        lid0, k0, ka = p * period, p * (period - 1), p
-        if lead:
-            lid0, k0, ka = lid0 + lead, k0 + lead_rec, ka + lead_attn
-        carry = ssm_run(carry, k0, lid0, min(count, offset))
-        if count > offset:
-            carry = run(carry, True, ka, lid0 + offset)
-            carry = ssm_run(carry, k0 + offset, lid0 + offset + 1,
-                            count - offset - 1)
+    def walk(carry, layers, seen, lid, outermost=False):
+        # seen: {key: layers that name it before `layers`}; lid: layers
+        # before them
+        for unit, reps in tandem_runs(layers):
+            per = held(unit)
+
+            def after(j, per=per, seen=seen):
+                """`seen` behind j turns of the unit."""
+                return {**seen, **{k: seen.get(k, 0) + j * n
+                                   for k, n in per.items()}}
+
+            def turn(c, j, unit=unit, after=after, lid=lid):
+                return walk(c, unit, after(j), lid + j * len(unit))
+
+            if reps == 1:           # one layer
+                layer, = unit
+                carry = run(carry, layer,
+                            {k: seen.get(k, 0) for k in layer}, lid)
+            elif scan_runs or (outermost and len(unit) > 1):
+                carry = jax.lax.scan(
+                    lambda c, j, turn=turn: (turn(c, j), None), carry,
+                    jnp.arange(reps, dtype=jnp.int32))[0]
+            else:
+                for j in range(reps):
+                    carry = turn(carry, j)
+            seen, lid = after(reps), lid + reps * len(unit)
         return carry
 
-    whole, rest = divmod(cfg.num_layers - lead, period)
-    if whole == 1:
-        carry = part_period(carry, jnp.int32(0), period)
-    elif whole:
-        carry = jax.lax.scan(
-            lambda c, p: (part_period(c, p, period), None), carry,
-            jnp.arange(whole, dtype=jnp.int32))[0]
-    if rest:
-        carry = part_period(carry, jnp.int32(whole), rest)
-    return carry
+    lead = sum("ffn_lead" in layer for layer in plan)
+    for at in range(lead):
+        carry = walk(carry, plan[at:at + 1], held(plan[:at]), at)
+    return walk(carry, plan[lead:], held(plan[:lead]), lead, outermost=True)
 
 
-def hybrid_layer_params(stacked_p, attends: bool, k, layer_id,
-                        lead: bool = False):
-    """One layer's params out of a hybrid stack: row k of its kind's first
-    halves and its feed-forward half: row layer_id of "ffn_lead" for a
-    leading dense layer (`lead`), else of "ffn", which in a stack with
-    leading dense layers starts behind them."""
-    def row(stack, i):
-        return jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            stack)
-    kind = "mixers_attn" if attends else next(
-        k for k in ("mixers_ssm", "mixers_conv", "mixers_swa")
-        if k in stacked_p)
-    if lead:
-        ffn = row(stacked_p["ffn_lead"], layer_id)
-    elif "ffn_lead" in stacked_p:
-        ffn = row(stacked_p["ffn"], layer_id - jax.tree.leaves(
-            stacked_p["ffn_lead"])[0].shape[0])
-    else:
-        ffn = row(stacked_p["ffn"], layer_id)
-    return {**row(stacked_p[kind], k), **ffn}
+def layer_params(stacks, layer, rows):
+    """One layer's params out of cfg.stack_plan's stacks: row rows[key] of
+    the stack under each key its entry `layer` names, the halves merged
+    (layer_forward runs the halves a layer holds). The feed-forward's rows
+    are cut first: the order in which the stacks enter a scanned run as its
+    operands, which the steps' pinned texts hold."""
+    layer_p = {}
+    for key in reversed(layer):
+        layer_p.update(jax.tree.map(
+            lambda a, row=rows[key]: jax.lax.dynamic_index_in_dim(
+                a, row, 0, keepdims=False), stacks[key]))
+    return layer_p
 
 
 def init_block_params(rng, cfg: TransformerConfig, num_layers: int = None,
@@ -793,7 +698,7 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
 
         from megatronapp_tpu.transformer.moe import TRAIN_COUNTS
 
-        def one_layer(stacks, carry, k, lid, attends, lead):
+        def one_layer(stacks, carry, rows, lid, layer):
             # The layer's rows are cut out of the stacks INSIDE the
             # recomputed body: cut outside it they are its inputs, and the
             # scans would keep every layer's row for the backward pass, a
@@ -802,19 +707,14 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             # carry beside the stream, in both passes and under
             # recomputation (the counts are integers: no cotangent).
             h, aux_sum, counts = carry
-            windowed = bool(cfg.sliding_window) and not attends
-            # a pattern stack's `attends` is the layer's letter
-            layer_p = (pattern_layer_params(stacks, attends, k)
-                       if cfg.layer_pattern is not None else
-                       hybrid_layer_params(stacks, attends, k, lid, lead))
             (h2, _), aux = layer_forward(
-                layer_p, h, cfg,
+                layer_params(stacks, layer, rows), h, cfg,
                 rope_cos, rope_sin, attention_mask,
                 layer_id=lid + layer_offset, ctx=ctx,
                 segment_ids=segment_ids,
-                window_rope=window_rope if windowed else None,
+                window_rope=window_rope if "mixers_swa" in layer else None,
                 moe_counts=moe_counts)
-            if aux is not None:         # a leading dense layer has none
+            if aux is not None:         # a dense feed-forward has none
                 if moe_counts:
                     aux, layer_counts = aux
                     counts = counts + layer_counts
@@ -822,18 +722,14 @@ def block_forward(stacked_p, x: jnp.ndarray, cfg: TransformerConfig,
             return h2, aux_sum, counts
 
         # a body a kind of layer: which one a layer is, is static
-        pattern = cfg.layer_pattern is not None
-        bodies = {(a, ld): _remat_wrap(
-            functools.partial(one_layer, attends=a, lead=ld),
-            cfg.remat_policy)
-            for a in (set(cfg.layer_pattern) if pattern else (False, True))
-            for ld in (False, True)}
-        x, aux, counts = (pattern_layer_loop if pattern
-                          else hybrid_layer_loop)(
+        bodies = {layer: _remat_wrap(
+            functools.partial(one_layer, layer=layer), cfg.remat_policy)
+            for layer in set(cfg.stack_plan)}
+        x, aux, counts = layer_loop(
             cfg, (x, jnp.zeros((), jnp.float32),
                   jnp.zeros((len(TRAIN_COUNTS),), jnp.int32)),
-            lambda c, attends, k, lid, lead=False: bodies[attends, lead](
-                stacked_p, c, k, lid), scan_runs=False)
+            lambda c, layer, rows, lid: bodies[layer](
+                stacked_p, c, rows, lid), scan_runs=False)
         return (x, aux, counts) if moe_counts else (x, aux)
     if moe_counts:
         raise NotImplementedError(

@@ -29,6 +29,7 @@ from megatronapp_tpu.models.presets import (
 from megatronapp_tpu.ops.pallas.ssm_update import (
     ssm_update, ssm_update_reference,
 )
+from megatronapp_tpu.config.transformer_config import PATTERN_STACKS
 from megatronapp_tpu.transformer import block, ssm
 from perfbench import manifest
 
@@ -151,6 +152,9 @@ class TestPattern:
     def test_runs_spell_the_pattern(self, pattern, runs):
         assert block.tandem_runs(pattern) == runs
         assert "".join(u * r for u, r in runs) == pattern
+        # a plan's entries repeat as its letters do
+        assert block.tandem_runs(tuple((c,) for c in pattern)) == [
+            (tuple((c,) for c in u), r) for u, r in runs]
 
     @pytest.mark.parametrize("pattern", [
         "MEMEM*E", "MEMEM*EMEMEM*", NEMOTRON_3_NANO_PATTERN, "*M-E-M*"])
@@ -160,19 +164,20 @@ class TestPattern:
         through scanned runs (traced indices) and written out alike."""
         cfg = PRESETS["nemotron-3-nano-30b-a3b"](
             num_layers=len(pattern), layer_pattern=pattern)
-        kinds = "M*E-"
+        kinds = [PATTERN_STACKS[c] for c in "M*E-"]
 
-        def run(carry, kind, k, lid):
+        def run(carry, layer, rows, lid):
             seen, at = carry
+            kind, = layer
             row = jnp.stack([jnp.int32(lid), jnp.int32(kinds.index(kind)),
-                             jnp.int32(k)])
+                             jnp.int32(rows[kind])])
             return jax.lax.dynamic_update_slice(
                 seen, row[None], (at, 0)), at + 1
 
-        seen, at = block.pattern_layer_loop(
+        seen, at = block.layer_loop(
             cfg, (jnp.zeros((len(pattern), 3), jnp.int32), jnp.int32(0)),
             run, scan_runs=scan_runs)
-        want = [(i, kinds.index(c), pattern[:i].count(c))
+        want = [(i, "M*E-".index(c), pattern[:i].count(c))
                 for i, c in enumerate(pattern)]
         assert int(at) == len(pattern)
         assert np.asarray(seen).tolist() == [list(w) for w in want]
@@ -442,11 +447,133 @@ class TestShare:
             # the program's layer on this share: the same part
             cfg = MODEL.model_config(tiny, "float32", init_method_std=STD,
                                      compute_dtype=jnp.float32)
-            layer_p = block.pattern_layer_params(part["block"], "E", 1)
+            layer_p = block.layer_params(part["block"], ("ffn",), {"ffn": 1})
             (got, _), _ = block.layer_forward(layer_p, x, cfg, layer_id=3)
             np.testing.assert_allclose(got, x + routed + shared, atol=2e-5)
             assert float(jnp.abs(routed).max()) > WRONG
         np.testing.assert_allclose(total, routed_w, atol=2e-5)
+
+
+# ---- the plan of every stack of several kinds -------------------------------
+
+# (preset, what it is built with): (the layers that attend, (attention,
+# window, recurrent, state-space, convolution, expert layers)) as the eight
+# properties of TransformerConfig gave them at 67a371b, before they counted
+# over stack_plan: the six hybrid presets at their published depths, the
+# depths, periods and offsets of test_jamba.py's and test_granite.py's
+# test_any_period_and_offset, three leading dense layers, a uniform stack.
+PLANNED = [
+    ("jamba2-3b", {}, [7, 21], (2, 0, 26, 26, 0, 0)),
+    ("lfm2-24b-a2b", {}, list(range(2, 40, 4)), (10, 0, 30, 0, 30, 38)),
+    ("laguna-xs.2", {}, list(range(0, 40, 4)), (10, 30, 0, 0, 0, 39)),
+    ("mellum2-12b-a2.5b", {}, list(range(3, 28, 4)), (7, 21, 0, 0, 0, 28)),
+    ("granite-4.0-h-small", {}, [5, 15, 25, 35], (4, 0, 36, 36, 0, 40)),
+    ("nemotron-3-nano-30b-a3b", {}, [5, 12, 19, 26, 33, 42],
+     (6, 0, 23, 23, 0, 23)),
+    ("jamba2-3b", dict(num_layers=7, attn_layer_period=3,
+                       attn_layer_offset=2), [2, 5], (2, 0, 5, 5, 0, 0)),
+    ("jamba2-3b", dict(num_layers=5, attn_layer_period=4,
+                       attn_layer_offset=0), [0, 4], (2, 0, 3, 3, 0, 0)),
+    ("granite-4.0-h-small", dict(num_layers=7, attn_layer_period=3,
+                                 attn_layer_offset=2), [2, 5],
+     (2, 0, 5, 5, 0, 7)),
+    ("lfm2-24b-a2b", dict(num_layers=9, moe_first_k_dense=3), [2, 6],
+     (2, 0, 7, 0, 7, 6)),
+    ("gpt2-125m", {}, list(range(12)), (12, 0, 0, 0, 0, 0)),
+]
+MIXER_LETTER = {"mixers_attn": "A", "mixers_ssm": "S", "mixers_conv": "S",
+                "mixers_swa": "S"}
+
+
+def _letter(layer):
+    """A for an attention layer, S for a layer of the other kind, lower case
+    for a leading dense layer; a single sublayer that is no mixer: F."""
+    letter = MIXER_LETTER.get(layer[0], "F")
+    return letter.lower() if "ffn_lead" in layer else letter
+
+
+def _python_walk(monkeypatch, cfg, scan_runs):
+    """([(layer id, entry, rows)] as layer_loop hands them to run, the nest
+    it built: a letter a layer, "(...)xN" a scan of N turns), with
+    lax.scan replaced by a Python loop over its turns: no program is
+    traced."""
+    visits, nest = [], [[]]
+
+    def scan(turn, carry, turns):
+        nest.append([])
+        for j in np.asarray(turns).tolist():
+            carry, _ = turn(carry, j)
+            if j == 0:
+                unit = "".join(nest.pop())
+                nest.append([])         # the later turns spell the same
+        nest.pop()
+        nest[-1].append(f"({unit})x{len(turns)}")
+        return carry, None
+
+    def run(carry, layer, rows, lid):
+        visits.append((lid, layer, dict(rows)))
+        nest[-1].append(_letter(layer))
+        return carry
+
+    monkeypatch.setattr(jax.lax, "scan", scan)
+    block.layer_loop(cfg, None, run, scan_runs=scan_runs)
+    assert len(nest) == 1
+    return visits, "".join(nest[0])
+
+
+@pytest.mark.parametrize("preset,over,attends,counts", PLANNED, ids=[
+    "-".join([p] + [str(v) for v in o.values()]) for p, o, _, _ in PLANNED])
+def test_the_plan_spells_the_kinds_and_the_counts(monkeypatch, preset, over,
+                                                  attends, counts):
+    cfg = PRESETS[preset](**over)
+    plan = cfg.stack_plan
+    assert [i for i in range(cfg.num_layers)
+            if cfg.layer_is_attention(i)] == attends
+    assert (cfg.num_attention_layers, cfg.num_window_layers,
+            cfg.num_recurrent_layers, cfg.num_ssm_layers,
+            cfg.num_conv_layers, cfg.num_moe_layers) == counts
+    assert cfg.kv_planes == len(attends)
+    assert cfg.hybrid_stack is (plan is not None)
+    if plan is None:
+        return
+    assert len(plan) == cfg.num_layers
+    assert [i for i, layer in enumerate(plan)
+            if "mixers_attn" in layer] == attends
+    assert [i for i, layer in enumerate(plan) if "ffn_lead" in layer] \
+        == list(range(cfg.moe_first_k_dense))
+    # the walker: every layer once, in order, its rows the layers before it
+    # that name the same stack; scanned runs and written out alike
+    for scan_runs in (True, False):
+        visits, _ = _python_walk(monkeypatch, cfg, scan_runs)
+        assert visits == [
+            (i, layer, {k: sum(k in before for before in plan[:i])
+                        for k in layer})
+            for i, layer in enumerate(plan)]
+
+
+@pytest.mark.parametrize("preset,served,trained", [
+    # ROADMAP D15's nests at the published (layers, period, offset, leading
+    # dense layers); the differentiated walk keeps the scan over the unit
+    # and writes its runs out
+    ("jamba2-3b", "((S)x7A(S)x6)x2", "(SSSSSSSASSSSSS)x2"),
+    ("granite-4.0-h-small", "((S)x5A(S)x4)x4", "(SSSSSASSSS)x4"),
+    ("mellum2-12b-a2.5b", "((S)x3A)x7", "(SSSA)x7"),
+    ("laguna-xs.2", "a((S)x3A)x9(S)x3", "a(SSSA)x9SSS"),
+    ("lfm2-24b-a2b", "ss(A(S)x3)x9AS", "ss(ASSS)x9AS"),
+])
+def test_the_published_stacks_nests(monkeypatch, preset, served, trained):
+    cfg = PRESETS[preset]()
+    assert _python_walk(monkeypatch, cfg, True)[1] == served
+    assert _python_walk(monkeypatch, cfg, False)[1] == trained
+
+
+def test_one_periods_share_is_written_out_when_differentiated(monkeypatch):
+    """The share-training cell's four layers: no scan at all under a
+    gradient (a scanned run of three held 14.00 GiB against 10.49, PERF.md
+    PR 48), one over the run when served."""
+    cfg = PRESETS["mellum2-12b-a2.5b"](num_layers=4)
+    assert _python_walk(monkeypatch, cfg, False)[1] == "SSSA"
+    assert _python_walk(monkeypatch, cfg, True)[1] == "(S)x3A"
 
 
 def test_the_calibrated_bias_levels_the_experts_load():

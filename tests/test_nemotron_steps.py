@@ -1,13 +1,18 @@
-"""What NVIDIA-Nemotron-3-Nano-30B-A3B's PR left as it was, and what its
-three sources say alike: the paged steps of the stacks the benchmark already
-serves, which this PR's groups, inner width, single-sublayer layers and
-pattern loop leave instruction for instruction the parent's; and the
-preset, the benchmark's configuration file and the catalog's row."""
+"""The lowered programs and the seeded weights of the stacks the benchmark
+serves, pinned against the commit before the PR that last touched them: the
+paged steps of every serving configuration, the differentiated walk of the
+two stacks that train through block_forward's plan walker, and the block
+parameters of the six stacks of several kinds of layer (ISSUE 57: one plan,
+one walker, one initialiser); and what NVIDIA-Nemotron-3-Nano-30B-A3B's
+three sources say alike: the preset, the benchmark's configuration file and
+the catalog's row."""
 import copy
+import dataclasses
 import hashlib
 import json
 import os
 
+import numpy as np
 import pytest
 
 import jax
@@ -27,34 +32,57 @@ with open(os.path.join(ROOT, "perfbench", "configs",
 
 # ---- the other stacks' steps -----------------------------------------------
 
-# sha256[:16] of the two paged steps' lowered text at the parent commit
-# (e44ba59), by _step_hashes run there: this PR's groups, inner width,
-# single-sublayer layers and pattern loop leave the programs of the stacks
-# the benchmark already serves as they were, instruction for instruction.
+# sha256[:16] of the two paged steps' lowered text (_step_hashes) at the
+# commit before the change each comment names: a PR that leaves a stack's
+# program alone leaves its text alone, instruction for instruction.
 PARENT_STEP_SHA = {
-    "jamba2-3b": ("ce6a5eb71a9343d5", "98407e6d9df0494b"),
-    "granite-4.0-h-small": ("dfede5ecf3f97b30", "a61a29068a205cc8"),
-    "lfm2-24b-a2b": ("77e79b5e094da346", "b6ef03faa7549118"),
+    # Re-pinned at ISSUE 57 (one plan, one walker), whose texts differ from
+    # the parent's (67a371b) in rank-0 int32 arithmetic on rows and layer ids
+    # alone: the same `while` nest and carry, no slice or copy of a stack
+    # (CHANGES.md, PR 57, has the diff's count a step).
+    "jamba2-3b": ("0f87c8757f623313", "241f1c39e8748487"),
+    "granite-4.0-h-small": ("31ae6f773308eca7", "c759b474ffea6fb7"),
+    "lfm2-24b-a2b": ("f0fe5e3c2856f8f5", "4a54bb0234d14142"),
+    "laguna-xs.2": ("488dcdaa4a8d108b", "e16d9e4d4fad5566"),
+    # pinned at e44ba59, the commit before ISSUE 54
     "longcat-flash-chat": ("80a3e0ed3b2db33a", "f23b233fcf2ddf07"),
-    "laguna-xs.2": ("8d37ec62f7139778", "f5d104a2d77a44ad"),
     # pinned at 85aae3c, the commit before ISSUE 56 (heads in a page's rows
     # at 2 or 4 key/value heads of 128): the dense and the MoE cell's steps
     "gpt3-2.7b": ("39a6c57cfa2ab242", "4ec78d2d59251593"),
     "deepseek-v2-lite": ("0843401105404845", "6027d2a3ee99bd3c"),
+    # the parent's (67a371b) text, one of the two it had: its loop walked a
+    # Python set of letters, whose order changes with the process's hash seed
+    # and with it the order of a turn's row arithmetic. The plan is walked in
+    # layer order.
+    "nemotron-3-nano-30b-a3b": ("0177b96e930f522a", "d29989c6e5bbf080"),
 }
 
 
-def _step_hashes(name):
-    """(decode, prefill): sha256[:16] of configuration `name`'s two paged
-    steps' StableHLO at its model module's rehearsal sizes."""
+def _rehearsal(name, **over):
+    """(its model module, configuration `name` at the module's rehearsal
+    sizes with `over` on top as the program's model configuration)."""
     with open(os.path.join(ROOT, "perfbench", "configs",
                            name + ".json")) as f:
         config = json.load(f)
     model = manifest.load_module("models", config["model"])
-    config.update(copy.deepcopy(model.REHEARSAL))
-    cfg = model.model_config(config, "float32")
+    config.update(copy.deepcopy(model.REHEARSAL), **over)
+    return model, model.model_config(config, "float32")
+
+
+def _sha(*texts):
+    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts)
+
+
+def _step_texts(name):
+    """(decode, prefill): configuration `name`'s two paged steps' StableHLO
+    at its model module's rehearsal sizes."""
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    _, cfg = _rehearsal(name)
+    # a text is lowered from shapes: no weights are drawn
     eng = DynamicInferenceEngine(
-        model.init_params(cfg, seed=1), cfg, max_batch=2, max_seq_len=64,
+        jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                       jax.random.PRNGKey(0)),
+        cfg, max_batch=2, max_seq_len=64,
         paged=True, num_blocks=16, block_size=4, prefill_chunk=8)
     sds = jax.ShapeDtypeStruct
 
@@ -62,7 +90,7 @@ def _step_hashes(name):
         return sds(shape, jnp.int32)
 
     b = eng.max_batch
-    texts = (
+    return (
         eng._decode.lower(
             eng.params, i32(b, 1), eng._pools(), eng.pool.scales,
             eng._tables(), i32(b), sds((b,), bool), None).as_text(),
@@ -71,7 +99,10 @@ def _step_hashes(name):
             eng.pool.scales, eng._tables(slice(0, 1)), i32(1), i32(1),
             sds((1,), bool), None, i32(1) if eng.has_state else None,
             i32(1)).as_text())
-    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts)
+
+
+def _step_hashes(name):
+    return _sha(*_step_texts(name))
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_STEP_SHA))
@@ -79,6 +110,95 @@ def test_the_other_stacks_steps_lower_to_the_parents_text(name):
     """(A change to the steps' other code moves these hashes too: re-pin
     them from the commit before it.)"""
     assert _step_hashes(name) == PARENT_STEP_SHA[name]
+
+
+# ---- the differentiated walk ------------------------------------------------
+
+# Loss and gradients of a micro-batch through block_forward's walk
+# (scan_runs=False): two periods of the share-training cell's stack (a scan
+# over the period, its run of three window layers written out, every body
+# recomputed "selective"), and the pattern stack at its rehearsal letters.
+TRAINED = {
+    "mellum2-12b-a2.5b": dict(
+        num_hidden_layers=8, mlp_layer_types=["sparse"] * 8,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"]
+        + ["sliding_attention"] * 3 + ["full_attention"]),
+    "nemotron-3-nano-30b-a3b": {},
+}
+# Mellum's differs from the parent's (67a371b) text in rank-0 int32 index
+# arithmetic and in one [2] int32 stack more among the forward scan's
+# residuals (a turn's feed-forward row, which the parent read off the layer
+# id); the same scan over the period, the same bodies written out. The
+# pattern stack's is this PR's own: its "ME" x 2 is an outermost unit of
+# several layers, which ISSUE 57's one rule keeps a scan where the parent
+# wrote every repeat of a pattern out (no cell trains one).
+PARENT_TRAIN_SHA = {
+    "mellum2-12b-a2.5b": "fba39901a614aedb",
+    "nemotron-3-nano-30b-a3b": "0fbd2c3ff870a300",
+}
+
+
+def _train_text(name):
+    """StableHLO of value_and_grad(gpt_loss) of configuration `name` at
+    TRAINED's sizes, over 2 x 32 tokens (packed, where no layer keeps a
+    state along the row)."""
+    from megatronapp_tpu.models.gpt import gpt_loss, init_gpt_params
+    _, cfg = _rehearsal(name, **TRAINED[name])
+    cfg = dataclasses.replace(cfg, remat_policy="selective")
+    params = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+
+    def loss(p, tokens, labels, mask, segments):
+        # a state-space layer's state would cross packed segments
+        return gpt_loss(p, tokens, labels, mask, cfg,
+                        segment_ids=None if cfg.num_ssm_layers else segments)
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, tok, tok, jax.ShapeDtypeStruct((2, 32), jnp.float32),
+        tok).as_text()
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TRAIN_SHA))
+def test_the_differentiated_walk_lowers_to_the_parents_text(name):
+    assert _sha(_train_text(name)) == (PARENT_TRAIN_SHA[name],)
+
+
+# ---- the seeded weights ----------------------------------------------------
+
+# sha256[:16] over the leaves (path, shape, dtype, bytes) of
+# gpt_dense.init_params(cfg, seed=1)["block"] at rehearsal sizes, at the
+# commit before ISSUE 57: the benchmark draws its models through this
+# initialiser (Nemotron's module then calibrates its routers' bias on top),
+# and another draw routes otherwise.
+PARENT_BLOCK_SHA = {
+    "jamba2-3b": "bbb15e90495114a2",
+    "granite-4.0-h-small": "1b4c44dfe5f00191",
+    "lfm2-24b-a2b": "0a527616cdd78658",
+    "laguna-xs.2": "c553657b52bfe2f2",
+    "mellum2-12b-a2.5b": "ed2858f0fb822bf6",
+    "nemotron-3-nano-30b-a3b": "c364afca2a250b75",
+}
+
+
+def _block_sha(name):
+    _, cfg = _rehearsal(name)
+    digest = hashlib.sha256()
+    # the draw every model module starts from
+    draw = manifest.load_module("models", "gpt_dense").init_params
+    leaves = jax.tree_util.tree_leaves_with_path(
+        draw(cfg, seed=1)["block"])
+    for path, leaf in sorted(
+            (jax.tree_util.keystr(path), leaf) for path, leaf in leaves):
+        leaf = np.asarray(leaf)
+        digest.update(f"{path} {leaf.shape} {leaf.dtype}".encode())
+        digest.update(leaf.tobytes())
+    return digest.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_BLOCK_SHA))
+def test_the_seeded_block_is_the_parents_bit_for_bit(name):
+    assert _block_sha(name) == PARENT_BLOCK_SHA[name]
 
 
 class TestThreeSourcesAgree:
