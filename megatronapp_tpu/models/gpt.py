@@ -346,6 +346,13 @@ def gpt_loss(p, tokens: jnp.ndarray, targets: jnp.ndarray,
                     cfg.moe_experts_here[1] * moe_layers),
                 "moe_layer_passes": jnp.int32(moe_layers),
                 "router_loss": aux}
+            if segment_ids is not None:
+                # and the tiles its flash kernels computed of the packed
+                # rows' causal triangles and bands
+                from megatronapp_tpu.transformer.attention import (
+                    flash_tile_counts,
+                )
+                more["sums"].update(flash_tile_counts(cfg, segment_ids, ctx))
     if zigzag_active(cfg, ctx) and segment_ids is None:
         # Logits are in zigzag order — permute targets/mask to match (the
         # masked-mean CE is permutation-invariant).

@@ -10,6 +10,13 @@ replacement obligation). This kernel:
 - a sliding window (`window` keys a query, inside its segment): the grid of
   a window layer's kernels holds only the tiles its band can touch
   (`_BandGrid`), under names of their own (`flash_window_*`);
+- packed documents (`segment_ids`): a call of more than one tile a sequence
+  is handed, by scalar prefetch, every tile's range of document ids
+  (`segment_tile_table`). A tile whose query ids and key ids cannot be equal
+  is neither computed nor copied (the index maps hold the row's last live
+  tile, as they do above the causal diagonal of such a call), and a tile
+  inside one document under the diagonal takes the unmasked path; exact for
+  any ids, the same numbers as masking every tile;
 - GQA: KV heads indexed as h // group via BlockSpec index maps, no repeat;
 - custom VJP with two backward kernels (dq; dk/dv), log-sum-exp residuals —
   the FlashAttention-2 recipe;
@@ -81,43 +88,54 @@ def _valid_mask(q_start, k_start, block_q, block_kv, seq_q, seq_kv,
     return valid
 
 
-def _dispatch_tiles(compute, causal, edge_mask, q_start, k_start,
-                    block_q, block_kv, window=0, live=None):
+def _dispatch_tiles(compute, causal, mask_all, q_start, k_start,
+                    block_q, block_kv, window=0, live=None, docs=None):
     """Shared tile dispatch for all three kernels: skip tiles entirely
     above the causal diagonal, and route interior tiles (strictly below
-    the diagonal, in-bounds, no segment ids) to compute(masked=False) —
-    skipping the iota/compare/select chain on [bq, bkv] is the kernels'
-    main VPU saving. A window layer's grid (`_BandGrid`) holds no tile
-    outside the band but the steps a short row of tiles leaves over, on
-    which `live` is false; its interior tiles lie inside the band too."""
+    the diagonal, in-bounds) to compute(masked=False), which skips the
+    iota/compare/select chain on [bq, bkv] (1.4-3.6% of a kernel's time at
+    D 128 and 512 x 512 on a v5e with 28% of the tiles unmasked: PR 58).
+    A window layer's grid (`_BandGrid`) holds no tile outside the band but
+    the steps a short row of tiles leaves over, on which `live` is false;
+    its interior tiles lie inside the band too.
+
+    `docs` (a packed call that was handed its table, `segment_tile_table`)
+    is (meet, one) of this tile: whether the query tile's range of document
+    ids meets the key tile's, and whether both are one and the same id. A
+    tile that does not meet is not computed (no pair has equal ids), and an
+    interior tile of one document needs no mask. `mask_all`: every tile
+    takes the mask (ragged tiles, or segment ids without a table)."""
+    meet, one = docs if docs is not None else (None, None)
+
+    def both(*terms):
+        terms = [t for t in terms if t is not None]
+        return functools.reduce(lambda a, b: a & b, terms) if terms else None
+
+    def when(condition, masked):
+        if condition is None:
+            compute(masked)
+        else:
+            pl.when(condition)(lambda: compute(masked))
+
+    def on_or_under_the_diagonal():
+        if causal and not window:
+            return q_start + block_q - 1 >= k_start
+
+    if mask_all:
+        when(both(on_or_under_the_diagonal(), live, meet), True)
+        return
     if window:
-        if edge_mask:
-            pl.when(live)(lambda: compute(True))
-        else:
-            interior = ((q_start >= k_start + block_kv)
-                        & (q_start + block_q - 1 - k_start < window))
-            pl.when(live & interior)(lambda: compute(False))
-            pl.when(live & jnp.logical_not(interior))(
-                lambda: compute(True))
+        interior = ((q_start >= k_start + block_kv)
+                    & (q_start + block_q - 1 - k_start < window))
     elif causal:
-        if edge_mask:
-            @pl.when(q_start + block_q - 1 >= k_start)
-            def _():
-                compute(True)
-        else:
-            interior = q_start >= k_start + block_kv
-
-            @pl.when(interior)
-            def _():
-                compute(False)
-
-            @pl.when(jnp.logical_not(interior)
-                     & (q_start + block_q - 1 >= k_start))
-            def _():
-                compute(True)
+        interior = q_start >= k_start + block_kv
     else:
-        compute(edge_mask)
-
+        interior = None
+    interior = both(interior, one)
+    when(both(interior, live), False)
+    if interior is not None:
+        when(both(jnp.logical_not(interior), on_or_under_the_diagonal(),
+                  live, meet), True)
 
 
 class _BandGrid(NamedTuple):
@@ -167,121 +185,261 @@ class _BandGrid(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# BlockSpec builders shared by all kernels. Every kernel runs on a
-# (b, h, major, minor) grid where (major, minor) is (iq, ik) for
-# q-major kernels (forward, dq) and (ik, iq) for kv-major ones (dkv);
-# `q_major` picks which grid slot indexes the q blocks. Segment-id and
-# lse/delta specs come in straight ([bq,1] columns) and transposed
-# ([1,bq] lane rows) orientations.
+# The documents' table of a packed call. `segment_ids` restricts attention
+# to pairs of equal id, so a tile whose query ids and key ids cannot be
+# equal has nothing to compute. The table holds, a (sequence, tile), the
+# range [min, max] of the tile's ids and the run [lo, hi] of the other
+# side's tiles that the tile can meet inside the grid's causal or band
+# part; the kernels and their index maps read it from SMEM (scalar
+# prefetch). It is exact for any ids: sorted ids (packed documents) make a
+# row's live tiles one run, unsorted ones only skip less.
+# ---------------------------------------------------------------------------
+
+_TABLE_COLUMNS = 4      # a tile's (min id, max id, lo, hi)
+
+
+def _tile_ranges(ids, block):
+    """[B, S] ids -> ([B, n], [B, n]): the least and the largest id of
+    every tile of `block` positions (a ragged last tile counts its real
+    positions alone)."""
+    b, s = ids.shape
+    n = _cdiv(s, block)
+    pad = ((0, 0), (0, n * block - s))
+    big = jnp.iinfo(jnp.int32)
+    return (jnp.pad(ids, pad, constant_values=big.max)
+            .reshape(b, n, block).min(-1),
+            jnp.pad(ids, pad, constant_values=big.min)
+            .reshape(b, n, block).max(-1))
+
+
+def _grid_part(nq, nk, block_q, block_kv, causal, window):
+    """bool [nq, nk] (numpy, static): the tiles that the kernels' grids
+    compute whatever the documents are — the band's tiles of a window
+    layer, the tiles on and under the diagonal of a causal one, else all."""
+    iq, ik = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    if window:
+        lo, hi = _BandGrid(window, block_q, block_kv, nq, nk).kv_range(iq, np)
+        return (lo <= ik) & (ik <= hi)
+    if causal:
+        return iq * block_q + block_q - 1 >= ik * block_kv
+    return np.ones((nq, nk), bool)
+
+
+def segment_tile_table(q_ids, kv_ids, block_q, block_kv, causal=True,
+                       window=0):
+    """The table of one packed call, from its [B, Sq] and [B, Skv] int32
+    document ids and its tiles: (q_table [B, nq, 4], kv_table [B, nk, 4],
+    tiles, computed). A row of q_table is a q tile's (min id, max id, first
+    and last kv tile it is live with), a row of kv_table the same of a kv
+    tile over the q tiles. A tile pair is live where it lies in the grid's
+    causal or band part (`_grid_part`) and the two ranges of ids meet;
+    `tiles` (a Python int) counts the part over the batch and `computed`
+    (int32 scalar) the live pairs: what the kernels compute of it. A tile
+    with no live partner holds lo > hi clamped to a valid index; nothing
+    is computed for it either way."""
+    q_min, q_max = _tile_ranges(q_ids, block_q)
+    k_min, k_max = _tile_ranges(kv_ids, block_kv)
+    nq, nk = q_min.shape[1], k_min.shape[1]
+    part = _grid_part(nq, nk, block_q, block_kv, causal, window)
+    live = ((q_min[:, :, None] <= k_max[:, None, :])
+            & (k_min[:, None, :] <= q_max[:, :, None]) & part)
+
+    def run(live, n, axis):
+        at = jnp.arange(n, dtype=jnp.int32).reshape(
+            (1, 1, n) if axis == 2 else (1, n, 1))
+        lo = jnp.min(jnp.where(live, at, n - 1), axis=axis)
+        return lo, jnp.maximum(jnp.max(jnp.where(live, at, 0), axis=axis),
+                               lo)
+
+    q_table = jnp.stack([q_min, q_max, *run(live, nk, 2)], axis=-1)
+    kv_table = jnp.stack([k_min, k_max, *run(live, nq, 1)], axis=-1)
+    return (q_table.astype(jnp.int32), kv_table.astype(jnp.int32),
+            int(part.sum()) * q_ids.shape[0], jnp.sum(live, dtype=jnp.int32))
+
+
+def segment_tile_counts(segment_ids, block_q=None, block_kv=None,
+                        causal=True, window=0):
+    """(tiles, computed) of `flash_attention(..., segment_ids=segment_ids)`
+    with the same tiles and window: the tile pairs of its grids' causal or
+    band part over the batch, and how many of them its kernels compute
+    (`segment_tile_table`; a call of one tile a sequence is handed no table
+    and computes its one tile, which is what the table says of it too)."""
+    block_q, block_kv = flash_tiles(segment_ids.shape[1], block_q, block_kv,
+                                    window)
+    ids = segment_ids.astype(jnp.int32)
+    return segment_tile_table(ids, ids, block_q, block_kv, causal,
+                              window)[2:]
+
+
+# ---------------------------------------------------------------------------
+# How a kernel's grid walks the tiles, and the BlockSpecs that follow it.
+# Every kernel runs on a (b, h, row, step) grid. Rows are q tiles and steps
+# their kv tiles in the q-major kernels (forward, dq), the other way round
+# in the kv-major ones (dkv). Segment-id and lse/delta specs come in straight
+# ([bq,1] columns) and transposed ([1,bq] lane rows) orientations.
 # ---------------------------------------------------------------------------
 
 
-def _spec_q(block_q, d, q_major):
-    if q_major:
-        return pl.BlockSpec((1, 1, block_q, d),
-                            lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    return pl.BlockSpec((1, 1, block_q, d),
-                        lambda b_, h_, ik, iq: (b_, h_, iq, 0))
+class _Walk(NamedTuple):
+    """One kernel's walk over a call's tiles. `band` (a window layer): a
+    row's steps are its band's tiles alone, else all the tiles of the other
+    side. `tabled` (a packed call of more than one tile): the kernel and
+    its index maps are handed the documents' table by scalar prefetch,
+    flat: q_table [B * nq * 4] and kv_table [B * nk * 4]."""
+    block_q: int
+    block_kv: int
+    nq: int
+    nk: int
+    q_major: bool
+    band: Optional[_BandGrid] = None
+    tabled: bool = False
+
+    @property
+    def window(self) -> int:
+        return self.band.window if self.band else 0
+
+    @property
+    def rows(self) -> int:
+        return self.nq if self.q_major else self.nk
+
+    @property
+    def steps(self) -> int:
+        if self.band:
+            return self.band.kv_steps if self.q_major else self.band.q_steps
+        return self.nk if self.q_major else self.nq
+
+    def tile(self, row, step):
+        """(iq, ik, live) that a grid step stands for; `live` is None but
+        on a band's grid."""
+        if self.band is None:
+            other, live = step, None
+        elif self.q_major:
+            other, live = self.band.kv_tile(row, step)
+        else:
+            other, live = self.band.q_tile(row, step)
+        return (row, other, live) if self.q_major else (other, row, live)
+
+    def copied(self, b, row, step, tables):
+        """(iq, ik) whose blocks a grid step holds: the step's own tile,
+        held inside the run [lo, hi] of the row's live tiles where there is
+        a table, so a dead step (another document's tile, one above the
+        causal diagonal) starts no copy."""
+        iq, ik, _ = self.tile(row, step)
+        if tables:
+            q_table, kv_table = tables
+            if self.q_major:
+                at = (b * self.nq + iq) * _TABLE_COLUMNS
+                ik = jnp.minimum(jnp.maximum(ik, q_table[at + 2]),
+                                 q_table[at + 3])
+            else:
+                at = (b * self.nk + ik) * _TABLE_COLUMNS
+                iq = jnp.minimum(jnp.maximum(iq, kv_table[at + 2]),
+                                 kv_table[at + 3])
+        return iq, ik
+
+    def docs(self, b, iq, ik, tables):
+        """(meet, one) of tile pair (iq, ik) for `_dispatch_tiles`."""
+        q_table, kv_table = tables
+        at_q = (b * self.nq + iq) * _TABLE_COLUMNS
+        at_k = (b * self.nk + ik) * _TABLE_COLUMNS
+        q_min, q_max = q_table[at_q], q_table[at_q + 1]
+        k_min, k_max = kv_table[at_k], kv_table[at_k + 1]
+        return ((q_min <= k_max) & (k_min <= q_max),
+                (q_min == q_max) & (k_min == k_max) & (q_min == k_min))
+
+    def enter(self, refs):
+        """A kernel's first lines: (refs without the table, step, iq, ik,
+        live, docs) of this grid step."""
+        tables, refs = (refs[:2], refs[2:]) if self.tabled else (None, refs)
+        row = pl.program_id(2)
+        step = pl.program_id(3)
+        iq, ik, live = self.tile(row, step)
+        docs = self.docs(pl.program_id(0), iq, ik, tables) if tables else None
+        return refs, step, iq, ik, live, docs
+
+    def specs(self, d, group):
+        """BlockSpecs by operand. Blocks: q [bq, d], kv [bkv, d] of the
+        group's key/value head, dkv [bkv, d] a query head; qcol / qrow the
+        [bq, 1] columns and [1, bq] lane rows of lse and delta; q_t / dkv_t
+        the transposed kernels' [d, bq] and [d, bkv] outputs; segs / segs_t
+        the (q ids, kv ids) of the straight ([bq, 1], [1, bkv]) and the
+        transposed ([1, bq], [bkv, 1]) kernels."""
+        bq, bkv = self.block_q, self.block_kv
+
+        def spec(shape, index):
+            return pl.BlockSpec(shape, lambda b_, h_, r, t, *tables: index(
+                b_, h_, *self.copied(b_, r, t, tables)))
+
+        return {
+            "q": spec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            "kv": spec((1, 1, bkv, d),
+                       lambda b_, h_, iq, ik: (b_, h_ // group, ik, 0)),
+            "dkv": spec((1, 1, bkv, d),
+                        lambda b_, h_, iq, ik: (b_, h_, ik, 0)),
+            "qcol": spec((1, 1, bq, 1),
+                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            "qrow": spec((1, 1, 1, bq),
+                         lambda b_, h_, iq, ik: (b_, h_, 0, iq)),
+            "q_t": spec((1, 1, d, bq),
+                        lambda b_, h_, iq, ik: (b_, h_, 0, iq)),
+            "dkv_t": spec((1, 1, d, bkv),
+                          lambda b_, h_, iq, ik: (b_, h_, 0, ik)),
+            "segs": [
+                spec((1, bq, 1), lambda b_, h_, iq, ik: (b_, iq, 0)),
+                spec((1, 1, bkv), lambda b_, h_, iq, ik: (b_, 0, ik))],
+            "segs_t": [
+                spec((1, 1, bq), lambda b_, h_, iq, ik: (b_, 0, iq)),
+                spec((1, bkv, 1), lambda b_, h_, iq, ik: (b_, ik, 0))],
+        }
+
+    def call(self, kernel, b, h, tables, in_specs, out_specs, out_shape,
+             scratch_shapes, name):
+        """The pallas_call of `kernel` on this walk's grid, to be applied
+        to (*tables, *inputs)."""
+        grid = dict(grid=(b, h, self.rows, self.steps), in_specs=in_specs,
+                    out_specs=out_specs, scratch_shapes=scratch_shapes)
+        if self.tabled:
+            grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables), **grid))
+        return pl.pallas_call(kernel, out_shape=out_shape,
+                              interpret=_interpret(), name=name, **grid)
 
 
-def _spec_kv(block_kv, d, group, q_major):
-    if q_major:
-        return pl.BlockSpec(
-            (1, 1, block_kv, d),
-            lambda b_, h_, iq, ik, g_=group: (b_, h_ // g_, ik, 0))
-    return pl.BlockSpec(
-        (1, 1, block_kv, d),
-        lambda b_, h_, ik, iq, g_=group: (b_, h_ // g_, ik, 0))
+def _walk_of(block_q, block_kv, nq, nk, q_major, window, tables) -> _Walk:
+    return _Walk(block_q, block_kv, nq, nk, q_major,
+                 _BandGrid(window, block_q, block_kv, nq, nk) if window
+                 else None, bool(tables))
 
 
-def _spec_segs(block_q, block_kv, q_major, transposed):
-    """(q_segs, kv_segs) specs. Straight orientation reads q ids as a
-    [bq, 1] column from [B,Sq,1] and kv ids as a [1, bkv] row from
-    [B,1,Skv]; the transposed kernels read q ids as a [1, bq] row and kv
-    ids as a [bkv, 1] column (callers swap the arrays to match)."""
-    if transposed:
-        q_shape, q_idx = (1, 1, block_q), (lambda b_, m, n: (b_, 0, m))
-        k_shape, k_idx = (1, block_kv, 1), (lambda b_, m, n: (b_, n, 0))
-    else:
-        q_shape, q_idx = (1, block_q, 1), (lambda b_, m, n: (b_, m, 0))
-        k_shape, k_idx = (1, 1, block_kv), (lambda b_, m, n: (b_, 0, n))
-    iq_of = (lambda mj, mn: mj) if q_major else (lambda mj, mn: mn)
-    ik_of = (lambda mj, mn: mn) if q_major else (lambda mj, mn: mj)
-    return [
-        pl.BlockSpec(q_shape,
-                     lambda b_, h_, mj, mn: q_idx(b_, iq_of(mj, mn),
-                                                  ik_of(mj, mn))),
-        pl.BlockSpec(k_shape,
-                     lambda b_, h_, mj, mn: k_idx(b_, iq_of(mj, mn),
-                                                  ik_of(mj, mn))),
-    ]
-
-
-def _spec_qcol(block_q, q_major):
-    """[bq, 1] per-q-row scalars (straight-orientation lse/delta)."""
-    if q_major:
-        return pl.BlockSpec((1, 1, block_q, 1),
-                            lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    return pl.BlockSpec((1, 1, block_q, 1),
-                        lambda b_, h_, ik, iq: (b_, h_, iq, 0))
-
-
-def _spec_qrow(block_q, q_major):
-    """[1, bq] lane-row scalars (transposed-orientation lse/delta)."""
-    if q_major:
-        return pl.BlockSpec((1, 1, 1, block_q),
-                            lambda b_, h_, iq, ik: (b_, h_, 0, iq))
-    return pl.BlockSpec((1, 1, 1, block_q),
-                        lambda b_, h_, ik, iq: (b_, h_, 0, iq))
-
-
-def _band_specs(band: _BandGrid, d, group, q_major, has_segs):
-    """The window kernels' BlockSpecs by operand, on a (b, h, row, step)
-    grid whose (row, step) the band turns into (iq, ik): rows are q tiles
-    and steps their kv tiles (`q_major`: forward, dq), or the other way
-    round (dkv). Straight orientation only."""
-    if q_major:
-        iq_of = lambda row, step: row                       # noqa: E731
-        ik_of = lambda row, step: band.kv_tile(row, step)[0]  # noqa: E731
-    else:
-        iq_of = lambda row, step: band.q_tile(row, step)[0]   # noqa: E731
-        ik_of = lambda row, step: row                       # noqa: E731
-    bq, bkv = band.block_q, band.block_kv
-    specs = {
-        "q": pl.BlockSpec((1, 1, bq, d), lambda b_, h_, r, t: (
-            b_, h_, iq_of(r, t), 0)),
-        "kv": pl.BlockSpec((1, 1, bkv, d), lambda b_, h_, r, t: (
-            b_, h_ // group, ik_of(r, t), 0)),
-        "dkv": pl.BlockSpec((1, 1, bkv, d), lambda b_, h_, r, t: (
-            b_, h_, ik_of(r, t), 0)),
-        "qcol": pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, r, t: (
-            b_, h_, iq_of(r, t), 0)),
-    }
-    specs["segs"] = [
-        pl.BlockSpec((1, bq, 1), lambda b_, h_, r, t: (b_, iq_of(r, t), 0)),
-        pl.BlockSpec((1, 1, bkv), lambda b_, h_, r, t: (b_, 0, ik_of(r, t))),
-    ] if has_segs else []
-    return specs
+def _doc_tables(segs, block_q, block_kv, nq, nk, causal, window):
+    """() for a call that is handed no table (no segment ids, or one tile a
+    sequence: nothing to skip, and the kernels stay the ones they were),
+    else the call's (q_table, kv_table), flat for SMEM."""
+    if segs is None or nq * nk == 1:
+        return ()
+    q_segs, kv_segs = segs                  # [B,Sq,1] / [B,1,Skv]
+    q_table, kv_table, _, _ = segment_tile_table(
+        q_segs[:, :, 0], kv_segs[:, 0, :], block_q, block_kv, causal, window)
+    return q_table.reshape(-1), kv_table.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
-                num_kv, seq_q, seq_kv, has_segs, bounded, band=None):
-    """`band` (a window layer): the grid's last axis walks the band's
-    `num_kv` steps of q tile iq, not all the kv tiles."""
+def _fwd_kernel(*refs, scale, causal, walk: _Walk, seq_q, seq_kv, has_segs,
+                bounded):
+    """The grid's last axis walks `walk.steps` kv tiles of q tile iq: all of
+    them, or a window layer's band alone."""
+    refs, step, iq, ik, live, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref,
          o_ref, lse_ref, acc, m_scr, l_scr) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(2)
-    step = pl.program_id(3)
-    window = band.window if band else 0
-    ik, live = band.kv_tile(iq, step) if band else (step, None)
+    block_q, block_kv = walk.block_q, walk.block_kv
+    window = walk.window
 
     @pl.when(step == 0)
     def _init():
@@ -324,10 +482,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
         acc[:] = acc[:] * corr[:, None] + pv
         m_scr[:, 0] = m_new
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv, window, live)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, window, live, docs)
 
-    @pl.when(step == num_kv - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         l = l_scr[:, 0]
         o_ref[0, 0] = (acc[:] / jnp.maximum(l, 1e-20)[:, None]).astype(
@@ -339,24 +497,24 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_kv,
         lse_ref[0, 0] = lse[:, None]
 
 
-def _fwd_kernel_t(*refs, scale, causal, block_q, block_kv,
-                  num_kv, seq_q, seq_kv, has_segs, bounded):
+def _fwd_kernel_t(*refs, scale, causal, walk: _Walk, seq_q, seq_kv,
+                  has_segs, bounded):
     """Forward in transposed orientation for D < 128: scores as
     s^T = k·q^T [bkv, bq], accumulator o^T [D, bq] filled by
     (p·v)^T = v^T·p — full-width contraction (bkv) and output (bq) dims
     where the straight orientation's p@v has only D output lanes. The
     online-softmax running max/sum live as [1, bq] lane rows; reductions
     run over sublanes (axis 0)."""
+    refs, step, iq, ik, _, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref,
          ot_ref, lse_ref, acc, m_scr, l_scr) = refs
     else:
         q_ref, k_ref, v_ref, ot_ref, lse_ref, acc, m_scr, l_scr = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    block_q, block_kv = walk.block_q, walk.block_kv
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
@@ -397,10 +555,10 @@ def _fwd_kernel_t(*refs, scale, causal, block_q, block_kv,
         acc[:] = acc[:] * corr[None, :] + pvt
         m_scr[0] = m_new
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, docs=docs)
 
-    @pl.when(ik == num_kv - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         l = l_scr[0]
         ot_ref[0, 0] = (acc[:] / jnp.maximum(l, 1e-20)[None, :]).astype(
@@ -412,87 +570,12 @@ def _fwd_kernel_t(*refs, scale, causal, block_q, block_kv,
         lse_ref[0, 0] = lse[None, :]
 
 
-def _flash_forward_t(q, k, v, scale, causal, block_q, block_kv, nq, nk,
-                     bounded, group, segs):
-    """D<128 forward: transposed-orientation kernel; output comes out as
-    [B,H,D,Sq] and is swapped back here, lse as [B,H,1,Sq] rows."""
-    b, h, sq, d = q.shape
-    skv = k.shape[2]
-    has_segs = segs is not None
-
-    kernel = functools.partial(
-        _fwd_kernel_t, scale=scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, num_kv=nk, seq_q=sq, seq_kv=skv,
-        has_segs=has_segs, bounded=bounded)
-
-    in_specs = [_spec_q(block_q, d, q_major=True),
-                _spec_kv(block_kv, d, group, q_major=True),
-                _spec_kv(block_kv, d, group, q_major=True)]
-    inputs = [q, k, v]
-    if has_segs:
-        q_segs, kv_segs = segs                # [B,Sq,1] / [B,1,Skv]
-        qs_row = jnp.swapaxes(q_segs, 1, 2)   # [B,1,Sq]
-        ks_col = jnp.swapaxes(kv_segs, 1, 2)  # [B,Skv,1]
-        in_specs += _spec_segs(block_q, block_kv, q_major=True,
-                               transposed=True)
-        inputs += [qs_row, ks_col]
-
-    ot, lse_row = pl.pallas_call(
-        kernel,
-        grid=(b, h, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, d, block_q),
-                         lambda b_, h_, iq, ik: (b_, h_, 0, iq)),
-            pl.BlockSpec((1, 1, 1, block_q),
-                         lambda b_, h_, iq, ik: (b_, h_, 0, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, d, sq), q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((d, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_fwd_t",
-    )(*inputs)
-    return jnp.swapaxes(ot, -1, -2), lse_row[:, :, 0, :]
-
-
-def _flash_forward_window(q, k, v, scale, band: _BandGrid, bounded, group,
-                          segs):
-    """A window layer's forward: the straight kernel on the band's grid."""
-    b, h, sq, d = q.shape
-    specs = _band_specs(band, d, group, True, segs is not None)
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=scale, causal=True, block_q=band.block_q,
-            block_kv=band.block_kv, num_kv=band.kv_steps, seq_q=sq,
-            seq_kv=k.shape[2], has_segs=segs is not None, bounded=bounded,
-            band=band),
-        grid=(b, h, band.nq, band.kv_steps),
-        in_specs=[specs["q"], specs["kv"], specs["kv"]] + specs["segs"],
-        out_specs=[specs["q"], specs["qcol"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((band.block_q, d), jnp.float32),
-            pltpu.VMEM((band.block_q, 1), jnp.float32),
-            pltpu.VMEM((band.block_q, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_window_fwd",
-    )(q, k, v, *(segs or ()))
-    return out, lse[..., 0]
-
-
 def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None,
                    window=0):
+    """One forward kernel on one walk: a window layer's band under its own
+    name (straight orientation at every D), else the transposed-orientation
+    kernel for D < 128 (output [B,H,D,Sq] and lse [B,H,1,Sq] rows, swapped
+    back here) and the straight one from there."""
     b, h, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = h // hkv
@@ -500,53 +583,44 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_kv, segs=None,
     block_kv = min(block_kv, skv)
     nq = _cdiv(sq, block_q)
     nk = _cdiv(skv, block_kv)
-
     bounded = (sq % block_q != 0) or (skv % block_kv != 0)
-    if window:
-        return _flash_forward_window(
-            q, k, v, scale, _BandGrid(window, block_q, block_kv, nq, nk),
-            bounded, group, segs)
-    if d < 128 and not _force_straight():
-        return _flash_forward_t(q, k, v, scale, causal, block_q, block_kv,
-                                nq, nk, bounded, group, segs)
+    has_segs = segs is not None
+    transposed = not window and d < 128 and not _force_straight()
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, num_kv=nk, seq_q=sq, seq_kv=skv,
-        has_segs=segs is not None, bounded=bounded)
-
-    in_specs = [_spec_q(block_q, d, q_major=True),
-                _spec_kv(block_kv, d, group, q_major=True),
-                _spec_kv(block_kv, d, group, q_major=True)]
+    tables = _doc_tables(segs, block_q, block_kv, nq, nk, causal, window)
+    walk = _walk_of(block_q, block_kv, nq, nk, True, window, tables)
+    specs = walk.specs(d, group)
     inputs = [q, k, v]
-    if segs is not None:
-        q_segs, kv_segs = segs  # [B,Sq,1] / [B,1,Skv] int32
-        in_specs += _spec_segs(block_q, block_kv, q_major=True,
-                               transposed=False)
+    in_specs = [specs["q"], specs["kv"], specs["kv"]]
+    if has_segs:
+        q_segs, kv_segs = segs                # [B,Sq,1] / [B,1,Skv] int32
+        if transposed:                        # [B,1,Sq] / [B,Skv,1]
+            q_segs, kv_segs = (jnp.swapaxes(x, 1, 2) for x in segs)
         inputs += [q_segs, kv_segs]
+        in_specs += specs["segs_t" if transposed else "segs"]
+    static = dict(scale=scale, causal=causal, walk=walk,
+                  seq_q=sq, seq_kv=skv, has_segs=has_segs, bounded=bounded)
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, h, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_fwd",
-    )(*inputs)
+    if transposed:
+        ot, lse_row = walk.call(
+            functools.partial(_fwd_kernel_t, **static), b, h, tables,
+            in_specs, [specs["q_t"], specs["qrow"]],
+            [jax.ShapeDtypeStruct((b, h, d, sq), q.dtype),
+             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32)],
+            [pltpu.VMEM((d, block_q), jnp.float32),
+             pltpu.VMEM((1, block_q), jnp.float32),
+             pltpu.VMEM((1, block_q), jnp.float32)],
+            "flash_fwd_t")(*tables, *inputs)
+        return jnp.swapaxes(ot, -1, -2), lse_row[:, :, 0, :]
+    out, lse = walk.call(
+        functools.partial(_fwd_kernel, **static), b, h, tables,
+        in_specs, [specs["q"], specs["qcol"]],
+        [jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+         jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
+        [pltpu.VMEM((block_q, d), jnp.float32),
+         pltpu.VMEM((block_q, 1), jnp.float32),
+         pltpu.VMEM((block_q, 1), jnp.float32)],
+        "flash_window_fwd" if window else "flash_fwd")(*tables, *inputs)
     return out, lse[..., 0]
 
 
@@ -588,11 +662,12 @@ def _valid_mask_t(q_start, k_start, block_q, block_kv, seq_q, seq_kv,
     return valid
 
 
-def _bwd_dq_kernel_t(*refs, scale, causal, block_q, block_kv, num_kv,
-                     seq_q, seq_kv, has_segs, bounded):
+def _bwd_dq_kernel_t(*refs, scale, causal, walk: _Walk, seq_q, seq_kv,
+                     has_segs, bounded):
     """dq in transposed orientation: scores as s^T = k·q^T [bkv, bq],
     accumulator dq^T [D, bq], final matmul k^T·ds^T with full-width
     contraction (bkv) and output (bq) dims."""
+    refs, step, iq, ik, _, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dqt_ref, dqt_acc) = refs
@@ -600,10 +675,9 @@ def _bwd_dq_kernel_t(*refs, scale, causal, block_q, block_kv, num_kv,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dqt_ref, dqt_acc) = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    block_q, block_kv = walk.block_q, walk.block_kv
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dqt_acc[:] = jnp.zeros_like(dqt_acc)
 
@@ -640,21 +714,21 @@ def _bwd_dq_kernel_t(*refs, scale, causal, block_q, block_kv, num_kv,
             k, dst.astype(k.dtype), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [D, bq]
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, docs=docs)
 
-    @pl.when(ik == num_kv - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         dqt_ref[0, 0] = dqt_acc[:].astype(dqt_ref.dtype)
 
 
-def _bwd_dkv_kernel_t(*refs, scale, causal,
-                      block_q, block_kv, num_q, seq_q, seq_kv, has_segs,
-                      bounded):
+def _bwd_dkv_kernel_t(*refs, scale, causal, walk: _Walk, seq_q, seq_kv,
+                      has_segs, bounded):
     """dk/dv in transposed orientation: scores stay [bq, bkv] (so the
     standard mask applies), but the accumulating matmuls contract over
     bq with D-row outputs: dv^T = do^T·p, dk^T = q^T·ds — full-width
     contraction and output dims, [D, bkv] accumulators."""
+    refs, step, iq, ik, _, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dkt_ref, dvt_ref, dkt_acc, dvt_acc) = refs
@@ -662,10 +736,9 @@ def _bwd_dkv_kernel_t(*refs, scale, causal,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dkt_ref, dvt_ref, dkt_acc, dvt_acc) = refs
         qs_ref = ks_ref = None
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
+    block_q, block_kv = walk.block_q, walk.block_kv
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dkt_acc[:] = jnp.zeros_like(dkt_acc)
         dvt_acc[:] = jnp.zeros_like(dvt_acc)
@@ -711,10 +784,10 @@ def _bwd_dkv_kernel_t(*refs, scale, causal,
             (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, docs=docs)
 
-    @pl.when(iq == num_q - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         dkt_ref[0, 0] = dkt_acc[:].astype(dkt_ref.dtype)
         dvt_ref[0, 0] = dvt_acc[:].astype(dvt_ref.dtype)
@@ -989,8 +1062,9 @@ def _flash_backward_fold(q, k, v, g, lse, delta, scale, causal,
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
-                   seq_q, seq_kv, has_segs, bounded, band=None):
+def _bwd_dq_kernel(*refs, scale, causal, walk: _Walk, seq_q, seq_kv,
+                   has_segs, bounded):
+    refs, step, iq, ik, live, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_acc) = refs
@@ -998,10 +1072,8 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_acc) = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(2)
-    step = pl.program_id(3)
-    window = band.window if band else 0
-    ik, live = band.kv_tile(iq, step) if band else (step, None)
+    block_q, block_kv = walk.block_q, walk.block_kv
+    window = walk.window
 
     @pl.when(step == 0)
     def _init():
@@ -1040,19 +1112,19 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_kv, num_kv,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv, window, live)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, window, live, docs)
 
-    @pl.when(step == num_kv - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal,
-                    block_q, block_kv, num_q, seq_q, seq_kv, has_segs,
-                    bounded, band=None):
-    """`band` (a window layer): the grid's last axis walks the band's
-    `num_q` steps of kv tile ik, not all the q tiles."""
+def _bwd_dkv_kernel(*refs, scale, causal, walk: _Walk, seq_q, seq_kv,
+                    has_segs, bounded):
+    """The grid's last axis walks `walk.steps` q tiles of kv tile ik: all
+    of them, or a window layer's band alone."""
+    refs, step, iq, ik, live, docs = walk.enter(refs)
     if has_segs:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -1060,10 +1132,8 @@ def _bwd_dkv_kernel(*refs, scale, causal,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         qs_ref = ks_ref = None
-    ik = pl.program_id(2)
-    step = pl.program_id(3)
-    window = band.window if band else 0
-    iq, live = band.q_tile(ik, step) if band else (step, None)
+    block_q, block_kv = walk.block_q, walk.block_kv
+    window = walk.window
 
     @pl.when(step == 0)
     def _init():
@@ -1110,69 +1180,24 @@ def _bwd_dkv_kernel(*refs, scale, causal,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _dispatch_tiles(compute, causal, bounded or has_segs, q_start, k_start,
-                    block_q, block_kv, window, live)
+    _dispatch_tiles(compute, causal, bounded or (has_segs and docs is None),
+                    q_start, k_start, block_q, block_kv, window, live, docs)
 
-    @pl.when(step == num_q - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward_window(q, k, v, g, lse, delta, scale, band: _BandGrid,
-                           bounded, group, segs):
-    """A window layer's backward: the straight dq and dkv kernels on the
-    band's two grids; dk/dv come out a query head and are summed over a
-    key/value head's group outside, as in `_flash_backward`."""
-    b, h, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
-    has_segs = segs is not None
-    static = dict(scale=scale, causal=True, block_q=band.block_q,
-                  block_kv=band.block_kv, seq_q=sq, seq_kv=skv,
-                  has_segs=has_segs, bounded=bounded, band=band)
-    inputs = (q, k, v, *(segs or ()), g, lse[..., None], delta[..., None])
-
-    def in_specs(specs):
-        return ([specs["q"], specs["kv"], specs["kv"]] + specs["segs"]
-                + [specs["q"], specs["qcol"], specs["qcol"]])
-
-    specs = _band_specs(band, d, group, True, has_segs)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, num_kv=band.kv_steps, **static),
-        grid=(b, h, band.nq, band.kv_steps),
-        in_specs=in_specs(specs),
-        out_specs=specs["q"],
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((band.block_q, d), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_window_bwd_dq",
-    )(*inputs)
-
-    specs = _band_specs(band, d, group, False, has_segs)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, num_q=band.q_steps, **static),
-        grid=(b, h, band.nk, band.q_steps),
-        in_specs=in_specs(specs),
-        out_specs=[specs["dkv"], specs["dkv"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, skv, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((band.block_kv, d), jnp.float32),
-            pltpu.VMEM((band.block_kv, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_window_bwd_dkv",
-    )(*inputs)
-    if group > 1:
-        dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2)
-        dv = dv.reshape(b, hkv, group, skv, d).sum(axis=2)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-
-
 def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
                     head_fold: bool = False, window=0):
+    """dq on the forward's walk and dk/dv on the kv-major one: a window
+    layer's straight kernels on its band's two grids, else the transposed-
+    orientation kernels for D < 128 (full MXU lanes — see the orientation
+    note above; gradients come out [B,H,D,S] and are swapped back here) and
+    the straight ones from there. dk/dv are computed at q-head granularity
+    and a key/value head's group is summed outside (GQA) — simple and
+    correct; a fused variant can accumulate in-kernel later."""
     q, k, v, out, lse = res
     b, h, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -1182,194 +1207,82 @@ def _flash_backward(res, g, scale, causal, block_q, block_kv, segs=None,
     nq = _cdiv(sq, block_q)
     nk = _cdiv(skv, block_kv)
     bounded = (sq % block_q != 0) or (skv % block_kv != 0)
+    has_segs = segs is not None
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [B,H,Sq]
-    if window:
-        return _flash_backward_window(
-            q, k, v, g, lse, delta, scale,
-            _BandGrid(window, block_q, block_kv, nq, nk), bounded, group,
-            segs)
-    if head_fold and head_fold_eligible(h, hkv, d, segs):
+    if not window and head_fold and head_fold_eligible(h, hkv, d, segs):
         return _flash_backward_fold(
             q, k, v, g, lse, delta, scale, causal, block_q, block_kv,
             nq, nk, bounded, group)
-    if d < 128 and not _force_straight():
-        return _flash_backward_t(
-            q, k, v, g, lse, delta, scale, causal, block_q, block_kv,
-            nq, nk, bounded, group, segs)
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
+    transposed = not window and d < 128 and not _force_straight()
+    family = "flash_window_bwd" if window else "flash_bwd"
+    suffix = "_t" if transposed else ""
 
-    dq_in_specs = [_spec_q(block_q, d, q_major=True),
-                   _spec_kv(block_kv, d, group, q_major=True),
-                   _spec_kv(block_kv, d, group, q_major=True)]
-    dq_inputs = [q, k, v]
-    if segs is not None:
-        q_segs, kv_segs = segs
-        dq_in_specs += _spec_segs(block_q, block_kv, q_major=True,
-                                  transposed=False)
-        dq_inputs += [q_segs, kv_segs]
-    dq_in_specs += [_spec_q(block_q, d, q_major=True),
-                    _spec_qcol(block_q, q_major=True),
-                    _spec_qcol(block_q, q_major=True)]
+    tables = _doc_tables(segs, block_q, block_kv, nq, nk, causal, window)
+    static = dict(scale=scale, causal=causal, seq_q=sq, seq_kv=skv,
+                  has_segs=has_segs, bounded=bounded)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_kv=nk,
-                          seq_q=sq, seq_kv=skv, has_segs=segs is not None,
-                          bounded=bounded),
-        grid=(b, h, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(*dq_inputs, g, lse4, delta4)
+    def columns():                          # [B,H,Sq,1]
+        return [lse[..., None], delta[..., None]]
 
-    # dk/dv computed at q-head granularity [B, H, Skv, D]; grouped heads are
-    # reduced outside (GQA) — simple and correct; a fused variant can
-    # accumulate in-kernel later.
-    dkv_in_specs = [_spec_q(block_q, d, q_major=False),
-                    _spec_kv(block_kv, d, group, q_major=False),
-                    _spec_kv(block_kv, d, group, q_major=False)]
-    dkv_inputs = [q, k, v]
-    if segs is not None:
-        q_segs, kv_segs = segs
-        dkv_in_specs += _spec_segs(block_q, block_kv, q_major=False,
-                                   transposed=False)
-        dkv_inputs += [q_segs, kv_segs]
-    dkv_in_specs += [_spec_q(block_q, d, q_major=False),
-                     _spec_qcol(block_q, q_major=False),
-                     _spec_qcol(block_q, q_major=False)]
-
-    dk_full, dv_full = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_q=nq,
-                          seq_q=sq, seq_kv=skv, has_segs=segs is not None,
-                          bounded=bounded),
-        grid=(b, h, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_kv, d),
-                         lambda b_, h_, ik, iq: (b_, h_, ik, 0)),
-            pl.BlockSpec((1, 1, block_kv, d),
-                         lambda b_, h_, ik, iq: (b_, h_, ik, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, skv, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_bwd_dkv",
-    )(*dkv_inputs, g, lse4, delta4)
-
-    if group > 1:
-        dk = dk_full.reshape(b, hkv, group, skv, d).sum(axis=2)
-        dv = dv_full.reshape(b, hkv, group, skv, d).sum(axis=2)
+    # dq. The transposed kernel reads the ids as a [1, bq] lane row and a
+    # [bkv, 1] column, lse and delta as [B,H,1,Sq] lane rows.
+    walk = _walk_of(block_q, block_kv, nq, nk, True, window, tables)
+    specs = walk.specs(d, group)
+    ids = list(segs or ())
+    if transposed:
+        per_row = [lse[:, :, None, :], delta[:, :, None, :]]
+        ids = [jnp.swapaxes(x, 1, 2) for x in ids]
     else:
-        dk, dv = dk_full, dv_full
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+        per_row = columns()
+    row_spec = specs["qrow" if transposed else "qcol"]
+    dq = walk.call(
+        functools.partial(_bwd_dq_kernel_t if transposed else _bwd_dq_kernel,
+                          walk=walk, **static), b, h, tables,
+        [specs["q"], specs["kv"], specs["kv"]]
+        + (specs["segs_t" if transposed else "segs"] if has_segs else [])
+        + [specs["q"], row_spec, row_spec],
+        specs["q_t" if transposed else "q"],
+        jax.ShapeDtypeStruct((b, h, d, sq) if transposed else (b, h, sq, d),
+                             q.dtype),
+        [pltpu.VMEM((d, block_q) if transposed else (block_q, d),
+                    jnp.float32)],
+        f"{family}_dq{suffix}")(*tables, q, k, v, *ids, g, *per_row)
 
+    # dk/dv: scores stay [bq, bkv] in both orientations, so the ids, lse
+    # and delta are the straight ones.
+    if transposed:
+        per_row = columns()
+    walk = _walk_of(block_q, block_kv, nq, nk, False, window, tables)
+    specs = walk.specs(d, group)
+    out_spec = specs["dkv_t" if transposed else "dkv"]
+    shape = (b, h, d, skv) if transposed else (b, h, skv, d)
+    acc = pltpu.VMEM((d, block_kv) if transposed else (block_kv, d),
+                     jnp.float32)
+    dk, dv = walk.call(
+        functools.partial(
+            _bwd_dkv_kernel_t if transposed else _bwd_dkv_kernel,
+            walk=walk, **static), b, h, tables,
+        [specs["q"], specs["kv"], specs["kv"]]
+        + (specs["segs"] if has_segs else [])
+        + [specs["q"], specs["qcol"], specs["qcol"]],
+        [out_spec, out_spec],
+        [jax.ShapeDtypeStruct(shape, k.dtype),
+         jax.ShapeDtypeStruct(shape, v.dtype)],
+        [acc, acc],
+        f"{family}_dkv{suffix}")(*tables, q, k, v, *(segs or ()), g,
+                                 *per_row)
 
-def _flash_backward_t(q, k, v, g, lse, delta, scale, causal,
-                      block_q, block_kv, nq, nk, bounded, group, segs):
-    """D<128 backward: transposed-orientation kernels (full MXU lanes —
-    see the orientation note above). Gradients come out as [B,H,D,S] and
-    are swapped back here."""
-    b, h, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
-    has_segs = segs is not None
+    def swapped_back(x):
+        return jnp.swapaxes(x, -1, -2) if transposed else x
 
-    # lse/delta as [B,H,1,Sq] lane rows for the dq^T kernel.
-    lse_row = lse[:, :, None, :]
-    delta_row = delta[:, :, None, :]
+    def of_kv_head(x):
+        if group > 1:
+            x = x.reshape((b, hkv, group) + x.shape[2:]).sum(axis=2)
+        return swapped_back(x)
 
-    dq_in_specs = [_spec_q(block_q, d, q_major=True),
-                   _spec_kv(block_kv, d, group, q_major=True),
-                   _spec_kv(block_kv, d, group, q_major=True)]
-    dq_inputs = [q, k, v]
-    if has_segs:
-        q_segs, kv_segs = segs              # [B,Sq,1] / [B,1,Skv]
-        qs_row = jnp.swapaxes(q_segs, 1, 2)   # [B,1,Sq]
-        ks_col = jnp.swapaxes(kv_segs, 1, 2)  # [B,Skv,1]
-        dq_in_specs += _spec_segs(block_q, block_kv, q_major=True,
-                                  transposed=True)
-        dq_inputs += [qs_row, ks_col]
-    dq_in_specs += [_spec_q(block_q, d, q_major=True),
-                    _spec_qrow(block_q, q_major=True),
-                    _spec_qrow(block_q, q_major=True)]
-
-    dqt = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_t, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_kv=nk,
-                          seq_q=sq, seq_kv=skv, has_segs=has_segs,
-                          bounded=bounded),
-        grid=(b, h, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, d, block_q),
-                               lambda b_, h_, iq, ik: (b_, h_, 0, iq)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d, sq), q.dtype),
-        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_bwd_dq_t",
-    )(*dq_inputs, g, lse_row, delta_row)
-
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
-    dkv_in_specs = [_spec_q(block_q, d, q_major=False),
-                    _spec_kv(block_kv, d, group, q_major=False),
-                    _spec_kv(block_kv, d, group, q_major=False)]
-    dkv_inputs = [q, k, v]
-    if has_segs:
-        q_segs, kv_segs = segs
-        dkv_in_specs += _spec_segs(block_q, block_kv, q_major=False,
-                                   transposed=False)
-        dkv_inputs += [q_segs, kv_segs]
-    dkv_in_specs += [_spec_q(block_q, d, q_major=False),
-                     _spec_qcol(block_q, q_major=False),
-                     _spec_qcol(block_q, q_major=False)]
-
-    dkt_full, dvt_full = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_t, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_q=nq,
-                          seq_q=sq, seq_kv=skv, has_segs=has_segs,
-                          bounded=bounded),
-        grid=(b, h, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, d, block_kv),
-                         lambda b_, h_, ik, iq: (b_, h_, 0, ik)),
-            pl.BlockSpec((1, 1, d, block_kv),
-                         lambda b_, h_, ik, iq: (b_, h_, 0, ik)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, d, skv), k.dtype),
-            jax.ShapeDtypeStruct((b, h, d, skv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((d, block_kv), jnp.float32),
-            pltpu.VMEM((d, block_kv), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_bwd_dkv_t",
-    )(*dkv_inputs, g, lse4, delta4)
-
-    dq = jnp.swapaxes(dqt, -1, -2)
-    if group > 1:
-        dk = jnp.swapaxes(
-            dkt_full.reshape(b, hkv, group, d, skv).sum(axis=2), -1, -2)
-        dv = jnp.swapaxes(
-            dvt_full.reshape(b, hkv, group, d, skv).sum(axis=2), -1, -2)
-    else:
-        dk = jnp.swapaxes(dkt_full, -1, -2)
-        dv = jnp.swapaxes(dvt_full, -1, -2)
+    dq, dk, dv = swapped_back(dq), of_kv_head(dk), of_kv_head(dv)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -1495,7 +1408,12 @@ def flash_tiles(seq: int, block_q: Optional[int] = None,
     slower still; at S 2048 tiles of 1024 gain under 10% and Mosaic refuses
     them with segments at batch 4 (VMEM), so the four-chip cell keeps its
     512 x 512. A compile for a described v5e takes one tile a sequence at
-    every S <= 1024, D <= 128, batch 1..16, packed or not, grouped or not."""
+    every S <= 1024, D <= 128, batch 1..16, packed or not, grouped or not.
+    What a packed call skips follows from the tiles: at 512 x 512 the tiles
+    whose documents cannot meet are not computed (46.5% of a causal layer's
+    at S 8192 over documents of median 1,500, PR 58); one tile a sequence
+    has nothing to skip and is handed no table, so a packed S 1024 call
+    still masks its whole tile (ROADMAP S5a's open half)."""
     chosen = seq if seq <= 1024 and not 0 < window < seq else 512
     return min(block_q or chosen, seq), min(block_kv or chosen, seq)
 
@@ -1561,7 +1479,9 @@ def flash_attention(q, k, v, causal: bool = True,
     segment_ids: optional [B, S] int packing map — attention is restricted
     to within-segment (packed sequences, reference THD/packed_seq_params
     semantics) with the same O(S) memory profile; segment masking composes
-    with the causal block-skip.
+    with the causal block-skip, and where a sequence is more than one tile
+    the tiles whose documents cannot meet are skipped too
+    (`segment_tile_table`; any ids, sorted ones skip most).
 
     head_fold: fold q-head pairs into the trailing block dim in the
     BACKWARD kernels (D=64 → full 128-lane rows; PERF.md lever 1,

@@ -54,6 +54,50 @@ def _backend() -> str:
     return jax.default_backend()
 
 
+def _whole_sequence_choice(cfg: TransformerConfig, ctx, b: int, s: int,
+                           nq: int, dtype, segments: bool,
+                           window: int) -> fa.AttentionChoice:
+    """`choose_attention` for a layer's whole-sequence call of `b` rows and
+    `nq` query heads, told the batch and heads ON ONE DEVICE: the [B,H,S,S]
+    scores of the dense path shard only over dp/ep/tp; pp/cp devices each
+    hold a full copy."""
+    if ctx is not None and ctx.num_devices > 1:
+        b = -(-b // (ctx.dp * ctx.ep))
+        nq = -(-nq // ctx.tp)
+    return fa.choose_attention(
+        impl=cfg.attention_impl, batch=b, seq=s, heads=nq,
+        head_dim=cfg.head_dim, dtype=dtype, segments=segments,
+        backend=_backend(), window=window,
+        block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv)
+
+
+def flash_tile_counts(cfg: TransformerConfig, segment_ids, ctx=None) -> dict:
+    """What the flash kernels compute of one packed forward pass through a
+    hybrid stack's causal attention layers: `flash_tiles`, the tile pairs
+    of their grids' causal or band part over the batch and the layers, and
+    `flash_tiles_computed`, those the documents' table lets the kernels
+    compute (flash_attention.segment_tile_counts, from the same table the
+    kernels are handed). Empty where no layer takes the kernels."""
+    b, s = segment_ids.shape
+    tiles, computed = 0, jnp.int32(0)
+    for layers, heads, window in (
+            (cfg.num_attention_layers, cfg.num_attention_heads, 0),
+            (cfg.num_window_layers, cfg.window_heads, cfg.sliding_window)):
+        if not layers:
+            continue
+        choice = _whole_sequence_choice(cfg, ctx, b, s, heads,
+                                        cfg.compute_dtype, True, window)
+        if choice.impl != "pallas":
+            continue
+        layer_tiles, layer_computed = fa.segment_tile_counts(
+            segment_ids, choice.block_q, choice.block_kv, window=window)
+        tiles += layers * layer_tiles
+        computed += layers * layer_computed
+    if not tiles:
+        return {}
+    return {"flash_tiles": jnp.int32(tiles), "flash_tiles_computed": computed}
+
+
 def _announce(site: str, impl: str, interpreted=None) -> None:
     """Print, once per distinct choice in this process, which attention
     implementation a call site traced — and, for a Pallas kernel, whether
@@ -541,19 +585,9 @@ def attention_forward(
     else:
         from megatronapp_tpu.parallel.collectives import current_manual_axes
 
-        # Per-device batch and heads: the [B,H,S,S] scores of the dense
-        # path shard only over dp/ep/tp; pp/cp devices each hold a full
-        # copy.
         multi_device = ctx is not None and ctx.num_devices > 1
-        b_dev, nq_dev = b, nq
-        if multi_device:
-            b_dev = -(-b // (ctx.dp * ctx.ep))
-            nq_dev = -(-nq // ctx.tp)
-        choice = fa.choose_attention(
-            impl=cfg.attention_impl, batch=b_dev, seq=s, heads=nq_dev,
-            head_dim=d, dtype=q.dtype, segments=segment_ids is not None,
-            backend=_backend(), window=window,
-            block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv)
+        choice = _whole_sequence_choice(cfg, ctx, b, s, nq, q.dtype,
+                                        segment_ids is not None, window)
         impl = choice.impl
         # GSPMD cannot partition a pallas_call (it would replicate full
         # attention on every device), so the kernel must be placed

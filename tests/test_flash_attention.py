@@ -323,3 +323,116 @@ def test_segment_grads_unequal_tiles():
             assert bool(jnp.all(jnp.isfinite(a)))
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5)
+
+
+# ---------------------------------------------------------------------------
+# The documents' table of a packed call (segment_tile_table): which tile
+# pairs the kernels compute, against a count from the [S, S] mask itself.
+# ---------------------------------------------------------------------------
+
+def _live_by_hand(ids, block_q, block_kv, causal, window):
+    """(tiles, meet, allowed) by brute force over the batch: the tile pairs
+    that hold a (query, key) of the [S, S] causal or band mask; of them,
+    those whose ranges of ids meet (what the table promises to compute);
+    and those that hold a pair the masks and the ids allow (what has to
+    be computed)."""
+    ids = np.asarray(ids)
+    b, s = ids.shape
+    at_q, at_k = np.arange(s)[:, None], np.arange(s)[None, :]
+    part = np.ones((s, s), bool)
+    if causal:
+        part &= at_q >= at_k
+    if window:
+        part &= at_q - at_k < window
+    nq, nk = -(-s // block_q), -(-s // block_kv)
+    tiles = meet = allowed = 0
+    for row in ids:
+        same = (row[:, None] == row[None, :]) & part
+        for iq in range(nq):
+            rows = slice(iq * block_q, (iq + 1) * block_q)
+            for ik in range(nk):
+                cols = slice(ik * block_kv, (ik + 1) * block_kv)
+                if not part[rows, cols].any():
+                    continue
+                tiles += 1
+                allowed += bool(same[rows, cols].any())
+                meet += bool(row[rows].min() <= row[cols].max()
+                             and row[cols].min() <= row[rows].max())
+    return tiles, meet, allowed
+
+
+@pytest.mark.parametrize("causal,window,tiles", [
+    (True, 0, (32, 32)), (True, 0, (16, 48)), (True, 72, (32, 32)),
+    (True, 40, (48, 16)), (False, 0, (32, 32)), (True, 0, (40, 40)),
+], ids=["causal", "causal-unequal", "band", "band-unequal", "bidirectional",
+        "ragged"])
+@pytest.mark.parametrize("sorted_ids", [True, False],
+                         ids=["sorted", "unsorted"])
+def test_the_table_counts_what_the_masks_leave(causal, window, tiles,
+                                               sorted_ids):
+    """`tiles` is the grid's causal or band part, `computed` the pairs whose
+    ranges of ids meet (for sorted ids exactly those that hold an allowed
+    pair or straddle a diagonal tile's corner; never fewer), and each
+    tile's [lo, hi] is the hull of its live partners."""
+    from megatronapp_tpu.ops.pallas import flash_attention as fa
+    rng = np.random.default_rng(7)
+    ids = rng.integers(0, 5, (3, 240))
+    if sorted_ids:
+        ids = np.sort(ids, axis=1)
+    ids[2] = 3                               # one document spans the row
+    bq, bkv = tiles
+    q_table, kv_table, n, computed = fa.segment_tile_table(
+        jnp.asarray(ids, jnp.int32), jnp.asarray(ids, jnp.int32), bq, bkv,
+        causal, window)
+    want_tiles, meet, allowed = _live_by_hand(ids, bq, bkv, causal, window)
+    assert (n, int(computed)) == (want_tiles, meet)
+    assert allowed <= meet
+    if sorted_ids and not window and bq == bkv and causal:
+        # ranges that meet share an id, and on or under the diagonal a
+        # shared id of sorted rows is an allowed pair
+        assert allowed == meet
+    q_table, kv_table = np.asarray(q_table), np.asarray(kv_table)
+    part = fa._grid_part(q_table.shape[1], kv_table.shape[1], bq, bkv,
+                         causal, window)
+    for b in range(ids.shape[0]):
+        live = ((q_table[b, :, None, 0] <= kv_table[b, None, :, 1])
+                & (kv_table[b, None, :, 0] <= q_table[b, :, None, 1]) & part)
+        for iq, row in enumerate(live):
+            at = np.flatnonzero(row)
+            assert tuple(q_table[b, iq, 2:]) == (at.min(), at.max())
+        for ik, col in enumerate(live.T):
+            at = np.flatnonzero(col)
+            assert tuple(kv_table[b, ik, 2:]) == (at.min(), at.max())
+
+
+def test_the_share_training_cells_rows_leave_half_the_full_layers_tiles():
+    """ISSUE 58's count: over 48 rows of the cell's own generator (seed 0,
+    six batches) at 512 x 512, 53.5% of the full layer's causal tiles and
+    94.3% of the window layers' band tiles hold a query and a key whose
+    documents can meet."""
+    import itertools
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from perfbench.generators import train_packed
+    finally:
+        sys.path.remove(root)
+    from megatronapp_tpu.ops.pallas.flash_attention import (
+        segment_tile_counts,
+    )
+    with open(os.path.join(root, "perfbench/traffic/packed-8k.json")) as f:
+        job = json.load(f)
+    ids = jnp.asarray(np.concatenate([
+        batch["segment_ids"] for batch in itertools.islice(
+            train_packed.batches(job, 0, 24576, 8192), 6)]))
+    assert ids.shape == (48, 8192)
+    tiles, computed = segment_tile_counts(ids)           # 512 x 512
+    assert (tiles, int(computed)) == (6528, 3491)        # 53.5%
+    tiles, computed = segment_tile_counts(ids, window=1024)
+    assert (tiles, int(computed)) == (2160, 2037)        # 94.3%
+    # one tile a sequence is handed no table: all of it is computed
+    tiles, computed = segment_tile_counts(ids[:, :1024])
+    assert (tiles, int(computed)) == (48, 48)

@@ -121,6 +121,66 @@ def test_the_band_grid_holds_the_bands_tiles_alone(window, bq, bkv, s,
         assert (lo, hi) == (rows.min(), rows.max())
 
 
+# Rows packed like the share-training cell's, at tiles of 32: documents that
+# start and end inside tiles, one shorter than a tile, tiles that lie whole
+# inside one document (row 0's fourth, under the diagonal: the unmasked
+# path), a document that spans the row, and documents that end on tile edges.
+PACKED_ROWS = ([20, 70, 5, 100, 61], [256], [64, 32, 160])
+# ids that are not sorted, and an id that comes back after another (0, 1, 0):
+# a tile's range then only says too much, never too little
+UNSORTED_ROWS = ([(0, 100), (1, 60), (0, 96)],
+                 [(7, 40), (3, 90), (5, 30), (3, 96)],
+                 [(2, 256)])
+
+
+def _ids(rows):
+    return jnp.asarray([np.repeat([i for i, _ in row], [n for _, n in row])
+                        for row in rows], jnp.int32)
+
+
+PACKED = _ids([list(enumerate(row)) for row in PACKED_ROWS])
+UNSORTED = _ids(UNSORTED_ROWS)
+
+
+def _grads_and_out(q, k, v, w, segs, window, tile=32):
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, block_q=tile, block_kv=tile,
+                                 segment_ids=segs, window=window)
+        return jnp.sum(w * out), out
+    grads, out = jax.grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("ids", [PACKED, UNSORTED], ids=["packed", "unsorted"])
+@pytest.mark.parametrize("window,d", [(0, 16), (0, 128), (72, 16)],
+                         ids=["transposed", "straight", "window"])
+def test_the_documents_table_changes_no_number(window, d, ids, monkeypatch):
+    """A packed call of several tiles (plain causal in both orientations,
+    and a window layer's band): output and the three gradients against the
+    float32 oracle within the limits the kernels have always had, and
+    against the same kernels handed no table (every tile masked, none
+    skipped: the parent's program) to 1e-6."""
+    q, k, v, w, _ = _inputs(256, h=2, hkv=1, d=d, b=3, seed=5)
+
+    def oracle(q, k, v):
+        out = _dense(q, k, v, window, ids)
+        return jnp.sum(w * out), out
+    want_grads, want = jax.grad(oracle, (0, 1, 2), has_aux=True)(q, k, v)
+    got = _grads_and_out(q, k, v, w, ids, window)
+    np.testing.assert_allclose(got[0], want, atol=2e-5, rtol=2e-5)
+    for g, x in zip(got[1:], want_grads):
+        np.testing.assert_allclose(g, x, atol=5e-5, rtol=5e-5)
+
+    tabled = []
+    real = fa._doc_tables
+    monkeypatch.setattr(fa, "_doc_tables", lambda *a: tabled.append(
+        real(*a)) or ())
+    for g, x in zip(_grads_and_out(q, k, v, w, ids, window), got):
+        np.testing.assert_allclose(g, x, atol=1e-6, rtol=0)
+    # forward, and the backward rule once more: each had a table to drop
+    assert len(tabled) == 2 and all(len(t) == 2 for t in tabled)
+
+
 def test_the_kernels_of_a_window_layer_carry_their_own_names():
     q, k, v, w, segs = _inputs(64)
     text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
@@ -133,37 +193,62 @@ def test_the_kernels_of_a_window_layer_carry_their_own_names():
     assert "flash_fwd" not in text and "flash_bwd" not in text
 
 
-# sha256 of str(jax.make_jaxpr(grad)) at the parent commit (986eda0), by
+# sha256 of str(jax.make_jaxpr(grad)) at the parent commit, by
 # tests/test_flash_window.py::_window0_jaxprs run there: the three kernels'
-# bodies, names, grids and tiles as traced.
+# bodies, names, grids and tiles as traced. Unpacked calls at 2 x 2 tiles
+# (986eda0, and unchanged at f4cc4df), and packed calls of ONE tile a
+# sequence, which are handed no table (f4cc4df). A packed call of several
+# tiles is no longer the parent's: its kernels read the documents' table.
 PARENT_JAXPR_SHA = {
     "d64": "37d35ad33911b1827e4d97ae5e21b25e8fdd8b61ad3253f92f54e7dcc87edca7",
-    "d64-packed":
-        "aa6bbb38821a2c73313b7f0ec6917ebc3abfe8fc6a1db4f80f7e3cdf3d20615b",
+    "d64-packed-one-tile":
+        "8ab9b176b4aacbc243368c7b3779d602ad1af1996dd2d1214722f5c6ba788345",
     "d128":
         "bd7c3970642ec26eef131d53ac120e4c70d47b8fe6090952616cdb76784eb23d",
-    "d128-packed":
-        "552dc74edb9a2f83e6b938125822da491774c47ecaa1dc14e54f44e9abbcb6ec",
+    "d128-packed-one-tile":
+        "28c17d2594e16410e1eff05134b5d66395ce34d8d8bb1177a5b70381e3d2bf41",
 }
 
 
+def _grad_jaxpr(d, tile, segs):
+    q, k, v, w, ids = _inputs(256, h=4, hkv=2, d=d, b=1)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    return jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        w * fa.flash_attention(q, k, v, block_q=tile, block_kv=tile,
+                               segment_ids=ids if segs else None)),
+        (0, 1, 2)))(q, k, v)
+
+
 def _window0_jaxprs():
-    out = {}
-    for name, (d, packed) in {"d64": (64, False), "d64-packed": (64, True),
-                              "d128": (128, False),
-                              "d128-packed": (128, True)}.items():
-        q, k, v, w, segs = _inputs(256, h=4, hkv=2, d=d, b=1)
-        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
-        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
-            w * fa.flash_attention(q, k, v, block_q=128, block_kv=128,
-                                   segment_ids=segs if packed else None)),
-            (0, 1, 2)))(q, k, v))
-        out[name] = hashlib.sha256(text.encode()).hexdigest()
-    return out
+    return {name: hashlib.sha256(str(_grad_jaxpr(*case)).encode()).hexdigest()
+            for name, case in {
+                "d64": (64, 128, False), "d128": (128, 128, False),
+                "d64-packed-one-tile": (64, None, True),
+                "d128-packed-one-tile": (128, None, True)}.items()}
 
 
 def test_with_window_0_the_traced_kernels_are_the_parents():
     assert _window0_jaxprs() == PARENT_JAXPR_SHA
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("tile,operands", [(None, 0), (128, 2)],
+                         ids=["one-tile", "four-tiles"])
+def test_only_a_packed_call_of_several_tiles_is_handed_a_table(tile,
+                                                               operands):
+    """One tile a sequence has nothing to skip and lowers without a scalar
+    prefetch operand; 2 x 2 tiles are handed the two tables."""
+    calls = list(_pallas_calls(_grad_jaxpr(64, tile, True).jaxpr))
+    assert len(calls) == 3
+    assert {c.params["grid_mapping"].num_index_operands
+            for c in calls} == {operands}
 
 
 def test_choose_attention_gives_a_window_layer_the_kernels():

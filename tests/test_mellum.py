@@ -236,6 +236,41 @@ def test_the_hybrid_loop_carries_loss_and_counts_under_recomputation(
     assert sums["row_buffer_rows"] >= 8 * 240
 
 
+def test_a_packed_step_through_the_flash_kernels_counts_its_tiles(seeded):
+    """With the flash kernels asked for (tiles of 32 over rows of 96: three
+    tiles a sequence, so the kernels are handed the documents' table), the
+    loss is the one under XLA's dense attention and the step's sums gain the
+    tiles of the causal triangle (1 full layer) and the band (3 window
+    layers of 24 keys) and how many of them the table lets the kernels
+    compute; XLA's dense attention counts none."""
+    from megatronapp_tpu.ops.pallas import flash_attention as fa
+    config, dense_cfg, params = seeded
+    cfg = dataclasses.replace(dense_cfg, attention_impl="pallas",
+                              flash_block_q=32, flash_block_kv=32)
+    micro = _micro(config, packed=True)
+
+    def forward(cfg):
+        with jax.default_matmul_precision("highest"):
+            return gpt_loss(params, jnp.asarray(micro["tokens"]),
+                            jnp.asarray(micro["labels"]),
+                            jnp.asarray(micro["loss_mask"]), cfg,
+                            segment_ids=jnp.asarray(micro["segment_ids"]))
+
+    value, metrics = forward(cfg)
+    dense_value, dense_metrics = forward(dense_cfg)
+    assert abs(float(value) - float(dense_value)) < 5e-6
+    sums = jax.tree.map(float, metrics["sums"])
+    ids = jnp.asarray(micro["segment_ids"])
+    full = fa.segment_tile_counts(ids, 32, 32)
+    band = fa.segment_tile_counts(ids, 32, 32, window=24)
+    assert (full[0], band[0]) == (2 * 6, 2 * 5)
+    assert sums["flash_tiles"] == full[0] + 3 * band[0]
+    assert sums["flash_tiles_computed"] == int(full[1]) + 3 * int(band[1])
+    assert 2 * 3 * 4 <= sums["flash_tiles_computed"] < sums["flash_tiles"]
+    assert "flash_tiles" not in dense_metrics["sums"]
+    assert "assignments" in dense_metrics["sums"]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bf16"])
 def test_the_laddered_model_is_the_full_buffers(dtype, small_rungs):
     """The whole model, jitted, with its held experts on the ladder and on
@@ -394,10 +429,18 @@ def test_compute_dtype_kernels_casts_what_is_multiplied_in_it(seeded):
         **dense, compute_dtype=jnp.float32)), "compute_copies")
 
 
-def test_the_loop_logs_moe_and_emits_its_spans(tmp_path, small_rungs):
+def test_the_loop_logs_moe_and_emits_its_spans(tmp_path, small_rungs,
+                                               monkeypatch):
     from megatronapp_tpu.parallel.mesh import build_mesh
     from megatronapp_tpu.trace.request_trace import get_request_tracer
+    from megatronapp_tpu.trace.tracer import get_tracer
     from megatronapp_tpu.training.train import pretrain_gpt
+    # MegaScan's tracer is one a process, and `pretrain_gpt` configures it
+    # only where it is asked to trace: a test that traced earlier in this
+    # worker (tests/test_scope_map.py, test_megascan.py, ...) leaves it
+    # enabled, the loop then takes every step for a traced one and syncs
+    # after each ([1, 1, 1, 1] where the log interval gives [2, 2]).
+    monkeypatch.setattr(get_tracer(), "enabled", False)
     sys.path.insert(0, os.path.join(ROOT, "perfbench"))
     pretrain_cell = manifest.load_module("cells", "pretrain")
     config = _small()

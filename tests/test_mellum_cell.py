@@ -110,6 +110,33 @@ def test_the_share_of_rows_walked_reads_the_syncs_of_the_window():
     assert read(run()) is None and read({}) is None
 
 
+@pytest.mark.parametrize("counts,want", [
+    # the parent: routing counters and no tile count, every tile computed
+    ({}, 1.0),
+    # two steps of 8 rows: 136 causal tiles of the full layer and 3 x 45
+    # band tiles of the window layers a row
+    ({"flash_tiles": 2 * 8 * 271.0, "flash_tiles_computed": 2 * 8 * 200.0},
+     200 / 271),
+], ids=["no-count", "counted"])
+def test_the_tiles_computed_share(counts, want):
+    """`flash_tiles_computed_share.packed8k` on the hand-built run of
+    perfbench/tests/test_share_train_readers.py (the case lives here: that
+    file is the benchmark's, and not a program PR's to edit)."""
+    from perfbench import manifest as mf
+    ev, run_of = _mod.ev, _mod.run_of
+    stats = {"spans": [ev("mta.train.sync", 0.5, 99.5,
+                          {**_mod.SYNC, **counts})]}
+    read = mf.load_reader("flash_tiles_computed_share.packed8k")
+    assert read(run_of(_mod.DEVICE, stats, _mod.MODULES, _mod.MAPS,
+                       _mod.PAIRS)) == pytest.approx(want)
+    # a program without the loop's spans, and a run without a trace
+    assert read(run_of(_mod.DEVICE, {"spans": []}, _mod.MODULES, (),
+                       None)) is None
+    assert read({"kind": "train", "config": _mod.CONFIG,
+                 "peaks": _mod.PEAKS, "traced_steps": 0,
+                 "device_summary": None}) is None
+
+
 def test_the_runners_window_counters():
     from perfbench import manifest as mf
     runner = mf.load_module("cells", "pretrain_share")
@@ -173,7 +200,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
         "expert_gemm_roofline_pct.packed8k",
         "expert_rows_here_share.packed8k",
         "expert_load_max_over_mean.packed8k",
-        "expert_rows_walked_share.packed8k"]
+        "expert_rows_walked_share.packed8k",
+        "flash_tiles_computed_share.packed8k"]
     for name in mine:
         assert mf.load_reader(name) is not None, name
     assert [m["name"] for m in mf.cell_metrics(manifest, CELL, "end_to_end")
@@ -216,6 +244,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
     assert manifest["workloads"][len(was["workloads"])]["name"] == CELL
     assert manifest["configs"][len(was["configs"])]["name"] \
         == "mellum2-12b-a2.5b"
-    # (what later PRs append comes behind the cell's own)
+    # (what later PRs append comes behind the cell's own of PRs 48 and 49;
+    # PR 58's flash_tiles_computed_share.packed8k is at the list's end)
+    own = mine[10:-1]
     assert [m["name"] for m in manifest["per_layer"][len(was["per_layer"]):]
-            ][:len(mine) - 10] == mine[10:]
+            ][:len(own)] == own
