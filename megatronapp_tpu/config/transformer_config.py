@@ -54,14 +54,15 @@ PATTERN_STACKS = {"M": "mixers_ssm", "*": "mixers_attn", "E": "ffn",
 
 
 @functools.lru_cache(maxsize=None)
-def _stack_plan(num_layers, period, offset, pattern, lead, conv, window):
+def _stack_plan(num_layers, period, offset, pattern, lead, conv, window,
+                kda):
     """TransformerConfig.stack_plan of the fields that spell it."""
     if pattern is not None:
         return tuple((PATTERN_STACKS[letter],) for letter in pattern)
     if period is None:
         return None
-    other = ("mixers_swa" if window else
-             "mixers_conv" if conv else "mixers_ssm")
+    other = ("mixers_swa" if window else "mixers_conv" if conv else
+             "mixers_kda" if kda else "mixers_ssm")
     return tuple(("mixers_attn" if i % period == offset else other,
                   "ffn_lead" if i < lead else "ffn")
                  for i in range(num_layers))
@@ -246,6 +247,19 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_chunk_size: int = 256
     shortconv_kernel: int = 0
+    # kda_heads > 0 makes every non-attention layer's first half a Kimi
+    # delta attention mixer instead (Kimi Linear, arXiv:2510.26692; HF
+    # `solar_open2`: linear_attn_config, kda_*; transformer/kda.py): kda_heads
+    # heads, each a matrix state S [ssm_state_dim, ssm_head_dim] (key
+    # channels x value columns) under the gated delta rule, S' = diag(a) S;
+    # S = S' + b k (v - S'^T k)^T, with a decay a KEY CHANNEL a in (0, 1)
+    # and b in (0, 2); q, k and v each through a causal convolution of
+    # ssm_conv_kernel taps; the decay and the output gate from low-rank
+    # projections of the head's width (rank ssm_head_dim). Its state
+    # lives in the state-space mixers' tenant ([L, slots, ssm_state_dim,
+    # ssm_inner] float32 and one tail row over q, k and v), and a prefill
+    # runs in chunks of ssm_chunk_size positions.
+    kda_heads: int = 0
 
     # Sliding-window attention layers beside full ones in one stack (HF
     # `laguna`: layer_types, sliding_window, num_attention_heads_per_layer,
@@ -264,12 +278,16 @@ class TransformerConfig:
     # behind the window (inference/paged_cache.py).
     # attention_output_gate: every attention layer multiplies each head's
     # output by sigmoid(u w_g) of the layer's normed input u before the
-    # output projection ("gate_kernel" [hidden, heads]).
+    # output projection ("gate_kernel" [hidden, heads]); with
+    # attention_gate_elementwise the gate is one an ELEMENT of the heads'
+    # outputs ("gate_kernel" [hidden, heads x head_dim]: the G1 form of Qiu et
+    # al., arXiv:2505.06708; HF `solar_open2` use_gqa_gate).
     sliding_window: int = 0
     sliding_window_heads: Optional[int] = None
     sliding_rotary_base: Optional[float] = None
     sliding_rotary_percent: float = 1.0
     attention_output_gate: bool = False
+    attention_gate_elementwise: bool = False
 
     # EVA attention (HF `evabyte`: attention_class "eva", window_size,
     # chunk_size; Zheng et al. 2023, "Efficient Attention via Control
@@ -555,6 +573,18 @@ class TransformerConfig:
                     "ssm_expand is not read), whose B and C are shared by "
                     f"the heads of each of ssm_groups={self.ssm_groups} "
                     "groups: a whole number of heads a group")
+        if self.kda_heads and (
+                self.attn_layer_period is None or self.ssm_heads
+                or self.shortconv_kernel or self.sliding_window
+                or self.ssm_chunk_size < 1):
+            raise ValueError(
+                f"kda_heads={self.kda_heads} makes the non-attention layers "
+                "of a hybrid stack (attn_layer_period) Kimi delta attention "
+                "mixers: no ssm_heads, shortconv_kernel or sliding_window")
+        if self.attention_gate_elementwise and not self.attention_output_gate:
+            raise ValueError(
+                "attention_gate_elementwise is the form of "
+                "attention_output_gate's gate")
         if self.attention_multiplier is not None and (
                 self.multi_latent_attention or self.is_eva):
             raise ValueError(
@@ -654,7 +684,8 @@ class TransformerConfig:
         from either spelling: a period of two-half layers (a mixer:
         "mixers_attn" where i % attn_layer_period == attn_layer_offset, else
         "mixers_swa" with sliding_window, "mixers_conv" with
-        shortconv_kernel, "mixers_ssm"; and a feed-forward: "ffn_lead", the
+        shortconv_kernel, "mixers_kda" with kda_heads, "mixers_ssm"; and a
+        feed-forward: "ffn_lead", the
         dense one of the moe_first_k_dense leading layers, else "ffn"), or a
         layer_pattern of single-sublayer ones (PATTERN_STACKS). What walks,
         initialises or serves such a stack reads this (transformer/block.py
@@ -662,7 +693,8 @@ class TransformerConfig:
         return _stack_plan(
             self.num_layers, self.attn_layer_period, self.attn_layer_offset,
             self.layer_pattern, self.moe_first_k_dense,
-            bool(self.shortconv_kernel), bool(self.sliding_window))
+            bool(self.shortconv_kernel), bool(self.sliding_window),
+            bool(self.kda_heads))
 
     @property
     def hybrid_stack(self) -> bool:
@@ -726,8 +758,15 @@ class TransformerConfig:
 
     @property
     def num_ssm_layers(self) -> int:
-        """Layers whose mixer is a state-space mixer."""
-        return self._layers_holding("mixers_ssm")
+        """Layers whose mixer keeps a matrix or vector state a slot beside
+        a convolution's tail: state-space mixers, or Kimi delta attention
+        (num_kda_layers; a model has one of the two)."""
+        return self._layers_holding("mixers_ssm") + self.num_kda_layers
+
+    @property
+    def num_kda_layers(self) -> int:
+        """Layers whose mixer is Kimi delta attention."""
+        return self._layers_holding("mixers_kda")
 
     @property
     def num_conv_layers(self) -> int:
@@ -748,16 +787,19 @@ class TransformerConfig:
     @property
     def ssm_inner(self) -> int:
         """E, the state-space mixer's inner width: Mamba-2's heads x
-        head_dim, Mamba-1's ssm_expand x hidden_size."""
-        if self.ssm_heads:
-            return self.ssm_heads * self.ssm_head_dim
+        head_dim, Mamba-1's ssm_expand x hidden_size; Kimi delta attention's
+        heads x value columns."""
+        if self.ssm_heads or self.kda_heads:
+            return (self.ssm_heads or self.kda_heads) * self.ssm_head_dim
         return self.ssm_expand * self.hidden_size
 
     @property
     def ssm_conv_channels(self) -> int:
         """Columns the state-space mixer's causal convolution runs over, and
         so of a slot's cached tail: Mamba-1's expanded input; Mamba-2's x, B
-        and C side by side."""
+        and C side by side; Kimi delta attention's q, k and v."""
+        if self.kda_heads:
+            return 2 * self.kda_heads * self.ssm_state_dim + self.ssm_inner
         if self.ssm_heads:
             return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_dim
         return self.ssm_inner

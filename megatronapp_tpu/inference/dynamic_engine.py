@@ -319,11 +319,11 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
     place alike: (k, v, ssm, conv), (k, v, conv) or (k, v, k_window,
     v_window). A layer's mixer says which it writes, in the plane that is
     its row of its stack: "mixers_attn" the KV pools (kv_plane), "mixers_swa"
-    the window pools through their own table, "mixers_ssm" / "mixers_conv"
-    the state pools at `rows`, the slots of h's rows (None: row b is slot
-    b); a feed-forward alone none. The experts' stacks are read through the
-    layer's row of "ffn" as below, and the layers' counts are summed along
-    the loop's carry.
+    the window pools through their own table, "mixers_ssm" / "mixers_kda" /
+    "mixers_conv" the state pools at `rows`, the slots of h's rows (None: row
+    b is slot b); a feed-forward alone none. The experts' stacks are read
+    through the layer's row of "ffn" as below, and the layers' counts are
+    summed along the loop's carry.
 
     A shortcut-connected double layer (cfg.moe_shortcut_double_layer) is
     one step of the same scan: layer_forward runs its two attention
@@ -362,7 +362,7 @@ def _scan_paged_layers(params, h, pages, scales, lora, cfg, layer,
                 (hh, new), aux = layer(layer_p, hh, lid, pools[2:4], None,
                                        None, kv_plane=at[mixer], window=True)
                 pools = pools[:2] + tuple(new[:2]) + pools[4:]
-            elif mixer in ("mixers_ssm", "mixers_conv"):
+            elif mixer in ("mixers_ssm", "mixers_kda", "mixers_conv"):
                 (hh, new), aux = layer(layer_p, hh, lid, None, None, None,
                                        ssm_state=pools[2:] + (at[mixer],),
                                        state_rows=rows)
@@ -740,6 +740,14 @@ def prefill_call_costs(cfg: TransformerConfig, params):
         q, n, e = cfg.ssm_chunk_size, cfg.ssm_state_dim, cfg.ssm_inner
         flops += cfg.num_ssm_layers * 2.0 * (
             q * n * cfg.ssm_groups + q * e + 2 * n * e)
+    if cfg.kda_heads:
+        # A Kimi-delta-attention layer's chunked pass (transformer/kda.py),
+        # a position: its row of A and of B (Q scores of K a head each), of
+        # the solve and of B U (Q x V a head each), K x E into the chunk's
+        # state and twice K x E out of the one that came in.
+        q, n, e = cfg.ssm_chunk_size, cfg.ssm_state_dim, cfg.ssm_inner
+        flops += cfg.num_kda_layers * 2.0 * (
+            2 * q * n * cfg.kda_heads + 2 * q * e + 3 * n * e)
     return stream, flops
 
 
@@ -1112,6 +1120,7 @@ class DynamicInferenceEngine:
 
     def _state_words(self) -> str:
         return ("gated short-convolution layers" if self.state_kind == "conv"
+                else "Kimi-delta-attention layers" if self.cfg.kda_heads
                 else "state-space layers")
 
     def startup_line(self) -> str:
@@ -2895,7 +2904,7 @@ class DynamicInferenceEngine:
         `resets` (sequences started from zeros at admission), `dropped`
         (states thrown away by preemption), `prefill_scans` (chunk scans
         run: prefill calls x such layers); of kind "ssm" also which
-        `mixer` ("mamba1", "mamba2"), its `heads` (0: a vector state a
+        `mixer` ("mamba1", "mamba2", "kda"), its `heads` (0: a vector state a
         channel), `state_dim` and the convolution's `conv_channels`.
         "sampler" counts what the sampler was asked for, by
         plain decode rounds and by prefills' first samples: `*_greedy`
@@ -3005,8 +3014,9 @@ class DynamicInferenceEngine:
                 bytes_per_slot=self.pool.state_bytes_per_slot)
             if self.state_kind == "ssm":
                 out["state"].update(
-                    mixer="mamba2" if self.cfg.ssm_heads else "mamba1",
-                    heads=self.cfg.ssm_heads,
+                    mixer=("kda" if self.cfg.kda_heads else
+                           "mamba2" if self.cfg.ssm_heads else "mamba1"),
+                    heads=self.cfg.ssm_heads or self.cfg.kda_heads,
                     state_dim=self.cfg.ssm_state_dim,
                     conv_channels=self.cfg.ssm_conv_channels)
         if self.cfg.is_moe:

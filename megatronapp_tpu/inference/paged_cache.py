@@ -177,7 +177,8 @@ _STATE_WHY = ("this model has {}, whose recurrent state lives in the paged "
               "snapshots: ROADMAP M4)")
 TENANT_LACKS = {
     "ssm": (lambda cfg: cfg.num_ssm_layers > 0,
-            _STATE_WHY.format("state-space layers"), _STATE_LACKS),
+            _STATE_WHY.format("recurrent mixers (state-space or Kimi delta "
+                              "attention layers)"), _STATE_LACKS),
     "conv": (lambda cfg: cfg.num_conv_layers > 0,
              _STATE_WHY.format("gated short-convolution layers"),
              _STATE_LACKS),
@@ -382,13 +383,15 @@ class PagedKVCache:
                                 for _ in shapes)
 
         # The second tenant: the recurrent state of a hybrid stack's
-        # state-space layers (transformer/ssm.py), which no page table
+        # recurrent mixers (state-space layers, transformer/ssm.py, or Kimi
+        # delta attention, transformer/kda.py), which no page table
         # names. A slot owns row `slot` of every layer's plane, whatever
         # its sequence's length: h [N, E] in float32 (E minor: a whole
-        # number of 128-lane vregs; a Mamba-2 layer's matrix state a head
-        # is that head's columns of it, 4 MiB a slot a layer at [128, 8192])
+        # number of 128-lane vregs; a Mamba-2 or Kimi-delta-attention
+        # layer's matrix state a head is that head's columns of it, 4 MiB a
+        # slot a layer at [128, 8192])
         # and the convolution's last k-1 inputs (Mamba-1: E columns each;
-        # Mamba-2: x, B and C, E + 2N)
+        # Mamba-2: x, B and C, E + 2N; Kimi delta attention: q, k and v, 3E)
         # in the compute type, side by side in one row [(k-1) * C] (as
         # [L, slots, k-1, E] XLA pads 3 taps to 4 sublanes and relayouts
         # all of it on the way into a decode step and out again; as
@@ -610,8 +613,8 @@ class PagedKVCache:
 
     @property
     def state_bytes_per_slot(self) -> int:
-        """What one slot's recurrent state takes (0 without state-space
-        layers)."""
+        """What one slot's recurrent state takes (0 without recurrent
+        mixers)."""
         return sum(p.size * p.dtype.itemsize
                    for p in self.state or ()) // self.max_batch
 

@@ -366,6 +366,59 @@ def nemotron_3_nano_30b_a3b(**kw) -> TransformerConfig:
     return TransformerConfig(**d)
 
 
+def solar_open2_250b(**kw) -> TransformerConfig:
+    """upstage/Solar-Open2-250B (`solar_open2`; 250B parameters, ~15B
+    active) as its config.json publishes it
+    (https://huggingface.co/upstage/Solar-Open2-250B/blob/main/config.json):
+    48 layers of H 4096 of which 0, 4, 8, ... attend (gqa_layers: 64 query
+    heads over 8 key/value heads of 128, NO positional term, an elementwise
+    sigmoid gate on the heads' outputs) and the other 36 are Kimi delta
+    attention (transformer/kda.py: 64 heads, a [128, 128] float32 state a
+    head under the gated delta rule with a decay a key channel, b in (0, 2),
+    convolutions of 4 taps over q, k and v, low-rank (128) decay and output
+    gates); every layer ends in 320 experts of width 1280 (SwiGLU), a
+    sigmoid router whose 8 picks are the largest of s + b, the weights s
+    renormalised, beside one shared expert of 1280; RMSNorm 1e-5, an untied
+    head over 196,608. Whole it is 500 GB of bf16 weights: a deployment
+    passes its share (num_layers, moe_experts_held, vocab_size), as the
+    benchmark's configuration does
+    (perfbench/configs/solar-open2-250b.json, which also lists what the
+    config leaves to the family's convention). Serves through --engine
+    dynamic."""
+    d = dict(num_layers=48, hidden_size=4096, num_attention_heads=64,
+             num_query_groups=8, kv_channels=128, ffn_hidden_size=10240,
+             vocab_size=196608, max_position_embeddings=1048576,
+             normalization=NormKind.rmsnorm, layernorm_epsilon=1e-5,
+             activation=ActivationKind.swiglu, add_bias_linear=False,
+             position_embedding=PositionEmbeddingKind.none,
+             untie_embeddings_and_output_weights=True,
+             attn_layer_period=4, attn_layer_offset=0, scaled_init_layers=48,
+             attention_output_gate=True, attention_gate_elementwise=True,
+             kda_heads=64, ssm_head_dim=128, ssm_state_dim=128,
+             ssm_conv_kernel=4, ssm_chunk_size=64,
+             num_moe_experts=320, moe_router_topk=8,
+             moe_ffn_hidden_size=1280,
+             moe_shared_expert_intermediate_size=1280,
+             moe_router_score="sigmoid", moe_router_selection_bias=True,
+             moe_router_norm_topk_prob=True, moe_routed_scaling_factor=1.0)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
+def solar_open2_tiny(**kw) -> TransformerConfig:
+    """solar_open2_250b at a size the CPU tests run: 5 layers (G K K K G) of
+    H 64, 4 query heads over 2 key/value heads of 16, 2 Kimi-delta-attention
+    heads with a [16, 16] state in chunks of 32, 8 experts of width 32 top-2
+    beside a shared one, 256 tokens."""
+    return solar_open2_250b(**{**dict(
+        num_layers=5, hidden_size=64, num_attention_heads=4,
+        num_query_groups=2, kv_channels=16, ffn_hidden_size=128,
+        vocab_size=256, max_position_embeddings=512, scaled_init_layers=5,
+        kda_heads=2, ssm_head_dim=16, ssm_state_dim=16, ssm_chunk_size=32,
+        num_moe_experts=8, moe_router_topk=2, moe_ffn_hidden_size=32,
+        moe_shared_expert_intermediate_size=32), **kw})
+
+
 def evabyte_6p5b(**kw) -> TransformerConfig:
     """EvaByte/EvaByte (6.5B, byte-level) as its config.json publishes it:
     32 layers of H 4096, 32 query and 32 key/value heads of 128, RoPE
@@ -388,6 +441,8 @@ def evabyte_6p5b(**kw) -> TransformerConfig:
 
 
 PRESETS = {
+    "solar-open2-250b": solar_open2_250b,
+    "solar-open2-tiny": solar_open2_tiny,
     "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b,
     "evabyte-6.5b": evabyte_6p5b,
     "granite-4.0-h-small": granite_4_0_h_small,

@@ -60,8 +60,13 @@ PARTS = ("attention", "ssm", "conv", "mlp", "moe", "embedding", "head",
 # (transformer/block.py). A sub-part is no part: the window layers' time
 # stays in `attention`, and Scoped.sub says which of it is theirs. A Mamba-2
 # mixer's chunked scan and its gated norm are written under `ssm/ssd_chunk`
-# and `ssm/gated_norm` (transformer/ssm.py).
-SUBPARTS = {"attention": ("window",), "ssm": ("ssd_chunk", "gated_norm")}
+# and `ssm/gated_norm` (transformer/ssm.py); a Kimi-delta-attention mixer, a
+# recurrent mixer too, runs under part `ssm` with its chunked pass, its decode
+# kernel and its gated head norm under `ssm/kda_chunk`, `ssm/kda_update` and
+# `ssm/kda_gate_norm` (transformer/kda.py).
+SUBPARTS = {"attention": ("window",),
+            "ssm": ("ssd_chunk", "gated_norm", "kda_chunk", "kda_update",
+                    "kda_gate_norm")}
 OTHER = "other"
 MAX_STEPS = 16
 # An option at its default value: the compiled program is the same, the

@@ -15,6 +15,7 @@ Param leaf layout (per layer, unstacked):
   (optional) q_ln_scale, k_ln_scale [D]
   (EVA, cfg.eva_window_size) eva_phi, eva_mu [n_kv, D]: transformer/eva.py
   (cfg.attention_output_gate) gate_kernel [H, n_heads]   ('embed', 'heads')
+  (  + attention_gate_elementwise)        [H, n_heads*D] ('embed', 'qkv')
 
 n_heads is cfg.num_attention_heads, or in a sliding-window layer of a stack
 that says so cfg.window_heads: the forward reads it off q_kernel.
@@ -148,9 +149,12 @@ def init_attention_params(rng, cfg: TransformerConfig, out_std: float,
         p.update(eva_p)
         ax.update(eva_ax)
     if cfg.attention_output_gate:
+        # one gate a head, or (attention_gate_elementwise) one an element
+        wide = nq * d if cfg.attention_gate_elementwise else nq
         p["gate_kernel"] = jax.random.normal(
-            jax.random.fold_in(rng, 4), (h, nq), cfg.params_dtype) * std
-        ax["gate_kernel"] = ("embed", "heads")
+            jax.random.fold_in(rng, 4), (h, wide), cfg.params_dtype) * std
+        ax["gate_kernel"] = ("embed", "qkv" if cfg.attention_gate_elementwise
+                             else "heads")
     return p, ax
 
 
@@ -684,11 +688,14 @@ def attention_forward(
                 q_offset=q_offset, layer_id=layer_id)
     attn_out = scope_capture("context", attn_out, layer_id)
     if gated:
-        # one sigmoid gate a head, from the layer's normed input
+        # one sigmoid gate a head (gate_kernel [H, heads]) or an element
+        # ([H, heads x D]), from the layer's normed input
         gate = jax.nn.sigmoid(dense(
             x, resolve_param(p["gate_kernel"]).astype(cfg.compute_dtype)
         ).astype(jnp.float32))
-        attn_out = (attn_out * gate[..., None]).astype(attn_out.dtype)
+        gate = (gate[..., None] if gate.shape[-1] == nq
+                else gate.reshape(attn_out.shape))
+        attn_out = (attn_out * gate).astype(attn_out.dtype)
 
     out_kernel = _dist.apply("weight", resolve_param(p["out_kernel"]),
                              layer_id)

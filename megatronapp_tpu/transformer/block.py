@@ -56,7 +56,8 @@ def _init_mixer_half(rng, cfg: TransformerConfig, out_std,
     cfg.stack_plan stacks under `stack`: attention (or MLA), under
     "mixers_swa" a sliding-window attention layer of cfg.window_heads query
     heads, under "mixers_conv" a gated short convolution, under "mixers_ssm"
-    a selective-state-space mixer."""
+    a selective-state-space mixer, under "mixers_kda" Kimi delta
+    attention."""
     if stack == "mixers_swa":
         name = "attention"
         mix_p, mix_ax = init_attention_params(rng, cfg, out_std,
@@ -71,6 +72,10 @@ def _init_mixer_half(rng, cfg: TransformerConfig, out_std,
         from megatronapp_tpu.transformer.ssm import init_ssm_params, ssm_dims
         name = "ssm"
         mix_p, mix_ax = init_ssm_params(rng, cfg, ssm_dims(cfg), out_std)
+    elif stack == "mixers_kda":
+        from megatronapp_tpu.transformer.kda import init_kda_params
+        name = "kda"
+        mix_p, mix_ax = init_kda_params(rng, cfg, out_std)
     elif cfg.multi_latent_attention:
         from megatronapp_tpu.transformer.mla import init_mla_params
         name = "attention"
@@ -153,7 +158,9 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
     the window pools', their table's and its plane of them.
 
     A layer whose params hold "ssm" in place of "attention" runs the
-    selective-state-space mixer (transformer/ssm.py) as its first half.
+    selective-state-space mixer (transformer/ssm.py) as its first half; one
+    that holds "kda" Kimi delta attention (transformer/kda.py), on the same
+    two pools.
     In a paged serving step such a layer has no kv_cache: ssm_state =
     (ssm pool, conv pool, this layer's plane of them), state_rows maps x's
     rows to slots, and new_cache is the two state pools. A layer that
@@ -308,6 +315,25 @@ def layer_forward(p, x: jnp.ndarray, cfg: TransformerConfig,
                 else:
                     attn_out, _ = ssm_forward(p["ssm"], h, cfg, ssm_dims(cfg))
                     new_cache = None
+        elif "kda" in p:
+            if segment_ids is not None or tp_sharded or lora is not None:
+                raise NotImplementedError(
+                    "a Kimi delta attention layer runs whole sequences on "
+                    "one tp shard: no packed segments (the state would "
+                    "cross them), tp-sharded stage body or lora")
+            from megatronapp_tpu.transformer.kda import (
+                kda_forward, kda_paged_forward,
+            )
+            # the recurrent mixers' part (trace/scope_map.PARTS)
+            with jax.named_scope("ssm"):
+                if ssm_state is not None:
+                    attn_out, new_cache = kda_paged_forward(
+                        p["kda"], h, cfg, ssm_state, rows=state_rows,
+                        starts=cache_positions, counts=chunk_counts,
+                        active=active)
+                else:
+                    attn_out, _ = kda_forward(p["kda"], h, cfg)
+                    new_cache = None
         elif "conv" in p:
             if tp_sharded or lora is not None:
                 raise NotImplementedError(
@@ -455,7 +481,7 @@ def _vmapped_layers(keys, init):
 HALF_INITS = {
     **{stack: functools.partial(_init_mixer_half, stack=stack)
        for stack in ("mixers_attn", "mixers_swa", "mixers_conv",
-                     "mixers_ssm")},
+                     "mixers_ssm", "mixers_kda")},
     "ffn": _init_ffn_half,
     "ffn_lead": functools.partial(_init_ffn_half, force_dense=True),
     "ffn_dense": functools.partial(_init_ffn_half, force_dense=True),

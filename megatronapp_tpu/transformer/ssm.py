@@ -349,8 +349,8 @@ def _plain_update(h, dt, u, b, c, a_t, d):
 def _causal_conv(raw, tail, p, k: int):
     """The causal depthwise convolution of `raw` [B,S,C] over the k - 1
     inputs before it (`tail` [B,k-1,C]; None: a sequence's start, zeros),
-    its bias and silu, in float32 -> (the padded inputs [B,S+k-1,C], the
-    result [B,S,C])."""
+    its bias (where the mixer has one) and silu, in float32 -> (the padded
+    inputs [B,S+k-1,C], the result [B,S,C])."""
     s = raw.shape[1]
     if tail is None:
         padded = jnp.pad(raw, ((0, 0), (k - 1, 0), (0, 0)))
@@ -362,7 +362,9 @@ def _causal_conv(raw, tail, p, k: int):
     f32 = jnp.float32
     taps = p["conv_kernel"].astype(f32)
     conv = sum(padded[:, i:i + s].astype(f32) * taps[i] for i in range(k))
-    return padded, jax.nn.silu(conv + p["conv_bias"].astype(f32))
+    if "conv_bias" in p:
+        conv = conv + p["conv_bias"].astype(f32)
+    return padded, jax.nn.silu(conv)
 
 
 def _last_inputs(padded, s: int, counts, k: int):

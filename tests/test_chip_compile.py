@@ -1176,6 +1176,80 @@ def test_engine_pattern_steps_at_cell_shapes(one_chip, chip_compile, which,
     assert 11.9e9 < total < 13.0e9, total
 
 
+@pytest.mark.parametrize("which", ["decode", "chunked-prefill"])
+def test_engine_kda_steps_at_cell_shapes(one_chip, chip_compile, which):
+    """The two jits at Solar-Open2-250B's published widths and the doc
+    cell's sizes (its cut: layers 0-3 G K K K, experts 0-39 of 320, an
+    eighth of the vocabulary): 128 slots of S [128, 8192] float32 a Kimi-
+    delta-attention layer and one tail row over q, k and v, one gated GQA
+    layer of 8 key/value heads of 128 under 64 query heads, four layers of
+    40 held three-matrix experts of width 1280, a prefill call of the 1,024
+    positions the engine chooses for the cell on this chip (sixteen chunks
+    of 64). Mosaic takes `kda_update` (16 heads, a [128, 2048] block, a grid
+    step; its q, k and decay as [128, 48] columns) beside `paged_decode` at
+    8 heads (a page is whole tiles: the kernel starts its copies), and the
+    chunked pass is plain XLA under the prefill call; the step aliases the
+    page pools and the state pools alike and copies nothing of the state
+    pool's shape or one plane's; it fits the chip."""
+    from megatronapp_tpu.inference.dynamic_engine import (
+        DynamicInferenceEngine,
+    )
+    from megatronapp_tpu.models.gpt import init_gpt_params
+    batch, blocks, seq = 128, 65536, 18432
+    cut = dict(num_layers=4, moe_experts_held=(0, 40), vocab_size=24576,
+               vocab_slice_of=196608)
+    width = _cell_prefill_width(one_chip, "solar-open2-250b", seq, **cut)
+    assert width == 1024
+    cfg = PRESETS["solar-open2-250b"](params_dtype=jnp.bfloat16, **cut)
+    abstract = jax.eval_shape(lambda k: init_gpt_params(k, cfg)[0],
+                              jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree.leaves(abstract)) == 3_308_377_920
+    # two slots give the jits; the cell's 128 go in as abstract pools
+    eng = DynamicInferenceEngine(abstract, cfg, max_batch=2, max_seq_len=seq,
+                                 paged=True, num_blocks=8,
+                                 prefill_chunk=width)
+    ssm, conv = ((p.shape[0], batch) + p.shape[2:] for p in eng.pool.state)
+    assert ssm == (3, 128, 128, 8192) and conv == (3, 128, 3 * 24576)
+    pools = tuple(_sds(p.shape[:1] + (blocks,) + p.shape[2:], p.dtype,
+                       one_chip) for p in eng.pool.pages) \
+        + (_sds(ssm, jnp.float32, one_chip),
+           _sds(conv, jnp.bfloat16, one_chip))
+    assert pools[0].shape == (1, blocks, 16, 8, 128)
+    mb = eng.pool.page_table.shape[1]
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    p = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), abstract)
+    if which == "decode":
+        step, args = eng._decode, (
+            p, i32(batch, 1), pools, None, i32(batch, mb), i32(batch),
+            _sds((batch,), jnp.bool_, one_chip), None)
+        compiled = step.lower(*args).compile()
+        _assert_kernels_named(compiled, "paged_decode", "kda_update",
+                              "grouped_gemm")
+    else:
+        step, args = eng._mq_step, (
+            p, i32(1, eng.prefill_chunk), pools, None, i32(1, mb), i32(1),
+            i32(1), _sds((1,), jnp.bool_, one_chip), None, i32(1), i32(1))
+        compiled = step.lower(*args).compile()
+        _assert_kernels_named(compiled, "paged_mq", "grouped_gemm")
+    from megatronapp_tpu.utils.dispatch import page_copies
+    walk, = page_copies(jax.make_jaxpr(step)(*args).jaxpr).values()
+    assert walk == {"page_copies_step": 32, "page_copies_kernel": 32,
+                    "page_copy_bytes": [32768, 32768]}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in pools)
+    assert not _pool_shaped(compiled,
+                            r"copy|transpose|(?<!update[_-])slice",
+                            [ssm, ssm[1:], (1,) + ssm[1:]])
+    # weights 6.62 GB + state 1.67 GB + pages 4.29 GB and the step's own
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 12.5e9 < total < 13.2e9, total
+
+
 # The assist cell's cut of LFM2-24B-A2B: published layers 1..9
 # (perfbench/configs/lfm2-24b-a2b.json).
 LFM2_CUT = {"num_layers": 9, "attn_layer_offset": 1, "moe_first_k_dense": 1}

@@ -161,7 +161,7 @@ def test_benchmark_lists_the_cell_and_only_appends():
                   if c["name"] == "nemotron-3-nano-30b-a3b")
     assert len(config["source"]) <= 200 and len(config["why"]) <= 200
     assert config["reduced"] == PUBLISHED["reduced"]
-    assert len(man["per_layer"]) <= 128 and len(man["workloads"]) == 12
+    assert len(man["per_layer"]) <= 128 and len(man["workloads"]) >= 12
     assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 << 10
     parent = subprocess.run(["git", "show", PARENT + ":BENCHMARK.json"],
@@ -180,8 +180,8 @@ def test_benchmark_lists_the_cell_and_only_appends():
             assert old == new, old["name"]
     assert was["command"] == man["command"]
     assert was["run_seconds"] == man["run_seconds"]
-    assert [w["name"] for w in man["workloads"][len(was["workloads"]):]] \
-        == [CELL]
     # (later PRs append theirs behind these)
+    assert [w["name"] for w in man["workloads"][len(was["workloads"]):]][
+        :1] == [CELL]
     assert [m["name"] for m in man["per_layer"][len(was["per_layer"]):]][
         :len(MINE)] == MINE
