@@ -2857,8 +2857,10 @@ class DynamicInferenceEngine:
         pallas_calls a step, scan bodies times their length;
         `expert_stack_slices` its equations that cut one layer's expert
         kernel out of the stack (0 when the grouped GEMMs read the stack
-        in place, and on a dense model). One trace of the jaxpr, cached
-        per jit build; nothing is compiled."""
+        in place, and on a dense model); `scatters` its scatter equations
+        (0 where a kernel appends the pages: the dropless experts move rows
+        by gathers alone). One trace of the jaxpr, cached per jit build;
+        nothing is compiled."""
         if self._dispatch_stats is not None and not force:
             return self._dispatch_stats
         from megatronapp_tpu.utils.dispatch import launch_stats

@@ -32,6 +32,7 @@ Two dispatch modes, matching the reference's semantics:
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -118,6 +119,8 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
         topk_probs = jnp.take_along_axis(probs, topk_idx, axis=-1)
     else:
         topk_probs, topk_idx = jax.lax.top_k(probs, cfg.moe_router_topk)
+    topk_probs = _picked_given(probs, topk_idx,
+                               jax.lax.stop_gradient(topk_probs))
     if cfg.moe_router_norm_topk_prob:
         total = jnp.sum(topk_probs, -1, keepdims=True)
         # + 1e-6: the published constant of the sigmoid routers (lfm2_moe)
@@ -144,6 +147,31 @@ def _router(p, x_flat: jnp.ndarray, cfg: TransformerConfig,
         aux = aux + cfg.moe_z_loss_coeff * stats_mean(
             jnp.mean(jnp.square(z)))
     return topk_idx, topk_probs, aux
+
+
+@jax.custom_vjp
+def _picked_given(probs, idx, picked):
+    """`picked` [T, k], which is probs[t, idx[t, j]] as the top-k found it,
+    with that gather's gradient by compare-and-sum: the transpose JAX gives
+    ``lax.top_k`` and ``take_along_axis`` is a scatter-add of T*k scalars
+    (0.49 ms a layer pass of the share-training cell where this takes 0.05:
+    PERF.md, PR 60). A token's k picks are distinct, so every sum holds one
+    term and the gradient is the scatter's bit for bit."""
+    return picked
+
+
+def _picked_given_fwd(probs, idx, picked):
+    return picked, (idx, probs.shape[-1])
+
+
+def _picked_given_bwd(res, g):
+    idx, width = res
+    hit = idx[..., None] == jnp.arange(width)
+    return (jnp.sum(jnp.where(hit, g[..., None], 0), axis=-2), None,
+            jnp.zeros_like(g))
+
+
+_picked_given.defvjp(_picked_given_fwd, _picked_given_bwd)
 
 
 def _apply_act(cfg: TransformerConfig, y: jnp.ndarray) -> jnp.ndarray:
@@ -226,9 +254,10 @@ def _ragged_dot(x, w, group_sizes):
     it, and so is their row of x's cotangent in the backward products. A
     reader that only masks what it reads is not safe under a gradient: the
     zero cotangent of a masked row still meets the row's value (0 x NaN in
-    the router weights' and the gated activation's gradients), and the
-    undefined cotangent rows are scatter-added into the tokens'. With both
-    selects every value a training step touches is defined (its gradient
+    the router weights' and the gated activation's gradients), and an
+    undefined cotangent row is a row of the buffer that the tokens' gradient
+    is gathered from (_dispatch_rows selects by position, not by value). With
+    both selects every value a training step touches is defined (its gradient
     was NaN on the chip without them, from the first step or some steps
     later: PERF.md, PR 48). The paged serving steps run the Pallas kernel,
     not this."""
@@ -263,6 +292,107 @@ def _product_given_bwd(res, g):
 _product_given.defvjp(_product_given_fwd, _product_given_bwd)
 
 
+def _placed(at, values):
+    """out[at[i]] = values[i] for a permutation `at` [N] of the N places:
+    what a scatter would write, by a sort of the pairs (a TPU sorts 65,536
+    pairs in 36 us, gathers as many scalars in 470 and scatters 20,480 of
+    them in 174: PERF.md, PR 60)."""
+    return jax.lax.sort((at, values), num_keys=1, is_stable=False)[1]
+
+
+def _sorted_picks(slot, count: int):
+    """The sort of the picks by `slot` ([T*k] int32 in [0, count]: a pick's
+    expert among the `count` held here, or `count` for a pick of none of
+    them): (order, inv, group_sizes). `order` [T*k] is the stable sort's
+    permutation (the pick at each sorted position), `inv` [T*k] its inverse
+    (each pick's sorted position), `group_sizes` [count] int32 the picks of
+    each held expert, which lie first and group by group. Nothing here
+    scatters: the sizes are a compare-and-sum, and the inverse is a second
+    sort, of the positions by the picks found there."""
+    order = jnp.argsort(slot)
+    inv = _placed(order, jnp.arange(slot.shape[0], dtype=order.dtype))
+    group_sizes = jnp.sum(slot[:, None] == jnp.arange(count), axis=0,
+                          dtype=jnp.int32)
+    return order, inv, group_sizes
+
+
+def _sum_of_picks(buf, at, n, w=None):
+    """out[t] = Σ_j (w[j, t] ·) buf[at[j, t]] over the picks j whose sorted
+    position at[j, t] lies among the first `n`, [T, H] float32: ONE gather of
+    the k*T picks' rows of buf [rows, H], pick-major, and the sum of its k
+    [T, H] slabs one after another in pick order. An elementwise sum of
+    whole slabs, not a reduction: over an axis of k a TPU pads 10 rows to a
+    tile of 16 or copies the buffer to reshape it, and converts it whole
+    before it adds (PERF.md, PR 60). A pick behind the first n adds 0.0 by
+    a select, never by a product."""
+    k, t = at.shape
+    picked = jnp.take(buf, at.reshape(-1), axis=0, mode="clip")
+
+    def term(j):
+        rows = picked[j * t:(j + 1) * t].astype(jnp.float32)
+        if w is not None:
+            rows = rows * w[j][:, None]
+        return jnp.where((at[j] < n)[:, None], rows, 0.0)
+    return functools.reduce(jnp.add, map(term, range(k)))
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, token_of, at, n):
+    """x[token_of]: the tokens' rows x [T, H] in the sorted picks' order
+    (token_of [rows]: the first `rows` sorted picks' tokens). Its transpose
+    is a gather too, through `at` [k, T], the sorted position of token t's
+    pick j: a token's cotangent is the sum of its k picks' rows, of those
+    among the first `n` (the rows of the groups; behind them a row belongs
+    to no expert and a compact buffer may not hold it at all)."""
+    return jnp.take(x, token_of, axis=0, mode="clip")
+
+
+def _dispatch_rows_fwd(x, token_of, at, n):
+    return _dispatch_rows(x, token_of, at, n), (at, n)
+
+
+def _dispatch_rows_bwd(res, g):
+    at, n = res
+    return _sum_of_picks(g, at, n).astype(g.dtype), None, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(y, w, order, at, n):
+    """out[t] = Σ_j w[t, j] · y[at[j, t]] over the picks j of token t that
+    lie among the first `n` sorted rows, [T, H] float32 (_sum_of_picks: a
+    gather of the picks' rows of y [rows, H] through `at` [k, T], products
+    and sum in float32, in pick order). A pick behind the groups (an absent
+    or zero-compute expert's, or one behind a compact buffer) adds 0.0 by a
+    select: those rows of y are undefined on the chip, NaN among them
+    (_ragged_dot). Its transpose gathers as well, through `order` [T*k], the
+    picks of the buffer's rows; what it moves of scalars, a weight or its
+    cotangent a pick, a sort moves (_placed)."""
+    return _sum_of_picks(y, at, n, w.T.astype(jnp.float32))
+
+
+def _combine_rows_fwd(y, w, order, at, n):
+    return _combine_rows(y, w, order, at, n), (y, w, order, at, n)
+
+
+def _combine_rows_bwd(res, g):
+    y, w, order, at, n = res
+    rows, (t, k) = y.shape[0], w.shape
+    live = (jnp.arange(rows) < n)[:, None]
+    g_rows = jnp.take(g, order[:rows] // k, axis=0, mode="clip")
+    w_rows = _placed(at.reshape(-1), w.T.reshape(-1).astype(jnp.float32))
+    dy = jnp.where(live, g_rows * w_rows[:rows, None], 0.0).astype(y.dtype)
+    dw_rows = jnp.sum(jnp.where(live, g_rows * y.astype(jnp.float32), 0.0),
+                      axis=1)
+    dw = _placed(order, jnp.pad(dw_rows, (0, t * k - rows)))
+    return dy, dw.reshape(t, k).astype(w.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def _dropless_experts(p, x_flat, topk_idx, topk_probs,
                       cfg: TransformerConfig) -> jnp.ndarray:
     """Exact dropless dispatch: sort the T*k token copies by expert id and
@@ -270,6 +400,8 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
     row groups — static shapes, no capacity buffer, zero drops. This is
     the reference's default behavior (no --moe-expert-capacity-factor ⇒
     dispatchers never drop; experts.py GroupedMLP runs ragged groups).
+    Rows move by gathers alone, through the sort's permutation and its
+    inverse (_sorted_picks, _held_rows).
 
     A layer that holds a share of the experts (cfg.moe_experts_held) or
     routes to zero-compute ones (cfg.moe_zero_experts) takes
@@ -277,23 +409,10 @@ def _dropless_experts(p, x_flat, topk_idx, topk_probs,
     rows alone."""
     if cfg.moe_picks_unheld:
         return _dropless_held_experts(p, x_flat, topk_idx, topk_probs, cfg)
-    t, h = x_flat.shape
-    k = cfg.moe_router_topk
-    e = cfg.num_moe_experts
-    dt = cfg.compute_dtype
-    flat_expert = topk_idx.reshape(t * k)
-    order = jnp.argsort(flat_expert)
-    token_of = order // k
-    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
-
-    x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
-    y = _grouped_gemm(x_sorted, p["fc1_kernel"], group_sizes, dt)
-    y = _grouped_gemm(_apply_act(cfg, y), p["fc2_kernel"], group_sizes, dt)
-
-    w_sorted = jnp.take(topk_probs.reshape(t * k), order).astype(
-        jnp.float32)
-    return jnp.zeros((t, h), jnp.float32).at[token_of].add(
-        y.astype(jnp.float32) * w_sorted[:, None])
+    rows = topk_idx.size
+    picks = _sorted_picks(topk_idx.reshape(rows), cfg.num_moe_experts)
+    return _held_rows((p["fc1_kernel"], p["fc2_kernel"]), x_flat, topk_probs,
+                      *picks, cfg, rows)[0]
 
 
 def _held_slot(flat_expert, cfg: TransformerConfig):
@@ -349,29 +468,24 @@ def row_buffer_rows(n, rows: int, cfg: TransformerConfig):
     return jnp.asarray(rungs, jnp.int32)[_rung_taken(n, rungs)]
 
 
-def _held_rows(kernels, x_flat, topk_probs, order, token_of, group_sizes,
+def _held_rows(kernels, x_flat, topk_probs, order, inv, group_sizes,
                cfg: TransformerConfig, rows: int, given=(None, None)):
     """Σ_{picks of a held expert e} w_e · FFN_e(x) over a buffer of the first
-    `rows` sorted picks (`order`, and the tokens they are of): ([T, H]
-    float32, the two grouped products). Every group's rows have to lie
-    inside the buffer; the rows behind the groups belong to none, cost no
-    GEMM step, and their (undefined) output rows are masked before the
-    weighted sum. `given`: the two products as a forward pass kept them."""
-    t, h = x_flat.shape
+    `rows` sorted picks (_sorted_picks' `order`, its inverse and the groups'
+    sizes): ([T, H] float32, the two grouped products). Every group's rows
+    have to lie inside the buffer; the rows behind the groups belong to
+    none, cost no GEMM step, and their (undefined) output rows are never
+    read into the weighted sum (_combine_rows). `given`: the two products
+    as a forward pass kept them."""
     dt = cfg.compute_dtype
     fc1, fc2 = kernels
-    if rows < order.shape[0]:       # the T*k buffer: no slice, as before
-        order, token_of = order[:rows], token_of[:rows]
-    in_group = jnp.arange(rows) < jnp.sum(group_sizes)
-    x_sorted = jnp.take(x_flat.astype(dt), token_of, axis=0)
+    t, k = topk_probs.shape
+    at = inv.reshape(t, k).T
+    n = jnp.sum(group_sizes)
+    x_sorted = _dispatch_rows(x_flat.astype(dt), order[:rows] // k, at, n)
     y1 = _grouped_gemm(x_sorted, fc1, group_sizes, dt, given[0])
     y2 = _grouped_gemm(_apply_act(cfg, y1), fc2, group_sizes, dt, given[1])
-
-    flat_w = topk_probs.reshape(-1).astype(jnp.float32)
-    y = jnp.where(in_group[:, None],
-                  y2.astype(jnp.float32) * jnp.take(flat_w, order)[:, None],
-                  0.0)
-    return jnp.zeros((t, h), jnp.float32).at[token_of].add(y), (y1, y2)
+    return _combine_rows(y2, topk_probs, order, at, n), (y1, y2)
 
 
 def _laddered_rows(cfg: TransformerConfig, rungs: Tuple[int, ...]):
@@ -450,29 +564,22 @@ def _dropless_held_experts(p, x_flat, topk_idx, topk_probs,
     The picks of the held experts sort to the front, group by group; the
     group sizes cover those rows alone, so the grouped GEMMs' tiles run
     over them and over nothing else. What is gathered, multiplied, weighed
-    and scattered is a row buffer of the first R sorted picks (_held_rows),
-    R chosen in the call, on the device, as the smallest of a few static
-    sizes (_row_buffer_rungs) that holds the n picks that landed here: one
-    branch a size, of which a TPU runs the one taken. The last size is T*k
-    (any token may pick k held experts), so nothing is ever dropped, and
-    the sum is the T*k buffer's bit for bit: the real rows keep their order
-    in the scatter-add and the rows behind them added 0.0. What the absent
-    experts would have added is left out; the identity term needs no
-    weights and is computed here whole."""
-    t, _ = x_flat.shape
-    k = cfg.moe_router_topk
-    flat_expert = topk_idx.reshape(t * k)
-    slot, count = _held_slot(flat_expert, cfg)
-    order = jnp.argsort(slot)
-    token_of = order // k
-    group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
-        jnp.int32)
-
-    rungs = _row_buffer_rungs(t * k, count, cfg.moe_router_width)
+    and gathered back is a row buffer of the first R sorted picks
+    (_held_rows), R chosen in the call, on the device, as the smallest of a
+    few static sizes (_row_buffer_rungs) that holds the n picks that landed
+    here: one branch a size, of which a TPU runs the one taken. The last
+    size is T*k (any token may pick k held experts), so nothing is ever
+    dropped, and the sum is the T*k buffer's bit for bit: a token's picks
+    are summed in pick order whatever the buffer, and the picks behind it
+    add 0.0. What the absent experts would have added is left out; the
+    identity term needs no weights and is computed here whole."""
+    rows = topk_idx.size
+    slot, count = _held_slot(topk_idx.reshape(rows), cfg)
+    rungs = _row_buffer_rungs(rows, count, cfg.moe_router_width)
     operands = ((p["fc1_kernel"], p["fc2_kernel"]), x_flat, topk_probs,
-                order, token_of, group_sizes)
+                *_sorted_picks(slot, count))
     if len(rungs) == 1:
-        out, _ = _held_rows(*operands, cfg, t * k)
+        out, _ = _held_rows(*operands, cfg, rows)
     else:
         out = _laddered_rows(cfg, rungs)(*operands)
     if cfg.moe_zero_experts:

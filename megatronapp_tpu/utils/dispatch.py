@@ -12,7 +12,8 @@ overestimates; `kernels`, the pallas_calls a step, is exact, and is what
 chip_smoke.py checks of a served decode step. ``stack_slices`` counts the
 equations that cut one layer's array out of a stack: for a custom call
 (a grouped GEMM, a Pallas kernel) such a slice is a copy of the layer.
-Nothing is compiled or executed.
+``scatters`` counts the scatter equations (a TPU writes their rows one
+after another). Nothing is compiled or executed.
 """
 
 from __future__ import annotations
@@ -125,6 +126,16 @@ def stack_slices(jaxpr, shapes: Iterable[tuple]) -> int:
     return n
 
 
+def scatters(jaxpr) -> int:
+    """Equations of `jaxpr` (its inner jaxprs included) that scatter:
+    ``scatter``, ``scatter-add`` and kin, what ``x.at[i].set`` / ``.add``
+    and the transpose of a gather trace to. Equations are counted, not
+    executions."""
+    return sum(eqn.primitive.name.startswith("scatter")
+               + sum(scatters(j) for j in _inner_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
 def page_copies(jaxpr) -> Dict[str, Dict]:
     """What a step of each paged walk in `jaxpr` (its inner jaxprs
     included) copies, by kernel name, as `kernel_gen._walk_call` wrote it
@@ -150,7 +161,8 @@ def launch_stats(fn, *args, slice_shapes: Iterable[tuple] = ()
                  ) -> Dict[str, float]:
     """jaxpr_launch_stats of `fn` traced at the given (abstract or
     concrete) arguments; under `expert_stack_slices` its stack_slices
-    of `slice_shapes` (one layer's expert kernels); and `page_copies`'
+    of `slice_shapes` (one layer's expert kernels); under `scatters` its
+    scatter equations; and `page_copies`'
     three counts, each a dict by paged kernel name. `fn` may be jitted
     (the pjit wrapper is recursed through) — nothing is compiled or
     executed."""
@@ -159,6 +171,7 @@ def launch_stats(fn, *args, slice_shapes: Iterable[tuple] = ()
     stats = jaxpr_launch_stats(closed.jaxpr)
     stats["dispatches_per_step"] = stats["launches"] + stats["loop_steps"]
     stats["expert_stack_slices"] = stack_slices(closed.jaxpr, slice_shapes)
+    stats["scatters"] = scatters(closed.jaxpr)
     walks = page_copies(closed.jaxpr)
     for key in ("page_copies_step", "page_copy_bytes", "page_copies_kernel"):
         stats[key] = {name: walk[key] for name, walk in walks.items()}

@@ -248,6 +248,7 @@ class TestEngine:
                                      prefill_chunk=8)
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         assert disp["expert_stack_slices"] == slices, disp
+        assert disp["scatters"] == 0, disp      # rows move by gathers alone
         moe_layers = cfg.num_layers - cfg.moe_first_k_dense
         assert disp["kernels"] == cfg.num_layers + (   # the latent kernel
             0 if experts == "int8" else 2 * moe_layers)

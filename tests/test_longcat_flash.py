@@ -442,7 +442,11 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
     slice of an expert stack. Since ISSUE 43 the two MoE layers' grouped
     GEMMs are Pallas calls (3 + 4 kernels) whose visit lists are made in the
     step: 63 launches a call where ``lax.ragged_dot``'s zero-padded sizes
-    were 4 (1,603; the two calls of a layer share one list once compiled)."""
+    were 4 (1,603; the two calls of a layer share one list once compiled).
+    Since ISSUE 60 the two MoE layers move their rows by gathers alone: 18
+    equations a layer more (the six slabs of a token's picks written out),
+    less the dispatch gather's bounds check, 3 a layer (1,633), and
+    `scatters` 0."""
     model = manifest.load_module("models", "deepseek_v2")
     with open(os.path.join(ROOT, "perfbench", "configs",
                            "deepseek-v2-lite.json")) as f:
@@ -452,8 +456,8 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
         model.init_params(cfg, seed=5), cfg, max_batch=2, max_seq_len=64,
         paged=True, num_blocks=16, block_size=4, prefill_chunk=8)
     assert eng.stats_snapshot(include_dispatch=True)["decode_dispatch"] == {
-        "launches": 1603, "kernels": 7, "loop_steps": 15, "eqns": 1,
-        "dispatches_per_step": 1618, "expert_stack_slices": 0,
+        "launches": 1633, "kernels": 7, "loop_steps": 15, "eqns": 1,
+        "dispatches_per_step": 1648, "expert_stack_slices": 0, "scatters": 0,
         # ISSUE 46: a step of the latent walk, 16 blocks of 4 rows of
         # 32 + 8 float32 columns, none of them whole tiles
         "page_copies_step": {"paged_decode_latent": 32},
@@ -471,15 +475,20 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
     assert cfg.kv_planes == 4
     assert disp["kernels"] == cfg.kv_planes + 2 * cfg.num_layers
     assert disp["expert_stack_slices"] == 0
+    assert disp["scatters"] == 0            # a share held, zero-compute picks
 
 
-# sha256 of the two paged steps' lowered text at the parent commit (7ea5e97),
-# by _agent_step_texts run there.
+# sha256 of the two paged steps' lowered text, by _agent_step_texts; pinned
+# at the parent commit (7ea5e97) until ISSUE 60, and re-pinned there on the
+# change's own tree: that issue's change IS to these steps' MoE part (the
+# scatter-add of the pick rows and `bincount` went, a second sort and a
+# gather of the picks' rows came), with the ladder still out of a serving
+# step's way, which is what the test below holds.
 PARENT_STEP_SHA = {
     ("float32", "prefill"):
-        "8a6e6545d9235e4b", ("float32", "decode"): "b0321b94085b5559",
+        "78016fc5624b6ef8", ("float32", "decode"): "b733621241e60a8c",
     ("bfloat16", "prefill"):
-        "47cdadf3c07404fc", ("bfloat16", "decode"): "5a7db2b80e9f4ad1",
+        "07c98fdfe223e567", ("bfloat16", "decode"): "8569f13b6e47714f",
 }
 
 

@@ -760,6 +760,7 @@ class TestServerEndpoints:
         disp = first["decode_dispatch"]
         assert disp["kernels"] > 0
         assert disp["expert_stack_slices"] == 0     # a dense model
+        assert disp["scatters"] == 0        # its pages are appended by a kernel
         assert "compiled" not in disp
         assert second["decode_dispatch"] is disp
 

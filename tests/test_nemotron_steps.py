@@ -41,20 +41,23 @@ PARENT_STEP_SHA = {
     # alone: the same `while` nest and carry, no slice or copy of a stack
     # (CHANGES.md, PR 57, has the diff's count a step).
     "jamba2-3b": ("0f87c8757f623313", "241f1c39e8748487"),
-    "granite-4.0-h-small": ("31ae6f773308eca7", "c759b474ffea6fb7"),
-    "lfm2-24b-a2b": ("f0fe5e3c2856f8f5", "4a54bb0234d14142"),
-    "laguna-xs.2": ("488dcdaa4a8d108b", "e16d9e4d4fad5566"),
-    # pinned at e44ba59, the commit before ISSUE 54
-    "longcat-flash-chat": ("80a3e0ed3b2db33a", "f23b233fcf2ddf07"),
     # pinned at 85aae3c, the commit before ISSUE 56 (heads in a page's rows
-    # at 2 or 4 key/value heads of 128): the dense and the MoE cell's steps
+    # at 2 or 4 key/value heads of 128): the dense cell's steps
     "gpt3-2.7b": ("39a6c57cfa2ab242", "4ec78d2d59251593"),
-    "deepseek-v2-lite": ("0843401105404845", "6027d2a3ee99bd3c"),
-    # the parent's (67a371b) text, one of the two it had: its loop walked a
-    # Python set of letters, whose order changes with the process's hash seed
-    # and with it the order of a turn's row arithmetic. The plan is walked in
-    # layer order.
-    "nemotron-3-nano-30b-a3b": ("0177b96e930f522a", "d29989c6e5bbf080"),
+    # The two above hold no expert and did NOT move at ISSUE 60, which is the
+    # proof that no dense stack's program changed. The six below were
+    # re-pinned at ISSUE 60, whose change they are: in each MoE half the
+    # float32 scatter-add of the T*k pick rows and `bincount`'s scatter-add
+    # went, a second sort (the permutation's inverse), one gather of the
+    # picks' rows pick-major and the sum of its k slabs came
+    # (transformer/moe.py `_sorted_picks`, `_combine_rows`); moe.py is the
+    # one module on a step's path that differs from the parent's (9eb0e4a).
+    "granite-4.0-h-small": ("60956102c4ce7b52", "17779eb4c9b0e56f"),
+    "lfm2-24b-a2b": ("67d1244aa8623917", "39a110f7dd74b50f"),
+    "laguna-xs.2": ("e612a9a798aebe1a", "456f97f9e93b1114"),
+    "longcat-flash-chat": ("cbb87eac5da2f8bb", "6070c8ea6dc495f9"),
+    "deepseek-v2-lite": ("acce5b4c8ed14005", "177cc5704ebdf995"),
+    "nemotron-3-nano-30b-a3b": ("bd8e851880eb6af1", "b63c6c78dc51fe2a"),
 }
 
 
@@ -131,10 +134,14 @@ TRAINED = {
 # id); the same scan over the period, the same bodies written out. The
 # pattern stack's is this PR's own: its "ME" x 2 is an outermost unit of
 # several layers, which ISSUE 57's one rule keeps a scan where the parent
-# wrote every repeat of a pattern out (no cell trains one).
+# wrote every repeat of a pattern out (no cell trains one). Both re-pinned
+# at ISSUE 60 on the change's own tree: every layer of both holds experts,
+# whose rows that issue moves by gathers alone in both passes (the combine's
+# scatter-add, the dispatch's transposed gather, `bincount` and the router's
+# top-k transpose went: tests/test_moe_rows.py counts the scatters left, 0).
 PARENT_TRAIN_SHA = {
-    "mellum2-12b-a2.5b": "fba39901a614aedb",
-    "nemotron-3-nano-30b-a3b": "0fbd2c3ff870a300",
+    "mellum2-12b-a2.5b": "0708cb8dc947a45c",
+    "nemotron-3-nano-30b-a3b": "fb16edde9ed75d2f",
 }
 
 
