@@ -26,6 +26,14 @@ jax.config.update("jax_platforms", "cpu")
 # <checkout>/.jax_cache (utils/platform.py); in-process tests compile
 # everything themselves, as they always have.
 jax.config.update("jax_enable_compilation_cache", False)
+# What the tests hold is the program's arithmetic, not how well XLA:CPU
+# optimises it: their models are tiny and their time is compile time (a
+# paged engine's two steps: 10-20 s), so this process compiles at XLA's
+# level 0 without LLVM's expensive passes, as JAX's own tests do. Child
+# processes (the entry points, the cells' rehearsals) compile as they always
+# do, and tests/test_chip_compile.py, whose subject is the compiler, turns
+# it back on.
+jax.config.update("jax_disable_most_optimizations", True)
 
 import pytest  # noqa: E402
 
@@ -44,11 +52,42 @@ def devices8():
     return devs[:8]
 
 
+def _as_built(eng):
+    assert not eng.has_work and eng._round is None
+    assert eng.pool.blocks_in_use() == 0
+
+
+@pytest.fixture
+def lend():
+    """lend(engine) -> the engine, for a case that reads or runs an engine
+    its class (or module) built once. A DynamicInferenceEngine compiles its
+    steps anew for every instance, so a class builds one and its cases
+    share it; it is handed on only as it was built (no request waiting,
+    parked or in a slot, no round in flight, no block in use), checked here
+    before the case and after it, so that a case that leaks state fails
+    itself and not its neighbour. Counters run on: a case reads the
+    difference over its own run."""
+    lent = []
+
+    def lend(eng):
+        _as_built(eng)
+        lent.append(eng)
+        return eng
+    yield lend
+    for eng in lent:
+        try:
+            _as_built(eng)
+        except AssertionError:
+            eng.abort_all()     # the neighbour's engine, whatever this case did
+            raise
+
+
 def pytest_collection_modifyitems(config, items):
     """Apply the 'slow' marker from tests/slow_manifest.txt (measured
-    >6s tests; reference pytest.ini's internal/flaky gating). The fast
-    iteration lane is `pytest -m "not slow"` (~7 min); the full suite
-    remains the default so `pytest tests/` still covers everything."""
+    >10s tests; reference pytest.ini's internal/flaky gating). The fast
+    iteration lane is `pytest -m "not slow"` (tier 1: 2,024 cases, about
+    14 min on six workers, PR 62); the full suite remains the default so
+    `pytest tests/` still covers everything."""
     manifest = os.path.join(os.path.dirname(__file__), "slow_manifest.txt")
     try:
         with open(manifest) as f:
@@ -73,3 +112,24 @@ def pytest_collection_modifyitems(config, items):
             f"tests/slow_manifest.txt has {len(stale)} entries matching "
             f"no collected test (e.g. {sorted(stale)[0]}); regenerate "
             "with tools/update_slow_manifest.py", stacklevel=1)
+
+
+def pytest_terminal_summary(terminalreporter):
+    """One line on where the run's time went: the sum of the cases' time
+    (set-up, call and teardown, as the junit file counts a case) and the
+    five dearest files. A PR quotes it from its own log, so the run's
+    length is seen before the driver's limit cuts it."""
+    by_file = {}
+    for reports in terminalreporter.stats.values():
+        for rep in reports:
+            if hasattr(rep, "when"):        # a case's phase, not a warning
+                name = rep.nodeid.split("::")[0]
+                by_file[name] = by_file.get(name, 0.0) + rep.duration
+    if not by_file:
+        return
+    dearest = sorted(by_file.items(), key=lambda kv: -kv[1])[:5]
+    terminalreporter.write_line(
+        "case time: %.0f s in %d files; dearest: %s" % (
+            sum(by_file.values()), len(by_file),
+            ", ".join("%s %.0f" % (os.path.basename(f), t)
+                      for f, t in dearest)))

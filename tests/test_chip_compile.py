@@ -55,7 +55,12 @@ def chip_compile(monkeypatch):
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # the chip's compiler as the chip runs it (tests/conftest.py compiles
+    # the CPU's programs without most optimisations)
+    fast = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
     yield
+    jax.config.update("jax_disable_most_optimizations", fast)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 

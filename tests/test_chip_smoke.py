@@ -483,55 +483,99 @@ def test_forced_to_the_cpu_it_refuses(tmp_path):
     assert not any(ln.startswith("[train-auto] iter") for ln in lines)
 
 
-def test_tiny_rehearsal_runs_both_phases_and_still_refuses(tmp_path):
-    rc, last, phases, lines = _smoke(["--tiny"], tmp_path)
-    assert [p["phase"] for p in phases] == ["train-auto", "train-pallas",
-                                            "server", "hybrid", "eva",
-                                            "share", "conv", "window", "ssd"]
-    for p in phases:        # every phase's own checks passed ...
-        assert p["ok"], (p["phase"], p["problems"])
-    train = phases[1]
+@pytest.fixture(scope="module")
+def rehearsal(tmp_path_factory):
+    """`chip_smoke.py --tiny`, once: nine children one after another, the
+    dearest thing tier 1 runs. Each phase's record is read by a case of its
+    own below, the run's contract by another."""
+    rc, last, phases, lines = _smoke(["--tiny"],
+                                     tmp_path_factory.mktemp("rehearsal"))
+    return rc, last, {p["phase"]: p for p in phases}, lines
+
+
+def _rehearsed_train_auto(auto, phases):
+    assert abs(auto["losses"][0] - phases["train-pallas"]["losses"][0]) < 1e-2
+
+
+def _rehearsed_train_pallas(train, phases):
     assert train["steps"] == 20 and train["compile_s"] > 0
     assert any("pallas flash kernel" in a and "(interpreted)" in a
                for a in train["attention"])
-    assert abs(phases[0]["losses"][0] - train["losses"][0]) < 1e-2
-    server = phases[2]
+
+
+def _rehearsed_server(server, phases):
     assert server["driver_max_active"] >= 2
     assert server["max_blocks_in_use_seen"] > 0
     assert server["blocks_in_use_after"] == 0
     # 12 layers, each one paged_decode and a paged_append for K and for V
     assert server["decode_pallas_calls_per_step"] == 36
-    # ... and the run is refused all the same: this is not a TPU.
-    assert rc != 0
-    assert last == {"ok": False, "device": {"platform": "cpu",
-                                            "kind": "cpu", "count": 1}}
-    hybrid = phases[3]
+
+
+def _rehearsed_hybrid(hybrid, phases):
     assert hybrid["state"]["resets"] == 3 and hybrid["state"]["layers"] == 6
     assert hybrid["alias_bytes"] >= hybrid["pool_bytes"] > 0
-    eva = phases[4]
+
+
+def _rehearsed_eva(eva, phases):
     assert eva["eva"]["windows_closed"] == 3 and eva["table_blocks"] == 20
     assert eva["alias_bytes"] >= eva["pool_bytes"] > 0
-    share = phases[5]
+
+
+def _rehearsed_share(share, phases):
     assert share["moe"]["experts_here"] == 4
     assert share["pool_shapes"][0][0] == 4      # two planes a layer
     assert share["alias_bytes"] >= share["pool_bytes"] > 0
-    conv = phases[6]
+
+
+def _rehearsed_conv(conv, phases):
     assert conv["state"]["kind"] == "conv" and conv["state"]["resets"] == 3
     assert conv["moe"]["assignments"] == conv["moe"]["tokens"] * 2 * 4
     assert conv["expert_stack_slices"] == 0
     assert conv["alias_bytes"] >= conv["pool_bytes"] > 0
-    window = phases[7]
+
+
+def _rehearsed_window(window, phases):
     assert window["window"]["blocks_taken"] == window["window"][
         "blocks_given_back"] > 0
     assert window["pool_shapes"][2][:2] == [3, 37]
     assert window["moe"]["assignments"] == window["moe"]["tokens"] * 2 * 4
     assert window["alias_bytes"] >= window["pool_bytes"] > 0
     assert 5.5 < window["train_loss"] < 7.5 and window["train_grads_finite"]
-    ssd = phases[8]
+
+
+def _rehearsed_ssd(ssd, phases):
     assert ssd["state"]["mixer"] == "mamba2" and ssd["e_tiles"] == 2
     assert ssd["moe"]["assignments_here"] + ssd["moe"][
         "assignments_absent"] == ssd["moe"]["tokens"] * 3 * 8
     assert ssd["alias_bytes"] >= ssd["pool_bytes"] > 0
+
+
+# The rehearsal's phases in the order they run, each with what its record
+# has to say: a model's new phase is one more entry.
+REHEARSED = {
+    "train-auto": _rehearsed_train_auto,
+    "train-pallas": _rehearsed_train_pallas, "server": _rehearsed_server,
+    "hybrid": _rehearsed_hybrid, "eva": _rehearsed_eva,
+    "share": _rehearsed_share, "conv": _rehearsed_conv,
+    "window": _rehearsed_window, "ssd": _rehearsed_ssd}
+
+
+@pytest.mark.parametrize("name", REHEARSED)
+def test_tiny_rehearsal_runs_both_phases_and_still_refuses(rehearsal, name):
+    _, _, phases, _ = rehearsal
+    phase = phases[name]
+    assert phase["ok"], (name, phase["problems"])   # its own checks passed
+    REHEARSED[name](phase, phases)
+
+
+def test_tiny_rehearsal_runs_every_phase_and_is_refused_all_the_same(
+        rehearsal):
+    rc, last, phases, lines = rehearsal
+    assert list(phases) == list(REHEARSED)
+    # every phase passed, and the run is refused all the same: not a TPU
+    assert rc != 0
+    assert last == {"ok": False, "device": {"platform": "cpu",
+                                            "kind": "cpu", "count": 1}}
     assert sum("not a TPU" in ln for ln in lines) == 9
 
 

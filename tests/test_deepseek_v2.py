@@ -4,6 +4,7 @@ unabsorbed, every token through its experts), at tiny widths on the CPU with
 seeded random weights: 1 dense + 2 MoE layers, 8 experts top-3 with 1 shared,
 kv_lora 32, YaRN on. Each test fails if the mechanism it names is left out."""
 import dataclasses
+import functools
 import json
 import os
 
@@ -16,10 +17,11 @@ import jax.numpy as jnp
 from megatronapp_tpu.inference.dynamic_engine import (
     DynamicInferenceEngine, _paged_decode_step, _paged_multiquery_step,
 )
-from megatronapp_tpu.models.gpt import gpt_forward
 from megatronapp_tpu.models.presets import PRESETS
 from megatronapp_tpu.transformer.moe import routing_counts
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -43,9 +45,11 @@ TOL_F32 = 1e-4
 TOL_BF16 = 1.5e-2
 
 
-def _model(compute_dtype=jnp.float32, **kw):
-    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype,
-                             **kw)
+@functools.cache
+def _model(compute_dtype=jnp.float32):
+    """(cfg, params), built once for every case (none writes into the
+    tree it is handed)."""
+    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype)
     return cfg, MODEL.init_params(cfg, seed=5)
 
 
@@ -56,27 +60,33 @@ def _reference(params, tokens):
         params, TINY, tokens, jnp.zeros_like(tokens), pos))
 
 
+@pytest.fixture(scope="module")
+def reference_2x40():
+    """The reference's logits of TestForward's two rows of 40 tokens."""
+    return _reference(_model()[1], _tokens((2, 40)))
+
+
 def _tokens(shape, seed=0):
     return np.random.default_rng(seed).integers(
         0, TINY["vocab_size"], shape).astype(np.int32)
 
 
 class TestForward:
-    def test_gpt_forward_matches_reference(self):
+    def test_gpt_forward_matches_reference(self, reference_2x40):
         cfg, params = _model()
         assert cfg.moe_first_k_dense == 1 and cfg.num_layers == 3
         assert "mlp" in params["lead_block"] and "moe" in params["block"]
         toks = _tokens((2, 40))
         logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        assert np.abs(np.asarray(logits) - _reference(params, toks)).max() \
-            < TOL_F32
+        assert np.abs(np.asarray(logits) - reference_2x40).max() < TOL_F32
 
     @pytest.mark.parametrize("field,value", [
         ("moe_router_norm_topk_prob", True),
         ("moe_routed_scaling_factor", 2.0),
         ("yarn_mscale_coeff", 0.1),
     ])
-    def test_each_architecture_field_is_live(self, field, value):
+    def test_each_architecture_field_is_live(self, field, value,
+                                             reference_2x40):
         """The reference is DeepSeek-V2-Lite's: a router that renormalises
         its top-k, another routed scale or YaRN's default coefficient is
         another model and must not pass."""
@@ -84,7 +94,7 @@ class TestForward:
         other = dataclasses.replace(cfg, **{field: value})
         toks = _tokens((2, 40))
         logits, _ = gpt_forward(params, jnp.asarray(toks), other)
-        assert np.abs(np.asarray(logits) - _reference(params, toks)).max() \
+        assert np.abs(np.asarray(logits) - reference_2x40).max() \
             > 10 * TOL_F32
 
     def test_leading_dense_layer_is_not_skipped(self):
@@ -97,19 +107,32 @@ class TestForward:
             > 10 * TOL_F32
 
 
-def _prefill_then_decode(cfg, params, prompt, n_new, chunk=8, bs=4):
+MAX_LEN = 64
+
+
+def _paged_steps(compute_dtype):
+    """The engine's two step functions of TINY in a compute type, jitted."""
+    cfg, _ = _model(compute_dtype)
+    return (jax.jit(lambda *a: _paged_multiquery_step(*a, cfg, MAX_LEN)),
+            jax.jit(lambda *a: _paged_decode_step(*a, cfg, MAX_LEN)))
+
+
+_shared_steps = functools.cache(_paged_steps)   # compiled once a shape
+
+
+def _prefill_then_decode(cfg, params, prompt, n_new, chunk=8, bs=4,
+                         steps=_shared_steps):
     """The engine's two step functions on a hand-made page table: the
     prompt in [1, chunk] calls (the last one ragged), then n_new greedy
     decode steps. Returns (tokens fed, logits at every position, pools)."""
-    max_len = 64
+    max_len = MAX_LEN
     nb = max_len // bs
     dt = cfg.compute_dtype
     pages = (jnp.zeros((cfg.num_layers, nb, bs, cfg.kv_lora_rank), dt),
              jnp.zeros((cfg.num_layers, nb, bs, cfg.qk_pos_emb_head_dim), dt))
     table = jnp.arange(nb, dtype=jnp.int32)[None]
     active = jnp.ones((1,), bool)
-    prefill = jax.jit(lambda *a: _paged_multiquery_step(*a, cfg, max_len))
-    decode = jax.jit(lambda *a: _paged_decode_step(*a, cfg, max_len))
+    prefill, decode = steps(dt)
     rows, pos = [], 0
     while pos < len(prompt):
         count = min(chunk, len(prompt) - pos)
@@ -279,18 +302,20 @@ class TestEngine:
             chunk = choose_prefill_width(cfg, params, 16, 4,
                                          device_kind="TPU v5 lite")
             assert chunk == 16
+        steps = _shared_steps
         if chunk == 32:
             monkeypatch.setattr(kernel_gen, "_query_vmem_budget",
                                 lambda *a: 100_000)
+            steps = _paged_steps    # its own: traced under that budget
         prompt = _tokens((n,), seed)
         seq, _, _, _ = _prefill_then_decode(cfg, params, prompt, 6,
-                                            chunk=chunk)
-        toks = np.asarray(prompt)[None]
-        for _ in range(6):
-            logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-            toks = np.concatenate(
-                [toks, [[int(jnp.argmax(logits[0, -1]))]]], axis=1)
-        assert seq.tolist() == toks[0].tolist()
+                                            chunk=chunk, steps=steps)
+        assert seq[:n].tolist() == prompt.tolist() and len(seq) == n + 6
+        # the oracle's greedy stream is seq: causal, so its argmax after
+        # every prefix of seq is read off one pass over seq
+        logits, _ = gpt_forward(params, jnp.asarray(seq[None, :-1]), cfg)
+        assert seq[n:].tolist() == np.argmax(
+            np.asarray(logits[0, n - 1:]), -1).tolist()
 
     def test_dense_model_step_is_unchanged(self):
         """A dense model's decode step returns no counts, so its sampler

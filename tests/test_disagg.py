@@ -33,7 +33,7 @@ from megatronapp_tpu.inference.dynamic_engine import (
     DeadlineExceeded, DynamicInferenceEngine,
 )
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 from megatronapp_tpu.parallel.mesh import build_mesh
 
 
@@ -52,13 +52,7 @@ def gqa_params():
     return cfg, params
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = np.asarray(prompt)[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 def _tp2_ctx():

@@ -8,6 +8,7 @@ sizes appear only in shape tests. What such a model refuses and counts is in
 test_evabyte_refusals.py (which takes its tiny model from here), the server
 tool in test_evabyte_server.py: three files, so that `--dist loadfile` does
 not charge them all to one worker."""
+import functools
 import json
 import os
 
@@ -19,10 +20,11 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward
 from megatronapp_tpu.models.presets import PRESETS
 from megatronapp_tpu.transformer import eva
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = manifest.load_module("models", "evabyte")
@@ -44,9 +46,13 @@ TOL = 2e-4
 GREEDY = SamplingParams(greedy=True)
 
 
-def _model(seed=5, **kw):
+@functools.cache
+def _model(seed=5):
+    """(cfg, params), built once for every case here, in
+    tests/test_evabyte_refusals.py and in tests/test_paged_projection.py:
+    none writes into the tree it is handed."""
     cfg = MODEL.model_config(TINY, "float32", compute_dtype=jnp.float32,
-                             init_method_std=STD, **kw)
+                             init_method_std=STD)
     params = MODEL.init_params(cfg, seed=seed)
     # norm offsets away from 0, so that 1 + g is not 1
     key = jax.random.PRNGKey(seed + 1)

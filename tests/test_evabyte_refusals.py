@@ -18,10 +18,20 @@ from test_evabyte import C, GREEDY, W, _engine, _model, _tokens
 
 # ---- (g) what such a model refuses ------------------------------------------
 
+@pytest.fixture(scope="module")
+def shared_engine():
+    """One engine for the cases that ask it what it refuses or run a
+    request through it and leave it idle (conftest.py `lend`)."""
+    return _engine(*_model())
+
+
+@pytest.fixture
+def eng(shared_engine, lend):
+    return lend(shared_engine)
+
+
 class TestRefusals:
-    def test_prefix_reuse_is_off_and_said(self):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_prefix_reuse_is_off_and_said(self, eng):
         assert eng.pool.enable_prefix_caching is False
         line = eng.startup_line()
         for word in ("window 32", "every 4", "prefix reuse (off)",
@@ -50,9 +60,7 @@ class TestRefusals:
 
     @pytest.mark.parametrize("call", ["adopt_request", "export_request",
                                       "import_request"])
-    def test_moving_a_request_refuses(self, call):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_moving_a_request_refuses(self, call, eng):
         args = {"adopt_request": (None, 0, 0), "export_request": (0,),
                 "import_request": ({},)}[call]
         with pytest.raises(ValueError, match="chunk summaries"):
@@ -62,9 +70,7 @@ class TestRefusals:
         ("rewind", (0, 1)), ("export_slot", (0, 1)),
         ("import_slot", (0, {"kv_cache_dtype": "bf16"})),
         ("transfer_slot", (0, 1))])
-    def test_the_pool_refuses(self, call, args):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_the_pool_refuses(self, call, args, eng):
         with pytest.raises(ValueError, match="chunk summaries"):
             getattr(eng.pool, call)(*args)
 

@@ -68,8 +68,8 @@ def test_the_window_kernels_match_the_band_mask(s, window, tile, packed):
                              segment_ids=segs, window=window)
     np.testing.assert_allclose(out, _dense(q, k, v, window, segs),
                                atol=2e-5, rtol=2e-5)
-    for got, want in zip(jax.grad(mine, (0, 1, 2))(q, k, v),
-                         jax.grad(oracle, (0, 1, 2))(q, k, v)):
+    for got, want in zip(jax.jit(jax.grad(mine, (0, 1, 2)))(q, k, v),
+                         jax.jit(jax.grad(oracle, (0, 1, 2)))(q, k, v)):
         np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
 
 
@@ -89,9 +89,9 @@ def test_unequal_tiles_and_ungrouped_heads():
             return jnp.sum(w * fa.flash_attention(
                 q, k, v, block_q=bq, block_kv=bkv, segment_ids=segs,
                 window=40))
-        got = jax.grad(mine, (0, 1, 2))(q, k, v)
-        want = jax.grad(lambda q, k, v: jnp.sum(
-            w * _dense(q, k, v, 40, segs)), (0, 1, 2))(q, k, v)
+        got = jax.jit(jax.grad(mine, (0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            w * _dense(q, k, v, 40, segs)), (0, 1, 2)))(q, k, v)
         for g, x in zip(got, want):
             np.testing.assert_allclose(g, x, atol=5e-5, rtol=5e-5)
 

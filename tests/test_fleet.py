@@ -35,7 +35,7 @@ from megatronapp_tpu.inference.fleet import (
 from megatronapp_tpu.inference.paged_cache import (
     KV_CACHE_DTYPES, PagedKVCache, prefix_block_keys,
 )
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 
 ALL_DTYPES = sorted(KV_CACHE_DTYPES)
 
@@ -72,13 +72,7 @@ def mla_params():
     return cfg, params
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = np.asarray(prompt)[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 def _engine(params, cfg, dt="bf16", max_batch=2, num_blocks=None):

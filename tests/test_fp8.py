@@ -69,13 +69,7 @@ def _gqa_cfg(**kw):
     return TransformerConfig(**d)
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = prompt[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 # ---------------------------------------------------------------------------

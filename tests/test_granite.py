@@ -7,6 +7,7 @@ term), Mamba-2 mixers of 4 heads x 32 columns with a [32, 16] state a head
 in chunks of 16 positions, 4 held of 8 experts top-3 beside a shared expert.
 Each test fails if the mechanism it names is left out."""
 import dataclasses
+import functools
 import json
 import os
 
@@ -18,9 +19,10 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward
 from megatronapp_tpu.transformer import ssm
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = manifest.load_module("models", "granite_moe_hybrid")
@@ -47,9 +49,20 @@ TOL_BF16 = 1.5e-4
 GREEDY = SamplingParams(greedy=True)
 
 
-def _model(compute_dtype=jnp.float32, tiny=TINY, **kw):
+def _model(compute_dtype=jnp.float32, tiny=TINY):
+    if tiny is TINY:
+        return _tiny_model(compute_dtype)
     cfg = MODEL.model_config(tiny, "float32", compute_dtype=compute_dtype,
-                             init_method_std=STD, **kw)
+                             init_method_std=STD)
+    return cfg, MODEL.init_params(cfg, seed=5)
+
+
+@functools.cache
+def _tiny_model(compute_dtype):
+    """(cfg, params) of TINY in a compute type, built once for every case
+    (and for tests/test_granite_engine.py's)."""
+    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype,
+                             init_method_std=STD)
     return cfg, MODEL.init_params(cfg, seed=5)
 
 
@@ -57,6 +70,13 @@ def _reference(params, tokens, tiny=TINY, **control):
     tokens = jnp.asarray(tokens)
     return np.asarray(MODEL.reference_logits(
         params, tiny, tokens, jnp.zeros_like(tokens), None, **control))
+
+
+@pytest.fixture(scope="module")
+def reference40():
+    """The reference's logits of the two 40-token rows TestForward reads:
+    a position at a time, so worked out once."""
+    return _reference(_model()[1], np.stack([_tokens(40, 1), _tokens(40, 2)]))
 
 
 def _tokens(n, seed=0):
@@ -70,9 +90,23 @@ def _engine(cfg, params, **kw):
     return DynamicInferenceEngine(params, cfg, **kw)
 
 
-def _recorded(eng):
-    """Wrap the engine's two steps: logits[rid] collects, position by
-    position, the logits every call computed for that request."""
+@functools.cache
+def _shared_engine(dtype=jnp.float32, width=8):
+    """The engine of `_model(dtype)` whose prefill calls are `width` wide:
+    one a program, compiled once, for the cases (here and in
+    tests/test_granite_engine.py) that leave it as they found it. They take
+    it through `lend` (conftest.py)."""
+    return _engine(*_model(dtype), prefill_chunk=width)
+
+
+@pytest.fixture
+def eng(lend):
+    return lend(_shared_engine())
+
+
+def _recorded(eng, monkeypatch):
+    """Wrap the engine's two steps for the case: logits[rid] collects,
+    position by position, the logits every call computed for that request."""
     logits = {}
     mq, dec = eng._mq_step, eng._decode
 
@@ -94,7 +128,8 @@ def _recorded(eng):
                 np.asarray(out[0][slot:slot + 1], np.float32))
         return out
 
-    eng._mq_step, eng._decode = mq_step, decode
+    monkeypatch.setattr(eng, "_mq_step", mq_step)
+    monkeypatch.setattr(eng, "_decode", decode)
     return logits
 
 
@@ -288,9 +323,7 @@ class TestKernel:
         assert _tile(128, 8192) == 2048         # Granite: 1 MiB a block
         assert _tile(128, 8192 + 64) == 8192 + 64   # no whole lane tiles
 
-    def test_decode_step_runs_one_kernel_a_layer_loop(self):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_decode_step_runs_one_kernel_a_layer_loop(self, eng):
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         # a Mamba-2 layer: ssm_update; the attention layer: paged_append x 2
         # + paged_decode; every layer: two grouped GEMMs
@@ -300,7 +333,7 @@ class TestKernel:
 # ---- the model ----------------------------------------------------------------
 
 class TestForward:
-    def test_gpt_forward_matches_reference(self):
+    def test_gpt_forward_matches_reference(self, reference40):
         cfg, params = _model()
         assert cfg.num_ssm_layers == 3 and cfg.num_attention_layers == 1
         assert cfg.moe_experts_held == (0, 4) and cfg.moe_router_width == 8
@@ -311,7 +344,7 @@ class TestForward:
         assert block["ffn"]["moe"]["router_kernel"].shape == (4, 64, 8)
         toks = np.stack([_tokens(40, 1), _tokens(40, 2)])
         logits = gpt_forward(params, jnp.asarray(toks), cfg)[0]
-        ref = _reference(params, toks)
+        ref = reference40
         assert ref.std() > 3e-4
         assert np.abs(np.asarray(logits) - ref).max() < TOL_F32
 
@@ -320,7 +353,8 @@ class TestForward:
         ("attention_multiplier", {"attention_multiplier": 0.25}),
         ("residual_multiplier", {"residual_multiplier": 1.0}),
         ("logits_scaling", {})])
-    def test_each_multiplier_is_told_from_one(self, field, control):
+    def test_each_multiplier_is_told_from_one(self, field, control,
+                                              reference40):
         """The program with one of the four scalars at its default (1, or
         1 / sqrt(head size) = 0.25) is not the model (the logits move by
         0.0009 for the one attention layer's scale, 0.08 to 7.6 for the
@@ -331,7 +365,7 @@ class TestForward:
             field: None if field == "attention_multiplier" else 1.0})
         toks = _tokens(40, 1)[None]
         logits = np.asarray(gpt_forward(params, jnp.asarray(toks), wrong)[0])
-        assert np.abs(logits - _reference(params, toks)).max() > 1e4 * TOL_F32
+        assert np.abs(logits - reference40[:1]).max() > 1e4 * TOL_F32
         if control:
             assert np.abs(logits - _reference(params, toks, **control)).max() \
                 < TOL_F32
@@ -372,8 +406,13 @@ class TestForward:
         def loss(p):
             return gpt_loss(p, toks[:, :-1], toks[:, 1:], None, cfg)[0]
 
-        first, grads = jax.value_and_grad(loss)(params)
-        lower = loss(jax.tree.map(lambda p, g: p - 0.05 * g, params, grads))
+        @jax.jit    # one program: eagerly, a compile a primitive (20 s)
+        def step(params):
+            first, grads = jax.value_and_grad(loss)(params)
+            return first, loss(jax.tree.map(lambda p, g: p - 0.05 * g,
+                                            params, grads))
+
+        first, lower = step(params)
         assert np.isfinite(float(first)) and float(lower) < float(first)
 
 

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from perfbench import manifest
 from test_granite import (
     GREEDY, MODEL, PUBLISHED, TINY, TOL_BF16, TOL_F32, _engine, _model,
-    _recorded, _tokens, _worst_gap,
+    _recorded, _shared_engine, _tokens, _worst_gap, eng,  # noqa: F401
 )
 
 
@@ -23,32 +23,35 @@ class TestEngine:
         (jnp.float32, TOL_F32, 8, 18), (jnp.bfloat16, TOL_BF16, 8, 18),
         (jnp.float32, TOL_F32, 32, 34)],
         ids=["float32-8", "bfloat16-8", "float32-32-two-chunks"])
-    def test_chunked_prefill_then_decode(self, dtype, tol, width, n):
+    def test_chunked_prefill_then_decode(self, dtype, tol, width, n, lend,
+                                         monkeypatch):
         """Prefill in calls of `width` (8: half a chunk of 16; 32: two
         chunks a call), 2 past a call's edge so that the
         convolution's tail and the state cross it, then 12 decode rounds
         through ssm_update: the LOGITS at every position are the
         reference's full forward pass's."""
-        cfg, params = _model(dtype)
-        eng = _engine(cfg, params, prefill_chunk=width)
-        logits = _recorded(eng)
+        _, params = _model(dtype)
+        eng = lend(_shared_engine(dtype, width))
+        logits = _recorded(eng, monkeypatch)
+        was = eng.stats_snapshot()["state"]
         req = eng.requests[eng.add_request(_tokens(n, 4), 13, GREEDY)]
         eng.run_to_completion()
         assert _worst_gap(params, req, logits) < tol
         state = eng.stats_snapshot()["state"]
-        assert state["resets"] == 1 and state["dropped"] == 0
-        assert state["prefill_scans"] == -(-n // width) * 3
+        assert {k: state[k] - was[k]
+                for k in ("resets", "dropped", "prefill_scans")} == {
+            "resets": 1, "dropped": 0, "prefill_scans": -(-n // width) * 3}
         assert (state["kind"], state["mixer"], state["heads"],
                 state["state_dim"], state["conv_channels"]) == (
             "ssm", "mamba2", 4, 16, 160)
 
-    def test_continuous_batching_and_slot_reuse(self):
+    def test_continuous_batching_and_slot_reuse(self, eng, monkeypatch):
         """Requests of different lengths admitted at different steps; the
         fourth runs in the slot the first left, whose state it must not
         see; a slot that idles while others decode keeps its state."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
-        logits = _recorded(eng)
+        _, params = _model()
+        logits = _recorded(eng, monkeypatch)
+        start = eng.stats_snapshot()
 
         def add(n, seed, new):
             return eng.requests[eng.add_request(_tokens(n, seed), new,
@@ -72,14 +75,14 @@ class TestEngine:
         for req in reqs:
             assert _worst_gap(params, req, logits) < TOL_F32
         stats = eng.stats_snapshot()
-        assert stats["state"]["resets"] == 4
+        assert stats["state"]["resets"] - start["state"]["resets"] == 4
         # rounds dispatched before the one before them was read: the state
         # pools ride the run-ahead loop like the pages
-        assert stats["steps"]["rounds_ahead"] > 0
+        assert stats["steps"]["rounds_ahead"] \
+            > start["steps"]["rounds_ahead"]
 
-    def test_pools_and_bytes(self):
-        cfg, params = _model(jnp.bfloat16)
-        eng = _engine(cfg, params)
+    def test_pools_and_bytes(self, lend):
+        eng = lend(_shared_engine(jnp.bfloat16))
         k, v = eng.pool.pages
         assert k.shape == v.shape == (1, 24, 4, 2, 16)
         state, conv = eng.pool.state
@@ -92,16 +95,18 @@ class TestEngine:
         assert MODEL.state_bytes_per_slot(PUBLISHED, "float32") == 38_204_928
         assert MODEL.kv_bytes_per_token(PUBLISHED, "bfloat16") == 4096
 
-    def test_the_counters_add_up_through_a_state_space_stack(self):
+    def test_the_counters_add_up_through_a_state_space_stack(self, eng):
         """assignments_here + assignments_absent = tokens x top-k x layers,
         both above 0, over the plain decode rounds of a stack whose mixers
         are Mamba-2."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+        was = eng.stats_snapshot()["moe"]
         for seed, n in ((30, 9), (31, 14), (32, 6)):
             eng.add_request(_tokens(n, seed), 10, GREEDY)
         eng.run_to_completion()
         moe = eng.stats_snapshot()["moe"]
+        # this run's: the counts (what the engine holds is no count)
+        moe = {k: v if k == "experts_here" else v - was[k]
+               for k, v in moe.items()}
         picks = moe["tokens"] * 3 * 4
         assert moe["assignments"] == picks > 0
         assert moe["assignments_here"] + moe["assignments_absent"] == picks
@@ -112,7 +117,7 @@ class TestEngine:
         assert cell.share_problems(dict(moe, assignments_absent=0), TINY)
         assert cell.share_problems(dict(moe, experts_here=8), TINY)
 
-    def test_the_prefill_call_says_its_chunks(self):
+    def test_the_prefill_call_says_its_chunks(self, lend, monkeypatch):
         from megatronapp_tpu.inference.dynamic_engine import (
             choose_prefill_width, prefill_call_costs,
         )
@@ -124,7 +129,7 @@ class TestEngine:
         assert choose_prefill_width(cfg, params, 64, 4,
                                     device_kind="TPU v5 lite") in (
             16, 32, 64)
-        eng = _engine(cfg, params, prefill_chunk=32)
+        eng = lend(_shared_engine(jnp.float32, 32))
         seen = []
         real = eng._span
 
@@ -133,7 +138,7 @@ class TestEngine:
                 seen.append(attrs)
             return real(name, *a, **attrs)
 
-        eng._span = span
+        monkeypatch.setattr(eng, "_span", span)
         eng.add_request(_tokens(40, 33), 2, GREEDY)
         eng.run_to_completion()
         assert [a["ssd_chunks"] for a in seen] == [2, 2]
@@ -155,11 +160,10 @@ class TestRefusals:
             _engine(cfg, params, **kw)
         assert word in str(e.value)
 
-    def test_prefix_reuse_is_off_and_said(self):
+    def test_prefix_reuse_is_off_and_said(self, eng):
         from megatronapp_tpu.inference.paged_cache import TENANT_LACKS
-        cfg, params = _model()
+        cfg, _ = _model()
         assert TENANT_LACKS["ssm"][0](cfg) and TENANT_LACKS["double"][0](cfg)
-        eng = _engine(cfg, params)
         assert eng.pool.enable_prefix_caching is False
         assert "prefix reuse off" in eng.startup_line()
 
@@ -187,19 +191,18 @@ class TestRefusals:
 
 
 class TestStateControl:
-    def test_the_bf16_recurrence_is_told_from_the_float32_one(self):
+    def test_the_bf16_recurrence_is_told_from_the_float32_one(self, eng):
         """The second reading the cell's limit on the state's precision is
         sized by; and the reference's state in the program's layout is what
         a slot holds."""
         cell = manifest.load_module("cells", "serve_closed_state")
-        cfg, params = _model()
+        _, params = _model()
         tokens = np.stack([_tokens(40, s) for s in (1, 2)])
         fine = {t: cell._fine_share(MODEL.reference_state(
             params, TINY, jnp.asarray(tokens), state_dtype=t), "bfloat16")
             for t in ("float32", "bfloat16")}
         assert fine["float32"] > 0.99 > cell.FINE_SHARE > fine["bfloat16"]
         assert fine["bfloat16"] == 0.0
-        eng = _engine(cfg, params)
         req = eng.requests[eng.add_request(tokens[0][:30], 11, GREEDY)]
         eng.run_to_completion()
         want = MODEL.reference_state(

@@ -5,6 +5,7 @@ with seeded random weights: 8 layers of which 1 and 5 attend (period 4,
 offset 1) with one key/value head, state 8, expand 2, the inner norms on.
 Each test fails if the mechanism it names is left out."""
 import dataclasses
+import functools
 import json
 import os
 
@@ -16,8 +17,9 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = manifest.load_module("models", "jamba")
@@ -44,9 +46,11 @@ TOL_BF16 = 0.2
 GREEDY = SamplingParams(greedy=True)
 
 
-def _model(compute_dtype=jnp.float32, **kw):
+@functools.cache
+def _model(compute_dtype=jnp.float32):
+    """(cfg, params) of a compute type, built once for every case."""
     cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype,
-                             init_method_std=STD, **kw)
+                             init_method_std=STD)
     return cfg, MODEL.init_params(cfg, seed=5)
 
 
@@ -67,9 +71,28 @@ def _engine(cfg, params, **kw):
     return DynamicInferenceEngine(params, cfg, **kw)
 
 
-def _recorded(eng):
-    """Wrap the engine's two steps: logits[rid] collects, position by
-    position, the logits every call computed for that request."""
+@functools.cache
+def _shared_engine(dtype=jnp.float32, width=8):
+    """The engine of `_model(dtype)` whose prefill calls are `width` wide:
+    one a program, compiled once, for the cases that leave it as they
+    found it. They take it through `lend` (conftest.py)."""
+    return _engine(*_model(dtype), prefill_chunk=width)
+
+
+@pytest.fixture
+def eng(lend):
+    return lend(_shared_engine())
+
+
+@pytest.fixture(scope="module")
+def reference40():
+    """The reference's logits of the two 40-token rows TestForward reads."""
+    return _reference(_model()[1], np.stack([_tokens(40, 1), _tokens(40, 2)]))
+
+
+def _recorded(eng, monkeypatch):
+    """Wrap the engine's two steps for the case: logits[rid] collects,
+    position by position, the logits every call computed for that request."""
     logits = {}
     mq, dec = eng._mq_step, eng._decode
 
@@ -91,7 +114,8 @@ def _recorded(eng):
                 np.asarray(out[0][slot:slot + 1], np.float32))
         return out
 
-    eng._mq_step, eng._decode = mq_step, decode
+    monkeypatch.setattr(eng, "_mq_step", mq_step)
+    monkeypatch.setattr(eng, "_decode", decode)
     return logits
 
 
@@ -105,7 +129,7 @@ def _worst_gap(params, req, logits):
 
 
 class TestForward:
-    def test_gpt_forward_matches_reference(self):
+    def test_gpt_forward_matches_reference(self, reference40):
         cfg, params = _model()
         assert cfg.num_ssm_layers == 6 and cfg.num_attention_layers == 2
         block = params["block"]
@@ -114,10 +138,9 @@ class TestForward:
         assert block["ffn"]["mlp"]["fc1_kernel"].shape[0] == 8
         toks = np.stack([_tokens(40, 1), _tokens(40, 2)])
         logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        assert np.abs(np.asarray(logits) - _reference(params, toks)).max() \
-            < TOL_F32
+        assert np.abs(np.asarray(logits) - reference40).max() < TOL_F32
 
-    def test_inner_norms_are_live(self):
+    def test_inner_norms_are_live(self, reference40):
         cfg, params = _model()
         other = dataclasses.replace(cfg, ssm_inner_norms=False)
         ssm = params["block"]["mixers_ssm"]["ssm"]
@@ -127,7 +150,7 @@ class TestForward:
                                              ssm=bare)))
         toks = _tokens(40, 1)[None]
         logits, _ = gpt_forward(without, jnp.asarray(toks), other)
-        assert np.abs(np.asarray(logits) - _reference(params, toks)).max() \
+        assert np.abs(np.asarray(logits) - reference40[:1]).max() \
             > 10 * TOL_F32
 
     @pytest.mark.parametrize("layers,period,offset", [(7, 3, 2), (5, 4, 0)])
@@ -178,7 +201,8 @@ class TestEngine:
         (jnp.float32, TOL_F32, 7, 16)],
         ids=["float32-8", "bfloat16-8", "float32-32", "float32-a-v5e's",
              "float32-odd-7"])
-    def test_chunked_prefill_then_decode(self, dtype, tol, width, n):
+    def test_chunked_prefill_then_decode(self, dtype, tol, width, n, lend,
+                                         monkeypatch):
         """18 tokens in chunks of 8: two full calls and one of 2, under 3
         past the chunk's edge, so its convolution tail reaches back into
         the call before and h crosses the edge; then 12 decode rounds
@@ -198,18 +222,23 @@ class TestEngine:
             assert width == 16
             n = width + 2
         assert n % width == 2
-        eng = _engine(cfg, params, prefill_chunk=width)
-        logits = _recorded(eng)
+        eng = lend(_shared_engine(dtype, width))
+        logits = _recorded(eng, monkeypatch)
+        was = eng.stats_snapshot()
         req = eng.requests[eng.add_request(_tokens(n, 4), 13, GREEDY)]
         eng.run_to_completion()
         assert _worst_gap(params, req, logits) < tol
-        state = eng.stats_snapshot()["state"]
-        assert state["resets"] == 1 and state["dropped"] == 0
+        now = eng.stats_snapshot()
+        state = {k: now["state"][k] - was["state"][k]
+                 for k in ("resets", "dropped", "prefill_scans")}
         calls = -(-n // width)
-        assert state["prefill_scans"] == calls * cfg.num_ssm_layers
-        assert eng.stats_snapshot()["prefill"] == {
-            "calls": calls, "tokens": n, "width": width,
-            "fill_share": round(n / (calls * width), 4)}
+        assert state == {"resets": 1, "dropped": 0,
+                         "prefill_scans": calls * cfg.num_ssm_layers}
+        assert now["prefill"]["calls"] - was["prefill"]["calls"] == calls
+        assert now["prefill"]["tokens"] - was["prefill"]["tokens"] == n
+        assert now["prefill"]["width"] == width
+        assert now["prefill"]["fill_share"] == round(
+            now["prefill"]["tokens"] / (now["prefill"]["calls"] * width), 4)
 
     def test_chunk_scan_in_blocks(self, monkeypatch):
         """A call wider than the scan's block (ISSUE 35: a prefill call of
@@ -219,19 +248,19 @@ class TestEngine:
         from megatronapp_tpu.transformer import ssm
         monkeypatch.setattr(ssm, "SCAN_BLOCK", 3)
         cfg, params = _model()
-        eng = _engine(cfg, params, prefill_chunk=8)
-        logits = _recorded(eng)
+        eng = _engine(cfg, params)      # its own: another program
+        logits = _recorded(eng, monkeypatch)
         req = eng.requests[eng.add_request(_tokens(18, 4), 13, GREEDY)]
         eng.run_to_completion()
         assert _worst_gap(params, req, logits) < TOL_F32
 
-    def test_continuous_batching_and_slot_reuse(self):
+    def test_continuous_batching_and_slot_reuse(self, eng, monkeypatch):
         """Requests of different lengths admitted at different steps; the
         fourth runs in the slot the first left, whose state it must not
         see; a slot that idles while others decode keeps its state."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
-        logits = _recorded(eng)
+        _, params = _model()
+        logits = _recorded(eng, monkeypatch)
+        resets = eng.stats_snapshot()["state"]["resets"]
         def add(n, seed, new):
             return eng.requests[eng.add_request(_tokens(n, seed), new,
                                                 GREEDY)]
@@ -256,47 +285,47 @@ class TestEngine:
         eng.run_to_completion()
         for req in reqs:
             assert _worst_gap(params, req, logits) < TOL_F32
-        assert eng.stats_snapshot()["state"]["resets"] == 4
+        assert eng.stats_snapshot()["state"]["resets"] - resets == 4
 
-    def test_preempted_request_is_recomputed(self):
+    def test_preempted_request_is_recomputed(self, eng):
         """A pool too small for its load preempts; the state goes with the
         slot and the request's tokens are those of an unpreempted run."""
-        cfg, params = _model()
         prompts = [_tokens(10, 20), _tokens(9, 21)]
 
-        def run(num_blocks):
-            eng = _engine(cfg, params, max_batch=2, num_blocks=num_blocks)
+        def run(eng):
             rids = [eng.add_request(p, 12, GREEDY) for p in prompts]
             out = eng.run_to_completion()
-            return [out[r].tolist() for r in rids], eng
+            return [out[r].tolist() for r in rids]
 
-        whole, eng = run(24)
-        assert eng.pool.stats["preemptions"] == 0
-        tight, eng = run(8)
+        preemptions = eng.pool.stats["preemptions"]
+        whole = run(eng)
+        assert eng.pool.stats["preemptions"] == preemptions
+        # its own: a pool of 8 blocks is what preempts
+        eng = _engine(*_model(), max_batch=2, num_blocks=8)
+        tight = run(eng)
         assert eng.pool.stats["preemptions"] >= 1
         state = eng.stats_snapshot()["state"]
         assert state["dropped"] == eng.pool.stats["preemptions"]
         assert state["resets"] == 2 + state["dropped"]
         assert tight == whole
 
-    def test_kv_pools_hold_the_attention_layers_planes(self):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_kv_pools_hold_the_attention_layers_planes(self, eng):
         k, v = eng.pool.pages
         assert k.shape == v.shape == (2, 24, 4, 1, 16)
         ssm, conv = eng.pool.state
         assert ssm.shape == (6, 3, 8, 128) and ssm.dtype == jnp.float32
         assert conv.shape == (6, 3, 3 * 128)
+        was = np.asarray(k)
         req = eng.requests[eng.add_request(_tokens(5, 30), 3, GREEDY)]
         eng.step()
         blocks = eng.pool.page_table[req.slot][:2].copy()
         eng.run_to_completion()
         k = np.asarray(eng.pool.pages[0])
-        # 5 prompt rows and 2 decoded rows in each attention layer's plane
+        # 5 prompt rows and 2 decoded rows written in each attention
+        # layer's plane (whatever an earlier request left there)
         for plane in range(2):
-            rows = k[plane, blocks].reshape(8, -1)
-            assert (np.abs(rows).max(axis=1) > 0).tolist() == [True] * 7 \
-                + [False]
+            rows = (k != was)[plane, blocks].reshape(8, -1)
+            assert rows.any(axis=1).tolist() == [True] * 7 + [False]
         stats = eng.stats_snapshot()
         assert stats["pool"]["bytes_per_block"] == \
             4 * MODEL.kv_bytes_per_token(TINY, "float32")
@@ -306,11 +335,10 @@ class TestEngine:
             24 * stats["pool"]["bytes_per_block"] \
             + 3 * stats["state"]["bytes_per_slot"]
 
-    def test_state_bytes_are_the_stated_type(self):
+    def test_state_bytes_are_the_stated_type(self, lend):
         """What cells/serve_closed_state.py holds the engine to: h float32,
         the tail in the compute type."""
-        cfg, params = _model(jnp.bfloat16)
-        eng = _engine(cfg, params)
+        eng = lend(_shared_engine(jnp.bfloat16))
         stated = {**TINY, "serve": {"params_dtype": "bfloat16"}}
         assert eng.stats_snapshot()["state"]["bytes_per_slot"] == \
             MODEL.state_bytes_per_slot(stated, "float32") == \
@@ -341,9 +369,7 @@ class TestRefusals:
     """What would need a snapshot of the recurrent state refuses, once, in
     words (ROADMAP M4 holds what remains)."""
 
-    def test_prefix_reuse_is_off_and_said(self):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_prefix_reuse_is_off_and_said(self, eng):
         assert eng.pool.enable_prefix_caching is False
         assert "prefix reuse off" in eng.startup_line()
         prompt = _tokens(16, 40)
@@ -367,9 +393,7 @@ class TestRefusals:
 
     @pytest.mark.parametrize("call", ["export_request", "import_request",
                                       "adopt_request"])
-    def test_moving_a_request_refuses(self, call):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_moving_a_request_refuses(self, call, eng):
         rid = eng.add_request(_tokens(6, 41), 4, GREEDY)
         eng.step()
         args = {"export_request": (rid,), "import_request": ({},),
@@ -377,6 +401,7 @@ class TestRefusals:
         with pytest.raises(ValueError, match="state snapshots"):
             getattr(eng, call)(*args)
         assert eng.park_request(rid) is False       # no spill tier to park in
+        eng.run_to_completion()
 
     def test_staging_slots_refuse(self):
         from megatronapp_tpu.inference.paged_cache import PagedKVCache
@@ -434,11 +459,9 @@ class TestKernel:
             np.testing.assert_array_equal(np.asarray(new[other]),
                                           np.asarray(pool[other]))
 
-    def test_decode_step_runs_one_kernel_a_layer_loop(self):
+    def test_decode_step_runs_one_kernel_a_layer_loop(self, eng):
         """The traced decode step holds ssm_update once a scanned run of
         state-space layers (not once a layer: the stack is not unrolled)."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         # a layer: ssm_update, or paged_append x 2 + paged_decode
         assert disp["kernels"] == 6 * 1 + 2 * 3, disp

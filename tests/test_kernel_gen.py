@@ -32,7 +32,7 @@ from megatronapp_tpu.config.parallel_config import TP_AXIS, ParallelConfig
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 from megatronapp_tpu.ops.pallas import kernel_gen
 from megatronapp_tpu.ops.pallas.kernel_gen import (
     _NEG_INF, _dequant_block, _interpret, _pages_vmem_bytes,
@@ -1177,13 +1177,7 @@ def _stream(cfg, params, prompts, max_new=8, **kw):
     return [res[i].tolist() for i in ids], eng
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = np.asarray(prompt)[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 class TestPagedEngine:

@@ -47,13 +47,7 @@ def _gqa_cfg():
         compute_dtype=jnp.float32, remat_policy="none")
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = prompt[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 class TestQuantizedKernels:

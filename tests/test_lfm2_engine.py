@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from perfbench import manifest
 from test_lfm2_moe import (
     GREEDY, MODEL, PUBLISHED, ROOT, TINY, TOL_BF16, TOL_F32, _engine, _model,
-    _recorded, _tokens, _worst_gap,
+    _recorded, _shared_engine, _tokens, _worst_gap, eng,  # noqa: F401
 )
 
 
@@ -26,33 +26,37 @@ class TestEngine:
         (jnp.float32, TOL_F32, 1, 4), (jnp.float32, TOL_F32, 2, 5)],
         ids=["float32-8", "bfloat16-8", "float32-edge-1-after-the-start",
              "float32-edge-2-after-the-start"])
-    def test_chunked_prefill_then_decode(self, dtype, tol, width, n):
+    def test_chunked_prefill_then_decode(self, dtype, tol, width, n, lend,
+                                         monkeypatch):
         """18 tokens in calls of 8: two full ones and one of 2, whose taps
         reach back into the call before; calls of 1 and 2, so that a call's
         edge lies 1 and 2 tokens after the sequence's start, the tail's
         zeros are read and a new tail is one old column and one new. Then
         12 decode rounds through the cached columns.
         The logits at every position are the reference's."""
-        cfg, params = _model(dtype)
-        eng = _engine(cfg, params, prefill_chunk=width)
-        logits = _recorded(eng)
+        _, params = _model(dtype)
+        eng = lend(_shared_engine(dtype, width))
+        logits = _recorded(eng, monkeypatch)
+        was = eng.stats_snapshot()["state"]
         req = eng.requests[eng.add_request(_tokens(n, 4), 13, GREEDY)]
         eng.run_to_completion()
         assert _worst_gap(params, req, logits) < tol
         state = eng.stats_snapshot()["state"]
         calls = -(-n // width)
-        assert (state["kind"], state["layers"], state["resets"],
-                state["dropped"], state["prefill_scans"]) == (
+        assert (state["kind"], state["layers"],
+                state["resets"] - was["resets"],
+                state["dropped"] - was["dropped"],
+                state["prefill_scans"] - was["prefill_scans"]) == (
                     "conv", 4, 1, 0, calls * 4)
         eng.pool.audit()
 
-    def test_continuous_batching_and_slot_reuse(self):
+    def test_continuous_batching_and_slot_reuse(self, eng, monkeypatch):
         """Requests of different lengths admitted at different steps; the
         fourth runs in the slot the first left, whose columns it must not
         see; a slot that idles while others decode keeps its columns."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
-        logits = _recorded(eng)
+        _, params = _model()
+        logits = _recorded(eng, monkeypatch)
+        resets = eng.stats_snapshot()["state"]["resets"]
 
         def add(n, seed, new):
             return eng.requests[eng.add_request(_tokens(n, seed), new,
@@ -75,22 +79,23 @@ class TestEngine:
         eng.run_to_completion()
         for req in reqs:
             assert _worst_gap(params, req, logits) < TOL_F32
-        assert eng.stats_snapshot()["state"]["resets"] == 4
+        assert eng.stats_snapshot()["state"]["resets"] - resets == 4
         eng.pool.audit()
 
-    def test_preempted_request_is_recomputed(self):
-        cfg, params = _model()
+    def test_preempted_request_is_recomputed(self, eng):
         prompts = [_tokens(10, 20), _tokens(9, 21)]
 
-        def run(num_blocks):
-            eng = _engine(cfg, params, max_batch=2, num_blocks=num_blocks)
+        def run(eng):
             rids = [eng.add_request(p, 12, GREEDY) for p in prompts]
             out = eng.run_to_completion()
-            return [out[r].tolist() for r in rids], eng
+            return [out[r].tolist() for r in rids]
 
-        whole, eng = run(24)
-        assert eng.pool.stats["preemptions"] == 0
-        tight, eng = run(8)
+        preemptions = eng.pool.stats["preemptions"]
+        whole = run(eng)
+        assert eng.pool.stats["preemptions"] == preemptions
+        # its own: a pool of 8 blocks is what preempts
+        eng = _engine(*_model(), max_batch=2, num_blocks=8)
+        tight = run(eng)
         assert eng.pool.stats["preemptions"] >= 1
         state = eng.stats_snapshot()["state"]
         assert state["dropped"] == eng.pool.stats["preemptions"]
@@ -98,13 +103,12 @@ class TestEngine:
         assert tight == whole
         eng.pool.audit()
 
-    def test_the_tenant_is_the_tails_alone(self):
+    def test_the_tenant_is_the_tails_alone(self, eng):
         """The pools: one plane for the one attention layer, two key/value
         heads; the second tenant [conv layers, slots, 2 x H] in the compute
         type and no h; its bytes in the pool's total; a finished slot's two
         columns a layer are the reference's."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+        _, params = _model()
         k, v = eng.pool.pages
         assert k.shape == v.shape == (1, 24, 4, 2, 16)
         tails, = eng.pool.state
@@ -137,14 +141,17 @@ class TestEngine:
         assert MODEL.state_bytes_per_slot(PUBLISHED, "bfloat16") == 57_344
         assert round(MODEL.params_per_token(PUBLISHED) / 1e6) == 648
 
-    def test_moe_counters_against_a_count_by_hand(self):
+    def test_moe_counters_against_a_count_by_hand(self, eng):
         """One request alone: every decode round routes 1 token through 4
         MoE layers to 2 distinct experts each. Then two together. The
         traced decode step cuts no layer's experts out of the stacks (they
         are read through the layer id), holds the paged kernels once a
         scanned run, and the engine says what it runs."""
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+        was = eng.stats_snapshot()["moe"]
+
+        def moe_since():     # this case's: the counts (experts_here is none)
+            return {k: v if k == "experts_here" else v - was[k]
+                    for k, v in eng.stats_snapshot()["moe"].items()}
         disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
         assert disp["expert_stack_slices"] == 0, disp
         assert disp["scatters"] == 0, disp      # rows move by gathers alone
@@ -156,7 +163,7 @@ class TestEngine:
             assert word in line, line
         eng.add_request(_tokens(6, 40), 8, GREEDY)
         eng.run_to_completion()
-        moe = eng.stats_snapshot()["moe"]
+        moe = moe_since()
         rounds = 7          # the first token is the prefill's
         assert moe == {
             "decode_rounds": rounds, "tokens": rounds,
@@ -168,7 +175,7 @@ class TestEngine:
         for n in (5, 9):
             eng.add_request(_tokens(n, 41 + n), 6, GREEDY)
         eng.run_to_completion()
-        moe = eng.stats_snapshot()["moe"]
+        moe = moe_since()
         assert moe["tokens"] == rounds + 2 * 5
         assert moe["assignments"] == moe["tokens"] * 2 * 4
         cell = manifest.load_module("cells", "serve_closed_conv")
@@ -177,7 +184,7 @@ class TestEngine:
                                      TINY)
         assert cell.counter_problems(dict(moe, experts_here=4), TINY)
 
-    def test_a_cancel_that_races_admission_searches_again(self):
+    def test_a_cancel_that_races_admission_searches_again(self, eng):
         """The stepper's admission pops from the waiting queue while a
         canceller's thread searches it; a deque that changed under the
         search raises (RuntimeError from `in`, IndexError from `remove`),
@@ -194,8 +201,6 @@ class TestEngine:
                     raise self.raises.pop()
                 return super().remove(item)
 
-        cfg, params = _model()
-        eng = _engine(cfg, params, max_batch=1)
         rids = [eng.add_request(_tokens(5, s), 6, GREEDY) for s in (1, 2)]
         eng.waiting = Changing(eng.waiting)
         assert eng.abort_request(rids[1]) == "waiting"
@@ -205,6 +210,7 @@ class TestEngine:
         assert eng.abort_request(rids[1]) is None       # already aborted
         eng.run_to_completion()
         eng.pool.audit()
+        eng.waiting = deque(eng.waiting)
 
 
 # What the shared layer loop, router and tenant trace for the models that do

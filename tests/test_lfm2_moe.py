@@ -7,6 +7,7 @@ which a token takes 2 by sigmoid scores and a NON-ZERO seeded selection
 bias, 4 query and 2 key/value heads of 16. Each test fails if the mechanism
 it names is left out."""
 import dataclasses
+import functools
 import json
 import os
 
@@ -18,8 +19,9 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = manifest.load_module("models", "lfm2_moe")
@@ -92,9 +94,23 @@ def _engine(cfg, params, **kw):
     return DynamicInferenceEngine(params, cfg, **kw)
 
 
-def _recorded(eng):
-    """Wrap the engine's two steps: logits[rid] collects, position by
-    position, the logits every call computed for that request."""
+@functools.cache
+def _shared_engine(dtype=jnp.float32, width=8):
+    """The engine of `_model(dtype)` whose prefill calls are `width` wide:
+    one a program, compiled once, for the cases (tests/test_lfm2_engine.py's)
+    that leave it as they found it. They take it through `lend`
+    (conftest.py)."""
+    return _engine(*_model(dtype), prefill_chunk=width)
+
+
+@pytest.fixture
+def eng(lend):
+    return lend(_shared_engine())
+
+
+def _recorded(eng, monkeypatch):
+    """Wrap the engine's two steps for the case: logits[rid] collects,
+    position by position, the logits every call computed for that request."""
     logits = {}
     mq, dec = eng._mq_step, eng._decode
 
@@ -116,7 +132,8 @@ def _recorded(eng):
                 np.asarray(out[0][slot:slot + 1], np.float32))
         return out
 
-    eng._mq_step, eng._decode = mq_step, decode
+    monkeypatch.setattr(eng, "_mq_step", mq_step)
+    monkeypatch.setattr(eng, "_decode", decode)
     return logits
 
 

@@ -7,6 +7,7 @@ random weights: 2 double layers, hidden 64, 4 heads, ranks 32/16, 8 + 4
 experts of which 4 are held, top-3. Each test fails if the mechanism it
 names is left out."""
 import dataclasses
+import functools
 import json
 import os
 import types
@@ -22,11 +23,13 @@ from megatronapp_tpu.inference.dynamic_engine import (
     DynamicInferenceEngine, _paged_decode_step, _paged_multiquery_step,
 )
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 from megatronapp_tpu.models.presets import PRESETS
 from megatronapp_tpu.transformer import moe
 from megatronapp_tpu.transformer.block import layer_forward
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -50,11 +53,12 @@ TOL_F32 = 1e-4
 TOL_BF16 = 5e-2
 
 
-def _model(compute_dtype=jnp.float32, bias_seed=None, **kw):
-    """(cfg, params); bias_seed draws a non-zero router bias b (the
+@functools.cache
+def _model(compute_dtype=jnp.float32, bias_seed=None):
+    """(cfg, params), built once for the cases that ask for the same (none
+    writes into the tree); bias_seed draws a non-zero router bias b (the
     configuration assumes zeros: seeded weights route evenly already)."""
-    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype,
-                             **kw)
+    cfg = MODEL.model_config(TINY, "float32", compute_dtype=compute_dtype)
     params = MODEL.init_params(cfg, seed=5)
     if bias_seed is not None:
         mp = params["block"]["first"]["moe"]
@@ -75,6 +79,14 @@ def _tokens(shape, seed=0):
         0, TINY["vocab_size"], shape).astype(np.int32)
 
 
+@functools.cache
+def _paged_steps(compute_dtype, max_len):
+    """The two jitted steps of TINY in a compute type, compiled once."""
+    cfg, _ = _model(compute_dtype)
+    return (jax.jit(lambda *a: _paged_multiquery_step(*a, cfg, max_len)),
+            jax.jit(lambda *a: _paged_decode_step(*a, cfg, max_len)))
+
+
 def _prefill_then_decode(cfg, params, prompt, n_new, chunk=8, bs=4):
     """The engine's two step functions on a hand-made page table: the
     prompt in [1, chunk] calls (the last one ragged), then n_new greedy
@@ -87,8 +99,7 @@ def _prefill_then_decode(cfg, params, prompt, n_new, chunk=8, bs=4):
              jnp.zeros((cfg.kv_planes, nb, bs, cfg.qk_pos_emb_head_dim), dt))
     table = jnp.arange(nb, dtype=jnp.int32)[None]
     active = jnp.ones((1,), bool)
-    prefill = jax.jit(lambda *a: _paged_multiquery_step(*a, cfg, max_len))
-    decode = jax.jit(lambda *a: _paged_decode_step(*a, cfg, max_len))
+    prefill, decode = _paged_steps(dt, max_len)
     rows, pos, counts = [], 0, None
     while pos < len(prompt):
         count = min(chunk, len(prompt) - pos)
@@ -215,12 +226,9 @@ class TestPagedTwoPlanes:
         assert np.allclose(rms, (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5,
                            rtol=1e-3)
 
-    def test_engine_pool_has_two_planes_a_layer(self):
+    def test_engine_pool_has_two_planes_a_layer(self, lend):
         cfg, params = _model()
-        eng = DynamicInferenceEngine(params, cfg, max_batch=2,
-                                     max_seq_len=64, paged=True,
-                                     num_blocks=24, block_size=4,
-                                     prefill_chunk=8)
+        eng = lend(_shared_engine())
         line = eng.startup_line()
         for said in ("2 double layers x 2 attention sublayers = 4 planes",
                      "experts=4 held (0..3) of 8 published + 4 zero-compute",
@@ -372,6 +380,16 @@ class TestExpertShares:
         assert runner.share_problems(dict(got, experts_here=8), TINY)
 
 
+@functools.cache
+def _shared_engine():
+    """The engine of `_model()`, compiled once, for the cases that leave it
+    as they found it (conftest.py `lend`)."""
+    cfg, params = _model()
+    return DynamicInferenceEngine(params, cfg, max_batch=2, max_seq_len=64,
+                                  paged=True, num_blocks=24, block_size=4,
+                                  prefill_chunk=8)
+
+
 class _Ctx(types.SimpleNamespace):
     ep, cp, tp, dp = 2, 1, 1, 1
 
@@ -430,7 +448,7 @@ def test_what_it_cannot_do_yet_refuses_in_words(build, error, said):
         build()
 
 
-def test_deepseek_decode_step_did_not_grow_an_operation():
+def test_deepseek_decode_step_did_not_grow_an_operation(lend):
     """The shared MLA and MoE code serves DeepSeek-V2-Lite with the new
     fields off: its traced decode step (tiny widths, 1 dense + 2 MoE layers)
     launches what it launched before the double layer came (PR 38: 1,345
@@ -467,10 +485,8 @@ def test_deepseek_decode_step_did_not_grow_an_operation():
     assert moe_stats["experts_here"] == 8          # every expert is held
     # and the double layer's own step: two latent kernels a layer, the
     # held experts' stacks read in place
-    cfg, params = _model()
-    eng = DynamicInferenceEngine(params, cfg, max_batch=2, max_seq_len=64,
-                                 paged=True, num_blocks=16, block_size=4,
-                                 prefill_chunk=8)
+    cfg, _ = _model()
+    eng = lend(_shared_engine())
     disp = eng.stats_snapshot(include_dispatch=True)["decode_dispatch"]
     assert cfg.kv_planes == 4
     assert disp["kernels"] == cfg.kv_planes + 2 * cfg.num_layers

@@ -73,9 +73,10 @@ def _program(cfg, params, micro):
                         jnp.asarray(micro["labels"]),
                         jnp.asarray(micro["loss_mask"]), cfg,
                         segment_ids=jnp.asarray(micro["segment_ids"]))
+    # one program (eagerly: a compile a primitive, 20 s and more a case)
     with jax.default_matmul_precision("highest"):
-        (value, metrics), grads = jax.value_and_grad(loss, has_aux=True)(
-            params)
+        (value, metrics), grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(params)
     return float(value), metrics, grads
 
 
@@ -91,13 +92,28 @@ def seeded():
     return config, cfg, model.init_params(cfg, 7)
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
-def test_loss_and_every_leafs_gradient_match_the_reference(seeded, packed):
+@pytest.fixture(scope="module")
+def packed(seeded):
+    """The packed micro-batch every case below reads, the program's loss,
+    metrics and gradients on it and the reference's loss and gradients:
+    each worked out once."""
     config, cfg, params = seeded
-    micro = _micro(config, packed)
-    value, metrics, grads = _program(cfg, params, micro)
-    ref_value, ref_grads = model.reference_loss_and_grads(params, config,
-                                                          micro)
+    micro = _micro(config, packed=True)
+    return (micro, _program(cfg, params, micro),
+            model.reference_loss_and_grads(params, config, micro))
+
+
+@pytest.mark.parametrize("is_packed", [False, True], ids=["plain", "packed"])
+def test_loss_and_every_leafs_gradient_match_the_reference(seeded, packed,
+                                                           is_packed):
+    config, cfg, params = seeded
+    if is_packed:
+        _, (value, metrics, grads), (ref_value, ref_grads) = packed
+    else:
+        micro = _micro(config, packed=False)
+        value, metrics, grads = _program(cfg, params, micro)
+        ref_value, ref_grads = model.reference_loss_and_grads(params, config,
+                                                              micro)
     assert abs(value - ref_value) < 2e-6
     mine = dict(jax.tree_util.tree_leaves_with_path(grads))
     theirs = dict(jax.tree_util.tree_leaves_with_path(ref_grads))
@@ -121,28 +137,24 @@ GRAD_TOL, LOSS_TOL = 1e-4, 1e-5
 
 
 @pytest.mark.parametrize("control", model.CONTROLS)
-def test_each_wrong_model_is_past_the_tolerance(seeded, control):
+def test_each_wrong_model_is_past_the_tolerance(seeded, packed, control):
     config, cfg, params = seeded
-    micro = _micro(config, packed=True)
-    value, _, grads = _program(cfg, params, micro)
+    micro, (value, _, grads), (right_value, right_grads) = packed
     wrong_value, wrong_grads = model.reference_loss_and_grads(
         params, config, micro, control=control)
     moved = _norm(jax.tree.map(jnp.subtract, grads, wrong_grads)) / _norm(
         grads)
     assert moved > GRAD_TOL or abs(value - wrong_value) > LOSS_TOL
-    right_value, right_grads = model.reference_loss_and_grads(
-        params, config, micro)
     assert _norm(jax.tree.map(jnp.subtract, grads, right_grads)) / _norm(
         grads) < GRAD_TOL / 50
     assert abs(value - right_value) < LOSS_TOL / 5
 
 
-def test_the_reference_in_bf16_is_another_reading(seeded):
+def test_the_reference_in_bf16_is_another_reading(seeded, packed):
     """The precision control that the cell's limits have to refuse on the
     chip: the same equations on bf16 arrays with one-pass products."""
     config, _, params = seeded
-    micro = _micro(config, packed=True)
-    value, grads = model.reference_loss_and_grads(params, config, micro)
+    micro, _, (value, grads) = packed
     low_value, low_grads = model.reference_loss_and_grads(
         params, config, micro, compute="bfloat16")
     assert 1e-4 < abs(_norm(low_grads) / _norm(grads) - 1) < 0.1
@@ -368,7 +380,8 @@ def test_the_steps_sums_are_totals_and_its_copies_change_nothing():
                                out[False][1]["grad_norm"], rtol=1e-5)
 
 
-def test_the_precision_controls_on_either_side_of_the_runners_limit(seeded):
+def test_the_precision_controls_on_either_side_of_the_runners_limit(seeded,
+                                                                    packed):
     """The cell's runner holds |g - r| / |r| of the first gradient to
     FIRST_GRAD_GAP_TOL. At a small size too, the reference in the precision
     below the configuration's (operands in float8) lies beyond it and the
@@ -377,8 +390,7 @@ def test_the_precision_controls_on_either_side_of_the_runners_limit(seeded):
     controls through the cell's own command on the chip."""
     config, _, params = seeded
     limit = manifest.load_module("cells", "pretrain_share").FIRST_GRAD_GAP_TOL
-    micro = _micro(config, packed=True)
-    _, right = model.reference_loss_and_grads(params, config, micro)
+    micro, _, (_, right) = packed
     size = np.sqrt(sum(float(jnp.sum(jnp.square(g)))
                        for g in jax.tree.leaves(right)))
     gaps = {}

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward
 from megatronapp_tpu.models.presets import (
     NEMOTRON_3_NANO_PATTERN, PRESETS,
 )
@@ -32,6 +31,8 @@ from megatronapp_tpu.ops.pallas.ssm_update import (
 from megatronapp_tpu.config.transformer_config import PATTERN_STACKS
 from megatronapp_tpu.transformer import block, ssm
 from perfbench import manifest
+
+from jitted import gpt_forward  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = manifest.load_module("models", "nemotron_h")
@@ -97,9 +98,16 @@ def _engine(cfg, params, **kw):
     return DynamicInferenceEngine(params, cfg, **kw)
 
 
-def _recorded(eng):
-    """Wrap the engine's two steps: logits[rid] collects, position by
-    position, the logits every call computed for that request."""
+@functools.cache
+def _shared_engine(dtype=jnp.float32):
+    """The engine of `_model(dtype)`, compiled once, for the cases that
+    leave it as they found it. They take it through `lend` (conftest.py)."""
+    return _engine(*_model(dtype))
+
+
+def _recorded(eng, monkeypatch):
+    """Wrap the engine's two steps for the case: logits[rid] collects,
+    position by position, the logits every call computed for that request."""
     logits = {}
     mq, dec = eng._mq_step, eng._decode
 
@@ -121,20 +129,20 @@ def _recorded(eng):
                 np.asarray(out[0][slot:slot + 1], np.float32))
         return out
 
-    eng._mq_step, eng._decode = mq_step, decode
+    monkeypatch.setattr(eng, "_mq_step", mq_step)
+    monkeypatch.setattr(eng, "_decode", decode)
     return logits
 
 
-def _served(cfg, params, prompts, new_tokens=6, **kw):
-    """{request: (its tokens but the last, its logits position by
-    position)} of `prompts` served together through the paged pools."""
-    eng = _engine(cfg, params, **kw)
-    logits = _recorded(eng)
+def _served(eng, monkeypatch, prompts, new_tokens=6):
+    """[(its tokens but the last, its logits position by position)] of
+    `prompts` served together through `eng`'s paged pools."""
+    logits = _recorded(eng, monkeypatch)
     reqs = [eng.add_request(p, new_tokens, GREEDY) for p in prompts]
     while eng.has_work:
         eng.step()
-    return eng, [(eng.requests[r].tokens[:-1],
-                  np.concatenate(logits[r])) for r in reqs]
+    return [(eng.requests[r].tokens[:-1],
+             np.concatenate(logits[r])) for r in reqs]
 
 
 # ---- the pattern -----------------------------------------------------------
@@ -372,20 +380,26 @@ class TestForward:
 
 
 class TestPagedEngine:
-    def test_prefill_then_decode_against_the_reference(self):
+    def test_prefill_then_decode_against_the_reference(self, lend,
+                                                       monkeypatch):
         """Prompts that end inside a call, on a call's edge, past a chunk's
         edge (calls of 8, chunks of 16: 21 crosses both) and in one call,
         served together and decoded through the pools: every position's
         logits against the reference's full forward of the same tokens."""
-        cfg, params = _model()
+        _, params = _model()
         prompts = [_tokens(n, n) for n in (21, 16, 5)]
-        eng, served = _served(cfg, params, prompts)
+        eng = lend(_shared_engine())
+        was = eng.stats_snapshot()["moe"]
+        served = _served(eng, monkeypatch, prompts)
         for seq, got in served:
             assert got.shape[0] == len(seq)
             assert np.abs(got - _reference(params, seq[None])[0]).max() \
                 < TOL_F32
         stats = eng.stats_snapshot()
-        state, moe = stats["state"], stats["moe"]
+        # this run's: the counts (what the engine holds is no count)
+        state, moe = stats["state"], {
+            k: v if k == "experts_here" else v - was[k]
+            for k, v in stats["moe"].items()}
         assert (state["kind"], state["mixer"], state["layers"]) == (
             "ssm", "mamba2", 3)
         assert state["bytes_per_slot"] == MODEL.state_bytes_per_slot(
@@ -399,16 +413,18 @@ class TestPagedEngine:
         assert moe["experts_here"] == 4
         assert moe["expert_pairs_possible"] == 3 * 4 * moe["decode_rounds"]
 
-    def test_bf16_engine_against_the_float32_reference(self):
-        cfg, params = _model(jnp.bfloat16)
-        _, served = _served(cfg, params, [_tokens(21, 7), _tokens(9, 8)])
+    def test_bf16_engine_against_the_float32_reference(self, lend,
+                                                       monkeypatch):
+        _, params = _model(jnp.bfloat16)
+        served = _served(lend(_shared_engine(jnp.bfloat16)), monkeypatch,
+                         [_tokens(21, 7), _tokens(9, 8)])
         for seq, got in served:
             assert np.abs(got - _reference(params, seq[None])[0]).max() \
                 < TOL_BF16
 
-    def test_the_state_read_back_is_the_references(self):
-        cfg, params = _model()
-        eng = _engine(cfg, params)
+    def test_the_state_read_back_is_the_references(self, lend):
+        _, params = _model()
+        eng = lend(_shared_engine())
         prompt = _tokens(19, 4)
         rid = eng.add_request(prompt, 4, GREEDY)
         while eng.has_work:

@@ -203,8 +203,22 @@ def _block_sha(name):
     return digest.hexdigest()[:16]
 
 
+@pytest.fixture(scope="module")
+def as_the_benchmark_compiles():
+    """A draw's last bits follow the compiler: the benchmark's processes
+    compile with XLA's optimisations on, this one without most of them
+    (tests/conftest.py), and a program compiled before the switch is kept,
+    so the caches go too."""
+    jax.config.update("jax_disable_most_optimizations", False)
+    jax.clear_caches()
+    yield
+    jax.config.update("jax_disable_most_optimizations", True)
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("name", sorted(PARENT_BLOCK_SHA))
-def test_the_seeded_block_is_the_parents_bit_for_bit(name):
+def test_the_seeded_block_is_the_parents_bit_for_bit(
+        name, as_the_benchmark_compiles):
     assert _block_sha(name) == PARENT_BLOCK_SHA[name]
 
 

@@ -18,7 +18,7 @@ from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
 from megatronapp_tpu.inference.paged_cache import PagedKVCache, cdiv
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 
 
 def _gqa_cfg():
@@ -37,13 +37,7 @@ def _mla_cfg():
         compute_dtype=jnp.float32, remat_policy="none")
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = prompt[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 class TestPagedAttentionKernel:

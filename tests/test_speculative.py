@@ -21,7 +21,7 @@ import pytest
 from megatronapp_tpu.config.transformer_config import TransformerConfig
 from megatronapp_tpu.inference.dynamic_engine import DynamicInferenceEngine
 from megatronapp_tpu.inference.engine import SamplingParams
-from megatronapp_tpu.models.gpt import gpt_forward, init_gpt_params
+from megatronapp_tpu.models.gpt import init_gpt_params
 
 
 def _cfg(mtp=False):
@@ -39,13 +39,7 @@ def model():
     return params, cfg
 
 
-def _greedy_oracle(params, cfg, prompt, n):
-    toks = prompt[None].copy()
-    for _ in range(n):
-        logits, _ = gpt_forward(params, jnp.asarray(toks), cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        toks = np.concatenate([toks, [[nxt]]], axis=1)
-    return toks[0].tolist()
+from jitted import greedy_oracle as _greedy_oracle  # noqa: E402
 
 
 def _prompts(n=4):
