@@ -42,6 +42,16 @@ ITER_RE = re.compile(r"iter\s+(\d+)/\s*\d+ \| loss (\S+) \| grad_norm (\S+) "
 FIRST_LOSS_TOL = 1e-3
 
 
+def weights_seed(env) -> int:
+    """The seed the program draws its weights from: the traffic file's
+    ``weights_seed`` where it names one, else ``--seed``. A mix names one
+    where the WEIGHTS decide how much work a step is (a seeded router sends
+    a share of the picks to the held experts that is a property of the
+    draw: PERF.md section 6, PR 63); ``--seed`` then draws the token ids
+    alone, as it does for every serving cell's replayed trace."""
+    return int(env["traffic"].get("weights_seed", env["seed"]))
+
+
 class _Loop:
     """The harness's side of the training loop: the log sink that opens
     and closes the window, and the batch source that remembers the first
@@ -136,8 +146,8 @@ def run_cell(env) -> dict:
     train_cfg = TrainingConfig(
         micro_batch_size=tr["micro_batch_size"],
         global_batch_size=job["sequences_per_step"], seq_length=seq,
-        train_iters=10 ** 7, seed=env["seed"] % (2 ** 31), log_interval=1,
-        sharded_init=tr.get("sharded_init", False))
+        train_iters=10 ** 7, seed=weights_seed(env) % (2 ** 31),
+        log_interval=1, sharded_init=tr.get("sharded_init", False))
     opt_cfg = OptimizerConfig(lr=job["lr"], min_lr=job["min_lr"],
                               lr_warmup_iters=job["lr_warmup_iters"],
                               lr_decay_iters=job["lr_decay_iters"])
@@ -174,7 +184,8 @@ def run_cell(env) -> dict:
 
     # ---- the first step's loss against the plain reference ----------------
     t_ref = time.perf_counter()
-    params = model.init_params(model_cfg, env["seed"], env["devices"][0])
+    params = model.init_params(model_cfg, weights_seed(env),
+                               env["devices"][0])
     rows_per_micro = tr["micro_batch_size"] * (parallel.data_parallel or 1)
     micro_losses = []
     with jax.default_device(env["devices"][0]):

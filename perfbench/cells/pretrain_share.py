@@ -181,8 +181,9 @@ def _first_step_again(env, first_batch, ring):
     train_cfg = TrainingConfig(
         micro_batch_size=tr["micro_batch_size"],
         global_batch_size=job["sequences_per_step"], seq_length=seq,
-        train_iters=10 ** 7, seed=env["seed"] % (2 ** 31), log_interval=1,
-        exit_interval=1, sharded_init=tr.get("sharded_init", False))
+        train_iters=10 ** 7, seed=pretrain.weights_seed(env) % (2 ** 31),
+        log_interval=1, exit_interval=1,
+        sharded_init=tr.get("sharded_init", False))
     opt_cfg = OptimizerConfig(lr=job["lr"], min_lr=job["min_lr"],
                               lr_warmup_iters=job["lr_warmup_iters"],
                               lr_decay_iters=job["lr_decay_iters"])

@@ -8,7 +8,10 @@ drawn from the file's ``shape_seed``; ``--seed`` draws the token ids (and, in
 the runner, the weights). Runs with different seeds therefore do the same
 work at the same moments: with the sizes in an order of the seed's own, six
 runs of the closed loop spread by 12% where two runs of one seed agreed to
-four digits (PERF.md, PR 25).
+four digits (PERF.md, PR 25). Where the WEIGHTS decide how much work a step
+is (held experts under a seeded router), a training mix names a
+``weights_seed`` and ``--seed`` draws the token ids alone
+(``cells/pretrain.py::weights_seed``; PERF.md, PR 63).
 """
 
 from __future__ import annotations
